@@ -1,4 +1,4 @@
-"""Error taxonomy.
+"""Error classes.
 
 Single error family with typed control-flow variants, mirroring the reference's
 ``Error`` enum (ref: crates/arkflow-core/src/lib.rs:66-110). Two variants are

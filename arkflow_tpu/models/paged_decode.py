@@ -64,7 +64,6 @@ def _attend_paged(q, kp, vp, page_table, off, cfg: DecoderConfig,
     if kv_sharding is None:
         return paged_flash_attention(q, kp, vp, page_table, off,
                                      interpret=interpret)
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = kv_sharding.mesh
@@ -74,11 +73,11 @@ def _attend_paged(q, kp, vp, page_table, off, cfg: DecoderConfig,
         return paged_flash_attention(q_, kp_, vp_, table_, off_,
                                      interpret=interpret)
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(head_spec, kv_sharding.spec, kv_sharding.spec, P(), P()),
         out_specs=head_spec,
-        check_rep=False,
+        check_vma=False,
     )(q, kp, vp, page_table, off)
 
 

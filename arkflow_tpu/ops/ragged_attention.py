@@ -132,20 +132,36 @@ def ragged_flash_attention(q, k, v, lengths, *, causal: bool = False,
 # — a [B, P*page, heads, dh] copy of the whole context per layer per step —
 # then runs masked XLA attention over it. This kernel reads the page table
 # in place ("Ragged Paged Attention", PAPERS.md): the grid walks
-# (row, kv_head, page), the BlockSpec index map resolves each row's p-th
-# page through the scalar-prefetched table, and pages past the row's causal
+# (row, query tile, page), the BlockSpec index map resolves each row's p-th
+# page through the scalar-prefetched table, and pages past the tile's causal
 # bound resolve to the scratch page 0 so consecutive out-of-range steps
-# reuse one block copy and skip the math. GQA is folded into the query
-# tile: the ``group`` query heads sharing a KV head ride one [C*group, dh]
-# tile, so K/V are never repeated ``group``-fold in HBM or VMEM.
+# reuse one block copy and skip the math.
+#
+# Layout (what the TPU tiling accepts): a block takes ALL kv heads of a
+# page. The pool slice is viewed as [num_pages, page*kv_heads, dh] — a free
+# bitcast of the pool's HBM layout, rows ordered (slot, kv head) — and the
+# queries as [B, C*heads, dh], rows ordered (position, head): both views are
+# plain reshapes, and every block's last two dims equal the array's or are
+# (8k, dh). One [rows, dh] x [dh, page*kv_heads] product scores every query
+# head against every kv head of the page; entries whose kv head is not the
+# query head's own are masked with the causal bound. That is no more MXU or
+# VPU work than per-head [.., page]-wide products, which would fill only
+# page/128 of each lane tile, and GQA needs no ``jnp.repeat`` of K/V.
+
+#: folded query rows (positions x heads) per program: bounds VMEM whatever
+#: the chunk length is ([rows, 128] f32 score tiles of 512 KiB)
+_PAGED_ROWS = 1024
 
 
 def _paged_kernel(off_ref, table_ref, q_ref, k_ref, v_ref, o_ref,
-                  o_acc, m_acc, l_acc, *, page: int, group: int,
-                  pages_per: int):
+                  o_acc, m_acc, l_acc, *, page: int, kvh: int, heads: int,
+                  tile_c: int, pages_per: int):
     bi = pl.program_id(0)
+    ci = pl.program_id(1)
     pi = pl.program_id(2)
-    ql, d = q_ref.shape[2], q_ref.shape[3]
+    rows, d = q_ref.shape[1], q_ref.shape[2]
+    cols = page * kvh
+    group = heads // kvh
 
     @pl.when(pi == 0)
     def _init():
@@ -153,40 +169,47 @@ def _paged_kernel(off_ref, table_ref, q_ref, k_ref, v_ref, o_ref,
         m_acc[:] = jnp.full_like(m_acc, _NEG)
         l_acc[:] = jnp.zeros_like(l_acc)
 
-    off = off_ref[bi]
-    # folded query i is (chunk position i // group, q head i % group) at
-    # absolute position off + i//group; the row's last attendable key is
-    # off + C - 1, so later pages hold no admissible key for any query
-    max_pos = off + (ql // group - 1)
+    # folded row r is (chunk position ci*tile_c + r // heads, q head
+    # r % heads) at absolute position off + that; the tile's last attendable
+    # key is its last query's position, so later pages hold no admissible key
+    first = off_ref[bi] + ci * tile_c
+    max_pos = first + (tile_c - 1)
 
     @pl.when(pi * page <= max_pos)
     def _acc():
-        q = q_ref[0, 0].astype(jnp.float32)                       # [QL, D]
-        k = k_ref[0, :, 0, :].astype(jnp.float32)                 # [page, D]
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        q = q_ref[0].astype(jnp.float32)                          # [rows, D]
+        k = k_ref[0].astype(jnp.float32)                          # [cols, D]
+        v = v_ref[0].astype(jnp.float32)
         scale = 1.0 / math.sqrt(d)
         scores = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale           # [QL, page]
-        q_pos = off + jax.lax.broadcasted_iota(jnp.int32, (ql, page), 0) // group
-        k_pos = pi * page + jax.lax.broadcasted_iota(jnp.int32, (ql, page), 1)
-        scores = jnp.where(k_pos <= q_pos, scores, _NEG)
-        m = m_acc[:, :1]                                          # [QL, 1]
+            preferred_element_type=jnp.float32) * scale           # [rows, cols]
+        r = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 0)
+        c = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 1)
+        q_pos = first + r // heads
+        k_pos = pi * page + c // kvh
+        own_head = (r % heads) // group == c % kvh
+        scores = jnp.where(jnp.logical_and(own_head, k_pos <= q_pos),
+                           scores, _NEG)
+        m = m_acc[:, :1]                                          # [rows, 1]
         m_new = jnp.maximum(m, scores.max(axis=-1, keepdims=True))
         p = jnp.exp(scores - m_new)
         corr = jnp.exp(m - m_new)
-        l_acc[:, :1] = l_acc[:, :1] * corr + p.sum(axis=-1, keepdims=True)
-        m_acc[:, :1] = m_new
+        l_acc[:] = jnp.broadcast_to(
+            l_acc[:, :1] * corr + p.sum(axis=-1, keepdims=True), l_acc.shape)
+        m_acc[:] = jnp.broadcast_to(m_new, m_acc.shape)
         o_acc[:] = o_acc[:] * corr + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
     @pl.when(pi == pages_per - 1)
     def _fin():
-        # every query admits at least key 0 (k_pos=0 <= q_pos always), so l
-        # is never truly zero; the floor only guards numerical underflow
-        o_ref[0, 0] = (o_acc[:] / jnp.maximum(l_acc[:, :1], 1e-30)
-                       ).astype(o_ref.dtype)
+        # every query admits at least key 0 of its own kv head (k_pos=0 <=
+        # q_pos always) and page 0 is always within the bound, so m is real
+        # before any fully-masked page arrives and l is never truly zero;
+        # the floor only guards numerical underflow
+        o_ref[0] = (o_acc[:] / jnp.maximum(l_acc[:, :1], 1e-30)
+                    ).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -209,51 +232,54 @@ def paged_flash_attention(q, k_pages, v_pages, page_table, off, *,
     n_pages, page, kvh, _ = k_pages.shape
     if h % kvh:
         raise ValueError(f"q heads {h} must be a multiple of kv heads {kvh}")
-    group = h // kvh
-    ql = c * group
     pages_per = page_table.shape[1]
-    # fold the GQA group into the query tile: [B, KVH, C*G, dh] where folded
-    # index i = (chunk pos i//G, group member i%G) — all G members share the
-    # same KV head and the same absolute position
-    qf = (q.reshape(b, c, kvh, group, dh)
-          .transpose(0, 2, 1, 3, 4)
-          .reshape(b, kvh, ql, dh))
+    # query tile: the whole chunk when it is small (block == array, any C),
+    # else a multiple of 8 positions so the block's row count tiles
+    tile_c = c if c * h <= _PAGED_ROWS else max(8, _PAGED_ROWS // h // 8 * 8)
+    c_pad = -(-c // tile_c) * tile_c
+    if c_pad != c:
+        # padded queries sit past the chunk: finite garbage, sliced off below
+        q = jnp.pad(q, ((0, 0), (0, c_pad - c), (0, 0), (0, 0)))
+    rows = tile_c * h
     from jax.experimental.pallas import tpu as pltpu
 
-    grid = (b, kvh, pages_per)
+    grid = (b, c_pad // tile_c, pages_per)
     kernel = functools.partial(
-        _paged_kernel, page=page, group=group, pages_per=pages_per)
+        _paged_kernel, page=page, kvh=kvh, heads=h, tile_c=tile_c,
+        pages_per=pages_per)
 
-    def _page_index(bi, hi, pi, off_ref, table_ref):
-        # pages past the row's causal bound resolve to the scratch page 0:
+    def _page_index(bi, ci, pi, off_ref, table_ref):
+        # pages past the tile's causal bound resolve to the scratch page 0:
         # the index stays constant across the remaining grid steps, so the
         # pipeline skips the re-copy, and pl.when skips the math
-        max_pos = off_ref[bi] + (ql // group - 1)
-        return (jnp.where(pi * page <= max_pos, table_ref[bi, pi], 0), 0, hi, 0)
+        max_pos = off_ref[bi] + ci * tile_c + (tile_c - 1)
+        return (jnp.where(pi * page <= max_pos, table_ref[bi, pi], 0), 0, 0)
+
+    def _q_index(bi, ci, pi, *_):
+        return (bi, ci, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 1, ql, dh), lambda bi, hi, pi, *_: (bi, hi, 0, 0)),
-            pl.BlockSpec((1, page, 1, dh), _page_index),
-            pl.BlockSpec((1, page, 1, dh), _page_index),
+            pl.BlockSpec((1, rows, dh), _q_index),
+            pl.BlockSpec((1, page * kvh, dh), _page_index),
+            pl.BlockSpec((1, page * kvh, dh), _page_index),
         ],
-        out_specs=pl.BlockSpec((1, 1, ql, dh),
-                               lambda bi, hi, pi, *_: (bi, hi, 0, 0)),
+        out_specs=pl.BlockSpec((1, rows, dh), _q_index),
         scratch_shapes=[
-            pltpu.VMEM((ql, dh), jnp.float32),
-            pltpu.VMEM((ql, 128), jnp.float32),
-            pltpu.VMEM((ql, 128), jnp.float32),
+            pltpu.VMEM((rows, dh), jnp.float32),
+            pltpu.VMEM((rows, 128), jnp.float32),
+            pltpu.VMEM((rows, 128), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, kvh, ql, dh), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, c_pad * h, dh), q.dtype),
         interpret=interpret,
     )(jnp.asarray(off, jnp.int32), jnp.asarray(page_table, jnp.int32),
-      qf, k_pages, v_pages)
-    return (out.reshape(b, kvh, c, group, dh)
-            .transpose(0, 2, 1, 3, 4)
-            .reshape(b, c, h, dh))
+      q.reshape(b, c_pad * h, dh),
+      k_pages.reshape(n_pages, page * kvh, dh),
+      v_pages.reshape(n_pages, page * kvh, dh))
+    return out.reshape(b, c_pad, h, dh)[:, :c]

@@ -31,20 +31,18 @@ def _segment_kernel(q_ref, k_ref, v_ref, segq_ref, segk_ref, o_ref, *, tile_k: i
 
     q = q_ref[0, 0].astype(jnp.float32)  # [TQ, D]
     s = k_ref.shape[2]
-    seg_q = segq_ref[0]  # [TQ] int32
+    seg_q = segq_ref[0]  # [TQ, 1] int32
 
     def valid_at(t):
-        seg_k = segk_ref[0, pl.ds(t * tile_k, tile_k)]  # [TK]
+        seg_k = segk_ref[0, t]  # [1, TK]
         # block-diagonal mask from the segment ids: same segment AND live
-        return jnp.logical_and(
-            seg_q[:, None] == seg_k[None, :], seg_q[:, None] > 0)
+        return jnp.logical_and(seg_q == seg_k, seg_q > 0)
 
     o, m, l = flash_softmax_loop(q, k_ref, v_ref, s // tile_k, tile_k, valid_at)
     # dead queries (segment 0) emit zeros; their fully-masked softmax is
     # uniform, so the accumulator alone cannot zero them
-    q_live = (seg_q > 0)[:, None]
     o_ref[0, 0] = jnp.where(
-        q_live, o / jnp.maximum(l[:, None], 1e-30), 0.0
+        seg_q > 0, o / jnp.maximum(l[:, None], 1e-30), 0.0
     ).astype(o_ref.dtype)
 
 
@@ -72,15 +70,21 @@ def segment_flash_attention(q, k, v, segment_ids, *, tile_q: int = 128,
             pl.BlockSpec((1, 1, tile_q, d), lambda bi, hi, qi: (bi, hi, qi, 0)),
             pl.BlockSpec((1, 1, s, d), lambda bi, hi, qi: (bi, hi, 0, 0)),
             pl.BlockSpec((1, 1, s, d), lambda bi, hi, qi: (bi, hi, 0, 0)),
-            pl.BlockSpec((1, tile_q), lambda bi, hi, qi: (bi, qi)),
-            pl.BlockSpec((1, s), lambda bi, hi, qi: (bi, 0)),
+            pl.BlockSpec((1, tile_q, 1), lambda bi, hi, qi: (bi, qi, 0)),
+            pl.BlockSpec((1, s // tile_k, 1, tile_k),
+                         lambda bi, hi, qi: (bi, 0, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, tile_q, d), lambda bi, hi, qi: (bi, hi, qi, 0)),
     )
+    # the ids ride twice, each in the shape its side of the mask broadcasts
+    # from with no in-kernel relayout: a [TQ, 1] column for the queries and
+    # [1, TK] rows (one per K tile, picked by a leading index) for the keys.
+    # Every block's last two dims equal the array's or are (8k, 1) — the
+    # layout the TPU tiling accepts, which (1, tile) blocks of [B, S] are not
     seg = jnp.asarray(segment_ids, jnp.int32)
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
-    )(q, k, v, seg, seg)
+    )(q, k, v, seg[:, :, None], seg.reshape(b, s // tile_k, 1, tile_k))

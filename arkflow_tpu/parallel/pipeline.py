@@ -33,15 +33,6 @@ from arkflow_tpu.models.decoder import DecoderConfig, _attention_block, _mlp
 from arkflow_tpu.parallel.segment import StagePlan
 
 
-def _shard_map():
-    try:
-        from jax import shard_map  # jax >= 0.8
-        return shard_map
-    except ImportError:  # pragma: no cover - older jax
-        from jax.experimental.shard_map import shard_map
-        return shard_map
-
-
 def pp_param_specs(cfg: DecoderConfig) -> dict:
     """Layer stacks shard over pp on the layer dim; the rest replicates."""
     layer = {
@@ -80,8 +71,6 @@ def make_pp_train_step(cfg: DecoderConfig, optimizer, mesh: Mesh, *,
     sharded over dp. Params must be placed with ``pp_param_specs`` (layer
     stacks split across stages).
     """
-    shard_map = _shard_map()
-
     if cfg.num_experts > 1:
         raise ConfigError("pipeline parallelism + MoE (ep) is not composed yet")
     if cfg.use_ring_attention:
@@ -136,12 +125,9 @@ def make_pp_train_step(cfg: DecoderConfig, optimizer, mesh: Mesh, *,
 
     specs = pp_param_specs(cfg)
     data_spec = P("dp")
-    kwargs = dict(mesh=mesh, in_specs=(specs, data_spec, data_spec, data_spec),
-                  out_specs=P())
-    try:  # jax>=0.8 renamed the replication-check knob
-        loss_fn = shard_map(pp_loss, **kwargs, check_vma=False)
-    except TypeError:
-        loss_fn = shard_map(pp_loss, **kwargs, check_rep=False)
+    loss_fn = jax.shard_map(
+        pp_loss, mesh=mesh, in_specs=(specs, data_spec, data_spec, data_spec),
+        out_specs=P(), check_vma=False)
 
     def train_step(params, opt_state, batch):
         import optax
@@ -332,9 +318,5 @@ def make_pp_infer_step(family, cfg, mesh: Mesh, *, plan: StagePlan,
         return jax.tree_util.tree_map(bcast, out)
 
     data_spec = P("dp")
-    kwargs = dict(mesh=mesh, in_specs=(param_specs, data_spec),
-                  out_specs=data_spec)
-    try:  # jax>=0.8 renamed the replication-check knob
-        return _shard_map()(pp_infer, **kwargs, check_vma=False)
-    except TypeError:
-        return _shard_map()(pp_infer, **kwargs, check_rep=False)
+    return jax.shard_map(pp_infer, mesh=mesh, in_specs=(param_specs, data_spec),
+                         out_specs=data_spec, check_vma=False)

@@ -22,7 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 _NEG = -1e30
 
@@ -74,12 +73,12 @@ def make_ring_attention_spec(mesh: Mesh, sp_axis: str = "sp",
     attention memory/FLOPs stay O(S/n_sp * H/n_tp) per chip.
     """
     spec = P(batch_axis, sp_axis, head_axis, None)
-    return shard_map(
+    return jax.shard_map(
         partial(_ring_attention_local, axis_name=sp_axis, causal=causal),
         mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=spec,
-        check_rep=False,
+        check_vma=False,
     )
 
 

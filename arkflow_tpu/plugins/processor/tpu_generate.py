@@ -42,11 +42,12 @@ Config:
                              # TPU, gather elsewhere) | gather (dense
                              # reference) | paged — the Pallas kernel reads
                              # the KV page table in place for decode +
-                             # chunked prefill (TPU backends; argmax-parity
-                             # gated with fallback to gather;
-                             # kernel_parity_check: false skips the
-                             # init-time golden check, kernel_interpret:
-                             # true for CPU tests)
+                             # chunked prefill. Explicit paged needs a TPU
+                             # backend (ConfigError otherwise); a logit-
+                             # parity probe against gather gates it and a
+                             # mismatch fails construction
+                             # (kernel_parity_check: false skips the probe,
+                             # kernel_interpret: true for CPU tests)
     dispatch_depth: 2        # continuous mode: 2 pipelines decode — step
                              # N+1 dispatches from step N's device-resident
                              # tokens before N's outputs are fetched, so

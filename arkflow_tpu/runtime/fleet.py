@@ -322,7 +322,15 @@ class SubprocessSpawner:
     from the template config and reaps the processes it started.
 
     Owns only its own children — statically configured workers (or anything
-    else on the ring) are never touched by ``retire``."""
+    else on the ring) are never touched by ``retire``.
+
+    One process for each chip: every worker builds its own device
+    processors, and an accelerator belongs to one process at a time. The
+    controller's process must stay off jax (an ingest tier of ``remote_tpu``
+    stages does), and ``env`` is where a deployment gives each worker its
+    own device — or ``JAX_PLATFORMS=cpu``, as the soaks do. A worker that
+    finds its chip taken fails its start-up, and the adopt probe reports
+    it; it is never handed traffic."""
 
     def __init__(self, template: Any, *, host: str = "127.0.0.1",
                  env: Optional[Mapping[str, str]] = None,

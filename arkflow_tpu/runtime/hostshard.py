@@ -43,9 +43,12 @@ into the batch's trace (``Tracer.adopt_spans``) before finishing it, so
 ``stage_breakdown`` shows the sharded pipeline end to end.
 
 Device processors (``tpu_inference``/``tpu_generate``) are allowed in
-shards — in CPU/tiny mode every shard owns an independent XLA client.
-Against one REAL device, N shards would thrash it exactly like N pool
-workers; use the cluster/remote_tpu plane for that split instead.
+shards where every shard owns an independent XLA client: on the CPU
+platform (``JAX_PLATFORMS=cpu``, which the spawned shards inherit), or
+with a single shard (the parent never touches jax). A chip belongs to one
+process at a time, so N > 1 shards against an accelerator is refused at
+config time (``build_sharded_stream``) instead of hanging at start-up;
+use the cluster/remote_tpu plane for that split.
 """
 
 from __future__ import annotations
@@ -73,7 +76,7 @@ from arkflow_tpu.connect.flight import (
     batch_to_ipc,
     ipc_to_batches,
 )
-from arkflow_tpu.errors import EndOfInput, ProcessError
+from arkflow_tpu.errors import ConfigError, EndOfInput, ProcessError
 from arkflow_tpu.obs import global_registry
 from arkflow_tpu.obs.trace import TracingConfig, global_tracer
 from arkflow_tpu.runtime.cluster import HashRing
@@ -806,6 +809,18 @@ class ShardedIngestStream(Stream):
 def build_sharded_stream(cfg: StreamConfig, name: str) -> ShardedIngestStream:
     """Construct the parent endpoint + shard spec from a stream config
     (the ``build_stream`` seam for ``pipeline.ingest_shards > 0``)."""
+    from arkflow_tpu.runtime.procpool import DEVICE_PROCESSORS
+
+    device = sorted({p.get("type") for p in cfg.pipeline.processors}
+                    & DEVICE_PROCESSORS)
+    if (device and cfg.pipeline.ingest_shards > 1
+            and os.environ.get("JAX_PLATFORMS", "").lower() != "cpu"):
+        raise ConfigError(
+            f"pipeline.ingest_shards: {cfg.pipeline.ingest_shards} would "
+            f"start that many processes each building {device}, and an "
+            "accelerator belongs to one process at a time: run shards with "
+            "device processors under JAX_PLATFORMS=cpu, use one shard, or "
+            "split the device tier out with the cluster/remote_tpu plane")
     resource = Resource()
     input_ = build_component("input", cfg.input, resource)
     output = build_component("output", cfg.output, resource)

@@ -2,9 +2,9 @@
 
 The reference's ``thread_num`` workers are true multicore threads (Tokio,
 ref crates/arkflow-core/src/stream/mod.rs:117-126). Ours share one GIL:
-measured scaling is ~1.3x at 8 workers because the Arrow/C++ kernels
-already release the GIL and the Python glue serializes the rest
-(docs/ROUND2_NOTES.md "Measured this round"). For pipelines whose
+measured scaling was ~1.3x at 8 workers (CPU host, round 2) because the
+Arrow/C++ kernels already release the GIL and the Python glue serializes
+the rest. For pipelines whose
 transforms are genuinely Python-bound (heavy `python`/`remap` logic,
 many small batches), ``pipeline.process_pool: N`` runs the processor
 chain in N worker PROCESSES instead:

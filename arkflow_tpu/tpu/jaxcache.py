@@ -1,21 +1,22 @@
 """Persistent XLA compilation cache for the serving tier.
 
-Remote/tunneled TPU backends pay tens of seconds (sometimes minutes) per
-executable compile; with the persistent cache each (model, shape, dtype)
-bucket compiles once per machine instead of once per process, so engine
-restarts, benchmark reruns, and the driver's end-of-round `bench.py` all
-start serving at full speed immediately.
+A TPU executable takes tens of seconds to compile; with the persistent
+cache each (model, shape, dtype) bucket compiles once per machine instead
+of once per process, so engine restarts and benchmark reruns start serving
+at full speed immediately.
 
 The reference engine has no analog (an interpreted CPU data plane never
 compiles); this is TPU-native operational hygiene, same motivation as the
 executable warm-up hook (SURVEY.md §7.5: keep the compiled model fed, never
 stall steady-state on a compile).
 
-Knobs:
-- ``ARKFLOW_JAX_CACHE=0`` disables.
-- ``ARKFLOW_JAX_CACHE_DIR`` overrides the location (default: ``.jax_cache``
-  next to the package, i.e. the repo root; falls back silently if the
-  directory is not creatable).
+Placement comes from outside. Where ``JAX_COMPILATION_CACHE_DIR`` is set,
+JAX's own handling of it is the only thing that places the cache — this
+module sets no directory. Where it is not, the cache goes to a fixed path
+(the path is part of the cache key, so a directory that moves never hits):
+``.jax_cache`` next to the package for accelerator backends, a
+host-feature-keyed ``.jax_cache_cpu-<hash>`` for the CPU backend.
+``ARKFLOW_JAX_CACHE=0`` disables.
 """
 
 from __future__ import annotations
@@ -53,7 +54,7 @@ def _host_key() -> str:
 
 
 def enable_persistent_cache() -> Optional[str]:
-    """Idempotently point JAX at an on-disk compilation cache.
+    """Idempotently turn on JAX's on-disk compilation cache.
 
     Returns the cache directory in use, or None when disabled/unavailable.
     Must run before the first compile to help that compile; safe any time.
@@ -64,10 +65,13 @@ def enable_persistent_cache() -> Optional[str]:
     _attempted = True
     if os.environ.get("ARKFLOW_JAX_CACHE", "1") == "0":
         return None
-    path = os.environ.get("ARKFLOW_JAX_CACHE_DIR") or os.environ.get(
-        "JAX_COMPILATION_CACHE_DIR"
-    )
-    if not path:
+    import jax
+
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        # placed from outside: JAX read the variable itself, and no code
+        # here may move the cache elsewhere
+        path = jax.config.jax_compilation_cache_dir
+    else:
         repo_root = os.path.dirname(
             os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
         )
@@ -77,32 +81,26 @@ def enable_persistent_cache() -> Optional[str]:
             # that never includes XLA's prefer-no-gather/scatter
             # pseudo-features — so every reload logs two C++-level E lines
             # (cosmetic on the same host; a cross-host reload risks SIGILL).
-            # Bench fallback children must stay off the cache entirely: the
-            # round-2 driver artifact lost its metric line to that spew.
-            # Everywhere else (the test suite above all) the cache is worth
-            # ~9 min/run of recompiles, so keep it on, keyed by host CPU
-            # features so a copied repo on different silicon recompiles, and
-            # silence the loader lines via TF_CPP_MIN_LOG_LEVEL (set before
-            # jax import by cleanenv.pin_cpu_env).
-            if os.environ.get("ARKFLOW_BENCH_CHILD") == "1":
-                return None
+            # The cache is worth ~9 min/run of recompiles to the test suite,
+            # so keep it on, keyed by host CPU features so a copied repo on
+            # different silicon recompiles, and silence the loader lines via
+            # TF_CPP_MIN_LOG_LEVEL (set before jax import by
+            # cleanenv.pin_cpu_env).
             path = os.path.join(repo_root, f".jax_cache_cpu-{_host_key()}")
         else:
             path = os.path.join(repo_root, ".jax_cache")
-    try:
-        os.makedirs(path, exist_ok=True)
-        import jax
-
+        try:
+            os.makedirs(path, exist_ok=True)
+        except OSError as e:  # read-only checkout: serve without the cache
+            logger.warning("persistent compilation cache unavailable: %s", e)
+            return None
         jax.config.update("jax_compilation_cache_dir", path)
-        # cache every executable regardless of compile time (jax's default
-        # threshold of 1s would skip the small bucket-grid executables that
-        # recompile on every engine restart)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        _configured = path
-        logger.debug("persistent XLA compilation cache at %s", path)
-    except Exception as e:  # never let cache plumbing break serving
-        logger.warning("persistent compilation cache unavailable: %s", e)
-        _configured = None
+    # cache every executable regardless of compile time (jax's default
+    # threshold of 1s would skip the small bucket-grid executables that
+    # recompile on every engine restart)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    _configured = path
+    logger.debug("persistent XLA compilation cache at %s", path)
     return _configured
 
 

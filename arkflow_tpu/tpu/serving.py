@@ -32,6 +32,7 @@ probe backoff, rebuilds the jitted steps, and reinitializes the pools.
 from __future__ import annotations
 
 import asyncio
+import functools
 import logging
 import time
 from collections import deque
@@ -222,24 +223,21 @@ class GenerationServer:
         # chunked prefill. "auto" (default) picks paged on TPU backends and
         # gather elsewhere — same idiom as the runner's auto flash. Compiled
         # Pallas needs a TPU backend; CPU tests opt in via kernel_interpret.
-        # The swap is gated on argmax parity against the gather reference
-        # (mismatch falls back, never fails).
+        # The kernel serves only after a logit-parity probe against the
+        # gather reference; a mismatch fails construction.
         self.decode_kernel = str(decode_kernel)
         if self.decode_kernel not in ("auto", "gather", "paged"):
             raise ConfigError(
                 f"decode_kernel must be auto|gather|paged, got {decode_kernel!r}")
         self.kernel_interpret = bool(kernel_interpret)
+        can_run_kernel = self.kernel_interpret or self._on_tpu()
         if self.decode_kernel == "auto":
-            self.decode_kernel = (
-                "paged" if (self._on_tpu() or self.kernel_interpret)
-                else "gather")
-        elif (self.decode_kernel == "paged" and not self.kernel_interpret
-                and not self._on_tpu()):
-            logger.warning(
-                "decode_kernel: paged needs a TPU backend (or "
-                "kernel_interpret for CPU tests); serving with the dense "
-                "gather reference instead")
-            self.decode_kernel = "gather"
+            self.decode_kernel = "paged" if can_run_kernel else "gather"
+        elif self.decode_kernel == "paged" and not can_run_kernel:
+            raise ConfigError(
+                "decode_kernel: paged requires a TPU backend (or "
+                "kernel_interpret for CPU tests); leave it at auto to serve "
+                "with the dense gather reference here")
 
         # dispatch depth: 2 pipelines decode — step N+1 is dispatched with
         # step N's DEVICE-resident next-token array before N's outputs are
@@ -282,18 +280,22 @@ class GenerationServer:
         #: it executes, so the deadline watchdog grants it the first-compile
         #: budget (cleared on rebuild, like the runner's seen-shape set)
         self._seen_steps: set[tuple] = set()
+        #: verdict of the init-time parity probe (None: not run)
+        self.kernel_parity: Optional[dict] = None
         if (self.decode_kernel == "paged" and kernel_parity_check
                 and self.mesh is None):
-            # one tiny golden batch through both kernels before the swap is
-            # trusted (PR-6 convention: parity gates the fast path, failure
-            # falls back loudly instead of serving wrong tokens). Under a
-            # mesh the gate is skipped — per-shard math is identical and the
-            # tp parity suite covers it; the init-time check stays local.
-            if not self._paged_kernel_parity_ok():
-                logger.warning(
-                    "paged decode kernel failed argmax parity vs the dense "
-                    "gather reference; serving with gather")
-                self.decode_kernel = "gather"
+            # one tiny golden batch through both kernels before the kernel
+            # is trusted. A mismatch is a construction error — serving on
+            # with gather would hide a kernel that is wrong on this device.
+            # Under a mesh the gate is skipped — per-shard math is identical
+            # and the tp parity suite covers it; the init-time check stays
+            # local.
+            self.kernel_parity = verdict = self._paged_kernel_parity()
+            if not verdict["ok"]:
+                raise ConfigError(
+                    "paged decode kernel disagrees with the dense gather "
+                    f"reference on this device ({verdict}); set "
+                    "decode_kernel: gather to serve without it")
         self._build_jitted()
 
         # the shared serving-runner core: health state machine, step-deadline
@@ -387,15 +389,26 @@ class GenerationServer:
                 else None)
         return on_tpu_backend(devs)
 
-    def _paged_kernel_parity_ok(self) -> bool:
-        """Argmax-parity gate for the paged attention kernel: one tiny
+    def _paged_kernel_parity(self) -> dict:
+        """Logit-parity gate for the paged attention kernel: one tiny
         golden batch — prompts that cross a page boundary plus a
         single-token tail, on non-contiguous page tables — through prefill,
-        then one decode step and one 2-token chunk with BOTH kernels. The
-        fast path only serves if every argmax agrees with the dense-gather
-        reference (PR-6 convention: parity gates the measured default).
-        Runs eagerly on this server's params; one-time init cost."""
+        then one decode step and one 2-token chunk with BOTH kernels,
+        judged by ``logits_parity`` (bf16 tolerance; argmax must agree
+        wherever the reference's top-2 margin decides it). Returns the
+        worse of the two verdicts. The steps are jitted with params as an
+        argument, like the serving steps; one-time init cost."""
         from arkflow_tpu.models.paged_decode import paged_prefill_chunk
+        from arkflow_tpu.tpu.serving_core import logits_parity
+
+        kernel_args = ("attention_kernel", "kernel_interpret")
+        prefill = jax.jit(paged_prefill, static_argnums=1)
+        decode = jax.jit(paged_decode_step, static_argnums=1,
+                         static_argnames=("return_logits", *kernel_args))
+        chunk = jax.jit(paged_prefill_chunk, static_argnums=1,
+                        static_argnames=("return_all", *kernel_args))
+        paged = dict(attention_kernel="paged",
+                     kernel_interpret=self.kernel_interpret)
 
         page = self.page_size
         n0 = min(page + 1, self.max_seq)  # crosses a page boundary
@@ -410,30 +423,25 @@ class GenerationServer:
         table[0] = np.arange(1, 2 * pages_per, 2)[::-1]  # non-contiguous
         table[1] = np.arange(2, 2 * pages_per + 1, 2)
         table = jnp.asarray(table)
-        _, kp, vp = paged_prefill(
+        _, kp, vp = prefill(
             self.params, self.cfg, jnp.asarray(ids), lens, table, kp, vp)
         tok = jnp.asarray(ids[:, 0])
         act = jnp.asarray([True, True])
-        ref, *_ = paged_decode_step(
-            self.params, self.cfg, tok, lens, act, table, kp, vp,
-            return_logits=True)
-        got, *_ = paged_decode_step(
-            self.params, self.cfg, tok, lens, act, table, kp, vp,
-            return_logits=True, attention_kernel="paged",
-            kernel_interpret=self.kernel_interpret)
-        if not bool((jnp.argmax(ref, -1) == jnp.argmax(got, -1)).all()):
-            return False
+        ref, *_ = decode(self.params, self.cfg, tok, lens, act, table, kp, vp,
+                         return_logits=True)
+        got, *_ = decode(self.params, self.cfg, tok, lens, act, table, kp, vp,
+                         return_logits=True, **paged)
+        verdict = logits_parity(ref, got)
+        if not verdict["ok"]:
+            return verdict
         cids = jnp.asarray(rng.randint(1, self.cfg.vocab_size, (2, 2)),
                            jnp.int32)
         clen = jnp.asarray([2, 2], jnp.int32)
-        ref, *_ = paged_prefill_chunk(
-            self.params, self.cfg, cids, lens, clen, table, kp, vp,
-            return_all=True)
-        got, *_ = paged_prefill_chunk(
-            self.params, self.cfg, cids, lens, clen, table, kp, vp,
-            return_all=True, attention_kernel="paged",
-            kernel_interpret=self.kernel_interpret)
-        return bool((jnp.argmax(ref, -1) == jnp.argmax(got, -1)).all())
+        ref, *_ = chunk(self.params, self.cfg, cids, lens, clen, table, kp, vp,
+                        return_all=True)
+        got, *_ = chunk(self.params, self.cfg, cids, lens, clen, table, kp, vp,
+                        return_all=True, **paged)
+        return logits_parity(ref, got)
 
     def _init_pools(self):
         """Fresh KV page pools, placed with their tensor-parallel sharding
@@ -461,49 +469,54 @@ class GenerationServer:
         def _pick(logits, key):
             return select_token(logits, key, self.temperature, self.top_k)
 
-        # donate the KV pools: they are pure in->out state, so XLA updates
-        # them in place instead of copying hundreds of MB per decode step
-        def _decode(tok, lens, act, table, kp, vp, key):
+        # params ride every step as an ARGUMENT (bound below): closed over,
+        # they would be baked into each executable as constants — a copy of
+        # the weights per compiled step, and gigabytes of literals to lower
+        # at real widths. The KV pools donate: they are pure in->out state,
+        # so XLA updates them in place instead of copying hundreds of MB per
+        # decode step.
+        def _decode(params, tok, lens, act, table, kp, vp, key):
             logits, kp, vp = paged_decode_step(
-                self.params, cfg, tok, lens, act, table, kp, vp,
+                params, cfg, tok, lens, act, table, kp, vp,
                 return_logits=True, kv_sharding=kv_layer, **kern)
             return _pick(logits, key), kp, vp
 
-        def _prefill(ids, lens, table, kp, vp, key):
+        def _prefill(params, ids, lens, table, kp, vp, key):
             logits, kp, vp = paged_prefill(
-                self.params, cfg, ids, lens, table, kp, vp, return_logits=True,
+                params, cfg, ids, lens, table, kp, vp, return_logits=True,
                 kv_sharding=kv_layer)
             return _pick(logits, key), kp, vp
 
-        def _chunk(ids, off, clen, table, kp, vp):
-            return paged_prefill_chunk(self.params, cfg, ids, off, clen,
+        def _chunk(params, ids, off, clen, table, kp, vp):
+            return paged_prefill_chunk(params, cfg, ids, off, clen,
                                        table, kp, vp, kv_sharding=kv_layer,
                                        **kern)
 
-        def _verify(ids, off, clen, table, kp, vp):
-            return paged_prefill_chunk(self.params, cfg, ids, off, clen,
+        def _verify(params, ids, off, clen, table, kp, vp):
+            return paged_prefill_chunk(params, cfg, ids, off, clen,
                                        table, kp, vp, return_all=True,
                                        kv_sharding=kv_layer, **kern)
 
-        if self.mesh is None:
-            self._decode = jax.jit(_decode, donate_argnums=(4, 5))
-            self._prefill = jax.jit(_prefill, donate_argnums=(3, 4))
-            self._chunk = jax.jit(_chunk, donate_argnums=(4, 5))
-            self._verify = jax.jit(_verify, donate_argnums=(4, 5))
-            return
-        r, kv = self._repl_sharding, self._kv_io_sharding
-        self._decode = jax.jit(_decode, donate_argnums=(4, 5),
-                               in_shardings=(r, r, r, r, kv, kv, r),
-                               out_shardings=(r, kv, kv))
-        self._prefill = jax.jit(_prefill, donate_argnums=(3, 4),
-                                in_shardings=(r, r, r, kv, kv, r),
-                                out_shardings=(r, kv, kv))
-        self._chunk = jax.jit(_chunk, donate_argnums=(4, 5),
-                              in_shardings=(r, r, r, r, kv, kv),
-                              out_shardings=(r, kv, kv))
-        self._verify = jax.jit(_verify, donate_argnums=(4, 5),
-                               in_shardings=(r, r, r, r, kv, kv),
-                               out_shardings=(r, kv, kv))
+        def bind(fn, n_before: int, n_after: int):
+            """jit ``fn(params, *n_before args, kp, vp, *n_after args)`` with
+            the pools donated, and bind the current params."""
+            kw = {}
+            if self.mesh is not None:
+                from arkflow_tpu.parallel.mesh import param_shardings
+
+                r, kv = self._repl_sharding, self._kv_io_sharding
+                kw = dict(
+                    in_shardings=(param_shardings(self.params),
+                                  *[r] * n_before, kv, kv, *[r] * n_after),
+                    out_shardings=(r, kv, kv))
+            jitted = jax.jit(
+                fn, donate_argnums=(1 + n_before, 2 + n_before), **kw)
+            return functools.partial(jitted, self.params)
+
+        self._decode = bind(_decode, 4, 1)
+        self._prefill = bind(_prefill, 3, 1)
+        self._chunk = bind(_chunk, 4, 0)
+        self._verify = bind(_verify, 4, 0)
 
     def _rebuild_after_incident(self) -> None:
         """Core rebuild hook (runs inside the heal gate, before the recovery
@@ -533,9 +546,8 @@ class GenerationServer:
     async def swap_params(self, placed, drain_timeout_s: float = 30.0):
         """Adopt a new (pre-placed) param tree with zero dropped requests.
 
-        Unlike the batch runner — whose params ride the jitted step as an
-        argument — the four generation jits close over ``self.params`` as
-        traced constants, so a flip must rebuild them. The sequence: pause
+        The four generation steps bind ``self.params`` at build time
+        (``_build_jitted``), so a flip rebinds them. The sequence: pause
         admission, let the lockstep slot grid run dry (queued requests WAIT,
         they are never failed), flip params, rebuild the jits (the cleared
         ``_seen_steps`` grants the next step the first-compile budget), and
@@ -585,8 +597,8 @@ class GenerationServer:
 
     def _bitflip_params(self) -> None:
         """Corrupt the largest float leaf of ``self.params`` in place. The
-        generation jits close over params as traced constants, so the flip
-        must also rebuild them (same sequence as ``swap_params``, minus the
+        generation steps bind params at build time, so the flip must also
+        rebuild them (same sequence as ``swap_params``, minus the
         drain — arming and the serve loop share the event loop, and a
         corrupted tree mid-decode is exactly what real HBM corruption does).
         Nothing on the serving path notices by itself; only the integrity

@@ -83,15 +83,52 @@ def is_oom_error(e: BaseException) -> bool:
 def on_tpu_backend(devices=None) -> bool:
     """Is the (first) execution device a TPU? The one backend probe the
     auto-resolved fast paths share (runner auto-flash, serving auto decode
-    kernel) — a device_kind fix lands once, not per copy."""
-    try:
-        import jax
+    kernel) — a device_kind fix lands once, not per copy. A failing device
+    query propagates: "no TPU" is an answer, a backend that cannot be
+    asked is not."""
+    import jax
 
-        dev = devices[0] if devices else jax.devices()[0]
-        return (dev.platform == "tpu"
-                or "tpu" in getattr(dev, "device_kind", "").lower())
-    except Exception:
-        return False
+    dev = devices[0] if devices else jax.devices()[0]
+    return (dev.platform == "tpu"
+            or "tpu" in getattr(dev, "device_kind", "").lower())
+
+
+#: bfloat16 machine epsilon (8 bits of significand)
+_BF16_EPS = 2.0 ** -7
+
+
+def bf16_logit_tolerance(ref) -> float:
+    """How far two bf16-served computations of the logits ``ref`` may sit
+    apart: 4 bf16 ulps of the largest reference logit (on a v5e the paged
+    and gather decode paths differ by about one: 0.019 at a largest logit
+    of 2.6, PR 21's chip run)."""
+    import numpy as np
+
+    return 4 * _BF16_EPS * max(1.0, float(np.abs(np.asarray(ref)).max()))
+
+
+def logits_parity(ref, got) -> dict:
+    """Do two ``[..., vocab]`` logit arrays agree as far as bf16 serving can?
+
+    Random-weight models produce near-tied logits, where a different
+    accumulation order legitimately flips an argmax — bare argmax equality
+    is the wrong test. ``ok`` needs every logit within
+    ``bf16_logit_tolerance`` AND argmax equal on
+    every row whose reference top-2 margin exceeds twice the tolerance (a
+    flip there is a real disagreement, not a tie). Returns the verdict with
+    its numbers so callers can print or raise with them."""
+    import numpy as np
+
+    ref = np.asarray(ref, np.float32)
+    got = np.asarray(got, np.float32)
+    tol = bf16_logit_tolerance(ref)
+    top2 = np.partition(ref, -2, axis=-1)[..., -2:]
+    decided = (top2[..., 1] - top2[..., 0]) > 2 * tol
+    flips = int(((ref.argmax(-1) != got.argmax(-1)) & decided).sum())
+    diff = float(np.abs(ref - got).max())
+    return {"ok": bool(diff <= tol and flips == 0), "max_abs_diff": diff,
+            "tol": tol, "rows": int(decided.size),
+            "decided_rows": int(decided.sum()), "decided_flips": flips}
 
 
 def parse_core_config(config: Mapping[str, Any]) -> dict:

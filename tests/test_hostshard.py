@@ -107,6 +107,27 @@ def test_ingest_shards_config_validation():
         })
 
 
+def test_device_shards_off_the_cpu_platform_fail_at_config_time(monkeypatch):
+    """A chip belongs to one process: N > 1 shard processes each building a
+    device processor would fail or hang at start-up on an accelerator, so
+    the stream refuses at build time with a message. On the pinned CPU
+    platform (every shard its own XLA client) and with one shard it builds."""
+    def cfg(shards):
+        return StreamConfig.from_mapping({
+            "input": {"type": "generate", "payload": "x"},
+            "pipeline": {"ingest_shards": shards, "processors": [
+                {"type": "tpu_inference", "model": "bert_classifier"}]},
+            "output": {"type": "drop"},
+        })
+
+    monkeypatch.delenv("JAX_PLATFORMS")
+    with pytest.raises(ConfigError, match="belongs to one process"):
+        build_stream(cfg(2))
+    assert isinstance(build_stream(cfg(1)), ShardedIngestStream)
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    assert isinstance(build_stream(cfg(2)), ShardedIngestStream)
+
+
 def test_generate_tenants_rotation():
     """generate.tenants stamps consecutive batches with rotating tenant ids
     (identical payloads otherwise share one fingerprint -> one shard)."""

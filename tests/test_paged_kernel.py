@@ -93,6 +93,27 @@ def test_paged_flash_attention_chunked_prefill_regime():
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=3e-5)
 
 
+def test_paged_flash_attention_tiles_long_chunks():
+    """A chunk whose folded rows (C x heads) exceed one program's budget is
+    split into query tiles (C padded up to a tile multiple, the pad sliced
+    off): same answers as the dense reference, per-tile causal bounds."""
+    rng = np.random.RandomState(5)
+    b, c, h, kvh, dh = 2, 37, 32, 8, 8   # 37*32 rows > 1024 -> tile_c 32
+    page, pages_per = 4, 12
+    n_pages = 1 + b * pages_per
+    q = jnp.asarray(rng.randn(b, c, h, dh), jnp.float32) * 0.5
+    kp = jnp.asarray(rng.randn(n_pages, page, kvh, dh) * 0.5, jnp.bfloat16)
+    vp = jnp.asarray(rng.randn(n_pages, page, kvh, dh) * 0.5, jnp.bfloat16)
+    table = jnp.asarray(
+        [np.random.RandomState(i).permutation(np.arange(1, n_pages))[:pages_per]
+         for i in range(b)], jnp.int32)
+    off = jnp.asarray([3, pages_per * page - c], jnp.int32)
+    out = paged_flash_attention(q, kp, vp, table, off, interpret=True)
+    ref = _dense_paged_reference(q, kp, vp, table, off)
+    assert out.shape == q.shape
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=3e-5)
+
+
 def test_paged_flash_attention_decode_shape_and_gqa():
     """Decode regime: C=1 queries, GQA group folded into the kernel tile
     (heads never repeated in memory) — bit-for-shape parity with the dense
@@ -357,11 +378,35 @@ def test_depth2_page_pressure_no_leak():
     assert all(len(o) >= 1 for o in outs)
 
 
-def test_server_kernel_falls_back_on_cpu_without_interpret():
+def test_server_explicit_paged_kernel_without_tpu_is_config_error():
+    """An explicit ``decode_kernel: paged`` that cannot run here (no TPU, no
+    kernel_interpret) fails construction instead of quietly serving gather."""
     cfg, params = _tiny_setup()
-    _, srv = _serve(params, cfg, [[9]], 2, decode_kernel="paged")
-    assert srv.decode_kernel == "gather"
-    assert srv.m_kernel_paged.value == 0
+    with pytest.raises(ConfigError, match="requires a TPU backend"):
+        GenerationServer(params, cfg, decode_kernel="paged")
+
+
+def test_server_paged_kernel_parity_mismatch_raises(monkeypatch):
+    """A kernel that disagrees with the gather reference is a construction
+    error naming the numbers — never a warning and a quiet swap."""
+    from arkflow_tpu.ops import ragged_attention
+
+    real = ragged_attention.paged_flash_attention
+    monkeypatch.setattr(
+        ragged_attention, "paged_flash_attention",
+        lambda q, *a, **kw: real(q, *a, **kw) * 0.0)
+    # a config no other test traces: jit's trace cache is keyed on the step
+    # function and its static cfg, and a cached trace holds the real kernel
+    fam = get_model("decoder_lm")
+    cfg = fam.make_config(**{**TINY, "ffn": 80})
+    params = fam.init(jax.random.PRNGKey(3), cfg)
+    with pytest.raises(ConfigError, match="disagrees with the dense gather"):
+        GenerationServer(params, cfg, decode_kernel="paged",
+                         kernel_interpret=True)
+    # the gate can still be skipped explicitly (and then serves the kernel)
+    srv = GenerationServer(params, cfg, decode_kernel="paged",
+                           kernel_interpret=True, kernel_parity_check=False)
+    assert srv.decode_kernel == "paged"
 
 
 def test_server_kernel_auto_resolution():

@@ -1,6 +1,7 @@
 """TPU execution layer: bucketing, runner, tokenizer, and the e2e inference slice."""
 
 import asyncio
+import os
 
 import numpy as np
 import pytest
@@ -170,41 +171,110 @@ def test_flash_floor_env_override_applies_to_explicit_config(monkeypatch):
     assert _env_flash_floor() == 128
 
 
-def test_persistent_cache_idempotent(tmp_path, monkeypatch):
+@pytest.fixture
+def fresh_jaxcache(monkeypatch):
+    """An un-attempted jaxcache module; jax.config is process-global, so the
+    cache settings are restored for the tests that follow."""
     import jax
 
     from arkflow_tpu.tpu import jaxcache
 
-    # jax.config is process-global: restore it so later tests don't compile
-    # into this test's tmp dir
     old_dir = jax.config.jax_compilation_cache_dir
     old_min = jax.config.jax_persistent_cache_min_compile_time_secs
-    try:
-        monkeypatch.setattr(jaxcache, "_attempted", False)
-        monkeypatch.setattr(jaxcache, "_configured", None)
-        monkeypatch.setenv("ARKFLOW_JAX_CACHE_DIR", str(tmp_path / "jc"))
-        p1 = jaxcache.enable_persistent_cache()
-        p2 = jaxcache.enable_persistent_cache()
-        assert p1 == p2 == str(tmp_path / "jc")
-        monkeypatch.setattr(jaxcache, "_attempted", False)
-        monkeypatch.setenv("ARKFLOW_JAX_CACHE", "0")
-        assert jaxcache.enable_persistent_cache() is None
-        # CPU backend: cache stays ON (host-feature-keyed dir) for normal
-        # runs — the test suite depends on it — but OFF for bench fallback
-        # children whose merged output must stay spew-free (VERDICT r3 #6)
-        monkeypatch.delenv("ARKFLOW_JAX_CACHE", raising=False)
-        monkeypatch.delenv("ARKFLOW_JAX_CACHE_DIR", raising=False)
-        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
-        monkeypatch.setenv("JAX_PLATFORMS", "cpu")
-        monkeypatch.setattr(jaxcache, "_attempted", False)
-        p_cpu = jaxcache.enable_persistent_cache()
-        assert p_cpu is not None and f".jax_cache_cpu-{jaxcache._host_key()}" in p_cpu
-        monkeypatch.setenv("ARKFLOW_BENCH_CHILD", "1")
-        monkeypatch.setattr(jaxcache, "_attempted", False)
-        assert jaxcache.enable_persistent_cache() is None
-    finally:
-        jax.config.update("jax_compilation_cache_dir", old_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", old_min)
+    monkeypatch.setattr(jaxcache, "_attempted", False)
+    monkeypatch.setattr(jaxcache, "_configured", None)
+    monkeypatch.delenv("ARKFLOW_JAX_CACHE", raising=False)
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    yield jaxcache
+    jax.config.update("jax_compilation_cache_dir", old_dir)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", old_min)
+
+
+def test_persistent_cache_placed_by_jax_env(fresh_jaxcache, tmp_path, monkeypatch):
+    """With JAX_COMPILATION_CACHE_DIR set, JAX's own handling of it places
+    the cache: enable_persistent_cache() leaves the config at that value,
+    sets no other directory and creates none."""
+    import jax
+
+    outside = str(tmp_path / "placed-from-outside")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", outside)
+    # jax reads the variable at import; stand in for that here
+    jax.config.update("jax_compilation_cache_dir", outside)
+    p1 = fresh_jaxcache.enable_persistent_cache()
+    p2 = fresh_jaxcache.enable_persistent_cache()
+    assert p1 == p2 == outside
+    assert jax.config.jax_compilation_cache_dir == outside
+    assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
+    assert fresh_jaxcache.cache_info()["dir"] == outside
+
+
+def test_persistent_cache_fixed_default_dirs(fresh_jaxcache, monkeypatch):
+    """Without the variable the cache goes to a fixed path (the path is part
+    of the cache key): host-keyed on the CPU backend, ``.jax_cache`` beside
+    the package otherwise."""
+    import jax
+
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    p_cpu = fresh_jaxcache.enable_persistent_cache()
+    assert p_cpu.endswith(f".jax_cache_cpu-{fresh_jaxcache._host_key()}")
+    assert jax.config.jax_compilation_cache_dir == p_cpu
+    monkeypatch.setattr(fresh_jaxcache, "_attempted", False)
+    monkeypatch.setenv("JAX_PLATFORMS", "")
+    p_dev = fresh_jaxcache.enable_persistent_cache()
+    assert os.path.basename(p_dev) == ".jax_cache"
+    assert os.path.dirname(p_dev) == os.path.dirname(p_cpu)
+
+
+def test_persistent_cache_kill_switch(fresh_jaxcache, monkeypatch):
+    import jax
+
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("ARKFLOW_JAX_CACHE", "0")
+    assert fresh_jaxcache.enable_persistent_cache() is None
+    assert jax.config.jax_compilation_cache_dir == before
+    assert fresh_jaxcache.cache_info() == {"enabled": False}
+
+
+def test_on_tpu_backend_propagates_device_query_errors(monkeypatch):
+    """"Not a TPU" is an answer; a backend that cannot be asked is an error,
+    not a quiet False that would route serving onto a fallback path."""
+    import jax
+
+    from arkflow_tpu.tpu.serving_core import on_tpu_backend
+
+    assert on_tpu_backend() is False  # the CPU test platform
+
+    def boom():
+        raise RuntimeError("Unable to initialize backend 'tpu'")
+
+    monkeypatch.setattr(jax, "devices", boom)
+    with pytest.raises(RuntimeError, match="Unable to initialize backend"):
+        on_tpu_backend()
+
+    class FakeTpu:
+        platform = "tpu"
+        device_kind = "TPU v5 lite"
+
+    assert on_tpu_backend([FakeTpu()]) is True  # explicit devices: no query
+
+
+def test_bench_peak_table_is_exact_and_raises_on_unknown_kind(monkeypatch):
+    """The bench's peaks are one table keyed by the exact device_kind, each
+    with its source; a kind that is not listed is an error — no substring
+    guess, no env override."""
+    import bench  # repo root is on sys.path (conftest)
+
+    monkeypatch.delenv("BENCH_DTYPE", raising=False)
+    monkeypatch.setenv("BENCH_PEAK_TFLOPS", "999")  # the old override: inert
+    assert bench._device_peak_tflops("TPU v5 lite") == 197.0
+    monkeypatch.setenv("BENCH_DTYPE", "int8")
+    assert bench._device_peak_tflops("TPU v5 lite") == 393.0
+    assert all(p["source"] for p in bench.DEVICE_PEAKS.values())
+    for kind in ("TPU v5", "TPU v5p", "tpu v5 lite", "cpu"):
+        with pytest.raises(KeyError, match="no peak on record"):
+            bench._device_peak_tflops(kind)
+    with pytest.raises(KeyError, match="no peak on record"):
+        bench._device_peak_tflops()  # this process: the CPU test platform
 
 
 def test_e2e_streaming_bert_classification():
