@@ -39,6 +39,16 @@ Nested instrumentation (runner device steps, processor infeed prep) uses a
 ``record_stage``/``stage_span`` without threading a context object through
 every API. The contextvar carries the *tracer* too, so worker-hosted
 processors record into the worker's tracer, not the global one.
+
+Second sink, same names: ``annotated``/``loop_stage`` enter a
+``jax.profiler.TraceAnnotation`` around a SYNCHRONOUS stretch, so a profiler
+session sees the program's stages on its own clock beside the device's ops
+and can name idle time by them. Never hold one across an ``await`` (the
+event loop interleaves tasks on one thread, so the annotation would claim
+their time too). Executor threads carry no scope: annotate and time there,
+record from the coroutine. ``loop_stage``/``observe_stage`` are the form for
+work that belongs to no request (a serve loop's own phases): stage histogram
+and annotation, no trace tree.
 """
 
 from __future__ import annotations
@@ -48,7 +58,7 @@ import random
 import threading
 import time
 from collections import OrderedDict, deque
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from contextvars import ContextVar
 from dataclasses import dataclass, field
 from typing import Any, Mapping, Optional
@@ -112,13 +122,17 @@ class Span:
     span_id: str
     parent_id: str = ""
     start_ms: float = 0.0  # wall clock, display/ordering only
+    #: ``time.perf_counter()`` at the span's start: process-local, the clock
+    #: a harness or a profiler session in the same process can be laid against
+    start_mono: float = 0.0
     tier: str = ""
     attrs: dict = field(default_factory=dict)
 
     def to_dict(self) -> dict:
         out = {"stage": self.stage, "dur_ms": round(self.dur_s * 1000.0, 3),
                "span_id": self.span_id, "parent_id": self.parent_id,
-               "start_ms": round(self.start_ms, 1), "tier": self.tier}
+               "start_ms": round(self.start_ms, 1),
+               "start_mono_s": round(self.start_mono, 6), "tier": self.tier}
         if self.attrs:
             out["attrs"] = self.attrs
         return out
@@ -131,6 +145,7 @@ class Span:
                        span_id=str(d.get("span_id") or _new_id()),
                        parent_id=str(d.get("parent_id") or ""),
                        start_ms=float(d.get("start_ms", 0.0)),
+                       start_mono=float(d.get("start_mono_s", 0.0)),
                        tier=str(d.get("tier") or ""),
                        attrs=dict(d.get("attrs") or {}))
         except (KeyError, TypeError, ValueError):
@@ -257,6 +272,7 @@ class Tracer:
     def record(self, ctx: Optional[TraceContext], stage: str, dur_s: float,
                *, parent_id: Optional[str] = None, attrs: Optional[dict] = None,
                start_wall: Optional[float] = None,
+               start_mono: Optional[float] = None,
                span_id: Optional[str] = None) -> str:
         """Record one completed span; returns its span id (so callers can
         parent later spans under it). ``span_id`` lets a caller pre-allocate
@@ -273,6 +289,8 @@ class Tracer:
                                is not None else ctx.span_id),
                     start_ms=(start_wall if start_wall is not None
                               else time.time() - dur) * 1000.0,
+                    start_mono=(start_mono if start_mono is not None
+                                else time.perf_counter() - dur),
                     tier=self.tier, attrs=dict(attrs or {}))
         self._observe_stage(stage, span.dur_s)
         self._append(ctx.trace_id, span)
@@ -514,12 +532,18 @@ def current_scope() -> Optional[_Scope]:
 
 
 def record_stage(stage: str, dur_s: float, *,
-                 attrs: Optional[dict] = None) -> str:
-    """Record a span under the ambient scope (no-op when untraced)."""
-    scope = _ACTIVE.get()
+                 attrs: Optional[dict] = None,
+                 scope: Optional[_Scope] = None,
+                 start_mono: Optional[float] = None) -> str:
+    """Record a span under the ambient scope, or under ``scope`` — one a
+    caller captured with ``current_scope()`` while its request's trace was
+    ambient, for code that runs outside it (a serve loop recording into
+    each request's OWN trace). No-op when untraced."""
+    scope = scope or _ACTIVE.get()
     if scope is None:
         return ""
-    return scope.tracer.record(scope.ctx, stage, dur_s, attrs=attrs)
+    return scope.tracer.record(scope.ctx, stage, dur_s, attrs=attrs,
+                               start_mono=start_mono)
 
 
 @contextmanager
@@ -548,4 +572,79 @@ def stage_span(stage: str, attrs: Optional[dict] = None):
             a["error"] = True
         scope.tracer.record(scope.ctx, stage, time.perf_counter() - t0,
                             parent_id=scope.ctx.span_id, attrs=a,
-                            start_wall=wall, span_id=span_id)
+                            start_wall=wall, start_mono=t0, span_id=span_id)
+
+
+# ---------------------------------------------------------------------------
+# the profiler sink + the form for work that belongs to no request
+# ---------------------------------------------------------------------------
+
+_NO_ANNOTATION = nullcontext()
+_trace_annotation = None  # jax.profiler.TraceAnnotation, imported at first use
+
+
+def _annotation(name: str):
+    """A ``jax.profiler.TraceAnnotation`` for ``name`` (about 0.4 us to enter
+    and leave with no profiler session), or nothing while tracing is off.
+    JAX is imported at first use: only code that already runs on JAX
+    annotates, and a tier that never does keeps this module dependency-free."""
+    global _trace_annotation
+    if not _GLOBAL.cfg.enabled:
+        return _NO_ANNOTATION
+    if _trace_annotation is None:
+        from jax.profiler import TraceAnnotation
+
+        _trace_annotation = TraceAnnotation
+    return _trace_annotation(name)
+
+
+class annotated:
+    """Enter a profiler annotation around a synchronous stretch and stamp
+    its ends on ``time.perf_counter()``. Records nothing by itself: on an
+    executor thread (no scope) hand ``t0``/``t1`` back and let the coroutine
+    call ``record_stage``/``observe_stage``. The annotation is built on
+    entry, on the thread that runs the stretch (a ``TraceMe`` starts its
+    clock when constructed)."""
+
+    __slots__ = ("name", "_ann", "t0", "t1")
+
+    def __init__(self, name: str):
+        self.name = name
+
+    def __enter__(self) -> "annotated":
+        self._ann = _annotation(self.name)
+        self._ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.t1 = time.perf_counter()
+        self._ann.__exit__(*exc)
+
+    @property
+    def dur_s(self) -> float:
+        return self.t1 - self.t0
+
+
+def observe_stage(stage: str, dur_s: float) -> None:
+    """Feed ``arkflow_stage_seconds{stage}`` alone: a stretch that belongs
+    to no request touches no trace tree."""
+    if _GLOBAL.cfg.enabled:
+        Tracer._observe_stage(stage, max(0.0, float(dur_s)))
+
+
+class loop_stage(annotated):
+    """``annotated`` + ``observe_stage`` for a synchronous phase of a loop
+    that serves many requests at once. The step's ``kind`` goes in the
+    annotation's name (``gen_prepare:decode``), not the histogram's label."""
+
+    __slots__ = ("stage",)
+
+    def __init__(self, stage: str, kind: str = ""):
+        super().__init__(f"{stage}:{kind}" if kind else stage)
+        self.stage = stage
+
+    def __exit__(self, *exc) -> None:
+        super().__exit__(*exc)
+        observe_stage(self.stage, self.dur_s)
+

@@ -32,6 +32,7 @@ probe backoff, rebuilds the jitted steps, and reinitializes the pools.
 from __future__ import annotations
 
 import asyncio
+import contextvars
 import functools
 import logging
 import time
@@ -51,6 +52,8 @@ from arkflow_tpu.models.paged_decode import (
     paged_prefill,
 )
 from arkflow_tpu.obs import global_registry
+from arkflow_tpu.obs.trace import (annotated, current_scope, loop_stage,
+                                   observe_stage, record_stage)
 from arkflow_tpu.tpu.serving_core import ServingRunnerCore
 
 logger = logging.getLogger("arkflow.serving")
@@ -60,9 +63,11 @@ logger = logging.getLogger("arkflow.serving")
 class _Request:
     prompt: list[int]
     max_new_tokens: int
-    future: asyncio.Future
+    #: created at submit (``_submit``), on the caller's running loop
+    future: Optional[asyncio.Future] = None
     tokens: list[int] = field(default_factory=list)
-    #: wall-clock (monotonic) submit stamp for the TTFT histogram
+    #: ``time.perf_counter()`` at submit, for the TTFT histogram and the
+    #: request's spans (one clock for every stamp below)
     submitted_at: float = 0.0
     #: set once the first decoded token has been observed for this request
     ttft_stamped: bool = False
@@ -74,6 +79,16 @@ class _Request:
     adopt: Optional[dict] = None
     #: export payload built by ``_export_and_finish`` (prefill_only path)
     export: Optional[dict] = None
+    #: the submitter's trace scope: the serve loop runs outside every trace
+    #: and records this request's spans into the request's OWN trace
+    scope: Optional[object] = None
+    #: stamps behind gen_queue_wait / gen_prefill / gen_decode and the
+    #: per-token gap: slot assigned, first token, latest token
+    slot_at: float = 0.0
+    first_token_at: float = 0.0
+    last_token_at: float = 0.0
+    chunks: int = 0
+    shared_tokens: int = 0
 
 
 @dataclass
@@ -90,6 +105,33 @@ class _InFlightDecode:
     act: "np.ndarray"
     reqs: list
     dispatched_at: float
+    #: the enqueue's share of this step's ``gen_device_wait`` and
+    #: ``gen_handoff``, observed once with the fetch's share at apply
+    wait_s: float = 0.0
+    handoff_s: float = 0.0
+
+
+class _Hop:
+    """Stamps around one blocking call handed to an executor thread. The
+    thread carries no trace scope, so it annotates and stamps
+    (``gen_device_wait:<kind>``) and the coroutine reads the result:
+    seconds inside the call, and seconds in the two thread hops around it
+    (call -> the thread starts, the thread ends -> the coroutine resumes,
+    whatever else the event loop ran in between included)."""
+
+    __slots__ = ("wait", "t_call")
+
+    def __init__(self, kind: str):
+        self.wait = annotated(f"gen_device_wait:{kind}")
+        self.t_call = time.perf_counter()
+
+    def run(self, fn):
+        with self.wait:
+            return fn()
+
+    def done(self) -> tuple[float, float]:
+        w = self.wait
+        return w.dur_s, (w.t0 - self.t_call) + (time.perf_counter() - w.t1)
 
 
 class GenerationServer:
@@ -317,7 +359,6 @@ class GenerationServer:
             "arkflow_gen_spec_drafted_total", "draft tokens offered for verification")
         self.m_spec_accepted = reg.counter(
             "arkflow_gen_spec_accepted_total", "draft tokens accepted")
-        self.m_active = reg.gauge("arkflow_gen_active_slots", "busy decode slots")
         self.m_waiting = reg.gauge("arkflow_gen_waiting_requests", "admission queue depth")
         self.m_truncated = reg.counter(
             "arkflow_gen_truncated_total",
@@ -348,11 +389,6 @@ class GenerationServer:
             "gap between step N completing and step N+1 launching "
             "(device idle between consecutive steps)",
             {"model": name, "path": "generate"})
-        self.m_depth = reg.gauge(
-            "arkflow_gen_dispatch_depth",
-            "configured decode dispatch depth (2 = pipelined)",
-            {"model": name})
-        self.m_depth.set(self.dispatch_depth)
         self.m_kernel_paged = reg.gauge(
             "arkflow_gen_decode_kernel_paged",
             "1 when the paged flash-attention kernel serves decode/chunk "
@@ -365,6 +401,13 @@ class GenerationServer:
         self.m_ttft = reg.histogram(
             "arkflow_gen_ttft_seconds",
             "submit-to-first-decoded-token latency per request",
+            {"model": name})
+        # the per-token stamp TTFT lacks: time between one request's
+        # consecutive tokens (decode-step cadence as the caller feels it,
+        # prefill chunks of other slots included)
+        self.m_token_gap = reg.histogram(
+            "arkflow_gen_token_gap_seconds",
+            "gap between consecutive generated tokens of one request",
             {"model": name})
         #: per-server TTFT reservoir behind health_report() percentiles
         #: (m_ttft is registry-global and would mix servers in-process)
@@ -701,15 +744,21 @@ class GenerationServer:
 
         def blocking():
             core.apply_chaos()
-            return jax.block_until_ready(fn())
+            # the jitted call only enqueues; named apart inside the hop's
+            # gen_device_wait so a profile tells dispatch from waiting
+            with annotated(f"gen_dispatch:{key[0]}"):
+                out = fn()
+            return jax.block_until_ready(out)
 
         self._track_gen_dispatch()
+        hop = _Hop(key[0])
         try:
             if deadline is None:
                 out = await asyncio.get_running_loop().run_in_executor(
-                    None, blocking)
+                    None, hop.run, blocking)
             else:
-                out = await core.run_deadlined(blocking, deadline)
+                out = await core.run_deadlined(
+                    functools.partial(hop.run, blocking), deadline)
         except StepDeadlineExceeded:
             raise  # the core already marked UNHEALTHY + scheduled rebuild
         except Exception as e:
@@ -719,6 +768,9 @@ class GenerationServer:
             # an abandoned step counts complete: the device stopped doing
             # useful work, and the reset path rebuilds from fresh pools
             self._track_gen_complete()
+        wait_s, handoff_s = hop.done()
+        observe_stage("gen_device_wait", wait_s)
+        observe_stage("gen_handoff", handoff_s)
         core.health.mark_success()
         return out
 
@@ -735,14 +787,21 @@ class GenerationServer:
             raise ConfigError(
                 f"prompt({len(prompt_ids)}) + max_new({max_new_tokens}) exceeds "
                 f"max_seq={self.max_seq}")
-        req = _Request(list(prompt_ids), max_new_tokens,
-                       asyncio.get_running_loop().create_future(),
-                       submitted_at=time.monotonic())
+        return await self._submit(_Request(list(prompt_ids), max_new_tokens))
+
+    def _submit(self, req: _Request) -> asyncio.Future:
+        """Queue ``req`` for admission and make sure the serve loop runs."""
+        req.future = asyncio.get_running_loop().create_future()
+        req.submitted_at = time.perf_counter()
+        req.scope = current_scope()
         self._pending.append(req)
         self.m_waiting.set(len(self._pending))
         if self._loop_task is None or self._loop_task.done():
-            self._loop_task = asyncio.create_task(self._serve_loop())
-        return await req.future
+            # an empty context: the loop outlives the request that starts it
+            # and must not live inside that request's trace scope
+            self._loop_task = asyncio.create_task(
+                self._serve_loop(), context=contextvars.Context())
+        return req.future
 
     async def prefill_export(self, prompt_ids: list[int],
                              max_new_tokens: int = 64) -> dict:
@@ -767,14 +826,8 @@ class GenerationServer:
             raise ConfigError(
                 f"prompt({len(prompt_ids)}) + max_new({max_new_tokens}) exceeds "
                 f"max_seq={self.max_seq}")
-        req = _Request(list(prompt_ids), max_new_tokens,
-                       asyncio.get_running_loop().create_future(),
-                       submitted_at=time.monotonic(), prefill_only=True)
-        self._pending.append(req)
-        self.m_waiting.set(len(self._pending))
-        if self._loop_task is None or self._loop_task.done():
-            self._loop_task = asyncio.create_task(self._serve_loop())
-        return await req.future
+        return await self._submit(
+            _Request(list(prompt_ids), max_new_tokens, prefill_only=True))
 
     async def generate_from_pages(self, export: Mapping) -> list[int]:
         """Disaggregated decode: adopt a KV-page export produced by a
@@ -816,19 +869,13 @@ class GenerationServer:
                 f"not match pool geometry {pool_shape} for a "
                 f"{len(prompt)}-token prompt")
         first = int(export["first_token"])
-        req = _Request(prompt, max_new,
-                       asyncio.get_running_loop().create_future(),
-                       tokens=[first], submitted_at=time.monotonic(),
-                       ttft_stamped=True, adopt=dict(export))
         if first == self.eos_id or max_new <= 1:
             # complete at the first token: nothing to decode, don't touch
             # the pool (mirrors _handle_token's EOS/budget handling)
             return [] if first == self.eos_id else [first]
-        self._pending.append(req)
-        self.m_waiting.set(len(self._pending))
-        if self._loop_task is None or self._loop_task.done():
-            self._loop_task = asyncio.create_task(self._serve_loop())
-        return await req.future
+        return await self._submit(_Request(
+            prompt, max_new, tokens=[first], ttft_stamped=True,
+            adopt=dict(export)))
 
     async def close(self) -> None:
         self._closed = True
@@ -982,6 +1029,7 @@ class GenerationServer:
         if req.adopt is not None:
             await self._admit_adopted(slot, req)
             return
+        req.shared_tokens = shared_len
         if shared_len > 0:
             self.m_prefix_hits.inc()
             self.m_prefix_pages.inc(shared_len // self.page_size)
@@ -992,13 +1040,14 @@ class GenerationServer:
             # remainder is ever computed.
             self._prefill_pos[slot] = shared_len
             return
-        bucket = self._bucket(n)
-        ids = np.zeros((1, bucket), np.int32)
-        ids[0, :n] = req.prompt
-        # single-row table padded to the slot width
-        table = np.zeros((1, self.pages_per_slot), np.int32)
-        table[0, :len(pages)] = pages
-        self._key, sub = jax.random.split(self._key)
+        with loop_stage("gen_prepare", "prefill"):
+            bucket = self._bucket(n)
+            ids = np.zeros((1, bucket), np.int32)
+            ids[0, :n] = req.prompt
+            # single-row table padded to the slot width
+            table = np.zeros((1, self.pages_per_slot), np.int32)
+            table[0, :len(pages)] = pages
+            self._key, sub = jax.random.split(self._key)
         # off-loop + gated: first call per bucket compiles (seconds on TPU)
         # pools bound EAGERLY: a deadline-abandoned zombie step waking after
         # a pool reset must consume the pools it already owned, never the
@@ -1011,12 +1060,14 @@ class GenerationServer:
             lambda kp=self.k_pages, vp=self.v_pages: self._prefill(
                 jnp.asarray(ids), jnp.asarray([n], jnp.int32), jnp.asarray(table),
                 kp, vp, sub))
-        self._lengths[slot] = n
-        self._cur_tokens[slot] = int(nxt[0])
-        if req.prefill_only:
-            await self._export_and_finish(slot)
-            return
-        self._handle_token(slot, int(nxt[0]))
+        with loop_stage("gen_apply", "prefill"):
+            req.chunks = 1
+            self._lengths[slot] = n
+            self._cur_tokens[slot] = int(nxt[0])
+            if not req.prefill_only:
+                self._handle_token(slot, int(nxt[0]))
+                return
+        await self._export_and_finish(slot)
 
     async def _admit_adopted(self, slot: int, req: _Request) -> None:
         """Seed the slot from a received KV-page export: upload the slabs
@@ -1046,31 +1097,45 @@ class GenerationServer:
         self._lengths[slot] = n
         self._cur_tokens[slot] = int(exp["first_token"])
         # the first token is pre-seeded in req.tokens (counted on the
-        # prefill side); the slot decodes from position n next step
+        # prefill side); the slot decodes from position n next step, and
+        # its token gaps and gen_decode span run from here
+        req.first_token_at = req.last_token_at = time.perf_counter()
 
-    def _stamp_ttft(self, req: _Request) -> None:
-        """First decoded token for this request: record TTFT exactly once
-        (EOS-as-first-token still counts — the model answered)."""
+    def _stamp_ttft(self, req: _Request, now: float) -> bool:
+        """First decoded token for this request: record TTFT and the
+        ``gen_prefill`` span exactly once (EOS-as-first-token still counts —
+        the model answered). True when this was that token."""
         if req.ttft_stamped or req.submitted_at <= 0.0:
-            return
+            return False
         req.ttft_stamped = True
-        dt = time.monotonic() - req.submitted_at
+        dt = now - req.submitted_at
         self.m_ttft.observe(dt)
         self._ttft_samples.append(dt)
         self._ttft_count += 1
+        req.first_token_at = req.last_token_at = now
+        record_stage("gen_prefill", now - req.slot_at, scope=req.scope,
+                     start_mono=req.slot_at,
+                     attrs={"prompt_tokens": len(req.prompt),
+                            "chunks": req.chunks,
+                            "shared_tokens": req.shared_tokens})
+        return True
 
     def _handle_token(self, slot: int, token: int) -> None:
         """Record one generated token; completes the request on EOS/limit."""
         req = self._slot_req[slot]
         if req is None:
             return
-        self._stamp_ttft(req)
+        now = time.perf_counter()  # the one clock read a token costs
+        first = self._stamp_ttft(req, now)
         if token == self.eos_id:
             self._finish(slot)
             return
         req.tokens.append(token)
         self.m_tokens.inc()
         self._tokens_emitted += 1
+        if not first:
+            self.m_token_gap.observe(now - req.last_token_at)
+        req.last_token_at = now
         if len(req.tokens) >= req.max_new_tokens:
             self._finish(slot)
 
@@ -1088,6 +1153,11 @@ class GenerationServer:
         self._lengths[slot] = 0
         self._cur_tokens[slot] = 0
         if req is not None and not req.future.done():
+            if req.first_token_at and not req.prefill_only:
+                record_stage("gen_decode",
+                             req.last_token_at - req.first_token_at,
+                             scope=req.scope, start_mono=req.first_token_at,
+                             attrs={"new_tokens": len(req.tokens)})
             req.future.set_result(
                 req.tokens if req.export is None else req.export)
 
@@ -1098,38 +1168,42 @@ class GenerationServer:
         if req is None:
             self._prefill_pos.pop(slot, None)
             return
-        off = self._prefill_pos[slot]
-        n = len(req.prompt)
-        # chunk width: the configured chunk size, or (prefix-cache remainder
-        # with chunking off) one bucketed span covering the rest
-        c = self.prefill_chunk if self.prefill_chunk else self._bucket(n - off)
-        chunk = req.prompt[off:off + c]
-        ids = np.zeros((1, c), np.int32)
-        ids[0, :len(chunk)] = chunk
-        table = np.zeros((1, self.pages_per_slot), np.int32)
-        table[0, :len(self._slot_pages[slot])] = self._slot_pages[slot]
+        with loop_stage("gen_prepare", "chunk"):
+            off = self._prefill_pos[slot]
+            n = len(req.prompt)
+            # chunk width: the configured chunk size, or (prefix-cache
+            # remainder with chunking off) one bucketed span covering the rest
+            c = (self.prefill_chunk if self.prefill_chunk
+                 else self._bucket(n - off))
+            chunk = req.prompt[off:off + c]
+            ids = np.zeros((1, c), np.int32)
+            ids[0, :len(chunk)] = chunk
+            table = np.zeros((1, self.pages_per_slot), np.int32)
+            table[0, :len(self._slot_pages[slot])] = self._slot_pages[slot]
         logits, self.k_pages, self.v_pages = await self._run_device_step(
             ("chunk", c),
             lambda kp=self.k_pages, vp=self.v_pages: self._chunk(
                 jnp.asarray(ids), jnp.asarray([off], jnp.int32),
                 jnp.asarray([len(chunk)], jnp.int32), jnp.asarray(table),
                 kp, vp))
-        new_off = off + len(chunk)
-        if new_off < n:
-            self._prefill_pos[slot] = new_off
-            return
-        # final chunk: sample the first generated token and join decode
-        del self._prefill_pos[slot]
-        from arkflow_tpu.models.decoder import select_token
+        with loop_stage("gen_apply", "chunk"):
+            req.chunks += 1
+            new_off = off + len(chunk)
+            if new_off < n:
+                self._prefill_pos[slot] = new_off
+                return
+            # final chunk: sample the first generated token and join decode
+            del self._prefill_pos[slot]
+            from arkflow_tpu.models.decoder import select_token
 
-        self._key, sub = jax.random.split(self._key)
-        nxt = select_token(logits, sub, self.temperature, self.top_k)
-        self._lengths[slot] = n
-        self._cur_tokens[slot] = int(nxt[0])
-        if req.prefill_only:
-            await self._export_and_finish(slot)
-            return
-        self._handle_token(slot, int(nxt[0]))
+            self._key, sub = jax.random.split(self._key)
+            nxt = select_token(logits, sub, self.temperature, self.top_k)
+            self._lengths[slot] = n
+            self._cur_tokens[slot] = int(nxt[0])
+            if not req.prefill_only:
+                self._handle_token(slot, int(nxt[0]))
+                return
+        await self._export_and_finish(slot)
 
     async def _export_and_finish(self, slot: int) -> None:
         """Prefill-only completion: fetch the prompt's KV pages to host,
@@ -1145,7 +1219,7 @@ class GenerationServer:
             return
         n = len(req.prompt)
         first = int(self._cur_tokens[slot])
-        self._stamp_ttft(req)
+        self._stamp_ttft(req, time.perf_counter())
         done = first == self.eos_id or req.max_new_tokens <= 1
         if not done:
             req.tokens.append(first)
@@ -1227,7 +1301,6 @@ class GenerationServer:
             act[longest] = False
 
     def _update_gauges(self, busy: int) -> None:
-        self.m_active.set(busy)
         self.m_slots_busy.set(busy)
         self.m_waiting.set(len(self._pending))
         total = self.num_pages - 1
@@ -1318,10 +1391,16 @@ class GenerationServer:
             if self._slot_req[slot] is not None or not self._pending:
                 continue
             req = self._pending[0]  # peek
-            reserved = self._try_reserve(req)
+            with loop_stage("gen_admit"):
+                reserved = self._try_reserve(req)
+                if reserved is not None:
+                    self._pending.popleft()
+                    req.slot_at = time.perf_counter()
+                    record_stage("gen_queue_wait",
+                                 req.slot_at - req.submitted_at,
+                                 scope=req.scope, start_mono=req.submitted_at)
             if reserved is None:
                 break  # head-of-line waits for pages (FIFO fairness)
-            self._pending.popleft()
             pages, shared_len = reserved
             # catch host state up before the admission prefill dispatches:
             # its (possibly first-compile) deadline must not also cover an
@@ -1350,28 +1429,30 @@ class GenerationServer:
         active = [s for s in active if self._slot_req[s] is not None]
         if not active:
             return
-        act = np.zeros(self.slots, bool)
-        act[active] = True
-        for s in active:
-            self._reserve_or_truncate(s, act)
-        cur = jnp.asarray(self._cur_tokens)
-        lens = jnp.asarray(self._lengths)
-        act_dev = jnp.asarray(act)
-        table = self._table_array()
-        self._key, sub = jax.random.split(self._key)
+        with loop_stage("gen_prepare", "decode"):
+            act = np.zeros(self.slots, bool)
+            act[active] = True
+            for s in active:
+                self._reserve_or_truncate(s, act)
+            cur = jnp.asarray(self._cur_tokens)
+            lens = jnp.asarray(self._lengths)
+            act_dev = jnp.asarray(act)
+            table = self._table_array()
+            self._key, sub = jax.random.split(self._key)
         # off-loop + gated: one device-step of wall time (plus first compile)
         nxt, self.k_pages, self.v_pages = await self._run_device_step(
             ("decode",),
             lambda kp=self.k_pages, vp=self.v_pages: self._decode(
                 cur, lens, act_dev, table, kp, vp, sub))
-        self.m_steps.inc()
-        nxt_host = np.asarray(nxt)
-        for s in range(self.slots):
-            if not act[s] or self._slot_req[s] is None:
-                continue
-            self._lengths[s] += 1
-            self._cur_tokens[s] = nxt_host[s]
-            self._handle_token(s, int(nxt_host[s]))
+        with loop_stage("gen_apply", "decode"):
+            self.m_steps.inc()
+            nxt_host = np.asarray(nxt)
+            for s in range(self.slots):
+                if not act[s] or self._slot_req[s] is None:
+                    continue
+                self._lengths[s] += 1
+                self._cur_tokens[s] = nxt_host[s]
+                self._handle_token(s, int(nxt_host[s]))
 
     # -- pipelined dispatch (dispatch_depth 2) -------------------------------
 
@@ -1402,34 +1483,47 @@ class GenerationServer:
                 or self.core.health.state != HEALTHY:
             await self._drain_pipeline()
             return False
-        act = np.zeros(self.slots, bool)
-        act[active] = True
-        pend = self._pipeline
-        eff_lens = self._lengths.copy()
-        if pend is not None:
-            eff_lens += pend.act.astype(np.int32)
-            for s in active:
-                req = self._slot_req[s]
-                if req is None or (pend.act[s] and req is not pend.reqs[s]):
-                    act[s] = False
-                elif pend.act[s] and len(req.tokens) + 1 >= req.max_new_tokens:
-                    # the pending token completes this lane's budget: it
-                    # must not ride the next dispatch
-                    act[s] = False
-        if not act.any():
-            # every lane is finishing on the pending step: apply it and let
-            # the loop re-evaluate (admission / drain / exit)
+        # ``bail``: the verdict of the two ways out that dispatch nothing.
+        # The stretch is annotated either way; only a dispatch observes it,
+        # so gen_prepare counts device steps
+        bail: Optional[bool] = None
+        prep = annotated("gen_prepare:decode")
+        with prep:
+            act = np.zeros(self.slots, bool)
+            act[active] = True
+            pend = self._pipeline
+            eff_lens = self._lengths.copy()
+            if pend is not None:
+                eff_lens += pend.act.astype(np.int32)
+                for s in active:
+                    req = self._slot_req[s]
+                    if req is None or (pend.act[s]
+                                       and req is not pend.reqs[s]):
+                        act[s] = False
+                    elif (pend.act[s]
+                          and len(req.tokens) + 1 >= req.max_new_tokens):
+                        # the pending token completes this lane's budget: it
+                        # must not ride the next dispatch
+                        act[s] = False
+            if not act.any():
+                # every lane is finishing on the pending step: apply it and
+                # let the loop re-evaluate (admission / drain / exit)
+                bail = True
+            elif not all(
+                    self._ensure_page_capacity(int(s), int(eff_lens[s]) + 1)
+                    for s in np.flatnonzero(act)):
+                bail = False  # classic path owns the truncation policy
+            else:
+                cur = (pend.nxt if pend is not None
+                       else jnp.asarray(self._cur_tokens))
+                lens = jnp.asarray(eff_lens)
+                act_dev = jnp.asarray(act)
+                table = self._table_array()
+                self._key, sub = jax.random.split(self._key)
+        if bail is not None:
             await self._drain_pipeline()
-            return True
-        for s in np.flatnonzero(act):
-            if not self._ensure_page_capacity(int(s), int(eff_lens[s]) + 1):
-                await self._drain_pipeline()
-                return False  # classic path owns the truncation policy
-        cur = pend.nxt if pend is not None else jnp.asarray(self._cur_tokens)
-        lens = jnp.asarray(eff_lens)
-        act_dev = jnp.asarray(act)
-        table = self._table_array()
-        self._key, sub = jax.random.split(self._key)
+            return bail
+        observe_stage("gen_prepare", prep.dur_s)
         loop = asyncio.get_running_loop()
         self._track_gen_dispatch()
 
@@ -1439,10 +1533,13 @@ class GenerationServer:
         def enqueue(kp=self.k_pages, vp=self.v_pages):
             return self._decode(cur, lens, act_dev, table, kp, vp, sub)
 
+        hop = _Hop("decode")
         nxt, self.k_pages, self.v_pages = await loop.run_in_executor(
-            None, enqueue)
+            None, hop.run, enqueue)
+        wait_s, handoff_s = hop.done()
         rec = _InFlightDecode(nxt=nxt, act=act, reqs=list(self._slot_req),
-                              dispatched_at=time.monotonic())
+                              dispatched_at=time.monotonic(),
+                              wait_s=wait_s, handoff_s=handoff_s)
         self._pipelined_dispatches += 1
         if pend is not None:
             self._pipeline = None
@@ -1484,14 +1581,15 @@ class GenerationServer:
             return np.asarray(jax.device_get(rec.nxt))
 
         deadline = core.deadline_for(False)  # pipelined steps are warm
+        hop = _Hop("decode")
         try:
             if deadline is None:
                 nxt_host = await asyncio.get_running_loop().run_in_executor(
-                    None, blocking)
+                    None, hop.run, blocking)
             else:
                 nxt_host = await core.run_deadlined(
-                    blocking, core.deadline_remaining(
-                        deadline, rec.dispatched_at))
+                    functools.partial(hop.run, blocking),
+                    core.deadline_remaining(deadline, rec.dispatched_at))
         except StepDeadlineExceeded:
             raise  # core marked UNHEALTHY; the serve loop fails + resets
         except Exception as e:
@@ -1499,17 +1597,21 @@ class GenerationServer:
             raise
         finally:
             self._track_gen_complete()
+        wait_s, handoff_s = hop.done()
+        observe_stage("gen_device_wait", rec.wait_s + wait_s)
+        observe_stage("gen_handoff", rec.handoff_s + handoff_s)
         core.health.mark_success()
-        self.m_steps.inc()
-        for s in range(self.slots):
-            if not rec.act[s]:
-                continue
-            req = self._slot_req[s]
-            if req is None or req is not rec.reqs[s]:
-                continue
-            self._lengths[s] += 1
-            self._cur_tokens[s] = nxt_host[s]
-            self._handle_token(s, int(nxt_host[s]))
+        with loop_stage("gen_apply", "decode"):
+            self.m_steps.inc()
+            for s in range(self.slots):
+                if not rec.act[s]:
+                    continue
+                req = self._slot_req[s]
+                if req is None or req is not rec.reqs[s]:
+                    continue
+                self._lengths[s] += 1
+                self._cur_tokens[s] = nxt_host[s]
+                self._handle_token(s, int(nxt_host[s]))
 
     # -- speculative decode -------------------------------------------------
 
@@ -1537,48 +1639,50 @@ class GenerationServer:
         up to ``speculative_tokens`` drafts in a single chunk call; the
         accepted prefix (argmax-consistent) all lands this step."""
         k = self.speculative_tokens + 1
-        act = np.zeros(self.slots, bool)
-        act[active] = True
-        clen = np.zeros(self.slots, np.int32)
-        ids = np.zeros((self.slots, k), np.int32)
-        for s in active:
-            # width-1 capacity first (truncation policy identical to _step)
-            self._reserve_or_truncate(s, act)
-            if not act[s] or self._slot_req[s] is None:
-                continue
-            req = self._slot_req[s]
-            remaining = req.max_new_tokens - len(req.tokens)
-            room = self.max_seq - int(self._lengths[s])
-            c = max(1, min(k, remaining, room))
-            # widen only as far as free pages allow (never truncate for width)
-            while c > 1 and not self._ensure_page_capacity(
-                    s, int(self._lengths[s]) + c):
-                c -= 1
-            clen[s] = c
-            ids[s, 0] = self._cur_tokens[s]
-            if c > 1:
-                ids[s, 1:c] = self._draft(req, c - 1)
-        table = self._table_array()
+        with loop_stage("gen_prepare", "verify"):
+            act = np.zeros(self.slots, bool)
+            act[active] = True
+            clen = np.zeros(self.slots, np.int32)
+            ids = np.zeros((self.slots, k), np.int32)
+            for s in active:
+                # width-1 capacity first (truncation policy identical to _step)
+                self._reserve_or_truncate(s, act)
+                if not act[s] or self._slot_req[s] is None:
+                    continue
+                req = self._slot_req[s]
+                remaining = req.max_new_tokens - len(req.tokens)
+                room = self.max_seq - int(self._lengths[s])
+                c = max(1, min(k, remaining, room))
+                # widen only as far as free pages allow (never truncate for width)
+                while c > 1 and not self._ensure_page_capacity(
+                        s, int(self._lengths[s]) + c):
+                    c -= 1
+                clen[s] = c
+                ids[s, 0] = self._cur_tokens[s]
+                if c > 1:
+                    ids[s, 1:c] = self._draft(req, c - 1)
+            table = self._table_array()
         logits, self.k_pages, self.v_pages = await self._run_device_step(
             ("verify", k),
             lambda kp=self.k_pages, vp=self.v_pages: self._verify(
                 jnp.asarray(ids), jnp.asarray(self._lengths),
                 jnp.asarray(clen), table, kp, vp))
-        self.m_steps.inc()
-        lg = np.asarray(logits)
-        for s in range(self.slots):
-            if not act[s] or self._slot_req[s] is None or clen[s] == 0:
-                continue
-            c = int(clen[s])
-            outs = lg[s, :c].argmax(-1).astype(np.int32)
-            accepted = 0
-            while accepted < c - 1 and ids[s, accepted + 1] == outs[accepted]:
-                accepted += 1
-            self.m_spec_drafted.inc(c - 1)
-            self.m_spec_accepted.inc(accepted)
-            self._lengths[s] += accepted + 1
-            self._cur_tokens[s] = int(outs[accepted])
-            for t in outs[:accepted + 1]:
-                self._handle_token(s, int(t))
-                if self._slot_req[s] is None:
-                    break
+        with loop_stage("gen_apply", "verify"):
+            self.m_steps.inc()
+            lg = np.asarray(logits)
+            for s in range(self.slots):
+                if not act[s] or self._slot_req[s] is None or clen[s] == 0:
+                    continue
+                c = int(clen[s])
+                outs = lg[s, :c].argmax(-1).astype(np.int32)
+                accepted = 0
+                while accepted < c - 1 and ids[s, accepted + 1] == outs[accepted]:
+                    accepted += 1
+                self.m_spec_drafted.inc(c - 1)
+                self.m_spec_accepted.inc(accepted)
+                self._lengths[s] += accepted + 1
+                self._cur_tokens[s] = int(outs[accepted])
+                for t in outs[:accepted + 1]:
+                    self._handle_token(s, int(t))
+                    if self._slot_req[s] is None:
+                        break

@@ -649,3 +649,234 @@ def test_device_idle_gap_histogram_exists():
     h = [m for m in reg.collect()
          if m.name == "arkflow_tpu_device_idle_gap_seconds"]
     assert h and h[0].count >= 1  # the second dispatch observed one gap
+
+
+# -- the generation serve loop + the classify step (PR 25) --------------------
+
+TINY_DECODER = dict(vocab_size=128, dim=32, layers=1, heads=2, kv_heads=1,
+                    ffn=48, max_seq=64)
+GEN_PROMPTS = [list(range(3, 25)),   # 22 tokens: chunked prefill
+               [9, 4],                # admits one-shot
+               list(range(40, 55)),
+               [7]]
+GEN_STAGES = ("gen_queue_wait", "gen_prefill", "gen_decode")
+LOOP_STAGES = ("gen_admit", "gen_prepare", "gen_handoff", "gen_device_wait",
+               "gen_apply")
+
+
+def _tiny_generation_server(name: str, **kw):
+    import jax
+
+    from arkflow_tpu.models import get_model
+    from arkflow_tpu.tpu.serving import GenerationServer
+
+    fam = get_model("decoder_lm")
+    cfg = fam.make_config(**TINY_DECODER)
+    params = fam.init(jax.random.PRNGKey(11), cfg)
+    kw.setdefault("prefill_chunk", 4)
+    return GenerationServer(params, cfg, slots=2, page_size=4, max_seq=48,
+                            eos_id=-1, name=name, **kw)
+
+
+def _hist_counts(name: str, label: str) -> dict:
+    from arkflow_tpu.obs import global_registry
+
+    return {m.labels.get(label): m.count for m in global_registry().collect()
+            if m.name == name}
+
+
+def _delta(after: dict, before: dict) -> dict:
+    return {k: v - before.get(k, 0) for k, v in after.items()
+            if v - before.get(k, 0)}
+
+
+async def _traced_batch(tracer, server, prompts, max_new=5):
+    """One batch as the stream runs it: its own trace, ambient around a
+    ``process`` span, every row one ``generate`` call."""
+    ctx = tracer.begin()
+    with activate(tracer, ctx):
+        with stage_span("process"):
+            outs = await asyncio.gather(
+                *[server.generate(p, max_new) for p in prompts])
+    tracer.finish(ctx)
+    return (ctx.trace_id if ctx is not None else None), outs
+
+
+def _trace_by_id(tracer, trace_id: str) -> dict:
+    return next(r for r in tracer.slowest(64) if r["trace_id"] == trace_id)
+
+
+def test_generation_spans_land_in_each_requests_own_trace():
+    """Every row of a generated batch gets gen_queue_wait / gen_prefill /
+    gen_decode under ITS batch's ``process`` span — also when the serve loop
+    was started by another batch (it used to live for ever inside the first
+    caller's trace scope) — and the loop's own stages enter no trace."""
+    import time
+
+    tracer = _fresh_global()
+    server = _tiny_generation_server("trace-own")
+
+    async def go():
+        t0 = time.perf_counter()
+        # two batches in flight together: the loop the first one starts
+        # serves the second one's rows too
+        (id1, _), (id2, _) = await asyncio.gather(
+            _traced_batch(tracer, server, GEN_PROMPTS[:2]),
+            _traced_batch(tracer, server, GEN_PROMPTS[2:]))
+        first_before = _trace_by_id(tracer, id1)
+        n_first = len(first_before["spans"])
+        # a third batch after both finished: nothing lands in a closed trace
+        id3, _ = await _traced_batch(tracer, server, GEN_PROMPTS[:1])
+        return t0, time.perf_counter(), (id1, id2, id3), n_first
+
+    t0, t1, ids, n_first = asyncio.run(asyncio.wait_for(go(), timeout=120))
+    for trace_id, rows in zip(ids, (2, 2, 1)):
+        rec = _trace_by_id(tracer, trace_id)
+        process = [s for s in rec["spans"] if s["stage"] == "process"]
+        assert len(process) == 1
+        for stage in GEN_STAGES:
+            spans = [s for s in rec["spans"] if s["stage"] == stage]
+            assert len(spans) == rows, (stage, rec["spans"])
+            assert all(s["parent_id"] == process[0]["span_id"] for s in spans)
+            # the monotonic start: on the clock this test stamped with
+            assert all(t0 <= s["start_mono_s"] <= t1 for s in spans)
+        assert {s["stage"] for s in rec["spans"]} == {"process", *GEN_STAGES}
+        prefill = [s for s in rec["spans"] if s["stage"] == "gen_prefill"]
+        assert all(s["attrs"]["chunks"] >= 1 and s["attrs"]["prompt_tokens"]
+                   for s in prefill)
+        decode = [s for s in rec["spans"] if s["stage"] == "gen_decode"]
+        assert all(s["attrs"]["new_tokens"] == 5 for s in decode)
+    assert len(_trace_by_id(tracer, ids[0])["spans"]) == n_first
+    # a span recorded into a finished trace would reopen it
+    assert tracer.summary()["traces_open"] == 0
+
+
+@pytest.mark.parametrize("server_kw", [
+    dict(prefill_chunk=4),                    # chunked prefill + decode
+    dict(prefill_chunk=0),                    # one-shot prefill
+    dict(prefill_chunk=4, dispatch_depth=2),  # pipelined decode
+    dict(prefill_chunk=4, speculative_tokens=2),
+], ids=["chunked", "one_shot", "depth2", "speculative"])
+def test_serve_loop_stages_count_device_steps_and_token_gaps(server_kw):
+    """One gen_prepare / gen_device_wait / gen_handoff / gen_apply
+    observation per device step, whatever its kind; one token-gap
+    observation per token after a request's first."""
+    _fresh_global()
+    name = "trace-count-" + "-".join(f"{k}{v}" for k, v in server_kw.items())
+    server = _tiny_generation_server(name, **server_kw)
+    steps = {"n": 0}
+    for step in ("_decode", "_chunk", "_prefill", "_verify"):
+        def counted(*a, _fn=getattr(server, step), **kw):
+            steps["n"] += 1
+            return _fn(*a, **kw)
+
+        setattr(server, step, counted)
+
+    stages0 = _hist_counts("arkflow_stage_seconds", "stage")
+    gaps0 = _hist_counts("arkflow_gen_token_gap_seconds", "model")
+
+    async def go():
+        return await asyncio.gather(
+            *[server.generate(p, 6) for p in GEN_PROMPTS])
+
+    outs = asyncio.run(asyncio.wait_for(go(), timeout=120))
+    stages = _delta(_hist_counts("arkflow_stage_seconds", "stage"), stages0)
+    assert steps["n"] > 0
+    for stage in ("gen_prepare", "gen_device_wait", "gen_handoff", "gen_apply"):
+        assert stages.get(stage) == steps["n"], (stage, stages, steps)
+    assert stages.get("gen_admit", 0) >= len(GEN_PROMPTS)
+    gaps = _delta(_hist_counts("arkflow_gen_token_gap_seconds", "model"), gaps0)
+    assert gaps == {name: sum(len(o) for o in outs) - len(outs)}
+
+
+def test_tracing_disabled_same_tokens_and_no_span():
+    tracer = _fresh_global()
+    server = _tiny_generation_server("trace-off")
+
+    async def go():
+        return await _traced_batch(tracer, server, GEN_PROMPTS)
+
+    try:
+        _, on = asyncio.run(asyncio.wait_for(go(), timeout=120))
+        tracer.configure(TracingConfig(enabled=False))
+        recorded = tracer.spans_recorded
+        stages0 = _hist_counts("arkflow_stage_seconds", "stage")
+        _, off = asyncio.run(asyncio.wait_for(go(), timeout=120))
+        assert off == on
+        assert tracer.spans_recorded == recorded
+        assert _delta(_hist_counts("arkflow_stage_seconds", "stage"),
+                      stages0) == {}
+    finally:
+        tracer.configure(TracingConfig())
+
+
+@pytest.mark.parametrize("depth", [1, 2])
+def test_device_fetch_recorded_beside_device_step(depth):
+    """The classify step's fetch (copy + host conversion) is its own span
+    on the depth-1 and the depth-2 path, inside the step that contains it."""
+    import numpy as np
+
+    from arkflow_tpu.tpu.bucketing import BucketPolicy
+    from arkflow_tpu.tpu.runner import ModelRunner
+
+    tracer = _fresh_global()
+    runner = ModelRunner(
+        "bert_classifier",
+        {"vocab_size": 128, "hidden": 16, "layers": 1, "heads": 2,
+         "ffn": 32, "max_positions": 32, "num_labels": 2},
+        buckets=BucketPolicy((2,), (16,)), dispatch_depth=depth)
+    inputs = {"input_ids": np.zeros((2, 16), dtype=np.int32),
+              "attention_mask": np.ones((2, 16), dtype=np.int32)}
+
+    async def go():
+        ctx = tracer.begin()
+        with activate(tracer, ctx):
+            await runner.infer(inputs)  # first-seen shape: the watched path
+            await runner.infer(inputs)  # warm: depth 2 splits enqueue / fetch
+        tracer.finish(ctx)
+        return ctx.trace_id
+
+    rec = _trace_by_id(tracer, asyncio.run(go()))
+    by_stage: dict = {}
+    for s in rec["spans"]:
+        by_stage.setdefault(s["stage"], []).append(s["dur_ms"])
+    assert len(by_stage["device_fetch"]) == 2
+    assert len(by_stage["device_step"]) == 1
+    steps = by_stage["device_step_first"] + by_stage["device_step"]
+    assert all(step >= fetch
+               for step, fetch in zip(steps, by_stage["device_fetch"]))
+
+
+def test_loop_stage_feeds_histogram_and_profiler_not_the_trace_tree(tmp_path):
+    """The form for work that belongs to no request: stage histogram and a
+    profiler annotation of the same name (kind appended), no span — even
+    with a trace scope ambient."""
+    import glob
+    import os
+
+    import jax
+    from jax.profiler import ProfileData
+
+    from arkflow_tpu.obs.trace import annotated, loop_stage
+
+    tracer = _fresh_global()
+    before = _hist_counts("arkflow_stage_seconds", "stage")
+    ctx = tracer.begin()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with activate(tracer, ctx):
+            with loop_stage("gen_prepare", "decode"):
+                pass
+            with annotated("gen_device_wait:decode") as wait:
+                pass
+    finally:
+        jax.profiler.stop_trace()
+    assert wait.dur_s >= 0.0
+    assert _delta(_hist_counts("arkflow_stage_seconds", "stage"),
+                  before) == {"gen_prepare": 1}
+    assert tracer.spans_recorded == 0
+    path = sorted(glob.glob(os.path.join(
+        str(tmp_path), "plugins", "profile", "*", "*.xplane.pb")))[-1]
+    names = {e.name for pl in ProfileData.from_file(path).planes
+             for ln in pl.lines for e in ln.events}
+    assert {"gen_prepare:decode", "gen_device_wait:decode"} <= names
