@@ -5,7 +5,8 @@ Design rules (TPU-first):
   jittable, shardable with ``NamedSharding`` pytrees, no framework state.
 - Compute dtype is bfloat16 (MXU-native); normalisation statistics and softmax
   run in float32 for stability; params are kept in float32 master copies and
-  cast at use (standard mixed-precision recipe).
+  cast at use (standard mixed-precision recipe; a no-op where a serving path
+  placed the leaf in its compute dtype already, as ``tpu_generate`` does).
 - No Python control flow on data; recurrences use ``lax.scan``.
 """
 

@@ -352,6 +352,38 @@ def param_specs(cfg: DecoderConfig, axes: dict) -> dict:
     }
 
 
+def serve_dtypes(cfg: DecoderConfig) -> dict:
+    """The dtype the forward consumes each leaf in, in ``param_specs``'s tree
+    shape: bfloat16 wherever ``cm.dense`` / ``cm.embedding`` / the expert
+    einsums cast at use, float32 for what is multiplied in float32 (norm
+    scales, the MoE router). A serving path that places leaves in these
+    dtypes once runs the same arithmetic with no per-step cast of a weight;
+    a layer added to ``init`` states its dtype here
+    (tests/test_generate_placed_params.py fails on a cast that is left)."""
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    layer = {
+        "attn_norm": {"scale": f32},
+        "wq": {"w": bf16},
+        "wk": {"w": bf16},
+        "wv": {"w": bf16},
+        "wo": {"w": bf16},
+        "mlp_norm": {"scale": f32},
+    }
+    if cfg.num_experts > 1:
+        layer["router"] = {"w": f32}
+        layer["experts"] = {"w_gate": bf16, "w_up": bf16, "w_down": bf16}
+    else:
+        layer["w_gate"] = {"w": bf16}
+        layer["w_up"] = {"w": bf16}
+        layer["w_down"] = {"w": bf16}
+    return {
+        "embed": {"table": bf16},
+        "norm_out": {"scale": f32},
+        "lm_head": {"w": bf16},
+        "layers": layer,
+    }
+
+
 def from_hf_state_dict(state: dict, cfg: DecoderConfig) -> dict:
     """Convert a HuggingFace ``LlamaForCausalLM`` state_dict (torch tensors —
     any dtype including bfloat16 — or numpy arrays) into this model's param
@@ -656,6 +688,7 @@ register_model(
         input_spec=input_spec,
         param_specs=param_specs,
         extras={
+            "serve_dtypes": serve_dtypes,
             "forward": forward,
             "loss_fn": loss_fn,
             "make_train_step": make_train_step,

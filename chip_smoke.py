@@ -235,6 +235,16 @@ def leaf_devices(tree) -> list[str]:
     return sorted(devs)
 
 
+def placed_param_bytes() -> dict:
+    """dtype -> bytes of the generate tree as placed: the program's
+    ``arkflow_gen_param_bytes`` gauge, set by ``tpu_generate`` at placement."""
+    from arkflow_tpu.obs import global_registry
+
+    return {m.labels["dtype"]: int(m.value)
+            for m in global_registry().collect()
+            if getattr(m, "name", "") == "arkflow_gen_param_bytes"}
+
+
 # -- classify ------------------------------------------------------------------
 
 
@@ -468,6 +478,7 @@ def generate_phase(seed: int, base: dict, against: dict, tag: str,
         "mesh": base.get("mesh"), "decode_kernel": announced,
         "kernel_parity_probe": server.kernel_parity,
         "params_on": leaf_devices(processor.params),
+        "param_bytes_placed": placed_param_bytes(),
         "kv_pool_shape": list(server.k_pages.shape),
         "kv_pool_shard_shapes": sorted(
             {str(tuple(sh.data.shape)) for sh in server.k_pages.addressable_shards}),
@@ -477,6 +488,9 @@ def generate_phase(seed: int, base: dict, against: dict, tag: str,
                                  for k in server._seen_steps),
         "device_bytes_in_use": device_bytes(),
     }
+    placed = info["param_bytes_placed"]
+    require(placed.get("bfloat16", 0) > 100 * placed.get("float32", 0),
+            f"generate weights are not placed in bfloat16: {placed}")
     require(max(plens) > server.prefill_chunk > 0
             and ("chunk", server.prefill_chunk) in server._seen_steps,
             "no prompt went through chunked prefill")

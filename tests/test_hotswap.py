@@ -426,7 +426,7 @@ def test_continuous_swap_keeps_processor_params_alias_in_sync(tmp_path):
         "page_size": 4,
     }, Resource())
     ck = str(tmp_path / "ck")
-    checkpoint.save(ck, proc.params)
+    checkpoint.save(ck, proc.host_params)
     boot_params = proc.params
 
     async def go():
@@ -494,7 +494,7 @@ def test_generate_batch_swap_keeps_outputs(tmp_path):
         "seq_buckets": [16],
     }, Resource())
     ck = str(tmp_path / "ck")
-    checkpoint.save(ck, proc.params)
+    checkpoint.save(ck, proc.host_params)
     batch = MessageBatch.new_binary([b"one small step", b"for a model"])
 
     async def go():
@@ -518,7 +518,7 @@ def test_generate_continuous_swap_drains_and_resets_caches(tmp_path):
     }, Resource())
     srv = proc._server
     ck = str(tmp_path / "ck")
-    checkpoint.save(ck, proc.params)
+    checkpoint.save(ck, proc.host_params)
     batch = MessageBatch.new_binary([b"repeated prompt text goes here"])
 
     async def go():
@@ -551,7 +551,7 @@ def test_generate_continuous_swap_under_inflight_load(tmp_path):
         "page_size": 4,
     }, Resource())
     ck = str(tmp_path / "ck")
-    checkpoint.save(ck, proc.params)
+    checkpoint.save(ck, proc.host_params)
     prompts = [f"prompt number {i} padding words".encode() for i in range(6)]
 
     async def go():

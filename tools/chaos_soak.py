@@ -1200,7 +1200,7 @@ def run_swap_soak(seconds: float = 120.0, seed: int = 7, messages: int = 64,
         import os
 
         ck = os.path.join(ckpt_dir, "generate")
-        checkpoint.save(ck, proc.params)
+        checkpoint.save(ck, proc.host_params)
 
         events: dict = {"good_committed": False}
 
