@@ -16,6 +16,7 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 from functools import partial
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -55,6 +56,106 @@ class DecoderConfig:
     #: FLOPs for HBM so long-context training fits (activations are O(layers)
     #: otherwise)
     remat: bool = False
+    # -- latent attention (MLA, the DeepSeek-V2/V3 layout), under the
+    # published key names. ``kv_lora_rank`` > 0 turns it on: per token one
+    # normed latent row of that width and one rotated rope key of
+    # ``qk_rope_head_dim`` are cached for ALL heads; heads carry their own
+    # sizes (q/k ``qk_nope_head_dim + qk_rope_head_dim``, v ``v_head_dim``),
+    # not ``dim // heads``, and ``kv_heads`` is unused.
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    #: a low-rank query projection is not implemented: only None (a plain
+    #: ``dim -> heads * qk`` product) is accepted
+    q_lora_rank: Optional[int] = None
+    #: rotate the pairs (2i, 2i+1) instead of (i, i + d/2): what a latent
+    #: model does, and only a latent model (any other value raises)
+    rope_interleave: bool = False
+    # -- dropless top-k routed experts with shared experts (DeepSeek-V3
+    # routing). ``n_routed_experts`` > 0 turns it on for every layer from
+    # ``first_k_dense_replace`` on (the leading layers stay dense SwiGLU of
+    # width ``ffn``): scores ``sigmoid(x W_r)`` in float32, the top
+    # ``num_experts_per_tok`` of ``score + selection bias`` are chosen, the
+    # chosen experts are weighed by their UNBIASED scores, normalised to sum
+    # 1, times ``routed_scaling_factor``, and ``n_shared_experts`` experts
+    # of the same width see every token. No capacity: nothing is dropped at
+    # any load. Latent attention and routed experts are served together
+    # only (the one published layout that combines them), after at least
+    # one leading dense layer.
+    n_routed_experts: int = 0
+    num_experts_per_tok: int = 0
+    n_shared_experts: int = 0
+    moe_intermediate_size: int = 0
+    first_k_dense_replace: int = 0
+    routed_scaling_factor: float = 1.0
+    #: only what the routing above states is implemented; other values of
+    #: these five raise ConfigError
+    norm_topk_prob: bool = True
+    scoring_func: str = "sigmoid"
+    topk_method: str = "noaux_tc"
+    n_group: int = 1
+    topk_group: int = 1
+
+    def __post_init__(self):
+        from arkflow_tpu.errors import ConfigError
+
+        if self.latent:
+            if min(self.qk_nope_head_dim, self.qk_rope_head_dim,
+                   self.v_head_dim) <= 0 or self.qk_rope_head_dim % 2:
+                raise ConfigError(
+                    "kv_lora_rank > 0 (latent attention) needs "
+                    "qk_nope_head_dim, v_head_dim and an even "
+                    "qk_rope_head_dim")
+            if self.q_lora_rank is not None:
+                raise ConfigError(
+                    "latent attention with a low-rank query projection "
+                    f"(q_lora_rank={self.q_lora_rank}) is not implemented")
+            if self.use_ring_attention or self.num_experts > 1:
+                raise ConfigError(
+                    "latent attention composes with neither ring attention "
+                    "nor the Switch top-1 layer (num_experts)")
+        if self.latent != self.routed or self.latent != self.rope_interleave:
+            raise ConfigError(
+                "latent attention (kv_lora_rank), top-k routed experts "
+                "(n_routed_experts) and rope_interleave are served together "
+                "or not at all so far")
+        if self.routed:
+            if not (0 < self.num_experts_per_tok <= self.n_routed_experts
+                    and self.moe_intermediate_size > 0
+                    and 0 < self.first_k_dense_replace < self.layers):
+                raise ConfigError(
+                    "n_routed_experts needs 0 < num_experts_per_tok <= "
+                    "n_routed_experts, moe_intermediate_size > 0 and "
+                    "0 < first_k_dense_replace < layers (a leading dense "
+                    "stack, then an expert stack)")
+            if (self.scoring_func, self.topk_method, self.n_group,
+                    self.topk_group, self.norm_topk_prob) != (
+                    "sigmoid", "noaux_tc", 1, 1, True):
+                raise ConfigError(
+                    "routed experts implement scoring_func sigmoid, "
+                    "topk_method noaux_tc, n_group = topk_group = 1 (no "
+                    "group-limited selection) and norm_topk_prob true; got "
+                    f"{self.scoring_func!r}, {self.topk_method!r}, "
+                    f"{self.n_group}, {self.topk_group}, {self.norm_topk_prob}")
+
+    @property
+    def latent(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def routed(self) -> bool:
+        return self.n_routed_experts > 0
+
+    @property
+    def dense_layers(self) -> int:
+        """Layers of the leading dense stack (all of them without experts)."""
+        return self.first_k_dense_replace if self.routed else self.layers
+
+    @property
+    def expert_layers(self) -> int:
+        """Layers of the routed-expert stack that follows it."""
+        return self.layers - self.dense_layers
 
 
 def llama3_8b() -> DecoderConfig:
@@ -64,7 +165,80 @@ def llama3_8b() -> DecoderConfig:
     )
 
 
+def _init_latent_layer(key, cfg: DecoderConfig, routed: bool) -> dict:
+    """One layer of a latent-attention model: the MLA projections (HF names:
+    q_proj, kv_a_proj_with_mqa, kv_a_layernorm, kv_b_proj, o_proj) and
+    either a dense SwiGLU or the routed experts. ``experts`` holds the
+    routed experts FIRST and the shared experts after them, each of width
+    ``moe_intermediate_size``: a shared MLP of ``n_shared_experts`` times
+    that width is the sum of so many SwiGLUs of one width, and one stacked
+    tensor lets one product (``ops/moe_experts``) serve both."""
+    k = iter(jax.random.split(key, 12))
+    qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+    layer = {
+        "attn_norm": cm.rms_norm_init(cfg.dim),
+        "wq": cm.dense_init(next(k), cfg.dim, cfg.heads * qk, bias=False),
+        "wkv_a": cm.dense_init(next(k), cfg.dim,
+                               cfg.kv_lora_rank + cfg.qk_rope_head_dim, bias=False),
+        "kv_norm": cm.rms_norm_init(cfg.kv_lora_rank),
+        "wkv_b": cm.dense_init(
+            next(k), cfg.kv_lora_rank,
+            cfg.heads * (cfg.qk_nope_head_dim + cfg.v_head_dim), bias=False),
+        "wo": cm.dense_init(next(k), cfg.heads * cfg.v_head_dim, cfg.dim, bias=False),
+        "mlp_norm": cm.rms_norm_init(cfg.dim),
+    }
+    if not routed:
+        layer["w_gate"] = cm.dense_init(next(k), cfg.dim, cfg.ffn, bias=False)
+        layer["w_up"] = cm.dense_init(next(k), cfg.dim, cfg.ffn, bias=False)
+        layer["w_down"] = cm.dense_init(next(k), cfg.ffn, cfg.dim, bias=False)
+        return layer
+    e = cfg.n_routed_experts + cfg.n_shared_experts
+    f = cfg.moe_intermediate_size
+    up, down = 1.0 / (cfg.dim ** 0.5), 1.0 / (f ** 0.5)
+    layer["router"] = cm.dense_init(next(k), cfg.dim, cfg.n_routed_experts, bias=False)
+    # the noaux_tc selection bias: trained to BALANCE load, so never zero in
+    # a real checkpoint. Seeded non-zero, of the order of the gap between
+    # neighbouring scores (~0.01 for 128 experts): selecting by score + bias
+    # and by the score alone then differ, and a random router, balanced
+    # already, stays balanced. (+-0.1 was tried on the chip, PERF.md PR 27:
+    # it decides the selection, 16 lanes then hit 52 experts a layer and
+    # not 69, and the busiest expert takes 9 x the mean load.)
+    layer["router_bias"] = jax.random.uniform(
+        next(k), (cfg.n_routed_experts,), jnp.float32, -0.01, 0.01)
+    layer["experts"] = {
+        "w_gate": jax.random.uniform(next(k), (e, cfg.dim, f), jnp.float32, -up, up),
+        "w_up": jax.random.uniform(next(k), (e, cfg.dim, f), jnp.float32, -up, up),
+        "w_down": jax.random.uniform(next(k), (e, f, cfg.dim), jnp.float32, -down, down),
+    }
+    return layer
+
+
+def _init_latent(rng, cfg: DecoderConfig) -> dict:
+    keys = iter(jax.random.split(rng, 2 + cfg.layers))
+    params = {
+        "embed": cm.embedding_init(next(keys), cfg.vocab_size, cfg.dim),
+        "norm_out": cm.rms_norm_init(cfg.dim),
+        "lm_head": cm.dense_init(next(keys), cfg.dim, cfg.vocab_size, bias=False),
+    }
+    for name, n, routed in (("dense_layers", cfg.dense_layers, False),
+                            ("layers", cfg.expert_layers, True)):
+        stack = [_init_latent_layer(next(keys), cfg, routed) for _ in range(n)]
+        params[name] = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *stack)
+    return params
+
+
+def layer_stacks(params: dict, cfg: DecoderConfig) -> list:
+    """The model's layer stacks in order, as (stacked params, routed?): one
+    for every model but a latent one, which has ``dense_layers`` and then
+    ``layers`` (the expert stack)."""
+    if cfg.routed:
+        return [(params["dense_layers"], False), (params["layers"], True)]
+    return [(params["layers"], False)]
+
+
 def init(rng, cfg: DecoderConfig) -> dict:
+    if cfg.latent:
+        return _init_latent(rng, cfg)
     dh = cfg.dim // cfg.heads
     keys = iter(jax.random.split(rng, 4 + 7 * cfg.layers))
     params = {
@@ -111,6 +285,141 @@ def _rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)
+
+
+def _rope_interleaved(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
+    """Rotary embedding over the pairs (2i, 2i+1) (``rope_interleave``).
+    x: [B, S, ..., D]; positions: [B, S]."""
+    d = x.shape[-1]
+    freqs = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    angles = positions[..., None].astype(jnp.float32) * freqs  # [B, S, D/2]
+    angles = angles.reshape(angles.shape[:2] + (1,) * (x.ndim - 3) + (d // 2,))
+    cos, sin = jnp.cos(angles), jnp.sin(angles)
+    pairs = x.astype(jnp.float32).reshape(x.shape[:-1] + (d // 2, 2))
+    a, b = pairs[..., 0], pairs[..., 1]
+    out = jnp.stack([a * cos - b * sin, a * sin + b * cos], axis=-1)
+    return out.reshape(x.shape).astype(x.dtype)
+
+
+def mla_project(lp: dict, y: jnp.ndarray, cfg: DecoderConfig, positions):
+    """The latent-attention projections of normed activations ``y``
+    [B, S, dim] at ``positions`` [B, S]: per-head queries split into their
+    no-position part [B, S, H, nope] and rotated rope part [B, S, H, rope],
+    and — what the cache holds — the normed latent row ``c`` [B, S,
+    kv_lora_rank] and the one rotated rope key ``k_r`` [B, S, rope] that
+    every head shares."""
+    b, s = positions.shape
+    nope, rope = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    rot = _rope_interleaved
+    q = cm.dense(lp["wq"], y).reshape(b, s, cfg.heads, nope + rope)
+    kv = cm.dense(lp["wkv_a"], y)
+    c = cm.rms_norm(lp["kv_norm"], kv[..., :cfg.kv_lora_rank], cfg.norm_eps)
+    k_r = rot(kv[..., None, cfg.kv_lora_rank:], positions, cfg.rope_theta)[:, :, 0]
+    return q[..., :nope], rot(q[..., nope:], positions, cfg.rope_theta), c, k_r
+
+
+def _mla_up(lp: dict, cfg: DecoderConfig):
+    """``kv_b_proj`` as its two per-head halves: W_uk [L, H, nope] (latent
+    -> the keys' no-position part) and W_uv [L, H, v] (latent -> values)."""
+    w = lp["wkv_b"]["w"].reshape(
+        cfg.kv_lora_rank, cfg.heads, cfg.qk_nope_head_dim + cfg.v_head_dim)
+    return w[..., :cfg.qk_nope_head_dim], w[..., cfg.qk_nope_head_dim:]
+
+
+def mla_absorb_query(lp: dict, q_nope: jnp.ndarray, cfg: DecoderConfig) -> jnp.ndarray:
+    """Carry the queries into the latent space (``q_nope W_uk^T``): scored
+    against the cached ``c`` they give ``q_nope . k_nope`` without ever
+    expanding a key. [B, S, H, nope] -> [B, S, H, kv_lora_rank]."""
+    w_uk, _ = _mla_up(lp, cfg)
+    return jnp.einsum("bshn,lhn->bshl", q_nope, w_uk.astype(q_nope.dtype))
+
+
+def mla_output(lp: dict, o_lat: jnp.ndarray, cfg: DecoderConfig) -> jnp.ndarray:
+    """``sum p c`` per head [B, S, H, kv_lora_rank] -> the attention
+    block's output [B, S, dim]: ``W_uv`` then ``o_proj``."""
+    _, w_uv = _mla_up(lp, cfg)
+    o = jnp.einsum("bshl,lhv->bshv", o_lat, w_uv.astype(o_lat.dtype))
+    return cm.dense(lp["wo"], o.reshape(o.shape[:2] + (cfg.heads * cfg.v_head_dim,)))
+
+
+def mla_expanded_attention(lp: dict, q_nope, q_rope, c, k_r, mask,
+                           cfg: DecoderConfig) -> jnp.ndarray:
+    """The published (expanded) form over a block that holds its own keys:
+    ``[k_nope | v] = c W_kvb`` per head, the shared ``k_r`` appended to
+    every head's key, softmax over ``q . k / sqrt(nope + rope)``. Used by
+    the full forward and the one-shot prefill; the paged paths run the
+    absorbed form against the cache. Returns [B, S, dim]."""
+    w_uk, w_uv = _mla_up(lp, cfg)
+    k_nope = jnp.einsum("bsl,lhn->bshn", c, w_uk.astype(c.dtype))
+    v = jnp.einsum("bsl,lhv->bshv", c, w_uv.astype(c.dtype))
+    q = jnp.concatenate([q_nope, q_rope], axis=-1)
+    k = jnp.concatenate(
+        [k_nope, jnp.broadcast_to(k_r[:, :, None, :], k_nope.shape[:3] + k_r.shape[-1:])],
+        axis=-1)
+    attn = cm.attention(q, k, v, mask)
+    return cm.dense(lp["wo"], attn.reshape(attn.shape[:2] + (cfg.heads * cfg.v_head_dim,)))
+
+
+def route_topk(lp: dict, y: jnp.ndarray, cfg: DecoderConfig, token_mask=None):
+    """The router of one expert layer over tokens ``y`` [T, dim]: float32
+    sigmoid scores (the product at ``highest`` precision — on a TPU a
+    float32 product otherwise runs in bfloat16 passes, and a 6th-against-7th
+    choice is decided in the fourth decimal), the top
+    ``num_experts_per_tok`` of ``score + router_bias`` chosen, weighed by
+    the unbiased scores (normalised, times ``routed_scaling_factor``).
+
+    Returns the combine weights [T, E + shared] float32 — routed weights in
+    their experts' columns, 1 in the shared experts' — and the layer's load
+    [E] int32 (tokens routed to each expert). Tokens that ``token_mask``
+    excludes (inactive lanes, padding) have an all-zero row: they route
+    nowhere and count nowhere."""
+    e, k = cfg.n_routed_experts, cfg.num_experts_per_tok
+    scores = jax.nn.sigmoid(jnp.dot(
+        y.astype(jnp.float32), lp["router"]["w"].astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    _, idx = jax.lax.top_k(scores + lp["router_bias"].astype(jnp.float32), k)
+    w = jnp.take_along_axis(scores, idx, axis=-1)                 # [T, k]
+    w = w / (w.sum(axis=-1, keepdims=True) + 1e-20) * cfg.routed_scaling_factor
+    chosen = jax.nn.one_hot(idx, e, dtype=jnp.float32)            # [T, k, E]
+    live = (jnp.ones(y.shape[:1], jnp.float32) if token_mask is None
+            else token_mask.reshape(-1).astype(jnp.float32))
+    assign = chosen.sum(axis=1) * live[:, None]                   # [T, E] 0/1
+    cw = jnp.einsum("tk,tke->te", w, chosen) * live[:, None]
+    shared = jnp.broadcast_to(live[:, None], (y.shape[0], cfg.n_shared_experts))
+    return (jnp.concatenate([cw, shared], axis=-1),
+            assign.sum(axis=0).astype(jnp.int32))
+
+
+def routed_mlp(lp: dict, y: jnp.ndarray, cfg: DecoderConfig, token_mask=None,
+               kernel: bool = False, interpret: bool = False, stacked=None):
+    """Routed + shared experts over ``y`` [B, S, dim] -> (output [B, S,
+    dim], load [E]). ``kernel`` runs the products through the Pallas kernel
+    that reads only the experts hit (``ops/moe_experts``); otherwise plain
+    XLA over every expert. The experts are ``lp["experts"]``, or — from a
+    layer loop that must not slice them — ``stacked = (experts of the whole
+    stack, this layer's index)``."""
+    from arkflow_tpu.ops.moe_experts import expert_swiglu_dense, moe_expert_swiglu
+
+    b, s, d = y.shape
+    yf = y.reshape(b * s, d)
+    cw, load = route_topk(lp, yf, cfg, token_mask)
+    ex, layer = stacked if stacked is not None else (lp["experts"], None)
+    if kernel:
+        out = moe_expert_swiglu(yf, cw, ex["w_gate"], ex["w_up"], ex["w_down"],
+                                layer, interpret=interpret)
+    else:
+        if layer is not None:
+            ex = jax.tree_util.tree_map(lambda a: a[layer], ex)
+        out = expert_swiglu_dense(yf, cw, ex["w_gate"], ex["w_up"], ex["w_down"])
+    return out.reshape(b, s, d), load
+
+
+def moe_step_stats(loads: jnp.ndarray) -> jnp.ndarray:
+    """The three counters a serving step reports, from the expert layers'
+    loads [layers, E]: (token, expert) pairs routed (summed over layers),
+    distinct experts hit (summed over layers) and the largest expert's load
+    (over layers). int32 [3]."""
+    return jnp.stack([loads.sum(), (loads > 0).sum(), loads.max()]).astype(jnp.int32)
 
 
 def _moe_mlp(lp: dict, y: jnp.ndarray, cfg: DecoderConfig,
@@ -232,6 +541,8 @@ def forward(params: dict, cfg: DecoderConfig, input_ids, *, axes=None, mesh=None
     """
     axes = axes or {}
     b, s = input_ids.shape
+    if cfg.latent:
+        return _forward_latent(params, cfg, input_ids, axes, return_aux)
     dh = cfg.dim // cfg.heads
     group = cfg.heads // cfg.kv_heads
     x = cm.embedding(params["embed"], input_ids)
@@ -269,6 +580,38 @@ def forward(params: dict, cfg: DecoderConfig, input_ids, *, axes=None, mesh=None
     logits = cm.dense(params["lm_head"], x).astype(jnp.float32)
     if return_aux:
         return logits, {"load_balance": lb_per_layer.mean(), "router_z": z_per_layer.mean()}
+    return logits
+
+
+def _forward_latent(params: dict, cfg: DecoderConfig, input_ids, axes: dict,
+                    return_aux: bool):
+    """``forward`` for a latent-attention model: the published (expanded)
+    attention, and one scan per layer stack — the leading dense layers, then
+    the expert layers (plain XLA over every expert; dropless, so there is no
+    auxiliary loss to carry and the aux terms are zero)."""
+    b, s = input_ids.shape
+    x = _shard_act(cm.embedding(params["embed"], input_ids), axes)
+    positions = jnp.broadcast_to(jnp.arange(s)[None, :], (b, s))
+    causal = jnp.tril(jnp.ones((s, s), bool))[None, None, :, :]
+
+    def make_layer(routed: bool):
+        def layer(x, lp):
+            y = cm.rms_norm(lp["attn_norm"], x, cfg.norm_eps)
+            x = x + mla_expanded_attention(
+                lp, *mla_project(lp, y, cfg, positions), causal, cfg)
+            x = _shard_act(x, axes)
+            y = cm.rms_norm(lp["mlp_norm"], x, cfg.norm_eps)
+            x = x + (routed_mlp(lp, y, cfg)[0] if routed else _mlp(lp, y, cfg))
+            return _shard_act(x, axes), None
+        return jax.checkpoint(layer, prevent_cse=False) if cfg.remat else layer
+
+    for stack, routed in layer_stacks(params, cfg):
+        x, _ = jax.lax.scan(make_layer(routed), x, stack)
+    x = cm.rms_norm(params["norm_out"], x, cfg.norm_eps)
+    logits = cm.dense(params["lm_head"], x).astype(jnp.float32)
+    if return_aux:
+        zero = jnp.zeros((), jnp.float32)
+        return logits, {"load_balance": zero, "router_z": zero}
     return logits
 
 
@@ -320,6 +663,11 @@ def make_train_step(cfg: DecoderConfig, optimizer, *, axes=None, mesh=None):
 def param_specs(cfg: DecoderConfig, axes: dict) -> dict:
     """Sharding layout: attention heads and FFN over ``tp``; expert dim over
     ``ep`` (MoE); embed/lm_head on the vocab dim; norms replicated."""
+    if cfg.latent:
+        # a latent model is served on one chip so far (tp / ep over latent
+        # pages and routed experts is refused where a mesh is built): every
+        # leaf is replicated, in the tree's own shape
+        return jax.tree_util.tree_map(lambda _: P(), serve_dtypes(cfg))
     tp = axes.get("tp")
     ep = axes.get("ep")
     layer = {
@@ -361,6 +709,8 @@ def serve_dtypes(cfg: DecoderConfig) -> dict:
     a layer added to ``init`` states its dtype here
     (tests/test_generate_placed_params.py fails on a cast that is left)."""
     bf16, f32 = jnp.bfloat16, jnp.float32
+    if cfg.latent:
+        return _serve_dtypes_latent(cfg)
     layer = {
         "attn_norm": {"scale": f32},
         "wq": {"w": bf16},
@@ -384,12 +734,51 @@ def serve_dtypes(cfg: DecoderConfig) -> dict:
     }
 
 
+def _serve_dtypes_latent(cfg: DecoderConfig) -> dict:
+    """``serve_dtypes`` for a latent-attention model: the router, its
+    selection bias and every norm scale (the latent norm too) float32 — the
+    router is multiplied in float32 and its bias added to float32 scores —
+    and every other leaf bfloat16."""
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    attn = {
+        "attn_norm": {"scale": f32},
+        "wq": {"w": bf16},
+        "wkv_a": {"w": bf16},
+        "kv_norm": {"scale": f32},
+        "wkv_b": {"w": bf16},
+        "wo": {"w": bf16},
+        "mlp_norm": {"scale": f32},
+    }
+    dense = {**attn, "w_gate": {"w": bf16}, "w_up": {"w": bf16},
+             "w_down": {"w": bf16}}
+    routed = {**attn, "router": {"w": f32}, "router_bias": f32,
+              "experts": {"w_gate": bf16, "w_up": bf16, "w_down": bf16}}
+    return {
+        "embed": {"table": bf16},
+        "norm_out": {"scale": f32},
+        "lm_head": {"w": bf16},
+        "dense_layers": dense,
+        "layers": routed,
+    }
+
+
+def _no_latent(cfg: DecoderConfig, what: str) -> None:
+    """The paths that know only per-head K/V refuse a latent model."""
+    if cfg.latent:
+        from arkflow_tpu.errors import ConfigError
+
+        raise ConfigError(
+            f"{what} does not carry a latent (MLA) cache: a latent-attention "
+            "model generates through serving: continuous (the paged pool)")
+
+
 def from_hf_state_dict(state: dict, cfg: DecoderConfig) -> dict:
     """Convert a HuggingFace ``LlamaForCausalLM`` state_dict (torch tensors —
     any dtype including bfloat16 — or numpy arrays) into this model's param
     pytree. Linear weights transpose from torch's [out, in] to [in, out]."""
-    if cfg.num_experts > 1:
-        raise ValueError("from_hf_state_dict maps dense Llama checkpoints; MoE configs unsupported")
+    if cfg.num_experts > 1 or cfg.latent:
+        raise ValueError("from_hf_state_dict maps dense Llama checkpoints; "
+                         "MoE and latent-attention configs unsupported")
 
     def t(name, transpose=False):
         return cm.hf_tensor(state, name, transpose)
@@ -447,6 +836,7 @@ def init_kv_cache(cfg: DecoderConfig, batch: int, max_len: int) -> dict:
       attention forever).
     - ``prompt_len``: width of the prefilled prompt block (0 = pure stepwise).
     """
+    _no_latent(cfg, "the contiguous KV cache (serving: batch)")
     dh = cfg.dim // cfg.heads
     shape = (cfg.layers, batch, max_len, cfg.kv_heads, dh)
     return {
@@ -649,6 +1039,7 @@ def pp_stage_fns(cfg: DecoderConfig):
     long context through sp/ring — not composed with pp, same as training."""
     from arkflow_tpu.errors import ConfigError
 
+    _no_latent(cfg, "pipeline-parallel serving")
     if cfg.num_experts > 1:
         raise ConfigError("pipeline parallelism + MoE (ep) is not composed yet")
     if cfg.use_ring_attention:

@@ -11,6 +11,14 @@ step jits once and replays (no dynamic shapes, XLA-friendly).
 Page 0 is a reserved scratch page: inactive slots and masked prompt padding
 write there, which keeps the scatter free of conditionals.
 
+Two cache kinds live here, selected by the model config inside the one layer
+body of each step: per-head K/V (GQA; the pools are scanned layer by layer)
+and latent rows (MLA, ``cfg.latent``: one normed latent row and one rotated
+rope key a token for all heads; the pools ride whole through the layer loop,
+``_latent_layers``, and attention runs in the absorbed form over them). The
+MLP half is dense SwiGLU, the Switch top-1 layer, or dropless routed experts
+(``cfg.routed``) — the last only on latent layers so far.
+
 The reference has no serving layer at all (its python processor is
 user-code); this implements the engine the `tpu_generate` processor's
 continuous-batching mode runs on. Design follows the public PagedAttention
@@ -24,14 +32,178 @@ import jax
 import jax.numpy as jnp
 
 from arkflow_tpu.models import common as cm
-from arkflow_tpu.models.decoder import DecoderConfig, _mlp, _rope
+from arkflow_tpu.models.decoder import (DecoderConfig, _mlp, _rope,
+                                        layer_stacks, mla_absorb_query,
+                                        mla_expanded_attention, mla_output,
+                                        mla_project, moe_step_stats,
+                                        routed_mlp)
 
 
 def init_page_pool(cfg: DecoderConfig, num_pages: int, page_size: int):
-    """KV page pools: [layers, num_pages, page, kv_heads, dh] bf16."""
+    """The two page pools the model's cache needs, bf16 — the cache's shape
+    is the model family's to state, not the server's to compute:
+
+    - per-head K/V (GQA): K and V, each [layers, num_pages, page, kv_heads, dh];
+    - latent (MLA): the normed latent rows [layers, num_pages, page,
+      kv_lora_rank] and the rotated rope keys [layers, num_pages, page,
+      qk_rope_head_dim], one row each per token for ALL heads (no head axis:
+      a page's last two dims then tile the chip's memory as they are)."""
+    if cfg.latent:
+        shape = (cfg.layers, num_pages, page_size)
+        return (jnp.zeros(shape + (cfg.kv_lora_rank,), jnp.bfloat16),
+                jnp.zeros(shape + (cfg.qk_rope_head_dim,), jnp.bfloat16))
     dh = cfg.dim // cfg.heads
     shape = (cfg.layers, num_pages, page_size, cfg.kv_heads, dh)
     return jnp.zeros(shape, jnp.bfloat16), jnp.zeros(shape, jnp.bfloat16)
+
+
+def kv_bytes_per_token(cfg: DecoderConfig) -> int:
+    """Bytes one cached token costs over all layers (bf16 values as the
+    pools hold them, before any padding the device's tiling adds)."""
+    per_layer = (cfg.kv_lora_rank + cfg.qk_rope_head_dim if cfg.latent
+                 else 2 * cfg.kv_heads * (cfg.dim // cfg.heads))
+    return 2 * per_layer * cfg.layers
+
+
+def _latent_layers(params: dict, cfg: DecoderConfig, x, c_pages, r_pages,
+                   positions, page_idx, offset, attend, token_mask,
+                   attention_kernel: str, kernel_interpret: bool):
+    """The layer loop of a latent-attention model over the paged cache: one
+    scan per layer stack (leading dense layers, then expert layers), ONE
+    layer index for the pools. The pools ride in the carry whole — each
+    layer scatters its tokens' latent rows and rope keys at
+    (layer, page_idx, offset) in place and hands the whole pools on, so two
+    stacks share them without slicing or re-joining — and ``attend(lp,
+    q_nope, q_rope, c, k_r, c_pages, r_pages, layer)`` is the caller's
+    attention over them. ``token_mask`` [B, S] names the tokens that route
+    (active lanes, unpadded positions). Returns (x, c_pages, r_pages, the
+    step's routing counters ``moe_step_stats``)."""
+    kernel = attention_kernel == "paged"
+
+    def make_layer(routed: bool, experts, first: int):
+        # the stack's experts stay OUT of the scanned tree: scanned, each
+        # layer's slice (1.2 GB at Kanana-2 widths) would be copied out for
+        # the kernel every step; whole, the kernel indexes the layer itself
+        def layer(carry, lp):
+            x, cp, rp, li = carry
+            y = cm.rms_norm(lp["attn_norm"], x, cfg.norm_eps)
+            q_nope, q_rope, c, k_r = mla_project(lp, y, cfg, positions)
+            cp = cp.at[li, page_idx, offset].set(c.astype(cp.dtype))
+            rp = rp.at[li, page_idx, offset].set(k_r.astype(rp.dtype))
+            x = x + attend(lp, q_nope, q_rope, c, k_r, cp, rp, li)
+            y = cm.rms_norm(lp["mlp_norm"], x, cfg.norm_eps)
+            if routed:
+                out, load = routed_mlp(lp, y, cfg, token_mask=token_mask,
+                                       kernel=kernel, interpret=kernel_interpret,
+                                       stacked=(experts, li - first))
+            else:
+                out, load = _mlp(lp, y, cfg), None
+            return (x + out, cp, rp, li + 1), load
+        return layer
+
+    carry = (x, c_pages, r_pages, jnp.zeros((), jnp.int32))
+    first = 0
+    for stack, routed in layer_stacks(params, cfg):
+        scanned = {k: v for k, v in stack.items() if k != "experts"}
+        carry, loads = jax.lax.scan(
+            make_layer(routed, stack.get("experts"), first), carry, scanned)
+        first += stack["attn_norm"]["scale"].shape[0]
+    x, c_pages, r_pages, _ = carry
+    return x, c_pages, r_pages, moe_step_stats(loads)  # the expert stack's
+
+
+def _attend_latent(lp, q_nope, q_rope, c_pages, r_pages, layer, page_table,
+                   off, mask, cfg: DecoderConfig, attention_kernel: str,
+                   kernel_interpret: bool):
+    """Absorbed latent attention over the paged cache: the queries are
+    carried into the latent space (``W_uk`` absorbed), scored against the
+    cached latent rows and rope keys of every head's ONE shared row per
+    token, the value sum is taken over the latent rows, and ``W_uv`` /
+    ``o_proj`` finish. ``"paged"`` reads the pools in place
+    (ops/ragged_attention.mla_paged_attention; ``off`` is each row's first
+    query position); ``"gather"`` materializes the layer's context and
+    masks with ``mask`` — the reference."""
+    q_lat = mla_absorb_query(lp, q_nope, cfg)
+    scale = float(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+    if attention_kernel == "paged":
+        from arkflow_tpu.ops.ragged_attention import mla_paged_attention
+
+        o_lat = mla_paged_attention(q_lat, q_rope, c_pages, r_pages, layer,
+                                    page_table, off, scale=scale,
+                                    interpret=kernel_interpret)
+    else:
+        b, ctx = page_table.shape[0], page_table.shape[1] * c_pages.shape[2]
+        cc = c_pages[layer][page_table].reshape(b, ctx, -1).astype(q_lat.dtype)
+        rr = r_pages[layer][page_table].reshape(b, ctx, -1).astype(q_lat.dtype)
+        scores = (jnp.einsum("bqhl,bkl->bhqk", q_lat, cc)
+                  + jnp.einsum("bqhr,bkr->bhqk", q_rope, rr)
+                  ).astype(jnp.float32) * scale
+        scores = jnp.where(mask, scores, jnp.finfo(jnp.float32).min)
+        probs = jax.nn.softmax(scores, axis=-1).astype(q_lat.dtype)
+        o_lat = jnp.einsum("bhqk,bkl->bqhl", probs, cc)
+    return mla_output(lp, o_lat, cfg)
+
+
+def latent_kernel_probe(params: dict, cfg: DecoderConfig, page_size: int,
+                        kernel_interpret: bool = False) -> list:
+    """(name, reference, kernel output) for each Pallas kernel a latent
+    model's ``decode_kernel: paged`` serves with, on the model's own first
+    layer at its real widths and seeded inputs: the latent attention
+    (decode and a 2-token chunk, rows on non-contiguous pages, one crossing
+    a page boundary) against the gather path, and the expert product on
+    GIVEN routing against plain XLA over the experts routed to.
+
+    Kernel by kernel, not logits of the whole model as the per-head probe
+    does: with routed experts two arithmetically different attention paths
+    round a router's input differently, a near-tie then picks another
+    expert, and the logits differ by tenths though no kernel is wrong
+    (seen on the chip: 4 of 6 seeds, PERF.md PR 27)."""
+    from arkflow_tpu.ops.moe_experts import expert_swiglu_dense, moe_expert_swiglu
+
+    lp = jax.tree_util.tree_map(lambda a: a[0], params["dense_layers"])
+    keys = iter(jax.random.split(jax.random.PRNGKey(1234), 8))
+    n0 = page_size + 1
+    pages_per = -(-(n0 + 3) // page_size)
+    pools = [jax.random.normal(next(keys), p.shape, jnp.float32).astype(p.dtype)
+             for p in init_page_pool(cfg, 1 + 2 * pages_per, page_size)]
+    table = jnp.stack([jnp.arange(1, 2 * pages_per, 2)[::-1],
+                       jnp.arange(2, 2 * pages_per + 1, 2)]).astype(jnp.int32)
+    off = jnp.asarray([n0, 1], jnp.int32)
+    ctx = pages_per * page_size
+    out = []
+    for name, c in (("latent_attention_decode", 1), ("latent_attention_chunk", 2)):
+        shape = (2, c, cfg.heads)
+        q_nope = jax.random.normal(next(keys), shape + (cfg.qk_nope_head_dim,),
+                                   jnp.float32).astype(jnp.bfloat16)
+        q_rope = jax.random.normal(next(keys), shape + (cfg.qk_rope_head_dim,),
+                                   jnp.float32).astype(jnp.bfloat16)
+        positions = off[:, None] + jnp.arange(c)[None, :]
+        mask = jnp.arange(ctx)[None, None, None, :] <= positions[:, None, :, None]
+        ref, got = (_attend_latent(lp, q_nope, q_rope, *pools, 0, table, off,
+                                   mask, cfg, kern, kernel_interpret)
+                    for kern in ("gather", "paged"))
+        out.append((name, ref, got))
+    # the expert product: tokens routed among a HANDFUL of the first expert
+    # layer's experts, so that the XLA twin, which multiplies every expert
+    # it is given, copies those few (94 MB at Kanana-2 widths, by static
+    # slices: an index array over the stack cost 1.9 GB on a v5e) and not
+    # the layer (1.2 GB); the kernel reads the whole stack as when serving
+    e, k = cfg.n_routed_experts, cfg.num_experts_per_tok
+    few = min(e, 8)
+    x = jax.random.normal(next(keys), (16, cfg.dim), jnp.float32).astype(jnp.bfloat16)
+    chosen = jnp.argsort(jax.random.uniform(next(keys), (16, few)), axis=-1)[:, :min(k, few)]
+    cw = jax.nn.one_hot(chosen, e, dtype=jnp.float32).sum(1) * (
+        cfg.routed_scaling_factor / k)
+    cw = jnp.concatenate([cw, jnp.ones((16, cfg.n_shared_experts))], axis=-1)
+    ex = params["layers"]["experts"]
+    cols = jnp.concatenate([jnp.arange(few), jnp.arange(e, e + cfg.n_shared_experts)])
+    twin = [jnp.concatenate([ex[w][0, :few], ex[w][0, e:]])  # static slices
+            for w in ("w_gate", "w_up", "w_down")]
+    out.append(("expert_product",
+                expert_swiglu_dense(x, cw[:, cols], *twin),
+                moe_expert_swiglu(x, cw, ex["w_gate"], ex["w_up"],
+                                  ex["w_down"], 0, interpret=kernel_interpret)))
+    return out
 
 
 def _constrain(x, sharding):
@@ -83,7 +255,8 @@ def _attend_paged(q, kp, vp, page_table, off, cfg: DecoderConfig,
 
 def paged_prefill(params: dict, cfg: DecoderConfig, input_ids, lengths,
                   page_table, k_pages, v_pages, return_logits: bool = False,
-                  kv_sharding=None):
+                  kv_sharding=None, attention_kernel: str = "gather",
+                  kernel_interpret: bool = False):
     """Prefill prompts and scatter their K/V into pages.
 
     input_ids: [B, T] right-padded; lengths: [B]; page_table: [B, P].
@@ -92,6 +265,11 @@ def paged_prefill(params: dict, cfg: DecoderConfig, input_ids, lengths,
 
     ``kv_sharding``: optional per-layer-pool ``NamedSharding`` (KV heads over
     ``tp``) for tensor-parallel serving; see ``_constrain``.
+
+    A latent model attends in the published (expanded) form here — the
+    block holds its own keys — and writes the latent rows the absorbed
+    paths read later; ``attention_kernel`` picks only its expert product.
+    A routed model's step returns its routing counters as a fourth value.
     """
     b, t = input_ids.shape
     page = k_pages.shape[2]
@@ -135,15 +313,24 @@ def paged_prefill(params: dict, cfg: DecoderConfig, input_ids, lengths,
         x = x + _mlp(lp, y, cfg, token_mask=pos_valid)
         return (x,), (kp, vp)
 
-    (x,), (new_k, new_v) = jax.lax.scan(
-        layer, (x,), (params["layers"], k_pages, v_pages))
+    moe = ()  # a routed model appends its counters (``moe_step_stats``)
+    if cfg.latent:
+        def attend(lp, q_nope, q_rope, c, k_r, cp, rp, li):
+            return mla_expanded_attention(lp, q_nope, q_rope, c, k_r, mask, cfg)
+
+        x, new_k, new_v, *moe = _latent_layers(
+            params, cfg, x, k_pages, v_pages, positions, page_idx, offset,
+            attend, pos_valid, attention_kernel, kernel_interpret)
+    else:
+        (x,), (new_k, new_v) = jax.lax.scan(
+            layer, (x,), (params["layers"], k_pages, v_pages))
     x = cm.rms_norm(params["norm_out"], x, cfg.norm_eps)
     logits = cm.dense(params["lm_head"], x).astype(jnp.float32)
     last = jnp.clip(lengths - 1, 0, t - 1)
     last_logits = jnp.take_along_axis(logits, last[:, None, None], axis=1)[:, 0, :]
-    if return_logits:
-        return last_logits, new_k, new_v
-    return jnp.argmax(last_logits, axis=-1).astype(jnp.int32), new_k, new_v
+    if not return_logits:
+        last_logits = jnp.argmax(last_logits, axis=-1).astype(jnp.int32)
+    return (last_logits, new_k, new_v, *moe)
 
 
 def paged_prefill_chunk(params: dict, cfg: DecoderConfig, input_ids, chunk_off,
@@ -237,15 +424,27 @@ def paged_prefill_chunk(params: dict, cfg: DecoderConfig, input_ids, chunk_off,
         x = x + _mlp(lp, y, cfg, token_mask=pos_valid)
         return (x,), (kp, vp)
 
-    (x,), (new_k, new_v) = jax.lax.scan(
-        layer, (x,), (params["layers"], k_pages, v_pages))
+    moe = ()  # a routed model appends its counters (``moe_step_stats``)
+    if cfg.latent:
+        # the same causal rule over the latent rows: this chunk's own rows
+        # were just scattered, earlier chunks' come back through the table
+        def attend(lp, q_nope, q_rope, c, k_r, cp, rp, li):
+            return _attend_latent(lp, q_nope, q_rope, cp, rp, li, page_table,
+                                  chunk_off, mask, cfg, attention_kernel,
+                                  kernel_interpret)
+
+        x, new_k, new_v, *moe = _latent_layers(
+            params, cfg, x, k_pages, v_pages, positions, page_idx, offset,
+            attend, pos_valid, attention_kernel, kernel_interpret)
+    else:
+        (x,), (new_k, new_v) = jax.lax.scan(
+            layer, (x,), (params["layers"], k_pages, v_pages))
     x = cm.rms_norm(params["norm_out"], x, cfg.norm_eps)
     logits = cm.dense(params["lm_head"], x).astype(jnp.float32)
-    if return_all:
-        return logits, new_k, new_v
-    last = jnp.clip(chunk_len - 1, 0, t - 1)
-    last_logits = jnp.take_along_axis(logits, last[:, None, None], axis=1)[:, 0, :]
-    return last_logits, new_k, new_v
+    if not return_all:
+        last = jnp.clip(chunk_len - 1, 0, t - 1)
+        logits = jnp.take_along_axis(logits, last[:, None, None], axis=1)[:, 0, :]
+    return (logits, new_k, new_v, *moe)
 
 
 def paged_decode_step(params: dict, cfg: DecoderConfig, token_ids, lengths,
@@ -321,10 +520,23 @@ def paged_decode_step(params: dict, cfg: DecoderConfig, token_ids, lengths,
         x = x + _mlp(lp, y, cfg, token_mask=active[:, None])
         return (x,), (kp, vp)
 
-    (x,), (new_k, new_v) = jax.lax.scan(
-        layer, (x,), (params["layers"], k_pages, v_pages))
+    moe = ()  # a routed model appends its counters (``moe_step_stats``)
+    if cfg.latent:
+        # inactive lanes write to the scratch page and route nowhere
+        def attend(lp, q_nope, q_rope, c, k_r, cp, rp, li):
+            return _attend_latent(lp, q_nope, q_rope, cp, rp, li, page_table,
+                                  lengths, valid, cfg, attention_kernel,
+                                  kernel_interpret)
+
+        x, new_k, new_v, *moe = _latent_layers(
+            params, cfg, x, k_pages, v_pages, positions, write_page[:, None],
+            write_off[:, None], attend, active[:, None], attention_kernel,
+            kernel_interpret)
+    else:
+        (x,), (new_k, new_v) = jax.lax.scan(
+            layer, (x,), (params["layers"], k_pages, v_pages))
     x = cm.rms_norm(params["norm_out"], x, cfg.norm_eps)
-    logits = cm.dense(params["lm_head"], x).astype(jnp.float32)
-    if return_logits:
-        return logits[:, -1, :], new_k, new_v
-    return jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32), new_k, new_v
+    logits = cm.dense(params["lm_head"], x).astype(jnp.float32)[:, -1, :]
+    if not return_logits:
+        logits = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    return (logits, new_k, new_v, *moe)

@@ -1,0 +1,172 @@
+"""Dropless expert products: SwiGLU experts over the tokens routed to them.
+
+A top-k routed MoE layer sends each token to ``k`` of ``E`` experts. With
+few tokens in a step (16 decode lanes, one 128-token prefill chunk) the step
+is bound by reading expert weights, and most experts receive no token at
+all: 16 lanes x top-6 of 128 hit ~69. So the product that matters is "read
+each expert that was hit exactly once, and no other".
+
+``moe_expert_swiglu`` is that product as one Pallas kernel. The caller hands
+it the combine weights ``cw[t, e]`` (the routing weight of expert ``e`` for
+token ``t``, 0 where ``t`` was not routed to ``e``). The experts that were
+hit are compacted to the front of a scalar-prefetched list; the grid walks
+(list entry, slice of the expert width); every step reads one slice of one
+hit expert's three matrices and accumulates
+
+    out += ((silu(x W_gate[e]) * (x W_up[e])) * cw[:, e]) W_down[e]
+
+for ALL rows of the token tile — rows not routed to ``e`` carry weight 0.
+That is exact and dropless at any load (an expert takes however many rows
+were routed to it; there is no capacity), and below the chip's ridge (about
+240 rows on a v5e) the rows that ride along cost nothing: the step waits on
+the weights either way. Entries past the last hit expert repeat its block
+index, so the pipeline elides the copy, and ``pl.when`` skips the math.
+Token tiles of more than ``_TOKEN_TILE`` rows run tile by tile (each with
+its own hit list).
+
+``expert_swiglu_dense`` is the same arithmetic in plain XLA over every
+expert — the reference path (CPU, training forward, ``decode_kernel:
+gather``), as ``paged_decode``'s gather path is to the paged kernel.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+
+#: rows of one kernel call (bounds VMEM: x, out and the float32 accumulator)
+_TOKEN_TILE = 128
+
+
+def expert_swiglu_dense(x, cw, w_gate, w_up, w_down):
+    """x: [T, D]; cw: [T, E] float32 combine weights; w_gate / w_up:
+    [E, D, F]; w_down: [E, F, D]. Every expert over every token, combined by
+    ``cw`` in float32. Returns [T, D] in x's dtype."""
+    dtype = x.dtype
+    gate = jnp.einsum("td,edf->etf", x, w_gate.astype(dtype))
+    up = jnp.einsum("td,edf->etf", x, w_up.astype(dtype))
+    act = jax.nn.silu(gate.astype(jnp.float32)).astype(dtype) * up
+    out = jnp.einsum("etf,efd->etd", act, w_down.astype(dtype))
+    return jnp.einsum("te,etd->td", cw, out.astype(jnp.float32)).astype(dtype)
+
+
+def _expert_kernel(layer_ref, ids_ref, nhit_ref, x_ref, cw_ref, wg_ref, wu_ref,
+                   wd_ref, o_ref, acc_ref, *, n_slices: int):
+    i = pl.program_id(0)
+    j = pl.program_id(1)
+
+    @pl.when(jnp.logical_and(i == 0, j == 0))
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(i < nhit_ref[0])
+    def _acc():
+        x = x_ref[...]                                            # [T, D]
+        dims = (((1,), (0,)), ((), ()))
+        gate = jax.lax.dot_general(x, wg_ref[...], dims,
+                                   preferred_element_type=jnp.float32)
+        up = jax.lax.dot_general(x, wu_ref[...], dims,
+                                 preferred_element_type=jnp.float32)
+        cw = cw_ref[...]                                          # [T, E]
+        col = jax.lax.broadcasted_iota(jnp.int32, cw.shape, 1)
+        w = jnp.sum(jnp.where(col == ids_ref[i], cw, 0.0), axis=1,
+                    keepdims=True)                                # [T, 1]
+        act = (jax.nn.silu(gate) * up * w).astype(x.dtype)        # [T, tf]
+        acc_ref[...] += jax.lax.dot_general(
+            act, wd_ref[...], dims, preferred_element_type=jnp.float32)
+
+    @pl.when(jnp.logical_and(i == pl.num_programs(0) - 1, j == n_slices - 1))
+    def _fin():
+        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+
+
+def _slice_width(f: int) -> int:
+    """Slice of the expert width one grid step takes: the largest of 384,
+    256, 128 that divides it (three [D, slice] blocks, double-buffered, stay
+    under 10 MB at D = 2048), or all of a width that none divides."""
+    for tf in (384, 256, 128):
+        if f % tf == 0:
+            return tf
+    return f
+
+
+def _one_tile(x, cw, w_gate, w_up, w_down, layer, interpret: bool):
+    from jax.experimental.pallas import tpu as pltpu
+
+    t, d = x.shape
+    _, e, _, f = w_gate.shape
+    tf = _slice_width(f)
+    n_slices = f // tf
+    hit = jnp.any(cw != 0.0, axis=0)                              # [E]
+    n_hit = hit.sum().astype(jnp.int32)
+    order = jnp.argsort(jnp.logical_not(hit), stable=True).astype(jnp.int32)
+    # entries past the last hit expert repeat it: same block, no copy
+    ids = order[jnp.minimum(jnp.arange(e), jnp.maximum(n_hit - 1, 0))]
+
+    def _slice(i, j, nhit_ref):
+        return jnp.where(i < nhit_ref[0], j, n_slices - 1)
+
+    def _up_index(i, j, layer_ref, ids_ref, nhit_ref):
+        return (layer_ref[0], ids_ref[i], 0, _slice(i, j, nhit_ref))
+
+    def _down_index(i, j, layer_ref, ids_ref, nhit_ref):
+        return (layer_ref[0], ids_ref[i], _slice(i, j, nhit_ref), 0)
+
+    def _whole(i, j, *_):
+        return (0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(e, n_slices),
+        in_specs=[
+            pl.BlockSpec((t, d), _whole),
+            pl.BlockSpec((t, e), _whole),
+            pl.BlockSpec((None, None, d, tf), _up_index),
+            pl.BlockSpec((None, None, d, tf), _up_index),
+            pl.BlockSpec((None, None, tf, d), _down_index),
+        ],
+        out_specs=pl.BlockSpec((t, d), _whole),
+        scratch_shapes=[pltpu.VMEM((t, d), jnp.float32)],
+    )
+    return pl.pallas_call(
+        functools.partial(_expert_kernel, n_slices=n_slices),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((t, d), x.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=48 * 1024 * 1024),
+        interpret=interpret,
+        name="moe_expert_swiglu",
+    )(layer, ids, n_hit.reshape(1), x, cw, w_gate, w_up, w_down)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def moe_expert_swiglu(x, cw, w_gate, w_up, w_down, layer=None, *,
+                      interpret: bool = False):
+    """The hit experts' SwiGLU products, combined: see the module docstring.
+
+    x: [T, D] bfloat16; cw: [T, E] float32 (0 = not routed); w_gate / w_up:
+    [E, D, F]; w_down: [E, F, D], in x's dtype — or a whole stack of layers
+    ([layers, E, ...]) with ``layer`` the index of the one to use: the
+    kernel's index maps pick the layer, so a layer loop never slices (and
+    XLA never copies) a layer's 1.2 GB of experts. Returns [T, D]."""
+    t, d = x.shape
+    cw = cw.astype(jnp.float32)
+    if w_gate.ndim == 3:
+        w_gate, w_up, w_down, layer = w_gate[None], w_up[None], w_down[None], 0
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
+    tile = min(_TOKEN_TILE, -(-t // 16) * 16)   # bf16 packs 16 rows a tile
+    t_pad = -(-t // tile) * tile
+    if t_pad != t:
+        # padding rows carry weight 0 everywhere: they hit no expert
+        x = jnp.pad(x, ((0, t_pad - t), (0, 0)))
+        cw = jnp.pad(cw, ((0, t_pad - t), (0, 0)))
+    if t_pad == tile:
+        return _one_tile(x, cw, w_gate, w_up, w_down, layer, interpret)[:t]
+    out = jax.lax.map(
+        lambda xc: _one_tile(xc[0], xc[1], w_gate, w_up, w_down, layer, interpret),
+        (x.reshape(-1, tile, d), cw.reshape(-1, tile, cw.shape[1])))
+    return out.reshape(t_pad, d)[:t]

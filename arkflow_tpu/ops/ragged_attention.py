@@ -283,3 +283,158 @@ def paged_flash_attention(q, k_pages, v_pages, page_table, off, *,
       k_pages.reshape(n_pages, page * kvh, dh),
       v_pages.reshape(n_pages, page * kvh, dh))
     return out.reshape(b, c_pad, h, dh)[:, :c]
+
+
+# -- latent (MLA) paged attention ---------------------------------------------
+#
+# Multi-head latent attention (DeepSeek-V2/V3) caches, per token and layer,
+# one normed latent row ``c`` (``kv_lora_rank`` wide) and one rotated rope
+# key ``k_r`` shared by every head — not per-head K and V. Decode absorbs the
+# up-projection: each head's query is carried into the latent space
+# (``q_lat = q_nope W_uk^T``), scored against ``c`` and ``k_r`` directly,
+# and the value sum is taken over ``c`` itself (``o_lat = sum p c``; the
+# caller applies ``W_uv``). So the pools hold one "KV head" for all query
+# heads: a page is read ONCE and every head scores against it.
+#
+# Same grid idea as ``paged_flash_attention`` — (row, query tile, page
+# group) with the page table scalar-prefetched — with three differences:
+#
+# * the pools ride whole (``[layers, pages, page, width]``) with the layer
+#   index scalar-prefetched, so the layer loop never slices a pool;
+# * a grid step takes ``_LATENT_GROUP`` pages, each through its own
+#   BlockSpec over the same pool (the index map of the g-th resolves the
+#   row's (step * group + g)-th page): with one shared KV head a single
+#   16-token page would fill 16 of 128 score lanes and cost a grid step
+#   for 18 KB; eight pages make a [rows, 128] score tile;
+# * operands stay bfloat16 into the MXU (float32 accumulation); the
+#   probabilities are rounded to bfloat16 for the value product.
+
+#: pages a grid step attends over (8 x 16-token pages = one 128-lane tile)
+_LATENT_GROUP = 8
+
+
+def _latent_kernel(layer_ref, off_ref, table_ref, ql_ref, qr_ref, *rest,
+                   page: int, heads: int, tile_c: int, steps: int,
+                   group: int, scale: float):
+    c_refs, r_refs = rest[:group], rest[group:2 * group]
+    o_ref, o_acc, m_acc, l_acc = rest[2 * group:]
+    bi = pl.program_id(0)
+    ci = pl.program_id(1)
+    si = pl.program_id(2)
+    rows = ql_ref.shape[0]
+    cols = group * page
+
+    @pl.when(si == 0)
+    def _init():
+        o_acc[:] = jnp.zeros_like(o_acc)
+        m_acc[:] = jnp.full_like(m_acc, _NEG)
+        l_acc[:] = jnp.zeros_like(l_acc)
+
+    first = off_ref[bi] + ci * tile_c
+    max_pos = first + (tile_c - 1)
+
+    @pl.when(si * cols <= max_pos)
+    def _acc():
+        kc = jnp.concatenate([r[...] for r in c_refs], axis=0)    # [cols, L]
+        kr = jnp.concatenate([r[...] for r in r_refs], axis=0)    # [cols, R]
+        dims = (((1,), (1,)), ((), ()))
+        scores = (jax.lax.dot_general(ql_ref[...], kc, dims,
+                                      preferred_element_type=jnp.float32)
+                  + jax.lax.dot_general(qr_ref[...], kr, dims,
+                                        preferred_element_type=jnp.float32)
+                  ) * scale                                       # [rows, cols]
+        r = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 0)
+        c = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 1)
+        scores = jnp.where(si * cols + c <= first + r // heads, scores, _NEG)
+        m = m_acc[:, :1]
+        m_new = jnp.maximum(m, scores.max(axis=-1, keepdims=True))
+        p = jnp.exp(scores - m_new)
+        corr = jnp.exp(m - m_new)
+        l_acc[:] = jnp.broadcast_to(
+            l_acc[:, :1] * corr + p.sum(axis=-1, keepdims=True), l_acc.shape)
+        m_acc[:] = jnp.broadcast_to(m_new, m_acc.shape)
+        o_acc[:] = o_acc[:] * corr + jax.lax.dot_general(
+            p.astype(kc.dtype), kc, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    @pl.when(si == steps - 1)
+    def _fin():
+        # key 0 is admissible to every query and the first step is always
+        # within the bound, so l is never truly zero (see _paged_kernel)
+        o_ref[...] = (o_acc[:] / jnp.maximum(l_acc[:, :1], 1e-30)
+                      ).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def mla_paged_attention(q_lat, q_rope, c_pages, r_pages, layer, page_table,
+                        off, *, scale: float, interpret: bool = False):
+    """Absorbed latent attention straight from the latent page pools.
+
+    q_lat: [B, C, H, L] (queries carried into the latent space), q_rope:
+    [B, C, H, R]; c_pages: [layers, pages, page, L], r_pages: [layers,
+    pages, page, R] (the WHOLE pools; ``layer`` picks the slice inside the
+    kernel's index maps). Query i of row b sits at absolute position
+    ``off[b] + i`` and attends keys 0..off+i with score
+    ``(q_lat . c + q_rope . k_r) * scale``. Returns ``sum p c``:
+    [B, C, H, L] in q_lat's dtype — the caller applies ``W_uv``."""
+    b, c, h, lat = q_lat.shape
+    rope = q_rope.shape[-1]
+    page = c_pages.shape[2]
+    group = _LATENT_GROUP
+    steps = -(-page_table.shape[1] // group)
+    table = jnp.asarray(page_table, jnp.int32)
+    if steps * group != table.shape[1]:
+        # padding entries name the scratch page; the causal bound hides them
+        table = jnp.pad(table, ((0, 0), (0, steps * group - table.shape[1])))
+    tile_c = c if c * h <= _PAGED_ROWS else max(8, _PAGED_ROWS // h // 8 * 8)
+    c_pad = -(-c // tile_c) * tile_c
+    if c_pad != c:
+        pad = ((0, 0), (0, c_pad - c), (0, 0), (0, 0))
+        q_lat, q_rope = jnp.pad(q_lat, pad), jnp.pad(q_rope, pad)
+    rows = tile_c * h
+    from jax.experimental.pallas import tpu as pltpu
+
+    def _q_index(bi, ci, si, *_):
+        return (bi, ci, 0)
+
+    def _page_index(g):
+        def index(bi, ci, si, layer_ref, off_ref, table_ref):
+            pi = si * group + g
+            max_pos = off_ref[bi] + ci * tile_c + (tile_c - 1)
+            return (layer_ref[0],
+                    jnp.where(pi * page <= max_pos, table_ref[bi, pi], 0),
+                    0, 0)
+        return index
+
+    kernel = functools.partial(
+        _latent_kernel, page=page, heads=h, tile_c=tile_c, steps=steps,
+        group=group, scale=float(scale))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(b, c_pad // tile_c, steps),
+        in_specs=[
+            pl.BlockSpec((None, rows, lat), _q_index),
+            pl.BlockSpec((None, rows, rope), _q_index),
+            *[pl.BlockSpec((None, None, page, lat), _page_index(g))
+              for g in range(group)],
+            *[pl.BlockSpec((None, None, page, rope), _page_index(g))
+              for g in range(group)],
+        ],
+        out_specs=pl.BlockSpec((None, rows, lat), _q_index),
+        scratch_shapes=[
+            pltpu.VMEM((rows, lat), jnp.float32),
+            pltpu.VMEM((rows, 128), jnp.float32),
+            pltpu.VMEM((rows, 128), jnp.float32),
+        ],
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, c_pad * h, lat), q_lat.dtype),
+        interpret=interpret,
+        name="mla_paged_attention",
+    )(jnp.asarray(layer, jnp.int32).reshape(1), jnp.asarray(off, jnp.int32),
+      table, q_lat.reshape(b, c_pad * h, lat),
+      q_rope.reshape(b, c_pad * h, rope),
+      *[c_pages] * group, *[r_pages] * group)
+    return out.reshape(b, c_pad, h, lat)[:, :c]

@@ -25,7 +25,10 @@ Config:
 
     type: tpu_generate
     model: decoder_lm
-    model_config: {vocab_size: 2048, ...}
+    model_config: {vocab_size: 2048, ...}   # latent attention (MLA) +
+                             # routed experts: kv_lora_rank, n_routed_experts,
+                             # ... (docs/CONFIG.md); such a model serves
+                             # through serving: continuous on one chip only
     text_field: __value__
     tokenizer: meta-llama/Llama-3-8B     # optional (hash fallback otherwise)
     max_input: 256
@@ -143,6 +146,19 @@ class TpuGenerateProcessor(Processor):
         if not {"generate", "serve_dtypes"} <= set(self.family.extras):
             raise ConfigError(f"model {model!r} does not support incremental decoding")
         self.cfg = self.family.make_config(**(model_config or {}))
+        if getattr(self.cfg, "latent", False):
+            # before the host init: seconds to minutes at real widths
+            if serving != "continuous":
+                raise ConfigError(
+                    "a latent-attention model (kv_lora_rank > 0) generates "
+                    "through serving: continuous only: the batch path's "
+                    "contiguous cache holds per-head K/V")
+            if mesh_config:
+                raise ConfigError(
+                    "a latent-attention model is served on one chip: "
+                    "continuous serving shards the KV pool over KV heads, "
+                    "and a latent (MLA) page has one shared row per token "
+                    "(remove mesh)")
         self.text_field = text_field
         self.tokenizer = tokenizer
         self.max_input = max_input
@@ -233,8 +249,11 @@ class TpuGenerateProcessor(Processor):
             #: prefill/decode disaggregation adapter: a prefill-role
             #: cluster worker (runtime/cluster.py) finds this through the
             #: same ``_inner``-chain walk as ``.runner``/``.swapper`` and
-            #: drives prefill_rows -> kv_push -> finalize_rows
-            self.disagg = self
+            #: drives prefill_rows -> kv_push -> finalize_rows. A latent
+            #: (MLA) page has no wire format yet: no adapter is offered,
+            #: and the server's export / adopt calls raise ConfigError
+            if not getattr(self.cfg, "latent", False):
+                self.disagg = self
 
         reg = global_registry()
         self.m_tokens = reg.counter("arkflow_generated_tokens_total", "tokens generated",
@@ -448,6 +467,18 @@ def _build(config: dict, resource: Resource) -> TpuGenerateProcessor:
         health_config=core_cfg["health_config"],
         checkpoint=config.get("checkpoint"),
     )
+    if getattr(proc.cfg, "latent", False):
+        # the swap canary and the integrity golden run the family's batch
+        # forward against per-head-cache assumptions that were never checked
+        # for a latent model: refuse the keys, attach neither
+        for key in ("swap", "integrity"):
+            if config.get(key) is not None:
+                raise ConfigError(
+                    f"tpu_generate: {key} is not supported for a "
+                    "latent-attention model yet (its drain / flip / pool "
+                    "reset and golden forward are unverified for latent "
+                    "pages); remove the key")
+        return proc
     from arkflow_tpu.tpu.swap import build_generate_swapper, parse_swap_config
 
     proc.swapper = build_generate_swapper(

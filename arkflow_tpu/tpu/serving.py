@@ -89,6 +89,9 @@ class _Request:
     last_token_at: float = 0.0
     chunks: int = 0
     shared_tokens: int = 0
+    #: a routed-expert model's counters over this prompt's chunks so far
+    #: (pairs, distinct experts, largest load, chunks), kept on the device
+    chunk_moe: Optional[object] = None
 
 
 @dataclass
@@ -177,6 +180,12 @@ class GenerationServer:
         # token ids, lengths, active masks) stays replicated, so admission /
         # page accounting is identical whether one chip serves or eight
         self.mesh = mesh
+        if cfg.latent and mesh is not None:
+            raise ConfigError(
+                "continuous serving shards the KV pools over KV heads on the "
+                "tp axis; a latent (MLA) pool has one shared row per token "
+                "and no head axis to split — serve a latent-attention model "
+                "on one chip (no mesh)")
         self._kv_io_sharding = None     # full pool  [L, pages, page, kv, dh]
         self._kv_layer_sharding = None  # scan slice [pages, page, kv, dh]
         self._repl_sharding = None
@@ -306,7 +315,7 @@ class GenerationServer:
                 raise ConfigError(
                     "dispatch_depth > 1 and speculative_tokens are mutually "
                     "exclusive (both restructure the decode loop)")
-            if getattr(cfg, "num_experts", 0) > 0:
+            if getattr(cfg, "num_experts", 0) > 0 or cfg.routed:
                 raise ConfigError(
                     "dispatch_depth > 1 does not compose with MoE models: "
                     "a finished-but-still-riding lane consumes shared "
@@ -409,6 +418,28 @@ class GenerationServer:
             "arkflow_gen_token_gap_seconds",
             "gap between consecutive generated tokens of one request",
             {"model": name})
+        from arkflow_tpu.models.paged_decode import kv_bytes_per_token
+
+        reg.gauge("arkflow_gen_kv_bytes_per_token",
+                  "bytes one cached token costs over all layers, as the page "
+                  "pools hold it", {"model": name}).set(kv_bytes_per_token(cfg))
+        # routed experts (dropless top-k): what each device step routed,
+        # computed on the device inside the step and fetched with its
+        # tokens — (token, expert) pairs, distinct experts hit (mean over
+        # the expert layers) and the largest expert's load (over layers)
+        self._moe_layers = cfg.expert_layers
+        self.m_moe = {} if not self._moe_layers else {
+            kind: (reg.counter("arkflow_gen_moe_assignments_total",
+                               "(token, expert) pairs routed, summed over "
+                               "expert layers", {"model": name, "kind": kind}),
+                   reg.histogram("arkflow_gen_moe_experts_hit",
+                                 "distinct experts hit a step, mean over "
+                                 "expert layers", {"model": name, "kind": kind}),
+                   reg.histogram("arkflow_gen_moe_max_load",
+                                 "tokens routed to the busiest expert of a "
+                                 "step, over expert layers",
+                                 {"model": name, "kind": kind}))
+            for kind in ("decode", "chunk", "prefill")}
         #: per-server TTFT reservoir behind health_report() percentiles
         #: (m_ttft is registry-global and would mix servers in-process)
         self._ttft_samples: deque[float] = deque(maxlen=2048)
@@ -443,6 +474,20 @@ class GenerationServer:
         argument, like the serving steps; one-time init cost."""
         from arkflow_tpu.models.paged_decode import paged_prefill_chunk
         from arkflow_tpu.tpu.serving_core import logits_parity
+
+        if self.cfg.latent:
+            # kernel by kernel on given routing (why: latent_kernel_probe);
+            # the verdict is the worst kernel's
+            from arkflow_tpu.models.paged_decode import latent_kernel_probe
+
+            verdicts = [
+                {"kernel": name, **logits_parity(ref, got)}
+                for name, ref, got in latent_kernel_probe(
+                    self.params, self.cfg, self.page_size,
+                    self.kernel_interpret)]
+            worst = max(verdicts, key=lambda v: (not v["ok"],
+                                                 v["max_abs_diff"] / v["tol"]))
+            return {**worst, "kernels": [v["kernel"] for v in verdicts]}
 
         kernel_args = ("attention_kernel", "kernel_interpret")
         prefill = jax.jit(paged_prefill, static_argnums=1)
@@ -508,9 +553,16 @@ class GenerationServer:
         kv_layer = self._kv_layer_sharding
         kern = dict(attention_kernel=self.decode_kernel,
                     kernel_interpret=self.kernel_interpret)
+        # a routed-expert model's steps also return their three routing
+        # counters (int32 [3]): ``_decode`` / ``_prefill`` append them to the
+        # token array; ``_chunk``, whose logits stay on the device, adds them
+        # to the prompt's running counters, which ride from chunk to chunk
+        # on the device and come back with the last chunk's token — so they
+        # reach the host in the fetch the step makes anyway
 
-        def _pick(logits, key):
-            return select_token(logits, key, self.temperature, self.top_k)
+        def _pick(logits, key, stats=None):
+            nxt = select_token(logits, key, self.temperature, self.top_k)
+            return nxt if stats is None else jnp.concatenate([nxt, stats])
 
         # params ride every step as an ARGUMENT (bound below): closed over,
         # they would be baked into each executable as constants — a copy of
@@ -519,26 +571,32 @@ class GenerationServer:
         # so XLA updates them in place instead of copying hundreds of MB per
         # decode step.
         def _decode(params, tok, lens, act, table, kp, vp, key):
-            logits, kp, vp = paged_decode_step(
+            logits, kp, vp, *stats = paged_decode_step(
                 params, cfg, tok, lens, act, table, kp, vp,
                 return_logits=True, kv_sharding=kv_layer, **kern)
-            return _pick(logits, key), kp, vp
+            return _pick(logits, key, *stats), kp, vp
 
         def _prefill(params, ids, lens, table, kp, vp, key):
-            logits, kp, vp = paged_prefill(
+            logits, kp, vp, *stats = paged_prefill(
                 params, cfg, ids, lens, table, kp, vp, return_logits=True,
-                kv_sharding=kv_layer)
-            return _pick(logits, key), kp, vp
+                kv_sharding=kv_layer, **kern)
+            return _pick(logits, key, *stats), kp, vp
 
-        def _chunk(params, ids, off, clen, table, kp, vp):
-            return paged_prefill_chunk(params, cfg, ids, off, clen,
-                                       table, kp, vp, kv_sharding=kv_layer,
-                                       **kern)
+        def _chunk(params, ids, off, clen, table, kp, vp, *so_far):
+            logits, kp, vp, *stats = paged_prefill_chunk(
+                params, cfg, ids, off, clen, table, kp, vp,
+                kv_sharding=kv_layer, **kern)
+            if so_far:  # a routed model: the prompt's counters so far
+                (pairs, hit, load), (acc,) = stats[0], so_far
+                logits = (logits, jnp.stack([
+                    acc[0] + pairs, acc[1] + hit, jnp.maximum(acc[2], load),
+                    acc[3] + 1]))
+            return logits, kp, vp
 
         def _verify(params, ids, off, clen, table, kp, vp):
             return paged_prefill_chunk(params, cfg, ids, off, clen,
                                        table, kp, vp, return_all=True,
-                                       kv_sharding=kv_layer, **kern)
+                                       kv_sharding=kv_layer, **kern)[:3]
 
         def bind(fn, n_before: int, n_after: int):
             """jit ``fn(params, *n_before args, kp, vp, *n_after args)`` with
@@ -558,8 +616,19 @@ class GenerationServer:
 
         self._decode = bind(_decode, 4, 1)
         self._prefill = bind(_prefill, 3, 1)
-        self._chunk = bind(_chunk, 4, 0)
+        self._chunk = bind(_chunk, 4, int(cfg.routed))
         self._verify = bind(_verify, 4, 0)
+
+    def _note_moe(self, kind: str, stats, steps: int = 1) -> None:
+        """Record the routing counters (``moe_step_stats``, on the host) of
+        one step, or of a prompt's ``steps`` chunks summed: each chunk then
+        counts as one step that hit their mean."""
+        pairs, hit, max_load = (int(v) for v in stats[:3])
+        total, experts_hit, load = self.m_moe[kind]
+        total.inc(pairs)
+        for _ in range(steps):
+            experts_hit.observe(hit / steps / self._moe_layers)
+        load.observe(max_load)
 
     def _rebuild_after_incident(self) -> None:
         """Core rebuild hook (runs inside the heal gate, before the recovery
@@ -803,6 +872,14 @@ class GenerationServer:
                 self._serve_loop(), context=contextvars.Context())
         return req.future
 
+    def _refuse_latent_pages(self, what: str) -> None:
+        if self.cfg.latent:
+            raise ConfigError(
+                f"{what} ships per-head K/V page slabs split along the "
+                "kv_heads axis; a latent (MLA) page has no head axis and no "
+                "wire format yet — a latent-attention model prefills and "
+                "decodes on the same server")
+
     async def prefill_export(self, prompt_ids: list[int],
                              max_new_tokens: int = 64) -> dict:
         """Disaggregated prefill: run (chunked) prefill for one prompt, then
@@ -817,6 +894,7 @@ class GenerationServer:
         ``done`` and ships no pages. Pages are unreffed (and donated to the
         prefix cache) locally once exported — the scratch pool recycles.
         """
+        self._refuse_latent_pages("prefill_export (kv_push)")
         if self._closed:
             raise ConfigError("generation server is closed")
         if len(prompt_ids) == 0:
@@ -840,6 +918,7 @@ class GenerationServer:
         table it is handed just points at the adopted pages. Returns the
         full token list including the shipped first token, exactly what
         :meth:`generate` would have returned locally."""
+        self._refuse_latent_pages("generate_from_pages (kv_push)")
         if self._closed:
             raise ConfigError("generation server is closed")
         if export.get("done"):
@@ -1063,6 +1142,9 @@ class GenerationServer:
         with loop_stage("gen_apply", "prefill"):
             req.chunks = 1
             self._lengths[slot] = n
+            if self._moe_layers:
+                nxt = np.asarray(nxt)  # one fetch: the token, then counters
+                self._note_moe("prefill", nxt[1:])
             self._cur_tokens[slot] = int(nxt[0])
             if not req.prefill_only:
                 self._handle_token(slot, int(nxt[0]))
@@ -1180,13 +1262,18 @@ class GenerationServer:
             ids[0, :len(chunk)] = chunk
             table = np.zeros((1, self.pages_per_slot), np.int32)
             table[0, :len(self._slot_pages[slot])] = self._slot_pages[slot]
+            so_far = () if not self._moe_layers else (
+                np.zeros((4,), np.int32) if req.chunk_moe is None
+                else req.chunk_moe,)
         logits, self.k_pages, self.v_pages = await self._run_device_step(
             ("chunk", c),
-            lambda kp=self.k_pages, vp=self.v_pages: self._chunk(
+            lambda kp=self.k_pages, vp=self.v_pages, so_far=so_far: self._chunk(
                 jnp.asarray(ids), jnp.asarray([off], jnp.int32),
                 jnp.asarray([len(chunk)], jnp.int32), jnp.asarray(table),
-                kp, vp))
+                kp, vp, *so_far))
         with loop_stage("gen_apply", "chunk"):
+            if self._moe_layers:
+                logits, req.chunk_moe = logits  # both stay on the device
             req.chunks += 1
             new_off = off + len(chunk)
             if new_off < n:
@@ -1198,6 +1285,10 @@ class GenerationServer:
 
             self._key, sub = jax.random.split(self._key)
             nxt = select_token(logits, sub, self.temperature, self.top_k)
+            if self._moe_layers:
+                # one fetch: the token, then the counters of all its chunks
+                nxt = np.asarray(jnp.concatenate([nxt, req.chunk_moe]))
+                self._note_moe("chunk", nxt[1:], steps=int(nxt[4]))
             self._lengths[slot] = n
             self._cur_tokens[slot] = int(nxt[0])
             if not req.prefill_only:
@@ -1447,6 +1538,8 @@ class GenerationServer:
         with loop_stage("gen_apply", "decode"):
             self.m_steps.inc()
             nxt_host = np.asarray(nxt)
+            if self._moe_layers:
+                self._note_moe("decode", nxt_host[self.slots:])
             for s in range(self.slots):
                 if not act[s] or self._slot_req[s] is None:
                     continue

@@ -152,3 +152,38 @@ def test_paged_flash_compiles_under_tp4_shard_map(v5e, chunk):
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert "all-gather" not in text and "all-reduce" not in text
+
+
+# -- latent (MLA) paged attention and the expert product (Kanana-2 widths) ----
+
+KANANA_HEADS, KANANA_LATENT, KANANA_ROPE = 32, 512, 64
+KANANA_EXPERTS, KANANA_DIM, KANANA_WIDTH = 130, 2048, 768  # 128 routed + 2 shared
+
+
+@pytest.mark.parametrize("rows,chunk", [(16, 1), (1, 128)], ids=["decode", "chunk128"])
+def test_mla_paged_attention_compiles(v5e, rows, chunk):
+    from arkflow_tpu.ops.ragged_attention import mla_paged_attention
+
+    compiled = _compile(
+        lambda ql, qr, cp, rp, layer, table, off: mla_paged_attention(
+            ql, qr, cp, rp, layer, table, off, scale=192 ** -0.5),
+        v5e, ((rows, chunk, KANANA_HEADS, KANANA_LATENT), BF16),
+        ((rows, chunk, KANANA_HEADS, KANANA_ROPE), BF16),
+        ((6, 2177, 16, KANANA_LATENT), BF16), ((6, 2177, 16, KANANA_ROPE), BF16),
+        ((), I32), ((rows, 136), I32), ((rows,), I32))
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("tokens", [16, 128, 300], ids=["decode", "chunk128", "tiled"])
+def test_moe_expert_swiglu_compiles(v5e, tokens):
+    """The stacked experts ride whole (5 layers), so no layer's 1.2 GB is
+    copied out for the kernel: the program needs no temporary of that size."""
+    from arkflow_tpu.ops.moe_experts import moe_expert_swiglu
+
+    up = ((5, KANANA_EXPERTS, KANANA_DIM, KANANA_WIDTH), BF16)
+    compiled = _compile(
+        lambda x, cw, wg, wu, wd, layer: moe_expert_swiglu(x, cw, wg, wu, wd, layer),
+        v5e, ((tokens, KANANA_DIM), BF16), ((tokens, KANANA_EXPERTS), jnp.float32),
+        up, up, ((5, KANANA_EXPERTS, KANANA_WIDTH, KANANA_DIM), BF16), ((), I32))
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 1024 * 1024
