@@ -24,7 +24,10 @@ Config:
     tensor_field: window           # list/binary column for tensor models
     outputs: [label, score]        # default: all rank-1 outputs
     batch_buckets: [8, 32, 128]    # default pow2 grid
-    seq_buckets: [32, 64, 128]
+    seq_buckets: [32, 64, 128]     # a batch of token rows is split by length
+                                   # across this (rows, seq) grid before the
+                                   # step (tpu/bucketing.py carve_by_length):
+                                   # short rows are not padded to the longest
     mesh: {dp: 1, tp: 4}           # optional multi-chip serving (GSPMD: one
                                    # sharded program; dp splits the batch dim
                                    # and scales every batch bucket by dp)
@@ -126,7 +129,7 @@ import pyarrow as pa
 from arkflow_tpu.batch import DEFAULT_BINARY_VALUE_FIELD, MessageBatch
 from arkflow_tpu.components import Processor, Resource, register_processor
 from arkflow_tpu.errors import ConfigError, ProcessError
-from arkflow_tpu.tpu.bucketing import BucketPolicy
+from arkflow_tpu.tpu.bucketing import BucketPolicy, carve_by_length
 from arkflow_tpu.tpu.tokenizer import build_tokenizer
 
 if TYPE_CHECKING:  # jax-importing modules load lazily in the builder
@@ -169,6 +172,13 @@ class TpuInferenceProcessor(Processor):
             "arkflow_tpu_extract_seconds",
             "host-side Arrow->tensor extraction + tokenization per batch",
             {"model": runner.family.name})
+        # the length split's counter: how many (rows, seq) steps one batch
+        # was carved into (1 = not split); tensor and packed batches never
+        # enter the carve and observe nothing
+        self.m_steps = global_registry().histogram(
+            "arkflow_tpu_steps_per_batch",
+            "device steps one batch's rows were split into by token length",
+            {"model": runner.family.name}, buckets=(1, 2, 4, 8, 16, 32, 64))
 
     def attach_overload_controller(self, controller) -> None:
         """Stream hook (runtime/overload.attach_overload): hand the tenant
@@ -198,27 +208,29 @@ class TpuInferenceProcessor(Processor):
                 return self.tokenizer.encode_batch_view(values, offsets, max_len)
         return self.tokenizer.encode_batch(batch.to_binary(self.text_field), max_len)
 
-    def _extract(self, batch: MessageBatch) -> dict[str, np.ndarray]:
+    def _extract(self, batch: MessageBatch
+                 ) -> tuple[dict[str, np.ndarray], Optional[np.ndarray]]:
+        """The model's inputs for ``batch`` and, for token models, every
+        row's token length (None for tensor rows). Token arrays come back
+        ``max_seq`` wide: ``_infer`` cuts them to the seq bucket(s) the
+        lengths call for."""
         inputs: dict[str, np.ndarray] = {}
         spec = self.runner.spec
         needs_tokens = any(t == ("seq",) for _, t in spec.values()) and "input_ids" in spec
         if needs_tokens:
-            # bucket sequence length by the longest text in the batch
             ids, mask = self._encode_texts(batch, self.max_seq)
             lengths = mask.sum(axis=1)
             if self.tuner is not None:
                 # the tuner's workload sketch: true tokenized lengths, one
                 # O(rows) ring insert — the observe half of the loop
                 self.tuner.observe(lengths)
-            used = int(lengths.max()) if mask.size else 1
-            sb = self.runner.buckets.seq_bucket(used)
-            inputs["input_ids"] = ids[:, :sb]
+            inputs["input_ids"] = ids
             if "attention_mask" in spec:
-                inputs["attention_mask"] = mask[:, :sb]
-            return inputs
+                inputs["attention_mask"] = mask
+            return inputs, lengths
         for name, (dtype, trailing) in spec.items():
             inputs[name] = self._extract_tensor(batch, name, dtype, trailing)
-        return inputs
+        return inputs, None
 
     def _extract_tensor(self, batch: MessageBatch, name: str, dtype: str, trailing: tuple) -> np.ndarray:
         from arkflow_tpu.tpu.extract import extract_tensor
@@ -284,7 +296,15 @@ class TpuInferenceProcessor(Processor):
         return [self._attach(batch, outputs)]
 
     async def _infer(self, batch: MessageBatch) -> dict[str, np.ndarray]:
-        """One un-cached inference: extract -> device step(s)."""
+        """One un-cached inference: extract -> device step(s).
+
+        Token rows are carved by length across the declared (rows, seq) grid
+        (``bucketing.carve_by_length``) so short rows are not padded to the
+        batch's longest: the pieces serve concurrently, like the packed
+        path's windows (the runner's in-flight semaphore pipelines them),
+        and their outputs scatter back into the batch's row order. A batch
+        whose rows share a seq bucket is one piece — the one step it always
+        was; one failed piece fails the batch."""
         from arkflow_tpu.obs.trace import record_stage
 
         if self.packing:
@@ -293,12 +313,35 @@ class TpuInferenceProcessor(Processor):
 
         t0 = _time.perf_counter()
         with self.m_extract.time():
-            inputs = self._extract(batch)
+            inputs, lengths = self._extract(batch)
         # extraction/tokenization is infeed prep too — same stage name as
         # the runner's pad/stage span, so the breakdown shows ONE infeed
         # cost (the two sites sum)
         record_stage("infeed_prep", _time.perf_counter() - t0)
-        return await self.runner.infer(inputs)
+        if lengths is None:  # tensor rows have no length to split by
+            return await self.runner.infer(inputs)
+        t0 = _time.perf_counter()
+        buckets = self.runner.buckets
+        pieces = carve_by_length(
+            lengths, buckets.batch_buckets, buckets.seq_buckets,
+            compiled=self.runner.compiled_grid())
+        self.m_steps.observe(len(pieces))
+        if len(pieces) == 1:
+            sb = pieces[0][2]
+            record_stage("length_split", _time.perf_counter() - t0)
+            return await self.runner.infer(
+                {k: v[:, :sb] for k, v in inputs.items()})
+        parts = [{k: v[idx, :sb] for k, v in inputs.items()}
+                 for idx, _, sb in pieces]
+        split_s = _time.perf_counter() - t0
+        outs = await asyncio.gather(*[self.runner.infer(p) for p in parts])
+        t0 = _time.perf_counter()
+        merged = _scatter_rows(
+            batch.num_rows, [idx for idx, _, _ in pieces], outs)
+        # carve + scatter host time, so it has a name in the trace's idle
+        # gaps should it ever hold the chip up
+        record_stage("length_split", split_s + _time.perf_counter() - t0)
+        return merged
 
     async def _infer_packed(self, batch: MessageBatch) -> dict[str, np.ndarray]:
         """Token-packed inference (tpu/packing.py): tokenize off the payload
@@ -340,17 +383,22 @@ class TpuInferenceProcessor(Processor):
         record_stage("infeed_prep", _time.perf_counter() - t0)
         outs = await asyncio.gather(
             *[self.runner.infer(inputs) for inputs, _ in windows])
-        # scatter each window's [E_w, ...] outputs back into original row
-        # order (window examples are row-sorted, not input-ordered)
-        n = batch.num_rows
-        merged: dict[str, np.ndarray] = {}
-        for key in outs[0]:
-            first = np.asarray(outs[0][key])
-            out = np.empty((n, *first.shape[1:]), first.dtype)
-            for (_, idx), chunk in zip(windows, outs):
-                out[idx] = np.asarray(chunk[key])
-            merged[key] = out
-        return merged
+        # window examples are row-sorted, not input-ordered
+        return _scatter_rows(batch.num_rows, [idx for _, idx in windows], outs)
+
+
+def _scatter_rows(n: int, indices: list[np.ndarray],
+                  outs: list[dict[str, np.ndarray]]) -> dict[str, np.ndarray]:
+    """Per-piece ``[rows_i, ...]`` outputs back into the batch's original
+    row order (``indices[i]`` are the batch rows piece ``i`` held)."""
+    merged: dict[str, np.ndarray] = {}
+    for key in outs[0]:
+        first = np.asarray(outs[0][key])
+        out = np.empty((n, *first.shape[1:]), first.dtype)
+        for idx, chunk in zip(indices, outs):
+            out[idx] = np.asarray(chunk[key])
+        merged[key] = out
+    return merged
 
 
 @register_processor("tpu_inference")

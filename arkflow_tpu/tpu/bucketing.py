@@ -14,7 +14,7 @@ from __future__ import annotations
 
 from collections import deque
 from dataclasses import dataclass, field
-from typing import TYPE_CHECKING, Optional, Sequence
+from typing import TYPE_CHECKING, Collection, Optional, Sequence
 
 import numpy as np
 
@@ -143,6 +143,105 @@ class BucketPolicy:
             return self
         return BucketPolicy(tuple(b * dp for b in self.batch_buckets),
                             self.seq_buckets, self.example_scale)
+
+
+#: Fixed charge per dispatched step in the length split's objective, in token
+#: slots: the time of the smallest warm step over the per-slot slope of the
+#: large ones. On a v5e, BERT-base in bfloat16 (chip run, PR 28): the 8 x 32
+#: step takes 3.15 ms through ``infer_sync`` and steps of 256+ rows cost
+#: 1.9 (seq 32) to 3.3 (seq 512) us a slot, 2.4 in the 1,024 x 512 step of a
+#: mixed read: 3.15 ms / 2.4 us. The time of a 1,024-row read moves by under
+#: 2 % for any charge from 256 to 2,048 slots.
+STEP_CHARGE_SLOTS = 1300
+
+#: The charge of a step whose program is not compiled yet. A cold compile of
+#: one (rows, seq) program takes 1.8-3.4 s on the chip (PR 28), a million
+#: slots of device time, charged here as if spread over 64 batches. It makes
+#: a process that did not warm its grid settle on a few large programs, the
+#: same ones whatever the read (log-normal reads of 1,024 rows name the same
+#: five in 85 % of seeds, against 43 different sets in 60 seeds at the warm
+#: charge), so a restart finds them in the compile cache; a finer program is
+#: compiled later only where it saves this much in one batch.
+COLD_STEP_CHARGE_SLOTS = 16384
+
+
+def carve_by_length(lengths: Sequence[int], batch_buckets: Sequence[int],
+                    seq_buckets: Sequence[int],
+                    compiled: Optional[Collection[tuple[int, int]]] = None,
+                    ) -> list[tuple[np.ndarray, int, int]]:
+    """Carve one batch's rows by token length into sub-batches that each sit
+    on a ``(batch_bucket, seq_bucket)`` pair of the grid, instead of padding
+    every row to the longest one.
+
+    Returns the pieces as ``(row indices, bb, sb)``: every row in exactly one
+    piece, ``bb`` the batch bucket the runner pads ``len(indices)`` rows to,
+    ``sb`` the seq bucket of the piece's longest row. Rows sorted by length,
+    longest first, are cut into consecutive pieces that minimise
+
+        sum(bb_i * sb_i + charge_i)
+
+    with ``charge_i`` = ``STEP_CHARGE_SLOTS`` for a program in ``compiled``
+    (None: the whole grid is) and ``COLD_STEP_CHARGE_SLOTS`` for one that
+    would compile on first sight. Every piece but the last is exactly one
+    batch bucket full (a shorter row riding in a longer piece's spare rows
+    costs nothing, so row padding only ever pays at the shortest seq
+    bucket), which also decomposes a row count that is off the batch grid
+    (351 short rows -> 256 + 128, not 512) when that is cheaper. The charge keeps a trickle
+    whole: 8 mixed rows split five ways would dispatch 8 x (32 + ... + 512)
+    slots against 8 x 512 unsplit. The unsplit batch is one of the cuts
+    weighed, so the result never costs more than it.
+
+    A batch whose rows share one seq bucket is never cut: it comes back as
+    the single ``(batch_bucket(n), sb)`` piece the runner pads it to anyway.
+    """
+    lens = np.asarray(lengths, dtype=np.int64)
+    n = int(lens.shape[0])
+    bbs = tuple(sorted(int(b) for b in batch_buckets))
+    sbs = np.asarray(sorted(int(s) for s in seq_buckets), dtype=np.int64)
+    if n == 0:
+        return [(np.arange(0), bbs[0], int(sbs[0]))]
+    order = np.argsort(-lens, kind="stable")
+    # seq bucket of each row, longest first (over-long rows: the top bucket)
+    row_sb = sbs[np.minimum(np.searchsorted(sbs, lens[order]), len(sbs) - 1)]
+    if row_sb[0] == row_sb[-1]:
+        return [(np.arange(n), BucketPolicy._pick(n, bbs), int(row_sb[0]))]
+    # dynamic programming over the offsets full pieces can reach, from the
+    # shortest rows back: cost[i] is the cheapest cut of rows i..n
+    cost: dict[int, int] = {n: 0}
+    take: dict[int, int] = {}
+    for i in sorted(_reachable(n, bbs), reverse=True):
+        sb = int(row_sb[i])
+        for bb in bbs:
+            # a piece that holds all that is left is the last one, padded to
+            # its bucket; a larger bucket for it only pads more
+            nxt = min(i + bb, n)
+            if nxt in cost:
+                warm = compiled is None or (bb, sb) in compiled
+                c = (bb * sb + cost[nxt]
+                     + (STEP_CHARGE_SLOTS if warm else COLD_STEP_CHARGE_SLOTS))
+                if i not in cost or c < cost[i]:
+                    cost[i], take[i] = c, bb
+            if nxt == n:
+                break
+    pieces, i = [], 0
+    while i < n:
+        bb = take[i]
+        pieces.append((order[i:i + bb], bb, int(row_sb[i])))
+        i += bb
+    return pieces
+
+
+def _reachable(n: int, sizes: Sequence[int]) -> set[int]:
+    """Offsets in ``[0, n)`` that sums of ``sizes`` reach from 0."""
+    seen = {0}
+    frontier = [0]
+    while frontier:
+        i = frontier.pop()
+        for s in sizes:
+            if i + s < n and i + s not in seen:
+                seen.add(i + s)
+                frontier.append(i + s)
+    return seen
 
 
 class BucketCapBus:

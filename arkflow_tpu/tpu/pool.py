@@ -237,6 +237,11 @@ class ModelRunnerPool:
                 out[k] = out.get(k, 0) + v
         return out
 
+    def compiled_grid(self) -> set[tuple[int, int]]:
+        """``(rows, seq)`` programs every member has compiled: a piece of a
+        length split may land on any of them."""
+        return set.intersection(*(m.compiled_grid() for m in self.members))
+
     # -- dispatch ----------------------------------------------------------
 
     def _pick(self, exclude: set[int]) -> Optional[int]:

@@ -930,6 +930,14 @@ class ModelRunner:
         with self._flash_lock:
             return dict(self._dispatch_counts)
 
+    def compiled_grid(self) -> set[tuple[int, int]]:
+        """``(rows, seq)`` of every token program compiled so far — by
+        warm-up, an off-path warm or traffic: what the length split
+        (``bucketing.carve_by_length``) names without a first-sight compile."""
+        with self._flash_lock:
+            return {shape for key in self._seen_shapes
+                    for name, shape in key if name == "input_ids"}
+
     # -- pipelined-parallel bubble accounting -------------------------------
 
     def _pp_geometry(self, padded: dict[str, Any]) -> tuple[int, int, int]:

@@ -63,6 +63,7 @@ import numpy as np
 
 from arkflow_tpu.errors import ConfigError, TunerError
 from arkflow_tpu.obs import global_registry
+from arkflow_tpu.tpu.bucketing import carve_by_length
 
 logger = logging.getLogger("arkflow.tpu.tuner")
 
@@ -427,15 +428,14 @@ def predict_waste(view: SketchView, shape: ShapeConfig) -> tuple[float, float]:
             true += float(ls.sum())
     else:
         # coalesced steady state: bucket-exact emissions of the top row
-        # bucket; seq buckets by each emission's longest row (what the
-        # processor's seq_bucket(max) does), tail emission on its row bucket
+        # bucket, each carved by length across the grid exactly as the
+        # processor serves it (one piece when its rows share a seq bucket)
         rows_per = shape.batch_buckets[-1]
         for start in range(0, lengths.size, rows_per):
             em = lengths[start:start + rows_per]
-            sb = _pick(int(em.max()), shape.seq_buckets)
-            rb = _pick(int(em.size), shape.batch_buckets)
-            cap += rb * sb
-            true += float(np.minimum(em, sb).sum())
+            cap += sum(bb * sb for _, bb, sb in carve_by_length(
+                em, shape.batch_buckets, shape.seq_buckets))
+            true += float(np.minimum(em, shape.seq_buckets[-1]).sum())
     if cap <= 0:
         return 0.0, 1.0
     fill = true / cap
