@@ -399,19 +399,6 @@ class MessageBatch:
         """Just the distinct trace ids (see ``source_trace_contexts``)."""
         return [c.trace_id for c in self.source_trace_contexts()]
 
-    def ext_values(self, key: str) -> list[str]:
-        """Distinct non-null values of ``__meta_ext_<key>`` across this
-        batch's rows, in first-seen row order; [] when the column is absent.
-        The per-row analogue of ``get_meta`` — a merged coalescer emission
-        carries one value per source batch (the sharded-ingest plane reads
-        its delivery ids through merges this way, exactly like
-        ``source_trace_contexts`` reads the trace column)."""
-        name = META_EXT_PREFIX + key
-        if not self.has_column(name) or self.num_rows == 0:
-            return []
-        return [v for v in self.column(name).unique().to_pylist()
-                if v is not None]
-
     def tenant(self, default: str | None = None) -> str | None:
         """Tenant id from ``__meta_ext_tenant``, or ``default`` when the
         batch is untagged (single-tenant streams never pay for the column)."""

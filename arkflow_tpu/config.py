@@ -34,13 +34,6 @@ class PipelineConfig:
     #: >0 runs the chain in that many worker PROCESSES (GIL escape for
     #: Python-bound transforms; see runtime/procpool.py). 0 = in-process.
     process_pool: int = 0
-    #: >0 shards the ENTIRE ingest hot path (decode -> coalesce -> admission
-    #: -> dispatch) across that many OS processes behind one endpoint: the
-    #: stage queue between input and workers becomes an Arrow-IPC flight hop
-    #: partitioned by batch_fingerprint/tenant hash (runtime/hostshard.py).
-    #: 0 = the single-process stream. Mutually exclusive with process_pool,
-    #: which shards only the processor chain, not the queue/coalescer.
-    ingest_shards: int = 0
     #: how many times a batch may be delivered (processed + written) before
     #: it is quarantined to error_output instead of redelivered. 1 keeps the
     #: quarantine-on-first-failure behavior; >1 lets transient processing
@@ -73,15 +66,13 @@ class PipelineConfig:
         if not isinstance(pool, int) or pool < 0:
             raise ConfigError(
                 f"pipeline.process_pool must be a non-negative int, got {pool!r}")
-        shards = m.get("ingest_shards", 0)
-        if isinstance(shards, bool) or not isinstance(shards, int) or shards < 0:
+        if m.get("ingest_shards", 0) != 0:
+            # a removed key: from_mapping reads with .get, so without this a
+            # left-over value would be ignored and the stream run unsharded
             raise ConfigError(
-                f"pipeline.ingest_shards must be a non-negative int, got {shards!r}")
-        if shards > 0 and pool > 0:
-            raise ConfigError(
-                "pipeline.ingest_shards and pipeline.process_pool are mutually "
-                "exclusive: ingest sharding already runs the whole hot path "
-                "(coalesce + admission + chain) in shard processes")
+                "pipeline.ingest_shards is gone: a stream runs in one process. "
+                "Move a CPU-bound chain off the GIL with pipeline.process_pool; "
+                "put a device tier in other processes behind remote_tpu")
         procs = m.get("processors", [])
         if not isinstance(procs, list):
             raise ConfigError("pipeline.processors must be a list")
@@ -106,7 +97,7 @@ class PipelineConfig:
         overload = OverloadConfig.from_config(
             m.get("overload"), deadline_ms=deadline, priority=priority)
         return cls(thread_num=threads, processors=[dict(p) for p in procs],
-                   process_pool=pool, ingest_shards=shards,
+                   process_pool=pool,
                    max_delivery_attempts=attempts,
                    queue_size=qsize, deadline_ms=deadline, priority=priority,
                    overload=overload)

@@ -49,8 +49,8 @@ def batch_to_ipc(rb: pa.RecordBatch) -> pa.Buffer:
     every payload a second time before the transport copied it onto the
     wire; a ``pa.Buffer`` supports the buffer protocol (``len``,
     ``memoryview``, pickle), so every consumer — flight frames, the
-    process-pool submit, the shard hop — hands it on zero-copy. Callers
-    that truly need ``bytes`` wrap with ``bytes(...)`` explicitly."""
+    process-pool submit — hands it on zero-copy. Callers that truly need
+    ``bytes`` wrap with ``bytes(...)`` explicitly."""
     sink = pa.BufferOutputStream()
     with pa.ipc.new_stream(sink, rb.schema) as w:
         w.write_batch(rb)

@@ -13,11 +13,6 @@ the stream EOFs. Config:
     batch_size: 128
     count: 100000         # optional total-row cap
     codec: json           # optional; raw __value__ bytes otherwise
-    tenants: 8            # optional; stamp batches round-robin with tenant
-                          # ids tenant0..tenantN-1 (multi-tenant traffic for
-                          # fairness/quota benches and sharded-ingest routing
-                          # — identical payloads otherwise share one
-                          # fingerprint and land on one shard)
 """
 
 from __future__ import annotations
@@ -34,25 +29,18 @@ from arkflow_tpu.utils.duration import parse_duration
 
 class GenerateInput(Input):
     def __init__(self, payloads: list[bytes], interval_s: float, batch_size: int,
-                 count: Optional[int], codec=None, tenants: int = 0):
+                 count: Optional[int], codec=None):
         if batch_size <= 0:
             raise ConfigError("generate.batch_size must be positive")
         if not payloads:
             raise ConfigError("generate input requires a payload")
-        if tenants < 0:
-            raise ConfigError("generate.tenants must be non-negative")
         self.payloads = payloads
         self.interval_s = interval_s
         self.batch_size = batch_size
         self.count = count
         self.codec = codec
-        self.tenants = tenants
         self._emitted = 0
-        self._reads = 0
         self._template: Optional[MessageBatch] = None
-        # stamped-template cache: (tenant lane, rows) -> batch; the tenant
-        # column is constant per batch so N lanes = N cached variants
-        self._stamped: dict[tuple[int, int], MessageBatch] = {}
 
     async def connect(self) -> None:
         self._emitted = 0
@@ -72,16 +60,6 @@ class GenerateInput(Input):
             rows = [self.payloads[i % len(self.payloads)] for i in range(size)]
             self._template = decode_payloads(rows, self.codec)
         batch = self._template if n == self._template.num_rows else self._template.slice(0, n)
-        if self.tenants:
-            # round-robin tenant stamp per READ: consecutive batches carry
-            # different tenant ids (multi-tenant traffic), cached per lane
-            lane = self._reads % self.tenants
-            key = (lane, batch.num_rows)
-            stamped = self._stamped.get(key)
-            if stamped is None:
-                stamped = self._stamped[key] = batch.with_tenant(f"tenant{lane}")
-            batch = stamped
-        self._reads += 1
         self._emitted += n
         return batch.with_source("generate"), NoopAck()
 
@@ -115,5 +93,4 @@ def _build(config: dict, resource: Resource) -> GenerateInput:
         batch_size=int(config.get("batch_size", 1)),
         count=int(config["count"]) if config.get("count") is not None else None,
         codec=build_codec(config.get("codec"), resource),
-        tenants=int(config.get("tenants", 0)),
     )

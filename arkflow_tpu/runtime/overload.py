@@ -260,18 +260,6 @@ class TenantPolicy:
                    for q in (*self.quotas.values(),
                              *((self.default_quota,) if self.default_quota else ())))
 
-    def without_quotas(self) -> "TenantPolicy":
-        """This policy with every quota stripped (weights/fairness kept).
-
-        The sharded-ingest plane hands each shard process this view: tenant
-        TokenBuckets are granted exactly ONCE, in the parent's shared quota
-        plane, while the WDRR fairness lanes and weighted admission shares
-        still operate per shard — N shards each holding the full quota
-        would over-grant every tenant's contract N times."""
-        import dataclasses
-
-        return dataclasses.replace(self, default_quota=None, quotas={})
-
 
 class _TenantState:
     """Per-tenant admission accounting inside one controller."""
@@ -443,19 +431,6 @@ class OverloadConfig:
                 f"overload.protect_priority ({self.protect_priority}) must be "
                 f"> pipeline.priority ({self.priority}): protecting the "
                 "default band disables queue shedding entirely")
-
-    def shard_local(self) -> "OverloadConfig":
-        """The view of this config an ingest SHARD process runs: identical
-        AIMD window / deadline / priority / fairness knobs, tenant quotas
-        stripped (``TenantPolicy.without_quotas``). The parent keeps the
-        original config and grants quota tokens exactly once in its shared
-        quota plane (:meth:`OverloadController.admit_quota`); per-shard
-        windows stay independent — each shard adapts to its own backlog."""
-        import dataclasses
-
-        return dataclasses.replace(
-            self, tenants=(self.tenants.without_quotas()
-                           if self.tenants is not None else None))
 
 
 class OverloadController:
@@ -798,45 +773,18 @@ class OverloadController:
             # (negative balance): the refill must pay the whole batch off
             # before the tenant admits again, so batching can't ride the
             # clamp past the contracted rate.
-            reason = self._check_quota(ts, rows, tokens)
-            if reason is not None:
-                return reason
+            if ts.rows_bucket is not None and ts.rows_bucket.time_until(
+                    min(rows, ts.rows_bucket.capacity)) > 0:
+                return self._shed("quota", ts)
+            if (tokens > 0 and ts.tokens_bucket is not None
+                    and ts.tokens_bucket.time_until(
+                        min(tokens, ts.tokens_bucket.capacity)) > 0):
+                return self._shed("quota", ts)
+            if rows > 0 and ts.rows_bucket is not None:
+                ts.rows_bucket.drain(rows)
+            if tokens > 0 and ts.tokens_bucket is not None:
+                ts.tokens_bucket.drain(tokens)
         return None
-
-    def _check_quota(self, ts: _TenantState, rows: float,
-                     tokens: float) -> Optional[str]:
-        """The quota gate + charge, shared by :meth:`admit` and the sharded
-        plane's :meth:`admit_quota`: both axes gated (capacity-clamped)
-        before either drains, then the REAL cost is charged as debt."""
-        if ts.rows_bucket is not None and ts.rows_bucket.time_until(
-                min(rows, ts.rows_bucket.capacity)) > 0:
-            return self._shed("quota", ts)
-        if (tokens > 0 and ts.tokens_bucket is not None
-                and ts.tokens_bucket.time_until(
-                    min(tokens, ts.tokens_bucket.capacity)) > 0):
-            return self._shed("quota", ts)
-        if rows > 0 and ts.rows_bucket is not None:
-            ts.rows_bucket.drain(rows)
-        if tokens > 0 and ts.tokens_bucket is not None:
-            ts.tokens_bucket.drain(tokens)
-        return None
-
-    def admit_quota(self, tenant: Optional[str] = None, rows: float = 1.0,
-                    tokens: float = 0.0) -> Optional[str]:
-        """Quota-ONLY admission: the parent side of the sharded-ingest
-        split. The parent process owns every tenant's TokenBuckets (the
-        shared quota plane — granted exactly once, never N-times across N
-        shards) and consults this before routing a batch to its shard;
-        window/deadline/priority/fair-share admission then runs INSIDE the
-        owning shard against its local backlog, quota-stripped
-        (:meth:`OverloadConfig.shard_local`). Returns None to admit, else
-        ``"quota"`` (already counted in ``arkflow_shed_total``)."""
-        if not self.cfg.enabled:
-            return None
-        ts = self.tenant_state(tenant)
-        if ts is None:
-            return None
-        return self._check_quota(ts, rows, tokens)
 
     def expire(self, tenant: Optional[str] = None) -> str:
         """Count a batch that went stale WHILE queued (the worker's

@@ -44,9 +44,9 @@ _worker_loop = None  # ONE persistent loop per worker: connections opened at
 
 def batch_to_ipc(batch: MessageBatch) -> pa.Buffer:
     """Serialize for the process hop — the ONE IPC helper (connect/flight)
-    shared with the cluster plane and the ingest-shard hop. Returns the
-    Arrow buffer itself: pickle ships its bytes once; the old
-    ``.to_pybytes()`` here copied every payload a second time first."""
+    shared with the cluster plane. Returns the Arrow buffer itself: pickle
+    ships its bytes once; the old ``.to_pybytes()`` here copied every
+    payload a second time first."""
     return _rb_to_ipc(batch.record_batch)
 
 

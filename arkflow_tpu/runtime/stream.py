@@ -840,13 +840,6 @@ class Stream:
 def build_stream(cfg: StreamConfig, name: Optional[str] = None) -> Stream:
     """Construct a Stream from config via the builder registries
     (ref StreamConfig::build, stream/mod.rs:453-492)."""
-    if cfg.pipeline.ingest_shards > 0:
-        # the whole hot path (coalesce -> admission -> chain) runs in shard
-        # PROCESSES behind this parent endpoint (runtime/hostshard.py);
-        # only input/output/error_output are built in-parent
-        from arkflow_tpu.runtime.hostshard import build_sharded_stream
-
-        return build_sharded_stream(cfg, name=name or cfg.name or "stream")
     resource = Resource()
     # temporaries first, so processors can look them up (ref :459-467)
     for tcfg in cfg.temporary:

@@ -120,15 +120,6 @@ def _bench_integrity() -> str | None:
     return "500ms" if v == "1" else v
 
 
-def _bench_ingest_shards() -> int:
-    """BENCH_INGEST_SHARDS=N runs the headline phase's hot path in N ingest
-    shard processes (runtime/hostshard.py); 0 (default) = single process.
-    NOTE: a throughput WIN needs >= N+1 host cores — on fewer, the parent
-    and shards timeshare and the hop is pure overhead (recorded honestly
-    via host_cores in the detail)."""
-    return int(os.environ.get("BENCH_INGEST_SHARDS", "0"))
-
-
 # latency phase offered load: batch_size rows every interval. The artifact
 # tags derive from these SAME constants, so tuning the phase cannot leave a
 # stale literal in bench_logs/latest_latency.json.
@@ -202,12 +193,6 @@ def build_stream_config(batch: int, seq: int, tiny: bool) -> dict:
                   "coalesce": {"batch_buckets": [batch], "deadline": "5ms"}}
     else:
         buffer = {"type": "memory", "capacity": batch, "timeout": "5ms"}
-    shards = _bench_ingest_shards()
-    if shards:
-        # sharded ingest spreads by tenant hash; identical generate payloads
-        # share one fingerprint and would all land on one shard otherwise
-        src["tenants"] = int(os.environ.get("BENCH_SHARD_TENANTS",
-                                            str(4 * shards)))
     return {
         # per-phase stream name: metrics are labeled by stream, so the packed
         # phase must NOT share the padded phase's rows counter / e2e
@@ -222,11 +207,6 @@ def build_stream_config(batch: int, seq: int, tiny: bool) -> dict:
         },
         "buffer": buffer,
         "pipeline": {
-            # BENCH_INGEST_SHARDS=N: the whole hot path (coalesce ->
-            # admission -> inference) runs in N shard processes behind the
-            # parent endpoint (runtime/hostshard.py); the buffer moves into
-            # the shards with it
-            **({"ingest_shards": shards} if shards else {}),
             # workers must cover the device queue depth or the semaphore
             # can't fill: each in-flight step is held by one processor call
             "thread_num": max(2, int(os.environ.get("BENCH_INFLIGHT", "6"))),
@@ -683,13 +663,6 @@ def _print_headline(res: dict, tiny: bool, batch: int, seq: int,
                                   else os.environ.get("BENCH_SOFTMAX_DTYPE", "bfloat16")),
                 **_packing_detail(batch, seq),
                 **_flops_detail(res["rows_per_sec"], exec_rate, seq, tiny),
-                # sharded-ingest knob record: shard count + the share of
-                # e2e spent waiting for a worker (the host-wall symptom the
-                # shards exist to cut) + cores (a win needs >= shards+1)
-                "ingest_shards": _bench_ingest_shards(),
-                "queue_wait_share": res.get("stage_breakdown", {}).get(
-                    "queue_wait", {}).get("share_of_e2e"),
-                "host_cores": os.cpu_count(),
                 # trace-layer per-stage attribution for THIS phase: a
                 # regression names the stage that slowed down
                 "stage_breakdown": res.get("stage_breakdown", {}),

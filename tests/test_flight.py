@@ -47,6 +47,19 @@ def test_ipc_roundtrip_and_url_parsing():
         parse_remote_url("arkflow://nohost")
 
 
+def test_batch_to_ipc_zero_copy_buffer_roundtrip():
+    """The shared IPC helper returns a pyarrow Buffer (no bytes() copy of
+    the payload) and round-trips through ipc_to_batches."""
+    b = MessageBatch.new_binary([b"alpha", b"beta"]).with_source("s")
+    buf = batch_to_ipc(b.record_batch)
+    assert isinstance(buf, pa.Buffer)
+    out = ipc_to_batches(buf)
+    assert len(out) == 1
+    back = MessageBatch(out[0])
+    assert back.to_binary() == [b"alpha", b"beta"]
+    assert back.get_meta("__meta_source") == "s"
+
+
 def test_remote_scan_streams_filtered_batches(tmp_path):
     f = tmp_path / "events.parquet"
     _write_parquet(f, rows=1000)

@@ -96,6 +96,27 @@ def test_queue_size_validation(bad):
             {"thread_num": 1, "queue_size": bad, "processors": []})
 
 
+@pytest.mark.parametrize("extra, rejected", [
+    ({"ingest_shards": 0}, False),
+    ({"ingest_shards": 1}, True),
+    ({"ingest_shards": 2}, True),
+    ({"ingest_shards": 2, "process_pool": 2}, True),
+])
+def test_removed_ingest_shards_key_is_rejected_not_ignored(extra, rejected):
+    """``from_mapping`` reads keys with ``.get``: without the check a
+    left-over ``ingest_shards: 2`` would run unsharded in silence. The
+    error sends the user to the two tiers that remain."""
+    mapping = {"thread_num": 1, "processors": [], **extra}
+    if not rejected:
+        assert PipelineConfig.from_mapping(mapping).process_pool == 0
+        return
+    with pytest.raises(ConfigError) as err:
+        PipelineConfig.from_mapping(mapping)
+    assert "ingest_shards" in str(err.value)
+    assert "process_pool" in str(err.value)
+    assert "remote_tpu" in str(err.value)
+
+
 @pytest.mark.parametrize("bad", [0, -250, True, "250"])
 def test_deadline_ms_validation(bad):
     with pytest.raises(ConfigError):

@@ -82,25 +82,6 @@ stayed within the SLO (serving never wedged through a preemption), offered
 == delivered + shed over distinct rows (zero silent loss), and the ramp
 fired ``scale_out`` with zero dispatch failures before any shed.
 
-``--hostshard`` soaks the process-sharded ingest plane
-(runtime/hostshard.py): the ingest hot path fanned over 2 shard processes
-behind ONE parent endpoint — duplicate groups land whole on one shard, a
-SIGKILLed shard's in-flight deliveries redispatch to the survivor with
-global output order intact, and tenant quotas grant once in the parent
-(same delivered allowance at 2 shards as single-process)::
-
-    python tools/chaos_soak.py --hostshard --fast    # tier-1 smoke
-    python tools/chaos_soak.py --hostshard --seed 3
-
-Hostshard PASS means: zero silent loss in every phase (offered ==
-delivered + shed), ordered exactly-once delivery through the shard SIGKILL
-with redispatches counted, shard-affinity batch counts that are exact
-multiples of the duplicate factor, a sharded ``queue_wait`` share below
-30%, quota identity + granted-once allowance, and a rows/s scaling ratio
->= 1.5x when the host has >= shards+1 cores (on smaller hosts the parent
-and shards timeshare — the verdict records the honest ratio and gates on
-the invariants instead, like the multichip bench's forced host mesh).
-
 ``--disagg`` soaks prefill/decode disaggregation on the cluster plane
 (runtime/cluster.py + tpu/serving.py): a mixed-length generation load
 serves co-hosted (2 ``both`` workers) and disaggregated (1 prefill + 1
@@ -114,7 +95,7 @@ SIGKILL::
 Disagg PASS means: the disaggregated topology beats co-hosted on BOTH
 worker-side TTFT p99 and tokens/sec when the host has >= 3 cores (on
 smaller hosts everything timeshares — the verdict records the honest
-ratios and gates on the invariants, hostshard-style), every KV page flowed
+ratios and gates on the invariants instead), every KV page flowed
 cross-process (``kv_pushed`` == ``kv_adopted``, zero refusals counted as
 losses), duplicate prompts land on ONE prefill worker, and a decode worker
 SIGKILLed mid-stream loses nothing — in-flight requests nack through
@@ -2162,8 +2143,8 @@ def run_disagg_soak(seconds: float = 90.0, seed: int = 7,
       decode at the SAME worker count, KV pages streamed over ``kv_push``);
       disagg must beat co-hosted on BOTH worker-side TTFT p99 and
       tokens/sec. The ratio assertion is gated on >= 3 host cores
-      (hostshard-style: on smaller hosts the processes timeshare and the
-      verdict records the honest ratios behind soft floors);
+      (on smaller hosts the processes timeshare and the verdict records
+      the honest ratios behind soft floors);
     - **prefill-ring affinity**: with 2 prefill workers on the ring,
       duplicate prompts under prefix routing all land on ONE prefill
       worker (prefix-cache affinity survives the role split verbatim);
@@ -3071,309 +3052,6 @@ def run_tuner_soak(seconds: float = 90.0, seed: int = 7,
     return _attach_tracing(verdict, trace_seq0, trace_forced0)
 
 
-# -- sharded-ingest soak (runtime/hostshard.py) -------------------------------
-
-
-def _hostshard_config(name: str, shards: int, input_cfg: dict,
-                      processors: list | None = None,
-                      overload: dict | None = None) -> dict:
-    """One ingest stream, optionally process-sharded. ``shards=0`` is the
-    single-process control — IDENTICAL config minus the knob, so every
-    phase compares the same pipeline with and without the plane."""
-    pipeline: dict = {"thread_num": 2, "processors": processors or []}
-    if shards:
-        pipeline["ingest_shards"] = shards
-    if overload is not None:
-        pipeline["overload"] = overload
-    return {
-        "name": name,
-        "input": input_cfg,
-        "pipeline": pipeline,
-        "output": {"type": "drop"},
-        "error_output": {"type": "drop"},
-    }
-
-
-def run_hostshard_soak(seconds: float = 60.0, seed: int = 7,
-                       fast: bool = False) -> dict:
-    """Process-sharded ingest soak (runtime/hostshard.py): one endpoint in
-    the parent, the ingest hot path fanned over 2 shard PROCESSES, proving
-
-    - **throughput**: the same CPU-bound pipeline runs single-process and
-      at 2 shards; at 2 shards admission drains into the shard hop, so the
-      measured ``queue_wait`` share collapses below 30%. The rows/s ratio
-      is asserted >= 1.5x only when the host has >= shards+1 cores (parent
-      and shards must actually run in parallel — the multichip bench's
-      forced-host-mesh caveat); on smaller hosts the hop is pure overhead
-      and the verdict records the honest ratio behind a soft floor;
-    - **affinity**: byte-identical duplicate groups land whole on ONE
-      shard — every shard's processed-batch count is an exact multiple of
-      the duplicate factor, so coalescer/cache state never splits;
-    - **chaos**: a shard SIGKILLed mid-load loses nothing — its in-flight
-      deliveries redispatch to the survivor and every row arrives exactly
-      once IN global dispatch order (the reorder window holds the seqs);
-    - **quota-once**: the same paced over-quota load delivers the SAME
-      token allowance at 2 shards as single-process (quotas grant once in
-      the parent's shared plane, not once per shard), with offered ==
-      delivered + shed both times.
-
-    The parent builds the streams in-process (``main`` pins the virtual-CPU
-    platform first); shard children inherit that env through spawn.
-    """
-    trace_seq0, trace_forced0 = _tracing_watermark()
-    import asyncio
-    import os
-    import random
-    import signal
-
-    from arkflow_tpu.batch import MessageBatch
-    from arkflow_tpu.components import ensure_plugins_loaded
-    from arkflow_tpu.config import StreamConfig
-    from arkflow_tpu.obs.trace import global_tracer
-    from arkflow_tpu.plugins.output.drop import DropOutput
-    from arkflow_tpu.runtime import build_stream
-
-    ensure_plugins_loaded()
-    rng = random.Random(seed)
-    shards = 2
-    cores = os.cpu_count() or 1
-    cores_ok = cores >= shards + 1
-
-    spin = 10_000 if fast else 40_000      # per-batch host work (throughput)
-    n_tput = 40 if fast else 150           # batches per throughput run
-    tput_batch = 16 if fast else 32
-    groups, repeats = (8, 5) if fast else (12, 8)
-    n_chaos = 36 if fast else 120
-    quota_rows_s = 150
-    n_quota = 1200 if fast else 3000       # offered rows, paced over-quota
-
-    spin_proc = [{
-        "type": "python",
-        "script": ("def process(batch):\n"
-                   "    s = 0\n"
-                   f"    for i in range({spin}):\n"
-                   "        s += i * i\n"
-                   "    return batch\n"),
-    }]
-    sleep_proc = [{
-        "type": "python",
-        "script": ("import time\n"
-                   "def process(batch):\n"
-                   "    time.sleep(0.03)\n"
-                   "    return batch\n"),
-    }]
-
-    class _Collect(DropOutput):
-        def __init__(self, sink: list):
-            self._sink = sink
-            self.t_first: float | None = None
-            self.t_last: float | None = None
-
-        async def write(self, batch: MessageBatch) -> None:
-            now = time.monotonic()
-            if self.t_first is None:
-                self.t_first = now
-            self.t_last = now
-            self._sink.extend(batch.to_binary())
-
-    def run_phase(cfg_map: dict, budget_s: float, driver=None) -> dict:
-        """Build + run one stream to EOF (bounded); returns the collected
-        rows, the stream, the run wall-clock and the phase's queue_wait
-        share (per-phase trace watermark — the store is process-global)."""
-        wm_seq, _ = _tracing_watermark()
-        stream = build_stream(StreamConfig.from_mapping(cfg_map))
-        delivered: list[bytes] = []
-        shed: list[bytes] = []
-        out_sink, err_sink = _Collect(delivered), _Collect(shed)
-        stream.output = out_sink
-        stream.error_output = err_sink
-        out: dict = {"delivered": delivered, "shed": shed, "stream": stream,
-                     "out_sink": out_sink, "err_sink": err_sink}
-
-        async def bounded() -> None:
-            cancel = asyncio.Event()
-            task = asyncio.create_task(stream.run(cancel))
-            driver_task = (asyncio.create_task(driver(stream, delivered))
-                           if driver is not None else None)
-            t0 = time.monotonic()
-            done, _ = await asyncio.wait({task}, timeout=budget_s)
-            out["elapsed_s"] = time.monotonic() - t0
-            out["wedged"] = not done
-            if done:
-                task.result()  # surface a crashed stream with its traceback
-            else:
-                cancel.set()
-                try:
-                    await asyncio.wait_for(task, timeout=15.0)
-                except (asyncio.TimeoutError, Exception):
-                    task.cancel()
-            if driver_task is not None:
-                try:
-                    await asyncio.wait_for(driver_task, timeout=5.0)
-                except (asyncio.TimeoutError, Exception):
-                    driver_task.cancel()
-
-        asyncio.run(bounded())
-        stages = global_tracer().stage_breakdown(wm_seq)["stages"]
-        out["queue_wait_share"] = float(
-            stages.get("queue_wait", {}).get("share_of_e2e") or 0.0)
-        return out
-
-    def rows_per_s(phase: dict) -> float:
-        """Delivery-window rate (first delivered row to last): shard spawn
-        and imports happen before the first row, so they don't skew the
-        single-vs-sharded comparison."""
-        sink = phase["out_sink"]
-        if sink.t_first is None:
-            return 0.0
-        return len(phase["delivered"]) / max(sink.t_last - sink.t_first, 0.05)
-
-    t_start = time.monotonic()
-    budget = max(seconds, 120.0)
-    verdict: dict = {"mode": "hostshard", "seed": seed, "shards": shards,
-                     "host_cores": cores}
-
-    # -- phase 1: single process vs 2 shards, same CPU-bound pipeline ------
-    tput_rows = n_tput * tput_batch
-    tput_input = {"type": "generate", "payload": "hostshard soak payload",
-                  "batch_size": tput_batch, "count": tput_rows,
-                  "tenants": 4 * shards}
-    one = run_phase(_hostshard_config("hostshard-tput1", 0, tput_input,
-                                      spin_proc), budget)
-    two = run_phase(_hostshard_config("hostshard-tput2", shards, tput_input,
-                                      spin_proc), budget)
-    r1, r2 = rows_per_s(one), rows_per_s(two)
-    ratio = r2 / max(r1, 1e-9)
-    ratio_floor = 1.5 if cores_ok else 0.10
-    throughput = {
-        "offered_rows": tput_rows,
-        "single_rows_per_s": round(r1, 1),
-        "sharded_rows_per_s": round(r2, 1),
-        "scaling_ratio": round(ratio, 3),
-        "single_queue_wait_share": round(one["queue_wait_share"], 4),
-        "sharded_queue_wait_share": round(two["queue_wait_share"], 4),
-        "cores_gated": not cores_ok,
-        "ratio_floor": ratio_floor,
-    }
-    if not cores_ok:
-        throughput["caveat"] = (
-            f"host has {cores} core(s) < shards+1={shards + 1}: parent and "
-            "shards timeshare one core, so the hop cannot win wall-clock "
-            "here (the multichip forced-host-mesh caveat); gating on the "
-            "plane's invariants + queue_wait collapse, not the speedup")
-    throughput["pass"] = bool(
-        len(one["delivered"]) == tput_rows
-        and len(two["delivered"]) == tput_rows
-        and not one["wedged"] and not two["wedged"]
-        and ratio >= ratio_floor
-        and two["queue_wait_share"] < 0.30)
-    verdict["throughput"] = throughput
-
-    # -- phase 2: duplicate groups land whole on one shard -----------------
-    aff_payloads = [f"group-{g:02d} payload"
-                    for g in range(groups) for _ in range(repeats)]
-    rng.shuffle(aff_payloads)
-    aff = run_phase(_hostshard_config(
-        "hostshard-affinity", shards,
-        {"type": "memory", "messages": aff_payloads}), budget)
-    counts = {sid: s.get("batches", 0)
-              for sid, s in aff["stream"].shard_stats().items()}
-    affinity = {
-        "offered_batches": groups * repeats,
-        "duplicate_factor": repeats,
-        "batches_by_shard": counts,
-        "delivered_rows": len(aff["delivered"]),
-        # each group's duplicates share a fingerprint -> one shard, so
-        # every shard's count is a whole number of groups
-        "whole_groups_ok": all(c % repeats == 0 for c in counts.values()),
-    }
-    affinity["pass"] = bool(not aff["wedged"]
-                            and len(aff["delivered"]) == groups * repeats
-                            and sum(counts.values()) == groups * repeats
-                            and affinity["whole_groups_ok"])
-    verdict["affinity"] = affinity
-
-    # -- phase 3: SIGKILL a shard mid-load — ordered, zero silent loss -----
-    chaos_payloads = [f"chaos-{i:05d}" for i in range(n_chaos)]
-    chaos_events: dict = {"killed": False}
-
-    async def chaos_driver(stream, delivered) -> None:
-        # wait until BOTH shards hold in-flight work, then kill the one
-        # owning the most of it (redispatch is guaranteed non-trivial)
-        for _ in range(1200):
-            await asyncio.sleep(0.05)
-            owners = [e.shard for e in stream._outstanding.values()
-                      if e.shard is not None]
-            pids = stream.shard_pids()
-            if stream.m_batches_out.value > 0 and len(set(owners)) == shards:
-                victim = max(set(owners), key=owners.count)
-                os.kill(pids[victim], signal.SIGKILL)
-                chaos_events["killed"] = True
-                chaos_events["victim"] = victim
-                chaos_events["killed_at_delivered"] = len(delivered)
-                return
-
-    chaos = run_phase(_hostshard_config(
-        "hostshard-chaos", shards,
-        {"type": "memory", "messages": chaos_payloads}, sleep_proc),
-        budget, driver=chaos_driver)
-    expected = [p.encode() for p in chaos_payloads]
-    chaos_out = {
-        **chaos_events,
-        "wedged": chaos["wedged"],
-        "offered_rows": n_chaos,
-        "delivered_rows": len(chaos["delivered"]),
-        "shed_rows": len(chaos["shed"]),
-        "lost_rows": len(set(expected) - set(chaos["delivered"])
-                         - set(chaos["shed"])),
-        "redispatched": int(chaos["stream"].m_redispatch.value),
-        # exactly once AND in global dispatch order, through the kill
-        "ordered_exactly_once": chaos["delivered"] == expected,
-    }
-    chaos_out["pass"] = bool(chaos_events["killed"]
-                             and not chaos["wedged"]
-                             and chaos_out["ordered_exactly_once"]
-                             and chaos_out["redispatched"] > 0)
-    verdict["chaos"] = chaos_out
-
-    # -- phase 4: quota allowance identical at 1 process and 2 shards ------
-    overload_cfg = {
-        "enabled": True,
-        "max_window": 64,
-        "tenants": {"default_quota": {"rows_per_sec": quota_rows_s},
-                    "burst": "1s"},
-    }
-    quota_input = {"type": "generate", "payload": "quota soak row",
-                   "interval": "10ms", "batch_size": 10, "count": n_quota}
-    q1 = run_phase(_hostshard_config("hostshard-quota1", 0, quota_input,
-                                     None, overload_cfg), budget)
-    q2 = run_phase(_hostshard_config("hostshard-quota2", shards, quota_input,
-                                     None, overload_cfg), budget)
-    d1, s1 = len(q1["delivered"]), len(q1["shed"])
-    d2, s2 = len(q2["delivered"]), len(q2["shed"])
-    quota_out = {
-        "offered_rows": n_quota,
-        "rows_per_sec": quota_rows_s,
-        "single": {"delivered": d1, "shed": s1},
-        "sharded": {"delivered": d2, "shed": s2},
-        "identity_ok": (d1 + s1 == n_quota and d2 + s2 == n_quota),
-        # N shards each holding the full quota would deliver ~N x the
-        # single-process allowance; granted-once keeps them equal (the
-        # 1.3 headroom absorbs whole-batch granting + pacing jitter)
-        "granted_once_ok": (d2 <= 1.3 * d1 + 2 * 10 and d2 >= 0.4 * d1),
-    }
-    quota_out["pass"] = bool(not q1["wedged"] and not q2["wedged"]
-                             and s1 > 0 and s2 > 0
-                             and quota_out["identity_ok"]
-                             and quota_out["granted_once_ok"])
-    verdict["quota"] = quota_out
-
-    verdict["pass"] = bool(throughput["pass"] and affinity["pass"]
-                           and chaos_out["pass"] and quota_out["pass"])
-    verdict["elapsed_s"] = round(time.monotonic() - t_start, 3)
-    return _attach_tracing(verdict, trace_seq0, trace_forced0)
-
-
 # -- silent-data-corruption soak (tpu/integrity.py) ---------------------------
 
 
@@ -3824,13 +3502,6 @@ def main(argv=None) -> int:
                          "AND padding_waste_frac with zero on-path recompiles "
                          "after warmup, a forced probe-failure rollback, and "
                          "zero silent loss across flips")
-    ap.add_argument("--hostshard", action="store_true",
-                    help="sharded-ingest soak: the ingest hot path fanned "
-                         "over 2 shard processes behind one endpoint; "
-                         "asserts queue_wait collapse, duplicate-group "
-                         "shard affinity, ordered zero-silent-loss through "
-                         "a shard SIGKILL, and quota-once admission "
-                         "(rows/s ratio gated on host cores)")
     ap.add_argument("--sdc", action="store_true",
                     help="silent-data-corruption soak: a bitflipped pool "
                          "member is digest-detected, quarantined, repaired "
@@ -3920,18 +3591,6 @@ def main(argv=None) -> int:
             pin_cpu_env(os.environ, n_devices=2)
         verdict = run_sdc_soak(seconds=args.seconds, seed=args.seed,
                                fast=args.fast)
-        print(json.dumps(verdict, indent=2))
-        return 0 if verdict["pass"] else 1
-
-    if args.hostshard:
-        if os.environ.get("ARKFLOW_SOAK_KEEP_ENV") != "1":
-            # the parent builds the streams in-process; shard children
-            # inherit the pinned virtual-CPU env through spawn
-            from arkflow_tpu.utils.cleanenv import pin_cpu_env
-
-            pin_cpu_env(os.environ, n_devices=1)
-        verdict = run_hostshard_soak(seconds=args.seconds, seed=args.seed,
-                                     fast=args.fast)
         print(json.dumps(verdict, indent=2))
         return 0 if verdict["pass"] else 1
 
