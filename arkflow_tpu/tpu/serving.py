@@ -101,8 +101,7 @@ class _InFlightDecode:
     ``nxt`` is the step's DEVICE-resident next-token array — fed straight
     into the next dispatch so the device never waits for a host round trip.
     ``reqs`` snapshots per-slot request identity at dispatch: a slot whose
-    request finished (or was replaced) between dispatch and apply drops its
-    token instead of crediting it to the wrong request."""
+    request finished (or was replaced) before apply drops its token."""
 
     nxt: object
     act: "np.ndarray"
@@ -135,6 +134,24 @@ class _Hop:
     def done(self) -> tuple[float, float]:
         w = self.wait
         return w.dur_s, (w.t0 - self.t_call) + (time.perf_counter() - w.t1)
+
+
+def pack_operands(ids, a, b, table) -> np.ndarray:
+    """A device step's small operands as ONE host int32 array (one upload a
+    step): ``[ids(rows x c) | a(rows) | b(rows) | table(rows x pages)]``.
+    decode: tokens (c = 1), lengths, active 0/1; chunk and admission prefill
+    (one row): ids, offset, tokens present; verify: ids, lengths, tokens."""
+    return np.concatenate([np.asarray(part, np.int32).reshape(-1)
+                           for part in (ids, a, b, table)])
+
+
+def unpack_operands(packed, rows: int, pages: int):
+    """Inside a step's program: ``pack_operands``' four parts again (static
+    slices; ``c`` is whatever the array's size leaves)."""
+    c = packed.shape[0] // rows - 2 - pages
+    ids, a, b, table = jnp.split(
+        packed, [rows * c, rows * (c + 1), rows * (c + 2)])
+    return ids.reshape(rows, c), a, b, table.reshape(rows, pages)
 
 
 class GenerationServer:
@@ -252,7 +269,9 @@ class GenerationServer:
 
         self.temperature = float(temperature)
         self.top_k = int(top_k)
-        self._key = jax.random.PRNGKey(seed)
+        # a sampling server's key lives ON the device: a step's program
+        # splits it and returns the successor. A greedy server has no key
+        self._key = jax.random.PRNGKey(seed) if self.temperature > 0.0 else None
         # self-speculative greedy decode: draft k-1 tokens by n-gram lookup
         # over the sequence's own history, verify all k in ONE chunk call.
         # Decode steps are HBM-bandwidth-bound (weights + KV reads dominate),
@@ -364,6 +383,12 @@ class GenerationServer:
         reg = global_registry()
         self.m_steps = reg.counter("arkflow_gen_decode_steps_total", "lockstep decode steps")
         self.m_tokens = reg.counter("arkflow_gen_tokens_total", "tokens generated")
+        # host arrays handed to the device for a step (``_build_jitted``):
+        # 1 a step while every step's operands go up packed
+        self.m_uploads = {
+            kind: reg.counter("arkflow_gen_step_uploads_total", "host arrays "
+                              "handed to a step", {"model": name, "kind": kind})
+            for kind in ("decode", "chunk", "prefill", "verify")}
         self.m_spec_drafted = reg.counter(
             "arkflow_gen_spec_drafted_total", "draft tokens offered for verification")
         self.m_spec_accepted = reg.counter(
@@ -541,11 +566,12 @@ class GenerationServer:
         return kp, vp
 
     def _build_jitted(self) -> None:
-        """(Re)build the four jitted steps. Under a mesh every step carries
-        explicit in/out shardings: the KV pools split over KV heads on
-        ``tp``, everything else (token ids, lengths, page tables, keys) is
-        replicated — page-table gathers stay static-shaped, so the layer
-        scan lowers to plain GSPMD collectives with no dynamic shapes."""
+        """(Re)build the four jitted steps, each ``fn(params, packed, kp, vp,
+        *device operands) -> (tokens, kp, vp, *key)``; ``packed`` is the
+        step's ONE host array (``pack_operands``). Under a mesh every step
+        carries explicit in/out shardings: the KV pools split over KV heads
+        on ``tp``, everything else is replicated — page-table gathers stay
+        static-shaped, so the layer scan lowers to plain GSPMD collectives."""
         from arkflow_tpu.models.decoder import select_token
         from arkflow_tpu.models.paged_decode import paged_prefill_chunk
 
@@ -553,16 +579,23 @@ class GenerationServer:
         kv_layer = self._kv_layer_sharding
         kern = dict(attention_kernel=self.decode_kernel,
                     kernel_interpret=self.kernel_interpret)
-        # a routed-expert model's steps also return their three routing
-        # counters (int32 [3]): ``_decode`` / ``_prefill`` append them to the
-        # token array; ``_chunk``, whose logits stay on the device, adds them
-        # to the prompt's running counters, which ride from chunk to chunk
-        # on the device and come back with the last chunk's token — so they
-        # reach the host in the fetch the step makes anyway
+        pages = self.pages_per_slot
+        # what else rides a step, on the device already: a sampling server's
+        # key, the step before's tokens (depth 2), a routed chunk's counters
+        keyed = int(self._key is not None)
+        piped = int(self.dispatch_depth > 1)
+        routed = int(cfg.routed)
 
-        def _pick(logits, key, stats=None):
-            nxt = select_token(logits, key, self.temperature, self.top_k)
-            return nxt if stats is None else jnp.concatenate([nxt, stats])
+        def _pick(logits, keys, *stats):
+            """The step's token array (a routed model's counters appended:
+            one fetch brings both) and the successor of the key in ``keys``,
+            if any: split here, in the order the steps run."""
+            sub = None
+            if keys:
+                key, sub = jax.random.split(keys[0])
+                keys = (key,)
+            nxt = select_token(logits, sub, self.temperature, self.top_k)
+            return (jnp.concatenate([nxt, *stats]) if stats else nxt), *keys
 
         # params ride every step as an ARGUMENT (bound below): closed over,
         # they would be baked into each executable as constants — a copy of
@@ -570,54 +603,81 @@ class GenerationServer:
         # at real widths. The KV pools donate: they are pure in->out state,
         # so XLA updates them in place instead of copying hundreds of MB per
         # decode step.
-        def _decode(params, tok, lens, act, table, kp, vp, key):
+        def _decode(params, packed, kp, vp, *dev):
+            tok, lens, act, table = unpack_operands(packed, self.slots, pages)
+            tok = tok[:, 0]
+            if piped:  # a lane packed as -1 takes the previous step's token
+                prev, *dev = dev
+                tok = jnp.where(tok < 0, prev, tok)
             logits, kp, vp, *stats = paged_decode_step(
-                params, cfg, tok, lens, act, table, kp, vp,
+                params, cfg, tok, lens, act != 0, table, kp, vp,
                 return_logits=True, kv_sharding=kv_layer, **kern)
-            return _pick(logits, key, *stats), kp, vp
+            out, *key = _pick(logits, dev, *stats)
+            return out, kp, vp, *key
 
-        def _prefill(params, ids, lens, table, kp, vp, key):
+        def _prefill(params, packed, kp, vp, *key):
+            ids, _, lens, table = unpack_operands(packed, 1, pages)
             logits, kp, vp, *stats = paged_prefill(
                 params, cfg, ids, lens, table, kp, vp, return_logits=True,
                 kv_sharding=kv_layer, **kern)
-            return _pick(logits, key, *stats), kp, vp
+            out, *key = _pick(logits, key, *stats)
+            return out, kp, vp, *key
 
-        def _chunk(params, ids, off, clen, table, kp, vp, *so_far):
+        def _chunk(params, packed, kp, vp, *dev):
+            ids, off, clen, table = unpack_operands(packed, 1, pages)
             logits, kp, vp, *stats = paged_prefill_chunk(
                 params, cfg, ids, off, clen, table, kp, vp,
                 kv_sharding=kv_layer, **kern)
-            if so_far:  # a routed model: the prompt's counters so far
-                (pairs, hit, load), (acc,) = stats[0], so_far
-                logits = (logits, jnp.stack([
-                    acc[0] + pairs, acc[1] + hit, jnp.maximum(acc[2], load),
-                    acc[3] + 1]))
-            return logits, kp, vp
+            if stats:
+                # a routed model: the prompt's counters ride on the device
+                # behind the chunk before's token (``_no_counts`` at first)
+                so_far, *dev = dev
+                (pairs, hit, load), acc = stats[0], so_far[1:]
+                stats = [jnp.stack([acc[0] + pairs, acc[1] + hit,
+                                    jnp.maximum(acc[2], load), acc[3] + 1])]
+            out, *key = _pick(logits, dev, *stats)
+            return out, kp, vp, *key
 
-        def _verify(params, ids, off, clen, table, kp, vp):
-            return paged_prefill_chunk(params, cfg, ids, off, clen,
-                                       table, kp, vp, return_all=True,
-                                       kv_sharding=kv_layer, **kern)[:3]
+        def _verify(params, packed, kp, vp):
+            ids, lens, clen, table = unpack_operands(packed, self.slots, pages)
+            logits, kp, vp = paged_prefill_chunk(
+                params, cfg, ids, lens, clen, table, kp, vp, return_all=True,
+                kv_sharding=kv_layer, **kern)[:3]
+            return jnp.argmax(logits, axis=-1).astype(jnp.int32), kp, vp
 
-        def bind(fn, n_before: int, n_after: int):
-            """jit ``fn(params, *n_before args, kp, vp, *n_after args)`` with
-            the pools donated, and bind the current params."""
+        def bind(fn, n_dev: int, n_key: int):
+            """jit ``fn`` with the pools donated, bind the current params,
+            and count what the host hands it."""
             kw = {}
             if self.mesh is not None:
                 from arkflow_tpu.parallel.mesh import param_shardings
 
                 r, kv = self._repl_sharding, self._kv_io_sharding
                 kw = dict(
-                    in_shardings=(param_shardings(self.params),
-                                  *[r] * n_before, kv, kv, *[r] * n_after),
-                    out_shardings=(r, kv, kv))
-            jitted = jax.jit(
-                fn, donate_argnums=(1 + n_before, 2 + n_before), **kw)
-            return functools.partial(jitted, self.params)
+                    in_shardings=(param_shardings(self.params), r, kv, kv,
+                                  *[r] * n_dev),
+                    out_shardings=(r, kv, kv, *[r] * n_key))
+            jitted = jax.jit(fn, donate_argnums=(2, 3), **kw)
+            kind, params = fn.__name__[1:], self.params
 
-        self._decode = bind(_decode, 4, 1)
-        self._prefill = bind(_prefill, 3, 1)
-        self._chunk = bind(_chunk, 4, int(cfg.routed))
-        self._verify = bind(_verify, 4, 0)
+            def step(packed, kp, vp, *dev):
+                # the one place a step's operands are handed over: a host
+                # array goes up inside the call, placed by ``in_shardings``
+                self.m_uploads[kind].inc(sum(
+                    not isinstance(x, jax.Array) for x in (packed, *dev)))
+                return jitted(params, packed, kp, vp, *dev)
+
+            return step
+
+        self._decode = bind(_decode, piped + keyed, keyed)
+        self._prefill = bind(_prefill, keyed, keyed)
+        self._chunk = bind(_chunk, routed + keyed, keyed)
+        self._verify = bind(_verify, 0, 0)
+        #: device stand-ins: no step in flight (depth 2), a first chunk
+        zeros = functools.partial(jnp.zeros, dtype=jnp.int32,
+                                  device=self._repl_sharding)
+        self._no_prev = (zeros(self.slots),) if piped else ()
+        self._no_counts = zeros(5)
 
     def _note_moe(self, kind: str, stats, steps: int = 1) -> None:
         """Record the routing counters (``moe_step_stats``, on the host) of
@@ -800,27 +860,53 @@ class GenerationServer:
         if self._gen_inflight == 0 and self._gen_idle_since is None:
             self._gen_idle_since = time.monotonic()
 
-    async def _run_device_step(self, key: tuple, fn):
-        """One health-gated jitted call: the same admission gate pool
+    async def _run_device_step(self, key: tuple, packed, *dev,
+                               final: bool = True):
+        """One health-gated step of kind ``key[0]`` over its packed operands
+        (and ``dev``, already on the device): the same admission gate pool
         dispatch uses, a first-compile-aware deadline watchdog, and the
         chaos hook. A deadline miss marks the server UNHEALTHY, schedules a
         rebuild, and raises — the serve loop fails every in-flight request,
         so their batches nack for redelivery; the next step waits out the
-        probe backoff and runs as the recovery probe."""
+        probe backoff and runs as the recovery probe.
+
+        Returns the step's token array ON THE HOST (copied inside the same
+        executor hop). ``final=False``, a prompt's chunk before its last,
+        leaves it on the device and a sampling server's key where it was."""
         core = self.core
         await core.heal_gate()
         deadline = core.deadline_for(self._note_step(key))
+        keys = () if self._key is None else (self._key,)
 
-        def blocking():
+        # pools bound EAGERLY: a deadline-abandoned zombie step waking after
+        # a pool reset must consume the pools it already owned, never the
+        # fresh ones. The jitted fn resolves LAZILY at call time: the probe
+        # step must use the heal gate's rebuilt executable, not the cached one
+        def blocking(kp=self.k_pages, vp=self.v_pages):
             core.apply_chaos()
             # the jitted call only enqueues; named apart inside the hop's
             # gen_device_wait so a profile tells dispatch from waiting
             with annotated(f"gen_dispatch:{key[0]}"):
-                out = fn()
-            return jax.block_until_ready(out)
+                out = getattr(self, "_" + key[0])(packed, kp, vp, *dev, *keys)
+            if final:
+                out[0].copy_to_host_async()
+            jax.block_until_ready(out)
+            return (np.asarray(out[0]) if final else out[0]), *out[1:]
 
         self._track_gen_dispatch()
-        hop = _Hop(key[0])
+        tokens, self.k_pages, self.v_pages, *keys = await self._finish_step(
+            key[0], blocking, deadline)
+        if final and keys:
+            self._key = keys[0]
+        return tokens
+
+    async def _finish_step(self, kind: str, blocking, deadline,
+                           wait_s: float = 0.0, handoff_s: float = 0.0):
+        """Run ``blocking``, the call that ends one step, on an executor
+        thread under ``deadline`` (None: unwatched) and observe the step's
+        ``gen_device_wait`` / ``gen_handoff`` (plus an earlier hop's share)."""
+        core = self.core
+        hop = _Hop(kind)
         try:
             if deadline is None:
                 out = await asyncio.get_running_loop().run_in_executor(
@@ -837,9 +923,9 @@ class GenerationServer:
             # an abandoned step counts complete: the device stopped doing
             # useful work, and the reset path rebuilds from fresh pools
             self._track_gen_complete()
-        wait_s, handoff_s = hop.done()
-        observe_stage("gen_device_wait", wait_s)
-        observe_stage("gen_handoff", handoff_s)
+        wait, handoff = hop.done()
+        observe_stage("gen_device_wait", wait_s + wait)
+        observe_stage("gen_handoff", handoff_s + handoff)
         core.health.mark_success()
         return out
 
@@ -1085,11 +1171,13 @@ class GenerationServer:
 
     # -- scheduler ---------------------------------------------------------
 
-    def _table_array(self) -> jnp.ndarray:
-        table = np.zeros((self.slots, self.pages_per_slot), np.int32)
-        for s, pages in enumerate(self._slot_pages):
-            table[s, :len(pages)] = pages
-        return jnp.asarray(table)
+    def _table(self, *slots: int) -> np.ndarray:
+        """Page table rows of ``slots`` (default: all), padded to slot width."""
+        slots = slots or range(self.slots)
+        table = np.zeros((len(slots), self.pages_per_slot), np.int32)
+        for row, s in enumerate(slots):
+            table[row, :len(self._slot_pages[s])] = self._slot_pages[s]
+        return table
 
     def _bucket(self, n: int) -> int:
         for b in self.prompt_buckets:
@@ -1119,37 +1207,7 @@ class GenerationServer:
             # remainder is ever computed.
             self._prefill_pos[slot] = shared_len
             return
-        with loop_stage("gen_prepare", "prefill"):
-            bucket = self._bucket(n)
-            ids = np.zeros((1, bucket), np.int32)
-            ids[0, :n] = req.prompt
-            # single-row table padded to the slot width
-            table = np.zeros((1, self.pages_per_slot), np.int32)
-            table[0, :len(pages)] = pages
-            self._key, sub = jax.random.split(self._key)
-        # off-loop + gated: first call per bucket compiles (seconds on TPU)
-        # pools bound EAGERLY: a deadline-abandoned zombie step waking after
-        # a pool reset must consume the pools it already owned, never the
-        # fresh ones. The jitted fn resolves LAZILY at call time: the heal
-        # gate's rebuild runs before the probe step executes, and the probe
-        # must use the rebuilt executable, not the distrusted cached one.
-        # (Same for the other three step kinds below.)
-        nxt, self.k_pages, self.v_pages = await self._run_device_step(
-            ("prefill", bucket),
-            lambda kp=self.k_pages, vp=self.v_pages: self._prefill(
-                jnp.asarray(ids), jnp.asarray([n], jnp.int32), jnp.asarray(table),
-                kp, vp, sub))
-        with loop_stage("gen_apply", "prefill"):
-            req.chunks = 1
-            self._lengths[slot] = n
-            if self._moe_layers:
-                nxt = np.asarray(nxt)  # one fetch: the token, then counters
-                self._note_moe("prefill", nxt[1:])
-            self._cur_tokens[slot] = int(nxt[0])
-            if not req.prefill_only:
-                self._handle_token(slot, int(nxt[0]))
-                return
-        await self._export_and_finish(slot)
+        await self._prefill_step(slot, "prefill")
 
     async def _admit_adopted(self, slot: int, req: _Request) -> None:
         """Seed the slot from a received KV-page export: upload the slabs
@@ -1243,52 +1301,42 @@ class GenerationServer:
             req.future.set_result(
                 req.tokens if req.export is None else req.export)
 
-    async def _prefill_one_chunk(self, slot: int) -> None:
-        """One fixed-size prefill chunk for an admitting slot (one device
-        call); seeds the slot for decode after the final chunk."""
+    async def _prefill_step(self, slot: int, kind: str = "chunk") -> None:
+        """One prefill step for an admitting slot (one device call): a chunk
+        of its prompt, or (``kind`` "prefill") all of it in one bucketed
+        step; seeds the slot for decode after the prompt's last token."""
         req = self._slot_req[slot]
         if req is None:
             self._prefill_pos.pop(slot, None)
             return
-        with loop_stage("gen_prepare", "chunk"):
-            off = self._prefill_pos[slot]
+        with loop_stage("gen_prepare", kind):
+            off = self._prefill_pos.get(slot, 0)
             n = len(req.prompt)
-            # chunk width: the configured chunk size, or (prefix-cache
-            # remainder with chunking off) one bucketed span covering the rest
-            c = (self.prefill_chunk if self.prefill_chunk
+            # width: the configured chunk, or one bucketed span over the rest
+            # (one-shot; a prefix-cache remainder with chunking off)
+            c = (self.prefill_chunk if kind == "chunk" and self.prefill_chunk
                  else self._bucket(n - off))
             chunk = req.prompt[off:off + c]
-            ids = np.zeros((1, c), np.int32)
-            ids[0, :len(chunk)] = chunk
-            table = np.zeros((1, self.pages_per_slot), np.int32)
-            table[0, :len(self._slot_pages[slot])] = self._slot_pages[slot]
-            so_far = () if not self._moe_layers else (
-                np.zeros((4,), np.int32) if req.chunk_moe is None
-                else req.chunk_moe,)
-        logits, self.k_pages, self.v_pages = await self._run_device_step(
-            ("chunk", c),
-            lambda kp=self.k_pages, vp=self.v_pages, so_far=so_far: self._chunk(
-                jnp.asarray(ids), jnp.asarray([off], jnp.int32),
-                jnp.asarray([len(chunk)], jnp.int32), jnp.asarray(table),
-                kp, vp, *so_far))
-        with loop_stage("gen_apply", "chunk"):
-            if self._moe_layers:
-                logits, req.chunk_moe = logits  # both stay on the device
-            req.chunks += 1
+            ids = np.zeros(c, np.int32)
+            ids[:len(chunk)] = chunk
+            packed = pack_operands(ids, off, len(chunk), self._table(slot))
             new_off = off + len(chunk)
+            so_far = () if kind != "chunk" or not self._moe_layers else (
+                self._no_counts if req.chunk_moe is None else req.chunk_moe,)
+        # off-loop + gated; only the prompt's last step's token is fetched
+        nxt = await self._run_device_step(
+            (kind, c), packed, *so_far, final=new_off >= n)
+        with loop_stage("gen_apply", kind):
+            req.chunks += 1
             if new_off < n:
                 self._prefill_pos[slot] = new_off
+                req.chunk_moe = nxt  # stays on the device (routed: counters)
                 return
-            # final chunk: sample the first generated token and join decode
-            del self._prefill_pos[slot]
-            from arkflow_tpu.models.decoder import select_token
-
-            self._key, sub = jax.random.split(self._key)
-            nxt = select_token(logits, sub, self.temperature, self.top_k)
+            # prefilled: the one fetch seeds decode (token, then counters)
+            self._prefill_pos.pop(slot, None)
             if self._moe_layers:
-                # one fetch: the token, then the counters of all its chunks
-                nxt = np.asarray(jnp.concatenate([nxt, req.chunk_moe]))
-                self._note_moe("chunk", nxt[1:], steps=int(nxt[4]))
+                self._note_moe(kind, nxt[1:],
+                               int(nxt[4]) if kind == "chunk" else 1)
             self._lengths[slot] = n
             self._cur_tokens[slot] = int(nxt[0])
             if not req.prefill_only:
@@ -1433,7 +1481,7 @@ class GenerationServer:
                 if prefilling and (not active or self._turn_prefill):
                     self._turn_prefill = False
                     await self._drain_pipeline()
-                    await self._prefill_one_chunk(prefilling[0])
+                    await self._prefill_step(prefilling[0])
                     continue
                 self._turn_prefill = True
                 if self.speculative_tokens > 0:
@@ -1502,21 +1550,17 @@ class GenerationServer:
         return admitted
 
     async def _step(self, active: list[int]) -> None:
-        """One lockstep decode over all slots (inactive lanes masked).
-
-        At ``dispatch_depth`` 2 the pipelined path runs instead: step N+1
-        is dispatched from step N's device-resident tokens before N's
-        outputs reach the host, then N is applied — host bookkeeping and
-        device compute overlap. Cold/recovering states (first compile,
-        probe steps, page-pool pressure) fall back to this classic path."""
+        """One lockstep decode over all slots (inactive lanes masked). At
+        ``dispatch_depth`` 2 the pipelined path runs instead; cold or
+        recovering states (first compile, probe steps, page-pool pressure)
+        fall back to this classic path."""
         if self.dispatch_depth > 1 and await self._step_pipelined(active):
             return
         await self._drain_pipeline()
         # the drains above may have APPLIED a pending step whose tokens
         # finished requests in `active` (slot freed, pages returned):
-        # recompute from host truth, or _reserve_or_truncate would feed a
-        # ghost lane — allocating a page the next admission leaks, or
-        # truncating a live request to serve a slot with no request
+        # recompute, or _reserve_or_truncate would feed a ghost lane — a page
+        # the next admission leaks, or a live request truncated for no one
         active = [s for s in active if self._slot_req[s] is not None]
         if not active:
             return
@@ -1525,46 +1569,41 @@ class GenerationServer:
             act[active] = True
             for s in active:
                 self._reserve_or_truncate(s, act)
-            cur = jnp.asarray(self._cur_tokens)
-            lens = jnp.asarray(self._lengths)
-            act_dev = jnp.asarray(act)
-            table = self._table_array()
-            self._key, sub = jax.random.split(self._key)
+            packed = pack_operands(self._cur_tokens, self._lengths, act,
+                                   self._table())
         # off-loop + gated: one device-step of wall time (plus first compile)
-        nxt, self.k_pages, self.v_pages = await self._run_device_step(
-            ("decode",),
-            lambda kp=self.k_pages, vp=self.v_pages: self._decode(
-                cur, lens, act_dev, table, kp, vp, sub))
+        self._apply_decode(act, await self._run_device_step(
+            ("decode",), packed, *self._no_prev))
+
+    def _apply_decode(self, act, nxt, reqs=None) -> None:
+        """One decode step's fetched tokens (then a routed model's counters)
+        onto host state. A lane whose request is no longer the one in
+        ``reqs`` (a pipelined dispatch's snapshot) drops its token."""
         with loop_stage("gen_apply", "decode"):
             self.m_steps.inc()
-            nxt_host = np.asarray(nxt)
             if self._moe_layers:
-                self._note_moe("decode", nxt_host[self.slots:])
-            for s in range(self.slots):
-                if not act[s] or self._slot_req[s] is None:
+                self._note_moe("decode", nxt[self.slots:])
+            for s in map(int, np.flatnonzero(act)):
+                req = self._slot_req[s]
+                if req is None or (reqs is not None and req is not reqs[s]):
                     continue
                 self._lengths[s] += 1
-                self._cur_tokens[s] = nxt_host[s]
-                self._handle_token(s, int(nxt_host[s]))
+                self._cur_tokens[s] = nxt[s]
+                self._handle_token(s, int(nxt[s]))
 
     # -- pipelined dispatch (dispatch_depth 2) -------------------------------
 
     async def _step_pipelined(self, active: list[int]) -> bool:
-        """Dispatch decode step N+1, THEN apply the in-flight step N.
+        """Dispatch decode step N+1, THEN apply the in-flight step N: the
+        dispatch consumes N's un-fetched next-token array ON the device, so
+        the device queue always holds the successor before the host fetches,
+        and page accounting / EOS checks overlap device compute.
 
-        The data dependency between consecutive decode steps (next step's
-        token ids are this step's outputs) is left ON the device: the
-        dispatch consumes the in-flight step's un-fetched next-token array,
-        so the device queue always holds the successor before the host
-        fetches, and host-side page accounting / EOS checks overlap device
-        compute instead of serializing with it.
-
-        What the host cannot know one step early is EOS: a lane whose
-        pending token turns out to be EOS still rides the speculative
-        dispatch; its token is dropped at apply (request identity is
-        snapshotted). Budget exhaustion IS host-known, so those lanes are
-        masked out up front. Greedy-only (validated at construction), so
-        the emitted token streams are bitwise identical to depth 1.
+        EOS is what the host cannot know one step early: such a lane still
+        rides the dispatch and its token is dropped at apply (request
+        identity is snapshotted). Budget exhaustion IS host-known, so those
+        lanes are masked out up front. Greedy-only (validated at
+        construction): token streams are bitwise identical to depth 1.
 
         Returns False when the classic path should run instead: cold
         decode jit (first-compile budget), non-HEALTHY core (probe steps
@@ -1607,12 +1646,12 @@ class GenerationServer:
                     for s in np.flatnonzero(act)):
                 bail = False  # classic path owns the truncation policy
             else:
-                cur = (pend.nxt if pend is not None
-                       else jnp.asarray(self._cur_tokens))
-                lens = jnp.asarray(eff_lens)
-                act_dev = jnp.asarray(act)
-                table = self._table_array()
-                self._key, sub = jax.random.split(self._key)
+                # the in-flight step's tokens stay on the device: its
+                # lanes are packed as -1 and take them there
+                prev = self._no_prev[0] if pend is None else pend.nxt
+                cur = (self._cur_tokens if pend is None
+                       else np.full(self.slots, -1, np.int32))
+                packed = pack_operands(cur, eff_lens, act, self._table())
         if bail is not None:
             await self._drain_pipeline()
             return bail
@@ -1622,9 +1661,11 @@ class GenerationServer:
 
         # pools bound eagerly (same zombie discipline as the classic path);
         # the dispatch only ENQUEUES — the jit returns device futures, all
-        # waiting happens in _apply_pipeline under the per-step deadline
+        # waiting happens in _drain_pipeline under the per-step deadline
         def enqueue(kp=self.k_pages, vp=self.v_pages):
-            return self._decode(cur, lens, act_dev, table, kp, vp, sub)
+            out = self._decode(packed, kp, vp, prev)
+            out[0].copy_to_host_async()  # lands while the step still runs
+            return out
 
         hop = _Hop("decode")
         nxt, self.k_pages, self.v_pages = await loop.run_in_executor(
@@ -1635,14 +1676,11 @@ class GenerationServer:
                               wait_s=wait_s, handoff_s=handoff_s)
         self._pipelined_dispatches += 1
         if pend is not None:
-            self._pipeline = None
-            await self._apply_pipeline(pend)
-            # honest idle accounting under pipelining: the in-flight count
-            # alone can't see a drained device (one step is always nominally
-            # in flight). If the successor's outputs are ALREADY computed,
-            # the device finished its whole queue during our apply and sits
-            # idle until the next enqueue — open the idle window so the gap
-            # records instead of silently reading as perfect overlap.
+            await self._drain_pipeline()
+            # honest idle accounting: one step is always nominally in
+            # flight, so the count can't see a drained device. If the
+            # successor's outputs are ALREADY computed, the device sits idle
+            # until the next enqueue — open the idle window so the gap records
             if self._gen_idle_since is None:
                 try:
                     drained = bool(rec.nxt.is_ready())
@@ -1654,57 +1692,23 @@ class GenerationServer:
         return True
 
     async def _drain_pipeline(self) -> None:
-        """Fetch + apply the in-flight decode step, if any: every non-decode
-        event (admission prefill, chunked prefill, speculative steps, swap
-        drain, loop exit) runs against caught-up host state."""
+        """Fetch + apply the in-flight decode step, if any (deadlined from
+        ITS dispatch): every non-decode event (prefill, speculative steps,
+        swap drain, loop exit) runs against caught-up host state."""
         if self._pipeline is None:
             return
-        pend, self._pipeline = self._pipeline, None
-        await self._apply_pipeline(pend)
-
-    async def _apply_pipeline(self, rec: _InFlightDecode) -> None:
-        """Fetch one in-flight step's tokens (deadlined from ITS dispatch
-        time — serving_core.deadline_remaining) and apply them to host
-        state. A lane whose request finished or was replaced since dispatch
-        drops its token (wasted compute, never wrong tokens)."""
+        rec, self._pipeline = self._pipeline, None
         core = self.core
 
         def blocking():
             core.apply_chaos()
-            return np.asarray(jax.device_get(rec.nxt))
+            return np.asarray(rec.nxt)
 
         deadline = core.deadline_for(False)  # pipelined steps are warm
-        hop = _Hop("decode")
-        try:
-            if deadline is None:
-                nxt_host = await asyncio.get_running_loop().run_in_executor(
-                    None, hop.run, blocking)
-            else:
-                nxt_host = await core.run_deadlined(
-                    functools.partial(hop.run, blocking),
-                    core.deadline_remaining(deadline, rec.dispatched_at))
-        except StepDeadlineExceeded:
-            raise  # core marked UNHEALTHY; the serve loop fails + resets
-        except Exception as e:
-            core.health.mark_unhealthy(f"generate step failed: {e}")
-            raise
-        finally:
-            self._track_gen_complete()
-        wait_s, handoff_s = hop.done()
-        observe_stage("gen_device_wait", rec.wait_s + wait_s)
-        observe_stage("gen_handoff", rec.handoff_s + handoff_s)
-        core.health.mark_success()
-        with loop_stage("gen_apply", "decode"):
-            self.m_steps.inc()
-            for s in range(self.slots):
-                if not rec.act[s]:
-                    continue
-                req = self._slot_req[s]
-                if req is None or req is not rec.reqs[s]:
-                    continue
-                self._lengths[s] += 1
-                self._cur_tokens[s] = nxt_host[s]
-                self._handle_token(s, int(nxt_host[s]))
+        if deadline is not None:
+            deadline = core.deadline_remaining(deadline, rec.dispatched_at)
+        self._apply_decode(rec.act, await self._finish_step(
+            "decode", blocking, deadline, rec.wait_s, rec.handoff_s), rec.reqs)
 
     # -- speculative decode -------------------------------------------------
 
@@ -1754,20 +1758,16 @@ class GenerationServer:
                 ids[s, 0] = self._cur_tokens[s]
                 if c > 1:
                     ids[s, 1:c] = self._draft(req, c - 1)
-            table = self._table_array()
-        logits, self.k_pages, self.v_pages = await self._run_device_step(
-            ("verify", k),
-            lambda kp=self.k_pages, vp=self.v_pages: self._verify(
-                jnp.asarray(ids), jnp.asarray(self._lengths),
-                jnp.asarray(clen), table, kp, vp))
+            packed = pack_operands(ids, self._lengths, clen, self._table())
+        # the program scores every position and picks its argmax there
+        picked = await self._run_device_step(("verify", k), packed)
         with loop_stage("gen_apply", "verify"):
             self.m_steps.inc()
-            lg = np.asarray(logits)
-            for s in range(self.slots):
-                if not act[s] or self._slot_req[s] is None or clen[s] == 0:
+            for s in map(int, np.flatnonzero(clen)):
+                if self._slot_req[s] is None:
                     continue
                 c = int(clen[s])
-                outs = lg[s, :c].argmax(-1).astype(np.int32)
+                outs = picked[s, :c]
                 accepted = 0
                 while accepted < c - 1 and ids[s, accepted + 1] == outs[accepted]:
                     accepted += 1

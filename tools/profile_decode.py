@@ -86,7 +86,7 @@ def _child(n: int) -> None:
 
     from arkflow_tpu.models import get_model
     from arkflow_tpu.parallel.mesh import MeshSpec, create_mesh, shard_params
-    from arkflow_tpu.tpu.serving import GenerationServer
+    from arkflow_tpu.tpu.serving import GenerationServer, pack_operands
 
     tiny = os.environ.get("PROF_TINY", "1") == "1"
     slots = int(os.environ.get("PROF_SLOTS", "8"))
@@ -118,16 +118,13 @@ def _child(n: int) -> None:
         for s in range(slots):
             table[s, :pages_per] = np.arange(
                 1 + s * pages_per, 1 + (s + 1) * pages_per)
-        tok = jnp.zeros((slots,), jnp.int32)
-        lens = jnp.full((slots,), ctx, jnp.int32)
-        act = jnp.ones((slots,), bool)
-        tbl = jnp.asarray(table)
-        key = jax.random.PRNGKey(1)
+        packed = jnp.asarray(pack_operands(
+            np.zeros(slots), np.full(slots, ctx), np.ones(slots), table))
         kp, vp = srv.k_pages, srv.v_pages
 
         def step():
             nonlocal kp, vp
-            nxt, kp, vp = srv._decode(tok, lens, act, tbl, kp, vp, key)
+            nxt, kp, vp = srv._decode(packed, kp, vp)
             jax.block_until_ready(nxt)
 
         step()  # compile
