@@ -14,6 +14,7 @@ Defaults are a small test shape; ``llama3_8b()`` gives the production shape.
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from functools import partial
 from typing import Optional
@@ -24,6 +25,34 @@ from jax.sharding import PartitionSpec as P
 
 from arkflow_tpu.models import common as cm
 from arkflow_tpu.models.registry import ModelFamily, register_model
+
+
+FULL, SLIDING = "full_attention", "sliding_attention"
+
+
+@dataclass(frozen=True)
+class AttnSpec:
+    """What one kind of latent-attention layer is made of (``DecoderConfig.
+    attn``): a full layer reads the model's own keys, a sliding layer its
+    ``swa_*`` keys. ``window`` > 0 bounds the keys below (the last
+    ``window`` positions, the query's own included); ``index_topk`` > 0
+    selects them by the layer's indexer."""
+    kind: str
+    dim: int
+    norm_eps: float
+    heads: int
+    q_lora_rank: Optional[int]
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    rope_theta: float
+    gate: bool = False
+    rescale: bool = False
+    window: int = 0
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
 
 
 @dataclass(frozen=True)
@@ -66,8 +95,8 @@ class DecoderConfig:
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
-    #: a low-rank query projection is not implemented: only None (a plain
-    #: ``dim -> heads * qk`` product) is accepted
+    #: a low-rank query: ``cq = RMSNorm(y W_qa)`` of this width, then
+    #: ``q = cq W_qb``; None is a plain ``dim -> heads * qk`` product
     q_lora_rank: Optional[int] = None
     #: rotate the pairs (2i, 2i+1) instead of (i, i + d/2): what a latent
     #: model does, and only a latent model (any other value raises)
@@ -96,10 +125,45 @@ class DecoderConfig:
     topk_method: str = "noaux_tc"
     n_group: int = 1
     topk_group: int = 1
+    #: the experts this chip holds of every expert layer, ``(first, count)``
+    #: (expert parallelism's share): the router keeps ``n_routed_experts``
+    #: outputs and ``num_experts_per_tok`` choices, weights are normalised
+    #: over all the chosen, and the layer computes its own experts' part of
+    #: the result; what absent experts would add is left out. None: all
+    experts_held: Optional[tuple] = None
+    # -- a layer pattern over latent layers, under the published key names.
+    # ``layer_types`` names each layer ``full_attention`` or
+    # ``sliding_attention`` (the first ``layers`` entries are read; None: all
+    # full). A sliding layer attends the last ``sliding_window`` positions,
+    # itself included, and has sizes of its own (``swa_*``; its rope key
+    # width may differ too); a full layer with ``index_topk`` > 0 attends the
+    # ``index_topk`` positions its indexer scores highest (``index_n_heads``
+    # heads of ``index_head_dim``, one index key a token cached beside the
+    # latent row). ``attention_gate_type`` "headwise" scales each head's
+    # output by ``sigmoid(y W_g)``; ``apply_mla_qkv_lora_rescale`` scales the
+    # normed query / key-value latents by ``sqrt(dim / rank)``.
+    layer_types: Optional[tuple] = None
+    sliding_window: int = 0
+    swa_heads: int = 0
+    swa_q_lora_rank: Optional[int] = None
+    swa_kv_lora_rank: int = 0
+    swa_qk_nope_head_dim: int = 0
+    swa_qk_rope_head_dim: int = 0
+    swa_v_head_dim: int = 0
+    swa_rope_theta: float = 0.0
+    swa_attention_gate_type: str = ""
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+    attention_gate_type: str = ""
+    apply_mla_qkv_lora_rescale: bool = False
 
     def __post_init__(self):
         from arkflow_tpu.errors import ConfigError
 
+        for name in ("layer_types", "experts_held"):  # JSON lists: hashable
+            if isinstance(getattr(self, name), list):
+                object.__setattr__(self, name, tuple(getattr(self, name)))
         if self.latent:
             if min(self.qk_nope_head_dim, self.qk_rope_head_dim,
                    self.v_head_dim) <= 0 or self.qk_rope_head_dim % 2:
@@ -107,10 +171,10 @@ class DecoderConfig:
                     "kv_lora_rank > 0 (latent attention) needs "
                     "qk_nope_head_dim, v_head_dim and an even "
                     "qk_rope_head_dim")
-            if self.q_lora_rank is not None:
+            if self.q_lora_rank is not None and self.q_lora_rank <= 0:
                 raise ConfigError(
-                    "latent attention with a low-rank query projection "
-                    f"(q_lora_rank={self.q_lora_rank}) is not implemented")
+                    "q_lora_rank is None (a plain query projection) or the "
+                    f"width of the query latent, got {self.q_lora_rank}")
             if self.use_ring_attention or self.num_experts > 1:
                 raise ConfigError(
                     "latent attention composes with neither ring attention "
@@ -138,14 +202,114 @@ class DecoderConfig:
                     "group-limited selection) and norm_topk_prob true; got "
                     f"{self.scoring_func!r}, {self.topk_method!r}, "
                     f"{self.n_group}, {self.topk_group}, {self.norm_topk_prob}")
+        if self.experts_held is not None:
+            first, count = (tuple(self.experts_held) + (0, 0))[:2]
+            if not (self.routed and len(self.experts_held) == 2
+                    and 0 <= first and 0 < count
+                    and first + count <= self.n_routed_experts):
+                raise ConfigError(
+                    "experts_held is (first, count) within n_routed_experts "
+                    f"of a routed model, got {self.experts_held}")
+        self._check_layer_pattern()
+
+    def _check_layer_pattern(self) -> None:
+        from arkflow_tpu.errors import ConfigError
+
+        extras = (self.sliding_window, self.swa_heads, self.swa_kv_lora_rank,
+                  self.index_topk, self.index_n_heads, self.index_head_dim)
+        gates = (self.attention_gate_type, self.swa_attention_gate_type)
+        if not self.latent:
+            if (self.layer_types is not None or any(extras) or any(gates)
+                    or self.apply_mla_qkv_lora_rescale):
+                raise ConfigError(
+                    "layer_types, sliding_window / swa_*, index_*, the "
+                    "attention gate and the latent rescale belong to a "
+                    "latent-attention model (kv_lora_rank > 0)")
+            return
+        if any(g not in ("", "headwise") for g in gates):
+            raise ConfigError(
+                f"attention_gate_type is '' or 'headwise', got {gates}")
+        kinds = self.kinds
+        if len(kinds) != self.layers or set(kinds) - {FULL, SLIDING}:
+            raise ConfigError(
+                f"layer_types names each of the {self.layers} layers "
+                f"{FULL!r} or {SLIDING!r}, got {self.layer_types}")
+        if SLIDING in kinds:
+            if (min(self.sliding_window, self.swa_heads, self.swa_kv_lora_rank,
+                    self.swa_qk_nope_head_dim, self.swa_qk_rope_head_dim,
+                    self.swa_v_head_dim) <= 0 or self.swa_rope_theta <= 0
+                    or self.swa_qk_rope_head_dim % 2
+                    or (self.swa_q_lora_rank is not None
+                        and self.swa_q_lora_rank <= 0)):
+                raise ConfigError(
+                    "a sliding_attention layer needs sliding_window, "
+                    "swa_heads, swa_kv_lora_rank, swa_qk_nope_head_dim, an "
+                    "even swa_qk_rope_head_dim, swa_v_head_dim and "
+                    "swa_rope_theta (swa_q_lora_rank None or > 0)")
+        elif self.sliding_window or self.swa_heads or self.swa_kv_lora_rank:
+            raise ConfigError("sliding_window / swa_* without a "
+                              "sliding_attention layer in layer_types")
+        if self.index_topk:
+            if (min(self.index_n_heads, self.index_topk) <= 0
+                    or self.index_head_dim < self.qk_rope_head_dim
+                    or self.q_lora_rank is None):
+                raise ConfigError(
+                    "index_topk > 0 needs index_n_heads, index_head_dim >= "
+                    "qk_rope_head_dim and q_lora_rank (the indexer's queries "
+                    "are projected from the query latent)")
+        elif self.index_n_heads or self.index_head_dim:
+            raise ConfigError("index_n_heads / index_head_dim without index_topk")
 
     @property
     def latent(self) -> bool:
         return self.kv_lora_rank > 0
 
     @property
+    def kinds(self) -> tuple:
+        """Each layer's attention kind (all full without ``layer_types``)."""
+        if self.layer_types is None:
+            return (FULL,) * self.layers
+        return tuple(self.layer_types[:self.layers])
+
+    @property
+    def layered(self) -> bool:
+        """True where the cache is not one row shape for every layer: a
+        sliding layer's window pool or an indexed layer's index keys."""
+        return SLIDING in self.kinds or self.index_topk > 0
+
+    def attn(self, kind: str) -> "AttnSpec":
+        """The sizes of one kind of latent layer, under the names the
+        ``mla_*`` functions read (the model's own keys for a full layer)."""
+        if kind == SLIDING:
+            return AttnSpec(
+                kind=kind, dim=self.dim, norm_eps=self.norm_eps,
+                heads=self.swa_heads, q_lora_rank=self.swa_q_lora_rank,
+                kv_lora_rank=self.swa_kv_lora_rank,
+                qk_nope_head_dim=self.swa_qk_nope_head_dim,
+                qk_rope_head_dim=self.swa_qk_rope_head_dim,
+                v_head_dim=self.swa_v_head_dim, rope_theta=self.swa_rope_theta,
+                gate=self.swa_attention_gate_type == "headwise",
+                rescale=self.apply_mla_qkv_lora_rescale,
+                window=self.sliding_window)
+        return AttnSpec(
+            kind=kind, dim=self.dim, norm_eps=self.norm_eps, heads=self.heads,
+            q_lora_rank=self.q_lora_rank, kv_lora_rank=self.kv_lora_rank,
+            qk_nope_head_dim=self.qk_nope_head_dim,
+            qk_rope_head_dim=self.qk_rope_head_dim, v_head_dim=self.v_head_dim,
+            rope_theta=self.rope_theta,
+            gate=self.attention_gate_type == "headwise",
+            rescale=self.apply_mla_qkv_lora_rescale,
+            index_n_heads=self.index_n_heads,
+            index_head_dim=self.index_head_dim, index_topk=self.index_topk)
+
+    @property
     def routed(self) -> bool:
         return self.n_routed_experts > 0
+
+    @property
+    def held(self) -> tuple:
+        """(first, count) of the routed experts this chip holds."""
+        return tuple(self.experts_held or (0, self.n_routed_experts))
 
     @property
     def dense_layers(self) -> int:
@@ -165,34 +329,58 @@ def llama3_8b() -> DecoderConfig:
     )
 
 
-def _init_latent_layer(key, cfg: DecoderConfig, routed: bool) -> dict:
+def _init_latent_layer(key, cfg: DecoderConfig, routed: bool,
+                       kind: str = FULL) -> dict:
     """One layer of a latent-attention model: the MLA projections (HF names:
-    q_proj, kv_a_proj_with_mqa, kv_a_layernorm, kv_b_proj, o_proj) and
-    either a dense SwiGLU or the routed experts. ``experts`` holds the
-    routed experts FIRST and the shared experts after them, each of width
-    ``moe_intermediate_size``: a shared MLP of ``n_shared_experts`` times
-    that width is the sum of so many SwiGLUs of one width, and one stacked
-    tensor lets one product (``ops/moe_experts``) serve both."""
+    q_proj — or q_a_proj, q_a_layernorm, q_b_proj with a low-rank query —,
+    kv_a_proj_with_mqa, kv_a_layernorm, kv_b_proj, o_proj) at the sizes of
+    its ``kind``, the headwise gate and the indexer where the kind has them,
+    and either a dense SwiGLU or the routed experts. ``experts`` holds the
+    routed experts HELD here first and the shared experts after them, each
+    of width ``moe_intermediate_size``: a shared MLP of ``n_shared_experts``
+    times that width is the sum of so many SwiGLUs of one width, and one
+    stacked tensor lets one product (``ops/moe_experts``) serve both."""
     k = iter(jax.random.split(key, 12))
-    qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+    sp = cfg.attn(kind)
+    qk = sp.qk_nope_head_dim + sp.qk_rope_head_dim
     layer = {
         "attn_norm": cm.rms_norm_init(cfg.dim),
-        "wq": cm.dense_init(next(k), cfg.dim, cfg.heads * qk, bias=False),
+        "wq": cm.dense_init(next(k), sp.q_lora_rank or cfg.dim, sp.heads * qk,
+                            bias=False),
         "wkv_a": cm.dense_init(next(k), cfg.dim,
-                               cfg.kv_lora_rank + cfg.qk_rope_head_dim, bias=False),
-        "kv_norm": cm.rms_norm_init(cfg.kv_lora_rank),
+                               sp.kv_lora_rank + sp.qk_rope_head_dim, bias=False),
+        "kv_norm": cm.rms_norm_init(sp.kv_lora_rank),
         "wkv_b": cm.dense_init(
-            next(k), cfg.kv_lora_rank,
-            cfg.heads * (cfg.qk_nope_head_dim + cfg.v_head_dim), bias=False),
-        "wo": cm.dense_init(next(k), cfg.heads * cfg.v_head_dim, cfg.dim, bias=False),
+            next(k), sp.kv_lora_rank,
+            sp.heads * (sp.qk_nope_head_dim + sp.v_head_dim), bias=False),
+        "wo": cm.dense_init(next(k), sp.heads * sp.v_head_dim, cfg.dim, bias=False),
         "mlp_norm": cm.rms_norm_init(cfg.dim),
     }
+    # what a layer pattern adds draws from keys of its own (fold_in): the
+    # leaves above keep the values they had before there were kinds
+    extra = (jax.random.fold_in(key, 100 + i) for i in range(8))
+    if sp.q_lora_rank:
+        layer["wq_a"] = cm.dense_init(next(extra), cfg.dim, sp.q_lora_rank, bias=False)
+        layer["q_norm"] = cm.rms_norm_init(sp.q_lora_rank)
+    if sp.gate:
+        layer["w_head_gate"] = cm.dense_init(next(extra), cfg.dim, sp.heads, bias=False)
+    if sp.index_topk:
+        # the indexer (DeepSeek-V3.2: wq_b, wk + k_norm, weights_proj): it
+        # SELECTS, as the router does, so its leaves are float32 as served
+        layer["index_wq"] = cm.dense_init(
+            next(extra), sp.q_lora_rank, sp.index_n_heads * sp.index_head_dim,
+            bias=False)
+        layer["index_wk"] = cm.dense_init(next(extra), cfg.dim, sp.index_head_dim,
+                                          bias=False)
+        layer["index_k_norm"] = cm.layer_norm_init(sp.index_head_dim)
+        layer["index_w"] = cm.dense_init(next(extra), cfg.dim, sp.index_n_heads,
+                                         bias=False)
     if not routed:
         layer["w_gate"] = cm.dense_init(next(k), cfg.dim, cfg.ffn, bias=False)
         layer["w_up"] = cm.dense_init(next(k), cfg.dim, cfg.ffn, bias=False)
         layer["w_down"] = cm.dense_init(next(k), cfg.ffn, cfg.dim, bias=False)
         return layer
-    e = cfg.n_routed_experts + cfg.n_shared_experts
+    e = cfg.held[1] + cfg.n_shared_experts
     f = cfg.moe_intermediate_size
     up, down = 1.0 / (cfg.dim ** 0.5), 1.0 / (f ** 0.5)
     layer["router"] = cm.dense_init(next(k), cfg.dim, cfg.n_routed_experts, bias=False)
@@ -213,6 +401,31 @@ def _init_latent_layer(key, cfg: DecoderConfig, routed: bool) -> dict:
     return layer
 
 
+#: the stack a layer's parameters live in, by (kind, routed?): layers of one
+#: shape stack on a leading axis
+_STACKS = {(FULL, False): "dense_layers", (FULL, True): "layers",
+           (SLIDING, False): "swa_dense_layers", (SLIDING, True): "swa_layers"}
+
+
+def layer_runs(cfg: DecoderConfig) -> list:
+    """The model's layers in order as runs of one shape: each ``(stack name,
+    first, stop, kind, routed?, kind_first)`` — layers ``first..stop`` of
+    that stack, ``kind_first`` the index of the run's first layer among the
+    layers of its kind (the cache pools' layer axis). A model without a
+    pattern has ``dense_layers`` then ``layers``, each whole."""
+    runs, in_stack, of_kind = [], {}, {}
+    for i, kind in enumerate(cfg.kinds):
+        routed = cfg.routed and i >= cfg.first_k_dense_replace
+        name = _STACKS[kind, routed] if cfg.latent else "layers"
+        at, kat = in_stack.get(name, 0), of_kind.get(kind, 0)
+        if runs and runs[-1][0] == name:
+            runs[-1][2] = at + 1
+        else:
+            runs.append([name, at, at + 1, kind, routed, kat])
+        in_stack[name], of_kind[kind] = at + 1, kat + 1
+    return [tuple(r) for r in runs]
+
+
 def _init_latent(rng, cfg: DecoderConfig) -> dict:
     keys = iter(jax.random.split(rng, 2 + cfg.layers))
     params = {
@@ -220,20 +433,29 @@ def _init_latent(rng, cfg: DecoderConfig) -> dict:
         "norm_out": cm.rms_norm_init(cfg.dim),
         "lm_head": cm.dense_init(next(keys), cfg.dim, cfg.vocab_size, bias=False),
     }
-    for name, n, routed in (("dense_layers", cfg.dense_layers, False),
-                            ("layers", cfg.expert_layers, True)):
-        stack = [_init_latent_layer(next(keys), cfg, routed) for _ in range(n)]
+    stacks: dict = {}
+    for name, first, stop, kind, routed, _ in layer_runs(cfg):
+        stacks.setdefault(name, []).extend(
+            _init_latent_layer(next(keys), cfg, routed, kind)
+            for _ in range(first, stop))
+    for name, stack in stacks.items():
         params[name] = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *stack)
     return params
 
 
 def layer_stacks(params: dict, cfg: DecoderConfig) -> list:
-    """The model's layer stacks in order, as (stacked params, routed?): one
-    for every model but a latent one, which has ``dense_layers`` and then
-    ``layers`` (the expert stack)."""
-    if cfg.routed:
-        return [(params["dense_layers"], False), (params["layers"], True)]
-    return [(params["layers"], False)]
+    """The model's layer runs in order, as (stacked params of the run,
+    routed?, kind, index of its first layer among its kind's): a dense model
+    has one, a latent one ``dense_layers`` and then ``layers`` (the expert
+    stack), a layer pattern as many as its kinds alternate. A run that is
+    not its whole stack is sliced out of it."""
+    out = []
+    for name, first, stop, kind, routed, kind_first in layer_runs(cfg):
+        stack = params[name]
+        if (first, stop) != (0, stack["attn_norm"]["scale"].shape[0]):
+            stack = jax.tree_util.tree_map(lambda a: a[first:stop], stack)
+        out.append((stack, routed, kind, kind_first))
+    return out
 
 
 def init(rng, cfg: DecoderConfig) -> dict:
@@ -301,24 +523,53 @@ def _rope_interleaved(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> j
     return out.reshape(x.shape).astype(x.dtype)
 
 
-def mla_project(lp: dict, y: jnp.ndarray, cfg: DecoderConfig, positions):
+def _rescaled(x: jnp.ndarray, cfg: AttnSpec, rank: int) -> jnp.ndarray:
+    """A normed latent times ``sqrt(dim / rank)`` under the rescale flag."""
+    if not cfg.rescale:
+        return x
+    return (x.astype(jnp.float32) * (cfg.dim / rank) ** 0.5).astype(x.dtype)
+
+
+def mla_query_latent(lp: dict, y: jnp.ndarray, cfg: AttnSpec):
+    """The low-rank query's latent ``cq = RMSNorm(y W_qa)`` [B, S,
+    q_lora_rank] (times ``sqrt(dim / rank)`` under the rescale flag), which
+    the query projection and a full layer's indexer both read; None where
+    the kind projects its queries from ``y`` directly."""
+    if not cfg.q_lora_rank:
+        return None
+    return _rescaled(cm.rms_norm(lp["q_norm"], cm.dense(lp["wq_a"], y),
+                                 cfg.norm_eps), cfg, cfg.q_lora_rank)
+
+
+def mla_project(lp: dict, y: jnp.ndarray, cfg: AttnSpec, positions, cq=None):
     """The latent-attention projections of normed activations ``y``
     [B, S, dim] at ``positions`` [B, S]: per-head queries split into their
     no-position part [B, S, H, nope] and rotated rope part [B, S, H, rope],
     and — what the cache holds — the normed latent row ``c`` [B, S,
     kv_lora_rank] and the one rotated rope key ``k_r`` [B, S, rope] that
-    every head shares."""
+    every head shares. ``cfg`` is the layer kind's ``AttnSpec``; ``cq`` the
+    query latent (``mla_query_latent``), if any."""
     b, s = positions.shape
     nope, rope = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
     rot = _rope_interleaved
-    q = cm.dense(lp["wq"], y).reshape(b, s, cfg.heads, nope + rope)
+    q = cm.dense(lp["wq"], y if cq is None else cq).reshape(
+        b, s, cfg.heads, nope + rope)
     kv = cm.dense(lp["wkv_a"], y)
-    c = cm.rms_norm(lp["kv_norm"], kv[..., :cfg.kv_lora_rank], cfg.norm_eps)
+    c = _rescaled(cm.rms_norm(lp["kv_norm"], kv[..., :cfg.kv_lora_rank],
+                              cfg.norm_eps), cfg, cfg.kv_lora_rank)
     k_r = rot(kv[..., None, cfg.kv_lora_rank:], positions, cfg.rope_theta)[:, :, 0]
     return q[..., :nope], rot(q[..., nope:], positions, cfg.rope_theta), c, k_r
 
 
-def _mla_up(lp: dict, cfg: DecoderConfig):
+def mla_head_gate(lp: dict, y: jnp.ndarray, cfg):
+    """The headwise output gate ``sigmoid(y W_g)`` [B, S, H] of the block's
+    normed input; None where the kind has no gate."""
+    if not cfg.gate:
+        return None
+    return jax.nn.sigmoid(cm.dense(lp["w_head_gate"], y).astype(jnp.float32))
+
+
+def _mla_up(lp: dict, cfg):
     """``kv_b_proj`` as its two per-head halves: W_uk [L, H, nope] (latent
     -> the keys' no-position part) and W_uv [L, H, v] (latent -> values)."""
     w = lp["wkv_b"]["w"].reshape(
@@ -326,7 +577,7 @@ def _mla_up(lp: dict, cfg: DecoderConfig):
     return w[..., :cfg.qk_nope_head_dim], w[..., cfg.qk_nope_head_dim:]
 
 
-def mla_absorb_query(lp: dict, q_nope: jnp.ndarray, cfg: DecoderConfig) -> jnp.ndarray:
+def mla_absorb_query(lp: dict, q_nope: jnp.ndarray, cfg) -> jnp.ndarray:
     """Carry the queries into the latent space (``q_nope W_uk^T``): scored
     against the cached ``c`` they give ``q_nope . k_nope`` without ever
     expanding a key. [B, S, H, nope] -> [B, S, H, kv_lora_rank]."""
@@ -334,16 +585,24 @@ def mla_absorb_query(lp: dict, q_nope: jnp.ndarray, cfg: DecoderConfig) -> jnp.n
     return jnp.einsum("bshn,lhn->bshl", q_nope, w_uk.astype(q_nope.dtype))
 
 
-def mla_output(lp: dict, o_lat: jnp.ndarray, cfg: DecoderConfig) -> jnp.ndarray:
-    """``sum p c`` per head [B, S, H, kv_lora_rank] -> the attention
-    block's output [B, S, dim]: ``W_uv`` then ``o_proj``."""
-    _, w_uv = _mla_up(lp, cfg)
-    o = jnp.einsum("bshl,lhv->bshv", o_lat, w_uv.astype(o_lat.dtype))
+def _gated_out(lp: dict, o: jnp.ndarray, cfg, gate) -> jnp.ndarray:
+    """Per-head outputs [B, S, H, v] -> [B, S, dim]: the headwise gate,
+    if any, then ``o_proj``."""
+    if gate is not None:
+        o = (o.astype(jnp.float32) * gate[..., None]).astype(o.dtype)
     return cm.dense(lp["wo"], o.reshape(o.shape[:2] + (cfg.heads * cfg.v_head_dim,)))
 
 
+def mla_output(lp: dict, o_lat: jnp.ndarray, cfg, gate=None) -> jnp.ndarray:
+    """``sum p c`` per head [B, S, H, kv_lora_rank] -> the attention
+    block's output [B, S, dim]: ``W_uv``, the gate, then ``o_proj``."""
+    _, w_uv = _mla_up(lp, cfg)
+    o = jnp.einsum("bshl,lhv->bshv", o_lat, w_uv.astype(o_lat.dtype))
+    return _gated_out(lp, o, cfg, gate)
+
+
 def mla_expanded_attention(lp: dict, q_nope, q_rope, c, k_r, mask,
-                           cfg: DecoderConfig) -> jnp.ndarray:
+                           cfg, gate=None) -> jnp.ndarray:
     """The published (expanded) form over a block that holds its own keys:
     ``[k_nope | v] = c W_kvb`` per head, the shared ``k_r`` appended to
     every head's key, softmax over ``q . k / sqrt(nope + rope)``. Used by
@@ -356,8 +615,58 @@ def mla_expanded_attention(lp: dict, q_nope, q_rope, c, k_r, mask,
     k = jnp.concatenate(
         [k_nope, jnp.broadcast_to(k_r[:, :, None, :], k_nope.shape[:3] + k_r.shape[-1:])],
         axis=-1)
-    attn = cm.attention(q, k, v, mask)
-    return cm.dense(lp["wo"], attn.reshape(attn.shape[:2] + (cfg.heads * cfg.v_head_dim,)))
+    return _gated_out(lp, cm.attention(q, k, v, mask), cfg, gate)
+
+
+def index_project(lp: dict, y: jnp.ndarray, cq: jnp.ndarray, cfg, positions):
+    """A full layer's indexer over normed activations ``y`` and the query
+    latent ``cq`` (DeepSeek-V3.2's lightning indexer): index queries
+    ``q_i = cq W`` [B, S, Hi, Di], the ONE index key a token ``k_i =
+    LayerNorm(y W_k)`` [B, S, Di] (what the cache holds beside the latent
+    row) — rope on the first ``qk_rope_head_dim`` of the Di dims of both, in
+    split halves — and the heads' weights ``w = y W_w / sqrt(Hi Di)``
+    [B, S, Hi]. All float32 at ``highest`` precision from float32 leaves:
+    the indexer selects, as the router does."""
+    b, s = positions.shape
+    hi, di, rope = cfg.index_n_heads, cfg.index_head_dim, cfg.qk_rope_head_dim
+    f32 = functools.partial(jnp.dot, precision=jax.lax.Precision.HIGHEST)
+    yf = y.astype(jnp.float32)
+    q_i = f32(cq.astype(jnp.float32), lp["index_wq"]["w"].astype(jnp.float32)
+              ).reshape(b, s, hi, di)
+    k_i = cm.layer_norm(lp["index_k_norm"],
+                        f32(yf, lp["index_wk"]["w"].astype(jnp.float32)),
+                        cfg.norm_eps)[:, :, None, :]
+    q_i, k_i = (jnp.concatenate(
+        [_rope(t[..., :rope], positions, cfg.rope_theta), t[..., rope:]],
+        axis=-1) for t in (q_i, k_i))
+    w = f32(yf, lp["index_w"]["w"].astype(jnp.float32)) * (hi * di) ** -0.5
+    return q_i, k_i[:, :, 0], w
+
+
+def index_scores(q_i, w, k_i) -> jnp.ndarray:
+    """``I(t, s) = sum_j w_j(t) relu(q_i_j(t) . k_i(s))``: [B, S, Hi, Di],
+    [B, S, Hi], keys [B, K, Di] -> float32 [B, S, K]. The product is of
+    bfloat16 operands (the cache holds index keys in bfloat16) accumulated
+    in float32."""
+    dots = jnp.einsum("bshd,bkd->bshk", q_i.astype(jnp.bfloat16),
+                      k_i.astype(jnp.bfloat16),
+                      preferred_element_type=jnp.float32)
+    return jnp.einsum("bshk,bsh->bsk", jax.nn.relu(dots), w)
+
+
+def index_mask(scores, positions, key_pos, topk: int):
+    """The allowed set of each query as a mask over the keys: of the keys at
+    ``key_pos`` [K] (or [B, K]) not after the query (``positions`` [B, S]),
+    the ``topk`` of largest score [B, S, K] — all of them while there are no
+    more than ``topk``. Exact (a full sort; ties keep the earlier key)."""
+    causal = jnp.reshape(key_pos, (-1, 1, scores.shape[-1])) <= positions[..., None]
+    if scores.shape[-1] <= topk:
+        return causal
+    masked = jnp.where(causal, scores, -jnp.inf)
+    _, idx = jax.lax.top_k(masked, topk)
+    chosen = jnp.zeros(scores.shape, bool)
+    chosen = jnp.put_along_axis(chosen, idx, True, axis=-1, inplace=False)
+    return chosen & causal
 
 
 def route_topk(lp: dict, y: jnp.ndarray, cfg: DecoderConfig, token_mask=None):
@@ -368,11 +677,13 @@ def route_topk(lp: dict, y: jnp.ndarray, cfg: DecoderConfig, token_mask=None):
     ``num_experts_per_tok`` of ``score + router_bias`` chosen, weighed by
     the unbiased scores (normalised, times ``routed_scaling_factor``).
 
-    Returns the combine weights [T, E + shared] float32 — routed weights in
-    their experts' columns, 1 in the shared experts' — and the layer's load
-    [E] int32 (tokens routed to each expert). Tokens that ``token_mask``
-    excludes (inactive lanes, padding) have an all-zero row: they route
-    nowhere and count nowhere."""
+    Returns the combine weights [T, held + shared] float32 — routed weights
+    in their experts' columns (of the experts HELD here, ``cfg.held``: the
+    weights are normalised over all the chosen, and a choice of an absent
+    expert has no column), 1 in the shared experts' — and the layer's load
+    [E] int32 (tokens routed to each of ALL the experts). Tokens that
+    ``token_mask`` excludes (inactive lanes, padding) have an all-zero row:
+    they route nowhere and count nowhere."""
     e, k = cfg.n_routed_experts, cfg.num_experts_per_tok
     scores = jax.nn.sigmoid(jnp.dot(
         y.astype(jnp.float32), lp["router"]["w"].astype(jnp.float32),
@@ -385,6 +696,9 @@ def route_topk(lp: dict, y: jnp.ndarray, cfg: DecoderConfig, token_mask=None):
             else token_mask.reshape(-1).astype(jnp.float32))
     assign = chosen.sum(axis=1) * live[:, None]                   # [T, E] 0/1
     cw = jnp.einsum("tk,tke->te", w, chosen) * live[:, None]
+    if cfg.experts_held is not None:
+        first, count = cfg.held
+        cw = cw[:, first:first + count]
     shared = jnp.broadcast_to(live[:, None], (y.shape[0], cfg.n_shared_experts))
     return (jnp.concatenate([cw, shared], axis=-1),
             assign.sum(axis=0).astype(jnp.int32))
@@ -414,12 +728,19 @@ def routed_mlp(lp: dict, y: jnp.ndarray, cfg: DecoderConfig, token_mask=None,
     return out.reshape(b, s, d), load
 
 
-def moe_step_stats(loads: jnp.ndarray) -> jnp.ndarray:
+def moe_step_stats(loads: jnp.ndarray, held=None) -> jnp.ndarray:
     """The three counters a serving step reports, from the expert layers'
     loads [layers, E]: (token, expert) pairs routed (summed over layers),
     distinct experts hit (summed over layers) and the largest expert's load
-    (over layers). int32 [3]."""
-    return jnp.stack([loads.sum(), (loads > 0).sum(), loads.max()]).astype(jnp.int32)
+    (over layers). int32 [3]. With ``held`` = (first, count) — a chip that
+    holds a share — the experts hit and the largest load are of the experts
+    held (what the step computes), the pairs still of all, and a fourth
+    counter follows: the pairs routed to the experts held. int32 [4]."""
+    if held is None:
+        return jnp.stack([loads.sum(), (loads > 0).sum(), loads.max()]).astype(jnp.int32)
+    here = loads[:, held[0]:held[0] + held[1]]
+    return jnp.stack([loads.sum(), (here > 0).sum(), here.max(),
+                      here.sum()]).astype(jnp.int32)
 
 
 def _moe_mlp(lp: dict, y: jnp.ndarray, cfg: DecoderConfig,
@@ -586,27 +907,39 @@ def forward(params: dict, cfg: DecoderConfig, input_ids, *, axes=None, mesh=None
 def _forward_latent(params: dict, cfg: DecoderConfig, input_ids, axes: dict,
                     return_aux: bool):
     """``forward`` for a latent-attention model: the published (expanded)
-    attention, and one scan per layer stack — the leading dense layers, then
-    the expert layers (plain XLA over every expert; dropless, so there is no
-    auxiliary loss to carry and the aux terms are zero)."""
+    attention under each layer kind's mask (causal; the last
+    ``sliding_window`` positions; the indexer's ``index_topk``), and one
+    scan per layer run — the leading dense layers, then the expert layers
+    (plain XLA over every expert held; dropless, so there is no auxiliary
+    loss to carry and the aux terms are zero)."""
     b, s = input_ids.shape
     x = _shard_act(cm.embedding(params["embed"], input_ids), axes)
     positions = jnp.broadcast_to(jnp.arange(s)[None, :], (b, s))
     causal = jnp.tril(jnp.ones((s, s), bool))[None, None, :, :]
+    key_pos = jnp.arange(s)
 
-    def make_layer(routed: bool):
+    def make_layer(routed: bool, sp: AttnSpec):
         def layer(x, lp):
             y = cm.rms_norm(lp["attn_norm"], x, cfg.norm_eps)
+            cq = mla_query_latent(lp, y, sp)
+            mask = causal
+            if sp.window:
+                mask = causal & (key_pos[None, :] > key_pos[:, None] - sp.window)
+            if sp.index_topk:
+                q_i, k_i, w = index_project(lp, y, cq, sp, positions)
+                mask = index_mask(index_scores(q_i, w, k_i), positions,
+                                  key_pos, sp.index_topk)[:, None]
             x = x + mla_expanded_attention(
-                lp, *mla_project(lp, y, cfg, positions), causal, cfg)
+                lp, *mla_project(lp, y, sp, positions, cq), mask, sp,
+                mla_head_gate(lp, y, sp))
             x = _shard_act(x, axes)
             y = cm.rms_norm(lp["mlp_norm"], x, cfg.norm_eps)
             x = x + (routed_mlp(lp, y, cfg)[0] if routed else _mlp(lp, y, cfg))
             return _shard_act(x, axes), None
         return jax.checkpoint(layer, prevent_cse=False) if cfg.remat else layer
 
-    for stack, routed in layer_stacks(params, cfg):
-        x, _ = jax.lax.scan(make_layer(routed), x, stack)
+    for stack, routed, kind, _ in layer_stacks(params, cfg):
+        x, _ = jax.lax.scan(make_layer(routed, cfg.attn(kind)), x, stack)
     x = cm.rms_norm(params["norm_out"], x, cfg.norm_eps)
     logits = cm.dense(params["lm_head"], x).astype(jnp.float32)
     if return_aux:
@@ -736,30 +1069,41 @@ def serve_dtypes(cfg: DecoderConfig) -> dict:
 
 def _serve_dtypes_latent(cfg: DecoderConfig) -> dict:
     """``serve_dtypes`` for a latent-attention model: the router, its
-    selection bias and every norm scale (the latent norm too) float32 — the
-    router is multiplied in float32 and its bias added to float32 scores —
-    and every other leaf bfloat16."""
+    selection bias, the indexer (it selects too) and every norm scale (the
+    latent norms too) float32 — they are multiplied in float32 — and every
+    other leaf bfloat16. One entry a layer stack the model has."""
     bf16, f32 = jnp.bfloat16, jnp.float32
-    attn = {
-        "attn_norm": {"scale": f32},
-        "wq": {"w": bf16},
-        "wkv_a": {"w": bf16},
-        "kv_norm": {"scale": f32},
-        "wkv_b": {"w": bf16},
-        "wo": {"w": bf16},
-        "mlp_norm": {"scale": f32},
-    }
-    dense = {**attn, "w_gate": {"w": bf16}, "w_up": {"w": bf16},
-             "w_down": {"w": bf16}}
-    routed = {**attn, "router": {"w": f32}, "router_bias": f32,
-              "experts": {"w_gate": bf16, "w_up": bf16, "w_down": bf16}}
-    return {
+    out = {
         "embed": {"table": bf16},
         "norm_out": {"scale": f32},
         "lm_head": {"w": bf16},
-        "dense_layers": dense,
-        "layers": routed,
     }
+    for name, _, _, kind, routed, _ in layer_runs(cfg):
+        sp = cfg.attn(kind)
+        layer = {
+            "attn_norm": {"scale": f32},
+            "wq": {"w": bf16},
+            "wkv_a": {"w": bf16},
+            "kv_norm": {"scale": f32},
+            "wkv_b": {"w": bf16},
+            "wo": {"w": bf16},
+            "mlp_norm": {"scale": f32},
+        }
+        if sp.q_lora_rank:
+            layer.update(wq_a={"w": bf16}, q_norm={"scale": f32})
+        if sp.gate:
+            layer["w_head_gate"] = {"w": bf16}
+        if sp.index_topk:
+            layer.update(index_wq={"w": f32}, index_wk={"w": f32},
+                         index_k_norm={"scale": f32, "bias": f32},
+                         index_w={"w": f32})
+        if routed:
+            layer.update(router={"w": f32}, router_bias=f32,
+                         experts={"w_gate": bf16, "w_up": bf16, "w_down": bf16})
+        else:
+            layer.update(w_gate={"w": bf16}, w_up={"w": bf16}, w_down={"w": bf16})
+        out[name] = layer
+    return out
 
 
 def _no_latent(cfg: DecoderConfig, what: str) -> None:

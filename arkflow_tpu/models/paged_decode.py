@@ -11,13 +11,17 @@ step jits once and replays (no dynamic shapes, XLA-friendly).
 Page 0 is a reserved scratch page: inactive slots and masked prompt padding
 write there, which keeps the scatter free of conditionals.
 
-Two cache kinds live here, selected by the model config inside the one layer
-body of each step: per-head K/V (GQA; the pools are scanned layer by layer)
-and latent rows (MLA, ``cfg.latent``: one normed latent row and one rotated
+The cache's shape is the model's to state and this module's to own
+(``cache_spec``): per-head K/V (GQA; the pools are scanned layer by layer),
+or latent rows (MLA, ``cfg.latent``: one normed latent row and one rotated
 rope key a token for all heads; the pools ride whole through the layer loop,
-``_latent_layers``, and attention runs in the absorbed form over them). The
-MLP half is dense SwiGLU, the Switch top-1 layer, or dropless routed experts
-(``cfg.routed``) — the last only on latent layers so far.
+``_latent_layers``, and attention runs in the absorbed form over them). A
+latent model with a layer pattern has three kinds of row side by side: its
+full layers' latent rows, their index keys, and its sliding layers' (wider)
+latent rows, which live only while the window covers them — in a pool and
+under a page table of their own. The MLP half is dense SwiGLU, the Switch
+top-1 layer, or dropless routed experts (``cfg.routed``) — the last only on
+latent layers so far.
 
 The reference has no serving layer at all (its python processor is
 user-code); this implements the engine the `tpu_generate` processor's
@@ -32,22 +36,81 @@ import jax
 import jax.numpy as jnp
 
 from arkflow_tpu.models import common as cm
-from arkflow_tpu.models.decoder import (DecoderConfig, _mlp, _rope,
-                                        layer_stacks, mla_absorb_query,
-                                        mla_expanded_attention, mla_output,
-                                        mla_project, moe_step_stats,
+from dataclasses import dataclass
+
+from arkflow_tpu.models.decoder import (FULL, SLIDING, DecoderConfig, _mlp,
+                                        _rope, index_project, index_scores,
+                                        layer_runs, layer_stacks, mla_absorb_query,
+                                        mla_expanded_attention, mla_head_gate,
+                                        mla_output, mla_project,
+                                        mla_query_latent, moe_step_stats,
                                         routed_mlp)
 
 
-def init_page_pool(cfg: DecoderConfig, num_pages: int, page_size: int):
-    """The two page pools the model's cache needs, bf16 — the cache's shape
-    is the model family's to state, not the server's to compute:
+@dataclass(frozen=True)
+class CachePool:
+    """One kind of cached row: how many layers hold it, the widths of its
+    arrays (values a token a layer), and for how many tokens a row stays
+    live (``window`` 0: for the request's life)."""
+    name: str
+    layers: int
+    widths: tuple
+    window: int = 0
+
+    @property
+    def bytes_per_token(self) -> int:
+        """bf16 bytes one cached token costs over the pool's layers."""
+        return 2 * sum(self.widths) * self.layers
+
+
+def cache_spec(cfg: DecoderConfig) -> tuple:
+    """The kinds of row the model caches — the one place that states them:
+
+    - ``kv``: per-head K and V (GQA), every layer;
+    - ``latent``: a full latent layer's normed latent row and rotated rope
+      key, one each a token for ALL heads;
+    - ``index``: an indexed full layer's index key (the indexer scores it
+      against every later query);
+    - ``window``: a sliding latent layer's latent row and rope key, live for
+      ``sliding_window`` tokens: its pages are freed as the window passes."""
+    if not cfg.latent:
+        kv = cfg.kv_heads * (cfg.dim // cfg.heads)
+        return (CachePool("kv", cfg.layers, (kv, kv)),)
+    full, swa = (cfg.kinds.count(k) for k in (FULL, SLIDING))
+    pools = [CachePool("latent", full, (cfg.kv_lora_rank, cfg.qk_rope_head_dim))]
+    if cfg.index_topk:
+        pools.append(CachePool("index", full, (cfg.index_head_dim,)))
+    if swa:
+        pools.append(CachePool(
+            "window", swa, (cfg.swa_kv_lora_rank, cfg.swa_qk_rope_head_dim),
+            cfg.sliding_window))
+    return tuple(pools)
+
+
+def init_page_pool(cfg: DecoderConfig, num_pages: int, page_size: int,
+                   window_pages: int = 0):
+    """The page pools of ``cache_spec``, bf16, as the two values every step
+    carries (and donates):
 
     - per-head K/V (GQA): K and V, each [layers, num_pages, page, kv_heads, dh];
     - latent (MLA): the normed latent rows [layers, num_pages, page,
       kv_lora_rank] and the rotated rope keys [layers, num_pages, page,
       qk_rope_head_dim], one row each per token for ALL heads (no head axis:
-      a page's last two dims then tile the chip's memory as they are)."""
+      a page's last two dims then tile the chip's memory as they are);
+    - a latent model with a layer pattern: two dicts by pool name — the
+      wide rows ``{"latent", "window"[, "index"]}`` and the rope keys
+      ``{"latent", "window"}`` — each pool over its OWN layers, the window
+      pool over ``window_pages`` pages of its own."""
+    if cfg.latent and cfg.layered:
+        wide, rope = {}, {}
+        for pool in cache_spec(cfg):
+            pages = window_pages if pool.window else num_pages
+            arrays = [jnp.zeros((pool.layers, pages, page_size, w), jnp.bfloat16)
+                      for w in pool.widths]
+            wide[pool.name] = arrays[0]
+            if len(arrays) > 1:
+                rope[pool.name] = arrays[1]
+        return wide, rope
     if cfg.latent:
         shape = (cfg.layers, num_pages, page_size)
         return (jnp.zeros(shape + (cfg.kv_lora_rank,), jnp.bfloat16),
@@ -58,63 +121,269 @@ def init_page_pool(cfg: DecoderConfig, num_pages: int, page_size: int):
 
 
 def kv_bytes_per_token(cfg: DecoderConfig) -> int:
-    """Bytes one cached token costs over all layers (bf16 values as the
-    pools hold them, before any padding the device's tiling adds)."""
-    per_layer = (cfg.kv_lora_rank + cfg.qk_rope_head_dim if cfg.latent
-                 else 2 * cfg.kv_heads * (cfg.dim // cfg.heads))
-    return 2 * per_layer * cfg.layers
+    """Bytes one cached token costs over all layers and pools (bf16 values
+    as the pools hold them, before any padding the device's tiling adds; a
+    window row while it is live)."""
+    return sum(pool.bytes_per_token for pool in cache_spec(cfg))
 
 
-def _latent_layers(params: dict, cfg: DecoderConfig, x, c_pages, r_pages,
-                   positions, page_idx, offset, attend, token_mask,
-                   attention_kernel: str, kernel_interpret: bool):
+def window_ring_pages(cfg: DecoderConfig, page_size: int, step_tokens: int) -> int:
+    """Columns of a row's window page table: the table is a ring — the
+    page of logical index ``i`` sits in column ``i % columns`` — wide enough
+    for every page a step of ``step_tokens`` queries can attend or write:
+    the ``sliding_window - 1`` positions before its first query to its last,
+    however they fall on page boundaries. 0 without sliding layers."""
+    if SLIDING not in cfg.kinds:
+        return 0
+    return (cfg.sliding_window + max(step_tokens, 1) - 2) // page_size + 2
+
+
+def _write_coords(table, positions, valid, page: int, ring: bool = False):
+    """(page, offset) each token's row is written at: through ``table``
+    (a ring of pages where ``ring``) for the tokens ``valid`` names, the
+    scratch page 0 for the rest."""
+    logical = positions // page
+    col = logical % table.shape[1] if ring else jnp.minimum(
+        logical, table.shape[1] - 1)
+    return (jnp.where(valid, jnp.take_along_axis(table, col, axis=1), 0),
+            jnp.where(valid, positions % page, 0))
+
+
+def _latent_layers(params: dict, cfg: DecoderConfig, x, k_pages, v_pages,
+                   positions, page_idx, offset, token_mask, *, page_table,
+                   off, mask, block: bool, attention_kernel: str,
+                   kernel_interpret: bool):
     """The layer loop of a latent-attention model over the paged cache: one
-    scan per layer stack (leading dense layers, then expert layers), ONE
-    layer index for the pools. The pools ride in the carry whole — each
-    layer scatters its tokens' latent rows and rope keys at
-    (layer, page_idx, offset) in place and hands the whole pools on, so two
-    stacks share them without slicing or re-joining — and ``attend(lp,
-    q_nope, q_rope, c, k_r, c_pages, r_pages, layer)`` is the caller's
-    attention over them. ``token_mask`` [B, S] names the tokens that route
-    (active lanes, unpadded positions). Returns (x, c_pages, r_pages, the
-    step's routing counters ``moe_step_stats``)."""
-    kernel = attention_kernel == "paged"
+    scan per layer run (leading dense layers, then expert layers; a layer
+    pattern alternates kinds), each layer reading its kind's sizes
+    (``cfg.attn``) and writing its kind's pools. The pools ride in the carry
+    whole — each layer scatters its tokens' rows at (its index among its
+    kind's layers, page, offset) in place and hands the pools on, so runs
+    share them without slicing or re-joining.
 
-    def make_layer(routed: bool, experts, first: int):
+    ``page_idx`` / ``offset`` [B, S] place each token's row in the kept
+    pools; ``page_table`` is the kept table [B, P] or, with sliding layers,
+    (kept, window ring); query i of row b sits at ``off[b] + i``;
+    ``token_mask`` [B, S] names the tokens that are written and that route
+    (active lanes, unpadded positions). A plain full layer attends under
+    ``mask`` — over the block's own keys in the published form where
+    ``block`` (the one-shot prefill), else over the cache in the absorbed
+    form —, a sliding layer over its window, an indexed layer over its
+    indexer's choice. Returns (x, k_pages, v_pages, the step's counters:
+    ``moe_step_stats``, then with indexed layers (keys attended, keys in
+    context) summed over queries and indexed layers)."""
+    kernel = attention_kernel == "paged"
+    kern = dict(attention_kernel=attention_kernel, kernel_interpret=kernel_interpret)
+    layered = isinstance(k_pages, dict)
+    kept, ring = page_table if isinstance(page_table, tuple) else (page_table, None)
+    page = (k_pages["latent"] if layered else k_pages).shape[2]
+    where = {FULL: (page_idx, offset)}
+    if ring is not None:
+        where[SLIDING] = _write_coords(ring, positions, token_mask, page, ring=True)
+
+    def make_layer(routed: bool, kind: str, experts):
+        sp = cfg.attn(kind)
+        name = "window" if sp.window else "latent"
+        pi, po = where[kind]
+
         # the stack's experts stay OUT of the scanned tree: scanned, each
         # layer's slice (1.2 GB at Kanana-2 widths) would be copied out for
         # the kernel every step; whole, the kernel indexes the layer itself
-        def layer(carry, lp):
-            x, cp, rp, li = carry
+        def layer(carry, scanned):
+            x, kp, vp, picked = carry
+            lp, li, ei = scanned
+            cp, rp = (kp[name], vp[name]) if layered else (kp, vp)
             y = cm.rms_norm(lp["attn_norm"], x, cfg.norm_eps)
-            q_nope, q_rope, c, k_r = mla_project(lp, y, cfg, positions)
-            cp = cp.at[li, page_idx, offset].set(c.astype(cp.dtype))
-            rp = rp.at[li, page_idx, offset].set(k_r.astype(rp.dtype))
-            x = x + attend(lp, q_nope, q_rope, c, k_r, cp, rp, li)
+            cq = mla_query_latent(lp, y, sp)
+            q_nope, q_rope, c, k_r = mla_project(lp, y, sp, positions, cq)
+            cp = cp.at[li, pi, po].set(c.astype(cp.dtype))
+            rp = rp.at[li, pi, po].set(k_r.astype(rp.dtype))
+            gate = mla_head_gate(lp, y, sp)
+            if sp.index_topk:
+                q_i, k_i, w = index_project(lp, y, cq, sp, positions)
+                ip = kp["index"].at[li, pi, po].set(k_i.astype(cp.dtype))
+                kp = {**kp, "index": ip}
+                sel, ok = _index_select(q_i, w, ip, li, kept, positions,
+                                        sp.index_topk, **kern)
+                attn = _attend_selected(lp, q_nope, q_rope, cp, rp, li, kept,
+                                        sel, ok, sp, gate, off=off, **kern)
+                picked = picked + jnp.stack([
+                    (ok & token_mask[..., None]).sum(),
+                    ((positions + 1) * token_mask).sum()]).astype(jnp.int32)
+            elif sp.window:
+                attn = _attend_window(lp, q_nope, q_rope, cp, rp, li, ring,
+                                      off, positions, sp, gate, **kern)
+            elif block:
+                attn = mla_expanded_attention(lp, q_nope, q_rope, c, k_r,
+                                              mask, sp, gate)
+            else:
+                attn = _attend_latent(lp, q_nope, q_rope, cp, rp, li, kept,
+                                      off, mask, sp, gate=gate, **kern)
+            if layered:
+                kp, vp = {**kp, name: cp}, {**vp, name: rp}
+            else:
+                kp, vp = cp, rp
+            x = x + attn
             y = cm.rms_norm(lp["mlp_norm"], x, cfg.norm_eps)
             if routed:
                 out, load = routed_mlp(lp, y, cfg, token_mask=token_mask,
                                        kernel=kernel, interpret=kernel_interpret,
-                                       stacked=(experts, li - first))
+                                       stacked=(experts, ei))
             else:
                 out, load = _mlp(lp, y, cfg), None
-            return (x + out, cp, rp, li + 1), load
+            return (x + out, kp, vp, picked), load
         return layer
 
-    carry = (x, c_pages, r_pages, jnp.zeros((), jnp.int32))
-    first = 0
-    for stack, routed in layer_stacks(params, cfg):
+    carry = (x, k_pages, v_pages, jnp.zeros((2,), jnp.int32))
+    loads = []
+    for stack, routed, kind, kind_first in layer_stacks(params, cfg):
+        n = stack["attn_norm"]["scale"].shape[0]
         scanned = {k: v for k, v in stack.items() if k != "experts"}
-        carry, loads = jax.lax.scan(
-            make_layer(routed, stack.get("experts"), first), carry, scanned)
-        first += stack["attn_norm"]["scale"].shape[0]
-    x, c_pages, r_pages, _ = carry
-    return x, c_pages, r_pages, moe_step_stats(loads)  # the expert stack's
+        carry, load = jax.lax.scan(
+            make_layer(routed, kind, stack.get("experts")), carry,
+            (scanned, kind_first + jnp.arange(n), jnp.arange(n)))
+        if routed:
+            loads.append(load)
+    x, k_pages, v_pages, picked = carry
+    stats = moe_step_stats(jnp.concatenate(loads), cfg.experts_held and cfg.held)
+    if cfg.index_topk:
+        stats = jnp.concatenate([stats, picked])
+    return x, k_pages, v_pages, stats
+
+
+def _tiled(fn, tile: int, *per_query):
+    """``fn`` over the queries (axis 1 of every array) ``tile`` at a time,
+    one tile after another: a chunk's per-query intermediates ([queries,
+    keys, width] gathers, [queries, heads, keys] scores) stay a tile's."""
+    s = per_query[0].shape[1]
+    if s <= tile or s % tile:
+        return fn(*per_query)
+    split = [jnp.moveaxis(a.reshape(a.shape[0], s // tile, tile, *a.shape[2:]), 1, 0)
+             for a in per_query]
+    out = jax.lax.map(lambda xs: fn(*xs), tuple(split))
+    return jax.tree_util.tree_map(
+        lambda o: jnp.moveaxis(o, 0, 1).reshape(o.shape[1], s, *o.shape[3:]), out)
+
+
+#: queries of a chunk the XLA forms of the indexed layer take at a time
+_QUERY_TILE = 64
+
+
+def _index_select(q_i, w, index_pages, layer, page_table, positions, topk: int,
+                  attention_kernel: str, kernel_interpret: bool):
+    """The indexer's choice for each query: the ``topk`` positions of
+    largest index score among the keys not after it (``decoder.
+    index_scores`` against the row's cached index keys, read through the
+    page table; exact: a full sort of float32 scores, of equal scores the
+    earlier position). Returns (positions [B, S, K] int32, ``ok`` [B, S, K]:
+    False where the context has fewer than K keys and the entry names none)
+    with K = min(topk, context) — or, ``"paged"``, the same choice as a
+    float32 mask over the context [B, S, context] (1: attend) and ``ok``:
+    what the latent kernel reads the pool in place under."""
+    b, ctx = page_table.shape[0], page_table.shape[1] * index_pages.shape[2]
+    key_pos = jnp.arange(ctx)
+
+    def select(q_i, w, positions):
+        if attention_kernel == "paged":
+            from arkflow_tpu.ops.ragged_attention import dsa_index_scores
+
+            scores = dsa_index_scores(q_i, w, index_pages, layer, page_table,
+                                      positions[:, 0], interpret=kernel_interpret)
+        else:
+            keys = index_pages[layer, page_table].reshape(b, ctx, -1)
+            scores = index_scores(q_i, w, keys)
+        seen = key_pos <= positions[..., None]
+        scores = jnp.where(seen, scores, -jnp.inf)
+        top, idx = jax.lax.top_k(scores, min(topk, ctx))
+        ok = top > -jnp.inf
+        if attention_kernel != "paged":
+            return idx.astype(jnp.int32), ok
+        # the last chosen's score and position say who else was chosen: the
+        # sort is stable, so of its equals those before it
+        kth, last = top[..., -1:], idx[..., -1:]
+        chosen = (scores > kth) | ((scores == kth) & (key_pos <= last))
+        return (chosen & seen).astype(jnp.float32), ok
+
+    return _tiled(select, _QUERY_TILE, q_i, w, positions)
+
+
+def _attend_selected(lp, q_nope, q_rope, c_pages, r_pages, layer, page_table,
+                     sel, ok, cfg, gate, attention_kernel: str,
+                     kernel_interpret: bool, off=None):
+    """Absorbed latent attention of each query over the positions chosen
+    for it (``_index_select``). ``"paged"``: ``sel`` is the choice as a
+    float32 mask over the context [B, S, context]; the latent kernel reads
+    the pools in place, every page once for all of a chunk's queries, and
+    the mask narrows each query's keys (``off``: each row's first query
+    position). Otherwise the plain-XLA form the kernel is held to: ``sel``
+    int32 [B, S, K] (valid entries first, ``ok``), their latent rows and
+    rope keys gathered by token index out of the pools ([B, S, K, width])
+    and scored as ``_attend_latent`` scores a context."""
+    page = c_pages.shape[2]
+    q_lat = mla_absorb_query(lp, q_nope, cfg)
+    scale = float(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+    if attention_kernel == "paged":
+        from arkflow_tpu.ops.ragged_attention import mla_paged_attention
+
+        o_lat = mla_paged_attention(
+            q_lat, q_rope, c_pages, r_pages, layer, page_table, off,
+            scale=scale, allowed=sel, interpret=kernel_interpret,
+            name="dsa_sparse_attention")
+        return mla_output(lp, o_lat, cfg, gate)
+    phys = jnp.take_along_axis(page_table[:, None, :], sel // page, axis=2)
+
+    def attend(q_lat, q_rope, phys, sel, ok):
+        cc = c_pages[layer, phys, sel % page].astype(q_lat.dtype)  # [B, T, K, L]
+        rr = r_pages[layer, phys, sel % page].astype(q_lat.dtype)
+        scores = (jnp.einsum("bqhl,bqkl->bqhk", q_lat, cc)
+                  + jnp.einsum("bqhr,bqkr->bqhk", q_rope, rr)
+                  ).astype(jnp.float32) * scale
+        scores = jnp.where(ok[:, :, None, :], scores, jnp.finfo(jnp.float32).min)
+        probs = jax.nn.softmax(scores, axis=-1).astype(q_lat.dtype)
+        return jnp.einsum("bqhk,bqkl->bqhl", probs, cc)
+
+    o_lat = _tiled(attend, _QUERY_TILE, q_lat, q_rope, phys, sel, ok)
+    return mla_output(lp, o_lat, cfg, gate)
+
+
+def _attend_window(lp, q_nope, q_rope, c_pages, r_pages, layer, ring, off,
+                   positions, cfg, gate, attention_kernel: str,
+                   kernel_interpret: bool):
+    """Absorbed latent attention of a sliding layer: query at position t
+    over the keys ``t - window < s <= t``, read through the row's ring of
+    window pages (``window_ring_pages``: logical page i in column i %
+    columns; pages the window has passed were freed and may be another
+    page's by now — the bound hides them). ``"paged"`` reads the pool in
+    place (``mla_paged_attention`` with its lower bound)."""
+    q_lat = mla_absorb_query(lp, q_nope, cfg)
+    scale = float(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+    b, cols = ring.shape
+    page = c_pages.shape[2]
+    if attention_kernel == "paged":
+        from arkflow_tpu.ops.ragged_attention import mla_paged_attention
+
+        o_lat = mla_paged_attention(
+            q_lat, q_rope, c_pages, r_pages, layer, ring, off, scale=scale,
+            window=cfg.window, interpret=kernel_interpret,
+            name="swa_latent_attention")
+        return mla_output(lp, o_lat, cfg, gate)
+    # column j holds the newest logical page i <= the step's last with
+    # i % columns == j: its tokens' positions follow from that
+    last = (off + positions.shape[1] - 1) // page                  # [B]
+    j = jnp.arange(cols)[None, :]
+    logical = last[:, None] - (last[:, None] - j) % cols           # [B, cols]
+    key_pos = (logical[:, :, None] * page + jnp.arange(page)).reshape(b, -1)
+    cc = c_pages[layer, ring].reshape(b, cols * page, -1).astype(q_lat.dtype)
+    rr = r_pages[layer, ring].reshape(b, cols * page, -1).astype(q_lat.dtype)
+    qp, kpos = positions[:, None, :, None], key_pos[:, None, None, :]
+    mask = (kpos <= qp) & (kpos > qp - cfg.window) & (kpos >= 0)
+    return mla_output(lp, _masked_latent_attention(q_lat, q_rope, cc, rr, mask,
+                                                   scale), cfg, gate)
 
 
 def _attend_latent(lp, q_nope, q_rope, c_pages, r_pages, layer, page_table,
-                   off, mask, cfg: DecoderConfig, attention_kernel: str,
-                   kernel_interpret: bool):
+                   off, mask, cfg, attention_kernel: str,
+                   kernel_interpret: bool, gate=None):
     """Absorbed latent attention over the paged cache: the queries are
     carried into the latent space (``W_uk`` absorbed), scored against the
     cached latent rows and rope keys of every head's ONE shared row per
@@ -135,23 +404,33 @@ def _attend_latent(lp, q_nope, q_rope, c_pages, r_pages, layer, page_table,
         b, ctx = page_table.shape[0], page_table.shape[1] * c_pages.shape[2]
         cc = c_pages[layer][page_table].reshape(b, ctx, -1).astype(q_lat.dtype)
         rr = r_pages[layer][page_table].reshape(b, ctx, -1).astype(q_lat.dtype)
-        scores = (jnp.einsum("bqhl,bkl->bhqk", q_lat, cc)
-                  + jnp.einsum("bqhr,bkr->bhqk", q_rope, rr)
-                  ).astype(jnp.float32) * scale
-        scores = jnp.where(mask, scores, jnp.finfo(jnp.float32).min)
-        probs = jax.nn.softmax(scores, axis=-1).astype(q_lat.dtype)
-        o_lat = jnp.einsum("bhqk,bkl->bqhl", probs, cc)
-    return mla_output(lp, o_lat, cfg)
+        o_lat = _masked_latent_attention(q_lat, q_rope, cc, rr, mask, scale)
+    return mla_output(lp, o_lat, cfg, gate)
+
+
+def _masked_latent_attention(q_lat, q_rope, cc, rr, mask, scale: float):
+    """``sum p c`` of absorbed queries [B, Q, H, *] over a row's gathered
+    latent rows ``cc`` and rope keys ``rr`` [B, K, *] under ``mask``
+    [B, 1, Q, K]: the plain-XLA form the kernels are held to."""
+    scores = (jnp.einsum("bqhl,bkl->bhqk", q_lat, cc)
+              + jnp.einsum("bqhr,bkr->bhqk", q_rope, rr)
+              ).astype(jnp.float32) * scale
+    scores = jnp.where(mask, scores, jnp.finfo(jnp.float32).min)
+    probs = jax.nn.softmax(scores, axis=-1).astype(q_lat.dtype)
+    return jnp.einsum("bhqk,bkl->bqhl", probs, cc)
 
 
 def latent_kernel_probe(params: dict, cfg: DecoderConfig, page_size: int,
                         kernel_interpret: bool = False) -> list:
     """(name, reference, kernel output) for each Pallas kernel a latent
     model's ``decode_kernel: paged`` serves with, on the model's own first
-    layer at its real widths and seeded inputs: the latent attention
+    layers at their real widths and seeded inputs: the latent attention
     (decode and a 2-token chunk, rows on non-contiguous pages, one crossing
-    a page boundary) against the gather path, and the expert product on
-    GIVEN routing against plain XLA over the experts routed to.
+    a page boundary) against the gather path; with a layer pattern the
+    sliding layer's window attention, the indexer's scores and the
+    attention over a GIVEN selection, each against its plain-XLA form; and
+    the expert product on GIVEN routing against plain XLA over the experts
+    routed to.
 
     Kernel by kernel, not logits of the whole model as the per-head probe
     does: with routed experts two arithmetically different attention paths
@@ -160,42 +439,101 @@ def latent_kernel_probe(params: dict, cfg: DecoderConfig, page_size: int,
     (seen on the chip: 4 of 6 seeds, PERF.md PR 27)."""
     from arkflow_tpu.ops.moe_experts import expert_swiglu_dense, moe_expert_swiglu
 
-    lp = jax.tree_util.tree_map(lambda a: a[0], params["dense_layers"])
-    keys = iter(jax.random.split(jax.random.PRNGKey(1234), 8))
+    keys = iter(jax.random.split(jax.random.PRNGKey(1234), 32))
+    rand = lambda shape: jax.random.normal(  # noqa: E731
+        next(keys), shape, jnp.float32).astype(jnp.bfloat16)
+    kerns = [dict(attention_kernel=k, kernel_interpret=kernel_interpret)
+             for k in ("gather", "paged")]
     n0 = page_size + 1
     pages_per = -(-(n0 + 3) // page_size)
-    pools = [jax.random.normal(next(keys), p.shape, jnp.float32).astype(p.dtype)
-             for p in init_page_pool(cfg, 1 + 2 * pages_per, page_size)]
     table = jnp.stack([jnp.arange(1, 2 * pages_per, 2)[::-1],
                        jnp.arange(2, 2 * pages_per + 1, 2)]).astype(jnp.int32)
     off = jnp.asarray([n0, 1], jnp.int32)
     ctx = pages_per * page_size
+    steps = (("decode", 1), ("chunk", 2))
     out = []
-    for name, c in (("latent_attention_decode", 1), ("latent_attention_chunk", 2)):
-        shape = (2, c, cfg.heads)
-        q_nope = jax.random.normal(next(keys), shape + (cfg.qk_nope_head_dim,),
-                                   jnp.float32).astype(jnp.bfloat16)
-        q_rope = jax.random.normal(next(keys), shape + (cfg.qk_rope_head_dim,),
-                                   jnp.float32).astype(jnp.bfloat16)
+
+    def first_layer(kind):
+        name = next(r[0] for r in layer_runs(cfg) if r[3] == kind)
+        return jax.tree_util.tree_map(lambda a: a[0], {
+            k: v for k, v in params[name].items() if k != "experts"})
+
+    def pools(sp, pages):
+        return [rand((1, pages, page_size, w))
+                for w in (sp.kv_lora_rank, sp.qk_rope_head_dim)]
+
+    def queries(sp, c):
+        return (rand((2, c, sp.heads, sp.qk_nope_head_dim)),
+                rand((2, c, sp.heads, sp.qk_rope_head_dim)))
+
+    sp, lp = cfg.attn(FULL), first_layer(FULL)
+    cp, rp = pools(sp, 1 + 2 * pages_per)
+    for name, c in steps:
+        q_nope, q_rope = queries(sp, c)
         positions = off[:, None] + jnp.arange(c)[None, :]
         mask = jnp.arange(ctx)[None, None, None, :] <= positions[:, None, :, None]
-        ref, got = (_attend_latent(lp, q_nope, q_rope, *pools, 0, table, off,
-                                   mask, cfg, kern, kernel_interpret)
-                    for kern in ("gather", "paged"))
-        out.append((name, ref, got))
+        out.append((f"latent_attention_{name}", *(
+            _attend_latent(lp, q_nope, q_rope, cp, rp, 0, table, off, mask, sp,
+                           **kern) for kern in kerns)))
+    if SLIDING in cfg.kinds:
+        # rows deep into their window: one at the ring's wrap, one short
+        ws, wl = cfg.attn(SLIDING), first_layer(SLIDING)
+        cols = window_ring_pages(cfg, page_size, 2)
+        ring = (1 + jnp.arange(2 * cols, dtype=jnp.int32)).reshape(2, cols)
+        wcp, wrp = pools(ws, 1 + 2 * cols)
+        woff = jnp.asarray([cols * page_size + ws.window // 2, 3], jnp.int32)
+        for name, c in steps:
+            q_nope, q_rope = queries(ws, c)
+            positions = woff[:, None] + jnp.arange(c)[None, :]
+            out.append((f"swa_latent_attention_{name}", *(
+                _attend_window(wl, q_nope, q_rope, wcp, wrp, 0, ring, woff,
+                               positions, ws, None, **kern) for kern in kerns)))
+    if cfg.index_topk:
+        from arkflow_tpu.ops.ragged_attention import dsa_index_scores
+
+        ip = rand((1, 1 + 2 * pages_per, page_size, sp.index_head_dim))
+        for name, c in steps:
+            q_i = rand((2, c, sp.index_n_heads, sp.index_head_dim))
+            w = jax.random.normal(next(keys), (2, c, sp.index_n_heads), jnp.float32)
+            positions = off[:, None] + jnp.arange(c)[None, :]
+            seen = jnp.arange(ctx)[None, None, :] <= positions[..., None]
+            out.append((f"dsa_index_scores_{name}", *(
+                jnp.where(seen, s, 0.0) for s in (
+                    index_scores(q_i, w, ip[0, table].reshape(2, ctx, -1)),
+                    dsa_index_scores(q_i, w, ip, 0, table, off,
+                                     interpret=kernel_interpret)))))
+        # attention over a GIVEN selection of ``page`` positions a query
+        # (the first row's short of one entry): the kernel in place under
+        # the choice as a mask against the rows gathered by index
+        k = page_size
+        for name, c in steps:
+            q_nope, q_rope = queries(sp, c)
+            sel = jnp.stack([jax.random.permutation(next(keys), n0)[:k]
+                             for _ in range(2 * c)]).reshape(2, c, k)
+            sel = jnp.where(jnp.arange(2)[:, None, None] == 1, 0, sel).astype(jnp.int32)
+            ok = jnp.broadcast_to(jnp.arange(k)[None, None, :] < jnp.asarray(
+                [k - 1, 1])[:, None, None], sel.shape)
+            chosen = (jax.nn.one_hot(sel, ctx) * ok[..., None]).max(2)
+            out.append((
+                f"dsa_sparse_attention_{name}",
+                _attend_selected(lp, q_nope, q_rope, cp, rp, 0, table, sel, ok,
+                                 sp, None, **kerns[0]),
+                _attend_selected(lp, q_nope, q_rope, cp, rp, 0, table,
+                                 chosen.astype(jnp.float32), ok, sp, None,
+                                 off=off, **kerns[1])))
     # the expert product: tokens routed among a HANDFUL of the first expert
     # layer's experts, so that the XLA twin, which multiplies every expert
     # it is given, copies those few (94 MB at Kanana-2 widths, by static
     # slices: an index array over the stack cost 1.9 GB on a v5e) and not
     # the layer (1.2 GB); the kernel reads the whole stack as when serving
-    e, k = cfg.n_routed_experts, cfg.num_experts_per_tok
+    e, k = cfg.held[1], cfg.num_experts_per_tok
     few = min(e, 8)
-    x = jax.random.normal(next(keys), (16, cfg.dim), jnp.float32).astype(jnp.bfloat16)
+    x = rand((16, cfg.dim))
     chosen = jnp.argsort(jax.random.uniform(next(keys), (16, few)), axis=-1)[:, :min(k, few)]
     cw = jax.nn.one_hot(chosen, e, dtype=jnp.float32).sum(1) * (
         cfg.routed_scaling_factor / k)
     cw = jnp.concatenate([cw, jnp.ones((16, cfg.n_shared_experts))], axis=-1)
-    ex = params["layers"]["experts"]
+    ex = params[next(r[0] for r in layer_runs(cfg) if r[4])]["experts"]
     cols = jnp.concatenate([jnp.arange(few), jnp.arange(e, e + cfg.n_shared_experts)])
     twin = [jnp.concatenate([ex[w][0, :few], ex[w][0, e:]])  # static slices
             for w in ("w_gate", "w_up", "w_down")]
@@ -271,6 +609,13 @@ def paged_prefill(params: dict, cfg: DecoderConfig, input_ids, lengths,
     paths read later; ``attention_kernel`` picks only its expert product.
     A routed model's step returns its routing counters as a fourth value.
     """
+    if cfg.latent and cfg.layered:
+        from arkflow_tpu.errors import ConfigError
+
+        raise ConfigError(
+            "a model with a layer pattern (sliding or indexed latent layers) "
+            "prefills in chunks through the cache (prefill_chunk > 0): the "
+            "one-shot prefill attends over its own block under one mask")
     b, t = input_ids.shape
     page = k_pages.shape[2]
     dh = cfg.dim // cfg.heads
@@ -315,12 +660,11 @@ def paged_prefill(params: dict, cfg: DecoderConfig, input_ids, lengths,
 
     moe = ()  # a routed model appends its counters (``moe_step_stats``)
     if cfg.latent:
-        def attend(lp, q_nope, q_rope, c, k_r, cp, rp, li):
-            return mla_expanded_attention(lp, q_nope, q_rope, c, k_r, mask, cfg)
-
         x, new_k, new_v, *moe = _latent_layers(
             params, cfg, x, k_pages, v_pages, positions, page_idx, offset,
-            attend, pos_valid, attention_kernel, kernel_interpret)
+            pos_valid, page_table=page_table, off=None, mask=mask, block=True,
+            attention_kernel=attention_kernel,
+            kernel_interpret=kernel_interpret)
     else:
         (x,), (new_k, new_v) = jax.lax.scan(
             layer, (x,), (params["layers"], k_pages, v_pages))
@@ -370,8 +714,11 @@ def paged_prefill_chunk(params: dict, cfg: DecoderConfig, input_ids, chunk_off,
     tolerance; the serving layer gates the swap on argmax parity.
     """
     b, t = input_ids.shape
+    # a layer pattern's table is (kept pages, the window pool's ring)
+    tables, page_table = page_table, (
+        page_table[0] if isinstance(page_table, tuple) else page_table)
     p_slots = page_table.shape[1]
-    page = k_pages.shape[2]
+    page = jax.tree_util.tree_leaves(k_pages)[0].shape[2]
     ctx = p_slots * page
     dh = cfg.dim // cfg.heads
     group = cfg.heads // cfg.kv_heads
@@ -428,14 +775,11 @@ def paged_prefill_chunk(params: dict, cfg: DecoderConfig, input_ids, chunk_off,
     if cfg.latent:
         # the same causal rule over the latent rows: this chunk's own rows
         # were just scattered, earlier chunks' come back through the table
-        def attend(lp, q_nope, q_rope, c, k_r, cp, rp, li):
-            return _attend_latent(lp, q_nope, q_rope, cp, rp, li, page_table,
-                                  chunk_off, mask, cfg, attention_kernel,
-                                  kernel_interpret)
-
         x, new_k, new_v, *moe = _latent_layers(
             params, cfg, x, k_pages, v_pages, positions, page_idx, offset,
-            attend, pos_valid, attention_kernel, kernel_interpret)
+            pos_valid, page_table=tables, off=chunk_off, mask=mask,
+            block=False, attention_kernel=attention_kernel,
+            kernel_interpret=kernel_interpret)
     else:
         (x,), (new_k, new_v) = jax.lax.scan(
             layer, (x,), (params["layers"], k_pages, v_pages))
@@ -466,8 +810,10 @@ def paged_decode_step(params: dict, cfg: DecoderConfig, token_ids, lengths,
     context is never materialized and fully-invalid pages are skipped.
     """
     s = token_ids.shape[0]
+    tables, page_table = page_table, (
+        page_table[0] if isinstance(page_table, tuple) else page_table)
     p_slots = page_table.shape[1]
-    page = k_pages.shape[2]
+    page = jax.tree_util.tree_leaves(k_pages)[0].shape[2]
     ctx = p_slots * page
     dh = cfg.dim // cfg.heads
     group = cfg.heads // cfg.kv_heads
@@ -523,15 +869,12 @@ def paged_decode_step(params: dict, cfg: DecoderConfig, token_ids, lengths,
     moe = ()  # a routed model appends its counters (``moe_step_stats``)
     if cfg.latent:
         # inactive lanes write to the scratch page and route nowhere
-        def attend(lp, q_nope, q_rope, c, k_r, cp, rp, li):
-            return _attend_latent(lp, q_nope, q_rope, cp, rp, li, page_table,
-                                  lengths, valid, cfg, attention_kernel,
-                                  kernel_interpret)
-
         x, new_k, new_v, *moe = _latent_layers(
             params, cfg, x, k_pages, v_pages, positions, write_page[:, None],
-            write_off[:, None], attend, active[:, None], attention_kernel,
-            kernel_interpret)
+            write_off[:, None], active[:, None], page_table=tables,
+            off=lengths, mask=valid, block=False,
+            attention_kernel=attention_kernel,
+            kernel_interpret=kernel_interpret)
     else:
         (x,), (new_k, new_v) = jax.lax.scan(
             layer, (x,), (params["layers"], k_pages, v_pages))

@@ -39,6 +39,9 @@ from jax.experimental import pallas as pl
 
 #: rows of one kernel call (bounds VMEM: x, out and the float32 accumulator)
 _TOKEN_TILE = 128
+#: token tiles up to which each is a call of its own (a prefill chunk);
+#: more of them (a one-shot prefill of a long prompt) run under ``lax.map``
+_UNROLLED_TILES = 4
 
 
 def expert_swiglu_dense(x, cw, w_gate, w_up, w_down):
@@ -164,8 +167,13 @@ def moe_expert_swiglu(x, cw, w_gate, w_up, w_down, layer=None, *,
         # padding rows carry weight 0 everywhere: they hit no expert
         x = jnp.pad(x, ((0, t_pad - t), (0, 0)))
         cw = jnp.pad(cw, ((0, t_pad - t), (0, 0)))
-    if t_pad == tile:
-        return _one_tile(x, cw, w_gate, w_up, w_down, layer, interpret)[:t]
+    if t_pad <= _UNROLLED_TILES * tile:
+        # a chunk's few tiles, one call each: inside ``lax.map`` the call
+        # is compiled under the loop's own (default) fast-memory limit,
+        # which three double-buffered [5120, 384] blocks exceed
+        out = [_one_tile(x[i:i + tile], cw[i:i + tile], w_gate, w_up, w_down,
+                         layer, interpret) for i in range(0, t_pad, tile)]
+        return jnp.concatenate(out)[:t] if len(out) > 1 else out[0][:t]
     out = jax.lax.map(
         lambda xc: _one_tile(xc[0], xc[1], w_gate, w_up, w_down, layer, interpret),
         (x.reshape(-1, tile, d), cw.reshape(-1, tile, cw.shape[1])))

@@ -313,9 +313,18 @@ def paged_flash_attention(q, k_pages, v_pages, page_table, off, *,
 _LATENT_GROUP = 8
 
 
+def _window_start(first, window: int, cols: int):
+    """The first page GROUP a tile whose first query sits at ``first`` walks
+    under a lower bound: the one that holds ``first - (window - 1)``."""
+    return jnp.maximum(first - (window - 1), 0) // cols
+
+
 def _latent_kernel(layer_ref, off_ref, table_ref, ql_ref, qr_ref, *rest,
                    page: int, heads: int, tile_c: int, steps: int,
-                   group: int, scale: float):
+                   group: int, scale: float, window: int = 0,
+                   masked: bool = False):
+    if masked:  # [tile_c, cols] float32, > 0 where the query may attend
+        allow_ref, *rest = rest
     c_refs, r_refs = rest[:group], rest[group:2 * group]
     o_ref, o_acc, m_acc, l_acc = rest[2 * group:]
     bi = pl.program_id(0)
@@ -323,8 +332,10 @@ def _latent_kernel(layer_ref, off_ref, table_ref, ql_ref, qr_ref, *rest,
     si = pl.program_id(2)
     rows = ql_ref.shape[0]
     cols = group * page
+    if window:  # the walk starts at the window's first group, not at 0
+        si = si + _window_start(off_ref[bi] + ci * tile_c, window, cols)
 
-    @pl.when(si == 0)
+    @pl.when(pl.program_id(2) == 0)
     def _init():
         o_acc[:] = jnp.zeros_like(o_acc)
         m_acc[:] = jnp.full_like(m_acc, _NEG)
@@ -345,7 +356,14 @@ def _latent_kernel(layer_ref, off_ref, table_ref, ql_ref, qr_ref, *rest,
                   ) * scale                                       # [rows, cols]
         r = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 0)
         c = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 1)
-        scores = jnp.where(si * cols + c <= first + r // heads, scores, _NEG)
+        keep = si * cols + c <= first + r // heads
+        if window:
+            keep = keep & (si * cols + c > first + r // heads - window)
+        if masked:
+            allow = jnp.broadcast_to(allow_ref[...][:, None, :],
+                                     (tile_c, heads, cols)).reshape(rows, cols)
+            keep = keep & (allow > 0.0)
+        scores = jnp.where(keep, scores, _NEG)
         m = m_acc[:, :1]
         m_new = jnp.maximum(m, scores.max(axis=-1, keepdims=True))
         p = jnp.exp(scores - m_new)
@@ -357,17 +375,21 @@ def _latent_kernel(layer_ref, off_ref, table_ref, ql_ref, qr_ref, *rest,
             p.astype(kc.dtype), kc, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    @pl.when(si == steps - 1)
+    @pl.when(pl.program_id(2) == steps - 1)
     def _fin():
         # key 0 is admissible to every query and the first step is always
-        # within the bound, so l is never truly zero (see _paged_kernel)
+        # within the bound, so l is never truly zero (see _paged_kernel);
+        # under a lower bound a query's own key is, in some step of its walk
         o_ref[...] = (o_acc[:] / jnp.maximum(l_acc[:, :1], 1e-30)
                       ).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+@functools.partial(jax.jit,
+                   static_argnames=("scale", "interpret", "window", "name"))
 def mla_paged_attention(q_lat, q_rope, c_pages, r_pages, layer, page_table,
-                        off, *, scale: float, interpret: bool = False):
+                        off, *, scale: float, interpret: bool = False,
+                        window: int = 0, name: str = "mla_paged_attention",
+                        allowed=None):
     """Absorbed latent attention straight from the latent page pools.
 
     q_lat: [B, C, H, L] (queries carried into the latent space), q_rope:
@@ -376,17 +398,33 @@ def mla_paged_attention(q_lat, q_rope, c_pages, r_pages, layer, page_table,
     kernel's index maps). Query i of row b sits at absolute position
     ``off[b] + i`` and attends keys 0..off+i with score
     ``(q_lat . c + q_rope . k_r) * scale``. Returns ``sum p c``:
-    [B, C, H, L] in q_lat's dtype — the caller applies ``W_uv``."""
+    [B, C, H, L] in q_lat's dtype — the caller applies ``W_uv``.
+
+    ``window`` > 0 (a sliding layer) bounds the keys below too — query at t
+    attends ``t - window < s <= t`` — and ``page_table`` is then a RING:
+    logical page i sits in column ``i % columns``. A (row, query tile) walks
+    only the page groups its window touches, from the one that holds its
+    first query's oldest key. ``allowed`` [B, C, P * page] (> 0: attend)
+    narrows each query's keys further — an indexer's choice —, the causal
+    bound still applied. ``name`` names the kernel in a trace."""
     b, c, h, lat = q_lat.shape
     rope = q_rope.shape[-1]
     page = c_pages.shape[2]
     group = _LATENT_GROUP
-    steps = -(-page_table.shape[1] // group)
     table = jnp.asarray(page_table, jnp.int32)
-    if steps * group != table.shape[1]:
-        # padding entries name the scratch page; the causal bound hides them
-        table = jnp.pad(table, ((0, 0), (0, steps * group - table.shape[1])))
-    tile_c = c if c * h <= _PAGED_ROWS else max(8, _PAGED_ROWS // h // 8 * 8)
+    ring = table.shape[1]
+    # rows of a tile: the [rows, L] float32 accumulator and the query and
+    # output blocks grow with the latent width; past 512 the rows shrink
+    cap = _PAGED_ROWS * 512 // max(lat, 512)
+    tile_c = c if c * h <= cap else max(8, cap // h // 8 * 8)
+    if window:
+        # groups from the first query's oldest key to the tile's last query
+        steps = (window + tile_c - 2) // (group * page) + 2
+    else:
+        steps = -(-ring // group)
+        if steps * group != ring:
+            # padding entries name the scratch page; the causal bound hides them
+            table = jnp.pad(table, ((0, 0), (0, steps * group - ring)))
     c_pad = -(-c // tile_c) * tile_c
     if c_pad != c:
         pad = ((0, 0), (0, c_pad - c), (0, 0), (0, 0))
@@ -397,24 +435,38 @@ def mla_paged_attention(q_lat, q_rope, c_pages, r_pages, layer, page_table,
     def _q_index(bi, ci, si, *_):
         return (bi, ci, 0)
 
+    narrowed = []
+    if allowed is not None:
+        cols = group * page
+        allowed = jnp.pad(allowed.astype(jnp.float32), (
+            (0, 0), (0, c_pad - c), (0, steps * cols - allowed.shape[-1])))
+        narrowed = [(allowed, pl.BlockSpec(
+            (None, tile_c, cols), lambda bi, ci, si, *_: (bi, ci, si)))]
+
     def _page_index(g):
         def index(bi, ci, si, layer_ref, off_ref, table_ref):
+            first = off_ref[bi] + ci * tile_c
+            max_pos = first + (tile_c - 1)
+            if window:
+                si = si + _window_start(first, window, group * page)
             pi = si * group + g
-            max_pos = off_ref[bi] + ci * tile_c + (tile_c - 1)
+            col = pi % ring if window else pi
             return (layer_ref[0],
-                    jnp.where(pi * page <= max_pos, table_ref[bi, pi], 0),
+                    jnp.where(pi * page <= max_pos, table_ref[bi, col], 0),
                     0, 0)
         return index
 
     kernel = functools.partial(
         _latent_kernel, page=page, heads=h, tile_c=tile_c, steps=steps,
-        group=group, scale=float(scale))
+        group=group, scale=float(scale), window=window,
+        masked=allowed is not None)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(b, c_pad // tile_c, steps),
         in_specs=[
             pl.BlockSpec((None, rows, lat), _q_index),
             pl.BlockSpec((None, rows, rope), _q_index),
+            *[spec for _, spec in narrowed],
             *[pl.BlockSpec((None, None, page, lat), _page_index(g))
               for g in range(group)],
             *[pl.BlockSpec((None, None, page, rope), _page_index(g))
@@ -432,9 +484,102 @@ def mla_paged_attention(q_lat, q_rope, c_pages, r_pages, layer, page_table,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, c_pad * h, lat), q_lat.dtype),
         interpret=interpret,
-        name="mla_paged_attention",
+        name=name,
     )(jnp.asarray(layer, jnp.int32).reshape(1), jnp.asarray(off, jnp.int32),
       table, q_lat.reshape(b, c_pad * h, lat),
-      q_rope.reshape(b, c_pad * h, rope),
+      q_rope.reshape(b, c_pad * h, rope), *[a for a, _ in narrowed],
       *[c_pages] * group, *[r_pages] * group)
     return out.reshape(b, c_pad, h, lat)[:, :c]
+
+
+# -- the indexer's scores (learned sparse attention) ----------------------------
+#
+# A full layer with an indexer (DeepSeek-V3.2's lightning indexer) scores every
+# cached token for every query: ``I(t, s) = sum_j w_j(t) relu(q_j(t) . k(s))``
+# over ``Hi`` index heads, against ONE index key a token. The kernel walks the
+# row's page table like ``mla_paged_attention`` (page groups of 128 keys, the
+# pool whole with the layer scalar-prefetched) and writes the scores; the top-k
+# over them is the caller's.
+
+
+def _index_kernel(layer_ref, off_ref, table_ref, q_ref, w_ref, *rest,
+                  page: int, heads: int, tile_c: int, group: int):
+    k_refs, o_ref = rest[:group], rest[group]
+    bi = pl.program_id(0)
+    ci = pl.program_id(1)
+    si = pl.program_id(2)
+    cols = group * page
+    max_pos = off_ref[bi] + ci * tile_c + (tile_c - 1)
+
+    @pl.when(si * cols <= max_pos)
+    def _score():
+        kk = jnp.concatenate([r[...] for r in k_refs], axis=0)    # [cols, Di]
+        dots = jax.lax.dot_general(q_ref[...], kk, (((1,), (1,)), ((), ())),
+                                   preferred_element_type=jnp.float32)
+        weighed = jnp.maximum(dots, 0.0) * w_ref[...]             # [rows, cols]
+        o_ref[...] = weighed.reshape(tile_c, heads, cols).sum(axis=1)
+
+    @pl.when(si * cols > max_pos)
+    def _past():  # keys after every query of the tile: the caller masks them
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def dsa_index_scores(q_i, w, index_pages, layer, page_table, off, *,
+                     interpret: bool = False):
+    """Index scores straight from the paged index-key pool.
+
+    q_i: [B, C, Hi, Di] index queries, w: [B, C, Hi] float32 head weights;
+    index_pages: [layers, pages, page, Di] (the WHOLE pool; ``layer`` picks
+    the slice in the index maps). Query i of row b sits at ``off[b] + i``.
+    Returns float32 [B, C, P * page]: ``sum_j w_j relu(q_j . k)`` for every
+    key of the row's table (bfloat16 operands, float32 accumulation); keys
+    in groups past a tile's last query read 0 — the caller masks by
+    position anyway."""
+    b, c, h, di = q_i.shape
+    page = index_pages.shape[2]
+    group = _LATENT_GROUP
+    table = jnp.asarray(page_table, jnp.int32)
+    ctx = table.shape[1] * page
+    steps = -(-table.shape[1] // group)
+    if steps * group != table.shape[1]:
+        table = jnp.pad(table, ((0, 0), (0, steps * group - table.shape[1])))
+    tile_c = c if c * h <= 512 or c % 8 else 8
+    from jax.experimental.pallas import tpu as pltpu
+
+    def _q_index(bi, ci, si, *_):
+        return (bi, ci, 0)
+
+    def _page_index(g):
+        def index(bi, ci, si, layer_ref, off_ref, table_ref):
+            pi = si * group + g
+            max_pos = off_ref[bi] + ci * tile_c + (tile_c - 1)
+            return (layer_ref[0],
+                    jnp.where(pi * page <= max_pos, table_ref[bi, pi], 0),
+                    0, 0)
+        return index
+
+    rows = tile_c * h
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(b, c // tile_c, steps),
+        in_specs=[
+            pl.BlockSpec((None, rows, di), _q_index),
+            pl.BlockSpec((None, rows, 1), _q_index),
+            *[pl.BlockSpec((None, None, page, di), _page_index(g))
+              for g in range(group)],
+        ],
+        out_specs=pl.BlockSpec((None, tile_c, group * page),
+                               lambda bi, ci, si, *_: (bi, ci, si)),
+    )
+    out = pl.pallas_call(
+        functools.partial(_index_kernel, page=page, heads=h, tile_c=tile_c,
+                          group=group),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, c, steps * group * page), jnp.float32),
+        interpret=interpret,
+        name="dsa_index_topk_scores",
+    )(jnp.asarray(layer, jnp.int32).reshape(1), jnp.asarray(off, jnp.int32),
+      table, q_i.astype(jnp.bfloat16).reshape(b, c * h, di),
+      w.astype(jnp.float32).reshape(b, c * h, 1), *[index_pages] * group)
+    return out[..., :ctx]

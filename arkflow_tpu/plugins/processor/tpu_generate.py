@@ -27,8 +27,10 @@ Config:
     model: decoder_lm
     model_config: {vocab_size: 2048, ...}   # latent attention (MLA) +
                              # routed experts: kv_lora_rank, n_routed_experts,
-                             # ... (docs/CONFIG.md); such a model serves
-                             # through serving: continuous on one chip only
+                             # ...; a layer pattern: layer_types, swa_*,
+                             # index_*, experts_held (docs/CONFIG.md); such
+                             # a model serves through serving: continuous
+                             # on one chip only
     text_field: __value__
     tokenizer: meta-llama/Llama-3-8B     # optional (hash fallback otherwise)
     max_input: 256

@@ -47,9 +47,11 @@ import numpy as np
 from arkflow_tpu.errors import ConfigError, StepDeadlineExceeded
 from arkflow_tpu.models.decoder import DecoderConfig
 from arkflow_tpu.models.paged_decode import (
+    cache_spec,
     init_page_pool,
     paged_decode_step,
     paged_prefill,
+    window_ring_pages,
 )
 from arkflow_tpu.obs import global_registry
 from arkflow_tpu.obs.trace import (annotated, current_scope, loop_stage,
@@ -90,7 +92,8 @@ class _Request:
     chunks: int = 0
     shared_tokens: int = 0
     #: a routed-expert model's counters over this prompt's chunks so far
-    #: (pairs, distinct experts, largest load, chunks), kept on the device
+    #: (pairs, distinct experts, largest load, chunks, then what else the
+    #: model's steps count: ``_chunk_counts``), kept on the device
     chunk_moe: Optional[object] = None
 
 
@@ -145,13 +148,29 @@ def pack_operands(ids, a, b, table) -> np.ndarray:
                            for part in (ids, a, b, table)])
 
 
-def unpack_operands(packed, rows: int, pages: int):
+def unpack_operands(packed, rows: int, pages: int, ring: int = 0):
     """Inside a step's program: ``pack_operands``' four parts again (static
-    slices; ``c`` is whatever the array's size leaves)."""
-    c = packed.shape[0] // rows - 2 - pages
+    slices; ``c`` is whatever the array's size leaves). ``ring`` > 0: a
+    table row is the kept pages and then so many columns of the window
+    pool's ring, handed on as (kept, ring)."""
+    c = packed.shape[0] // rows - 2 - pages - ring
     ids, a, b, table = jnp.split(
         packed, [rows * c, rows * (c + 1), rows * (c + 2)])
-    return ids.reshape(rows, c), a, b, table.reshape(rows, pages)
+    table = table.reshape(rows, pages + ring)
+    if ring:
+        table = (table[:, :pages], table[:, pages:])
+    return ids.reshape(rows, c), a, b, table
+
+
+def _chunk_counts(so_far, stats):
+    """A prompt's counters after one more chunk, on the device: ``so_far``
+    is the chunk before's token and then (pairs, experts hit, largest load,
+    chunks, the model's further counters); ``stats`` this chunk's (pairs,
+    hit, load, further counters). Sums, but the largest load."""
+    acc = so_far[1:]
+    return jnp.concatenate([
+        acc[:2] + stats[:2], jnp.maximum(acc[2:3], stats[2:3]), acc[3:4] + 1,
+        acc[4:] + stats[3:]])
 
 
 class GenerationServer:
@@ -203,6 +222,35 @@ class GenerationServer:
                 "tp axis; a latent (MLA) pool has one shared row per token "
                 "and no head axis to split — serve a latent-attention model "
                 "on one chip (no mesh)")
+        # a layer pattern: rows of more than one kind and lifetime (the
+        # model's ``cache_spec``). Window rows live in a pool of their own,
+        # each slot's pages in a ring that a step's queries fit in
+        self.prefill_chunk = int(prefill_chunk)
+        self._layered = bool(cfg.latent and cfg.layered)
+        # what a held share and a layer pattern add to a step's counters,
+        # in the order they follow the routing's three (``_note_moe``):
+        # pairs routed to the experts HELD here; keys an indexed layer
+        # attended and keys in its context, over queries and indexed layers
+        reg = global_registry()
+        self._extra_counters = [
+            {kind: reg.counter(metric, text, {"model": name, "kind": kind})
+             for kind in ("decode", "chunk")}
+            for has, metric, text in (
+                (cfg.experts_held is not None,
+                 "arkflow_gen_moe_held_assignments_total",
+                 "(token, expert) pairs routed to the experts held here"),
+                (cfg.index_topk, "arkflow_gen_dsa_selected_total",
+                 "keys the indexed layers attended (their indexer's choice)"),
+                (cfg.index_topk, "arkflow_gen_dsa_context_total",
+                 "keys in context of the indexed layers' queries"))
+            if has]
+        if self._layered:
+            self._refuse_layered(prefix_cache_pages, speculative_tokens)
+        self._win_cols = window_ring_pages(
+            cfg, page_size, self.prefill_chunk) if cfg.latent else 0
+        #: page 0 of the window pool is scratch too; every slot can hold a
+        #: whole ring, so a window page is never waited for
+        self.num_win_pages = 1 + self.slots * self._win_cols if self._win_cols else 0
         self._kv_io_sharding = None     # full pool  [L, pages, page, kv, dh]
         self._kv_layer_sharding = None  # scan slice [pages, page, kv, dh]
         self._repl_sharding = None
@@ -222,10 +270,10 @@ class GenerationServer:
             self._repl_sharding = replicated(mesh)
         self.k_pages, self.v_pages = self._init_pools()
 
-        # chunked prefill: prompts longer than this admit in fixed-size
-        # chunks interleaved with decode steps, so one long prompt never
-        # stalls every decode lane for a monolithic prefill (0 = one-shot)
-        self.prefill_chunk = int(prefill_chunk)
+        # chunked prefill (``prefill_chunk``, set above): prompts longer
+        # than it admit in fixed-size chunks interleaved with decode steps,
+        # so one long prompt never stalls every decode lane for a
+        # monolithic prefill (0 = one-shot)
         #: slot -> next absolute prefill offset (present while admitting)
         self._prefill_pos: dict[int, int] = {}
         self._turn_prefill = True  # alternate chunk/decode under contention
@@ -255,6 +303,10 @@ class GenerationServer:
         self._page_refs: dict[int, int] = {}
         self._slot_req: list[Optional[_Request]] = [None] * slots
         self._slot_pages: list[list[int]] = [[] for _ in range(slots)]
+        #: the window pool's ledger: free pages, and per slot the live pages
+        #: by logical index (oldest first; ring column = index % columns)
+        self._win_free: list[int] = list(range(1, self.num_win_pages))
+        self._slot_win: list[dict[int, int]] = [{} for _ in range(slots)]
         self._lengths = np.zeros(slots, np.int32)
         self._cur_tokens = np.zeros(slots, np.int32)
         # plain deque: admission needs FIFO peek, which asyncio.Queue only
@@ -465,6 +517,17 @@ class GenerationServer:
                                  "step, over expert layers",
                                  {"model": name, "kind": kind}))
             for kind in ("decode", "chunk", "prefill")}
+        #: (gauge, of the window pool?, bytes a page) per pool of a pattern
+        self.m_kv_live = [
+            (reg.gauge("arkflow_gen_kv_live_bytes", "bytes of cached rows "
+                       "live in a pool (pages held by slots or the prefix "
+                       "cache)", {"model": name, "pool": pool.name}),
+             bool(pool.window), page_size * pool.bytes_per_token)
+            for pool in cache_spec(cfg)] if self._layered else []
+        self.m_win_freed = reg.counter(
+            "arkflow_gen_window_pages_freed_total",
+            "window-pool pages freed because the window passed them "
+            "(a finished request's pages are not counted)", {"model": name})
         #: per-server TTFT reservoir behind health_report() percentiles
         #: (m_ttft is registry-global and would mix servers in-process)
         self._ttft_samples: deque[float] = deque(maxlen=2048)
@@ -478,6 +541,25 @@ class GenerationServer:
         self._rate_window: Optional[tuple[float, int]] = None
 
     # -- device plumbing (jit build / sharding / reset) --------------------
+
+    def _refuse_layered(self, prefix_cache_pages, speculative_tokens) -> None:
+        """What a model with a layer pattern is not served with yet."""
+        if self.prefill_chunk <= 0:
+            raise ConfigError(
+                "a model with a layer pattern (sliding or indexed latent "
+                "layers) prefills in chunks through the cache: set "
+                "prefill_chunk > 0 (its size bounds a slot's window pages)")
+        if prefix_cache_pages and self.cfg.sliding_window:
+            raise ConfigError(
+                "prefix_cache_pages does not compose with window pages: a "
+                "sliding layer's rows are freed as the window passes, so a "
+                "finished prompt has no full pages of them to donate")
+        if speculative_tokens:
+            raise ConfigError(
+                "speculative_tokens does not compose with indexed or sliding "
+                "layers: a rejected draft leaves its index key behind, which "
+                "a later query's indexer may select, and the verify step "
+                "does not slide the window pool")
 
     def _on_tpu(self) -> bool:
         """Backend check for the compiled Pallas path (the probe shared
@@ -505,11 +587,19 @@ class GenerationServer:
             # the verdict is the worst kernel's
             from arkflow_tpu.models.paged_decode import latent_kernel_probe
 
-            verdicts = [
-                {"kernel": name, **logits_parity(ref, got)}
-                for name, ref, got in latent_kernel_probe(
-                    self.params, self.cfg, self.page_size,
-                    self.kernel_interpret)]
+            # ONE program: op by op, the probes of a layer pattern's kernels
+            # at published widths are hundreds of small compiles (minutes)
+            names = []
+
+            def probe(params):
+                out = latent_kernel_probe(params, self.cfg, self.page_size,
+                                          self.kernel_interpret)
+                names.extend(n for n, _, _ in out)
+                return [(ref, got) for _, ref, got in out]
+
+            pairs = jax.jit(probe)(self.params)
+            verdicts = [{"kernel": name, **logits_parity(ref, got)}
+                        for name, (ref, got) in zip(names, pairs)]
             worst = max(verdicts, key=lambda v: (not v["ok"],
                                                  v["max_abs_diff"] / v["tol"]))
             return {**worst, "kernels": [v["kernel"] for v in verdicts]}
@@ -559,7 +649,8 @@ class GenerationServer:
     def _init_pools(self):
         """Fresh KV page pools, placed with their tensor-parallel sharding
         under a mesh (KV heads over ``tp``; replicated otherwise)."""
-        kp, vp = init_page_pool(self.cfg, self.num_pages, self.page_size)
+        kp, vp = init_page_pool(self.cfg, self.num_pages, self.page_size,
+                                self.num_win_pages)
         if self._kv_io_sharding is not None:
             kp = jax.device_put(kp, self._kv_io_sharding)
             vp = jax.device_put(vp, self._kv_io_sharding)
@@ -579,7 +670,7 @@ class GenerationServer:
         kv_layer = self._kv_layer_sharding
         kern = dict(attention_kernel=self.decode_kernel,
                     kernel_interpret=self.kernel_interpret)
-        pages = self.pages_per_slot
+        pages, ring = self.pages_per_slot, self._win_cols
         # what else rides a step, on the device already: a sampling server's
         # key, the step before's tokens (depth 2), a routed chunk's counters
         keyed = int(self._key is not None)
@@ -604,7 +695,7 @@ class GenerationServer:
         # so XLA updates them in place instead of copying hundreds of MB per
         # decode step.
         def _decode(params, packed, kp, vp, *dev):
-            tok, lens, act, table = unpack_operands(packed, self.slots, pages)
+            tok, lens, act, table = unpack_operands(packed, self.slots, pages, ring)
             tok = tok[:, 0]
             if piped:  # a lane packed as -1 takes the previous step's token
                 prev, *dev = dev
@@ -616,7 +707,7 @@ class GenerationServer:
             return out, kp, vp, *key
 
         def _prefill(params, packed, kp, vp, *key):
-            ids, _, lens, table = unpack_operands(packed, 1, pages)
+            ids, _, lens, table = unpack_operands(packed, 1, pages, ring)
             logits, kp, vp, *stats = paged_prefill(
                 params, cfg, ids, lens, table, kp, vp, return_logits=True,
                 kv_sharding=kv_layer, **kern)
@@ -624,7 +715,7 @@ class GenerationServer:
             return out, kp, vp, *key
 
         def _chunk(params, packed, kp, vp, *dev):
-            ids, off, clen, table = unpack_operands(packed, 1, pages)
+            ids, off, clen, table = unpack_operands(packed, 1, pages, ring)
             logits, kp, vp, *stats = paged_prefill_chunk(
                 params, cfg, ids, off, clen, table, kp, vp,
                 kv_sharding=kv_layer, **kern)
@@ -632,14 +723,12 @@ class GenerationServer:
                 # a routed model: the prompt's counters ride on the device
                 # behind the chunk before's token (``_no_counts`` at first)
                 so_far, *dev = dev
-                (pairs, hit, load), acc = stats[0], so_far[1:]
-                stats = [jnp.stack([acc[0] + pairs, acc[1] + hit,
-                                    jnp.maximum(acc[2], load), acc[3] + 1])]
+                stats = [_chunk_counts(so_far, stats[0])]
             out, *key = _pick(logits, dev, *stats)
             return out, kp, vp, *key
 
         def _verify(params, packed, kp, vp):
-            ids, lens, clen, table = unpack_operands(packed, self.slots, pages)
+            ids, lens, clen, table = unpack_operands(packed, self.slots, pages, ring)
             logits, kp, vp = paged_prefill_chunk(
                 params, cfg, ids, lens, clen, table, kp, vp, return_all=True,
                 kv_sharding=kv_layer, **kern)[:3]
@@ -677,7 +766,7 @@ class GenerationServer:
         zeros = functools.partial(jnp.zeros, dtype=jnp.int32,
                                   device=self._repl_sharding)
         self._no_prev = (zeros(self.slots),) if piped else ()
-        self._no_counts = zeros(5)
+        self._no_counts = zeros(5 + len(self._extra_counters))
 
     def _note_moe(self, kind: str, stats, steps: int = 1) -> None:
         """Record the routing counters (``moe_step_stats``, on the host) of
@@ -689,6 +778,10 @@ class GenerationServer:
         for _ in range(steps):
             experts_hit.observe(hit / steps / self._moe_layers)
         load.observe(max_load)
+        # a prompt's chunk count sits between the three and the rest
+        rest = stats[4:] if kind == "chunk" else stats[3:]
+        for counters, v in zip(self._extra_counters, rest):
+            counters[kind].inc(int(v))
 
     def _rebuild_after_incident(self) -> None:
         """Core rebuild hook (runs inside the heal gate, before the recovery
@@ -711,6 +804,8 @@ class GenerationServer:
         self._prefix_lengths.clear()
         self._page_refs.clear()
         self._free_pages = list(range(1, self.num_pages))
+        self._win_free = list(range(1, self.num_win_pages))
+        self._slot_win = [{} for _ in range(self.slots)]
         self.k_pages, self.v_pages = self._init_pools()
 
     # -- live hot-swap surface (tpu/swap.py) --------------------------------
@@ -1172,12 +1267,40 @@ class GenerationServer:
     # -- scheduler ---------------------------------------------------------
 
     def _table(self, *slots: int) -> np.ndarray:
-        """Page table rows of ``slots`` (default: all), padded to slot width."""
+        """Page table rows of ``slots`` (default: all), padded to slot width;
+        with a window pool, the slot's ring of window pages follows (the
+        page of logical index i in column ``i % columns``)."""
         slots = slots or range(self.slots)
-        table = np.zeros((len(slots), self.pages_per_slot), np.int32)
+        kept, ring = self.pages_per_slot, self._win_cols
+        table = np.zeros((len(slots), kept + ring), np.int32)
         for row, s in enumerate(slots):
             table[row, :len(self._slot_pages[s])] = self._slot_pages[s]
+            for i, p in self._slot_win[s].items():
+                table[row, kept + i % ring] = p
         return table
+
+    def _slide_window(self, slot: int, first: int, last: int) -> None:
+        """The slot's window pages for a step whose queries sit at positions
+        ``first..last``: pages the window has passed (every token before
+        ``first - (window - 1)``) go back to the pool, pages up to ``last``'s
+        are taken. The pool holds a ring for every slot, so one is always
+        free (``window_ring_pages``)."""
+        if not self._win_cols:
+            return
+        live = self._slot_win[slot]
+        oldest = max(first - (self.cfg.sliding_window - 1), 0) // self.page_size
+        for i in [i for i in live if i < oldest]:
+            self._win_free.append(live.pop(i))
+            self.m_win_freed.inc()
+        newest = last // self.page_size
+        start = max(oldest, max(live, default=-1) + 1)
+        for i in range(start, newest + 1):
+            live[i] = self._win_free.pop()
+
+    def _drop_window(self, slot: int) -> None:
+        """A finished (or failed) slot's window pages, back to the pool."""
+        self._win_free.extend(self._slot_win[slot].values())
+        self._slot_win[slot] = {}
 
     def _bucket(self, n: int) -> int:
         for b in self.prompt_buckets:
@@ -1200,7 +1323,8 @@ class GenerationServer:
         if shared_len > 0:
             self.m_prefix_hits.inc()
             self.m_prefix_pages.inc(shared_len // self.page_size)
-        if shared_len > 0 or (self.prefill_chunk and n > self.prefill_chunk):
+        if (shared_len > 0 or self._layered
+                or (self.prefill_chunk and n > self.prefill_chunk)):
             # cooperative admission: the serve loop interleaves prefill
             # steps with decode; the slot joins decode once fully prefilled.
             # A cached prefix starts prefill at its boundary — only the
@@ -1290,6 +1414,7 @@ class GenerationServer:
         for p in self._slot_pages[slot]:
             self._unref_page(p)
         self._slot_pages[slot] = []
+        self._drop_window(slot)
         self._lengths[slot] = 0
         self._cur_tokens[slot] = 0
         if req is not None and not req.future.done():
@@ -1319,6 +1444,7 @@ class GenerationServer:
             chunk = req.prompt[off:off + c]
             ids = np.zeros(c, np.int32)
             ids[:len(chunk)] = chunk
+            self._slide_window(slot, off, off + len(chunk) - 1)
             packed = pack_operands(ids, off, len(chunk), self._table(slot))
             new_off = off + len(chunk)
             so_far = () if kind != "chunk" or not self._moe_layers else (
@@ -1445,6 +1571,10 @@ class GenerationServer:
         total = self.num_pages - 1
         if total:
             self.m_pool_occupancy.set((total - len(self._free_pages)) / total)
+        for gauge, window, page_bytes in self.m_kv_live:
+            held = (self.num_win_pages - 1 - len(self._win_free) if window
+                    else total - len(self._free_pages))
+            gauge.set(held * page_bytes)
         # windowed tokens/sec: cheap enough to refresh every loop pass
         now = time.monotonic()
         if self._rate_window is None:
@@ -1459,8 +1589,14 @@ class GenerationServer:
         try:
             while not self._closed:
                 admitted = await self._admit_pending()
-                prefilling = [s for s in range(self.slots)
-                              if s in self._prefill_pos and self._slot_req[s]]
+                # first admitted, first prefilled: by slot index a long
+                # prompt in a high slot would wait out every later admission
+                # to a lower one (and, outputs being written in read order,
+                # hold their rows back with it)
+                prefilling = sorted(
+                    (s for s in range(self.slots)
+                     if s in self._prefill_pos and self._slot_req[s]),
+                    key=lambda s: self._slot_req[s].slot_at)
                 active = [s for s in range(self.slots)
                           if self._slot_req[s] and s not in self._prefill_pos]
                 self._update_gauges(len(active) + len(prefilling))
@@ -1515,6 +1651,7 @@ class GenerationServer:
             for p in self._slot_pages[s]:
                 self._unref_page(p)
             self._slot_pages[s] = []
+            self._drop_window(s)
             self._lengths[s] = 0
             self._cur_tokens[s] = 0
         while self._pending:
@@ -1569,6 +1706,8 @@ class GenerationServer:
             act[active] = True
             for s in active:
                 self._reserve_or_truncate(s, act)
+            for s in map(int, np.flatnonzero(act)):
+                self._slide_window(s, int(self._lengths[s]), int(self._lengths[s]))
             packed = pack_operands(self._cur_tokens, self._lengths, act,
                                    self._table())
         # off-loop + gated: one device-step of wall time (plus first compile)

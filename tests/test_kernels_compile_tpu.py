@@ -187,3 +187,81 @@ def test_moe_expert_swiglu_compiles(v5e, tokens):
         up, up, ((5, KANANA_EXPERTS, KANANA_WIDTH, KANANA_DIM), BF16), ((), I32))
     assert "tpu_custom_call" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 64 * 1024 * 1024
+
+
+# -- a layer pattern's kernels (dots3-note-prev widths, page 16, chunk 512) ---
+
+DOTS_DIM, DOTS_WIDTH, DOTS_HELD = 5120, 1536, 33      # 32 held + 1 shared
+DOTS_PAGES = 784                                      # 12,544 tokens a slot
+STEPS = pytest.mark.parametrize("rows,chunk", [(32, 1), (1, 512)],
+                                ids=["decode", "chunk512"])
+
+
+@STEPS
+def test_swa_latent_attention_compiles(v5e, rows, chunk):
+    """The sliding layers' window attention: 64 heads, latent 1,024, the
+    lower bound (513) over a ring of 65 window pages."""
+    from arkflow_tpu.ops.ragged_attention import mla_paged_attention
+
+    compiled = _compile(
+        lambda ql, qr, cp, rp, layer, ring, off: mla_paged_attention(
+            ql, qr, cp, rp, layer, ring, off, scale=256 ** -0.5, window=513,
+            name="swa_latent_attention"),
+        v5e, ((rows, chunk, 64, 1024), BF16), ((rows, chunk, 64, 64), BF16),
+        ((3, 2081, 16, 1024), BF16), ((3, 2081, 16, 64), BF16),
+        ((), I32), ((rows, 65), I32), ((rows,), I32))
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "swa_latent_attention" in text
+
+
+@pytest.mark.parametrize("rows,chunk", [(32, 1), (1, 64)], ids=["decode", "tile64"])
+def test_dsa_index_scores_compiles(v5e, rows, chunk):
+    """The indexer's scores: 64 index heads of 128 against every index key
+    of a 12,544-token table."""
+    from arkflow_tpu.ops.ragged_attention import dsa_index_scores
+
+    compiled = _compile(
+        lambda q, w, ip, layer, table, off: dsa_index_scores(
+            q, w, ip, layer, table, off),
+        v5e, ((rows, chunk, 64, 128), jnp.float32), ((rows, chunk, 64), jnp.float32),
+        ((2, 25089, 16, 128), BF16), ((), I32), ((rows, DOTS_PAGES), I32),
+        ((rows,), I32))
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "dsa_index_topk_scores" in text
+
+
+@pytest.mark.parametrize("tokens", [32, 512], ids=["decode", "chunk512"])
+def test_moe_expert_swiglu_compiles_at_the_held_share(v5e, tokens):
+    """32 held + 1 shared experts of hidden 5,120 x 1,536, three sliding
+    layers stacked (3.1 GB a matrix kind... a third each): no copy of a layer."""
+    from arkflow_tpu.ops.moe_experts import moe_expert_swiglu
+
+    up = ((3, DOTS_HELD, DOTS_DIM, DOTS_WIDTH), BF16)
+    compiled = _compile(
+        lambda x, cw, wg, wu, wd, layer: moe_expert_swiglu(x, cw, wg, wu, wd, layer),
+        v5e, ((tokens, DOTS_DIM), BF16), ((tokens, DOTS_HELD), jnp.float32),
+        up, up, ((3, DOTS_HELD, DOTS_WIDTH, DOTS_DIM), BF16), ((), I32))
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 1024 * 1024
+
+
+@pytest.mark.parametrize("pages", [DOTS_PAGES, 2048], ids=["12k", "32k"])
+@STEPS
+def test_dsa_sparse_attention_compiles(v5e, rows, chunk, pages):
+    """The indexed layers' attention: the latent kernel in place under the
+    indexer's choice (a float32 mask over the slot's table), 128 heads; at
+    the cell's 12,544-token table and at a 32,768-token one (the one form
+    serves every context)."""
+    from arkflow_tpu.ops.ragged_attention import mla_paged_attention
+
+    pool = 32 * pages + 1
+    compiled = _compile(
+        lambda ql, qr, cp, rp, layer, table, off, allowed: mla_paged_attention(
+            ql, qr, cp, rp, layer, table, off, scale=192 ** -0.5,
+            allowed=allowed, name="dsa_sparse_attention"),
+        v5e, ((rows, chunk, 128, 512), BF16), ((rows, chunk, 128, 64), BF16),
+        ((2, pool, 16, 512), BF16), ((2, pool, 16, 64), BF16), ((), I32),
+        ((rows, pages), I32), ((rows,), I32),
+        ((rows, chunk, pages * 16), jnp.float32))
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "dsa_sparse_attention" in text

@@ -528,7 +528,7 @@ def test_latent_model_refuses_kv_push_and_bad_routing_keys():
 
 
 @pytest.mark.parametrize("bad", [
-    {"scoring_func": "softmax"}, {"n_group": 4}, {"q_lora_rank": 8},
+    {"scoring_func": "softmax"}, {"n_group": 4}, {"q_lora_rank": 0},
     {"kv_lora_rank": 0}, {"num_experts_per_tok": 9},
     # accepted at their one published value only, and only together
     {"norm_topk_prob": False}, {"rope_interleave": False},

@@ -215,6 +215,40 @@ def test_generation_server_chunked_prefill_matches_one_shot():
     asyncio.run(go())
 
 
+def test_chunked_prefill_goes_to_the_slot_admitted_first():
+    """Two slots. A short prompt (slot 0) and a long one (slot 1, three more
+    chunks to go) are admitted; the short one finishes and a third request
+    takes slot 0 while the long one is still prefilling: the long prompt's
+    chunks come first — first admitted, first prefilled, whatever the slot —
+    and the outputs are what they were."""
+    fam = get_model("decoder_lm")
+    cfg = fam.make_config(**TINY)
+    params = fam.init(jax.random.PRNGKey(0), cfg)
+    prompts = [[5, 6, 7], list(range(10, 36)), [40, 41, 42, 43, 44, 45, 46, 47, 48]]
+
+    async def go():
+        server = GenerationServer(params, cfg, slots=2, page_size=4,
+                                  max_seq=64, prefill_chunk=8)
+        step, order = server._prefill_step, []
+
+        async def recording(slot, kind="chunk"):
+            order.append(prompts.index(server._slot_req[slot].prompt))
+            await step(slot, kind)
+
+        server._prefill_step = recording
+        outs = await asyncio.gather(*[
+            server.generate(p, n) for p, n in zip(prompts, (2, 4, 4))])
+        await server.close()
+        return outs, order
+
+    outs, order = asyncio.run(go())
+    assert order.count(1) == 4 and order.count(2) == 2
+    # the third request's chunks start only after the long prompt's last
+    assert max(i for i, r in enumerate(order) if r == 1) < order.index(2)
+    for prompt, out in zip(prompts, outs):
+        assert out == _reference_generate(fam, params, cfg, prompt, len(out))
+
+
 def test_speculative_decode_matches_greedy_exactly():
     """Speculative verify (n-gram drafts) must reproduce exact greedy
     outputs for repetitive AND non-repetitive prompts, and actually accept
