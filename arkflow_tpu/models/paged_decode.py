@@ -12,10 +12,11 @@ Page 0 is a reserved scratch page: inactive slots and masked prompt padding
 write there, which keeps the scatter free of conditionals.
 
 The cache's shape is the model's to state and this module's to own
-(``cache_spec``): per-head K/V (GQA; the pools are scanned layer by layer),
-or latent rows (MLA, ``cfg.latent``: one normed latent row and one rotated
-rope key a token for all heads; the pools ride whole through the layer loop,
-``_latent_layers``, and attention runs in the absorbed form over them). A
+(``cache_spec``): per-head K/V (GQA), or latent rows (MLA, ``cfg.latent``:
+one normed latent row and one rotated rope key a token for all heads;
+attention runs in the absorbed form over them). Either kind's pools ride
+whole through the layer loop (``_dense_layers``, ``_latent_layers``): a
+layer writes at (layer, page, offset) in place and attends by index. A
 latent model with a layer pattern has three kinds of row side by side: its
 full layers' latent rows, their index keys, and its sliding layers' (wider)
 latent rows, which live only while the window covers them — in a pool and
@@ -31,6 +32,8 @@ masked attention instead of custom CUDA paging.
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -545,8 +548,8 @@ def latent_kernel_probe(params: dict, cfg: DecoderConfig, page_size: int,
 
 
 def _constrain(x, sharding):
-    """Pin a per-layer pool slice to its tensor-parallel sharding (KV heads
-    over ``tp``). Under GSPMD the layer scan would otherwise be free to
+    """Pin a carried pool to its tensor-parallel sharding (KV heads over
+    ``tp``). Under GSPMD the layer scan would otherwise be free to
     all-gather the pools at every step — hundreds of MB of HBM churn; the
     constraint keeps scatter/gather partitioned. ``None`` (single-device
     serving) is a no-op so the unsharded path traces identically."""
@@ -555,13 +558,14 @@ def _constrain(x, sharding):
     return jax.lax.with_sharding_constraint(x, sharding)
 
 
-def _attend_paged(q, kp, vp, page_table, off, cfg: DecoderConfig,
-                  kv_sharding, interpret: bool):
-    """Page-table-indirect flash attention over one layer's pool slices
-    (ops/ragged_attention.paged_flash_attention): query i of row b sits at
-    absolute position ``off[b] + i`` and attends keys 0..off+i, read
-    straight from the pools — the [B, ctx, heads, dh] gather+repeat the
-    dense reference materializes per layer per step never exists.
+def _attend_paged(q, k_pages, v_pages, layer, page_table, off,
+                  cfg: DecoderConfig, kv_sharding, interpret: bool):
+    """Page-table-indirect flash attention over layer ``layer`` of the
+    WHOLE pools (ops/ragged_attention.paged_flash_attention): query i of
+    row b sits at absolute position ``off[b] + i`` and attends keys
+    0..off+i, read straight from the pools — neither the layer's slice nor
+    the [B, ctx, heads, dh] gather+repeat the dense reference materializes
+    per layer per step ever exists.
 
     Under tensor parallelism the kernel runs inside ``shard_map`` over the
     ``kv_sharding`` mesh's tp axis: attention is independent per KV head,
@@ -572,23 +576,75 @@ def _attend_paged(q, kp, vp, page_table, off, cfg: DecoderConfig,
     from arkflow_tpu.ops.ragged_attention import paged_flash_attention
 
     if kv_sharding is None:
-        return paged_flash_attention(q, kp, vp, page_table, off,
-                                     interpret=interpret)
+        return paged_flash_attention(q, k_pages, v_pages, layer, page_table,
+                                     off, interpret=interpret)
     from jax.sharding import PartitionSpec as P
 
     mesh = kv_sharding.mesh
     head_spec = P(None, None, "tp", None)  # q/out: [B, C, H, dh], H over tp
 
-    def local(q_, kp_, vp_, table_, off_):
-        return paged_flash_attention(q_, kp_, vp_, table_, off_,
-                                     interpret=interpret)
-
     return jax.shard_map(
-        local, mesh=mesh,
-        in_specs=(head_spec, kv_sharding.spec, kv_sharding.spec, P(), P()),
+        functools.partial(paged_flash_attention, interpret=interpret), mesh=mesh,
+        in_specs=(head_spec, kv_sharding.spec, kv_sharding.spec, P(), P(), P()),
         out_specs=head_spec,
         check_vma=False,
-    )(q, kp, vp, page_table, off)
+    )(q, k_pages, v_pages, layer, page_table, off)
+
+
+def _dense_layers(params: dict, cfg: DecoderConfig, x, k_pages, v_pages,
+                  positions, page_idx, offset, token_mask, *, page_table,
+                  off, mask, block: bool, kv_sharding, attention_kernel: str,
+                  kernel_interpret: bool):
+    """The layer loop of a per-head K/V (GQA) model over the paged cache,
+    with ``_latent_layers``' operands. The pools ride in the carry whole:
+    each layer scatters its tokens' K and V at (layer, page, offset) in
+    place and hands the pools on, so no layer's slice is copied out,
+    written into and stacked back.
+
+    ``page_idx`` / ``offset`` [B, S] place each token's row; query i of row
+    b sits at ``off[b] + i``; ``token_mask`` [B, S] names the tokens that
+    consume expert capacity (Switch MoE). A layer attends under ``mask`` —
+    over the block's own keys where ``block`` (the one-shot prefill), else
+    over the cache, this step's keys included: ``"paged"`` reads the page
+    table in place through the Pallas kernel (its causal bound key <=
+    off + i is exactly ``mask``), ``"gather"`` (the reference) gathers the
+    pages the table names out of the layer, [B, P * page] keys a row.
+    Returns (x, k_pages, v_pages)."""
+    b, t = positions.shape
+    dh = cfg.dim // cfg.heads
+    group = cfg.heads // cfg.kv_heads
+    ctx = page_table.shape[1] * k_pages.shape[2]
+
+    def layer(carry, scanned):
+        x, kp, vp = carry
+        lp, li = scanned
+        y = cm.rms_norm(lp["attn_norm"], x, cfg.norm_eps)
+        q = cm.dense(lp["wq"], y).reshape(b, t, cfg.heads, dh)
+        k = cm.dense(lp["wk"], y).reshape(b, t, cfg.kv_heads, dh)
+        v = cm.dense(lp["wv"], y).reshape(b, t, cfg.kv_heads, dh)
+        q = _rope(q, positions, cfg.rope_theta)
+        k = _rope(k, positions, cfg.rope_theta)
+        kp = _constrain(kp.at[li, page_idx, offset].set(k.astype(kp.dtype)),
+                        kv_sharding)
+        vp = _constrain(vp.at[li, page_idx, offset].set(v.astype(vp.dtype)),
+                        kv_sharding)
+        if attention_kernel == "paged" and not block:
+            attn = _attend_paged(q, kp, vp, li, page_table, off, cfg,
+                                 kv_sharding, kernel_interpret)
+        else:
+            if not block:
+                k = kp[li, page_table].reshape(b, ctx, cfg.kv_heads, dh).astype(x.dtype)
+                v = vp[li, page_table].reshape(b, ctx, cfg.kv_heads, dh).astype(x.dtype)
+            attn = cm.attention(q, jnp.repeat(k, group, axis=2),
+                                jnp.repeat(v, group, axis=2), mask)
+        x = x + cm.dense(lp["wo"], attn.reshape(b, t, cfg.heads * dh))
+        y = cm.rms_norm(lp["mlp_norm"], x, cfg.norm_eps)
+        x = x + _mlp(lp, y, cfg, token_mask=token_mask)
+        return (x, kp, vp), None
+
+    (x, k_pages, v_pages), _ = jax.lax.scan(
+        layer, (x, k_pages, v_pages), (params["layers"], jnp.arange(cfg.layers)))
+    return x, k_pages, v_pages
 
 
 def paged_prefill(params: dict, cfg: DecoderConfig, input_ids, lengths,
@@ -601,8 +657,8 @@ def paged_prefill(params: dict, cfg: DecoderConfig, input_ids, lengths,
     Returns (next_ids [B], k_pages, v_pages) — pools updated for all
     positions < lengths (padding scatters to scratch page 0).
 
-    ``kv_sharding``: optional per-layer-pool ``NamedSharding`` (KV heads over
-    ``tp``) for tensor-parallel serving; see ``_constrain``.
+    ``kv_sharding``: optional ``NamedSharding`` of the whole pools (KV heads
+    over ``tp``) for tensor-parallel serving; see ``_constrain``.
 
     A latent model attends in the published (expanded) form here — the
     block holds its own keys — and writes the latent rows the absorbed
@@ -618,8 +674,6 @@ def paged_prefill(params: dict, cfg: DecoderConfig, input_ids, lengths,
             "one-shot prefill attends over its own block under one mask")
     b, t = input_ids.shape
     page = k_pages.shape[2]
-    dh = cfg.dim // cfg.heads
-    group = cfg.heads // cfg.kv_heads
     positions = jnp.broadcast_to(jnp.arange(t)[None, :], (b, t))
     causal = jnp.tril(jnp.ones((t, t), bool))[None, None]
     key_valid = (jnp.arange(t)[None, :] < lengths[:, None])[:, None, None, :]
@@ -637,27 +691,6 @@ def paged_prefill(params: dict, cfg: DecoderConfig, input_ids, lengths,
     )                                                            # [B, T]
     offset = jnp.where(pos_valid, positions % page, 0)           # [B, T]
 
-    def layer(carry, lp_and_pools):
-        x, = carry
-        lp, kp, vp = lp_and_pools
-        y = cm.rms_norm(lp["attn_norm"], x, cfg.norm_eps)
-        q = cm.dense(lp["wq"], y).reshape(b, t, cfg.heads, dh)
-        k = cm.dense(lp["wk"], y).reshape(b, t, cfg.kv_heads, dh)
-        v = cm.dense(lp["wv"], y).reshape(b, t, cfg.kv_heads, dh)
-        q = _rope(q, positions, cfg.rope_theta)
-        k = _rope(k, positions, cfg.rope_theta)
-        kp = _constrain(kp.at[page_idx, offset].set(k.astype(jnp.bfloat16)),
-                        kv_sharding)
-        vp = _constrain(vp.at[page_idx, offset].set(v.astype(jnp.bfloat16)),
-                        kv_sharding)
-        kk = jnp.repeat(k, group, axis=2)
-        vv = jnp.repeat(v, group, axis=2)
-        attn = cm.attention(q, kk, vv, mask).reshape(b, t, cfg.heads * dh)
-        x = x + cm.dense(lp["wo"], attn)
-        y = cm.rms_norm(lp["mlp_norm"], x, cfg.norm_eps)
-        x = x + _mlp(lp, y, cfg, token_mask=pos_valid)
-        return (x,), (kp, vp)
-
     moe = ()  # a routed model appends its counters (``moe_step_stats``)
     if cfg.latent:
         x, new_k, new_v, *moe = _latent_layers(
@@ -666,8 +699,11 @@ def paged_prefill(params: dict, cfg: DecoderConfig, input_ids, lengths,
             attention_kernel=attention_kernel,
             kernel_interpret=kernel_interpret)
     else:
-        (x,), (new_k, new_v) = jax.lax.scan(
-            layer, (x,), (params["layers"], k_pages, v_pages))
+        x, new_k, new_v = _dense_layers(
+            params, cfg, x, k_pages, v_pages, positions, page_idx, offset,
+            pos_valid, page_table=page_table, off=None, mask=mask, block=True,
+            kv_sharding=kv_sharding, attention_kernel=attention_kernel,
+            kernel_interpret=kernel_interpret)
     x = cm.rms_norm(params["norm_out"], x, cfg.norm_eps)
     logits = cm.dense(params["lm_head"], x).astype(jnp.float32)
     last = jnp.clip(lengths - 1, 0, t - 1)
@@ -720,8 +756,6 @@ def paged_prefill_chunk(params: dict, cfg: DecoderConfig, input_ids, chunk_off,
     p_slots = page_table.shape[1]
     page = jax.tree_util.tree_leaves(k_pages)[0].shape[2]
     ctx = p_slots * page
-    dh = cfg.dim // cfg.heads
-    group = cfg.heads // cfg.kv_heads
 
     positions = chunk_off[:, None] + jnp.arange(t)[None, :]       # [B, C]
     pos_valid = jnp.arange(t)[None, :] < chunk_len[:, None]       # [B, C]
@@ -741,36 +775,6 @@ def paged_prefill_chunk(params: dict, cfg: DecoderConfig, input_ids, chunk_off,
     mask = key_pos <= positions[:, None, :, None]                 # [B,1,C,ctx]
     x = cm.embedding(params["embed"], input_ids)
 
-    def layer(carry, lp_and_pools):
-        x, = carry
-        lp, kp, vp = lp_and_pools
-        y = cm.rms_norm(lp["attn_norm"], x, cfg.norm_eps)
-        q = cm.dense(lp["wq"], y).reshape(b, t, cfg.heads, dh)
-        k = cm.dense(lp["wk"], y).reshape(b, t, cfg.kv_heads, dh)
-        v = cm.dense(lp["wv"], y).reshape(b, t, cfg.kv_heads, dh)
-        q = _rope(q, positions, cfg.rope_theta)
-        k = _rope(k, positions, cfg.rope_theta)
-        kp = _constrain(kp.at[page_idx, offset].set(k.astype(jnp.bfloat16)),
-                        kv_sharding)
-        vp = _constrain(vp.at[page_idx, offset].set(v.astype(jnp.bfloat16)),
-                        kv_sharding)
-        if attention_kernel == "paged":
-            attn = _attend_paged(q, kp, vp, page_table, chunk_off, cfg,
-                                 kv_sharding, kernel_interpret)
-            attn = attn.reshape(b, t, cfg.heads * dh)
-        else:
-            # earlier chunks' keys come back through the page gather (this
-            # chunk's own keys were just scattered, so they are included too)
-            kk = kp[page_table].reshape(b, ctx, cfg.kv_heads, dh).astype(x.dtype)
-            vv = vp[page_table].reshape(b, ctx, cfg.kv_heads, dh).astype(x.dtype)
-            kk = jnp.repeat(kk, group, axis=2)
-            vv = jnp.repeat(vv, group, axis=2)
-            attn = cm.attention(q, kk, vv, mask).reshape(b, t, cfg.heads * dh)
-        x = x + cm.dense(lp["wo"], attn)
-        y = cm.rms_norm(lp["mlp_norm"], x, cfg.norm_eps)
-        x = x + _mlp(lp, y, cfg, token_mask=pos_valid)
-        return (x,), (kp, vp)
-
     moe = ()  # a routed model appends its counters (``moe_step_stats``)
     if cfg.latent:
         # the same causal rule over the latent rows: this chunk's own rows
@@ -781,8 +785,12 @@ def paged_prefill_chunk(params: dict, cfg: DecoderConfig, input_ids, chunk_off,
             block=False, attention_kernel=attention_kernel,
             kernel_interpret=kernel_interpret)
     else:
-        (x,), (new_k, new_v) = jax.lax.scan(
-            layer, (x,), (params["layers"], k_pages, v_pages))
+        x, new_k, new_v = _dense_layers(
+            params, cfg, x, k_pages, v_pages, positions, page_idx, offset,
+            pos_valid, page_table=page_table, off=chunk_off, mask=mask,
+            block=False, kv_sharding=kv_sharding,
+            attention_kernel=attention_kernel,
+            kernel_interpret=kernel_interpret)
     x = cm.rms_norm(params["norm_out"], x, cfg.norm_eps)
     logits = cm.dense(params["lm_head"], x).astype(jnp.float32)
     if not return_all:
@@ -815,8 +823,6 @@ def paged_decode_step(params: dict, cfg: DecoderConfig, token_ids, lengths,
     p_slots = page_table.shape[1]
     page = jax.tree_util.tree_leaves(k_pages)[0].shape[2]
     ctx = p_slots * page
-    dh = cfg.dim // cfg.heads
-    group = cfg.heads // cfg.kv_heads
 
     positions = lengths[:, None]                                  # [S, 1]
     x = cm.embedding(params["embed"], token_ids[:, None])         # [S, 1, D]
@@ -832,40 +838,6 @@ def paged_decode_step(params: dict, cfg: DecoderConfig, token_ids, lengths,
     key_pos = jnp.arange(ctx)[None, :]                            # [1, ctx]
     valid = (key_pos <= lengths[:, None])[:, None, None, :]       # [S,1,1,ctx]
 
-    def layer(carry, lp_and_pools):
-        x, = carry
-        lp, kp, vp = lp_and_pools
-        y = cm.rms_norm(lp["attn_norm"], x, cfg.norm_eps)
-        q = cm.dense(lp["wq"], y).reshape(s, 1, cfg.heads, dh)
-        k = cm.dense(lp["wk"], y).reshape(s, 1, cfg.kv_heads, dh)
-        v = cm.dense(lp["wv"], y).reshape(s, 1, cfg.kv_heads, dh)
-        q = _rope(q, positions, cfg.rope_theta)
-        k = _rope(k, positions, cfg.rope_theta)
-        kp = _constrain(
-            kp.at[write_page, write_off].set(k[:, 0].astype(jnp.bfloat16)),
-            kv_sharding)
-        vp = _constrain(
-            vp.at[write_page, write_off].set(v[:, 0].astype(jnp.bfloat16)),
-            kv_sharding)
-        if attention_kernel == "paged":
-            # the single query sits at absolute position lengths[s]; the
-            # kernel's causal bound (key <= lengths) is exactly `valid`
-            attn = _attend_paged(q, kp, vp, page_table, lengths, cfg,
-                                 kv_sharding, kernel_interpret)
-            attn = attn.reshape(s, 1, cfg.heads * dh)
-        else:
-            # gather each slot's context from the pool: [S, P, page, kh, dh]
-            kk = kp[page_table].reshape(s, ctx, cfg.kv_heads, dh).astype(x.dtype)
-            vv = vp[page_table].reshape(s, ctx, cfg.kv_heads, dh).astype(x.dtype)
-            kk = jnp.repeat(kk, group, axis=2)
-            vv = jnp.repeat(vv, group, axis=2)
-            attn = cm.attention(q, kk, vv, valid).reshape(s, 1, cfg.heads * dh)
-        x = x + cm.dense(lp["wo"], attn)
-        y = cm.rms_norm(lp["mlp_norm"], x, cfg.norm_eps)
-        # inactive lanes must not consume expert capacity (MoE)
-        x = x + _mlp(lp, y, cfg, token_mask=active[:, None])
-        return (x,), (kp, vp)
-
     moe = ()  # a routed model appends its counters (``moe_step_stats``)
     if cfg.latent:
         # inactive lanes write to the scratch page and route nowhere
@@ -876,8 +848,15 @@ def paged_decode_step(params: dict, cfg: DecoderConfig, token_ids, lengths,
             attention_kernel=attention_kernel,
             kernel_interpret=kernel_interpret)
     else:
-        (x,), (new_k, new_v) = jax.lax.scan(
-            layer, (x,), (params["layers"], k_pages, v_pages))
+        # the single query sits at absolute position lengths[s]: the
+        # kernel's causal bound (key <= lengths) is exactly ``valid``;
+        # inactive lanes must not consume expert capacity (MoE)
+        x, new_k, new_v = _dense_layers(
+            params, cfg, x, k_pages, v_pages, positions, write_page[:, None],
+            write_off[:, None], active[:, None], page_table=page_table,
+            off=lengths, mask=valid, block=False, kv_sharding=kv_sharding,
+            attention_kernel=attention_kernel,
+            kernel_interpret=kernel_interpret)
     x = cm.rms_norm(params["norm_out"], x, cfg.norm_eps)
     logits = cm.dense(params["lm_head"], x).astype(jnp.float32)[:, -1, :]
     if not return_logits:

@@ -138,15 +138,17 @@ def ragged_flash_attention(q, k, v, lengths, *, causal: bool = False,
 # reuse one block copy and skip the math.
 #
 # Layout (what the TPU tiling accepts): a block takes ALL kv heads of a
-# page. The pool slice is viewed as [num_pages, page*kv_heads, dh] — a free
-# bitcast of the pool's HBM layout, rows ordered (slot, kv head) — and the
-# queries as [B, C*heads, dh], rows ordered (position, head): both views are
-# plain reshapes, and every block's last two dims equal the array's or are
-# (8k, dh). One [rows, dh] x [dh, page*kv_heads] product scores every query
-# head against every kv head of the page; entries whose kv head is not the
-# query head's own are masked with the causal bound. That is no more MXU or
-# VPU work than per-head [.., page]-wide products, which would fill only
-# page/128 of each lane tile, and GQA needs no ``jnp.repeat`` of K/V.
+# page. The pool rides whole and is viewed as [layers*num_pages,
+# page*kv_heads, dh] — a free bitcast of its HBM layout, rows ordered (slot,
+# kv head), the table offset to the layer's pages so the layer loop never
+# slices it — and the queries as [B, C*heads, dh], rows ordered
+# (position, head): both views are plain reshapes, and every block's last
+# two dims equal the array's or are (8k, dh). One [rows, dh] x
+# [dh, page*kv_heads] product scores every query head against every kv head
+# of the page; entries whose kv head is not the query head's own are masked
+# with the causal bound. That is no more MXU or VPU work than per-head
+# [.., page]-wide products, which would fill only page/128 of each lane
+# tile, and GQA needs no ``jnp.repeat`` of K/V.
 
 #: folded query rows (positions x heads) per program: bounds VMEM whatever
 #: the chunk length is ([rows, 128] f32 score tiles of 512 KiB)
@@ -213,26 +215,33 @@ def _paged_kernel(off_ref, table_ref, q_ref, k_ref, v_ref, o_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def paged_flash_attention(q, k_pages, v_pages, page_table, off, *,
+def paged_flash_attention(q, k_pages, v_pages, layer, page_table, off, *,
                           interpret: bool = False):
     """Flash attention that reads K/V straight from the serving page pools.
 
     q: [B, C, H, dh] — C queries per row at absolute positions
     ``off[b] + i`` (decode: C=1, off=lengths; chunked prefill: off=chunk
-    offset). k_pages/v_pages: [num_pages, page, kv_heads, dh] (one layer's
-    pool slice). page_table: [B, P] int32 — entries past a row's context
-    may be 0 (the scratch page; never read through the causal mask).
-    off: [B] int32.
+    offset). k_pages/v_pages: [layers, num_pages, page, kv_heads, dh] (the
+    WHOLE pools; ``layer`` picks the pages the kernel's index maps resolve
+    to, so the layer loop never slices a pool). page_table: [B, P] int32 —
+    entries past a row's context may be 0 (the scratch page; never read
+    through the causal mask). off: [B] int32.
 
     Query i attends keys 0..off+i — exactly the dense-gather reference's
     ``key_pos <= positions`` mask — with GQA resolved inside the kernel
     (no ``jnp.repeat`` of K/V). Returns [B, C, H, dh] in q's dtype.
     """
     b, c, h, dh = q.shape
-    n_pages, page, kvh, _ = k_pages.shape
+    layers, n_pages, page, kvh, _ = k_pages.shape
     if h % kvh:
         raise ValueError(f"q heads {h} must be a multiple of kv heads {kvh}")
     pages_per = page_table.shape[1]
+    # the layer rides in the page index: the pools are viewed as one run of
+    # layers * num_pages pages and the table names pages of that run. (A
+    # layer axis of its own, the layer scalar-prefetched into the index
+    # maps, cost 20 us a 16-lane call more on a v5e: 9 ns a grid step.)
+    table = (jnp.asarray(page_table, jnp.int32)
+             + jnp.asarray(layer, jnp.int32) * n_pages)
     # query tile: the whole chunk when it is small (block == array, any C),
     # else a multiple of 8 positions so the block's row count tiles
     tile_c = c if c * h <= _PAGED_ROWS else max(8, _PAGED_ROWS // h // 8 * 8)
@@ -249,9 +258,10 @@ def paged_flash_attention(q, k_pages, v_pages, page_table, off, *,
         pages_per=pages_per)
 
     def _page_index(bi, ci, pi, off_ref, table_ref):
-        # pages past the tile's causal bound resolve to the scratch page 0:
-        # the index stays constant across the remaining grid steps, so the
-        # pipeline skips the re-copy, and pl.when skips the math
+        # pages past the tile's causal bound resolve to the scratch page 0
+        # (of layer 0: never read either): the index stays constant across
+        # the remaining grid steps, so the pipeline skips the re-copy, and
+        # pl.when skips the math
         max_pos = off_ref[bi] + ci * tile_c + (tile_c - 1)
         return (jnp.where(pi * page <= max_pos, table_ref[bi, pi], 0), 0, 0)
 
@@ -278,10 +288,9 @@ def paged_flash_attention(q, k_pages, v_pages, page_table, off, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, c_pad * h, dh), q.dtype),
         interpret=interpret,
-    )(jnp.asarray(off, jnp.int32), jnp.asarray(page_table, jnp.int32),
-      q.reshape(b, c_pad * h, dh),
-      k_pages.reshape(n_pages, page * kvh, dh),
-      v_pages.reshape(n_pages, page * kvh, dh))
+    )(jnp.asarray(off, jnp.int32), table, q.reshape(b, c_pad * h, dh),
+      k_pages.reshape(layers * n_pages, page * kvh, dh),
+      v_pages.reshape(layers * n_pages, page * kvh, dh))
     return out.reshape(b, c_pad, h, dh)[:, :c]
 
 
@@ -297,10 +306,11 @@ def paged_flash_attention(q, k_pages, v_pages, page_table, off, *,
 # heads: a page is read ONCE and every head scores against it.
 #
 # Same grid idea as ``paged_flash_attention`` — (row, query tile, page
-# group) with the page table scalar-prefetched — with three differences:
+# group) with the page table scalar-prefetched, the pools riding whole —
+# with three differences:
 #
-# * the pools ride whole (``[layers, pages, page, width]``) with the layer
-#   index scalar-prefetched, so the layer loop never slices a pool;
+# * the layer is an axis of the pool view (``[layers, pages, page, width]``)
+#   with the layer index scalar-prefetched into the index maps;
 # * a grid step takes ``_LATENT_GROUP`` pages, each through its own
 #   BlockSpec over the same pool (the index map of the g-th resolves the
 #   row's (step * group + g)-th page): with one shared KV head a single

@@ -91,20 +91,17 @@ def replicated(mesh: Mesh) -> NamedSharding:
     return NamedSharding(mesh, P())
 
 
-def kv_pool_shardings(mesh: Mesh) -> tuple[NamedSharding, NamedSharding]:
-    """Shardings for the paged KV pools under tensor-parallel serving.
+def kv_pool_sharding(mesh: Mesh) -> NamedSharding:
+    """Sharding of the paged KV pools under tensor-parallel serving.
 
-    Returns ``(full_pool, per_layer)``: the full pool is
-    ``[layers, num_pages, page, kv_heads, dh]`` (the jitted steps' in/out
-    sharding), the per-layer slice inside the layer scan is
-    ``[num_pages, page, kv_heads, dh]`` (applied as a sharding constraint so
-    GSPMD keeps the pools partitioned instead of all-gathering hundreds of
-    MB per step). KV heads split over ``tp``; the page dims stay replicated,
-    so page-table gathers/scatters remain static-shaped and local."""
+    A pool is ``[layers, num_pages, page, kv_heads, dh]``: the jitted steps'
+    in/out sharding, and the constraint on the pools the layer scan carries
+    (so GSPMD keeps them partitioned instead of all-gathering hundreds of MB
+    per step). KV heads split over ``tp``; the page dims stay replicated, so
+    page-table gathers/scatters remain static-shaped and local."""
     if tp_size(mesh) > 1:
-        return (NamedSharding(mesh, P(None, None, None, "tp", None)),
-                NamedSharding(mesh, P(None, None, "tp", None)))
-    return replicated(mesh), replicated(mesh)
+        return NamedSharding(mesh, P(None, None, None, "tp", None))
+    return replicated(mesh)
 
 
 def validate_tp_heads(tp: int, kv_heads: int, who: str = "serving") -> None:

@@ -251,11 +251,10 @@ class GenerationServer:
         #: page 0 of the window pool is scratch too; every slot can hold a
         #: whole ring, so a window page is never waited for
         self.num_win_pages = 1 + self.slots * self._win_cols if self._win_cols else 0
-        self._kv_io_sharding = None     # full pool  [L, pages, page, kv, dh]
-        self._kv_layer_sharding = None  # scan slice [pages, page, kv, dh]
+        self._kv_io_sharding = None     # a pool: [L, pages, page, kv, dh]
         self._repl_sharding = None
         if mesh is not None:
-            from arkflow_tpu.parallel.mesh import (dp_size, kv_pool_shardings,
+            from arkflow_tpu.parallel.mesh import (dp_size, kv_pool_sharding,
                                                    replicated, tp_size,
                                                    validate_tp_heads)
 
@@ -266,7 +265,7 @@ class GenerationServer:
                     "serving: batch or tpu_inference for dp)")
             validate_tp_heads(tp_size(mesh), cfg.kv_heads,
                               who="continuous serving")
-            self._kv_io_sharding, self._kv_layer_sharding = kv_pool_shardings(mesh)
+            self._kv_io_sharding = kv_pool_sharding(mesh)
             self._repl_sharding = replicated(mesh)
         self.k_pages, self.v_pages = self._init_pools()
 
@@ -667,7 +666,7 @@ class GenerationServer:
         from arkflow_tpu.models.paged_decode import paged_prefill_chunk
 
         cfg = self.cfg
-        kv_layer = self._kv_layer_sharding
+        kv = self._kv_io_sharding
         kern = dict(attention_kernel=self.decode_kernel,
                     kernel_interpret=self.kernel_interpret)
         pages, ring = self.pages_per_slot, self._win_cols
@@ -702,7 +701,7 @@ class GenerationServer:
                 tok = jnp.where(tok < 0, prev, tok)
             logits, kp, vp, *stats = paged_decode_step(
                 params, cfg, tok, lens, act != 0, table, kp, vp,
-                return_logits=True, kv_sharding=kv_layer, **kern)
+                return_logits=True, kv_sharding=kv, **kern)
             out, *key = _pick(logits, dev, *stats)
             return out, kp, vp, *key
 
@@ -710,7 +709,7 @@ class GenerationServer:
             ids, _, lens, table = unpack_operands(packed, 1, pages, ring)
             logits, kp, vp, *stats = paged_prefill(
                 params, cfg, ids, lens, table, kp, vp, return_logits=True,
-                kv_sharding=kv_layer, **kern)
+                kv_sharding=kv, **kern)
             out, *key = _pick(logits, key, *stats)
             return out, kp, vp, *key
 
@@ -718,7 +717,7 @@ class GenerationServer:
             ids, off, clen, table = unpack_operands(packed, 1, pages, ring)
             logits, kp, vp, *stats = paged_prefill_chunk(
                 params, cfg, ids, off, clen, table, kp, vp,
-                kv_sharding=kv_layer, **kern)
+                kv_sharding=kv, **kern)
             if stats:
                 # a routed model: the prompt's counters ride on the device
                 # behind the chunk before's token (``_no_counts`` at first)
@@ -731,7 +730,7 @@ class GenerationServer:
             ids, lens, clen, table = unpack_operands(packed, self.slots, pages, ring)
             logits, kp, vp = paged_prefill_chunk(
                 params, cfg, ids, lens, clen, table, kp, vp, return_all=True,
-                kv_sharding=kv_layer, **kern)[:3]
+                kv_sharding=kv, **kern)[:3]
             return jnp.argmax(logits, axis=-1).astype(jnp.int32), kp, vp
 
         def bind(fn, n_dev: int, n_key: int):

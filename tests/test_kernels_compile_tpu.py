@@ -104,13 +104,14 @@ def test_segment_flash_is_a_mosaic_kernel(v5e):
 
 #: (kv_heads, heads, dh): Llama-3-8B head geometry, and the decoder default
 GEOMETRIES = {"llama3_8b": (8, 32, 128), "decoder_default": (4, 8, 32)}
-SLOTS, PAGE, PAGES_PER, POOL_PAGES = 8, 16, 32, 257
+SLOTS, PAGE, PAGES_PER, POOL_PAGES, POOL_LAYERS = 8, 16, 32, 257, 4
 
 
 def _paged_shapes(geometry: str, chunk: int):
+    """q, the WHOLE pools, the layer, the page table, the offsets."""
     kvh, h, dh = GEOMETRIES[geometry]
-    pool = ((POOL_PAGES, PAGE, kvh, dh), BF16)
-    return (((SLOTS, chunk, h, dh), BF16), pool, pool,
+    pool = ((POOL_LAYERS, POOL_PAGES, PAGE, kvh, dh), BF16)
+    return (((SLOTS, chunk, h, dh), BF16), pool, pool, ((), I32),
             ((SLOTS, PAGES_PER), I32), ((SLOTS,), I32))
 
 
@@ -134,24 +135,128 @@ def test_paged_flash_compiles_under_tp4_shard_map(v5e, chunk):
     per kv head."""
     from arkflow_tpu.models.decoder import llama3_8b
     from arkflow_tpu.models.paged_decode import _attend_paged
-    from arkflow_tpu.parallel.mesh import kv_pool_shardings
+    from arkflow_tpu.parallel.mesh import kv_pool_sharding
 
     import numpy as np
 
     mesh = Mesh(np.asarray(v5e).reshape(4), ("tp",))
-    _, kv_layer = kv_pool_shardings(mesh)
+    kv = kv_pool_sharding(mesh)
     heads = NamedSharding(mesh, P(None, None, "tp", None))
     repl = NamedSharding(mesh, P())
     cfg = llama3_8b()
 
-    def attend(q, kp, vp, table, off):
-        return _attend_paged(q, kp, vp, table, off, cfg, kv_layer, False)
+    def attend(q, kp, vp, layer, table, off):
+        return _attend_paged(q, kp, vp, layer, table, off, cfg, kv, False)
 
     compiled = _compile(attend, v5e, *_paged_shapes("llama3_8b", chunk),
-                        shardings=[heads, kv_layer, kv_layer, repl, repl])
+                        shardings=[heads, kv, kv, repl, repl, repl])
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert "all-gather" not in text and "all-reduce" not in text
+
+
+# -- the whole dense steps (Mistral-7B widths, the benchmark's pools) ----------
+
+#: 16 slots x 136 pages of 16 tokens + the scratch page, as both Mistral cells
+MISTRAL_SLOTS, MISTRAL_PAGES, MISTRAL_TABLE = 16, 2177, 136
+#: one layer's slice of ONE pool is 71.3 MB: a single surviving copy fails
+POOL_TEMP_LIMIT = 64 * 1024 * 1024
+
+
+def _dense_steps(devices, layers: int, tp: int):
+    """The dense ``_decode`` (16 lanes) and ``_chunk`` (1 x 128) programs as
+    the server jits them — pools donated, under ``tp`` the server's in / out
+    shardings — compiled for the described chip(s) at Mistral-7B widths."""
+    import numpy as np
+
+    from arkflow_tpu.models import decoder as dec
+    from arkflow_tpu.models.paged_decode import (init_page_pool, paged_decode_step,
+                                                 paged_prefill_chunk)
+    from arkflow_tpu.parallel.mesh import kv_pool_sharding
+
+    cfg = dec.DecoderConfig(vocab_size=32768, dim=4096, layers=layers, heads=32,
+                            kv_heads=8, ffn=14336, max_seq=32768,
+                            rope_theta=1e6, norm_eps=1e-5)
+    params = jax.tree_util.tree_map(
+        lambda a, dtype: jax.ShapeDtypeStruct(a.shape, dtype),
+        jax.eval_shape(lambda: dec.init(jax.random.PRNGKey(0), cfg)),
+        dec.serve_dtypes(cfg))               # as the server places them
+    pool, _ = jax.eval_shape(
+        lambda: init_page_pool(cfg, MISTRAL_PAGES, PAGE))
+    kv = None
+    if tp > 1:
+        mesh = Mesh(np.asarray(devices).reshape(tp), ("tp",))
+        kv, repl = kv_pool_sharding(mesh), NamedSharding(mesh, P())
+        place = jax.tree_util.tree_map(
+            lambda spec: NamedSharding(mesh, spec),
+            dec.param_specs(cfg, {"tp": "tp"}), is_leaf=lambda x: isinstance(x, P))
+    else:
+        repl = SingleDeviceSharding(devices[0])
+        place = jax.tree_util.tree_map(lambda _: repl, params)
+    kern = dict(kv_sharding=kv, attention_kernel="paged")
+
+    def decode(p, tok, lens, act, table, kp, vp):
+        return paged_decode_step(p, cfg, tok, lens, act, table, kp, vp,
+                                 return_logits=True, **kern)
+
+    def chunk(p, ids, off, clen, table, kp, vp):
+        return paged_prefill_chunk(p, cfg, ids, off, clen, table, kp, vp, **kern)
+
+    def compiled(fn, *operands):
+        def struct(a, sharding):
+            return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding)
+
+        pools = kv or repl
+        n = len(operands)
+        return jax.jit(fn, donate_argnums=(n + 1, n + 2),
+                       out_shardings=(repl, pools, pools)).lower(
+            jax.tree_util.tree_map(struct, params, place),
+            *[jax.ShapeDtypeStruct(s, d, sharding=repl) for s, d in operands],
+            struct(pool, pools), struct(pool, pools)).compile()
+
+    s = MISTRAL_SLOTS
+    return (
+        compiled(decode, ((s,), I32), ((s,), I32), ((s,), jnp.bool_),
+                 ((s, MISTRAL_TABLE), I32)),
+        compiled(chunk, ((1, 128), I32), ((1,), I32), ((1,), I32),
+                 ((1, MISTRAL_TABLE), I32)))
+
+
+def _collectives(text: str) -> dict:
+    """The collectives of a compiled module by kind, each with its result
+    shape (the text before ``kind(``)."""
+    import re
+
+    found = {}
+    for line in text.splitlines():
+        m = re.search(r"= (.*?) (all-gather|all-reduce|all-to-all|"
+                      r"collective-permute|reduce-scatter)(-start)?\(", line)
+        if m:
+            found.setdefault(m.group(2), []).append(m.group(1))
+    return found
+
+
+@pytest.mark.parametrize("layers,tp", [(6, 1), (16, 4)], ids=["l6", "tp4"])
+def test_dense_steps_carry_the_pools_whole(v5e, layers, tp):
+    """No layer's pool slice is copied out, scattered into and written back,
+    and the pools are not copied once a step: the programs need no temporary
+    the size of a slice. Under tp the collectives are the layer's own (two
+    all-gathers, three all-reduces in the loop body's text) and none moves
+    a pool."""
+    for step in _dense_steps(v5e, layers, tp):
+        text = step.as_text()
+        assert "tpu_custom_call" in text
+        assert step.memory_analysis().temp_size_in_bytes < POOL_TEMP_LIMIT
+        found = _collectives(text)
+        if tp == 1:
+            assert not found
+            continue
+        assert set(found) <= {"all-gather", "all-reduce"}
+        assert len(found.get("all-gather", ())) <= 2
+        assert len(found.get("all-reduce", ())) <= 3
+        pool_dims = f"{MISTRAL_PAGES},{PAGE},"
+        assert not any(pool_dims in shape for shapes in found.values()
+                       for shape in shapes)
 
 
 # -- latent (MLA) paged attention and the expert product (Kanana-2 widths) ----

@@ -626,12 +626,13 @@ def test_judge_holds_the_float32_leaves_to_their_masters():
 
 # -- the dense path is what it was ------------------------------------------------------
 
-#: sha256 of the lowered text of the dense GQA programs at the parent commit
-#: (PR 26), gather / paged kernel: ``_decode`` then ``_chunk``. A PR that
-#: changes the dense programs on purpose recomputes them with this test's code.
+#: sha256 of the lowered text of the dense GQA programs as PR 32 left them
+#: (the pools carried whole through the layer scan), gather / paged kernel:
+#: ``_decode`` then ``_chunk``. A PR that changes the dense programs on
+#: purpose recomputes them with this test's code.
 DENSE_HLO = {
-    "gather": ("51b2b80cfc21fa8c", "2d8ceabcea3bb874"),
-    "paged": ("e8944e061b67d091", "fb71ded5f00e62be"),
+    "gather": ("fdea09887a70df43", "e5fbbb5138b92dea"),
+    "paged": ("9ef71f8186e0a71d", "3fc1c77221acf62b"),
 }
 
 
@@ -654,3 +655,43 @@ def test_dense_programs_lower_to_the_same_text(kern):
                     s((1, 2), i32), kp, vp).as_text())
     got = tuple(hashlib.sha256(t.encode()).hexdigest()[:16] for t in texts)
     assert got == DENSE_HLO[kern]
+
+
+#: sha256 of the tiny dense model's greedy tokens — a 13- and a 9-token
+#: prompt prefilled in two chunks of 8, then 8 decode steps — recorded at
+#: PR 32's parent, whose layer scan sliced the pools. Unlike the text above
+#: this does not move with the lowering: a change of the programs' form
+#: serves these tokens or is not the same program. (Seed 3 leaves 0.031
+#: between the two largest logits at every step; both kernels serve the same.)
+DENSE_TOKENS = "ef130c011f7cffcf"
+
+
+def _dense_tokens(kern: str):
+    cfg = dec.DecoderConfig(vocab_size=128, dim=32, layers=2, heads=4,
+                            kv_heads=2, ffn=64)
+    p = dec.init(jax.random.PRNGKey(3), cfg)
+    kp, vp = init_page_pool(cfg, 9, 8)
+    kw = dict(attention_kernel=kern, kernel_interpret=True)
+    table = jnp.asarray([[3, 1, 5, 0], [2, 7, 4, 0]], jnp.int32)
+    prompts = np.asarray(jax.random.randint(jax.random.PRNGKey(32), (2, 16), 1, 128))
+    lens = np.asarray([13, 9])
+    for off in (0, 8):
+        logits, kp, vp = paged_prefill_chunk(
+            p, cfg, jnp.asarray(prompts[:, off:off + 8]),
+            jnp.full((2,), off, jnp.int32),
+            jnp.asarray(np.clip(lens - off, 0, 8), jnp.int32), table, kp, vp, **kw)
+    tokens, lens = [jnp.argmax(logits, -1)], jnp.asarray(lens, jnp.int32)
+    for _ in range(8):
+        logits, kp, vp = paged_decode_step(
+            p, cfg, tokens[-1].astype(jnp.int32), lens, jnp.ones((2,), bool),
+            table, kp, vp, return_logits=True, **kw)
+        lens = lens + 1
+        tokens.append(jnp.argmax(logits, -1))
+    return np.stack(tokens).astype(np.int32)
+
+
+@pytest.mark.parametrize("kern", sorted(DENSE_HLO))
+def test_dense_programs_serve_the_parents_tokens(kern):
+    tokens = _dense_tokens(kern)
+    assert tokens.shape == (9, 2)
+    assert hashlib.sha256(tokens.tobytes()).hexdigest()[:16] == DENSE_TOKENS

@@ -70,7 +70,21 @@ def _dense_paged_reference(q, kp, vp, table, off):
     return cm.attention(q, kk, vv, mask)
 
 
-def test_paged_flash_attention_chunked_prefill_regime():
+#: the kernel takes the WHOLE pools and a layer: every case runs on the
+#: first, a middle and the last layer of a 3-layer pool
+LAYERS = pytest.mark.parametrize("layer", [0, 1, 2],
+                                 ids=["first", "middle", "last"])
+
+
+def _in_pool(layer, *slices):
+    """Each one-layer slice as layer ``layer`` of a 3-layer pool whose OTHER
+    layers are NaN, so a wrong layer index cannot pass."""
+    return [jnp.full((3, *x.shape), jnp.nan, x.dtype).at[layer].set(x)
+            for x in slices]
+
+
+@LAYERS
+def test_paged_flash_attention_chunked_prefill_regime(layer):
     """The chunked-prefill shape regime the ragged kernel family never had
     coverage for: C > 1 queries at NONZERO absolute offsets, ragged rows
     including an empty row (off 0) and a single-token tail, against the
@@ -88,12 +102,14 @@ def test_paged_flash_attention_chunked_prefill_regime():
     # offsets: mid-page, page-aligned, EMPTY row (0), single-token tail
     # (last attendable position in the table)
     off = jnp.asarray([6, 8, 0, pages_per * page - c], jnp.int32)
-    out = paged_flash_attention(q, kp, vp, table, off, interpret=True)
+    out = paged_flash_attention(q, *_in_pool(layer, kp, vp), layer, table, off,
+                                interpret=True)
     ref = _dense_paged_reference(q, kp, vp, table, off)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=3e-5)
 
 
-def test_paged_flash_attention_tiles_long_chunks():
+@LAYERS
+def test_paged_flash_attention_tiles_long_chunks(layer):
     """A chunk whose folded rows (C x heads) exceed one program's budget is
     split into query tiles (C padded up to a tile multiple, the pad sliced
     off): same answers as the dense reference, per-tile causal bounds."""
@@ -108,13 +124,15 @@ def test_paged_flash_attention_tiles_long_chunks():
         [np.random.RandomState(i).permutation(np.arange(1, n_pages))[:pages_per]
          for i in range(b)], jnp.int32)
     off = jnp.asarray([3, pages_per * page - c], jnp.int32)
-    out = paged_flash_attention(q, kp, vp, table, off, interpret=True)
+    out = paged_flash_attention(q, *_in_pool(layer, kp, vp), layer, table, off,
+                                interpret=True)
     ref = _dense_paged_reference(q, kp, vp, table, off)
     assert out.shape == q.shape
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=3e-5)
 
 
-def test_paged_flash_attention_decode_shape_and_gqa():
+@LAYERS
+def test_paged_flash_attention_decode_shape_and_gqa(layer):
     """Decode regime: C=1 queries, GQA group folded into the kernel tile
     (heads never repeated in memory) — bit-for-shape parity with the dense
     reference, including a zero-length (empty/inactive) row."""
@@ -127,12 +145,14 @@ def test_paged_flash_attention_decode_shape_and_gqa():
     vp = jnp.asarray(rng.randn(n_pages, page, kvh, dh) * 0.5, jnp.bfloat16)
     table = jnp.asarray([[1, 2, 3], [6, 4, 5], [7, 0, 0]], jnp.int32)
     off = jnp.asarray([9, 11, 0], jnp.int32)  # row 2: empty (one key only)
-    out = paged_flash_attention(q, kp, vp, table, off, interpret=True)
+    out = paged_flash_attention(q, *_in_pool(layer, kp, vp), layer, table, off,
+                                interpret=True)
     ref = _dense_paged_reference(q, kp, vp, table, off)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=3e-5)
 
 
-def test_paged_flash_attention_ignores_stale_pages_past_bound():
+@LAYERS
+def test_paged_flash_attention_ignores_stale_pages_past_bound(layer):
     """A slot mid-eviction leaves table entries past its causal bound
     pointing at pages another slot now owns. Whatever lives there must not
     contribute: poisoning those pages with huge values may not change the
@@ -147,14 +167,17 @@ def test_paged_flash_attention_ignores_stale_pages_past_bound():
     table = np.asarray([[1, 2, 7, 8], [3, 4, 5, 6]], np.int32)
     off = jnp.asarray([3, 2], jnp.int32)  # row 0 uses pages 0..1 only
     base = paged_flash_attention(
-        q, jnp.asarray(kp, jnp.bfloat16), jnp.asarray(vp, jnp.bfloat16),
-        jnp.asarray(table), off, interpret=True)
+        q, *_in_pool(layer, jnp.asarray(kp, jnp.bfloat16),
+                     jnp.asarray(vp, jnp.bfloat16)),
+        layer, jnp.asarray(table), off, interpret=True)
     # poison the pages row 0 maps past its bound (7, 8) AND the scratch page
     kp[[0, 7, 8]] = 1e4
     vp[[0, 7, 8]] = -1e4
     poisoned = paged_flash_attention(
-        q, jnp.asarray(kp, jnp.bfloat16), jnp.asarray(vp, jnp.bfloat16),
-        jnp.asarray(table), off, interpret=True)
+        q, *_in_pool(layer, jnp.asarray(kp, jnp.bfloat16),
+                     jnp.asarray(vp, jnp.bfloat16)),
+        layer, jnp.asarray(table), off, interpret=True)
+    assert np.isfinite(np.asarray(base)).all()
     np.testing.assert_array_equal(np.asarray(base)[0, :, :],
                                   np.asarray(poisoned)[0, :, :])
 
@@ -235,6 +258,61 @@ def test_paged_kernel_decode_and_chunk_argmax_parity():
     assert np.isfinite(np.asarray(got)).all()
 
 
+def test_carried_pools_are_written_in_place_and_alike():
+    """The pools ride whole through the layer scan: after a chunk and two
+    decode steps every layer's writes touched only its own (layer, page,
+    offset) cells — the rest of both pools (a random fill) is as it was,
+    bit for bit — and ``gather`` and ``paged`` hold the same rows: the same
+    bits at layer 0, whose keys no attention precedes, and within the
+    rounding of the two kernels' accumulation orders below it."""
+    fam = get_model("decoder_lm")
+    cfg = fam.make_config(**{**TINY, "layers": 3})
+    params = fam.init(jax.random.PRNGKey(3), cfg)
+    page, rng = 4, np.random.RandomState(0)
+    zeros, _ = init_page_pool(cfg, num_pages=11, page_size=page)
+    fill = [jnp.asarray(rng.randn(*zeros.shape) * 0.5, zeros.dtype)
+            for _ in range(2)]
+    table = jnp.asarray([[5, 2, 7, 9], [1, 3, 4, 6], [0, 0, 0, 0]], jnp.int32)
+    ids = jnp.asarray([[3, 17, 42, 7, 91, 2], [5, 9, 1, 0, 0, 0],
+                       [0, 0, 0, 0, 0, 0]], jnp.int32)
+    off = jnp.asarray([2, 5, 0], jnp.int32)   # mid-page, across a boundary
+    clen = jnp.asarray([6, 3, 0], jnp.int32)  # incl. an EMPTY row
+    act = jnp.asarray([True, True, False])
+
+    def run(kern):
+        kw = dict(attention_kernel=kern, kernel_interpret=True)
+        logits, kp, vp = paged_prefill_chunk(params, cfg, ids, off, clen,
+                                             table, *fill, **kw)
+        lens = off + clen
+        for _ in range(2):
+            tok = jnp.argmax(logits, -1).astype(jnp.int32)
+            logits, kp, vp = paged_decode_step(params, cfg, tok, lens, act,
+                                               table, kp, vp,
+                                               return_logits=True, **kw)
+            lens = lens + act
+        return np.asarray(kp, np.float32), np.asarray(vp, np.float32)
+
+    gather, paged = run("gather"), run("paged")
+    # the cells a layer may write: positions off .. off+clen+2 of each live
+    # row through its table, and the scratch page's first cell (page 0,
+    # offset 0: padding and the inactive lane)
+    written = np.zeros(zeros.shape[1:3], bool)
+    written[0, 0] = True
+    for row, (o, n) in enumerate(zip(np.asarray(off), np.asarray(clen))):
+        for pos in range(o, o + n + 2) if n else ():
+            written[int(table[row, pos // page]), pos % page] = True
+    for got, ref, was in zip(paged, gather, fill):
+        np.testing.assert_array_equal(got[0], ref[0])
+        np.testing.assert_allclose(got, ref, atol=0.05)
+        was = np.asarray(was, np.float32)
+        live = written.copy()
+        live[0, 0] = False                               # scratch: any value
+        for pool in (got, ref):
+            changed = (pool != was).any(axis=(-1, -2))   # [layers, pages, page]
+            assert not changed[:, ~written].any()
+            assert changed[:, live].all()
+
+
 def test_paged_kernel_tp_host_mesh_parity():
     """tp=2 forced host mesh: the kernel runs per-shard inside shard_map
     (pools sharded over KV heads, no all-gather) and must match the
@@ -242,7 +320,7 @@ def test_paged_kernel_tp_host_mesh_parity():
     if len(jax.devices()) < 2:
         pytest.skip("needs 2 virtual devices")
     from arkflow_tpu.parallel.mesh import (MeshSpec, create_mesh,
-                                           kv_pool_shardings, shard_params)
+                                           kv_pool_sharding, shard_params)
 
     fam = get_model("decoder_lm")
     cfg = fam.make_config(**TINY)
@@ -250,24 +328,24 @@ def test_paged_kernel_tp_host_mesh_parity():
     mesh = create_mesh(MeshSpec(tp=2), devices=jax.devices()[:2])
     axes = {n: n for n in mesh.axis_names}
     sharded = shard_params(params, fam.param_specs(cfg, axes), mesh)
-    kv_io, kv_layer = kv_pool_shardings(mesh)
+    kv = kv_pool_sharding(mesh)
 
     kp, vp = init_page_pool(cfg, num_pages=9, page_size=4)
-    kp = jax.device_put(kp, kv_io)
-    vp = jax.device_put(vp, kv_io)
+    kp = jax.device_put(kp, kv)
+    vp = jax.device_put(vp, kv)
     table = jnp.asarray([[5, 2, 7, 0, 0, 0, 0, 0],
                          [1, 3, 4, 6, 8, 0, 0, 0]], jnp.int32)
     ids = jnp.asarray([[3, 17, 42, 7, 91, 0, 0, 0],
                        [5, 9, 1, 2, 3, 4, 5, 6]], jnp.int32)
     lens = jnp.asarray([5, 8], jnp.int32)
     nxt, kp, vp = paged_prefill(sharded, cfg, ids, lens, table, kp, vp,
-                                kv_sharding=kv_layer)
+                                kv_sharding=kv)
     act = jnp.asarray([True, True])
 
     def step(kern):
         fn = jax.jit(lambda kp, vp: paged_decode_step(
             sharded, cfg, nxt, lens, act, table, kp, vp, return_logits=True,
-            kv_sharding=kv_layer, attention_kernel=kern,
+            kv_sharding=kv, attention_kernel=kern,
             kernel_interpret=True))
         lg, *_ = fn(kp, vp)
         return lg
@@ -280,7 +358,7 @@ def test_paged_kernel_tp_host_mesh_parity():
         clen = jnp.asarray([2, 2], jnp.int32)
         fn = jax.jit(lambda kp, vp: paged_prefill_chunk(
             sharded, cfg, cids, lens, clen, table, kp, vp, return_all=True,
-            kv_sharding=kv_layer, attention_kernel=kern,
+            kv_sharding=kv, attention_kernel=kern,
             kernel_interpret=True))
         lg, *_ = fn(kp, vp)
         return lg
