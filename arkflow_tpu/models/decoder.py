@@ -157,13 +157,47 @@ class DecoderConfig:
     index_topk: int = 0
     attention_gate_type: str = ""
     apply_mla_qkv_lora_rescale: bool = False
+    #: the size of an attention head where it is not ``dim // heads``
+    #: (per-head K/V layers only; 0: ``dim // heads``)
+    head_dim: int = 0
+    # -- the hybrid block (Falcon-H1), under the published key names.
+    # ``mamba_d_ssm`` > 0 turns it on for EVERY layer: beside the GQA
+    # attention, and fed by the same normed input, a Mamba-2 mixer
+    # (``mamba_n_heads`` heads of ``mamba_d_head``, state ``mamba_d_state``,
+    # B and C shared by ``mamba_n_groups`` groups of heads, a depthwise
+    # causal conv over ``mamba_d_conv`` inputs, the gate before a grouped
+    # RMSNorm); their outputs add into one residual. What a sequence caches
+    # of it is a fixed-size state (``paged_decode.cache_spec``: kind
+    # ``ssm``), not rows by token. The multipliers scale, in this order of
+    # use: the embedding; the block's input into attention, its keys, its
+    # output; the input into the mixer, the five segments z | x | B | C | dt
+    # of its input projection, its output; the MLP's gate and output; the
+    # logits.
+    mamba_d_ssm: int = 0
+    mamba_n_heads: int = 0
+    mamba_d_head: int = 0
+    mamba_d_state: int = 0
+    mamba_n_groups: int = 1
+    mamba_d_conv: int = 4
+    mamba_chunk_size: int = 128
+    embedding_multiplier: float = 1.0
+    attention_in_multiplier: float = 1.0
+    attention_out_multiplier: float = 1.0
+    key_multiplier: float = 1.0
+    ssm_in_multiplier: float = 1.0
+    ssm_out_multiplier: float = 1.0
+    ssm_multipliers: tuple = (1.0, 1.0, 1.0, 1.0, 1.0)
+    mlp_multipliers: tuple = (1.0, 1.0)
+    lm_head_multiplier: float = 1.0
 
     def __post_init__(self):
         from arkflow_tpu.errors import ConfigError
 
-        for name in ("layer_types", "experts_held"):  # JSON lists: hashable
+        for name in ("layer_types", "experts_held", "ssm_multipliers",
+                     "mlp_multipliers"):  # JSON lists: hashable
             if isinstance(getattr(self, name), list):
                 object.__setattr__(self, name, tuple(getattr(self, name)))
+        self._check_hybrid()
         if self.latent:
             if min(self.qk_nope_head_dim, self.qk_rope_head_dim,
                    self.v_head_dim) <= 0 or self.qk_rope_head_dim % 2:
@@ -211,6 +245,46 @@ class DecoderConfig:
                     "experts_held is (first, count) within n_routed_experts "
                     f"of a routed model, got {self.experts_held}")
         self._check_layer_pattern()
+
+    def _check_hybrid(self) -> None:
+        from arkflow_tpu.errors import ConfigError
+
+        if self.head_dim < 0 or self.head_dim % 2 or (
+                self.head_dim and self.latent):
+            raise ConfigError(
+                "head_dim is an even attention head size of a per-head K/V "
+                f"model (a latent model states its own), got {self.head_dim}")
+        if not self.hybrid:
+            if self.mamba_n_heads or self.mamba_d_head or self.mamba_d_state:
+                raise ConfigError("mamba_n_heads / mamba_d_head / "
+                                  "mamba_d_state without mamba_d_ssm")
+            return
+        if self.latent or self.routed or self.num_experts > 1 \
+                or self.use_ring_attention:
+            raise ConfigError(
+                "the hybrid block (mamba_d_ssm > 0: a Mamba-2 mixer beside "
+                "per-head GQA attention, dense SwiGLU) composes with "
+                "neither latent attention (kv_lora_rank), routed experts "
+                "(n_routed_experts), the Switch layer (num_experts) nor "
+                "ring attention")
+        if (min(self.mamba_n_heads, self.mamba_d_head, self.mamba_d_state,
+                self.mamba_n_groups, self.mamba_chunk_size) <= 0
+                or self.mamba_d_conv < 2
+                or self.mamba_n_heads * self.mamba_d_head != self.mamba_d_ssm
+                or self.mamba_n_heads % self.mamba_n_groups
+                or self.mamba_d_ssm % self.mamba_n_groups):
+            raise ConfigError(
+                "mamba_d_ssm = mamba_n_heads x mamba_d_head, with "
+                "mamba_d_state, mamba_chunk_size > 0, mamba_d_conv >= 2 and "
+                "mamba_n_groups dividing the heads; got "
+                f"{self.mamba_d_ssm}, {self.mamba_n_heads}, "
+                f"{self.mamba_d_head}, {self.mamba_d_state}, "
+                f"{self.mamba_chunk_size}, {self.mamba_d_conv}, "
+                f"{self.mamba_n_groups}")
+        if len(self.ssm_multipliers) != 5 or len(self.mlp_multipliers) != 2:
+            raise ConfigError(
+                "ssm_multipliers has five entries (z, x, B, C, dt) and "
+                "mlp_multipliers two (gate, output)")
 
     def _check_layer_pattern(self) -> None:
         from arkflow_tpu.errors import ConfigError
@@ -263,6 +337,21 @@ class DecoderConfig:
     @property
     def latent(self) -> bool:
         return self.kv_lora_rank > 0
+
+    @property
+    def hybrid(self) -> bool:
+        """True where every layer runs a Mamba-2 mixer beside its attention."""
+        return self.mamba_d_ssm > 0
+
+    @property
+    def dh(self) -> int:
+        """The size of a per-head K/V layer's attention head."""
+        return self.head_dim or self.dim // self.heads
+
+    @property
+    def ssm_conv_dim(self) -> int:
+        """Channels the mixer's causal conv runs over: x | B | C."""
+        return self.mamba_d_ssm + 2 * self.mamba_n_groups * self.mamba_d_state
 
     @property
     def kinds(self) -> tuple:
@@ -461,7 +550,7 @@ def layer_stacks(params: dict, cfg: DecoderConfig) -> list:
 def init(rng, cfg: DecoderConfig) -> dict:
     if cfg.latent:
         return _init_latent(rng, cfg)
-    dh = cfg.dim // cfg.heads
+    dh = cfg.dh
     keys = iter(jax.random.split(rng, 4 + 7 * cfg.layers))
     params = {
         "embed": cm.embedding_init(next(keys), cfg.vocab_size, cfg.dim),
@@ -492,15 +581,131 @@ def init(rng, cfg: DecoderConfig) -> dict:
             layer["w_gate"] = cm.dense_init(next(keys), cfg.dim, cfg.ffn, bias=False)
             layer["w_up"] = cm.dense_init(next(keys), cfg.dim, cfg.ffn, bias=False)
             layer["w_down"] = cm.dense_init(next(keys), cfg.ffn, cfg.dim, bias=False)
+        if cfg.hybrid:
+            # the mixer draws from keys of its own: the leaves above keep
+            # the values a model without one has
+            layer.update(_init_mixer(
+                jax.random.fold_in(rng, 1000 + len(params["layers"])), cfg))
         params["layers"].append(layer)
     params["layers"] = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *params["layers"])
     return params
 
 
+def _init_mixer(key, cfg: DecoderConfig) -> dict:
+    """One layer's Mamba-2 mixer (HF names: in_proj, conv1d, dt_bias, A_log,
+    D, norm, out_proj). ``ssm_in``'s columns are z | x | B | C | dt. What a
+    uniform draw would make degenerate takes Mamba-2's published init: A
+    uniform in [1, 16] (``A_log`` its log), dt log-uniform in [1e-3, 1e-1]
+    through the inverse softplus into ``dt_bias``, D = 1."""
+    k = jax.random.split(key, 6)
+    h, conv = cfg.mamba_n_heads, cfg.ssm_conv_dim
+    bound = cfg.mamba_d_conv ** -0.5
+    dt = jnp.exp(jax.random.uniform(k[4], (h,), jnp.float32,
+                                    jnp.log(1e-3), jnp.log(1e-1)))
+    return {
+        "ssm_in": cm.dense_init(k[0], cfg.dim, cfg.mamba_d_ssm + conv + h, bias=False),
+        "ssm_conv": {
+            "w": jax.random.uniform(k[1], (conv, cfg.mamba_d_conv), jnp.float32,
+                                    -bound, bound),
+            "b": jax.random.uniform(k[2], (conv,), jnp.float32, -bound, bound)},
+        "ssm_A_log": jnp.log(jax.random.uniform(k[3], (h,), jnp.float32, 1.0, 16.0)),
+        "ssm_dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+        "ssm_D": jnp.ones((h,), jnp.float32),
+        "ssm_norm": cm.rms_norm_init(cfg.mamba_d_ssm),
+        "ssm_out": cm.dense_init(k[5], cfg.mamba_d_ssm, cfg.dim, bias=False),
+    }
+
+
+def _scaled(x: jnp.ndarray, by: float) -> jnp.ndarray:
+    """``x * by``, and ``x`` itself where ``by`` is 1 (no op is traced)."""
+    return x if by == 1.0 else (x.astype(jnp.float32) * by).astype(x.dtype)
+
+
+def ssm_project(lp: dict, y: jnp.ndarray, cfg: DecoderConfig):
+    """The mixer's input projection of normed activations ``y`` [B, S, dim]:
+    the gate ``z`` [B, S, d_ssm] and the raw step ``dt`` [B, S, heads],
+    float32, times their ``ssm_multipliers`` entries, and the conv's input
+    ``x | B | C`` [B, S, conv channels] AS PROJECTED (bfloat16, before its
+    multipliers: what the conv window caches; ``ssm_conv`` scales it)."""
+    u = cm.dense(lp["ssm_in"], _scaled(y, cfg.ssm_in_multiplier))
+    d, conv = cfg.mamba_d_ssm, cfg.ssm_conv_dim
+    mz, _, _, _, mdt = cfg.ssm_multipliers
+    return (u[..., :d].astype(jnp.float32) * mz, u[..., d:d + conv],
+            u[..., d + conv:].astype(jnp.float32) * mdt)
+
+
+def ssm_conv(lp: dict, ext: jnp.ndarray, s: int, cfg: DecoderConfig) -> jnp.ndarray:
+    """The depthwise causal conv and its SiLU over ``ext`` [B, d_conv - 1 +
+    S, channels] — the ``d_conv - 1`` projected inputs before the block,
+    then the block's — in float32: each segment x | B | C times its
+    multiplier, then output t reads ``ext[t : t + d_conv]``."""
+    _, mx, mb, mc, _ = cfg.ssm_multipliers
+    gn = cfg.mamba_n_groups * cfg.mamba_d_state
+    scale = jnp.concatenate([jnp.full((cfg.mamba_d_ssm,), mx, jnp.float32),
+                             jnp.full((gn,), mb, jnp.float32),
+                             jnp.full((gn,), mc, jnp.float32)])
+    ext = ext.astype(jnp.float32) * scale
+    w = lp["ssm_conv"]["w"].astype(jnp.float32)                   # [C, K]
+    out = lp["ssm_conv"]["b"].astype(jnp.float32) + sum(
+        ext[:, k:k + s] * w[:, k] for k in range(w.shape[1]))
+    return jax.nn.silu(out)
+
+
+def ssm_operands(lp: dict, conved: jnp.ndarray, dt: jnp.ndarray,
+                 cfg: DecoderConfig, valid=None):
+    """What the recurrence reads, from the conv's output [B, S, channels]
+    and the raw step [B, S, heads]: ``x`` [B, S, H, P], the step
+    ``softplus(dt + dt_bias)`` [B, S, H] — 0 where ``valid`` [B, S] is
+    false, so that a padded position or an idle lane leaves the state as it
+    is —, ``A = -exp(A_log)`` [H], ``B`` and ``C`` [B, S, G, N]."""
+    b, s = conved.shape[:2]
+    d, g, n = cfg.mamba_d_ssm, cfg.mamba_n_groups, cfg.mamba_d_state
+    x = conved[..., :d].reshape(b, s, cfg.mamba_n_heads, cfg.mamba_d_head)
+    bm = conved[..., d:d + g * n].reshape(b, s, g, n)
+    cmat = conved[..., d + g * n:].reshape(b, s, g, n)
+    step = jax.nn.softplus(dt + lp["ssm_dt_bias"].astype(jnp.float32))
+    if valid is not None:
+        step = jnp.where(valid[..., None], step, 0.0)
+    return x, step, -jnp.exp(lp["ssm_A_log"].astype(jnp.float32)), bm, cmat
+
+
+def ssm_output(lp: dict, o: jnp.ndarray, x: jnp.ndarray, z: jnp.ndarray,
+               cfg: DecoderConfig, dtype) -> jnp.ndarray:
+    """The recurrence's output ``o`` [B, S, H, P] -> the mixer's [B, S,
+    dim]: the skip ``D x``, the gate ``silu(z)`` FIRST, then RMSNorm over
+    each of the ``mamba_n_groups`` groups of channels
+    (``mamba_norm_before_gate`` false), ``out_proj``, the multiplier."""
+    b, s = z.shape[:2]
+    o = o + lp["ssm_D"].astype(jnp.float32)[:, None] * x
+    gated = (o.reshape(b, s, -1) * jax.nn.silu(z)).reshape(
+        b, s, cfg.mamba_n_groups, -1)
+    normed = gated * jax.lax.rsqrt(
+        jnp.square(gated).mean(-1, keepdims=True) + cfg.norm_eps)
+    normed = normed.reshape(b, s, -1) * lp["ssm_norm"]["scale"]
+    return _scaled(cm.dense(lp["ssm_out"], normed.astype(dtype)),
+                   cfg.ssm_out_multiplier)
+
+
+def _mixer_block(lp: dict, y: jnp.ndarray, cfg: DecoderConfig) -> jnp.ndarray:
+    """The Mamba-2 mixer over a whole block from a zero state (``forward``):
+    the chunked scan in plain XLA, no cache."""
+    from arkflow_tpu.ops.ssm_scan import scan_from
+
+    b, s = y.shape[:2]
+    z, u, dt = ssm_project(lp, y, cfg)
+    ext = jnp.pad(u, ((0, 0), (cfg.mamba_d_conv - 1, 0), (0, 0)))
+    x, step, a, bm, cmat = ssm_operands(lp, ssm_conv(lp, ext, s, cfg), dt, cfg)
+    s0 = jnp.zeros((b, cfg.mamba_n_heads, cfg.mamba_d_state, cfg.mamba_d_head),
+                   jnp.float32)
+    o, _ = scan_from(s0, x, step, a, bm, cmat, cfg.mamba_chunk_size)
+    return ssm_output(lp, o, x, z, cfg, y.dtype)
+
+
 def _rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
     """Rotary embedding. x: [B, S, H, Dh]; positions: [B, S]."""
     dh = x.shape[-1]
-    freqs = 1.0 / (theta ** (jnp.arange(0, dh, 2, dtype=jnp.float32) / dh))
+    # float: a published theta of 1e11 read as an int does not fit 32 bits
+    freqs = 1.0 / (float(theta) ** (jnp.arange(0, dh, 2, dtype=jnp.float32) / dh))
     angles = positions[..., None].astype(jnp.float32) * freqs  # [B, S, Dh/2]
     cos = jnp.cos(angles)[:, :, None, :]
     sin = jnp.sin(angles)[:, :, None, :]
@@ -812,12 +1017,10 @@ def _attention_block(lp: dict, x: jnp.ndarray, cfg: DecoderConfig, positions,
     Used by forward() and the pipeline-parallel stage apply — one source of
     truth for the layer math."""
     b, s = positions.shape
-    dh = cfg.dim // cfg.heads
+    dh = cfg.dh
     group = cfg.heads // cfg.kv_heads
     y = cm.rms_norm(lp["attn_norm"], x, cfg.norm_eps)
-    q = cm.dense(lp["wq"], y).reshape(b, s, cfg.heads, dh)
-    k = cm.dense(lp["wk"], y).reshape(b, s, cfg.kv_heads, dh)
-    v = cm.dense(lp["wv"], y).reshape(b, s, cfg.kv_heads, dh)
+    q, k, v = qkv_project(lp, y, cfg)
     q = _rope(q, positions, cfg.rope_theta)
     k = _rope(k, positions, cfg.rope_theta)
     k = jnp.repeat(k, group, axis=2)
@@ -826,7 +1029,24 @@ def _attention_block(lp: dict, x: jnp.ndarray, cfg: DecoderConfig, positions,
         attn = ring_attn(q, k, v)
     else:
         attn = cm.attention(q, k, v, causal)
-    return x + cm.dense(lp["wo"], attn.reshape(b, s, cfg.heads * dh))
+    out = _scaled(cm.dense(lp["wo"], attn.reshape(b, s, cfg.heads * dh)),
+                  cfg.attention_out_multiplier)
+    if cfg.hybrid:  # the parallel mixer: one norm feeds both, one residual
+        out = out + _mixer_block(lp, y, cfg)
+    return x + out
+
+
+def qkv_project(lp: dict, y: jnp.ndarray, cfg: DecoderConfig):
+    """Per-head queries [B, S, H, dh], keys and values [B, S, KV, dh] of
+    normed activations ``y`` [B, S, dim], before the rotary embedding: the
+    block's input and its keys times their multipliers where the model
+    states any."""
+    b, s = y.shape[:2]
+    y = _scaled(y, cfg.attention_in_multiplier)
+    q = cm.dense(lp["wq"], y).reshape(b, s, cfg.heads, cfg.dh)
+    k = _scaled(cm.dense(lp["wk"], y), cfg.key_multiplier).reshape(
+        b, s, cfg.kv_heads, cfg.dh)
+    return q, k, cm.dense(lp["wv"], y).reshape(b, s, cfg.kv_heads, cfg.dh)
 
 
 def _mlp(lp: dict, y: jnp.ndarray, cfg: DecoderConfig, token_mask=None) -> jnp.ndarray:
@@ -836,8 +1056,18 @@ def _mlp(lp: dict, y: jnp.ndarray, cfg: DecoderConfig, token_mask=None) -> jnp.n
     if cfg.num_experts > 1:
         out, _aux = _moe_mlp(lp, y, cfg, token_mask=token_mask)
         return out
-    gate = jax.nn.silu(cm.dense(lp["w_gate"], y).astype(jnp.float32)).astype(y.dtype)
-    return cm.dense(lp["w_down"], gate * cm.dense(lp["w_up"], y))
+    gate_mult, out_mult = cfg.mlp_multipliers
+    pre = cm.dense(lp["w_gate"], y).astype(jnp.float32)
+    gate = jax.nn.silu(pre if gate_mult == 1.0 else pre * gate_mult).astype(y.dtype)
+    return _scaled(cm.dense(lp["w_down"], gate * cm.dense(lp["w_up"], y)), out_mult)
+
+
+def lm_logits(params: dict, x: jnp.ndarray, cfg: DecoderConfig) -> jnp.ndarray:
+    """The final norm and the output head: [..., dim] -> float32 [..., vocab]
+    (times ``lm_head_multiplier`` where the model states one)."""
+    x = cm.rms_norm(params["norm_out"], x, cfg.norm_eps)
+    logits = cm.dense(params["lm_head"], x).astype(jnp.float32)
+    return logits if cfg.lm_head_multiplier == 1.0 else logits * cfg.lm_head_multiplier
 
 
 def _shard_act(x, axes):
@@ -864,9 +1094,7 @@ def forward(params: dict, cfg: DecoderConfig, input_ids, *, axes=None, mesh=None
     b, s = input_ids.shape
     if cfg.latent:
         return _forward_latent(params, cfg, input_ids, axes, return_aux)
-    dh = cfg.dim // cfg.heads
-    group = cfg.heads // cfg.kv_heads
-    x = cm.embedding(params["embed"], input_ids)
+    x = _scaled(cm.embedding(params["embed"], input_ids), cfg.embedding_multiplier)
     x = _shard_act(x, axes)
     positions = jnp.broadcast_to(jnp.arange(s)[None, :], (b, s))
     causal = jnp.tril(jnp.ones((s, s), bool))[None, None, :, :]
@@ -888,8 +1116,7 @@ def forward(params: dict, cfg: DecoderConfig, input_ids, *, axes=None, mesh=None
             moe_out, aux = _moe_mlp(lp, y, cfg)
             x = x + moe_out
         else:
-            gate = jax.nn.silu(cm.dense(lp["w_gate"], y).astype(jnp.float32)).astype(y.dtype)
-            x = x + cm.dense(lp["w_down"], gate * cm.dense(lp["w_up"], y))
+            x = x + _mlp(lp, y, cfg)
             aux = (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32))
         return _shard_act(x, axes), aux
 
@@ -897,8 +1124,7 @@ def forward(params: dict, cfg: DecoderConfig, input_ids, *, axes=None, mesh=None
     # optimization barriers would block XLA fusion in the backward pass
     scan_body = jax.checkpoint(layer, prevent_cse=False) if cfg.remat else layer
     x, (lb_per_layer, z_per_layer) = jax.lax.scan(scan_body, x, params["layers"])
-    x = cm.rms_norm(params["norm_out"], x, cfg.norm_eps)
-    logits = cm.dense(params["lm_head"], x).astype(jnp.float32)
+    logits = lm_logits(params, x, cfg)
     if return_aux:
         return logits, {"load_balance": lb_per_layer.mean(), "router_z": z_per_layer.mean()}
     return logits
@@ -1022,6 +1248,8 @@ def param_specs(cfg: DecoderConfig, axes: dict) -> dict:
         layer["w_gate"] = {"w": P(None, tp)}
         layer["w_up"] = {"w": P(None, tp)}
         layer["w_down"] = {"w": P(tp, None)}
+    if cfg.hybrid:
+        layer.update(jax.tree_util.tree_map(lambda _: P(), _mixer_dtypes()))
     layer = jax.tree_util.tree_map(
         lambda sp: P(None, *sp), layer, is_leaf=lambda x: isinstance(x, P)
     )
@@ -1059,12 +1287,24 @@ def serve_dtypes(cfg: DecoderConfig) -> dict:
         layer["w_gate"] = {"w": bf16}
         layer["w_up"] = {"w": bf16}
         layer["w_down"] = {"w": bf16}
+    if cfg.hybrid:
+        layer.update(_mixer_dtypes())
     return {
         "embed": {"table": bf16},
         "norm_out": {"scale": f32},
         "lm_head": {"w": bf16},
         "layers": layer,
     }
+
+
+def _mixer_dtypes() -> dict:
+    """``serve_dtypes`` of the mixer's leaves: what the recurrence reads in
+    float32 (A_log, D, dt_bias) and the gated norm's scale float32, the
+    projections and the conv bfloat16."""
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    return {"ssm_in": {"w": bf16}, "ssm_conv": {"w": bf16, "b": bf16},
+            "ssm_A_log": f32, "ssm_dt_bias": f32, "ssm_D": f32,
+            "ssm_norm": {"scale": f32}, "ssm_out": {"w": bf16}}
 
 
 def _serve_dtypes_latent(cfg: DecoderConfig) -> dict:
@@ -1107,7 +1347,15 @@ def _serve_dtypes_latent(cfg: DecoderConfig) -> dict:
 
 
 def _no_latent(cfg: DecoderConfig, what: str) -> None:
-    """The paths that know only per-head K/V refuse a latent model."""
+    """The paths that know only per-head K/V refuse a latent model, and a
+    model that carries a recurrent state beside them."""
+    if cfg.hybrid:
+        from arkflow_tpu.errors import ConfigError
+
+        raise ConfigError(
+            f"{what} does not carry a recurrent state: a model with the "
+            "hybrid block (mamba_d_ssm > 0) generates through serving: "
+            "continuous (the state pool beside the K/V pages)")
     if cfg.latent:
         from arkflow_tpu.errors import ConfigError
 
@@ -1120,9 +1368,9 @@ def from_hf_state_dict(state: dict, cfg: DecoderConfig) -> dict:
     """Convert a HuggingFace ``LlamaForCausalLM`` state_dict (torch tensors —
     any dtype including bfloat16 — or numpy arrays) into this model's param
     pytree. Linear weights transpose from torch's [out, in] to [in, out]."""
-    if cfg.num_experts > 1 or cfg.latent:
+    if cfg.num_experts > 1 or cfg.latent or cfg.hybrid:
         raise ValueError("from_hf_state_dict maps dense Llama checkpoints; "
-                         "MoE and latent-attention configs unsupported")
+                         "MoE, latent-attention and hybrid configs unsupported")
 
     def t(name, transpose=False):
         return cm.hf_tensor(state, name, transpose)
@@ -1181,7 +1429,7 @@ def init_kv_cache(cfg: DecoderConfig, batch: int, max_len: int) -> dict:
     - ``prompt_len``: width of the prefilled prompt block (0 = pure stepwise).
     """
     _no_latent(cfg, "the contiguous KV cache (serving: batch)")
-    dh = cfg.dim // cfg.heads
+    dh = cfg.dh
     shape = (cfg.layers, batch, max_len, cfg.kv_heads, dh)
     return {
         "k": jnp.zeros(shape, jnp.bfloat16),
@@ -1203,7 +1451,7 @@ def prefill(params: dict, cfg: DecoderConfig, input_ids, cache: dict,
     continuing from a non-empty cache is not supported (cursor must be 0).
     """
     b, t = input_ids.shape
-    dh = cfg.dim // cfg.heads
+    dh = cfg.dh
     group = cfg.heads // cfg.kv_heads
     if lengths is None:
         lengths = jnp.full((b,), t, jnp.int32)
@@ -1261,7 +1509,7 @@ def decode_step(params: dict, cfg: DecoderConfig, token_ids, cache: dict,
     the summarization processor.
     """
     b = token_ids.shape[0]
-    dh = cfg.dim // cfg.heads
+    dh = cfg.dh
     group = cfg.heads // cfg.kv_heads
     pos = cache["length"]  # scalar write cursor (shared slot)
     lengths = cache["lengths"]  # [B] true per-row context lengths (RoPE)

@@ -42,28 +42,47 @@ from arkflow_tpu.models import common as cm
 from dataclasses import dataclass
 
 from arkflow_tpu.models.decoder import (FULL, SLIDING, DecoderConfig, _mlp,
-                                        _rope, index_project, index_scores,
-                                        layer_runs, layer_stacks, mla_absorb_query,
+                                        _rope, _scaled, index_project,
+                                        index_scores, layer_runs, layer_stacks,
+                                        lm_logits, mla_absorb_query,
                                         mla_expanded_attention, mla_head_gate,
                                         mla_output, mla_project,
                                         mla_query_latent, moe_step_stats,
-                                        routed_mlp)
+                                        qkv_project, routed_mlp, ssm_conv,
+                                        ssm_operands, ssm_output, ssm_project)
 
 
 @dataclass(frozen=True)
 class CachePool:
     """One kind of cached row: how many layers hold it, the widths of its
-    arrays (values a token a layer), and for how many tokens a row stays
-    live (``window`` 0: for the request's life)."""
+    arrays (values a row a layer) and their item sizes (bf16 unless stated),
+    and for how many tokens a row stays live (``window`` 0: for the
+    request's life). A row is a TOKEN'S, addressed by (page, offset) — or,
+    ``per_slot``, a SEQUENCE'S: one row a serving slot, overwritten by every
+    token, addressed by slot."""
     name: str
     layers: int
     widths: tuple
     window: int = 0
+    itemsizes: tuple = ()
+    per_slot: bool = False
+
+    @property
+    def _row_bytes(self) -> int:
+        sizes = self.itemsizes or (2,) * len(self.widths)
+        return sum(w * b for w, b in zip(self.widths, sizes)) * self.layers
 
     @property
     def bytes_per_token(self) -> int:
-        """bf16 bytes one cached token costs over the pool's layers."""
-        return 2 * sum(self.widths) * self.layers
+        """Bytes one cached token costs over the pool's layers (0 for a
+        pool whose rows are a sequence's: ``bytes_per_slot``)."""
+        return 0 if self.per_slot else self._row_bytes
+
+    @property
+    def bytes_per_slot(self) -> int:
+        """Bytes one busy slot holds over the pool's layers, whatever its
+        context (0 for a pool of token rows)."""
+        return self._row_bytes if self.per_slot else 0
 
 
 def cache_spec(cfg: DecoderConfig) -> tuple:
@@ -75,10 +94,20 @@ def cache_spec(cfg: DecoderConfig) -> tuple:
     - ``index``: an indexed full layer's index key (the indexer scores it
       against every later query);
     - ``window``: a sliding latent layer's latent row and rope key, live for
-      ``sliding_window`` tokens: its pages are freed as the window passes."""
+      ``sliding_window`` tokens: its pages are freed as the window passes;
+    - ``ssm``: a hybrid layer's recurrent state — the mixer's float32 state
+      matrices and the conv's last ``d_conv - 1`` inputs —, one row a
+      SEQUENCE whatever its length, beside that layer's ``kv`` rows."""
     if not cfg.latent:
-        kv = cfg.kv_heads * (cfg.dim // cfg.heads)
-        return (CachePool("kv", cfg.layers, (kv, kv)),)
+        kv = cfg.kv_heads * cfg.dh
+        pools = (CachePool("kv", cfg.layers, (kv, kv)),)
+        if cfg.hybrid:
+            pools += (CachePool(
+                "ssm", cfg.layers,
+                (cfg.mamba_d_ssm * cfg.mamba_d_state,
+                 (cfg.mamba_d_conv - 1) * cfg.ssm_conv_dim),
+                itemsizes=(4, 2), per_slot=True),)
+        return pools
     full, swa = (cfg.kinds.count(k) for k in (FULL, SLIDING))
     pools = [CachePool("latent", full, (cfg.kv_lora_rank, cfg.qk_rope_head_dim))]
     if cfg.index_topk:
@@ -91,7 +120,7 @@ def cache_spec(cfg: DecoderConfig) -> tuple:
 
 
 def init_page_pool(cfg: DecoderConfig, num_pages: int, page_size: int,
-                   window_pages: int = 0):
+                   window_pages: int = 0, slots: int = 0):
     """The page pools of ``cache_spec``, bf16, as the two values every step
     carries (and donates):
 
@@ -103,7 +132,13 @@ def init_page_pool(cfg: DecoderConfig, num_pages: int, page_size: int,
     - a latent model with a layer pattern: two dicts by pool name — the
       wide rows ``{"latent", "window"[, "index"]}`` and the rope keys
       ``{"latent", "window"}`` — each pool over its OWN layers, the window
-      pool over ``window_pages`` pages of its own."""
+      pool over ``window_pages`` pages of its own;
+    - a hybrid model: two dicts by pool name — ``{"kv": K, "ssm": the
+      states}`` and ``{"kv": V, "ssm": the conv windows}`` — the states
+      float32 [layers, slots + 1, heads, d_state, d_head] (``ops/ssm_scan``
+      says why in that order), the windows [layers, slots + 1, d_conv - 1,
+      conv channels]: row 0 scratch, as page 0 is, row ``s + 1`` slot
+      ``s``'s."""
     if cfg.latent and cfg.layered:
         wide, rope = {}, {}
         for pool in cache_spec(cfg):
@@ -118,9 +153,17 @@ def init_page_pool(cfg: DecoderConfig, num_pages: int, page_size: int,
         shape = (cfg.layers, num_pages, page_size)
         return (jnp.zeros(shape + (cfg.kv_lora_rank,), jnp.bfloat16),
                 jnp.zeros(shape + (cfg.qk_rope_head_dim,), jnp.bfloat16))
-    dh = cfg.dim // cfg.heads
+    dh = cfg.dh
     shape = (cfg.layers, num_pages, page_size, cfg.kv_heads, dh)
-    return jnp.zeros(shape, jnp.bfloat16), jnp.zeros(shape, jnp.bfloat16)
+    k, v = jnp.zeros(shape, jnp.bfloat16), jnp.zeros(shape, jnp.bfloat16)
+    if not cfg.hybrid:
+        return k, v
+    rows = (cfg.layers, slots + 1)
+    return ({"kv": k, "ssm": jnp.zeros(
+                rows + (cfg.mamba_n_heads, cfg.mamba_d_state, cfg.mamba_d_head),
+                jnp.float32)},
+            {"kv": v, "ssm": jnp.zeros(
+                rows + (cfg.mamba_d_conv - 1, cfg.ssm_conv_dim), jnp.bfloat16)})
 
 
 def kv_bytes_per_token(cfg: DecoderConfig) -> int:
@@ -591,10 +634,46 @@ def _attend_paged(q, k_pages, v_pages, layer, page_table, off,
     )(q, k_pages, v_pages, layer, page_table, off)
 
 
+def _mixer_paged(lp: dict, y, cfg: DecoderConfig, states, windows, layer, rows,
+                 fresh, valid, kernel: bool, interpret: bool):
+    """The Mamba-2 mixer of one layer over the state pool: ``states`` /
+    ``windows`` whole (``init_page_pool``), ``rows`` [B] each row's pool row
+    (0: scratch — an idle lane), ``valid`` [B, S] the tokens that advance
+    the state (a prefix of each row: unpadded positions, active lanes).
+    ``fresh`` None: a decode step, one token a lane (``ssm_state_update``);
+    else [B] bool and a chunk from the row's state — a zero state and an
+    empty window where ``fresh`` — to the row's state (``ssm_chunk_scan``).
+    A position that is not valid leaves state and window as they are: its
+    step is 0, and the window keeps the last ``d_conv - 1`` VALID inputs.
+    Returns (the mixer's output [B, S, dim], states, windows)."""
+    from arkflow_tpu.ops.ssm_scan import ssm_chunk_scan, ssm_state_update
+
+    t, keep = y.shape[1], cfg.mamba_d_conv - 1
+    z, u, dt = ssm_project(lp, y, cfg)
+    before = windows[layer, rows]                                 # [B, K-1, C]
+    if fresh is not None:
+        before = jnp.where(fresh[:, None, None], 0, before)
+    ext = jnp.concatenate([before, u.astype(before.dtype)], axis=1)
+    after = jax.vmap(lambda e, n: jax.lax.dynamic_slice_in_dim(e, n, keep))(
+        ext, valid.sum(axis=1).astype(jnp.int32))
+    windows = windows.at[layer, rows].set(after)
+    x, step, a, bm, cmat = ssm_operands(lp, ssm_conv(lp, ext, t, cfg), dt, cfg,
+                                        valid)
+    kern = dict(kernel=kernel, interpret=interpret)
+    if fresh is None:
+        o, states = ssm_state_update(states, layer, rows, x[:, 0], step[:, 0],
+                                     a, bm[:, 0], cmat[:, 0], **kern)
+        o = o[:, None]
+    else:
+        o, states = ssm_chunk_scan(states, layer, rows, fresh, x, step, a, bm,
+                                   cmat, cfg.mamba_chunk_size, **kern)
+    return ssm_output(lp, o, x, z, cfg, y.dtype), states, windows
+
+
 def _dense_layers(params: dict, cfg: DecoderConfig, x, k_pages, v_pages,
                   positions, page_idx, offset, token_mask, *, page_table,
                   off, mask, block: bool, kv_sharding, attention_kernel: str,
-                  kernel_interpret: bool):
+                  kernel_interpret: bool, ssm_rows=None, ssm_fresh=None):
     """The layer loop of a per-head K/V (GQA) model over the paged cache,
     with ``_latent_layers``' operands. The pools ride in the carry whole:
     each layer scatters its tokens' K and V at (layer, page, offset) in
@@ -609,19 +688,26 @@ def _dense_layers(params: dict, cfg: DecoderConfig, x, k_pages, v_pages,
     table in place through the Pallas kernel (its causal bound key <=
     off + i is exactly ``mask``), ``"gather"`` (the reference) gathers the
     pages the table names out of the layer, [B, P * page] keys a row.
+
+    A hybrid model's pools are the two dicts of ``init_page_pool``: beside
+    its attention, from the same normed input, each layer runs its mixer
+    over the state pool (``_mixer_paged``: ``ssm_rows`` [B], ``ssm_fresh``
+    [B] or None in a decode step, ``token_mask`` the tokens that advance a
+    state) and the two outputs add into one residual.
     Returns (x, k_pages, v_pages)."""
     b, t = positions.shape
-    dh = cfg.dim // cfg.heads
+    dh = cfg.dh
     group = cfg.heads // cfg.kv_heads
-    ctx = page_table.shape[1] * k_pages.shape[2]
+    page = (k_pages["kv"] if cfg.hybrid else k_pages).shape[2]
+    ctx = page_table.shape[1] * page
 
     def layer(carry, scanned):
         x, kp, vp = carry
         lp, li = scanned
+        if cfg.hybrid:
+            (kp, states), (vp, windows) = ((p["kv"], p["ssm"]) for p in (kp, vp))
         y = cm.rms_norm(lp["attn_norm"], x, cfg.norm_eps)
-        q = cm.dense(lp["wq"], y).reshape(b, t, cfg.heads, dh)
-        k = cm.dense(lp["wk"], y).reshape(b, t, cfg.kv_heads, dh)
-        v = cm.dense(lp["wv"], y).reshape(b, t, cfg.kv_heads, dh)
+        q, k, v = qkv_project(lp, y, cfg)
         q = _rope(q, positions, cfg.rope_theta)
         k = _rope(k, positions, cfg.rope_theta)
         kp = _constrain(kp.at[li, page_idx, offset].set(k.astype(kp.dtype)),
@@ -637,7 +723,15 @@ def _dense_layers(params: dict, cfg: DecoderConfig, x, k_pages, v_pages,
                 v = vp[li, page_table].reshape(b, ctx, cfg.kv_heads, dh).astype(x.dtype)
             attn = cm.attention(q, jnp.repeat(k, group, axis=2),
                                 jnp.repeat(v, group, axis=2), mask)
-        x = x + cm.dense(lp["wo"], attn.reshape(b, t, cfg.heads * dh))
+        out = _scaled(cm.dense(lp["wo"], attn.reshape(b, t, cfg.heads * dh)),
+                      cfg.attention_out_multiplier)
+        if cfg.hybrid:
+            mixed, states, windows = _mixer_paged(
+                lp, y, cfg, states, windows, li, ssm_rows, ssm_fresh,
+                token_mask, attention_kernel == "paged", kernel_interpret)
+            out = out + mixed
+            kp, vp = {"kv": kp, "ssm": states}, {"kv": vp, "ssm": windows}
+        x = x + out
         y = cm.rms_norm(lp["mlp_norm"], x, cfg.norm_eps)
         x = x + _mlp(lp, y, cfg, token_mask=token_mask)
         return (x, kp, vp), None
@@ -645,6 +739,16 @@ def _dense_layers(params: dict, cfg: DecoderConfig, x, k_pages, v_pages,
     (x, k_pages, v_pages), _ = jax.lax.scan(
         layer, (x, k_pages, v_pages), (params["layers"], jnp.arange(cfg.layers)))
     return x, k_pages, v_pages
+
+
+def _ssm_operands(cfg: DecoderConfig, rows, fresh) -> dict:
+    """``_dense_layers``' state operands: none for a model without a mixer."""
+    if not cfg.hybrid:
+        return {}
+    if rows is None:
+        raise ValueError("a hybrid model's chunk names its rows of the "
+                         "state pool (ssm_rows)")
+    return dict(ssm_rows=jnp.asarray(rows, jnp.int32), ssm_fresh=fresh)
 
 
 def paged_prefill(params: dict, cfg: DecoderConfig, input_ids, lengths,
@@ -665,6 +769,13 @@ def paged_prefill(params: dict, cfg: DecoderConfig, input_ids, lengths,
     paths read later; ``attention_kernel`` picks only its expert product.
     A routed model's step returns its routing counters as a fourth value.
     """
+    if cfg.hybrid:
+        from arkflow_tpu.errors import ConfigError
+
+        raise ConfigError(
+            "a model that carries a recurrent state prefills in chunks "
+            "through the cache (prefill_chunk > 0): the chunk's program is "
+            "the one that is told its slot's row of the state pool")
     if cfg.latent and cfg.layered:
         from arkflow_tpu.errors import ConfigError
 
@@ -678,7 +789,7 @@ def paged_prefill(params: dict, cfg: DecoderConfig, input_ids, lengths,
     causal = jnp.tril(jnp.ones((t, t), bool))[None, None]
     key_valid = (jnp.arange(t)[None, :] < lengths[:, None])[:, None, None, :]
     mask = jnp.logical_and(causal, key_valid)
-    x = cm.embedding(params["embed"], input_ids)
+    x = _scaled(cm.embedding(params["embed"], input_ids), cfg.embedding_multiplier)
 
     # scatter coordinates for every (row, position): valid positions route
     # through the page table, padding goes to scratch page 0
@@ -704,8 +815,7 @@ def paged_prefill(params: dict, cfg: DecoderConfig, input_ids, lengths,
             pos_valid, page_table=page_table, off=None, mask=mask, block=True,
             kv_sharding=kv_sharding, attention_kernel=attention_kernel,
             kernel_interpret=kernel_interpret)
-    x = cm.rms_norm(params["norm_out"], x, cfg.norm_eps)
-    logits = cm.dense(params["lm_head"], x).astype(jnp.float32)
+    logits = lm_logits(params, x, cfg)
     last = jnp.clip(lengths - 1, 0, t - 1)
     last_logits = jnp.take_along_axis(logits, last[:, None, None], axis=1)[:, 0, :]
     if not return_logits:
@@ -717,7 +827,7 @@ def paged_prefill_chunk(params: dict, cfg: DecoderConfig, input_ids, chunk_off,
                         chunk_len, page_table, k_pages, v_pages,
                         return_all: bool = False, kv_sharding=None,
                         attention_kernel: str = "gather",
-                        kernel_interpret: bool = False):
+                        kernel_interpret: bool = False, ssm_rows=None):
     """Prefill ONE CHUNK of a prompt at absolute offset ``chunk_off``.
 
     Chunked prefill keeps continuous serving responsive: a long prompt no
@@ -748,6 +858,13 @@ def paged_prefill_chunk(params: dict, cfg: DecoderConfig, input_ids, chunk_off,
     Pallas kernel reads the page table in place; ``kernel_interpret`` runs
     it interpreted for CPU tests). Both produce the same attention to float
     tolerance; the serving layer gates the swap on argmax parity.
+
+    A hybrid model's row carries its state on from the state pool's row
+    ``ssm_rows[b]`` — from a ZERO state where ``chunk_off`` is 0: a prompt's
+    first chunk resets its slot, so admission costs no device call and a
+    slot's earlier tenant cannot leak. Its padded positions leave the state
+    as it is. (Not a verifier for such a model: a rejected draft would have
+    advanced the state.)
     """
     b, t = input_ids.shape
     # a layer pattern's table is (kept pages, the window pool's ring)
@@ -773,7 +890,7 @@ def paged_prefill_chunk(params: dict, cfg: DecoderConfig, input_ids, chunk_off,
     # into real tokens' expert inputs. Their finite garbage output is
     # excluded from routing by token_mask and never read out.
     mask = key_pos <= positions[:, None, :, None]                 # [B,1,C,ctx]
-    x = cm.embedding(params["embed"], input_ids)
+    x = _scaled(cm.embedding(params["embed"], input_ids), cfg.embedding_multiplier)
 
     moe = ()  # a routed model appends its counters (``moe_step_stats``)
     if cfg.latent:
@@ -790,9 +907,9 @@ def paged_prefill_chunk(params: dict, cfg: DecoderConfig, input_ids, chunk_off,
             pos_valid, page_table=page_table, off=chunk_off, mask=mask,
             block=False, kv_sharding=kv_sharding,
             attention_kernel=attention_kernel,
-            kernel_interpret=kernel_interpret)
-    x = cm.rms_norm(params["norm_out"], x, cfg.norm_eps)
-    logits = cm.dense(params["lm_head"], x).astype(jnp.float32)
+            kernel_interpret=kernel_interpret, **_ssm_operands(
+                cfg, ssm_rows, chunk_off == 0))
+    logits = lm_logits(params, x, cfg)
     if not return_all:
         last = jnp.clip(chunk_len - 1, 0, t - 1)
         logits = jnp.take_along_axis(logits, last[:, None, None], axis=1)[:, 0, :]
@@ -808,7 +925,9 @@ def paged_decode_step(params: dict, cfg: DecoderConfig, token_ids, lengths,
 
     token_ids: [S] current token per slot; lengths: [S] tokens already in
     cache (the new token writes at position lengths[s]); active: [S] bool;
-    page_table: [S, P]. Returns (next_ids [S], k_pages, v_pages).
+    page_table: [S, P]. Returns (next_ids [S], k_pages, v_pages). A hybrid
+    model's lane s advances the state pool's row s + 1 (an inactive lane
+    the scratch row 0, by a zero step: nothing changes).
 
     ``attention_kernel="gather"`` (reference) gathers each slot's pages —
     a [S, P*page] dense context copy per layer — and masks positions
@@ -825,7 +944,8 @@ def paged_decode_step(params: dict, cfg: DecoderConfig, token_ids, lengths,
     ctx = p_slots * page
 
     positions = lengths[:, None]                                  # [S, 1]
-    x = cm.embedding(params["embed"], token_ids[:, None])         # [S, 1, D]
+    x = _scaled(cm.embedding(params["embed"], token_ids[:, None]),
+                cfg.embedding_multiplier)                         # [S, 1, D]
 
     write_logical = lengths // page
     write_page = jnp.where(
@@ -856,9 +976,11 @@ def paged_decode_step(params: dict, cfg: DecoderConfig, token_ids, lengths,
             write_off[:, None], active[:, None], page_table=page_table,
             off=lengths, mask=valid, block=False, kv_sharding=kv_sharding,
             attention_kernel=attention_kernel,
-            kernel_interpret=kernel_interpret)
-    x = cm.rms_norm(params["norm_out"], x, cfg.norm_eps)
-    logits = cm.dense(params["lm_head"], x).astype(jnp.float32)[:, -1, :]
+            kernel_interpret=kernel_interpret,
+            # lane s holds slot s: its state is row s + 1; an inactive lane
+            # reads and writes the scratch row
+            **_ssm_operands(cfg, jnp.where(active, jnp.arange(s) + 1, 0), None))
+    logits = lm_logits(params, x, cfg)[:, -1, :]
     if not return_logits:
         logits = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     return (logits, new_k, new_v, *moe)

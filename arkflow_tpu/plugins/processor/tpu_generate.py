@@ -30,7 +30,10 @@ Config:
                              # ...; a layer pattern: layer_types, swa_*,
                              # index_*, experts_held (docs/CONFIG.md); such
                              # a model serves through serving: continuous
-                             # on one chip only
+                             # on one chip only; so does the hybrid block
+                             # (mamba_*: a Mamba-2 mixer beside attention,
+                             # a recurrent state a slot; needs
+                             # prefill_chunk > 0)
     text_field: __value__
     tokenizer: meta-llama/Llama-3-8B     # optional (hash fallback otherwise)
     max_input: 256
@@ -161,6 +164,18 @@ class TpuGenerateProcessor(Processor):
                     "continuous serving shards the KV pool over KV heads, "
                     "and a latent (MLA) page has one shared row per token "
                     "(remove mesh)")
+        if getattr(self.cfg, "hybrid", False):
+            # before the host init too
+            if serving != "continuous":
+                raise ConfigError(
+                    "a model with the hybrid block (mamba_d_ssm > 0) "
+                    "generates through serving: continuous only: the batch "
+                    "path's contiguous cache carries no recurrent state")
+            if mesh_config:
+                raise ConfigError(
+                    "a model with the hybrid block is served on one chip: "
+                    "the state pool and the mixer's heads have no sharding "
+                    "over a mesh yet (remove mesh)")
         self.text_field = text_field
         self.tokenizer = tokenizer
         self.max_input = max_input
@@ -254,7 +269,9 @@ class TpuGenerateProcessor(Processor):
             #: drives prefill_rows -> kv_push -> finalize_rows. A latent
             #: (MLA) page has no wire format yet: no adapter is offered,
             #: and the server's export / adopt calls raise ConfigError
-            if not getattr(self.cfg, "latent", False):
+            # (nor has a recurrent state)
+            if not (getattr(self.cfg, "latent", False)
+                    or getattr(self.cfg, "hybrid", False)):
                 self.disagg = self
 
         reg = global_registry()

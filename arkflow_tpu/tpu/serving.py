@@ -148,18 +148,22 @@ def pack_operands(ids, a, b, table) -> np.ndarray:
                            for part in (ids, a, b, table)])
 
 
-def unpack_operands(packed, rows: int, pages: int, ring: int = 0):
+def unpack_operands(packed, rows: int, pages: int, ring: int = 0,
+                    state: int = 0):
     """Inside a step's program: ``pack_operands``' four parts again (static
     slices; ``c`` is whatever the array's size leaves). ``ring`` > 0: a
     table row is the kept pages and then so many columns of the window
-    pool's ring, handed on as (kept, ring)."""
-    c = packed.shape[0] // rows - 2 - pages - ring
+    pool's ring, handed on as (kept, ring). ``state`` 1: a table row ends
+    with the slot's row of the state pool, handed on as a fifth part."""
+    c = packed.shape[0] // rows - 2 - pages - ring - state
     ids, a, b, table = jnp.split(
         packed, [rows * c, rows * (c + 1), rows * (c + 2)])
-    table = table.reshape(rows, pages + ring)
+    table = table.reshape(rows, pages + ring + state)
+    held = (table[:, -1],) if state else ()
+    table = table[:, :pages + ring]
     if ring:
         table = (table[:, :pages], table[:, pages:])
-    return ids.reshape(rows, c), a, b, table
+    return ids.reshape(rows, c), a, b, table, *held
 
 
 def _chunk_counts(so_far, stats):
@@ -246,6 +250,11 @@ class GenerationServer:
             if has]
         if self._layered:
             self._refuse_layered(prefix_cache_pages, speculative_tokens)
+        #: a recurrent state a slot beside the K/V pages (the hybrid block)
+        self._stateful = bool(cfg.hybrid)
+        if self._stateful:
+            self._refuse_stateful(prefix_cache_pages, speculative_tokens,
+                                  dispatch_depth)
         self._win_cols = window_ring_pages(
             cfg, page_size, self.prefill_chunk) if cfg.latent else 0
         #: page 0 of the window pool is scratch too; every slot can hold a
@@ -516,13 +525,32 @@ class GenerationServer:
                                  "step, over expert layers",
                                  {"model": name, "kind": kind}))
             for kind in ("decode", "chunk", "prefill")}
-        #: (gauge, of the window pool?, bytes a page) per pool of a pattern
+        #: (gauge, what holds its rows: "window" / "pages" / "slots", bytes
+        #: one of those holds) per pool, where there is more than one kind
         self.m_kv_live = [
             (reg.gauge("arkflow_gen_kv_live_bytes", "bytes of cached rows "
                        "live in a pool (pages held by slots or the prefix "
-                       "cache)", {"model": name, "pool": pool.name}),
-             bool(pool.window), page_size * pool.bytes_per_token)
-            for pool in cache_spec(cfg)] if self._layered else []
+                       "cache; a state pool: busy slots)",
+                       {"model": name, "pool": pool.name}),
+             "slots" if pool.per_slot else "window" if pool.window else "pages",
+             pool.bytes_per_slot or page_size * pool.bytes_per_token)
+            for pool in cache_spec(cfg)] if self._layered or self._stateful else []
+        # a recurrent state: what advanced it and what a step carried past
+        # it, known on the host from lengths (no fetch), and how often a
+        # slot was handed to a new tenant
+        self.m_ssm = {} if not self._stateful else {
+            kind: tuple(reg.counter(metric, text, {"model": name, "kind": kind})
+                        for metric, text in (
+                            ("arkflow_gen_ssm_tokens_total",
+                             "valid tokens that advanced a recurrent state"),
+                            ("arkflow_gen_ssm_masked_total",
+                             "padded positions and idle lanes a step carried "
+                             "past the states")))
+            for kind in ("decode", "chunk")}
+        self.m_ssm_resets = reg.counter(
+            "arkflow_gen_ssm_state_resets_total",
+            "slots whose recurrent state a prompt's first chunk reset",
+            {"model": name})
         self.m_win_freed = reg.counter(
             "arkflow_gen_window_pages_freed_total",
             "window-pool pages freed because the window passed them "
@@ -560,6 +588,40 @@ class GenerationServer:
                 "a later query's indexer may select, and the verify step "
                 "does not slide the window pool")
 
+    def _refuse_stateful(self, prefix_cache_pages, speculative_tokens,
+                         dispatch_depth) -> None:
+        """What a model that carries a recurrent state a slot is not served
+        with yet, and why: a state is overwritten by every token, so what
+        is benign for K/V rows (a stale row, an aliased page, a lane that
+        rides one step too long) is not for it."""
+        if self.mesh is not None:
+            raise ConfigError(
+                "a model with the hybrid block (mamba_d_ssm > 0) is served "
+                "on one chip: the state pool and the mixer's heads have no "
+                "sharding over a mesh yet (remove mesh)")
+        if self.prefill_chunk <= 0:
+            raise ConfigError(
+                "a model that carries a recurrent state prefills in chunks "
+                "through the cache: set prefill_chunk > 0 (the chunk's "
+                "program is the one that is told its slot and resets it)")
+        if prefix_cache_pages:
+            raise ConfigError(
+                "prefix_cache_pages does not compose with a recurrent "
+                "state: aliased pages skip the very tokens whose state the "
+                "rest of the prompt needs, and no state snapshot is kept "
+                "beside a cached prefix yet")
+        if speculative_tokens:
+            raise ConfigError(
+                "speculative_tokens does not compose with a recurrent "
+                "state: a rejected draft has already advanced the state "
+                "(for K/V it only leaves a stale row), and there is no "
+                "rollback yet")
+        if int(dispatch_depth) > 1:
+            raise ConfigError(
+                "dispatch_depth > 1 does not compose with a recurrent "
+                "state yet: a lane that finished at step N still rides "
+                "step N+1 and advances its slot's state")
+
     def _on_tpu(self) -> bool:
         """Backend check for the compiled Pallas path (the probe shared
         with the runner's auto-flash resolution)."""
@@ -576,8 +638,10 @@ class GenerationServer:
         then one decode step and one 2-token chunk with BOTH kernels,
         judged by ``logits_parity`` (bf16 tolerance; argmax must agree
         wherever the reference's top-2 margin decides it). Returns the
-        worse of the two verdicts. The steps are jitted with params as an
-        argument, like the serving steps; one-time init cost."""
+        worse of the two verdicts (a hybrid model's steps carry their states
+        along, so the mixer's two kernels are held too). The steps are
+        jitted with params as an argument, like the serving steps; one-time
+        init cost."""
         from arkflow_tpu.models.paged_decode import paged_prefill_chunk
         from arkflow_tpu.tpu.serving_core import logits_parity
 
@@ -615,7 +679,9 @@ class GenerationServer:
         page = self.page_size
         n0 = min(page + 1, self.max_seq)  # crosses a page boundary
         pages_per = -(-(n0 + 3) // page)  # room for prompt + decode + chunk
-        kp, vp = init_page_pool(self.cfg, 1 + 2 * pages_per, page)
+        kp, vp = init_page_pool(self.cfg, 1 + 2 * pages_per, page, slots=2)
+        # a hybrid model's two rows hold slots 0 and 1: state rows 1 and 2
+        held = {"ssm_rows": jnp.asarray([1, 2], jnp.int32)} if self._stateful else {}
         rng = np.random.RandomState(1234)
         ids = np.zeros((2, n0), np.int32)
         ids[0] = rng.randint(1, self.cfg.vocab_size, n0)
@@ -625,31 +691,45 @@ class GenerationServer:
         table[0] = np.arange(1, 2 * pages_per, 2)[::-1]  # non-contiguous
         table[1] = np.arange(2, 2 * pages_per + 1, 2)
         table = jnp.asarray(table)
-        _, kp, vp = prefill(
-            self.params, self.cfg, jnp.asarray(ids), lens, table, kp, vp)
+        if self._stateful:  # prefills in chunks only: seed as it serves
+            _, kp, vp = chunk(self.params, self.cfg, jnp.asarray(ids),
+                              jnp.zeros_like(lens), lens, table, kp, vp, **held)
+        else:
+            _, kp, vp = prefill(
+                self.params, self.cfg, jnp.asarray(ids), lens, table, kp, vp)
         tok = jnp.asarray(ids[:, 0])
         act = jnp.asarray([True, True])
         ref, *_ = decode(self.params, self.cfg, tok, lens, act, table, kp, vp,
                          return_logits=True)
         got, *_ = decode(self.params, self.cfg, tok, lens, act, table, kp, vp,
                          return_logits=True, **paged)
-        verdict = logits_parity(ref, got)
+        # the tolerance is of the output head's own product: a model that
+        # scales its logits (``lm_head_multiplier``) is judged before that
+        parity = lambda a, b: logits_parity(  # noqa: E731
+            a / self.cfg.lm_head_multiplier, b / self.cfg.lm_head_multiplier)
+        verdict = parity(ref, got)
         if not verdict["ok"]:
             return verdict
         cids = jnp.asarray(rng.randint(1, self.cfg.vocab_size, (2, 2)),
                            jnp.int32)
         clen = jnp.asarray([2, 2], jnp.int32)
         ref, *_ = chunk(self.params, self.cfg, cids, lens, clen, table, kp, vp,
-                        return_all=True)
+                        return_all=True, **held)
         got, *_ = chunk(self.params, self.cfg, cids, lens, clen, table, kp, vp,
-                        return_all=True, **paged)
-        return logits_parity(ref, got)
+                        return_all=True, **paged, **held)
+        return parity(ref, got)
 
     def _init_pools(self):
         """Fresh KV page pools, placed with their tensor-parallel sharding
         under a mesh (KV heads over ``tp``; replicated otherwise)."""
         kp, vp = init_page_pool(self.cfg, self.num_pages, self.page_size,
-                                self.num_win_pages)
+                                self.num_win_pages, slots=self.slots)
+        #: whose state each slot's row of the state pool holds — the
+        #: tenant's (prompt, tokens), its own lists — and which tenant of
+        #: the slot that is: set where a prompt's first chunk resets the row
+        #: and KEPT when the tenant finishes (the row moves again only under
+        #: the next tenant)
+        self._state_tenant: list[tuple] = [(None, None, 0)] * self.slots
         if self._kv_io_sharding is not None:
             kp = jax.device_put(kp, self._kv_io_sharding)
             vp = jax.device_put(vp, self._kv_io_sharding)
@@ -670,6 +750,7 @@ class GenerationServer:
         kern = dict(attention_kernel=self.decode_kernel,
                     kernel_interpret=self.kernel_interpret)
         pages, ring = self.pages_per_slot, self._win_cols
+        state = int(self._stateful)
         # what else rides a step, on the device already: a sampling server's
         # key, the step before's tokens (depth 2), a routed chunk's counters
         keyed = int(self._key is not None)
@@ -694,7 +775,8 @@ class GenerationServer:
         # so XLA updates them in place instead of copying hundreds of MB per
         # decode step.
         def _decode(params, packed, kp, vp, *dev):
-            tok, lens, act, table = unpack_operands(packed, self.slots, pages, ring)
+            tok, lens, act, table = unpack_operands(
+                packed, self.slots, pages, ring, state)[:4]
             tok = tok[:, 0]
             if piped:  # a lane packed as -1 takes the previous step's token
                 prev, *dev = dev
@@ -714,10 +796,11 @@ class GenerationServer:
             return out, kp, vp, *key
 
         def _chunk(params, packed, kp, vp, *dev):
-            ids, off, clen, table = unpack_operands(packed, 1, pages, ring)
+            ids, off, clen, table, *held = unpack_operands(
+                packed, 1, pages, ring, state)
             logits, kp, vp, *stats = paged_prefill_chunk(
                 params, cfg, ids, off, clen, table, kp, vp,
-                kv_sharding=kv, **kern)
+                kv_sharding=kv, **kern, ssm_rows=next(iter(held), None))
             if stats:
                 # a routed model: the prompt's counters ride on the device
                 # behind the chunk before's token (``_no_counts`` at first)
@@ -1053,6 +1136,12 @@ class GenerationServer:
         return req.future
 
     def _refuse_latent_pages(self, what: str) -> None:
+        if self._stateful:
+            raise ConfigError(
+                f"{what} ships K/V page slabs; a recurrent state has no "
+                "wire form yet, and pages without it cannot be decoded from "
+                "— a model with the hybrid block prefills and decodes on "
+                "the same server")
         if self.cfg.latent:
             raise ConfigError(
                 f"{what} ships per-head K/V page slabs split along the "
@@ -1268,15 +1357,34 @@ class GenerationServer:
     def _table(self, *slots: int) -> np.ndarray:
         """Page table rows of ``slots`` (default: all), padded to slot width;
         with a window pool, the slot's ring of window pages follows (the
-        page of logical index i in column ``i % columns``)."""
+        page of logical index i in column ``i % columns``); with a state
+        pool, the slot's row of it comes last."""
         slots = slots or range(self.slots)
         kept, ring = self.pages_per_slot, self._win_cols
-        table = np.zeros((len(slots), kept + ring), np.int32)
+        table = np.zeros((len(slots), kept + ring + self._stateful), np.int32)
         for row, s in enumerate(slots):
             table[row, :len(self._slot_pages[s])] = self._slot_pages[s]
             for i, p in self._slot_win[s].items():
                 table[row, kept + i % ring] = p
+            if self._stateful:  # slot s's state: row s + 1 (row 0 scratch)
+                table[row, -1] = s + 1
         return table
+
+    def slot_state(self, slot: int) -> dict:
+        """What ``slot``'s row of the state pool holds, fetched from the
+        device: ``prompt`` and ``tokens`` of the tenant whose first chunk
+        reset the row last (None: never held), ``tenancy`` which tenant of
+        the slot that is, ``state`` [layers, heads, d_state, d_head]
+        float32. A finished tenant's row stays as its last step left it —
+        after its prompt and all but the last of its tokens — until the next
+        tenant's first chunk. Call between steps: a step in flight holds the
+        donated pools."""
+        if not self._stateful:
+            raise ConfigError("slot_state: this model carries no recurrent state")
+        prompt, tokens, tenancy = self._state_tenant[slot]
+        row = jnp.asarray(slot + 1, jnp.int32)  # an operand: one program
+        return {"prompt": prompt, "tokens": tokens, "tenancy": tenancy,
+                "state": jax.device_get(self.k_pages["ssm"][:, row])}
 
     def _slide_window(self, slot: int, first: int, last: int) -> None:
         """The slot's window pages for a step whose queries sit at positions
@@ -1322,7 +1430,7 @@ class GenerationServer:
         if shared_len > 0:
             self.m_prefix_hits.inc()
             self.m_prefix_pages.inc(shared_len // self.page_size)
-        if (shared_len > 0 or self._layered
+        if (shared_len > 0 or self._layered or self._stateful
                 or (self.prefill_chunk and n > self.prefill_chunk)):
             # cooperative admission: the serve loop interleaves prefill
             # steps with decode; the slot joins decode once fully prefilled.
@@ -1445,6 +1553,14 @@ class GenerationServer:
             ids[:len(chunk)] = chunk
             self._slide_window(slot, off, off + len(chunk) - 1)
             packed = pack_operands(ids, off, len(chunk), self._table(slot))
+            if self._stateful:
+                valid, masked = self.m_ssm["chunk"]
+                valid.inc(len(chunk))
+                masked.inc(c - len(chunk))
+                if off == 0:  # the chunk's program starts from a zero state
+                    self.m_ssm_resets.inc()
+                    self._state_tenant[slot] = (
+                        req.prompt, req.tokens, self._state_tenant[slot][2] + 1)
             new_off = off + len(chunk)
             so_far = () if kind != "chunk" or not self._moe_layers else (
                 self._no_counts if req.chunk_moe is None else req.chunk_moe,)
@@ -1570,10 +1686,11 @@ class GenerationServer:
         total = self.num_pages - 1
         if total:
             self.m_pool_occupancy.set((total - len(self._free_pages)) / total)
-        for gauge, window, page_bytes in self.m_kv_live:
-            held = (self.num_win_pages - 1 - len(self._win_free) if window
-                    else total - len(self._free_pages))
-            gauge.set(held * page_bytes)
+        for gauge, holder, unit_bytes in self.m_kv_live:
+            held = {"window": self.num_win_pages - 1 - len(self._win_free),
+                    "pages": total - len(self._free_pages),
+                    "slots": busy}[holder]
+            gauge.set(held * unit_bytes)
         # windowed tokens/sec: cheap enough to refresh every loop pass
         now = time.monotonic()
         if self._rate_window is None:
@@ -1709,6 +1826,10 @@ class GenerationServer:
                 self._slide_window(s, int(self._lengths[s]), int(self._lengths[s]))
             packed = pack_operands(self._cur_tokens, self._lengths, act,
                                    self._table())
+            if self._stateful:
+                valid, masked = self.m_ssm["decode"]
+                valid.inc(int(act.sum()))
+                masked.inc(self.slots - int(act.sum()))
         # off-loop + gated: one device-step of wall time (plus first compile)
         self._apply_decode(act, await self._run_device_step(
             ("decode",), packed, *self._no_prev))

@@ -370,3 +370,114 @@ def test_dsa_sparse_attention_compiles(v5e, rows, chunk, pages):
         ((rows, chunk, pages * 16), jnp.float32))
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "dsa_sparse_attention" in text
+
+
+# -- the recurrent-state kind (Falcon-H1-34B widths, 128 slots, chunk 256) ----
+
+FALCON_SLOTS, FALCON_TABLE, FALCON_CHUNK = 128, 64, 256
+#: one layer's slab of the state pool is 129 x 4 MiB = 541 MB: a single
+#: surviving copy of it (or of the 2.16 GB pool) fails
+STATE_TEMP_LIMIT = 256 * 1024 * 1024
+
+
+def _falcon_cfg(layers: int = 4):
+    from arkflow_tpu.models import decoder as dec
+
+    return dec.DecoderConfig(
+        vocab_size=261120, dim=5120, layers=layers, heads=20, kv_heads=4,
+        head_dim=128, ffn=21504, max_seq=262144, rope_theta=100000000000,
+        norm_eps=1e-5, mamba_d_ssm=4096, mamba_n_heads=32, mamba_d_head=128,
+        mamba_d_state=256, mamba_n_groups=2, mamba_d_conv=4,
+        mamba_chunk_size=128, embedding_multiplier=5.656854249492381,
+        attention_out_multiplier=0.0375, key_multiplier=0.011048543456039804,
+        lm_head_multiplier=0.0078125, ssm_in_multiplier=0.25,
+        ssm_out_multiplier=0.08838834764831845,
+        ssm_multipliers=(0.3535533905932738, 0.25, 0.1767766952966369, 0.5,
+                         0.3535533905932738),
+        mlp_multipliers=(0.1767766952966369, 0.011160714285714284))
+
+
+@pytest.mark.parametrize("step", ["update", "scan"])
+def test_ssm_kernels_compile_in_place(v5e, step):
+    """The decode update over 128 lanes and the chunk scan of one 256-token
+    chunk at Falcon-H1-34B's mixer sizes, on the whole 2.16 GB state pool:
+    the pool is aliased to the output (no temporary at all of its size)."""
+    from arkflow_tpu.ops import ssm_scan as ss
+
+    f32 = jnp.float32
+    pool = ((4, FALCON_SLOTS + 1, 32, 256, 128), f32)
+    if step == "update":
+        b = FALCON_SLOTS
+        fn = lambda st, layer, rows, x, dt, a, bm, cm: ss.ssm_state_update(  # noqa: E731
+            st, layer, rows, x, dt, a, bm, cm, kernel=True)
+        shapes = (pool, ((), I32), ((b,), I32), ((b, 32, 128), f32),
+                  ((b, 32), f32), ((32,), f32), ((b, 2, 256), f32),
+                  ((b, 2, 256), f32))
+    else:
+        t = FALCON_CHUNK
+        fn = lambda st, layer, rows, fr, x, dt, a, bm, cm: ss.ssm_chunk_scan(  # noqa: E731
+            st, layer, rows, fr, x, dt, a, bm, cm, 128, kernel=True)
+        shapes = (pool, ((), I32), ((1,), I32), ((1,), jnp.bool_),
+                  ((1, t, 32, 128), f32), ((1, t, 32), f32), ((32,), f32),
+                  ((1, t, 2, 256), f32), ((1, t, 2, 256), f32))
+    one = SingleDeviceSharding(v5e[0])
+    compiled = jax.jit(fn, donate_argnums=(0,)).lower(
+        *[jax.ShapeDtypeStruct(s, d, sharding=one) for s, d in shapes]).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert ("ssm_state_update" if step == "update" else "ssm_chunk_scan") in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 1024 * 1024
+
+
+def _hybrid_steps(devices):
+    """The hybrid ``_decode`` (128 lanes) and ``_chunk`` (1 x 256) programs
+    as the server jits them — both pool dicts donated — compiled for the
+    described chip at the cell's shapes."""
+    from arkflow_tpu.models import decoder as dec
+    from arkflow_tpu.models.paged_decode import (init_page_pool, paged_decode_step,
+                                                 paged_prefill_chunk)
+
+    cfg = _falcon_cfg()
+    one = SingleDeviceSharding(devices[0])
+
+    def struct(a, dtype=None):
+        return jax.ShapeDtypeStruct(a.shape, dtype or a.dtype, sharding=one)
+
+    params = jax.tree_util.tree_map(
+        struct, jax.eval_shape(lambda: dec.init(jax.random.PRNGKey(0), cfg)),
+        dec.serve_dtypes(cfg))
+    kp, vp = jax.tree_util.tree_map(struct, jax.eval_shape(
+        lambda: init_page_pool(cfg, 1 + FALCON_SLOTS * FALCON_TABLE, PAGE,
+                               slots=FALCON_SLOTS)))
+    kern = dict(attention_kernel="paged")
+
+    def decode(p, tok, lens, act, table, kp, vp):
+        return paged_decode_step(p, cfg, tok, lens, act, table, kp, vp,
+                                 return_logits=True, **kern)
+
+    def chunk(p, ids, off, clen, table, rows, kp, vp):
+        return paged_prefill_chunk(p, cfg, ids, off, clen, table, kp, vp,
+                                   ssm_rows=rows, **kern)
+
+    def compiled(fn, *operands):
+        n = len(operands)
+        return jax.jit(fn, donate_argnums=(n + 1, n + 2)).lower(
+            params, *[jax.ShapeDtypeStruct(s, d, sharding=one) for s, d in operands],
+            kp, vp).compile()
+
+    s = FALCON_SLOTS
+    return (
+        compiled(decode, ((s,), I32), ((s,), I32), ((s,), jnp.bool_),
+                 ((s, FALCON_TABLE), I32)),
+        compiled(chunk, ((1, FALCON_CHUNK), I32), ((1,), I32), ((1,), I32),
+                 ((1, FALCON_TABLE), I32), ((1,), I32)))
+
+
+def test_hybrid_steps_carry_the_state_pool_whole(v5e):
+    """The state pool rides through the layer scan beside the K/V pools: no
+    layer's 541 MB slab of states is copied out, updated and stacked back,
+    and the whole programs fit the chip beside 8.8 GB of weights."""
+    for step, name in zip(_hybrid_steps(v5e), ("ssm_state_update", "ssm_chunk_scan")):
+        text = step.as_text()
+        assert "tpu_custom_call" in text and name in text
+        assert step.memory_analysis().temp_size_in_bytes < STATE_TEMP_LIMIT
