@@ -297,11 +297,11 @@ class Tracer:
         return span.span_id
 
     @staticmethod
-    def _observe_stage(stage: str, dur_s: float) -> None:
+    def _observe_stage(stage: str, dur_s: float, **labels: str) -> None:
         global_registry().histogram(
             "arkflow_stage_seconds",
             "per-batch stage latency from the trace layer",
-            {"stage": stage}).observe(dur_s)
+            {"stage": stage, **labels}).observe(dur_s)
 
     def _append(self, trace_id: str, span: Span) -> None:
         with self._lock:
@@ -626,11 +626,13 @@ class annotated:
         return self.t1 - self.t0
 
 
-def observe_stage(stage: str, dur_s: float) -> None:
+def observe_stage(stage: str, dur_s: float, **labels: str) -> None:
     """Feed ``arkflow_stage_seconds{stage}`` alone: a stretch that belongs
-    to no request touches no trace tree."""
+    to no request touches no trace tree. ``labels`` join ``stage`` in the
+    label set (the stages inside a generate step's hop carry the step's
+    ``kind``: a reader subtracts ONE program's device time from them)."""
     if _GLOBAL.cfg.enabled:
-        Tracer._observe_stage(stage, max(0.0, float(dur_s)))
+        Tracer._observe_stage(stage, max(0.0, float(dur_s)), **labels)
 
 
 class loop_stage(annotated):

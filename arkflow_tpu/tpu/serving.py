@@ -110,33 +110,45 @@ class _InFlightDecode:
     act: "np.ndarray"
     reqs: list
     dispatched_at: float
-    #: the enqueue's share of this step's ``gen_device_wait`` and
-    #: ``gen_handoff``, observed once with the fetch's share at apply
-    wait_s: float = 0.0
-    handoff_s: float = 0.0
+    #: the enqueue's hop: its share of this step's ``gen_device_wait`` and
+    #: ``gen_handoff`` and its ``gen_dispatch``, observed once with the
+    #: fetch's hop at apply
+    hop: "_Hop"
 
 
 class _Hop:
     """Stamps around one blocking call handed to an executor thread. The
-    thread carries no trace scope, so it annotates and stamps
-    (``gen_device_wait:<kind>``) and the coroutine reads the result:
-    seconds inside the call, and seconds in the two thread hops around it
+    thread carries no trace scope, so it annotates and stamps and the
+    coroutine reads the result: seconds inside the call
+    (``gen_device_wait:<kind>``), seconds in the two thread hops around it
     (call -> the thread starts, the thread ends -> the coroutine resumes,
-    whatever else the event loop ran in between included)."""
+    whatever else the event loop ran in between included), and the call's
+    own division into ``stage``s, which the call opens itself (it is handed
+    the hop): ``gen_dispatch``, ``gen_ready_wait``, ``gen_fetch``."""
 
-    __slots__ = ("wait", "t_call")
+    __slots__ = ("kind", "wait", "t_call", "parts", "handoff_s")
 
     def __init__(self, kind: str):
+        self.kind = kind
         self.wait = annotated(f"gen_device_wait:{kind}")
+        self.parts: list[tuple[str, annotated]] = []
         self.t_call = time.perf_counter()
 
     def run(self, fn):
         with self.wait:
-            return fn()
+            return fn(self)
 
-    def done(self) -> tuple[float, float]:
+    def stage(self, stage: str) -> annotated:
+        """``<stage>:<kind>`` around a stretch of the call, on its thread."""
+        part = annotated(f"{stage}:{self.kind}")
+        self.parts.append((stage, part))
+        return part
+
+    def done(self) -> "_Hop":
+        """Back on the coroutine: close the second thread hop."""
         w = self.wait
-        return w.dur_s, (w.t0 - self.t_call) + (time.perf_counter() - w.t1)
+        self.handoff_s = (w.t0 - self.t_call) + (time.perf_counter() - w.t1)
+        return self
 
 
 def pack_operands(ids, a, b, table) -> np.ndarray:
@@ -483,11 +495,6 @@ class GenerationServer:
             "gap between step N completing and step N+1 launching "
             "(device idle between consecutive steps)",
             {"model": name, "path": "generate"})
-        self.m_kernel_paged = reg.gauge(
-            "arkflow_gen_decode_kernel_paged",
-            "1 when the paged flash-attention kernel serves decode/chunk "
-            "(0 = dense gather reference)", {"model": name})
-        self.m_kernel_paged.set(1 if self.decode_kernel == "paged" else 0)
         # time-to-first-token: the latency-bound regime's headline metric —
         # stamped once per request at its first decoded token (or at page
         # export on a prefill-role worker, where the first token ships with
@@ -1059,16 +1066,22 @@ class GenerationServer:
         # a pool reset must consume the pools it already owned, never the
         # fresh ones. The jitted fn resolves LAZILY at call time: the probe
         # step must use the heal gate's rebuilt executable, not the cached one
-        def blocking(kp=self.k_pages, vp=self.v_pages):
+        def blocking(hop, kp=self.k_pages, vp=self.v_pages):
             core.apply_chaos()
-            # the jitted call only enqueues; named apart inside the hop's
-            # gen_device_wait so a profile tells dispatch from waiting
-            with annotated(f"gen_dispatch:{key[0]}"):
+            # the hop's gen_device_wait, divided: the jitted call uploads
+            # the packed array and enqueues; the wait is launch + the
+            # device's step + this thread's wake; the fetch is the copy
+            with hop.stage("gen_dispatch"):
                 out = getattr(self, "_" + key[0])(packed, kp, vp, *dev, *keys)
-            if final:
-                out[0].copy_to_host_async()
-            jax.block_until_ready(out)
-            return (np.asarray(out[0]) if final else out[0]), *out[1:]
+            with hop.stage("gen_ready_wait"):
+                if final:
+                    out[0].copy_to_host_async()
+                jax.block_until_ready(out)
+            if not final:
+                return out
+            with hop.stage("gen_fetch"):
+                tokens = np.asarray(out[0])
+            return tokens, *out[1:]
 
         self._track_gen_dispatch()
         tokens, self.k_pages, self.v_pages, *keys = await self._finish_step(
@@ -1078,10 +1091,12 @@ class GenerationServer:
         return tokens
 
     async def _finish_step(self, kind: str, blocking, deadline,
-                           wait_s: float = 0.0, handoff_s: float = 0.0):
+                           earlier: Optional[_Hop] = None):
         """Run ``blocking``, the call that ends one step, on an executor
         thread under ``deadline`` (None: unwatched) and observe the step's
-        ``gen_device_wait`` / ``gen_handoff`` (plus an earlier hop's share)."""
+        ``gen_device_wait`` / ``gen_handoff`` and, by ``kind``, the stages
+        inside the wait (plus those of ``earlier``, the hop that enqueued a
+        pipelined step)."""
         core = self.core
         hop = _Hop(kind)
         try:
@@ -1100,9 +1115,12 @@ class GenerationServer:
             # an abandoned step counts complete: the device stopped doing
             # useful work, and the reset path rebuilds from fresh pools
             self._track_gen_complete()
-        wait, handoff = hop.done()
-        observe_stage("gen_device_wait", wait_s + wait)
-        observe_stage("gen_handoff", handoff_s + handoff)
+        hops = (hop.done(),) if earlier is None else (earlier, hop.done())
+        observe_stage("gen_device_wait", sum(h.wait.dur_s for h in hops))
+        observe_stage("gen_handoff", sum(h.handoff_s for h in hops))
+        for h in hops:
+            for stage, part in h.parts:
+                observe_stage(stage, part.dur_s, kind=kind)
         core.health.mark_success()
         return out
 
@@ -1921,18 +1939,17 @@ class GenerationServer:
         # pools bound eagerly (same zombie discipline as the classic path);
         # the dispatch only ENQUEUES — the jit returns device futures, all
         # waiting happens in _drain_pipeline under the per-step deadline
-        def enqueue(kp=self.k_pages, vp=self.v_pages):
-            out = self._decode(packed, kp, vp, prev)
+        def enqueue(hop, kp=self.k_pages, vp=self.v_pages):
+            with hop.stage("gen_dispatch"):
+                out = self._decode(packed, kp, vp, prev)
             out[0].copy_to_host_async()  # lands while the step still runs
             return out
 
         hop = _Hop("decode")
         nxt, self.k_pages, self.v_pages = await loop.run_in_executor(
             None, hop.run, enqueue)
-        wait_s, handoff_s = hop.done()
         rec = _InFlightDecode(nxt=nxt, act=act, reqs=list(self._slot_req),
-                              dispatched_at=time.monotonic(),
-                              wait_s=wait_s, handoff_s=handoff_s)
+                              dispatched_at=time.monotonic(), hop=hop.done())
         self._pipelined_dispatches += 1
         if pend is not None:
             await self._drain_pipeline()
@@ -1959,15 +1976,18 @@ class GenerationServer:
         rec, self._pipeline = self._pipeline, None
         core = self.core
 
-        def blocking():
+        def blocking(hop):
             core.apply_chaos()
-            return np.asarray(rec.nxt)
+            with hop.stage("gen_ready_wait"):
+                jax.block_until_ready(rec.nxt)
+            with hop.stage("gen_fetch"):
+                return np.asarray(rec.nxt)
 
         deadline = core.deadline_for(False)  # pipelined steps are warm
         if deadline is not None:
             deadline = core.deadline_remaining(deadline, rec.dispatched_at)
         self._apply_decode(rec.act, await self._finish_step(
-            "decode", blocking, deadline, rec.wait_s, rec.handoff_s), rec.reqs)
+            "decode", blocking, deadline, rec.hop), rec.reqs)
 
     # -- speculative decode -------------------------------------------------
 

@@ -751,42 +751,127 @@ def test_generation_spans_land_in_each_requests_own_trace():
     assert tracer.summary()["traces_open"] == 0
 
 
-@pytest.mark.parametrize("server_kw", [
+GEN_SERVERS = pytest.mark.parametrize("server_kw", [
     dict(prefill_chunk=4),                    # chunked prefill + decode
     dict(prefill_chunk=0),                    # one-shot prefill
     dict(prefill_chunk=4, dispatch_depth=2),  # pipelined decode
     dict(prefill_chunk=4, speculative_tokens=2),
 ], ids=["chunked", "one_shot", "depth2", "speculative"])
-def test_serve_loop_stages_count_device_steps_and_token_gaps(server_kw):
-    """One gen_prepare / gen_device_wait / gen_handoff / gen_apply
-    observation per device step, whatever its kind; one token-gap
-    observation per token after a request's first."""
+HOP_STAGES = ("gen_dispatch", "gen_ready_wait", "gen_fetch")
+STEP_KINDS = {"decode", "chunk", "prefill", "verify"}
+
+
+def _stage_hists() -> dict:
+    """``arkflow_stage_seconds`` by (stage, kind): (seconds, observations);
+    kind None for a stage observed without one."""
+    from arkflow_tpu.obs import global_registry
+
+    return {(m.labels["stage"], m.labels.get("kind")): (m.sum, m.count)
+            for m in global_registry().collect()
+            if m.name == "arkflow_stage_seconds"}
+
+
+def _stages_since(before: dict) -> dict:
+    """What ``arkflow_stage_seconds`` gained since ``before``, same keys."""
+    added = {}
+    for key, (s, c) in _stage_hists().items():
+        s0, c0 = before.get(key, (0.0, 0))
+        if c - c0:
+            added[key] = (s - s0, c - c0)
+    return added
+
+
+def _run_counted(tag: str, server_kw: dict):
+    """Serve GEN_PROMPTS on a fresh tiny server named by ``tag`` and its
+    options. Returns the name, the outputs, what the run added to
+    ``arkflow_stage_seconds`` by (stage, kind), and the device steps it made
+    with how many of them left their tokens on the device."""
     _fresh_global()
-    name = "trace-count-" + "-".join(f"{k}{v}" for k, v in server_kw.items())
+    name = tag + "-".join(f"{k}{v}" for k, v in server_kw.items())
     server = _tiny_generation_server(name, **server_kw)
-    steps = {"n": 0}
+    made = {"steps": 0, "unfetched": 0}
     for step in ("_decode", "_chunk", "_prefill", "_verify"):
         def counted(*a, _fn=getattr(server, step), **kw):
-            steps["n"] += 1
+            made["steps"] += 1
             return _fn(*a, **kw)
 
         setattr(server, step, counted)
+    run_step = server._run_device_step
 
-    stages0 = _hist_counts("arkflow_stage_seconds", "stage")
-    gaps0 = _hist_counts("arkflow_gen_token_gap_seconds", "model")
+    def run_counted(*a, final=True, **kw):
+        made["unfetched"] += not final
+        return run_step(*a, final=final, **kw)
+
+    server._run_device_step = run_counted
+    before = _stage_hists()
 
     async def go():
         return await asyncio.gather(
             *[server.generate(p, 6) for p in GEN_PROMPTS])
 
     outs = asyncio.run(asyncio.wait_for(go(), timeout=120))
-    stages = _delta(_hist_counts("arkflow_stage_seconds", "stage"), stages0)
-    assert steps["n"] > 0
+    return name, outs, _stages_since(before), made
+
+
+@GEN_SERVERS
+def test_serve_loop_stages_count_device_steps_and_token_gaps(server_kw):
+    """One gen_prepare / gen_device_wait / gen_handoff / gen_apply
+    observation per device step, whatever its kind; one token-gap
+    observation per token after a request's first."""
+    gaps0 = _hist_counts("arkflow_gen_token_gap_seconds", "model")
+    name, outs, added, made = _run_counted("trace-count-", server_kw)
+    assert made["steps"] > 0
     for stage in ("gen_prepare", "gen_device_wait", "gen_handoff", "gen_apply"):
-        assert stages.get(stage) == steps["n"], (stage, stages, steps)
-    assert stages.get("gen_admit", 0) >= len(GEN_PROMPTS)
+        assert added[stage, None][1] == made["steps"], (stage, added, made)
+    assert added["gen_admit", None][1] >= len(GEN_PROMPTS)
     gaps = _delta(_hist_counts("arkflow_gen_token_gap_seconds", "model"), gaps0)
     assert gaps == {name: sum(len(o) for o in outs) - len(outs)}
+
+
+@GEN_SERVERS
+def test_hop_stages_count_device_steps_by_kind(server_kw):
+    """Inside the hop: one gen_dispatch and one gen_ready_wait per device
+    step, one gen_fetch per step that fetched its tokens, each under the
+    step's kind; the stages around the hop carry no kind."""
+    _, _, added, made = _run_counted("hop-count-", server_kw)
+    assert made["steps"] > 0
+    count = {st: sum(c for (stage, _), (_, c) in added.items() if stage == st)
+             for st in HOP_STAGES}
+    assert count["gen_dispatch"] == count["gen_ready_wait"] == made["steps"]
+    assert count["gen_fetch"] == made["steps"] - made["unfetched"]
+    if server_kw["prefill_chunk"]:
+        assert made["unfetched"] > 0  # the 22-token prompt: chunks before its last
+    for stage, kind in added:
+        if stage in HOP_STAGES:
+            assert kind in STEP_KINDS, (stage, kind)
+        elif stage in LOOP_STAGES:
+            assert kind is None, (stage, kind)
+    # per kind too: a kind's steps dispatch and wait once each
+    for kind in {k for (_, k) in added if k}:
+        assert added["gen_dispatch", kind][1] == added["gen_ready_wait", kind][1]
+
+
+@GEN_SERVERS
+def test_hop_stages_lie_inside_gen_device_wait(server_kw):
+    _, _, added, _ = _run_counted("hop-inside-", server_kw)
+    inside = sum(s for (stage, _), (s, _) in added.items()
+                 if stage in HOP_STAGES)
+    assert 0.0 < inside <= added["gen_device_wait", None][0]
+
+
+def test_observe_stage_labels_make_distinct_label_sets():
+    from arkflow_tpu.obs.trace import observe_stage
+
+    _fresh_global()
+    before = _stage_hists()
+    observe_stage("hop_label_probe", 0.25)
+    observe_stage("hop_label_probe", 0.5, kind="decode")
+    observe_stage("hop_label_probe", 1.0, kind="chunk")
+    observe_stage("hop_label_probe", 2.0, kind="chunk")
+    assert _stages_since(before) == {
+        ("hop_label_probe", None): (0.25, 1),
+        ("hop_label_probe", "decode"): (0.5, 1),
+        ("hop_label_probe", "chunk"): (3.0, 2)}
 
 
 def test_tracing_disabled_same_tokens_and_no_span():
