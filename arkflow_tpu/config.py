@@ -501,10 +501,11 @@ def _validate_dispatch_knobs(processors: list[dict]) -> None:
     through ``fault.inner`` chaos wrappers like the other cross-checks:
 
     - ``tpu_inference.dispatch_depth`` / ``tpu_generate.dispatch_depth``
-      must be positive ints; the generate path caps at 2 (lockstep decode
-      can only lag host bookkeeping by one step) and composes with neither
-      speculative decoding nor sampling (both at ``--validate``, not as a
-      shape/state error at stream build);
+      must be positive ints; the generate path caps at 2 (the serve loop
+      keeps one step ahead of the device, never more). What depth 2 does
+      not compose with — sampling, speculative decoding, a block or state
+      on which a lane riding one step too long is not exact — is no error:
+      such a server runs in lockstep (``GenerationServer._ahead``);
     - ``tpu_generate.decode_kernel`` must name a known kernel.
     """
     for p in processors:
@@ -531,18 +532,8 @@ def _validate_dispatch_knobs(processors: list[dict]) -> None:
                 f"got {kernel!r}")
         if depth is not None and depth > 2:
             raise ConfigError(
-                "tpu_generate.dispatch_depth caps at 2: lockstep decode "
-                "can only lag host bookkeeping by one in-flight step")
-        if depth is not None and depth > 1:
-            if int(p.get("speculative_tokens", 0) or 0) > 0:
-                raise ConfigError(
-                    "tpu_generate: dispatch_depth > 1 and speculative_tokens "
-                    "are mutually exclusive (both restructure the decode loop)")
-            if float(p.get("temperature", 0.0) or 0.0) != 0.0:
-                raise ConfigError(
-                    "tpu_generate: dispatch_depth > 1 requires greedy "
-                    "decoding (temperature 0) — a lane that finished at step "
-                    "N still rides step N+1 and would consume sampling RNG")
+                "tpu_generate.dispatch_depth caps at 2: the serve loop keeps "
+                "one step ahead of the device, never more")
 
 
 def _restart_config(m: Any) -> Optional[dict]:

@@ -66,11 +66,14 @@ Config:
                              # mismatch fails construction
                              # (kernel_parity_check: false skips the probe,
                              # kernel_interpret: true for CPU tests)
-    dispatch_depth: 2        # continuous mode: 2 pipelines decode — step
-                             # N+1 dispatches from step N's device-resident
-                             # tokens before N's outputs are fetched, so
-                             # host bookkeeping overlaps device compute.
-                             # Greedy-only; exact same tokens as depth 1
+    dispatch_depth: 2        # continuous mode, the default: one step ahead
+                             # of the device — step N+1 of any kind is
+                             # enqueued before step N is waited for,
+                             # fetched and applied, so host bookkeeping
+                             # overlaps device compute. Exact same tokens
+                             # as 1 (lockstep); where it would not be
+                             # (sampling, speculation, ...) the server
+                             # serves in lockstep by itself
     step_deadline: 2s        # continuous mode: per-step watchdog from the
                              # shared serving core (tpu/serving_core.py) — a
                              # hung step marks the server UNHEALTHY and the
@@ -118,7 +121,7 @@ class TpuGenerateProcessor(Processor):
                  mesh_config: Optional[dict] = None, prefill_chunk: int = 0,
                  speculative_tokens: int = 0, prefix_cache_pages: int = 0,
                  decode_kernel: str = "auto", kernel_interpret: bool = False,
-                 kernel_parity_check: bool = True, dispatch_depth: int = 1,
+                 kernel_parity_check: bool = True, dispatch_depth: int = 2,
                  step_deadline_s: Optional[float] = None,
                  step_deadline_first_s: Optional[float] = None,
                  health_config=None, checkpoint: Optional[str] = None):
@@ -480,7 +483,7 @@ def _build(config: dict, resource: Resource) -> TpuGenerateProcessor:
         decode_kernel=str(config.get("decode_kernel", "auto")),
         kernel_interpret=bool(config.get("kernel_interpret", False)),
         kernel_parity_check=bool(config.get("kernel_parity_check", True)),
-        dispatch_depth=int(config.get("dispatch_depth", 1)),
+        dispatch_depth=int(config.get("dispatch_depth", 2)),
         step_deadline_s=core_cfg["step_deadline_s"],
         step_deadline_first_s=core_cfg["step_deadline_first_s"],
         health_config=core_cfg["health_config"],

@@ -38,7 +38,7 @@ import logging
 import time
 from collections import deque
 from dataclasses import dataclass, field
-from typing import Mapping, Optional
+from typing import Callable, Mapping, Optional
 
 import jax
 import jax.numpy as jnp
@@ -56,6 +56,7 @@ from arkflow_tpu.models.paged_decode import (
 from arkflow_tpu.obs import global_registry
 from arkflow_tpu.obs.trace import (annotated, current_scope, loop_stage,
                                    observe_stage, record_stage)
+from arkflow_tpu.tpu.health import HEALTHY
 from arkflow_tpu.tpu.serving_core import ServingRunnerCore
 
 logger = logging.getLogger("arkflow.serving")
@@ -98,22 +99,40 @@ class _Request:
 
 
 @dataclass
-class _InFlightDecode:
-    """One dispatched-but-unapplied decode step (``dispatch_depth`` 2).
+class _InFlightStep:
+    """One step of any kind (decode, chunk, one-shot prefill) that is
+    enqueued on the device and not yet applied on the host. The serve loop
+    keeps ONE such step while it prepares and enqueues the next, from host
+    state as it will stand once this one is applied.
 
-    ``nxt`` is the step's DEVICE-resident next-token array — fed straight
-    into the next dispatch so the device never waits for a host round trip.
-    ``reqs`` snapshots per-slot request identity at dispatch: a slot whose
-    request finished (or was replaced) before apply drops its token."""
+    ``out`` is the step's DEVICE-resident token array: a decode step behind
+    a decode step takes its lanes' tokens from it there, so the device never
+    waits for a host round trip. ``apply`` takes the fetched tokens onto
+    host state when the step lands; None for a prompt's chunk before its
+    last, whose output nobody on the host reads (its bookkeeping is done at
+    the enqueue). ``act`` / ``reqs`` (decode): the lanes that ride and whose
+    they were at dispatch; a lane whose request is no longer that one at
+    apply drops its token. ``slot`` (chunk / prefill): the slot whose prompt
+    the step advances; with ``apply`` set it is the prompt's last step, and
+    the slot joins decode only once its first token is applied."""
 
-    nxt: object
-    act: "np.ndarray"
-    reqs: list
-    dispatched_at: float
+    kind: str
+    out: object
     #: the enqueue's hop: its share of this step's ``gen_device_wait`` and
     #: ``gen_handoff`` and its ``gen_dispatch``, observed once with the
-    #: fetch's hop at apply
+    #: fetch's hop when the step lands
     hop: "_Hop"
+    #: ``time.monotonic()`` at the enqueue: the step's deadline runs from it
+    dispatched_at: float
+    apply: Optional[Callable] = None
+    act: Optional["np.ndarray"] = None
+    reqs: Optional[list] = None
+    slot: int = -1
+
+    @property
+    def seeding(self) -> int:
+        """The slot that waits for this step's token to join decode, or -1."""
+        return self.slot if self.apply is not None else -1
 
 
 class _Hop:
@@ -200,7 +219,7 @@ class GenerationServer:
                  prefill_chunk: int = 0, speculative_tokens: int = 0,
                  prefix_cache_pages: int = 0, mesh=None,
                  decode_kernel: str = "auto", kernel_interpret: bool = False,
-                 kernel_parity_check: bool = True, dispatch_depth: int = 1,
+                 kernel_parity_check: bool = True, dispatch_depth: int = 2,
                  step_deadline_s: Optional[float] = None,
                  step_deadline_first_s: Optional[float] = None,
                  health_config=None, name: str = "decoder_lm"):
@@ -265,8 +284,7 @@ class GenerationServer:
         #: a recurrent state a slot beside the K/V pages (the hybrid block)
         self._stateful = bool(cfg.hybrid)
         if self._stateful:
-            self._refuse_stateful(prefix_cache_pages, speculative_tokens,
-                                  dispatch_depth)
+            self._refuse_stateful(prefix_cache_pages, speculative_tokens)
         self._win_cols = window_ring_pages(
             cfg, page_size, self.prefill_chunk) if cfg.latent else 0
         #: page 0 of the window pool is scratch too; every slot can hold a
@@ -381,42 +399,51 @@ class GenerationServer:
                 "kernel_interpret for CPU tests); leave it at auto to serve "
                 "with the dense gather reference here")
 
-        # dispatch depth: 2 pipelines decode — step N+1 is dispatched with
-        # step N's DEVICE-resident next-token array before N's outputs are
-        # fetched, so host bookkeeping overlaps device compute. Greedy-only:
-        # the host learns about EOS one step late, so a lane that finished
-        # at N still rides N+1 (its token is dropped on apply) — exact for
-        # argmax decoding, but a sampled RNG stream or an MoE's shared
-        # expert capacity would see the dead lane and diverge from depth-1.
+        # dispatch depth: 2 (the default) keeps one step ahead of the device
+        # — step N+1 of any kind is enqueued before step N is waited for,
+        # fetched and applied, so the host's work on a step overlaps the
+        # device's (``_run_ahead``). 1 forces lockstep: every step runs to
+        # its end before the next is prepared. Running ahead must serve the
+        # same tokens, so it is on only where it is exact, decided here
+        # where it is the configuration's and per step where it is the
+        # moment's (``_may_run_ahead``):
+        # - a prompt's last chunk is applied one step later than in
+        #   lockstep, so its lane joins decode one step later. Lanes of a
+        #   dense or dropless-routed model are independent and no request's
+        #   tokens change; a sampled lane would draw from another step's
+        #   key, and the capacity-based Switch block (``num_experts``)
+        #   queues a step's live lanes into shared expert capacity, so
+        #   either serves in lockstep;
+        # - a budget's end is known a step early (the lane is masked out of
+        #   the step behind), an EOS is not: with a live ``eos_id`` a lane
+        #   that finished at N still rides N+1 and its token is dropped at
+        #   apply. Exact for greedy dense and dropless-routed lanes; a
+        #   recurrent state would be advanced past its end;
+        # - a model that carries a recurrent state serves in lockstep
+        #   whatever its ``eos_id``: with ``eos_id`` < 0 running ahead is
+        #   exact for it too (its tests pass either way), but its one
+        #   benchmark cell cannot judge a speed-up yet (PERF.md, PR 39), so
+        #   the state-kind condition stays whole until it can;
+        # - speculative decoding restructures the decode step: lockstep.
         self.dispatch_depth = int(dispatch_depth)
         if self.dispatch_depth < 1:
             raise ConfigError("dispatch_depth must be >= 1")
         if self.dispatch_depth > 2:
             raise ConfigError(
-                "dispatch_depth > 2 is not supported: lockstep decode can "
-                "only lag host bookkeeping by one step (deeper queues would "
-                "admit tokens the host has never validated)")
-        if self.dispatch_depth > 1:
-            if self.temperature != 0.0:
-                raise ConfigError(
-                    "dispatch_depth > 1 requires greedy decoding "
-                    "(temperature 0): a lane that finished at step N still "
-                    "rides step N+1, which would consume sampling RNG")
-            if self.speculative_tokens > 0:
-                raise ConfigError(
-                    "dispatch_depth > 1 and speculative_tokens are mutually "
-                    "exclusive (both restructure the decode loop)")
-            if getattr(cfg, "num_experts", 0) > 0 or cfg.routed:
-                raise ConfigError(
-                    "dispatch_depth > 1 does not compose with MoE models: "
-                    "a finished-but-still-riding lane consumes shared "
-                    "expert capacity and changes other lanes' outputs")
-        #: the one in-flight, not-yet-applied decode step (depth 2)
-        self._pipeline: Optional[_InFlightDecode] = None
-        #: monotonic count of pipelined dispatches — unlike ``_pipeline``
-        #: (None while the previous step's fetch applies), this is a stable
-        #: "did the depth-2 path engage" signal for tests/diagnostics
-        self._pipelined_dispatches = 0
+                "dispatch_depth > 2 is not supported: the serve loop keeps "
+                "one step ahead of the device (deeper queues would admit "
+                "tokens the host has never validated)")
+        self._ahead = bool(
+            self.dispatch_depth > 1 and self.temperature == 0.0
+            and self.speculative_tokens == 0
+            and not getattr(cfg, "num_experts", 0)
+            and not self._stateful)
+        #: the one enqueued, not-yet-applied step (``_run_ahead``)
+        self._pipeline: Optional[_InFlightStep] = None
+        #: steps THIS server enqueued while another was still in flight
+        #: (``m_ahead`` is registry-global) — unlike ``_pipeline`` (None
+        #: while a step lands), a stable "did it engage" signal
+        self._steps_ahead = 0
 
         #: first-seen jitted-step keys — a cold (kind, shape) compiles before
         #: it executes, so the deadline watchdog grants it the first-compile
@@ -461,6 +488,14 @@ class GenerationServer:
             kind: reg.counter("arkflow_gen_step_uploads_total", "host arrays "
                               "handed to a step", {"model": name, "kind": kind})
             for kind in ("decode", "chunk", "prefill", "verify")}
+        # how often running ahead engages: beside the observations of
+        # ``gen_device_wait`` (one a step) it is the share of steps that
+        # found the device's queue occupied when they arrived
+        self.m_ahead = {
+            kind: reg.counter("arkflow_gen_steps_ahead_total", "steps "
+                              "enqueued while another step of this server "
+                              "was still in flight", {"model": name, "kind": kind})
+            for kind in ("decode", "chunk", "prefill")}
         self.m_spec_drafted = reg.counter(
             "arkflow_gen_spec_drafted_total", "draft tokens offered for verification")
         self.m_spec_accepted = reg.counter(
@@ -595,12 +630,12 @@ class GenerationServer:
                 "a later query's indexer may select, and the verify step "
                 "does not slide the window pool")
 
-    def _refuse_stateful(self, prefix_cache_pages, speculative_tokens,
-                         dispatch_depth) -> None:
+    def _refuse_stateful(self, prefix_cache_pages, speculative_tokens) -> None:
         """What a model that carries a recurrent state a slot is not served
         with yet, and why: a state is overwritten by every token, so what
-        is benign for K/V rows (a stale row, an aliased page, a lane that
-        rides one step too long) is not for it."""
+        is benign for K/V rows (a stale row, an aliased page) is not for it.
+        A lane that rides one step too long is the third such thing: such
+        a model serves in lockstep (``_ahead``)."""
         if self.mesh is not None:
             raise ConfigError(
                 "a model with the hybrid block (mamba_d_ssm > 0) is served "
@@ -623,11 +658,6 @@ class GenerationServer:
                 "state: a rejected draft has already advanced the state "
                 "(for K/V it only leaves a stale row), and there is no "
                 "rollback yet")
-        if int(dispatch_depth) > 1:
-            raise ConfigError(
-                "dispatch_depth > 1 does not compose with a recurrent "
-                "state yet: a lane that finished at step N still rides "
-                "step N+1 and advances its slot's state")
 
     def _on_tpu(self) -> bool:
         """Backend check for the compiled Pallas path (the probe shared
@@ -759,9 +789,10 @@ class GenerationServer:
         pages, ring = self.pages_per_slot, self._win_cols
         state = int(self._stateful)
         # what else rides a step, on the device already: a sampling server's
-        # key, the step before's tokens (depth 2), a routed chunk's counters
+        # key, the decode step before's output (a server that runs ahead),
+        # a routed chunk's counters
         keyed = int(self._key is not None)
-        piped = int(self.dispatch_depth > 1)
+        piped = int(self._ahead)
         routed = int(cfg.routed)
 
         def _pick(logits, keys, *stats):
@@ -785,9 +816,9 @@ class GenerationServer:
             tok, lens, act, table = unpack_operands(
                 packed, self.slots, pages, ring, state)[:4]
             tok = tok[:, 0]
-            if piped:  # a lane packed as -1 takes the previous step's token
+            if piped:  # a lane packed as -1 takes the step before's token
                 prev, *dev = dev
-                tok = jnp.where(tok < 0, prev, tok)
+                tok = jnp.where(tok < 0, prev[:self.slots], tok)
             logits, kp, vp, *stats = paged_decode_step(
                 params, cfg, tok, lens, act != 0, table, kp, vp,
                 return_logits=True, kv_sharding=kv, **kern)
@@ -845,17 +876,20 @@ class GenerationServer:
                     not isinstance(x, jax.Array) for x in (packed, *dev)))
                 return jitted(params, packed, kp, vp, *dev)
 
+            step.jitted = jitted  # tests count its compiled programs
             return step
 
         self._decode = bind(_decode, piped + keyed, keyed)
         self._prefill = bind(_prefill, keyed, keyed)
         self._chunk = bind(_chunk, routed + keyed, keyed)
         self._verify = bind(_verify, 0, 0)
-        #: device stand-ins: no step in flight (depth 2), a first chunk
+        #: device stand-ins: no decode step in flight (shaped as one's
+        #: output: tokens, then a routed model's counters), a first chunk
         zeros = functools.partial(jnp.zeros, dtype=jnp.int32,
                                   device=self._repl_sharding)
-        self._no_prev = (zeros(self.slots),) if piped else ()
-        self._no_counts = zeros(5 + len(self._extra_counters))
+        counted = len(self._extra_counters)
+        self._no_prev = (zeros(self.slots + routed * (3 + counted)),) if piped else ()
+        self._no_counts = zeros(5 + counted)
 
     def _note_moe(self, kind: str, stats, steps: int = 1) -> None:
         """Record the routing counters (``moe_step_stats``, on the host) of
@@ -990,6 +1024,7 @@ class GenerationServer:
         rep["serving"] = "continuous"
         rep["decode_kernel"] = self.decode_kernel
         rep["dispatch_depth"] = self.dispatch_depth
+        rep["runs_ahead"] = self._ahead
         rep["draining"] = self._draining
         rep["slots"] = self.slots
         rep["slots_busy"] = sum(1 for r in self._slot_req if r is not None)
@@ -1029,9 +1064,8 @@ class GenerationServer:
 
     def _track_gen_dispatch(self) -> None:
         """Device-idle-gap bookkeeping at step launch: an open idle window
-        (no step in flight, or a drained device queue detected by the
-        pipelined path via ``is_ready`` — see ``_step_pipelined``) closes
-        here and records its gap."""
+        (no step in flight, or a drained device queue that ``_run_ahead``
+        detected via ``is_ready``) closes here and records its gap."""
         if self._gen_idle_since is not None:
             self.m_idle_gap.observe(time.monotonic() - self._gen_idle_since)
             self._gen_idle_since = None
@@ -1115,14 +1149,123 @@ class GenerationServer:
             # an abandoned step counts complete: the device stopped doing
             # useful work, and the reset path rebuilds from fresh pools
             self._track_gen_complete()
-        hops = (hop.done(),) if earlier is None else (earlier, hop.done())
+        self._observe_hops(kind, *(() if earlier is None else (earlier,)),
+                           hop.done())
+        core.health.mark_success()
+        return out
+
+    @staticmethod
+    def _observe_hops(kind: str, *hops: _Hop) -> None:
+        """One device step's ``gen_device_wait`` and ``gen_handoff`` (one
+        observation each, its hops summed) and, by ``kind``, the stages the
+        hops were divided into."""
         observe_stage("gen_device_wait", sum(h.wait.dur_s for h in hops))
         observe_stage("gen_handoff", sum(h.handoff_s for h in hops))
         for h in hops:
             for stage, part in h.parts:
                 observe_stage(stage, part.dur_s, kind=kind)
-        core.health.mark_success()
+
+    # -- one step ahead of the device ---------------------------------------
+
+    def _may_run_ahead(self, key: tuple) -> bool:
+        """Whether the step ``key`` may be enqueued before the step in
+        flight is waited for: where the configuration allows it at all
+        (``_ahead``), and the moment does — its program is warm (a cold one
+        compiles under the first-compile budget, with nothing queued before
+        it), the core is HEALTHY (a probe step takes the gated path) and no
+        hot swap is running the slot grid dry. Otherwise the step runs in
+        lockstep: drain, then run to its end."""
+        return (self._ahead and not self._draining
+                and key in self._seen_steps
+                and self.core.health.state == HEALTHY)
+
+    async def _run_ahead(self, key: tuple, packed, dev, apply=None,
+                         **riding):
+        """Enqueue the step ``key`` (the jitted call only: upload + enqueue,
+        stage ``gen_dispatch``) behind the step in flight, THEN wait for,
+        fetch and apply that one. The pools chain on the device through
+        donation; ``dev`` is what else the step takes there. The step stays
+        in flight as ``_pipeline`` until the next step is enqueued behind it
+        (or ``_drain_pipeline``). Returns its token array, on the device."""
+        kind = key[0]
+        pend = self._pipeline
+        self._track_gen_dispatch()
+
+        # pools bound eagerly (the zombie discipline of ``_run_device_step``)
+        def enqueue(hop, kp=self.k_pages, vp=self.v_pages):
+            with hop.stage("gen_dispatch"):
+                out = getattr(self, "_" + kind)(packed, kp, vp, *dev)
+            if apply is not None:
+                out[0].copy_to_host_async()  # lands while the step still runs
+            return out
+
+        hop = _Hop(kind)
+        try:
+            out, self.k_pages, self.v_pages = (
+                await asyncio.get_running_loop().run_in_executor(
+                    None, hop.run, enqueue))
+        except Exception as e:
+            self.core.health.mark_unhealthy(f"generate step failed: {e}")
+            raise
+        rec = _InFlightStep(kind, out, hop.done(), time.monotonic(), apply,
+                            **riding)
+        self._pipeline = None
+        if pend is not None:
+            self._steps_ahead += 1
+            self.m_ahead[kind].inc()
+            await self._land(pend, rec)
+            # honest idle accounting: one step is always nominally in
+            # flight, so the count cannot see a drained device. If this
+            # step's output is ALREADY computed, the device sits idle until
+            # the next enqueue: open the idle window so the gap records
+            if self._gen_idle_since is None:
+                try:
+                    drained = bool(rec.out.is_ready())
+                except Exception:
+                    drained = False
+                if drained:
+                    self._gen_idle_since = time.monotonic()
+        self._pipeline = rec
         return out
+
+    async def _land(self, rec: _InFlightStep,
+                    behind: Optional[_InFlightStep] = None) -> None:
+        """Wait for ``rec``, fetch its tokens and apply them, under its
+        deadline counted from ITS dispatch. A prompt's chunk before its last
+        (nothing of it is read on the host) is not waited for at all where
+        the step ``behind`` it will be: that one cannot end before it, so its
+        wait inherits the chunk's dispatch stamp and holds both deadlines."""
+        if rec.apply is None and behind is not None and behind.apply is not None:
+            behind.dispatched_at = rec.dispatched_at
+            self._track_gen_complete()
+            self._observe_hops(rec.kind, rec.hop)
+            return
+        core = self.core
+
+        def blocking(hop):
+            core.apply_chaos()
+            with hop.stage("gen_ready_wait"):
+                jax.block_until_ready(rec.out)
+            if rec.apply is None:
+                return None
+            with hop.stage("gen_fetch"):
+                return np.asarray(rec.out)
+
+        deadline = core.deadline_for(False)  # a step that ran ahead is warm
+        if deadline is not None:
+            deadline = core.deadline_remaining(deadline, rec.dispatched_at)
+        tokens = await self._finish_step(rec.kind, blocking, deadline, rec.hop)
+        if rec.apply is not None:
+            rec.apply(tokens)
+
+    async def _drain_pipeline(self) -> None:
+        """Land the step in flight, if any: whatever runs in lockstep (a
+        cold or probe step, truncation under page pressure, a speculative
+        step, an export, an adopted upload, the loop's exit) runs against
+        caught-up host state and an empty device queue."""
+        if self._pipeline is not None:
+            rec, self._pipeline = self._pipeline, None
+            await self._land(rec)
 
     # -- public API --------------------------------------------------------
 
@@ -1456,6 +1599,9 @@ class GenerationServer:
             # remainder is ever computed.
             self._prefill_pos[slot] = shared_len
             return
+        # one bucketed step over the whole prompt, now: while it is in
+        # flight the slot counts as prefilling (it decodes from its token)
+        self._prefill_pos[slot] = 0
         await self._prefill_step(slot, "prefill")
 
     async def _admit_adopted(self, slot: int, req: _Request) -> None:
@@ -1554,22 +1700,31 @@ class GenerationServer:
     async def _prefill_step(self, slot: int, kind: str = "chunk") -> None:
         """One prefill step for an admitting slot (one device call): a chunk
         of its prompt, or (``kind`` "prefill") all of it in one bucketed
-        step; seeds the slot for decode after the prompt's last token."""
+        step; seeds the slot for decode after the prompt's last token.
+        Enqueued behind the step in flight where ``_may_run_ahead`` allows
+        (a chunk's operands depend on no other step's tokens); a prompt
+        that stops after prefill exports its pages with nothing in flight."""
         req = self._slot_req[slot]
         if req is None:
             self._prefill_pos.pop(slot, None)
             return
+        off = self._prefill_pos.get(slot, 0)
+        n = len(req.prompt)
+        # width: the configured chunk, or one bucketed span over the rest
+        # (one-shot; a prefix-cache remainder with chunking off)
+        c = (self.prefill_chunk if kind == "chunk" and self.prefill_chunk
+             else self._bucket(n - off))
+        new_off = min(off + c, n)
+        final = new_off >= n
+        ahead = (self._may_run_ahead((kind, c))
+                 and not (final and req.prefill_only))
+        if not ahead:
+            await self._drain_pipeline()
         with loop_stage("gen_prepare", kind):
-            off = self._prefill_pos.get(slot, 0)
-            n = len(req.prompt)
-            # width: the configured chunk, or one bucketed span over the rest
-            # (one-shot; a prefix-cache remainder with chunking off)
-            c = (self.prefill_chunk if kind == "chunk" and self.prefill_chunk
-                 else self._bucket(n - off))
-            chunk = req.prompt[off:off + c]
+            chunk = req.prompt[off:new_off]
             ids = np.zeros(c, np.int32)
             ids[:len(chunk)] = chunk
-            self._slide_window(slot, off, off + len(chunk) - 1)
+            self._slide_window(slot, off, new_off - 1)
             packed = pack_operands(ids, off, len(chunk), self._table(slot))
             if self._stateful:
                 valid, masked = self.m_ssm["chunk"]
@@ -1579,29 +1734,48 @@ class GenerationServer:
                     self.m_ssm_resets.inc()
                     self._state_tenant[slot] = (
                         req.prompt, req.tokens, self._state_tenant[slot][2] + 1)
-            new_off = off + len(chunk)
             so_far = () if kind != "chunk" or not self._moe_layers else (
                 self._no_counts if req.chunk_moe is None else req.chunk_moe,)
+        seed = (functools.partial(self._apply_prefill, slot, req, kind)
+                if final else None)
+        if ahead:
+            out = await self._run_ahead((kind, c), packed, so_far, seed,
+                                        slot=slot)
+            if not final:  # nothing of it is read: its books close here
+                self._apply_chunk(slot, req, new_off, out)
+            return
         # off-loop + gated; only the prompt's last step's token is fetched
         nxt = await self._run_device_step(
-            (kind, c), packed, *so_far, final=new_off >= n)
+            (kind, c), packed, *so_far, final=final)
+        if not final:
+            self._apply_chunk(slot, req, new_off, nxt)
+        elif seed(nxt):
+            await self._export_and_finish(slot)
+
+    def _apply_chunk(self, slot: int, req: _Request, new_off: int, out) -> None:
+        """A prompt's chunk before its last: the slot's next offset; its
+        output stays on the device (a routed model's counters ride it)."""
+        with loop_stage("gen_apply", "chunk"):
+            req.chunks += 1
+            self._prefill_pos[slot] = new_off
+            req.chunk_moe = out
+
+    def _apply_prefill(self, slot: int, req: _Request, kind: str, nxt) -> bool:
+        """A prompt's last prefill step, fetched (its token, then a routed
+        model's counters): the one fetch seeds the slot for decode. True
+        where the request stops here and its pages are to be exported."""
         with loop_stage("gen_apply", kind):
             req.chunks += 1
-            if new_off < n:
-                self._prefill_pos[slot] = new_off
-                req.chunk_moe = nxt  # stays on the device (routed: counters)
-                return
-            # prefilled: the one fetch seeds decode (token, then counters)
             self._prefill_pos.pop(slot, None)
             if self._moe_layers:
                 self._note_moe(kind, nxt[1:],
                                int(nxt[4]) if kind == "chunk" else 1)
-            self._lengths[slot] = n
+            self._lengths[slot] = len(req.prompt)
             self._cur_tokens[slot] = int(nxt[0])
-            if not req.prefill_only:
-                self._handle_token(slot, int(nxt[0]))
-                return
-        await self._export_and_finish(slot)
+            if req.prefill_only:
+                return True
+            self._handle_token(slot, int(nxt[0]))
+            return False
 
     async def _export_and_finish(self, slot: int) -> None:
         """Prefill-only completion: fetch the prompt's KV pages to host,
@@ -1720,27 +1894,39 @@ class GenerationServer:
             self._rate_window = (now, self._tokens_emitted)
 
     async def _serve_loop(self) -> None:
+        """Pick the next step from host state as it will stand once the step
+        in flight is applied, for everything the host already knows, and
+        hand it to the device before that one is waited for (the step
+        methods; ``_run_ahead``)."""
         try:
             while not self._closed:
                 admitted = await self._admit_pending()
+                # a prompt's last prefill step in flight: its slot has no
+                # further chunk and decodes only once its token is applied
+                pend = self._pipeline
+                seeding = -1 if pend is None else pend.seeding
                 # first admitted, first prefilled: by slot index a long
                 # prompt in a high slot would wait out every later admission
                 # to a lower one (and, outputs being written in read order,
                 # hold their rows back with it)
                 prefilling = sorted(
                     (s for s in range(self.slots)
-                     if s in self._prefill_pos and self._slot_req[s]),
+                     if s in self._prefill_pos and self._slot_req[s]
+                     and s != seeding),
                     key=lambda s: self._slot_req[s].slot_at)
                 active = [s for s in range(self.slots)
                           if self._slot_req[s] and s not in self._prefill_pos]
-                self._update_gauges(len(active) + len(prefilling))
+                self._update_gauges(
+                    len(active) + len(prefilling) + (seeding >= 0))
                 if not active and not prefilling:
-                    # a pipelined successor can outlive its lanes (every
-                    # request EOS'd on the step that was applied AFTER it
-                    # was dispatched): apply it before idling or exiting,
-                    # or its step would leak in-flight accounting and only
-                    # be fetched by some future wave's admission drain
-                    await self._drain_pipeline()
+                    # nothing to enqueue behind the step in flight: land it
+                    # (a seeding slot decodes from here; a step can outlive
+                    # its lanes, every one of them finished by the step
+                    # applied after it was dispatched) before idling or
+                    # exiting, or it would leak in-flight accounting
+                    if pend is not None:
+                        await self._drain_pipeline()
+                        continue
                     if not self._pending:
                         return  # drained; next generate() restarts the loop
                     if not admitted:
@@ -1750,7 +1936,6 @@ class GenerationServer:
                 # with one decode step so neither starves the other
                 if prefilling and (not active or self._turn_prefill):
                     self._turn_prefill = False
-                    await self._drain_pipeline()
                     await self._prefill_step(prefilling[0])
                     continue
                 self._turn_prefill = True
@@ -1769,8 +1954,8 @@ class GenerationServer:
             self._reset_device_state()
 
     def _fail_all(self, err: Exception) -> None:
-        # both in-flight pipelined steps (the un-applied one and any just
-        # dispatched successor) die with their requests: their tokens are
+        # both steps in flight (the one not yet applied and the successor
+        # enqueued behind it) die with their requests: their tokens are
         # never applied, and the reset below rebuilds from fresh pools
         self._pipeline = None
         self._gen_inflight = 0
@@ -1812,182 +1997,121 @@ class GenerationServer:
             if reserved is None:
                 break  # head-of-line waits for pages (FIFO fairness)
             pages, shared_len = reserved
-            # catch host state up before the admission prefill dispatches:
-            # its (possibly first-compile) deadline must not also cover an
-            # in-flight decode step queued ahead of it on the device
-            await self._drain_pipeline()
+            # an adopted page set is a device call of its own (an upload
+            # into the pools): it runs against a drained queue. Any other
+            # admission is host bookkeeping; its first step drains by itself
+            # where its program is cold (``_may_run_ahead``)
+            if req.adopt is not None:
+                await self._drain_pipeline()
             await self._admit_one(slot, req, pages, shared_len)
             admitted = True
         return admitted
 
     async def _step(self, active: list[int]) -> None:
-        """One lockstep decode over all slots (inactive lanes masked). At
-        ``dispatch_depth`` 2 the pipelined path runs instead; cold or
-        recovering states (first compile, probe steps, page-pool pressure)
-        fall back to this classic path."""
-        if self.dispatch_depth > 1 and await self._step_pipelined(active):
+        """One decode step over all slots (inactive lanes masked), enqueued
+        behind the step in flight where ``_may_run_ahead`` allows and the
+        page pool covers every riding lane, else in lockstep (drain, then
+        run to the end: ``_reserve_or_truncate`` owns the truncation).
+
+        Behind a decode step its lanes ride at lengths + 1 and take their
+        tokens from its output ON the device (packed as -1), so the queue
+        holds the successor before the host fetches. What the host knows a
+        step early it acts on: a lane whose budget the step in flight
+        exhausts is masked out. An EOS it cannot know: such a lane rides
+        and its token is dropped at apply (request identity is snapshotted);
+        where that is not exact the server never runs ahead (``_ahead``)."""
+        ahead = self._may_run_ahead(("decode",))
+        prepared = self._prepare_decode(active, True) if ahead else None
+        if prepared is None:  # lockstep (under page pressure: it owns truncation)
+            ahead = False
+            await self._drain_pipeline()
+            # the drain may have APPLIED a step whose tokens finished
+            # requests in `active` (slot freed, pages returned): recompute,
+            # or _reserve_or_truncate would feed a ghost lane — a page the
+            # next admission leaks, or a live request truncated for no one
+            active = [s for s in active if self._slot_req[s] is not None]
+            prepared = self._prepare_decode(active, False)
+        act, packed, prev, prep_s = prepared
+        if packed is None:
+            # no lane rides (every one finishes on the step in flight):
+            # land it and let the loop re-evaluate (admission / drain / exit)
+            await self._drain_pipeline()
             return
-        await self._drain_pipeline()
-        # the drains above may have APPLIED a pending step whose tokens
-        # finished requests in `active` (slot freed, pages returned):
-        # recompute, or _reserve_or_truncate would feed a ghost lane — a page
-        # the next admission leaks, or a live request truncated for no one
-        active = [s for s in active if self._slot_req[s] is not None]
-        if not active:
+        # only a step that is issued observes its preparation, so
+        # gen_prepare counts device steps
+        observe_stage("gen_prepare", prep_s)
+        if self._stateful:
+            valid, masked = self.m_ssm["decode"]
+            valid.inc(int(act.sum()))
+            masked.inc(self.slots - int(act.sum()))
+        if ahead:
+            reqs = list(self._slot_req)
+            await self._run_ahead(
+                ("decode",), packed, prev,
+                functools.partial(self._apply_decode, act, reqs=reqs),
+                act=act, reqs=reqs)
             return
-        with loop_stage("gen_prepare", "decode"):
-            act = np.zeros(self.slots, bool)
-            act[active] = True
-            for s in active:
-                self._reserve_or_truncate(s, act)
-            for s in map(int, np.flatnonzero(act)):
-                self._slide_window(s, int(self._lengths[s]), int(self._lengths[s]))
-            packed = pack_operands(self._cur_tokens, self._lengths, act,
-                                   self._table())
-            if self._stateful:
-                valid, masked = self.m_ssm["decode"]
-                valid.inc(int(act.sum()))
-                masked.inc(self.slots - int(act.sum()))
         # off-loop + gated: one device-step of wall time (plus first compile)
         self._apply_decode(act, await self._run_device_step(
-            ("decode",), packed, *self._no_prev))
+            ("decode",), packed, *prev))
+
+    def _prepare_decode(self, active: list[int], ahead: bool):
+        """A decode step's lanes and packed operands from host state as it
+        will stand once the step in flight is applied: (act, packed, what
+        the step takes on the device, seconds spent); packed None where no
+        lane rides. ``ahead``: None, nothing else done, where the page pool
+        cannot cover every riding lane; else (lockstep, nothing in flight)
+        ``_reserve_or_truncate`` makes room."""
+        pend = self._pipeline
+        with annotated("gen_prepare:decode") as prep:
+            act = np.zeros(self.slots, bool)
+            act[active] = True
+            lens, cur, prev = self._lengths, self._cur_tokens, self._no_prev
+            if pend is not None and pend.kind == "decode":
+                # lanes of the step in flight: one token further, which
+                # stays on the device; none of them if the pending token
+                # completes the lane's budget
+                rides = pend.act & act
+                for s in map(int, np.flatnonzero(rides)):
+                    req = self._slot_req[s]
+                    if (req is not pend.reqs[s]
+                            or len(req.tokens) + 1 >= req.max_new_tokens):
+                        rides[s] = act[s] = False
+                lens = lens + rides.astype(np.int32)
+                cur = np.where(rides, -1, cur)
+                prev = (pend.out,)
+            if not ahead:
+                for s in active:
+                    self._reserve_or_truncate(s, act)
+            elif not all(self._ensure_page_capacity(int(s), int(lens[s]) + 1)
+                         for s in np.flatnonzero(act)):
+                return None
+            packed = None
+            if act.any():
+                for s in map(int, np.flatnonzero(act)):
+                    self._slide_window(s, int(lens[s]), int(lens[s]))
+                packed = pack_operands(cur, lens, act, self._table())
+        return act, packed, prev, prep.dur_s
 
     def _apply_decode(self, act, nxt, reqs=None) -> None:
         """One decode step's fetched tokens (then a routed model's counters)
         onto host state. A lane whose request is no longer the one in
-        ``reqs`` (a pipelined dispatch's snapshot) drops its token."""
+        ``reqs`` (the snapshot of a step that ran ahead) rode one step too
+        long: its token is dropped, and the step's routing counters, which
+        counted the lane, are not recorded."""
         with loop_stage("gen_apply", "decode"):
             self.m_steps.inc()
-            if self._moe_layers:
+            lanes = np.flatnonzero(act)
+            if self._moe_layers and (reqs is None or all(
+                    self._slot_req[s] is reqs[s] for s in lanes)):
                 self._note_moe("decode", nxt[self.slots:])
-            for s in map(int, np.flatnonzero(act)):
+            for s in map(int, lanes):
                 req = self._slot_req[s]
                 if req is None or (reqs is not None and req is not reqs[s]):
                     continue
                 self._lengths[s] += 1
                 self._cur_tokens[s] = nxt[s]
                 self._handle_token(s, int(nxt[s]))
-
-    # -- pipelined dispatch (dispatch_depth 2) -------------------------------
-
-    async def _step_pipelined(self, active: list[int]) -> bool:
-        """Dispatch decode step N+1, THEN apply the in-flight step N: the
-        dispatch consumes N's un-fetched next-token array ON the device, so
-        the device queue always holds the successor before the host fetches,
-        and page accounting / EOS checks overlap device compute.
-
-        EOS is what the host cannot know one step early: such a lane still
-        rides the dispatch and its token is dropped at apply (request
-        identity is snapshotted). Budget exhaustion IS host-known, so those
-        lanes are masked out up front. Greedy-only (validated at
-        construction): token streams are bitwise identical to depth 1.
-
-        Returns False when the classic path should run instead: cold
-        decode jit (first-compile budget), non-HEALTHY core (probe steps
-        take the gated path), or page-pool pressure (truncation policy
-        lives in the classic path)."""
-        from arkflow_tpu.tpu.health import HEALTHY
-
-        if ("decode",) not in self._seen_steps \
-                or self.core.health.state != HEALTHY:
-            await self._drain_pipeline()
-            return False
-        # ``bail``: the verdict of the two ways out that dispatch nothing.
-        # The stretch is annotated either way; only a dispatch observes it,
-        # so gen_prepare counts device steps
-        bail: Optional[bool] = None
-        prep = annotated("gen_prepare:decode")
-        with prep:
-            act = np.zeros(self.slots, bool)
-            act[active] = True
-            pend = self._pipeline
-            eff_lens = self._lengths.copy()
-            if pend is not None:
-                eff_lens += pend.act.astype(np.int32)
-                for s in active:
-                    req = self._slot_req[s]
-                    if req is None or (pend.act[s]
-                                       and req is not pend.reqs[s]):
-                        act[s] = False
-                    elif (pend.act[s]
-                          and len(req.tokens) + 1 >= req.max_new_tokens):
-                        # the pending token completes this lane's budget: it
-                        # must not ride the next dispatch
-                        act[s] = False
-            if not act.any():
-                # every lane is finishing on the pending step: apply it and
-                # let the loop re-evaluate (admission / drain / exit)
-                bail = True
-            elif not all(
-                    self._ensure_page_capacity(int(s), int(eff_lens[s]) + 1)
-                    for s in np.flatnonzero(act)):
-                bail = False  # classic path owns the truncation policy
-            else:
-                # the in-flight step's tokens stay on the device: its
-                # lanes are packed as -1 and take them there
-                prev = self._no_prev[0] if pend is None else pend.nxt
-                cur = (self._cur_tokens if pend is None
-                       else np.full(self.slots, -1, np.int32))
-                packed = pack_operands(cur, eff_lens, act, self._table())
-        if bail is not None:
-            await self._drain_pipeline()
-            return bail
-        observe_stage("gen_prepare", prep.dur_s)
-        loop = asyncio.get_running_loop()
-        self._track_gen_dispatch()
-
-        # pools bound eagerly (same zombie discipline as the classic path);
-        # the dispatch only ENQUEUES — the jit returns device futures, all
-        # waiting happens in _drain_pipeline under the per-step deadline
-        def enqueue(hop, kp=self.k_pages, vp=self.v_pages):
-            with hop.stage("gen_dispatch"):
-                out = self._decode(packed, kp, vp, prev)
-            out[0].copy_to_host_async()  # lands while the step still runs
-            return out
-
-        hop = _Hop("decode")
-        nxt, self.k_pages, self.v_pages = await loop.run_in_executor(
-            None, hop.run, enqueue)
-        rec = _InFlightDecode(nxt=nxt, act=act, reqs=list(self._slot_req),
-                              dispatched_at=time.monotonic(), hop=hop.done())
-        self._pipelined_dispatches += 1
-        if pend is not None:
-            await self._drain_pipeline()
-            # honest idle accounting: one step is always nominally in
-            # flight, so the count can't see a drained device. If the
-            # successor's outputs are ALREADY computed, the device sits idle
-            # until the next enqueue — open the idle window so the gap records
-            if self._gen_idle_since is None:
-                try:
-                    drained = bool(rec.nxt.is_ready())
-                except Exception:
-                    drained = False
-                if drained:
-                    self._gen_idle_since = time.monotonic()
-        self._pipeline = rec
-        return True
-
-    async def _drain_pipeline(self) -> None:
-        """Fetch + apply the in-flight decode step, if any (deadlined from
-        ITS dispatch): every non-decode event (prefill, speculative steps,
-        swap drain, loop exit) runs against caught-up host state."""
-        if self._pipeline is None:
-            return
-        rec, self._pipeline = self._pipeline, None
-        core = self.core
-
-        def blocking(hop):
-            core.apply_chaos()
-            with hop.stage("gen_ready_wait"):
-                jax.block_until_ready(rec.nxt)
-            with hop.stage("gen_fetch"):
-                return np.asarray(rec.nxt)
-
-        deadline = core.deadline_for(False)  # pipelined steps are warm
-        if deadline is not None:
-            deadline = core.deadline_remaining(deadline, rec.dispatched_at)
-        self._apply_decode(rec.act, await self._finish_step(
-            "decode", blocking, deadline, rec.hop), rec.reqs)
 
     # -- speculative decode -------------------------------------------------
 

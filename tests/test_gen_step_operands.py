@@ -206,7 +206,7 @@ def test_every_step_hands_the_device_one_host_array(model_kw, server_kw, kinds):
     assert uploads == steps  # 1.0 a step, kind by kind
     assert set(host_arrays) == {1}
     if server_kw.get("dispatch_depth", 1) > 1:
-        assert server._pipelined_dispatches > 0
+        assert server._steps_ahead > 0
 
 
 def test_greedy_server_never_splits_a_key(monkeypatch):

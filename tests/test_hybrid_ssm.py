@@ -480,7 +480,7 @@ def test_kernel_parity_probe_holds_the_mixers_kernels():
 @pytest.mark.parametrize("extra,needle", [
     ({"prefix_cache_pages": 8}, "aliased pages skip"),
     ({"speculative_tokens": 2}, "rejected draft"),
-    ({"dispatch_depth": 2}, "rides step N\\+1"),
+    ({"dispatch_depth": 3}, "dispatch_depth > 2"),
     ({"mesh": {"tp": 2}}, "one chip"),
     ({"serving": "batch"}, "serving: continuous"),
     ({"prefill_chunk": 0}, "prefills in chunks"),

@@ -511,7 +511,7 @@ def test_kernel_probe_judges_each_kernel_on_given_routing():
     ({"serving": "batch"}, "serving: continuous"),
     ({"swap": {"drain_timeout": "1s"}}, "swap is not supported"),
     ({"integrity": {"probe_interval": "1s"}}, "integrity is not supported"),
-    ({"dispatch_depth": 2}, "MoE"),
+    ({"dispatch_depth": 3}, "dispatch_depth > 2"),
 ])
 def test_latent_model_refuses_what_cannot_carry_its_pages(extra, needle):
     with pytest.raises(ConfigError, match=needle):

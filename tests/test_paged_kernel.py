@@ -403,14 +403,16 @@ def test_server_dispatch_depth2_bitwise_identical():
     decode, chunked prefill interleave, prefix-cache hits, and multi-wave
     admission (5 prompts on 2 slots)."""
     cfg, params = _tiny_setup(seed=3)
-    ref, _ = _serve(params, cfg, TP_PROMPTS, 6)
+    ref, _ = _serve(params, cfg, TP_PROMPTS, 6, dispatch_depth=1)
     got, srv = _serve(params, cfg, TP_PROMPTS, 6, dispatch_depth=2)
     assert got == ref
     assert srv.health_report()["dispatch_depth"] == 2
+    assert srv._steps_ahead > 0
 
     long_prompts = [list(range(3, 25)), [9, 4], list(range(40, 55)), [7],
                     list(range(3, 25))]
-    ref, _ = _serve(params, cfg, long_prompts, 5, prefill_chunk=8)
+    ref, _ = _serve(params, cfg, long_prompts, 5, prefill_chunk=8,
+                    dispatch_depth=1)
     got, _ = _serve(params, cfg, long_prompts, 5, prefill_chunk=8,
                     dispatch_depth=2, prefix_cache_pages=8)
     assert got == ref
@@ -418,7 +420,7 @@ def test_server_dispatch_depth2_bitwise_identical():
 
 def test_server_depth2_composes_with_paged_kernel():
     cfg, params = _tiny_setup(seed=3)
-    ref, _ = _serve(params, cfg, TP_PROMPTS, 6)
+    ref, _ = _serve(params, cfg, TP_PROMPTS, 6, dispatch_depth=1)
     got, srv = _serve(params, cfg, TP_PROMPTS, 6, dispatch_depth=2,
                       decode_kernel="paged", kernel_interpret=True)
     assert got == ref
@@ -503,18 +505,22 @@ def test_server_dispatch_depth_validation():
         GenerationServer(params, cfg, dispatch_depth=0)
     with pytest.raises(ConfigError, match="dispatch_depth > 2"):
         GenerationServer(params, cfg, dispatch_depth=3)
-    with pytest.raises(ConfigError, match="greedy"):
-        GenerationServer(params, cfg, dispatch_depth=2, temperature=0.8)
-    with pytest.raises(ConfigError, match="speculative"):
-        GenerationServer(params, cfg, dispatch_depth=2, speculative_tokens=2)
     with pytest.raises(ConfigError, match="decode_kernel"):
         GenerationServer(params, cfg, decode_kernel="warp")
+    # what depth 2 is not exact with is no error any more: the server is
+    # built and serves in lockstep (tests/test_gen_run_ahead.py serves it)
+    assert GenerationServer(params, cfg)._ahead  # the default is depth 2
+    assert not GenerationServer(params, cfg, dispatch_depth=1)._ahead
+    assert not GenerationServer(params, cfg, dispatch_depth=2,
+                                temperature=0.8)._ahead
+    assert not GenerationServer(params, cfg, dispatch_depth=2,
+                                speculative_tokens=2)._ahead
     fam = get_model("decoder_lm")
     moe = fam.make_config(**{**TINY, "dim": 32, "heads": 2, "kv_heads": 1,
                              "ffn": 48, "num_experts": 4})
-    with pytest.raises(ConfigError, match="MoE"):
-        GenerationServer(fam.init(jax.random.PRNGKey(0), moe), moe,
-                         dispatch_depth=2)
+    srv = GenerationServer(fam.init(jax.random.PRNGKey(0), moe), moe,
+                           dispatch_depth=2)
+    assert not srv._ahead and not srv.health_report()["runs_ahead"]
 
 
 def test_depth2_deadline_miss_fails_both_in_flight_steps_and_heals():
@@ -546,10 +552,10 @@ def test_depth2_deadline_miss_fails_both_in_flight_steps_and_heals():
         # always runs with its dispatched successor already on the device
         # queue, so the miss lands with both steps in flight
         for _ in range(2000):
-            if srv._pipelined_dispatches > 0:
+            if srv._steps_ahead > 0:
                 break
             await asyncio.sleep(0.002)
-        assert srv._pipelined_dispatches > 0, "pipelined path never engaged"
+        assert srv._steps_ahead > 0, "pipelined path never engaged"
         srv.inject_step_fault("hang", 3.0)
         results = await asyncio.gather(*tasks, return_exceptions=True)
         assert all(isinstance(r, StepDeadlineExceeded) for r in results), results
@@ -629,7 +635,7 @@ def test_depth2_oom_chaos_zero_loss():
         ref = await srv.generate([9, 4], max_new_tokens=4)
         task = asyncio.ensure_future(srv.generate([9, 4], max_new_tokens=24))
         for _ in range(2000):
-            if srv._pipelined_dispatches > 0:
+            if srv._steps_ahead > 0:
                 break
             await asyncio.sleep(0.002)
         srv.inject_step_fault("oom")
@@ -738,14 +744,20 @@ def test_config_validates_dispatch_knobs_through_fault_wrappers():
            "serving": "continuous"}
     StreamConfig.from_mapping(stream({**gen, "dispatch_depth": 2,
                                       "decode_kernel": "paged"}))
+    # what depth 2 is not exact with parses: such a server runs in lockstep
+    StreamConfig.from_mapping(stream({**gen, "dispatch_depth": 2,
+                                      "speculative_tokens": 2}))
+    StreamConfig.from_mapping(stream({**gen, "dispatch_depth": 2,
+                                      "temperature": 0.7}))
     for bad, msg in (
             ({**gen, "dispatch_depth": 3}, "caps at 2"),
             ({**gen, "dispatch_depth": 0}, "positive int"),
             ({**gen, "dispatch_depth": True}, "positive int"),
             ({**gen, "decode_kernel": "warp"}, "gather|paged"),
-            ({**gen, "dispatch_depth": 2, "speculative_tokens": 2},
-             "mutually exclusive"),
-            ({**gen, "dispatch_depth": 2, "temperature": 0.7}, "greedy"),
+            ({**gen, "dispatch_depth": 3, "speculative_tokens": 2},
+             "caps at 2"),
+            ({**gen, "dispatch_depth": "2", "temperature": 0.7},
+             "positive int"),
             ({"type": "tpu_inference", "model": "bert_classifier",
               "dispatch_depth": -1}, "positive int")):
         with pytest.raises(ConfigError, match=msg.replace("|", r"\|")):

@@ -528,7 +528,7 @@ def test_server_counters_equal_a_hand_count():
     ({"serving": "batch"}, "serving: continuous"),
     ({"swap": {"drain_timeout": "1s"}}, "swap is not supported"),
     ({"integrity": {"probe_interval": "1s"}}, "integrity is not supported"),
-    ({"dispatch_depth": 2}, "MoE"),
+    ({"dispatch_depth": 3}, "dispatch_depth > 2"),
     ({"prefix_cache_pages": 8}, "window pages"),
     ({"speculative_tokens": 2}, "indexed or sliding"),
     ({"prefill_chunk": 0}, "prefills in chunks"),

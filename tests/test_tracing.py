@@ -754,9 +754,10 @@ def test_generation_spans_land_in_each_requests_own_trace():
 GEN_SERVERS = pytest.mark.parametrize("server_kw", [
     dict(prefill_chunk=4),                    # chunked prefill + decode
     dict(prefill_chunk=0),                    # one-shot prefill
-    dict(prefill_chunk=4, dispatch_depth=2),  # pipelined decode
+    dict(prefill_chunk=4, dispatch_depth=2),  # one step ahead (the default)
     dict(prefill_chunk=4, speculative_tokens=2),
-], ids=["chunked", "one_shot", "depth2", "speculative"])
+    dict(prefill_chunk=4, dispatch_depth=1),  # lockstep
+], ids=["chunked", "one_shot", "depth2", "speculative", "lockstep"])
 HOP_STAGES = ("gen_dispatch", "gen_ready_wait", "gen_fetch")
 STEP_KINDS = {"decode", "chunk", "prefill", "verify"}
 
@@ -785,11 +786,13 @@ def _run_counted(tag: str, server_kw: dict):
     """Serve GEN_PROMPTS on a fresh tiny server named by ``tag`` and its
     options. Returns the name, the outputs, what the run added to
     ``arkflow_stage_seconds`` by (stage, kind), and the device steps it made
-    with how many of them left their tokens on the device."""
+    with how many of them left their tokens on the device (``unfetched``)
+    and how many of those nobody waited for (``unwaited``: a chunk that ran
+    ahead of a step that was)."""
     _fresh_global()
     name = tag + "-".join(f"{k}{v}" for k, v in server_kw.items())
     server = _tiny_generation_server(name, **server_kw)
-    made = {"steps": 0, "unfetched": 0}
+    made = {"steps": 0, "unfetched": 0, "unwaited": 0}
     for step in ("_decode", "_chunk", "_prefill", "_verify"):
         def counted(*a, _fn=getattr(server, step), **kw):
             made["steps"] += 1
@@ -803,6 +806,18 @@ def _run_counted(tag: str, server_kw: dict):
         return run_step(*a, final=final, **kw)
 
     server._run_device_step = run_counted
+    run_ahead, land = server._run_ahead, server._land
+
+    def ahead_counted(key, packed, dev, apply=None, **kw):
+        made["unfetched"] += apply is None
+        return run_ahead(key, packed, dev, apply, **kw)
+
+    def land_counted(rec, behind=None):
+        made["unwaited"] += (rec.apply is None and behind is not None
+                             and behind.apply is not None)
+        return land(rec, behind)
+
+    server._run_ahead, server._land = ahead_counted, land_counted
     before = _stage_hists()
 
     async def go():
@@ -837,18 +852,25 @@ def test_hop_stages_count_device_steps_by_kind(server_kw):
     assert made["steps"] > 0
     count = {st: sum(c for (stage, _), (_, c) in added.items() if stage == st)
              for st in HOP_STAGES}
-    assert count["gen_dispatch"] == count["gen_ready_wait"] == made["steps"]
+    assert count["gen_dispatch"] == made["steps"]
+    assert count["gen_ready_wait"] == made["steps"] - made["unwaited"]
     assert count["gen_fetch"] == made["steps"] - made["unfetched"]
+    assert made["unwaited"] <= made["unfetched"]
     if server_kw["prefill_chunk"]:
         assert made["unfetched"] > 0  # the 22-token prompt: chunks before its last
+    if server_kw.get("dispatch_depth") == 1 or "speculative_tokens" in server_kw:
+        assert made["unwaited"] == 0  # lockstep waits for every step
     for stage, kind in added:
         if stage in HOP_STAGES:
             assert kind in STEP_KINDS, (stage, kind)
         elif stage in LOOP_STAGES:
             assert kind is None, (stage, kind)
-    # per kind too: a kind's steps dispatch and wait once each
+    # per kind too: a kind's steps dispatch and wait once each (but a
+    # chunk nobody waited for)
     for kind in {k for (_, k) in added if k}:
-        assert added["gen_dispatch", kind][1] == added["gen_ready_wait", kind][1]
+        unwaited = made["unwaited"] if kind == "chunk" else 0
+        assert (added["gen_dispatch", kind][1]
+                == added.get(("gen_ready_wait", kind), (0, 0))[1] + unwaited)
 
 
 @GEN_SERVERS

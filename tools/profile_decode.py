@@ -124,7 +124,7 @@ def _child(n: int) -> None:
 
         def step():
             nonlocal kp, vp
-            nxt, kp, vp = srv._decode(packed, kp, vp)
+            nxt, kp, vp = srv._decode(packed, kp, vp, *srv._no_prev)
             jax.block_until_ready(nxt)
 
         step()  # compile
