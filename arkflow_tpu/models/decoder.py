@@ -160,6 +160,18 @@ class DecoderConfig:
     #: the size of an attention head where it is not ``dim // heads``
     #: (per-head K/V layers only; 0: ``dim // heads``)
     head_dim: int = 0
+    # -- a layer pattern over per-head K/V (GQA) layers: ``layer_types`` and
+    # ``sliding_window`` as above, every layer at the model's one set of
+    # sizes. A sliding layer attends the last ``sliding_window`` positions
+    # and caches only those (``paged_decode.cache_spec``: ``kv_window``
+    # beside ``kv``). ``qk_norm``: an RMSNorm over each head's query and key
+    # (scales of ``head_dim``, one set for queries and one for keys), before
+    # the rotary embedding. ``full_attention_rope`` false: a full layer's
+    # queries and keys are NOT rotated (positions reach it through the
+    # sliding layers alone). Such a model may route its experts too
+    # (``n_routed_experts``: a leading dense stack, then an expert stack).
+    qk_norm: bool = False
+    full_attention_rope: bool = True
     # -- the hybrid block (Falcon-H1), under the published key names.
     # ``mamba_d_ssm`` > 0 turns it on for EVERY layer: beside the GQA
     # attention, and fed by the same normed input, a Mamba-2 mixer
@@ -213,11 +225,17 @@ class DecoderConfig:
                 raise ConfigError(
                     "latent attention composes with neither ring attention "
                     "nor the Switch top-1 layer (num_experts)")
-        if self.latent != self.routed or self.latent != self.rope_interleave:
+        if self.latent != self.rope_interleave or (
+                self.latent and not self.routed):
             raise ConfigError(
-                "latent attention (kv_lora_rank), top-k routed experts "
-                "(n_routed_experts) and rope_interleave are served together "
-                "or not at all so far")
+                "latent attention (kv_lora_rank) is served with top-k routed "
+                "experts (n_routed_experts) and rope_interleave, and "
+                "rope_interleave with latent attention only")
+        if self.routed and (self.num_experts > 1 or self.use_ring_attention):
+            raise ConfigError(
+                "top-k routed experts (n_routed_experts) compose with "
+                "neither the Switch top-1 layer (num_experts) nor ring "
+                "attention")
         if self.routed:
             if not (0 < self.num_experts_per_tok <= self.n_routed_experts
                     and self.moe_intermediate_size > 0
@@ -292,22 +310,40 @@ class DecoderConfig:
         extras = (self.sliding_window, self.swa_heads, self.swa_kv_lora_rank,
                   self.index_topk, self.index_n_heads, self.index_head_dim)
         gates = (self.attention_gate_type, self.swa_attention_gate_type)
-        if not self.latent:
-            if (self.layer_types is not None or any(extras) or any(gates)
-                    or self.apply_mla_qkv_lora_rescale):
-                raise ConfigError(
-                    "layer_types, sliding_window / swa_*, index_*, the "
-                    "attention gate and the latent rescale belong to a "
-                    "latent-attention model (kv_lora_rank > 0)")
-            return
-        if any(g not in ("", "headwise") for g in gates):
-            raise ConfigError(
-                f"attention_gate_type is '' or 'headwise', got {gates}")
         kinds = self.kinds
-        if len(kinds) != self.layers or set(kinds) - {FULL, SLIDING}:
+        if self.layer_types is not None and (
+                len(kinds) != self.layers or set(kinds) - {FULL, SLIDING}):
             raise ConfigError(
                 f"layer_types names each of the {self.layers} layers "
                 f"{FULL!r} or {SLIDING!r}, got {self.layer_types}")
+        if not self.latent:
+            if any(extras[1:]) or any(gates) or self.apply_mla_qkv_lora_rescale:
+                raise ConfigError(
+                    "swa_* sizes, index_*, the attention gate and the latent "
+                    "rescale belong to a latent-attention model "
+                    "(kv_lora_rank > 0): a per-head K/V model's sliding "
+                    "layers have the model's one set of sizes")
+            if (SLIDING in kinds) != (self.sliding_window > 0):
+                raise ConfigError(
+                    "sliding_window > 0 and a sliding_attention layer in "
+                    f"layer_types go together, got {self.sliding_window} "
+                    f"and {self.layer_types}")
+            if (self.layer_types is not None or self.qk_norm) and (
+                    self.hybrid or self.num_experts > 1
+                    or self.use_ring_attention):
+                raise ConfigError(
+                    "a layer pattern over per-head K/V layers, and qk_norm, "
+                    "compose with neither the hybrid block (mamba_d_ssm), "
+                    "the Switch top-1 layer (num_experts) nor ring attention")
+            return
+        if self.qk_norm or not self.full_attention_rope:
+            raise ConfigError(
+                "qk_norm and full_attention_rope belong to a per-head K/V "
+                "model (a latent model norms its latents and rotates its "
+                "rope key)")
+        if any(g not in ("", "headwise") for g in gates):
+            raise ConfigError(
+                f"attention_gate_type is '' or 'headwise', got {gates}")
         if SLIDING in kinds:
             if (min(self.sliding_window, self.swa_heads, self.swa_kv_lora_rank,
                     self.swa_qk_nope_head_dim, self.swa_qk_rope_head_dim,
@@ -365,6 +401,18 @@ class DecoderConfig:
         """True where the cache is not one row shape for every layer: a
         sliding layer's window pool or an indexed layer's index keys."""
         return SLIDING in self.kinds or self.index_topk > 0
+
+    @property
+    def by_runs(self) -> bool:
+        """True where the parameter tree stacks its layers by runs
+        (``layer_runs``: a latent model, routed experts, a layer pattern,
+        per-head norms); otherwise ``layers`` is the one stack of identical
+        layers, as it always was."""
+        return self.latent or self.routed or self.layered or self.qk_norm
+
+    def window(self, kind: str) -> int:
+        """Keys a layer of ``kind`` attends below its query (0: all)."""
+        return self.sliding_window if kind == SLIDING else 0
 
     def attn(self, kind: str) -> "AttnSpec":
         """The sizes of one kind of latent layer, under the names the
@@ -464,11 +512,19 @@ def _init_latent_layer(key, cfg: DecoderConfig, routed: bool,
         layer["index_k_norm"] = cm.layer_norm_init(sp.index_head_dim)
         layer["index_w"] = cm.dense_init(next(extra), cfg.dim, sp.index_n_heads,
                                          bias=False)
+    layer.update(_init_ffn(k, cfg, routed))
+    return layer
+
+
+def _init_ffn(k, cfg: DecoderConfig, routed: bool) -> dict:
+    """A layer's MLP half, drawn from the key iterator ``k``: a dense SwiGLU
+    of width ``ffn``, or the router, its selection bias and the stacked
+    experts (those HELD here first, the shared experts after them)."""
     if not routed:
-        layer["w_gate"] = cm.dense_init(next(k), cfg.dim, cfg.ffn, bias=False)
-        layer["w_up"] = cm.dense_init(next(k), cfg.dim, cfg.ffn, bias=False)
-        layer["w_down"] = cm.dense_init(next(k), cfg.ffn, cfg.dim, bias=False)
-        return layer
+        return {"w_gate": cm.dense_init(next(k), cfg.dim, cfg.ffn, bias=False),
+                "w_up": cm.dense_init(next(k), cfg.dim, cfg.ffn, bias=False),
+                "w_down": cm.dense_init(next(k), cfg.ffn, cfg.dim, bias=False)}
+    layer = {}
     e = cfg.held[1] + cfg.n_shared_experts
     f = cfg.moe_intermediate_size
     up, down = 1.0 / (cfg.dim ** 0.5), 1.0 / (f ** 0.5)
@@ -501,13 +557,16 @@ def layer_runs(cfg: DecoderConfig) -> list:
     first, stop, kind, routed?, kind_first)`` — layers ``first..stop`` of
     that stack, ``kind_first`` the index of the run's first layer among the
     layers of its kind (the cache pools' layer axis). A model without a
-    pattern has ``dense_layers`` then ``layers``, each whole."""
+    pattern has ``dense_layers`` then ``layers``, each whole. A per-head K/V
+    model's layers have one shape of attention whatever their kind: they
+    stack by dense | routed alone (``layers`` the only stack without routed
+    experts), and a pattern makes runs WITHIN a stack."""
     runs, in_stack, of_kind = [], {}, {}
     for i, kind in enumerate(cfg.kinds):
         routed = cfg.routed and i >= cfg.first_k_dense_replace
-        name = _STACKS[kind, routed] if cfg.latent else "layers"
+        name = _STACKS[kind if cfg.latent else FULL, routed or not cfg.routed]
         at, kat = in_stack.get(name, 0), of_kind.get(kind, 0)
-        if runs and runs[-1][0] == name:
+        if runs and runs[-1][0] == name and runs[-1][3] == kind:
             runs[-1][2] = at + 1
         else:
             runs.append([name, at, at + 1, kind, routed, kat])
@@ -515,7 +574,29 @@ def layer_runs(cfg: DecoderConfig) -> list:
     return [tuple(r) for r in runs]
 
 
-def _init_latent(rng, cfg: DecoderConfig) -> dict:
+def _init_gqa_layer(key, cfg: DecoderConfig, routed: bool) -> dict:
+    """One layer of a per-head K/V model that stacks by runs (routed experts
+    or a layer pattern): GQA projections (HF names: q_proj, k_proj, v_proj,
+    o_proj; q_norm / k_norm over a head with ``qk_norm``), the same for a
+    full and a sliding layer, and a dense SwiGLU or the routed experts."""
+    k = iter(jax.random.split(key, 12))
+    dh = cfg.dh
+    layer = {
+        "attn_norm": cm.rms_norm_init(cfg.dim),
+        "wq": cm.dense_init(next(k), cfg.dim, cfg.heads * dh, bias=False),
+        "wk": cm.dense_init(next(k), cfg.dim, cfg.kv_heads * dh, bias=False),
+        "wv": cm.dense_init(next(k), cfg.dim, cfg.kv_heads * dh, bias=False),
+        "wo": cm.dense_init(next(k), cfg.heads * dh, cfg.dim, bias=False),
+        "mlp_norm": cm.rms_norm_init(cfg.dim),
+    }
+    if cfg.qk_norm:
+        layer.update(q_head_norm=cm.rms_norm_init(dh), k_head_norm=cm.rms_norm_init(dh))
+    layer.update(_init_ffn(k, cfg, routed))
+    return layer
+
+
+def _init_runs(rng, cfg: DecoderConfig) -> dict:
+    """``init`` for a model whose layers stack by runs (``layer_runs``)."""
     keys = iter(jax.random.split(rng, 2 + cfg.layers))
     params = {
         "embed": cm.embedding_init(next(keys), cfg.vocab_size, cfg.dim),
@@ -525,7 +606,8 @@ def _init_latent(rng, cfg: DecoderConfig) -> dict:
     stacks: dict = {}
     for name, first, stop, kind, routed, _ in layer_runs(cfg):
         stacks.setdefault(name, []).extend(
-            _init_latent_layer(next(keys), cfg, routed, kind)
+            _init_latent_layer(next(keys), cfg, routed, kind) if cfg.latent
+            else _init_gqa_layer(next(keys), cfg, routed)
             for _ in range(first, stop))
     for name, stack in stacks.items():
         params[name] = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *stack)
@@ -548,8 +630,8 @@ def layer_stacks(params: dict, cfg: DecoderConfig) -> list:
 
 
 def init(rng, cfg: DecoderConfig) -> dict:
-    if cfg.latent:
-        return _init_latent(rng, cfg)
+    if cfg.by_runs:
+        return _init_runs(rng, cfg)
     dh = cfg.dh
     keys = iter(jax.random.split(rng, 4 + 7 * cfg.layers))
     params = {
@@ -1010,19 +1092,22 @@ def _moe_mlp(lp: dict, y: jnp.ndarray, cfg: DecoderConfig,
 
 
 def _attention_block(lp: dict, x: jnp.ndarray, cfg: DecoderConfig, positions,
-                     causal=None, ring_attn=None) -> jnp.ndarray:
+                     causal=None, ring_attn=None, kind: str = FULL) -> jnp.ndarray:
     """Shared pre-norm GQA attention block (rope, kv-head repeat, residual).
 
     ``ring_attn`` substitutes the sp-ring kernel for plain masked attention.
     Used by forward() and the pipeline-parallel stage apply — one source of
-    truth for the layer math."""
+    truth for the layer math. A sliding layer (``kind``) attends the last
+    ``sliding_window`` positions under ``causal``."""
     b, s = positions.shape
     dh = cfg.dh
     group = cfg.heads // cfg.kv_heads
     y = cm.rms_norm(lp["attn_norm"], x, cfg.norm_eps)
     q, k, v = qkv_project(lp, y, cfg)
-    q = _rope(q, positions, cfg.rope_theta)
-    k = _rope(k, positions, cfg.rope_theta)
+    q, k = qk_positioned(lp, q, k, cfg, positions, kind)
+    if cfg.window(kind):
+        causal = causal & (positions[:, None, None, :]
+                           > positions[:, None, :, None] - cfg.window(kind))
     k = jnp.repeat(k, group, axis=2)
     v = jnp.repeat(v, group, axis=2)
     if ring_attn is not None:
@@ -1047,6 +1132,21 @@ def qkv_project(lp: dict, y: jnp.ndarray, cfg: DecoderConfig):
     k = _scaled(cm.dense(lp["wk"], y), cfg.key_multiplier).reshape(
         b, s, cfg.kv_heads, cfg.dh)
     return q, k, cm.dense(lp["wv"], y).reshape(b, s, cfg.kv_heads, cfg.dh)
+
+
+def qk_positioned(lp: dict, q, k, cfg: DecoderConfig, positions, kind: str = FULL):
+    """A per-head layer's queries and keys [B, S, heads, dh] as attention
+    scores them: each head normed where the model has ``qk_norm`` (float32
+    statistics, the layer's own scales), then the rotary embedding at
+    ``positions`` — on every layer, or with ``full_attention_rope`` false on
+    the sliding layers only."""
+    if cfg.qk_norm:
+        q = cm.rms_norm(lp["q_head_norm"], q, cfg.norm_eps)
+        k = cm.rms_norm(lp["k_head_norm"], k, cfg.norm_eps)
+    if kind == SLIDING or cfg.full_attention_rope:
+        q = _rope(q, positions, cfg.rope_theta)
+        k = _rope(k, positions, cfg.rope_theta)
+    return q, k
 
 
 def _mlp(lp: dict, y: jnp.ndarray, cfg: DecoderConfig, token_mask=None) -> jnp.ndarray:
@@ -1108,22 +1208,32 @@ def forward(params: dict, cfg: DecoderConfig, input_ids, *, axes=None, mesh=None
             head_axis=axes.get("tp"), causal=True,
         )
 
-    def layer(x, lp):
-        x = _attention_block(lp, x, cfg, positions, causal, ring_attn)
-        x = _shard_act(x, axes)
-        y = cm.rms_norm(lp["mlp_norm"], x, cfg.norm_eps)
-        if cfg.num_experts > 1:
-            moe_out, aux = _moe_mlp(lp, y, cfg)
-            x = x + moe_out
-        else:
-            x = x + _mlp(lp, y, cfg)
+    def make_layer(routed: bool, kind: str):
+        def layer(x, lp):
+            x = _attention_block(lp, x, cfg, positions, causal, ring_attn, kind)
+            x = _shard_act(x, axes)
+            y = cm.rms_norm(lp["mlp_norm"], x, cfg.norm_eps)
             aux = (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32))
-        return _shard_act(x, axes), aux
+            if cfg.num_experts > 1:
+                moe_out, aux = _moe_mlp(lp, y, cfg)
+                x = x + moe_out
+            elif routed:  # plain XLA over every expert held; dropless: no aux
+                x = x + routed_mlp(lp, y, cfg)[0]
+            else:
+                x = x + _mlp(lp, y, cfg)
+            return _shard_act(x, axes), aux
+        # prevent_cse=False: scan already isolates iterations, and the default
+        # optimization barriers would block XLA fusion in the backward pass
+        return jax.checkpoint(layer, prevent_cse=False) if cfg.remat else layer
 
-    # prevent_cse=False: scan already isolates iterations, and the default
-    # optimization barriers would block XLA fusion in the backward pass
-    scan_body = jax.checkpoint(layer, prevent_cse=False) if cfg.remat else layer
-    x, (lb_per_layer, z_per_layer) = jax.lax.scan(scan_body, x, params["layers"])
+    # one scan a run of layers of one shape and kind (``layer_runs``): a
+    # model without routed experts or a pattern has ONE, over ``layers``
+    aux = []
+    for stack, routed, kind, _ in layer_stacks(params, cfg):
+        x, run_aux = jax.lax.scan(make_layer(routed, kind), x, stack)
+        aux.append(run_aux)
+    lb_per_layer, z_per_layer = (jnp.concatenate(a) if len(aux) > 1 else a[0]
+                                 for a in zip(*aux))
     logits = lm_logits(params, x, cfg)
     if return_aux:
         return logits, {"load_balance": lb_per_layer.mean(), "router_z": z_per_layer.mean()}
@@ -1222,10 +1332,11 @@ def make_train_step(cfg: DecoderConfig, optimizer, *, axes=None, mesh=None):
 def param_specs(cfg: DecoderConfig, axes: dict) -> dict:
     """Sharding layout: attention heads and FFN over ``tp``; expert dim over
     ``ep`` (MoE); embed/lm_head on the vocab dim; norms replicated."""
-    if cfg.latent:
-        # a latent model is served on one chip so far (tp / ep over latent
-        # pages and routed experts is refused where a mesh is built): every
-        # leaf is replicated, in the tree's own shape
+    if cfg.by_runs:
+        # a model that stacks by runs (latent attention, routed experts, a
+        # layer pattern) is served on one chip so far (tp / ep over latent
+        # or window pages and routed experts is refused where a mesh is
+        # built): every leaf is replicated, in the tree's own shape
         return jax.tree_util.tree_map(lambda _: P(), serve_dtypes(cfg))
     tp = axes.get("tp")
     ep = axes.get("ep")
@@ -1270,8 +1381,8 @@ def serve_dtypes(cfg: DecoderConfig) -> dict:
     a layer added to ``init`` states its dtype here
     (tests/test_generate_placed_params.py fails on a cast that is left)."""
     bf16, f32 = jnp.bfloat16, jnp.float32
-    if cfg.latent:
-        return _serve_dtypes_latent(cfg)
+    if cfg.by_runs:
+        return _serve_dtypes_runs(cfg)
     layer = {
         "attn_norm": {"scale": f32},
         "wq": {"w": bf16},
@@ -1307,11 +1418,12 @@ def _mixer_dtypes() -> dict:
             "ssm_norm": {"scale": f32}, "ssm_out": {"w": bf16}}
 
 
-def _serve_dtypes_latent(cfg: DecoderConfig) -> dict:
-    """``serve_dtypes`` for a latent-attention model: the router, its
+def _serve_dtypes_runs(cfg: DecoderConfig) -> dict:
+    """``serve_dtypes`` for a model that stacks by runs: the router, its
     selection bias, the indexer (it selects too) and every norm scale (the
-    latent norms too) float32 — they are multiplied in float32 — and every
-    other leaf bfloat16. One entry a layer stack the model has."""
+    latent and the per-head norms too) float32 — they are multiplied in
+    float32 — and every other leaf bfloat16. One entry a layer stack the
+    model has."""
     bf16, f32 = jnp.bfloat16, jnp.float32
     out = {
         "embed": {"table": bf16},
@@ -1320,15 +1432,15 @@ def _serve_dtypes_latent(cfg: DecoderConfig) -> dict:
     }
     for name, _, _, kind, routed, _ in layer_runs(cfg):
         sp = cfg.attn(kind)
-        layer = {
-            "attn_norm": {"scale": f32},
-            "wq": {"w": bf16},
-            "wkv_a": {"w": bf16},
-            "kv_norm": {"scale": f32},
-            "wkv_b": {"w": bf16},
-            "wo": {"w": bf16},
-            "mlp_norm": {"scale": f32},
-        }
+        layer = {"attn_norm": {"scale": f32}, "wq": {"w": bf16},
+                 "wo": {"w": bf16}, "mlp_norm": {"scale": f32}}
+        if cfg.latent:
+            layer.update(wkv_a={"w": bf16}, kv_norm={"scale": f32},
+                         wkv_b={"w": bf16})
+        else:
+            layer.update(wk={"w": bf16}, wv={"w": bf16})
+            if cfg.qk_norm:
+                layer.update(q_head_norm={"scale": f32}, k_head_norm={"scale": f32})
         if sp.q_lora_rank:
             layer.update(wq_a={"w": bf16}, q_norm={"scale": f32})
         if sp.gate:
@@ -1347,8 +1459,18 @@ def _serve_dtypes_latent(cfg: DecoderConfig) -> dict:
 
 
 def _no_latent(cfg: DecoderConfig, what: str) -> None:
-    """The paths that know only per-head K/V refuse a latent model, and a
-    model that carries a recurrent state beside them."""
+    """The paths that know only per-head K/V kept for a request's life
+    refuse a latent model, a model that carries a recurrent state beside
+    them, and a per-head model with routed experts or a layer pattern."""
+    if cfg.by_runs and not cfg.latent:
+        from arkflow_tpu.errors import ConfigError
+
+        raise ConfigError(
+            f"{what} runs one stack of identical dense layers over a "
+            "contiguous cache: a per-head K/V model with routed experts "
+            "(n_routed_experts), a layer pattern (layer_types: window "
+            "pages beside kept pages) or qk_norm generates through "
+            "serving: continuous")
     if cfg.hybrid:
         from arkflow_tpu.errors import ConfigError
 
@@ -1368,9 +1490,10 @@ def from_hf_state_dict(state: dict, cfg: DecoderConfig) -> dict:
     """Convert a HuggingFace ``LlamaForCausalLM`` state_dict (torch tensors —
     any dtype including bfloat16 — or numpy arrays) into this model's param
     pytree. Linear weights transpose from torch's [out, in] to [in, out]."""
-    if cfg.num_experts > 1 or cfg.latent or cfg.hybrid:
+    if cfg.num_experts > 1 or cfg.by_runs or cfg.hybrid:
         raise ValueError("from_hf_state_dict maps dense Llama checkpoints; "
-                         "MoE, latent-attention and hybrid configs unsupported")
+                         "MoE, latent-attention, layer-pattern and hybrid "
+                         "configs unsupported")
 
     def t(name, transpose=False):
         return cm.hf_tensor(state, name, transpose)

@@ -20,9 +20,12 @@ layer writes at (layer, page, offset) in place and attends by index. A
 latent model with a layer pattern has three kinds of row side by side: its
 full layers' latent rows, their index keys, and its sliding layers' (wider)
 latent rows, which live only while the window covers them — in a pool and
-under a page table of their own. The MLP half is dense SwiGLU, the Switch
-top-1 layer, or dropless routed experts (``cfg.routed``) — the last only on
-latent layers so far.
+under a page table of their own. A per-head model with a layer pattern has two:
+``kv`` rows of its full layers, kept, and ``kv_window`` rows of its sliding
+layers, live while the window covers them (a pool and a ring of pages of
+their own, as the latent window pool). The MLP half is dense SwiGLU, the
+Switch top-1 layer, or dropless routed experts (``cfg.routed``), on either
+kind of attention.
 
 The reference has no serving layer at all (its python processor is
 user-code); this implements the engine the `tpu_generate` processor's
@@ -42,13 +45,14 @@ from arkflow_tpu.models import common as cm
 from dataclasses import dataclass
 
 from arkflow_tpu.models.decoder import (FULL, SLIDING, DecoderConfig, _mlp,
-                                        _rope, _scaled, index_project,
+                                        _scaled, index_project,
                                         index_scores, layer_runs, layer_stacks,
                                         lm_logits, mla_absorb_query,
                                         mla_expanded_attention, mla_head_gate,
                                         mla_output, mla_project,
                                         mla_query_latent, moe_step_stats,
-                                        qkv_project, routed_mlp, ssm_conv,
+                                        qk_positioned, qkv_project,
+                                        routed_mlp, ssm_conv,
                                         ssm_operands, ssm_output, ssm_project)
 
 
@@ -88,7 +92,10 @@ class CachePool:
 def cache_spec(cfg: DecoderConfig) -> tuple:
     """The kinds of row the model caches — the one place that states them:
 
-    - ``kv``: per-head K and V (GQA), every layer;
+    - ``kv``: per-head K and V (GQA), every layer — with a layer pattern
+      the full layers only;
+    - ``kv_window``: a per-head sliding layer's K and V, live for
+      ``sliding_window`` tokens: its pages are freed as the window passes;
     - ``latent``: a full latent layer's normed latent row and rotated rope
       key, one each a token for ALL heads;
     - ``index``: an indexed full layer's index key (the indexer scores it
@@ -100,7 +107,10 @@ def cache_spec(cfg: DecoderConfig) -> tuple:
       SEQUENCE whatever its length, beside that layer's ``kv`` rows."""
     if not cfg.latent:
         kv = cfg.kv_heads * cfg.dh
-        pools = (CachePool("kv", cfg.layers, (kv, kv)),)
+        full, swa = (cfg.kinds.count(k) for k in (FULL, SLIDING))
+        pools = (CachePool("kv", full, (kv, kv)),)
+        if swa:
+            pools += (CachePool("kv_window", swa, (kv, kv), cfg.sliding_window),)
         if cfg.hybrid:
             pools += (CachePool(
                 "ssm", cfg.layers,
@@ -133,6 +143,10 @@ def init_page_pool(cfg: DecoderConfig, num_pages: int, page_size: int,
       wide rows ``{"latent", "window"[, "index"]}`` and the rope keys
       ``{"latent", "window"}`` — each pool over its OWN layers, the window
       pool over ``window_pages`` pages of its own;
+    - a per-head model with a layer pattern: two dicts by pool name —
+      ``{"kv": K, "kv_window": K}`` and the same of V — ``kv`` over the full
+      layers and ``num_pages``, ``kv_window`` over the sliding layers and
+      ``window_pages`` pages of its own;
     - a hybrid model: two dicts by pool name — ``{"kv": K, "ssm": the
       states}`` and ``{"kv": V, "ssm": the conv windows}`` — the states
       float32 [layers, slots + 1, heads, d_state, d_head] (``ops/ssm_scan``
@@ -154,6 +168,13 @@ def init_page_pool(cfg: DecoderConfig, num_pages: int, page_size: int,
         return (jnp.zeros(shape + (cfg.kv_lora_rank,), jnp.bfloat16),
                 jnp.zeros(shape + (cfg.qk_rope_head_dim,), jnp.bfloat16))
     dh = cfg.dh
+    if cfg.layered:
+        pools = tuple({
+            pool.name: jnp.zeros(
+                (pool.layers, window_pages if pool.window else num_pages,
+                 page_size, cfg.kv_heads, dh), jnp.bfloat16)
+            for pool in cache_spec(cfg)} for _ in range(2))
+        return pools
     shape = (cfg.layers, num_pages, page_size, cfg.kv_heads, dh)
     k, v = jnp.zeros(shape, jnp.bfloat16), jnp.zeros(shape, jnp.bfloat16)
     if not cfg.hybrid:
@@ -483,8 +504,6 @@ def latent_kernel_probe(params: dict, cfg: DecoderConfig, page_size: int,
     round a router's input differently, a near-tie then picks another
     expert, and the logits differ by tenths though no kernel is wrong
     (seen on the chip: 4 of 6 seeds, PERF.md PR 27)."""
-    from arkflow_tpu.ops.moe_experts import expert_swiglu_dense, moe_expert_swiglu
-
     keys = iter(jax.random.split(jax.random.PRNGKey(1234), 32))
     rand = lambda shape: jax.random.normal(  # noqa: E731
         next(keys), shape, jnp.float32).astype(jnp.bfloat16)
@@ -567,11 +586,20 @@ def latent_kernel_probe(params: dict, cfg: DecoderConfig, page_size: int,
                 _attend_selected(lp, q_nope, q_rope, cp, rp, 0, table,
                                  chosen.astype(jnp.float32), ok, sp, None,
                                  off=off, **kerns[1])))
-    # the expert product: tokens routed among a HANDFUL of the first expert
-    # layer's experts, so that the XLA twin, which multiplies every expert
-    # it is given, copies those few (94 MB at Kanana-2 widths, by static
-    # slices: an index array over the stack cost 1.9 GB on a v5e) and not
-    # the layer (1.2 GB); the kernel reads the whole stack as when serving
+    out.append(_expert_probe(params, cfg, keys, rand, kernel_interpret))
+    return out
+
+
+def _expert_probe(params: dict, cfg: DecoderConfig, keys, rand,
+                  kernel_interpret: bool) -> tuple:
+    """(name, reference, kernel output) of the expert product on GIVEN
+    routing: tokens routed among a HANDFUL of the first expert layer's
+    experts, so that the XLA twin, which multiplies every expert it is
+    given, copies those few (94 MB at Kanana-2 widths, by static slices: an
+    index array over the stack cost 1.9 GB on a v5e) and not the layer
+    (1.2 GB); the kernel reads the whole stack as when serving."""
+    from arkflow_tpu.ops.moe_experts import expert_swiglu_dense, moe_expert_swiglu
+
     e, k = cfg.held[1], cfg.num_experts_per_tok
     few = min(e, 8)
     x = rand((16, cfg.dim))
@@ -583,10 +611,59 @@ def latent_kernel_probe(params: dict, cfg: DecoderConfig, page_size: int,
     cols = jnp.concatenate([jnp.arange(few), jnp.arange(e, e + cfg.n_shared_experts)])
     twin = [jnp.concatenate([ex[w][0, :few], ex[w][0, e:]])  # static slices
             for w in ("w_gate", "w_up", "w_down")]
-    out.append(("expert_product",
-                expert_swiglu_dense(x, cw[:, cols], *twin),
-                moe_expert_swiglu(x, cw, ex["w_gate"], ex["w_up"],
-                                  ex["w_down"], 0, interpret=kernel_interpret)))
+    return ("expert_product",
+            expert_swiglu_dense(x, cw[:, cols], *twin),
+            moe_expert_swiglu(x, cw, ex["w_gate"], ex["w_up"],
+                              ex["w_down"], 0, interpret=kernel_interpret))
+
+
+def gqa_kernel_probe(params: dict, cfg: DecoderConfig, page_size: int,
+                     kernel_interpret: bool = False) -> list:
+    """``latent_kernel_probe`` for a per-head K/V model that stacks by runs
+    (routed experts, a layer pattern): (name, reference, kernel output) of
+    the paged attention kernel on seeded queries and pools at the model's
+    own head sizes — decode and a 2-token chunk, rows on non-contiguous
+    pages, one crossing a page boundary — against the gathered context
+    under its mask; with sliding layers the windowed call (rows deep into
+    their window, one at the ring's wrap, one short) against
+    ``_attend_ring``; and the expert product on GIVEN routing."""
+    keys = iter(jax.random.split(jax.random.PRNGKey(1234), 32))
+    rand = lambda shape: jax.random.normal(  # noqa: E731
+        next(keys), shape, jnp.float32).astype(jnp.bfloat16)
+    kvh, dh, group = cfg.kv_heads, cfg.dh, cfg.heads // cfg.kv_heads
+    n0 = page_size + 1
+    pages_per = -(-(n0 + 3) // page_size)
+    table = jnp.stack([jnp.arange(1, 2 * pages_per, 2)[::-1],
+                       jnp.arange(2, 2 * pages_per + 1, 2)]).astype(jnp.int32)
+    off = jnp.asarray([n0, 1], jnp.int32)
+    ctx = pages_per * page_size
+    steps = (("decode", 1), ("chunk", 2))
+    out = []
+    kp, vp = (rand((1, 1 + 2 * pages_per, page_size, kvh, dh)) for _ in range(2))
+    for name, c in steps:
+        q = rand((2, c, cfg.heads, dh))
+        positions = off[:, None] + jnp.arange(c)[None, :]
+        mask = jnp.arange(ctx)[None, None, None, :] <= positions[:, None, :, None]
+        k, v = (jnp.repeat(p[0, table].reshape(2, ctx, kvh, dh), group, axis=2)
+                for p in (kp, vp))
+        out.append((f"paged_attention_{name}", cm.attention(q, k, v, mask),
+                    _attend_paged(q, kp, vp, 0, table, off, cfg, None,
+                                  kernel_interpret)))
+    if SLIDING in cfg.kinds:
+        window = cfg.sliding_window
+        cols = window_ring_pages(cfg, page_size, 2)
+        ring = (1 + jnp.arange(2 * cols, dtype=jnp.int32)).reshape(2, cols)
+        kp, vp = (rand((1, 1 + 2 * cols, page_size, kvh, dh)) for _ in range(2))
+        woff = jnp.asarray([cols * page_size + window // 2, 3], jnp.int32)
+        for name, c in steps:
+            q = rand((2, c, cfg.heads, dh))
+            positions = woff[:, None] + jnp.arange(c)[None, :]
+            out.append((f"paged_window_attention_{name}",
+                        _attend_ring(q, kp, vp, 0, ring, positions, window),
+                        _attend_paged(q, kp, vp, 0, ring, woff, cfg, None,
+                                      kernel_interpret, window)))
+    if cfg.routed:
+        out.append(_expert_probe(params, cfg, keys, rand, kernel_interpret))
     return out
 
 
@@ -602,13 +679,15 @@ def _constrain(x, sharding):
 
 
 def _attend_paged(q, k_pages, v_pages, layer, page_table, off,
-                  cfg: DecoderConfig, kv_sharding, interpret: bool):
+                  cfg: DecoderConfig, kv_sharding, interpret: bool,
+                  window: int = 0):
     """Page-table-indirect flash attention over layer ``layer`` of the
     WHOLE pools (ops/ragged_attention.paged_flash_attention): query i of
     row b sits at absolute position ``off[b] + i`` and attends keys
     0..off+i, read straight from the pools — neither the layer's slice nor
     the [B, ctx, heads, dh] gather+repeat the dense reference materializes
-    per layer per step ever exists.
+    per layer per step ever exists. ``window`` > 0: a sliding layer, the
+    pools its window pools and ``page_table`` the rows' rings.
 
     Under tensor parallelism the kernel runs inside ``shard_map`` over the
     ``kv_sharding`` mesh's tp axis: attention is independent per KV head,
@@ -620,7 +699,7 @@ def _attend_paged(q, k_pages, v_pages, layer, page_table, off,
 
     if kv_sharding is None:
         return paged_flash_attention(q, k_pages, v_pages, layer, page_table,
-                                     off, interpret=interpret)
+                                     off, interpret=interpret, window=window)
     from jax.sharding import PartitionSpec as P
 
     mesh = kv_sharding.mesh
@@ -632,6 +711,28 @@ def _attend_paged(q, k_pages, v_pages, layer, page_table, off,
         out_specs=head_spec,
         check_vma=False,
     )(q, k_pages, v_pages, layer, page_table, off)
+
+
+def _attend_ring(q, k_pages, v_pages, layer, ring, positions, window: int):
+    """The plain-XLA form of a per-head sliding layer's attention over its
+    window pool (what ``paged_flash_attention(window=)`` is held to): the
+    row's ring of pages gathered out of the layer, each column's positions
+    worked out from the step's last query (column j holds the newest
+    logical page i <= the last with i % columns == j), masked to
+    ``t - window < s <= t``. Pages the window has passed were freed and may
+    be another row's by now: the bound hides them."""
+    b, cols = ring.shape
+    page, kvh, dh = k_pages.shape[2:]
+    last = positions[:, -1] // page                                # [B]
+    logical = last[:, None] - (last[:, None] - jnp.arange(cols)[None, :]) % cols
+    key_pos = (logical[:, :, None] * page + jnp.arange(page)).reshape(b, -1)
+    k = k_pages[layer, ring].reshape(b, cols * page, kvh, dh).astype(q.dtype)
+    v = v_pages[layer, ring].reshape(b, cols * page, kvh, dh).astype(q.dtype)
+    qp, kpos = positions[:, None, :, None], key_pos[:, None, None, :]
+    mask = (kpos <= qp) & (kpos > qp - window) & (kpos >= 0)
+    group = q.shape[2] // kvh
+    return cm.attention(q, jnp.repeat(k, group, axis=2),
+                        jnp.repeat(v, group, axis=2), mask)
 
 
 def _mixer_paged(lp: dict, y, cfg: DecoderConfig, states, windows, layer, rows,
@@ -675,70 +776,137 @@ def _dense_layers(params: dict, cfg: DecoderConfig, x, k_pages, v_pages,
                   off, mask, block: bool, kv_sharding, attention_kernel: str,
                   kernel_interpret: bool, ssm_rows=None, ssm_fresh=None):
     """The layer loop of a per-head K/V (GQA) model over the paged cache,
-    with ``_latent_layers``' operands. The pools ride in the carry whole:
-    each layer scatters its tokens' K and V at (layer, page, offset) in
-    place and hands the pools on, so no layer's slice is copied out,
-    written into and stacked back.
+    with ``_latent_layers``' operands: one scan per run of layers of one
+    shape and kind (``layer_runs``) — ONE, over ``layers``, for a model
+    without routed experts or a layer pattern. The pools ride in the carry
+    whole: each layer scatters its tokens' K and V at (its index among its
+    kind's layers, page, offset) in place and hands the pools on, so no
+    layer's slice is copied out, written into and stacked back, and runs
+    share them without slicing or re-joining.
 
-    ``page_idx`` / ``offset`` [B, S] place each token's row; query i of row
-    b sits at ``off[b] + i``; ``token_mask`` [B, S] names the tokens that
-    consume expert capacity (Switch MoE). A layer attends under ``mask`` —
-    over the block's own keys where ``block`` (the one-shot prefill), else
-    over the cache, this step's keys included: ``"paged"`` reads the page
-    table in place through the Pallas kernel (its causal bound key <=
-    off + i is exactly ``mask``), ``"gather"`` (the reference) gathers the
-    pages the table names out of the layer, [B, P * page] keys a row.
+    ``page_idx`` / ``offset`` [B, S] place each token's row in the kept
+    pool; ``page_table`` is the kept table [B, P] or, with sliding layers,
+    (kept, window ring); query i of row b sits at ``off[b] + i``;
+    ``token_mask`` [B, S] names the tokens that are written to a window
+    pool, that route (routed experts) and that consume expert capacity
+    (Switch MoE). A full layer attends under ``mask`` — over the block's
+    own keys where ``block`` (the one-shot prefill), else over the cache,
+    this step's keys included: ``"paged"`` reads the page table in place
+    through the Pallas kernel (its causal bound key <= off + i is exactly
+    ``mask``), ``"gather"`` (the reference) gathers the pages the table
+    names out of the layer, [B, P * page] keys a row. A sliding layer
+    attends the last ``sliding_window`` positions through its row's ring of
+    window pages (``_attend_ring``, or the kernel with its lower bound).
 
     A hybrid model's pools are the two dicts of ``init_page_pool``: beside
     its attention, from the same normed input, each layer runs its mixer
     over the state pool (``_mixer_paged``: ``ssm_rows`` [B], ``ssm_fresh``
     [B] or None in a decode step, ``token_mask`` the tokens that advance a
     state) and the two outputs add into one residual.
-    Returns (x, k_pages, v_pages)."""
+    Returns (x, k_pages, v_pages) and, from a routed model, the step's
+    counters (``moe_step_stats``)."""
     b, t = positions.shape
     dh = cfg.dh
     group = cfg.heads // cfg.kv_heads
-    page = (k_pages["kv"] if cfg.hybrid else k_pages).shape[2]
-    ctx = page_table.shape[1] * page
+    kernel = attention_kernel == "paged"
+    kept, ring = page_table if isinstance(page_table, tuple) else (page_table, None)
+    page = jax.tree_util.tree_leaves(k_pages)[0].shape[2]
+    ctx = kept.shape[1] * page
+    where = {FULL: (page_idx, offset)}
+    if ring is not None:
+        where[SLIDING] = _write_coords(ring, positions, token_mask, page, ring=True)
 
-    def layer(carry, scanned):
-        x, kp, vp = carry
-        lp, li = scanned
-        if cfg.hybrid:
-            (kp, states), (vp, windows) = ((p["kv"], p["ssm"]) for p in (kp, vp))
-        y = cm.rms_norm(lp["attn_norm"], x, cfg.norm_eps)
-        q, k, v = qkv_project(lp, y, cfg)
-        q = _rope(q, positions, cfg.rope_theta)
-        k = _rope(k, positions, cfg.rope_theta)
-        kp = _constrain(kp.at[li, page_idx, offset].set(k.astype(kp.dtype)),
-                        kv_sharding)
-        vp = _constrain(vp.at[li, page_idx, offset].set(v.astype(vp.dtype)),
-                        kv_sharding)
-        if attention_kernel == "paged" and not block:
-            attn = _attend_paged(q, kp, vp, li, page_table, off, cfg,
-                                 kv_sharding, kernel_interpret)
-        else:
-            if not block:
-                k = kp[li, page_table].reshape(b, ctx, cfg.kv_heads, dh).astype(x.dtype)
-                v = vp[li, page_table].reshape(b, ctx, cfg.kv_heads, dh).astype(x.dtype)
-            attn = cm.attention(q, jnp.repeat(k, group, axis=2),
-                                jnp.repeat(v, group, axis=2), mask)
-        out = _scaled(cm.dense(lp["wo"], attn.reshape(b, t, cfg.heads * dh)),
-                      cfg.attention_out_multiplier)
-        if cfg.hybrid:
-            mixed, states, windows = _mixer_paged(
-                lp, y, cfg, states, windows, li, ssm_rows, ssm_fresh,
-                token_mask, attention_kernel == "paged", kernel_interpret)
-            out = out + mixed
-            kp, vp = {"kv": kp, "ssm": states}, {"kv": vp, "ssm": windows}
-        x = x + out
-        y = cm.rms_norm(lp["mlp_norm"], x, cfg.norm_eps)
-        x = x + _mlp(lp, y, cfg, token_mask=token_mask)
-        return (x, kp, vp), None
+    def make_layer(routed: bool, kind: str, experts):
+        window = cfg.window(kind)
+        name = "kv_window" if window else "kv"
+        pi, po = where[kind]
 
-    (x, k_pages, v_pages), _ = jax.lax.scan(
-        layer, (x, k_pages, v_pages), (params["layers"], jnp.arange(cfg.layers)))
-    return x, k_pages, v_pages
+        def layer(carry, scanned):
+            x, kp, vp = carry
+            lp, li, *ei = scanned
+            pools = kp, vp
+            if cfg.hybrid:
+                (kp, states), (vp, windows) = ((p["kv"], p["ssm"]) for p in (kp, vp))
+            elif cfg.layered:
+                kp, vp = kp[name], vp[name]
+            y = cm.rms_norm(lp["attn_norm"], x, cfg.norm_eps)
+            q, k, v = qkv_project(lp, y, cfg)
+            q, k = qk_positioned(lp, q, k, cfg, positions, kind)
+            kp = _constrain(kp.at[li, pi, po].set(k.astype(kp.dtype)),
+                            kv_sharding)
+            vp = _constrain(vp.at[li, pi, po].set(v.astype(vp.dtype)),
+                            kv_sharding)
+            if kernel and not block:
+                attn = _attend_paged(q, kp, vp, li, ring if window else kept,
+                                     off, cfg, kv_sharding, kernel_interpret,
+                                     window)
+            elif window:
+                attn = _attend_ring(q, kp, vp, li, ring, positions, window)
+            else:
+                if not block:
+                    k = kp[li, kept].reshape(b, ctx, cfg.kv_heads, dh).astype(x.dtype)
+                    v = vp[li, kept].reshape(b, ctx, cfg.kv_heads, dh).astype(x.dtype)
+                attn = cm.attention(q, jnp.repeat(k, group, axis=2),
+                                    jnp.repeat(v, group, axis=2), mask)
+            out = _scaled(cm.dense(lp["wo"], attn.reshape(b, t, cfg.heads * dh)),
+                          cfg.attention_out_multiplier)
+            if cfg.hybrid:
+                mixed, states, windows = _mixer_paged(
+                    lp, y, cfg, states, windows, li, ssm_rows, ssm_fresh,
+                    token_mask, kernel, kernel_interpret)
+                out = out + mixed
+                kp, vp = {"kv": kp, "ssm": states}, {"kv": vp, "ssm": windows}
+            elif cfg.layered:
+                kp, vp = {**pools[0], name: kp}, {**pools[1], name: vp}
+            x = x + out
+            y = cm.rms_norm(lp["mlp_norm"], x, cfg.norm_eps)
+            if not routed:
+                return (x + _mlp(lp, y, cfg, token_mask=token_mask), kp, vp), None
+            # the stack's experts stay OUT of the scanned tree and whole:
+            # the kernel indexes the layer itself (``_latent_layers``)
+            out, load = routed_mlp(lp, y, cfg, token_mask=token_mask,
+                                   kernel=kernel, interpret=kernel_interpret,
+                                   stacked=(experts, ei[0]))
+            return (x + out, kp, vp), load
+        return layer
+
+    carry = (x, k_pages, v_pages)
+    loads = []
+    for name, first, stop, kind, routed, kind_first in layer_runs(cfg):
+        carry, load = _scan_run(
+            make_layer(routed, kind, params[name].get("experts")), carry,
+            params[name], first, stop, kind_first, routed)
+        if routed:
+            loads.append(load)
+    if not loads:
+        return carry
+    return (*carry, moe_step_stats(jnp.concatenate(loads),
+                                   cfg.experts_held and cfg.held))
+
+
+def _scan_run(layer, carry, stack: dict, first: int, stop: int,
+              kind_first: int, routed: bool):
+    """``lax.scan`` of ``layer(carry, (the layer's params, its index among
+    its kind's layers[, its index in the stack]))`` over layers
+    ``first..stop`` of ``stack`` (a routed stack's experts left out: the
+    layer reads them whole, by the index). A run that is its whole stack
+    scans the stack; a part of one scans indices and reads each layer's
+    leaves out of the stack inside the loop — a static slice of the run
+    would copy its weights once a step."""
+    scanned = ({k: v for k, v in stack.items() if k != "experts"} if routed
+               else stack)
+    depth = scanned["attn_norm"]["scale"].shape[0]
+    of_kind = jnp.arange(kind_first, kind_first + stop - first)
+    in_stack = (jnp.arange(first, stop),) if routed else ()
+    if (first, stop) == (0, depth):
+        return jax.lax.scan(layer, carry, (scanned, of_kind, *in_stack))
+
+    def part(carry, indices):
+        lp = jax.tree_util.tree_map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, indices[-1], keepdims=False),
+            scanned)
+        return layer(carry, (lp, *indices[:1 + routed]))
+    return jax.lax.scan(part, carry, (of_kind, *in_stack, jnp.arange(first, stop)))
 
 
 def _ssm_operands(cfg: DecoderConfig, rows, fresh) -> dict:
@@ -776,11 +944,12 @@ def paged_prefill(params: dict, cfg: DecoderConfig, input_ids, lengths,
             "a model that carries a recurrent state prefills in chunks "
             "through the cache (prefill_chunk > 0): the chunk's program is "
             "the one that is told its slot's row of the state pool")
-    if cfg.latent and cfg.layered:
+    if cfg.layered:
         from arkflow_tpu.errors import ConfigError
 
         raise ConfigError(
-            "a model with a layer pattern (sliding or indexed latent layers) "
+            "a model with a layer pattern (sliding or indexed layers: "
+            f"pools {', '.join(pool.name for pool in cache_spec(cfg))}) "
             "prefills in chunks through the cache (prefill_chunk > 0): the "
             "one-shot prefill attends over its own block under one mask")
     b, t = input_ids.shape
@@ -810,7 +979,7 @@ def paged_prefill(params: dict, cfg: DecoderConfig, input_ids, lengths,
             attention_kernel=attention_kernel,
             kernel_interpret=kernel_interpret)
     else:
-        x, new_k, new_v = _dense_layers(
+        x, new_k, new_v, *moe = _dense_layers(
             params, cfg, x, k_pages, v_pages, positions, page_idx, offset,
             pos_valid, page_table=page_table, off=None, mask=mask, block=True,
             kv_sharding=kv_sharding, attention_kernel=attention_kernel,
@@ -902,9 +1071,9 @@ def paged_prefill_chunk(params: dict, cfg: DecoderConfig, input_ids, chunk_off,
             block=False, attention_kernel=attention_kernel,
             kernel_interpret=kernel_interpret)
     else:
-        x, new_k, new_v = _dense_layers(
+        x, new_k, new_v, *moe = _dense_layers(
             params, cfg, x, k_pages, v_pages, positions, page_idx, offset,
-            pos_valid, page_table=page_table, off=chunk_off, mask=mask,
+            pos_valid, page_table=tables, off=chunk_off, mask=mask,
             block=False, kv_sharding=kv_sharding,
             attention_kernel=attention_kernel,
             kernel_interpret=kernel_interpret, **_ssm_operands(
@@ -971,9 +1140,9 @@ def paged_decode_step(params: dict, cfg: DecoderConfig, token_ids, lengths,
         # the single query sits at absolute position lengths[s]: the
         # kernel's causal bound (key <= lengths) is exactly ``valid``;
         # inactive lanes must not consume expert capacity (MoE)
-        x, new_k, new_v = _dense_layers(
+        x, new_k, new_v, *moe = _dense_layers(
             params, cfg, x, k_pages, v_pages, positions, write_page[:, None],
-            write_off[:, None], active[:, None], page_table=page_table,
+            write_off[:, None], active[:, None], page_table=tables,
             off=lengths, mask=valid, block=False, kv_sharding=kv_sharding,
             attention_kernel=attention_kernel,
             kernel_interpret=kernel_interpret,

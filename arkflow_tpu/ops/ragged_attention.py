@@ -155,17 +155,25 @@ def ragged_flash_attention(q, k, v, lengths, *, causal: bool = False,
 _PAGED_ROWS = 1024
 
 
+def _window_start(first, window: int, cols: int):
+    """The first page GROUP a tile whose first query sits at ``first`` walks
+    under a lower bound: the one that holds ``first - (window - 1)``."""
+    return jnp.maximum(first - (window - 1), 0) // cols
+
+
 def _paged_kernel(off_ref, table_ref, q_ref, k_ref, v_ref, o_ref,
                   o_acc, m_acc, l_acc, *, page: int, kvh: int, heads: int,
-                  tile_c: int, pages_per: int):
+                  tile_c: int, pages_per: int, window: int = 0):
     bi = pl.program_id(0)
     ci = pl.program_id(1)
-    pi = pl.program_id(2)
+    pi = step = pl.program_id(2)
     rows, d = q_ref.shape[1], q_ref.shape[2]
     cols = page * kvh
     group = heads // kvh
+    if window:  # the walk starts at the window's first page, not at 0
+        pi = step + _window_start(off_ref[bi] + ci * tile_c, window, page)
 
-    @pl.when(pi == 0)
+    @pl.when(step == 0)
     def _init():
         o_acc[:] = jnp.zeros_like(o_acc)
         m_acc[:] = jnp.full_like(m_acc, _NEG)
@@ -191,8 +199,10 @@ def _paged_kernel(off_ref, table_ref, q_ref, k_ref, v_ref, o_ref,
         q_pos = first + r // heads
         k_pos = pi * page + c // kvh
         own_head = (r % heads) // group == c % kvh
-        scores = jnp.where(jnp.logical_and(own_head, k_pos <= q_pos),
-                           scores, _NEG)
+        keep = jnp.logical_and(own_head, k_pos <= q_pos)
+        if window:
+            keep = jnp.logical_and(keep, k_pos > q_pos - window)
+        scores = jnp.where(keep, scores, _NEG)
         m = m_acc[:, :1]                                          # [rows, 1]
         m_new = jnp.maximum(m, scores.max(axis=-1, keepdims=True))
         p = jnp.exp(scores - m_new)
@@ -204,19 +214,26 @@ def _paged_kernel(off_ref, table_ref, q_ref, k_ref, v_ref, o_ref,
             p, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    @pl.when(pi == pages_per - 1)
+    @pl.when(step == pages_per - 1)
     def _fin():
         # every query admits at least key 0 of its own kv head (k_pos=0 <=
         # q_pos always) and page 0 is always within the bound, so m is real
         # before any fully-masked page arrives and l is never truly zero;
-        # the floor only guards numerical underflow
+        # the floor only guards numerical underflow. Under a lower bound a
+        # page may hold no key of a query whose own key comes in a later
+        # step of the walk: what it summed meanwhile is scaled to 0 there
         o_ref[0] = (o_acc[:] / jnp.maximum(l_acc[:, :1], 1e-30)
                     ).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+#: the windowed call's name in a device trace (the full call keeps the
+#: jitted function's own, ``paged_flash_attention``)
+PAGED_WINDOW_NAME = "paged_window_attention"
+
+
+@functools.partial(jax.jit, static_argnames=("interpret", "window"))
 def paged_flash_attention(q, k_pages, v_pages, layer, page_table, off, *,
-                          interpret: bool = False):
+                          interpret: bool = False, window: int = 0):
     """Flash attention that reads K/V straight from the serving page pools.
 
     q: [B, C, H, dh] — C queries per row at absolute positions
@@ -230,6 +247,14 @@ def paged_flash_attention(q, k_pages, v_pages, layer, page_table, off, *,
     Query i attends keys 0..off+i — exactly the dense-gather reference's
     ``key_pos <= positions`` mask — with GQA resolved inside the kernel
     (no ``jnp.repeat`` of K/V). Returns [B, C, H, dh] in q's dtype.
+
+    ``window`` > 0 (a sliding layer) bounds the keys below too — the query
+    at t attends ``t - window < s <= t`` — and ``page_table`` is then a
+    RING over the layer's window pool: logical page i sits in column
+    ``i % columns``. A (row, query tile) walks only the pages its window
+    touches, from the one that holds its first query's oldest key, and the
+    call is named ``paged_window_attention`` in a trace. 0 lowers to the
+    call as it was before there were windows.
     """
     b, c, h, dh = q.shape
     layers, n_pages, page, kvh, _ = k_pages.shape
@@ -252,10 +277,15 @@ def paged_flash_attention(q, k_pages, v_pages, layer, page_table, off, *,
     rows = tile_c * h
     from jax.experimental.pallas import tpu as pltpu
 
+    ring, named = pages_per, {}
+    if window:
+        # pages from the first query's oldest key to the tile's last query
+        pages_per = (window + tile_c - 2) // page + 2
+        named = {"name": PAGED_WINDOW_NAME}
     grid = (b, c_pad // tile_c, pages_per)
     kernel = functools.partial(
         _paged_kernel, page=page, kvh=kvh, heads=h, tile_c=tile_c,
-        pages_per=pages_per)
+        pages_per=pages_per, window=window)
 
     def _page_index(bi, ci, pi, off_ref, table_ref):
         # pages past the tile's causal bound resolve to the scratch page 0
@@ -263,6 +293,10 @@ def paged_flash_attention(q, k_pages, v_pages, layer, page_table, off, *,
         # the remaining grid steps, so the pipeline skips the re-copy, and
         # pl.when skips the math
         max_pos = off_ref[bi] + ci * tile_c + (tile_c - 1)
+        if window:
+            pi = pi + _window_start(off_ref[bi] + ci * tile_c, window, page)
+            return (jnp.where(pi * page <= max_pos, table_ref[bi, pi % ring], 0),
+                    0, 0)
         return (jnp.where(pi * page <= max_pos, table_ref[bi, pi], 0), 0, 0)
 
     def _q_index(bi, ci, pi, *_):
@@ -287,7 +321,7 @@ def paged_flash_attention(q, k_pages, v_pages, layer, page_table, off, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, c_pad * h, dh), q.dtype),
-        interpret=interpret,
+        interpret=interpret, **named,
     )(jnp.asarray(off, jnp.int32), table, q.reshape(b, c_pad * h, dh),
       k_pages.reshape(layers * n_pages, page * kvh, dh),
       v_pages.reshape(layers * n_pages, page * kvh, dh))
@@ -321,12 +355,6 @@ def paged_flash_attention(q, k_pages, v_pages, layer, page_table, off, *,
 
 #: pages a grid step attends over (8 x 16-token pages = one 128-lane tile)
 _LATENT_GROUP = 8
-
-
-def _window_start(first, window: int, cols: int):
-    """The first page GROUP a tile whose first query sits at ``first`` walks
-    under a lower bound: the one that holds ``first - (window - 1)``."""
-    return jnp.maximum(first - (window - 1), 0) // cols
 
 
 def _latent_kernel(layer_ref, off_ref, table_ref, ql_ref, qr_ref, *rest,

@@ -28,7 +28,10 @@ Config:
     model_config: {vocab_size: 2048, ...}   # latent attention (MLA) +
                              # routed experts: kv_lora_rank, n_routed_experts,
                              # ...; a layer pattern: layer_types, swa_*,
-                             # index_*, experts_held (docs/CONFIG.md); such
+                             # index_*, experts_held (docs/CONFIG.md); on
+                             # per-head K/V layers: layer_types,
+                             # sliding_window, qk_norm, full_attention_rope,
+                             # with or without n_routed_experts; such
                              # a model serves through serving: continuous
                              # on one chip only; so does the hybrid block
                              # (mamba_*: a Mamba-2 mixer beside attention,
@@ -167,6 +170,22 @@ class TpuGenerateProcessor(Processor):
                     "continuous serving shards the KV pool over KV heads, "
                     "and a latent (MLA) page has one shared row per token "
                     "(remove mesh)")
+        if getattr(self.cfg, "by_runs", False) and not self.cfg.latent:
+            # a per-head K/V model with routed experts or a layer pattern
+            # (layer_types / sliding_window: window pages beside kept pages)
+            if serving != "continuous":
+                raise ConfigError(
+                    "a per-head K/V model with routed experts "
+                    "(n_routed_experts) or a layer pattern (layer_types) "
+                    "generates through serving: continuous only: the batch "
+                    "path runs one stack of dense layers over a contiguous "
+                    "cache")
+            if mesh_config:
+                raise ConfigError(
+                    "a per-head K/V model with routed experts or a layer "
+                    "pattern is served on one chip: its expert stack and "
+                    "its window pool have no sharding over a mesh yet "
+                    "(remove mesh)")
         if getattr(self.cfg, "hybrid", False):
             # before the host init too
             if serving != "continuous":
@@ -273,8 +292,10 @@ class TpuGenerateProcessor(Processor):
             #: (MLA) page has no wire format yet: no adapter is offered,
             #: and the server's export / adopt calls raise ConfigError
             # (nor has a recurrent state)
+            # (nor has a window pool's ring of live pages)
             if not (getattr(self.cfg, "latent", False)
-                    or getattr(self.cfg, "hybrid", False)):
+                    or getattr(self.cfg, "hybrid", False)
+                    or getattr(self.cfg, "layered", False)):
                 self.disagg = self
 
         reg = global_registry()
@@ -489,17 +510,19 @@ def _build(config: dict, resource: Resource) -> TpuGenerateProcessor:
         health_config=core_cfg["health_config"],
         checkpoint=config.get("checkpoint"),
     )
-    if getattr(proc.cfg, "latent", False):
+    if getattr(proc.cfg, "by_runs", False):
         # the swap canary and the integrity golden run the family's batch
         # forward against per-head-cache assumptions that were never checked
-        # for a latent model: refuse the keys, attach neither
+        # for a model that stacks by runs (latent attention, routed experts,
+        # a layer pattern): refuse the keys, attach neither
         for key in ("swap", "integrity"):
             if config.get(key) is not None:
                 raise ConfigError(
                     f"tpu_generate: {key} is not supported for a "
-                    "latent-attention model yet (its drain / flip / pool "
-                    "reset and golden forward are unverified for latent "
-                    "pages); remove the key")
+                    "latent-attention model, nor for a per-head K/V model "
+                    "with routed experts or a layer pattern, yet (its drain "
+                    "/ flip / pool reset and golden forward are unverified "
+                    "for latent and window pages); remove the key")
         return proc
     from arkflow_tpu.tpu.swap import build_generate_swapper, parse_swap_config
 

@@ -257,11 +257,17 @@ class GenerationServer:
                 "tp axis; a latent (MLA) pool has one shared row per token "
                 "and no head axis to split — serve a latent-attention model "
                 "on one chip (no mesh)")
+        if cfg.by_runs and mesh is not None:
+            raise ConfigError(
+                "a per-head K/V model with routed experts or a layer pattern "
+                f"(pools {self._pool_names()}) is served on one chip: its "
+                "expert stack and its window pool have no sharding over a "
+                "mesh yet (remove mesh)")
         # a layer pattern: rows of more than one kind and lifetime (the
         # model's ``cache_spec``). Window rows live in a pool of their own,
         # each slot's pages in a ring that a step's queries fit in
         self.prefill_chunk = int(prefill_chunk)
-        self._layered = bool(cfg.latent and cfg.layered)
+        self._layered = bool(cfg.layered)
         # what a held share and a layer pattern add to a step's counters,
         # in the order they follow the routing's three (``_note_moe``):
         # pairs routed to the experts HELD here; keys an indexed layer
@@ -285,8 +291,7 @@ class GenerationServer:
         self._stateful = bool(cfg.hybrid)
         if self._stateful:
             self._refuse_stateful(prefix_cache_pages, speculative_tokens)
-        self._win_cols = window_ring_pages(
-            cfg, page_size, self.prefill_chunk) if cfg.latent else 0
+        self._win_cols = window_ring_pages(cfg, page_size, self.prefill_chunk)
         #: page 0 of the window pool is scratch too; every slot can hold a
         #: whole ring, so a window page is never waited for
         self.num_win_pages = 1 + self.slots * self._win_cols if self._win_cols else 0
@@ -611,24 +616,30 @@ class GenerationServer:
 
     # -- device plumbing (jit build / sharding / reset) --------------------
 
+    def _pool_names(self) -> str:
+        """The model's cache pools by name, for a refusal's message."""
+        return ", ".join(pool.name for pool in cache_spec(self.cfg))
+
     def _refuse_layered(self, prefix_cache_pages, speculative_tokens) -> None:
         """What a model with a layer pattern is not served with yet."""
+        pools = self._pool_names()
         if self.prefill_chunk <= 0:
             raise ConfigError(
-                "a model with a layer pattern (sliding or indexed latent "
-                "layers) prefills in chunks through the cache: set "
+                "a model with a layer pattern (sliding or indexed layers: "
+                f"pools {pools}) prefills in chunks through the cache: set "
                 "prefill_chunk > 0 (its size bounds a slot's window pages)")
         if prefix_cache_pages and self.cfg.sliding_window:
             raise ConfigError(
-                "prefix_cache_pages does not compose with window pages: a "
-                "sliding layer's rows are freed as the window passes, so a "
-                "finished prompt has no full pages of them to donate")
+                "prefix_cache_pages does not compose with window pages "
+                f"(pools {pools}): a sliding layer's rows are freed as the "
+                "window passes, so a finished prompt has no full pages of "
+                "them to donate")
         if speculative_tokens:
             raise ConfigError(
                 "speculative_tokens does not compose with indexed or sliding "
-                "layers: a rejected draft leaves its index key behind, which "
-                "a later query's indexer may select, and the verify step "
-                "does not slide the window pool")
+                f"layers (pools {pools}): a rejected draft leaves its index "
+                "key behind, which a later query's indexer may select, and "
+                "the verify step does not slide the window pool")
 
     def _refuse_stateful(self, prefix_cache_pages, speculative_tokens) -> None:
         """What a model that carries a recurrent state a slot is not served
@@ -682,18 +693,22 @@ class GenerationServer:
         from arkflow_tpu.models.paged_decode import paged_prefill_chunk
         from arkflow_tpu.tpu.serving_core import logits_parity
 
-        if self.cfg.latent:
+        if self.cfg.by_runs:
             # kernel by kernel on given routing (why: latent_kernel_probe);
             # the verdict is the worst kernel's
-            from arkflow_tpu.models.paged_decode import latent_kernel_probe
+            from arkflow_tpu.models.paged_decode import (gqa_kernel_probe,
+                                                         latent_kernel_probe)
+
+            kernel_probe = (latent_kernel_probe if self.cfg.latent
+                            else gqa_kernel_probe)
 
             # ONE program: op by op, the probes of a layer pattern's kernels
             # at published widths are hundreds of small compiles (minutes)
             names = []
 
             def probe(params):
-                out = latent_kernel_probe(params, self.cfg, self.page_size,
-                                          self.kernel_interpret)
+                out = kernel_probe(params, self.cfg, self.page_size,
+                                   self.kernel_interpret)
                 names.extend(n for n, _, _ in out)
                 return [(ref, got) for _, ref, got in out]
 
@@ -1308,6 +1323,13 @@ class GenerationServer:
                 f"{what} ships per-head K/V page slabs split along the "
                 "kv_heads axis; a latent (MLA) page has no head axis and no "
                 "wire format yet — a latent-attention model prefills and "
+                "decodes on the same server")
+        if self._layered:
+            raise ConfigError(
+                f"{what} ships the pages of ONE kept pool; a layer pattern's "
+                f"pools ({self._pool_names()}) have no wire form yet — the "
+                "window pool's live pages and their ring would have to ship "
+                "beside the kept pages — so such a model prefills and "
                 "decodes on the same server")
 
     async def prefill_export(self, prompt_ids: list[int],

@@ -476,8 +476,7 @@ def test_gen_steps_ahead_pct_reader(ahead, steps, want):
     assert got == want if want is None else got == pytest.approx(want)
     with open(os.path.join(root, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    entry = bench["per_layer"][-1]
-    assert entry["name"] == "gen_steps_ahead_pct"
+    (entry,) = [m for m in bench["per_layer"] if m["name"] == "gen_steps_ahead_pct"]
     assert entry["layer"] == "scheduler, generate"
     assert entry["source"] == "program_counter" and entry["moves"] == "tokens_per_s"
     # the generate cells whose servers run ahead: not the one with a

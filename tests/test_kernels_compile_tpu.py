@@ -481,3 +481,90 @@ def test_hybrid_steps_carry_the_state_pool_whole(v5e):
         text = step.as_text()
         assert "tpu_custom_call" in text and name in text
         assert step.memory_analysis().temp_size_in_bytes < STATE_TEMP_LIMIT
+
+
+# -- a layer pattern over per-head K/V with routed experts (K-EXAONE widths) ----
+
+KEXAONE = dict(vocab_size=19200, dim=6144, layers=5, heads=64, kv_heads=8,
+               head_dim=128, ffn=18432, max_seq=262144, rope_theta=1e6,
+               norm_eps=1e-5, qk_norm=True, full_attention_rope=False,
+               layer_types=("sliding_attention",) * 3 + ("full_attention",
+                                                          "sliding_attention"),
+               sliding_window=128, n_routed_experts=128, experts_held=(0, 16),
+               num_experts_per_tok=8, n_shared_experts=1,
+               moe_intermediate_size=2048, first_k_dense_replace=1,
+               routed_scaling_factor=2.5)
+#: 48 slots x 528 kept pages + scratch; 48 rings of 41 window pages + scratch
+KEXAONE_SLOTS, KEXAONE_TABLE, KEXAONE_RING = 48, 528, 41
+
+
+@pytest.mark.parametrize("rows,chunk", [(48, 1), (1, 512)], ids=["decode", "chunk512"])
+def test_paged_window_attention_compiles(v5e, rows, chunk):
+    """The per-head kernel with its lower bound over a ring of window pages,
+    at the cell's lanes and chunk; named for the trace."""
+    pool = ((4, 1 + KEXAONE_SLOTS * KEXAONE_RING, PAGE, 8, 128), BF16)
+    compiled = _compile(
+        lambda q, kp, vp, layer, ring, off: paged_flash_attention(
+            q, kp, vp, layer, ring, off, window=128),
+        v5e, ((rows, chunk, 64, 128), BF16), pool, pool, ((), I32),
+        ((rows, KEXAONE_RING), I32), ((rows,), I32))
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "paged_window_attention" in text
+
+
+def _kexaone_steps(devices):
+    """The ``_decode`` (48 lanes) and ``_chunk`` (1 x 512) programs of the
+    K-EXAONE cut as the server jits them, pools donated, for the described
+    chip."""
+    from arkflow_tpu.models import decoder as dec
+    from arkflow_tpu.models.paged_decode import (init_page_pool, paged_decode_step,
+                                                 paged_prefill_chunk)
+
+    cfg = dec.DecoderConfig(**KEXAONE)
+    repl = SingleDeviceSharding(devices[0])
+
+    def struct(a, dtype=None):
+        return jax.ShapeDtypeStruct(a.shape, dtype or a.dtype, sharding=repl)
+
+    params = jax.tree_util.tree_map(
+        struct, jax.eval_shape(lambda: dec.init(jax.random.PRNGKey(0), cfg)),
+        dec.serve_dtypes(cfg))
+    kp, vp = jax.tree_util.tree_map(struct, jax.eval_shape(
+        lambda: init_page_pool(cfg, 1 + KEXAONE_SLOTS * KEXAONE_TABLE, PAGE,
+                               1 + KEXAONE_SLOTS * KEXAONE_RING)))
+    kern = dict(attention_kernel="paged")
+
+    def decode(p, tok, lens, act, kept, ring, kp, vp):
+        return paged_decode_step(p, cfg, tok, lens, act, (kept, ring), kp, vp,
+                                 return_logits=True, **kern)
+
+    def chunk(p, ids, off, clen, kept, ring, kp, vp):
+        return paged_prefill_chunk(p, cfg, ids, off, clen, (kept, ring), kp, vp,
+                                   **kern)
+
+    def compiled(fn, *operands):
+        n = len(operands)
+        return jax.jit(fn, donate_argnums=(n + 1, n + 2)).lower(
+            params, *[jax.ShapeDtypeStruct(s, d, sharding=repl)
+                      for s, d in operands], kp, vp).compile()
+
+    s = KEXAONE_SLOTS
+    return (compiled(decode, ((s,), I32), ((s,), I32), ((s,), jnp.bool_),
+                     ((s, KEXAONE_TABLE), I32), ((s, KEXAONE_RING), I32)),
+            compiled(chunk, ((1, 512), I32), ((1,), I32), ((1,), I32),
+                     ((1, KEXAONE_TABLE), I32), ((1, KEXAONE_RING), I32)))
+
+
+def test_pattern_steps_carry_pools_and_stacks_whole(v5e):
+    """Runs within the expert stack read their layers out of it inside the
+    loop: no run's experts (1.2 GB a layer) and no pool (1.66 GB kept) is
+    copied for a step. The programs' temporaries are about one layer's
+    attention weights (229 MB: the compiler re-lays ``wq`` for its product
+    inside the loop, in a whole-stack scan too, 102 MB there); both
+    kernels' names are in the programs' text."""
+    for step in _kexaone_steps(v5e):
+        text = step.as_text()
+        for name in ("paged_window_attention", "paged_flash_attention",
+                     "moe_expert_swiglu"):
+            assert name in text, name
+        assert step.memory_analysis().temp_size_in_bytes < 300 * 1024 * 1024

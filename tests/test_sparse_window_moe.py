@@ -562,13 +562,24 @@ def test_model_config_values_not_served_raise(bad):
         dec.DecoderConfig(**{**TINY, **bad})
 
 
-def test_a_dense_model_refuses_the_pattern_s_keys():
-    for bad in ({"layer_types": (FULL, FULL)}, {"sliding_window": 9},
-                {"index_topk": 4}, {"attention_gate_type": "headwise"},
-                {"apply_mla_qkv_lora_rescale": True}):
-        with pytest.raises(ConfigError, match="latent-attention model"):
-            dec.DecoderConfig(vocab_size=64, dim=32, layers=2, heads=4,
-                              kv_heads=2, ffn=64, **bad)
+@pytest.mark.parametrize("bad,needle", [
+    ({"sliding_window": 9}, "go together"),
+    ({"index_topk": 4}, "latent-attention model"),
+    ({"attention_gate_type": "headwise"}, "latent-attention model"),
+    ({"apply_mla_qkv_lora_rescale": True}, "latent-attention model"),
+    ({"swa_kv_lora_rank": 8}, "latent-attention model"),
+    ({"layer_types": (FULL, FULL)}, None),
+], ids=lambda v: "-".join(v) if isinstance(v, dict) else None)
+def test_a_dense_model_refuses_the_latent_pattern_s_keys(bad, needle):
+    """A per-head K/V model serves a layer pattern of its own since PR 40
+    (``tests/test_window_gqa_moe.py``): ``layer_types`` alone passes, a
+    window needs a sliding layer, the latent pattern's sizes stay refused."""
+    sizes = dict(vocab_size=64, dim=32, layers=2, heads=4, kv_heads=2, ffn=64)
+    if needle is None:
+        assert not dec.DecoderConfig(**sizes, **bad).by_runs
+        return
+    with pytest.raises(ConfigError, match=needle):
+        dec.DecoderConfig(**sizes, **bad)
 
 
 def test_serve_dtypes_cover_every_leaf_and_state_the_choosers_float32():
