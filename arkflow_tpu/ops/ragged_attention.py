@@ -131,28 +131,89 @@ def ragged_flash_attention(q, k, v, lengths, *, causal: bool = False,
 # dense-gather path in models/paged_decode.py materializes kp[page_table]
 # — a [B, P*page, heads, dh] copy of the whole context per layer per step —
 # then runs masked XLA attention over it. This kernel reads the page table
-# in place ("Ragged Paged Attention", PAPERS.md): the grid walks
-# (row, query tile, page), the BlockSpec index map resolves each row's p-th
-# page through the scalar-prefetched table, and pages past the tile's causal
-# bound resolve to the scratch page 0 so consecutive out-of-range steps
-# reuse one block copy and skip the math.
+# in place ("Ragged Paged Attention", PAPERS.md), and the KERNEL walks it,
+# not the grid: the grid is (row, query tile), one program each; the pools
+# stay in HBM, the table and the offsets are scalar-prefetched, and a
+# program works out from them the logical pages its queries can attend —
+# up to its last query's page, from 0 or from the window's first page —,
+# loops over them in groups of ``G`` and copies each group's K and V pages
+# out of the pool itself into one of two VMEM slots, group g + 1 started
+# before group g is waited for. A table column past a row's context costs
+# nothing: no grid step, no copy, no skipped branch. (A grid over every
+# column cost 0.14-0.24 us a column a lane on a v5e, 110 ns where the math
+# was skipped, with a third of the columns live: PERF.md, PR 41.)
 #
-# Layout (what the TPU tiling accepts): a block takes ALL kv heads of a
+# One case keeps that grid, ``_paged_grid_kernel`` below: a head that is no
+# multiple of 128 lanes, compiled for a chip. Mosaic sees such a pool padded
+# to whole 128-lane rows and takes a copy out of it only in whole rows
+# ("Slice shape along dimension 2 must be aligned to tiling (128)": of a
+# VMEM slot padded to 128 lanes and of ``pltpu.emit_pipeline``'s copies
+# too); only a BlockSpec of the grid may read it. Serving such a model with
+# gather instead costs 1.9-2.9 x a decode step (PERF.md, PR 41); the pool's
+# shape is what stands in the walk's way there (ROADMAP S11 (v)).
+#
+# Layout (what the TPU tiling accepts): a copy takes ALL kv heads of a
 # page. The pool rides whole and is viewed as [layers*num_pages,
-# page*kv_heads, dh] — a free bitcast of its HBM layout, rows ordered (slot,
-# kv head), the table offset to the layer's pages so the layer loop never
-# slices it — and the queries as [B, C*heads, dh], rows ordered
+# page*kv_heads, dh] — a free bitcast of its HBM layout at a head of 128
+# lanes, rows ordered (slot, kv head), the table offset to the layer's
+# pages so the layer loop never slices it — and the queries as
+# [B, C*heads, dh], rows ordered
 # (position, head): both views are plain reshapes, and every block's last
 # two dims equal the array's or are (8k, dh). One [rows, dh] x
-# [dh, page*kv_heads] product scores every query head against every kv head
-# of the page; entries whose kv head is not the query head's own are masked
-# with the causal bound. That is no more MXU or VPU work than per-head
-# [.., page]-wide products, which would fill only page/128 of each lane
-# tile, and GQA needs no ``jnp.repeat`` of K/V.
+# [dh, G*page*kv_heads] product scores every query head against every kv
+# head of the group's pages; entries whose kv head is not the query head's
+# own are masked with the causal bound. That is no more MXU or VPU work
+# than per-head [.., page]-wide products, which would fill only page/128 of
+# each lane tile, and GQA needs no ``jnp.repeat`` of K/V.
 
 #: folded query rows (positions x heads) per program: bounds VMEM whatever
 #: the chunk length is ([rows, 128] f32 score tiles of 512 KiB)
 _PAGED_ROWS = 1024
+
+#: what a program's walk may hold in VMEM where the core has plenty. A v5e
+#: core has 128 MiB of it and a kernel gets 16 by default, so the call asks
+#: for its own limit: this budget, the query and output blocks and the
+#: accumulators (up to 3 MiB at 1,024 rows) and as much again for the
+#: compiler's temporaries. Measured on a v5e (PERF.md, PR 41): a 1,024-row
+#: chunk tile costs 2.25 us a page at one page a step, 1.56 at two, 0.74 at
+#: five, 0.62 at ten, 0.56 at sixteen (the row reductions and the
+#: accumulators' read-modify-write are paid a step, not a page)
+_PAGED_WALK_BYTES = 24 << 20
+
+
+def _walk_budget() -> int:
+    """The walk's VMEM budget on the chip this process drives: a fifth of a
+    core's VMEM, at most ``_PAGED_WALK_BYTES`` (a v5e's or a v6e's 128 MiB
+    give all 24 MiB, a v5p's 64 give 12.8, a v4's 16 give 3.2, so the limit
+    the call asks for, twice the budget and 8 MiB, fits each of them)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    try:
+        vmem = pltpu.get_tpu_info().vmem_capacity_bytes
+    except ValueError:  # no TPU here: interpreted, or compiled for a described v5e
+        vmem = 128 << 20
+    return min(_PAGED_WALK_BYTES, vmem // 5)
+
+
+#: pages a group may take whatever the budget allows: each page is two
+#: copies (K and V) issued one by one, and a decode step gains nothing past
+#: sixteen (l6, 16 lanes: 236 us a call at 4, 178 at 8, 155 at 16, 161 at 32)
+_PAGED_GROUP_MAX = 16
+
+
+def _page_group(rows: int, page: int, kvh: int, dh: int, itemsize: int) -> int:
+    """Pages a program takes per step of its walk, from the shapes it is
+    called with. A page costs VMEM in two places: its K and V rows — twice
+    (two slots) in the pools' type, once more in float32 for the products —
+    and its ``page * kvh`` columns of every [rows, columns] float32 tile the
+    softmax holds at once (scores, probabilities, the mask's bounds: four).
+    A decode step's 8-64 folded rows leave room for many pages, so its
+    groups stop at ``_PAGED_GROUP_MAX``; a chunk tile of 1,024 rows takes
+    ten pages of 128 columns (8 kv heads), sixteen of 64 or of 32 (under a
+    v5e's budget; fewer where ``_walk_budget`` is smaller)."""
+    cols = page * kvh
+    a_page = cols * (dh * (4 * itemsize + 8) + rows * 16)
+    return max(1, min(_PAGED_GROUP_MAX, _walk_budget() // a_page))
 
 
 def _window_start(first, window: int, cols: int):
@@ -161,9 +222,131 @@ def _window_start(first, window: int, cols: int):
     return jnp.maximum(first - (window - 1), 0) // cols
 
 
-def _paged_kernel(off_ref, table_ref, q_ref, k_ref, v_ref, o_ref,
-                  o_acc, m_acc, l_acc, *, page: int, kvh: int, heads: int,
-                  tile_c: int, pages_per: int, window: int = 0):
+def _paged_kernel(off_ref, table_ref, q_ref, k_hbm, v_hbm, o_ref,
+                  k_buf, v_buf, sems, o_acc, m_acc, l_acc, *, page: int,
+                  kvh: int, heads: int, tile_c: int, group: int, ring: int,
+                  window: int = 0):
+    from jax.experimental.pallas import tpu as pltpu
+
+    bi = pl.program_id(0)
+    ci = pl.program_id(1)
+    rows, d = q_ref.shape[1], q_ref.shape[2]
+    cols = page * kvh
+    width = group * cols
+
+    # folded row r is (chunk position ci*tile_c + r // heads, q head
+    # r % heads) at absolute position off + that; the tile's last attendable
+    # key is its last query's position, so the walk ends at that page. The
+    # table's width bounds it too: queries padded past a chunk may sit past
+    # the last column (a ring has no last column: it wraps)
+    first = off_ref[bi] + ci * tile_c
+    hi = (first + (tile_c - 1)) // page + 1
+    if window:  # the walk starts at the window's first page, not at 0
+        lo = _window_start(first, window, page)
+    else:
+        lo, hi = 0, jnp.minimum(hi, ring)
+    steps = (hi - lo + (group - 1)) // group
+
+    def copies(slot, j, src):
+        """The K and the V copy of page ``src`` of the pool into ``slot``, as
+        the ``j``-th page of a group."""
+        dst = pl.ds(pl.multiple_of(j * cols, cols), cols)
+        return (pltpu.make_async_copy(k_hbm.at[src], k_buf.at[slot, dst],
+                                      sems.at[0, slot]),
+                pltpu.make_async_copy(v_hbm.at[src], v_buf.at[slot, dst],
+                                      sems.at[1, slot]))
+
+    def live(g):
+        """Pages of the g-th group up to ``hi``: all but the walk's last are
+        whole. The others are not copied."""
+        return jnp.minimum(hi - (lo + g * group), group)
+
+    def start(g, slot):
+        def page_at(j, _):
+            i = lo + g * group + j
+            for dma in copies(slot, j, table_ref[bi, i % ring if window else i]):
+                dma.start()
+
+        jax.lax.fori_loop(0, live(g), page_at, None)
+
+    def wait(g, slot):
+        def page_at(j, _):  # a wait takes its size from the copy, not its source
+            for dma in copies(slot, j, 0):
+                dma.wait()
+
+        jax.lax.fori_loop(0, live(g), page_at, None)
+
+    @pl.when(jnp.logical_and(bi == 0, ci == 0))
+    def _finite():
+        # a group's dead pages keep what the slot held: rows of the pool or,
+        # before the call's first copy, whatever VMEM held. Their columns
+        # are masked, but a probability of 0 times a NaN is a NaN
+        v_buf[...] = jnp.zeros_like(v_buf)
+
+    start(0, 0)
+    o_acc[:] = jnp.zeros_like(o_acc)
+    m_acc[:] = jnp.full_like(m_acc, _NEG)
+    l_acc[:] = jnp.zeros_like(l_acc)
+
+    # the mask, but for the group's first position: column c of a group is
+    # key (c // cols) * page + (c % cols) // kvh of kv head c % kvh, and
+    # ``base + key <= q_pos`` on the query head's own kv head is ``base <=
+    # bound`` with the bound worked out once (-1, below every base, on the
+    # other heads); the window's lower bound likewise
+    r = jax.lax.broadcasted_iota(jnp.int32, (rows, width), 0)
+    c = jax.lax.broadcasted_iota(jnp.int32, (rows, width), 1)
+    ahead = first + r // heads - ((c // cols) * page + (c % cols) // kvh)
+    own_head = (r % heads) // (heads // kvh) == c % kvh
+    bound = jnp.where(own_head, ahead, -1)
+    q = q_ref[0].astype(jnp.float32)                              # [rows, D]
+    scale = 1.0 / math.sqrt(d)
+
+    def body(g, _):
+        slot = g % 2
+
+        @pl.when(g + 1 < steps)
+        def _ahead():
+            start(g + 1, 1 - slot)
+
+        wait(g, slot)
+        k = k_buf[slot].astype(jnp.float32)                       # [width, D]
+        v = v_buf[slot].astype(jnp.float32)
+        scores = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale           # [rows, width]
+        base = (lo + g * group) * page
+        keep = base <= bound
+        if window:
+            keep = jnp.logical_and(keep, base > ahead - window)
+        scores = jnp.where(keep, scores, _NEG)
+        m = m_acc[:, :1]                                          # [rows, 1]
+        m_new = jnp.maximum(m, scores.max(axis=-1, keepdims=True))
+        p = jnp.exp(scores - m_new)
+        corr = jnp.exp(m - m_new)
+        l_acc[:] = jnp.broadcast_to(
+            l_acc[:, :1] * corr + p.sum(axis=-1, keepdims=True), l_acc.shape)
+        m_acc[:] = jnp.broadcast_to(m_new, m_acc.shape)
+        o_acc[:] = o_acc[:] * corr + jax.lax.dot_general(
+            p, v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    jax.lax.fori_loop(0, steps, body, None)
+    # every query admits at least key 0 of its own kv head (k_pos=0 <= q_pos
+    # always) and the walk starts at page 0, so m is real before any
+    # fully-masked group arrives and l is never truly zero; the floor only
+    # guards numerical underflow. Under a lower bound a group may hold no
+    # key of a query whose own key comes later in the walk: what it summed
+    # meanwhile is scaled to 0 there
+    o_ref[0] = (o_acc[:] / jnp.maximum(l_acc[:, :1], 1e-30)).astype(o_ref.dtype)
+
+
+def _paged_grid_kernel(off_ref, table_ref, q_ref, k_ref, v_ref, o_ref,
+                       o_acc, m_acc, l_acc, *, page: int, kvh: int, heads: int,
+                       tile_c: int, pages_per: int, window: int = 0):
+    """The walk by the GRID, (row, query tile, page), a page a step through a
+    BlockSpec, as every head size was served before PR 41: kept for heads of
+    no multiple of 128 lanes, which ``_paged_kernel``'s own copies cannot
+    take on a chip. A step past the tile's causal bound skips the math."""
     bi = pl.program_id(0)
     ci = pl.program_id(1)
     pi = step = pl.program_id(2)
@@ -179,9 +362,6 @@ def _paged_kernel(off_ref, table_ref, q_ref, k_ref, v_ref, o_ref,
         m_acc[:] = jnp.full_like(m_acc, _NEG)
         l_acc[:] = jnp.zeros_like(l_acc)
 
-    # folded row r is (chunk position ci*tile_c + r // heads, q head
-    # r % heads) at absolute position off + that; the tile's last attendable
-    # key is its last query's position, so later pages hold no admissible key
     first = off_ref[bi] + ci * tile_c
     max_pos = first + (tile_c - 1)
 
@@ -215,15 +395,91 @@ def _paged_kernel(off_ref, table_ref, q_ref, k_ref, v_ref, o_ref,
             preferred_element_type=jnp.float32)
 
     @pl.when(step == pages_per - 1)
-    def _fin():
-        # every query admits at least key 0 of its own kv head (k_pos=0 <=
-        # q_pos always) and page 0 is always within the bound, so m is real
-        # before any fully-masked page arrives and l is never truly zero;
-        # the floor only guards numerical underflow. Under a lower bound a
-        # page may hold no key of a query whose own key comes in a later
-        # step of the walk: what it summed meanwhile is scaled to 0 there
+    def _fin():  # l is never truly zero, as in ``_paged_kernel``
         o_ref[0] = (o_acc[:] / jnp.maximum(l_acc[:, :1], 1e-30)
                     ).astype(o_ref.dtype)
+
+
+def _walk_call(b, tiles, rows, dh, dtype, *, page, kvh, heads, tile_c, ring,
+               window):
+    """``_paged_kernel``'s kernel, grid and compiler parameters."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    group = _page_group(rows, page, kvh, dh, dtype.itemsize)
+
+    def _q_index(bi, ci, *_):
+        return (bi, ci, 0)
+
+    kernel = functools.partial(
+        _paged_kernel, page=page, kvh=kvh, heads=heads, tile_c=tile_c,
+        group=group, ring=ring, window=window)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(b, tiles),
+        in_specs=[
+            pl.BlockSpec((1, rows, dh), _q_index),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((1, rows, dh), _q_index),
+        scratch_shapes=[
+            pltpu.VMEM((2, group * page * kvh, dh), dtype),
+            pltpu.VMEM((2, group * page * kvh, dh), dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.VMEM((rows, dh), jnp.float32),
+            pltpu.VMEM((rows, 128), jnp.float32),
+            pltpu.VMEM((rows, 128), jnp.float32),
+        ],
+    )
+    # programs run one after another on one core: the slots are zeroed by
+    # the first and carry pool rows from then on (``_finite``)
+    return kernel, grid_spec, {"compiler_params": pltpu.CompilerParams(
+        dimension_semantics=("arbitrary", "arbitrary"),
+        vmem_limit_bytes=2 * _walk_budget() + (8 << 20))}
+
+
+def _grid_call(b, tiles, rows, dh, dtype, *, page, kvh, heads, tile_c, ring,
+               window):
+    """``_paged_grid_kernel``'s kernel and grid: every column of the table
+    a step (under a window: the pages from the first query's oldest key to
+    the tile's last query)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    pages_per = (window + tile_c - 2) // page + 2 if window else ring
+
+    def _page_index(bi, ci, pi, off_ref, table_ref):
+        # pages past the tile's causal bound resolve to the scratch page 0
+        # (of layer 0: never read either): the index stays constant across
+        # the remaining grid steps, so the pipeline skips the re-copy
+        max_pos = off_ref[bi] + ci * tile_c + (tile_c - 1)
+        if window:
+            pi = pi + _window_start(off_ref[bi] + ci * tile_c, window, page)
+        live = pi * page <= max_pos
+        return (jnp.where(live, table_ref[bi, pi % ring if window else pi], 0),
+                0, 0)
+
+    def _q_index(bi, ci, pi, *_):
+        return (bi, ci, 0)
+
+    kernel = functools.partial(
+        _paged_grid_kernel, page=page, kvh=kvh, heads=heads, tile_c=tile_c,
+        pages_per=pages_per, window=window)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(b, tiles, pages_per),
+        in_specs=[
+            pl.BlockSpec((1, rows, dh), _q_index),
+            pl.BlockSpec((1, page * kvh, dh), _page_index),
+            pl.BlockSpec((1, page * kvh, dh), _page_index),
+        ],
+        out_specs=pl.BlockSpec((1, rows, dh), _q_index),
+        scratch_shapes=[
+            pltpu.VMEM((rows, dh), jnp.float32),
+            pltpu.VMEM((rows, 128), jnp.float32),
+            pltpu.VMEM((rows, 128), jnp.float32),
+        ],
+    )
+    return kernel, grid_spec, {}
 
 
 #: the windowed call's name in a device trace (the full call keeps the
@@ -239,10 +495,9 @@ def paged_flash_attention(q, k_pages, v_pages, layer, page_table, off, *,
     q: [B, C, H, dh] — C queries per row at absolute positions
     ``off[b] + i`` (decode: C=1, off=lengths; chunked prefill: off=chunk
     offset). k_pages/v_pages: [layers, num_pages, page, kv_heads, dh] (the
-    WHOLE pools; ``layer`` picks the pages the kernel's index maps resolve
-    to, so the layer loop never slices a pool). page_table: [B, P] int32 —
-    entries past a row's context may be 0 (the scratch page; never read
-    through the causal mask). off: [B] int32.
+    WHOLE pools, left in HBM; ``layer`` picks the pages the kernel copies,
+    so the layer loop never slices a pool). page_table: [B, P] int32 —
+    entries past a row's context are never looked at. off: [B] int32.
 
     Query i attends keys 0..off+i — exactly the dense-gather reference's
     ``key_pos <= positions`` mask — with GQA resolved inside the kernel
@@ -253,18 +508,17 @@ def paged_flash_attention(q, k_pages, v_pages, layer, page_table, off, *,
     RING over the layer's window pool: logical page i sits in column
     ``i % columns``. A (row, query tile) walks only the pages its window
     touches, from the one that holds its first query's oldest key, and the
-    call is named ``paged_window_attention`` in a trace. 0 lowers to the
-    call as it was before there were windows.
+    call is named ``paged_window_attention`` in a trace.
+
+    A head of no multiple of 128 lanes, compiled for a chip, is walked by
+    the grid instead (``_paged_grid_kernel``): the same keys, a page a step.
     """
     b, c, h, dh = q.shape
     layers, n_pages, page, kvh, _ = k_pages.shape
     if h % kvh:
         raise ValueError(f"q heads {h} must be a multiple of kv heads {kvh}")
-    pages_per = page_table.shape[1]
     # the layer rides in the page index: the pools are viewed as one run of
-    # layers * num_pages pages and the table names pages of that run. (A
-    # layer axis of its own, the layer scalar-prefetched into the index
-    # maps, cost 20 us a 16-lane call more on a v5e: 9 ns a grid step.)
+    # layers * num_pages pages and the table names pages of that run
     table = (jnp.asarray(page_table, jnp.int32)
              + jnp.asarray(layer, jnp.int32) * n_pages)
     # query tile: the whole chunk when it is small (block == array, any C),
@@ -275,53 +529,19 @@ def paged_flash_attention(q, k_pages, v_pages, layer, page_table, off, *,
         # padded queries sit past the chunk: finite garbage, sliced off below
         q = jnp.pad(q, ((0, 0), (0, c_pad - c), (0, 0), (0, 0)))
     rows = tile_c * h
-    from jax.experimental.pallas import tpu as pltpu
-
-    ring, named = pages_per, {}
-    if window:
-        # pages from the first query's oldest key to the tile's last query
-        pages_per = (window + tile_c - 2) // page + 2
-        named = {"name": PAGED_WINDOW_NAME}
-    grid = (b, c_pad // tile_c, pages_per)
-    kernel = functools.partial(
-        _paged_kernel, page=page, kvh=kvh, heads=h, tile_c=tile_c,
-        pages_per=pages_per, window=window)
-
-    def _page_index(bi, ci, pi, off_ref, table_ref):
-        # pages past the tile's causal bound resolve to the scratch page 0
-        # (of layer 0: never read either): the index stays constant across
-        # the remaining grid steps, so the pipeline skips the re-copy, and
-        # pl.when skips the math
-        max_pos = off_ref[bi] + ci * tile_c + (tile_c - 1)
-        if window:
-            pi = pi + _window_start(off_ref[bi] + ci * tile_c, window, page)
-            return (jnp.where(pi * page <= max_pos, table_ref[bi, pi % ring], 0),
-                    0, 0)
-        return (jnp.where(pi * page <= max_pos, table_ref[bi, pi], 0), 0, 0)
-
-    def _q_index(bi, ci, pi, *_):
-        return (bi, ci, 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, rows, dh), _q_index),
-            pl.BlockSpec((1, page * kvh, dh), _page_index),
-            pl.BlockSpec((1, page * kvh, dh), _page_index),
-        ],
-        out_specs=pl.BlockSpec((1, rows, dh), _q_index),
-        scratch_shapes=[
-            pltpu.VMEM((rows, dh), jnp.float32),
-            pltpu.VMEM((rows, 128), jnp.float32),
-            pltpu.VMEM((rows, 128), jnp.float32),
-        ],
-    )
+    # who walks the table: the kernel, but for a head of no multiple of 128
+    # lanes compiled for a chip, whose pages only the grid can read
+    call = _walk_call if interpret or dh % 128 == 0 else _grid_call
+    kernel, grid_spec, params = call(
+        b, c_pad // tile_c, rows, dh, k_pages.dtype, page=page, kvh=kvh,
+        heads=h, tile_c=tile_c, ring=table.shape[1], window=window)
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, c_pad * h, dh), q.dtype),
-        interpret=interpret, **named,
+        interpret=interpret,
+        **params,
+        **({"name": PAGED_WINDOW_NAME} if window else {}),
     )(jnp.asarray(off, jnp.int32), table, q.reshape(b, c_pad * h, dh),
       k_pages.reshape(layers * n_pages, page * kvh, dh),
       v_pages.reshape(layers * n_pages, page * kvh, dh))
@@ -339,9 +559,10 @@ def paged_flash_attention(q, k_pages, v_pages, layer, page_table, off, *,
 # caller applies ``W_uv``). So the pools hold one "KV head" for all query
 # heads: a page is read ONCE and every head scores against it.
 #
-# Same grid idea as ``paged_flash_attention`` — (row, query tile, page
-# group) with the page table scalar-prefetched, the pools riding whole —
-# with three differences:
+# Here the GRID still walks the table — (row, query tile, page group) with
+# the page table scalar-prefetched, the pools riding whole, as
+# ``paged_flash_attention`` did before its kernel took the walk over
+# (ROADMAP S13) — with three differences from that kernel:
 #
 # * the layer is an axis of the pool view (``[layers, pages, page, width]``)
 #   with the layer index scalar-prefetched into the index maps;

@@ -594,6 +594,21 @@ class GenerationServer:
                              "padded positions and idle lanes a step carried "
                              "past the states")))
             for kind in ("decode", "chunk")}
+        # the per-head kernel walks a row's kept pages up to its last query
+        # and no further (ops/ragged_attention.paged_flash_attention): pages
+        # walked beside the table's columns, a layer, from lengths on the
+        # host. Their ratio is the live share of the table (a chunk's
+        # earlier query tiles stop sooner than its last, which is counted)
+        self.m_attn_walk = {} if cfg.latent or self.decode_kernel != "paged" else {
+            kind: tuple(reg.counter(metric, text, {"model": name, "kind": kind})
+                        for metric, text in (
+                            ("arkflow_gen_attn_pages_walked_total",
+                             "kept-pool pages the attention kernel's rows "
+                             "walked, a layer"),
+                            ("arkflow_gen_attn_table_columns_total",
+                             "kept page-table columns of the rows the "
+                             "attention kernel was called with, a layer")))
+            for kind in ("decode", "chunk")}
         self.m_ssm_resets = reg.counter(
             "arkflow_gen_ssm_state_resets_total",
             "slots whose recurrent state a prompt's first chunk reset",
@@ -1748,6 +1763,8 @@ class GenerationServer:
             ids[:len(chunk)] = chunk
             self._slide_window(slot, off, new_off - 1)
             packed = pack_operands(ids, off, len(chunk), self._table(slot))
+            if kind == "chunk":
+                self._note_walk("chunk", np.asarray([off + c - 1]))
             if self._stateful:
                 valid, masked = self.m_ssm["chunk"]
                 valid.inc(len(chunk))
@@ -2113,7 +2130,18 @@ class GenerationServer:
                 for s in map(int, np.flatnonzero(act)):
                     self._slide_window(s, int(lens[s]), int(lens[s]))
                 packed = pack_operands(cur, lens, act, self._table())
+                self._note_walk("decode", lens)  # a packed step is issued
         return act, packed, prev, prep.dur_s
+
+    def _note_walk(self, kind: str, last) -> None:
+        """Count the kept pages a step's rows walk: ``last`` [rows] each
+        row's last query position as the kernel is given it (an idle lane's
+        0 walks its one scratch page)."""
+        if self.m_attn_walk:
+            walked, columns = self.m_attn_walk[kind]
+            cols = self.pages_per_slot
+            walked.inc(int(np.minimum(last // self.page_size + 1, cols).sum()))
+            columns.inc(cols * len(last))
 
     def _apply_decode(self, act, nxt, reqs=None) -> None:
         """One decode step's fetched tokens (then a routed model's counters)

@@ -23,6 +23,7 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 from arkflow_tpu.ops.ragged_attention import (
+    _page_group,
     paged_flash_attention,
     ragged_flash_attention,
 )
@@ -102,8 +103,11 @@ def test_segment_flash_is_a_mosaic_kernel(v5e):
 
 # -- paged (auto-selected decode + chunked-prefill kernel on a TPU) -----------
 
-#: (kv_heads, heads, dh): Llama-3-8B head geometry, and the decoder default
-GEOMETRIES = {"llama3_8b": (8, 32, 128), "decoder_default": (4, 8, 32)}
+#: (kv_heads, heads, dh): Llama-3-8B's head geometry, the decoder default's,
+#: Llama-3.2-1B's, Phi-3-mini's and Gemma-7B's
+GEOMETRIES = {"llama3_8b": (8, 32, 128), "decoder_default": (4, 8, 32),
+              "llama32_1b": (8, 32, 64), "phi3_mini": (32, 32, 96),
+              "gemma_7b": (16, 16, 256)}
 SLOTS, PAGE, PAGES_PER, POOL_PAGES, POOL_LAYERS = 8, 16, 32, 257, 4
 
 
@@ -118,7 +122,45 @@ def _paged_shapes(geometry: str, chunk: int):
 @pytest.mark.parametrize("chunk", [1, 128], ids=["decode", "chunk128"])
 @pytest.mark.parametrize("geometry", list(GEOMETRIES))
 def test_paged_flash_compiles(v5e, geometry, chunk):
+    """Every head size compiles. A head of a multiple of 128 lanes by the
+    kernel's own walk; a narrower one (32, 64, 96) by the grid's, since the
+    chip's compiler takes no copy of its pages out of the pool ("Slice shape
+    along dimension 2 must be aligned to tiling (128)": the message the
+    kernel's walk gets there, and the reason the grid's is kept)."""
+    from arkflow_tpu.ops import ragged_attention
+
     _compile(paged_flash_attention, v5e, *_paged_shapes(geometry, chunk))
+    if GEOMETRIES[geometry][2] % 128:
+        with pytest.MonkeyPatch.context() as patch, \
+                pytest.raises(Exception, match="aligned to tiling"):
+            patch.setattr(ragged_attention, "_grid_call", ragged_attention._walk_call)
+            _compile(paged_flash_attention.__wrapped__, v5e,
+                     *_paged_shapes(geometry, chunk))
+
+
+#: the walk as the benchmark's cells serve it: (lanes, table columns, heads,
+#: kv heads, the chunk's length); the pools hold every lane's columns once
+SERVED = {"mistral_l6": (16, 136, 32, 8, 128), "mistral_tp4_local": (16, 136, 8, 2, 128),
+          "falconh1_l4": (128, 64, 20, 4, 256), "kexaone_l5_full": (48, 528, 64, 8, 512)}
+
+
+@pytest.mark.parametrize("step", ["decode", "chunk"])
+@pytest.mark.parametrize("cell", list(SERVED))
+def test_paged_flash_compiles_as_served(v5e, cell, step):
+    """The four geometries whose steps the kernel's walk carries (tp4's as
+    one chip sees it inside ``shard_map``: a quarter of the heads), a decode
+    step over every lane and one row's chunk. The window layers' ring of 41
+    columns: ``test_paged_window_attention_compiles``."""
+    lanes, table, h, kvh, chunk = SERVED[cell]
+    b, c = (lanes, 1) if step == "decode" else (1, chunk)
+    pool = ((2, 1 + lanes * table, PAGE, kvh, 128), BF16)
+    compiled = _compile(paged_flash_attention, v5e, ((b, c, h, 128), BF16),
+                        pool, pool, ((), I32), ((b, table), I32), ((b,), I32))
+    assert "tpu_custom_call" in compiled.as_text()
+    # many pages a step of the walk under a decode step's few rows, few
+    # under a chunk tile's ~1,024
+    rows = c * h if c * h <= 1024 else 1024 // h // 8 * 8 * h
+    assert 1 <= _page_group(rows, PAGE, kvh, 128, 2) <= _page_group(h, PAGE, kvh, 128, 2)
 
 
 def test_paged_flash_is_a_mosaic_kernel(v5e):

@@ -626,13 +626,14 @@ def test_judge_holds_the_float32_leaves_to_their_masters():
 
 # -- the dense path is what it was ------------------------------------------------------
 
-#: sha256 of the lowered text of the dense GQA programs as PR 32 left them
-#: (the pools carried whole through the layer scan), gather / paged kernel:
-#: ``_decode`` then ``_chunk``. A PR that changes the dense programs on
+#: sha256 of the lowered text of the dense GQA programs, ``_decode`` then
+#: ``_chunk``: gather as PR 32 left them (the pools carried whole through
+#: the layer scan), paged as PR 41 left them (the kernel walks the page table
+#: itself). A PR that changes the dense programs on
 #: purpose recomputes them with this test's code.
 DENSE_HLO = {
     "gather": ("fdea09887a70df43", "e5fbbb5138b92dea"),
-    "paged": ("9ef71f8186e0a71d", "3fc1c77221acf62b"),
+    "paged": ("1af74e9152f85a54", "34b2db03d57a9a4a"),
 }
 
 

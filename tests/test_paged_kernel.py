@@ -40,7 +40,12 @@ from arkflow_tpu.models.paged_decode import (
     paged_prefill,
     paged_prefill_chunk,
 )
-from arkflow_tpu.ops.ragged_attention import paged_flash_attention
+from arkflow_tpu.ops import ragged_attention
+from arkflow_tpu.ops.ragged_attention import (
+    _page_group,
+    _walk_budget,
+    paged_flash_attention,
+)
 from arkflow_tpu.tpu.serving import GenerationServer
 
 TINY = dict(vocab_size=128, dim=64, layers=2, heads=4, kv_heads=2, ffn=96,
@@ -180,6 +185,176 @@ def test_paged_flash_attention_ignores_stale_pages_past_bound(layer):
     assert np.isfinite(np.asarray(base)).all()
     np.testing.assert_array_equal(np.asarray(base)[0, :, :],
                                   np.asarray(poisoned)[0, :, :])
+
+
+def _walk_case(name):
+    """(c, c padded to whole query tiles, heads, offsets, table columns):
+    shapes of page 4 x 2 kv heads x 8, whose groups ``_page_group`` sizes;
+    the offsets in units of its pages."""
+    page, h = 4, 4
+    if name == "chunk_bound_crosses_a_group":
+        # 48 positions x 32 heads: tiles of 32 positions (1,024 folded rows);
+        # row 0's first tile ends a page short of the seam between its
+        # second and third groups and its second tile starts there, row 1's
+        # first tile straddles the seam
+        h, c = 32, 48
+        g = _page_group(1024, page, 2, 8, 2)
+        offs = [2 * g * page - 32 - page, 2 * g * page - 10]
+        return c, 64, h, offs, 4 * g
+    g = _page_group(h, page, 2, 8, 2)
+    assert g > 1  # a decode step's few rows take many pages a group
+    cols = 2 * g + 3
+    offs = {
+        # lanes of 1 token, exactly a group, a group + 1 page, the whole table
+        "decode_lanes_of_unequal_length": [0, g * page - 1, g * page,
+                                           cols * page - 1],
+        # a context that ends inside a page: first slot, middle, last slot
+        "context_ends_inside_a_page": [(g + 1) * page, (g + 1) * page + 2,
+                                       (g + 2) * page - 1, 2],
+    }[name]
+    return 1, 1, h, offs, cols
+
+
+def _walk_operands(name):
+    """q, the K and V pages of one layer (the last a page of NaN), the table
+    whose columns past a row's last (padded) query name that page, the same
+    table naming page 0 there, the offsets."""
+    rng = np.random.RandomState(13)
+    c, c_pad, h, offs, cols = _walk_case(name)
+    b, kvh, dh, page = len(offs), 2, 8, 4
+    n_pages = 2 + b * cols
+    q = jnp.asarray(rng.randn(b, c, h, dh), jnp.float32) * 0.5
+    kp = np.asarray(rng.randn(n_pages, page, kvh, dh) * 0.5, np.float32)
+    vp = np.asarray(rng.randn(n_pages, page, kvh, dh) * 0.5, np.float32)
+    kp[-1] = vp[-1] = np.nan
+    table = np.asarray(
+        [np.random.RandomState(i).permutation(np.arange(1, n_pages - 1))[:cols]
+         for i in range(b)], np.int32)
+    clean = table.copy()
+    for r, off in enumerate(offs):
+        table[r, (off + c_pad - 1) // page + 1:] = n_pages - 1
+        clean[r, (off + c_pad - 1) // page + 1:] = 0
+    return (q, jnp.asarray(kp, jnp.bfloat16), jnp.asarray(vp, jnp.bfloat16),
+            jnp.asarray(table), jnp.asarray(clean), jnp.asarray(offs, jnp.int32))
+
+
+WALK_CASES = ["decode_lanes_of_unequal_length", "context_ends_inside_a_page",
+              "chunk_bound_crosses_a_group"]
+
+
+@LAYERS
+@pytest.mark.parametrize("name", WALK_CASES)
+def test_paged_flash_attention_walks_live_pages_in_groups(name, layer):
+    """The kernel walks each row's pages itself, a group at a time, and
+    copies only those up to its last query: rows whose walks end at
+    different groups in one call, the last group partly dead, every column
+    past a row's bound naming a page of NaN (never copied: a NaN read there
+    would show even under the mask, through 0 x NaN)."""
+    q, kp, vp, table, clean, off = _walk_operands(name)
+    out = paged_flash_attention(q, *_in_pool(layer, kp, vp), layer, table, off,
+                                interpret=True)
+    ref = _dense_paged_reference(q, kp, vp, clean, off)
+    assert np.isfinite(np.asarray(out)).all()
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=3e-5)
+
+
+def _walked_by_the_grid(monkeypatch):
+    """``paged_flash_attention`` as a chip compiles it for a head of no
+    multiple of 128 lanes — the grid walks the table, a page a step —, here
+    interpreted: untraced, so no cached trace of the kernel's walk serves."""
+    monkeypatch.setattr(ragged_attention, "_walk_call", ragged_attention._grid_call)
+    return paged_flash_attention.__wrapped__
+
+
+@pytest.mark.parametrize("name", WALK_CASES)
+def test_the_grid_walk_of_narrow_heads_gives_the_same(monkeypatch, name):
+    """The walk a narrow head keeps on a chip (PR 41 left it the grid: the
+    chip's compiler takes no copy of its pages) against the dense reference
+    on the kernel walk's own cases; a dead column resolves to page 0 there
+    and is never read either."""
+    q, kp, vp, table, clean, off = _walk_operands(name)
+    out = _walked_by_the_grid(monkeypatch)(
+        q, *_in_pool(1, kp, vp), 1, table, off, interpret=True)
+    ref = _dense_paged_reference(q, kp, vp, clean, off)
+    assert np.isfinite(np.asarray(out)).all()
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=3e-5)
+
+
+def _paged_calls(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for v in eqn.params.values():
+            inner = getattr(v, "jaxpr", v)
+            inner = getattr(inner, "jaxpr", inner)
+            if hasattr(inner, "eqns"):
+                yield from _paged_calls(inner)
+
+
+def _paged_call(dh, **kw):
+    """The kernel call ``paged_flash_attention`` traces to at head size ``dh``."""
+    q = jnp.zeros((2, 1, 4, dh), jnp.bfloat16)
+    pool = jnp.zeros((2, 9, 16, 2, dh), jnp.bfloat16)
+    traced = jax.make_jaxpr(lambda *a: paged_flash_attention(*a, **kw))(
+        q, pool, pool, 0, jnp.zeros((2, 4), jnp.int32), jnp.zeros((2,), jnp.int32))
+    (call,) = _paged_calls(traced.jaxpr)
+    return call
+
+
+@pytest.mark.parametrize("dh,compiled,interpreted", [
+    (128, 2, 2), (256, 2, 2), (32, 3, 2), (64, 3, 2), (96, 3, 2)])
+def test_who_walks_the_table_follows_the_head_size(dh, compiled, interpreted):
+    """The kernel walks the table — a grid of (row, query tile) — wherever
+    its copies are legal: a head of a multiple of 128 lanes on a chip, any
+    head interpreted (so every CPU test above holds the kernel's walk to
+    the reference). A narrower head compiled for a chip keeps the grid of
+    (row, query tile, page); test_kernels_compile_tpu.py compiles both."""
+    assert len(_paged_call(dh).params["grid_mapping"].grid) == compiled
+    assert len(_paged_call(dh, interpret=True).params["grid_mapping"].grid) == interpreted
+
+
+def test_page_group_follows_the_rows_of_the_call():
+    """Many pages a step of the walk where a decode step folds 8-32 rows,
+    few where a chunk tile folds ~1,024; at least one whatever the shapes."""
+    decode = [_page_group(rows, 16, kvh, 128, 2)
+              for rows, kvh in ((32, 8), (8, 2), (20, 4), (64, 8))]
+    chunk = [_page_group(rows, 16, kvh, 128, 2)
+             for rows, kvh in ((1024, 8), (1024, 2), (960, 4), (1024, 8))]
+    assert all(d >= 8 for d in decode) and all(c >= 1 for c in chunk)
+    assert all(c <= d for c, d in zip(chunk, decode))
+    assert chunk[0] < decode[0]  # 1,024 rows x 128 columns a page: the budget
+    assert chunk[0] < chunk[1]   # a quarter of the kv heads: narrower pages
+    assert _page_group(4096, 64, 8, 256, 4) == 1
+
+
+@pytest.mark.parametrize("vmem_mib,budget_mib", [(128, 24.0), (64, 12.8), (16, 3.2)],
+                         ids=["v5e", "v5p", "v4"])
+def test_walk_budget_follows_the_chips_vmem(monkeypatch, vmem_mib, budget_mib):
+    """The walk's budget is a fifth of a core's VMEM, at most 24 MiB, so the
+    limit the call asks for (twice the budget and 8 MiB) fits the chip, and
+    the groups shrink with it; with no TPU (this CI) it is a v5e's."""
+    import types
+
+    from jax.experimental.pallas import tpu as pltpu
+
+    assert _walk_budget() == 24 << 20
+    at_v5e = _page_group(1024, 16, 8, 128, 2)
+    monkeypatch.setattr(pltpu, "get_tpu_info", lambda: types.SimpleNamespace(
+        vmem_capacity_bytes=vmem_mib << 20))
+    assert _walk_budget() == int(budget_mib * (1 << 20))
+    assert 2 * _walk_budget() + (8 << 20) < vmem_mib << 20
+    assert 1 <= _page_group(1024, 16, 8, 128, 2) <= at_v5e
+    assert (_page_group(1024, 16, 8, 128, 2) < at_v5e) == (vmem_mib < 128)
+
+
+def test_paged_kernel_programs_run_in_order():
+    """The call's first program zeroes the V slots and every later one
+    counts on it (a dead page's columns are masked, but 0 x NaN is NaN): the
+    grid's dimensions have to stay ``arbitrary`` — one core, in order. A
+    ``parallel`` dimension needs the dead tail zeroed a program first."""
+    for window in (0, 32):
+        params = _paged_call(128, window=window).params["compiler_params"]
+        assert params["mosaic_tpu"].dimension_semantics == ("arbitrary", "arbitrary")
 
 
 def test_ragged_flash_attention_empty_and_single_token_rows():
@@ -397,6 +572,35 @@ def test_server_paged_kernel_matches_gather():
     assert srv.health_report()["decode_kernel"] == "paged"
 
 
+def test_server_counts_the_pages_its_rows_walk():
+    """``arkflow_gen_attn_pages_walked_total`` beside ``_table_columns_total``,
+    from lengths on the host: one prompt of 13 tokens in chunks of 8, then
+    decode steps over two lanes, one of them idle (it walks its one scratch
+    page). A ``gather`` server, which walks nothing, counts nothing."""
+    cfg, params = _tiny_setup(seed=3)
+    plain = GenerationServer(params, cfg, slots=2, page_size=4, max_seq=40)
+    assert plain.m_attn_walk == {}
+    asyncio.run(plain.close())
+
+    async def go():
+        srv = GenerationServer(params, cfg, slots=2, page_size=4, max_seq=40,
+                               prefill_chunk=8, decode_kernel="paged",
+                               kernel_interpret=True)
+        before = {k: [m.value for m in pair] for k, pair in srv.m_attn_walk.items()}
+        out = await srv.generate(list(range(1, 14)), max_new_tokens=5)
+        await srv.close()
+        return out, {k: [m.value - v for m, v in zip(pair, before[k])]
+                     for k, pair in srv.m_attn_walk.items()}
+
+    out, got = asyncio.run(go())
+    assert len(out) == 5
+    cols = 10                                    # 40 positions of 4 a page
+    # the chunks' last queries sit at 7 and 15: 2 and 4 pages
+    assert got["chunk"] == [2 + 4, 2 * cols]
+    # four decode steps at lengths 13..16 (4, 4, 4, 5 pages) + the idle lane
+    assert got["decode"] == [4 + 4 + 4 + 5 + 4, 4 * 2 * cols]
+
+
 def test_server_dispatch_depth2_bitwise_identical():
     """Depth 2 pipelines decode (step N+1 dispatched before N's tokens
     reach the host) yet must emit the same greedy streams — across plain
@@ -496,6 +700,18 @@ def test_server_kernel_auto_resolution():
     _, srv = _serve(params, cfg, [[9]], 2)
     assert srv.decode_kernel == "gather"
     _, srv = _serve(params, cfg, [[9]], 2, kernel_interpret=True)
+    assert srv.decode_kernel == "paged"
+
+
+@pytest.mark.parametrize("heads", [1, 4], ids=["dh128", "dh32"])
+def test_server_kernel_auto_serves_the_kernel_on_a_tpu_at_any_head(monkeypatch, heads):
+    """On a TPU ``auto`` is the kernel whatever the model's head size: who
+    walks the table is the kernel module's own affair."""
+    fam = get_model("decoder_lm")
+    cfg = fam.make_config(**{**TINY, "dim": 128, "heads": heads, "kv_heads": 1})
+    params = fam.init(jax.random.PRNGKey(5), cfg)
+    monkeypatch.setattr(GenerationServer, "_on_tpu", lambda self: True)
+    srv = GenerationServer(params, cfg, kernel_parity_check=False)
     assert srv.decode_kernel == "paged"
 
 

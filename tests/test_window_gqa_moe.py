@@ -283,10 +283,20 @@ def test_kernel_probe_covers_the_pattern_s_kernels(params):
 @pytest.mark.parametrize("window,page,c,offs", [
     (9, 8, 1, (0, 7, 30, 101)), (9, 8, 12, (0, 5, 40, 99)),
     (16, 8, 24, (0, 16, 33, 64)), (128, 16, 40, (0, 100, 300, 1000)),
-    (128, 16, 1, (1, 127, 128, 4000))])
-def test_windowed_kernel_matches_its_plain_xla_twin(window, page, c, offs):
+    (128, 16, 1, (1, 127, 128, 4000)),
+    # the ring wraps INSIDE a group of the kernel's walk: a window of 40 over
+    # pages of 4 is a ring of 12 (decode) or 14 (a chunk of 8) columns, which
+    # a walk of 8-page groups enters at any column and leaves past the last;
+    # and a walk of more than one group, its last partly dead
+    (40, 4, 1, (3, 39, 57, 200, 1001)), (40, 4, 8, (0, 36, 95, 642, 3001)),
+    (70, 4, 1, (68, 69, 70, 333, 1702))])
+@pytest.mark.parametrize("walker", ["kernel", "grid"])
+def test_windowed_kernel_matches_its_plain_xla_twin(monkeypatch, walker, window,
+                                                    page, c, offs):
     """Rows at their start, inside their first window, past it and past the
-    ring's wrap, each ring holding only the pages a server would hold."""
+    ring's wrap, each ring holding only the pages a server would hold. By
+    the kernel's own walk, and by the grid's, which a head of no multiple of
+    128 lanes keeps on a chip (untraced: no cached trace of the other)."""
     cfg = dataclasses.replace(CFG, sliding_window=window)
     cols = window_ring_pages(cfg, page, c)
     b = len(offs)
@@ -303,18 +313,40 @@ def test_windowed_kernel_matches_its_plain_xla_twin(window, page, c, offs):
     q = rand((b, c, 4, 8))
     positions = off[:, None] + jnp.arange(c)[None, :]
     want = _attend_ring(q, kp, vp, 1, ring, positions, window)
-    got = _attend_paged(q, kp, vp, 1, ring, off, cfg, None, True, window)
+    if walker == "grid":
+        from arkflow_tpu.ops import ragged_attention
+
+        monkeypatch.setattr(ragged_attention, "_walk_call", ragged_attention._grid_call)
+        got = ragged_attention.paged_flash_attention.__wrapped__(
+            q, kp, vp, 1, ring, off, interpret=True, window=window)
+    else:
+        got = _attend_paged(q, kp, vp, 1, ring, off, cfg, None, True, window)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
 
 
+def _jaxpr_text(jaxpr) -> str:
+    """A jaxpr's text without source positions and addresses."""
+    return re.sub(r"0x[0-9a-f]+", "0x", re.sub(r" at [^\s\]]+:\d+", "", str(jaxpr)))
+
+
+def _text_hash(text: str) -> str:
+    return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+
 #: sha256 (first 16 hex) of the jaxprs (source positions stripped) of a decode
-#: step and a prefill chunk at the parent commit (PR 39), at tiny dense (the
-#: Mistral layout) and hybrid (the Falcon-H1 layout) sizes, gather then paged
+#: step and a prefill chunk at tiny dense (the Mistral layout) and hybrid (the
+#: Falcon-H1 layout) sizes: gather and paged as PR 39 left them, before there
+#: were windows, at a head of 8 — which a chip's compiler has the GRID walk,
+#: as every head was walked before PR 41 —, and ``walk`` at a head of 128, as
+#: PR 41 left it: the kernel walks the page table itself (``window`` 0 still
+#: lowers to ONE form of each)
 WINDOW_0_GOLDEN = {
     "dense.decode.gather": "48f260fdc014f61e", "dense.chunk.gather": "02b87477d5d7db5c",
     "dense.decode.paged": "64ce15284ca65c61", "dense.chunk.paged": "75e38e2a56da97f5",
     "hybrid.decode.gather": "b93168be9c2f3624", "hybrid.chunk.gather": "ddfdb61920de41cd",
-    "hybrid.decode.paged": "d314b67f4e837d21", "hybrid.chunk.paged": "31135aa3fc6bb0df"}
+    "hybrid.decode.paged": "d314b67f4e837d21", "hybrid.chunk.paged": "31135aa3fc6bb0df",
+    "dense.decode.walk": "8fb05ab0dea5dbe0", "dense.chunk.walk": "9d47f7f4d33e5f36",
+    "hybrid.decode.walk": "41b44ac90e51a17a", "hybrid.chunk.walk": "9f2f3d5814b985ff"}
 
 
 @pytest.mark.parametrize("case", sorted(WINDOW_0_GOLDEN))
@@ -328,11 +360,14 @@ def test_window_0_gives_the_present_jaxpr(case):
     if layout == "hybrid":
         sizes.update(mamba_d_ssm=32, mamba_n_heads=4, mamba_d_head=8,
                      mamba_d_state=8, mamba_n_groups=2)
+    if kern == "walk":  # a head of 128 lanes: the kernel's own walk
+        sizes.update(dim=256, heads=2, kv_heads=1)
     cfg = dec.DecoderConfig(**sizes)
     p = jax.eval_shape(lambda: dec.init(jax.random.PRNGKey(0), cfg))
     kp, vp = jax.eval_shape(lambda: init_page_pool(cfg, 9, 8, slots=2))
     table = jnp.zeros((2, 4), jnp.int32)
-    kw = dict(attention_kernel=kern, kernel_interpret=False)
+    kw = dict(attention_kernel="paged" if kern == "walk" else kern,
+              kernel_interpret=False)
     if step == "decode":
         jaxpr = jax.make_jaxpr(lambda p, k, v: paged_decode_step(
             p, cfg, jnp.zeros((2,), jnp.int32), jnp.ones((2,), jnp.int32),
@@ -342,9 +377,63 @@ def test_window_0_gives_the_present_jaxpr(case):
         jaxpr = jax.make_jaxpr(lambda p, k, v: paged_prefill_chunk(
             p, cfg, jnp.zeros((1, 8), jnp.int32), jnp.zeros((1,), jnp.int32),
             jnp.full((1,), 5, jnp.int32), table[:1], k, v, **kw, **extra))(p, kp, vp)
-    text = re.sub(r"0x[0-9a-f]+", "0x", re.sub(r" at [^\s\]]+:\d+", "", str(jaxpr)))
+    text = _jaxpr_text(jaxpr)
     assert layout == "hybrid" or text.count("scan[") == 1  # the ONE layer loop
-    assert hashlib.sha256(text.encode()).hexdigest()[:16] == WINDOW_0_GOLDEN[case]
+    assert _text_hash(text) == WINDOW_0_GOLDEN[case]
+
+
+#: the same hashes of programs that never call the per-head kernel, recorded
+#: at PR 41's parent before that kernel was touched: a tiny latent model (the
+#: kanana2_l6 layout) and a tiny latent layer pattern with indexed full layers
+#: and sliding layers (the dots3_l5 layout), through their Pallas kernels. What
+#: "their programs are unchanged" means for the cells that bypass a change to
+#: ``paged_flash_attention``.
+BYPASS_GOLDEN = {
+    "latent.decode": "3a0f9a616b1af93d", "latent.chunk": "c697262a43550287",
+    "pattern.decode": "4394292e666c84a0", "pattern.chunk": "158c1079bde200c3"}
+
+
+@pytest.mark.parametrize("case", sorted(BYPASS_GOLDEN))
+def test_latent_programs_do_not_move_with_the_per_head_kernel(case):
+    layout, step = case.split(".")
+    sizes = dict(vocab_size=64, dim=32, layers=3, heads=4, ffn=48, max_seq=64,
+                 kv_lora_rank=16, qk_nope_head_dim=8, qk_rope_head_dim=4,
+                 v_head_dim=8, rope_interleave=True, n_routed_experts=8,
+                 num_experts_per_tok=2, n_shared_experts=1,
+                 moe_intermediate_size=16, first_k_dense_replace=1)
+    window_pages = 0
+    if layout == "pattern":
+        sizes.update(layers=4, layer_types=(FULL, SLIDING, SLIDING, FULL),
+                     sliding_window=9, q_lora_rank=12, swa_heads=2,
+                     swa_q_lora_rank=12, swa_kv_lora_rank=24,
+                     swa_qk_nope_head_dim=12, swa_qk_rope_head_dim=4,
+                     swa_v_head_dim=8, swa_rope_theta=5e3, index_n_heads=4, index_head_dim=8,
+                     index_topk=16, experts_held=(4, 2))
+        window_pages = 9
+    cfg = dec.DecoderConfig(**sizes)
+    p = jax.eval_shape(lambda: dec.init(jax.random.PRNGKey(0), cfg))
+    kp, vp = jax.eval_shape(lambda: init_page_pool(cfg, 9, 8, window_pages))
+    kw = dict(attention_kernel="paged", kernel_interpret=False)
+
+    def tables(rows, step_tokens):
+        kept = jnp.zeros((rows, 4), jnp.int32)
+        if layout == "latent":
+            return kept
+        return kept, jnp.zeros((rows, window_ring_pages(cfg, 8, step_tokens)),
+                               jnp.int32)
+
+    if step == "decode":
+        jaxpr = jax.make_jaxpr(lambda p, k, v: paged_decode_step(
+            p, cfg, jnp.zeros((2,), jnp.int32), jnp.ones((2,), jnp.int32),
+            jnp.ones((2,), bool), tables(2, 1), k, v, **kw))(p, kp, vp)
+    else:
+        jaxpr = jax.make_jaxpr(lambda p, k, v: paged_prefill_chunk(
+            p, cfg, jnp.zeros((1, 8), jnp.int32), jnp.zeros((1,), jnp.int32),
+            jnp.full((1,), 5, jnp.int32), tables(1, 8), k, v, **kw))(p, kp, vp)
+    text = _jaxpr_text(jaxpr)
+    assert "mla_paged_attention" in text or layout == "pattern"
+    assert "paged_flash_attention" not in text
+    assert _text_hash(text) == BYPASS_GOLDEN[case], _text_hash(text)
 
 
 # -- the held share ---------------------------------------------------------------
