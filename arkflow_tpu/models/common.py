@@ -75,17 +75,26 @@ def gelu(x: jnp.ndarray) -> jnp.ndarray:
     return jax.nn.gelu(x, approximate=True)
 
 
-def attention(q, k, v, mask=None, *, softmax_dtype=jnp.float32):
-    """Batched multi-head attention core: [B, S, H, Dh] tensors.
+def attention(q, k, v, mask=None, *, softmax_dtype=jnp.float32, sink=None):
+    """Batched multi-head attention core: [B, S, H, Dh] tensors (values may
+    be of another width than queries and keys).
 
     Softmax in float32; matmuls in the input dtype (bfloat16) for the MXU.
-    ``mask``: broadcastable to [B, H, Sq, Sk], True = attend.
+    ``mask``: broadcastable to [B, H, Sq, Sk], True = attend. ``sink`` [H]:
+    one logit a head that joins every softmax as one more column and is
+    dropped after it: it takes probability and adds no value.
     """
     dh = q.shape[-1]
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(softmax_dtype) / math.sqrt(dh)
     if mask is not None:
         scores = jnp.where(mask, scores, jnp.finfo(softmax_dtype).min)
+    if sink is not None:
+        col = jnp.broadcast_to(sink.astype(softmax_dtype)[None, :, None, None],
+                               scores.shape[:3] + (1,))
+        scores = jnp.concatenate([scores, col], axis=-1)
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    if sink is not None:
+        probs = probs[..., :-1]
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 

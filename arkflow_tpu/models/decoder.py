@@ -56,6 +56,47 @@ class AttnSpec:
 
 
 @dataclass(frozen=True)
+class GqaSpec:
+    """What one kind of per-head K/V (GQA) layer is made of (``DecoderConfig.
+    gqa``): a full layer reads the model's own keys, a sliding layer its
+    ``swa_*`` keys where the model states any. Keys and queries are ``dk``
+    wide, values ``dv``; the first ``rotary`` values of a key are rotated at
+    base ``rope_theta`` (0: none); ``sink``: a learned logit a query head
+    joins the softmax's denominator and adds no value."""
+    kind: str
+    kv_heads: int
+    dk: int
+    dv: int
+    rope_theta: float
+    rotary: int
+    window: int = 0
+    sink: bool = False
+
+    @property
+    def shape(self) -> tuple:
+        """What decides the shape of the layer's parameter tree."""
+        return self.kv_heads, self.dk, self.dv, self.sink
+
+    @property
+    def key_parts(self) -> int:
+        """Lane-dense parts the page pools hold a key in: a key wider than
+        128 lanes and no multiple of them is held as parts of 128 (192: its
+        first 128 values, then the other 64 and 64 zeros), each part a
+        layer of the pool's own (``paged_decode.CachePool``), so that the
+        attention kernel's own walk can copy its pages on a chip
+        (``ops/ragged_attention``: a copy out of a pool takes whole
+        128-lane rows, and a [.., 4 heads, 256] pool is re-laid for every
+        call). Any other key is held as it is, in one part: a narrow head
+        padded would double its pool."""
+        return 1 if self.dk <= 128 or self.dk % 128 == 0 else -(-self.dk // 128)
+
+    @property
+    def dk_held(self) -> int:
+        """A key's width as the page pools hold it, its padding included."""
+        return self.dk if self.key_parts == 1 else self.key_parts * 128
+
+
+@dataclass(frozen=True)
 class DecoderConfig:
     vocab_size: int = 2048
     dim: int = 256
@@ -157,12 +198,13 @@ class DecoderConfig:
     index_topk: int = 0
     attention_gate_type: str = ""
     apply_mla_qkv_lora_rescale: bool = False
-    #: the size of an attention head where it is not ``dim // heads``
-    #: (per-head K/V layers only; 0: ``dim // heads``)
+    #: the size of an attention head (its queries and keys) where it is not
+    #: ``dim // heads`` (per-head K/V layers only; 0: ``dim // heads``)
     head_dim: int = 0
     # -- a layer pattern over per-head K/V (GQA) layers: ``layer_types`` and
     # ``sliding_window`` as above, every layer at the model's one set of
-    # sizes. A sliding layer attends the last ``sliding_window`` positions
+    # sizes unless the per-kind keys below state another (``gqa``). A
+    # sliding layer attends the last ``sliding_window`` positions
     # and caches only those (``paged_decode.cache_spec``: ``kv_window``
     # beside ``kv``). ``qk_norm``: an RMSNorm over each head's query and key
     # (scales of ``head_dim``, one set for queries and one for keys), before
@@ -172,6 +214,21 @@ class DecoderConfig:
     # (``n_routed_experts``: a leading dense stack, then an expert stack).
     qk_norm: bool = False
     full_attention_rope: bool = True
+    # -- per-head layers whose sizes go by kind, under the published key
+    # names (``gqa(kind)`` states them): a sliding layer has
+    # ``swa_kv_heads`` K/V heads and rotates at ``swa_rope_theta`` (0: the
+    # model's own); a value head is ``v_head_dim`` wide (``swa_v_head_dim``
+    # on a sliding layer; 0: the key's ``head_dim``); only the first
+    # ``int(head_dim * partial_rotary_factor)`` values of a query and key
+    # head are rotated; values are scaled by ``attention_value_scale``; a
+    # kind with its sink flag set has one learned float32 logit a query
+    # head (``attn_sink``) that joins the softmax and adds no value. Kinds
+    # of different shapes stack apart (``layer_runs``).
+    swa_kv_heads: int = 0
+    partial_rotary_factor: float = 1.0
+    attention_value_scale: float = 1.0
+    add_swa_attention_sink_bias: bool = False
+    add_full_attention_sink_bias: bool = False
     # -- the hybrid block (Falcon-H1), under the published key names.
     # ``mamba_d_ssm`` > 0 turns it on for EVERY layer: beside the GQA
     # attention, and fed by the same normed input, a Mamba-2 mixer
@@ -317,12 +374,15 @@ class DecoderConfig:
                 f"layer_types names each of the {self.layers} layers "
                 f"{FULL!r} or {SLIDING!r}, got {self.layer_types}")
         if not self.latent:
-            if any(extras[1:]) or any(gates) or self.apply_mla_qkv_lora_rescale:
+            if any(extras[1:]) or any(gates) or self.apply_mla_qkv_lora_rescale \
+                    or self.swa_q_lora_rank is not None \
+                    or self.swa_qk_nope_head_dim or self.swa_qk_rope_head_dim:
                 raise ConfigError(
-                    "swa_* sizes, index_*, the attention gate and the latent "
-                    "rescale belong to a latent-attention model "
-                    "(kv_lora_rank > 0): a per-head K/V model's sliding "
-                    "layers have the model's one set of sizes")
+                    "swa_heads, swa_*_lora_rank, swa_qk_*_head_dim, index_*, "
+                    "the attention gate and the latent rescale belong to a "
+                    "latent-attention model (kv_lora_rank > 0): a per-head "
+                    "K/V model's sliding layers differ by swa_kv_heads, "
+                    "swa_v_head_dim, swa_rope_theta and their sink alone")
             if (SLIDING in kinds) != (self.sliding_window > 0):
                 raise ConfigError(
                     "sliding_window > 0 and a sliding_attention layer in "
@@ -335,7 +395,17 @@ class DecoderConfig:
                     "a layer pattern over per-head K/V layers, and qk_norm, "
                     "compose with neither the hybrid block (mamba_d_ssm), "
                     "the Switch top-1 layer (num_experts) nor ring attention")
+            self._check_gqa_kinds()
             return
+        if (self.swa_kv_heads or self.partial_rotary_factor != 1.0
+                or self.attention_value_scale != 1.0
+                or self.add_swa_attention_sink_bias
+                or self.add_full_attention_sink_bias):
+            raise ConfigError(
+                "swa_kv_heads, partial_rotary_factor, attention_value_scale "
+                "and add_*_attention_sink_bias belong to a per-head K/V "
+                "model: a latent row has no K/V heads, rotates its own rope "
+                "key whole and has no sink beside it yet")
         if self.qk_norm or not self.full_attention_rope:
             raise ConfigError(
                 "qk_norm and full_attention_rope belong to a per-head K/V "
@@ -408,11 +478,83 @@ class DecoderConfig:
         (``layer_runs``: a latent model, routed experts, a layer pattern,
         per-head norms); otherwise ``layers`` is the one stack of identical
         layers, as it always was."""
-        return self.latent or self.routed or self.layered or self.qk_norm
+        return (self.latent or self.routed or self.layered or self.qk_norm
+                or self.hetero)
 
-    def window(self, kind: str) -> int:
-        """Keys a layer of ``kind`` attends below its query (0: all)."""
-        return self.sliding_window if kind == SLIDING else 0
+    def _check_gqa_kinds(self) -> None:
+        """The per-kind keys of a per-head K/V model (``gqa``)."""
+        from arkflow_tpu.errors import ConfigError
+
+        per_kind = (self.swa_kv_heads, self.swa_v_head_dim, self.swa_rope_theta,
+                    self.add_swa_attention_sink_bias)
+        if any(per_kind) and SLIDING not in self.kinds:
+            raise ConfigError(
+                "swa_kv_heads / swa_v_head_dim / swa_rope_theta / "
+                "add_swa_attention_sink_bias without a sliding_attention "
+                "layer in layer_types")
+        rotary = self.dh * self.partial_rotary_factor
+        if not 0 < self.partial_rotary_factor <= 1.0 or int(rotary) % 2:
+            raise ConfigError(
+                "partial_rotary_factor rotates the first int(head_dim * "
+                f"factor) values of a head, an even number in (0, head_dim]; "
+                f"got {self.partial_rotary_factor} of {self.dh}")
+        if self.v_head_dim < 0 or self.swa_v_head_dim < 0 \
+                or self.swa_rope_theta < 0 or self.attention_value_scale <= 0:
+            raise ConfigError(
+                "v_head_dim, swa_v_head_dim, swa_rope_theta >= 0 and "
+                "attention_value_scale > 0")
+        for kind in set(self.kinds):
+            kvh = self.gqa(kind).kv_heads
+            if kvh <= 0 or self.heads % kvh:
+                key = "swa_kv_heads" if kind == SLIDING else "kv_heads"
+                raise ConfigError(
+                    f"a {kind} layer's K/V heads ({key} {kvh}) divide the "
+                    f"{self.heads} query heads")
+        if self.hetero and (self.hybrid or self.num_experts > 1
+                            or self.use_ring_attention):
+            raise ConfigError(
+                "per-kind head sizes, a value head of its own width "
+                "(v_head_dim), partial_rotary_factor, attention_value_scale "
+                "and a sink compose with neither the hybrid block "
+                "(mamba_d_ssm), the Switch top-1 layer (num_experts) nor "
+                "ring attention")
+
+    def gqa(self, kind: str) -> "GqaSpec":
+        """The sizes of one kind of per-head K/V layer: the one place that
+        says what a sliding layer has of its own."""
+        sliding = kind == SLIDING
+        rotate = sliding or self.full_attention_rope
+        return GqaSpec(
+            kind=kind,
+            kv_heads=(sliding and self.swa_kv_heads) or self.kv_heads,
+            dk=self.dh,
+            dv=(sliding and self.swa_v_head_dim) or self.v_head_dim or self.dh,
+            rope_theta=(sliding and self.swa_rope_theta) or self.rope_theta,
+            rotary=int(self.dh * self.partial_rotary_factor) if rotate else 0,
+            window=self.sliding_window if sliding else 0,
+            sink=(self.add_swa_attention_sink_bias if sliding
+                  else self.add_full_attention_sink_bias))
+
+    @property
+    def hetero(self) -> bool:
+        """True where a per-head K/V model departs from one head size for
+        keys and values on every layer: such a model stacks by runs, and
+        kinds of different shapes in stacks of their own."""
+        if self.latent:
+            return False
+        specs = [self.gqa(kind) for kind in dict.fromkeys(self.kinds)]
+        return (any(sp.dv != sp.dk or sp.sink for sp in specs)
+                or len({sp.shape for sp in specs}) > 1
+                or self.partial_rotary_factor != 1.0
+                or self.attention_value_scale != 1.0)
+
+    @property
+    def kind_stacks(self) -> bool:
+        """True where a layer's kind decides the stack its parameters live
+        in: a latent model's kinds, and a per-head model's where their
+        shapes differ."""
+        return self.latent or len(
+            {self.gqa(kind).shape for kind in self.kinds}) > 1
 
     def attn(self, kind: str) -> "AttnSpec":
         """The sizes of one kind of latent layer, under the names the
@@ -558,13 +700,16 @@ def layer_runs(cfg: DecoderConfig) -> list:
     that stack, ``kind_first`` the index of the run's first layer among the
     layers of its kind (the cache pools' layer axis). A model without a
     pattern has ``dense_layers`` then ``layers``, each whole. A per-head K/V
-    model's layers have one shape of attention whatever their kind: they
-    stack by dense | routed alone (``layers`` the only stack without routed
-    experts), and a pattern makes runs WITHIN a stack."""
+    model whose layers have one shape of attention whatever their kind
+    stacks by dense | routed alone (``layers`` the only stack without routed
+    experts), and a pattern makes runs WITHIN a stack; where the kinds'
+    shapes differ (``kind_stacks``) each kind has stacks of its own, as a
+    latent model's."""
     runs, in_stack, of_kind = [], {}, {}
+    by_kind = cfg.kind_stacks
     for i, kind in enumerate(cfg.kinds):
         routed = cfg.routed and i >= cfg.first_k_dense_replace
-        name = _STACKS[kind if cfg.latent else FULL, routed or not cfg.routed]
+        name = _STACKS[kind if by_kind else FULL, routed or not cfg.routed]
         at, kat = in_stack.get(name, 0), of_kind.get(kind, 0)
         if runs and runs[-1][0] == name and runs[-1][3] == kind:
             runs[-1][2] = at + 1
@@ -574,23 +719,35 @@ def layer_runs(cfg: DecoderConfig) -> list:
     return [tuple(r) for r in runs]
 
 
-def _init_gqa_layer(key, cfg: DecoderConfig, routed: bool) -> dict:
+def _init_gqa_layer(key, cfg: DecoderConfig, routed: bool,
+                    kind: str = FULL) -> dict:
     """One layer of a per-head K/V model that stacks by runs (routed experts
     or a layer pattern): GQA projections (HF names: q_proj, k_proj, v_proj,
-    o_proj; q_norm / k_norm over a head with ``qk_norm``), the same for a
-    full and a sliding layer, and a dense SwiGLU or the routed experts."""
+    o_proj; q_norm / k_norm over a head with ``qk_norm``) at the sizes of
+    its ``kind`` (``cfg.gqa``), the kind's sink logits where it has them,
+    and a dense SwiGLU or the routed experts."""
     k = iter(jax.random.split(key, 12))
-    dh = cfg.dh
+    sp = cfg.gqa(kind)
     layer = {
         "attn_norm": cm.rms_norm_init(cfg.dim),
-        "wq": cm.dense_init(next(k), cfg.dim, cfg.heads * dh, bias=False),
-        "wk": cm.dense_init(next(k), cfg.dim, cfg.kv_heads * dh, bias=False),
-        "wv": cm.dense_init(next(k), cfg.dim, cfg.kv_heads * dh, bias=False),
-        "wo": cm.dense_init(next(k), cfg.heads * dh, cfg.dim, bias=False),
+        "wq": cm.dense_init(next(k), cfg.dim, cfg.heads * sp.dk, bias=False),
+        "wk": cm.dense_init(next(k), cfg.dim, sp.kv_heads * sp.dk, bias=False),
+        "wv": cm.dense_init(next(k), cfg.dim, sp.kv_heads * sp.dv, bias=False),
+        "wo": cm.dense_init(next(k), cfg.heads * sp.dv, cfg.dim, bias=False),
         "mlp_norm": cm.rms_norm_init(cfg.dim),
     }
     if cfg.qk_norm:
-        layer.update(q_head_norm=cm.rms_norm_init(dh), k_head_norm=cm.rms_norm_init(dh))
+        layer.update(q_head_norm=cm.rms_norm_init(sp.dk),
+                     k_head_norm=cm.rms_norm_init(sp.dk))
+    if sp.sink:
+        # a trained sink is no zero: it takes what a window's keys do not
+        # claim. normal(2, 1), of the order of a window's largest score: a
+        # few percent to a third of a head's probability beside 128 seeded
+        # keys (at normal(0, 1) it takes 1 % and leaving it out moves no
+        # token: PERF.md, PR 42), from a key of its own (the leaves above
+        # keep their values)
+        layer["attn_sink"] = 2.0 + jax.random.normal(
+            jax.random.fold_in(key, 200), (cfg.heads,), jnp.float32)
     layer.update(_init_ffn(k, cfg, routed))
     return layer
 
@@ -607,7 +764,7 @@ def _init_runs(rng, cfg: DecoderConfig) -> dict:
     for name, first, stop, kind, routed, _ in layer_runs(cfg):
         stacks.setdefault(name, []).extend(
             _init_latent_layer(next(keys), cfg, routed, kind) if cfg.latent
-            else _init_gqa_layer(next(keys), cfg, routed)
+            else _init_gqa_layer(next(keys), cfg, routed, kind)
             for _ in range(first, stop))
     for name, stack in stacks.items():
         params[name] = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *stack)
@@ -1098,54 +1255,64 @@ def _attention_block(lp: dict, x: jnp.ndarray, cfg: DecoderConfig, positions,
     ``ring_attn`` substitutes the sp-ring kernel for plain masked attention.
     Used by forward() and the pipeline-parallel stage apply — one source of
     truth for the layer math. A sliding layer (``kind``) attends the last
-    ``sliding_window`` positions under ``causal``."""
+    ``sliding_window`` positions under ``causal``, at its kind's sizes."""
     b, s = positions.shape
-    dh = cfg.dh
-    group = cfg.heads // cfg.kv_heads
+    sp = cfg.gqa(kind)
+    group = cfg.heads // sp.kv_heads
     y = cm.rms_norm(lp["attn_norm"], x, cfg.norm_eps)
-    q, k, v = qkv_project(lp, y, cfg)
+    q, k, v = qkv_project(lp, y, cfg, kind)
     q, k = qk_positioned(lp, q, k, cfg, positions, kind)
-    if cfg.window(kind):
+    if sp.window:
         causal = causal & (positions[:, None, None, :]
-                           > positions[:, None, :, None] - cfg.window(kind))
+                           > positions[:, None, :, None] - sp.window)
     k = jnp.repeat(k, group, axis=2)
     v = jnp.repeat(v, group, axis=2)
     if ring_attn is not None:
         attn = ring_attn(q, k, v)
     else:
-        attn = cm.attention(q, k, v, causal)
-    out = _scaled(cm.dense(lp["wo"], attn.reshape(b, s, cfg.heads * dh)),
+        attn = cm.attention(q, k, v, causal, sink=lp.get("attn_sink"))
+    out = _scaled(cm.dense(lp["wo"], attn.reshape(b, s, cfg.heads * sp.dv)),
                   cfg.attention_out_multiplier)
     if cfg.hybrid:  # the parallel mixer: one norm feeds both, one residual
         out = out + _mixer_block(lp, y, cfg)
     return x + out
 
 
-def qkv_project(lp: dict, y: jnp.ndarray, cfg: DecoderConfig):
-    """Per-head queries [B, S, H, dh], keys and values [B, S, KV, dh] of
-    normed activations ``y`` [B, S, dim], before the rotary embedding: the
-    block's input and its keys times their multipliers where the model
-    states any."""
+def qkv_project(lp: dict, y: jnp.ndarray, cfg: DecoderConfig, kind: str = FULL):
+    """Per-head queries [B, S, H, dk], keys [B, S, KV, dk] and values [B, S,
+    KV, dv] of normed activations ``y`` [B, S, dim] at the sizes of the
+    layer's ``kind``, before the rotary embedding: the block's input and
+    its keys times their multipliers where the model states any, the values
+    times ``attention_value_scale``."""
     b, s = y.shape[:2]
+    sp = cfg.gqa(kind)
     y = _scaled(y, cfg.attention_in_multiplier)
-    q = cm.dense(lp["wq"], y).reshape(b, s, cfg.heads, cfg.dh)
+    q = cm.dense(lp["wq"], y).reshape(b, s, cfg.heads, sp.dk)
     k = _scaled(cm.dense(lp["wk"], y), cfg.key_multiplier).reshape(
-        b, s, cfg.kv_heads, cfg.dh)
-    return q, k, cm.dense(lp["wv"], y).reshape(b, s, cfg.kv_heads, cfg.dh)
+        b, s, sp.kv_heads, sp.dk)
+    v = _scaled(cm.dense(lp["wv"], y), cfg.attention_value_scale)
+    return q, k, v.reshape(b, s, sp.kv_heads, sp.dv)
 
 
 def qk_positioned(lp: dict, q, k, cfg: DecoderConfig, positions, kind: str = FULL):
-    """A per-head layer's queries and keys [B, S, heads, dh] as attention
+    """A per-head layer's queries and keys [B, S, heads, dk] as attention
     scores them: each head normed where the model has ``qk_norm`` (float32
     statistics, the layer's own scales), then the rotary embedding at
-    ``positions`` — on every layer, or with ``full_attention_rope`` false on
+    ``positions`` and the kind's base over the first ``rotary`` values of a
+    head (all of them without ``partial_rotary_factor``; the rest pass as
+    they are) — on every layer, or with ``full_attention_rope`` false on
     the sliding layers only."""
+    sp = cfg.gqa(kind)
     if cfg.qk_norm:
         q = cm.rms_norm(lp["q_head_norm"], q, cfg.norm_eps)
         k = cm.rms_norm(lp["k_head_norm"], k, cfg.norm_eps)
-    if kind == SLIDING or cfg.full_attention_rope:
-        q = _rope(q, positions, cfg.rope_theta)
-        k = _rope(k, positions, cfg.rope_theta)
+    if sp.rotary == sp.dk:
+        q = _rope(q, positions, sp.rope_theta)
+        k = _rope(k, positions, sp.rope_theta)
+    elif sp.rotary:
+        q, k = (jnp.concatenate(
+            [_rope(t[..., :sp.rotary], positions, sp.rope_theta),
+             t[..., sp.rotary:]], axis=-1) for t in (q, k))
     return q, k
 
 
@@ -1431,7 +1598,7 @@ def _serve_dtypes_runs(cfg: DecoderConfig) -> dict:
         "lm_head": {"w": bf16},
     }
     for name, _, _, kind, routed, _ in layer_runs(cfg):
-        sp = cfg.attn(kind)
+        sp = cfg.attn(kind)  # a per-head model's reads no latent extra
         layer = {"attn_norm": {"scale": f32}, "wq": {"w": bf16},
                  "wo": {"w": bf16}, "mlp_norm": {"scale": f32}}
         if cfg.latent:
@@ -1441,6 +1608,8 @@ def _serve_dtypes_runs(cfg: DecoderConfig) -> dict:
             layer.update(wk={"w": bf16}, wv={"w": bf16})
             if cfg.qk_norm:
                 layer.update(q_head_norm={"scale": f32}, k_head_norm={"scale": f32})
+            if cfg.gqa(kind).sink:
+                layer["attn_sink"] = f32
         if sp.q_lora_rank:
             layer.update(wq_a={"w": bf16}, q_norm={"scale": f32})
         if sp.gate:
@@ -1469,8 +1638,10 @@ def _no_latent(cfg: DecoderConfig, what: str) -> None:
             f"{what} runs one stack of identical dense layers over a "
             "contiguous cache: a per-head K/V model with routed experts "
             "(n_routed_experts), a layer pattern (layer_types: window "
-            "pages beside kept pages) or qk_norm generates through "
-            "serving: continuous")
+            "pages beside kept pages), qk_norm, or head sizes by kind "
+            "(swa_kv_heads, v_head_dim, partial_rotary_factor, "
+            "attention_value_scale, a sink) generates through serving: "
+            "continuous")
     if cfg.hybrid:
         from arkflow_tpu.errors import ConfigError
 

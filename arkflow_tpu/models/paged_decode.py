@@ -23,9 +23,11 @@ latent rows, which live only while the window covers them — in a pool and
 under a page table of their own. A per-head model with a layer pattern has two:
 ``kv`` rows of its full layers, kept, and ``kv_window`` rows of its sliding
 layers, live while the window covers them (a pool and a ring of pages of
-their own, as the latent window pool). The MLP half is dense SwiGLU, the
-Switch top-1 layer, or dropless routed experts (``cfg.routed``), on either
-kind of attention.
+their own, as the latent window pool) — each pool at its kind's K/V heads
+and widths, which may differ (``DecoderConfig.gqa``), a key wider than 128
+lanes and no multiple of them held in parts of 128. The MLP half is dense
+SwiGLU, the Switch top-1 layer, or dropless routed experts (``cfg.routed``),
+on either kind of attention.
 
 The reference has no serving layer at all (its python processor is
 user-code); this implements the engine the `tpu_generate` processor's
@@ -63,13 +65,26 @@ class CachePool:
     and for how many tokens a row stays live (``window`` 0: for the
     request's life). A row is a TOKEN'S, addressed by (page, offset) — or,
     ``per_slot``, a SEQUENCE'S: one row a serving slot, overwritten by every
-    token, addressed by slot."""
+    token, addressed by slot. ``heads`` > 0: each array has a head axis of
+    so many heads before its width's last (per-head K and V), and the
+    first array (K) is held in ``key_parts`` parts of equal width, each a
+    layer of the array's own (``GqaSpec.key_parts``: layer ``l``'s part
+    ``p`` is the array's layer ``p * layers + l``, so part 0 is indexed as
+    V is)."""
     name: str
     layers: int
     widths: tuple
     window: int = 0
     itemsizes: tuple = ()
     per_slot: bool = False
+    heads: int = 0
+    key_parts: int = 1
+
+    def shapes(self, pages: int, page_size: int) -> list:
+        """The shapes of a per-head pool's K and V over ``pages`` pages."""
+        return [(self.layers * parts, pages, page_size, self.heads,
+                 width // self.heads // parts)
+                for width, parts in zip(self.widths, (self.key_parts, 1))]
 
     @property
     def _row_bytes(self) -> int:
@@ -93,9 +108,11 @@ def cache_spec(cfg: DecoderConfig) -> tuple:
     """The kinds of row the model caches — the one place that states them:
 
     - ``kv``: per-head K and V (GQA), every layer — with a layer pattern
-      the full layers only;
-    - ``kv_window``: a per-head sliding layer's K and V, live for
-      ``sliding_window`` tokens: its pages are freed as the window passes;
+      the full layers only —, at the full layers' sizes (``cfg.gqa``: K/V
+      heads x the key's width AS HELD, x the value's);
+    - ``kv_window``: a per-head sliding layer's K and V at the sliding
+      layers' sizes, live for ``sliding_window`` tokens: its pages are
+      freed as the window passes;
     - ``latent``: a full latent layer's normed latent row and rotated rope
       key, one each a token for ALL heads;
     - ``index``: an indexed full layer's index key (the indexer scores it
@@ -106,11 +123,16 @@ def cache_spec(cfg: DecoderConfig) -> tuple:
       matrices and the conv's last ``d_conv - 1`` inputs —, one row a
       SEQUENCE whatever its length, beside that layer's ``kv`` rows."""
     if not cfg.latent:
-        kv = cfg.kv_heads * cfg.dh
         full, swa = (cfg.kinds.count(k) for k in (FULL, SLIDING))
-        pools = (CachePool("kv", full, (kv, kv)),)
+
+        def widths(sp):
+            return dict(widths=(sp.kv_heads * sp.dk_held, sp.kv_heads * sp.dv),
+                        heads=sp.kv_heads, key_parts=sp.key_parts)
+
+        pools = (CachePool("kv", full, **widths(cfg.gqa(FULL))),)
         if swa:
-            pools += (CachePool("kv_window", swa, (kv, kv), cfg.sliding_window),)
+            pools += (CachePool("kv_window", swa, window=cfg.sliding_window,
+                                **widths(cfg.gqa(SLIDING))),)
         if cfg.hybrid:
             pools += (CachePool(
                 "ssm", cfg.layers,
@@ -134,7 +156,9 @@ def init_page_pool(cfg: DecoderConfig, num_pages: int, page_size: int,
     """The page pools of ``cache_spec``, bf16, as the two values every step
     carries (and donates):
 
-    - per-head K/V (GQA): K and V, each [layers, num_pages, page, kv_heads, dh];
+    - per-head K/V (GQA): K and V, each [layers, num_pages, page, kv_heads,
+      its width a head] (``CachePool.shapes``; a key held in parts has a
+      layer a part);
     - latent (MLA): the normed latent rows [layers, num_pages, page,
       kv_lora_rank] and the rotated rope keys [layers, num_pages, page,
       qk_rope_head_dim], one row each per token for ALL heads (no head axis:
@@ -146,7 +170,7 @@ def init_page_pool(cfg: DecoderConfig, num_pages: int, page_size: int,
     - a per-head model with a layer pattern: two dicts by pool name —
       ``{"kv": K, "kv_window": K}`` and the same of V — ``kv`` over the full
       layers and ``num_pages``, ``kv_window`` over the sliding layers and
-      ``window_pages`` pages of its own;
+      ``window_pages`` pages of its own, each at its kind's heads and widths;
     - a hybrid model: two dicts by pool name — ``{"kv": K, "ssm": the
       states}`` and ``{"kv": V, "ssm": the conv windows}`` — the states
       float32 [layers, slots + 1, heads, d_state, d_head] (``ops/ssm_scan``
@@ -167,16 +191,14 @@ def init_page_pool(cfg: DecoderConfig, num_pages: int, page_size: int,
         shape = (cfg.layers, num_pages, page_size)
         return (jnp.zeros(shape + (cfg.kv_lora_rank,), jnp.bfloat16),
                 jnp.zeros(shape + (cfg.qk_rope_head_dim,), jnp.bfloat16))
-    dh = cfg.dh
+    spec = cache_spec(cfg)
     if cfg.layered:
-        pools = tuple({
-            pool.name: jnp.zeros(
-                (pool.layers, window_pages if pool.window else num_pages,
-                 page_size, cfg.kv_heads, dh), jnp.bfloat16)
-            for pool in cache_spec(cfg)} for _ in range(2))
-        return pools
-    shape = (cfg.layers, num_pages, page_size, cfg.kv_heads, dh)
-    k, v = jnp.zeros(shape, jnp.bfloat16), jnp.zeros(shape, jnp.bfloat16)
+        return tuple({pool.name: jnp.zeros(
+            pool.shapes(window_pages if pool.window else num_pages,
+                        page_size)[i], jnp.bfloat16)
+            for pool in spec} for i in range(2))
+    k, v = (jnp.zeros(shape, jnp.bfloat16)
+            for shape in spec[0].shapes(num_pages, page_size))
     if not cfg.hybrid:
         return k, v
     rows = (cfg.layers, slots + 1)
@@ -630,7 +652,6 @@ def gqa_kernel_probe(params: dict, cfg: DecoderConfig, page_size: int,
     keys = iter(jax.random.split(jax.random.PRNGKey(1234), 32))
     rand = lambda shape: jax.random.normal(  # noqa: E731
         next(keys), shape, jnp.float32).astype(jnp.bfloat16)
-    kvh, dh, group = cfg.kv_heads, cfg.dh, cfg.heads // cfg.kv_heads
     n0 = page_size + 1
     pages_per = -(-(n0 + 3) // page_size)
     table = jnp.stack([jnp.arange(1, 2 * pages_per, 2)[::-1],
@@ -639,32 +660,71 @@ def gqa_kernel_probe(params: dict, cfg: DecoderConfig, page_size: int,
     ctx = pages_per * page_size
     steps = (("decode", 1), ("chunk", 2))
     out = []
-    kp, vp = (rand((1, 1 + 2 * pages_per, page_size, kvh, dh)) for _ in range(2))
+
+    def operands(kind, pages):
+        """A kind's sizes, its seeded pools (a held key's padding is seeded
+        too: the queries' zeros must hide it) and its first layer's sinks."""
+        sp = cfg.gqa(kind)
+        stack = params[next(r[0] for r in layer_runs(cfg) if r[3] == kind)]
+        sink = stack["attn_sink"][0] if sp.sink else None
+        return (sp, rand((sp.key_parts, pages, page_size, sp.kv_heads,
+                          sp.dk_held // sp.key_parts)),
+                rand((1, pages, page_size, sp.kv_heads, sp.dv)), sink)
+
+    sp, kp, vp, sink = operands(FULL, 1 + 2 * pages_per)
+    group = cfg.heads // sp.kv_heads
     for name, c in steps:
-        q = rand((2, c, cfg.heads, dh))
+        q = rand((2, c, cfg.heads, sp.dk))
         positions = off[:, None] + jnp.arange(c)[None, :]
         mask = jnp.arange(ctx)[None, None, None, :] <= positions[:, None, :, None]
-        k, v = (jnp.repeat(p[0, table].reshape(2, ctx, kvh, dh), group, axis=2)
-                for p in (kp, vp))
-        out.append((f"paged_attention_{name}", cm.attention(q, k, v, mask),
+        k = jnp.repeat(_read_keys(kp, 0, table, sp.dk), group, axis=2)
+        v = jnp.repeat(vp[0, table].reshape(2, ctx, sp.kv_heads, -1), group, axis=2)
+        out.append((f"paged_attention_{name}",
+                    cm.attention(q, k, v, mask, sink=sink),
                     _attend_paged(q, kp, vp, 0, table, off, cfg, None,
-                                  kernel_interpret)))
+                                  kernel_interpret, sink=sink)))
     if SLIDING in cfg.kinds:
         window = cfg.sliding_window
         cols = window_ring_pages(cfg, page_size, 2)
         ring = (1 + jnp.arange(2 * cols, dtype=jnp.int32)).reshape(2, cols)
-        kp, vp = (rand((1, 1 + 2 * cols, page_size, kvh, dh)) for _ in range(2))
+        sp, kp, vp, sink = operands(SLIDING, 1 + 2 * cols)
         woff = jnp.asarray([cols * page_size + window // 2, 3], jnp.int32)
         for name, c in steps:
-            q = rand((2, c, cfg.heads, dh))
+            q = rand((2, c, cfg.heads, sp.dk))
             positions = woff[:, None] + jnp.arange(c)[None, :]
             out.append((f"paged_window_attention_{name}",
-                        _attend_ring(q, kp, vp, 0, ring, positions, window),
+                        _attend_ring(q, kp, vp, 0, ring, positions, window, sink),
                         _attend_paged(q, kp, vp, 0, ring, woff, cfg, None,
-                                      kernel_interpret, window)))
+                                      kernel_interpret, window, sink)))
     if cfg.routed:
         out.append(_expert_probe(params, cfg, keys, rand, kernel_interpret))
     return out
+
+
+def _write_keys(kp, k, layer, pi, po, parts: int):
+    """The K pool with the step's keys ``k`` [B, S, kv heads, dk] written
+    at (``layer``, ``pi``, ``po``): whole, or — a key held in ``parts``
+    (``GqaSpec.key_parts``) — padded with zeros to whole parts, part ``p``
+    into the pool's layer ``p * layers + layer``."""
+    if parts == 1:
+        return kp.at[layer, pi, po].set(k.astype(kp.dtype))
+    w, layers = kp.shape[-1], kp.shape[0] // parts
+    k = jnp.pad(k, ((0, 0),) * 3 + ((0, parts * w - k.shape[-1]),)).astype(kp.dtype)
+    for p in range(parts):
+        kp = kp.at[p * layers + layer, pi, po].set(k[..., p * w:(p + 1) * w])
+    return kp
+
+
+def _read_keys(kp, layer, table, dk: int):
+    """A layer's keys [B, columns * page, kv heads, dk] gathered through
+    ``table`` [B, columns], a key held in parts joined and cut to ``dk``."""
+    parts = -(-dk // kp.shape[-1])
+    b, cols = table.shape
+    k = jnp.concatenate(
+        [kp[p * (kp.shape[0] // parts) + layer, table] for p in range(parts)],
+        axis=-1) if parts > 1 else kp[layer, table]
+    k = k.reshape(b, cols * kp.shape[2], kp.shape[3], -1)
+    return k if k.shape[-1] == dk else k[..., :dk]
 
 
 def _constrain(x, sharding):
@@ -680,14 +740,15 @@ def _constrain(x, sharding):
 
 def _attend_paged(q, k_pages, v_pages, layer, page_table, off,
                   cfg: DecoderConfig, kv_sharding, interpret: bool,
-                  window: int = 0):
+                  window: int = 0, sink=None):
     """Page-table-indirect flash attention over layer ``layer`` of the
     WHOLE pools (ops/ragged_attention.paged_flash_attention): query i of
     row b sits at absolute position ``off[b] + i`` and attends keys
     0..off+i, read straight from the pools — neither the layer's slice nor
     the [B, ctx, heads, dh] gather+repeat the dense reference materializes
     per layer per step ever exists. ``window`` > 0: a sliding layer, the
-    pools its window pools and ``page_table`` the rows' rings.
+    pools its window pools and ``page_table`` the rows' rings. ``sink``
+    [heads]: the layer's sink logits, if its kind has them.
 
     Under tensor parallelism the kernel runs inside ``shard_map`` over the
     ``kv_sharding`` mesh's tp axis: attention is independent per KV head,
@@ -699,7 +760,8 @@ def _attend_paged(q, k_pages, v_pages, layer, page_table, off,
 
     if kv_sharding is None:
         return paged_flash_attention(q, k_pages, v_pages, layer, page_table,
-                                     off, interpret=interpret, window=window)
+                                     off, interpret=interpret, window=window,
+                                     sink=sink)
     from jax.sharding import PartitionSpec as P
 
     mesh = kv_sharding.mesh
@@ -713,26 +775,28 @@ def _attend_paged(q, k_pages, v_pages, layer, page_table, off,
     )(q, k_pages, v_pages, layer, page_table, off)
 
 
-def _attend_ring(q, k_pages, v_pages, layer, ring, positions, window: int):
+def _attend_ring(q, k_pages, v_pages, layer, ring, positions, window: int,
+                 sink=None):
     """The plain-XLA form of a per-head sliding layer's attention over its
     window pool (what ``paged_flash_attention(window=)`` is held to): the
     row's ring of pages gathered out of the layer, each column's positions
     worked out from the step's last query (column j holds the newest
     logical page i <= the last with i % columns == j), masked to
     ``t - window < s <= t``. Pages the window has passed were freed and may
-    be another row's by now: the bound hides them."""
+    be another row's by now: the bound hides them. Keys held in parts are
+    joined (``_read_keys``); ``sink`` [heads] joins the softmax."""
     b, cols = ring.shape
-    page, kvh, dh = k_pages.shape[2:]
+    page, kvh = k_pages.shape[2:4]
     last = positions[:, -1] // page                                # [B]
     logical = last[:, None] - (last[:, None] - jnp.arange(cols)[None, :]) % cols
     key_pos = (logical[:, :, None] * page + jnp.arange(page)).reshape(b, -1)
-    k = k_pages[layer, ring].reshape(b, cols * page, kvh, dh).astype(q.dtype)
-    v = v_pages[layer, ring].reshape(b, cols * page, kvh, dh).astype(q.dtype)
+    k = _read_keys(k_pages, layer, ring, q.shape[-1]).astype(q.dtype)
+    v = v_pages[layer, ring].reshape(b, cols * page, kvh, -1).astype(q.dtype)
     qp, kpos = positions[:, None, :, None], key_pos[:, None, None, :]
     mask = (kpos <= qp) & (kpos > qp - window) & (kpos >= 0)
     group = q.shape[2] // kvh
     return cm.attention(q, jnp.repeat(k, group, axis=2),
-                        jnp.repeat(v, group, axis=2), mask)
+                        jnp.repeat(v, group, axis=2), mask, sink=sink)
 
 
 def _mixer_paged(lp: dict, y, cfg: DecoderConfig, states, windows, layer, rows,
@@ -806,8 +870,6 @@ def _dense_layers(params: dict, cfg: DecoderConfig, x, k_pages, v_pages,
     Returns (x, k_pages, v_pages) and, from a routed model, the step's
     counters (``moe_step_stats``)."""
     b, t = positions.shape
-    dh = cfg.dh
-    group = cfg.heads // cfg.kv_heads
     kernel = attention_kernel == "paged"
     kept, ring = page_table if isinstance(page_table, tuple) else (page_table, None)
     page = jax.tree_util.tree_leaves(k_pages)[0].shape[2]
@@ -817,7 +879,8 @@ def _dense_layers(params: dict, cfg: DecoderConfig, x, k_pages, v_pages,
         where[SLIDING] = _write_coords(ring, positions, token_mask, page, ring=True)
 
     def make_layer(routed: bool, kind: str, experts):
-        window = cfg.window(kind)
+        sp = cfg.gqa(kind)
+        window, group = sp.window, cfg.heads // sp.kv_heads
         name = "kv_window" if window else "kv"
         pi, po = where[kind]
 
@@ -830,25 +893,27 @@ def _dense_layers(params: dict, cfg: DecoderConfig, x, k_pages, v_pages,
             elif cfg.layered:
                 kp, vp = kp[name], vp[name]
             y = cm.rms_norm(lp["attn_norm"], x, cfg.norm_eps)
-            q, k, v = qkv_project(lp, y, cfg)
+            q, k, v = qkv_project(lp, y, cfg, kind)
             q, k = qk_positioned(lp, q, k, cfg, positions, kind)
-            kp = _constrain(kp.at[li, pi, po].set(k.astype(kp.dtype)),
+            sink = lp.get("attn_sink")
+            kp = _constrain(_write_keys(kp, k, li, pi, po, sp.key_parts),
                             kv_sharding)
             vp = _constrain(vp.at[li, pi, po].set(v.astype(vp.dtype)),
                             kv_sharding)
             if kernel and not block:
                 attn = _attend_paged(q, kp, vp, li, ring if window else kept,
                                      off, cfg, kv_sharding, kernel_interpret,
-                                     window)
+                                     window, sink)
             elif window:
-                attn = _attend_ring(q, kp, vp, li, ring, positions, window)
+                attn = _attend_ring(q, kp, vp, li, ring, positions, window, sink)
             else:
                 if not block:
-                    k = kp[li, kept].reshape(b, ctx, cfg.kv_heads, dh).astype(x.dtype)
-                    v = vp[li, kept].reshape(b, ctx, cfg.kv_heads, dh).astype(x.dtype)
+                    k = _read_keys(kp, li, kept, sp.dk).astype(x.dtype)
+                    v = vp[li, kept].reshape(
+                        b, ctx, sp.kv_heads, sp.dv).astype(x.dtype)
                 attn = cm.attention(q, jnp.repeat(k, group, axis=2),
-                                    jnp.repeat(v, group, axis=2), mask)
-            out = _scaled(cm.dense(lp["wo"], attn.reshape(b, t, cfg.heads * dh)),
+                                    jnp.repeat(v, group, axis=2), mask, sink=sink)
+            out = _scaled(cm.dense(lp["wo"], attn.reshape(b, t, cfg.heads * sp.dv)),
                           cfg.attention_out_multiplier)
             if cfg.hybrid:
                 mixed, states, windows = _mixer_paged(

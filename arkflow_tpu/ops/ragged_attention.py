@@ -201,9 +201,11 @@ def _walk_budget() -> int:
 _PAGED_GROUP_MAX = 16
 
 
-def _page_group(rows: int, page: int, kvh: int, dh: int, itemsize: int) -> int:
+def _page_group(rows: int, page: int, kvh: int, dh: int, itemsize: int,
+                dv: int = 0) -> int:
     """Pages a program takes per step of its walk, from the shapes it is
-    called with. A page costs VMEM in two places: its K and V rows — twice
+    called with (``dh`` the keys' width as held, ``dv`` the values' where it
+    is another). A page costs VMEM in two places: its K and V rows — twice
     (two slots) in the pools' type, once more in float32 for the products —
     and its ``page * kvh`` columns of every [rows, columns] float32 tile the
     softmax holds at once (scores, probabilities, the mask's bounds: four).
@@ -212,7 +214,7 @@ def _page_group(rows: int, page: int, kvh: int, dh: int, itemsize: int) -> int:
     ten pages of 128 columns (8 kv heads), sixteen of 64 or of 32 (under a
     v5e's budget; fewer where ``_walk_budget`` is smaller)."""
     cols = page * kvh
-    a_page = cols * (dh * (4 * itemsize + 8) + rows * 16)
+    a_page = cols * ((dh + (dv or dh)) // 2 * (4 * itemsize + 8) + rows * 16)
     return max(1, min(_PAGED_GROUP_MAX, _walk_budget() // a_page))
 
 
@@ -222,12 +224,15 @@ def _window_start(first, window: int, cols: int):
     return jnp.maximum(first - (window - 1), 0) // cols
 
 
-def _paged_kernel(off_ref, table_ref, q_ref, k_hbm, v_hbm, o_ref,
-                  k_buf, v_buf, sems, o_acc, m_acc, l_acc, *, page: int,
+def _paged_kernel(off_ref, table_ref, q_ref, *rest, page: int,
                   kvh: int, heads: int, tile_c: int, group: int, ring: int,
-                  window: int = 0):
+                  window: int = 0, scale: float = 0.0, sink: bool = False,
+                  parts: int = 1, part_stride: int = 0):
     from jax.experimental.pallas import tpu as pltpu
 
+    if sink:  # [rows, 1] float32: each folded row's head's sink logit
+        sink_ref, *rest = rest
+    k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, o_acc, m_acc, l_acc = rest
     bi = pl.program_id(0)
     ci = pl.program_id(1)
     rows, d = q_ref.shape[1], q_ref.shape[2]
@@ -249,9 +254,14 @@ def _paged_kernel(off_ref, table_ref, q_ref, k_hbm, v_hbm, o_ref,
 
     def copies(slot, j, src):
         """The K and the V copy of page ``src`` of the pool into ``slot``, as
-        the ``j``-th page of a group."""
+        the ``j``-th page of a group (a key held in parts: a copy a part,
+        the parts ``part_stride`` pages apart, into slots 2p + slot)."""
         dst = pl.ds(pl.multiple_of(j * cols, cols), cols)
-        return (pltpu.make_async_copy(k_hbm.at[src], k_buf.at[slot, dst],
+        return (*(pltpu.make_async_copy(k_hbm.at[src + p * part_stride],
+                                        k_buf.at[2 * p + slot, dst],
+                                        sems.at[0, slot])
+                  for p in range(1, parts)),
+                pltpu.make_async_copy(k_hbm.at[src], k_buf.at[slot, dst],
                                       sems.at[0, slot]),
                 pltpu.make_async_copy(v_hbm.at[src], v_buf.at[slot, dst],
                                       sems.at[1, slot]))
@@ -285,8 +295,14 @@ def _paged_kernel(off_ref, table_ref, q_ref, k_hbm, v_hbm, o_ref,
 
     start(0, 0)
     o_acc[:] = jnp.zeros_like(o_acc)
-    m_acc[:] = jnp.full_like(m_acc, _NEG)
-    l_acc[:] = jnp.zeros_like(l_acc)
+    if sink:
+        # the sink is one more key with score b_h and no value: the running
+        # maximum starts at it, the denominator at exp(b_h - b_h) = 1
+        m_acc[:] = jnp.broadcast_to(sink_ref[...], m_acc.shape)
+        l_acc[:] = jnp.ones_like(l_acc)
+    else:
+        m_acc[:] = jnp.full_like(m_acc, _NEG)
+        l_acc[:] = jnp.zeros_like(l_acc)
 
     # the mask, but for the group's first position: column c of a group is
     # key (c // cols) * page + (c % cols) // kvh of kv head c % kvh, and
@@ -299,7 +315,7 @@ def _paged_kernel(off_ref, table_ref, q_ref, k_hbm, v_hbm, o_ref,
     own_head = (r % heads) // (heads // kvh) == c % kvh
     bound = jnp.where(own_head, ahead, -1)
     q = q_ref[0].astype(jnp.float32)                              # [rows, D]
-    scale = 1.0 / math.sqrt(d)
+    scale = scale or 1.0 / math.sqrt(d)
 
     def body(g, _):
         slot = g % 2
@@ -311,9 +327,16 @@ def _paged_kernel(off_ref, table_ref, q_ref, k_hbm, v_hbm, o_ref,
         wait(g, slot)
         k = k_buf[slot].astype(jnp.float32)                       # [width, D]
         v = v_buf[slot].astype(jnp.float32)
-        scores = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale           # [rows, width]
+        if parts == 1:
+            scores = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale       # [rows, width]
+        else:  # a key in parts: the products of each part's lanes, summed
+            w = k.shape[1]
+            scores = sum(jax.lax.dot_general(
+                q[:, p * w:(p + 1) * w], k_buf[2 * p + slot].astype(jnp.float32),
+                (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+                for p in range(parts)) * scale
         base = (lo + g * group) * page
         keep = base <= bound
         if window:
@@ -401,32 +424,39 @@ def _paged_grid_kernel(off_ref, table_ref, q_ref, k_ref, v_ref, o_ref,
 
 
 def _walk_call(b, tiles, rows, dh, dtype, *, page, kvh, heads, tile_c, ring,
-               window):
-    """``_paged_kernel``'s kernel, grid and compiler parameters."""
+               window, dv=0, scale=0.0, sink=False, parts=1, part_stride=0):
+    """``_paged_kernel``'s kernel, grid and compiler parameters (``dh`` the
+    keys' width as held, in ``parts`` parts ``part_stride`` pages apart;
+    ``dv`` the values' where it is another; ``scale`` the scores' where the
+    keys are held wider than they are; ``sink``: one more operand, each
+    folded row's sink logit)."""
     from jax.experimental.pallas import tpu as pltpu
 
-    group = _page_group(rows, page, kvh, dh, dtype.itemsize)
+    group = _page_group(rows, page, kvh, dh, dtype.itemsize, dv)
+    dv = dv or dh
 
     def _q_index(bi, ci, *_):
         return (bi, ci, 0)
 
     kernel = functools.partial(
         _paged_kernel, page=page, kvh=kvh, heads=heads, tile_c=tile_c,
-        group=group, ring=ring, window=window)
+        group=group, ring=ring, window=window, scale=scale, sink=sink,
+        parts=parts, part_stride=part_stride)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, tiles),
         in_specs=[
             pl.BlockSpec((1, rows, dh), _q_index),
+            *([pl.BlockSpec((rows, 1), lambda *_: (0, 0))] if sink else []),
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, rows, dh), _q_index),
+        out_specs=pl.BlockSpec((1, rows, dv), _q_index),
         scratch_shapes=[
-            pltpu.VMEM((2, group * page * kvh, dh), dtype),
-            pltpu.VMEM((2, group * page * kvh, dh), dtype),
+            pltpu.VMEM((2 * parts, group * page * kvh, dh // parts), dtype),
+            pltpu.VMEM((2, group * page * kvh, dv), dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
-            pltpu.VMEM((rows, dh), jnp.float32),
+            pltpu.VMEM((rows, dv), jnp.float32),
             pltpu.VMEM((rows, 128), jnp.float32),
             pltpu.VMEM((rows, 128), jnp.float32),
         ],
@@ -489,7 +519,7 @@ PAGED_WINDOW_NAME = "paged_window_attention"
 
 @functools.partial(jax.jit, static_argnames=("interpret", "window"))
 def paged_flash_attention(q, k_pages, v_pages, layer, page_table, off, *,
-                          interpret: bool = False, window: int = 0):
+                          interpret: bool = False, window: int = 0, sink=None):
     """Flash attention that reads K/V straight from the serving page pools.
 
     q: [B, C, H, dh] — C queries per row at absolute positions
@@ -510,11 +540,25 @@ def paged_flash_attention(q, k_pages, v_pages, layer, page_table, off, *,
     touches, from the one that holds its first query's oldest key, and the
     call is named ``paged_window_attention`` in a trace.
 
-    A head of no multiple of 128 lanes, compiled for a chip, is walked by
-    the grid instead (``_paged_grid_kernel``): the same keys, a page a step.
+    The values may be of another width than the keys (the output is the
+    values' wide), and a key may be HELD in parts (``GqaSpec.key_parts``):
+    ``k_pages`` is then [parts * layers, ..., 128], layer ``l``'s part ``p``
+    at ``p * layers + l``, the last part padded with zeros, so that the walk
+    below can copy its pages in whole lanes. The queries are padded
+    likewise here and the scores scaled by the queries' own width. ``sink``
+    [H] float32: one logit a query head
+    that joins every softmax and adds no value (the online softmax starts
+    at it: maximum ``sink``, denominator 1, sum 0).
+
+    A head of no multiple of 128 lanes as held, compiled for a chip, is
+    walked by the grid instead (``_paged_grid_kernel``): the same keys, a
+    page a step, for keys and values of one width and no sink.
     """
-    b, c, h, dh = q.shape
-    layers, n_pages, page, kvh, _ = k_pages.shape
+    b, c, h, dq = q.shape
+    layers, n_pages, page, kvh, dh = k_pages.shape
+    dv = v_pages.shape[-1]
+    parts = -(-dq // dh)
+    dh = parts * dh  # a key as held: its parts side by side
     if h % kvh:
         raise ValueError(f"q heads {h} must be a multiple of kv heads {kvh}")
     # the layer rides in the page index: the pools are viewed as one run of
@@ -525,27 +569,40 @@ def paged_flash_attention(q, k_pages, v_pages, layer, page_table, off, *,
     # else a multiple of 8 positions so the block's row count tiles
     tile_c = c if c * h <= _PAGED_ROWS else max(8, _PAGED_ROWS // h // 8 * 8)
     c_pad = -(-c // tile_c) * tile_c
-    if c_pad != c:
-        # padded queries sit past the chunk: finite garbage, sliced off below
-        q = jnp.pad(q, ((0, 0), (0, c_pad - c), (0, 0), (0, 0)))
+    if c_pad != c or dq != dh:
+        # padded queries sit past the chunk: finite garbage, sliced off
+        # below; padded lanes meet a held key's zeros
+        q = jnp.pad(q, ((0, 0), (0, c_pad - c), (0, 0), (0, dh - dq)))
     rows = tile_c * h
     # who walks the table: the kernel, but for a head of no multiple of 128
     # lanes compiled for a chip, whose pages only the grid can read
-    call = _walk_call if interpret or dh % 128 == 0 else _grid_call
+    plain = dv == dh == dq and sink is None
+    walk = interpret or (dh % 128 == 0 and dv % 128 == 0)
+    if not (walk or plain):
+        raise ValueError(
+            "a sink, and keys and values of different widths, are served "
+            "by the kernel's own walk: on a chip, keys (as held) and values "
+            f"of multiples of 128 lanes; got keys {dh}, values {dv}")
+    call = _walk_call if walk else _grid_call
     kernel, grid_spec, params = call(
         b, c_pad // tile_c, rows, dh, k_pages.dtype, page=page, kvh=kvh,
-        heads=h, tile_c=tile_c, ring=table.shape[1], window=window)
+        heads=h, tile_c=tile_c, ring=table.shape[1], window=window,
+        **({} if plain else dict(dv=dv, scale=dq ** -0.5, sink=sink is not None,
+                                 parts=parts,
+                                 part_stride=layers // parts * n_pages)))
+    sinks = [] if sink is None else [
+        jnp.tile(sink.astype(jnp.float32), tile_c)[:, None]]
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, c_pad * h, dh), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, c_pad * h, dv), q.dtype),
         interpret=interpret,
         **params,
         **({"name": PAGED_WINDOW_NAME} if window else {}),
-    )(jnp.asarray(off, jnp.int32), table, q.reshape(b, c_pad * h, dh),
-      k_pages.reshape(layers * n_pages, page * kvh, dh),
-      v_pages.reshape(layers * n_pages, page * kvh, dh))
-    return out.reshape(b, c_pad, h, dh)[:, :c]
+    )(jnp.asarray(off, jnp.int32), table, q.reshape(b, c_pad * h, dh), *sinks,
+      k_pages.reshape(layers * n_pages, page * kvh, dh // parts),
+      v_pages.reshape(v_pages.shape[0] * n_pages, page * kvh, dv))
+    return out.reshape(b, c_pad, h, dv)[:, :c]
 
 
 # -- latent (MLA) paged attention ---------------------------------------------

@@ -171,21 +171,24 @@ class TpuGenerateProcessor(Processor):
                     "and a latent (MLA) page has one shared row per token "
                     "(remove mesh)")
         if getattr(self.cfg, "by_runs", False) and not self.cfg.latent:
-            # a per-head K/V model with routed experts or a layer pattern
+            # a per-head K/V model with routed experts, a layer pattern
             # (layer_types / sliding_window: window pages beside kept pages)
+            # or head sizes by kind (swa_kv_heads, v_head_dim, a sink, ...)
             if serving != "continuous":
                 raise ConfigError(
                     "a per-head K/V model with routed experts "
-                    "(n_routed_experts) or a layer pattern (layer_types) "
+                    "(n_routed_experts), a layer pattern (layer_types) or "
+                    "head sizes by kind (swa_kv_heads, v_head_dim, "
+                    "partial_rotary_factor, attention_value_scale, a sink) "
                     "generates through serving: continuous only: the batch "
                     "path runs one stack of dense layers over a contiguous "
-                    "cache")
+                    "cache of keys and values of one width")
             if mesh_config:
                 raise ConfigError(
-                    "a per-head K/V model with routed experts or a layer "
-                    "pattern is served on one chip: its expert stack and "
-                    "its window pool have no sharding over a mesh yet "
-                    "(remove mesh)")
+                    "a per-head K/V model with routed experts, a layer "
+                    "pattern or head sizes by kind is served on one chip: "
+                    "its expert stack, its window pool and its stacks by "
+                    "kind have no sharding over a mesh yet (remove mesh)")
         if getattr(self.cfg, "hybrid", False):
             # before the host init too
             if serving != "continuous":
@@ -292,10 +295,12 @@ class TpuGenerateProcessor(Processor):
             #: (MLA) page has no wire format yet: no adapter is offered,
             #: and the server's export / adopt calls raise ConfigError
             # (nor has a recurrent state)
-            # (nor has a window pool's ring of live pages)
+            # (nor has a window pool's ring of live pages, nor K and V of
+            # different widths)
             if not (getattr(self.cfg, "latent", False)
                     or getattr(self.cfg, "hybrid", False)
-                    or getattr(self.cfg, "layered", False)):
+                    or getattr(self.cfg, "layered", False)
+                    or getattr(self.cfg, "hetero", False)):
                 self.disagg = self
 
         reg = global_registry()
@@ -520,7 +525,8 @@ def _build(config: dict, resource: Resource) -> TpuGenerateProcessor:
                 raise ConfigError(
                     f"tpu_generate: {key} is not supported for a "
                     "latent-attention model, nor for a per-head K/V model "
-                    "with routed experts or a layer pattern, yet (its drain "
+                    "with routed experts, a layer pattern or head sizes by "
+                    "kind, yet (its drain "
                     "/ flip / pool reset and golden forward are unverified "
                     "for latent and window pages); remove the key")
         return proc

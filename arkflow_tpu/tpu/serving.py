@@ -259,10 +259,11 @@ class GenerationServer:
                 "on one chip (no mesh)")
         if cfg.by_runs and mesh is not None:
             raise ConfigError(
-                "a per-head K/V model with routed experts or a layer pattern "
-                f"(pools {self._pool_names()}) is served on one chip: its "
-                "expert stack and its window pool have no sharding over a "
-                "mesh yet (remove mesh)")
+                "a per-head K/V model with routed experts, a layer pattern "
+                f"or head sizes by kind (pools {self._pool_names()}) is "
+                "served on one chip: its expert stack, its window pool and "
+                "its stacks by kind have no sharding over a mesh yet "
+                "(remove mesh)")
         # a layer pattern: rows of more than one kind and lifetime (the
         # model's ``cache_spec``). Window rows live in a pool of their own,
         # each slot's pages in a ring that a step's queries fit in
@@ -608,6 +609,18 @@ class GenerationServer:
                             ("arkflow_gen_attn_table_columns_total",
                              "kept page-table columns of the rows the "
                              "attention kernel was called with, a layer")))
+            for kind in ("decode", "chunk")}
+        # a sink joins the softmax of every query of its kind's layers: the
+        # rows (queries x layers of a kind with a sink) that went through
+        # one, from lengths on the host (padding and idle lanes not counted)
+        self._sink_layers = 0 if cfg.latent else sum(
+            cfg.gqa(kind).sink for kind in cfg.kinds)
+        self.m_sink_rows = {} if not self._sink_layers else {
+            kind: reg.counter(
+                "arkflow_gen_attn_sink_rows_total",
+                "query rows (queries x layers with a sink) whose softmax "
+                "had a sink logit beside its keys",
+                {"model": name, "kind": kind})
             for kind in ("decode", "chunk")}
         self.m_ssm_resets = reg.counter(
             "arkflow_gen_ssm_state_resets_total",
@@ -1339,6 +1352,13 @@ class GenerationServer:
                 "kv_heads axis; a latent (MLA) page has no head axis and no "
                 "wire format yet — a latent-attention model prefills and "
                 "decodes on the same server")
+        if self.cfg.hetero:
+            raise ConfigError(
+                f"{what} ships K and V page slabs of one shape; this model's "
+                f"pools ({self._pool_names()}) hold keys and values of "
+                "different widths (v_head_dim; a key held in parts) or "
+                "sizes by kind, which have no wire form yet — such a model "
+                "prefills and decodes on the same server")
         if self._layered:
             raise ConfigError(
                 f"{what} ships the pages of ONE kept pool; a layer pattern's "
@@ -1764,7 +1784,7 @@ class GenerationServer:
             self._slide_window(slot, off, new_off - 1)
             packed = pack_operands(ids, off, len(chunk), self._table(slot))
             if kind == "chunk":
-                self._note_walk("chunk", np.asarray([off + c - 1]))
+                self._note_walk("chunk", np.asarray([off + c - 1]), len(chunk))
             if self._stateful:
                 valid, masked = self.m_ssm["chunk"]
                 valid.inc(len(chunk))
@@ -2130,13 +2150,18 @@ class GenerationServer:
                 for s in map(int, np.flatnonzero(act)):
                     self._slide_window(s, int(lens[s]), int(lens[s]))
                 packed = pack_operands(cur, lens, act, self._table())
-                self._note_walk("decode", lens)  # a packed step is issued
+                # a packed step is issued
+                self._note_walk("decode", lens, int(act.sum()))
         return act, packed, prev, prep.dur_s
 
-    def _note_walk(self, kind: str, last) -> None:
+    def _note_walk(self, kind: str, last, queries: int) -> None:
         """Count the kept pages a step's rows walk: ``last`` [rows] each
         row's last query position as the kernel is given it (an idle lane's
-        0 walks its one scratch page)."""
+        0 walks its one scratch page). ``queries``: the step's real queries
+        (active lanes, a chunk's unpadded positions), each a row of every
+        layer with a sink."""
+        if self.m_sink_rows:
+            self.m_sink_rows[kind].inc(queries * self._sink_layers)
         if self.m_attn_walk:
             walked, columns = self.m_attn_walk[kind]
             cols = self.pages_per_slot

@@ -610,3 +610,109 @@ def test_pattern_steps_carry_pools_and_stacks_whole(v5e):
                      "moe_expert_swiglu"):
             assert name in text, name
         assert step.memory_analysis().temp_size_in_bytes < 300 * 1024 * 1024
+
+
+# -- layers whose head sizes go by kind (MiMo-V2.5: mimo_l7) ------------------
+
+MIMO = dict(vocab_size=19072, dim=4096, layers=7, heads=64, kv_heads=4,
+            swa_kv_heads=8, head_dim=192, v_head_dim=128, swa_v_head_dim=128,
+            ffn=16384, max_seq=1048576, rope_theta=1e7, swa_rope_theta=1e4,
+            partial_rotary_factor=0.334, attention_value_scale=0.707,
+            add_swa_attention_sink_bias=True, norm_eps=1e-5,
+            layer_types=("full_attention",) + ("sliding_attention",) * 4
+            + ("full_attention", "sliding_attention"),
+            sliding_window=128, n_routed_experts=256, experts_held=(0, 16),
+            num_experts_per_tok=8, n_shared_experts=0,
+            moe_intermediate_size=2048, first_k_dense_replace=1)
+#: 64 slots x 832 kept pages + scratch; 64 rings of 41 window pages + scratch
+MIMO_SLOTS, MIMO_TABLE, MIMO_RING = 64, 832, 41
+
+
+@pytest.mark.parametrize("rows,chunk", [(64, 1), (1, 512)], ids=["decode", "chunk512"])
+@pytest.mark.parametrize("kind", ["full", "sliding"])
+def test_hetero_paged_attention_compiles(v5e, kind, rows, chunk):
+    """The kernel's own walk at the cell's shapes: keys of 192 held in two
+    parts of 128 lanes (a pool layer a part), values of 128, 16 query heads
+    a K/V head over 832 kept columns on a full layer, 8 over the 41-column
+    ring with the sink on a sliding one. No pool is re-laid for the call
+    (a [.., 4 heads, 256] key pool would be: 131 MB of temporaries at 2,001
+    pages, its whole size)."""
+    kvh, table, window = (4, MIMO_TABLE, 0) if kind == "full" else (8, MIMO_RING, 128)
+    pages = 1 + MIMO_SLOTS * table
+    compiled = _compile(
+        lambda q, kp, vp, layer, t, off, sink: paged_flash_attention(
+            q, kp, vp, layer, t, off, window=window,
+            sink=sink if window else None),
+        v5e, ((rows, chunk, 64, 192), BF16), ((2 * 2, pages, PAGE, kvh, 128), BF16),
+        ((2, pages, PAGE, kvh, 128), BF16), ((), I32), ((rows, table), I32),
+        ((rows,), I32), ((64,), jnp.float32))
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert ("paged_window_attention" in text) == bool(window)
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 * 1024 * 1024
+
+
+def test_hetero_keys_of_192_lanes_are_not_walked_as_one_part(v5e):
+    """Why a 192-wide key is held in parts: as one row of 192 (or of 256
+    under 4 K/V heads) the kernel's own walk cannot copy its pages."""
+    pool = ((2, 257, PAGE, 4, 192), BF16)
+    with pytest.raises(Exception, match="different widths|aligned to tiling"):
+        _compile(paged_flash_attention, v5e, ((8, 1, 64, 192), BF16), pool,
+                 ((2, 257, PAGE, 4, 128), BF16), ((), I32), ((8, 32), I32),
+                 ((8,), I32))
+
+
+def test_hetero_steps_carry_pools_and_stacks_whole(v5e):
+    """The ``_decode`` (64 lanes) and ``_chunk`` (1 x 512) programs of the
+    MiMo-V2.5 cut as the server jits them, pools donated: all three kernels'
+    names are in the text, and the temporaries (104 MB decode, 105 MB
+    chunk) stay under 200 MB — no pool (5.2 GB kept) and no run's experts
+    (0.8 GB a layer) is copied for a step; what is left is a layer's
+    attention weights re-laid for their products inside the loop (the
+    sliding stack is two runs read out of it by index: ROADMAP R4)."""
+    from arkflow_tpu.models import decoder as dec
+    from arkflow_tpu.models.paged_decode import (init_page_pool, paged_decode_step,
+                                                 paged_prefill_chunk)
+
+    cfg = dec.DecoderConfig(**MIMO)
+    repl = SingleDeviceSharding(v5e[0])
+
+    def struct(a, dtype=None):
+        return jax.ShapeDtypeStruct(a.shape, dtype or a.dtype, sharding=repl)
+
+    params = jax.tree_util.tree_map(
+        struct, jax.eval_shape(lambda: dec.init(jax.random.PRNGKey(0), cfg)),
+        dec.serve_dtypes(cfg))
+    kp, vp = jax.tree_util.tree_map(struct, jax.eval_shape(
+        lambda: init_page_pool(cfg, 1 + MIMO_SLOTS * MIMO_TABLE, PAGE,
+                               1 + MIMO_SLOTS * MIMO_RING)))
+    assert kp["kv"].shape == (2 * 2, 1 + 64 * 832, 16, 4, 128)
+    assert vp["kv_window"].shape == (5, 1 + 64 * 41, 16, 8, 128)
+    kern = dict(attention_kernel="paged")
+
+    def decode(p, tok, lens, act, kept, ring, kp, vp):
+        return paged_decode_step(p, cfg, tok, lens, act, (kept, ring), kp, vp,
+                                 return_logits=True, **kern)
+
+    def chunk(p, ids, off, clen, kept, ring, kp, vp):
+        return paged_prefill_chunk(p, cfg, ids, off, clen, (kept, ring), kp, vp,
+                                   **kern)
+
+    def compiled(fn, *operands):
+        n = len(operands)
+        return jax.jit(fn, donate_argnums=(n + 1, n + 2)).lower(
+            params, *[jax.ShapeDtypeStruct(s, d, sharding=repl)
+                      for s, d in operands], kp, vp).compile()
+
+    s = MIMO_SLOTS
+    for step in (
+            compiled(decode, ((s,), I32), ((s,), I32), ((s,), jnp.bool_),
+                     ((s, MIMO_TABLE), I32), ((s, MIMO_RING), I32)),
+            compiled(chunk, ((1, 512), I32), ((1,), I32), ((1,), I32),
+                     ((1, MIMO_TABLE), I32), ((1, MIMO_RING), I32))):
+        text = step.as_text()
+        for name in ("paged_window_attention", "paged_flash_attention",
+                     "moe_expert_swiglu"):
+            assert name in text, name
+        assert step.memory_analysis().temp_size_in_bytes < 200 * 1024 * 1024, \
+            step.memory_analysis().temp_size_in_bytes
