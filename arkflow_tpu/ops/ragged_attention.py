@@ -156,15 +156,33 @@ def ragged_flash_attention(q, k, v, lengths, *, causal: bool = False,
 # page. The pool rides whole and is viewed as [layers*num_pages,
 # page*kv_heads, dh] — a free bitcast of its HBM layout at a head of 128
 # lanes, rows ordered (slot, kv head), the table offset to the layer's
-# pages so the layer loop never slices it — and the queries as
-# [B, C*heads, dh], rows ordered
-# (position, head): both views are plain reshapes, and every block's last
-# two dims equal the array's or are (8k, dh). One [rows, dh] x
-# [dh, G*page*kv_heads] product scores every query head against every kv
-# head of the group's pages; entries whose kv head is not the query head's
-# own are masked with the causal bound. That is no more MXU or VPU work
-# than per-head [.., page]-wide products, which would fill only page/128 of
-# each lane tile, and GQA needs no ``jnp.repeat`` of K/V.
+# pages so the layer loop never slices it. (The lane-dense view [..,
+# page, kv_heads*dh] is NOT free: XLA keeps the pool tiled over (kv head,
+# dh) and re-lays all of it for that view; and a bfloat16 pool packs two
+# kv heads' rows into one word, so no copy can take one head's rows. A
+# group's pages therefore land in VMEM as the pool holds them.)
+#
+# Two cuts of one algorithm, chosen by ``per_kv_head`` from the call's
+# shapes. A DECODE step's tile has ``heads / kv_heads`` rows a K/V head —
+# 4 to 16, under or at one float32 sublane tile — so its queries are folded
+# [B, heads, dh], ONE [rows, dh] x [dh, G*page*kv_heads] product scores
+# every query head against every kv head of the group's pages, and entries
+# whose kv head is not the row's own are masked with the causal bound: the
+# product is small beside the copies, and per-head products of 4-16 rows
+# measured the same on a v5e (PERF.md, PR 42). A CHUNK's tile has a
+# sublane tile's multiple of rows a K/V head, and there the masked product
+# IS the call: three columns of four, or seven of eight, multiplied,
+# exponentiated and reduced for nothing (3,667 us a 512-token call at
+# offset 4,096 on MiMo's full layers against 1,614 a head at a time,
+# PERF.md PR 43). So a chunk's queries are folded (kv head, position,
+# query head of the group) by the wrapper, the group's K and V are cast
+# once into float32 scratch (a strided read of VMEM takes 32-bit rows of
+# 128 lanes, not bfloat16's packed ones), and each K/V head's rows are read
+# out of it with a sublane stride and multiplied by that head's query rows
+# alone: [rows / kv_heads, dh] x [dh, G*page], nothing masked across heads,
+# the online softmax a head, its sums carried by the walk's loop (in VMEM
+# scratch, read and written a step, a call cost 20-25 % more). GQA needs
+# no ``jnp.repeat`` of K/V either way.
 
 #: folded query rows (positions x heads) per program: bounds VMEM whatever
 #: the chunk length is ([rows, 128] f32 score tiles of 512 KiB)
@@ -200,22 +218,67 @@ def _walk_budget() -> int:
 #: sixteen (l6, 16 lanes: 236 us a call at 4, 178 at 8, 155 at 16, 161 at 32)
 _PAGED_GROUP_MAX = 16
 
+#: and a chunk tile's, a K/V head at a time, where a page adds only ``page``
+#: columns a head: a 512-token call at offset 4,096 / 11,776 on MiMo's full
+#: layers 1,926 / 5,019 us at 16, 1,614 / 4,154 at 32, 1,669 / 4,022 at 64;
+#: at 2,048 on K-EXAONE's 989, 961, 1,068; at 512 on l6 104, 116, 116 (the
+#: walk's last group is multiplied whole, its dead pages too: PERF.md, PR 43)
+_PAGED_CHUNK_GROUP_MAX = 32
+
+
+def query_tile(c: int, heads: int) -> int:
+    """Positions of a call's ``c`` a (row, query tile) program takes: the
+    whole chunk when it is small (block == array, any C), else a multiple of
+    8 positions so the block's row count tiles."""
+    return c if c * heads <= _PAGED_ROWS else max(8, _PAGED_ROWS // heads // 8 * 8)
+
+
+def kernel_walks(dh: int, dv: int, interpret: bool) -> bool:
+    """Who walks the page table: the kernel (``_paged_kernel``), but for a
+    head of no multiple of 128 lanes as held, compiled for a chip, whose
+    pages only the grid can read (``_paged_grid_kernel``)."""
+    return interpret or (dh % 128 == 0 and dv % 128 == 0)
+
+
+def per_kv_head(tile_c: int, heads: int, kvh: int) -> bool:
+    """Whether a query tile's two products are made a K/V head at a time,
+    over that head's own query rows (a chunk), or once for all heads with
+    the other heads' columns masked (a decode step): per head where a head's
+    rows of the tile, ``tile_c`` positions x its query heads, are whole
+    float32 sublane tiles. The ONE predicate: the kernel's walk cuts its
+    tile by it and the server counts its programs by it (``tpu/serving.py``:
+    ``arkflow_gen_attn_tiles_total``); the grid's walk is all heads at once."""
+    return tile_c > 1 and tile_c * (heads // kvh) % 8 == 0
+
 
 def _page_group(rows: int, page: int, kvh: int, dh: int, itemsize: int,
-                dv: int = 0) -> int:
+                dv: int = 0, per_head: bool = False) -> int:
     """Pages a program takes per step of its walk, from the shapes it is
     called with (``dh`` the keys' width as held, ``dv`` the values' where it
     is another). A page costs VMEM in two places: its K and V rows — twice
     (two slots) in the pools' type, once more in float32 for the products —
-    and its ``page * kvh`` columns of every [rows, columns] float32 tile the
-    softmax holds at once (scores, probabilities, the mask's bounds: four).
-    A decode step's 8-64 folded rows leave room for many pages, so its
-    groups stop at ``_PAGED_GROUP_MAX``; a chunk tile of 1,024 rows takes
-    ten pages of 128 columns (8 kv heads), sixteen of 64 or of 32 (under a
-    v5e's budget; fewer where ``_walk_budget`` is smaller)."""
+    and its columns of every [rows, columns] float32 tile the softmax holds
+    at once (scores, probabilities, the mask's bounds: four). All heads at
+    once, a page is ``page * kvh`` columns under all ``rows``: a decode
+    step's 8-64 folded rows leave room for many pages, so its groups stop at
+    ``_PAGED_GROUP_MAX``. A K/V head at a time (``per_head``, a chunk tile)
+    it is ``page`` columns under that head's ``rows / kvh`` rows, and the
+    head's K and V rows once more as read out of the scratch."""
     cols = page * kvh
-    a_page = cols * ((dh + (dv or dh)) // 2 * (4 * itemsize + 8) + rows * 16)
+    kv = (dh + (dv or dh)) // 2
+    if per_head:
+        a_page = (cols * kv * (4 * itemsize + 8) + page * kv * 8
+                  + page * (rows // kvh) * 16)
+        return max(1, min(_PAGED_CHUNK_GROUP_MAX, _walk_budget() // a_page))
+    a_page = cols * (kv * (4 * itemsize + 8) + rows * 16)
     return max(1, min(_PAGED_GROUP_MAX, _walk_budget() // a_page))
+
+
+def _lane_runs(width: int) -> list[tuple[int, int]]:
+    """``width`` lanes as the runs a strided read of VMEM takes: of 128
+    (the chip's; a narrower or ragged width, interpreted only, whole)."""
+    run = 128 if width % 128 == 0 else width
+    return [(a, a + run) for a in range(0, width, run)]
 
 
 def _window_start(first, window: int, cols: int):
@@ -227,12 +290,19 @@ def _window_start(first, window: int, cols: int):
 def _paged_kernel(off_ref, table_ref, q_ref, *rest, page: int,
                   kvh: int, heads: int, tile_c: int, group: int, ring: int,
                   window: int = 0, scale: float = 0.0, sink: bool = False,
-                  parts: int = 1, part_stride: int = 0):
+                  parts: int = 1, part_stride: int = 0, per_head: bool = False):
     from jax.experimental.pallas import tpu as pltpu
 
     if sink:  # [rows, 1] float32: each folded row's head's sink logit
         sink_ref, *rest = rest
-    k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, o_acc, m_acc, l_acc = rest
+    if per_head:
+        # the group's K and V in float32, rows as held, a scratch a run of
+        # 128 lanes; the accumulators ride the walk's loop, not VMEM scratch
+        k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, *floats = rest
+        k_lanes = _lane_runs(q_ref.shape[2] // parts) * parts
+        kf_bufs, vf_bufs = floats[:len(k_lanes)], floats[len(k_lanes):]
+    else:
+        k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, o_acc, m_acc, l_acc = rest
     bi = pl.program_id(0)
     ci = pl.program_id(1)
     rows, d = q_ref.shape[1], q_ref.shape[2]
@@ -240,10 +310,11 @@ def _paged_kernel(off_ref, table_ref, q_ref, *rest, page: int,
     width = group * cols
 
     # folded row r is (chunk position ci*tile_c + r // heads, q head
-    # r % heads) at absolute position off + that; the tile's last attendable
-    # key is its last query's position, so the walk ends at that page. The
-    # table's width bounds it too: queries padded past a chunk may sit past
-    # the last column (a ring has no last column: it wraps)
+    # r % heads) at absolute position off + that — per head: (kv head
+    # r // (rows / kvh), position, q head of its group) —; the tile's last
+    # attendable key is its last query's position, so the walk ends at that
+    # page. The table's width bounds it too: queries padded past a chunk may
+    # sit past the last column (a ring has no last column: it wraps)
     first = off_ref[bi] + ci * tile_c
     hi = (first + (tile_c - 1)) // page + 1
     if window:  # the walk starts at the window's first page, not at 0
@@ -294,6 +365,81 @@ def _paged_kernel(off_ref, table_ref, q_ref, *rest, page: int,
         v_buf[...] = jnp.zeros_like(v_buf)
 
     start(0, 0)
+    scale = scale or 1.0 / math.sqrt(d)
+    dims = (((1,), (1,)), ((), ()))
+
+    def arrive(g):
+        """Start the copies of the group after the g-th, wait for the
+        g-th's: its slot."""
+        slot = g % 2
+
+        @pl.when(g + 1 < steps)
+        def _ahead():
+            start(g + 1, 1 - slot)
+
+        wait(g, slot)
+        return slot
+
+    def kept(g, bound, ahead):
+        """The g-th group's mask from the bounds worked out once: ``base +
+        key <= q_pos`` is ``base <= bound``, the window's lower bound
+        likewise."""
+        base = (lo + g * group) * page
+        keep = base <= bound
+        if window:
+            keep = jnp.logical_and(keep, base > ahead - window)
+        return keep
+
+    if per_head:
+        # a head's [rows / kvh, group * page] tile: row r is position
+        # r // (heads / kvh) of the tile, column c key c of the group;
+        # nothing to mask across heads
+        mine, keys, w = rows // kvh, group * page, k_lanes[0][1]
+        r = jax.lax.broadcasted_iota(jnp.int32, (mine, keys), 0)
+        c = jax.lax.broadcasted_iota(jnp.int32, (mine, keys), 1)
+        ahead = first + r // (heads // kvh) - c
+
+        def head_by_head(g, acc):
+            slot = arrive(g)
+            # a strided read takes whole 128-lane rows of 32 bits
+            per = len(kf_bufs) // parts
+            for i, (buf, (a, z)) in enumerate(zip(kf_bufs, k_lanes)):
+                buf[...] = k_buf[2 * (i // per) + slot, :, a:z].astype(jnp.float32)
+            for buf, (a, z) in zip(vf_bufs, _lane_runs(v_buf.shape[-1])):
+                buf[...] = v_buf[slot, :, a:z].astype(jnp.float32)
+            keep = kept(g, ahead, ahead)
+            out = []
+            for j, (o, m, l) in enumerate(acc):
+                at = slice(j * mine, (j + 1) * mine)
+                own = pl.ds(j, keys, stride=kvh)    # kv head j's row of every key
+                scores = sum(jax.lax.dot_general(
+                    q_ref[0, at, i * w:(i + 1) * w].astype(jnp.float32),
+                    buf[own, :], dims, preferred_element_type=jnp.float32)
+                    for i, buf in enumerate(kf_bufs)) * scale     # [mine, keys]
+                scores = jnp.where(keep, scores, _NEG)
+                m_new = jnp.maximum(m, scores.max(axis=-1, keepdims=True))
+                p = jnp.exp(scores - m_new)
+                corr = jnp.exp(m - m_new)
+                v = jnp.concatenate([buf[own, :] for buf in vf_bufs], axis=1)
+                out.append((o * corr + jax.lax.dot_general(
+                    p, v, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32),
+                    m_new, l * corr + p.sum(axis=-1, keepdims=True)))
+            return tuple(out)
+
+        # (sum, maximum, denominator) a head; a sink is one more key with
+        # score b_h and no value: maximum b_h, denominator exp(b_h - b_h)
+        acc = jax.lax.fori_loop(0, steps, head_by_head, tuple(
+            (jnp.zeros((mine, o_ref.shape[2]), jnp.float32),
+             sink_ref[j * mine:(j + 1) * mine, :] if sink
+             else jnp.full((mine, 1), _NEG, jnp.float32),
+             jnp.full((mine, 1), 1.0 if sink else 0.0, jnp.float32))
+            for j in range(kvh)))
+        for j, (o, _, l) in enumerate(acc):  # l is never truly zero: below
+            o_ref[0, j * mine:(j + 1) * mine, :] = (
+                o / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+        return
+
     o_acc[:] = jnp.zeros_like(o_acc)
     if sink:
         # the sink is one more key with score b_h and no value: the running
@@ -305,43 +451,29 @@ def _paged_kernel(off_ref, table_ref, q_ref, *rest, page: int,
         l_acc[:] = jnp.zeros_like(l_acc)
 
     # the mask, but for the group's first position: column c of a group is
-    # key (c // cols) * page + (c % cols) // kvh of kv head c % kvh, and
-    # ``base + key <= q_pos`` on the query head's own kv head is ``base <=
-    # bound`` with the bound worked out once (-1, below every base, on the
-    # other heads); the window's lower bound likewise
+    # key (c // cols) * page + (c % cols) // kvh of kv head c % kvh, and the
+    # bound is -1, below every base, on the other kv heads than the row's own
     r = jax.lax.broadcasted_iota(jnp.int32, (rows, width), 0)
     c = jax.lax.broadcasted_iota(jnp.int32, (rows, width), 1)
     ahead = first + r // heads - ((c // cols) * page + (c % cols) // kvh)
     own_head = (r % heads) // (heads // kvh) == c % kvh
     bound = jnp.where(own_head, ahead, -1)
     q = q_ref[0].astype(jnp.float32)                              # [rows, D]
-    scale = scale or 1.0 / math.sqrt(d)
 
     def body(g, _):
-        slot = g % 2
-
-        @pl.when(g + 1 < steps)
-        def _ahead():
-            start(g + 1, 1 - slot)
-
-        wait(g, slot)
+        slot = arrive(g)
         k = k_buf[slot].astype(jnp.float32)                       # [width, D]
         v = v_buf[slot].astype(jnp.float32)
         if parts == 1:
             scores = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale       # [rows, width]
+                q, k, dims, preferred_element_type=jnp.float32) * scale
         else:  # a key in parts: the products of each part's lanes, summed
             w = k.shape[1]
             scores = sum(jax.lax.dot_general(
                 q[:, p * w:(p + 1) * w], k_buf[2 * p + slot].astype(jnp.float32),
-                (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+                dims, preferred_element_type=jnp.float32)
                 for p in range(parts)) * scale
-        base = (lo + g * group) * page
-        keep = base <= bound
-        if window:
-            keep = jnp.logical_and(keep, base > ahead - window)
-        scores = jnp.where(keep, scores, _NEG)
+        scores = jnp.where(kept(g, bound, ahead), scores, _NEG)   # [rows, width]
         m = m_acc[:, :1]                                          # [rows, 1]
         m_new = jnp.maximum(m, scores.max(axis=-1, keepdims=True))
         p = jnp.exp(scores - m_new)
@@ -432,7 +564,12 @@ def _walk_call(b, tiles, rows, dh, dtype, *, page, kvh, heads, tile_c, ring,
     folded row's sink logit)."""
     from jax.experimental.pallas import tpu as pltpu
 
-    group = _page_group(rows, page, kvh, dh, dtype.itemsize, dv)
+    per_head = per_kv_head(tile_c, heads, kvh)
+    group = _page_group(rows, page, kvh, dh, dtype.itemsize, dv, per_head)
+    if per_head and window:
+        # a tile's whole walk under a window: pages past it would be dead
+        # columns of every step's products
+        group = min(group, (window + tile_c - 2) // page + 2)
     dv = dv or dh
 
     def _q_index(bi, ci, *_):
@@ -441,7 +578,7 @@ def _walk_call(b, tiles, rows, dh, dtype, *, page, kvh, heads, tile_c, ring,
     kernel = functools.partial(
         _paged_kernel, page=page, kvh=kvh, heads=heads, tile_c=tile_c,
         group=group, ring=ring, window=window, scale=scale, sink=sink,
-        parts=parts, part_stride=part_stride)
+        parts=parts, part_stride=part_stride, per_head=per_head)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, tiles),
@@ -456,9 +593,11 @@ def _walk_call(b, tiles, rows, dh, dtype, *, page, kvh, heads, tile_c, ring,
             pltpu.VMEM((2 * parts, group * page * kvh, dh // parts), dtype),
             pltpu.VMEM((2, group * page * kvh, dv), dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
-            pltpu.VMEM((rows, dv), jnp.float32),
-            pltpu.VMEM((rows, 128), jnp.float32),
-            pltpu.VMEM((rows, 128), jnp.float32),
+            *([pltpu.VMEM((group * page * kvh, z - a), jnp.float32)
+               for a, z in (*_lane_runs(dh // parts) * parts, *_lane_runs(dv))]
+              if per_head else [pltpu.VMEM((rows, dv), jnp.float32),
+                                pltpu.VMEM((rows, 128), jnp.float32),
+                                pltpu.VMEM((rows, 128), jnp.float32)]),
         ],
     )
     # programs run one after another on one core: the slots are zeroed by
@@ -565,19 +704,15 @@ def paged_flash_attention(q, k_pages, v_pages, layer, page_table, off, *,
     # layers * num_pages pages and the table names pages of that run
     table = (jnp.asarray(page_table, jnp.int32)
              + jnp.asarray(layer, jnp.int32) * n_pages)
-    # query tile: the whole chunk when it is small (block == array, any C),
-    # else a multiple of 8 positions so the block's row count tiles
-    tile_c = c if c * h <= _PAGED_ROWS else max(8, _PAGED_ROWS // h // 8 * 8)
+    tile_c = query_tile(c, h)
     c_pad = -(-c // tile_c) * tile_c
     if c_pad != c or dq != dh:
         # padded queries sit past the chunk: finite garbage, sliced off
         # below; padded lanes meet a held key's zeros
         q = jnp.pad(q, ((0, 0), (0, c_pad - c), (0, 0), (0, dh - dq)))
     rows = tile_c * h
-    # who walks the table: the kernel, but for a head of no multiple of 128
-    # lanes compiled for a chip, whose pages only the grid can read
     plain = dv == dh == dq and sink is None
-    walk = interpret or (dh % 128 == 0 and dv % 128 == 0)
+    walk = kernel_walks(dh, dv, interpret)
     if not (walk or plain):
         raise ValueError(
             "a sink, and keys and values of different widths, are served "
@@ -590,8 +725,16 @@ def paged_flash_attention(q, k_pages, v_pages, layer, page_table, off, *,
         **({} if plain else dict(dv=dv, scale=dq ** -0.5, sink=sink is not None,
                                  parts=parts,
                                  part_stride=layers // parts * n_pages)))
+    # a tile's rows: (position, query head) or, where the kernel's walk
+    # multiplies a K/V head at a time, (kv head, position, its query heads)
+    tiles, group = c_pad // tile_c, h // kvh
+    per_head = kernel.keywords.get("per_head", False)
+    if per_head:
+        q = q.reshape(b, tiles, tile_c, kvh, group, dh).transpose(0, 1, 3, 2, 4, 5)
     sinks = [] if sink is None else [
-        jnp.tile(sink.astype(jnp.float32), tile_c)[:, None]]
+        jnp.broadcast_to(sink.astype(jnp.float32).reshape(kvh, 1, group),
+                         (kvh, tile_c, group)).reshape(rows, 1)
+        if per_head else jnp.tile(sink.astype(jnp.float32), tile_c)[:, None]]
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -602,6 +745,9 @@ def paged_flash_attention(q, k_pages, v_pages, layer, page_table, off, *,
     )(jnp.asarray(off, jnp.int32), table, q.reshape(b, c_pad * h, dh), *sinks,
       k_pages.reshape(layers * n_pages, page * kvh, dh // parts),
       v_pages.reshape(v_pages.shape[0] * n_pages, page * kvh, dv))
+    if per_head:
+        out = out.reshape(b, tiles, kvh, tile_c, group, dv).transpose(
+            0, 1, 3, 2, 4, 5)
     return out.reshape(b, c_pad, h, dv)[:, :c]
 
 

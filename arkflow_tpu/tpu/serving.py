@@ -610,6 +610,19 @@ class GenerationServer:
                              "kept page-table columns of the rows the "
                              "attention kernel was called with, a layer")))
             for kind in ("decode", "chunk")}
+        # the (row, query tile) programs of those calls, a layer, by the
+        # product each makes: a K/V head at a time over that head's own query
+        # rows, or all heads at once under a mask — the kernel's own
+        # predicate on the step's shapes as one chip sees them
+        self._tiles_of: dict[int, dict[str, int]] = {}
+        self.m_attn_tiles = {
+            (kind, product): reg.counter(
+                "arkflow_gen_attn_tiles_total",
+                "(row, query tile) programs of the attention kernel, summed "
+                "over layers, by the product a tile makes",
+                {"model": name, "kind": kind, "product": product})
+            for kind in self.m_attn_walk
+            for product in ("per_kv_head", "all_heads")}
         # a sink joins the softmax of every query of its kind's layers: the
         # rows (queries x layers of a kind with a sink) that went through
         # one, from lengths on the host (padding and idle lanes not counted)
@@ -1784,7 +1797,7 @@ class GenerationServer:
             self._slide_window(slot, off, new_off - 1)
             packed = pack_operands(ids, off, len(chunk), self._table(slot))
             if kind == "chunk":
-                self._note_walk("chunk", np.asarray([off + c - 1]), len(chunk))
+                self._note_walk("chunk", np.asarray([off + c - 1]), len(chunk), c)
             if self._stateful:
                 valid, masked = self.m_ssm["chunk"]
                 valid.inc(len(chunk))
@@ -2154,12 +2167,16 @@ class GenerationServer:
                 self._note_walk("decode", lens, int(act.sum()))
         return act, packed, prev, prep.dur_s
 
-    def _note_walk(self, kind: str, last, queries: int) -> None:
+    def _note_walk(self, kind: str, last, queries: int, width: int = 1) -> None:
         """Count the kept pages a step's rows walk: ``last`` [rows] each
         row's last query position as the kernel is given it (an idle lane's
         0 walks its one scratch page). ``queries``: the step's real queries
         (active lanes, a chunk's unpadded positions), each a row of every
-        layer with a sink."""
+        layer with a sink. ``width``: the positions a row of the step."""
+        if self.m_attn_tiles:
+            for product, tiles in (self._tiles_of.get(width)
+                                   or self._attn_tiles(width)).items():
+                self.m_attn_tiles[kind, product].inc(tiles * len(last))
         if self.m_sink_rows:
             self.m_sink_rows[kind].inc(queries * self._sink_layers)
         if self.m_attn_walk:
@@ -2167,6 +2184,28 @@ class GenerationServer:
             cols = self.pages_per_slot
             walked.inc(int(np.minimum(last // self.page_size + 1, cols).sum()))
             columns.inc(cols * len(last))
+
+    def _attn_tiles(self, width: int) -> dict[str, int]:
+        """The attention kernel's query tiles of one row of ``width``
+        positions, summed over the layers, by the product a tile makes: the
+        kernel's own cut (``ops/ragged_attention``) of the shapes one chip
+        sees. Worked out once a width."""
+        from arkflow_tpu.ops.ragged_attention import (kernel_walks, per_kv_head,
+                                                      query_tile)
+
+        shards = 1
+        if self.mesh is not None:
+            from arkflow_tpu.parallel.mesh import tp_size
+
+            shards = tp_size(self.mesh)
+        tiles = self._tiles_of[width] = {"per_kv_head": 0, "all_heads": 0}
+        for spec in map(self.cfg.gqa, self.cfg.kinds):
+            heads, kvh = self.cfg.heads // shards, spec.kv_heads // shards
+            tile_c = query_tile(width, heads)
+            per_head = (kernel_walks(spec.dk_held, spec.dv, self.kernel_interpret)
+                        and per_kv_head(tile_c, heads, kvh))
+            tiles["per_kv_head" if per_head else "all_heads"] += -(-width // tile_c)
+        return tiles
 
     def _apply_decode(self, act, nxt, reqs=None) -> None:
         """One decode step's fetched tokens (then a routed model's counters)

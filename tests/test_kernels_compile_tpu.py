@@ -163,6 +163,55 @@ def test_paged_flash_compiles_as_served(v5e, cell, step):
     assert 1 <= _page_group(rows, PAGE, kvh, 128, 2) <= _page_group(h, PAGE, kvh, 128, 2)
 
 
+#: a prefill chunk as each per-head cell serves it, one chip's share: (query
+#: heads, K/V heads, key width, table columns, chunk, window, sink)
+CHUNKS = {"mistral_l6": (32, 8, 128, 136, 128, 0, False),
+          "mistral_tp4_local": (8, 2, 128, 136, 128, 0, False),
+          "falconh1_l4": (20, 4, 128, 64, 256, 0, False),
+          "kexaone_l5_full": (64, 8, 128, 528, 512, 0, False),
+          "kexaone_l5_window": (64, 8, 128, 41, 512, 128, False),
+          "mimo_l7_full_key_parts": (64, 4, 192, 832, 512, 0, False),
+          "mimo_l7_window_sink": (64, 8, 192, 41, 512, 128, True)}
+
+
+@pytest.mark.parametrize("cell", list(CHUNKS))
+def test_a_chunks_per_head_product_compiles_inside_its_vmem_limit(v5e, cell):
+    """Every per-head cell's chunk takes the K/V-head-at-a-time cut (the
+    kernel's own predicate on the call's shapes), what its program holds in
+    VMEM — two slots of a group's pages, their float32 copies in 128-lane
+    runs, the accumulators, the query and output blocks twice — is inside
+    the limit the call asks for, and the chip's compiler takes its strided
+    reads of the scratch."""
+    import math
+
+    from arkflow_tpu.ops import ragged_attention as ra
+
+    h, kvh, dk, table, chunk, window, sink = CHUNKS[cell]
+    parts = 1 if dk % 128 == 0 else -(-dk // 128)
+    held = dk // parts if dk % 128 == 0 else 128
+    tile_c = ra.query_tile(chunk, h)
+    assert ra.per_kv_head(tile_c, h, kvh) and ra.kernel_walks(parts * held, 128, False)
+    rows = tile_c * h
+    _, spec, params = ra._walk_call(
+        1, chunk // tile_c, rows, parts * held, jnp.dtype(BF16), page=PAGE, kvh=kvh,
+        heads=h, tile_c=tile_c, ring=table, window=window, dv=128, scale=dk ** -0.5,
+        sink=sink, parts=parts, part_stride=2 * 257)
+    limit = params["compiler_params"].vmem_limit_bytes
+    scratch = sum(math.prod(s.shape) * jnp.dtype(s.dtype).itemsize
+                  for s in spec.scratch_shapes if "sem" not in str(s.dtype))
+    blocks = 2 * rows * (parts * held + 128) * 2
+    assert scratch + blocks < limit <= 64 << 20
+    pages = 1 + 16 * table
+    compiled = _compile(
+        lambda q, kp, vp, layer, t, off, s: paged_flash_attention(
+            q, kp, vp, layer, t, off, window=window, sink=s if sink else None),
+        v5e, ((1, chunk, h, dk), BF16), ((2 * parts, pages, PAGE, kvh, held), BF16),
+        ((2, pages, PAGE, kvh, 128), BF16), ((), I32), ((1, table), I32),
+        ((1,), I32), ((h,), jnp.float32))
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 * 1024 * 1024
+
+
 def test_paged_flash_is_a_mosaic_kernel(v5e):
     compiled = _compile(paged_flash_attention, v5e,
                         *_paged_shapes("llama3_8b", 1))

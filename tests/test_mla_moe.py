@@ -629,11 +629,12 @@ def test_judge_holds_the_float32_leaves_to_their_masters():
 #: sha256 of the lowered text of the dense GQA programs, ``_decode`` then
 #: ``_chunk``: gather as PR 32 left them (the pools carried whole through
 #: the layer scan), paged as PR 41 left them (the kernel walks the page table
-#: itself). A PR that changes the dense programs on
-#: purpose recomputes them with this test's code.
+#: itself) but for ``_chunk``, whose tile multiplies a K/V head at a time
+#: since PR 43 (was 34b2db03d57a9a4a; ``_decode`` stands). A PR that changes
+#: the dense programs on purpose recomputes them with this test's code.
 DENSE_HLO = {
     "gather": ("fdea09887a70df43", "e5fbbb5138b92dea"),
-    "paged": ("1af74e9152f85a54", "34b2db03d57a9a4a"),
+    "paged": ("1af74e9152f85a54", "2626a52b0b48a18d"),
 }
 
 

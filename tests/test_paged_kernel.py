@@ -280,6 +280,147 @@ def test_the_grid_walk_of_narrow_heads_gives_the_same(monkeypatch, name):
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=3e-5)
 
 
+# -- a chunk's tile: each K/V head's keys by that head's own query rows ---------
+
+#: (query heads, K/V heads, key width, value width, page, chunk, offsets,
+#: window, sink) at the head geometries the benchmark's cells serve, small:
+#: query heads a K/V head 4, 5, 8, 16; K/V heads 2, 4, 8; a key of 192 held
+#: in two parts of 128 lanes beside values of 128; a window of 128 over a
+#: ring; a sink; a chunk that is one query tile and one that is several
+#: (its last padded); offsets past one group of the walk, whose last group
+#: is partly dead
+PER_HEAD_CASES = {
+    "l6_4_a_head_8_kv_one_tile": (32, 8, 8, 8, 4, 16, (0, 37, 150), 0, False),
+    "tp4_share_4_a_head_2_kv": (8, 2, 8, 8, 4, 24, (5, 190), 0, False),
+    "falconh1_5_a_head_tiles_of_48": (20, 4, 8, 8, 4, 112, (3, 64), 0, False),
+    "kexaone_full_8_a_head_three_tiles": (64, 8, 8, 8, 4, 40, (0, 129), 0, False),
+    "kexaone_window_ring": (64, 8, 8, 8, 16, 40, (0, 100, 4000), 128, False),
+    "mimo_full_16_a_head_key_in_parts": (64, 4, 192, 128, 4, 32, (7, 200), 0, False),
+    "mimo_window_ring_sink_parts": (64, 8, 192, 128, 16, 32, (0, 130, 3000), 128, True),
+    "full_layer_sink_one_kv_head": (8, 1, 8, 16, 4, 8, (2, 140), 0, True),
+}
+
+
+def _per_head_operands(name):
+    """The logical keys and values of each row [rows, T, kv heads, width]
+    (bfloat16 values), the pools that hold them by a scattered table — or a
+    ring of just the pages a window needs —, K in parts of 128 lanes where
+    it is wider, as layer 1 of three (the others NaN), every column past a
+    row's last padded query a page of NaN."""
+    h, kvh, dk, dv, page, c, offs, window, sink = PER_HEAD_CASES[name]
+    rng = np.random.RandomState(len(name))
+    b = len(offs)
+    tile_c = ragged_attention.query_tile(c, h)
+    c_pad = -(-c // tile_c) * tile_c
+    t = -(-(max(offs) + c_pad) // page) * page
+    bf = lambda a: np.asarray(jnp.asarray(a, jnp.bfloat16).astype(jnp.float32))  # noqa: E731
+    k, v = bf(rng.randn(b, t, kvh, dk) * 0.5), bf(rng.randn(b, t, kvh, dv) * 0.5)
+    cols = (window + c_pad - 2) // page + 2 if window else t // page
+    n_pages = 2 + b * cols
+    parts = 1 if dk <= 128 or dk % 128 == 0 else -(-dk // 128)
+    held = dk if parts == 1 else 128
+    kp = np.zeros((n_pages, page, kvh, parts * held), np.float32)
+    vp = np.zeros((n_pages, page, kvh, dv), np.float32)
+    kp[-1] = vp[-1] = np.nan
+    table = np.full((b, cols), n_pages - 1 if not window else 0, np.int32)
+    for r, off in enumerate(offs):
+        last = (off + c_pad - 1) // page
+        first = max(off - (window - 1), 0) // page if window else 0
+        own = 1 + r * cols + rng.permutation(cols)
+        for i in range(first, last + 1):
+            at = own[i % cols]
+            table[r, i % cols] = at
+            kp[at, :, :, :dk] = k[r, i * page:(i + 1) * page]
+            vp[at] = v[r, i * page:(i + 1) * page]
+    pool = lambda a: jnp.full((3, *a.shape), jnp.nan, jnp.bfloat16).at[1].set(  # noqa: E731
+        jnp.asarray(a, jnp.bfloat16))
+    k_pool = jnp.concatenate([pool(kp[..., p * held:(p + 1) * held])
+                              for p in range(parts)])
+    q = bf(rng.randn(b, c, h, dk) * 0.5)
+    sinks = rng.randn(h).astype(np.float32) + 1.0 if sink else None
+    return q, k, v, k_pool, pool(vp), jnp.asarray(table), np.asarray(offs), sinks
+
+
+def _plain_attention(q, k, v, offs, window, sinks):
+    """Float32, a row at a time: query i at ``off + i`` attends ``s <= t``
+    (``t - window < s`` under a window); a sink joins the denominator."""
+    b, c, h, dk = q.shape
+    rep = h // k.shape[2]
+    out = np.zeros((b, c, h, v.shape[-1]), np.float32)
+    for r, off in enumerate(offs):
+        kk, vv = np.repeat(k[r], rep, axis=1), np.repeat(v[r], rep, axis=1)
+        s = np.einsum("chd,shd->hcs", q[r], kk) * dk ** -0.5
+        pos, key = off + np.arange(c)[None, :, None], np.arange(kk.shape[0])[None, None, :]
+        keep = key <= pos
+        if window:
+            keep &= key > pos - window
+        s = np.where(keep, s, -np.inf)
+        m = s.max(-1, keepdims=True)
+        if sinks is not None:
+            m = np.maximum(m, sinks[:, None, None])
+        p = np.exp(s - m)
+        den = p.sum(-1, keepdims=True)
+        if sinks is not None:
+            den = den + np.exp(sinks[:, None, None] - m)
+        out[r] = np.einsum("hcs,shd->chd", p / den, vv)
+    return out
+
+
+@pytest.mark.parametrize("name", list(PER_HEAD_CASES))
+def test_a_chunks_tile_multiplies_each_kv_head_by_its_own_rows(name):
+    """Every geometry's chunk takes the per-head cut (the ONE predicate),
+    walks more than one group where its offsets reach past one, and gives
+    the plain attention's answer; no NaN page is ever copied."""
+    h, kvh, dk, dv, page, c, offs, window, sink = PER_HEAD_CASES[name]
+    tile_c = ragged_attention.query_tile(c, h)
+    assert ragged_attention.per_kv_head(tile_c, h, kvh)
+    q, k, v, k_pool, v_pool, table, offs, sinks = _per_head_operands(name)
+    parts = k_pool.shape[0] // 3
+    group = _page_group(tile_c * h, page, kvh, parts * k_pool.shape[-1], 2, dv,
+                        per_head=True)
+    walked = [(o + c - 1) // page + 1 - (max(o - (window - 1), 0) // page if window else 0)
+              for o in offs]
+    assert window or (max(walked) > group and max(walked) % group)
+    out = paged_flash_attention(
+        jnp.asarray(q), k_pool, v_pool, 1, table, jnp.asarray(offs, jnp.int32),
+        interpret=True, window=window,
+        sink=None if sinks is None else jnp.asarray(sinks))
+    assert out.shape == (*q.shape[:3], dv) and np.isfinite(np.asarray(out)).all()
+    np.testing.assert_allclose(np.asarray(out), _plain_attention(
+        q, k, v, offs, window, sinks), atol=3e-5)
+
+
+#: sha256 (first 16 hex, source positions stripped) of the jaxprs of calls
+#: the predicate keeps on the all-heads product, recorded at PR 43's parent
+#: (f4299c3): a decode tile, a decode tile under a window with a sink, and
+#: chunk tiles whose rows a K/V head are no multiple of a sublane tile
+ALL_HEADS_GOLDEN = {
+    "decode": ((2, 1, 8, 2, 0, False), "4cf27ca18560d7e8"),
+    "decode.window.sink": ((2, 1, 8, 4, 32, True), "9c5d04ac365f833f"),
+    "odd_rows": ((1, 3, 4, 2, 0, False), "492d84aa8529d818"),
+    "odd_rows.five_a_head": ((1, 7, 10, 2, 0, False), "c9a9dc405233f779")}
+
+
+@pytest.mark.parametrize("case", sorted(ALL_HEADS_GOLDEN))
+def test_the_predicate_keeps_decode_and_odd_tiles_on_the_all_heads_product(case):
+    """A decode step's tile (one position: 4 rows a K/V head here, 2 under
+    the window) and a chunk tile of 6 or 35 rows a head trace to exactly
+    what they traced to before the per-head cut existed."""
+    import hashlib
+    import re
+
+    (b, c, h, kvh, window, sink), want = ALL_HEADS_GOLDEN[case]
+    assert not ragged_attention.per_kv_head(ragged_attention.query_tile(c, h), h, kvh)
+    q = jnp.zeros((b, c, h, 128), jnp.bfloat16)
+    pool = jnp.zeros((2, 9, 16, kvh, 128), jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(lambda q, k, v, t, o, s: paged_flash_attention(
+        q, k, v, 1, t, o, window=window, sink=s))(
+        q, pool, pool, jnp.zeros((b, 4), jnp.int32), jnp.zeros((b,), jnp.int32),
+        jnp.zeros((h,), jnp.float32) if sink else None)
+    text = re.sub(r"0x[0-9a-f]+", "0x", re.sub(r" at [^\s\]]+:\d+", "", str(jaxpr)))
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == want
+
+
 def _paged_calls(jaxpr):
     for eqn in jaxpr.eqns:
         if eqn.primitive.name == "pallas_call":
@@ -599,6 +740,41 @@ def test_server_counts_the_pages_its_rows_walk():
     assert got["chunk"] == [2 + 4, 2 * cols]
     # four decode steps at lengths 13..16 (4, 4, 4, 5 pages) + the idle lane
     assert got["decode"] == [4 + 4 + 4 + 5 + 4, 4 * 2 * cols]
+
+
+def test_server_counts_its_query_tiles_by_the_product_they_make():
+    """``arkflow_gen_attn_tiles_total{kind, product}``: the kernel's (row,
+    query tile) programs, a layer, by the kernel's own predicate on the
+    step's shapes. The same serve as above over two layers: two chunks of 8
+    positions x 2 query heads a K/V head (16 rows a head: a K/V head at a
+    time), four decode steps of two lanes (2 rows a head: all heads at
+    once). A chunk of 4 positions x 1 query head is no multiple of a
+    sublane tile and stays on the all-heads product."""
+    from arkflow_tpu.ops.ragged_attention import per_kv_head, query_tile
+
+    cfg, params = _tiny_setup(seed=3)
+
+    async def go(cfg, params, chunk):
+        srv = GenerationServer(params, cfg, slots=2, page_size=4, max_seq=40,
+                               prefill_chunk=chunk, decode_kernel="paged",
+                               kernel_interpret=True)
+        before = {k: m.value for k, m in srv.m_attn_tiles.items()}
+        await srv.generate(list(range(1, 14)), max_new_tokens=5)
+        await srv.close()
+        return {k: m.value - before[k] for k, m in srv.m_attn_tiles.items()}
+
+    got = asyncio.run(go(cfg, params, 8))
+    assert per_kv_head(query_tile(8, cfg.heads), cfg.heads, cfg.kv_heads)
+    assert not per_kv_head(query_tile(1, cfg.heads), cfg.heads, cfg.kv_heads)
+    assert got == {("chunk", "per_kv_head"): 2 * cfg.layers, ("chunk", "all_heads"): 0,
+                   ("decode", "per_kv_head"): 0,
+                   ("decode", "all_heads"): 4 * 2 * cfg.layers}
+    model = get_model("decoder_lm")
+    mha = model.make_config(**{**TINY, "kv_heads": 4})
+    got = asyncio.run(go(mha, model.init(jax.random.PRNGKey(3), mha), 4))
+    assert not per_kv_head(query_tile(4, 4), 4, 4)
+    assert got["chunk", "per_kv_head"] == 0
+    assert got["chunk", "all_heads"] == 4 * mha.layers   # 13 tokens: 4 chunks
 
 
 def test_server_dispatch_depth2_bitwise_identical():
@@ -1020,3 +1196,26 @@ def test_profile_decode_kernel_mode_smoke():
     assert "p50" in out["device_idle_gap_ms_depth1"]
     assert "p50" in out["device_idle_gap_ms_depth2"]
     assert out["paged_interpreted"] is True  # CPU child: honest caveat
+
+
+def test_profile_paged_attention_rehearses_on_the_cpu_and_times_nothing_there():
+    """``tools/profile_paged_attention.py``: without a TPU it refuses to
+    time; ``--interpret`` rehearses a cell's points at tiny sizes — the
+    decode call all heads at once, the chunk calls a K/V head at a time,
+    each against the gather reference — and writes no time."""
+    from arkflow_tpu.utils.cleanenv import cpu_child_env
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    tool = [sys.executable, os.path.join(repo, "tools", "profile_paged_attention.py"),
+            "--cell", "mistral_tp4_local"]
+    env = cpu_child_env(n_devices=1)
+    res = subprocess.run(tool, env=env, capture_output=True, timeout=300, cwd=repo)
+    assert res.returncode == 1 and b"found no TPU" in res.stderr and not res.stdout
+    res = subprocess.run([*tool, "--interpret", "--check"], env=env,
+                         capture_output=True, timeout=300, cwd=repo)
+    assert res.returncode == 0, res.stderr.decode(errors="replace")[-2000:]
+    lines = [json.loads(l) for l in res.stdout.decode().strip().splitlines()]
+    assert [(l["kind"], l["per_kv_head"]) for l in lines] == [
+        ("decode", False), ("chunk", True), ("chunk", True)]
+    assert all(l["rehearsal"] and "us_per_call" not in l for l in lines)
+    assert all(l["max_abs_err"] < 2e-3 for l in lines)

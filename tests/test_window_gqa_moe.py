@@ -337,16 +337,18 @@ def _text_hash(text: str) -> str:
 #: step and a prefill chunk at tiny dense (the Mistral layout) and hybrid (the
 #: Falcon-H1 layout) sizes: gather and paged as PR 39 left them, before there
 #: were windows, at a head of 8 — which a chip's compiler has the GRID walk,
-#: as every head was walked before PR 41 —, and ``walk`` at a head of 128, as
-#: PR 41 left it: the kernel walks the page table itself (``window`` 0 still
-#: lowers to ONE form of each)
+#: as every head was walked before PR 41 —, and ``walk`` at a head of 128:
+#: the kernel walks the page table itself (``window`` 0 still lowers to ONE
+#: form of each), a decode step as PR 41 left it, a chunk as PR 43 did (its
+#: tile multiplies a K/V head at a time: 16 rows a head here; re-recorded
+#: from 9d47f7f4d33e5f36 / 9f2f3d5814b985ff, which the parent reproduces)
 WINDOW_0_GOLDEN = {
     "dense.decode.gather": "48f260fdc014f61e", "dense.chunk.gather": "02b87477d5d7db5c",
     "dense.decode.paged": "64ce15284ca65c61", "dense.chunk.paged": "75e38e2a56da97f5",
     "hybrid.decode.gather": "b93168be9c2f3624", "hybrid.chunk.gather": "ddfdb61920de41cd",
     "hybrid.decode.paged": "d314b67f4e837d21", "hybrid.chunk.paged": "31135aa3fc6bb0df",
-    "dense.decode.walk": "8fb05ab0dea5dbe0", "dense.chunk.walk": "9d47f7f4d33e5f36",
-    "hybrid.decode.walk": "41b44ac90e51a17a", "hybrid.chunk.walk": "9f2f3d5814b985ff"}
+    "dense.decode.walk": "8fb05ab0dea5dbe0", "dense.chunk.walk": "b81f1547cdd2021a",
+    "hybrid.decode.walk": "41b44ac90e51a17a", "hybrid.chunk.walk": "e6532038eb591939"}
 
 
 @pytest.mark.parametrize("case", sorted(WINDOW_0_GOLDEN))

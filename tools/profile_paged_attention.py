@@ -1,0 +1,255 @@
+"""Time ``paged_flash_attention`` alone, at the shapes the benchmark's cells serve.
+
+The kernel-alone microbench behind PERF.md's S11 numbers (PR 41, 42 and 43
+each needed it): one call a layer of the per-head paged kernel — a decode
+step over many lanes, or one row's prefill chunk at an offset — at a cell's
+head geometry, table width and lane count, on the chip this process holds.
+One JSON line a point:
+
+- ``us_per_call``: best of ``--rounds`` timed loops of ``--reps`` calls
+  (``lax.fori_loop`` over the pool's layers inside ONE jitted program, so
+  the host's dispatch is paid once a loop, not a call);
+- ``held_mb`` / ``held_roof_pct``: the bytes of the K and V rows the call's
+  queries can attend AS HELD in the pools (a key held in parts counts its
+  padding), and that over the chip's HBM peak (``benchmark/peaks.json``) and
+  the call's time: a decode call's roof. A chunk re-reads its context a
+  query tile, so its share of that roof says how far it is from one read;
+- ``per_kv_head``, ``tile_c``, ``tiles``, ``group``: how the call was cut
+  (``ops/ragged_attention.per_kv_head``, ``_page_group``).
+
+    python tools/profile_paged_attention.py                      # every point
+    python tools/profile_paged_attention.py --cell mimo_l7_full --kind chunk
+    python tools/profile_paged_attention.py --group-max 16,32,64 # a sweep
+
+``--group-max`` sets the module's two group ceilings for the run (a
+microbench's lever, not a serving knob). ``--check`` compares each point's
+output with the gather reference on the device. Needs a TPU: on the CPU the
+kernel is interpreted and a time says nothing (``--interpret`` runs tiny
+shapes there to rehearse the control flow; its lines say ``"rehearsal"``).
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import sys
+import time
+
+sys.path.insert(0, __file__.rsplit("/", 2)[0])
+
+PAGE = 16
+
+#: a cell's per-head attention as one chip sees it: query heads, K/V heads,
+#: key width (192: held in two parts of 128 lanes), value width, window,
+#: sink, lanes of a decode step, table columns (a ring's under a window),
+#: chunk length, the chunk offsets timed, a decode lane's contexts
+#: (lognormal median / sigma / clip, as the cell's traffic draws them)
+CELLS = {
+    "mistral_l6": dict(h=32, kvh=8, dk=128, dv=128, lanes=16, cols=136,
+                       chunk=128, offsets=(512, 1920), ctx=(640, 0.6, 190, 2176)),
+    "mistral_tp4_local": dict(h=8, kvh=2, dk=128, dv=128, lanes=16, cols=136,
+                              chunk=128, offsets=(512, 1920),
+                              ctx=(640, 0.6, 190, 2176)),
+    "falconh1_l4": dict(h=20, kvh=4, dk=128, dv=128, lanes=128, cols=64,
+                        chunk=256, offsets=(0, 256), ctx=(384, 0.8, 30, 1024)),
+    "kexaone_l5_full": dict(h=64, kvh=8, dk=128, dv=128, lanes=48, cols=528,
+                            chunk=512, offsets=(512, 2048, 7680),
+                            ctx=(900, 1.2, 64, 8448)),
+    "kexaone_l5_window": dict(h=64, kvh=8, dk=128, dv=128, window=128, lanes=48,
+                              cols=41, chunk=512, offsets=(2048,),
+                              ctx=(900, 1.2, 64, 8448)),
+    "mimo_l7_full": dict(h=64, kvh=4, dk=192, dv=128, lanes=64, cols=832,
+                         chunk=512, offsets=(1024, 4096, 11776),
+                         ctx=(4600, 0.6, 1100, 13300)),
+    "mimo_l7_window": dict(h=64, kvh=8, dk=192, dv=128, window=128, sink=True,
+                           lanes=64, cols=41, chunk=512, offsets=(4096,),
+                           ctx=(4600, 0.6, 1100, 13300)),
+}
+
+
+def _points(args):
+    for name, cell in CELLS.items():
+        if args.cell not in ("all", name):
+            continue
+        if args.kind in ("all", "decode"):
+            yield name, cell, "decode", None
+        if args.kind in ("all", "chunk"):
+            for off in cell["offsets"]:
+                yield name, cell, "chunk", off
+
+
+def _pools(cell, seed: int, tiny: bool):
+    """A cell's K and V pools (two layers, every lane's columns once, a key
+    of 192 in two parts of 128 lanes) and its sink logits."""
+    import jax
+    import jax.numpy as jnp
+
+    lanes, cols = (min(cell["lanes"], 3), min(cell["cols"], 24)) if tiny else (
+        cell["lanes"], cell["cols"])
+    dk, dv, kvh = cell["dk"], cell["dv"], cell["kvh"]
+    parts, held = (1, dk) if dk % 128 == 0 else (-(-dk // 128), 128)
+    keys = jax.random.split(jax.random.PRNGKey(seed), 3)
+    fill = lambda key, shape: (jax.random.normal(key, shape, jnp.bfloat16) * 0.5)  # noqa: E731
+    k = fill(keys[0], (2 * parts, 1 + lanes * cols, PAGE, kvh, held))
+    v = fill(keys[1], (2, 1 + lanes * cols, PAGE, kvh, dv))
+    sink = (jax.random.normal(keys[2], (cell["h"],), jnp.float32) + 2.0
+            if cell.get("sink") else None)
+    return k, v, sink, lanes, cols
+
+
+def _queries(cell, kind, off, rng, lanes: int, cols: int, tiny: bool):
+    """q, the table and the offsets of one point over a cell's pools."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    b, c = (lanes, 1) if kind == "decode" else (1, 32 if tiny else cell["chunk"])
+    if kind == "decode":
+        med, sigma, lo, hi = cell["ctx"]
+        ctx = np.clip(np.exp(rng.normal(np.log(med), sigma, b)), lo, hi)
+    else:
+        ctx = np.full((b,), off, np.float64)
+    if not cell.get("window", 0):
+        ctx = np.minimum(ctx, cols * PAGE - c)
+    q = jax.random.normal(jax.random.PRNGKey(int(rng.randint(1 << 30))),
+                          (b, c, cell["h"], cell["dk"]), jnp.bfloat16)
+    table = 1 + rng.permutation(lanes * cols)[:b * cols].reshape(b, cols)
+    return q, jnp.asarray(table, jnp.int32), jnp.asarray(ctx.astype(np.int32))
+
+
+def _held_bytes(cell, ctx, c) -> float:
+    """Bytes of the K and V rows the call can attend, as the pools hold them."""
+    import numpy as np
+
+    window = cell.get("window", 0)
+    last = ctx.astype(np.int64) + c
+    keys = np.minimum(last, window + c - 1) if window else last
+    dk = cell["dk"] if cell["dk"] % 128 == 0 else -(-cell["dk"] // 128) * 128
+    return float(keys.sum()) * cell["kvh"] * (dk + cell["dv"]) * 2
+
+
+def _reference(q, k, v, table, off, cell):
+    """The gather reference, float32, on the device (layer 1), a row at a
+    time and the query heads of a K/V head side by side (no repeat)."""
+    import jax
+    import jax.numpy as jnp
+
+    b, c, h, dk = q.shape
+    kvh, parts = cell["kvh"], k.shape[0] // 2
+
+    def row(args):
+        q, table, off = args
+        kk = jnp.concatenate([k[p * 2 + 1][table] for p in range(parts)], axis=-1)
+        kk = kk[..., :dk].reshape(-1, kvh, dk).astype(jnp.float32)
+        vv = v[1][table].reshape(-1, kvh, cell["dv"]).astype(jnp.float32)
+        qq = q.astype(jnp.float32).reshape(c, kvh, h // kvh, dk)
+        s = jnp.einsum("cjgd,sjd->jgcs", qq, kk) * dk ** -0.5
+        keep = jnp.arange(kk.shape[0])[None, :] <= off + jnp.arange(c)[:, None]
+        s = jnp.where(keep[None, None], s, -1e30)
+        p = jnp.exp(s - s.max(-1, keepdims=True))
+        return jnp.einsum("jgcs,sjd->cjgd", p / p.sum(-1, keepdims=True), vv
+                          ).reshape(c, h, cell["dv"])
+
+    return jax.lax.map(row, (q, table, off))
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--cell", default="all", choices=["all", *CELLS])
+    ap.add_argument("--kind", default="all", choices=["all", "decode", "chunk"])
+    ap.add_argument("--group-max", default="",
+                    help="comma-separated ceilings of pages a group to sweep")
+    ap.add_argument("--reps", type=int, default=10)
+    ap.add_argument("--rounds", type=int, default=5)
+    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--check", action="store_true")
+    ap.add_argument("--interpret", action="store_true")
+    ap.add_argument("--root", default="",
+                    help="another checkout to import arkflow_tpu from (a parent "
+                         "commit unpacked beside this one)")
+    args = ap.parse_args()
+    if args.root:
+        sys.path.insert(0, args.root)
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from arkflow_tpu.ops import ragged_attention as ra
+
+    device = jax.devices()[0]
+    if device.platform != "tpu" and not args.interpret:
+        print("found no TPU: a time from the CPU says nothing (--interpret "
+              "rehearses tiny shapes)", file=sys.stderr)
+        return 1
+    peak = None
+    if device.platform == "tpu":
+        with open(__file__.rsplit("/", 2)[0] + "/benchmark/peaks.json") as f:
+            peak = json.load(f)[device.device_kind]["hbm_bytes_per_s"]
+    ceilings = [int(g) for g in args.group_max.split(",") if g] or [None]
+    rng = np.random.RandomState(args.seed)
+    held_for = None
+    for name, cell, kind, off in _points(args):
+        if held_for != name:  # one cell's pools on the device at a time
+            k = v = sink = None
+            k, v, sink, lanes, cols = _pools(cell, args.seed, args.interpret)
+            held_for = name
+        q, table, ctx = _queries(cell, kind, off, rng, lanes, cols, args.interpret)
+        b, c, h, _ = q.shape
+        window = cell.get("window", 0)
+        for ceiling in ceilings:
+            if ceiling:
+                ra._PAGED_GROUP_MAX = ra._PAGED_CHUNK_GROUP_MAX = ceiling
+
+            def call(layer, q, k, v, table, ctx, sink):
+                return ra.paged_flash_attention.__wrapped__(
+                    q, k, v, layer, table, ctx, interpret=args.interpret,
+                    window=window, sink=sink)
+
+            def many(reps, *operands):  # the pools are arguments, not constants
+                def body(i, acc):
+                    return acc + call(i % 2, *operands).astype(jnp.float32)
+                return jax.lax.fori_loop(0, reps, body, jnp.zeros(
+                    (b, c, h, cell["dv"]), jnp.float32))
+
+            operands = (q, k, v, table, ctx, sink)
+            line = {"cell": name, "kind": kind, "lanes": b, "chunk": c,
+                    "offset": off, "ctx_mean": float(ctx.mean()),
+                    **({"group_max": ceiling} if ceiling else {}),
+                    "device": device.device_kind}
+            if hasattr(ra, "per_kv_head"):  # a checkout that cuts tiles per head
+                tile_c = ra.query_tile(c, h)
+                per_head = ra.per_kv_head(tile_c, h, cell["kvh"])
+                line.update(
+                    tile_c=tile_c, tiles=-(-c // tile_c), per_kv_head=per_head,
+                    group=ra._page_group(
+                        tile_c * h, PAGE, cell["kvh"], 128 * -(-cell["dk"] // 128),
+                        2, cell["dv"], per_head=per_head))
+            if args.interpret:
+                jax.block_until_ready(jax.jit(call)(1, *operands))
+            if args.check and not (window or sink is not None):
+                # a ring's table and a sink have no plain twin here: the
+                # tests hold them (tests/test_paged_kernel.py)
+                got = jax.jit(call)(1, *operands).astype(jnp.float32)
+                line["max_abs_err"] = float(jnp.abs(
+                    got - _reference(q, k, v, table, ctx, cell)).max())
+            if args.interpret:
+                line["rehearsal"] = True
+            else:
+                timed = jax.jit(many, static_argnums=0)
+                jax.block_until_ready(timed(args.reps, *operands))
+                best = float("inf")
+                for _ in range(args.rounds):
+                    t0 = time.perf_counter()
+                    jax.block_until_ready(timed(args.reps, *operands))
+                    best = min(best, time.perf_counter() - t0)
+                us = best / args.reps * 1e6
+                need = _held_bytes(cell, np.asarray(ctx), c)
+                line.update(us_per_call=round(us, 1), held_mb=round(need / 1e6, 2),
+                            held_roof_pct=round(need / peak / (us * 1e-6) * 100, 2))
+            print(json.dumps(line), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
