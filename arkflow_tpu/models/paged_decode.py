@@ -104,6 +104,17 @@ class CachePool:
         return self._row_bytes if self.per_slot else 0
 
 
+def _held_lanes(width: int) -> int:
+    """A latent model's rope key as its pools hold it: in whole 128-lane
+    rows, zeros behind the key, written where the key is written. The chip
+    tiles a narrower pool's rows to 128 lanes anyway, and a kernel's own
+    copy takes a page out of a pool only in whole such rows
+    (``ops/ragged_attention.mla_paged_attention`` walks the table by its
+    own copies); the plain-XLA forms read the key's own lanes. One layout,
+    whatever serves."""
+    return -(-width // 128) * 128
+
+
 def cache_spec(cfg: DecoderConfig) -> tuple:
     """The kinds of row the model caches — the one place that states them:
 
@@ -114,11 +125,13 @@ def cache_spec(cfg: DecoderConfig) -> tuple:
       layers' sizes, live for ``sliding_window`` tokens: its pages are
       freed as the window passes;
     - ``latent``: a full latent layer's normed latent row and rotated rope
-      key, one each a token for ALL heads;
+      key, one each a token for ALL heads, the rope key AS HELD: in whole
+      128-lane rows (``_held_lanes``), zeros behind the key;
     - ``index``: an indexed full layer's index key (the indexer scores it
       against every later query);
-    - ``window``: a sliding latent layer's latent row and rope key, live for
-      ``sliding_window`` tokens: its pages are freed as the window passes;
+    - ``window``: a sliding latent layer's latent row and rope key (held
+      likewise), live for ``sliding_window`` tokens: its pages are freed as
+      the window passes;
     - ``ssm``: a hybrid layer's recurrent state — the mixer's float32 state
       matrices and the conv's last ``d_conv - 1`` inputs —, one row a
       SEQUENCE whatever its length, beside that layer's ``kv`` rows."""
@@ -141,12 +154,14 @@ def cache_spec(cfg: DecoderConfig) -> tuple:
                 itemsizes=(4, 2), per_slot=True),)
         return pools
     full, swa = (cfg.kinds.count(k) for k in (FULL, SLIDING))
-    pools = [CachePool("latent", full, (cfg.kv_lora_rank, cfg.qk_rope_head_dim))]
+    pools = [CachePool("latent", full, (cfg.kv_lora_rank,
+                                        _held_lanes(cfg.qk_rope_head_dim)))]
     if cfg.index_topk:
         pools.append(CachePool("index", full, (cfg.index_head_dim,)))
     if swa:
         pools.append(CachePool(
-            "window", swa, (cfg.swa_kv_lora_rank, cfg.swa_qk_rope_head_dim),
+            "window", swa, (cfg.swa_kv_lora_rank,
+                            _held_lanes(cfg.swa_qk_rope_head_dim)),
             cfg.sliding_window))
     return tuple(pools)
 
@@ -161,8 +176,9 @@ def init_page_pool(cfg: DecoderConfig, num_pages: int, page_size: int,
       layer a part);
     - latent (MLA): the normed latent rows [layers, num_pages, page,
       kv_lora_rank] and the rotated rope keys [layers, num_pages, page,
-      qk_rope_head_dim], one row each per token for ALL heads (no head axis:
-      a page's last two dims then tile the chip's memory as they are);
+      qk_rope_head_dim as held: ``cache_spec``], one row each per token for
+      ALL heads (no head axis: a page's last two dims then tile the chip's
+      memory as they are);
     - a latent model with a layer pattern: two dicts by pool name — the
       wide rows ``{"latent", "window"[, "index"]}`` and the rope keys
       ``{"latent", "window"}`` — each pool over its OWN layers, the window
@@ -188,9 +204,8 @@ def init_page_pool(cfg: DecoderConfig, num_pages: int, page_size: int,
                 rope[pool.name] = arrays[1]
         return wide, rope
     if cfg.latent:
-        shape = (cfg.layers, num_pages, page_size)
-        return (jnp.zeros(shape + (cfg.kv_lora_rank,), jnp.bfloat16),
-                jnp.zeros(shape + (cfg.qk_rope_head_dim,), jnp.bfloat16))
+        return tuple(jnp.zeros((cfg.layers, num_pages, page_size, w), jnp.bfloat16)
+                     for w in cache_spec(cfg)[0].widths)
     spec = cache_spec(cfg)
     if cfg.layered:
         return tuple({pool.name: jnp.zeros(
@@ -286,7 +301,8 @@ def _latent_layers(params: dict, cfg: DecoderConfig, x, k_pages, v_pages,
             cq = mla_query_latent(lp, y, sp)
             q_nope, q_rope, c, k_r = mla_project(lp, y, sp, positions, cq)
             cp = cp.at[li, pi, po].set(c.astype(cp.dtype))
-            rp = rp.at[li, pi, po].set(k_r.astype(rp.dtype))
+            rp = rp.at[li, pi, po].set(jnp.pad(k_r.astype(rp.dtype), (
+                (0, 0), (0, 0), (0, rp.shape[-1] - k_r.shape[-1]))))
             gate = mla_head_gate(lp, y, sp)
             if sp.index_topk:
                 q_i, k_i, w = index_project(lp, y, cq, sp, positions)
@@ -423,7 +439,7 @@ def _attend_selected(lp, q_nope, q_rope, c_pages, r_pages, layer, page_table,
 
     def attend(q_lat, q_rope, phys, sel, ok):
         cc = c_pages[layer, phys, sel % page].astype(q_lat.dtype)  # [B, T, K, L]
-        rr = r_pages[layer, phys, sel % page].astype(q_lat.dtype)
+        rr = r_pages[layer, phys, sel % page, :q_rope.shape[-1]].astype(q_lat.dtype)
         scores = (jnp.einsum("bqhl,bqkl->bqhk", q_lat, cc)
                   + jnp.einsum("bqhr,bqkr->bqhk", q_rope, rr)
                   ).astype(jnp.float32) * scale
@@ -463,7 +479,8 @@ def _attend_window(lp, q_nope, q_rope, c_pages, r_pages, layer, ring, off,
     logical = last[:, None] - (last[:, None] - j) % cols           # [B, cols]
     key_pos = (logical[:, :, None] * page + jnp.arange(page)).reshape(b, -1)
     cc = c_pages[layer, ring].reshape(b, cols * page, -1).astype(q_lat.dtype)
-    rr = r_pages[layer, ring].reshape(b, cols * page, -1).astype(q_lat.dtype)
+    rr = r_pages[layer, ring, :, :q_rope.shape[-1]].reshape(
+        b, cols * page, -1).astype(q_lat.dtype)
     qp, kpos = positions[:, None, :, None], key_pos[:, None, None, :]
     mask = (kpos <= qp) & (kpos > qp - cfg.window) & (kpos >= 0)
     return mla_output(lp, _masked_latent_attention(q_lat, q_rope, cc, rr, mask,
@@ -492,7 +509,8 @@ def _attend_latent(lp, q_nope, q_rope, c_pages, r_pages, layer, page_table,
     else:
         b, ctx = page_table.shape[0], page_table.shape[1] * c_pages.shape[2]
         cc = c_pages[layer][page_table].reshape(b, ctx, -1).astype(q_lat.dtype)
-        rr = r_pages[layer][page_table].reshape(b, ctx, -1).astype(q_lat.dtype)
+        rr = r_pages[layer][page_table][..., :q_rope.shape[-1]].reshape(
+            b, ctx, -1).astype(q_lat.dtype)
         o_lat = _masked_latent_attention(q_lat, q_rope, cc, rr, mask, scale)
     return mla_output(lp, o_lat, cfg, gate)
 
@@ -545,9 +563,11 @@ def latent_kernel_probe(params: dict, cfg: DecoderConfig, page_size: int,
         return jax.tree_util.tree_map(lambda a: a[0], {
             k: v for k, v in params[name].items() if k != "experts"})
 
-    def pools(sp, pages):
-        return [rand((1, pages, page_size, w))
-                for w in (sp.kv_lora_rank, sp.qk_rope_head_dim)]
+    def pools(sp, pages):  # the rope keys as held: zeros behind the key
+        cp, rp = (rand((1, pages, page_size, w))
+                  for w in (sp.kv_lora_rank, sp.qk_rope_head_dim))
+        return cp, jnp.pad(rp, ((0, 0),) * 3 + (
+            (0, _held_lanes(rp.shape[-1]) - rp.shape[-1]),))
 
     def queries(sp, c):
         return (rand((2, c, sp.heads, sp.qk_nope_head_dim)),

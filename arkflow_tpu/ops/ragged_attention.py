@@ -762,88 +762,203 @@ def paged_flash_attention(q, k_pages, v_pages, layer, page_table, off, *,
 # caller applies ``W_uv``). So the pools hold one "KV head" for all query
 # heads: a page is read ONCE and every head scores against it.
 #
-# Here the GRID still walks the table — (row, query tile, page group) with
-# the page table scalar-prefetched, the pools riding whole, as
-# ``paged_flash_attention`` did before its kernel took the walk over
-# (ROADMAP S13) — with three differences from that kernel:
+# The KERNEL walks the table here too, as ``_paged_kernel`` does above: the
+# grid is (row, query tile), the pools stay in HBM, ``layer``, the offsets and
+# the table are scalar-prefetched, and a program loops over its row's live
+# pages in groups, copying each group's latent and rope-key pages itself into
+# one of two VMEM slots, a group ahead (``_page_walk``). (A grid over every
+# column of the table, 8 pages a step, cost ~1 us a dead step and 7-8 us a
+# live one under 1,024 rows on a v5e: 2.1-2.9 x this walk's time a call,
+# PERF.md PR 44.) Beside the per-head kernel:
 #
-# * the layer is an axis of the pool view (``[layers, pages, page, width]``)
-#   with the layer index scalar-prefetched into the index maps;
-# * a grid step takes ``_LATENT_GROUP`` pages, each through its own
-#   BlockSpec over the same pool (the index map of the g-th resolves the
-#   row's (step * group + g)-th page): with one shared KV head a single
-#   16-token page would fill 16 of 128 score lanes and cost a grid step
-#   for 18 KB; eight pages make a [rows, 128] score tile;
+# * the layer is an axis of the pools (``[layers, pages, page, width]``) and
+#   a copy takes ``pool[layer, page]``, a latent page and a rope-key page;
+# * the rope keys are HELD in whole 128-lane rows, zeros behind the key
+#   (``models/paged_decode.cache_spec``): Mosaic sees a narrower pool padded
+#   to 128 lanes and takes a copy out of it only in whole rows, so a 64-lane
+#   pool could be read by a grid's BlockSpec alone. The queries' rope part is
+#   padded to the held width here and meets the zeros;
+# * with one shared KV head a page is ``page`` score lanes under every row,
+#   so a group is many pages (``_latent_group``: 32, 512 keys a step, where a
+#   walk is unbounded; a tile's whole walk under a window);
 # * operands stay bfloat16 into the MXU (float32 accumulation); the
-#   probabilities are rounded to bfloat16 for the value product.
+#   probabilities are rounded to bfloat16 for the value product;
+# * an indexer's choice rides as one float32 block a program, [tile_c,
+#   context], and a step reads its group's lanes of it.
 
-#: pages a grid step attends over (8 x 16-token pages = one 128-lane tile)
+#: pages a step of ``dsa_index_scores``'s grid attends over (8 x 16-token
+#: pages = one 128-lane tile)
 _LATENT_GROUP = 8
+
+#: pages a step of the latent walk takes where the budget allows more (the
+#: sums' rescaling and the row reductions are paid a step, the copies a
+#: page; the walk's last group is multiplied whole, dead pages too). On a
+#: v5e, us a call a layer at 8 / 16 / 32 / 64 pages (PERF.md, PR 44):
+#: dots3_l5's indexed layer, a 512-token chunk at offset 4,096 8,069 /
+#: 6,696 / 6,413 / 6,705 and at 11,776 20,113 / 16,333 / 15,243 / 14,696, a
+#: decode step of 32 lanes 1,814 / 1,385 / 1,261 / 1,200; kanana2_l6's
+#: 128-token chunk at 512 142 / 146 / 158 / 157, its 16 lanes 188 / 167 /
+#: 151 / 147
+_LATENT_WALK_MAX = 32
+
+
+def latent_query_tile(c: int, heads: int, lat: int) -> int:
+    """Positions of a call's ``c`` a (row, query tile) program of the latent
+    kernel takes: the [rows, L] float32 sums and the query and output blocks
+    grow with the latent width, so past 512 the rows shrink."""
+    cap = _PAGED_ROWS * 512 // max(lat, 512)
+    return c if c * heads <= cap else max(8, cap // heads // 8 * 8)
+
+
+def _latent_group(tile_c: int, heads: int, page: int, lat: int, held: int,
+                  itemsize: int, window: int = 0) -> int:
+    """Pages a (row, query tile) program of the latent kernel takes a step
+    of its walk, from the shapes it is called with. A page costs VMEM as its
+    latent and rope rows (``held`` lanes) in two slots and as ``page``
+    columns of every [rows, columns] float32 tile a step holds at once
+    (scores, probabilities, the mask's bounds, the choice: five). Under a
+    ``window`` a tile's whole walk — the pages from its first query's
+    oldest key to its last query — is one group where it fits: one step a
+    program. Whole 128-lane score tiles where a group has that many keys."""
+    fit = max(1, _walk_budget() // (
+        page * ((lat + held) * 2 * itemsize + tile_c * heads * 20)))
+    lanes = max(1, 128 // page)
+    if window:
+        span = (window + tile_c - 2) // page + 2
+        return min(fit, -(-span // lanes) * lanes)
+    group = min(fit, _LATENT_WALK_MAX)
+    return group if group < lanes else group // lanes * lanes
+
+
+def _page_walk(copies, page_of, lo, hi, group: int):
+    """The double-buffered walk of the logical pages ``lo`` .. ``hi`` - 1 in
+    groups of ``group``, ``_paged_kernel``'s shape: ``copies(slot, j, src)``
+    are the copies of physical page ``src`` into ``slot`` as a group's
+    ``j``-th page, ``page_of(i)`` the physical page of logical page ``i``.
+    Returns (steps, ``start``, ``arrive``): ``start(0, 0)`` once, then
+    ``arrive(g)`` a step — it starts the group after the g-th, waits for the
+    g-th and returns its slot. A group's pages past ``hi`` are not copied."""
+    steps = (hi - lo + (group - 1)) // group
+
+    def live(g):
+        return jnp.minimum(hi - (lo + g * group), group)
+
+    def start(g, slot):
+        def page_at(j, _):
+            for dma in copies(slot, j, page_of(lo + g * group + j)):
+                dma.start()
+
+        jax.lax.fori_loop(0, live(g), page_at, None)
+
+    def wait(g, slot):
+        def page_at(j, _):  # a wait takes its size from the copy, not its source
+            for dma in copies(slot, j, 0):
+                dma.wait()
+
+        jax.lax.fori_loop(0, live(g), page_at, None)
+
+    def arrive(g):
+        slot = g % 2
+
+        @pl.when(g + 1 < steps)
+        def _ahead():
+            start(g + 1, 1 - slot)
+
+        wait(g, slot)
+        return slot
+
+    return steps, start, arrive
 
 
 def _latent_kernel(layer_ref, off_ref, table_ref, ql_ref, qr_ref, *rest,
-                   page: int, heads: int, tile_c: int, steps: int,
-                   group: int, scale: float, window: int = 0,
-                   masked: bool = False):
-    if masked:  # [tile_c, cols] float32, > 0 where the query may attend
+                   page: int, heads: int, tile_c: int, group: int, ring: int,
+                   scale: float, window: int = 0, masked: bool = False):
+    from jax.experimental.pallas import tpu as pltpu
+
+    if masked:  # [tile_c, context] float32, > 0 where the query may attend
         allow_ref, *rest = rest
-    c_refs, r_refs = rest[:group], rest[group:2 * group]
-    o_ref, o_acc, m_acc, l_acc = rest[2 * group:]
+    c_hbm, r_hbm, o_ref, c_buf, r_buf, sems = rest
     bi = pl.program_id(0)
     ci = pl.program_id(1)
-    si = pl.program_id(2)
-    rows = ql_ref.shape[0]
-    cols = group * page
-    if window:  # the walk starts at the window's first group, not at 0
-        si = si + _window_start(off_ref[bi] + ci * tile_c, window, cols)
+    rows, lat = ql_ref.shape
+    width = group * page
 
-    @pl.when(pl.program_id(2) == 0)
-    def _init():
-        o_acc[:] = jnp.zeros_like(o_acc)
-        m_acc[:] = jnp.full_like(m_acc, _NEG)
-        l_acc[:] = jnp.zeros_like(l_acc)
-
+    # folded row r is (chunk position ci*tile_c + r // heads, head r % heads)
+    # at absolute position off + that; the walk ends at the page of the
+    # tile's last query and starts at 0 or at the window's first page. The
+    # table's width bounds it: queries padded past a chunk may sit past the
+    # last column (a ring has no last column: it wraps)
     first = off_ref[bi] + ci * tile_c
-    max_pos = first + (tile_c - 1)
+    hi = (first + (tile_c - 1)) // page + 1
+    if window:
+        lo = _window_start(first, window, page)
+    else:
+        lo, hi = 0, jnp.minimum(hi, ring)
+    layer = layer_ref[0]
 
-    @pl.when(si * cols <= max_pos)
-    def _acc():
-        kc = jnp.concatenate([r[...] for r in c_refs], axis=0)    # [cols, L]
-        kr = jnp.concatenate([r[...] for r in r_refs], axis=0)    # [cols, R]
-        dims = (((1,), (1,)), ((), ()))
+    def copies(slot, j, src):
+        dst = pl.ds(pl.multiple_of(j * page, page), page)
+        return (pltpu.make_async_copy(c_hbm.at[layer, src], c_buf.at[slot, dst],
+                                      sems.at[0, slot]),
+                pltpu.make_async_copy(r_hbm.at[layer, src], r_buf.at[slot, dst],
+                                      sems.at[1, slot]))
+
+    steps, start, arrive = _page_walk(
+        copies, lambda i: table_ref[bi, i % ring if window else i], lo, hi, group)
+
+    @pl.when(jnp.logical_and(bi == 0, ci == 0))
+    def _finite():
+        # a group's dead pages keep what the slot held: rows of the pool or,
+        # before the call's first copy, whatever VMEM held. Their columns
+        # are masked, but a probability of 0 times a NaN is a NaN, and the
+        # latent rows are the values too
+        c_buf[...] = jnp.zeros_like(c_buf)
+
+    start(0, 0)
+    # the mask, but for the group's first position: ``base + c <= q_pos`` is
+    # ``base <= ahead``, the window's lower bound likewise
+    r = jax.lax.broadcasted_iota(jnp.int32, (rows, width), 0)
+    c = jax.lax.broadcasted_iota(jnp.int32, (rows, width), 1)
+    ahead = first + r // heads - c
+    dims = (((1,), (1,)), ((), ()))
+
+    def body(g, acc):
+        o, m, l = acc
+        slot = arrive(g)
+        kc, kr = c_buf[slot], r_buf[slot]                 # [width, L], [width, R]
         scores = (jax.lax.dot_general(ql_ref[...], kc, dims,
                                       preferred_element_type=jnp.float32)
                   + jax.lax.dot_general(qr_ref[...], kr, dims,
                                         preferred_element_type=jnp.float32)
-                  ) * scale                                       # [rows, cols]
-        r = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 0)
-        c = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 1)
-        keep = si * cols + c <= first + r // heads
+                  ) * scale                               # [rows, width]
+        base = (lo + g * group) * page
+        keep = base <= ahead
         if window:
-            keep = keep & (si * cols + c > first + r // heads - window)
+            keep = keep & (base > ahead - window)
         if masked:
-            allow = jnp.broadcast_to(allow_ref[...][:, None, :],
-                                     (tile_c, heads, cols)).reshape(rows, cols)
-            keep = keep & (allow > 0.0)
+            allow = allow_ref[:, pl.ds(pl.multiple_of(g * width, width), width)]
+            keep = keep & (jnp.broadcast_to(
+                allow[:, None, :], (tile_c, heads, width)).reshape(rows, width) > 0.0)
         scores = jnp.where(keep, scores, _NEG)
-        m = m_acc[:, :1]
         m_new = jnp.maximum(m, scores.max(axis=-1, keepdims=True))
         p = jnp.exp(scores - m_new)
         corr = jnp.exp(m - m_new)
-        l_acc[:] = jnp.broadcast_to(
-            l_acc[:, :1] * corr + p.sum(axis=-1, keepdims=True), l_acc.shape)
-        m_acc[:] = jnp.broadcast_to(m_new, m_acc.shape)
-        o_acc[:] = o_acc[:] * corr + jax.lax.dot_general(
+        return (o * corr + jax.lax.dot_general(
             p.astype(kc.dtype), kc, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+            preferred_element_type=jnp.float32),
+            m_new, l * corr + p.sum(axis=-1, keepdims=True))
 
-    @pl.when(pl.program_id(2) == steps - 1)
-    def _fin():
-        # key 0 is admissible to every query and the first step is always
-        # within the bound, so l is never truly zero (see _paged_kernel);
-        # under a lower bound a query's own key is, in some step of its walk
-        o_ref[...] = (o_acc[:] / jnp.maximum(l_acc[:, :1], 1e-30)
-                      ).astype(o_ref.dtype)
+    # the sums ride the loop: in VMEM scratch a decode call cost 5-9 % more
+    # and a 1,024-row chunk tile 4 % less on a v5e (PERF.md, PR 44)
+    o, _, l = jax.lax.fori_loop(0, steps, body, (
+        jnp.zeros((rows, lat), jnp.float32),
+        jnp.full((rows, 1), _NEG, jnp.float32),
+        jnp.zeros((rows, 1), jnp.float32)))
+    # every query attends its own key at least (an indexer chooses among the
+    # keys not after it and never none), in some step of its walk: what a
+    # step without any key of a query summed meanwhile is scaled to 0 there,
+    # and l is never truly zero
+    o_ref[...] = (o / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit,
@@ -856,101 +971,86 @@ def mla_paged_attention(q_lat, q_rope, c_pages, r_pages, layer, page_table,
 
     q_lat: [B, C, H, L] (queries carried into the latent space), q_rope:
     [B, C, H, R]; c_pages: [layers, pages, page, L], r_pages: [layers,
-    pages, page, R] (the WHOLE pools; ``layer`` picks the slice inside the
-    kernel's index maps). Query i of row b sits at absolute position
-    ``off[b] + i`` and attends keys 0..off+i with score
-    ``(q_lat . c + q_rope . k_r) * scale``. Returns ``sum p c``:
-    [B, C, H, L] in q_lat's dtype — the caller applies ``W_uv``.
+    pages, page, R as held] (the WHOLE pools, left in HBM; the kernel copies
+    ``pool[layer, page]``; a rope key is held in whole 128-lane rows, zeros
+    behind it, and the queries' rope part is padded to that width here).
+    Query i of row b sits at absolute position ``off[b] + i`` and attends
+    keys 0..off+i with score ``(q_lat . c + q_rope . k_r) * scale``. Returns
+    ``sum p c``: [B, C, H, L] in q_lat's dtype — the caller applies ``W_uv``.
+
+    A (row, query tile) program walks its row's live pages itself, in
+    groups, up to its last query's page: a table column past a row's
+    context costs nothing.
 
     ``window`` > 0 (a sliding layer) bounds the keys below too — query at t
     attends ``t - window < s <= t`` — and ``page_table`` is then a RING:
     logical page i sits in column ``i % columns``. A (row, query tile) walks
-    only the page groups its window touches, from the one that holds its
-    first query's oldest key. ``allowed`` [B, C, P * page] (> 0: attend)
-    narrows each query's keys further — an indexer's choice —, the causal
-    bound still applied. ``name`` names the kernel in a trace."""
+    only the pages its window touches, from the one that holds its first
+    query's oldest key. ``allowed`` [B, C, P * page] (> 0: attend) narrows
+    each query's keys further — an indexer's choice —, the causal bound
+    still applied. ``name`` names the kernel in a trace."""
     b, c, h, lat = q_lat.shape
-    rope = q_rope.shape[-1]
-    page = c_pages.shape[2]
-    group = _LATENT_GROUP
+    page, held = c_pages.shape[2], r_pages.shape[-1]
     table = jnp.asarray(page_table, jnp.int32)
     ring = table.shape[1]
-    # rows of a tile: the [rows, L] float32 accumulator and the query and
-    # output blocks grow with the latent width; past 512 the rows shrink
-    cap = _PAGED_ROWS * 512 // max(lat, 512)
-    tile_c = c if c * h <= cap else max(8, cap // h // 8 * 8)
-    if window:
-        # groups from the first query's oldest key to the tile's last query
-        steps = (window + tile_c - 2) // (group * page) + 2
-    else:
-        steps = -(-ring // group)
-        if steps * group != ring:
-            # padding entries name the scratch page; the causal bound hides them
-            table = jnp.pad(table, ((0, 0), (0, steps * group - ring)))
+    tile_c = latent_query_tile(c, h, lat)
     c_pad = -(-c // tile_c) * tile_c
-    if c_pad != c:
-        pad = ((0, 0), (0, c_pad - c), (0, 0), (0, 0))
-        q_lat, q_rope = jnp.pad(q_lat, pad), jnp.pad(q_rope, pad)
     rows = tile_c * h
+    group = _latent_group(tile_c, h, page, lat, held, c_pages.dtype.itemsize,
+                          window)
+    pad = ((0, 0), (0, c_pad - c), (0, 0))
+    # padded queries sit past the chunk: finite garbage, sliced off below;
+    # padded lanes meet a held key's zeros
+    q_lat = jnp.pad(q_lat, (*pad, (0, 0)))
+    q_rope = jnp.pad(q_rope, (*pad, (0, held - q_rope.shape[-1])))
     from jax.experimental.pallas import tpu as pltpu
 
-    def _q_index(bi, ci, si, *_):
+    def _q_index(bi, ci, *_):
         return (bi, ci, 0)
 
     narrowed = []
     if allowed is not None:
-        cols = group * page
-        allowed = jnp.pad(allowed.astype(jnp.float32), (
-            (0, 0), (0, c_pad - c), (0, steps * cols - allowed.shape[-1])))
-        narrowed = [(allowed, pl.BlockSpec(
-            (None, tile_c, cols), lambda bi, ci, si, *_: (bi, ci, si)))]
-
-    def _page_index(g):
-        def index(bi, ci, si, layer_ref, off_ref, table_ref):
-            first = off_ref[bi] + ci * tile_c
-            max_pos = first + (tile_c - 1)
-            if window:
-                si = si + _window_start(first, window, group * page)
-            pi = si * group + g
-            col = pi % ring if window else pi
-            return (layer_ref[0],
-                    jnp.where(pi * page <= max_pos, table_ref[bi, col], 0),
-                    0, 0)
-        return index
-
+        # whole groups: the walk's last reads its lanes past the context
+        ctx = -(-ring // group) * group * page
+        narrowed = [(jnp.pad(allowed.astype(jnp.float32), (
+            *pad[:2], (0, ctx - allowed.shape[-1]))), pl.BlockSpec(
+                (None, tile_c, ctx), _q_index))]
     kernel = functools.partial(
-        _latent_kernel, page=page, heads=h, tile_c=tile_c, steps=steps,
-        group=group, scale=float(scale), window=window,
+        _latent_kernel, page=page, heads=h, tile_c=tile_c, group=group,
+        ring=ring, scale=float(scale), window=window,
         masked=allowed is not None)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(b, c_pad // tile_c, steps),
+        grid=(b, c_pad // tile_c),
         in_specs=[
             pl.BlockSpec((None, rows, lat), _q_index),
-            pl.BlockSpec((None, rows, rope), _q_index),
+            pl.BlockSpec((None, rows, held), _q_index),
             *[spec for _, spec in narrowed],
-            *[pl.BlockSpec((None, None, page, lat), _page_index(g))
-              for g in range(group)],
-            *[pl.BlockSpec((None, None, page, rope), _page_index(g))
-              for g in range(group)],
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec((None, rows, lat), _q_index),
         scratch_shapes=[
-            pltpu.VMEM((rows, lat), jnp.float32),
-            pltpu.VMEM((rows, 128), jnp.float32),
-            pltpu.VMEM((rows, 128), jnp.float32),
+            pltpu.VMEM((2, group * page, lat), c_pages.dtype),
+            pltpu.VMEM((2, group * page, held), r_pages.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
         ],
     )
+    # programs run one after another on one core: the latent slots are
+    # zeroed by the first and carry pool rows from then on (``_finite``)
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, c_pad * h, lat), q_lat.dtype),
         interpret=interpret,
         name=name,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=2 * _walk_budget() + (8 << 20)),
     )(jnp.asarray(layer, jnp.int32).reshape(1), jnp.asarray(off, jnp.int32),
       table, q_lat.reshape(b, c_pad * h, lat),
-      q_rope.reshape(b, c_pad * h, rope), *[a for a, _ in narrowed],
-      *[c_pages] * group, *[r_pages] * group)
+      q_rope.reshape(b, c_pad * h, held), *[a for a, _ in narrowed],
+      c_pages, r_pages)
     return out.reshape(b, c_pad, h, lat)[:, :c]
 
 

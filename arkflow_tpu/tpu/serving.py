@@ -595,12 +595,13 @@ class GenerationServer:
                              "padded positions and idle lanes a step carried "
                              "past the states")))
             for kind in ("decode", "chunk")}
-        # the per-head kernel walks a row's kept pages up to its last query
-        # and no further (ops/ragged_attention.paged_flash_attention): pages
-        # walked beside the table's columns, a layer, from lengths on the
-        # host. Their ratio is the live share of the table (a chunk's
-        # earlier query tiles stop sooner than its last, which is counted)
-        self.m_attn_walk = {} if cfg.latent or self.decode_kernel != "paged" else {
+        # the attention kernel walks a row's kept pages up to its last query
+        # and no further (ops/ragged_attention: paged_flash_attention, and
+        # since PR 44 a latent model's mla_paged_attention): pages walked
+        # beside the table's columns, a layer, from lengths on the host.
+        # Their ratio is the live share of the table (a chunk's earlier
+        # query tiles stop sooner than its last, which is counted)
+        self.m_attn_walk = {} if self.decode_kernel != "paged" else {
             kind: tuple(reg.counter(metric, text, {"model": name, "kind": kind})
                         for metric, text in (
                             ("arkflow_gen_attn_pages_walked_total",
@@ -610,10 +611,11 @@ class GenerationServer:
                              "kept page-table columns of the rows the "
                              "attention kernel was called with, a layer")))
             for kind in ("decode", "chunk")}
-        # the (row, query tile) programs of those calls, a layer, by the
-        # product each makes: a K/V head at a time over that head's own query
-        # rows, or all heads at once under a mask — the kernel's own
-        # predicate on the step's shapes as one chip sees them
+        # the (row, query tile) programs of the per-head kernel's calls, a
+        # layer, by the product each makes: a K/V head at a time over that
+        # head's own query rows, or all heads at once under a mask — the
+        # kernel's own predicate on the step's shapes as one chip sees them
+        # (a latent row has one shared head: nothing to cut, none counted)
         self._tiles_of: dict[int, dict[str, int]] = {}
         self.m_attn_tiles = {
             (kind, product): reg.counter(
@@ -621,7 +623,7 @@ class GenerationServer:
                 "(row, query tile) programs of the attention kernel, summed "
                 "over layers, by the product a tile makes",
                 {"model": name, "kind": kind, "product": product})
-            for kind in self.m_attn_walk
+            for kind in ({} if cfg.latent else self.m_attn_walk)
             for product in ("per_kv_head", "all_heads")}
         # a sink joins the softmax of every query of its kind's layers: the
         # rows (queries x layers of a kind with a sink) that went through

@@ -354,20 +354,49 @@ def test_dense_steps_carry_the_pools_whole(v5e, layers, tp):
 
 KANANA_HEADS, KANANA_LATENT, KANANA_ROPE = 32, 512, 64
 KANANA_EXPERTS, KANANA_DIM, KANANA_WIDTH = 130, 2048, 768  # 128 routed + 2 shared
+#: a latent model's rope keys as its pools hold them (``cache_spec``): whole
+#: 128-lane rows, which the kernel's own walk can copy a page of
+ROPE_HELD = 128
+#: no copy of a pool (0.2 GB and up) survives around a latent kernel's call:
+#: the queries' rope part padded to the held width and the indexer's choice
+#: padded to whole groups are its largest temporaries
+LATENT_TEMP_LIMIT = 64 * 1024 * 1024
+
+
+def _latent_compiled(v5e, rows, chunk, heads, latent, pool, table, rope_held=ROPE_HELD,
+                     masked=False, **kw):
+    """``mla_paged_attention`` as served, compiled: the kernel walks the
+    table itself, its group's two slots, score tiles and carried sums inside
+    the VMEM limit the call asks for (the chip's compiler refuses a kernel
+    that is not)."""
+    from arkflow_tpu.ops.ragged_attention import mla_paged_attention
+
+    layers, pages = pool
+    allowed = [((rows, chunk, table * 16), jnp.float32)] if masked else []
+    return _compile(
+        lambda ql, qr, cp, rp, layer, table, off, *allowed: mla_paged_attention(
+            ql, qr, cp, rp, layer, table, off, allowed=(allowed or (None,))[0], **kw),
+        v5e, ((rows, chunk, heads, latent), BF16), ((rows, chunk, heads, 64), BF16),
+        ((layers, pages, 16, latent), BF16), ((layers, pages, 16, rope_held), BF16),
+        ((), I32), ((rows, table), I32), ((rows,), I32), *allowed)
 
 
 @pytest.mark.parametrize("rows,chunk", [(16, 1), (1, 128)], ids=["decode", "chunk128"])
 def test_mla_paged_attention_compiles(v5e, rows, chunk):
-    from arkflow_tpu.ops.ragged_attention import mla_paged_attention
-
-    compiled = _compile(
-        lambda ql, qr, cp, rp, layer, table, off: mla_paged_attention(
-            ql, qr, cp, rp, layer, table, off, scale=192 ** -0.5),
-        v5e, ((rows, chunk, KANANA_HEADS, KANANA_LATENT), BF16),
-        ((rows, chunk, KANANA_HEADS, KANANA_ROPE), BF16),
-        ((6, 2177, 16, KANANA_LATENT), BF16), ((6, 2177, 16, KANANA_ROPE), BF16),
-        ((), I32), ((rows, 136), I32), ((rows,), I32))
+    compiled = _latent_compiled(v5e, rows, chunk, KANANA_HEADS, KANANA_LATENT,
+                                (6, 2177), 136, scale=192 ** -0.5)
     assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < LATENT_TEMP_LIMIT
+
+
+def test_latent_rope_keys_of_64_lanes_are_not_walked(v5e):
+    """Why the rope keys are held in whole 128-lane rows: the kernel's own
+    copy of a page out of a 64-lane pool is refused (Mosaic sees the pool
+    padded to 128 lanes and slices it in whole rows only), out of the held
+    pool it compiles (the case above)."""
+    with pytest.raises(Exception, match="aligned to tiling"):
+        _latent_compiled(v5e, 16, 1, KANANA_HEADS, KANANA_LATENT, (6, 2177), 136,
+                         rope_held=KANANA_ROPE, scale=192 ** -0.5)
 
 
 @pytest.mark.parametrize("tokens", [16, 128, 300], ids=["decode", "chunk128", "tiled"])
@@ -396,18 +425,14 @@ STEPS = pytest.mark.parametrize("rows,chunk", [(32, 1), (1, 512)],
 @STEPS
 def test_swa_latent_attention_compiles(v5e, rows, chunk):
     """The sliding layers' window attention: 64 heads, latent 1,024, the
-    lower bound (513) over a ring of 65 window pages."""
-    from arkflow_tpu.ops.ragged_attention import mla_paged_attention
-
-    compiled = _compile(
-        lambda ql, qr, cp, rp, layer, ring, off: mla_paged_attention(
-            ql, qr, cp, rp, layer, ring, off, scale=256 ** -0.5, window=513,
-            name="swa_latent_attention"),
-        v5e, ((rows, chunk, 64, 1024), BF16), ((rows, chunk, 64, 64), BF16),
-        ((3, 2081, 16, 1024), BF16), ((3, 2081, 16, 64), BF16),
-        ((), I32), ((rows, 65), I32), ((rows,), I32))
+    lower bound (513) over a ring of 65 window pages, a tile's whole walk
+    one group."""
+    compiled = _latent_compiled(v5e, rows, chunk, 64, 1024, (3, 2081), 65,
+                                scale=256 ** -0.5, window=513,
+                                name="swa_latent_attention")
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "swa_latent_attention" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < LATENT_TEMP_LIMIT
 
 
 @pytest.mark.parametrize("rows,chunk", [(32, 1), (1, 64)], ids=["decode", "tile64"])
@@ -447,20 +472,15 @@ def test_dsa_sparse_attention_compiles(v5e, rows, chunk, pages):
     """The indexed layers' attention: the latent kernel in place under the
     indexer's choice (a float32 mask over the slot's table), 128 heads; at
     the cell's 12,544-token table and at a 32,768-token one (the one form
-    serves every context)."""
-    from arkflow_tpu.ops.ragged_attention import mla_paged_attention
-
-    pool = 32 * pages + 1
-    compiled = _compile(
-        lambda ql, qr, cp, rp, layer, table, off, allowed: mla_paged_attention(
-            ql, qr, cp, rp, layer, table, off, scale=192 ** -0.5,
-            allowed=allowed, name="dsa_sparse_attention"),
-        v5e, ((rows, chunk, 128, 512), BF16), ((rows, chunk, 128, 64), BF16),
-        ((2, pool, 16, 512), BF16), ((2, pool, 16, 64), BF16), ((), I32),
-        ((rows, pages), I32), ((rows,), I32),
-        ((rows, chunk, pages * 16), jnp.float32))
+    serves every context: the choice rides as one [tile_c, context] block a
+    program, 1 MB a chunk tile at 32k)."""
+    compiled = _latent_compiled(v5e, rows, chunk, 128, 512, (2, 32 * pages + 1),
+                                pages, masked=True, scale=192 ** -0.5,
+                                name="dsa_sparse_attention")
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "dsa_sparse_attention" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < (
+        LATENT_TEMP_LIMIT if pages == DOTS_PAGES else 3 * LATENT_TEMP_LIMIT)
 
 
 # -- the recurrent-state kind (Falcon-H1-34B widths, 128 slots, chunk 256) ----

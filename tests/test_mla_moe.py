@@ -311,7 +311,7 @@ def test_chunked_prefill_then_decode_matches_reference(params, kern):
     pages_per = 5
     table = jnp.asarray(_tables(3, pages_per))
     kp, vp = init_page_pool(CFG, 1 + 3 * pages_per, PAGE)
-    assert kp.shape[-1] + vp.shape[-1] == 20 and kp.ndim == 4
+    assert (kp.shape[-1], vp.shape[-1]) == (16, 128) and kp.ndim == 4  # rope as held
     chunk = jax.jit(lambda p, *a: paged_prefill_chunk(p, CFG, *a, **kern))
     step = jax.jit(lambda p, *a: paged_decode_step(
         p, CFG, *a, return_logits=True, **kern))
@@ -462,7 +462,7 @@ def test_server_counters_equal_a_hand_count():
     assert abs(delta["prefill"][2] - hits) <= 0.5  # a near-tie may move one
     gauge = global_registry().gauge("arkflow_gen_kv_bytes_per_token",
                                     labels={"model": "decoder_lm"})
-    assert gauge.value == kv_bytes_per_token(proc.cfg) == 2 * 20 * 3
+    assert gauge.value == kv_bytes_per_token(proc.cfg) == 2 * (16 + 128) * 3  # as held
 
 
 def _reference_hits(params, ids, hp) -> float:

@@ -172,23 +172,25 @@ def _tables(cfg, rows: int, pages_per: int, step: int):
 def test_cache_spec_states_three_kinds_of_row():
     pools = {p.name: p for p in cache_spec(CFG)}
     assert set(pools) == {"latent", "index", "window"}
-    assert (pools["latent"].layers, pools["latent"].widths) == (2, (16, 4))
+    # a rope key (4 lanes here, 64 published) is HELD in whole 128-lane rows
+    assert (pools["latent"].layers, pools["latent"].widths) == (2, (16, 128))
     assert (pools["index"].layers, pools["index"].widths) == (2, (8,))
     assert (pools["window"].layers, pools["window"].widths,
-            pools["window"].window) == (3, (24, 4), 9)
-    assert kv_bytes_per_token(CFG) == 2 * (2 * 20 + 2 * 8 + 3 * 28)
+            pools["window"].window) == (3, (24, 128), 9)
+    assert kv_bytes_per_token(CFG) == 2 * (2 * 144 + 2 * 8 + 3 * 152)
     wide, rope = init_page_pool(CFG, 7, PAGE, window_pages=5)
     assert {k: v.shape for k, v in wide.items()} == {
         "latent": (2, 7, PAGE, 16), "index": (2, 7, PAGE, 8),
         "window": (3, 5, PAGE, 24)}
     assert {k: v.shape for k, v in rope.items()} == {
-        "latent": (2, 7, PAGE, 4), "window": (3, 5, PAGE, 4)}
-    # the published dots3 sizes: bytes a token a layer, as the issue counts
+        "latent": (2, 7, PAGE, 128), "window": (3, 5, PAGE, 128)}
+    # the published dots3 sizes: bytes a token a layer as held (1,152 and
+    # 2,176 of them needed: the rope key's 64 lanes of 128)
     big = dataclasses.replace(
         CFG, kv_lora_rank=512, qk_rope_head_dim=64, index_head_dim=128,
         swa_kv_lora_rank=1024, swa_qk_rope_head_dim=64, sliding_window=513)
     by = {p.name: p.bytes_per_token // p.layers for p in cache_spec(big)}
-    assert by == {"latent": 1152, "index": 256, "window": 2176}
+    assert by == {"latent": 1280, "index": 256, "window": 2304}
     assert window_ring_pages(big, 16, 512) == (513 + 512 - 2) // 16 + 2
     # a model without a pattern keeps its two arrays
     plain = dec.DecoderConfig(**{k: v for k, v in TINY.items() if k in (
@@ -601,7 +603,8 @@ def test_serve_dtypes_cover_every_leaf_and_state_the_choosers_float32():
 #: sha256 (first 16 hex) of the tiny Kanana-2 layout's outputs at the parent
 #: commit (PR 30): the full forward's logits; three prefill chunks and three
 #: decode steps through the cache (logits, counters), gather then paged
-#: kernels; a one-shot prefill's logits and both pools
+#: kernels; a one-shot prefill's logits and both pools (the rope keys' own
+#: lanes: since PR 44 the pool holds them in 128-lane rows, zeros behind)
 PLAIN_GOLDEN = [
     '1681f486121feaa8', 'e5b551f1b54fc661', '7db5e01f1a915efa', '12d0ce652901d025',
     'fd454e1abc1dbd04', '9b67eade2e001352', 'b0fbac695a7e85fa', '7bc4815f061fa0d7',
@@ -641,7 +644,9 @@ def _plain_outputs():
     logits, kp, vp, st = paged_prefill(
         params, cfg, jnp.asarray(ids[None, :16]), jnp.asarray([13]), table,
         kp, vp, return_logits=True)
-    return out + [np.asarray(logits), np.asarray(kp), np.asarray(vp, np.float32)]
+    vp = np.asarray(vp, np.float32)
+    assert vp.shape[-1] == 128 and not vp[..., 4:].any()
+    return out + [np.asarray(logits), np.asarray(kp), vp[..., :4]]
 
 
 def test_a_model_without_a_pattern_gives_bit_identical_outputs():

@@ -384,15 +384,20 @@ def test_window_0_gives_the_present_jaxpr(case):
     assert _text_hash(text) == WINDOW_0_GOLDEN[case]
 
 
-#: the same hashes of programs that never call the per-head kernel, recorded
-#: at PR 41's parent before that kernel was touched: a tiny latent model (the
-#: kanana2_l6 layout) and a tiny latent layer pattern with indexed full layers
-#: and sliding layers (the dots3_l5 layout), through their Pallas kernels. What
-#: "their programs are unchanged" means for the cells that bypass a change to
-#: ``paged_flash_attention``.
+#: the same hashes of programs that never call the per-head kernel: a tiny
+#: latent model (the kanana2_l6 layout) and a tiny latent layer pattern with
+#: indexed full layers and sliding layers (the dots3_l5 layout), through their
+#: Pallas kernels. What "their programs are unchanged" means for the cells
+#: that bypass a change to ``paged_flash_attention``. RE-RECORDED at PR 44,
+#: on purpose: that PR changed THESE programs — the latent kernel walks the
+#: table itself (its grid lost the page-group axis) and the rope keys' pools
+#: are 128 lanes wide — and left every per-head golden above as it stood:
+#: the bypass ran the other way. (Recorded at PR 41's parent, and standing
+#: through PR 43: 3a0f9a616b1af93d, c697262a43550287, 4394292e666c84a0,
+#: 158c1079bde200c3.)
 BYPASS_GOLDEN = {
-    "latent.decode": "3a0f9a616b1af93d", "latent.chunk": "c697262a43550287",
-    "pattern.decode": "4394292e666c84a0", "pattern.chunk": "158c1079bde200c3"}
+    "latent.decode": "ccb2ee94b5d8cfe9", "latent.chunk": "8f160b67834088ca",
+    "pattern.decode": "5ed8445db227631b", "pattern.chunk": "f4474904b5691023"}
 
 
 @pytest.mark.parametrize("case", sorted(BYPASS_GOLDEN))

@@ -1,10 +1,12 @@
-"""Time ``paged_flash_attention`` alone, at the shapes the benchmark's cells serve.
+"""Time the paged attention kernels alone, at the shapes the benchmark's cells serve.
 
-The kernel-alone microbench behind PERF.md's S11 numbers (PR 41, 42 and 43
-each needed it): one call a layer of the per-head paged kernel — a decode
-step over many lanes, or one row's prefill chunk at an offset — at a cell's
-head geometry, table width and lane count, on the chip this process holds.
-One JSON line a point:
+The kernel-alone microbench behind PERF.md's S11 / S13 numbers (PR 41, 42,
+43 and 44 each needed it): one call a layer of the per-head paged kernel
+(``paged_flash_attention``) or of the latent one (``mla_paged_attention``:
+``kanana2_l6``'s plain form, ``dots3_l5``'s under the indexer's choice and
+under a window) — a decode step over many lanes, or one row's prefill chunk
+at an offset — at a cell's geometry, table width and lane count, on the chip
+this process holds. One JSON line a point:
 
 - ``us_per_call``: best of ``--rounds`` timed loops of ``--reps`` calls
   (``lax.fori_loop`` over the pool's layers inside ONE jitted program, so
@@ -15,14 +17,16 @@ One JSON line a point:
   the call's time: a decode call's roof. A chunk re-reads its context a
   query tile, so its share of that roof says how far it is from one read;
 - ``per_kv_head``, ``tile_c``, ``tiles``, ``group``: how the call was cut
-  (``ops/ragged_attention.per_kv_head``, ``_page_group``).
+  (``ops/ragged_attention.per_kv_head``, ``_page_group``; a latent point:
+  ``group`` alone, ``_latent_group``).
 
     python tools/profile_paged_attention.py                      # every point
     python tools/profile_paged_attention.py --cell mimo_l7_full --kind chunk
     python tools/profile_paged_attention.py --group-max 16,32,64 # a sweep
 
-``--group-max`` sets the module's two group ceilings for the run (a
-microbench's lever, not a serving knob). ``--check`` compares each point's
+``--group-max`` sets the module's group ceilings for the run (a
+microbench's lever, not a serving knob; for a latent point it IS the group,
+under a window too, where the kernel would take a tile's whole walk). ``--check`` compares each point's
 output with the gather reference on the device. Needs a TPU: on the CPU the
 kernel is interpreted and a time says nothing (``--interpret`` runs tiny
 shapes there to rehearse the control flow; its lines say ``"rehearsal"``).
@@ -64,6 +68,16 @@ CELLS = {
     "mimo_l7_window": dict(h=64, kvh=8, dk=192, dv=128, window=128, sink=True,
                            lanes=64, cols=41, chunk=512, offsets=(4096,),
                            ctx=(4600, 0.6, 1100, 13300)),
+    # the latent kernel (``lat``: one shared row a token of that width beside
+    # a rope key of ``rope``; ``topk``: under an indexer's choice of so many)
+    "kanana2_l6": dict(h=32, lat=512, rope=64, lanes=16, cols=136, chunk=128,
+                       offsets=(512, 1920), ctx=(640, 0.6, 190, 2176)),
+    "dots3_l5_full": dict(h=128, lat=512, rope=64, topk=2048, lanes=32, cols=784,
+                          chunk=512, offsets=(2048, 4096, 11776),
+                          ctx=(4800, 0.5, 2400, 12500)),
+    "dots3_l5_window": dict(h=64, lat=1024, rope=64, window=513, lanes=32,
+                            cols=65, chunk=512, offsets=(4096,),
+                            ctx=(4800, 0.5, 2400, 12500)),
 }
 
 
@@ -78,14 +92,24 @@ def _points(args):
                 yield name, cell, "chunk", off
 
 
-def _pools(cell, seed: int, tiny: bool):
+def _pools(cell, seed: int, tiny: bool, rope_held: bool = True):
     """A cell's K and V pools (two layers, every lane's columns once, a key
-    of 192 in two parts of 128 lanes) and its sink logits."""
+    of 192 in two parts of 128 lanes) and its sink logits; a latent cell's
+    latent rows and rope keys, the keys in whole 128-lane rows with zeros
+    behind them where the checkout holds them so (``rope_held``)."""
     import jax
     import jax.numpy as jnp
 
     lanes, cols = (min(cell["lanes"], 3), min(cell["cols"], 24)) if tiny else (
         cell["lanes"], cell["cols"])
+    if "lat" in cell:
+        keys = jax.random.split(jax.random.PRNGKey(seed), 2)
+        pool = (2, 1 + lanes * cols, PAGE)
+        r = jax.random.normal(keys[1], pool + (cell["rope"],), jnp.bfloat16) * 0.5
+        if rope_held:
+            r = jnp.pad(r, ((0, 0),) * 3 + ((0, -cell["rope"] % 128),))
+        return (jax.random.normal(keys[0], pool + (cell["lat"],), jnp.bfloat16) * 0.5,
+                r, None, lanes, cols)
     dk, dv, kvh = cell["dk"], cell["dv"], cell["kvh"]
     parts, held = (1, dk) if dk % 128 == 0 else (-(-dk // 128), 128)
     keys = jax.random.split(jax.random.PRNGKey(seed), 3)
@@ -111,10 +135,25 @@ def _queries(cell, kind, off, rng, lanes: int, cols: int, tiny: bool):
         ctx = np.full((b,), off, np.float64)
     if not cell.get("window", 0):
         ctx = np.minimum(ctx, cols * PAGE - c)
-    q = jax.random.normal(jax.random.PRNGKey(int(rng.randint(1 << 30))),
-                          (b, c, cell["h"], cell["dk"]), jnp.bfloat16)
-    table = 1 + rng.permutation(lanes * cols)[:b * cols].reshape(b, cols)
-    return q, jnp.asarray(table, jnp.int32), jnp.asarray(ctx.astype(np.int32))
+    key = jax.random.PRNGKey(int(rng.randint(1 << 30)))
+    table = jnp.asarray(
+        1 + rng.permutation(lanes * cols)[:b * cols].reshape(b, cols), jnp.int32)
+    off = jnp.asarray(ctx.astype(np.int32))
+    if "lat" not in cell:
+        return (jax.random.normal(key, (b, c, cell["h"], cell["dk"]), jnp.bfloat16),
+                table, off)
+    kl, kr, ka = jax.random.split(key, 3)
+    q = tuple(jax.random.normal(k, (b, c, cell["h"], w), jnp.bfloat16) * 0.2
+              for k, w in ((kl, cell["lat"]), (kr, cell["rope"])))
+    if not cell.get("topk"):
+        return q, table, off
+    # an indexer's choice: ``topk`` of a query's seen keys on average, drawn
+    # key by key (every 16-token page holds one past a few thousand keys)
+    pos = off[:, None] + jnp.arange(c)[None, :]                      # [b, c]
+    seen = jnp.arange(cols * PAGE)[None, None, :] <= pos[..., None]
+    u = jax.random.uniform(ka, seen.shape)
+    allowed = seen & (u * (pos[..., None] + 1) < cell["topk"])
+    return (*q, allowed.astype(jnp.float32)), table, off
 
 
 def _held_bytes(cell, ctx, c) -> float:
@@ -124,6 +163,10 @@ def _held_bytes(cell, ctx, c) -> float:
     window = cell.get("window", 0)
     last = ctx.astype(np.int64) + c
     keys = np.minimum(last, window + c - 1) if window else last
+    if "lat" in cell:  # needed bytes: the rope key at its own width
+        if cell.get("topk"):
+            keys = np.minimum(keys, cell["topk"] + c - 1)
+        return float(keys.sum()) * (cell["lat"] + cell["rope"]) * 2
     dk = cell["dk"] if cell["dk"] % 128 == 0 else -(-cell["dk"] // 128) * 128
     return float(keys.sum()) * cell["kvh"] * (dk + cell["dv"]) * 2
 
@@ -151,6 +194,30 @@ def _reference(q, k, v, table, off, cell):
                           ).reshape(c, h, cell["dv"])
 
     return jax.lax.map(row, (q, table, off))
+
+
+def _latent_reference(q, c_pool, r_pool, table, off, cell):
+    """The latent kernel's plain-XLA twin (layer 1), a row at a time."""
+    import jax
+    import jax.numpy as jnp
+
+    from arkflow_tpu.models.paged_decode import _masked_latent_attention
+
+    q_lat, q_rope, *allowed = q
+    c = q_lat.shape[1]
+
+    def row(args):
+        q_lat, q_rope, table, off, *allowed = args
+        cc = c_pool[1][table].reshape(1, -1, cell["lat"])
+        rr = r_pool[1][table][..., :cell["rope"]].reshape(1, -1, cell["rope"])
+        mask = jnp.arange(cc.shape[1])[None, :] <= off + jnp.arange(c)[:, None]
+        if allowed:
+            mask = mask & (allowed[0] > 0)
+        return _masked_latent_attention(
+            q_lat[None], q_rope[None], cc, rr, mask[None, None],
+            (128 + cell["rope"]) ** -0.5)[0]
+
+    return jax.lax.map(row, (q_lat, q_rope, table, off, *allowed))
 
 
 def main() -> int:
@@ -192,16 +259,28 @@ def main() -> int:
     for name, cell, kind, off in _points(args):
         if held_for != name:  # one cell's pools on the device at a time
             k = v = sink = None
-            k, v, sink, lanes, cols = _pools(cell, args.seed, args.interpret)
+            k, v, sink, lanes, cols = _pools(cell, args.seed, args.interpret,
+                                             hasattr(ra, "_latent_group"))
             held_for = name
         q, table, ctx = _queries(cell, kind, off, rng, lanes, cols, args.interpret)
-        b, c, h, _ = q.shape
+        latent = "lat" in cell
+        b, c, h, _ = (q[0] if latent else q).shape
         window = cell.get("window", 0)
         for ceiling in ceilings:
             if ceiling:
                 ra._PAGED_GROUP_MAX = ra._PAGED_CHUNK_GROUP_MAX = ceiling
+                if hasattr(ra, "_latent_group"):  # the walk's group, a window's too
+                    ra._latent_group = lambda *_, ceiling=ceiling: ceiling
+                else:  # the grid's pages a step
+                    ra._LATENT_GROUP = ceiling
 
             def call(layer, q, k, v, table, ctx, sink):
+                if latent:
+                    q_lat, q_rope, *allowed = q
+                    return ra.mla_paged_attention.__wrapped__(
+                        q_lat, q_rope, k, v, layer, table, ctx,
+                        scale=(128 + cell["rope"]) ** -0.5, window=window,
+                        interpret=args.interpret, allowed=(allowed or [None])[0])
                 return ra.paged_flash_attention.__wrapped__(
                     q, k, v, layer, table, ctx, interpret=args.interpret,
                     window=window, sink=sink)
@@ -210,14 +289,18 @@ def main() -> int:
                 def body(i, acc):
                     return acc + call(i % 2, *operands).astype(jnp.float32)
                 return jax.lax.fori_loop(0, reps, body, jnp.zeros(
-                    (b, c, h, cell["dv"]), jnp.float32))
+                    (b, c, h, cell["lat" if latent else "dv"]), jnp.float32))
 
             operands = (q, k, v, table, ctx, sink)
             line = {"cell": name, "kind": kind, "lanes": b, "chunk": c,
                     "offset": off, "ctx_mean": float(ctx.mean()),
                     **({"group_max": ceiling} if ceiling else {}),
                     "device": device.device_kind}
-            if hasattr(ra, "per_kv_head"):  # a checkout that cuts tiles per head
+            if latent and hasattr(ra, "_latent_group"):  # a checkout that walks
+                tile_c = ra.latent_query_tile(c, h, cell["lat"])
+                line.update(tile_c=tile_c, tiles=-(-c // tile_c), group=ra._latent_group(
+                    tile_c, h, PAGE, cell["lat"], v.shape[-1], 2, window))
+            elif not latent and hasattr(ra, "per_kv_head"):  # cuts tiles per head
                 tile_c = ra.query_tile(c, h)
                 per_head = ra.per_kv_head(tile_c, h, cell["kvh"])
                 line.update(
@@ -231,8 +314,9 @@ def main() -> int:
                 # a ring's table and a sink have no plain twin here: the
                 # tests hold them (tests/test_paged_kernel.py)
                 got = jax.jit(call)(1, *operands).astype(jnp.float32)
-                line["max_abs_err"] = float(jnp.abs(
-                    got - _reference(q, k, v, table, ctx, cell)).max())
+                want = (_latent_reference if latent else _reference)(
+                    q, k, v, table, ctx, cell)
+                line["max_abs_err"] = float(jnp.abs(got - want).max())
             if args.interpret:
                 line["rehearsal"] = True
             else:
