@@ -321,15 +321,7 @@ def _validate_tuner(processors: list[dict]) -> None:
             continue
         if p.get("tuner") is None:
             continue
-        cfg = parse_tuner_config(p["tuner"], who="tpu_inference")
-        mesh = p.get("mesh")
-        pp = mesh.get("pp", 1) if isinstance(mesh, Mapping) else 1
-        if cfg is not None and cfg.enabled and isinstance(pp, int) and pp > 1:
-            raise ConfigError(
-                "tpu_inference: 'tuner' does not compose with mesh pp "
-                "(pipelined stages serve one schedule at a time; a warm "
-                "compile would interleave collectives with the live GPipe "
-                "ring)")
+        parse_tuner_config(p["tuner"], who="tpu_inference")
 
 
 def _validate_remote_tpu(processors: list[dict]) -> None:
@@ -353,27 +345,31 @@ def _validate_remote_tpu(processors: list[dict]) -> None:
 #: validation at parse time never drags jax into `--validate`
 _DECODER_LM_DEFAULT_KV_HEADS = 4
 
-#: model-family layer-count defaults, mirrored (not imported — jax) so the
-#: pp stage-count check runs at parse time; an unknown model defers the
-#: check to stream build, where the runner counts the real layer stack
-_MODEL_DEFAULT_LAYERS = {"bert_classifier": 12, "decoder_lm": 4}
+#: what selected pipelined-segmentation serving on ``tpu_inference`` beside
+#: ``mesh.pp``: a config that still carries one is refused, not served as
+#: something else
+_PP_SERVING_KEYS = ("pp_microbatch_rows", "pp_layer_costs", "pp_profile")
+
+PP_SERVING_REMOVED = (
+    "tpu_inference: pipelined-segmentation serving (mesh pp > 1, "
+    + ", ".join(_PP_SERVING_KEYS) + ") was removed in PR 45; serve several "
+    "chips with mesh: {dp: N} or device_pool: N (mesh pp remains tpu_train's)")
+
+
+def refuse_pp_serving(p: Mapping) -> None:
+    """The one refusal of the removed mode, for ``--validate`` and the
+    processor's build alike."""
+    mesh = p.get("mesh")
+    pp = mesh.get("pp", 1) if isinstance(mesh, Mapping) else 1
+    if (isinstance(pp, int) and pp > 1) or any(k in p for k in _PP_SERVING_KEYS):
+        raise ConfigError(PP_SERVING_REMOVED)
 
 
 def _validate_inference_mesh(processors: list[dict]) -> None:
     """Parse-time checks for multi-chip ``tpu_inference`` serving, looking
-    through ``fault.inner`` chaos wrappers like the other cross-checks:
-
-    - mesh axis values must be positive ints;
-    - ``pp`` (pipelined model segmentation) composes with ``dp`` only —
-      tp/sp alongside pp, ``device_pool`` on the same processor, and
-      ``packing`` all fail here with a clear message instead of a build
-      error after jax loads;
-    - ``pp`` must not exceed the model's layer count (each stage needs at
-      least one layer), checked against ``model_config.layers`` or the
-      family default when the config leaves it unset;
-    - the pp knobs (``pp_microbatch_rows`` / ``pp_layer_costs`` /
-      ``pp_profile``) are type-checked so ``--validate`` catches them.
-    """
+    through ``fault.inner`` chaos wrappers like the other cross-checks: mesh
+    axis values must be positive ints, and the removed pipelined mode is
+    refused by name."""
     for p in processors:
         while (isinstance(p, Mapping) and p.get("type") == "fault"
                and isinstance(p.get("inner"), Mapping)):
@@ -381,66 +377,15 @@ def _validate_inference_mesh(processors: list[dict]) -> None:
         if not isinstance(p, Mapping) or p.get("type") != "tpu_inference":
             continue
         mesh = p.get("mesh")
-        if mesh is None:
-            continue
-        if not isinstance(mesh, Mapping):
+        if mesh is not None and not isinstance(mesh, Mapping):
             raise ConfigError(
                 f"tpu_inference.mesh must be a mapping, got {mesh!r}")
-        axes: dict[str, int] = {}
         for k in ("dp", "tp", "sp", "pp"):
-            v = mesh.get(k, 1)
+            v = (mesh or {}).get(k, 1)
             if isinstance(v, bool) or not isinstance(v, int) or v < 1:
                 raise ConfigError(
                     f"tpu_inference.mesh.{k} must be a positive int, got {v!r}")
-            axes[k] = v
-        if axes["pp"] <= 1:
-            continue
-        for axis in ("tp", "sp"):
-            if axes[axis] > 1:
-                raise ConfigError(
-                    f"tpu_inference: mesh pp composes with dp only — mesh "
-                    f"{axis} > 1 alongside pp is unsupported (stages stream "
-                    "whole activations; shard tensors on a separate tp "
-                    "processor instead)")
-        if p.get("device_pool"):
-            raise ConfigError(
-                "tpu_inference: 'device_pool' and mesh pp are mutually "
-                "exclusive (a pool member is a single-device runner; pick "
-                "pipelined stages OR replicated serving)")
-        if p.get("packing", False) is True:
-            raise ConfigError(
-                "tpu_inference: packing + mesh pp is not supported — the pp "
-                "schedule streams fixed-shape microbatches, packed layouts "
-                "are data-dependent (serve pp unpacked, or keep packing on "
-                "dp/pool)")
-        mc = p.get("model_config")
-        layers = (mc.get("layers") if isinstance(mc, Mapping) else None)
-        if layers is None:
-            layers = _MODEL_DEFAULT_LAYERS.get(str(p.get("model", "")))
-        if (isinstance(layers, int) and not isinstance(layers, bool)
-                and axes["pp"] > layers):
-            raise ConfigError(
-                f"tpu_inference: mesh pp={axes['pp']} exceeds the model's "
-                f"{layers} layers (every pipeline stage needs at least one "
-                "layer)")
-        mb = p.get("pp_microbatch_rows")
-        if mb is not None and (isinstance(mb, bool) or not isinstance(mb, int)
-                               or mb < 1):
-            raise ConfigError(
-                f"tpu_inference.pp_microbatch_rows must be a positive int, "
-                f"got {mb!r}")
-        costs = p.get("pp_layer_costs")
-        if costs is not None and (
-                not isinstance(costs, list) or not costs
-                or not all(isinstance(c, (int, float)) and not isinstance(c, bool)
-                           and c >= 0 for c in costs)):
-            raise ConfigError(
-                "tpu_inference.pp_layer_costs must be a non-empty list of "
-                f"non-negative numbers, got {costs!r}")
-        prof = p.get("pp_profile")
-        if prof is not None and not isinstance(prof, str):
-            raise ConfigError(
-                f"tpu_inference.pp_profile must be a path string, got {prof!r}")
+        refuse_pp_serving(p)
 
 
 def _validate_generate_mesh(processors: list[dict]) -> None:

@@ -239,57 +239,6 @@ def apply_packed(params: dict, cfg: BertConfig, *, input_ids, segment_ids,
     }
 
 
-def pp_stage_fns(cfg: BertConfig):
-    """Stage bodies for pipelined-parallel serving (parallel/pipeline.py
-    ``make_pp_infer_step``): embeddings -> per-layer encoder block -> pooler/
-    classifier head. The layer math mirrors ``encode``'s XLA-attention scan
-    body exactly (pp serving always resolves flash OFF under a mesh, like
-    every sharded path), so pp outputs are bitwise-identical to the
-    single-device XLA path per row."""
-
-    def pre(params: dict, inputs: dict):
-        input_ids = inputs["input_ids"]
-        attention_mask = inputs["attention_mask"]
-        b, s = input_ids.shape
-        positions = jnp.arange(s)[None, :]
-        x = (
-            cm.embedding(params["embed"]["word"], input_ids)
-            + cm.embedding(params["embed"]["position"], positions)
-            + cm.embedding(params["embed"]["token_type"], jnp.zeros_like(input_ids))
-        )
-        x = cm.layer_norm(params["embed"]["ln"], x, cfg.ln_eps)
-        # [B,1,1,Sk] like encode(); rides aux so each microbatch slices its
-        # own rows' masks
-        mask = attention_mask[:, None, None, :].astype(bool)
-        return x, {"mask": mask}
-
-    def layer(lp: dict, x, aux: dict):
-        b, s = x.shape[0], x.shape[1]
-        h = cfg.heads
-        dh = cfg.hidden // h
-        q = cm.dense(lp["q"], x).reshape(b, s, h, dh)
-        k = cm.dense(lp["k"], x).reshape(b, s, h, dh)
-        v = cm.dense(lp["v"], x).reshape(b, s, h, dh)
-        attn = cm.attention(q, k, v, aux["mask"],
-                            softmax_dtype=jnp.dtype(cfg.softmax_dtype))
-        attn = attn.reshape(b, s, cfg.hidden)
-        x = cm.layer_norm(lp["attn_ln"], x + cm.dense(lp["attn_out"], attn), cfg.ln_eps)
-        ff = cm.dense(lp["ffn_out"], cm.gelu(cm.dense(lp["ffn_in"], x)))
-        return cm.layer_norm(lp["ffn_ln"], x + ff, cfg.ln_eps)
-
-    def post(params: dict, x, aux: dict):
-        pooled = jnp.tanh(cm.dense(params["pooler"], x[:, 0, :]))
-        logits = cm.dense(params["classifier"], pooled).astype(jnp.float32)
-        probs = jax.nn.softmax(logits, axis=-1)
-        return {
-            "label": jnp.argmax(logits, axis=-1).astype(jnp.int32),
-            "score": jnp.max(probs, axis=-1),
-            "logits": logits,
-        }
-
-    return pre, layer, post
-
-
 def input_spec(cfg: BertConfig) -> dict:
     return {"input_ids": ("int32", ("seq",)), "attention_mask": ("int32", ("seq",))}
 
@@ -395,7 +344,6 @@ register_model(
             "from_hf_state_dict": from_hf_state_dict,
             "apply_packed": apply_packed,
             "packed_input_spec": packed_input_spec,
-            "pp_stage_fns": pp_stage_fns,
         },
     )
 )

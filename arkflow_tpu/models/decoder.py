@@ -1916,42 +1916,6 @@ def generate(params: dict, cfg: DecoderConfig, input_ids, lengths,
     return out, counts
 
 
-def pp_stage_fns(cfg: DecoderConfig):
-    """Stage bodies for pipelined-parallel serving (parallel/pipeline.py
-    ``make_pp_infer_step``): embed -> dense decoder block -> norm/lm_head.
-    Mirrors ``forward``'s scan body (no mesh axes: pp streams whole
-    activations stage-to-stage, never sharding them), so pp outputs match
-    the single-device ``apply`` bitwise per row. MoE routes through ep and
-    long context through sp/ring — not composed with pp, same as training."""
-    from arkflow_tpu.errors import ConfigError
-
-    _no_latent(cfg, "pipeline-parallel serving")
-    if cfg.num_experts > 1:
-        raise ConfigError("pipeline parallelism + MoE (ep) is not composed yet")
-    if cfg.use_ring_attention:
-        raise ConfigError("pipeline parallelism + ring attention is not composed yet")
-
-    def pre(params: dict, inputs: dict):
-        return cm.embedding(params["embed"], inputs["input_ids"]), {}
-
-    def layer(lp: dict, x, aux: dict):
-        b, s = x.shape[0], x.shape[1]
-        positions = jnp.broadcast_to(jnp.arange(s)[None, :], (b, s))
-        causal = jnp.tril(jnp.ones((s, s), bool))[None, None, :, :]
-        x = _attention_block(lp, x, cfg, positions, causal)
-        y = cm.rms_norm(lp["mlp_norm"], x, cfg.norm_eps)
-        gate = jax.nn.silu(cm.dense(lp["w_gate"], y).astype(jnp.float32)).astype(y.dtype)
-        return x + cm.dense(lp["w_down"], gate * cm.dense(lp["w_up"], y))
-
-    def post(params: dict, x, aux: dict):
-        x = cm.rms_norm(params["norm_out"], x, cfg.norm_eps)
-        logits = cm.dense(params["lm_head"], x).astype(jnp.float32)
-        return {"logits": logits,
-                "next_token": jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)}
-
-    return pre, layer, post
-
-
 def input_spec(cfg: DecoderConfig) -> dict:
     return {"input_ids": ("int32", ("seq",))}
 
@@ -1975,7 +1939,6 @@ register_model(
             "prefill": prefill,
             "decode_step": decode_step,
             "generate": generate,
-            "pp_stage_fns": pp_stage_fns,
         },
     )
 )

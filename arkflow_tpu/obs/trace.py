@@ -400,7 +400,7 @@ class Tracer:
         against the summed trace e2e, so the shares of disjoint top-level
         stages sum to <= 1.0 — a nested span (``device_step`` inside
         ``process``, flight legs inside a hop) overlaps its parent and used
-        to inflate the sum past 1.0 in BENCH_RESULT.json. Stages whose
+        to inflate the sum past 1.0. Stages whose
         spans are ALL nested report ``nested: true`` plus ``nested_under``
         (their most common parent stage) and a 0.0 top-level share; their
         p50/p99/total still cover every span, so the within-parent cost

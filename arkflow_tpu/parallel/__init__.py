@@ -1,2 +1,1 @@
 from arkflow_tpu.parallel.mesh import MeshSpec, create_mesh, shard_params  # noqa: F401
-from arkflow_tpu.parallel.segment import StagePlan, plan_stages, uniform_plan  # noqa: F401

@@ -14,7 +14,7 @@ Environment-variable driven so k8s/slurm launchers need no config changes:
 
 Beyond the bootstrap, this module carries the **multi-host serving plane**
 for the cluster tier (``runtime/cluster.py``): one model too big for a
-single worker process served by a ``mesh: {pp: N}`` that spans several
+single worker process served by a ``mesh`` that spans several
 ``jax.distributed`` processes. The discipline is lockstep SPMD —
 
 - every process builds the IDENTICAL processor chain (same config, same
@@ -27,7 +27,7 @@ single worker process served by a ``mesh: {pp: N}`` that spans several
 - process 0 (the **primary**) opens the serving port; before running each
   batch it fans the Arrow payload out over :class:`BroadcastChannel`, and
   every other process (a **follower**, :func:`run_follower`) replays the
-  identical ``pipeline.process`` call — so the pp stages that live on the
+  identical ``pipeline.process`` call — so the shards that live on the
   follower's devices execute their half of each collective in step.
 
 The channel is two ``broadcast_one_to_all`` collectives per message (a

@@ -31,25 +31,6 @@ Config:
     mesh: {dp: 1, tp: 4}           # optional multi-chip serving (GSPMD: one
                                    # sharded program; dp splits the batch dim
                                    # and scales every batch bucket by dp)
-    mesh: {pp: 4}                  # pipelined-parallel serving (profiled
-                                   # model segmentation): the layer stack is
-                                   # cut into cost-balanced stages, one per
-                                   # chip, and microbatches stream
-                                   # stage-to-stage (GPipe) — every chip
-                                   # works on ONE request's layers, so
-                                   # small-bucket latency-bound traffic
-                                   # doesn't starve N chips on 1/N of a tiny
-                                   # batch. Composes with dp (dp x pp);
-                                   # tp/sp/device_pool/packing do not.
-    pp_microbatch_rows: 2          # rows per pp microbatch (default: the
-                                   # smallest batch bucket). Bucket B serves
-                                   # as M = B/mb microbatches over M+S-1
-                                   # ticks; bubble = (S-1)/(M+S-1)
-    pp_profile: prof.json          # per-layer costs from tools/
-                                   # profile_step.py --per-layer; the stage
-                                   # planner (parallel/segment.py) cuts
-                                   # stages minimizing the max-stage cost
-                                   # (pp_layer_costs: [...] inlines the same)
     device_pool: 4                 # ALTERNATIVE multi-chip serving: 4
                                    # independent single-device runners with
                                    # replicated params behind a least-loaded
@@ -128,6 +109,7 @@ import pyarrow as pa
 
 from arkflow_tpu.batch import DEFAULT_BINARY_VALUE_FIELD, MessageBatch
 from arkflow_tpu.components import Processor, Resource, register_processor
+from arkflow_tpu.config import refuse_pp_serving
 from arkflow_tpu.errors import ConfigError, ProcessError
 from arkflow_tpu.tpu.bucketing import BucketPolicy, carve_by_length
 from arkflow_tpu.tpu.tokenizer import build_tokenizer
@@ -407,6 +389,7 @@ def _build(config: dict, resource: Resource) -> TpuInferenceProcessor:
     from arkflow_tpu.parallel.mesh import MeshSpec
     from arkflow_tpu.tpu.runner import ModelRunner
 
+    refuse_pp_serving(config)
     model = config.get("model")
     if not model:
         raise ConfigError("tpu_inference requires 'model'")
@@ -427,21 +410,8 @@ def _build(config: dict, resource: Resource) -> TpuInferenceProcessor:
     mesh_spec = None
     if mesh_cfg:
         mesh_spec = MeshSpec(dp=int(mesh_cfg.get("dp", 1)), tp=int(mesh_cfg.get("tp", 1)),
-                             sp=int(mesh_cfg.get("sp", 1)), pp=int(mesh_cfg.get("pp", 1)))
+                             sp=int(mesh_cfg.get("sp", 1)))
     packing = packing_raw
-    # pipelined-parallel knobs (mesh {pp: N}): microbatch sizing + the
-    # per-layer cost profile the stage planner balances against
-    pp_kwargs: dict = {}
-    if mesh_spec is not None and mesh_spec.pp > 1:
-        if config.get("pp_microbatch_rows") is not None:
-            pp_kwargs["pp_microbatch_rows"] = int(config["pp_microbatch_rows"])
-        costs = config.get("pp_layer_costs")
-        if config.get("pp_profile"):
-            from arkflow_tpu.parallel.segment import load_layer_costs
-
-            costs = load_layer_costs(str(config["pp_profile"]))
-        if costs is not None:
-            pp_kwargs["pp_layer_costs"] = [float(c) for c in costs]
     pool_size = int(config.get("device_pool", 0) or 0)
     if pool_size and mesh_cfg:
         raise ConfigError(
@@ -474,8 +444,7 @@ def _build(config: dict, resource: Resource) -> TpuInferenceProcessor:
             model, config.get("model_config"), pool_size=pool_size, **common)
     else:  # device_pool: 1 is just single-device serving
         runner = ModelRunner(
-            model, config.get("model_config"), mesh_spec=mesh_spec,
-            **pp_kwargs, **common)
+            model, config.get("model_config"), mesh_spec=mesh_spec, **common)
     vocab = getattr(runner.cfg, "vocab_size", 30522)
     tokenizer = build_tokenizer(config.get("tokenizer"), vocab_size=vocab)
     from arkflow_tpu.runtime.respcache import build_response_cache

@@ -490,20 +490,16 @@ class ClusterWorkerServer:
         self-drain completes by setting the stop event — see
         :meth:`begin_self_drain`)."""
         if self._server is None:
-            await self.start()
-        async with self._server:
-            serve = asyncio.create_task(self._server.serve_forever())
-            stop = asyncio.create_task(self._stopping.wait())
-            try:
-                await asyncio.wait({serve, stop},
-                                   return_when=asyncio.FIRST_COMPLETED)
-            finally:
-                for t in (serve, stop):
-                    t.cancel()
-                    try:
-                        await t
-                    except (asyncio.CancelledError, Exception):
-                        pass
+            await self.start()  # accepting from here on
+        try:
+            await self._stopping.wait()
+        finally:
+            # stop accepting and return. Not ``Server.serve_forever`` /
+            # ``async with server``: leaving either awaits ``wait_closed()``,
+            # which waits for every open connection — a handler holding a
+            # batch past the grace budget would pin the exit the budget
+            # exists to bound (``stop`` bounds that wait itself)
+            self._server.close()
 
     async def stop(self) -> None:
         if self._server is not None:
@@ -1187,7 +1183,7 @@ async def run_worker(config: Mapping, *, host: str = "127.0.0.1",
     With a ``distributed:`` block (or the ``ARKFLOW_*`` distributed env)
     naming more than one process, the worker joins a multi-host
     ``jax.distributed`` mesh: every process builds the IDENTICAL processor
-    chain (so ``mesh: {pp: N}`` spans the global device list), process 0
+    chain (so its ``mesh`` spans the global device list), process 0
     opens the serving port and broadcasts each infer batch, processes > 0
     run the lockstep follower loop (parallel/distributed.py) — one model
     too big for one process, served across several."""

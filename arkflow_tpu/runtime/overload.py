@@ -1,8 +1,8 @@
 """Overload control: deadline-aware admission, AIMD queue windows, shedding.
 
 Once offered load exceeds device throughput, an engine that admits every
-batch turns a traffic burst into unbounded queue wait (BENCH_r05's
-``saturated_queueing_p99_ms`` ≈ 10.7s) and eventual memory pressure. In the
+batch turns a traffic burst into unbounded queue wait (a saturated queueing
+p99 of ≈ 10.7s in a pre-chip bench run) and eventual memory pressure. In the
 latency-bound serving regime (Answer Fast / TSP, PAPERS.md) finishing a
 stale request is strictly worse than shedding it up front, so the engine
 protects itself from its own traffic with three cooperating mechanisms, all

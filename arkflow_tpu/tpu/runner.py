@@ -35,6 +35,7 @@ from typing import Any, Optional
 import jax
 import numpy as np
 
+from arkflow_tpu.config import PP_SERVING_REMOVED
 from arkflow_tpu.errors import ConfigError, RunnerDead, StepDeadlineExceeded
 from arkflow_tpu.models import get_model
 from arkflow_tpu.obs import global_registry
@@ -225,8 +226,6 @@ class ModelRunner:
         step_deadline_s: Optional[float] = None,
         step_deadline_first_s: Optional[float] = None,
         health_config: Optional[HealthConfig] = None,
-        pp_microbatch_rows: Optional[int] = None,
-        pp_layer_costs: Optional[list] = None,
     ):
         from arkflow_tpu.tpu.jaxcache import enable_persistent_cache
 
@@ -280,8 +279,7 @@ class ModelRunner:
         #: is quarantined, and the reference tree its golden signature is
         #: computed against. Pool members share ONE tree (the pool passes
         #: ``host_params`` in), so retention costs one host copy per model,
-        #: not per chip. Captured BEFORE placement: the pp path repacks the
-        #: layer stack below, and ``place_params`` knows how to redo that.
+        #: not per chip.
         self.host_params = params
 
         self.mesh = None
@@ -291,19 +289,10 @@ class ModelRunner:
         #: so a hot-swap (tpu/swap.py) can place a candidate tree EXACTLY
         #: like the original, including the int8 spec rewrite
         self._pspecs = None
-        #: pipelined-parallel serving state (mesh {pp: N}): the profiled
-        #: stage plan, the microbatch row count the GPipe schedule streams,
-        #: and the per-seq-bucket measured tick time the bubble gauge uses
-        self._pp_plan = None
-        self._pp_mb_rows = 0
-        self._pp_tick_s: dict[int, float] = {}
-        self._pp_tick_pending: set[int] = set()
         axes: dict[str, str] = {}
         if mesh_spec is not None and mesh_spec.pp > 1:
-            params = self._init_pp(mesh_spec, params, devices,
-                                   pp_microbatch_rows, pp_layer_costs)
-            platform = next(iter(self.mesh.devices.flat)).platform
-        elif mesh_spec is not None and mesh_spec.num_devices > 1:
+            raise ConfigError(PP_SERVING_REMOVED)
+        if mesh_spec is not None and mesh_spec.num_devices > 1:
             self.mesh = create_mesh(mesh_spec, devices=devices)
             axes = {name: name for name in self.mesh.axis_names}
             pspecs = self.family.param_specs(self.cfg, axes) if self.family.param_specs else None
@@ -442,23 +431,12 @@ class ModelRunner:
         self._in_warmup = False
         #: device queue depth. 2 = double buffering (prep/dispatch n+1
         #: overlaps compute of n) — enough when dispatch latency ~ 0. Where
-        #: a step also pays a sizeable dispatch+sync round trip
-        #: (tools/profile_step.py measures it), keeping ceil((rtt+c)/c)
-        #: steps in flight is what saturates the chip; not yet measured on
-        #: a locally attached chip. Config ``max_in_flight`` / env
-        #: ARKFLOW_INFLIGHT override.
+        #: a step also pays a sizeable dispatch+sync round trip, keeping
+        #: ceil((rtt+c)/c) steps in flight is what saturates the chip; not
+        #: yet measured on a locally attached chip. Config ``max_in_flight``
+        #: / env ARKFLOW_INFLIGHT override.
         if max_in_flight is None:
-            # pp default: ONE GPipe schedule in flight. Concurrent pp steps
-            # interleave their per-tick ppermute/psum collectives on the
-            # same chips — on the CPU backend two in-flight schedules can
-            # deadlock the ring outright (observed: a 4-layer tiny step
-            # blowing a 5s deadline), and an interleaved second schedule
-            # double-counts the measured bubble either way. An explicit
-            # max_in_flight / ARKFLOW_INFLIGHT still overrides for
-            # real-chip experiments.
-            default_inflight = 1 if self._pp_plan is not None else 2
-            max_in_flight = _env_int("ARKFLOW_INFLIGHT", default_inflight,
-                                     minimum=1)
+            max_in_flight = _env_int("ARKFLOW_INFLIGHT", 2, minimum=1)
         if max_in_flight < 1:  # explicit config/kwarg values DO raise
             raise ConfigError(f"max_in_flight must be >= 1, got {max_in_flight}")
         self.max_in_flight = max_in_flight
@@ -536,15 +514,6 @@ class ModelRunner:
             "largest batch bucket currently served (shrinks after device OOM)",
             labels)
         self.m_bucket_cap.set(self.buckets.max_batch())
-        #: measured pipeline bubble (pp serving only): 1 - useful-tick time /
-        #: step wall time, against the per-seq-bucket tick time measured by a
-        #: single-microbatch probe — the analytic floor is (S-1)/(M+S-1)
-        self.m_pp_bubble = (
-            reg.gauge(
-                "arkflow_pp_bubble_frac",
-                "measured pipeline-bubble fraction of the last pp step "
-                "(1 - M*tick/step; analytic floor (S-1)/(M+S-1))", labels)
-            if self._pp_plan is not None else None)
 
     @staticmethod
     def _resolve_auto_flags(cfg, devices, mesh_spec, packed: bool = False):
@@ -612,99 +581,18 @@ class ModelRunner:
             # auto-chosen flash only engages at seqs where the kernel wins:
             # short buckets tile below the MXU (tile=seq<128) and the grid
             # overhead dominates — v5e A/B at seq 32 measured XLA 47% faster
-            # end-to-end; on-chip the two are within ~5% from seq 128 up
-            # (tools/profile_attention.py), with Pallas ahead at low fill.
+            # end-to-end; on-chip the two are within ~5% from seq 128 up,
+            # with Pallas ahead at low fill.
             # Only fills the floor when unset, so an operator-tuned
             # flash_min_seq in config survives auto-resolution.
             extra["flash_min_seq"] = _env_flash_floor()
         return dataclasses.replace(cfg, use_flash_attention=on_tpu, **extra)
-
-    def _init_pp(self, mesh_spec: MeshSpec, params, devices,
-                 pp_microbatch_rows: Optional[int],
-                 pp_layer_costs: Optional[list]):
-        """Pipelined-parallel serving setup (``mesh: {pp: N}``): cut the
-        layer stack into cost-balanced stages (parallel/segment.py — from a
-        measured per-layer profile when one is configured, uniform
-        otherwise), repack the stacked layer params into the stage-padded
-        layout, and shard them over the ``pp`` axis. Activations stream
-        stage-to-stage inside the jitted step; dp composes (the batch dim
-        splits over ``dp`` while each dp replica runs its own pipeline)."""
-        from arkflow_tpu.parallel.pipeline import (
-            pp_infer_param_specs,
-            pp_repack_layers,
-        )
-        from arkflow_tpu.parallel.segment import plan_stages, uniform_plan
-
-        if self.packed:
-            raise ConfigError(
-                "packing + mesh pp is not supported: the pp schedule streams "
-                "fixed-shape microbatches, packed layouts are data-dependent "
-                "(serve pp unpacked, or keep packing on dp/pool)")
-        if mesh_spec.tp > 1 or mesh_spec.sp > 1 or mesh_spec.ep > 1:
-            raise ConfigError(
-                "mesh pp composes with dp only (dp x pp); tp/sp/ep alongside "
-                "pp are not supported")
-        extras = self.family.extras or {}
-        if "pp_stage_fns" not in extras:
-            raise ConfigError(
-                f"model {self.family.name!r} has no pipeline-parallel serving "
-                "support (family extras lack pp_stage_fns)")
-        try:
-            n_layers = int(jax.tree_util.tree_leaves(params["layers"])[0].shape[0])
-        except (KeyError, IndexError, TypeError) as e:
-            raise ConfigError(
-                f"model {self.family.name!r} has no stacked 'layers' params "
-                "to segment for pp serving") from e
-        stages = mesh_spec.pp
-        if stages > n_layers:
-            raise ConfigError(
-                f"mesh pp={stages} exceeds the model's {n_layers} layers "
-                "(every stage needs at least one layer)")
-        if pp_layer_costs is not None:
-            if len(pp_layer_costs) != n_layers:
-                raise ConfigError(
-                    f"pp layer costs cover {len(pp_layer_costs)} layers but "
-                    f"the model has {n_layers} — re-profile with the served "
-                    "model_config")
-            plan = plan_stages(pp_layer_costs, stages)
-        else:
-            plan = uniform_plan(n_layers, stages)
-        mb = pp_microbatch_rows if pp_microbatch_rows is not None \
-            else self.buckets.batch_buckets[0]
-        if not isinstance(mb, int) or isinstance(mb, bool) or mb < 1:
-            raise ConfigError(
-                f"pp_microbatch_rows must be a positive int, got {mb!r}")
-        for b in self.buckets.batch_buckets:
-            # per-replica shapes: dp scaling multiplies the grid below, so
-            # the per-replica bucket IS the configured one
-            if b > mb and b % mb != 0:
-                raise ConfigError(
-                    f"batch bucket {b} does not divide by pp_microbatch_rows "
-                    f"{mb} — the GPipe schedule needs bucket-exact "
-                    "microbatches (pow2 grids with a pow2 microbatch always "
-                    "divide)")
-        self.mesh = create_mesh(mesh_spec, devices=devices)
-        repacked = pp_repack_layers(params, plan)
-        self._pspecs = pp_infer_param_specs(repacked)
-        placed = shard_params(repacked, self._pspecs, self.mesh)
-        self.buckets = self.buckets.dp_scaled(dp_size(self.mesh))
-        self._input_sharding = batch_sharding(self.mesh)
-        self._pp_plan = plan
-        self._pp_mb_rows = mb
-        logger.info(
-            "[%s] pp serving: %d stages over %d layers (sizes %s, imbalance "
-            "%.3f), microbatch %d rows", self.family.name, stages, n_layers,
-            plan.sizes, plan.imbalance, mb)
-        return placed
 
     def _build_jitted(self) -> None:
         """(Re)build the jitted step from the CURRENT self.cfg. jax.jit keys
         executables on the function object, so any cfg change that alters
         tracing (e.g. disabling flash attention) must rebuild — mutating
         self.cfg alone would keep serving stale executables for seen shapes."""
-        if self._pp_plan is not None:
-            self._build_jitted_pp()
-            return
         apply_fn = (self.family.extras["apply_packed"] if self.packed
                     else self.family.apply)
         # thread mesh/axes into families whose apply understands sharded
@@ -742,28 +630,6 @@ class ModelRunner:
                                           self._input_sharding)
             jit_kwargs["out_shardings"] = self._input_sharding
         self._jitted = jax.jit(classify_step, **jit_kwargs)
-
-    def _build_jitted_pp(self) -> None:
-        """Jit the pipelined-parallel step: shard_map over (dp, pp) with the
-        GPipe microbatch schedule inside (parallel/pipeline.py). Params ride
-        as an argument exactly like the plain path, so hot-swap flips and
-        post-incident rebuilds work unchanged."""
-        from arkflow_tpu.parallel.pipeline import make_pp_infer_step
-
-        fn = make_pp_infer_step(
-            self.family, self.cfg, self.mesh, plan=self._pp_plan,
-            microbatch_rows=self._pp_mb_rows, param_specs=self._pspecs)
-
-        def classify_step_pp(params, inputs):  # the program's stable name
-            return fn(params, inputs)
-
-        jit_kwargs: dict[str, Any] = {}
-        if self._donate:
-            jit_kwargs["donate_argnums"] = (1,)
-        jit_kwargs["in_shardings"] = (param_shardings(self.params),
-                                      self._input_sharding)
-        jit_kwargs["out_shardings"] = self._input_sharding
-        self._jitted = jax.jit(classify_step_pp, **jit_kwargs)
 
     def _disable_flash(self) -> None:
         """Auto-fallback: serve with XLA attention from now on (one
@@ -938,114 +804,6 @@ class ModelRunner:
             return {shape for key in self._seen_shapes
                     for name, shape in key if name == "input_ids"}
 
-    # -- pipelined-parallel bubble accounting -------------------------------
-
-    def _pp_geometry(self, padded: dict[str, Any]) -> tuple[int, int, int]:
-        """(seq bucket, microbatches, stages) of a padded pp step."""
-        seq = 0
-        for name, (_, trailing) in self.spec.items():
-            if "seq" in trailing and name in padded:
-                seq = int(padded[name].shape[1])
-                break
-        rows = int(next(iter(padded.values())).shape[0])
-        local = max(1, rows // dp_size(self.mesh))
-        mb = min(self._pp_mb_rows, local)
-        return seq, max(1, local // mb), self._pp_plan.stages
-
-    def _pp_ensure_tick(self, seq: int) -> None:
-        """Measure this seq bucket's per-tick cost once, via a
-        single-microbatch probe step (M=1 => the schedule is exactly S
-        ticks, so tick = step/S). The probe is how the bubble gauge stays a
-        MEASUREMENT: per-step bubble = 1 - M*tick/step against this
-        reference, so ppermute latency, imbalance, and host stalls all show
-        up instead of being assumed away by the analytic (S-1)/(M+S-1)."""
-        if self._pp_plan is None:
-            return
-        with self._flash_lock:
-            if seq in self._pp_tick_s or seq in self._pp_tick_pending:
-                return
-            self._pp_tick_pending.add(seq)
-        try:
-            import time
-
-            rows = self._pp_mb_rows * dp_size(self.mesh)
-            fake = {}
-            for name, (dtype, trailing) in self.spec.items():
-                dims = tuple(seq if d == "seq" else d for d in trailing)
-                fake[name] = np.ones((rows, *dims), dtype=dtype)
-            jax.device_get(self._dispatch(fake))  # compile
-            ts = []
-            for _ in range(3):
-                t0 = time.perf_counter()
-                jax.device_get(self._dispatch(fake))
-                ts.append(time.perf_counter() - t0)
-            ts.sort()
-            # the M=1 probe pays every stage once, so step/S is the MEAN
-            # stage cost; the steady-state tick is the MAX stage cost
-            # (stages run in lockstep), so scale by the plan's imbalance —
-            # an uneven-but-optimal cut must not read as extra bubble
-            tick = (ts[len(ts) // 2] / self._pp_plan.stages
-                    * self._pp_plan.imbalance)
-            with self._flash_lock:
-                self._pp_tick_s[seq] = max(tick, 1e-9)
-        except Exception as e:  # pragma: no cover - probe must never kill serving
-            logger.warning("[%s] pp tick probe failed at seq %d: %s",
-                           self.family.name, seq, e)
-        finally:
-            with self._flash_lock:
-                self._pp_tick_pending.discard(seq)
-
-    async def _pp_probe_async(self, seq: int) -> None:
-        """Lazy tick probe for a seq bucket warmup never saw: holds the
-        in-flight permit across the probe steps so they serialize with live
-        schedules instead of interleaving collectives with them."""
-        self._ensure_sems()
-        async with self._inflight_sem:
-            await asyncio.get_running_loop().run_in_executor(
-                None, self._pp_ensure_tick, seq)
-
-    def _pp_observe(self, padded: dict[str, Any], dt: float) -> None:
-        """Fold one pp step into the bubble gauge + trace spans:
-        ``pp_bubble`` is the step's measured idle share (vs M useful ticks at
-        the probed tick cost), ``pp_stage_wait`` the fill/drain ramp the
-        first/last microbatches spend waiting on other stages."""
-        if self._pp_plan is None or self._in_warmup or dt <= 0:
-            return
-        seq, m, s = self._pp_geometry(padded)
-        tick = self._pp_tick_s.get(seq)
-        if tick is None:
-            # not probed yet (warmup skipped): probe UNDER the in-flight
-            # permit so the probe's pipeline steps never interleave their
-            # collectives with a live schedule (the deadlock the
-            # one-schedule default exists to prevent). No loop => no safe
-            # slot to serialize against: skip, warmup is the probe site.
-            try:
-                asyncio.get_running_loop().create_task(
-                    self._pp_probe_async(seq))
-            except RuntimeError:
-                pass
-            return
-        bubble = min(1.0, max(0.0, 1.0 - (m * tick) / dt))
-        self.m_pp_bubble.set(bubble)
-        record_stage("pp_bubble", bubble * dt,
-                     attrs={"stages": s, "microbatches": m, "seq": seq})
-        record_stage("pp_stage_wait", min(dt, (s - 1) * tick),
-                     attrs={"stages": s})
-
-    def pp_report(self) -> Optional[dict]:
-        """JSON-able pp-serving snapshot (stage plan + measured bubble) for
-        /health and the bench detail; None off the pp path."""
-        if self._pp_plan is None:
-            return None
-        return {
-            **self._pp_plan.report(),
-            "microbatch_rows": self._pp_mb_rows,
-            "bubble_frac": (round(float(self.m_pp_bubble.value), 4)
-                            if self.m_pp_bubble is not None else None),
-            "tick_ms": {str(k): round(v * 1e3, 3)
-                        for k, v in sorted(self._pp_tick_s.items())},
-        }
-
     # -- self-healing: chaos hook / watchdog / OOM degradation --------------
     # (the health state machine, deadline watchdog, and chaos queue live in
     # the shared ServingRunnerCore; the runner keeps the OOM degradation
@@ -1185,15 +943,9 @@ class ModelRunner:
     def place_params(self, host_params):
         """Place a (converted) host param tree exactly like ``__init__``
         placed the original: sharded with the same PartitionSpecs under a
-        mesh, a one-hop transfer to the runner's device otherwise (pp
-        serving additionally repacks the layer stack into its stage-padded
-        layout first, so a hot-swap candidate lands in the same shape the
-        live tree serves from). Blocking (device transfer) — swap runs it
-        on an executor thread, never the serving loop."""
-        if self._pp_plan is not None:
-            from arkflow_tpu.parallel.pipeline import pp_repack_layers
-
-            host_params = pp_repack_layers(host_params, self._pp_plan)
+        mesh, a one-hop transfer to the runner's device otherwise.
+        Blocking (device transfer) — swap runs it on an executor thread,
+        never the serving loop."""
         if self.mesh is not None:
             return shard_params(host_params, self._pspecs, self.mesh)
         return jax.device_put(host_params, self._device)
@@ -1332,11 +1084,10 @@ class ModelRunner:
 
     async def warm_shapes_live(self, policy: BucketPolicy) -> int:
         """``warm_shapes`` for use WHILE serving: each compile holds the
-        in-flight permit — serializing with live device schedules, the same
-        discipline the pp tick probe follows — and runs under the
-        first-compile deadline on a watchdog thread, so a wedged compile is
-        abandoned (the runner heals through its normal probe path) instead
-        of blocking the caller forever."""
+        in-flight permit — serializing with live device schedules — and runs
+        under the first-compile deadline on a watchdog thread, so a wedged
+        compile is abandoned (the runner heals through its normal probe
+        path) instead of blocking the caller forever."""
         self._ensure_sems()
         loop = asyncio.get_running_loop()
         count = 0
@@ -1374,11 +1125,6 @@ class ModelRunner:
         if self.device_label is not None:
             rep["device"] = self.device_label
         rep["bucket_cap"] = self.buckets.max_batch()
-        pp = self.pp_report()
-        if pp is not None:
-            # the stage plan rides /health so pipeline imbalance is
-            # attributable to the profile that produced the cut
-            rep["pp"] = pp
         return rep
 
     # -- execution ---------------------------------------------------------
@@ -1436,8 +1182,6 @@ class ModelRunner:
         if not self._in_warmup:  # warmup compiles are not traffic latency
             dt = time.perf_counter() - t0
             self.m_infer.observe(dt)
-            if not first:  # compile steps are not schedule timing
-                self._pp_observe(padded, dt)
             self.m_rows.inc(n)
         self.health.mark_success()
         return {k: np.asarray(v)[:n] for k, v in out.items()}
@@ -1606,8 +1350,6 @@ class ModelRunner:
                     self._track_complete(time.perf_counter())
                 dt = time.perf_counter() - t0
                 self.m_infer.observe(dt)
-                if not first:
-                    self._pp_observe(padded, dt)
                 # first-compile steps get their own stage: one compile can
                 # be 1000x a warm step, and mixing the two makes both the
                 # p99 and the share-of-e2e unreadable
@@ -1661,8 +1403,6 @@ class ModelRunner:
                     self._track_complete(time.perf_counter())
             dt = time.perf_counter() - t0
             self.m_infer.observe(dt)
-            if not first:
-                self._pp_observe(padded, dt)
             record_stage("device_step_first" if first else "device_step",
                          dt, attrs={"bucket_rows": bucket_rows})
             record_stage("device_fetch", fetch_s)
@@ -1735,12 +1475,6 @@ class ModelRunner:
                         fake[name] = np.zeros((lead, *dims), dtype=dtype)
                     self.infer_sync(fake)
                     count += 1
-            if self._pp_plan is not None:
-                # probe each seq bucket's tick cost while the device is
-                # quiet, so the first measured bubble has its reference
-                for sl in seqs:
-                    if sl:
-                        self._pp_ensure_tick(sl)
         finally:
             self._in_warmup = False
         logger.info("[%s] warmed %d bucket executables", self.family.name, count)

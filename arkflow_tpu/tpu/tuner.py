@@ -950,15 +950,6 @@ def build_shape_tuner(runner, *, model: str, cfg: Optional[TunerConfig],
     """Processor-builder entry: None when the block is absent/disabled."""
     if cfg is None or not cfg.enabled:
         return None
-    if getattr(runner, "_pp_plan", None) is not None:
-        # a warm compile interleaving its collectives with a live GPipe
-        # schedule can deadlock the ring (the same hazard that pinned pp
-        # probes under the in-flight permit at max_in_flight 1 — which
-        # would serialize every warm compile against serving anyway)
-        raise ConfigError(
-            "tpu_inference: 'tuner' does not compose with mesh pp "
-            "(pipelined stages serve one schedule at a time; retune the "
-            "pp grid by redeploy instead)")
     tuner = ShapeTuner(runner, model=model, cfg=cfg, packed=packed)
     if cache is not None:
         tuner.add_commit_hook(cache.bump_epoch)

@@ -1,11 +1,10 @@
 """Environment helpers for CPU-only jax runs.
 
 A chip belongs to one process at a time, so every surface that is CPU-only
-by design (tests, multichip dryrun, soak workers, forced-host-mesh bench
-children) pins the CPU platform in its OWN environment before jax is
-imported — a child that inherited an accelerator default would contend for
-a chip its parent may hold. This module is the single source of truth for
-that pin, shared by ``tests/conftest.py``, ``bench.py``, the tools and
+by design (tests, multichip dryrun, soak workers) pins the CPU platform in
+its OWN environment before jax is imported — a child that inherited an
+accelerator default would contend for a chip its parent may hold. This module is the single source of truth for
+that pin, shared by ``tests/conftest.py``, the tools and
 ``__graft_entry__.py``.
 
 It must stay importable without jax side effects (conftest imports it before

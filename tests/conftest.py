@@ -2,7 +2,7 @@
 
 Multi-chip shardings are validated on CPU (the driver separately dry-runs
 ``__graft_entry__.dryrun_multichip`` the same way); the chip is reached
-through ``chip_smoke.py`` and ``bench.py`` outside pytest. Tests are
+through ``chip_smoke.py`` and ``benchmark/run.py`` outside pytest. Tests are
 CPU-only by design: the platform is pinned here, in this process's own
 environment, so neither pytest nor any child it spawns ever takes a chip.
 """

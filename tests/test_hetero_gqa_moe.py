@@ -712,12 +712,10 @@ def test_a_latent_model_refuses_the_per_head_kinds_keys():
             dec.DecoderConfig(**{**LATENT, **bad})
 
 
-def test_the_batch_cache_and_the_pipeline_refuse_sizes_by_kind():
+def test_the_batch_cache_refuses_sizes_by_kind():
     for cfg in (CFG, dec.DecoderConfig(**{**DENSE, "head_dim": 16, "v_head_dim": 8})):
         with pytest.raises(ConfigError, match="head sizes by kind.*serving: continuous"):
             dec.init_kv_cache(cfg, 1, 16)
-        with pytest.raises(ConfigError, match="serving: continuous"):
-            dec.pp_stage_fns(cfg)
 
 
 # -- the judge ----------------------------------------------------------------------

@@ -514,7 +514,5 @@ def test_hybrid_config_values_that_do_not_compose_raise(bad):
 def test_paths_without_a_state_refuse_the_hybrid_block():
     with pytest.raises(ConfigError, match="recurrent state"):
         dec.init_kv_cache(CFG, 1, 16)
-    with pytest.raises(ConfigError, match="recurrent state"):
-        dec.pp_stage_fns(CFG)
     # the mesh itself is refused where one is built (the server, the processor)
     assert dec.param_specs(CFG, {})["layers"]["ssm_A_log"] is not None

@@ -368,30 +368,6 @@ def test_coalescing_strictly_reduces_padding_waste():
     assert waste_on == 0.0  # every coalesced dispatch was bucket-exact
 
 
-# -- profiling harness smoke --------------------------------------------------
-
-def test_profile_infeed_smoke():
-    """tools/profile_infeed.py runs green on a tiny config and reports a
-    vectorized hot path — ``rowwise_hotpath`` flipping True means per-row
-    Python (as_py loops) crept back into extraction/tokenization."""
-    import json
-    import os
-    import subprocess
-    import sys
-
-    env = dict(os.environ, JAX_PLATFORMS="cpu", PROF_ROWS="16", PROF_STEPS="2")
-    res = subprocess.run(
-        [sys.executable, str(pathlib.Path(__file__).parent.parent
-                             / "tools" / "profile_infeed.py")],
-        capture_output=True, timeout=240, env=env)
-    assert res.returncode == 0, res.stderr.decode()[-2000:]
-    report = json.loads(res.stdout.decode().strip().splitlines()[-1])
-    assert report["metric"] == "infeed_prep_breakdown"
-    assert report["extract_tokenize_ms_per_step"] >= 0
-    assert report["pad_stage_ms_per_step"] >= 0
-    assert report["rowwise_hotpath"] is False, report["rowwise_frames"]
-
-
 # -- merged-batch ack / quarantine under faults ------------------------------
 
 class CollectOutput:

@@ -293,6 +293,51 @@ def test_a_mixed_batch_is_served_split_and_row_for_row_as_unsplit():
     assert proc.m_steps.sum - steps0[0] == sum(served.values())
 
 
+@pytest.mark.parametrize("packing", [False, True], ids=["unpacked", "packed"])
+@pytest.mark.parametrize("form", [{"mesh": {"dp": 2}}, {"mesh": {"dp": 4}},
+                                  {"device_pool": 2}],
+                         ids=["dp2", "dp4", "pool2"])
+def test_a_split_read_under_dp_or_a_pool_matches_one_device_row_for_row(form, packing):
+    """The two forms that serve several chips: the read is carved on the
+    FORM's grid (dp scales the batch buckets, a pool's member keeps them),
+    its pieces run as several steps — each sharded over the mesh, or spread
+    over the pool's members — and the rows come back in the read's order
+    with the outputs one device gives."""
+    import jax
+
+    if len(jax.devices()) < 4:
+        pytest.skip("needs 4 virtual devices")
+    single = make_proc(packing=packing)
+    served = make_proc(packing=packing, **form)
+    batch, _ = mixed_batch(7)
+
+    async def go():
+        await single.connect()
+        await served.connect()  # warm grid: the carve cuts at the warm charge
+        (want,) = await single.process(batch)
+        steps0 = (served.m_steps.sum, served.m_steps.count)
+        shapes0 = shapes(served.runner) if "mesh" in form else {}
+        (got,) = await served.process(batch)
+        return want, got, steps0, shapes0
+
+    want, got, steps0, shapes0 = asyncio.run(go())
+    if not packing:  # the packed path carves row windows, not lengths
+        assert served.m_steps.count - steps0[1] == 1
+        assert served.m_steps.sum - steps0[0] >= 2  # really split
+    if "mesh" in form:
+        dp = form["mesh"]["dp"]
+        ran = {k for k, v in shapes(served.runner).items() if v != shapes0.get(k, 0)}
+        assert ran and all(rows % dp == 0 for rows, _ in ran)
+    else:
+        assert all(int(c.value) > 0 for c in served.runner.m_dispatch)
+    assert got.to_binary("__value__") == batch.to_binary("__value__")
+    want_s, got_s = want.column("score").to_numpy(), got.column("score").to_numpy()
+    np.testing.assert_allclose(got_s, want_s, atol=1e-5)
+    decided = np.abs(want_s - 0.5) > 1e-4
+    assert decided.sum() > 100
+    assert (got.column("label").to_numpy() == want.column("label").to_numpy())[decided].all()
+
+
 def test_padding_counters_read_the_gain():
     """``arkflow_tpu_tokens_total`` / ``_token_capacity_total`` are counted
     per dispatched step, so the padding share falls with the split."""

@@ -1,4 +1,6 @@
-"""Multi-chip serving: dp-sharded dispatch + replicated device pool.
+"""Multi-chip serving: dp-sharded dispatch + replicated device pool, the two
+forms ``tpu_inference`` serves several chips in (pipelined segmentation,
+``mesh: {pp}``, was removed in PR 45 and is refused by name).
 
 Runs on the 8-device virtual CPU platform conftest pins
 (``--xla_force_host_platform_device_count=8``): real multi-device shardings,
@@ -9,10 +11,6 @@ at-least-once delivery when a member runner is fault-injected.
 """
 
 import asyncio
-import json
-import os
-import subprocess
-import sys
 
 import numpy as np
 import pytest
@@ -336,34 +334,6 @@ def test_seen_shapes_compile_count_thread_safe():
     assert r.m_compiles.value - before == 1
 
 
-# -- tooling smoke (satellite) ---------------------------------------------
-
-
-def test_profile_step_host_mesh_smoke():
-    """CI smoke for ``tools/profile_step.py --devices 2``: runs the
-    host-mesh mode end to end and emits sane per-chip stats."""
-    from arkflow_tpu.utils.cleanenv import cpu_child_env
-
-    env = cpu_child_env(n_devices=2)
-    env["PROF_STEPS"] = "4"
-    env["PROF_BATCH"] = "16"
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    res = subprocess.run(
-        [sys.executable, os.path.join(repo, "tools", "profile_step.py"),
-         "--devices", "2"],
-        env=env, capture_output=True, timeout=420, cwd=repo)
-    assert res.returncode == 0, res.stderr.decode(errors="replace")[-2000:]
-    line = res.stdout.decode().strip().splitlines()[-1]
-    out = json.loads(line)
-    assert out["devices"] == 2
-    assert len(out["per_chip_duty_cycle"]) == 2
-    assert out["rows_per_sec_1chip"] > 0 and out["rows_per_sec_nchip"] > 0
-    assert 0.0 < out["scaling_efficiency"] < 2.0
-    # phase 1 drives member 0 directly (no pool dispatch); phase 2 routes
-    # steps * n = 8 batches through the dispatcher
-    assert sum(out["dispatch_per_chip"]) == 8
-
-
 # -- tensor-parallel continuous generation: parse-time validation -----------
 
 
@@ -404,23 +374,231 @@ def test_generate_mesh_parse_time_validation():
         {**gen, "serving": "batch", "mesh": {"dp": 2, "tp": 2}}))
 
 
-def test_profile_decode_host_mesh_smoke():
-    """CI smoke for ``tools/profile_decode.py --devices 2``: profiles the
-    paged decode step at tp=1 vs tp=2 and emits sane TP-bubble stats."""
-    from arkflow_tpu.utils.cleanenv import cpu_child_env
+# -- the removed pipelined mode is refused wherever it could enter ----------
 
-    env = cpu_child_env(n_devices=2)
-    env["PROF_STEPS"] = "4"
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    res = subprocess.run(
-        [sys.executable, os.path.join(repo, "tools", "profile_decode.py"),
-         "--devices", "2"],
-        env=env, capture_output=True, timeout=420, cwd=repo)
-    assert res.returncode == 0, res.stderr.decode(errors="replace")[-2000:]
-    line = res.stdout.decode().strip().splitlines()[-1]
-    out = json.loads(line)
-    assert out["devices"] == 2
-    assert out["decode_step_ms_1chip"] > 0 and out["decode_step_ms_tp"] > 0
-    assert 0.0 < out["tp_scaling_efficiency"] < 2.0
-    assert 0.0 <= out["collective_share_est"] <= 1.0
-    assert len(out["per_chip_duty_cycle_est"]) == 2
+_PP_STALE_KEYS = {"pp_microbatch_rows": 2, "pp_layer_costs": [1.0, 1.0],
+                  "pp_profile": "prof.json"}
+
+
+def _inference_proc(extra: dict, wrapped: bool = False) -> dict:
+    proc = {"type": "tpu_inference", "model": "bert_classifier",
+            "model_config": TINY_BERT, "max_seq": 16,
+            "batch_buckets": [8], "seq_buckets": [16], **extra}
+    if wrapped:  # two chaos wrappers deep: the checks look through the chain
+        proc = {"type": "fault", "inner": {"type": "fault", "inner": proc}}
+    return proc
+
+
+_PP_PROCS = {
+    "pp2": _inference_proc({"mesh": {"pp": 2}}),
+    "dp2_pp2": _inference_proc({"mesh": {"dp": 2, "pp": 2}}),
+    **{key: _inference_proc({key: val}) for key, val in _PP_STALE_KEYS.items()},
+    "fault_inner_pp2": _inference_proc({"mesh": {"pp": 2}}, wrapped=True),
+    "fault_inner_stale_key": _inference_proc({"pp_profile": "p.json"}, wrapped=True),
+}
+
+
+def _build_proc(proc: dict):
+    from arkflow_tpu.components import Resource, ensure_plugins_loaded
+    from arkflow_tpu.components.registry import build_component
+
+    ensure_plugins_loaded()
+    return build_component("processor", proc, Resource())
+
+
+def _stream_of(proc: dict) -> dict:
+    return {"name": "pp-gone",
+            "input": {"type": "memory", "messages": ["x"]},
+            "pipeline": {"processors": [proc]},
+            "output": {"type": "drop"}}
+
+
+def _refused_by_validate(proc, tmp_path, capsys) -> str:
+    """``python -m arkflow_tpu --config ... --validate`` on a stream YAML."""
+    import yaml
+
+    from arkflow_tpu.runtime.cli import main
+
+    path = tmp_path / "stream.yaml"
+    path.write_text(yaml.safe_dump({"streams": [_stream_of(proc)]}))
+    assert main(["--config", str(path), "--validate"]) == 2
+    return capsys.readouterr().err
+
+
+def _refused_by_build(proc, tmp_path, capsys) -> str:
+    with pytest.raises(ConfigError) as e:
+        _build_proc(proc)
+    return str(e.value)
+
+
+@pytest.mark.parametrize("proc", sorted(_PP_PROCS))
+@pytest.mark.parametrize("entry", [_refused_by_validate, _refused_by_build],
+                         ids=["validate", "build"])
+def test_pp_serving_is_refused_by_name(entry, proc, tmp_path, capsys):
+    """``mesh.pp`` > 1 or any key of the removed mode, alone, is one refusal
+    that says what serves several chips — never ignored, never served as
+    something else; ``--validate`` and a build give the same answer."""
+    msg = entry(_PP_PROCS[proc], tmp_path, capsys)
+    assert "removed in PR 45" in msg
+    assert "mesh: {dp: N}" in msg and "device_pool: N" in msg
+
+
+@pytest.mark.parametrize("spec", [dict(pp=2), dict(dp=2, pp=2)],
+                         ids=["pp2", "dp2_pp2"])
+def test_model_runner_refuses_a_pp_mesh(spec):
+    from arkflow_tpu.parallel.mesh import MeshSpec
+    from arkflow_tpu.tpu.runner import ModelRunner
+
+    with pytest.raises(ConfigError, match=r"removed in PR 45.*dp: N.*device_pool: N"):
+        ModelRunner("bert_classifier", TINY_BERT,
+                    buckets=BucketPolicy((8,), (16,)), mesh_spec=MeshSpec(**spec))
+
+
+def test_tpu_train_keeps_its_pp_mesh_and_parallel_exports_what_stays(tmp_path, capsys):
+    """Training's GPipe schedule is another processor's: ``tpu_train`` with
+    ``mesh: {pp: 2}`` validates as before, and the package exports the
+    training half only."""
+    import yaml
+
+    import arkflow_tpu.parallel as par
+    from arkflow_tpu.parallel import pipeline
+    from arkflow_tpu.runtime.cli import main
+
+    path = tmp_path / "train.yaml"
+    path.write_text(yaml.safe_dump({"streams": [_stream_of(
+        {"type": "tpu_train", "model": "decoder_lm", "mesh": {"pp": 2}})]}))
+    assert main(["--config", str(path), "--validate"]) == 0, capsys.readouterr().err
+    assert {"MeshSpec", "create_mesh", "shard_params"} <= set(vars(par))
+    assert {"make_pp_train_step", "pp_param_specs"} <= set(vars(pipeline))
+    gone = {"StagePlan", "plan_stages", "uniform_plan", "make_pp_infer_step",
+            "pp_repack_layers", "pp_infer_param_specs", "pp_layer_slot_tables"}
+    assert not gone & (set(vars(par)) | set(vars(pipeline)))
+
+
+# -- one rule for the default in-flight depth --------------------------------
+
+
+@pytest.mark.parametrize("form,env,want", [
+    ("single", None, 2), ("dp", None, 2), ("pool_member", None, 2),
+    ("single", "3", 3), ("dp", "3", 3), ("pool_member", "1", 1)])
+def test_default_in_flight_depth_is_one_rule(form, env, want, monkeypatch):
+    """Two steps in flight whatever serves — one device, a dp mesh, a pool's
+    member — unless ``ARKFLOW_INFLIGHT`` says otherwise; an explicit value
+    wins over both and is checked."""
+    _need_devices(2)
+    from arkflow_tpu.parallel.mesh import MeshSpec
+    from arkflow_tpu.tpu.pool import ModelRunnerPool
+    from arkflow_tpu.tpu.runner import ModelRunner
+
+    if env is None:
+        monkeypatch.delenv("ARKFLOW_INFLIGHT", raising=False)
+    else:
+        monkeypatch.setenv("ARKFLOW_INFLIGHT", env)
+    buckets = BucketPolicy((8,), (16,))
+
+    def build(**kw):
+        if form == "pool_member":
+            pool = ModelRunnerPool("bert_classifier", TINY_BERT, pool_size=2,
+                                   buckets=buckets, **kw)
+            assert pool.max_in_flight == 2 * pool.members[0].max_in_flight
+            return pool.members[1]
+        mesh = MeshSpec(dp=2) if form == "dp" else None
+        return ModelRunner("bert_classifier", TINY_BERT, buckets=buckets,
+                           mesh_spec=mesh, **kw)
+
+    assert build().max_in_flight == want
+    if env is not None:
+        assert build(max_in_flight=5).max_in_flight == 5  # explicit beats the env
+    else:
+        with pytest.raises(ConfigError, match="max_in_flight"):
+            build(max_in_flight=0)
+
+
+# -- dp twins of what only the pipelined mode's tests covered under a mesh --
+
+
+#: ``tpu_inference`` under ``mesh: {dp: 2}``, two rows or four a chip
+_DP2_PROC = _inference_proc({"mesh": {"dp": 2}, "batch_buckets": [2, 4]})
+
+
+def test_dp_hot_swap_identical_weights_serves_identically(tmp_path):
+    """A candidate tree is placed with the live tree's shardings
+    (``place_params`` under a mesh), so a flip to the same weights serves the
+    same bytes before, while the swap rolls, and after it."""
+    _need_devices(2)
+    from arkflow_tpu.batch import MessageBatch
+    from arkflow_tpu.tpu import checkpoint
+
+    proc = _build_proc({**_DP2_PROC, "outputs": ["label", "score", "logits"]})
+    runner = proc.runner
+    ck = str(tmp_path / "ck")
+    checkpoint.save(ck, runner.params)
+    shardings = [x.sharding for x in jax.tree_util.tree_leaves(runner.params)]
+    batch = MessageBatch.new_binary([f"swap row {i}".encode() for i in range(7)])
+
+    async def go():
+        (before,) = await proc.process(batch)
+        old = runner.params
+        swap = asyncio.ensure_future(proc.swapper.swap(ck))
+        during = [(await proc.process(batch))[0] for _ in range(3)]
+        rep = await swap
+        assert rep["version"] == 1 and rep["completed"] == 1
+        assert runner.params is not old  # really flipped, not a no-op
+        (after,) = await proc.process(batch)
+        return before, during, after
+
+    before, during, after = asyncio.run(go())
+    assert [x.sharding for x in jax.tree_util.tree_leaves(runner.params)] == shardings
+    for out in (*during, after):
+        assert out == before
+
+
+def test_dp_stream_delivers_every_row_once_in_order_with_acks():
+    """A config-built stream under ``mesh: {dp: 2}``: coalesced emissions on
+    the dp-scaled grid, every source row written once, in order, and acked."""
+    _need_devices(2)
+    from arkflow_tpu.config import StreamConfig
+    from arkflow_tpu.runtime import build_stream
+    from tests.test_runtime import CollectOutput
+
+    rows = [f"dp row {i:02d}" for i in range(22)]  # no multiple of a bucket
+    cfg = StreamConfig.from_mapping({
+        "name": "dp-e2e",
+        "input": {"type": "fault", "redeliver_unacked": True,
+                  "inner": {"type": "memory", "messages": rows}},
+        "buffer": {"type": "memory", "capacity": 16, "timeout": "10ms",
+                   "coalesce": {"batch_buckets": [4, 8], "deadline": "5ms"}},
+        "pipeline": {
+            "thread_num": 2,
+            "processors": [_DP2_PROC],
+        },
+        "output": {"type": "drop"},
+    })
+    stream = build_stream(cfg)
+    sink = stream.output = CollectOutput()
+    runner = stream.pipeline.processors[0].runner
+    assert runner.mesh is not None and runner.buckets.batch_buckets == (4, 8)
+    asyncio.run(asyncio.wait_for(stream.run(asyncio.Event()), timeout=60))
+    written = [r.decode() for b in sink.batches for r in b.to_binary()]
+    assert written == rows  # once each (no redelivery: all acked), in order
+    assert all("label" in b.schema.names for b in sink.batches)
+    assert stream.m_errors.value == 0
+    assert stream.input._outstanding == 0  # the broker settled every read
+
+
+@pytest.mark.parametrize("dp", [2, 4])
+def test_health_report_under_a_mesh(dp):
+    """``/health`` of a runner on a mesh: the serving core's state, the
+    model, and the dp-scaled bucket cap (per-chip cap x dp); nothing of the
+    removed stage plan."""
+    _need_devices(dp)
+    from arkflow_tpu.parallel.mesh import MeshSpec
+    from arkflow_tpu.tpu.runner import ModelRunner
+
+    r = ModelRunner("bert_classifier", TINY_BERT,
+                    buckets=BucketPolicy((4, 8), (16,)), mesh_spec=MeshSpec(dp=dp))
+    r.infer_sync(_tiny_inputs(n=8 * dp))
+    rep = r.health_report()
+    assert rep["state"] == "healthy" and rep["model"] == "bert_classifier"
+    assert rep["bucket_cap"] == 8 * r.mesh.shape["dp"] == 8 * dp
+    assert "pp" not in rep and not hasattr(r, "pp_report")

@@ -86,8 +86,7 @@ def _labels(proc, texts):
 @pytest.mark.parametrize("dtype", ["bfloat16", "int8"])
 def test_packed_low_precision_argmax_parity(dtype):
     """The measured default (packed + bf16; int8 = W8A8) must label exactly
-    like the float32 unpacked reference on ragged mixes and edge batches —
-    the same gate bench.py runs before its headline phase."""
+    like the float32 unpacked reference on ragged mixes and edge batches."""
     packed = _processor(dtype, packing=True)
     ref = _processor("float32", packing=False)
 
@@ -579,53 +578,6 @@ def test_memory_buffer_builder_scales_token_budget_by_dp():
     }, Resource())
     assert buf._coalescer.token_budget == 200  # global = per-chip x dp
     assert buf._coalescer.buckets == (16,)
-
-
-# ---------------------------------------------------------------------------
-# CI smoke: the packed ragged bench phase end-to-end (tier-1-safe size)
-# ---------------------------------------------------------------------------
-
-def test_bench_packed_ragged_smoke():
-    """Runs bench.py the way the driver does — packed + low-precision
-    default, ragged payloads, token-budget coalescing — at smoke size, so a
-    packing/parity/waste regression surfaces in CI without a full bench.
-    Asserts the parity gate ran, the knobs are recorded in the detail, and
-    the capacity-weighted padding waste stays far below the unpacked
-    baseline's 0.6+ (full-size runs measure <= 0.05; the smoke's smaller
-    token budget leaves relatively larger residue windows)."""
-    import json
-    import os
-    import pathlib
-    import subprocess
-    import sys
-
-    env = dict(os.environ)
-    env.update({"BENCH_PACKING": "1", "BENCH_RAGGED": "1", "BENCH_TINY": "1",
-                "BENCH_BATCH": "128", "BENCH_SECONDS": "3",
-                "BENCH_SKIP_LATENCY": "1", "JAX_PLATFORMS": "cpu"})
-    from arkflow_tpu.utils.cleanenv import pin_cpu_env
-
-    pin_cpu_env(env)
-    repo = pathlib.Path(__file__).resolve().parent.parent
-    out = subprocess.run(
-        [sys.executable, str(repo / "bench.py")], env=env, cwd=str(repo),
-        capture_output=True, text=True, timeout=300)
-    assert out.returncode == 0, out.stderr[-2000:]
-    lines = [ln for ln in out.stdout.splitlines() if ln.startswith("{")]
-    assert lines, out.stdout
-    headline = json.loads(lines[-1])
-    detail = headline["detail"]
-    assert headline["value"] > 0
-    assert detail["packing"] is True
-    assert detail["ragged_payloads"] is True
-    assert detail["coalesce"] is True
-    assert detail["coalesce_token_budget"] == 128 * 32 - 2 * 32
-    assert detail["serving_dtype"] == "bfloat16"
-    # the parity gate really ran (a failure would have flipped the phase to
-    # the unpacked float32 fallback and tagged it so)
-    assert detail.get("parity") == "argmax_vs_unpacked_float32"
-    assert detail["padding_waste_frac"] <= 0.15
-    assert detail["tokens_per_sec"] > 0
 
 
 def test_memory_buffer_builder_rejects_bad_token_knobs():

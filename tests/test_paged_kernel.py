@@ -1174,30 +1174,6 @@ def test_tpu_generate_processor_plumbs_kernel_and_depth():
     assert rep["decode_kernel"] == "paged" and rep["dispatch_depth"] == 2
 
 
-@pytest.mark.slow
-def test_profile_decode_kernel_mode_smoke():
-    """CI smoke for ``tools/profile_decode.py --kernel paged``: both the
-    kernel speedup line and the depth-1-vs-2 idle-gap stats come out sane."""
-    from arkflow_tpu.utils.cleanenv import cpu_child_env
-
-    env = cpu_child_env(n_devices=1)
-    env.update({"PROF_STEPS": "4", "PROF_SLOTS": "4", "PROF_CTX": "32",
-                "PROF_PAGE": "8"})
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    res = subprocess.run(
-        [sys.executable, os.path.join(repo, "tools", "profile_decode.py"),
-         "--kernel", "paged"],
-        env=env, capture_output=True, timeout=420, cwd=repo)
-    assert res.returncode == 0, res.stderr.decode(errors="replace")[-2000:]
-    out = json.loads(res.stdout.decode().strip().splitlines()[-1])
-    assert out["kernel"] == "paged"
-    assert out["decode_step_ms_gather"] > 0 and out["decode_step_ms_paged"] > 0
-    assert out["paged_vs_gather_speedup"] > 0
-    assert "p50" in out["device_idle_gap_ms_depth1"]
-    assert "p50" in out["device_idle_gap_ms_depth2"]
-    assert out["paged_interpreted"] is True  # CPU child: honest caveat
-
-
 def test_profile_paged_attention_rehearses_on_the_cpu_and_times_nothing_there():
     """``tools/profile_paged_attention.py``: without a TPU it refuses to
     time; ``--interpret`` rehearses a cell's points at tiny sizes — the

@@ -258,23 +258,23 @@ def test_on_tpu_backend_propagates_device_query_errors(monkeypatch):
     assert on_tpu_backend([FakeTpu()]) is True  # explicit devices: no query
 
 
-def test_bench_peak_table_is_exact_and_raises_on_unknown_kind(monkeypatch):
+def test_bench_peak_table_is_exact_and_raises_on_unknown_kind():
     """The bench's peaks are one table keyed by the exact device_kind, each
     with its source; a kind that is not listed is an error — no substring
-    guess, no env override."""
-    import bench  # repo root is on sys.path (conftest)
+    guess, no default."""
+    import json
 
-    monkeypatch.delenv("BENCH_DTYPE", raising=False)
-    monkeypatch.setenv("BENCH_PEAK_TFLOPS", "999")  # the old override: inert
-    assert bench._device_peak_tflops("TPU v5 lite") == 197.0
-    monkeypatch.setenv("BENCH_DTYPE", "int8")
-    assert bench._device_peak_tflops("TPU v5 lite") == 393.0
-    assert all(p["source"] for p in bench.DEVICE_PEAKS.values())
+    from benchmark.lib import costs  # repo root is on sys.path (conftest)
+
+    peaks = costs.device_peaks("TPU v5 lite")
+    assert peaks["bf16_flops_per_s"] == 197e12
+    assert peaks["int8_ops_per_s"] == 393e12
+    assert peaks["hbm_bytes_per_s"] == 819e9
+    with open(os.path.join(costs.HERE, "peaks.json")) as f:
+        assert all(p["source"] for p in json.load(f).values())
     for kind in ("TPU v5", "TPU v5p", "tpu v5 lite", "cpu"):
-        with pytest.raises(KeyError, match="no peak on record"):
-            bench._device_peak_tflops(kind)
-    with pytest.raises(KeyError, match="no peak on record"):
-        bench._device_peak_tflops()  # this process: the CPU test platform
+        with pytest.raises(KeyError, match="no peaks on record"):
+            costs.device_peaks(kind)
 
 
 def test_e2e_streaming_bert_classification():

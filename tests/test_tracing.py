@@ -917,12 +917,15 @@ def test_tracing_disabled_same_tokens_and_no_span():
         tracer.configure(TracingConfig())
 
 
+@pytest.mark.parametrize("dp", [1, 2], ids=["one_device", "dp2"])
 @pytest.mark.parametrize("depth", [1, 2])
-def test_device_fetch_recorded_beside_device_step(depth):
+def test_device_fetch_recorded_beside_device_step(depth, dp):
     """The classify step's fetch (copy + host conversion) is its own span
-    on the depth-1 and the depth-2 path, inside the step that contains it."""
+    on the depth-1 and the depth-2 path, inside the step that contains it —
+    on one device and sharded over a dp mesh, under the same stage names."""
     import numpy as np
 
+    from arkflow_tpu.parallel.mesh import MeshSpec
     from arkflow_tpu.tpu.bucketing import BucketPolicy
     from arkflow_tpu.tpu.runner import ModelRunner
 
@@ -931,7 +934,8 @@ def test_device_fetch_recorded_beside_device_step(depth):
         "bert_classifier",
         {"vocab_size": 128, "hidden": 16, "layers": 1, "heads": 2,
          "ffn": 32, "max_positions": 32, "num_labels": 2},
-        buckets=BucketPolicy((2,), (16,)), dispatch_depth=depth)
+        buckets=BucketPolicy((2,), (16,)), dispatch_depth=depth,
+        mesh_spec=MeshSpec(dp=dp) if dp > 1 else None)
     inputs = {"input_ids": np.zeros((2, 16), dtype=np.int32),
               "attention_mask": np.ones((2, 16), dtype=np.int32)}
 

@@ -678,11 +678,9 @@ def test_a_latent_model_refuses_the_per_head_keys():
             dec.DecoderConfig(**{**LATENT, **bad})
 
 
-def test_the_batch_cache_and_the_pipeline_refuse_a_model_that_stacks_by_runs():
+def test_the_batch_cache_refuses_a_model_that_stacks_by_runs():
     with pytest.raises(ConfigError, match="serving: continuous"):
         dec.init_kv_cache(CFG, 1, 16)
-    with pytest.raises(ConfigError, match="serving: continuous"):
-        dec.pp_stage_fns(CFG)
 
 
 # -- the judge ----------------------------------------------------------------------

@@ -4,10 +4,7 @@ Default mode soaks the self-healing device layer: a fault-wrapped
 redelivering broker input, a memory buffer with bucket-exact coalescing, and
 a ``device_pool`` tpu_inference stage whose steps are chaos-injected
 (``hang`` / ``oom`` via the fault plugin's schedule, plus a ``disconnect``
-on the input), run to completion under a wall-clock bound — followed by a
-pipelined-parallel (``mesh: {pp: 2}``) phase whose first device step is
-chaos-hung past its step_deadline, proving a hung STAGE nacks the batch,
-heals through the shared ServingRunnerCore probe path, and loses zero rows:
+on the input), run to completion under a wall-clock bound:
 
     python tools/chaos_soak.py --fast            # tier-1 smoke (~seconds)
     python tools/chaos_soak.py --seconds 120 --seed 3 --messages 256
@@ -246,138 +243,6 @@ def _soak_config(seed: int, messages: int, pool: int, fast: bool) -> dict:
     }
 
 
-def _pp_soak_config(seed: int, messages: int, fast: bool) -> dict:
-    """Pipelined-parallel deadline-miss case: a ``mesh: {pp: 2}`` stream
-    whose first device step is chaos-hung past its step_deadline — a hung
-    STAGE wedges the whole pipeline step, so the watchdog must abandon it,
-    nack the batch, and heal through the shared ServingRunnerCore probe
-    path exactly like the single-device/pool paths."""
-    payloads = [f"pp row {i:04d}" for i in range(messages)]
-    tiny_model = {"vocab_size": 512, "hidden": 32, "layers": 2, "heads": 4,
-                  "ffn": 64, "max_positions": 64, "num_labels": 2}
-    return {
-        "name": "chaos-soak-pp",
-        "input": {
-            "type": "fault",
-            "seed": seed,
-            "redeliver_unacked": True,
-            "reconnect": {"initial_delay_ms": 1, "max_delay_ms": 50},
-            "inner": {"type": "memory", "messages": payloads},
-        },
-        "buffer": {
-            "type": "memory", "capacity": 64, "timeout": "20ms",
-            "coalesce": {"batch_buckets": [2, 4], "deadline": "10ms"},
-        },
-        "pipeline": {
-            "thread_num": 2,
-            "max_delivery_attempts": 8,
-            "processors": [{
-                "type": "fault",
-                "seed": seed,
-                "faults": [{"kind": "hang", "at": 1, "duration": "5s"}],
-                "inner": {
-                    "type": "tpu_inference",
-                    "model": "bert_classifier",
-                    "model_config": tiny_model,
-                    "max_seq": 16,
-                    "batch_buckets": [2, 4],
-                    "seq_buckets": [16],
-                    "mesh": {"pp": 2},
-                    "pp_microbatch_rows": 2,
-                    "warmup": True,  # honest steady-state step deadlines
-                    "step_deadline": "500ms",
-                    "step_deadline_first": "60s",
-                    "health": {"probe_backoff": "100ms",
-                               "probe_backoff_cap": "2s"},
-                },
-            }],
-        },
-        "output": {"type": "drop"},
-    }
-
-
-def _run_pp_deadline_phase(seconds: float, seed: int, fast: bool) -> dict:
-    """The pp-stage deadline-miss phase of the default soak. PASS = the hung
-    stage produced a deadline miss (counted + nacked), every offered row
-    was still delivered (zero silent loss through redelivery), and the pp
-    runner healed back to HEALTHY through the ServingRunnerCore probes."""
-    import asyncio
-
-    import jax
-
-    from arkflow_tpu.batch import MessageBatch
-    from arkflow_tpu.config import StreamConfig
-    from arkflow_tpu.obs import global_registry
-    from arkflow_tpu.plugins.output.drop import DropOutput
-    from arkflow_tpu.runtime import build_stream
-
-    if len(jax.devices()) < 2:
-        return {"skipped": "needs 2 devices", "pass": True}
-    messages = 6 if fast else 24
-    reg = global_registry()
-    misses0 = reg.sum_values("arkflow_tpu_step_deadline_misses")
-    cfg = StreamConfig.from_mapping(_pp_soak_config(seed, messages, fast))
-    stream = build_stream(cfg)
-    delivered: list[bytes] = []
-
-    class _Collect(DropOutput):
-        async def write(self, batch: MessageBatch) -> None:
-            await super().write(batch)
-            delivered.extend(batch.to_binary())
-
-    stream.output = _Collect()
-    runner = stream.pipeline.processors[0]._inner.runner
-
-    async def bounded() -> bool:
-        cancel = asyncio.Event()
-        task = asyncio.create_task(stream.run(cancel))
-        done, _ = await asyncio.wait({task}, timeout=seconds)
-        if done:
-            task.result()
-            return False
-        cancel.set()
-        try:
-            await asyncio.wait_for(task, timeout=15.0)
-        except (asyncio.TimeoutError, Exception):
-            task.cancel()
-        return True
-
-    async def heal() -> None:
-        import numpy as np
-
-        probe = {"input_ids": np.ones((2, 16), np.int32),
-                 "attention_mask": np.ones((2, 16), np.int32)}
-        deadline = time.monotonic() + 10
-        while (runner.health.state not in ("healthy", "degraded")
-               and time.monotonic() < deadline):
-            await asyncio.sleep(0.06)
-            try:
-                await runner.infer(probe)
-            except Exception:
-                pass
-
-    wedged = asyncio.run(bounded())
-    if not wedged:
-        asyncio.run(heal())
-    expected = {f"pp row {i:04d}".encode() for i in range(messages)}
-    missing = sorted(expected - set(delivered))
-    misses = reg.sum_values("arkflow_tpu_step_deadline_misses") - misses0
-    verdict = {
-        "pass": bool(not wedged and not missing and misses > 0
-                     and runner.health.state in ("healthy", "degraded")),
-        "wedged": wedged,
-        "messages": messages,
-        "delivered_rows": len(delivered),
-        "missing_rows": len(missing),
-        "deadline_misses": misses,
-        "runner_state": runner.health.state,
-        "pp": runner.pp_report(),
-    }
-    if missing:
-        verdict["missing_sample"] = [m.decode() for m in missing[:5]]
-    return verdict
-
-
 def run_soak(seconds: float = 60.0, seed: int = 7, messages: int = 48,
              pool: int = 2, fast: bool = False) -> dict:
     """Run the soak in-process and return the verdict dict. Importing this
@@ -477,11 +342,6 @@ def run_soak(seconds: float = 60.0, seed: int = 7, messages: int = 48,
     }
     if missing:
         verdict["missing_sample"] = [m.decode() for m in missing[:5]]
-    # pipelined-parallel deadline-miss case: a hung STAGE must nack, heal
-    # through the shared ServingRunnerCore probe path, and lose zero rows
-    verdict["pp"] = _run_pp_deadline_phase(
-        min(seconds, 30.0) if fast else seconds, seed, fast)
-    verdict["pass"] = bool(verdict["pass"] and verdict["pp"]["pass"])
     return _attach_tracing(verdict, trace_seq0, trace_forced0)
 
 
