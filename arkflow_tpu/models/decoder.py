@@ -28,6 +28,10 @@ from arkflow_tpu.models.registry import ModelFamily, register_model
 
 
 FULL, SLIDING = "full_attention", "sliding_attention"
+#: a layer whose mixer is a gated short convolution (``short_conv``): no
+#: attention weights, no keys or values, a per-slot window of its last
+#: ``conv_L_cache - 1`` gated inputs instead
+CONV = "conv"
 
 
 @dataclass(frozen=True)
@@ -94,6 +98,16 @@ class GqaSpec:
     def dk_held(self) -> int:
         """A key's width as the page pools hold it, its padding included."""
         return self.dk if self.key_parts == 1 else self.key_parts * 128
+
+    @property
+    def row_major(self) -> bool:
+        """True where the page pools hold a token's heads side by side,
+        [.., kv_heads * width], and not on an axis of their own: a head
+        narrower than 128 lanes, keys and values alike and no sink — what
+        the attention kernel's narrow-head walk reads
+        (``ops/ragged_attention._narrow_kernel``; a pool [.., kv_heads,
+        width < 128] cannot be copied a page at a time on a chip)."""
+        return self.dk == self.dv < 128 and not self.sink
 
 
 @dataclass(frozen=True)
@@ -258,6 +272,26 @@ class DecoderConfig:
     ssm_multipliers: tuple = (1.0, 1.0, 1.0, 1.0, 1.0)
     mlp_multipliers: tuple = (1.0, 1.0)
     lm_head_multiplier: float = 1.0
+    # -- gated short-convolution layers among per-head K/V layers (LFM2),
+    # under the published key names. ``layer_types`` names a layer ``conv``:
+    # its mixer is ``[B | C | u] = y W_in``, a depthwise causal conv of
+    # ``conv_L_cache`` taps over ``B * u`` (no activation, no bias:
+    # ``conv_bias`` true is refused until a source states it), times ``C``,
+    # then ``W_out`` — no attention weights and no keys or values: what a
+    # SEQUENCE caches of it is its last ``conv_L_cache - 1`` gated inputs
+    # (``paged_decode.cache_spec``: kind ``conv``, a row a slot). The
+    # leading layers with a dense SwiGLU (this family's ``num_dense_layers``)
+    # are ``first_k_dense_replace``; they may be conv layers.
+    # ``norm_topk_eps`` is what the routed weights' normalisation adds to
+    # their sum (this family: 1e-6).
+    conv_L_cache: int = 0
+    conv_bias: bool = False
+    norm_topk_eps: float = 1e-20
+    #: > 0: the routed layers' selection bias is SEEDED normal(0, this) and
+    #: not uniform within +-0.01: wide enough beside the scores' own spread
+    #: (~0.13 under a random router) that it decides choices and load, as a
+    #: trained balancing bias does (``_init_ffn``)
+    router_bias_std: float = 0.0
 
     def __post_init__(self):
         from arkflow_tpu.errors import ConfigError
@@ -369,10 +403,12 @@ class DecoderConfig:
         gates = (self.attention_gate_type, self.swa_attention_gate_type)
         kinds = self.kinds
         if self.layer_types is not None and (
-                len(kinds) != self.layers or set(kinds) - {FULL, SLIDING}):
+                len(kinds) != self.layers
+                or set(kinds) - {FULL, SLIDING, CONV}):
             raise ConfigError(
                 f"layer_types names each of the {self.layers} layers "
-                f"{FULL!r} or {SLIDING!r}, got {self.layer_types}")
+                f"{FULL!r}, {SLIDING!r} or {CONV!r}, got {self.layer_types}")
+        self._check_conv()
         if not self.latent:
             if any(extras[1:]) or any(gates) or self.apply_mla_qkv_lora_rescale \
                     or self.swa_q_lora_rank is not None \
@@ -392,9 +428,11 @@ class DecoderConfig:
                     self.hybrid or self.num_experts > 1
                     or self.use_ring_attention):
                 raise ConfigError(
-                    "a layer pattern over per-head K/V layers, and qk_norm, "
-                    "compose with neither the hybrid block (mamba_d_ssm), "
-                    "the Switch top-1 layer (num_experts) nor ring attention")
+                    "a layer pattern over per-head K/V layers (sliding or "
+                    "conv layers among them), and qk_norm, compose with "
+                    "neither the hybrid block (mamba_d_ssm: a Mamba-2 mixer "
+                    "beside every layer's attention), the Switch top-1 "
+                    "layer (num_experts) nor ring attention")
             self._check_gqa_kinds()
             return
         if (self.swa_kv_heads or self.partial_rotary_factor != 1.0
@@ -440,6 +478,28 @@ class DecoderConfig:
         elif self.index_n_heads or self.index_head_dim:
             raise ConfigError("index_n_heads / index_head_dim without index_topk")
 
+    def _check_conv(self) -> None:
+        """The conv kind's keys, and what it is not served beside yet."""
+        from arkflow_tpu.errors import ConfigError
+
+        if not self.conv:
+            if self.conv_L_cache or self.conv_bias:
+                raise ConfigError("conv_L_cache / conv_bias without a conv "
+                                  "layer in layer_types")
+            return
+        if self.conv_bias:
+            raise ConfigError("conv_bias: a bias a channel on the conv is "
+                              "not served (no source here states one)")
+        if self.conv_L_cache < 2:
+            raise ConfigError(
+                "a conv layer needs conv_L_cache >= 2 (the taps of its "
+                f"depthwise causal conv), got {self.conv_L_cache}")
+        if self.latent or SLIDING in self.kinds or FULL not in self.kinds:
+            raise ConfigError(
+                "conv layers are served among full_attention per-head K/V "
+                "layers (at least one): beside a latent row (kv_lora_rank) "
+                "or a sliding window's pool they are not, yet")
+
     @property
     def latent(self) -> bool:
         return self.kv_lora_rank > 0
@@ -448,6 +508,23 @@ class DecoderConfig:
     def hybrid(self) -> bool:
         """True where every layer runs a Mamba-2 mixer beside its attention."""
         return self.mamba_d_ssm > 0
+
+    @property
+    def conv(self) -> bool:
+        """True where some layers' mixer is a gated short convolution."""
+        return CONV in self.kinds
+
+    @property
+    def stateful(self) -> bool:
+        """True where a sequence caches a state beside its rows by token
+        (``paged_decode.cache_spec``: a ``per_slot`` pool)."""
+        return self.hybrid or self.conv
+
+    @property
+    def attn_kinds(self) -> tuple:
+        """The kinds of the layers that attend (``gqa`` / ``attn`` state
+        their sizes), in the layers' order."""
+        return tuple(k for k in self.kinds if k != CONV)
 
     @property
     def dh(self) -> int:
@@ -479,7 +556,7 @@ class DecoderConfig:
         per-head norms); otherwise ``layers`` is the one stack of identical
         layers, as it always was."""
         return (self.latent or self.routed or self.layered or self.qk_norm
-                or self.hetero)
+                or self.hetero or self.conv)
 
     def _check_gqa_kinds(self) -> None:
         """The per-kind keys of a per-head K/V model (``gqa``)."""
@@ -503,7 +580,7 @@ class DecoderConfig:
             raise ConfigError(
                 "v_head_dim, swa_v_head_dim, swa_rope_theta >= 0 and "
                 "attention_value_scale > 0")
-        for kind in set(self.kinds):
+        for kind in set(self.attn_kinds):
             kvh = self.gqa(kind).kv_heads
             if kvh <= 0 or self.heads % kvh:
                 key = "swa_kv_heads" if kind == SLIDING else "kv_heads"
@@ -542,7 +619,7 @@ class DecoderConfig:
         kinds of different shapes in stacks of their own."""
         if self.latent:
             return False
-        specs = [self.gqa(kind) for kind in dict.fromkeys(self.kinds)]
+        specs = [self.gqa(kind) for kind in dict.fromkeys(self.attn_kinds)]
         return (any(sp.dv != sp.dk or sp.sink for sp in specs)
                 or len({sp.shape for sp in specs}) > 1
                 or self.partial_rotary_factor != 1.0
@@ -552,8 +629,8 @@ class DecoderConfig:
     def kind_stacks(self) -> bool:
         """True where a layer's kind decides the stack its parameters live
         in: a latent model's kinds, and a per-head model's where their
-        shapes differ."""
-        return self.latent or len(
+        shapes differ (a conv layer has no attention weights at all)."""
+        return self.latent or self.conv or len(
             {self.gqa(kind).shape for kind in self.kinds}) > 1
 
     def attn(self, kind: str) -> "AttnSpec":
@@ -678,8 +755,12 @@ def _init_ffn(k, cfg: DecoderConfig, routed: bool) -> dict:
     # already, stays balanced. (+-0.1 was tried on the chip, PERF.md PR 27:
     # it decides the selection, 16 lanes then hit 52 experts a layer and
     # not 69, and the busiest expert takes 9 x the mean load.)
-    layer["router_bias"] = jax.random.uniform(
-        next(k), (cfg.n_routed_experts,), jnp.float32, -0.01, 0.01)
+    bias_key = next(k)
+    layer["router_bias"] = (
+        cfg.router_bias_std * jax.random.normal(
+            bias_key, (cfg.n_routed_experts,), jnp.float32)
+        if cfg.router_bias_std > 0 else jax.random.uniform(
+            bias_key, (cfg.n_routed_experts,), jnp.float32, -0.01, 0.01))
     layer["experts"] = {
         "w_gate": jax.random.uniform(next(k), (e, cfg.dim, f), jnp.float32, -up, up),
         "w_up": jax.random.uniform(next(k), (e, cfg.dim, f), jnp.float32, -up, up),
@@ -691,7 +772,8 @@ def _init_ffn(k, cfg: DecoderConfig, routed: bool) -> dict:
 #: the stack a layer's parameters live in, by (kind, routed?): layers of one
 #: shape stack on a leading axis
 _STACKS = {(FULL, False): "dense_layers", (FULL, True): "layers",
-           (SLIDING, False): "swa_dense_layers", (SLIDING, True): "swa_layers"}
+           (SLIDING, False): "swa_dense_layers", (SLIDING, True): "swa_layers",
+           (CONV, False): "conv_dense_layers", (CONV, True): "conv_layers"}
 
 
 def layer_runs(cfg: DecoderConfig) -> list:
@@ -752,6 +834,27 @@ def _init_gqa_layer(key, cfg: DecoderConfig, routed: bool,
     return layer
 
 
+def _init_conv_layer(key, cfg: DecoderConfig, routed: bool) -> dict:
+    """One conv layer (HF names: operator_norm, conv.in_proj, conv.conv,
+    conv.out_proj, ffn_norm): the input projection's columns are B | C | u,
+    ``conv_w`` [dim, conv_L_cache] the depthwise taps, oldest input first
+    (torch's default init: uniform within fan_in ** -0.5), and a dense
+    SwiGLU or the routed experts. The operator norm keeps the name every
+    stack's first norm has (``attn_norm``)."""
+    k = iter(jax.random.split(key, 12))
+    bound = cfg.conv_L_cache ** -0.5
+    layer = {
+        "attn_norm": cm.rms_norm_init(cfg.dim),
+        "conv_in": cm.dense_init(next(k), cfg.dim, 3 * cfg.dim, bias=False),
+        "conv_w": jax.random.uniform(next(k), (cfg.dim, cfg.conv_L_cache),
+                                     jnp.float32, -bound, bound),
+        "conv_out": cm.dense_init(next(k), cfg.dim, cfg.dim, bias=False),
+        "mlp_norm": cm.rms_norm_init(cfg.dim),
+    }
+    layer.update(_init_ffn(k, cfg, routed))
+    return layer
+
+
 def _init_runs(rng, cfg: DecoderConfig) -> dict:
     """``init`` for a model whose layers stack by runs (``layer_runs``)."""
     keys = iter(jax.random.split(rng, 2 + cfg.layers))
@@ -764,6 +867,7 @@ def _init_runs(rng, cfg: DecoderConfig) -> dict:
     for name, first, stop, kind, routed, _ in layer_runs(cfg):
         stacks.setdefault(name, []).extend(
             _init_latent_layer(next(keys), cfg, routed, kind) if cfg.latent
+            else _init_conv_layer(next(keys), cfg, routed) if kind == CONV
             else _init_gqa_layer(next(keys), cfg, routed, kind)
             for _ in range(first, stop))
     for name, stack in stacks.items():
@@ -938,6 +1042,30 @@ def _mixer_block(lp: dict, y: jnp.ndarray, cfg: DecoderConfig) -> jnp.ndarray:
                    jnp.float32)
     o, _ = scan_from(s0, x, step, a, bm, cmat, cfg.mamba_chunk_size)
     return ssm_output(lp, o, x, z, cfg, y.dtype)
+
+
+def short_conv(lp: dict, y: jnp.ndarray, cfg: DecoderConfig, before=None):
+    """A conv layer's mixer over normed activations ``y`` [B, S, dim]:
+    ``[B | C | u] = y W_in``, the gated input ``v = B * u`` (rounded to the
+    activations' type: what a sequence caches), the depthwise causal conv
+    ``c_t = sum_j w[:, j] v_{t - (L - 1) + j}`` in float32 with no
+    activation, ``C * c``, then ``W_out``. ``before`` [B, L - 1, dim]: the
+    gated inputs of the positions before the block, oldest first (None: the
+    sequence starts here, zeros). Returns (the mixer's output [B, S, dim],
+    ``before`` and the block's gated inputs joined [B, L - 1 + S, dim])."""
+    b, s, d = y.shape
+    taps = cfg.conv_L_cache
+    bcu = cm.dense(lp["conv_in"], y)
+    gate_b, gate_c, u = (bcu[..., i * d:(i + 1) * d].astype(jnp.float32)
+                         for i in range(3))
+    v = (gate_b * u).astype(bcu.dtype)
+    if before is None:
+        before = jnp.zeros((b, taps - 1, d), v.dtype)
+    ext = jnp.concatenate([before.astype(v.dtype), v], axis=1)
+    w = lp["conv_w"].astype(jnp.float32)                          # [dim, L]
+    conved = sum(ext[:, j:j + s].astype(jnp.float32) * w[:, j]
+                 for j in range(taps))
+    return cm.dense(lp["conv_out"], (gate_c * conved).astype(bcu.dtype)), ext
 
 
 def _rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
@@ -1134,7 +1262,8 @@ def route_topk(lp: dict, y: jnp.ndarray, cfg: DecoderConfig, token_mask=None):
         precision=jax.lax.Precision.HIGHEST))
     _, idx = jax.lax.top_k(scores + lp["router_bias"].astype(jnp.float32), k)
     w = jnp.take_along_axis(scores, idx, axis=-1)                 # [T, k]
-    w = w / (w.sum(axis=-1, keepdims=True) + 1e-20) * cfg.routed_scaling_factor
+    w = (w / (w.sum(axis=-1, keepdims=True) + cfg.norm_topk_eps)
+         * cfg.routed_scaling_factor)
     chosen = jax.nn.one_hot(idx, e, dtype=jnp.float32)            # [T, k, E]
     live = (jnp.ones(y.shape[:1], jnp.float32) if token_mask is None
             else token_mask.reshape(-1).astype(jnp.float32))
@@ -1377,7 +1506,12 @@ def forward(params: dict, cfg: DecoderConfig, input_ids, *, axes=None, mesh=None
 
     def make_layer(routed: bool, kind: str):
         def layer(x, lp):
-            x = _attention_block(lp, x, cfg, positions, causal, ring_attn, kind)
+            if kind == CONV:  # from the sequence's start: zeros before it
+                x = x + short_conv(
+                    lp, cm.rms_norm(lp["attn_norm"], x, cfg.norm_eps), cfg)[0]
+            else:
+                x = _attention_block(lp, x, cfg, positions, causal, ring_attn,
+                                     kind)
             x = _shard_act(x, axes)
             y = cm.rms_norm(lp["mlp_norm"], x, cfg.norm_eps)
             aux = (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32))
@@ -1585,6 +1719,31 @@ def _mixer_dtypes() -> dict:
             "ssm_norm": {"scale": f32}, "ssm_out": {"w": bf16}}
 
 
+def _attn_dtypes(cfg: DecoderConfig, kind: str) -> dict:
+    """``serve_dtypes`` of what an attending layer of ``kind`` holds beside
+    its query and output projections."""
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    sp = cfg.attn(kind)  # a per-head model's reads no latent extra
+    if cfg.latent:
+        layer = dict(wkv_a={"w": bf16}, kv_norm={"scale": f32},
+                     wkv_b={"w": bf16})
+    else:
+        layer = dict(wk={"w": bf16}, wv={"w": bf16})
+        if cfg.qk_norm:
+            layer.update(q_head_norm={"scale": f32}, k_head_norm={"scale": f32})
+        if cfg.gqa(kind).sink:
+            layer["attn_sink"] = f32
+    if sp.q_lora_rank:
+        layer.update(wq_a={"w": bf16}, q_norm={"scale": f32})
+    if sp.gate:
+        layer["w_head_gate"] = {"w": bf16}
+    if sp.index_topk:
+        layer.update(index_wq={"w": f32}, index_wk={"w": f32},
+                     index_k_norm={"scale": f32, "bias": f32},
+                     index_w={"w": f32})
+    return layer
+
+
 def _serve_dtypes_runs(cfg: DecoderConfig) -> dict:
     """``serve_dtypes`` for a model that stacks by runs: the router, its
     selection bias, the indexer (it selects too) and every norm scale (the
@@ -1598,26 +1757,12 @@ def _serve_dtypes_runs(cfg: DecoderConfig) -> dict:
         "lm_head": {"w": bf16},
     }
     for name, _, _, kind, routed, _ in layer_runs(cfg):
-        sp = cfg.attn(kind)  # a per-head model's reads no latent extra
-        layer = {"attn_norm": {"scale": f32}, "wq": {"w": bf16},
-                 "wo": {"w": bf16}, "mlp_norm": {"scale": f32}}
-        if cfg.latent:
-            layer.update(wkv_a={"w": bf16}, kv_norm={"scale": f32},
-                         wkv_b={"w": bf16})
+        layer = {"attn_norm": {"scale": f32}, "mlp_norm": {"scale": f32}}
+        if kind == CONV:
+            layer.update(conv_in={"w": bf16}, conv_w=bf16, conv_out={"w": bf16})
         else:
-            layer.update(wk={"w": bf16}, wv={"w": bf16})
-            if cfg.qk_norm:
-                layer.update(q_head_norm={"scale": f32}, k_head_norm={"scale": f32})
-            if cfg.gqa(kind).sink:
-                layer["attn_sink"] = f32
-        if sp.q_lora_rank:
-            layer.update(wq_a={"w": bf16}, q_norm={"scale": f32})
-        if sp.gate:
-            layer["w_head_gate"] = {"w": bf16}
-        if sp.index_topk:
-            layer.update(index_wq={"w": f32}, index_wk={"w": f32},
-                         index_k_norm={"scale": f32, "bias": f32},
-                         index_w={"w": f32})
+            layer.update(wq={"w": bf16}, wo={"w": bf16},
+                         **_attn_dtypes(cfg, kind))
         if routed:
             layer.update(router={"w": f32}, router_bias=f32,
                          experts={"w_gate": bf16, "w_up": bf16, "w_down": bf16})
@@ -1640,8 +1785,9 @@ def _no_latent(cfg: DecoderConfig, what: str) -> None:
             "(n_routed_experts), a layer pattern (layer_types: window "
             "pages beside kept pages), qk_norm, or head sizes by kind "
             "(swa_kv_heads, v_head_dim, partial_rotary_factor, "
-            "attention_value_scale, a sink) generates through serving: "
-            "continuous")
+            "attention_value_scale, a sink), or conv layers among its "
+            "attention layers (a conv pool a slot beside the K/V pages) "
+            "generates through serving: continuous")
     if cfg.hybrid:
         from arkflow_tpu.errors import ConfigError
 

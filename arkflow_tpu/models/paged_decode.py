@@ -12,7 +12,11 @@ Page 0 is a reserved scratch page: inactive slots and masked prompt padding
 write there, which keeps the scatter free of conditionals.
 
 The cache's shape is the model's to state and this module's to own
-(``cache_spec``): per-head K/V (GQA), or latent rows (MLA, ``cfg.latent``:
+(``cache_spec``): per-head K/V (GQA) — a head narrower than 128 lanes in
+ROW-MAJOR pools, ``[layers, pages, page, kv_heads * dh]``, which the
+attention kernel's narrow-head walk copies a page at a time —, a state a
+SEQUENCE beside them (a hybrid layer's Mamba-2 state, a conv layer's last
+gated inputs: a row a slot), or latent rows (MLA, ``cfg.latent``:
 one normed latent row and one rotated rope key a token for all heads;
 attention runs in the absorbed form over them). Either kind's pools ride
 whole through the layer loop (``_dense_layers``, ``_latent_layers``): a
@@ -43,10 +47,12 @@ import functools
 import jax
 import jax.numpy as jnp
 
+import dataclasses
+
 from arkflow_tpu.models import common as cm
 from dataclasses import dataclass
 
-from arkflow_tpu.models.decoder import (FULL, SLIDING, DecoderConfig, _mlp,
+from arkflow_tpu.models.decoder import (CONV, FULL, SLIDING, DecoderConfig, _mlp,
                                         _scaled, index_project,
                                         index_scores, layer_runs, layer_stacks,
                                         lm_logits, mla_absorb_query,
@@ -54,7 +60,7 @@ from arkflow_tpu.models.decoder import (FULL, SLIDING, DecoderConfig, _mlp,
                                         mla_output, mla_project,
                                         mla_query_latent, moe_step_stats,
                                         qk_positioned, qkv_project,
-                                        routed_mlp, ssm_conv,
+                                        routed_mlp, short_conv, ssm_conv,
                                         ssm_operands, ssm_output, ssm_project)
 
 
@@ -70,7 +76,9 @@ class CachePool:
     first array (K) is held in ``key_parts`` parts of equal width, each a
     layer of the array's own (``GqaSpec.key_parts``: layer ``l``'s part
     ``p`` is the array's layer ``p * layers + l``, so part 0 is indexed as
-    V is)."""
+    V is). ``row_major``: the heads have NO axis of their own, a token's
+    are side by side on the last (``GqaSpec.row_major``: a head narrower
+    than 128 lanes)."""
     name: str
     layers: int
     widths: tuple
@@ -79,9 +87,12 @@ class CachePool:
     per_slot: bool = False
     heads: int = 0
     key_parts: int = 1
+    row_major: bool = False
 
     def shapes(self, pages: int, page_size: int) -> list:
         """The shapes of a per-head pool's K and V over ``pages`` pages."""
+        if self.row_major:
+            return [(self.layers, pages, page_size, w) for w in self.widths]
         return [(self.layers * parts, pages, page_size, self.heads,
                  width // self.heads // parts)
                 for width, parts in zip(self.widths, (self.key_parts, 1))]
@@ -134,13 +145,17 @@ def cache_spec(cfg: DecoderConfig) -> tuple:
       the window passes;
     - ``ssm``: a hybrid layer's recurrent state — the mixer's float32 state
       matrices and the conv's last ``d_conv - 1`` inputs —, one row a
-      SEQUENCE whatever its length, beside that layer's ``kv`` rows."""
+      SEQUENCE whatever its length, beside that layer's ``kv`` rows;
+    - ``conv``: a conv layer's last ``conv_L_cache - 1`` gated inputs, one
+      row a SEQUENCE too, over the conv layers only (which have no ``kv``
+      rows: ``kv`` is over the attention layers only)."""
     if not cfg.latent:
         full, swa = (cfg.kinds.count(k) for k in (FULL, SLIDING))
 
         def widths(sp):
             return dict(widths=(sp.kv_heads * sp.dk_held, sp.kv_heads * sp.dv),
-                        heads=sp.kv_heads, key_parts=sp.key_parts)
+                        heads=sp.kv_heads, key_parts=sp.key_parts,
+                        row_major=sp.row_major)
 
         pools = (CachePool("kv", full, **widths(cfg.gqa(FULL))),)
         if swa:
@@ -152,6 +167,10 @@ def cache_spec(cfg: DecoderConfig) -> tuple:
                 (cfg.mamba_d_ssm * cfg.mamba_d_state,
                  (cfg.mamba_d_conv - 1) * cfg.ssm_conv_dim),
                 itemsizes=(4, 2), per_slot=True),)
+        if cfg.conv:
+            pools += (CachePool(
+                "conv", cfg.kinds.count(CONV),
+                ((cfg.conv_L_cache - 1) * cfg.dim,), per_slot=True),)
         return pools
     full, swa = (cfg.kinds.count(k) for k in (FULL, SLIDING))
     pools = [CachePool("latent", full, (cfg.kv_lora_rank,
@@ -192,7 +211,13 @@ def init_page_pool(cfg: DecoderConfig, num_pages: int, page_size: int,
       float32 [layers, slots + 1, heads, d_state, d_head] (``ops/ssm_scan``
       says why in that order), the windows [layers, slots + 1, d_conv - 1,
       conv channels]: row 0 scratch, as page 0 is, row ``s + 1`` slot
-      ``s``'s."""
+      ``s``'s;
+    - a model with conv layers: ``{"kv": K, "conv": the windows}`` and
+      ``{"kv": V}`` — ``kv`` over the attention layers, the windows [conv
+      layers, slots + 1, conv_L_cache - 1, dim], rows as a hybrid model's.
+
+    A head narrower than 128 lanes has ROW-MAJOR K and V, [layers, pages,
+    page, kv_heads * width] (``CachePool.row_major``)."""
     if cfg.latent and cfg.layered:
         wide, rope = {}, {}
         for pool in cache_spec(cfg):
@@ -214,6 +239,10 @@ def init_page_pool(cfg: DecoderConfig, num_pages: int, page_size: int,
             for pool in spec} for i in range(2))
     k, v = (jnp.zeros(shape, jnp.bfloat16)
             for shape in spec[0].shapes(num_pages, page_size))
+    if cfg.conv:
+        return ({"kv": k, "conv": jnp.zeros(
+            (spec[-1].layers, slots + 1, cfg.conv_L_cache - 1, cfg.dim),
+            jnp.bfloat16)}, {"kv": v})
     if not cfg.hybrid:
         return k, v
     rows = (cfg.layers, slots + 1)
@@ -240,6 +269,14 @@ def window_ring_pages(cfg: DecoderConfig, page_size: int, step_tokens: int) -> i
     if SLIDING not in cfg.kinds:
         return 0
     return (cfg.sliding_window + max(step_tokens, 1) - 2) // page_size + 2
+
+
+def _page_size(pools) -> int:
+    """Tokens a page of the pools a step carries (``init_page_pool``: one
+    array, or a dict by pool name whose per-slot pools have no pages)."""
+    if isinstance(pools, dict) and "kv" in pools:
+        return pools["kv"].shape[2]
+    return jax.tree_util.tree_leaves(pools)[0].shape[2]
 
 
 def _write_coords(table, positions, valid, page: int, ring: bool = False):
@@ -687,9 +724,9 @@ def gqa_kernel_probe(params: dict, cfg: DecoderConfig, page_size: int,
         sp = cfg.gqa(kind)
         stack = params[next(r[0] for r in layer_runs(cfg) if r[3] == kind)]
         sink = stack["attn_sink"][0] if sp.sink else None
-        return (sp, rand((sp.key_parts, pages, page_size, sp.kv_heads,
-                          sp.dk_held // sp.key_parts)),
-                rand((1, pages, page_size, sp.kv_heads, sp.dv)), sink)
+        pool = next(p for p in cache_spec(cfg) if bool(p.window) == bool(sp.window))
+        kshape, vshape = dataclasses.replace(pool, layers=1).shapes(pages, page_size)
+        return sp, rand(kshape), rand(vshape), sink
 
     sp, kp, vp, sink = operands(FULL, 1 + 2 * pages_per)
     group = cfg.heads // sp.kv_heads
@@ -698,7 +735,7 @@ def gqa_kernel_probe(params: dict, cfg: DecoderConfig, page_size: int,
         positions = off[:, None] + jnp.arange(c)[None, :]
         mask = jnp.arange(ctx)[None, None, None, :] <= positions[:, None, :, None]
         k = jnp.repeat(_read_keys(kp, 0, table, sp.dk), group, axis=2)
-        v = jnp.repeat(vp[0, table].reshape(2, ctx, sp.kv_heads, -1), group, axis=2)
+        v = jnp.repeat(_read_rows(vp, 0, table, sp.kv_heads), group, axis=2)
         out.append((f"paged_attention_{name}",
                     cm.attention(q, k, v, mask, sink=sink),
                     _attend_paged(q, kp, vp, 0, table, off, cfg, None,
@@ -727,7 +764,7 @@ def _write_keys(kp, k, layer, pi, po, parts: int):
     (``GqaSpec.key_parts``) — padded with zeros to whole parts, part ``p``
     into the pool's layer ``p * layers + layer``."""
     if parts == 1:
-        return kp.at[layer, pi, po].set(k.astype(kp.dtype))
+        return _write_rows(kp, k, layer, pi, po)
     w, layers = kp.shape[-1], kp.shape[0] // parts
     k = jnp.pad(k, ((0, 0),) * 3 + ((0, parts * w - k.shape[-1]),)).astype(kp.dtype)
     for p in range(parts):
@@ -735,9 +772,27 @@ def _write_keys(kp, k, layer, pi, po, parts: int):
     return kp
 
 
+def _write_rows(pool, x, layer, pi, po):
+    """``pool`` with the step's K or V rows ``x`` [B, S, kv heads, width]
+    written at (``layer``, ``pi``, ``po``): a row-major pool takes a
+    token's heads side by side."""
+    if pool.ndim == 4:
+        x = x.reshape(*x.shape[:2], -1)
+    return pool.at[layer, pi, po].set(x.astype(pool.dtype))
+
+
+def _read_rows(pool, layer, table, heads: int):
+    """A layer's K or V rows [B, columns * page, kv heads, width] gathered
+    through ``table`` [B, columns], however the pool holds a token's heads."""
+    b, cols = table.shape
+    return pool[layer, table].reshape(b, cols * pool.shape[2], heads, -1)
+
+
 def _read_keys(kp, layer, table, dk: int):
     """A layer's keys [B, columns * page, kv heads, dk] gathered through
     ``table`` [B, columns], a key held in parts joined and cut to ``dk``."""
+    if kp.ndim == 4:
+        return _read_rows(kp, layer, table, kp.shape[-1] // dk)
     parts = -(-dk // kp.shape[-1])
     b, cols = table.shape
     k = jnp.concatenate(
@@ -806,17 +861,27 @@ def _attend_ring(q, k_pages, v_pages, layer, ring, positions, window: int,
     be another row's by now: the bound hides them. Keys held in parts are
     joined (``_read_keys``); ``sink`` [heads] joins the softmax."""
     b, cols = ring.shape
-    page, kvh = k_pages.shape[2:4]
+    page = k_pages.shape[2]
     last = positions[:, -1] // page                                # [B]
     logical = last[:, None] - (last[:, None] - jnp.arange(cols)[None, :]) % cols
     key_pos = (logical[:, :, None] * page + jnp.arange(page)).reshape(b, -1)
     k = _read_keys(k_pages, layer, ring, q.shape[-1]).astype(q.dtype)
-    v = v_pages[layer, ring].reshape(b, cols * page, kvh, -1).astype(q.dtype)
+    kvh = k.shape[2]
+    v = _read_rows(v_pages, layer, ring, kvh).astype(q.dtype)
     qp, kpos = positions[:, None, :, None], key_pos[:, None, None, :]
     mask = (kpos <= qp) & (kpos > qp - window) & (kpos >= 0)
     group = q.shape[2] // kvh
     return cm.attention(q, jnp.repeat(k, group, axis=2),
                         jnp.repeat(v, group, axis=2), mask, sink=sink)
+
+
+def _last_valid(ext, valid, keep: int):
+    """A window's next rows: of ``ext`` [B, keep + S, channels] — the
+    window before a step, then the step's inputs — the ``keep`` rows that
+    end at each row's last VALID input (``valid`` [B, S], a prefix): a
+    position that is not valid leaves the window as it is."""
+    return jax.vmap(lambda e, n: jax.lax.dynamic_slice_in_dim(e, n, keep))(
+        ext, valid.sum(axis=1).astype(jnp.int32))
 
 
 def _mixer_paged(lp: dict, y, cfg: DecoderConfig, states, windows, layer, rows,
@@ -839,9 +904,7 @@ def _mixer_paged(lp: dict, y, cfg: DecoderConfig, states, windows, layer, rows,
     if fresh is not None:
         before = jnp.where(fresh[:, None, None], 0, before)
     ext = jnp.concatenate([before, u.astype(before.dtype)], axis=1)
-    after = jax.vmap(lambda e, n: jax.lax.dynamic_slice_in_dim(e, n, keep))(
-        ext, valid.sum(axis=1).astype(jnp.int32))
-    windows = windows.at[layer, rows].set(after)
+    windows = windows.at[layer, rows].set(_last_valid(ext, valid, keep))
     x, step, a, bm, cmat = ssm_operands(lp, ssm_conv(lp, ext, t, cfg), dt, cfg,
                                         valid)
     kern = dict(kernel=kernel, interpret=interpret)
@@ -853,6 +916,27 @@ def _mixer_paged(lp: dict, y, cfg: DecoderConfig, states, windows, layer, rows,
         o, states = ssm_chunk_scan(states, layer, rows, fresh, x, step, a, bm,
                                    cmat, cfg.mamba_chunk_size, **kern)
     return ssm_output(lp, o, x, z, cfg, y.dtype), states, windows
+
+
+def _conv_paged(lp: dict, y, cfg: DecoderConfig, windows, layer, rows, fresh,
+                valid):
+    """A conv layer's mixer over the conv pool: ``windows`` whole
+    (``init_page_pool``: [conv layers, slots + 1, conv_L_cache - 1, dim]),
+    ``rows`` [B] each row's pool row (0: scratch — an idle lane), ``valid``
+    [B, S] the tokens that advance it (a prefix of each row), ``fresh``
+    None in a decode step, else [B] bool: a chunk that starts its sequence
+    reads zeros, not its slot's earlier tenant (``_mixer_paged``'s rules).
+    The row keeps the last ``conv_L_cache - 1`` VALID gated inputs: a
+    position that is not valid leaves it as it is. Plain XLA: the pool is
+    9 MB at LFM2's widths and the update a scatter of ``B`` rows in place.
+    Returns (the mixer's output [B, S, dim], windows)."""
+    keep = cfg.conv_L_cache - 1
+    before = windows[layer, rows]                                 # [B, L-1, dim]
+    if fresh is not None:
+        before = jnp.where(fresh[:, None, None], 0, before)
+    out, ext = short_conv(lp, y, cfg, before)
+    return out, windows.at[layer, rows].set(
+        _last_valid(ext, valid, keep).astype(windows.dtype))
 
 
 def _dense_layers(params: dict, cfg: DecoderConfig, x, k_pages, v_pages,
@@ -886,19 +970,47 @@ def _dense_layers(params: dict, cfg: DecoderConfig, x, k_pages, v_pages,
     its attention, from the same normed input, each layer runs its mixer
     over the state pool (``_mixer_paged``: ``ssm_rows`` [B], ``ssm_fresh``
     [B] or None in a decode step, ``token_mask`` the tokens that advance a
-    state) and the two outputs add into one residual.
+    state) and the two outputs add into one residual. A model with conv
+    layers carries ``{"kv", "conv"}`` and ``{"kv"}``: a ``conv`` run's layers
+    read and write the conv pool's rows under the same three operands
+    (``_conv_paged``) and no K/V, the attention runs' layers the ``kv``
+    pools alone, each pool indexed by the layer's place among its kind's.
     Returns (x, k_pages, v_pages) and, from a routed model, the step's
     counters (``moe_step_stats``)."""
     b, t = positions.shape
     kernel = attention_kernel == "paged"
     kept, ring = page_table if isinstance(page_table, tuple) else (page_table, None)
-    page = jax.tree_util.tree_leaves(k_pages)[0].shape[2]
+    page = _page_size(k_pages)
     ctx = kept.shape[1] * page
     where = {FULL: (page_idx, offset)}
     if ring is not None:
         where[SLIDING] = _write_coords(ring, positions, token_mask, page, ring=True)
 
     def make_layer(routed: bool, kind: str, experts):
+        def ffn(lp, x, kp, vp, ei):
+            y = cm.rms_norm(lp["mlp_norm"], x, cfg.norm_eps)
+            if not routed:
+                return (x + _mlp(lp, y, cfg, token_mask=token_mask), kp, vp), None
+            # the stack's experts stay OUT of the scanned tree and whole:
+            # the kernel indexes the layer itself (``_latent_layers``)
+            out, load = routed_mlp(lp, y, cfg, token_mask=token_mask,
+                                   kernel=kernel, interpret=kernel_interpret,
+                                   stacked=(experts, ei[0]))
+            return (x + out, kp, vp), load
+
+        if kind == CONV:
+            def conv_layer(carry, scanned):
+                """A conv layer: its mixer over the conv pool's rows
+                (``_conv_paged``), no K/V written or read."""
+                x, kp, vp = carry
+                lp, li, *ei = scanned
+                mixed, windows = _conv_paged(
+                    lp, cm.rms_norm(lp["attn_norm"], x, cfg.norm_eps), cfg,
+                    kp["conv"], li, ssm_rows, ssm_fresh, token_mask)
+                return ffn(lp, x + mixed, {**kp, "conv": windows}, vp, ei)
+
+            return conv_layer
+
         sp = cfg.gqa(kind)
         window, group = sp.window, cfg.heads // sp.kv_heads
         name = "kv_window" if window else "kv"
@@ -910,7 +1022,7 @@ def _dense_layers(params: dict, cfg: DecoderConfig, x, k_pages, v_pages,
             pools = kp, vp
             if cfg.hybrid:
                 (kp, states), (vp, windows) = ((p["kv"], p["ssm"]) for p in (kp, vp))
-            elif cfg.layered:
+            elif cfg.layered or cfg.conv:
                 kp, vp = kp[name], vp[name]
             y = cm.rms_norm(lp["attn_norm"], x, cfg.norm_eps)
             q, k, v = qkv_project(lp, y, cfg, kind)
@@ -918,8 +1030,7 @@ def _dense_layers(params: dict, cfg: DecoderConfig, x, k_pages, v_pages,
             sink = lp.get("attn_sink")
             kp = _constrain(_write_keys(kp, k, li, pi, po, sp.key_parts),
                             kv_sharding)
-            vp = _constrain(vp.at[li, pi, po].set(v.astype(vp.dtype)),
-                            kv_sharding)
+            vp = _constrain(_write_rows(vp, v, li, pi, po), kv_sharding)
             if kernel and not block:
                 attn = _attend_paged(q, kp, vp, li, ring if window else kept,
                                      off, cfg, kv_sharding, kernel_interpret,
@@ -929,8 +1040,7 @@ def _dense_layers(params: dict, cfg: DecoderConfig, x, k_pages, v_pages,
             else:
                 if not block:
                     k = _read_keys(kp, li, kept, sp.dk).astype(x.dtype)
-                    v = vp[li, kept].reshape(
-                        b, ctx, sp.kv_heads, sp.dv).astype(x.dtype)
+                    v = _read_rows(vp, li, kept, sp.kv_heads).astype(x.dtype)
                 attn = cm.attention(q, jnp.repeat(k, group, axis=2),
                                     jnp.repeat(v, group, axis=2), mask, sink=sink)
             out = _scaled(cm.dense(lp["wo"], attn.reshape(b, t, cfg.heads * sp.dv)),
@@ -941,18 +1051,9 @@ def _dense_layers(params: dict, cfg: DecoderConfig, x, k_pages, v_pages,
                     token_mask, kernel, kernel_interpret)
                 out = out + mixed
                 kp, vp = {"kv": kp, "ssm": states}, {"kv": vp, "ssm": windows}
-            elif cfg.layered:
+            elif cfg.layered or cfg.conv:
                 kp, vp = {**pools[0], name: kp}, {**pools[1], name: vp}
-            x = x + out
-            y = cm.rms_norm(lp["mlp_norm"], x, cfg.norm_eps)
-            if not routed:
-                return (x + _mlp(lp, y, cfg, token_mask=token_mask), kp, vp), None
-            # the stack's experts stay OUT of the scanned tree and whole:
-            # the kernel indexes the layer itself (``_latent_layers``)
-            out, load = routed_mlp(lp, y, cfg, token_mask=token_mask,
-                                   kernel=kernel, interpret=kernel_interpret,
-                                   stacked=(experts, ei[0]))
-            return (x + out, kp, vp), load
+            return ffn(lp, x + out, kp, vp, ei)
         return layer
 
     carry = (x, k_pages, v_pages)
@@ -995,12 +1096,13 @@ def _scan_run(layer, carry, stack: dict, first: int, stop: int,
 
 
 def _ssm_operands(cfg: DecoderConfig, rows, fresh) -> dict:
-    """``_dense_layers``' state operands: none for a model without a mixer."""
-    if not cfg.hybrid:
+    """``_dense_layers``' state operands: none for a model that caches no
+    state a sequence (``cfg.stateful``: a mixer, conv layers)."""
+    if not cfg.stateful:
         return {}
     if rows is None:
-        raise ValueError("a hybrid model's chunk names its rows of the "
-                         "state pool (ssm_rows)")
+        raise ValueError("the chunk of a model that caches a state a "
+                         "sequence names its rows of the state pool (ssm_rows)")
     return dict(ssm_rows=jnp.asarray(rows, jnp.int32), ssm_fresh=fresh)
 
 
@@ -1022,13 +1124,14 @@ def paged_prefill(params: dict, cfg: DecoderConfig, input_ids, lengths,
     paths read later; ``attention_kernel`` picks only its expert product.
     A routed model's step returns its routing counters as a fourth value.
     """
-    if cfg.hybrid:
+    if cfg.stateful:
         from arkflow_tpu.errors import ConfigError
 
         raise ConfigError(
-            "a model that carries a recurrent state prefills in chunks "
-            "through the cache (prefill_chunk > 0): the chunk's program is "
-            "the one that is told its slot's row of the state pool")
+            "a model that caches a state a sequence (pools "
+            f"{', '.join(pool.name for pool in cache_spec(cfg))}) prefills "
+            "in chunks through the cache (prefill_chunk > 0): the chunk's "
+            "program is the one that is told its slot's row of the state pool")
     if cfg.layered:
         from arkflow_tpu.errors import ConfigError
 
@@ -1125,7 +1228,7 @@ def paged_prefill_chunk(params: dict, cfg: DecoderConfig, input_ids, chunk_off,
     tables, page_table = page_table, (
         page_table[0] if isinstance(page_table, tuple) else page_table)
     p_slots = page_table.shape[1]
-    page = jax.tree_util.tree_leaves(k_pages)[0].shape[2]
+    page = _page_size(k_pages)
     ctx = p_slots * page
 
     positions = chunk_off[:, None] + jnp.arange(t)[None, :]       # [B, C]
@@ -1194,7 +1297,7 @@ def paged_decode_step(params: dict, cfg: DecoderConfig, token_ids, lengths,
     tables, page_table = page_table, (
         page_table[0] if isinstance(page_table, tuple) else page_table)
     p_slots = page_table.shape[1]
-    page = jax.tree_util.tree_leaves(k_pages)[0].shape[2]
+    page = _page_size(k_pages)
     ctx = p_slots * page
 
     positions = lengths[:, None]                                  # [S, 1]

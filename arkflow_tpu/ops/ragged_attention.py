@@ -143,14 +143,17 @@ def ragged_flash_attention(q, k, v, lengths, *, causal: bool = False,
 # column cost 0.14-0.24 us a column a lane on a v5e, 110 ns where the math
 # was skipped, with a third of the columns live: PERF.md, PR 41.)
 #
-# One case keeps that grid, ``_paged_grid_kernel`` below: a head that is no
-# multiple of 128 lanes, compiled for a chip. Mosaic sees such a pool padded
-# to whole 128-lane rows and takes a copy out of it only in whole rows
-# ("Slice shape along dimension 2 must be aligned to tiling (128)": of a
-# VMEM slot padded to 128 lanes and of ``pltpu.emit_pipeline``'s copies
-# too); only a BlockSpec of the grid may read it. Serving such a model with
-# gather instead costs 1.9-2.9 x a decode step (PERF.md, PR 41); the pool's
-# shape is what stands in the walk's way there (ROADMAP S11 (v)).
+# A head NARROWER than 128 lanes has pools of another shape, and a kernel of
+# its own below (``_narrow_kernel``). Mosaic sees a [.., kv_heads, dh < 128]
+# pool padded to whole 128-lane rows and takes a copy out of it only in
+# whole rows ("Slice shape along dimension 2 must be aligned to tiling
+# (128)": of a VMEM slot padded to 128 lanes and of ``pltpu.emit_pipeline``'s
+# copies too), and XLA keeps such a pool with its pages minor and re-lays it
+# around every step. So such a model's pools are ROW-MAJOR, [layers, pages,
+# page, kv_heads * dh] (``models/paged_decode.cache_spec``): a token's heads
+# side by side, whole 128-lane runs (8 heads of 64: four), which a copy
+# takes as they are. (Until PR 46 a grid over every column of the table, a
+# page a step through a BlockSpec, read them: 3-6 x a call, PERF.md PR 41.)
 #
 # Layout (what the TPU tiling accepts): a copy takes ALL kv heads of a
 # page. The pool rides whole and is viewed as [layers*num_pages,
@@ -233,11 +236,28 @@ def query_tile(c: int, heads: int) -> int:
     return c if c * heads <= _PAGED_ROWS else max(8, _PAGED_ROWS // heads // 8 * 8)
 
 
-def kernel_walks(dh: int, dv: int, interpret: bool) -> bool:
-    """Who walks the page table: the kernel (``_paged_kernel``), but for a
-    head of no multiple of 128 lanes as held, compiled for a chip, whose
-    pages only the grid can read (``_paged_grid_kernel``)."""
-    return interpret or (dh % 128 == 0 and dv % 128 == 0)
+def kernel_walks(dh: int, dv: int, interpret: bool, kvh: int = 0) -> bool:
+    """Whether the kernel's own copies can take the pools' pages where the
+    call is compiled for a chip (interpreted, any width goes): a copy takes
+    whole 128-lane rows, so a head of a multiple of 128 lanes as held, its
+    pools ``[.., kv_heads, width]`` — or, ``kvh`` > 0, a narrower head whose
+    pools are row-major ``[.., kv_heads * width]``: keys and values of one
+    width, a token's heads whole runs (``narrow_run``)."""
+    if interpret:
+        return True
+    if kvh:
+        return dh == dv and kvh * dh % math.lcm(dh, 128) == 0
+    return dh % 128 == 0 and dv % 128 == 0
+
+
+def narrow_run(kvh: int, dh: int) -> int:
+    """Lanes of a row-major pool's token (``kvh`` heads of ``dh`` side by
+    side) the narrow-head walk multiplies at a time: the fewest whole
+    128-lane rows that hold whole heads (128 for heads of 32 or 64, 384 for
+    four heads of 96) where a token is whole such runs, else (interpreted
+    only) the whole token."""
+    run = math.lcm(dh, 128)
+    return run if kvh * dh % run == 0 else kvh * dh
 
 
 def per_kv_head(tile_c: int, heads: int, kvh: int) -> bool:
@@ -247,7 +267,9 @@ def per_kv_head(tile_c: int, heads: int, kvh: int) -> bool:
     rows of the tile, ``tile_c`` positions x its query heads, are whole
     float32 sublane tiles. The ONE predicate: the kernel's walk cuts its
     tile by it and the server counts its programs by it (``tpu/serving.py``:
-    ``arkflow_gen_attn_tiles_total``); the grid's walk is all heads at once."""
+    ``arkflow_gen_attn_tiles_total``). (A row-major pool's walk,
+    ``_narrow_kernel``, multiplies a 128-lane run of heads at a time over
+    those heads' own query rows whatever the tile: counted per head.)"""
     return tile_c > 1 and tile_c * (heads // kvh) % 8 == 0
 
 
@@ -495,66 +517,6 @@ def _paged_kernel(off_ref, table_ref, q_ref, *rest, page: int,
     o_ref[0] = (o_acc[:] / jnp.maximum(l_acc[:, :1], 1e-30)).astype(o_ref.dtype)
 
 
-def _paged_grid_kernel(off_ref, table_ref, q_ref, k_ref, v_ref, o_ref,
-                       o_acc, m_acc, l_acc, *, page: int, kvh: int, heads: int,
-                       tile_c: int, pages_per: int, window: int = 0):
-    """The walk by the GRID, (row, query tile, page), a page a step through a
-    BlockSpec, as every head size was served before PR 41: kept for heads of
-    no multiple of 128 lanes, which ``_paged_kernel``'s own copies cannot
-    take on a chip. A step past the tile's causal bound skips the math."""
-    bi = pl.program_id(0)
-    ci = pl.program_id(1)
-    pi = step = pl.program_id(2)
-    rows, d = q_ref.shape[1], q_ref.shape[2]
-    cols = page * kvh
-    group = heads // kvh
-    if window:  # the walk starts at the window's first page, not at 0
-        pi = step + _window_start(off_ref[bi] + ci * tile_c, window, page)
-
-    @pl.when(step == 0)
-    def _init():
-        o_acc[:] = jnp.zeros_like(o_acc)
-        m_acc[:] = jnp.full_like(m_acc, _NEG)
-        l_acc[:] = jnp.zeros_like(l_acc)
-
-    first = off_ref[bi] + ci * tile_c
-    max_pos = first + (tile_c - 1)
-
-    @pl.when(pi * page <= max_pos)
-    def _acc():
-        q = q_ref[0].astype(jnp.float32)                          # [rows, D]
-        k = k_ref[0].astype(jnp.float32)                          # [cols, D]
-        v = v_ref[0].astype(jnp.float32)
-        scale = 1.0 / math.sqrt(d)
-        scores = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale           # [rows, cols]
-        r = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 0)
-        c = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 1)
-        q_pos = first + r // heads
-        k_pos = pi * page + c // kvh
-        own_head = (r % heads) // group == c % kvh
-        keep = jnp.logical_and(own_head, k_pos <= q_pos)
-        if window:
-            keep = jnp.logical_and(keep, k_pos > q_pos - window)
-        scores = jnp.where(keep, scores, _NEG)
-        m = m_acc[:, :1]                                          # [rows, 1]
-        m_new = jnp.maximum(m, scores.max(axis=-1, keepdims=True))
-        p = jnp.exp(scores - m_new)
-        corr = jnp.exp(m - m_new)
-        l_acc[:] = jnp.broadcast_to(
-            l_acc[:, :1] * corr + p.sum(axis=-1, keepdims=True), l_acc.shape)
-        m_acc[:] = jnp.broadcast_to(m_new, m_acc.shape)
-        o_acc[:] = o_acc[:] * corr + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-
-    @pl.when(step == pages_per - 1)
-    def _fin():  # l is never truly zero, as in ``_paged_kernel``
-        o_ref[0] = (o_acc[:] / jnp.maximum(l_acc[:, :1], 1e-30)
-                    ).astype(o_ref.dtype)
-
-
 def _walk_call(b, tiles, rows, dh, dtype, *, page, kvh, heads, tile_c, ring,
                window, dv=0, scale=0.0, sink=False, parts=1, part_stride=0):
     """``_paged_kernel``'s kernel, grid and compiler parameters (``dh`` the
@@ -607,48 +569,193 @@ def _walk_call(b, tiles, rows, dh, dtype, *, page, kvh, heads, tile_c, ring,
         vmem_limit_bytes=2 * _walk_budget() + (8 << 20))}
 
 
-def _grid_call(b, tiles, rows, dh, dtype, *, page, kvh, heads, tile_c, ring,
-               window):
-    """``_paged_grid_kernel``'s kernel and grid: every column of the table
-    a step (under a window: the pages from the first query's oldest key to
-    the tile's last query)."""
+#: pages a step of the narrow-head walk takes where the budget allows more:
+#: a page is ``page`` columns of its tiles, as the latent kernel's. On a v5e,
+#: us a call a layer at 16 / 32 / 64 pages, 32 / 8 heads of 64 (PERF.md, PR
+#: 46): a decode step of 128 lanes at contexts 512 / 2,048 / 4,863 635, 582,
+#: 507 / 1,904, 1,513, 1,368 / 4,116, 3,254, 2,832; a 256-token chunk at
+#: offsets 512 / 2,048 / 4,608 194, 185, 179 / 308, 275, 287 / 506, 429, 411
+_NARROW_GROUP_MAX = 64
+
+
+def _narrow_group(mine: int, runs: int, page: int, lanes: int, itemsize: int,
+                  window: int, tile_c: int) -> int:
+    """Pages a program of the narrow-head walk takes a step: a page costs
+    VMEM as its K and V rows (``lanes`` wide, two slots each) and as
+    ``page`` columns of every [rows, columns] float32 tile the softmax holds
+    (five, a run's rows at a time but all runs' sums alive). Whole 128-lane
+    score tiles where a group has that many keys; a tile's whole walk under
+    a window."""
+    fit = max(1, _walk_budget() // (
+        page * (lanes * 4 * itemsize + mine * runs * 20)))
+    group = min(fit, _NARROW_GROUP_MAX)
+    if window:
+        group = min(group, (window + tile_c - 2) // page + 2)
+    per_tile = max(1, 128 // page)
+    return group if group < per_tile else group // per_tile * per_tile
+
+
+def _narrow_kernel(layer_ref, off_ref, table_ref, q_ref, k_hbm, v_hbm, o_ref,
+                   k_buf, v_buf, sems, *, page: int, tile_c: int, hpr: int,
+                   mine: int, group: int, ring: int, window: int, scale: float):
+    """The walk over ROW-MAJOR pools [layers, pages, page, kv_heads * dh]
+    (a head narrower than 128 lanes): a page is copied as it is held, every
+    head of its tokens side by side, and the products are made a 128-lane
+    RUN of heads at a time. Folded row r of a program is (run r // mine,
+    position (r % mine) // hpr of the tile, one of the run's ``hpr`` query
+    heads), its query ZERO-EXTENDED over the run — the head's ``dh`` values
+    at its K/V head's lanes, zeros at the run's other heads' — so
+    ``q . k_run`` over all 128 lanes is the score against its own head's
+    key and a column is a KEY, nothing to mask across heads; ``p . v_run``
+    gives every head's values under the row's probabilities, and the
+    wrapper keeps its own head's lanes. (Chosen over lane slices of a head:
+    a 64-lane slice of a VMEM row is a relayout a step, and the product is
+    bound by loading the keys into the MXU either way: its depth, 128 for
+    64, is not what it waits for.) Operands go to the MXU in the pools'
+    type, sums in float32; the probabilities are rounded to the values'
+    type, as the latent kernel's."""
     from jax.experimental.pallas import tpu as pltpu
 
-    pages_per = (window + tile_c - 2) // page + 2 if window else ring
+    bi = pl.program_id(0)
+    ci = pl.program_id(1)
+    run = q_ref.shape[-1]
+    runs = q_ref.shape[0] // mine
+    width = group * page
+    first = off_ref[bi] + ci * tile_c
+    hi = (first + (tile_c - 1)) // page + 1
+    if window:
+        lo = _window_start(first, window, page)
+    else:
+        lo, hi = 0, jnp.minimum(hi, ring)
+    layer = layer_ref[0]
 
-    def _page_index(bi, ci, pi, off_ref, table_ref):
-        # pages past the tile's causal bound resolve to the scratch page 0
-        # (of layer 0: never read either): the index stays constant across
-        # the remaining grid steps, so the pipeline skips the re-copy
-        max_pos = off_ref[bi] + ci * tile_c + (tile_c - 1)
+    def copies(slot, j, src):
+        dst = pl.ds(pl.multiple_of(j * page, page), page)
+        return (pltpu.make_async_copy(k_hbm.at[layer, src], k_buf.at[slot, dst],
+                                      sems.at[0, slot]),
+                pltpu.make_async_copy(v_hbm.at[layer, src], v_buf.at[slot, dst],
+                                      sems.at[1, slot]))
+
+    steps, start, arrive = _page_walk(
+        copies, lambda i: table_ref[bi, i % ring if window else i], lo, hi, group)
+
+    @pl.when(jnp.logical_and(bi == 0, ci == 0))
+    def _finite():
+        # a group's dead pages keep what the slot held (``_paged_kernel``):
+        # masked, but a probability of 0 times a NaN is a NaN
+        v_buf[...] = jnp.zeros_like(v_buf)
+
+    start(0, 0)
+    # rows past the tile's (``mine`` is rounded up to whole sublane tiles)
+    # take its last position: finite, dropped by the wrapper
+    r = jax.lax.broadcasted_iota(jnp.int32, (mine, width), 0)
+    c = jax.lax.broadcasted_iota(jnp.int32, (mine, width), 1)
+    ahead = first + jnp.minimum(r // hpr, tile_c - 1) - c
+    dims = (((1,), (1,)), ((), ()))
+
+    def body(g, acc):
+        slot = arrive(g)
+        base = (lo + g * group) * page
+        keep = base <= ahead
         if window:
-            pi = pi + _window_start(off_ref[bi] + ci * tile_c, window, page)
-        live = pi * page <= max_pos
-        return (jnp.where(live, table_ref[bi, pi % ring if window else pi], 0),
-                0, 0)
+            keep = jnp.logical_and(keep, base > ahead - window)
+        out = []
+        for j, (o, m, l) in enumerate(acc):
+            lanes = slice(j * run, (j + 1) * run)
+            k, v = k_buf[slot, :, lanes], v_buf[slot, :, lanes]   # [width, run]
+            scores = jax.lax.dot_general(
+                q_ref[j * mine:(j + 1) * mine, :], k, dims,
+                preferred_element_type=jnp.float32) * scale       # [mine, width]
+            scores = jnp.where(keep, scores, _NEG)
+            m_new = jnp.maximum(m, scores.max(axis=-1, keepdims=True))
+            p = jnp.exp(scores - m_new)
+            corr = jnp.exp(m - m_new)
+            out.append((o * corr + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32),
+                m_new, l * corr + p.sum(axis=-1, keepdims=True)))
+        return tuple(out)
 
-    def _q_index(bi, ci, pi, *_):
+    acc = jax.lax.fori_loop(0, steps, body, tuple(
+        (jnp.zeros((mine, run), jnp.float32),
+         jnp.full((mine, 1), _NEG, jnp.float32),
+         jnp.zeros((mine, 1), jnp.float32)) for _ in range(runs)))
+    for j, (o, _, l) in enumerate(acc):  # l is never truly zero: ``_paged_kernel``
+        o_ref[j * mine:(j + 1) * mine, :] = (
+            o / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+
+
+def _narrow_attention(q, k_pages, v_pages, layer, page_table, off, *,
+                      interpret: bool, window: int):
+    """``paged_flash_attention`` over row-major pools [layers, pages, page,
+    kv_heads * dh] (``_narrow_kernel``): folds the queries (run of heads,
+    position, query head of the run), zero-extends each over its run, and
+    keeps each row's own head's lanes of what comes back."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, c, h, dh = q.shape
+    page, lanes = k_pages.shape[2:]
+    kvh = lanes // dh
+    if lanes % dh or h % kvh or v_pages.shape != k_pages.shape:
+        raise ValueError(
+            f"row-major pools hold kv heads x {dh} lanes a token, keys and "
+            f"values alike, the {h} query heads a multiple of the kv heads; "
+            f"got {k_pages.shape} and {v_pages.shape}")
+    if not kernel_walks(dh, dh, interpret, kvh):
+        raise ValueError(
+            "a head narrower than 128 lanes is walked on a chip where a "
+            "token's heads are whole runs of lcm(width, 128) lanes; "
+            f"got {kvh} kv heads of {dh}")
+    run = narrow_run(kvh, dh)
+    per_run, runs, gq = run // dh, lanes // run, h // kvh
+    hpr = per_run * gq
+    tile_c = query_tile(c, h)
+    tiles = -(-c // tile_c)
+    sub = 32 // q.dtype.itemsize          # rows of a packed sublane tile
+    mine = -(-tile_c * hpr // sub) * sub
+    group = _narrow_group(mine, runs, page, lanes, k_pages.dtype.itemsize,
+                          window, tile_c)
+    eye = jnp.eye(per_run, dtype=q.dtype)
+    q = jnp.pad(q, ((0, 0), (0, tiles * tile_c - c), (0, 0), (0, 0)))
+    q = jnp.einsum("btcrjgd,jk->btrcjgkd",
+                   q.reshape(b, tiles, tile_c, runs, per_run, gq, dh), eye)
+    q = jnp.pad(q.reshape(b, tiles, runs, tile_c * hpr, run),
+                ((0, 0),) * 3 + ((0, mine - tile_c * hpr), (0, 0)))
+    rows = runs * mine
+
+    def _q_index(bi, ci, *_):
         return (bi, ci, 0)
 
     kernel = functools.partial(
-        _paged_grid_kernel, page=page, kvh=kvh, heads=heads, tile_c=tile_c,
-        pages_per=pages_per, window=window)
+        _narrow_kernel, page=page, tile_c=tile_c, hpr=hpr, mine=mine,
+        group=group, ring=page_table.shape[1], window=window,
+        scale=1.0 / math.sqrt(dh))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, tiles, pages_per),
-        in_specs=[
-            pl.BlockSpec((1, rows, dh), _q_index),
-            pl.BlockSpec((1, page * kvh, dh), _page_index),
-            pl.BlockSpec((1, page * kvh, dh), _page_index),
-        ],
-        out_specs=pl.BlockSpec((1, rows, dh), _q_index),
-        scratch_shapes=[
-            pltpu.VMEM((rows, dh), jnp.float32),
-            pltpu.VMEM((rows, 128), jnp.float32),
-            pltpu.VMEM((rows, 128), jnp.float32),
-        ],
-    )
-    return kernel, grid_spec, {}
+        num_scalar_prefetch=3,
+        grid=(b, tiles),
+        in_specs=[pl.BlockSpec((None, rows, run), _q_index),
+                  pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((None, rows, run), _q_index),
+        scratch_shapes=[pltpu.VMEM((2, group * page, lanes), k_pages.dtype),
+                        pltpu.VMEM((2, group * page, lanes), v_pages.dtype),
+                        pltpu.SemaphoreType.DMA((2, 2))])
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, tiles * rows, run), q.dtype),
+        interpret=interpret,
+        name=PAGED_WINDOW_NAME if window else "paged_flash_attention",
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=2 * _walk_budget() + (8 << 20)),
+    )(jnp.asarray(layer, jnp.int32).reshape(1), jnp.asarray(off, jnp.int32),
+      jnp.asarray(page_table, jnp.int32), q.reshape(b, tiles * rows, run),
+      k_pages, v_pages)
+    out = out.reshape(b, tiles, runs, mine, run)[:, :, :, :tile_c * hpr]
+    out = jnp.einsum("btrcjgkd,jk->btcrjgd", out.reshape(
+        b, tiles, runs, tile_c, per_run, gq, per_run, dh), eye)
+    return out.reshape(b, tiles * tile_c, h, dh)[:, :c]
 
 
 #: the windowed call's name in a device trace (the full call keeps the
@@ -689,10 +796,16 @@ def paged_flash_attention(q, k_pages, v_pages, layer, page_table, off, *,
     that joins every softmax and adds no value (the online softmax starts
     at it: maximum ``sink``, denominator 1, sum 0).
 
-    A head of no multiple of 128 lanes as held, compiled for a chip, is
-    walked by the grid instead (``_paged_grid_kernel``): the same keys, a
-    page a step, for keys and values of one width and no sink.
+    A head narrower than 128 lanes has ROW-MAJOR pools, [layers, num_pages,
+    page, kv_heads * dh] (keys and values of one width, no sink), and the
+    walk of ``_narrow_kernel``.
     """
+    if k_pages.ndim == 4:
+        if sink is not None:
+            raise ValueError("a sink is served at heads of 128 lanes' "
+                             "multiples: row-major pools have none")
+        return _narrow_attention(q, k_pages, v_pages, layer, page_table, off,
+                                 interpret=interpret, window=window)
     b, c, h, dq = q.shape
     layers, n_pages, page, kvh, dh = k_pages.shape
     dv = v_pages.shape[-1]
@@ -712,14 +825,12 @@ def paged_flash_attention(q, k_pages, v_pages, layer, page_table, off, *,
         q = jnp.pad(q, ((0, 0), (0, c_pad - c), (0, 0), (0, dh - dq)))
     rows = tile_c * h
     plain = dv == dh == dq and sink is None
-    walk = kernel_walks(dh, dv, interpret)
-    if not (walk or plain):
+    if not kernel_walks(dh, dv, interpret):
         raise ValueError(
-            "a sink, and keys and values of different widths, are served "
-            "by the kernel's own walk: on a chip, keys (as held) and values "
-            f"of multiples of 128 lanes; got keys {dh}, values {dv}")
-    call = _walk_call if walk else _grid_call
-    kernel, grid_spec, params = call(
+            "pools [.., kv heads, width] are walked on a chip at keys (as "
+            "held) and values of multiples of 128 lanes (a narrower head's "
+            f"are row-major: cache_spec); got keys {dh}, values {dv}")
+    kernel, grid_spec, params = _walk_call(
         b, c_pad // tile_c, rows, dh, k_pages.dtype, page=page, kvh=kvh,
         heads=h, tile_c=tile_c, ring=table.shape[1], window=window,
         **({} if plain else dict(dv=dv, scale=dq ** -0.5, sink=sink is not None,

@@ -91,16 +91,20 @@ def replicated(mesh: Mesh) -> NamedSharding:
     return NamedSharding(mesh, P())
 
 
-def kv_pool_sharding(mesh: Mesh) -> NamedSharding:
+def kv_pool_sharding(mesh: Mesh, row_major: bool = False) -> NamedSharding:
     """Sharding of the paged KV pools under tensor-parallel serving.
 
     A pool is ``[layers, num_pages, page, kv_heads, dh]``: the jitted steps'
     in/out sharding, and the constraint on the pools the layer scan carries
     (so GSPMD keeps them partitioned instead of all-gathering hundreds of MB
     per step). KV heads split over ``tp``; the page dims stay replicated, so
-    page-table gathers/scatters remain static-shaped and local."""
+    page-table gathers/scatters remain static-shaped and local. A
+    ``row_major`` pool (a head narrower than 128 lanes) is ``[layers,
+    num_pages, page, kv_heads * dh]``: its last axis splits into the same
+    contiguous groups of heads."""
     if tp_size(mesh) > 1:
-        return NamedSharding(mesh, P(None, None, None, "tp", None))
+        return NamedSharding(mesh, P(None, None, None, "tp") if row_major
+                             else P(None, None, None, "tp", None))
     return replicated(mesh)
 
 

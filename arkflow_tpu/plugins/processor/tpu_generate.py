@@ -36,7 +36,9 @@ Config:
                              # on one chip only; so does the hybrid block
                              # (mamba_*: a Mamba-2 mixer beside attention,
                              # a recurrent state a slot; needs
-                             # prefill_chunk > 0)
+                             # prefill_chunk > 0) and conv layers
+                             # (layer_types: conv, conv_L_cache: a window
+                             # of gated inputs a slot)
     text_field: __value__
     tokenizer: meta-llama/Llama-3-8B     # optional (hash fallback otherwise)
     max_input: 256
@@ -170,6 +172,24 @@ class TpuGenerateProcessor(Processor):
                     "continuous serving shards the KV pool over KV heads, "
                     "and a latent (MLA) page has one shared row per token "
                     "(remove mesh)")
+        if getattr(self.cfg, "stateful", False):
+            # before the host init too: the hybrid block's state, conv
+            # layers' windows (a pool a slot beside the K/V pages)
+            from arkflow_tpu.models.paged_decode import cache_spec
+
+            pools = ", ".join(pool.name for pool in cache_spec(self.cfg))
+            if serving != "continuous":
+                raise ConfigError(
+                    "a model with the hybrid block (mamba_d_ssm > 0) or "
+                    f"conv layers (pools {pools}) generates through "
+                    "serving: continuous only: the batch path's contiguous "
+                    "cache carries no recurrent state")
+            if mesh_config:
+                raise ConfigError(
+                    "a model with the hybrid block or conv layers (pools "
+                    f"{pools}) is served on one chip: the state pool and "
+                    "the mixer's channels have no sharding over a mesh yet "
+                    "(remove mesh)")
         if getattr(self.cfg, "by_runs", False) and not self.cfg.latent:
             # a per-head K/V model with routed experts, a layer pattern
             # (layer_types / sliding_window: window pages beside kept pages)
@@ -189,18 +209,6 @@ class TpuGenerateProcessor(Processor):
                     "pattern or head sizes by kind is served on one chip: "
                     "its expert stack, its window pool and its stacks by "
                     "kind have no sharding over a mesh yet (remove mesh)")
-        if getattr(self.cfg, "hybrid", False):
-            # before the host init too
-            if serving != "continuous":
-                raise ConfigError(
-                    "a model with the hybrid block (mamba_d_ssm > 0) "
-                    "generates through serving: continuous only: the batch "
-                    "path's contiguous cache carries no recurrent state")
-            if mesh_config:
-                raise ConfigError(
-                    "a model with the hybrid block is served on one chip: "
-                    "the state pool and the mixer's heads have no sharding "
-                    "over a mesh yet (remove mesh)")
         self.text_field = text_field
         self.tokenizer = tokenizer
         self.max_input = max_input
@@ -298,7 +306,7 @@ class TpuGenerateProcessor(Processor):
             # (nor has a window pool's ring of live pages, nor K and V of
             # different widths)
             if not (getattr(self.cfg, "latent", False)
-                    or getattr(self.cfg, "hybrid", False)
+                    or getattr(self.cfg, "stateful", False)
                     or getattr(self.cfg, "layered", False)
                     or getattr(self.cfg, "hetero", False)):
                 self.disagg = self

@@ -288,8 +288,9 @@ class GenerationServer:
             if has]
         if self._layered:
             self._refuse_layered(prefix_cache_pages, speculative_tokens)
-        #: a recurrent state a slot beside the K/V pages (the hybrid block)
-        self._stateful = bool(cfg.hybrid)
+        #: a state a slot beside the K/V pages (``cache_spec``'s per-slot
+        #: pool: the hybrid block's ``ssm``, conv layers' ``conv``)
+        self._stateful = bool(cfg.stateful)
         if self._stateful:
             self._refuse_stateful(prefix_cache_pages, speculative_tokens)
         self._win_cols = window_ring_pages(cfg, page_size, self.prefill_chunk)
@@ -310,7 +311,8 @@ class GenerationServer:
                     "serving: batch or tpu_inference for dp)")
             validate_tp_heads(tp_size(mesh), cfg.kv_heads,
                               who="continuous serving")
-            self._kv_io_sharding = kv_pool_sharding(mesh)
+            self._kv_io_sharding = kv_pool_sharding(
+                mesh, cache_spec(cfg)[0].row_major)
             self._repl_sharding = replicated(mesh)
         self.k_pages, self.v_pages = self._init_pools()
 
@@ -615,7 +617,10 @@ class GenerationServer:
         # layer, by the product each makes: a K/V head at a time over that
         # head's own query rows, or all heads at once under a mask — the
         # kernel's own predicate on the step's shapes as one chip sees them
-        # (a latent row has one shared head: nothing to cut, none counted)
+        # (a latent row has one shared head: nothing to cut, none counted) —
+        # or, over a row-major pool, a 128-lane RUN of narrow heads at a time,
+        # each head's queries zero-extended over its run (``head_run``: half
+        # or more of such a contraction is zeros, so it is no per-head tile)
         self._tiles_of: dict[int, dict[str, int]] = {}
         self.m_attn_tiles = {
             (kind, product): reg.counter(
@@ -624,7 +629,9 @@ class GenerationServer:
                 "over layers, by the product a tile makes",
                 {"model": name, "kind": kind, "product": product})
             for kind in ({} if cfg.latent else self.m_attn_walk)
-            for product in ("per_kv_head", "all_heads")}
+            for product in ("per_kv_head", "all_heads") + (
+                ("head_run",) if any(cfg.gqa(k).row_major for k in cfg.attn_kinds)
+                else ())}
         # a sink joins the softmax of every query of its kind's layers: the
         # rows (queries x layers of a kind with a sink) that went through
         # one, from lengths on the host (padding and idle lanes not counted)
@@ -689,29 +696,33 @@ class GenerationServer:
         with yet, and why: a state is overwritten by every token, so what
         is benign for K/V rows (a stale row, an aliased page) is not for it.
         A lane that rides one step too long is the third such thing: such
-        a model serves in lockstep (``_ahead``)."""
+        a model serves in lockstep (``_ahead``). The hybrid block's state
+        and conv layers' windows alike (the messages name the pools)."""
+        pools = self._pool_names()
         if self.mesh is not None:
             raise ConfigError(
-                "a model with the hybrid block (mamba_d_ssm > 0) is served "
-                "on one chip: the state pool and the mixer's heads have no "
-                "sharding over a mesh yet (remove mesh)")
+                "a model with the hybrid block (mamba_d_ssm > 0) or conv "
+                f"layers (pools {pools}) is served on one chip: the state "
+                "pool and the mixer's channels have no sharding over a mesh "
+                "yet (remove mesh)")
         if self.prefill_chunk <= 0:
             raise ConfigError(
-                "a model that carries a recurrent state prefills in chunks "
-                "through the cache: set prefill_chunk > 0 (the chunk's "
-                "program is the one that is told its slot and resets it)")
+                "a model that carries a recurrent state (pools "
+                f"{pools}) prefills in chunks through the cache: set "
+                "prefill_chunk > 0 (the chunk's program is the one that is "
+                "told its slot and resets it)")
         if prefix_cache_pages:
             raise ConfigError(
                 "prefix_cache_pages does not compose with a recurrent "
-                "state: aliased pages skip the very tokens whose state the "
-                "rest of the prompt needs, and no state snapshot is kept "
-                "beside a cached prefix yet")
+                f"state (pools {pools}): aliased pages skip the very tokens "
+                "whose state the rest of the prompt needs, and no state "
+                "snapshot is kept beside a cached prefix yet")
         if speculative_tokens:
             raise ConfigError(
                 "speculative_tokens does not compose with a recurrent "
-                "state: a rejected draft has already advanced the state "
-                "(for K/V it only leaves a stale row), and there is no "
-                "rollback yet")
+                f"state (pools {pools}): a rejected draft has already "
+                "advanced the state (for K/V it only leaves a stale row), "
+                "and there is no rollback yet")
 
     def _on_tpu(self) -> bool:
         """Backend check for the compiled Pallas path (the probe shared
@@ -1357,10 +1368,11 @@ class GenerationServer:
     def _refuse_latent_pages(self, what: str) -> None:
         if self._stateful:
             raise ConfigError(
-                f"{what} ships K/V page slabs; a recurrent state has no "
-                "wire form yet, and pages without it cannot be decoded from "
-                "— a model with the hybrid block prefills and decodes on "
-                "the same server")
+                f"{what} ships K/V page slabs; a recurrent state (pools "
+                f"{self._pool_names()}) has no wire form yet, and pages "
+                "without it cannot be decoded from — a model with the "
+                "hybrid block or conv layers prefills and decodes on the "
+                "same server")
         if self.cfg.latent:
             raise ConfigError(
                 f"{what} ships per-head K/V page slabs split along the "
@@ -1439,7 +1451,7 @@ class GenerationServer:
                 f"pool uses {self.page_size} (geometry must match end to end)")
         k_shards = export["k"]
         slab_shape = tuple(k_shards[0].shape)
-        pool_shape = tuple(self.k_pages.shape)
+        pool_shape = self._kv_geometry()
         kv_total = sum(int(s.shape[3]) for s in k_shards)
         expect = (pool_shape[0], self._pages_needed(len(prompt)),
                   pool_shape[2], pool_shape[3], pool_shape[4])
@@ -1457,6 +1469,16 @@ class GenerationServer:
         return await self._submit(_Request(
             prompt, max_new, tokens=[first], ttft_stamped=True,
             adopt=dict(export)))
+
+    def _kv_geometry(self) -> tuple:
+        """[layers, pages, page, kv heads, head width] of the K pool,
+        however it holds a token's heads (row-major: side by side): the
+        wire form of a page slab has the head axis either way."""
+        shape = tuple(self.k_pages.shape)
+        if len(shape) == 4:
+            kvh = self.cfg.kv_heads
+            shape = (*shape[:3], kvh, shape[3] // kvh)
+        return shape
 
     async def close(self) -> None:
         self._closed = True
@@ -1607,8 +1629,10 @@ class GenerationServer:
         """What ``slot``'s row of the state pool holds, fetched from the
         device: ``prompt`` and ``tokens`` of the tenant whose first chunk
         reset the row last (None: never held), ``tenancy`` which tenant of
-        the slot that is, ``state`` [layers, heads, d_state, d_head]
-        float32. A finished tenant's row stays as its last step left it —
+        the slot that is, ``state`` the row itself over the pool's layers
+        (the hybrid block: [layers, heads, d_state, d_head] float32; conv
+        layers: [conv layers, conv_L_cache - 1, dim], the last gated inputs
+        oldest first). A finished tenant's row stays as its last step left it —
         after its prompt and all but the last of its tokens — until the next
         tenant's first chunk. Call between steps: a step in flight holds the
         donated pools."""
@@ -1617,7 +1641,9 @@ class GenerationServer:
         prompt, tokens, tenancy = self._state_tenant[slot]
         row = jnp.asarray(slot + 1, jnp.int32)  # an operand: one program
         return {"prompt": prompt, "tokens": tokens, "tenancy": tenancy,
-                "state": jax.device_get(self.k_pages["ssm"][:, row])}
+                "state": jax.device_get(self.k_pages[
+                    next(p.name for p in cache_spec(self.cfg) if p.per_slot)
+                ][:, row])}
 
     def _slide_window(self, slot: int, first: int, last: int) -> None:
         """The slot's window pages for a step whose queries sit at positions
@@ -1690,6 +1716,8 @@ class GenerationServer:
         def upload(kp=self.k_pages, vp=self.v_pages):
             k = jnp.asarray(k_slab).astype(kp.dtype)
             v = jnp.asarray(v_slab).astype(vp.dtype)
+            if kp.ndim == 4:  # row-major pools: a token's heads side by side
+                k, v = (a.reshape(*a.shape[:3], -1) for a in (k, v))
             kp = kp.at[:, jnp.asarray(idx)].set(k)
             vp = vp.at[:, jnp.asarray(idx)].set(v)
             if self._kv_io_sharding is not None:
@@ -1879,9 +1907,13 @@ class GenerationServer:
 
                 shards = tp_size(self.mesh)
 
+            heads = self._kv_geometry()[3]
+
             def fetch(kp=self.k_pages, vp=self.v_pages):
-                return (np.asarray(jax.device_get(kp[:, idx])),
-                        np.asarray(jax.device_get(vp[:, idx])))
+                # the wire form has a head axis, however the pools hold it
+                return tuple(a.reshape(*a.shape[:3], heads, -1) for a in (
+                    np.asarray(jax.device_get(kp[:, idx])),
+                    np.asarray(jax.device_get(vp[:, idx]))))
 
             k_slab, v_slab = (
                 await asyncio.get_running_loop().run_in_executor(None, fetch))
@@ -2200,13 +2232,18 @@ class GenerationServer:
             from arkflow_tpu.parallel.mesh import tp_size
 
             shards = tp_size(self.mesh)
-        tiles = self._tiles_of[width] = {"per_kv_head": 0, "all_heads": 0}
-        for spec in map(self.cfg.gqa, self.cfg.kinds):
+        tiles = self._tiles_of[width] = {p: 0 for _, p in self.m_attn_tiles}
+        for spec in map(self.cfg.gqa, self.cfg.attn_kinds):
             heads, kvh = self.cfg.heads // shards, spec.kv_heads // shards
             tile_c = query_tile(width, heads)
-            per_head = (kernel_walks(spec.dk_held, spec.dv, self.kernel_interpret)
-                        and per_kv_head(tile_c, heads, kvh))
-            tiles["per_kv_head" if per_head else "all_heads"] += -(-width // tile_c)
+            if spec.row_major:  # a run of heads at a time, whatever the tile
+                product = "head_run"
+            elif (kernel_walks(spec.dk_held, spec.dv, self.kernel_interpret)
+                  and per_kv_head(tile_c, heads, kvh)):
+                product = "per_kv_head"
+            else:
+                product = "all_heads"
+            tiles[product] += -(-width // tile_c)
         return tiles
 
     def _apply_decode(self, act, nxt, reqs=None) -> None:

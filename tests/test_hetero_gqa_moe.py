@@ -448,14 +448,16 @@ def test_windowed_kernel_with_and_without_the_sink_matches_attend_ring(
 
 
 def test_on_a_chip_a_sink_needs_the_kernel_s_own_walk():
-    """Compiled (not interpreted) a head of no multiple of 128 lanes is
-    walked by the grid, which serves one width and no sink: refused by name."""
+    """Compiled (not interpreted) pools [.., kv heads, width] are walked at
+    heads of multiples of 128 lanes; a narrower head is served from
+    row-major pools, keys and values of one width and no sink: keys of 24
+    beside values of 16 are refused by name."""
     from arkflow_tpu.ops.ragged_attention import paged_flash_attention
 
     q = jnp.zeros((1, 1, 4, 24), jnp.bfloat16)
     kp = jnp.zeros((1, 3, PAGE, 2, 24), jnp.bfloat16)
     vp = jnp.zeros((1, 3, PAGE, 2, 16), jnp.bfloat16)
-    with pytest.raises(ValueError, match="kernel's own walk.*keys 24, values 16"):
+    with pytest.raises(ValueError, match="row-major: cache_spec.*keys 24, values 16"):
         paged_flash_attention(q, kp, vp, 0, jnp.zeros((1, 2), jnp.int32),
                               jnp.zeros((1,), jnp.int32))
 

@@ -287,7 +287,8 @@ def test_cache_spec_states_the_recurrent_kind():
     kp, vp = init_page_pool(CFG, 9, PAGE, slots=3)
     assert kp["ssm"].shape == (2, 4, 2, 16, 16) and kp["ssm"].dtype == jnp.float32
     assert vp["ssm"].shape == (2, 4, 3, 96) and vp["ssm"].dtype == jnp.bfloat16
-    assert kp["kv"].shape == vp["kv"].shape == (2, 9, PAGE, 2, 8)
+    # heads of 8, narrower than 128 lanes: a token's side by side
+    assert kp["kv"].shape == vp["kv"].shape == (2, 9, PAGE, 2 * 8)
     # a model without a mixer keeps its two arrays
     plain = dec.DecoderConfig(vocab_size=128, dim=32, layers=2, heads=4, kv_heads=2, ffn=64)
     assert [p.name for p in cache_spec(plain)] == ["kv"]

@@ -114,7 +114,9 @@ SLOTS, PAGE, PAGES_PER, POOL_PAGES, POOL_LAYERS = 8, 16, 32, 257, 4
 def _paged_shapes(geometry: str, chunk: int):
     """q, the WHOLE pools, the layer, the page table, the offsets."""
     kvh, h, dh = GEOMETRIES[geometry]
-    pool = ((POOL_LAYERS, POOL_PAGES, PAGE, kvh, dh), BF16)
+    # a head narrower than 128 lanes: row-major pools (``cache_spec``)
+    pool = ((POOL_LAYERS, POOL_PAGES, PAGE, *(
+        (kvh * dh,) if dh < 128 else (kvh, dh))), BF16)
     return (((SLOTS, chunk, h, dh), BF16), pool, pool, ((), I32),
             ((SLOTS, PAGES_PER), I32), ((SLOTS,), I32))
 
@@ -122,20 +124,43 @@ def _paged_shapes(geometry: str, chunk: int):
 @pytest.mark.parametrize("chunk", [1, 128], ids=["decode", "chunk128"])
 @pytest.mark.parametrize("geometry", list(GEOMETRIES))
 def test_paged_flash_compiles(v5e, geometry, chunk):
-    """Every head size compiles. A head of a multiple of 128 lanes by the
-    kernel's own walk; a narrower one (32, 64, 96) by the grid's, since the
-    chip's compiler takes no copy of its pages out of the pool ("Slice shape
-    along dimension 2 must be aligned to tiling (128)": the message the
-    kernel's walk gets there, and the reason the grid's is kept)."""
-    from arkflow_tpu.ops import ragged_attention
+    """Every head size compiles, by the kernel's own walk: a head of a
+    multiple of 128 lanes from pools [.., kv heads, width]; a narrower one
+    (32, 64, 96) from row-major pools, a token's heads whole runs of
+    lcm(width, 128) lanes. (Out of pools [.., kv heads, width < 128] the
+    chip's compiler takes no copy of a page — "Slice shape along dimension
+    2 must be aligned to tiling (128)" —: the call refuses them by name.)"""
+    compiled = _compile(paged_flash_attention, v5e, *_paged_shapes(geometry, chunk))
+    assert "tpu_custom_call" in compiled.as_text()
+    kvh, _, dh = GEOMETRIES[geometry]
+    if dh % 128:
+        shapes = list(_paged_shapes(geometry, chunk))
+        shapes[1] = shapes[2] = ((POOL_LAYERS, POOL_PAGES, PAGE, kvh, dh), BF16)
+        with pytest.raises(ValueError, match="row-major: cache_spec"):
+            _compile(paged_flash_attention, v5e, *shapes)
 
-    _compile(paged_flash_attention, v5e, *_paged_shapes(geometry, chunk))
-    if GEOMETRIES[geometry][2] % 128:
-        with pytest.MonkeyPatch.context() as patch, \
-                pytest.raises(Exception, match="aligned to tiling"):
-            patch.setattr(ragged_attention, "_grid_call", ragged_attention._walk_call)
-            _compile(paged_flash_attention.__wrapped__, v5e,
-                     *_paged_shapes(geometry, chunk))
+
+#: the narrow-head walk as ``lfm2_l12`` serves it: 32 / 8 heads of 64 (four
+#: 128-lane runs a token), 128 lanes of a 288-column table, a chunk of 256
+NARROW = {"decode": (128, 1), "chunk": (1, 256)}
+
+
+@pytest.mark.parametrize("step", list(NARROW))
+def test_narrow_head_walk_compiles_as_served(v5e, step):
+    """LFM2's attention layers: the row-major pools (3 layers x 1.2 GB of
+    pages) stay arguments of the Mosaic call — no temporary: nothing is
+    re-laid around it —, and a head of 64 under a 41-column ring compiles
+    too (no cell serves one; the CPU tests do)."""
+    b, c = NARROW[step]
+    pool = ((3, 1 + 128 * 288 // 4, PAGE, 8 * 64), BF16)
+    args = (((b, c, 32, 64), BF16), pool, pool, ((), I32), ((b, 288), I32),
+            ((b,), I32))
+    compiled = _compile(paged_flash_attention, v5e, *args)
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * 1024 * 1024
+    ring = list(args)
+    ring[4] = ((b, 41), I32)
+    _compile(lambda *a: paged_flash_attention(*a, window=128), v5e, *ring)
 
 
 #: the walk as the benchmark's cells serve it: (lanes, table columns, heads,
@@ -725,7 +750,7 @@ def test_hetero_keys_of_192_lanes_are_not_walked_as_one_part(v5e):
     """Why a 192-wide key is held in parts: as one row of 192 (or of 256
     under 4 K/V heads) the kernel's own walk cannot copy its pages."""
     pool = ((2, 257, PAGE, 4, 192), BF16)
-    with pytest.raises(Exception, match="different widths|aligned to tiling"):
+    with pytest.raises(Exception, match="row-major: cache_spec|aligned to tiling"):
         _compile(paged_flash_attention, v5e, ((8, 1, 64, 192), BF16), pool,
                  ((2, 257, PAGE, 4, 128), BF16), ((), I32), ((8, 32), I32),
                  ((8,), I32))
@@ -784,4 +809,72 @@ def test_hetero_steps_carry_pools_and_stacks_whole(v5e):
                      "moe_expert_swiglu"):
             assert name in text, name
         assert step.memory_analysis().temp_size_in_bytes < 200 * 1024 * 1024, \
+            step.memory_analysis().temp_size_in_bytes
+
+
+# -- LFM2-8B-A1B: conv layers among narrow-head attention layers, experts whole --
+
+LFM2 = dict(vocab_size=65536, dim=2048, layers=12, heads=32, kv_heads=8, head_dim=64,
+            ffn=7168, max_seq=128000, rope_theta=1e6, norm_eps=1e-5, qk_norm=True,
+            layer_types=("conv", "conv", "full_attention", "conv", "conv", "conv",
+                         "full_attention", "conv", "conv", "conv", "full_attention",
+                         "conv"),
+            conv_L_cache=3, n_routed_experts=32, num_experts_per_tok=4,
+            moe_intermediate_size=1792, first_k_dense_replace=2, norm_topk_eps=1e-6)
+#: 128 slots x 288 kept pages + scratch: every slot's whole table
+LFM2_SLOTS, LFM2_TABLE = 128, 288
+
+
+def test_conv_steps_carry_pools_and_stacks_whole(v5e):
+    """The ``_decode`` (128 lanes) and ``_chunk`` (1 x 256) programs of the
+    LFM2 cut as the server jits them, pools donated: the narrow-head walk
+    and the expert kernel are in the text, and the temporaries stay under
+    300 MB — no pool (3 x 1.21 GB of row-major K/V pages, which XLA re-laid
+    around every step as [.., 8 heads, 64]; the conv windows, 9.5 MB, are
+    updated by a scatter of the lanes' rows in place) and no run's experts
+    (0.7 GB a layer) is copied for a step."""
+    from arkflow_tpu.models import decoder as dec
+    from arkflow_tpu.models.paged_decode import (init_page_pool, paged_decode_step,
+                                                 paged_prefill_chunk)
+
+    cfg = dec.DecoderConfig(**LFM2)
+    repl = SingleDeviceSharding(v5e[0])
+
+    def struct(a, dtype=None):
+        return jax.ShapeDtypeStruct(a.shape, dtype or a.dtype, sharding=repl)
+
+    params = jax.tree_util.tree_map(
+        struct, jax.eval_shape(lambda: dec.init(jax.random.PRNGKey(0), cfg)),
+        dec.serve_dtypes(cfg))
+    kp, vp = jax.tree_util.tree_map(struct, jax.eval_shape(
+        lambda: init_page_pool(cfg, 1 + LFM2_SLOTS * LFM2_TABLE, PAGE,
+                               slots=LFM2_SLOTS)))
+    assert kp["kv"].shape == vp["kv"].shape == (3, 1 + 128 * 288, 16, 512)
+    assert kp["conv"].shape == (9, 129, 2, 2048)
+    kern = dict(attention_kernel="paged")
+
+    def decode(p, tok, lens, act, table, kp, vp):
+        return paged_decode_step(p, cfg, tok, lens, act, table, kp, vp,
+                                 return_logits=True, **kern)
+
+    def chunk(p, ids, off, clen, table, rows, kp, vp):
+        return paged_prefill_chunk(p, cfg, ids, off, clen, table, kp, vp,
+                                   ssm_rows=rows, **kern)
+
+    def compiled(fn, *operands):
+        n = len(operands)
+        return jax.jit(fn, donate_argnums=(n + 1, n + 2)).lower(
+            params, *[jax.ShapeDtypeStruct(s, d, sharding=repl)
+                      for s, d in operands], kp, vp).compile()
+
+    s = LFM2_SLOTS
+    for step in (
+            compiled(decode, ((s,), I32), ((s,), I32), ((s,), jnp.bool_),
+                     ((s, LFM2_TABLE), I32)),
+            compiled(chunk, ((1, 256), I32), ((1,), I32), ((1,), I32),
+                     ((1, LFM2_TABLE), I32), ((1,), I32))):
+        text = step.as_text()
+        for name in ("paged_flash_attention", "moe_expert_swiglu"):
+            assert name in text, name
+        assert step.memory_analysis().temp_size_in_bytes < 300 * 1024 * 1024, \
             step.memory_analysis().temp_size_in_bytes

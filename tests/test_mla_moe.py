@@ -627,21 +627,29 @@ def test_judge_holds_the_float32_leaves_to_their_masters():
 # -- the dense path is what it was ------------------------------------------------------
 
 #: sha256 of the lowered text of the dense GQA programs, ``_decode`` then
-#: ``_chunk``: gather as PR 32 left them (the pools carried whole through
-#: the layer scan), paged as PR 41 left them (the kernel walks the page table
-#: itself) but for ``_chunk``, whose tile multiplies a K/V head at a time
-#: since PR 43 (was 34b2db03d57a9a4a; ``_decode`` stands). A PR that changes
-#: the dense programs on purpose recomputes them with this test's code.
+#: ``_chunk``. ``*_wide``, a head of 128 lanes (what the per-head cells
+#: serve): gather with the pools carried whole through the layer scan (PR
+#: 32), paged with the kernel walking the page table itself (PR 41), its
+#: chunk tile a K/V head at a time (PR 43) — recorded at PR 46's parent, which
+#: PR 46 reproduces. At a head of 8 both were RE-RECORDED at PR 46 on purpose:
+#: a head narrower than 128 lanes has row-major pools and the narrow-head
+#: walk since (they stood at fdea09887a70df43, e5fbbb5138b92dea and
+#: 1af74e9152f85a54, 2626a52b0b48a18d). A PR that changes the dense programs
+#: on purpose recomputes them with this test's code.
 DENSE_HLO = {
-    "gather": ("fdea09887a70df43", "e5fbbb5138b92dea"),
-    "paged": ("1af74e9152f85a54", "2626a52b0b48a18d"),
+    "gather": ("60ffd56343915631", "9992b028c6149b1d"),
+    "paged": ("94a8d586436a9de5", "567d43b22ded3d2f"),
+    "gather_wide": ("eaf5b975d560c31b", "502b93d7f55f0b71"),
+    "paged_wide": ("5cd67e6cd4990bd3", "108db1c0c9f52ed8"),
 }
 
 
-@pytest.mark.parametrize("kern", sorted(DENSE_HLO))
-def test_dense_programs_lower_to_the_same_text(kern):
+def _dense_hlo(case: str) -> tuple:
+    """The hashes of ``_decode``'s and ``_chunk``'s lowered text at a head
+    of 8 or, ``_wide``, of 128 lanes."""
+    kern, _, wide = case.partition("_")
     cfg = dec.DecoderConfig(vocab_size=128, dim=32, layers=2, heads=4,
-                            kv_heads=2, ffn=64)
+                            kv_heads=2, ffn=64, head_dim=128 if wide else 0)
     p = jax.eval_shape(lambda: dec.init(jax.random.PRNGKey(0), cfg))
     kp, vp = jax.eval_shape(lambda: init_page_pool(cfg, 9, 8))
     s, i32 = jax.ShapeDtypeStruct, jnp.int32
@@ -655,8 +663,12 @@ def test_dense_programs_lower_to_the_same_text(kern):
                      s((4, 2), i32), kp, vp).as_text(),
         chunk.lower(p, s((1, 8), i32), s((1,), i32), s((1,), i32),
                     s((1, 2), i32), kp, vp).as_text())
-    got = tuple(hashlib.sha256(t.encode()).hexdigest()[:16] for t in texts)
-    assert got == DENSE_HLO[kern]
+    return tuple(hashlib.sha256(t.encode()).hexdigest()[:16] for t in texts)
+
+
+@pytest.mark.parametrize("case", sorted(DENSE_HLO))
+def test_dense_programs_lower_to_the_same_text(case):
+    assert _dense_hlo(case) == DENSE_HLO[case]
 
 
 #: sha256 of the tiny dense model's greedy tokens — a 13- and a 9-token

@@ -258,26 +258,91 @@ def test_paged_flash_attention_walks_live_pages_in_groups(name, layer):
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=3e-5)
 
 
-def _walked_by_the_grid(monkeypatch):
-    """``paged_flash_attention`` as a chip compiles it for a head of no
-    multiple of 128 lanes — the grid walks the table, a page a step —, here
-    interpreted: untraced, so no cached trace of the kernel's walk serves."""
-    monkeypatch.setattr(ragged_attention, "_walk_call", ragged_attention._grid_call)
-    return paged_flash_attention.__wrapped__
+def _row_major(*pools):
+    """Pools [.., kv heads, width] as a head narrower than 128 lanes is
+    served: a token's heads side by side on the last axis."""
+    return [x.reshape(*x.shape[:-2], -1) for x in pools]
 
 
 @pytest.mark.parametrize("name", WALK_CASES)
-def test_the_grid_walk_of_narrow_heads_gives_the_same(monkeypatch, name):
-    """The walk a narrow head keeps on a chip (PR 41 left it the grid: the
-    chip's compiler takes no copy of its pages) against the dense reference
-    on the kernel walk's own cases; a dead column resolves to page 0 there
-    and is never read either."""
+def test_the_walk_of_row_major_pools_gives_the_same(name):
+    """The walk a narrow head takes (``_narrow_kernel``: a page copied as it
+    is held, the products a run of heads at a time under zero-extended
+    queries) against the dense reference on the kernel walk's own cases;
+    dead columns name a page of NaN there too. The probabilities go to the
+    values' product in the pools' type (bfloat16, as the latent kernel's):
+    2e-3 of values of 0.5, where the float32 walk holds 3e-5."""
     q, kp, vp, table, clean, off = _walk_operands(name)
-    out = _walked_by_the_grid(monkeypatch)(
-        q, *_in_pool(1, kp, vp), 1, table, off, interpret=True)
+    out = paged_flash_attention(q, *_row_major(*_in_pool(1, kp, vp)), 1, table,
+                                off, interpret=True)
     ref = _dense_paged_reference(q, kp, vp, clean, off)
     assert np.isfinite(np.asarray(out)).all()
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=3e-5)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-3)
+
+
+#: (query heads, K/V heads, head width, page, chunk, offsets, window): heads
+#: of 64 and 32 whose tokens are whole 128-lane runs (LFM2's 32 / 8 x 64: four
+#: runs of two heads; the decoder default's 8 / 4 x 32: one run of four), a
+#: head of 96 (Phi-3-mini's: runs of 384 lanes, four heads), a token that is
+#: no whole run (interpreted only: one run), a decode step, a chunk of one
+#: tile and of several (its last padded), a window over a ring
+NARROW_CASES = {
+    "lfm2_decode_8_kv_of_64": (32, 8, 64, 16, 1, (0, 15, 16, 700), 0),
+    "lfm2_chunk_one_tile": (32, 8, 64, 16, 24, (0, 100, 333), 0),
+    "lfm2_chunk_three_tiles": (32, 8, 64, 16, 70, (5, 260), 0),
+    "default_4_kv_of_32": (8, 4, 32, 16, 40, (3, 200), 0),
+    "mha_8_kv_of_96": (8, 8, 96, 4, 12, (0, 50), 0),
+    "three_kv_of_8_one_run": (6, 3, 8, 4, 5, (2, 37), 0),
+    "window_ring_of_64": (16, 4, 64, 16, 40, (0, 100, 4000), 128),
+}
+
+
+@pytest.mark.parametrize("name", list(NARROW_CASES))
+def test_narrow_heads_walk_row_major_pools(name):
+    """Heads of 64, 32 and 96 from row-major pools against attention over
+    each row's logical keys: the zero-extended queries meet their own
+    head's lanes only, a row keeps its own head's lanes of the values'
+    product, GQA by the fold."""
+    h, kvh, dh, page, c, offs, window = NARROW_CASES[name]
+    rng = np.random.RandomState(len(name))
+    b = len(offs)
+    tile_c = ragged_attention.query_tile(c, h)
+    c_pad = -(-c // tile_c) * tile_c
+    t = -(-(max(offs) + c_pad) // page) * page
+    bf = lambda a: jnp.asarray(a, jnp.bfloat16)  # noqa: E731
+    k, v = (bf(rng.randn(b, t, kvh, dh) * 0.5) for _ in range(2))
+    cols = (window + c_pad - 2) // page + 2 if window else t // page
+    n_pages = 2 + b * cols
+    kp = np.zeros((3, n_pages, page, kvh * dh), np.float32)
+    vp = np.zeros_like(kp)
+    kp[[0, 2]] = vp[[0, 2]] = kp[1, -1] = vp[1, -1] = np.nan
+    table = np.full((b, cols), 0 if window else n_pages - 1, np.int32)
+    for r, off in enumerate(offs):
+        first = max(off - (window - 1), 0) // page if window else 0
+        own = 1 + r * cols + rng.permutation(cols)
+        for i in range(first, (off + c_pad - 1) // page + 1):
+            pg = own[i % cols]
+            table[r, i % cols if window else i] = pg
+            kp[1, pg] = np.asarray(k[r, i * page:(i + 1) * page], np.float32
+                                   ).reshape(page, -1)
+            vp[1, pg] = np.asarray(v[r, i * page:(i + 1) * page], np.float32
+                                   ).reshape(page, -1)
+    q = bf(rng.randn(b, c, h, dh) * 0.5)
+    off = jnp.asarray(offs, jnp.int32)
+    out = paged_flash_attention(q, bf(kp), bf(vp), 1, jnp.asarray(table), off,
+                                interpret=True, window=window)
+    from arkflow_tpu.models import common as cm
+
+    pos = off[:, None] + jnp.arange(c)[None, :]
+    keys = jnp.arange(t)[None, None, None, :]
+    mask = keys <= pos[:, None, :, None]
+    if window:
+        mask = mask & (keys > pos[:, None, :, None] - window)
+    f32 = lambda a: a.astype(jnp.float32)  # noqa: E731
+    ref = cm.attention(f32(q), jnp.repeat(f32(k), h // kvh, axis=2),
+                       jnp.repeat(f32(v), h // kvh, axis=2), mask)
+    assert out.shape == q.shape and np.isfinite(np.asarray(f32(out))).all()
+    np.testing.assert_allclose(np.asarray(f32(out)), np.asarray(ref), atol=2e-2)
 
 
 # -- a chunk's tile: each K/V head's keys by that head's own query rows ---------
@@ -432,26 +497,38 @@ def _paged_calls(jaxpr):
                 yield from _paged_calls(inner)
 
 
-def _paged_call(dh, **kw):
+def _paged_call(dh, row_major=False, **kw):
     """The kernel call ``paged_flash_attention`` traces to at head size ``dh``."""
     q = jnp.zeros((2, 1, 4, dh), jnp.bfloat16)
-    pool = jnp.zeros((2, 9, 16, 2, dh), jnp.bfloat16)
+    pool = jnp.zeros((2, 9, 16, 4 * dh) if row_major else (2, 9, 16, 4, dh),
+                     jnp.bfloat16)
     traced = jax.make_jaxpr(lambda *a: paged_flash_attention(*a, **kw))(
         q, pool, pool, 0, jnp.zeros((2, 4), jnp.int32), jnp.zeros((2,), jnp.int32))
     (call,) = _paged_calls(traced.jaxpr)
     return call
 
 
-@pytest.mark.parametrize("dh,compiled,interpreted", [
-    (128, 2, 2), (256, 2, 2), (32, 3, 2), (64, 3, 2), (96, 3, 2)])
-def test_who_walks_the_table_follows_the_head_size(dh, compiled, interpreted):
-    """The kernel walks the table — a grid of (row, query tile) — wherever
-    its copies are legal: a head of a multiple of 128 lanes on a chip, any
-    head interpreted (so every CPU test above holds the kernel's walk to
-    the reference). A narrower head compiled for a chip keeps the grid of
-    (row, query tile, page); test_kernels_compile_tpu.py compiles both."""
-    assert len(_paged_call(dh).params["grid_mapping"].grid) == compiled
-    assert len(_paged_call(dh, interpret=True).params["grid_mapping"].grid) == interpreted
+@pytest.mark.parametrize("dh", [128, 256, 32, 64, 96])
+def test_who_walks_the_table_follows_the_head_size(dh):
+    """The kernel walks the table — a grid of (row, query tile) — at every
+    head size: a head of a multiple of 128 lanes from pools [.., kv heads,
+    width], a narrower one from ROW-MAJOR pools [.., kv heads * width] (its
+    token whole runs of lcm(width, 128) lanes; test_kernels_compile_tpu.py
+    compiles both). Pools [.., kv heads, width < 128] are walked interpreted
+    only (so the CPU tests above hold the wide head's walk to the reference
+    at small widths) and compile for a chip to an error by name."""
+    narrow = dh % 128 != 0
+    assert len(_paged_call(dh, row_major=narrow).params["grid_mapping"].grid) == 2
+    if narrow:
+        assert len(_paged_call(dh, interpret=True).params["grid_mapping"].grid) == 2
+        with pytest.raises(ValueError, match="row-major: cache_spec"):
+            _paged_call(dh)
+    from arkflow_tpu.ops.ragged_attention import kernel_walks, narrow_run
+
+    assert kernel_walks(dh, dh, False, kvh=0 if not narrow else 4)
+    assert not kernel_walks(64, 64, False, kvh=1)     # half a run a token
+    assert (narrow_run(8, 64), narrow_run(4, 32), narrow_run(32, 96),
+            narrow_run(3, 8)) == (128, 128, 384, 24)
 
 
 def test_page_group_follows_the_rows_of_the_call():
@@ -624,7 +701,8 @@ def test_carried_pools_are_written_in_place_and_alike():
         live = written.copy()
         live[0, 0] = False                               # scratch: any value
         for pool in (got, ref):
-            changed = (pool != was).any(axis=(-1, -2))   # [layers, pages, page]
+            # [layers, pages, page]: a token's heads on one axis or two
+            changed = (pool != was).reshape(*pool.shape[:3], -1).any(axis=-1)
             assert not changed[:, ~written].any()
             assert changed[:, live].all()
 
@@ -644,9 +722,10 @@ def test_paged_kernel_tp_host_mesh_parity():
     mesh = create_mesh(MeshSpec(tp=2), devices=jax.devices()[:2])
     axes = {n: n for n in mesh.axis_names}
     sharded = shard_params(params, fam.param_specs(cfg, axes), mesh)
-    kv = kv_pool_sharding(mesh)
+    kv = kv_pool_sharding(mesh, row_major=True)  # heads of 16: row-major
 
     kp, vp = init_page_pool(cfg, num_pages=9, page_size=4)
+    assert kp.shape == (2, 9, 4, 2 * 16)
     kp = jax.device_put(kp, kv)
     vp = jax.device_put(vp, kv)
     table = jnp.asarray([[5, 2, 7, 0, 0, 0, 0, 0],
@@ -745,14 +824,20 @@ def test_server_counts_the_pages_its_rows_walk():
 def test_server_counts_its_query_tiles_by_the_product_they_make():
     """``arkflow_gen_attn_tiles_total{kind, product}``: the kernel's (row,
     query tile) programs, a layer, by the kernel's own predicate on the
-    step's shapes. The same serve as above over two layers: two chunks of 8
-    positions x 2 query heads a K/V head (16 rows a head: a K/V head at a
-    time), four decode steps of two lanes (2 rows a head: all heads at
-    once). A chunk of 4 positions x 1 query head is no multiple of a
-    sublane tile and stays on the all-heads product."""
+    step's shapes, at a head of 128 lanes (pools [.., kv heads, 128]). The
+    same serve as above over two layers: two chunks of 8 positions x 2
+    query heads a K/V head (16 rows a head: a K/V head at a time), four
+    decode steps of two lanes (2 rows a head: all heads at once). A chunk
+    of 4 positions x 1 query head is no multiple of a sublane tile and
+    stays on the all-heads product. A narrower head's row-major pools are
+    walked a run of heads at a time over those heads' own rows, whatever
+    the tile: every program counts under a label of its own (``head_run``:
+    queries zero-extended over their run are no per-head product)."""
     from arkflow_tpu.ops.ragged_attention import per_kv_head, query_tile
 
-    cfg, params = _tiny_setup(seed=3)
+    model = get_model("decoder_lm")
+    cfg = model.make_config(**{**TINY, "head_dim": 128})
+    params = model.init(jax.random.PRNGKey(3), cfg)
 
     async def go(cfg, params, chunk):
         srv = GenerationServer(params, cfg, slots=2, page_size=4, max_seq=40,
@@ -769,12 +854,17 @@ def test_server_counts_its_query_tiles_by_the_product_they_make():
     assert got == {("chunk", "per_kv_head"): 2 * cfg.layers, ("chunk", "all_heads"): 0,
                    ("decode", "per_kv_head"): 0,
                    ("decode", "all_heads"): 4 * 2 * cfg.layers}
-    model = get_model("decoder_lm")
-    mha = model.make_config(**{**TINY, "kv_heads": 4})
+    mha = model.make_config(**{**TINY, "kv_heads": 4, "head_dim": 128})
     got = asyncio.run(go(mha, model.init(jax.random.PRNGKey(3), mha), 4))
     assert not per_kv_head(query_tile(4, 4), 4, 4)
     assert got["chunk", "per_kv_head"] == 0
     assert got["chunk", "all_heads"] == 4 * mha.layers   # 13 tokens: 4 chunks
+    narrow, params = _tiny_setup(seed=3)                  # heads of 16
+    got = asyncio.run(go(narrow, params, 8))
+    assert got == {("chunk", "head_run"): 2 * narrow.layers,
+                   ("decode", "head_run"): 4 * 2 * narrow.layers,
+                   **{(kind, product): 0 for kind in ("chunk", "decode")
+                      for product in ("per_kv_head", "all_heads")}}
 
 
 def test_server_dispatch_depth2_bitwise_identical():

@@ -138,8 +138,13 @@ def test_cache_spec_states_kept_rows_and_window_rows():
     assert kv_bytes_per_token(CFG) == 5 * 2 * kv * 2
     kp, vp = init_page_pool(CFG, 7, PAGE, window_pages=5)
     assert set(kp) == set(vp) == {"kv", "kv_window"}
-    assert kp["kv"].shape == (1, 7, PAGE, 2, 8) and kp["kv"].dtype == jnp.bfloat16
-    assert vp["kv_window"].shape == (4, 5, PAGE, 2, 8)
+    # heads of 8, narrower than 128 lanes: row-major, a token's side by side
+    assert kp["kv"].shape == (1, 7, PAGE, 2 * 8) and kp["kv"].dtype == jnp.bfloat16
+    assert vp["kv_window"].shape == (4, 5, PAGE, 2 * 8)
+    assert pools["kv"].row_major and pools["kv_window"].row_major
+    wide = {p.name: p for p in cache_spec(dataclasses.replace(CFG, head_dim=128))}
+    assert not wide["kv"].row_major
+    assert wide["kv_window"].shapes(5, PAGE) == [(4, 5, PAGE, 2, 128)] * 2
     # a ring holds the window before a step's first query to its last
     assert window_ring_pages(CFG, PAGE, 1) == 3
     assert window_ring_pages(CFG, PAGE, 8) == 3
@@ -290,13 +295,13 @@ def test_kernel_probe_covers_the_pattern_s_kernels(params):
     # and a walk of more than one group, its last partly dead
     (40, 4, 1, (3, 39, 57, 200, 1001)), (40, 4, 8, (0, 36, 95, 642, 3001)),
     (70, 4, 1, (68, 69, 70, 333, 1702))])
-@pytest.mark.parametrize("walker", ["kernel", "grid"])
-def test_windowed_kernel_matches_its_plain_xla_twin(monkeypatch, walker, window,
-                                                    page, c, offs):
+@pytest.mark.parametrize("walker", ["kernel", "row_major"])
+def test_windowed_kernel_matches_its_plain_xla_twin(walker, window, page, c, offs):
     """Rows at their start, inside their first window, past it and past the
     ring's wrap, each ring holding only the pages a server would hold. By
-    the kernel's own walk, and by the grid's, which a head of no multiple of
-    128 lanes keeps on a chip (untraced: no cached trace of the other)."""
+    the walk over pools [.., kv heads, width] (a head of 128 lanes' on a
+    chip), and by the walk over row-major pools, which a narrower head's
+    are."""
     cfg = dataclasses.replace(CFG, sliding_window=window)
     cols = window_ring_pages(cfg, page, c)
     b = len(offs)
@@ -313,14 +318,12 @@ def test_windowed_kernel_matches_its_plain_xla_twin(monkeypatch, walker, window,
     q = rand((b, c, 4, 8))
     positions = off[:, None] + jnp.arange(c)[None, :]
     want = _attend_ring(q, kp, vp, 1, ring, positions, window)
-    if walker == "grid":
-        from arkflow_tpu.ops import ragged_attention
-
-        monkeypatch.setattr(ragged_attention, "_walk_call", ragged_attention._grid_call)
-        got = ragged_attention.paged_flash_attention.__wrapped__(
-            q, kp, vp, 1, ring, off, interpret=True, window=window)
-    else:
-        got = _attend_paged(q, kp, vp, 1, ring, off, cfg, None, True, window)
+    if walker == "row_major":
+        kp, vp = (a.reshape(*a.shape[:3], -1) for a in (kp, vp))
+        np.testing.assert_array_equal(
+            np.asarray(_attend_ring(q, kp, vp, 1, ring, positions, window)),
+            np.asarray(want))
+    got = _attend_paged(q, kp, vp, 1, ring, off, cfg, None, True, window)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
 
 
@@ -335,41 +338,46 @@ def _text_hash(text: str) -> str:
 
 #: sha256 (first 16 hex) of the jaxprs (source positions stripped) of a decode
 #: step and a prefill chunk at tiny dense (the Mistral layout) and hybrid (the
-#: Falcon-H1 layout) sizes: gather and paged as PR 39 left them, before there
-#: were windows, at a head of 8 — which a chip's compiler has the GRID walk,
-#: as every head was walked before PR 41 —, and ``walk`` at a head of 128:
-#: the kernel walks the page table itself (``window`` 0 still lowers to ONE
-#: form of each), a decode step as PR 41 left it, a chunk as PR 43 did (its
-#: tile multiplies a K/V head at a time: 16 rows a head here; re-recorded
-#: from 9d47f7f4d33e5f36 / 9f2f3d5814b985ff, which the parent reproduces)
+#: Falcon-H1 layout) sizes. At a head of 128 lanes — what the benchmark's
+#: per-head cells serve — ``walk`` is the kernel walking the page table itself
+#: (``window`` 0 still lowers to ONE form of each), a decode step as PR 41
+#: left it, a chunk as PR 43 did (its tile multiplies a K/V head at a time),
+#: and ``gather_wide`` the plain-XLA form, recorded at PR 46's parent (which
+#: reproduces all eight): PR 46 moved none of them. ``gather`` and ``paged``
+#: are a head of 64, RE-RECORDED at PR 46 on purpose: a head narrower than
+#: 128 lanes has row-major pools and the narrow-head walk since (until then
+#: these stood for the GRID's walk at a head of 8, 48f260fdc014f61e ..
+#: 31135aa3fc6bb0df, which went with the grid)
 WINDOW_0_GOLDEN = {
-    "dense.decode.gather": "48f260fdc014f61e", "dense.chunk.gather": "02b87477d5d7db5c",
-    "dense.decode.paged": "64ce15284ca65c61", "dense.chunk.paged": "75e38e2a56da97f5",
-    "hybrid.decode.gather": "b93168be9c2f3624", "hybrid.chunk.gather": "ddfdb61920de41cd",
-    "hybrid.decode.paged": "d314b67f4e837d21", "hybrid.chunk.paged": "31135aa3fc6bb0df",
+    "dense.decode.gather": "0c702f3da9e0d32f", "dense.chunk.gather": "9c8368cf57b10193",
+    "dense.decode.paged": "54c9aa64d0a27507", "dense.chunk.paged": "2d700266541cda06",
+    "hybrid.decode.gather": "6301e1548c8fe7d1", "hybrid.chunk.gather": "861f1ac755c688c9",
+    "hybrid.decode.paged": "58b5a45d283d50e2", "hybrid.chunk.paged": "6387207ad1c67272",
+    "dense.decode.gather_wide": "a4fe2d04ebb3b860",
+    "dense.chunk.gather_wide": "a6ada1e181277574",
+    "hybrid.decode.gather_wide": "652c84eb6ce512e5",
+    "hybrid.chunk.gather_wide": "d7b395f58ea511f1",
     "dense.decode.walk": "8fb05ab0dea5dbe0", "dense.chunk.walk": "b81f1547cdd2021a",
     "hybrid.decode.walk": "41b44ac90e51a17a", "hybrid.chunk.walk": "e6532038eb591939"}
 
 
-@pytest.mark.parametrize("case", sorted(WINDOW_0_GOLDEN))
-def test_window_0_gives_the_present_jaxpr(case):
-    """Without a window the layer loop and the kernel trace to what they
-    traced to before there were windows: one scan over ``layers``, the
-    kernel's call unchanged (Mistral's and Falcon-H1's programs)."""
+def _window_0_text(case: str) -> str:
+    """The jaxpr's text of ``case`` = layout.step.kernel."""
     layout, step, kern = case.split(".")
-    sizes = dict(vocab_size=64, dim=32, layers=2, heads=4, kv_heads=2, ffn=48,
+    # heads of 64: two K/V heads fill one 128-lane run of a row-major pool
+    sizes = dict(vocab_size=64, dim=256, layers=2, heads=4, kv_heads=2, ffn=48,
                  max_seq=64)
     if layout == "hybrid":
         sizes.update(mamba_d_ssm=32, mamba_n_heads=4, mamba_d_head=8,
                      mamba_d_state=8, mamba_n_groups=2)
-    if kern == "walk":  # a head of 128 lanes: the kernel's own walk
+    if kern in ("walk", "gather_wide"):  # a head of 128 lanes
         sizes.update(dim=256, heads=2, kv_heads=1)
     cfg = dec.DecoderConfig(**sizes)
     p = jax.eval_shape(lambda: dec.init(jax.random.PRNGKey(0), cfg))
     kp, vp = jax.eval_shape(lambda: init_page_pool(cfg, 9, 8, slots=2))
     table = jnp.zeros((2, 4), jnp.int32)
-    kw = dict(attention_kernel="paged" if kern == "walk" else kern,
-              kernel_interpret=False)
+    kw = dict(attention_kernel={"walk": "paged", "gather_wide": "gather"}.get(
+        kern, kern), kernel_interpret=False)
     if step == "decode":
         jaxpr = jax.make_jaxpr(lambda p, k, v: paged_decode_step(
             p, cfg, jnp.zeros((2,), jnp.int32), jnp.ones((2,), jnp.int32),
@@ -379,8 +387,16 @@ def test_window_0_gives_the_present_jaxpr(case):
         jaxpr = jax.make_jaxpr(lambda p, k, v: paged_prefill_chunk(
             p, cfg, jnp.zeros((1, 8), jnp.int32), jnp.zeros((1,), jnp.int32),
             jnp.full((1,), 5, jnp.int32), table[:1], k, v, **kw, **extra))(p, kp, vp)
-    text = _jaxpr_text(jaxpr)
-    assert layout == "hybrid" or text.count("scan[") == 1  # the ONE layer loop
+    return _jaxpr_text(jaxpr)
+
+
+@pytest.mark.parametrize("case", sorted(WINDOW_0_GOLDEN))
+def test_window_0_gives_the_present_jaxpr(case):
+    """Without a window the layer loop and the kernel trace to what they
+    traced to before there were windows: one scan over ``layers``, the
+    kernel's call unchanged (Mistral's and Falcon-H1's programs)."""
+    text = _window_0_text(case)
+    assert case.startswith("hybrid") or text.count("scan[") == 1  # ONE layer loop
     assert _text_hash(text) == WINDOW_0_GOLDEN[case]
 
 

@@ -23,6 +23,15 @@ this process holds. One JSON line a point:
     python tools/profile_paged_attention.py                      # every point
     python tools/profile_paged_attention.py --cell mimo_l7_full --kind chunk
     python tools/profile_paged_attention.py --group-max 16,32,64 # a sweep
+    python tools/profile_paged_attention.py --cell lfm2_l12 --form gather
+
+A head narrower than 128 lanes (``lfm2_l12``: 8 K/V heads of 64) has
+row-major pools and the narrow-head walk where the checkout has one
+(``ops/ragged_attention.narrow_run``); an older checkout (``--root``) gets
+pools [.., kv heads, 64] and walks them by its grid. ``--form gather`` times
+the plain-XLA form a ``decode_kernel: gather`` server runs instead (the
+layer's context gathered through the table, K and V repeated to the query
+heads, masked attention).
 
 ``--group-max`` sets the module's group ceilings for the run (a
 microbench's lever, not a serving knob; for a latent point it IS the group,
@@ -68,6 +77,12 @@ CELLS = {
     "mimo_l7_window": dict(h=64, kvh=8, dk=192, dv=128, window=128, sink=True,
                            lanes=64, cols=41, chunk=512, offsets=(4096,),
                            ctx=(4600, 0.6, 1100, 13300)),
+    # 8 K/V heads of 64: row-major pools, the narrow-head walk; every lane of
+    # a decode point at ONE context (``decode_ctx``: a point each). The table
+    # as ISSUE 46 sized it (768 new tokens: 304 columns) and PERF.md's
+    # kernel-alone table was measured; the cell serves 288 since (512 new)
+    "lfm2_l12": dict(h=32, kvh=8, dk=64, dv=64, lanes=128, cols=304, chunk=256,
+                     offsets=(512, 2048, 4608), decode_ctx=(512, 2048, 4863)),
     # the latent kernel (``lat``: one shared row a token of that width beside
     # a rope key of ``rope``; ``topk``: under an indexer's choice of so many)
     "kanana2_l6": dict(h=32, lat=512, rope=64, lanes=16, cols=136, chunk=128,
@@ -86,17 +101,21 @@ def _points(args):
         if args.cell not in ("all", name):
             continue
         if args.kind in ("all", "decode"):
-            yield name, cell, "decode", None
+            for ctx in cell.get("decode_ctx", (None,)):
+                yield name, cell, "decode", ctx
         if args.kind in ("all", "chunk"):
             for off in cell["offsets"]:
                 yield name, cell, "chunk", off
 
 
-def _pools(cell, seed: int, tiny: bool, rope_held: bool = True):
+def _pools(cell, seed: int, tiny: bool, rope_held: bool = True,
+           row_major: bool = True):
     """A cell's K and V pools (two layers, every lane's columns once, a key
-    of 192 in two parts of 128 lanes) and its sink logits; a latent cell's
-    latent rows and rope keys, the keys in whole 128-lane rows with zeros
-    behind them where the checkout holds them so (``rope_held``)."""
+    of 192 in two parts of 128 lanes; a head narrower than 128 lanes
+    row-major where the checkout walks such pools, ``row_major``) and its
+    sink logits; a latent cell's latent rows and rope keys, the keys in
+    whole 128-lane rows with zeros behind them where the checkout holds them
+    so (``rope_held``)."""
     import jax
     import jax.numpy as jnp
 
@@ -111,11 +130,14 @@ def _pools(cell, seed: int, tiny: bool, rope_held: bool = True):
         return (jax.random.normal(keys[0], pool + (cell["lat"],), jnp.bfloat16) * 0.5,
                 r, None, lanes, cols)
     dk, dv, kvh = cell["dk"], cell["dv"], cell["kvh"]
-    parts, held = (1, dk) if dk % 128 == 0 else (-(-dk // 128), 128)
+    parts, held = (1, dk) if dk % 128 == 0 or dk < 128 else (-(-dk // 128), 128)
     keys = jax.random.split(jax.random.PRNGKey(seed), 3)
     fill = lambda key, shape: (jax.random.normal(key, shape, jnp.bfloat16) * 0.5)  # noqa: E731
-    k = fill(keys[0], (2 * parts, 1 + lanes * cols, PAGE, kvh, held))
-    v = fill(keys[1], (2, 1 + lanes * cols, PAGE, kvh, dv))
+    flat = row_major and dk == dv < 128
+    k = fill(keys[0], (2 * parts, 1 + lanes * cols, PAGE,
+                       *((kvh * held,) if flat else (kvh, held))))
+    v = fill(keys[1], (2, 1 + lanes * cols, PAGE,
+                       *((kvh * dv,) if flat else (kvh, dv))))
     sink = (jax.random.normal(keys[2], (cell["h"],), jnp.float32) + 2.0
             if cell.get("sink") else None)
     return k, v, sink, lanes, cols
@@ -128,7 +150,9 @@ def _queries(cell, kind, off, rng, lanes: int, cols: int, tiny: bool):
     import numpy as np
 
     b, c = (lanes, 1) if kind == "decode" else (1, 32 if tiny else cell["chunk"])
-    if kind == "decode":
+    if kind == "decode" and off is not None:
+        ctx = np.full((b,), off, np.float64)
+    elif kind == "decode":
         med, sigma, lo, hi = cell["ctx"]
         ctx = np.clip(np.exp(rng.normal(np.log(med), sigma, b)), lo, hi)
     else:
@@ -167,7 +191,8 @@ def _held_bytes(cell, ctx, c) -> float:
         if cell.get("topk"):
             keys = np.minimum(keys, cell["topk"] + c - 1)
         return float(keys.sum()) * (cell["lat"] + cell["rope"]) * 2
-    dk = cell["dk"] if cell["dk"] % 128 == 0 else -(-cell["dk"] // 128) * 128
+    dk = cell["dk"] if cell["dk"] % 128 == 0 or cell["dk"] < 128 else (
+        -(-cell["dk"] // 128) * 128)
     return float(keys.sum()) * cell["kvh"] * (dk + cell["dv"]) * 2
 
 
@@ -182,8 +207,9 @@ def _reference(q, k, v, table, off, cell):
 
     def row(args):
         q, table, off = args
-        kk = jnp.concatenate([k[p * 2 + 1][table] for p in range(parts)], axis=-1)
-        kk = kk[..., :dk].reshape(-1, kvh, dk).astype(jnp.float32)
+        kk = jnp.concatenate([k[p * 2 + 1][table].reshape(-1, kvh, k.shape[-1] // (
+            kvh if k.ndim == 4 else 1)) for p in range(parts)], axis=-1)
+        kk = kk[..., :dk].astype(jnp.float32)
         vv = v[1][table].reshape(-1, kvh, cell["dv"]).astype(jnp.float32)
         qq = q.astype(jnp.float32).reshape(c, kvh, h // kvh, dk)
         s = jnp.einsum("cjgd,sjd->jgcs", qq, kk) * dk ** -0.5
@@ -194,6 +220,24 @@ def _reference(q, k, v, table, off, cell):
                           ).reshape(c, h, cell["dv"])
 
     return jax.lax.map(row, (q, table, off))
+
+
+def _gathered(q, k, v, layer, table, off, cell):
+    """The plain-XLA form ``paged_decode._dense_layers`` runs under
+    ``decode_kernel: gather``: the layer's context gathered through the
+    table, K and V repeated to the query heads, masked attention in the
+    pools' type (one part a key, no window, no sink)."""
+    import jax.numpy as jnp
+
+    from arkflow_tpu.models import common as cm
+
+    b, c, h, _ = q.shape
+    kvh = cell["kvh"]
+    kk, vv = (jnp.repeat(pool[layer][table].reshape(b, -1, kvh, q.shape[-1]),
+                         h // kvh, axis=2) for pool in (k, v))
+    pos = off[:, None] + jnp.arange(c)[None, :]
+    mask = jnp.arange(kk.shape[1])[None, None, None, :] <= pos[:, None, :, None]
+    return cm.attention(q, kk, vv, mask)
 
 
 def _latent_reference(q, c_pool, r_pool, table, off, cell):
@@ -230,6 +274,9 @@ def main() -> int:
     ap.add_argument("--rounds", type=int, default=5)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--check", action="store_true")
+    ap.add_argument("--form", default="kernel", choices=["kernel", "gather"],
+                    help="gather: time the plain-XLA form instead (per-head "
+                         "cells without a window or a sink)")
     ap.add_argument("--interpret", action="store_true")
     ap.add_argument("--root", default="",
                     help="another checkout to import arkflow_tpu from (a parent "
@@ -260,7 +307,8 @@ def main() -> int:
         if held_for != name:  # one cell's pools on the device at a time
             k = v = sink = None
             k, v, sink, lanes, cols = _pools(cell, args.seed, args.interpret,
-                                             hasattr(ra, "_latent_group"))
+                                             hasattr(ra, "_latent_group"),
+                                             hasattr(ra, "narrow_run"))
             held_for = name
         q, table, ctx = _queries(cell, kind, off, rng, lanes, cols, args.interpret)
         latent = "lat" in cell
@@ -269,6 +317,7 @@ def main() -> int:
         for ceiling in ceilings:
             if ceiling:
                 ra._PAGED_GROUP_MAX = ra._PAGED_CHUNK_GROUP_MAX = ceiling
+                ra._NARROW_GROUP_MAX = ceiling
                 if hasattr(ra, "_latent_group"):  # the walk's group, a window's too
                     ra._latent_group = lambda *_, ceiling=ceiling: ceiling
                 else:  # the grid's pages a step
@@ -281,6 +330,8 @@ def main() -> int:
                         q_lat, q_rope, k, v, layer, table, ctx,
                         scale=(128 + cell["rope"]) ** -0.5, window=window,
                         interpret=args.interpret, allowed=(allowed or [None])[0])
+                if args.form == "gather":
+                    return _gathered(q, k, v, layer, table, ctx, cell)
                 return ra.paged_flash_attention.__wrapped__(
                     q, k, v, layer, table, ctx, interpret=args.interpret,
                     window=window, sink=sink)
@@ -294,12 +345,21 @@ def main() -> int:
             operands = (q, k, v, table, ctx, sink)
             line = {"cell": name, "kind": kind, "lanes": b, "chunk": c,
                     "offset": off, "ctx_mean": float(ctx.mean()),
+                    **({"form": "gather"} if args.form == "gather" else {}),
                     **({"group_max": ceiling} if ceiling else {}),
                     "device": device.device_kind}
             if latent and hasattr(ra, "_latent_group"):  # a checkout that walks
                 tile_c = ra.latent_query_tile(c, h, cell["lat"])
                 line.update(tile_c=tile_c, tiles=-(-c // tile_c), group=ra._latent_group(
                     tile_c, h, PAGE, cell["lat"], v.shape[-1], 2, window))
+            elif not latent and k.ndim == 4:  # row-major: the narrow-head walk
+                tile_c = ra.query_tile(c, h)
+                run = ra.narrow_run(cell["kvh"], cell["dk"])
+                hpr = run // cell["dk"] * (h // cell["kvh"])
+                line.update(tile_c=tile_c, tiles=-(-c // tile_c), run=run,
+                            group=ra._narrow_group(
+                                -(-tile_c * hpr // 16) * 16, k.shape[-1] // run,
+                                PAGE, k.shape[-1], 2, window, tile_c))
             elif not latent and hasattr(ra, "per_kv_head"):  # cuts tiles per head
                 tile_c = ra.query_tile(c, h)
                 per_head = ra.per_kv_head(tile_c, h, cell["kvh"])
