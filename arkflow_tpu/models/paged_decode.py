@@ -416,35 +416,33 @@ def _index_select(q_i, w, index_pages, layer, page_table, positions, topk: int,
     """The indexer's choice for each query: the ``topk`` positions of
     largest index score among the keys not after it (``decoder.
     index_scores`` against the row's cached index keys, read through the
-    page table; exact: a full sort of float32 scores, of equal scores the
-    earlier position). Returns (positions [B, S, K] int32, ``ok`` [B, S, K]:
-    False where the context has fewer than K keys and the entry names none)
-    with K = min(topk, context) — or, ``"paged"``, the same choice as a
-    float32 mask over the context [B, S, context] (1: attend) and ``ok``:
-    what the latent kernel reads the pool in place under."""
+    page table; exact: ``jax.lax.top_k`` of float32 scores, of equal scores
+    the earlier position). Returns (positions [B, S, K] int32, ``ok`` [B, S,
+    K]: False where the context has fewer than K keys and the entry names
+    none) with K = min(topk, context) — or, ``"paged"``, the same choice as
+    a float32 mask over the context [B, S, context] (1: attend) and ``ok``:
+    what the latent kernel reads the pool in place under. No sort stands
+    behind that mask: ``ops/topk_select.dsa_topk_select`` finds each
+    query's K-th score and the position its equals are taken up to."""
     b, ctx = page_table.shape[0], page_table.shape[1] * index_pages.shape[2]
-    key_pos = jnp.arange(ctx)
+    k, key_pos = min(topk, ctx), jnp.arange(ctx)
 
     def select(q_i, w, positions):
         if attention_kernel == "paged":
             from arkflow_tpu.ops.ragged_attention import dsa_index_scores
+            from arkflow_tpu.ops.topk_select import dsa_topk_select
 
             scores = dsa_index_scores(q_i, w, index_pages, layer, page_table,
                                       positions[:, 0], interpret=kernel_interpret)
-        else:
-            keys = index_pages[layer, page_table].reshape(b, ctx, -1)
-            scores = index_scores(q_i, w, keys)
+            chosen = dsa_topk_select(scores, positions, k=k,
+                                     interpret=kernel_interpret)
+            # entry j names a key while the query has seen more than j
+            return chosen, jnp.arange(k) <= positions[..., None]
+        keys = index_pages[layer, page_table].reshape(b, ctx, -1)
         seen = key_pos <= positions[..., None]
-        scores = jnp.where(seen, scores, -jnp.inf)
-        top, idx = jax.lax.top_k(scores, min(topk, ctx))
-        ok = top > -jnp.inf
-        if attention_kernel != "paged":
-            return idx.astype(jnp.int32), ok
-        # the last chosen's score and position say who else was chosen: the
-        # sort is stable, so of its equals those before it
-        kth, last = top[..., -1:], idx[..., -1:]
-        chosen = (scores > kth) | ((scores == kth) & (key_pos <= last))
-        return (chosen & seen).astype(jnp.float32), ok
+        scores = jnp.where(seen, index_scores(q_i, w, keys), -jnp.inf)
+        top, idx = jax.lax.top_k(scores, k)
+        return idx.astype(jnp.int32), top > -jnp.inf
 
     return _tiled(select, _QUERY_TILE, q_i, w, positions)
 
@@ -571,8 +569,9 @@ def latent_kernel_probe(params: dict, cfg: DecoderConfig, page_size: int,
     layers at their real widths and seeded inputs: the latent attention
     (decode and a 2-token chunk, rows on non-contiguous pages, one crossing
     a page boundary) against the gather path; with a layer pattern the
-    sliding layer's window attention, the indexer's scores and the
-    attention over a GIVEN selection, each against its plain-XLA form; and
+    sliding layer's window attention, the indexer's scores, the attention
+    over a GIVEN selection and the choice of the largest GIVEN scores, each
+    against its plain-XLA form; and
     the expert product on GIVEN routing against plain XLA over the experts
     routed to.
 
@@ -665,6 +664,22 @@ def latent_kernel_probe(params: dict, cfg: DecoderConfig, page_size: int,
                 _attend_selected(lp, q_nope, q_rope, cp, rp, 0, table,
                                  chosen.astype(jnp.float32), ok, sp, None,
                                  off=off, **kerns[1])))
+        # the choice itself, of half a page's keys a query among scores in
+        # steps of a half (equals across the threshold; the second row has
+        # seen fewer keys than that): the threshold kernel's mask against
+        # the keys ``jax.lax.top_k`` names (keys of their own: the other
+        # probes' inputs stay those the chip has seen)
+        from arkflow_tpu.ops.topk_select import dsa_topk_select
+
+        k = max(1, page_size // 2)
+        for (name, c), key in zip(steps, jax.random.split(jax.random.PRNGKey(4321))):
+            s = jnp.round(2 * jax.random.normal(key, (2, c, ctx), jnp.float32)) / 2
+            positions = off[:, None] + jnp.arange(c)[None, :]
+            seen = jnp.arange(ctx)[None, None, :] <= positions[..., None]
+            top, idx = jax.lax.top_k(jnp.where(seen, s, -jnp.inf), k)
+            named = (jax.nn.one_hot(idx, ctx) * (top > -jnp.inf)[..., None]).max(2)
+            out.append((f"dsa_topk_select_{name}", named, dsa_topk_select(
+                s, positions, k=k, interpret=kernel_interpret)))
     out.append(_expert_probe(params, cfg, keys, rand, kernel_interpret))
     return out
 

@@ -1171,8 +1171,8 @@ def mla_paged_attention(q_lat, q_rope, c_pages, r_pages, layer, page_table,
 # cached token for every query: ``I(t, s) = sum_j w_j(t) relu(q_j(t) . k(s))``
 # over ``Hi`` index heads, against ONE index key a token. The kernel walks the
 # row's page table like ``mla_paged_attention`` (page groups of 128 keys, the
-# pool whole with the layer scalar-prefetched) and writes the scores; the top-k
-# over them is the caller's.
+# pool whole with the layer scalar-prefetched) and writes the scores; who is
+# chosen among them is ``ops/topk_select.dsa_topk_select``'s (a sort's before).
 
 
 def _index_kernel(layer_ref, off_ref, table_ref, q_ref, w_ref, *rest,

@@ -476,6 +476,24 @@ def test_dsa_index_scores_compiles(v5e, rows, chunk):
     assert "tpu_custom_call" in text and "dsa_index_topk_scores" in text
 
 
+@pytest.mark.parametrize("rows,chunk", [(32, 1), (1, 64)], ids=["decode", "tile64"])
+def test_dsa_topk_select_compiles(v5e, rows, chunk):
+    """The indexer's choice of 2,048 among a 12,544-token table's scores: 32
+    rows a block (1.6 MB each of scores, integer keys and mask in VMEM, the
+    first and the last double-buffered), int32 compares, shifts and row
+    counts, a scalar maximum of the block's positions."""
+    from arkflow_tpu.ops.topk_select import dsa_topk_select
+
+    compiled = _compile(
+        lambda s, positions: dsa_topk_select(s, positions, k=2048),
+        v5e, ((rows, chunk, 16 * DOTS_PAGES), jnp.float32), ((rows, chunk), I32))
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "dsa_topk_select" in text
+    assert "sort(" not in text
+    # the call's operands and result as they are: no padded copy at these shapes
+    assert compiled.memory_analysis().temp_size_in_bytes < 1024 * 1024
+
+
 @pytest.mark.parametrize("tokens", [32, 512], ids=["decode", "chunk512"])
 def test_moe_expert_swiglu_compiles_at_the_held_share(v5e, tokens):
     """32 held + 1 shared experts of hidden 5,120 x 1,536, three sliding

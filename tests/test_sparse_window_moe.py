@@ -381,7 +381,8 @@ def test_kernel_probe_covers_the_pattern_s_kernels(params):
                      "swa_latent_attention_decode", "swa_latent_attention_chunk",
                      "dsa_index_scores_decode", "dsa_index_scores_chunk",
                      "dsa_sparse_attention_decode",
-                     "dsa_sparse_attention_chunk", "expert_product"]
+                     "dsa_sparse_attention_chunk", "dsa_topk_select_decode",
+                     "dsa_topk_select_chunk", "expert_product"]
     from arkflow_tpu.tpu.serving_core import logits_parity
 
     for name, want, got in latent_kernel_probe(params, CFG, PAGE,

@@ -410,10 +410,13 @@ def test_window_0_gives_the_present_jaxpr(case):
 #: are 128 lanes wide — and left every per-head golden above as it stood:
 #: the bypass ran the other way. (Recorded at PR 41's parent, and standing
 #: through PR 43: 3a0f9a616b1af93d, c697262a43550287, 4394292e666c84a0,
-#: 158c1079bde200c3.)
+#: 158c1079bde200c3.) The two ``pattern`` hashes RE-RECORDED at PR 47, on
+#: purpose again: an indexed layer's choice is ``dsa_topk_select``'s where it
+#: was a sort's (PR 44's: 5ed8445db227631b, f4474904b5691023); the ``latent``
+#: two stood — the layout without an indexer never reaches that branch.
 BYPASS_GOLDEN = {
     "latent.decode": "ccb2ee94b5d8cfe9", "latent.chunk": "8f160b67834088ca",
-    "pattern.decode": "5ed8445db227631b", "pattern.chunk": "f4474904b5691023"}
+    "pattern.decode": "fc3e4a6979722248", "pattern.chunk": "4ac205702f34d60a"}
 
 
 @pytest.mark.parametrize("case", sorted(BYPASS_GOLDEN))
@@ -456,6 +459,8 @@ def test_latent_programs_do_not_move_with_the_per_head_kernel(case):
     text = _jaxpr_text(jaxpr)
     assert "mla_paged_attention" in text or layout == "pattern"
     assert "paged_flash_attention" not in text
+    # the served choice is the threshold kernel's: no sort but the router's
+    assert ("dsa_topk_select" in text) == (layout == "pattern")
     assert _text_hash(text) == BYPASS_GOLDEN[case], _text_hash(text)
 
 
