@@ -48,6 +48,7 @@ import jax
 import jax.numpy as jnp
 
 import dataclasses
+from typing import NamedTuple, Optional
 
 from arkflow_tpu.models import common as cm
 from dataclasses import dataclass
@@ -954,10 +955,23 @@ def _conv_paged(lp: dict, y, cfg: DecoderConfig, windows, layer, rows, fresh,
         _last_valid(ext, valid, keep).astype(windows.dtype))
 
 
+class _RidingChunk(NamedTuple):
+    """How the chunk behind a fused step's lanes attends (``_dense_layers``):
+    the block's first ``lanes`` tokens are the decode step's, a row each;
+    the rest are one prompt's chunk, one row under its own ``table`` [1, P],
+    ``off`` [1] and ``mask``."""
+
+    lanes: int
+    table: object
+    off: object
+    mask: object
+
+
 def _dense_layers(params: dict, cfg: DecoderConfig, x, k_pages, v_pages,
                   positions, page_idx, offset, token_mask, *, page_table,
                   off, mask, block: bool, kv_sharding, attention_kernel: str,
-                  kernel_interpret: bool, ssm_rows=None, ssm_fresh=None):
+                  kernel_interpret: bool, ssm_rows=None, ssm_fresh=None,
+                  chunk: Optional[_RidingChunk] = None):
     """The layer loop of a per-head K/V (GQA) model over the paged cache,
     with ``_latent_layers``' operands: one scan per run of layers of one
     shape and kind (``layer_runs``) — ONE, over ``layers``, for a model
@@ -990,6 +1004,13 @@ def _dense_layers(params: dict, cfg: DecoderConfig, x, k_pages, v_pages,
     read and write the conv pool's rows under the same three operands
     (``_conv_paged``) and no K/V, the attention runs' layers the ``kv``
     pools alone, each pool indexed by the layer's place among its kind's.
+
+    ``chunk`` (``paged_fused_step``; a ``fusable`` model): the block is ONE
+    row [1, lanes + C] — a decode step's lanes, a token each, then a prompt's
+    chunk — through every weight product, and ``chunk`` says how it attends:
+    the lanes as [lanes, 1] queries under ``page_table`` / ``off`` /
+    ``mask``, the chunk as [1, C] under its own (``_RidingChunk``), both
+    after every token's K/V is written.
     Returns (x, k_pages, v_pages) and, from a routed model, the step's
     counters (``moe_step_stats``)."""
     b, t = positions.shape
@@ -1046,18 +1067,34 @@ def _dense_layers(params: dict, cfg: DecoderConfig, x, k_pages, v_pages,
             kp = _constrain(_write_keys(kp, k, li, pi, po, sp.key_parts),
                             kv_sharding)
             vp = _constrain(_write_rows(vp, v, li, pi, po), kv_sharding)
-            if kernel and not block:
-                attn = _attend_paged(q, kp, vp, li, ring if window else kept,
-                                     off, cfg, kv_sharding, kernel_interpret,
-                                     window, sink)
-            elif window:
-                attn = _attend_ring(q, kp, vp, li, ring, positions, window, sink)
-            else:
+
+            def attend(q, table, off, mask):
+                """Queries ``q`` [rows, positions] over the layer's rows as
+                just written, through ``table`` / ``off`` / ``mask``."""
+                if kernel and not block:
+                    return _attend_paged(q, kp, vp, li, ring if window else table,
+                                         off, cfg, kv_sharding, kernel_interpret,
+                                         window, sink)
+                if window:
+                    return _attend_ring(q, kp, vp, li, ring, positions, window,
+                                        sink)
+                keys, values = k, v
                 if not block:
-                    k = _read_keys(kp, li, kept, sp.dk).astype(x.dtype)
-                    v = _read_rows(vp, li, kept, sp.kv_heads).astype(x.dtype)
-                attn = cm.attention(q, jnp.repeat(k, group, axis=2),
-                                    jnp.repeat(v, group, axis=2), mask, sink=sink)
+                    keys = _read_keys(kp, li, table, sp.dk).astype(x.dtype)
+                    values = _read_rows(vp, li, table, sp.kv_heads).astype(x.dtype)
+                return cm.attention(q, jnp.repeat(keys, group, axis=2),
+                                    jnp.repeat(values, group, axis=2), mask,
+                                    sink=sink)
+
+            if chunk is None:
+                attn = attend(q, kept, off, mask)
+            else:  # the lanes a row each, then the chunk's row
+                lanes = chunk.lanes
+                attn = jnp.concatenate([
+                    attend(q[0, :lanes, None], kept, off, mask).reshape(
+                        1, lanes, cfg.heads, sp.dv),
+                    attend(q[:, lanes:], chunk.table, chunk.off, chunk.mask)],
+                    axis=1)
             out = _scaled(cm.dense(lp["wo"], attn.reshape(b, t, cfg.heads * sp.dv)),
                           cfg.attention_out_multiplier)
             if cfg.hybrid:
@@ -1356,3 +1393,78 @@ def paged_decode_step(params: dict, cfg: DecoderConfig, token_ids, lengths,
     if not return_logits:
         logits = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     return (logits, new_k, new_v, *moe)
+
+
+def fusable(cfg: DecoderConfig) -> bool:
+    """Whether a prompt's chunk can ride a decode step as one row block
+    (``paged_fused_step``): a per-head K/V model whose tokens meet only in
+    attention and whose step takes no operand beside ``paged_decode_step``'s
+    and ``paged_prefill_chunk``'s own — no latent rows, no routed experts (a
+    prompt's router counters) nor the Switch layer (a step's tokens share
+    expert capacity), no state a sequence (a row of state slots), no layer
+    pattern (ring coordinates)."""
+    return not (cfg.latent or cfg.routed or cfg.num_experts > 1
+                or cfg.stateful or cfg.layered)
+
+
+def paged_fused_step(params: dict, cfg: DecoderConfig, token_ids, lengths,
+                     active, page_table, input_ids, chunk_off, chunk_len,
+                     chunk_table, k_pages, v_pages, return_logits: bool = False,
+                     kv_sharding=None, attention_kernel: str = "gather",
+                     kernel_interpret: bool = False):
+    """One decode step over all serving slots AND one chunk of a prompt that
+    is still prefilling, in one pass over the weights: ``paged_decode_step``'s
+    operands (``token_ids`` / ``lengths`` / ``active`` [S], ``page_table``
+    [S, P]) and ``paged_prefill_chunk``'s for one row (``input_ids`` [1, C],
+    ``chunk_off`` / ``chunk_len`` [1], ``chunk_table`` [1, P]). The prompt's
+    own lane is not active, so the two parts write no page in common.
+
+    The S + C tokens run as ONE row block [1, S + C, dim] through every
+    norm and weight product, so a layer's weights are read once for both;
+    attention is the two steps' own two calls (``_dense_layers``'
+    ``chunk``), and the head multiplies S + 1 rows: the lanes and the
+    chunk's last true position. A decode step and a chunk are each bound by
+    the weights' bytes; folded, the chunk's rows cost their attention.
+
+    Returns (logits [S + 1, vocab] — tokens with ``return_logits`` false —,
+    k_pages, v_pages): row S is the prompt's next token where the chunk was
+    its last. Only for a ``fusable`` model."""
+    if not fusable(cfg):
+        from arkflow_tpu.errors import ConfigError
+
+        raise ConfigError(
+            "a chunk rides a decode step only on a per-head K/V model without "
+            "routed experts, a state a sequence or a layer pattern (pools "
+            f"{', '.join(pool.name for pool in cache_spec(cfg))})")
+    s, c = token_ids.shape[0], input_ids.shape[1]
+    page = _page_size(k_pages)
+    ctx = page_table.shape[1] * page
+    chunk_pos = chunk_off[:, None] + jnp.arange(c)[None, :]       # [1, C]
+    chunk_valid = jnp.arange(c)[None, :] < chunk_len[:, None]     # [1, C]
+    lane_page, lane_at = _write_coords(
+        page_table, lengths[:, None], active[:, None], page)      # [S, 1]
+    chunk_page, chunk_at = _write_coords(chunk_table, chunk_pos, chunk_valid,
+                                         page)                    # [1, C]
+
+    def row(lanes, chunk):  # the block's one row: [1, S + C]
+        return jnp.concatenate([lanes.reshape(1, s), chunk], axis=1)
+
+    key_pos = jnp.arange(ctx)
+    # the two steps' masks (the gather form; the kernel's bound is its off)
+    lane_mask = (key_pos[None, :] <= lengths[:, None])[:, None, None, :]
+    chunk_mask = key_pos[None, None, None, :] <= chunk_pos[:, None, :, None]
+    x = _scaled(cm.embedding(params["embed"], row(token_ids, input_ids)),
+                cfg.embedding_multiplier)                         # [1, S + C, D]
+    x, new_k, new_v = _dense_layers(
+        params, cfg, x, k_pages, v_pages, row(lengths, chunk_pos),
+        row(lane_page, chunk_page), row(lane_at, chunk_at),
+        row(active, chunk_valid), page_table=page_table, off=lengths,
+        mask=lane_mask, block=False, kv_sharding=kv_sharding,
+        attention_kernel=attention_kernel, kernel_interpret=kernel_interpret,
+        chunk=_RidingChunk(s, chunk_table, chunk_off, chunk_mask))
+    last = s + jnp.clip(chunk_len - 1, 0, c - 1)                  # [1]
+    logits = lm_logits(params, jnp.concatenate(
+        [x[0, :s], jnp.take(x[0], last, axis=0)]), cfg)           # [S + 1, V]
+    if not return_logits:
+        logits = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    return logits, new_k, new_v

@@ -48,6 +48,7 @@ from arkflow_tpu.errors import ConfigError, StepDeadlineExceeded
 from arkflow_tpu.models.decoder import DecoderConfig
 from arkflow_tpu.models.paged_decode import (
     cache_spec,
+    fusable,
     init_page_pool,
     paged_decode_step,
     paged_prefill,
@@ -114,7 +115,11 @@ class _InFlightStep:
     they were at dispatch; a lane whose request is no longer that one at
     apply drops its token. ``slot`` (chunk / prefill): the slot whose prompt
     the step advances; with ``apply`` set it is the prompt's last step, and
-    the slot joins decode only once its first token is applied."""
+    the slot joins decode only once its first token is applied. A fused
+    step (a decode step that carries a chunk) is both: ``act`` / ``reqs``
+    its lanes, and ``slot`` the chunk's only where the chunk was its
+    prompt's last (else -1: a chunk before the last closes its books at the
+    enqueue and nobody waits for it)."""
 
     kind: str
     out: object
@@ -446,6 +451,16 @@ class GenerationServer:
             and self.speculative_tokens == 0
             and not getattr(cfg, "num_experts", 0)
             and not self._stateful)
+        # where a decode step is due and a slot is prefilling, ONE program
+        # carries the step's lanes and the prompt's next chunk through one
+        # pass over the weights (``paged_fused_step``; ``_step``): a greedy
+        # server that prefills in chunks, on a model whose step takes no
+        # operand beside the two programs' own (``fusable``). Speculation
+        # restructures the decode step, and a sampling server's key would
+        # have to split in the order the two steps ran: both alternate
+        self._fuses = bool(
+            self.prefill_chunk > 0 and self.temperature == 0.0
+            and self.speculative_tokens == 0 and fusable(cfg))
         #: the one enqueued, not-yet-applied step (``_run_ahead``)
         self._pipeline: Optional[_InFlightStep] = None
         #: steps THIS server enqueued while another was still in flight
@@ -495,7 +510,7 @@ class GenerationServer:
         self.m_uploads = {
             kind: reg.counter("arkflow_gen_step_uploads_total", "host arrays "
                               "handed to a step", {"model": name, "kind": kind})
-            for kind in ("decode", "chunk", "prefill", "verify")}
+            for kind in ("decode", "chunk", "prefill", "verify", "fused")}
         # how often running ahead engages: beside the observations of
         # ``gen_device_wait`` (one a step) it is the share of steps that
         # found the device's queue occupied when they arrived
@@ -503,7 +518,14 @@ class GenerationServer:
             kind: reg.counter("arkflow_gen_steps_ahead_total", "steps "
                               "enqueued while another step of this server "
                               "was still in flight", {"model": name, "kind": kind})
-            for kind in ("decode", "chunk", "prefill")}
+            for kind in ("decode", "chunk", "prefill", "fused")}
+        # a prompt's chunks by the step that carried them: a decode step
+        # (one pass over the weights for both) or a step of their own
+        self.m_chunks = {
+            mode: reg.counter("arkflow_gen_chunks_total", "prefill chunks "
+                              "issued, by the step that carried them",
+                              {"model": name, "mode": mode})
+            for mode in ("fused", "alone")}
         self.m_spec_drafted = reg.counter(
             "arkflow_gen_spec_drafted_total", "draft tokens offered for verification")
         self.m_spec_accepted = reg.counter(
@@ -842,14 +864,20 @@ class GenerationServer:
         return kp, vp
 
     def _build_jitted(self) -> None:
-        """(Re)build the four jitted steps, each ``fn(params, packed, kp, vp,
-        *device operands) -> (tokens, kp, vp, *key)``; ``packed`` is the
-        step's ONE host array (``pack_operands``). Under a mesh every step
+        """(Re)build the jitted steps — four, and ``_fused`` on a server
+        that lets a chunk ride a decode step (``_fuses``) —, each ``fn(params,
+        packed, kp, vp, *device operands) -> (tokens, kp, vp, *key)``;
+        ``packed`` is the step's ONE host array (``pack_operands``; a fused
+        step's: the decode step's, then the chunk's). Such a server's decode
+        steps all return ``slots + 1`` tokens — the last the prompt's, where
+        a chunk rode and was its prompt's last — so that a step takes the
+        step before's output whichever kind that was. Under a mesh every step
         carries explicit in/out shardings: the KV pools split over KV heads
         on ``tp``, everything else is replicated — page-table gathers stay
         static-shaped, so the layer scan lowers to plain GSPMD collectives."""
         from arkflow_tpu.models.decoder import select_token
-        from arkflow_tpu.models.paged_decode import paged_prefill_chunk
+        from arkflow_tpu.models.paged_decode import (paged_fused_step,
+                                                     paged_prefill_chunk)
 
         cfg = self.cfg
         kv = self._kv_io_sharding
@@ -863,17 +891,19 @@ class GenerationServer:
         keyed = int(self._key is not None)
         piped = int(self._ahead)
         routed = int(cfg.routed)
+        fuses = int(self._fuses)
 
-        def _pick(logits, keys, *stats):
-            """The step's token array (a routed model's counters appended:
-            one fetch brings both) and the successor of the key in ``keys``,
-            if any: split here, in the order the steps run."""
+        def _pick(logits, keys, *behind):
+            """The step's token array (``behind`` appended: a routed model's
+            counters, so that one fetch brings both; the empty place of a
+            prompt's token, ``_decode``) and the successor of the key in
+            ``keys``, if any: split here, in the order the steps run."""
             sub = None
             if keys:
                 key, sub = jax.random.split(keys[0])
                 keys = (key,)
             nxt = select_token(logits, sub, self.temperature, self.top_k)
-            return (jnp.concatenate([nxt, *stats]) if stats else nxt), *keys
+            return (jnp.concatenate([nxt, *behind]) if behind else nxt), *keys
 
         # params ride every step as an ARGUMENT (bound below): closed over,
         # they would be baked into each executable as constants — a copy of
@@ -891,7 +921,25 @@ class GenerationServer:
             logits, kp, vp, *stats = paged_decode_step(
                 params, cfg, tok, lens, act != 0, table, kp, vp,
                 return_logits=True, kv_sharding=kv, **kern)
-            out, *key = _pick(logits, dev, *stats)
+            # a server that fuses: shaped as a fused step's output, the
+            # place of the prompt's token empty (no chunk rode)
+            no_seed = [jnp.zeros(1, jnp.int32)] if fuses else []
+            out, *key = _pick(logits, dev, *stats, *no_seed)
+            return out, kp, vp, *key
+
+        def _fused(params, packed, kp, vp, *dev):
+            lanes = self.slots * (3 + pages)
+            tok, lens, act, table = unpack_operands(
+                packed[:lanes], self.slots, pages)
+            ids, off, clen, its_table = unpack_operands(packed[lanes:], 1, pages)
+            tok = tok[:, 0]
+            if piped:
+                prev, *dev = dev
+                tok = jnp.where(tok < 0, prev[:self.slots], tok)
+            logits, kp, vp = paged_fused_step(
+                params, cfg, tok, lens, act != 0, table, ids, off, clen,
+                its_table, kp, vp, return_logits=True, kv_sharding=kv, **kern)
+            out, *key = _pick(logits, dev)
             return out, kp, vp, *key
 
         def _prefill(params, packed, kp, vp, *key):
@@ -952,12 +1000,14 @@ class GenerationServer:
         self._prefill = bind(_prefill, keyed, keyed)
         self._chunk = bind(_chunk, routed + keyed, keyed)
         self._verify = bind(_verify, 0, 0)
+        self._fused = bind(_fused, piped, 0) if fuses else None
         #: device stand-ins: no decode step in flight (shaped as one's
         #: output: tokens, then a routed model's counters), a first chunk
         zeros = functools.partial(jnp.zeros, dtype=jnp.int32,
                                   device=self._repl_sharding)
         counted = len(self._extra_counters)
-        self._no_prev = (zeros(self.slots + routed * (3 + counted)),) if piped else ()
+        self._no_prev = (zeros(self.slots + fuses + routed * (3 + counted)),
+                         ) if piped else ()
         self._no_counts = zeros(5 + counted)
 
     def _note_moe(self, kind: str, stats, steps: int = 1) -> None:
@@ -1808,36 +1858,15 @@ class GenerationServer:
         if req is None:
             self._prefill_pos.pop(slot, None)
             return
-        off = self._prefill_pos.get(slot, 0)
-        n = len(req.prompt)
-        # width: the configured chunk, or one bucketed span over the rest
-        # (one-shot; a prefix-cache remainder with chunking off)
-        c = (self.prefill_chunk if kind == "chunk" and self.prefill_chunk
-             else self._bucket(n - off))
-        new_off = min(off + c, n)
-        final = new_off >= n
+        off, c, new_off, final = self._next_span(slot, kind)
         ahead = (self._may_run_ahead((kind, c))
                  and not (final and req.prefill_only))
         if not ahead:
             await self._drain_pipeline()
         with loop_stage("gen_prepare", kind):
-            chunk = req.prompt[off:new_off]
-            ids = np.zeros(c, np.int32)
-            ids[:len(chunk)] = chunk
-            self._slide_window(slot, off, new_off - 1)
-            packed = pack_operands(ids, off, len(chunk), self._table(slot))
-            if kind == "chunk":
-                self._note_walk("chunk", np.asarray([off + c - 1]), len(chunk), c)
-            if self._stateful:
-                valid, masked = self.m_ssm["chunk"]
-                valid.inc(len(chunk))
-                masked.inc(c - len(chunk))
-                if off == 0:  # the chunk's program starts from a zero state
-                    self.m_ssm_resets.inc()
-                    self._state_tenant[slot] = (
-                        req.prompt, req.tokens, self._state_tenant[slot][2] + 1)
-            so_far = () if kind != "chunk" or not self._moe_layers else (
-                self._no_counts if req.chunk_moe is None else req.chunk_moe,)
+            packed, so_far = self._span_operands(slot, req, kind, off, new_off, c)
+        if kind == "chunk":
+            self.m_chunks["alone"].inc()
         seed = (functools.partial(self._apply_prefill, slot, req, kind)
                 if final else None)
         if ahead:
@@ -1854,30 +1883,71 @@ class GenerationServer:
         elif seed(nxt):
             await self._export_and_finish(slot)
 
+    def _next_span(self, slot: int, kind: str = "chunk") -> tuple:
+        """The slot's next prefill step: (its prompt's offset, the step's
+        width — the configured chunk, or one bucketed span over the rest:
+        one-shot, a prefix-cache remainder with chunking off —, the offset
+        after it, whether it is the prompt's last)."""
+        off = self._prefill_pos.get(slot, 0)
+        n = len(self._slot_req[slot].prompt)
+        c = (self.prefill_chunk if kind == "chunk" and self.prefill_chunk
+             else self._bucket(n - off))
+        return off, c, min(off + c, n), off + c >= n
+
+    def _span_operands(self, slot: int, req: _Request, kind: str, off: int,
+                       new_off: int, c: int) -> tuple:
+        """A prefill step's packed operands (ids, offset, tokens present, the
+        slot's table row) and what it takes on the device (a routed prompt's
+        counters so far), its window pages slid and its counters counted."""
+        chunk = req.prompt[off:new_off]
+        ids = np.zeros(c, np.int32)
+        ids[:len(chunk)] = chunk
+        self._slide_window(slot, off, new_off - 1)
+        packed = pack_operands(ids, off, len(chunk), self._table(slot))
+        if kind == "chunk":
+            self._note_walk("chunk", np.asarray([off + c - 1]), len(chunk), c)
+        if self._stateful:
+            valid, masked = self.m_ssm["chunk"]
+            valid.inc(len(chunk))
+            masked.inc(c - len(chunk))
+            if off == 0:  # the chunk's program starts from a zero state
+                self.m_ssm_resets.inc()
+                self._state_tenant[slot] = (
+                    req.prompt, req.tokens, self._state_tenant[slot][2] + 1)
+        so_far = () if kind != "chunk" or not self._moe_layers else (
+            self._no_counts if req.chunk_moe is None else req.chunk_moe,)
+        return packed, so_far
+
     def _apply_chunk(self, slot: int, req: _Request, new_off: int, out) -> None:
         """A prompt's chunk before its last: the slot's next offset; its
         output stays on the device (a routed model's counters ride it)."""
         with loop_stage("gen_apply", "chunk"):
-            req.chunks += 1
-            self._prefill_pos[slot] = new_off
-            req.chunk_moe = out
+            self._advance_prompt(slot, req, new_off, out)
+
+    def _advance_prompt(self, slot: int, req: _Request, new_off: int, out) -> None:
+        req.chunks += 1
+        self._prefill_pos[slot] = new_off
+        req.chunk_moe = out
 
     def _apply_prefill(self, slot: int, req: _Request, kind: str, nxt) -> bool:
         """A prompt's last prefill step, fetched (its token, then a routed
         model's counters): the one fetch seeds the slot for decode. True
         where the request stops here and its pages are to be exported."""
         with loop_stage("gen_apply", kind):
-            req.chunks += 1
-            self._prefill_pos.pop(slot, None)
-            if self._moe_layers:
-                self._note_moe(kind, nxt[1:],
-                               int(nxt[4]) if kind == "chunk" else 1)
-            self._lengths[slot] = len(req.prompt)
-            self._cur_tokens[slot] = int(nxt[0])
-            if req.prefill_only:
-                return True
-            self._handle_token(slot, int(nxt[0]))
-            return False
+            return self._seed_slot(slot, req, kind, nxt)
+
+    def _seed_slot(self, slot: int, req: _Request, kind: str, nxt) -> bool:
+        req.chunks += 1
+        self._prefill_pos.pop(slot, None)
+        if self._moe_layers:
+            self._note_moe(kind, nxt[1:],
+                           int(nxt[4]) if kind == "chunk" else 1)
+        self._lengths[slot] = len(req.prompt)
+        self._cur_tokens[slot] = int(nxt[0])
+        if req.prefill_only:
+            return True
+        self._handle_token(slot, int(nxt[0]))
+        return False
 
     async def _export_and_finish(self, slot: int) -> None:
         """Prefill-only completion: fetch the prompt's KV pages to host,
@@ -2003,7 +2073,17 @@ class GenerationServer:
         """Pick the next step from host state as it will stand once the step
         in flight is applied, for everything the host already knows, and
         hand it to the device before that one is waited for (the step
-        methods; ``_run_ahead``)."""
+        methods; ``_run_ahead``).
+
+        What a step carries: lanes decoding and no slot prefilling, a decode
+        step; a slot prefilling and no lane decoding (the first fill), a
+        chunk of the prompt admitted first; both, ONE fused step — the
+        lanes and that prompt's next chunk through one pass over the weights
+        — where the server fuses (``_fuses``: greedy, chunked prefill, a
+        per-head K/V model without routed experts, a state a sequence or a
+        layer pattern), and else a chunk and a decode step in turn. A
+        prompt that stops after prefill keeps its last chunk's own step, a
+        one-shot prefill and a speculative step their own too."""
         try:
             while not self._closed:
                 admitted = await self._admit_pending()
@@ -2038,8 +2118,15 @@ class GenerationServer:
                     if not admitted:
                         await asyncio.sleep(0.01)  # waiting on pages
                     continue
-                # interleave under contention: alternate one prefill chunk
-                # with one decode step so neither starves the other
+                # a decode step is due and a slot is prefilling: the
+                # prompt's next chunk rides the step, one pass over the
+                # weights for both (``_fuses``)
+                if (self._fuses and active and prefilling
+                        and self._chunk_rides(prefilling[0])):
+                    await self._step(active, prefilling[0])
+                    continue
+                # else interleave under contention: alternate one prefill
+                # chunk with one decode step so neither starves the other
                 if prefilling and (not active or self._turn_prefill):
                     self._turn_prefill = False
                     await self._prefill_step(prefilling[0])
@@ -2113,11 +2200,25 @@ class GenerationServer:
             admitted = True
         return admitted
 
-    async def _step(self, active: list[int]) -> None:
+    def _chunk_rides(self, slot: int) -> bool:
+        """Whether the slot's next chunk may ride a decode step: all but
+        the last chunk of a prompt that stops after prefill (its pages are
+        exported off a drained queue right behind its own step)."""
+        return not (self._slot_req[slot].prefill_only
+                    and self._next_span(slot)[3])
+
+    async def _step(self, active: list[int], riding: int = -1) -> None:
         """One decode step over all slots (inactive lanes masked), enqueued
         behind the step in flight where ``_may_run_ahead`` allows and the
         page pool covers every riding lane, else in lockstep (drain, then
         run to the end: ``_reserve_or_truncate`` owns the truncation).
+
+        ``riding`` >= 0 (a server that fuses): the step carries the next
+        chunk of that slot's prompt too — ONE program, one packed array (the
+        decode step's operands, then the chunk's), ``slots + 1`` tokens back.
+        In flight it is a decode step to the step behind it (its lanes
+        ride) and, where the chunk was its prompt's last, a seeding slot to
+        the loop.
 
         Behind a decode step its lanes ride at lengths + 1 and take their
         tokens from its output ON the device (packed as -1), so the queue
@@ -2126,7 +2227,11 @@ class GenerationServer:
         exhausts is masked out. An EOS it cannot know: such a lane rides
         and its token is dropped at apply (request identity is snapshotted);
         where that is not exact the server never runs ahead (``_ahead``)."""
-        ahead = self._may_run_ahead(("decode",))
+        key, span = ("decode",), None
+        if riding >= 0:
+            span = self._next_span(riding)
+            key = ("fused", span[1])
+        ahead = self._may_run_ahead(key)
         prepared = self._prepare_decode(active, True) if ahead else None
         if prepared is None:  # lockstep (under page pressure: it owns truncation)
             ahead = False
@@ -2143,6 +2248,17 @@ class GenerationServer:
             # land it and let the loop re-evaluate (admission / drain / exit)
             await self._drain_pipeline()
             return
+        req, seeds = None, None
+        if span is not None:
+            off, c, new_off, final = span
+            req = self._slot_req[riding]
+            with annotated("gen_prepare:chunk") as prep:
+                its, _ = self._span_operands(riding, req, "chunk", off, new_off, c)
+                packed = np.concatenate([packed, its])
+            prep_s += prep.dur_s
+            self.m_chunks["fused"].inc()
+            if final:  # the step's last token seeds the slot, in its apply
+                seeds = functools.partial(self._seed_slot, riding, req, "chunk")
         # only a step that is issued observes its preparation, so
         # gen_prepare counts device steps
         observe_stage("gen_prepare", prep_s)
@@ -2152,14 +2268,16 @@ class GenerationServer:
             masked.inc(self.slots - int(act.sum()))
         if ahead:
             reqs = list(self._slot_req)
-            await self._run_ahead(
-                ("decode",), packed, prev,
-                functools.partial(self._apply_decode, act, reqs=reqs),
-                act=act, reqs=reqs)
-            return
-        # off-loop + gated: one device-step of wall time (plus first compile)
-        self._apply_decode(act, await self._run_device_step(
-            ("decode",), packed, *prev))
+            out = await self._run_ahead(
+                key, packed, prev,
+                functools.partial(self._apply_decode, act, reqs=reqs, seeds=seeds),
+                act=act, reqs=reqs, slot=riding if seeds else -1)
+        else:
+            # off-loop + gated: one device-step of wall time (plus first compile)
+            out = await self._run_device_step(key, packed, *prev)
+            self._apply_decode(act, out, seeds=seeds)
+        if req is not None and not seeds:  # the chunk's books close here
+            self._advance_prompt(riding, req, new_off, out)
 
     def _prepare_decode(self, active: list[int], ahead: bool):
         """A decode step's lanes and packed operands from host state as it
@@ -2173,7 +2291,7 @@ class GenerationServer:
             act = np.zeros(self.slots, bool)
             act[active] = True
             lens, cur, prev = self._lengths, self._cur_tokens, self._no_prev
-            if pend is not None and pend.kind == "decode":
+            if pend is not None and pend.act is not None:
                 # lanes of the step in flight: one token further, which
                 # stays on the device; none of them if the pending token
                 # completes the lane's budget
@@ -2246,12 +2364,14 @@ class GenerationServer:
             tiles[product] += -(-width // tile_c)
         return tiles
 
-    def _apply_decode(self, act, nxt, reqs=None) -> None:
+    def _apply_decode(self, act, nxt, reqs=None, seeds=None) -> None:
         """One decode step's fetched tokens (then a routed model's counters)
         onto host state. A lane whose request is no longer the one in
         ``reqs`` (the snapshot of a step that ran ahead) rode one step too
         long: its token is dropped, and the step's routing counters, which
-        counted the lane, are not recorded."""
+        counted the lane, are not recorded. ``seeds``: the step carried its
+        prompt's last chunk, and the token behind the lanes' seeds that slot
+        (``_seed_slot``)."""
         with loop_stage("gen_apply", "decode"):
             self.m_steps.inc()
             lanes = np.flatnonzero(act)
@@ -2265,6 +2385,8 @@ class GenerationServer:
                 self._lengths[s] += 1
                 self._cur_tokens[s] = nxt[s]
                 self._handle_token(s, int(nxt[s]))
+            if seeds is not None:
+                seeds(nxt[self.slots:])
 
     # -- speculative decode -------------------------------------------------
 

@@ -1,0 +1,527 @@
+"""A prefill chunk rides the decode step (tiny shapes, CPU).
+
+Where a decode step is due and a slot is prefilling, a greedy server of a
+dense per-head K/V model that prefills in chunks issues ONE program for both
+(``paged_decode.paged_fused_step``, ``GenerationServer._step(active,
+riding)``): the lanes and the chunk run as one row block through every
+weight product, attention is the two steps' own two calls. What is served
+must be what a chunk and a decode step in turn serve, request by request;
+every other model, a sampling and a speculative server keep alternating and
+build no such program; the step takes one host array; the counter
+``arkflow_gen_chunks_total{mode}`` says what rode.
+"""
+
+from __future__ import annotations
+
+import asyncio
+import importlib.util
+import json
+import os
+import types
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from arkflow_tpu.errors import ConfigError
+from arkflow_tpu.models import get_model
+from arkflow_tpu.models.decoder import FULL, SLIDING
+from arkflow_tpu.models.paged_decode import (fusable, init_page_pool,
+                                             paged_decode_step,
+                                             paged_fused_step,
+                                             paged_prefill_chunk)
+from arkflow_tpu.obs import global_registry
+from arkflow_tpu.tpu.serving import GenerationServer
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+DENSE = dict(vocab_size=128, dim=64, layers=2, heads=4, kv_heads=2, ffn=96,
+             max_seq=64)
+#: a head of 128 lanes: the kernel that walks the page table itself
+WIDE = dict(DENSE, dim=256, heads=2, kv_heads=1)
+LATENT = dict(vocab_size=128, dim=32, layers=3, heads=4, ffn=64, max_seq=128,
+              rope_theta=1e4, norm_eps=1e-6, kv_lora_rank=16,
+              qk_nope_head_dim=8, qk_rope_head_dim=4, v_head_dim=8,
+              rope_interleave=True)
+EXPERTS = dict(n_routed_experts=8, num_experts_per_tok=2, n_shared_experts=1,
+               moe_intermediate_size=16, first_k_dense_replace=1)
+HYBRID = dict(vocab_size=128, dim=64, layers=2, heads=4, kv_heads=2, head_dim=8,
+              ffn=96, mamba_d_ssm=32, mamba_n_heads=2, mamba_d_head=16,
+              mamba_d_state=16, mamba_n_groups=2, mamba_chunk_size=8)
+#: name -> (model, server options): what does NOT let a chunk ride
+ALTERNATES = {
+    "latent": ({**LATENT, **EXPERTS}, {}),  # served with routed experts only
+    "routed": ({**DENSE, **EXPERTS}, {}),
+    "switch": (dict(DENSE, num_experts=4), {}),
+    "hybrid": (HYBRID, {}),
+    "conv": (dict(DENSE, layers=3, layer_types=("conv", FULL, "conv"),
+                  conv_L_cache=3), {}),
+    "layered": (dict(DENSE, layer_types=(SLIDING, FULL), sliding_window=9), {}),
+    "speculative": (DENSE, dict(speculative_tokens=2)),
+    "sampling": (DENSE, dict(temperature=1.2, top_k=8, seed=42)),
+    "one-shot": (DENSE, dict(prefill_chunk=0)),
+}
+
+#: more prompts than slots; at a chunk of 4 prompts of 22, 29 and 17 tokens
+#: have first, middle and last chunks (the last short of a chunk), others are
+#: one chunk or a one-shot prefill; budgets end at different steps
+PROMPTS = [list(range(3, 25)), [9, 4], list(range(40, 55)), [7],
+           list(range(60, 70)), [5, 6, 7], list(range(70, 99)),
+           list(range(10, 27))]
+BUDGETS = [6, 3, 5, 1, 4, 6, 9, 12]
+
+_BUILT: dict = {}
+
+
+def _model(model_kw: dict, tp: int = 0):
+    key = (tuple(sorted((k, str(v)) for k, v in model_kw.items())), tp)
+    if key not in _BUILT:
+        fam = get_model("decoder_lm")
+        cfg = fam.make_config(**model_kw)
+        params, mesh = fam.init(jax.random.PRNGKey(11), cfg), None
+        if tp:
+            from arkflow_tpu.parallel.mesh import (MeshSpec, create_mesh,
+                                                   shard_params)
+
+            mesh = create_mesh(MeshSpec(tp=tp), devices=jax.devices()[:tp])
+            axes = {n: n for n in mesh.axis_names}
+            params = shard_params(params, fam.param_specs(cfg, axes), mesh)
+        _BUILT[key] = (cfg, params, mesh)
+    return _BUILT[key]
+
+
+def _server(model_kw=DENSE, name="decoder_lm", tp=0, slots=3, **kw):
+    if tp and len(jax.devices()) < tp:
+        pytest.skip(f"needs {tp} virtual devices")
+    cfg, params, mesh = _model(model_kw, tp)
+    kw.setdefault("prefill_chunk", 4)
+    return GenerationServer(params, cfg, slots=slots, page_size=4, max_seq=48,
+                            eos_id=-1, mesh=mesh, name=name, **kw)
+
+
+def _serve(server, prompts=PROMPTS, budgets=BUDGETS):
+    """Every prompt at once. Returns the outputs and the steps the server
+    made, in order: (kind, a step was in flight when it was issued)."""
+    steps = []
+    run_ahead, run_lockstep = server._run_ahead, server._run_device_step
+
+    def ahead(key, *a, **kw):
+        steps.append((key[0], server._pipeline is not None))
+        return run_ahead(key, *a, **kw)
+
+    def lockstep(key, *a, **kw):
+        assert server._pipeline is None  # lockstep runs on a drained queue
+        steps.append((key[0], False))
+        return run_lockstep(key, *a, **kw)
+
+    server._run_ahead, server._run_device_step = ahead, lockstep
+
+    async def go():
+        free0 = len(server._free_pages)
+        outs = await asyncio.gather(*[
+            server.generate(p, n) for p, n in zip(prompts, budgets)])
+        await server.close()
+        assert server._pipeline is None and server._gen_inflight == 0
+        assert len(server._free_pages) == free0 and not server._prefill_pos
+        return outs
+
+    return asyncio.run(asyncio.wait_for(go(), timeout=240)), steps
+
+
+def _counter(metric: str, name: str, **labels) -> float:
+    return global_registry().counter(
+        metric, labels={"model": name, **labels}).value
+
+
+def _chunks(name: str) -> dict:
+    return {mode: _counter("arkflow_gen_chunks_total", name, mode=mode)
+            for mode in ("fused", "alone")}
+
+
+# -- (b) the program: one pass is the two steps -----------------------------------------
+
+
+@pytest.mark.parametrize("clen", [8, 5], ids=["whole-chunk", "last-chunk"])
+@pytest.mark.parametrize("model_kw,kern", [
+    (DENSE, "gather"), (DENSE, "paged"), (WIDE, "paged")],
+    ids=["gather", "narrow-head-kernel", "wide-head-kernel"])
+def test_fused_step_is_a_decode_step_then_a_chunk(model_kw, kern, clen):
+    """``paged_fused_step``'s logits and pools equal ``paged_decode_step``
+    then ``paged_prefill_chunk`` on the same inputs — both attention forms,
+    both walks of the kernel, a whole chunk and a prompt's short last one,
+    an idle lane (the prefilling slot's own) among the lanes."""
+    cfg, params, _ = _model(model_kw)
+    lanes, cols, page, c = 3, 6, 4, 8
+    rng = np.random.RandomState(clen)
+    kp, vp = jax.tree_util.tree_map(
+        lambda a: jnp.asarray(rng.randn(*a.shape), a.dtype),
+        init_page_pool(cfg, 1 + lanes * cols, page, slots=lanes))
+    table = jnp.asarray(1 + np.arange(lanes * cols).reshape(lanes, cols), jnp.int32)
+    tok = jnp.asarray([5, 9, 0], jnp.int32)
+    lens = jnp.asarray([7, 13, 0], jnp.int32)
+    act = jnp.asarray([True, True, False])
+    ids = jnp.asarray(rng.randint(0, 128, (1, c)), jnp.int32)
+    off, n = jnp.asarray([8], jnp.int32), jnp.asarray([clen], jnp.int32)
+    kw = dict(attention_kernel=kern, kernel_interpret=True)
+    dec, k1, v1 = paged_decode_step(params, cfg, tok, lens, act, table, kp, vp,
+                                    return_logits=True, **kw)
+    chunk, k2, v2 = paged_prefill_chunk(params, cfg, ids, off, n, table[2:],
+                                        k1, v1, **kw)
+    got, kf, vf = paged_fused_step(params, cfg, tok, lens, act, table, ids, off,
+                                   n, table[2:], kp, vp, return_logits=True, **kw)
+    assert got.shape == (lanes + 1, cfg.vocab_size)
+    want = np.concatenate([np.asarray(dec), np.asarray(chunk)])
+    np.testing.assert_allclose(np.asarray(got), want, atol=2e-5, rtol=1e-5)
+    assert (np.asarray(got).argmax(-1) == want.argmax(-1)).all()
+    # every page but the scratch page, which padding and idle lanes share
+    for a, b in ((k2, kf), (v2, vf)):
+        np.testing.assert_allclose(np.asarray(b[:, 1:], np.float32),
+                                   np.asarray(a[:, 1:], np.float32),
+                                   atol=2e-5, rtol=1e-5)
+    picked = paged_fused_step(params, cfg, tok, lens, act, table, ids, off, n,
+                              table[2:], kp, vp, **kw)[0]
+    assert picked.dtype == jnp.int32
+    np.testing.assert_array_equal(np.asarray(picked), want.argmax(-1))
+
+
+@pytest.mark.parametrize("case", [k for k, v in ALTERNATES.items() if v[0] is not DENSE])
+def test_the_program_refuses_what_needs_more_operands(case):
+    """``fusable`` is what the code can see in the configuration; the fused
+    program refuses the others by name."""
+    cfg = get_model("decoder_lm").make_config(**ALTERNATES[case][0])
+    assert not fusable(cfg) and fusable(_model(DENSE)[0])
+    with pytest.raises(ConfigError, match="rides a decode step only"):
+        paged_fused_step(None, cfg, *[None] * 10)
+
+
+# -- (a) what is served is what alternation serves ----------------------------------------
+
+
+@pytest.mark.parametrize("depth", [1, 2], ids=["lockstep", "ahead"])
+@pytest.mark.parametrize("case", ["gather", "paged", "tp2"])
+def test_a_fusing_server_serves_what_an_alternating_one_serves(case, depth):
+    """Greedy tokens of every request equal those of the same server made
+    to alternate, over prompts whose first, middle and last chunks ride —
+    in lockstep and one step ahead, through the gather form and the
+    interpreted kernel, and over a 2-device ``tp`` mesh."""
+    kw = dict(dispatch_depth=depth, tp=2 if case == "tp2" else 0)
+    if case == "paged":
+        kw.update(decode_kernel="paged", kernel_interpret=True)
+    alternating = _server(**kw)
+    assert alternating._fuses and alternating._fused is not None
+    alternating._fuses = False
+    want, ref_steps = _serve(alternating)
+    assert not any(kind == "fused" for kind, _ in ref_steps)
+    assert [len(o) for o in want] == BUDGETS
+
+    server = _server(**kw)
+    rode, step = set(), server._step
+
+    def spy(active, riding=-1):
+        if riding >= 0:
+            off, _, _, final = server._next_span(riding)
+            rode.add("first" if off == 0 else "last" if final else "middle")
+        return step(active, riding)
+
+    server._step = spy
+    got, steps = _serve(server)
+    assert got == want
+    assert rode == {"first", "middle", "last"}
+    kinds = [kind for kind, _ in steps]
+    assert kinds.count("fused") > 8
+    # fewer device steps: a chunk that rides is no step of its own
+    assert len(steps) <= len(ref_steps) - kinds.count("fused") + 2
+    assert (depth == 2) == any(ahead for kind, ahead in steps if kind == "fused")
+    assert server._fused.jitted._cache_size() == 1
+    assert server._decode.jitted._cache_size() == 1
+
+
+def test_every_seam_of_a_fused_step_is_crossed_ahead():
+    """One step ahead: a fused step behind a fused step and behind a decode
+    step, a decode step behind a fused step (the lanes take their tokens on
+    the device from either kind's output), a step behind a fused step that
+    carried its prompt's last chunk (that slot joins decode one step later,
+    from the step's last token), and a lane masked out of the step behind
+    the one that exhausts its budget."""
+    server = _server()
+    seen, real_fused, real_decode = [], server._fused, server._decode
+
+    def spy(kind, real):
+        def call(packed, *a):
+            tok, _, act = (np.asarray(packed)[i * 3:(i + 1) * 3] for i in range(3))
+            pend = server._pipeline
+            seen.append((kind, None if pend is None else (pend.kind, pend.seeding),
+                         bool((tok[act != 0] == -1).any()), bool(act.any())))
+            return real(packed, *a)
+        return call
+
+    server._fused, server._decode = spy("fused", real_fused), spy("decode", real_decode)
+    outs, _ = _serve(server)
+    assert [len(o) for o in outs] == BUDGETS
+    behind = {(kind, pend[0]) for kind, pend, _, _ in seen if pend}
+    assert {("fused", "fused"), ("fused", "decode"), ("decode", "fused")} <= behind
+    # a step issued while a fused step that seeds a slot is in flight
+    assert any(pend and pend[0] == "fused" and pend[1] >= 0 for _, pend, _, _ in seen)
+    # lanes ride a fused step, and a fused step's lanes ride on
+    assert any(rides for kind, pend, rides, _ in seen if kind == "fused")
+    assert any(rides for kind, pend, rides, _ in seen
+               if kind == "decode" and pend and pend[0] == "fused")
+    assert all(any_lane for *_, any_lane in seen)
+
+
+def test_a_prompt_that_stops_after_prefill_keeps_its_last_chunks_own_step():
+    """``prefill_export`` beside a decoding request: the prompt's chunks
+    before its last ride the decode steps, its last runs alone against a
+    drained queue (its pages are fetched right behind it), and the export
+    equals an alternating server's."""
+    def export(fuses):
+        server = _server()
+        server._fuses = fuses
+        at_export, real = [], server._export_and_finish
+
+        async def spy(slot):
+            at_export.append(server._pipeline)
+            await real(slot)
+
+        server._export_and_finish = spy
+        steps, step, alone = [], server._step, server._prefill_step
+
+        def riding_spy(active, riding=-1):
+            steps.append("fused" if riding >= 0 else "decode")
+            return step(active, riding)
+
+        def alone_spy(slot, kind="chunk"):
+            steps.append(kind)
+            return alone(slot, kind)
+
+        server._step, server._prefill_step = riding_spy, alone_spy
+
+        async def go():
+            long = asyncio.ensure_future(server.generate(list(range(3, 25)), 24))
+            while not server._tokens_emitted:
+                await asyncio.sleep(0.001)
+            out = await server.prefill_export(list(range(30, 52)), 4)
+            return await long, out
+
+        tokens, out = asyncio.run(asyncio.wait_for(go(), timeout=120))
+        asyncio.run(server.close())
+        assert at_export == [None]
+        return tokens, out, steps
+
+    tokens1, out1, steps1 = export(False)
+    tokens2, out2, steps2 = export(True)
+    assert "fused" not in steps1 and steps2.count("fused") == 5
+    # 22 tokens at a chunk of 4: five chunks rode, the sixth ran alone
+    assert steps2.count("chunk") == steps1.count("chunk") - 5
+    assert tokens1 == tokens2 and out1["first_token"] == out2["first_token"]
+    for a, b in zip(out1["k"] + out1["v"], out2["k"] + out2["v"]):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+# -- (c) who does not fuse ---------------------------------------------------------------
+
+
+@pytest.mark.parametrize("case", sorted(ALTERNATES))
+def test_everything_else_still_alternates(case):
+    """A latent, routed, Switch, hybrid, conv or layered model, a sampling
+    and a speculative server, and one that prefills in one shot: no fused
+    program is built, no step carries a chunk, and the chunks count as
+    issued alone."""
+    model_kw, server_kw = ALTERNATES[case]
+    name = f"alternates-{case}"
+    server_kw = {"prefill_chunk": 8, **server_kw}
+    server = _server(model_kw, name=name, slots=2, **server_kw)
+    assert not server._fuses and server._fused is None
+    outs, steps = _serve(server, PROMPTS[:5], BUDGETS[:5])
+    assert [len(o) for o in outs] == BUDGETS[:5]
+    kinds = {kind for kind, _ in steps}
+    assert "fused" not in kinds and kinds & {"decode", "verify"}
+    chunks = _chunks(name)
+    assert chunks["fused"] == 0
+    assert chunks["alone"] == sum(kind == "chunk" for kind, _ in steps)
+    assert (chunks["alone"] > 0) == bool(server.prefill_chunk)
+
+
+# -- (d) one host array a fused step, and the counter counts what rode ---------------------
+
+
+@pytest.mark.parametrize("depth", [1, 2], ids=["lockstep", "ahead"])
+def test_a_fused_step_takes_one_host_array_and_counts_its_chunk(depth):
+    """``arkflow_gen_step_uploads_total{kind="fused"}`` moves by one a fused
+    step (the decode step's operands and the chunk's go up as ONE array;
+    the step before's tokens stay on the device), the array is the two
+    steps' packed arrays end to end, and ``arkflow_gen_chunks_total{mode}``
+    counts every chunk once, by the step that carried it."""
+    name = f"fused-uploads-{depth}"
+    server = _server(name=name, dispatch_depth=depth)
+    fused_steps, host_arrays, sizes = [0], [], set()
+    real = server._fused
+
+    def counted(*args):
+        fused_steps[0] += 1
+        host_arrays.append(sum(isinstance(a, np.ndarray) for a in args))
+        assert all(isinstance(a, (np.ndarray, jax.Array)) for a in args)
+        sizes.add(args[0].shape)
+        return real(*args)
+
+    server._fused = counted
+    alone, real_chunk = [0], server._chunk
+
+    def counted_chunk(*args):
+        alone[0] += 1
+        return real_chunk(*args)
+
+    server._chunk = counted_chunk
+    outs, steps = _serve(server)
+    assert [len(o) for o in outs] == BUDGETS
+    assert fused_steps[0] > 8 and set(host_arrays) == {1}
+    assert _counter("arkflow_gen_step_uploads_total", name, kind="fused") \
+        == fused_steps[0]
+    pages = server.pages_per_slot
+    assert sizes == {(3 * (3 + pages) + 4 + 2 + pages,)}
+    assert _chunks(name) == {"fused": fused_steps[0], "alone": alone[0]}
+    # every chunk of every prompt longer than a chunk, once
+    assert fused_steps[0] + alone[0] == sum(
+        -(-len(p) // 4) for p in PROMPTS if len(p) > 4)
+    # the loop's own stages are observed once a device step, fused or not
+    if depth == 2:
+        assert _counter("arkflow_gen_steps_ahead_total", name, kind="fused") > 0
+
+
+# -- the benchmark's three readers -------------------------------------------------------------
+
+
+def _reader(metric: str):
+    spec = importlib.util.spec_from_file_location(
+        metric, os.path.join(ROOT, "benchmark", "metrics", metric + ".py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+class _View:
+    """``run.py::View``'s calls over fixed window deltas."""
+
+    def __init__(self, fused=0.0, alone=0.0, modules=None, busy=(), prompts=(),
+                 chips=1):
+        self._chunks = {"fused": fused, "alone": alone}
+        self.trace = None if modules is None else {"modules": modules}
+        # Mistral-7B's widths at six layers; a chunk and a budget of 128
+        self.sizes = dict(hidden_size=4096, num_hidden_layers=6,
+                          num_attention_heads=32, num_key_value_heads=8,
+                          intermediate_size=14336, vocab_size=32768)
+        self.proc_cfg = dict(prefill_chunk=128, max_new_tokens=128)
+        self.peaks, self.chips = dict(hbm_bytes_per_s=819e9), chips
+        self.run = types.SimpleNamespace(
+            pool=types.SimpleNamespace(tokens=np.asarray(prompts, np.int64)))
+        self._busy = list(busy)
+
+    def gauge(self, name):
+        assert name == "arkflow_gen_slots_busy"
+        return self._busy
+
+    def counter(self, name, **labels):
+        assert name == "arkflow_gen_chunks_total"
+        return (self._chunks[labels["mode"]] if labels
+                else sum(self._chunks.values()))
+
+
+CELLS = ["mistral_l6.summarize_backlog", "mistral_tp4.summarize_backlog"]
+
+
+@pytest.mark.parametrize("fused,alone,want", [
+    (90.0, 10.0, 90.0), (3400.0, 25.0, 3400 / 34.25), (0.0, 80.0, 0.0),
+    (0.0, 0.0, None)],
+    ids=["most-ride", "a-window", "all-alone", "parent-or-no-chunks"])
+def test_gen_chunks_fused_pct_reader(fused, alone, want):
+    """``benchmark/metrics/gen_chunks_fused_pct.py``: the ``mode="fused"``
+    share of the chunks issued in the window, in percent; nothing on a
+    program without the counter (the parent) or a window without chunks."""
+    got = _reader("gen_chunks_fused_pct").read(_View(fused, alone))
+    assert got == want if want is None else got == pytest.approx(want)
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    (entry,) = [m for m in bench["per_layer"] if m["name"] == "gen_chunks_fused_pct"]
+    assert entry == {
+        "name": "gen_chunks_fused_pct", "unit": "%", "better": "higher",
+        "source": "program_counter", "layer": "scheduler, generate",
+        "moves": "tokens_per_s", "workloads": CELLS}
+
+
+@pytest.mark.parametrize("modules,want", [
+    ({"jit__fused(123)": [0.006, 0.0058, 0.0062], "jit__decode(7)": [0.0048]}, 6.0),
+    ({"jit__decode(7)": [0.0048], "jit__chunk(9)": [0.0049]}, None),
+    (None, None)], ids=["fused-steps", "the-parent", "no-trace"])
+def test_fused_step_ms_reader(modules, want):
+    """``benchmark/metrics/fused_step_ms.py``: median device time of the
+    ``jit__fused`` program's executions; nothing where there is none (the
+    parent, an untraced run) — and the accepted readers of ``jit__decode``
+    / ``jit__chunk`` do not match its name."""
+    got = _reader("fused_step_ms").read(_View(modules=modules))
+    assert got == want if want is None else got == pytest.approx(want)
+    if modules and want:
+        view = _View(modules=modules)
+        assert _reader("decode_step_ms").read(view) == pytest.approx(4.8)
+        assert _reader("prefill_chunk_ms").read(view) is None
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    (entry,) = [m for m in bench["per_layer"] if m["name"] == "fused_step_ms"]
+    assert entry == {
+        "name": "fused_step_ms", "unit": "ms", "better": "lower",
+        "source": "device_trace", "layer": "device step, generate",
+        "moves": "tokens_per_s", "workloads": CELLS}
+
+
+@pytest.mark.parametrize("prompts,want", [
+    ([300], (128 + 256 + 300) / 3), ([64, 128], 0.0), ([129, 640, 100],
+     (128 + 129 + 128 * 10 + 640) / 7)],
+    ids=["three-chunks", "one-shot-prompts", "a-mix"])
+def test_a_chunk_attends_over_its_prompt_so_far(prompts, want):
+    """``fused_hbm_pct``'s chunk term: chunk i of a prompt of n tokens reads
+    min(i x C, n) tokens; a prompt of one chunk or less is a one-shot
+    prefill and has no chunk."""
+    got = _reader("fused_hbm_pct").chunk_kv_tokens(prompts, 128)
+    assert got == pytest.approx(want)
+
+
+@pytest.mark.parametrize("modules,busy,chips,want", [
+    ({"jit__fused(1)": [0.0054, 0.0055, 0.0053], "jit__decode(7)": [0.0048]},
+     [16.0, 16.0], 1, "read"),
+    ({"jit__fused(1)": [0.0054], "jit__decode(7)": [0.0048]}, [16.0], 4, "read"),
+    ({"jit__decode(7)": [0.0048], "jit__chunk(9)": [0.0049]}, [16.0], 1, None),
+    ({"jit__fused(1)": [0.0054]}, [], 1, None),
+    (None, [16.0], 1, None)],
+    ids=["fused-steps", "four-chips", "the-parent", "no-gauge", "no-trace"])
+def test_fused_hbm_pct_reader(modules, busy, chips, want):
+    """``benchmark/metrics/fused_hbm_pct.py``: ``decode_hbm_pct``'s bytes —
+    the weights ONCE, the K/V of one lane fewer (the slot whose prompt rides
+    does not decode) — plus the K/V the chunk attends over, over the peak
+    and the ``jit__fused`` program's median time, not clamped; nothing on
+    the parent (no such module), without the gauge or without a trace."""
+    prompts = [512] * 8
+    view = _View(modules=modules, busy=busy, prompts=prompts, chips=chips)
+    got = _reader("fused_hbm_pct").read(view)
+    if want is None:
+        assert got is None
+    else:
+        from benchmark.lib.costs import decode_step_bytes
+
+        # 15 lanes at 512 + 64 tokens; chunks end at 128, 256, 384, 512
+        nbytes = decode_step_bytes(
+            dim=4096, layers=6, heads=32, kv_heads=8, ffn=14336, vocab=32768,
+            kv_tokens=15 * 576 + 320, chips=chips)
+        assert got == pytest.approx(100 * nbytes / 819e9 / 0.0054)
+        # at one chip: the weights' 2.9 GB and 0.22 GB of K/V in 5.4 ms
+        assert (60 < got < 80) if chips == 1 else (15 < got < 20)
+        # the pure decode steps' share is read from the decode program alone
+        pure = _reader("decode_hbm_pct").read(view)
+        assert pure > got * 0.0054 / 0.0048
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    (entry,) = [m for m in bench["per_layer"] if m["name"] == "fused_hbm_pct"]
+    assert entry == {
+        "name": "fused_hbm_pct", "unit": "%", "better": "higher",
+        "source": "device_trace", "layer": "kernels",
+        "moves": "tokens_per_s", "workloads": CELLS}
+    assert bench["per_layer"][-1] == entry

@@ -279,14 +279,16 @@ MISTRAL_SLOTS, MISTRAL_PAGES, MISTRAL_TABLE = 16, 2177, 136
 POOL_TEMP_LIMIT = 64 * 1024 * 1024
 
 
-def _dense_steps(devices, layers: int, tp: int):
-    """The dense ``_decode`` (16 lanes) and ``_chunk`` (1 x 128) programs as
-    the server jits them — pools donated, under ``tp`` the server's in / out
+def _dense_steps(devices, layers: int, tp: int, fused: bool = False):
+    """The dense ``_decode`` (16 lanes) and ``_chunk`` (1 x 128) programs —
+    or, ``fused``, the ONE program that carries both (``_fused``) — as the
+    server jits them — pools donated, under ``tp`` the server's in / out
     shardings — compiled for the described chip(s) at Mistral-7B widths."""
     import numpy as np
 
     from arkflow_tpu.models import decoder as dec
     from arkflow_tpu.models.paged_decode import (init_page_pool, paged_decode_step,
+                                                 paged_fused_step,
                                                  paged_prefill_chunk)
     from arkflow_tpu.parallel.mesh import kv_pool_sharding
 
@@ -318,6 +320,10 @@ def _dense_steps(devices, layers: int, tp: int):
     def chunk(p, ids, off, clen, table, kp, vp):
         return paged_prefill_chunk(p, cfg, ids, off, clen, table, kp, vp, **kern)
 
+    def both(p, tok, lens, act, table, ids, off, clen, its_table, kp, vp):
+        return paged_fused_step(p, cfg, tok, lens, act, table, ids, off, clen,
+                                its_table, kp, vp, return_logits=True, **kern)
+
     def compiled(fn, *operands):
         def struct(a, sharding):
             return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding)
@@ -331,11 +337,11 @@ def _dense_steps(devices, layers: int, tp: int):
             struct(pool, pools), struct(pool, pools)).compile()
 
     s = MISTRAL_SLOTS
-    return (
-        compiled(decode, ((s,), I32), ((s,), I32), ((s,), jnp.bool_),
-                 ((s, MISTRAL_TABLE), I32)),
-        compiled(chunk, ((1, 128), I32), ((1,), I32), ((1,), I32),
-                 ((1, MISTRAL_TABLE), I32)))
+    lanes = (((s,), I32), ((s,), I32), ((s,), jnp.bool_), ((s, MISTRAL_TABLE), I32))
+    prompt = (((1, 128), I32), ((1,), I32), ((1,), I32), ((1, MISTRAL_TABLE), I32))
+    if fused:
+        return (compiled(both, *lanes, *prompt),)
+    return compiled(decode, *lanes), compiled(chunk, *prompt)
 
 
 def _collectives(text: str) -> dict:
@@ -352,14 +358,16 @@ def _collectives(text: str) -> dict:
     return found
 
 
+@pytest.mark.parametrize("fused", [False, True], ids=["in-turn", "fused"])
 @pytest.mark.parametrize("layers,tp", [(6, 1), (16, 4)], ids=["l6", "tp4"])
-def test_dense_steps_carry_the_pools_whole(v5e, layers, tp):
+def test_dense_steps_carry_the_pools_whole(v5e, layers, tp, fused):
     """No layer's pool slice is copied out, scattered into and written back,
     and the pools are not copied once a step: the programs need no temporary
     the size of a slice. Under tp the collectives are the layer's own (two
     all-gathers, three all-reduces in the loop body's text) and none moves
-    a pool."""
-    for step in _dense_steps(v5e, layers, tp):
+    a pool. So too the ONE program of a decode step that carries a chunk
+    (144 rows through the weights, the kernel called twice a layer)."""
+    for step in _dense_steps(v5e, layers, tp, fused):
         text = step.as_text()
         assert "tpu_custom_call" in text
         assert step.memory_analysis().temp_size_in_bytes < POOL_TEMP_LIMIT

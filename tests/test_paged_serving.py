@@ -229,13 +229,19 @@ def test_chunked_prefill_goes_to_the_slot_admitted_first():
     async def go():
         server = GenerationServer(params, cfg, slots=2, page_size=4,
                                   max_seq=64, prefill_chunk=8)
-        step, order = server._prefill_step, []
+        step, decode, order = server._prefill_step, server._step, []
 
         async def recording(slot, kind="chunk"):
             order.append(prompts.index(server._slot_req[slot].prompt))
             await step(slot, kind)
 
-        server._prefill_step = recording
+        async def riding_too(active, riding=-1):
+            # a chunk that rides a decode step is the same prompt's turn
+            if riding >= 0:
+                order.append(prompts.index(server._slot_req[riding].prompt))
+            await decode(active, riding)
+
+        server._prefill_step, server._step = recording, riding_too
         outs = await asyncio.gather(*[
             server.generate(p, n) for p, n in zip(prompts, (2, 4, 4))])
         await server.close()

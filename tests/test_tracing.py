@@ -759,7 +759,7 @@ GEN_SERVERS = pytest.mark.parametrize("server_kw", [
     dict(prefill_chunk=4, dispatch_depth=1),  # lockstep
 ], ids=["chunked", "one_shot", "depth2", "speculative", "lockstep"])
 HOP_STAGES = ("gen_dispatch", "gen_ready_wait", "gen_fetch")
-STEP_KINDS = {"decode", "chunk", "prefill", "verify"}
+STEP_KINDS = {"decode", "chunk", "prefill", "verify", "fused"}
 
 
 def _stage_hists() -> dict:
@@ -792,10 +792,14 @@ def _run_counted(tag: str, server_kw: dict):
     _fresh_global()
     name = tag + "-".join(f"{k}{v}" for k, v in server_kw.items())
     server = _tiny_generation_server(name, **server_kw)
-    made = {"steps": 0, "unfetched": 0, "unwaited": 0}
-    for step in ("_decode", "_chunk", "_prefill", "_verify"):
-        def counted(*a, _fn=getattr(server, step), **kw):
+    made = {"steps": 0, "unfetched": 0, "unwaited": 0, "fused": 0}
+    for step in ("_decode", "_chunk", "_prefill", "_verify", "_fused"):
+        if getattr(server, step) is None:  # no chunk rides this server's steps
+            continue
+
+        def counted(*a, _fn=getattr(server, step), _step=step, **kw):
             made["steps"] += 1
+            made["fused"] += _step == "_fused"
             return _fn(*a, **kw)
 
         setattr(server, step, counted)
@@ -857,7 +861,9 @@ def test_hop_stages_count_device_steps_by_kind(server_kw):
     assert count["gen_fetch"] == made["steps"] - made["unfetched"]
     assert made["unwaited"] <= made["unfetched"]
     if server_kw["prefill_chunk"]:
-        assert made["unfetched"] > 0  # the 22-token prompt: chunks before its last
+        # the 22-token prompt: chunks before its last, alone (left on the
+        # device) or riding a decode step (fetched with its lanes' tokens)
+        assert made["unfetched"] + made["fused"] > 0
     if server_kw.get("dispatch_depth") == 1 or "speculative_tokens" in server_kw:
         assert made["unwaited"] == 0  # lockstep waits for every step
     for stage, kind in added:
