@@ -32,6 +32,10 @@ FULL, SLIDING = "full_attention", "sliding_attention"
 #: attention weights, no keys or values, a per-slot window of its last
 #: ``conv_L_cache - 1`` gated inputs instead
 CONV = "conv"
+#: a layer whose mixer is a Gated DeltaNet (``gated_delta_net``): linear
+#: attention by the gated delta rule — no keys or values by token, a float32
+#: matrix state a value head and a short conv window a SEQUENCE instead
+LINEAR = "linear_attention"
 
 
 @dataclass(frozen=True)
@@ -100,6 +104,21 @@ class GqaSpec:
         return self.dk if self.key_parts == 1 else self.key_parts * 128
 
     @property
+    def split_heads(self) -> bool:
+        """True where the page pools hold each K/V head in a LAYER of its
+        own, [kv_heads * layers, pages, page, 1, width] (head ``j`` of layer
+        ``l`` at ``j * layers + l``), and the attention kernel is called a
+        K/V head at a time: keys or values of more than one whole 128-lane
+        run on 2 to 7 K/V heads. A pool [.., 2 or 4 heads, 256] is re-laid
+        WHOLE for every call of the kernel on a chip (the call views a page
+        as [page * heads, width] rows, and XLA pads a page's (heads, width)
+        tile to 8 rows: two pool-sized temporaries a call, PERF.md PR 49);
+        pools [.., 1, 256] and [.., 8, 256] are viewed in place. A layer
+        that keeps every key only (a window pool's ring has the one layout)."""
+        return (self.key_parts == 1 and max(self.dk, self.dv) > 128
+                and 1 < self.kv_heads < 8 and not self.window)
+
+    @property
     def row_major(self) -> bool:
         """True where the page pools hold a token's heads side by side,
         [.., kv_heads * width], and not on an axis of their own: a head
@@ -166,7 +185,8 @@ class DecoderConfig:
     # of the same width see every token. No capacity: nothing is dropped at
     # any load. Latent attention and routed experts are served together
     # only (the one published layout that combines them), after at least
-    # one leading dense layer.
+    # one leading dense layer; a per-head K/V model may route EVERY layer
+    # (``first_k_dense_replace`` 0: no dense stack at all).
     n_routed_experts: int = 0
     num_experts_per_tok: int = 0
     n_shared_experts: int = 0
@@ -174,7 +194,11 @@ class DecoderConfig:
     first_k_dense_replace: int = 0
     routed_scaling_factor: float = 1.0
     #: only what the routing above states is implemented; other values of
-    #: these five raise ConfigError
+    #: these five raise ConfigError. ``scoring_func`` "softmax" with
+    #: ``topk_method`` "greedy" is the other router served: scores
+    #: ``softmax(x W_r)`` over all the experts, the largest chosen with NO
+    #: selection bias (the layer has no ``router_bias`` leaf), weighed by
+    #: their scores over the chosen ones' sum
     norm_topk_prob: bool = True
     scoring_func: str = "sigmoid"
     topk_method: str = "noaux_tc"
@@ -292,6 +316,29 @@ class DecoderConfig:
     #: (~0.13 under a random router) that it decides choices and load, as a
     #: trained balancing bias does (``_init_ffn``)
     router_bias_std: float = 0.0
+    # -- Gated DeltaNet layers among gated per-head K/V layers (Qwen3-Next),
+    # under the published key names. ``layer_types`` names a layer
+    # ``linear_attention``: its mixer (``gated_delta_net``) has
+    # ``linear_num_key_heads`` query / key heads of ``linear_key_head_dim``,
+    # each serving ``linear_num_value_heads / linear_num_key_heads`` value
+    # heads of ``linear_value_head_dim``, behind a depthwise causal conv of
+    # ``linear_conv_kernel_dim`` taps and SiLU; what a SEQUENCE caches of it
+    # is a float32 state [value heads, key dim, value dim] and the conv's
+    # last ``linear_conv_kernel_dim - 1`` inputs (``paged_decode.cache_spec``:
+    # kind ``gdn``). ``attention_gate_type`` "elementwise" on a per-head K/V
+    # model: an attention layer's output times ``sigmoid(y W_g)``, value by
+    # value, before ``o_proj``. ``norm_unit_offset``: every RMSNorm scale over
+    # the stream and over an attention head is held as an offset from one
+    # (``x_hat * (1 + w)``; the mixer's gated norm keeps a plain scale).
+    # ``shared_expert_gate``: the shared experts' output times
+    # ``sigmoid(y w_sg)``, one float32 logit a token.
+    linear_num_key_heads: int = 0
+    linear_num_value_heads: int = 0
+    linear_key_head_dim: int = 0
+    linear_value_head_dim: int = 0
+    linear_conv_kernel_dim: int = 0
+    norm_unit_offset: bool = False
+    shared_expert_gate: bool = False
 
     def __post_init__(self):
         from arkflow_tpu.errors import ConfigError
@@ -330,21 +377,28 @@ class DecoderConfig:
         if self.routed:
             if not (0 < self.num_experts_per_tok <= self.n_routed_experts
                     and self.moe_intermediate_size > 0
-                    and 0 < self.first_k_dense_replace < self.layers):
+                    and (self.first_k_dense_replace > 0 or not self.latent)
+                    and 0 <= self.first_k_dense_replace < self.layers):
                 raise ConfigError(
                     "n_routed_experts needs 0 < num_experts_per_tok <= "
                     "n_routed_experts, moe_intermediate_size > 0 and "
-                    "0 < first_k_dense_replace < layers (a leading dense "
-                    "stack, then an expert stack)")
-            if (self.scoring_func, self.topk_method, self.n_group,
-                    self.topk_group, self.norm_topk_prob) != (
-                    "sigmoid", "noaux_tc", 1, 1, True):
+                    "first_k_dense_replace < layers (a leading dense stack, "
+                    "then an expert stack; a latent model has at least one "
+                    "dense layer, a per-head K/V model may have none)")
+            if (self.scoring_func, self.topk_method) not in (
+                    ("sigmoid", "noaux_tc"), ("softmax", "greedy")) or (
+                    self.n_group, self.topk_group, self.norm_topk_prob) != (
+                    1, 1, True):
                 raise ConfigError(
-                    "routed experts implement scoring_func sigmoid, "
-                    "topk_method noaux_tc, n_group = topk_group = 1 (no "
+                    "routed experts implement scoring_func sigmoid with "
+                    "topk_method noaux_tc (a selection bias) or softmax "
+                    "with greedy (none), n_group = topk_group = 1 (no "
                     "group-limited selection) and norm_topk_prob true; got "
                     f"{self.scoring_func!r}, {self.topk_method!r}, "
                     f"{self.n_group}, {self.topk_group}, {self.norm_topk_prob}")
+        if self.shared_expert_gate and not (self.routed and self.n_shared_experts):
+            raise ConfigError("shared_expert_gate weighs the shared experts "
+                              "of a routed model (n_shared_experts > 0)")
         if self.experts_held is not None:
             first, count = (tuple(self.experts_held) + (0, 0))[:2]
             if not (self.routed and len(self.experts_held) == 2
@@ -404,12 +458,16 @@ class DecoderConfig:
         kinds = self.kinds
         if self.layer_types is not None and (
                 len(kinds) != self.layers
-                or set(kinds) - {FULL, SLIDING, CONV}):
+                or set(kinds) - {FULL, SLIDING, CONV, LINEAR}):
             raise ConfigError(
                 f"layer_types names each of the {self.layers} layers "
-                f"{FULL!r}, {SLIDING!r} or {CONV!r}, got {self.layer_types}")
+                f"{FULL!r}, {SLIDING!r}, {CONV!r} or {LINEAR!r}, got "
+                f"{self.layer_types}")
         self._check_conv()
+        self._check_linear()
         if not self.latent:
+            if self.attention_gate_type == "elementwise":
+                gates = gates[1:]  # a per-head layer's own gate
             if any(extras[1:]) or any(gates) or self.apply_mla_qkv_lora_rescale \
                     or self.swa_q_lora_rank is not None \
                     or self.swa_qk_nope_head_dim or self.swa_qk_rope_head_dim:
@@ -428,8 +486,9 @@ class DecoderConfig:
                     self.hybrid or self.num_experts > 1
                     or self.use_ring_attention):
                 raise ConfigError(
-                    "a layer pattern over per-head K/V layers (sliding or "
-                    "conv layers among them), and qk_norm, compose with "
+                    "a layer pattern over per-head K/V layers (sliding, "
+                    "conv or linear_attention layers among them), and "
+                    "qk_norm, compose with "
                     "neither the hybrid block (mamba_d_ssm: a Mamba-2 mixer "
                     "beside every layer's attention), the Switch top-1 "
                     "layer (num_experts) nor ring attention")
@@ -444,11 +503,11 @@ class DecoderConfig:
                 "and add_*_attention_sink_bias belong to a per-head K/V "
                 "model: a latent row has no K/V heads, rotates its own rope "
                 "key whole and has no sink beside it yet")
-        if self.qk_norm or not self.full_attention_rope:
+        if self.qk_norm or not self.full_attention_rope or self.norm_unit_offset:
             raise ConfigError(
-                "qk_norm and full_attention_rope belong to a per-head K/V "
-                "model (a latent model norms its latents and rotates its "
-                "rope key)")
+                "qk_norm, full_attention_rope and norm_unit_offset belong to "
+                "a per-head K/V model (a latent model norms its latents and "
+                "rotates its rope key)")
         if any(g not in ("", "headwise") for g in gates):
             raise ConfigError(
                 f"attention_gate_type is '' or 'headwise', got {gates}")
@@ -500,6 +559,36 @@ class DecoderConfig:
                 "layers (at least one): beside a latent row (kv_lora_rank) "
                 "or a sliding window's pool they are not, yet")
 
+    def _check_linear(self) -> None:
+        """The Gated DeltaNet kind's keys, and what it is not served beside
+        yet."""
+        from arkflow_tpu.errors import ConfigError
+
+        sizes = (self.linear_num_key_heads, self.linear_num_value_heads,
+                 self.linear_key_head_dim, self.linear_value_head_dim,
+                 self.linear_conv_kernel_dim)
+        if not self.linear:
+            if any(sizes):
+                raise ConfigError("linear_num_*_heads / linear_*_head_dim / "
+                                  "linear_conv_kernel_dim without a "
+                                  "linear_attention layer in layer_types")
+            return
+        if (min(sizes) <= 0 or self.linear_conv_kernel_dim < 2
+                or self.linear_num_value_heads % self.linear_num_key_heads):
+            raise ConfigError(
+                "a linear_attention layer needs linear_num_key_heads dividing "
+                "linear_num_value_heads, linear_key_head_dim, "
+                "linear_value_head_dim and linear_conv_kernel_dim >= 2; got "
+                f"{sizes}")
+        if (self.latent or self.hybrid or CONV in self.kinds
+                or SLIDING in self.kinds or FULL not in self.kinds):
+            raise ConfigError(
+                "linear_attention layers (pool gdn: a matrix state a "
+                "sequence) are served among full_attention per-head K/V "
+                "layers (at least one): beside a latent row (kv_lora_rank), "
+                "a sliding window's pool (kv_window), conv layers (pool "
+                "conv) or the hybrid block (pool ssm) they are not, yet")
+
     @property
     def latent(self) -> bool:
         return self.kv_lora_rank > 0
@@ -515,16 +604,33 @@ class DecoderConfig:
         return CONV in self.kinds
 
     @property
+    def linear(self) -> bool:
+        """True where some layers' mixer is a Gated DeltaNet."""
+        return LINEAR in self.kinds
+
+    @property
     def stateful(self) -> bool:
         """True where a sequence caches a state beside its rows by token
         (``paged_decode.cache_spec``: a ``per_slot`` pool)."""
-        return self.hybrid or self.conv
+        return self.hybrid or self.conv or self.linear
 
     @property
     def attn_kinds(self) -> tuple:
         """The kinds of the layers that attend (``gqa`` / ``attn`` state
         their sizes), in the layers' order."""
-        return tuple(k for k in self.kinds if k != CONV)
+        return tuple(k for k in self.kinds if k not in (CONV, LINEAR))
+
+    @property
+    def out_gate(self) -> bool:
+        """True where a per-head attention layer's output is gated value by
+        value (``attention_gate_type`` "elementwise")."""
+        return not self.latent and self.attention_gate_type == "elementwise"
+
+    @property
+    def gdn_conv_dim(self) -> int:
+        """Channels a Gated DeltaNet's causal conv runs over: q | k | v."""
+        return (2 * self.linear_num_key_heads * self.linear_key_head_dim
+                + self.linear_num_value_heads * self.linear_value_head_dim)
 
     @property
     def dh(self) -> int:
@@ -556,7 +662,7 @@ class DecoderConfig:
         per-head norms); otherwise ``layers`` is the one stack of identical
         layers, as it always was."""
         return (self.latent or self.routed or self.layered or self.qk_norm
-                or self.hetero or self.conv)
+                or self.hetero or self.conv or self.linear)
 
     def _check_gqa_kinds(self) -> None:
         """The per-kind keys of a per-head K/V model (``gqa``)."""
@@ -623,14 +729,15 @@ class DecoderConfig:
         return (any(sp.dv != sp.dk or sp.sink for sp in specs)
                 or len({sp.shape for sp in specs}) > 1
                 or self.partial_rotary_factor != 1.0
-                or self.attention_value_scale != 1.0)
+                or self.attention_value_scale != 1.0 or self.out_gate)
 
     @property
     def kind_stacks(self) -> bool:
         """True where a layer's kind decides the stack its parameters live
         in: a latent model's kinds, and a per-head model's where their
-        shapes differ (a conv layer has no attention weights at all)."""
-        return self.latent or self.conv or len(
+        shapes differ (a conv or linear_attention layer has no attention
+        weights at all)."""
+        return self.latent or self.conv or self.linear or len(
             {self.gqa(kind).shape for kind in self.kinds}) > 1
 
     def attn(self, kind: str) -> "AttnSpec":
@@ -756,11 +863,15 @@ def _init_ffn(k, cfg: DecoderConfig, routed: bool) -> dict:
     # it decides the selection, 16 lanes then hit 52 experts a layer and
     # not 69, and the busiest expert takes 9 x the mean load.)
     bias_key = next(k)
-    layer["router_bias"] = (
-        cfg.router_bias_std * jax.random.normal(
-            bias_key, (cfg.n_routed_experts,), jnp.float32)
-        if cfg.router_bias_std > 0 else jax.random.uniform(
-            bias_key, (cfg.n_routed_experts,), jnp.float32, -0.01, 0.01))
+    if cfg.topk_method == "noaux_tc":  # a greedy router has no bias at all
+        layer["router_bias"] = (
+            cfg.router_bias_std * jax.random.normal(
+                bias_key, (cfg.n_routed_experts,), jnp.float32)
+            if cfg.router_bias_std > 0 else jax.random.uniform(
+                bias_key, (cfg.n_routed_experts,), jnp.float32, -0.01, 0.01))
+    if cfg.shared_expert_gate:  # one logit a token: sigmoid of it weighs
+        layer["shared_gate"] = cm.dense_init(  # the shared experts' output
+            jax.random.fold_in(bias_key, 1), cfg.dim, 1, bias=False)
     layer["experts"] = {
         "w_gate": jax.random.uniform(next(k), (e, cfg.dim, f), jnp.float32, -up, up),
         "w_up": jax.random.uniform(next(k), (e, cfg.dim, f), jnp.float32, -up, up),
@@ -773,7 +884,8 @@ def _init_ffn(k, cfg: DecoderConfig, routed: bool) -> dict:
 #: shape stack on a leading axis
 _STACKS = {(FULL, False): "dense_layers", (FULL, True): "layers",
            (SLIDING, False): "swa_dense_layers", (SLIDING, True): "swa_layers",
-           (CONV, False): "conv_dense_layers", (CONV, True): "conv_layers"}
+           (CONV, False): "conv_dense_layers", (CONV, True): "conv_layers",
+           (LINEAR, False): "gdn_dense_layers", (LINEAR, True): "gdn_layers"}
 
 
 def layer_runs(cfg: DecoderConfig) -> list:
@@ -830,8 +942,69 @@ def _init_gqa_layer(key, cfg: DecoderConfig, routed: bool,
         # keep their values)
         layer["attn_sink"] = 2.0 + jax.random.normal(
             jax.random.fold_in(key, 200), (cfg.heads,), jnp.float32)
+    if cfg.out_gate:
+        # the output gate's half of the source's fused query projection
+        # ([q | gate] a head): a leaf of its own, a permutation of its columns
+        layer["w_out_gate"] = cm.dense_init(
+            jax.random.fold_in(key, 300), cfg.dim, cfg.heads * sp.dv, bias=False)
     layer.update(_init_ffn(k, cfg, routed))
     return layer
+
+
+def _init_gdn_layer(key, cfg: DecoderConfig, routed: bool) -> dict:
+    """One Gated DeltaNet layer (HF names: input_layernorm, linear_attn.
+    in_proj_qkvz, in_proj_ba, conv1d, A_log, dt_bias, norm, out_proj,
+    post_attention_layernorm). ``gdn_in``'s columns are q | k | v | z and
+    ``gdn_ba``'s b | a, each segment head-major (the source groups all four
+    by key head: a permutation of columns); ``gdn_conv_w`` [q | k | v
+    channels, taps] the depthwise taps, oldest input first, no bias (torch's
+    default init). ``A_log`` is the log of uniform(0, 16) and ``dt_bias``
+    ones in the family's initialisation; ``dt_bias`` and the gated norm's
+    scale are seeded AROUND one here, so that leaving either out shows."""
+    k = iter(jax.random.split(key, 12))
+    nv, dv = cfg.linear_num_value_heads, cfg.linear_value_head_dim
+    conv, taps = cfg.gdn_conv_dim, cfg.linear_conv_kernel_dim
+    bound = taps ** -0.5
+    extra = (jax.random.fold_in(key, 400 + i) for i in range(4))
+    layer = {
+        "attn_norm": cm.rms_norm_init(cfg.dim),
+        "gdn_in": cm.dense_init(next(k), cfg.dim, conv + nv * dv, bias=False),
+        "gdn_ba": cm.dense_init(next(k), cfg.dim, 2 * nv, bias=False),
+        "gdn_conv_w": jax.random.uniform(next(k), (conv, taps), jnp.float32,
+                                         -bound, bound),
+        "gdn_A_log": jnp.log(jax.random.uniform(next(extra), (nv,), jnp.float32,
+                                                1e-6, 16.0)),
+        "gdn_dt_bias": 1.0 + 0.1 * jax.random.normal(next(extra), (nv,),
+                                                     jnp.float32),
+        "gdn_norm": {"scale": 1.0 + 0.1 * jax.random.normal(
+            next(extra), (dv,), jnp.float32)},
+        "gdn_out": cm.dense_init(next(k), nv * dv, cfg.dim, bias=False),
+        "mlp_norm": cm.rms_norm_init(cfg.dim),
+    }
+    layer.update(_init_ffn(k, cfg, routed))
+    return layer
+
+
+#: the norms whose scale ``norm_unit_offset`` holds as an offset from one
+_OFFSET_NORMS = ("attn_norm", "mlp_norm", "norm_out", "q_head_norm", "k_head_norm")
+
+
+def _seed_offset_norms(params: dict, key) -> dict:
+    """``params`` with every offset-from-one norm scale seeded small and
+    non-zero (normal(0, 0.1): a trained ``w``; zeros would make ``1 + w`` and
+    a plain scale of ones the same model)."""
+    def walk(tree, key):
+        out = {}
+        for i, (name, sub) in enumerate(tree.items()):
+            sub_key = jax.random.fold_in(key, i)
+            if name in _OFFSET_NORMS:
+                sub = {"scale": 0.1 * jax.random.normal(
+                    sub_key, sub["scale"].shape, jnp.float32)}
+            elif isinstance(sub, dict):
+                sub = walk(sub, sub_key)
+            out[name] = sub
+        return out
+    return walk(params, key)
 
 
 def _init_conv_layer(key, cfg: DecoderConfig, routed: bool) -> dict:
@@ -868,10 +1041,13 @@ def _init_runs(rng, cfg: DecoderConfig) -> dict:
         stacks.setdefault(name, []).extend(
             _init_latent_layer(next(keys), cfg, routed, kind) if cfg.latent
             else _init_conv_layer(next(keys), cfg, routed) if kind == CONV
+            else _init_gdn_layer(next(keys), cfg, routed) if kind == LINEAR
             else _init_gqa_layer(next(keys), cfg, routed, kind)
             for _ in range(first, stop))
     for name, stack in stacks.items():
         params[name] = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *stack)
+    if cfg.norm_unit_offset:
+        params = _seed_offset_norms(params, jax.random.fold_in(rng, 500))
     return params
 
 
@@ -1068,6 +1244,110 @@ def short_conv(lp: dict, y: jnp.ndarray, cfg: DecoderConfig, before=None):
     return cm.dense(lp["conv_out"], (gate_c * conved).astype(bcu.dtype)), ext
 
 
+def _norm(p: dict, x: jnp.ndarray, cfg: DecoderConfig) -> jnp.ndarray:
+    """RMSNorm over the stream or an attention head at the model's epsilon:
+    the scale as it is, or — ``norm_unit_offset`` — held as an offset from
+    one, ``x_hat * (1 + w)``."""
+    if not cfg.norm_unit_offset:
+        return cm.rms_norm(p, x, cfg.norm_eps)
+    xf = x.astype(jnp.float32)
+    y = xf * jax.lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
+                           + cfg.norm_eps)
+    return (y * (1.0 + p["scale"])).astype(x.dtype)
+
+
+def gdn_project(lp: dict, y: jnp.ndarray, cfg: DecoderConfig):
+    """A Gated DeltaNet's input projections of normed activations ``y``
+    [B, S, dim]: the conv's input ``q | k | v`` [B, S, conv channels] AS
+    PROJECTED (bfloat16: what the conv window caches), the output gate ``z``
+    [B, S, value heads x value dim] and the raw ``b`` and ``a`` [B, S, value
+    heads], float32."""
+    u = cm.dense(lp["gdn_in"], y)
+    ba = cm.dense(lp["gdn_ba"], y).astype(jnp.float32)
+    conv, nv = cfg.gdn_conv_dim, cfg.linear_num_value_heads
+    return u[..., :conv], u[..., conv:].astype(jnp.float32), ba[..., :nv], ba[..., nv:]
+
+
+def gdn_conv(lp: dict, ext: jnp.ndarray, s: int) -> jnp.ndarray:
+    """The depthwise causal conv (no bias) and its SiLU over ``ext`` [B,
+    taps - 1 + S, channels] — the ``taps - 1`` projected inputs before the
+    block, then the block's — in float32: output t reads ``ext[t : t +
+    taps]``."""
+    ext = ext.astype(jnp.float32)
+    w = lp["gdn_conv_w"].astype(jnp.float32)                      # [C, taps]
+    return jax.nn.silu(sum(ext[:, j:j + s] * w[:, j] for j in range(w.shape[1])))
+
+
+def gdn_operands(lp: dict, conved: jnp.ndarray, b: jnp.ndarray, a: jnp.ndarray,
+                 cfg: DecoderConfig, valid=None):
+    """What the delta rule reads (``ops/gdn_scan``), from the conv's output
+    [B, S, channels] and the raw ``b`` / ``a`` [B, S, value heads], float32:
+    queries and keys L2-normalised a head (``x rsqrt(sum x^2 + 1e-6)``), a key
+    head serving ``value heads / key heads`` value heads in a row
+    (``repeat_interleave``), the queries times ``key dim ** -0.5``, [B, S,
+    value heads, key dim]; values [B, S, value heads, value dim]; ``g = -exp(
+    A_log) softplus(a + dt_bias)`` and ``beta = sigmoid(b)`` [B, S, value
+    heads] — both 0 where ``valid`` [B, S] is false, so that a padded
+    position or an idle lane leaves the state as it is."""
+    bsz, s = conved.shape[:2]
+    nk, nv = cfg.linear_num_key_heads, cfg.linear_num_value_heads
+    dk, dv = cfg.linear_key_head_dim, cfg.linear_value_head_dim
+
+    def heads(x):  # [B, S, key heads, key dim] -> normalised, a value head each
+        x = x.reshape(bsz, s, nk, dk)
+        x = x * jax.lax.rsqrt(jnp.square(x).sum(-1, keepdims=True) + 1e-6)
+        return jnp.repeat(x, nv // nk, axis=2)
+
+    q = heads(conved[..., :nk * dk]) * dk ** -0.5
+    k = heads(conved[..., nk * dk:2 * nk * dk])
+    v = conved[..., 2 * nk * dk:].reshape(bsz, s, nv, dv)
+    g = -jnp.exp(lp["gdn_A_log"].astype(jnp.float32)) * jax.nn.softplus(
+        a + lp["gdn_dt_bias"].astype(jnp.float32))
+    beta = jax.nn.sigmoid(b)
+    if valid is not None:
+        g = jnp.where(valid[..., None], g, 0.0)
+        beta = jnp.where(valid[..., None], beta, 0.0)
+    return q, k, v, g, beta
+
+
+def gdn_output(lp: dict, o: jnp.ndarray, z: jnp.ndarray, cfg: DecoderConfig,
+               dtype) -> jnp.ndarray:
+    """The delta rule's output ``o`` [B, S, value heads, value dim] -> the
+    mixer's [B, S, dim]: RMSNorm over each head's values times the norm's
+    plain scale (one set for all heads), times ``silu(z)``, ``out_proj``."""
+    bsz, s = z.shape[:2]
+    normed = o * jax.lax.rsqrt(jnp.square(o).mean(-1, keepdims=True)
+                               + cfg.norm_eps) * lp["gdn_norm"]["scale"]
+    gated = normed.reshape(bsz, s, -1) * jax.nn.silu(z)
+    return cm.dense(lp["gdn_out"], gated.astype(dtype))
+
+
+def _gdn_block(lp: dict, y: jnp.ndarray, cfg: DecoderConfig) -> jnp.ndarray:
+    """The Gated DeltaNet mixer over a whole block from a zero state and an
+    empty window (``forward``): the chunked form in plain XLA, no cache."""
+    from arkflow_tpu.ops.gdn_scan import chunk_from
+
+    bsz, s = y.shape[:2]
+    u, z, b, a = gdn_project(lp, y, cfg)
+    ext = jnp.pad(u, ((0, 0), (cfg.linear_conv_kernel_dim - 1, 0), (0, 0)))
+    q, k, v, g, beta = gdn_operands(lp, gdn_conv(lp, ext, s), b, a, cfg)
+    s0 = jnp.zeros((bsz, cfg.linear_num_value_heads, cfg.linear_key_head_dim,
+                    cfg.linear_value_head_dim), jnp.float32)
+    o, _ = chunk_from(s0, q, k, v, g, beta)
+    return gdn_output(lp, o, z, cfg, y.dtype)
+
+
+def attn_out_gate(lp: dict, y: jnp.ndarray, attn: jnp.ndarray,
+                  cfg: DecoderConfig) -> jnp.ndarray:
+    """A per-head layer's attention output [B, S, H, dv] under the model's
+    elementwise gate, ``attn * sigmoid(y W_g)`` in float32 (``y``: the
+    block's normed input); as it is where the model has none."""
+    if not cfg.out_gate:
+        return attn
+    gate = jax.nn.sigmoid(cm.dense(lp["w_out_gate"], y).astype(jnp.float32))
+    return (attn.astype(jnp.float32) * gate.reshape(attn.shape)).astype(attn.dtype)
+
+
 def _rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
     """Rotary embedding. x: [B, S, H, Dh]; positions: [B, S]."""
     dh = x.shape[-1]
@@ -1243,24 +1523,31 @@ def index_mask(scores, positions, key_pos, topk: int):
 
 def route_topk(lp: dict, y: jnp.ndarray, cfg: DecoderConfig, token_mask=None):
     """The router of one expert layer over tokens ``y`` [T, dim]: float32
-    sigmoid scores (the product at ``highest`` precision — on a TPU a
-    float32 product otherwise runs in bfloat16 passes, and a 6th-against-7th
-    choice is decided in the fourth decimal), the top
-    ``num_experts_per_tok`` of ``score + router_bias`` chosen, weighed by
-    the unbiased scores (normalised, times ``routed_scaling_factor``).
+    scores — ``scoring_func``: sigmoid of each logit, or softmax over all the
+    experts' — (the product at ``highest`` precision — on a TPU a float32
+    product otherwise runs in bfloat16 passes, and a 6th-against-7th choice
+    is decided in the fourth decimal), the top ``num_experts_per_tok`` of
+    ``score + router_bias`` chosen (of the scores alone where the layer has
+    no selection bias: ``topk_method`` greedy), weighed by the unbiased
+    scores (normalised, times ``routed_scaling_factor``).
 
     Returns the combine weights [T, held + shared] float32 — routed weights
     in their experts' columns (of the experts HELD here, ``cfg.held``: the
     weights are normalised over all the chosen, and a choice of an absent
-    expert has no column), 1 in the shared experts' — and the layer's load
+    expert has no column), 1 in the shared experts' (``shared_expert_gate``:
+    ``sigmoid(y w_sg)``, the token's own) — and the layer's load
     [E] int32 (tokens routed to each of ALL the experts). Tokens that
     ``token_mask`` excludes (inactive lanes, padding) have an all-zero row:
     they route nowhere and count nowhere."""
     e, k = cfg.n_routed_experts, cfg.num_experts_per_tok
-    scores = jax.nn.sigmoid(jnp.dot(
+    score = jax.nn.softmax if cfg.scoring_func == "softmax" else jax.nn.sigmoid
+    scores = score(jnp.dot(
         y.astype(jnp.float32), lp["router"]["w"].astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST))
-    _, idx = jax.lax.top_k(scores + lp["router_bias"].astype(jnp.float32), k)
+    biased = scores
+    if "router_bias" in lp:
+        biased = scores + lp["router_bias"].astype(jnp.float32)
+    _, idx = jax.lax.top_k(biased, k)
     w = jnp.take_along_axis(scores, idx, axis=-1)                 # [T, k]
     w = (w / (w.sum(axis=-1, keepdims=True) + cfg.norm_topk_eps)
          * cfg.routed_scaling_factor)
@@ -1273,6 +1560,10 @@ def route_topk(lp: dict, y: jnp.ndarray, cfg: DecoderConfig, token_mask=None):
         first, count = cfg.held
         cw = cw[:, first:first + count]
     shared = jnp.broadcast_to(live[:, None], (y.shape[0], cfg.n_shared_experts))
+    if cfg.shared_expert_gate:
+        shared = shared * jax.nn.sigmoid(jnp.dot(
+            y.astype(jnp.float32), lp["shared_gate"]["w"].astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST))
     return (jnp.concatenate([cw, shared], axis=-1),
             assign.sum(axis=0).astype(jnp.int32))
 
@@ -1388,7 +1679,7 @@ def _attention_block(lp: dict, x: jnp.ndarray, cfg: DecoderConfig, positions,
     b, s = positions.shape
     sp = cfg.gqa(kind)
     group = cfg.heads // sp.kv_heads
-    y = cm.rms_norm(lp["attn_norm"], x, cfg.norm_eps)
+    y = _norm(lp["attn_norm"], x, cfg)
     q, k, v = qkv_project(lp, y, cfg, kind)
     q, k = qk_positioned(lp, q, k, cfg, positions, kind)
     if sp.window:
@@ -1400,6 +1691,7 @@ def _attention_block(lp: dict, x: jnp.ndarray, cfg: DecoderConfig, positions,
         attn = ring_attn(q, k, v)
     else:
         attn = cm.attention(q, k, v, causal, sink=lp.get("attn_sink"))
+    attn = attn_out_gate(lp, y, attn, cfg)
     out = _scaled(cm.dense(lp["wo"], attn.reshape(b, s, cfg.heads * sp.dv)),
                   cfg.attention_out_multiplier)
     if cfg.hybrid:  # the parallel mixer: one norm feeds both, one residual
@@ -1433,8 +1725,8 @@ def qk_positioned(lp: dict, q, k, cfg: DecoderConfig, positions, kind: str = FUL
     the sliding layers only."""
     sp = cfg.gqa(kind)
     if cfg.qk_norm:
-        q = cm.rms_norm(lp["q_head_norm"], q, cfg.norm_eps)
-        k = cm.rms_norm(lp["k_head_norm"], k, cfg.norm_eps)
+        q = _norm(lp["q_head_norm"], q, cfg)
+        k = _norm(lp["k_head_norm"], k, cfg)
     if sp.rotary == sp.dk:
         q = _rope(q, positions, sp.rope_theta)
         k = _rope(k, positions, sp.rope_theta)
@@ -1461,7 +1753,7 @@ def _mlp(lp: dict, y: jnp.ndarray, cfg: DecoderConfig, token_mask=None) -> jnp.n
 def lm_logits(params: dict, x: jnp.ndarray, cfg: DecoderConfig) -> jnp.ndarray:
     """The final norm and the output head: [..., dim] -> float32 [..., vocab]
     (times ``lm_head_multiplier`` where the model states one)."""
-    x = cm.rms_norm(params["norm_out"], x, cfg.norm_eps)
+    x = _norm(params["norm_out"], x, cfg)
     logits = cm.dense(params["lm_head"], x).astype(jnp.float32)
     return logits if cfg.lm_head_multiplier == 1.0 else logits * cfg.lm_head_multiplier
 
@@ -1509,11 +1801,13 @@ def forward(params: dict, cfg: DecoderConfig, input_ids, *, axes=None, mesh=None
             if kind == CONV:  # from the sequence's start: zeros before it
                 x = x + short_conv(
                     lp, cm.rms_norm(lp["attn_norm"], x, cfg.norm_eps), cfg)[0]
+            elif kind == LINEAR:  # from a zero state and an empty window
+                x = x + _gdn_block(lp, _norm(lp["attn_norm"], x, cfg), cfg)
             else:
                 x = _attention_block(lp, x, cfg, positions, causal, ring_attn,
                                      kind)
             x = _shard_act(x, axes)
-            y = cm.rms_norm(lp["mlp_norm"], x, cfg.norm_eps)
+            y = _norm(lp["mlp_norm"], x, cfg)
             aux = (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32))
             if cfg.num_experts > 1:
                 moe_out, aux = _moe_mlp(lp, y, cfg)
@@ -1733,6 +2027,8 @@ def _attn_dtypes(cfg: DecoderConfig, kind: str) -> dict:
             layer.update(q_head_norm={"scale": f32}, k_head_norm={"scale": f32})
         if cfg.gqa(kind).sink:
             layer["attn_sink"] = f32
+        if cfg.out_gate:
+            layer["w_out_gate"] = {"w": bf16}
     if sp.q_lora_rank:
         layer.update(wq_a={"w": bf16}, q_norm={"scale": f32})
     if sp.gate:
@@ -1760,12 +2056,20 @@ def _serve_dtypes_runs(cfg: DecoderConfig) -> dict:
         layer = {"attn_norm": {"scale": f32}, "mlp_norm": {"scale": f32}}
         if kind == CONV:
             layer.update(conv_in={"w": bf16}, conv_w=bf16, conv_out={"w": bf16})
+        elif kind == LINEAR:  # what shapes the decay and the gated norm: f32
+            layer.update(gdn_in={"w": bf16}, gdn_ba={"w": bf16}, gdn_conv_w=bf16,
+                         gdn_A_log=f32, gdn_dt_bias=f32,
+                         gdn_norm={"scale": f32}, gdn_out={"w": bf16})
         else:
             layer.update(wq={"w": bf16}, wo={"w": bf16},
                          **_attn_dtypes(cfg, kind))
         if routed:
-            layer.update(router={"w": f32}, router_bias=f32,
+            layer.update(router={"w": f32},
                          experts={"w_gate": bf16, "w_up": bf16, "w_down": bf16})
+            if cfg.topk_method == "noaux_tc":
+                layer["router_bias"] = f32
+            if cfg.shared_expert_gate:
+                layer["shared_gate"] = {"w": f32}
         else:
             layer.update(w_gate={"w": bf16}, w_up={"w": bf16}, w_down={"w": bf16})
         out[name] = layer
@@ -1785,9 +2089,9 @@ def _no_latent(cfg: DecoderConfig, what: str) -> None:
             "(n_routed_experts), a layer pattern (layer_types: window "
             "pages beside kept pages), qk_norm, or head sizes by kind "
             "(swa_kv_heads, v_head_dim, partial_rotary_factor, "
-            "attention_value_scale, a sink), or conv layers among its "
-            "attention layers (a conv pool a slot beside the K/V pages) "
-            "generates through serving: continuous")
+            "attention_value_scale, a sink, an output gate), or conv layers "
+            "or linear_attention layers among its attention layers (a state "
+            "a slot beside the K/V pages) generates through serving: continuous")
     if cfg.hybrid:
         from arkflow_tpu.errors import ConfigError
 

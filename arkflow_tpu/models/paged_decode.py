@@ -16,7 +16,7 @@ The cache's shape is the model's to state and this module's to own
 ROW-MAJOR pools, ``[layers, pages, page, kv_heads * dh]``, which the
 attention kernel's narrow-head walk copies a page at a time —, a state a
 SEQUENCE beside them (a hybrid layer's Mamba-2 state, a conv layer's last
-gated inputs: a row a slot), or latent rows (MLA, ``cfg.latent``:
+gated inputs, a Gated DeltaNet layer's matrix state: a row a slot), or latent rows (MLA, ``cfg.latent``:
 one normed latent row and one rotated rope key a token for all heads;
 attention runs in the absorbed form over them). Either kind's pools ride
 whole through the layer loop (``_dense_layers``, ``_latent_layers``): a
@@ -53,8 +53,10 @@ from typing import NamedTuple, Optional
 from arkflow_tpu.models import common as cm
 from dataclasses import dataclass
 
-from arkflow_tpu.models.decoder import (CONV, FULL, SLIDING, DecoderConfig, _mlp,
-                                        _scaled, index_project,
+from arkflow_tpu.models.decoder import (CONV, FULL, LINEAR, SLIDING,
+                                        DecoderConfig, _mlp, _norm, _scaled,
+                                        attn_out_gate, gdn_conv, gdn_operands,
+                                        gdn_output, gdn_project, index_project,
                                         index_scores, layer_runs, layer_stacks,
                                         lm_logits, mla_absorb_query,
                                         mla_expanded_attention, mla_head_gate,
@@ -79,7 +81,9 @@ class CachePool:
     ``p`` is the array's layer ``p * layers + l``, so part 0 is indexed as
     V is). ``row_major``: the heads have NO axis of their own, a token's
     are side by side on the last (``GqaSpec.row_major``: a head narrower
-    than 128 lanes)."""
+    than 128 lanes). ``split_heads``: each head has a LAYER of each array
+    of its own (``GqaSpec.split_heads``: head ``j`` of layer ``l`` is the
+    array's layer ``j * layers + l``, its head axis of one)."""
     name: str
     layers: int
     widths: tuple
@@ -89,11 +93,15 @@ class CachePool:
     heads: int = 0
     key_parts: int = 1
     row_major: bool = False
+    split_heads: bool = False
 
     def shapes(self, pages: int, page_size: int) -> list:
         """The shapes of a per-head pool's K and V over ``pages`` pages."""
         if self.row_major:
             return [(self.layers, pages, page_size, w) for w in self.widths]
+        if self.split_heads:
+            return [(self.layers * self.heads, pages, page_size, 1,
+                     w // self.heads) for w in self.widths]
         return [(self.layers * parts, pages, page_size, self.heads,
                  width // self.heads // parts)
                 for width, parts in zip(self.widths, (self.key_parts, 1))]
@@ -149,14 +157,19 @@ def cache_spec(cfg: DecoderConfig) -> tuple:
       SEQUENCE whatever its length, beside that layer's ``kv`` rows;
     - ``conv``: a conv layer's last ``conv_L_cache - 1`` gated inputs, one
       row a SEQUENCE too, over the conv layers only (which have no ``kv``
-      rows: ``kv`` is over the attention layers only)."""
+      rows: ``kv`` is over the attention layers only);
+    - ``gdn``: a Gated DeltaNet layer's float32 state — a [key dim, value
+      dim] matrix a value head — and its conv's last
+      ``linear_conv_kernel_dim - 1`` projected inputs (bfloat16), one row a
+      SEQUENCE, over the linear_attention layers only (``kv`` over the
+      attention layers only, as beside ``conv``)."""
     if not cfg.latent:
         full, swa = (cfg.kinds.count(k) for k in (FULL, SLIDING))
 
         def widths(sp):
             return dict(widths=(sp.kv_heads * sp.dk_held, sp.kv_heads * sp.dv),
                         heads=sp.kv_heads, key_parts=sp.key_parts,
-                        row_major=sp.row_major)
+                        row_major=sp.row_major, split_heads=sp.split_heads)
 
         pools = (CachePool("kv", full, **widths(cfg.gqa(FULL))),)
         if swa:
@@ -172,6 +185,13 @@ def cache_spec(cfg: DecoderConfig) -> tuple:
             pools += (CachePool(
                 "conv", cfg.kinds.count(CONV),
                 ((cfg.conv_L_cache - 1) * cfg.dim,), per_slot=True),)
+        if cfg.linear:
+            pools += (CachePool(
+                "gdn", cfg.kinds.count(LINEAR),
+                (cfg.linear_num_value_heads * cfg.linear_key_head_dim
+                 * cfg.linear_value_head_dim,
+                 (cfg.linear_conv_kernel_dim - 1) * cfg.gdn_conv_dim),
+                itemsizes=(4, 2), per_slot=True),)
         return pools
     full, swa = (cfg.kinds.count(k) for k in (FULL, SLIDING))
     pools = [CachePool("latent", full, (cfg.kv_lora_rank,
@@ -215,7 +235,12 @@ def init_page_pool(cfg: DecoderConfig, num_pages: int, page_size: int,
       ``s``'s;
     - a model with conv layers: ``{"kv": K, "conv": the windows}`` and
       ``{"kv": V}`` — ``kv`` over the attention layers, the windows [conv
-      layers, slots + 1, conv_L_cache - 1, dim], rows as a hybrid model's.
+      layers, slots + 1, conv_L_cache - 1, dim], rows as a hybrid model's;
+    - a model with linear_attention layers: ``{"kv": K, "gdn": the states}``
+      and ``{"kv": V, "gdn": the conv windows}`` — ``kv`` over the attention
+      layers, the states float32 [linear layers, slots + 1, value heads, key
+      dim, value dim] (``ops/gdn_scan``), the windows [linear layers, slots
+      + 1, linear_conv_kernel_dim - 1, conv channels], rows as above.
 
     A head narrower than 128 lanes has ROW-MAJOR K and V, [layers, pages,
     page, kv_heads * width] (``CachePool.row_major``)."""
@@ -244,6 +269,14 @@ def init_page_pool(cfg: DecoderConfig, num_pages: int, page_size: int,
         return ({"kv": k, "conv": jnp.zeros(
             (spec[-1].layers, slots + 1, cfg.conv_L_cache - 1, cfg.dim),
             jnp.bfloat16)}, {"kv": v})
+    if cfg.linear:
+        rows = (spec[-1].layers, slots + 1)
+        return ({"kv": k, "gdn": jnp.zeros(
+                    rows + (cfg.linear_num_value_heads, cfg.linear_key_head_dim,
+                            cfg.linear_value_head_dim), jnp.float32)},
+                {"kv": v, "gdn": jnp.zeros(
+                    rows + (cfg.linear_conv_kernel_dim - 1, cfg.gdn_conv_dim),
+                    jnp.bfloat16)})
     if not cfg.hybrid:
         return k, v
     rows = (cfg.layers, slots + 1)
@@ -721,7 +754,9 @@ def gqa_kernel_probe(params: dict, cfg: DecoderConfig, page_size: int,
     pages, one crossing a page boundary — against the gathered context
     under its mask; with sliding layers the windowed call (rows deep into
     their window, one at the ring's wrap, one short) against
-    ``_attend_ring``; and the expert product on GIVEN routing."""
+    ``_attend_ring``; the expert product on GIVEN routing; and with
+    linear_attention layers the delta rule's two kernels against their plain
+    forms (``_gdn_probe``)."""
     keys = iter(jax.random.split(jax.random.PRNGKey(1234), 32))
     rand = lambda shape: jax.random.normal(  # noqa: E731
         next(keys), shape, jnp.float32).astype(jnp.bfloat16)
@@ -750,7 +785,7 @@ def gqa_kernel_probe(params: dict, cfg: DecoderConfig, page_size: int,
         q = rand((2, c, cfg.heads, sp.dk))
         positions = off[:, None] + jnp.arange(c)[None, :]
         mask = jnp.arange(ctx)[None, None, None, :] <= positions[:, None, :, None]
-        k = jnp.repeat(_read_keys(kp, 0, table, sp.dk), group, axis=2)
+        k = jnp.repeat(_read_keys(kp, 0, table, sp.dk, sp.kv_heads), group, axis=2)
         v = jnp.repeat(_read_rows(vp, 0, table, sp.kv_heads), group, axis=2)
         out.append((f"paged_attention_{name}",
                     cm.attention(q, k, v, mask, sink=sink),
@@ -771,7 +806,51 @@ def gqa_kernel_probe(params: dict, cfg: DecoderConfig, page_size: int,
                                       kernel_interpret, window, sink)))
     if cfg.routed:
         out.append(_expert_probe(params, cfg, keys, rand, kernel_interpret))
+    if cfg.linear:
+        out.extend(_gdn_probe(cfg, kernel_interpret))
     return out
+
+
+def _gdn_probe(cfg: DecoderConfig, kernel_interpret: bool) -> list:
+    """(name, reference, kernel output) of the delta rule's two kernels
+    against their plain forms (``ops/gdn_scan``) at the model's own head
+    counts and widths, on seeded operands of the statistics the layer hands
+    them (unit keys, scaled unit queries, gates of every strength): a decode
+    step of two lanes on rows 2 and 1 of a seeded pool, and a chunk of two
+    blocks and a ragged tail, its first row fresh, its last positions padded
+    (``g = beta = 0``). Each line is the outputs and the rows' states after,
+    joined."""
+    from arkflow_tpu.ops.gdn_scan import BLOCK, gdn_chunk_scan, gdn_state_update
+
+    nv, dk, dv = (cfg.linear_num_value_heads, cfg.linear_key_head_dim,
+                  cfg.linear_value_head_dim)
+    keys = iter(jax.random.split(jax.random.PRNGKey(4242), 8))
+    t = 2 * BLOCK + 5
+    pool = jax.random.normal(next(keys), (1, 3, nv, dk, dv), jnp.float32)
+    unit = lambda x: x * jax.lax.rsqrt(  # noqa: E731
+        jnp.square(x).sum(-1, keepdims=True) + 1e-6)
+    q = unit(jax.random.normal(next(keys), (2, t, nv, dk), jnp.float32)) * dk ** -0.5
+    k = unit(jax.random.normal(next(keys), (2, t, nv, dk), jnp.float32))
+    v = jax.random.normal(next(keys), (2, t, nv, dv), jnp.float32)
+    live = (jnp.arange(t) < t - 3)[None, :, None]
+    g = jnp.where(live, -jnp.exp(2 * jax.random.normal(next(keys), (2, t, nv)) - 2), 0.0)
+    beta = jnp.where(live, jax.nn.sigmoid(jax.random.normal(next(keys), (2, t, nv))), 0.0)
+    rows = jnp.asarray([2, 1], jnp.int32)
+    fresh = jnp.asarray([True, False])
+
+    def joined(o, states):
+        return jnp.concatenate([o.reshape(-1, dv), states[0, rows].reshape(-1, dv)])
+
+    def update(**kern):
+        return joined(*gdn_state_update(pool, 0, rows, q[:, 0], k[:, 0], v[:, 0],
+                                        g[:, 0], beta[:, 0], **kern))
+
+    def scan(**kern):
+        return joined(*gdn_chunk_scan(pool, 0, rows, fresh, q, k, v, g, beta, **kern))
+
+    kern = dict(kernel=True, interpret=kernel_interpret)
+    return [("gdn_state_update", update(), update(**kern)),
+            ("gdn_chunk_scan", scan(), scan(**kern))]
 
 
 def _write_keys(kp, k, layer, pi, po, parts: int):
@@ -794,6 +873,12 @@ def _write_rows(pool, x, layer, pi, po):
     token's heads side by side."""
     if pool.ndim == 4:
         x = x.reshape(*x.shape[:2], -1)
+    elif pool.shape[3] == 1 < x.shape[2]:  # a head a layer (``split_heads``)
+        layers = pool.shape[0] // x.shape[2]
+        for j in range(x.shape[2]):
+            pool = pool.at[j * layers + layer, pi, po].set(
+                x[:, :, j:j + 1].astype(pool.dtype))
+        return pool
     return pool.at[layer, pi, po].set(x.astype(pool.dtype))
 
 
@@ -801,14 +886,22 @@ def _read_rows(pool, layer, table, heads: int):
     """A layer's K or V rows [B, columns * page, kv heads, width] gathered
     through ``table`` [B, columns], however the pool holds a token's heads."""
     b, cols = table.shape
+    if pool.ndim == 5 and pool.shape[3] == 1 < heads:  # a head a layer
+        layers = pool.shape[0] // heads
+        return jnp.concatenate([pool[j * layers + layer, table]
+                                for j in range(heads)], axis=3).reshape(
+            b, cols * pool.shape[2], heads, -1)
     return pool[layer, table].reshape(b, cols * pool.shape[2], heads, -1)
 
 
-def _read_keys(kp, layer, table, dk: int):
+def _read_keys(kp, layer, table, dk: int, heads: int = 1):
     """A layer's keys [B, columns * page, kv heads, dk] gathered through
-    ``table`` [B, columns], a key held in parts joined and cut to ``dk``."""
+    ``table`` [B, columns], a key held in parts joined and cut to ``dk``
+    (``heads``: the K/V heads of a pool that holds a head a layer)."""
     if kp.ndim == 4:
         return _read_rows(kp, layer, table, kp.shape[-1] // dk)
+    if kp.shape[3] == 1 < heads:
+        return _read_rows(kp, layer, table, heads)
     parts = -(-dk // kp.shape[-1])
     b, cols = table.shape
     k = jnp.concatenate(
@@ -850,6 +943,18 @@ def _attend_paged(q, k_pages, v_pages, layer, page_table, off,
     from arkflow_tpu.ops.ragged_attention import paged_flash_attention
 
     if kv_sharding is None:
+        sp = cfg.gqa(SLIDING if window else FULL)
+        if sp.split_heads:
+            # a head a layer of the pools (``GqaSpec.split_heads``): a call
+            # a K/V head, over that head's own query heads
+            kvh = sp.kv_heads
+            layers, group = k_pages.shape[0] // kvh, q.shape[2] // kvh
+            return jnp.concatenate([paged_flash_attention(
+                q[:, :, j * group:(j + 1) * group], k_pages, v_pages,
+                layer + j * layers, page_table, off, interpret=interpret,
+                window=window,
+                sink=None if sink is None else sink[j * group:(j + 1) * group])
+                for j in range(kvh)], axis=2)
         return paged_flash_attention(q, k_pages, v_pages, layer, page_table,
                                      off, interpret=interpret, window=window,
                                      sink=sink)
@@ -955,6 +1060,38 @@ def _conv_paged(lp: dict, y, cfg: DecoderConfig, windows, layer, rows, fresh,
         _last_valid(ext, valid, keep).astype(windows.dtype))
 
 
+def _gdn_paged(lp: dict, y, cfg: DecoderConfig, states, windows, layer, rows,
+               fresh, valid, kernel: bool, interpret: bool):
+    """A Gated DeltaNet layer's mixer over the ``gdn`` pool: ``states`` /
+    ``windows`` whole (``init_page_pool``), ``rows`` / ``fresh`` / ``valid``
+    as ``_mixer_paged``'s: a decode step is one token a lane
+    (``gdn_state_update``), a chunk runs from the row's state — a zero state
+    and an empty window where ``fresh`` — to the row's state
+    (``gdn_chunk_scan``). A position that is not valid leaves state and
+    window as they are: its ``g`` and ``beta`` are 0, and the window keeps the
+    last ``linear_conv_kernel_dim - 1`` VALID inputs. Returns (the mixer's
+    output [B, S, dim], states, windows)."""
+    from arkflow_tpu.ops.gdn_scan import gdn_chunk_scan, gdn_state_update
+
+    t, keep = y.shape[1], cfg.linear_conv_kernel_dim - 1
+    u, z, b, a = gdn_project(lp, y, cfg)
+    before = windows[layer, rows]                                 # [B, K-1, C]
+    if fresh is not None:
+        before = jnp.where(fresh[:, None, None], 0, before)
+    ext = jnp.concatenate([before, u.astype(before.dtype)], axis=1)
+    windows = windows.at[layer, rows].set(_last_valid(ext, valid, keep))
+    q, k, v, g, beta = gdn_operands(lp, gdn_conv(lp, ext, t), b, a, cfg, valid)
+    kern = dict(kernel=kernel, interpret=interpret)
+    if fresh is None:
+        o, states = gdn_state_update(states, layer, rows, q[:, 0], k[:, 0],
+                                     v[:, 0], g[:, 0], beta[:, 0], **kern)
+        o = o[:, None]
+    else:
+        o, states = gdn_chunk_scan(states, layer, rows, fresh, q, k, v, g, beta,
+                                   **kern)
+    return gdn_output(lp, o, z, cfg, y.dtype), states, windows
+
+
 class _RidingChunk(NamedTuple):
     """How the chunk behind a fused step's lanes attends (``_dense_layers``):
     the block's first ``lanes`` tokens are the decode step's, a row each;
@@ -1003,7 +1140,10 @@ def _dense_layers(params: dict, cfg: DecoderConfig, x, k_pages, v_pages,
     layers carries ``{"kv", "conv"}`` and ``{"kv"}``: a ``conv`` run's layers
     read and write the conv pool's rows under the same three operands
     (``_conv_paged``) and no K/V, the attention runs' layers the ``kv``
-    pools alone, each pool indexed by the layer's place among its kind's.
+    pools alone, each pool indexed by the layer's place among its kind's. A
+    model with linear_attention layers carries ``{"kv", "gdn"}`` twice (the
+    states beside K, the conv windows beside V): a ``linear_attention`` run's
+    layers advance the ``gdn`` pool's rows (``_gdn_paged``) and touch no K/V.
 
     ``chunk`` (``paged_fused_step``; a ``fusable`` model): the block is ONE
     row [1, lanes + C] — a decode step's lanes, a token each, then a prompt's
@@ -1024,7 +1164,7 @@ def _dense_layers(params: dict, cfg: DecoderConfig, x, k_pages, v_pages,
 
     def make_layer(routed: bool, kind: str, experts):
         def ffn(lp, x, kp, vp, ei):
-            y = cm.rms_norm(lp["mlp_norm"], x, cfg.norm_eps)
+            y = _norm(lp["mlp_norm"], x, cfg)
             if not routed:
                 return (x + _mlp(lp, y, cfg, token_mask=token_mask), kp, vp), None
             # the stack's experts stay OUT of the scanned tree and whole:
@@ -1047,6 +1187,21 @@ def _dense_layers(params: dict, cfg: DecoderConfig, x, k_pages, v_pages,
 
             return conv_layer
 
+        if kind == LINEAR:
+            def gdn_layer(carry, scanned):
+                """A Gated DeltaNet layer: its mixer over the gdn pool's
+                rows (``_gdn_paged``), no K/V written or read."""
+                x, kp, vp = carry
+                lp, li, *ei = scanned
+                mixed, states, windows = _gdn_paged(
+                    lp, _norm(lp["attn_norm"], x, cfg), cfg, kp["gdn"],
+                    vp["gdn"], li, ssm_rows, ssm_fresh, token_mask, kernel,
+                    kernel_interpret)
+                return ffn(lp, x + mixed, {**kp, "gdn": states},
+                           {**vp, "gdn": windows}, ei)
+
+            return gdn_layer
+
         sp = cfg.gqa(kind)
         window, group = sp.window, cfg.heads // sp.kv_heads
         name = "kv_window" if window else "kv"
@@ -1058,9 +1213,9 @@ def _dense_layers(params: dict, cfg: DecoderConfig, x, k_pages, v_pages,
             pools = kp, vp
             if cfg.hybrid:
                 (kp, states), (vp, windows) = ((p["kv"], p["ssm"]) for p in (kp, vp))
-            elif cfg.layered or cfg.conv:
+            elif cfg.layered or cfg.conv or cfg.linear:
                 kp, vp = kp[name], vp[name]
-            y = cm.rms_norm(lp["attn_norm"], x, cfg.norm_eps)
+            y = _norm(lp["attn_norm"], x, cfg)
             q, k, v = qkv_project(lp, y, cfg, kind)
             q, k = qk_positioned(lp, q, k, cfg, positions, kind)
             sink = lp.get("attn_sink")
@@ -1080,7 +1235,8 @@ def _dense_layers(params: dict, cfg: DecoderConfig, x, k_pages, v_pages,
                                         sink)
                 keys, values = k, v
                 if not block:
-                    keys = _read_keys(kp, li, table, sp.dk).astype(x.dtype)
+                    keys = _read_keys(kp, li, table, sp.dk,
+                                      sp.kv_heads).astype(x.dtype)
                     values = _read_rows(vp, li, table, sp.kv_heads).astype(x.dtype)
                 return cm.attention(q, jnp.repeat(keys, group, axis=2),
                                     jnp.repeat(values, group, axis=2), mask,
@@ -1095,6 +1251,7 @@ def _dense_layers(params: dict, cfg: DecoderConfig, x, k_pages, v_pages,
                         1, lanes, cfg.heads, sp.dv),
                     attend(q[:, lanes:], chunk.table, chunk.off, chunk.mask)],
                     axis=1)
+            attn = attn_out_gate(lp, y, attn, cfg)
             out = _scaled(cm.dense(lp["wo"], attn.reshape(b, t, cfg.heads * sp.dv)),
                           cfg.attention_out_multiplier)
             if cfg.hybrid:
@@ -1103,7 +1260,7 @@ def _dense_layers(params: dict, cfg: DecoderConfig, x, k_pages, v_pages,
                     token_mask, kernel, kernel_interpret)
                 out = out + mixed
                 kp, vp = {"kv": kp, "ssm": states}, {"kv": vp, "ssm": windows}
-            elif cfg.layered or cfg.conv:
+            elif cfg.layered or cfg.conv or cfg.linear:
                 kp, vp = {**pools[0], name: kp}, {**pools[1], name: vp}
             return ffn(lp, x + out, kp, vp, ei)
         return layer
@@ -1149,7 +1306,8 @@ def _scan_run(layer, carry, stack: dict, first: int, stop: int,
 
 def _ssm_operands(cfg: DecoderConfig, rows, fresh) -> dict:
     """``_dense_layers``' state operands: none for a model that caches no
-    state a sequence (``cfg.stateful``: a mixer, conv layers)."""
+    state a sequence (``cfg.stateful``: a mixer, conv layers, linear
+    attention layers)."""
     if not cfg.stateful:
         return {}
     if rows is None:

@@ -221,6 +221,13 @@ def _walk_budget() -> int:
 #: sixteen (l6, 16 lanes: 236 us a call at 4, 178 at 8, 155 at 16, 161 at 32)
 _PAGED_GROUP_MAX = 16
 
+#: and where the pools hold ONE K/V head wider than a 128-lane run (a head a
+#: layer of a pool: ``GqaSpec.split_heads``), so that a page adds only
+#: ``page`` columns under the call's few rows — measured there and nowhere
+#: else: 128 lanes at 3,072 keys of 256, 8 query heads a call, 3,609 us at
+#: 16, 3,218 at 32, 2,965 at 64 (PERF.md, PR 49)
+_PAGED_ONE_HEAD_GROUP_MAX = 64
+
 #: and a chunk tile's, a K/V head at a time, where a page adds only ``page``
 #: columns a head: a 512-token call at offset 4,096 / 11,776 on MiMo's full
 #: layers 1,926 / 5,019 us at 16, 1,614 / 4,154 at 32, 1,669 / 4,022 at 64;
@@ -283,7 +290,8 @@ def _page_group(rows: int, page: int, kvh: int, dh: int, itemsize: int,
     at once (scores, probabilities, the mask's bounds: four). All heads at
     once, a page is ``page * kvh`` columns under all ``rows``: a decode
     step's 8-64 folded rows leave room for many pages, so its groups stop at
-    ``_PAGED_GROUP_MAX``. A K/V head at a time (``per_head``, a chunk tile)
+    ``_PAGED_GROUP_MAX`` (``_PAGED_ONE_HEAD_GROUP_MAX`` over pools of one K/V
+    head of more than 128 lanes). A K/V head at a time (``per_head``, a chunk tile)
     it is ``page`` columns under that head's ``rows / kvh`` rows, and the
     head's K and V rows once more as read out of the scratch."""
     cols = page * kvh
@@ -293,7 +301,9 @@ def _page_group(rows: int, page: int, kvh: int, dh: int, itemsize: int,
                   + page * (rows // kvh) * 16)
         return max(1, min(_PAGED_CHUNK_GROUP_MAX, _walk_budget() // a_page))
     a_page = cols * (kv * (4 * itemsize + 8) + rows * 16)
-    return max(1, min(_PAGED_GROUP_MAX, _walk_budget() // a_page))
+    most = (_PAGED_ONE_HEAD_GROUP_MAX if kvh == 1 and dh > 128
+            else _PAGED_GROUP_MAX)
+    return max(1, min(most, _walk_budget() // a_page))
 
 
 def _lane_runs(width: int) -> list[tuple[int, int]]:

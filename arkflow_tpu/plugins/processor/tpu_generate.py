@@ -36,9 +36,11 @@ Config:
                              # on one chip only; so does the hybrid block
                              # (mamba_*: a Mamba-2 mixer beside attention,
                              # a recurrent state a slot; needs
-                             # prefill_chunk > 0) and conv layers
+                             # prefill_chunk > 0), conv layers
                              # (layer_types: conv, conv_L_cache: a window
-                             # of gated inputs a slot)
+                             # of gated inputs a slot) and Gated DeltaNet
+                             # layers (layer_types: linear_attention,
+                             # linear_*: a float32 matrix state a slot)
     text_field: __value__
     tokenizer: meta-llama/Llama-3-8B     # optional (hash fallback otherwise)
     max_input: 256
@@ -174,20 +176,21 @@ class TpuGenerateProcessor(Processor):
                     "(remove mesh)")
         if getattr(self.cfg, "stateful", False):
             # before the host init too: the hybrid block's state, conv
-            # layers' windows (a pool a slot beside the K/V pages)
+            # layers' windows, linear attention layers' matrix states (a
+            # pool a slot beside the K/V pages)
             from arkflow_tpu.models.paged_decode import cache_spec
 
             pools = ", ".join(pool.name for pool in cache_spec(self.cfg))
             if serving != "continuous":
                 raise ConfigError(
-                    "a model with the hybrid block (mamba_d_ssm > 0) or "
-                    f"conv layers (pools {pools}) generates through "
+                    "a model with the hybrid block (mamba_d_ssm > 0), conv "
+                    f"or linear_attention layers (pools {pools}) generates through "
                     "serving: continuous only: the batch path's contiguous "
                     "cache carries no recurrent state")
             if mesh_config:
                 raise ConfigError(
-                    "a model with the hybrid block or conv layers (pools "
-                    f"{pools}) is served on one chip: the state pool and "
+                    "a model with the hybrid block, conv or linear_attention "
+                    f"layers (pools {pools}) is served on one chip: the state pool and "
                     "the mixer's channels have no sharding over a mesh yet "
                     "(remove mesh)")
         if getattr(self.cfg, "by_runs", False) and not self.cfg.latent:

@@ -294,7 +294,8 @@ class GenerationServer:
         if self._layered:
             self._refuse_layered(prefix_cache_pages, speculative_tokens)
         #: a state a slot beside the K/V pages (``cache_spec``'s per-slot
-        #: pool: the hybrid block's ``ssm``, conv layers' ``conv``)
+        #: pool: the hybrid block's ``ssm``, conv layers' ``conv``, linear
+        #: attention layers' ``gdn``)
         self._stateful = bool(cfg.stateful)
         if self._stateful:
             self._refuse_stateful(prefix_cache_pages, speculative_tokens)
@@ -718,15 +719,16 @@ class GenerationServer:
         with yet, and why: a state is overwritten by every token, so what
         is benign for K/V rows (a stale row, an aliased page) is not for it.
         A lane that rides one step too long is the third such thing: such
-        a model serves in lockstep (``_ahead``). The hybrid block's state
-        and conv layers' windows alike (the messages name the pools)."""
+        a model serves in lockstep (``_ahead``). The hybrid block's state,
+        conv layers' windows and linear attention layers' matrix states
+        alike (the messages name the pools)."""
         pools = self._pool_names()
         if self.mesh is not None:
             raise ConfigError(
-                "a model with the hybrid block (mamba_d_ssm > 0) or conv "
-                f"layers (pools {pools}) is served on one chip: the state "
-                "pool and the mixer's channels have no sharding over a mesh "
-                "yet (remove mesh)")
+                "a model with the hybrid block (mamba_d_ssm > 0), conv or "
+                f"linear_attention layers (pools {pools}) is served on one "
+                "chip: the state pool and the mixer's channels have no "
+                "sharding over a mesh yet (remove mesh)")
         if self.prefill_chunk <= 0:
             raise ConfigError(
                 "a model that carries a recurrent state (pools "
@@ -1421,8 +1423,8 @@ class GenerationServer:
                 f"{what} ships K/V page slabs; a recurrent state (pools "
                 f"{self._pool_names()}) has no wire form yet, and pages "
                 "without it cannot be decoded from — a model with the "
-                "hybrid block or conv layers prefills and decodes on the "
-                "same server")
+                "hybrid block, conv or linear_attention layers prefills and "
+                "decodes on the same server")
         if self.cfg.latent:
             raise ConfigError(
                 f"{what} ships per-head K/V page slabs split along the "
@@ -1682,7 +1684,10 @@ class GenerationServer:
         the slot that is, ``state`` the row itself over the pool's layers
         (the hybrid block: [layers, heads, d_state, d_head] float32; conv
         layers: [conv layers, conv_L_cache - 1, dim], the last gated inputs
-        oldest first). A finished tenant's row stays as its last step left it —
+        oldest first; linear attention layers: [linear layers, value heads,
+        key dim, value dim] float32, and under ``window`` the conv's last
+        projected inputs [linear layers, linear_conv_kernel_dim - 1,
+        channels], oldest first). A finished tenant's row stays as its last step left it —
         after its prompt and all but the last of its tokens — until the next
         tenant's first chunk. Call between steps: a step in flight holds the
         donated pools."""
@@ -1690,10 +1695,12 @@ class GenerationServer:
             raise ConfigError("slot_state: this model carries no recurrent state")
         prompt, tokens, tenancy = self._state_tenant[slot]
         row = jnp.asarray(slot + 1, jnp.int32)  # an operand: one program
-        return {"prompt": prompt, "tokens": tokens, "tenancy": tenancy,
-                "state": jax.device_get(self.k_pages[
-                    next(p.name for p in cache_spec(self.cfg) if p.per_slot)
-                ][:, row])}
+        pool = next(p.name for p in cache_spec(self.cfg) if p.per_slot)
+        out = {"prompt": prompt, "tokens": tokens, "tenancy": tenancy,
+               "state": jax.device_get(self.k_pages[pool][:, row])}
+        if self.cfg.linear:  # the pool's second array: the conv windows
+            out["window"] = jax.device_get(self.v_pages[pool][:, row])
+        return out
 
     def _slide_window(self, slot: int, first: int, last: int) -> None:
         """The slot's window pages for a step whose queries sit at positions

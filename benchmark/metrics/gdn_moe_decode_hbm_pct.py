@@ -1,0 +1,25 @@
+"""gdn_moe_decode_hbm_pct — share of the chip's HBM bandwidth a whole decode step reaches (Qwen3-Next's keys).
+
+Needed bytes of one lockstep decode step (``lib/costs_gdn_gqa_moe.
+decode_step_bytes``: every weight once — the head, the mixers, the routers
+and shared gates float32 —, the held experts the step HIT plus the shared
+one, each decoding lane's float32 states and conv windows read and written
+on the six linear layers, the K/V rows its queries may attend on the two
+full layers) over 819 GB/s (``peaks.json``) and over the ``_decode``
+program's device time (``decode_step_ms``'s source: the module's executions
+in the trace).
+"""
+
+from benchmark.lib.costs_gdn_gqa_moe import decode_step_bytes, sizes_of
+from benchmark.lib.costs_mla_moe import decode_context, decode_routing
+from benchmark.lib.readers import module_ms
+
+
+def read(view):
+    ms, ctx, s = module_ms(view, r"jit__decode"), decode_context(view), sizes_of(view)
+    routing = decode_routing(view)
+    if ms is None or ctx is None or s is None or routing is None:
+        return None
+    nbytes = decode_step_bytes(experts_hit=routing[0], lanes=ctx[0],
+                               context=ctx[1], **s)
+    return 100.0 * nbytes / view.peaks["hbm_bytes_per_s"] / (ms * 1e-3)

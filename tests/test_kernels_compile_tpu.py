@@ -904,3 +904,131 @@ def test_conv_steps_carry_pools_and_stacks_whole(v5e):
             assert name in text, name
         assert step.memory_analysis().temp_size_in_bytes < 300 * 1024 * 1024, \
             step.memory_analysis().temp_size_in_bytes
+
+
+# -- the matrix-state kind (Qwen3-Next-80B-A3B widths, 128 slots, chunk 512) ----
+
+QWEN3NEXT = dict(vocab_size=18992, dim=2048, layers=8, heads=16, kv_heads=2,
+                 head_dim=256, ffn=5120, max_seq=262144, rope_theta=10000000,
+                 norm_eps=1e-6, qk_norm=True, partial_rotary_factor=0.25,
+                 attention_gate_type="elementwise", norm_unit_offset=True,
+                 layer_types=(("linear_attention",) * 3 + ("full_attention",)) * 2,
+                 linear_num_key_heads=16, linear_num_value_heads=32,
+                 linear_key_head_dim=128, linear_value_head_dim=128,
+                 linear_conv_kernel_dim=4, n_routed_experts=512,
+                 experts_held=(0, 64), num_experts_per_tok=10, n_shared_experts=1,
+                 shared_expert_gate=True, moe_intermediate_size=512,
+                 first_k_dense_replace=0, scoring_func="softmax",
+                 topk_method="greedy")
+#: 128 slots x 576 kept pages + scratch: every slot's whole table
+QWEN3NEXT_SLOTS, QWEN3NEXT_TABLE, QWEN3NEXT_CHUNK = 128, 576, 512
+
+
+@pytest.mark.parametrize("step", ["update", "scan"])
+def test_gdn_kernels_compile_in_place(v5e, step):
+    """The decode update over 128 lanes and the chunk scan of one 512-token
+    chunk at Qwen3-Next's mixer sizes, on the whole 1.62 GB state pool: the
+    pool is aliased to the output, and k and q reach the update as ROWS (a
+    column operand a head would be padded to 64 x its bytes in HBM)."""
+    from arkflow_tpu.ops import gdn_scan as gs
+
+    f32 = jnp.float32
+    pool = ((6, QWEN3NEXT_SLOTS + 1, 32, 128, 128), f32)
+    if step == "update":
+        b = QWEN3NEXT_SLOTS
+        fn = lambda st, layer, rows, q, k, v, g, beta: gs.gdn_state_update(  # noqa: E731
+            st, layer, rows, q, k, v, g, beta, kernel=True)
+        heads = ((b, 32, 128), f32)
+        shapes = (pool, ((), I32), ((b,), I32), heads, heads, heads,
+                  ((b, 32), f32), ((b, 32), f32))
+    else:
+        t = QWEN3NEXT_CHUNK
+        fn = lambda st, layer, rows, fr, q, k, v, g, beta: gs.gdn_chunk_scan(  # noqa: E731
+            st, layer, rows, fr, q, k, v, g, beta, kernel=True)
+        heads = ((1, t, 32, 128), f32)
+        shapes = (pool, ((), I32), ((1,), I32), ((1,), jnp.bool_), heads, heads,
+                  heads, ((1, t, 32), f32), ((1, t, 32), f32))
+    one = SingleDeviceSharding(v5e[0])
+    compiled = jax.jit(fn, donate_argnums=(0,)).lower(
+        *[jax.ShapeDtypeStruct(s, d, sharding=one) for s, d in shapes]).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert ("gdn_state_update" if step == "update" else "gdn_chunk_scan") in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 1024 * 1024
+
+
+@pytest.mark.parametrize("kvh,pool_kvh,relaid", [(2, 2, True), (2, 1, False)],
+                         ids=["joined", "a-head-a-layer"])
+def test_a_head_of_256_on_two_kv_heads_is_viewed_in_place_a_head_a_layer(
+        v5e, kvh, pool_kvh, relaid):
+    """What ``GqaSpec.split_heads`` is for: pools [.., 2 heads, 256] are
+    re-laid WHOLE for every call of the paged kernel (two pool-sized
+    temporaries), pools [.., 1, 256] — a head a layer — are not."""
+    pages = 4097
+    pool = ((2 * kvh // pool_kvh, pages, PAGE, pool_kvh, 256), BF16)
+    h = 16 * pool_kvh // kvh
+    compiled = _compile(
+        lambda q, k, v, layer, table, off: paged_flash_attention(
+            q, k, v, layer, table, off),
+        v5e, ((128, 1, h, 256), BF16), pool, pool, ((), I32), ((128, 576), I32),
+        ((128,), I32))
+    one_pool = 2 * pages * PAGE * kvh * 256 * 2
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert (temp > one_pool) == relaid, (temp, one_pool)
+
+
+def test_gdn_steps_carry_pools_and_stacks_whole(v5e):
+    """The ``_decode`` (128 lanes) and ``_chunk`` (1 x 512) programs of the
+    Qwen3-Next cut as the server jits them, pools donated: the page walk, the
+    expert kernel and the delta rule's kernel are in the text, and the
+    temporaries stay under 300 MB — no pool (1.62 GB of float32 states, 4 x
+    1.21 GB of K/V pages a head a layer) and no run's experts (1.2 GB a run
+    of six) is copied for a step."""
+    from arkflow_tpu.models import decoder as dec
+    from arkflow_tpu.models.paged_decode import (init_page_pool, paged_decode_step,
+                                                 paged_prefill_chunk)
+
+    cfg = dec.DecoderConfig(**QWEN3NEXT)
+    repl = SingleDeviceSharding(v5e[0])
+
+    def struct(a, dtype=None):
+        return jax.ShapeDtypeStruct(a.shape, dtype or a.dtype, sharding=repl)
+
+    params = jax.tree_util.tree_map(
+        struct, jax.eval_shape(lambda: dec.init(jax.random.PRNGKey(0), cfg)),
+        dec.serve_dtypes(cfg))
+    kp, vp = jax.tree_util.tree_map(struct, jax.eval_shape(
+        lambda: init_page_pool(cfg, 1 + QWEN3NEXT_SLOTS * QWEN3NEXT_TABLE, PAGE,
+                               slots=QWEN3NEXT_SLOTS)))
+    assert kp["kv"].shape == vp["kv"].shape == (4, 1 + 128 * 576, 16, 1, 256)
+    assert kp["gdn"].shape == (6, 129, 32, 128, 128)
+    assert vp["gdn"].shape == (6, 129, 3, 8192)
+    kern = dict(attention_kernel="paged")
+
+    def decode(p, tok, lens, act, table, kp, vp):
+        return paged_decode_step(p, cfg, tok, lens, act, table, kp, vp,
+                                 return_logits=True, **kern)
+
+    def chunk(p, ids, off, clen, table, rows, kp, vp):
+        return paged_prefill_chunk(p, cfg, ids, off, clen, table, kp, vp,
+                                   ssm_rows=rows, **kern)
+
+    def compiled(fn, *operands):
+        n = len(operands)
+        return jax.jit(fn, donate_argnums=(n + 1, n + 2)).lower(
+            params, *[jax.ShapeDtypeStruct(s, d, sharding=repl)
+                      for s, d in operands], kp, vp).compile()
+
+    s = QWEN3NEXT_SLOTS
+    for kernel, step in (
+            ("gdn_state_update", compiled(
+                decode, ((s,), I32), ((s,), I32), ((s,), jnp.bool_),
+                ((s, QWEN3NEXT_TABLE), I32))),
+            ("gdn_chunk_scan", compiled(
+                chunk, ((1, QWEN3NEXT_CHUNK), I32), ((1,), I32), ((1,), I32),
+                ((1, QWEN3NEXT_TABLE), I32), ((1,), I32)))):
+        text = step.as_text()
+        for name in ("paged_flash_attention", "moe_expert_swiglu", kernel):
+            assert name in text, name
+        assert step.memory_analysis().temp_size_in_bytes < 300 * 1024 * 1024, \
+            step.memory_analysis().temp_size_in_bytes

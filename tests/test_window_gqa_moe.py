@@ -673,14 +673,15 @@ def test_a_per_head_model_is_served_with(ok):
     ({"layer_types": (SLIDING, FULL), "sliding_window": 9, "swa_heads": 2},
      "latent-attention model"),
     ({"layer_types": (FULL,)}, "names each of the 2 layers"),
-    ({"layer_types": (FULL, "linear_attention")}, "names each of the 2 layers"),
+    ({"layer_types": (FULL, "linear_attention")}, "a linear_attention layer needs"),
+    ({"layer_types": (FULL, "ring_attention")}, "names each of the 2 layers"),
     ({"layer_types": (SLIDING, FULL), "sliding_window": 9, "num_experts": 4},
      "Switch"),
     ({"layer_types": (SLIDING, FULL), "sliding_window": 9,
       "use_ring_attention": True}, "ring attention"),
     ({**ROUTED, "num_experts": 4}, "Switch"),
     ({"qk_norm": True, "num_experts": 4}, "Switch"),
-    ({**ROUTED, "first_k_dense_replace": 0}, "leading dense"),
+    ({**ROUTED, "first_k_dense_replace": 2}, "leading dense"),
     ({**ROUTED, "scoring_func": "softmax"}, "sigmoid"),
     ({**ROUTED, "experts_held": (6, 4)}, "experts_held"),
     ({"rope_interleave": True}, "rope_interleave"),

@@ -83,6 +83,17 @@ CELLS = {
     # kernel-alone table was measured; the cell serves 288 since (512 new)
     "lfm2_l12": dict(h=32, kvh=8, dk=64, dv=64, lanes=128, cols=304, chunk=256,
                      offsets=(512, 2048, 4608), decode_ctx=(512, 2048, 4863)),
+    # 2 K/V heads of 256 (keys AND values): the pools hold a head a layer,
+    # [heads x layers, pages, 16, 1, 256], and the kernel is called a head at
+    # a time (``split``: ``GqaSpec.split_heads``); ``_joined`` is what the
+    # pools would be without that, [layers, pages, 16, 2, 256], which XLA
+    # re-lays whole for every call (PERF.md section 6, PR 49)
+    "qwen3next_l8": dict(h=16, kvh=2, dk=256, dv=256, split=True, lanes=128,
+                         cols=576, chunk=512, offsets=(2048, 7680),
+                         decode_ctx=(3072,)),
+    "qwen3next_l8_joined": dict(h=16, kvh=2, dk=256, dv=256, lanes=128,
+                                cols=576, chunk=512, offsets=(2048,),
+                                decode_ctx=(3072,)),
     # the latent kernel (``lat``: one shared row a token of that width beside
     # a rope key of ``rope``; ``topk``: under an indexer's choice of so many)
     "kanana2_l6": dict(h=32, lat=512, rope=64, lanes=16, cols=136, chunk=128,
@@ -134,6 +145,10 @@ def _pools(cell, seed: int, tiny: bool, rope_held: bool = True,
     keys = jax.random.split(jax.random.PRNGKey(seed), 3)
     fill = lambda key, shape: (jax.random.normal(key, shape, jnp.bfloat16) * 0.5)  # noqa: E731
     flat = row_major and dk == dv < 128
+    if cell.get("split"):  # a head a layer: head j of layer l at 2 j + l
+        k = fill(keys[0], (2 * kvh, 1 + lanes * cols, PAGE, 1, held))
+        v = fill(keys[1], (2 * kvh, 1 + lanes * cols, PAGE, 1, dv))
+        return k, v, None, lanes, cols
     k = fill(keys[0], (2 * parts, 1 + lanes * cols, PAGE,
                        *((kvh * held,) if flat else (kvh, held))))
     v = fill(keys[1], (2, 1 + lanes * cols, PAGE,
@@ -317,6 +332,7 @@ def main() -> int:
         for ceiling in ceilings:
             if ceiling:
                 ra._PAGED_GROUP_MAX = ra._PAGED_CHUNK_GROUP_MAX = ceiling
+                ra._PAGED_ONE_HEAD_GROUP_MAX = ceiling
                 ra._NARROW_GROUP_MAX = ceiling
                 if hasattr(ra, "_latent_group"):  # the walk's group, a window's too
                     ra._latent_group = lambda *_, ceiling=ceiling: ceiling
@@ -332,6 +348,13 @@ def main() -> int:
                         interpret=args.interpret, allowed=(allowed or [None])[0])
                 if args.form == "gather":
                     return _gathered(q, k, v, layer, table, ctx, cell)
+                if cell.get("split"):  # as ``paged_decode._attend_paged``
+                    g = h // cell["kvh"]
+                    return jnp.concatenate([
+                        ra.paged_flash_attention.__wrapped__(
+                            q[:, :, j * g:(j + 1) * g], k, v, layer + 2 * j,
+                            table, ctx, interpret=args.interpret)
+                        for j in range(cell["kvh"])], axis=2)
                 return ra.paged_flash_attention.__wrapped__(
                     q, k, v, layer, table, ctx, interpret=args.interpret,
                     window=window, sink=sink)
@@ -361,16 +384,19 @@ def main() -> int:
                                 -(-tile_c * hpr // 16) * 16, k.shape[-1] // run,
                                 PAGE, k.shape[-1], 2, window, tile_c))
             elif not latent and hasattr(ra, "per_kv_head"):  # cuts tiles per head
-                tile_c = ra.query_tile(c, h)
-                per_head = ra.per_kv_head(tile_c, h, cell["kvh"])
+                # a call's heads: a K/V head's own where the pools hold a head a layer
+                hc, kc = (h // cell["kvh"], 1) if cell.get("split") else (h, cell["kvh"])
+                tile_c = ra.query_tile(c, hc)
+                per_head = ra.per_kv_head(tile_c, hc, kc)
                 line.update(
                     tile_c=tile_c, tiles=-(-c // tile_c), per_kv_head=per_head,
                     group=ra._page_group(
-                        tile_c * h, PAGE, cell["kvh"], 128 * -(-cell["dk"] // 128),
+                        tile_c * hc, PAGE, kc, 128 * -(-cell["dk"] // 128),
                         2, cell["dv"], per_head=per_head))
             if args.interpret:
                 jax.block_until_ready(jax.jit(call)(1, *operands))
-            if args.check and not (window or sink is not None):
+            if args.check and not (window or sink is not None
+                                   or cell.get("split")):
                 # a ring's table and a sink have no plain twin here: the
                 # tests hold them (tests/test_paged_kernel.py)
                 got = jax.jit(call)(1, *operands).astype(jnp.float32)
