@@ -429,15 +429,15 @@ class GenerationServer:
         #   queues a step's live lanes into shared expert capacity, so
         #   either serves in lockstep;
         # - a budget's end is known a step early (the lane is masked out of
-        #   the step behind), an EOS is not: with a live ``eos_id`` a lane
-        #   that finished at N still rides N+1 and its token is dropped at
-        #   apply. Exact for greedy dense and dropless-routed lanes; a
-        #   recurrent state would be advanced past its end;
-        # - a model that carries a recurrent state serves in lockstep
-        #   whatever its ``eos_id``: with ``eos_id`` < 0 running ahead is
-        #   exact for it too (its tests pass either way), but its one
-        #   benchmark cell cannot judge a speed-up yet (PERF.md, PR 39), so
-        #   the state-kind condition stays whole until it can;
+        #   the step behind: it reads and writes the scratch row, a state's
+        #   too), an EOS is not: with a live ``eos_id`` a lane that finished
+        #   at N still rides N+1 and its token is dropped at apply. Exact
+        #   for greedy dense and dropless-routed K/V lanes, whose stale row
+        #   nobody reads; a recurrent state (``cfg.stateful``) would be
+        #   advanced past its sequence's end, which ``slot_state`` shows. So
+        #   a model that carries a state runs ahead where no EOS is live
+        #   (``eos_id`` < 0: every end is a budget's) and serves in lockstep
+        #   where one is;
         # - speculative decoding restructures the decode step: lockstep.
         self.dispatch_depth = int(dispatch_depth)
         if self.dispatch_depth < 1:
@@ -451,7 +451,7 @@ class GenerationServer:
             self.dispatch_depth > 1 and self.temperature == 0.0
             and self.speculative_tokens == 0
             and not getattr(cfg, "num_experts", 0)
-            and not self._stateful)
+            and not (self._stateful and self.eos_id >= 0))
         # where a decode step is due and a slot is prefilling, ONE program
         # carries the step's lanes and the prompt's next chunk through one
         # pass over the weights (``paged_fused_step``; ``_step``): a greedy
@@ -718,8 +718,10 @@ class GenerationServer:
         """What a model that carries a recurrent state a slot is not served
         with yet, and why: a state is overwritten by every token, so what
         is benign for K/V rows (a stale row, an aliased page) is not for it.
-        A lane that rides one step too long is the third such thing: such
-        a model serves in lockstep (``_ahead``). The hybrid block's state,
+        A lane that rides one step too long is the third such thing, and
+        only an EOS makes one: such a model runs ahead of the device where
+        ``eos_id`` < 0 and serves in lockstep otherwise (``_ahead``; no
+        refusal here, the server decides). The hybrid block's state,
         conv layers' windows and linear attention layers' matrix states
         alike (the messages name the pools)."""
         pools = self._pool_names()
@@ -2231,9 +2233,11 @@ class GenerationServer:
         tokens from its output ON the device (packed as -1), so the queue
         holds the successor before the host fetches. What the host knows a
         step early it acts on: a lane whose budget the step in flight
-        exhausts is masked out. An EOS it cannot know: such a lane rides
-        and its token is dropped at apply (request identity is snapshotted);
-        where that is not exact the server never runs ahead (``_ahead``)."""
+        exhausts is masked out (its recurrent state, if the model carries
+        one, stays as its last token left it). An EOS it cannot know: such a
+        lane rides and its token is dropped at apply (request identity is
+        snapshotted); where that is not exact — a state behind a live
+        ``eos_id`` — the server never runs ahead (``_ahead``)."""
         key, span = ("decode",), None
         if riding >= 0:
             span = self._next_span(riding)

@@ -716,12 +716,14 @@ PROMPTS = [np.random.RandomState(s).randint(1, 128, n).tolist()
            for s, n in ((1, 44), (2, 23), (3, 61), (4, 9), (5, 17))]
 
 
-def test_the_server_serves_in_lockstep_and_reuses_slots():
-    """Five prompts over three slots: the model is stateful (lockstep), and
-    each request's tokens are those of a server it has to itself."""
+def test_the_server_runs_ahead_and_reuses_slots():
+    """Five prompts over three slots: the model is stateful and, no EOS
+    being live, runs one step ahead of the device (with one it serves in
+    lockstep); each request's tokens are those of a server it has to
+    itself."""
     proc = _proc()
     server = proc._server
-    assert server._stateful and not server._ahead and not server._layered
+    assert server._stateful and server._ahead and not server._layered
     assert not server._fuses
 
     async def run(srv, prompts):
@@ -731,6 +733,7 @@ def test_the_server_serves_in_lockstep_and_reuses_slots():
     alone = [asyncio.run(run(_proc()._server, [p]))[0] for p in PROMPTS[3:]]
     assert outs[3:] == alone and [len(o) for o in outs] == [6] * 5
     assert max(t[2] for t in server._state_tenant) >= 2       # a slot was reused
+    assert server._steps_ahead > 0
     assert len(server._free_pages) == server.num_pages - 1
     st = server.slot_state(0)
     assert st["state"].shape == (3, 4, 16, 128) and st["state"].dtype == np.float32

@@ -6,12 +6,18 @@ prefill) before step N is waited for, fetched and applied
 ``dispatch_depth: 1`` (lockstep) serves, request by request; where running
 ahead is not exact the server falls back to lockstep by what it observes;
 a failure with two steps in flight fails both steps' requests and leaves the
-page ledger whole; every step is still observed once, under its kind.
+page ledger whole; every step is still observed once, under its kind. A
+model that carries a recurrent state a slot (a Mamba-2 state, conv windows, a
+delta rule's matrix state) runs ahead where no EOS is live, and leaves in
+every slot's row of the state pool what lockstep leaves.
 """
 
 from __future__ import annotations
 
 import asyncio
+import json
+import os
+import sys
 import time
 
 import jax
@@ -23,6 +29,8 @@ from arkflow_tpu.models import get_model
 from arkflow_tpu.obs import global_registry
 from arkflow_tpu.tpu.health import HealthConfig
 from arkflow_tpu.tpu.serving import GenerationServer
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 DENSE = dict(vocab_size=128, dim=64, layers=2, heads=4, kv_heads=2, ffn=96,
              max_seq=64)
@@ -42,6 +50,30 @@ HYBRID = dict(vocab_size=128, dim=64, layers=2, heads=4, kv_heads=2, head_dim=8,
               ssm_in_multiplier=0.25, ssm_out_multiplier=0.4,
               ssm_multipliers=[0.35, 0.25, 0.18, 0.5, 0.35],
               mlp_multipliers=[0.18, 0.3], lm_head_multiplier=0.0078125)
+#: gated short convolutions among GQA layers of 64-wide heads, two dense
+#: layers and then routed experts (the LFM2 layout, ``test_conv_gqa_moe.py``
+#: at four layers): a window of two gated inputs a slot
+CONV = dict(vocab_size=128, dim=32, layers=4, heads=4, kv_heads=2, head_dim=64,
+            ffn=64, max_seq=256, rope_theta=1e6, norm_eps=1e-5, qk_norm=True,
+            layer_types=("conv", "conv", "full_attention", "conv"),
+            conv_L_cache=3, n_routed_experts=8, num_experts_per_tok=2,
+            n_shared_experts=0, moe_intermediate_size=16,
+            first_k_dense_replace=2, norm_topk_eps=1e-6, router_bias_std=0.1)
+#: Gated DeltaNet layers among gated GQA layers, every layer routed (the
+#: Qwen3-Next layout, ``test_gdn_gqa_moe.py`` at three layers and heads of
+#: 128): a float32 matrix state and a three-row window a slot
+GDN = dict(vocab_size=128, dim=32, layers=3, heads=4, kv_heads=2, head_dim=128,
+           ffn=64, max_seq=256, rope_theta=1e7, norm_eps=1e-6, qk_norm=True,
+           partial_rotary_factor=0.25, attention_gate_type="elementwise",
+           norm_unit_offset=True,
+           layer_types=("linear_attention", "full_attention", "linear_attention"),
+           linear_num_key_heads=2, linear_num_value_heads=4,
+           linear_key_head_dim=16, linear_value_head_dim=128,
+           linear_conv_kernel_dim=4, n_routed_experts=16, num_experts_per_tok=3,
+           n_shared_experts=1, shared_expert_gate=True, moe_intermediate_size=16,
+           first_k_dense_replace=0, scoring_func="softmax", topk_method="greedy")
+#: the three kinds of state a slot
+STATE_KINDS = {"hybrid": HYBRID, "conv": CONV, "gdn": GDN}
 #: the capacity-based Switch block: a step's live lanes share expert capacity
 SWITCH = dict(vocab_size=128, dim=32, layers=2, heads=2, kv_heads=1, ffn=48,
               max_seq=64, num_experts=4)
@@ -131,15 +163,20 @@ def _ahead_counts(name: str) -> dict:
     (DENSE, dict(eos_id=57)),
     (DENSE, dict(eos_id=24, prefill_chunk=8)),
     (ROUTED, dict(prefill_chunk=8, eos_id=1)),
+    (HYBRID, dict(prefill_chunk=8)),
+    (CONV, dict(prefill_chunk=8)),
+    (GDN, dict(prefill_chunk=8)),
 ], ids=["dense-chunked", "dense-prefix-cache", "dense-one-shot", "dense-paged",
-        "routed", "dense-live-eos", "dense-live-eos-first", "routed-live-eos"])
+        "routed", "dense-live-eos", "dense-live-eos-first", "routed-live-eos",
+        "recurrent-state", "conv-windows", "delta-rule-state"])
 def test_running_ahead_serves_what_lockstep_serves(model_kw, server_kw):
     """Token streams of every request equal ``dispatch_depth: 1``'s: dense
     greedy with chunked prefill and more prompts than slots, the prefix
-    cache, one-shot prefill, the paged kernel, a dropless-routed model, and
-    a live EOS (a lane rides one step too long and its token is dropped).
-    The counter ``arkflow_gen_steps_ahead_total`` moves with the steps that
-    ran ahead."""
+    cache, one-shot prefill, the paged kernel, a dropless-routed model, a
+    live EOS (a lane rides one step too long and its token is dropped), and
+    a state a slot of each kind with no EOS live (every end is a budget's,
+    known a step early). The counter ``arkflow_gen_steps_ahead_total`` moves
+    with the steps that ran ahead."""
     name = "ahead-" + "-".join(f"{k}{v}" for k, v in sorted(server_kw.items()))
     name += "-" + str(len(model_kw))
     want, ref_steps = _serve(_server(model_kw, dispatch_depth=1, **server_kw))
@@ -157,7 +194,8 @@ def test_running_ahead_serves_what_lockstep_serves(model_kw, server_kw):
     assert added == ran_ahead
     assert server._steps_ahead == sum(ran_ahead.values()) > 0
     # a dense greedy server that prefills in chunks lets them ride its decode
-    # steps, through one program too; a latent routed model's alternate
+    # steps, through one program too; a latent routed model's, and those of
+    # a model with a state a slot, alternate
     fuses = model_kw is DENSE and server_kw.get("prefill_chunk", 4) > 0
     assert server._fuses == fuses and (ran_ahead["fused"] > 0) == fuses
     assert server._fused is None or server._fused.jitted._cache_size() == 1
@@ -235,22 +273,24 @@ def test_every_seam_is_crossed_ahead():
     (DENSE, dict(temperature=1.2, top_k=8, seed=42)),
     (DENSE, dict(speculative_tokens=2)),
     (HYBRID, dict(prefill_chunk=8, eos_id=57)),
-    (HYBRID, dict(prefill_chunk=8)),
+    (CONV, dict(prefill_chunk=8, eos_id=57)),
+    (GDN, dict(prefill_chunk=8, eos_id=57)),
+    (HYBRID, dict(prefill_chunk=8, dispatch_depth=1)),
     (SWITCH, dict()),
     (DENSE, dict(dispatch_depth=1)),
 ], ids=["sampling-live-eos", "sampling", "speculative",
-        "recurrent-state-live-eos", "recurrent-state", "switch-capacity",
-        "depth1"])
+        "recurrent-state-live-eos", "conv-windows-live-eos",
+        "delta-rule-state-live-eos", "recurrent-state-depth1",
+        "switch-capacity", "depth1"])
 def test_falls_back_to_lockstep_where_running_ahead_is_not_exact(
         model_kw, server_kw):
     """Sampling (a lane that joins decode one step later would draw from
     another step's key; with a live EOS a dead lane would consume one),
-    speculation, a recurrent state (under a live EOS a lane riding one step
-    too long would advance a finished slot's state; without one the state
-    kind stays on lockstep until its benchmark cell can judge a speed-up),
-    the capacity-based Switch block, and ``dispatch_depth: 1``: no step is
-    enqueued ahead, the
-    counter stays where it was, and the tokens are lockstep's."""
+    speculation, a state a slot of any kind under a live EOS (a lane riding
+    one step too long would advance a finished slot's state), the
+    capacity-based Switch block, and ``dispatch_depth: 1``: no step is
+    enqueued ahead, the counter stays where it was, and the tokens are
+    lockstep's."""
     name = "lockstep-" + "-".join(f"{k}{v}" for k, v in sorted(server_kw.items()))
     want, _ = _serve(_server(model_kw, **{**server_kw, "dispatch_depth": 1}))
     before = _ahead_counts(name)
@@ -260,6 +300,96 @@ def test_falls_back_to_lockstep_where_running_ahead_is_not_exact(
     assert got == want
     assert not any(ahead for _, ahead, _ in steps)
     assert server._steps_ahead == 0 and _ahead_counts(name) == before
+
+
+def _state_books(server) -> dict:
+    """The host's counters of what advanced a state and what a step carried
+    past it, and of the rows a first chunk reset."""
+    books = {(kind, what): c.value for kind, pair in server.m_ssm.items()
+             for what, c in zip(("tokens", "masked"), pair)}
+    return {**books, "resets": server.m_ssm_resets.value}
+
+
+@pytest.mark.parametrize("server_kw", [
+    dict(), dict(decode_kernel="paged", kernel_interpret=True)],
+    ids=["gather", "paged"])
+@pytest.mark.parametrize("kind", list(STATE_KINDS))
+def test_running_ahead_leaves_the_states_lockstep_leaves(kind, server_kw):
+    """Six prompts on two slots (slots handed on, budgets that end on
+    different steps, prompts of one chunk and of three): once the loop has
+    drained, every slot's row of the state pool holds the same tenant and
+    the same state (and window) as the ``dispatch_depth: 1`` server's, bit
+    for bit — a lane masked out of the step behind its budget's end leaves
+    its row alone, a slot's next tenant resets it behind that step, and a
+    lane that joins decode one step later has seen the same tokens. The
+    host's books agree: what advanced a state, what a chunk padded, the
+    resets. Idle lanes a decode step carried are counted a step: running
+    ahead makes the same steps or a few more or fewer (a lane joins one
+    step later), so that count may differ by the steps' difference."""
+    model_kw = STATE_KINDS[kind]
+    name = f"state-{kind}-" + "-".join(sorted(server_kw))
+    ref = _server(model_kw, name=name + "-lockstep", prefill_chunk=8,
+                  dispatch_depth=1, **server_kw)
+    server = _server(model_kw, name=name, prefill_chunk=8, **server_kw)
+    assert server._stateful and server._ahead and not ref._ahead
+    want, ref_steps = _serve(ref)
+    got, steps = _serve(server)
+    assert got == want and [len(o) for o in got] == BUDGETS
+    assert server._steps_ahead > 0 and ref._steps_ahead == 0
+    for slot in range(2):
+        a, b = server.slot_state(slot), ref.slot_state(slot)
+        assert a["tenancy"] == b["tenancy"] >= 2          # the slot was handed on
+        assert a["prompt"] == b["prompt"] and a["tokens"] == b["tokens"]
+        for leaf in [k for k in ("state", "window") if k in b]:
+            np.testing.assert_array_equal(np.asarray(a[leaf], np.float32),
+                                          np.asarray(b[leaf], np.float32))
+    books, ref_books = _state_books(server), _state_books(ref)
+    idle = ("decode", "masked")
+    assert {k: v for k, v in books.items() if k != idle} == {
+        k: v for k, v in ref_books.items() if k != idle}
+    assert books["decode", "tokens"] == sum(n - 1 for n in BUDGETS)
+    assert books["resets"] == len(PROMPTS)
+    decodes = [sum(1 for k, _, _ in run if k == "decode")
+               for run in (steps, ref_steps)]
+    assert books[idle] - ref_books[idle] == 2 * (decodes[0] - decodes[1])
+
+
+@pytest.mark.parametrize("cell", [
+    "falconh1_l4.chat_backlog", "lfm2_l12.draft_backlog",
+    "qwen3next_l8.report_backlog"])
+def test_the_benchmark_s_cells_with_a_state_run_ahead(cell):
+    """The three cells whose models carry a state a slot, as their files
+    configure the server (the rehearsal overlay: tiny widths, the same
+    options): greedy, ``eos_id`` -1, the default ``dispatch_depth``, and
+    routed droplessly where routed at all (``num_experts``, the Switch
+    block's, is 0), so the server runs ahead, and says so."""
+    from arkflow_tpu.components import (Resource, build_component,
+                                        ensure_plugins_loaded)
+
+    sys.path.insert(0, ROOT)
+    from benchmark import run as br
+
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        _, conf = br.lookup(json.load(f), cell)
+    with open(os.path.join(ROOT, conf["file"])) as f:
+        eng, _ = br.build_engine_mapping(json.load(f), 3000000019, True)
+    proc = eng["streams"][0]["pipeline"]["processors"][0]
+    assert proc["eos_id"] == -1 and "dispatch_depth" not in proc
+    ensure_plugins_loaded()
+    server = build_component("processor", proc, Resource())._server
+    assert server._stateful and not server.cfg.num_experts
+    assert server._ahead and server.health_report()["runs_ahead"]
+    longest = int(proc["max_input"])
+    prompts = [[1 + i % 100 for i in range(n)]
+               for n in (longest, 5, longest // 2)]
+
+    async def go():
+        outs = await asyncio.gather(*[server.generate(p, 5) for p in prompts])
+        await server.close()
+        return outs
+
+    outs = asyncio.run(asyncio.wait_for(go(), timeout=180))
+    assert [len(o) for o in outs] == [5] * 3 and server._steps_ahead > 0
 
 
 def test_cold_programs_and_page_pressure_run_in_lockstep():
@@ -506,25 +636,23 @@ def test_gen_steps_ahead_pct_reader(ahead, steps, want):
     observations of ``gen_device_wait``, in percent; nothing on a program
     without the counter (the parent reads 0 of it) or without steps."""
     import importlib.util
-    import json
-    import os
 
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     spec = importlib.util.spec_from_file_location(
         "gen_steps_ahead_pct",
-        os.path.join(root, "benchmark", "metrics", "gen_steps_ahead_pct.py"))
+        os.path.join(ROOT, "benchmark", "metrics", "gen_steps_ahead_pct.py"))
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     got = mod.read(_View(ahead, steps))
     assert got == want if want is None else got == pytest.approx(want)
-    with open(os.path.join(root, "BENCHMARK.json")) as f:
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
     (entry,) = [m for m in bench["per_layer"] if m["name"] == "gen_steps_ahead_pct"]
     assert entry["layer"] == "scheduler, generate"
     assert entry["source"] == "program_counter" and entry["moves"] == "tokens_per_s"
-    # the generate cells whose servers run ahead: not those with a state a
-    # slot (a Mamba mixer, conv windows, a delta rule's matrix state), which
-    # serve in lockstep and read nothing
+    # the generate cells whose servers ran ahead when the list was written:
+    # those with a state a slot (a Mamba mixer, conv windows, a delta rule's
+    # matrix state) run ahead since PR 50 and the counter moves there too,
+    # but the list is the benchmark's to extend (ROADMAP S8 (b))
     assert set(entry["workloads"]) == {
         w["name"] for w in bench["workloads"]
         if w["config"] not in ("bert-base", "falcon-h1-34b-l4", "lfm2-8b-a1b-l12",
