@@ -15,3 +15,8 @@ from arkflow_tpu.obs.trace import (  # noqa: F401
     record_stage,
     stage_span,
 )
+from arkflow_tpu.obs.startup import (  # noqa: F401
+    cold_step,
+    setup_stage,
+    startup_report,
+)

@@ -113,6 +113,7 @@ from arkflow_tpu.batch import DEFAULT_BINARY_VALUE_FIELD, MessageBatch
 from arkflow_tpu.components import Processor, Resource, register_processor
 from arkflow_tpu.errors import ConfigError
 from arkflow_tpu.obs import global_registry
+from arkflow_tpu.obs.startup import setup_stage
 from arkflow_tpu.tpu.bucketing import BucketPolicy, pad_batch_dim
 from arkflow_tpu.tpu.tokenizer import build_tokenizer
 
@@ -368,7 +369,10 @@ class TpuGenerateProcessor(Processor):
         trees = [host_params, self.family.extras["serve_dtypes"](self.cfg)]
         if self._pspecs is not None:
             trees.append(self._pspecs)
-        placed = jax.tree_util.tree_map(put, *trees)
+        with setup_stage("setup_place"):
+            # a leaf that needed no cast was not waited for above
+            placed = jax.block_until_ready(
+                jax.tree_util.tree_map(put, *trees))
         by_dtype: Counter = Counter()
         for leaf in jax.tree_util.tree_leaves(placed):
             by_dtype[str(leaf.dtype)] += leaf.nbytes
@@ -488,6 +492,13 @@ class TpuGenerateProcessor(Processor):
 
 @register_processor("tpu_generate")
 def _build(config: dict, resource: Resource) -> TpuGenerateProcessor:
+    # the whole construction, less the stages nested in it (init, restore,
+    # placement, the probe): tokenizer, mesh, pools and page tables, jits
+    with setup_stage("setup_build"):
+        return _construct(config)
+
+
+def _construct(config: dict) -> TpuGenerateProcessor:
     from arkflow_tpu.tpu.serving_core import parse_core_config
 
     model = config.get("model", "decoder_lm")

@@ -111,6 +111,7 @@ from arkflow_tpu.batch import DEFAULT_BINARY_VALUE_FIELD, MessageBatch
 from arkflow_tpu.components import Processor, Resource, register_processor
 from arkflow_tpu.config import refuse_pp_serving
 from arkflow_tpu.errors import ConfigError, ProcessError
+from arkflow_tpu.obs.startup import setup_stage
 from arkflow_tpu.tpu.bucketing import BucketPolicy, carve_by_length
 from arkflow_tpu.tpu.tokenizer import build_tokenizer
 
@@ -385,6 +386,13 @@ def _scatter_rows(n: int, indices: list[np.ndarray],
 
 @register_processor("tpu_inference")
 def _build(config: dict, resource: Resource) -> TpuInferenceProcessor:
+    # the whole construction, less the stages nested in it (init, restore,
+    # placement): buckets, mesh, the jitted step, staging pools, the tuner
+    with setup_stage("setup_build"):
+        return _construct(config)
+
+
+def _construct(config: dict) -> TpuInferenceProcessor:
     # deferred: importing jax (and the TPU plugin) only when a model is built
     from arkflow_tpu.parallel.mesh import MeshSpec
     from arkflow_tpu.tpu.runner import ModelRunner

@@ -26,6 +26,7 @@ from aiohttp import web
 from arkflow_tpu.components.registry import ensure_plugins_loaded
 from arkflow_tpu.config import EngineConfig
 from arkflow_tpu.obs import global_registry
+from arkflow_tpu.obs.startup import startup_report
 from arkflow_tpu.obs.trace import global_tracer
 from arkflow_tpu.runtime.stream import Stream, build_stream
 
@@ -200,6 +201,9 @@ class Engine:
                     # sample rate and the forced-sample count — an operator
                     # can tell tracing is alive without hitting /trace
                     "tracing": global_tracer().summary(),
+                    # how long start-up took, stage by stage, and which
+                    # served programs are still cold (obs/startup.py)
+                    "startup": startup_report(),
                     "stream_health": self.stream_health()}
             return web.Response(text=json.dumps(body), content_type="application/json")
 

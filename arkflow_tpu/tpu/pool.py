@@ -45,6 +45,7 @@ import numpy as np
 from arkflow_tpu.errors import ConfigError, RunnerDead
 from arkflow_tpu.tpu.health import CORRUPT, DEAD, DEGRADED, HEALTHY, UNHEALTHY
 from arkflow_tpu.obs import global_registry
+from arkflow_tpu.obs.startup import setup_stage
 from arkflow_tpu.tpu.bucketing import BucketPolicy
 from arkflow_tpu.tpu.runner import (ModelRunner, convert_for_serving,
                                     init_host_params)
@@ -95,9 +96,10 @@ class ModelRunnerPool:
 
         family = get_model(model)
         cfg = family.make_config(**(model_config or {}))
-        host_params = convert_for_serving(
-            init_host_params(family, cfg, seed, checkpoint),
-            serving_dtype, family.name)
+        host_params = init_host_params(family, cfg, seed, checkpoint)
+        with setup_stage("setup_place"):  # the one cast is placement's
+            host_params = convert_for_serving(host_params, serving_dtype,
+                                              family.name)
         self.members: list[ModelRunner] = [
             ModelRunner(
                 model,

@@ -39,6 +39,7 @@ from arkflow_tpu.config import PP_SERVING_REMOVED
 from arkflow_tpu.errors import ConfigError, RunnerDead, StepDeadlineExceeded
 from arkflow_tpu.models import get_model
 from arkflow_tpu.obs import global_registry
+from arkflow_tpu.obs.startup import cold_step, note_programs, setup_stage
 from arkflow_tpu.obs.trace import annotated, record_stage
 from arkflow_tpu.parallel.mesh import (
     MeshSpec,
@@ -139,13 +140,18 @@ def init_host_params(family, cfg, seed: int, checkpoint: Optional[str] = None):
         cpu = cpus[0] if cpus else None
     except RuntimeError:
         cpu = None
-    with jax.default_device(cpu) if cpu is not None else _nullcontext():
-        params = family.init(jax.random.PRNGKey(seed), cfg)
+    with setup_stage("setup_init_params"):
+        with jax.default_device(cpu) if cpu is not None else _nullcontext():
+            params = family.init(jax.random.PRNGKey(seed), cfg)
+        # the eager ops are dispatched, not done: placement reads the tree
+        # next and would wait for them under its own name
+        jax.block_until_ready(params)
     if checkpoint:
         from arkflow_tpu.tpu.checkpoint import restore
 
         try:
-            params = restore(checkpoint, params)
+            with setup_stage("setup_restore"):
+                params = restore(checkpoint, params)
             logger.info("restored checkpoint from %s", checkpoint)
         except ConfigError:
             raise
@@ -263,24 +269,14 @@ class ModelRunner:
                 "(float32/bfloat16/float16/int8)")
         self.serving_dtype = serving_dtype
 
-        if host_params is not None:
-            # shared host tree (device pool): the pool inits/restores AND
-            # dtype-converts once; every member transfers the SAME finished
-            # weights to its own chip — replication by construction, and no
-            # N-fold init or full-tree cast/quantize walks
-            params = host_params
-        else:
-            params = convert_for_serving(
-                init_host_params(self.family, self.cfg, seed, checkpoint),
-                self.serving_dtype, self.family.name)
-
-        #: retained CONVERTED host tree — the known-good repair source the
-        #: integrity plane (tpu/integrity.py) re-adopts from when a member
-        #: is quarantined, and the reference tree its golden signature is
-        #: computed against. Pool members share ONE tree (the pool passes
-        #: ``host_params`` in), so retention costs one host copy per model,
-        #: not per chip.
-        self.host_params = params
+        # shared host tree (device pool): the pool inits/restores AND
+        # dtype-converts once; every member transfers the SAME finished
+        # weights to its own chip — replication by construction, and no
+        # N-fold init or full-tree cast/quantize walks
+        converted = host_params is not None
+        if not converted:
+            host_params = init_host_params(self.family, self.cfg, seed,
+                                           checkpoint)
 
         self.mesh = None
         self._device = None
@@ -304,7 +300,6 @@ class ModelRunner:
 
                 pspecs = quantize_param_specs(pspecs)
             self._pspecs = pspecs
-            params = shard_params(params, pspecs, self.mesh)
             # dp-sharded dispatch: the batch dim splits over the dp axis, so
             # every GLOBAL bucket scales by dp — per-chip shards stay exactly
             # on the configured bucket grid, and divisibility is structural
@@ -312,11 +307,21 @@ class ModelRunner:
             self._input_sharding = batch_sharding(self.mesh)
             platform = next(iter(self.mesh.devices.flat)).platform
         else:
-            target = (devices[0] if devices else jax.devices()[0])
-            params = jax.device_put(params, target)
-            self._device = target
-            platform = target.platform
-        self.params = params
+            self._device = devices[0] if devices else jax.devices()[0]
+            platform = self._device.platform
+        with setup_stage("setup_place"):
+            # the serving-dtype cast is placement's: transfer + cast
+            if not converted:
+                host_params = convert_for_serving(
+                    host_params, self.serving_dtype, self.family.name)
+            self.params = self._place(host_params)
+        #: retained CONVERTED host tree — the known-good repair source the
+        #: integrity plane (tpu/integrity.py) re-adopts from when a member
+        #: is quarantined, and the reference tree its golden signature is
+        #: computed against. Pool members share ONE tree (the pool passes
+        #: ``host_params`` in), so retention costs one host copy per model,
+        #: not per chip.
+        self.host_params = host_params
         #: per-leaf blake2b baseline (tpu/integrity.py); None = not yet
         #: baselined, or invalidated by ``adopt_params`` — the integrity
         #: monitor recomputes it lazily off-path at its next digest pass
@@ -630,6 +635,9 @@ class ModelRunner:
                                           self._input_sharding)
             jit_kwargs["out_shardings"] = self._input_sharding
         self._jitted = jax.jit(classify_step, **jit_kwargs)
+        #: the program's name, as JAX's compile events and a trace carry it
+        self._program = classify_step.__name__
+        note_programs((self._program,))
 
     def _disable_flash(self) -> None:
         """Auto-fallback: serve with XLA attention from now on (one
@@ -880,6 +888,14 @@ class ModelRunner:
         self.core.apply_chaos()
         return self._wait_and_fetch(self._enqueue_step(padded))
 
+    def _cold_step_blocking(self, padded: dict[str, Any]):
+        """``_step_blocking`` for a first-seen shape (``_note_shape``): the
+        program's first call — trace, lower, compile or cache load, first
+        execution — as ``setup_cold_step{program}``, timed on the thread
+        that makes it."""
+        with cold_step(self._program):
+            return self._step_blocking(padded)
+
     def _wait_and_fetch(self, dev_out):
         """Wait for a dispatched step, then copy its outputs to the host.
         The copy (and host conversion) is timed apart from the wait, so
@@ -946,9 +962,16 @@ class ModelRunner:
         mesh, a one-hop transfer to the runner's device otherwise.
         Blocking (device transfer) — swap runs it on an executor thread,
         never the serving loop."""
-        if self.mesh is not None:
-            return shard_params(host_params, self._pspecs, self.mesh)
-        return jax.device_put(host_params, self._device)
+        with setup_stage("setup_place"):
+            return self._place(host_params)
+
+    def _place(self, host_params):
+        placed = (shard_params(host_params, self._pspecs, self.mesh)
+                  if self.mesh is not None
+                  else jax.device_put(host_params, self._device))
+        # the transfers are enqueued, not done: the stage around this call
+        # ends where the tree is on the device (its caller needs it next)
+        return jax.block_until_ready(placed)
 
     def adopt_params(self, placed):
         """Atomically flip serving onto ``placed``; returns the prior tree
@@ -1053,7 +1076,8 @@ class ModelRunner:
         """Compile (and discard) one padded shape through the jitted step."""
         fake = {name: np.zeros(s, self.spec[name][0])
                 for name, s in shape.items()}
-        jax.device_get(self._dispatch(fake))
+        with cold_step(self._program):
+            jax.device_get(self._dispatch(fake))
 
     def _mark_warmed(self, key: tuple) -> None:
         with self._flash_lock:
@@ -1156,13 +1180,15 @@ class ModelRunner:
         first = self._note_shape(padded)
         bucket_rows = next(iter(padded.values())).shape[0]
         deadline = self.core.deadline_for(first)
+        step_blocking = (self._cold_step_blocking if first
+                         else self._step_blocking)
         t0 = time.perf_counter()
         try:
             if deadline is None:
-                out, _ = self._step_blocking(padded)
+                out, _ = step_blocking(padded)
             else:
                 out, _ = self.core.run_deadlined_sync(
-                    partial(self._step_blocking, padded), deadline,
+                    partial(step_blocking, padded), deadline,
                     on_zombie=partial(self._release_staging, padded))
         except StepDeadlineExceeded:
             raise  # the zombie step still owns the staging buffers
@@ -1314,6 +1340,8 @@ class ModelRunner:
         first = self._note_shape(padded)
         bucket_rows = next(iter(padded.values())).shape[0]
         deadline = self.core.deadline_for(first)
+        step_blocking = (self._cold_step_blocking if first
+                         else self._step_blocking)
         staged = padded  # host staging buffers, recycled once the step ends
 
         self._ensure_sems()
@@ -1335,14 +1363,14 @@ class ModelRunner:
                 try:
                     if deadline is None:
                         out, fetch_s = await loop.run_in_executor(
-                            None, self._step_blocking, padded)
+                            None, step_blocking, padded)
                     else:
                         # the shared core's watchdog: wait for the step, not
                         # forever, on a borrowed dedicated thread; on a miss
                         # the zombie's eventual end recycles the staging
                         # buffers (on_zombie)
                         out, fetch_s = await self.core.run_deadlined(
-                            partial(self._step_blocking, padded), deadline,
+                            partial(step_blocking, padded), deadline,
                             on_zombie=partial(self._release_staging, staged))
                 finally:
                     # an abandoned step counts as complete for duty-cycle

@@ -55,6 +55,7 @@ from arkflow_tpu.models.paged_decode import (
     window_ring_pages,
 )
 from arkflow_tpu.obs import global_registry
+from arkflow_tpu.obs.startup import cold_step, note_programs, setup_stage
 from arkflow_tpu.obs.trace import (annotated, current_scope, loop_stage,
                                    observe_stage, record_stage)
 from arkflow_tpu.tpu.health import HEALTHY
@@ -483,7 +484,9 @@ class GenerationServer:
             # Under a mesh the gate is skipped — per-shard math is identical
             # and the tp parity suite covers it; the init-time check stays
             # local.
-            self.kernel_parity = verdict = self._paged_kernel_parity()
+            # a program of its own: compiles and runs every kernel both ways
+            with setup_stage("setup_probe"):
+                self.kernel_parity = verdict = self._paged_kernel_parity()
             if not verdict["ok"]:
                 raise ConfigError(
                     "paged decode kernel disagrees with the dense gather "
@@ -1005,6 +1008,11 @@ class GenerationServer:
         self._chunk = bind(_chunk, routed + keyed, keyed)
         self._verify = bind(_verify, 0, 0)
         self._fused = bind(_fused, piped, 0) if fuses else None
+        # the programs this configuration can reach: cold until each has run
+        note_programs(fn.__name__ for fn, reached in (
+            (_decode, True), (_chunk, True), (_fused, fuses),
+            (_prefill, not (self._layered or self._stateful)),
+            (_verify, self.speculative_tokens)) if reached)
         #: device stand-ins: no decode step in flight (shaped as one's
         #: output: tokens, then a routed model's counters), a first chunk
         zeros = functools.partial(jnp.zeros, dtype=jnp.int32,
@@ -1216,7 +1224,8 @@ class GenerationServer:
         leaves it on the device and a sampling server's key where it was."""
         core = self.core
         await core.heal_gate()
-        deadline = core.deadline_for(self._note_step(key))
+        cold = self._note_step(key)
+        deadline = core.deadline_for(cold)
         keys = () if self._key is None else (self._key,)
 
         # pools bound EAGERLY: a deadline-abandoned zombie step waking after
@@ -1240,9 +1249,15 @@ class GenerationServer:
                 tokens = np.asarray(out[0])
             return tokens, *out[1:]
 
+        def first_call(hop):
+            # the program's first call: trace, lower, compile or cache load,
+            # first execution, on the thread that makes it
+            with cold_step("_" + key[0]):
+                return blocking(hop)
+
         self._track_gen_dispatch()
         tokens, self.k_pages, self.v_pages, *keys = await self._finish_step(
-            key[0], blocking, deadline)
+            key[0], first_call if cold else blocking, deadline)
         if final and keys:
             self._key = keys[0]
         return tokens
