@@ -6,28 +6,28 @@ is bound by reading expert weights, and most experts receive no token at
 all: 16 lanes x top-6 of 128 hit ~69. So the product that matters is "read
 each expert that was hit exactly once, and no other".
 
-``moe_expert_swiglu`` is that product as one Pallas kernel. The caller hands
-it the combine weights ``cw[t, e]`` (the routing weight of expert ``e`` for
-token ``t``, 0 where ``t`` was not routed to ``e``). The experts that were
-hit are compacted to the front of a scalar-prefetched list; the grid walks
-(list entry, slice of the expert width); every step reads one slice of one
-hit expert's three matrices and accumulates
-
+``moe_expert_swiglu`` is that product. The caller hands it the combine
+weights ``cw[t, e]`` (the routing weight of expert ``e`` for token ``t``, 0
+where ``t`` was not routed to ``e``). WHICH kernel runs is decided by the
+call's row count and nothing else. Up to ``_TOKEN_TILE`` rows (a decode
+step's lanes, a 128-token chunk, the set-up probe) it is the one-tile kernel
+of this file: the hit experts are compacted to the front of a scalar-
+prefetched list; the grid walks (list entry, slice of the expert width);
+every step reads one slice of one hit expert's three matrices and does
     out += ((silu(x W_gate[e]) * (x W_up[e])) * cw[:, e]) W_down[e]
-
-for ALL rows of the token tile — rows not routed to ``e`` carry weight 0.
-That is exact and dropless at any load (an expert takes however many rows
-were routed to it; there is no capacity), and below the chip's ridge (about
-240 rows on a v5e) the rows that ride along cost nothing: the step waits on
-the weights either way. Entries past the last hit expert repeat its block
-index, so the pipeline elides the copy, and ``pl.when`` skips the math.
-Token tiles of more than ``_TOKEN_TILE`` rows run tile by tile (each with
-its own hit list).
+for ALL rows of the tile — rows not routed to ``e`` carry weight 0. That is
+exact and dropless at any load (there is no capacity), and below the chip's
+ridge (about 240 rows on a v5e) the rows that ride along cost nothing: the
+step waits on the weights either way. Entries past the last hit expert
+repeat its block index (no copy) and ``pl.when`` skips the math. ABOVE one
+tile the rows that ride along stop being free, so the product runs grouped
+by expert (``ops/moe_grouped.py``): the same walk, each expert read once a
+call, multiplying its own rows only. The threshold is the chip's ridge, a
+property of the shapes: not a setting.
 
 ``expert_swiglu_dense`` is the same arithmetic in plain XLA over every
 expert — the reference path (CPU, training forward, ``decode_kernel:
-gather``), as ``paged_decode``'s gather path is to the paged kernel.
-"""
+gather``), as ``paged_decode``'s gather path is to the paged kernel."""
 
 from __future__ import annotations
 
@@ -39,9 +39,9 @@ from jax.experimental import pallas as pl
 
 #: rows of one kernel call (bounds VMEM: x, out and the float32 accumulator)
 _TOKEN_TILE = 128
-#: token tiles up to which each is a call of its own (a prefill chunk);
-#: more of them (a one-shot prefill of a long prompt) run under ``lax.map``
-_UNROLLED_TILES = 4
+#: (more rows run grouped by expert, ``ops/moe_grouped.py``; this file's
+#: kernel and its index maps keep their LINES: a Mosaic body carries them
+#: into the compile cache's key, ``PERF.md`` §7 "From PR 41 (1)")
 
 
 def expert_swiglu_dense(x, cw, w_gate, w_up, w_down):
@@ -88,8 +88,8 @@ def _expert_kernel(layer_ref, ids_ref, nhit_ref, x_ref, cw_ref, wg_ref, wu_ref,
 
 def _slice_width(f: int) -> int:
     """Slice of the expert width one grid step takes: the largest of 384,
-    256, 128 that divides it (three [D, slice] blocks, double-buffered, stay
-    under 10 MB at D = 2048), or all of a width that none divides."""
+    256, 128 that divides it (three [D, slice] blocks, double-buffered: at
+    most 23.6 of the 48 MB limit as served, 5,120 x 384), else all of it."""
     for tf in (384, 256, 128):
         if f % tf == 0:
             return tf
@@ -156,25 +156,28 @@ def moe_expert_swiglu(x, cw, w_gate, w_up, w_down, layer=None, *,
     ([layers, E, ...]) with ``layer`` the index of the one to use: the
     kernel's index maps pick the layer, so a layer loop never slices (and
     XLA never copies) a layer's 1.2 GB of experts. Returns [T, D]."""
-    t, d = x.shape
+    t = x.shape[0]
     cw = cw.astype(jnp.float32)
     if w_gate.ndim == 3:
         w_gate, w_up, w_down, layer = w_gate[None], w_up[None], w_down[None], 0
     layer = jnp.asarray(layer, jnp.int32).reshape(1)
-    tile = min(_TOKEN_TILE, -(-t // 16) * 16)   # bf16 packs 16 rows a tile
-    t_pad = -(-t // tile) * tile
-    if t_pad != t:
+    if runs_grouped(t):
+        # above the ridge: each hit expert over its own rows only
+        from arkflow_tpu.ops.moe_grouped import grouped_expert_swiglu
+
+        return grouped_expert_swiglu(x, cw, w_gate, w_up, w_down, layer, interpret)
+    tile = -(-t // 16) * 16                     # bf16 packs 16 rows a tile
+    if tile != t:
         # padding rows carry weight 0 everywhere: they hit no expert
-        x = jnp.pad(x, ((0, t_pad - t), (0, 0)))
-        cw = jnp.pad(cw, ((0, t_pad - t), (0, 0)))
-    if t_pad <= _UNROLLED_TILES * tile:
-        # a chunk's few tiles, one call each: inside ``lax.map`` the call
-        # is compiled under the loop's own (default) fast-memory limit,
-        # which three double-buffered [5120, 384] blocks exceed
-        out = [_one_tile(x[i:i + tile], cw[i:i + tile], w_gate, w_up, w_down,
-                         layer, interpret) for i in range(0, t_pad, tile)]
-        return jnp.concatenate(out)[:t] if len(out) > 1 else out[0][:t]
-    out = jax.lax.map(
-        lambda xc: _one_tile(xc[0], xc[1], w_gate, w_up, w_down, layer, interpret),
-        (x.reshape(-1, tile, d), cw.reshape(-1, tile, cw.shape[1])))
-    return out.reshape(t_pad, d)[:t]
+        x = jnp.pad(x, ((0, tile - t), (0, 0)))
+        cw = jnp.pad(cw, ((0, tile - t), (0, 0)))
+    tile_out = _one_tile(x, cw, w_gate, w_up, w_down,
+                         layer, interpret)      # this call keeps its line too
+    return tile_out[:t]
+
+
+def runs_grouped(rows: int) -> bool:
+    """Whether a call of ``rows`` rows runs grouped by expert: more rows than
+    one token tile. The one predicate, for the product and for who counts it
+    (``arkflow_gen_moe_grouped_products_total``)."""
+    return rows > _TOKEN_TILE

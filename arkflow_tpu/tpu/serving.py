@@ -1022,10 +1022,10 @@ class GenerationServer:
                          ) if piped else ()
         self._no_counts = zeros(5 + counted)
 
-    def _note_moe(self, kind: str, stats, steps: int = 1) -> None:
-        """Record the routing counters (``moe_step_stats``, on the host) of
-        one step, or of a prompt's ``steps`` chunks summed: each chunk then
-        counts as one step that hit their mean."""
+    def _note_moe(self, kind: str, stats, steps: int = 1, *, rows: int) -> None:
+        """Record the routing counters (``moe_step_stats``, on the host) of one
+        step of ``rows`` rows, or a prompt's ``steps`` chunks summed (each their mean)."""
+        _note_grouped(self, kind, steps, rows)
         pairs, hit, max_load = (int(v) for v in stats[:3])
         total, experts_hit, load = self.m_moe[kind]
         total.inc(pairs)
@@ -1962,10 +1962,10 @@ class GenerationServer:
 
     def _seed_slot(self, slot: int, req: _Request, kind: str, nxt) -> bool:
         req.chunks += 1
-        self._prefill_pos.pop(slot, None)
+        left = len(req.prompt) - self._prefill_pos.pop(slot, 0)
         if self._moe_layers:
-            self._note_moe(kind, nxt[1:],
-                           int(nxt[4]) if kind == "chunk" else 1)
+            self._note_moe(kind, nxt[1:], int(nxt[4]) if kind == "chunk" else 1,
+                           rows=_span_rows(self, kind, left))
         self._lengths[slot] = len(req.prompt)
         self._cur_tokens[slot] = int(nxt[0])
         if req.prefill_only:
@@ -2403,7 +2403,7 @@ class GenerationServer:
             lanes = np.flatnonzero(act)
             if self._moe_layers and (reqs is None or all(
                     self._slot_req[s] is reqs[s] for s in lanes)):
-                self._note_moe("decode", nxt[self.slots:])
+                self._note_moe("decode", nxt[self.slots:], rows=self.slots)
             for s in map(int, lanes):
                 req = self._slot_req[s]
                 if req is None or (reqs is not None and req is not reqs[s]):
@@ -2483,3 +2483,35 @@ class GenerationServer:
                     self._handle_token(s, int(t))
                     if self._slot_req[s] is None:
                         break
+
+
+# -- which expert kernel a step ran -------------------------------------------
+# Here, at the END of the file, and called from lines that took the place of
+# as many: a Mosaic kernel's body carries the line of every frame it was
+# traced under, the jitted steps' in this file among them, so a line added
+# above them re-keys every kernel of every program (PERF.md §7, "From PR 41
+# (1)").
+
+
+def _span_rows(server: GenerationServer, kind: str, left: int) -> int:
+    """Rows of the prefill steps of a prompt whose LAST step had ``left``
+    tokens to go: the configured chunk, or the one bucketed span."""
+    return (server.prefill_chunk if kind == "chunk" and server.prefill_chunk
+            else server._bucket(left))
+
+
+def _note_grouped(server: GenerationServer, kind: str, steps: int, rows: int) -> None:
+    """Count the expert layers of ``steps`` device steps whose product ran
+    grouped by expert (``ops/moe_grouped``): the step's row count decides,
+    per program; none under ``decode_kernel: gather``, which runs no expert
+    kernel. Registered at a model's first step, so it reads 0, not nothing,
+    where every step is one token tile."""
+    from arkflow_tpu.ops.moe_experts import runs_grouped
+
+    grouped = server.decode_kernel == "paged" and runs_grouped(rows)
+    global_registry().counter(
+        "arkflow_gen_moe_grouped_products_total",
+        "expert layers of device steps whose product ran grouped by expert "
+        "(more rows than one token tile)",
+        dict(server.m_moe[kind][0].labels),
+    ).inc(steps * server._moe_layers if grouped else 0)

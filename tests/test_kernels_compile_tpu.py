@@ -432,19 +432,57 @@ def test_latent_rope_keys_of_64_lanes_are_not_walked(v5e):
                          rope_held=KANANA_ROPE, scale=192 ** -0.5)
 
 
+def _expert_product_compiled(v5e, tokens, layers, experts, dim, width):
+    """``moe_expert_swiglu`` over a stack of ``layers``, compiled, and the
+    bytes of ONE layer's experts. A call of up to 128 rows compiles the
+    one-tile kernel, more rows the grouped one (``ops/moe_grouped.py``)."""
+    from arkflow_tpu.ops.moe_experts import moe_expert_swiglu
+
+    up = ((layers, experts, dim, width), BF16)
+    compiled = _compile(
+        lambda x, cw, wg, wu, wd, layer: moe_expert_swiglu(x, cw, wg, wu, wd, layer),
+        v5e, ((tokens, dim), BF16), ((tokens, experts), jnp.float32),
+        up, up, ((layers, experts, width, dim), BF16), ((), I32))
+    return compiled, 3 * experts * dim * width * 2
+
+
+def _no_copy_of_a_layer(compiled, layer_bytes, grouped):
+    """The stack rides whole: the program holds no temporary anywhere near
+    one layer's experts. The bound is a SHARE of that layer (a sixteenth:
+    26-80 MB at the widths served; it was a flat 64 MB while every call was
+    one tile), since a grouped product may hold temporaries that grow with
+    the call — this one's are the ``rank`` table and its transposes, well
+    under 1 MB: no (row, expert) pair is ever written to HBM."""
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert ("moe_expert_grouped" in text) == grouped
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes // 16
+
+
 @pytest.mark.parametrize("tokens", [16, 128, 300], ids=["decode", "chunk128", "tiled"])
 def test_moe_expert_swiglu_compiles(v5e, tokens):
     """The stacked experts ride whole (5 layers), so no layer's 1.2 GB is
-    copied out for the kernel: the program needs no temporary of that size."""
-    from arkflow_tpu.ops.moe_experts import moe_expert_swiglu
-
-    up = ((5, KANANA_EXPERTS, KANANA_DIM, KANANA_WIDTH), BF16)
-    compiled = _compile(
-        lambda x, cw, wg, wu, wd, layer: moe_expert_swiglu(x, cw, wg, wu, wd, layer),
-        v5e, ((tokens, KANANA_DIM), BF16), ((tokens, KANANA_EXPERTS), jnp.float32),
-        up, up, ((5, KANANA_EXPERTS, KANANA_WIDTH, KANANA_DIM), BF16), ((), I32))
-    assert "tpu_custom_call" in compiled.as_text()
+    copied out for the kernel: the program needs no temporary of that size.
+    300 rows compile the grouped kernel (three row tiles of a group)."""
+    compiled, layer_bytes = _expert_product_compiled(
+        v5e, tokens, 5, KANANA_EXPERTS, KANANA_DIM, KANANA_WIDTH)
+    _no_copy_of_a_layer(compiled, layer_bytes, grouped=tokens > 128)
     assert compiled.memory_analysis().temp_size_in_bytes < 64 * 1024 * 1024
+
+
+@pytest.mark.parametrize("tokens,layers,experts,dim,width", [
+    (512, 4, 17, 6144, 2048), (512, 6, 16, 4096, 2048), (512, 8, 65, 2048, 512),
+    (256, 10, 32, 2048, 1792)], ids=["kexaone512", "mimo512", "qwen3next512", "lfm2_256"])
+def test_grouped_expert_product_compiles(v5e, tokens, layers, experts, dim, width):
+    """A chunk's grouped product at the four other routed cells' widths and
+    expert layers (K-EXAONE 16 + 1 of 6,144 x 2,048, MiMo 16 of 4,096 x
+    2,048, Qwen3-Next 64 + 1 of 2,048 x 512, LFM2 32 of 2,048 x 1,792 at
+    its 256-row chunk): the rows, their float32 sums, a group's two
+    scratches and the weights' slices inside the fast-memory limit the call
+    asks for, and no copy of a layer."""
+    compiled, layer_bytes = _expert_product_compiled(
+        v5e, tokens, layers, experts, dim, width)
+    _no_copy_of_a_layer(compiled, layer_bytes, grouped=True)
 
 
 # -- a layer pattern's kernels (dots3-note-prev widths, page 16, chunk 512) ---
@@ -505,15 +543,11 @@ def test_dsa_topk_select_compiles(v5e, rows, chunk):
 @pytest.mark.parametrize("tokens", [32, 512], ids=["decode", "chunk512"])
 def test_moe_expert_swiglu_compiles_at_the_held_share(v5e, tokens):
     """32 held + 1 shared experts of hidden 5,120 x 1,536, three sliding
-    layers stacked (3.1 GB a matrix kind... a third each): no copy of a layer."""
-    from arkflow_tpu.ops.moe_experts import moe_expert_swiglu
-
-    up = ((3, DOTS_HELD, DOTS_DIM, DOTS_WIDTH), BF16)
-    compiled = _compile(
-        lambda x, cw, wg, wu, wd, layer: moe_expert_swiglu(x, cw, wg, wu, wd, layer),
-        v5e, ((tokens, DOTS_DIM), BF16), ((tokens, DOTS_HELD), jnp.float32),
-        up, up, ((3, DOTS_HELD, DOTS_WIDTH, DOTS_DIM), BF16), ((), I32))
-    assert "tpu_custom_call" in compiled.as_text()
+    layers stacked (3.1 GB a matrix kind... a third each): no copy of a
+    layer. The 512-row chunk compiles the grouped kernel."""
+    compiled, layer_bytes = _expert_product_compiled(
+        v5e, tokens, 3, DOTS_HELD, DOTS_DIM, DOTS_WIDTH)
+    _no_copy_of_a_layer(compiled, layer_bytes, grouped=tokens > 128)
     assert compiled.memory_analysis().temp_size_in_bytes < 64 * 1024 * 1024
 
 
