@@ -75,17 +75,20 @@ def gelu(x: jnp.ndarray) -> jnp.ndarray:
     return jax.nn.gelu(x, approximate=True)
 
 
-def attention(q, k, v, mask=None, *, softmax_dtype=jnp.float32, sink=None):
+def attention(q, k, v, mask=None, *, softmax_dtype=jnp.float32, sink=None,
+              scale=None):
     """Batched multi-head attention core: [B, S, H, Dh] tensors (values may
     be of another width than queries and keys).
 
     Softmax in float32; matmuls in the input dtype (bfloat16) for the MXU.
     ``mask``: broadcastable to [B, H, Sq, Sk], True = attend. ``sink`` [H]:
     one logit a head that joins every softmax as one more column and is
-    dropped after it: it takes probability and adds no value.
+    dropped after it: it takes probability and adds no value. ``scale``:
+    what the scores are multiplied by where it is not ``Dh^-0.5``.
     """
     dh = q.shape[-1]
-    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(softmax_dtype) / math.sqrt(dh)
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(softmax_dtype)
+    scores = scores / math.sqrt(dh) if scale is None else scores * scale
     if mask is not None:
         scores = jnp.where(mask, scores, jnp.finfo(softmax_dtype).min)
     if sink is not None:

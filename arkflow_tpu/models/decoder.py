@@ -15,6 +15,7 @@ Defaults are a small test shape; ``llama3_8b()`` gives the production shape.
 from __future__ import annotations
 
 import functools
+import math
 from dataclasses import dataclass
 from functools import partial
 from typing import Optional
@@ -61,6 +62,17 @@ class AttnSpec:
     index_n_heads: int = 0
     index_head_dim: int = 0
     index_topk: int = 0
+    #: ``DecoderConfig.rope_scaling`` (YaRN's keys as sorted pairs) or None
+    rope_scaling: Optional[tuple] = None
+
+    @property
+    def softmax_scale(self) -> float:
+        """What the scores are multiplied by before the softmax: ``(nope +
+        rope)^-0.5``, times YaRN's ``g(mscale_all_dim)^2`` under a scaling."""
+        scale = float(self.qk_nope_head_dim + self.qk_rope_head_dim) ** -0.5
+        if self.rope_scaling is None:
+            return scale
+        return scale * yarn_softmax_mult(self.rope_scaling)
 
 
 @dataclass(frozen=True)
@@ -316,6 +328,15 @@ class DecoderConfig:
     #: (~0.13 under a random router) that it decides choices and load, as a
     #: trained balancing bias does (``_init_ffn``)
     router_bias_std: float = 0.0
+    #: the embedding table is SEEDED normal(0, this). At the usual 0.02 a
+    #: seeded model's residual is soon its sub-layers' outputs (~0.1 a value),
+    #: and under random weights attention is near uniform, so those are the
+    #: context's pooled mean, the same for every token: by the tenth layer
+    #: 50-85 % of a router's input is common to a chunk's tokens and half the
+    #: experts are never chosen. At 0.5 a token's own embedding decides its
+    #: routing, as a trained model's peaked attention would, and every expert
+    #: is hit (PERF.md section 6, PR 53)
+    embed_init_std: float = 0.02
     # -- Gated DeltaNet layers among gated per-head K/V layers (Qwen3-Next),
     # under the published key names. ``layer_types`` names a layer
     # ``linear_attention``: its mixer (``gated_delta_net``) has
@@ -339,6 +360,30 @@ class DecoderConfig:
     linear_conv_kernel_dim: int = 0
     norm_unit_offset: bool = False
     shared_expert_gate: bool = False
+    # -- several residual streams mixed by manifold-constrained
+    # hyper-connections (mHC, arXiv:2512.24880; Xing4.0), under the published
+    # key names. ``hc_mult`` = n > 1: a token's residual is n rows of ``dim``
+    # (the embedding copied n times; summed before the final norm), and each
+    # SUB-layer (attention, then the MLP half) has float32 leaves of its own
+    # (``mhc_attn`` / ``mhc_mlp``: ``phi`` [n * dim, n * n + 2 n], ``b``,
+    # ``alpha`` [3]) that give, a token, the weights its input is read with
+    # (``sigmoid``), the weights its output is written back with (``2
+    # sigmoid``) and an n x n mixing of the streams: ``exp`` of logits clamped
+    # to ``mhc_h_res_clamp_min/max``, then ``hc_sinkhorn_iters`` rounds of
+    # column and row normalisation with ``hc_eps`` in the denominators
+    # (``ops/mhc_mix``). A latent model of full layers only.
+    hc_mult: int = 1
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    mhc_h_res_clamp_min: float = -30.0
+    mhc_h_res_clamp_max: float = 30.0
+    #: None, or the published ``rope_scaling`` mapping of ``type`` "yarn"
+    #: (DeepSeek-V3's: ``factor``, ``original_max_position_embeddings``,
+    #: ``beta_fast``, ``beta_slow``, ``mscale``, ``mscale_all_dim``), held as
+    #: sorted (key, value) pairs: the rope's frequencies are interpolated
+    #: (``rope_frequencies``) and a latent layer's softmax scale grows by
+    #: ``g(mscale_all_dim)^2``. A latent model's full layers only.
+    rope_scaling: Optional[tuple] = None
 
     def __post_init__(self):
         from arkflow_tpu.errors import ConfigError
@@ -347,6 +392,10 @@ class DecoderConfig:
                      "mlp_multipliers"):  # JSON lists: hashable
             if isinstance(getattr(self, name), list):
                 object.__setattr__(self, name, tuple(getattr(self, name)))
+        if isinstance(self.rope_scaling, dict):  # a JSON mapping: hashable
+            object.__setattr__(self, "rope_scaling",
+                               tuple(sorted(self.rope_scaling.items())))
+        self._check_streams()
         self._check_hybrid()
         if self.latent:
             if min(self.qk_nope_head_dim, self.qk_rope_head_dim,
@@ -408,6 +457,60 @@ class DecoderConfig:
                     "experts_held is (first, count) within n_routed_experts "
                     f"of a routed model, got {self.experts_held}")
         self._check_layer_pattern()
+
+    def _check_streams(self) -> None:
+        """``hc_mult`` and ``rope_scaling``: what they are served beside."""
+        from arkflow_tpu.errors import ConfigError
+
+        if self.rope_scaling is not None:
+            kind = dict(self.rope_scaling).get("type")
+            if kind != "yarn":
+                raise ConfigError(
+                    f"rope_scaling of type {kind!r} is not served: only "
+                    "'yarn' (DeepSeek-V3's frequency interpolation and "
+                    "softmax scale) is")
+            missing = set(_YARN_KEYS) - set(dict(self.rope_scaling))
+            if missing:
+                raise ConfigError(
+                    f"rope_scaling of type 'yarn' needs {sorted(_YARN_KEYS)}, "
+                    f"missing {sorted(missing)}")
+            if not self.latent or set(self.kinds) != {FULL} or self.index_topk:
+                raise ConfigError(
+                    "rope_scaling (yarn) is served on a latent-attention "
+                    "model's full layers only: the per-head K/V layers, "
+                    "sliding latent layers and the indexer rotate at "
+                    "unscaled frequencies and score at d^-0.5")
+        if self.hc_mult < 1 or self.hc_sinkhorn_iters < 1 \
+                or self.mhc_h_res_clamp_min >= self.mhc_h_res_clamp_max:
+            raise ConfigError(
+                "hc_mult >= 1, hc_sinkhorn_iters >= 1 and "
+                "mhc_h_res_clamp_min < mhc_h_res_clamp_max, got "
+                f"{self.hc_mult}, {self.hc_sinkhorn_iters}, "
+                f"{self.mhc_h_res_clamp_min}, {self.mhc_h_res_clamp_max}")
+        if self.hc_mult == 1:
+            return
+        if not self.latent:
+            raise ConfigError(
+                f"hc_mult {self.hc_mult} (several residual streams mixed by "
+                "hyper-connections) is served with latent attention "
+                "(kv_lora_rank > 0) only: the per-head K/V layer loop and "
+                "the layers that carry a state (hybrid, conv, "
+                "linear_attention) carry ONE residual stream")
+        if set(self.kinds) != {FULL} or self.index_topk:
+            raise ConfigError(
+                f"hc_mult {self.hc_mult} is served over full_attention "
+                "latent layers only: sliding_attention layers and indexed "
+                f"layers carry ONE residual stream, got {self.layer_types} "
+                f"and index_topk {self.index_topk}")
+        if self.remat:
+            raise ConfigError(
+                "hc_mult > 1 is served, not trained: remat wraps a layer "
+                "that carries ONE residual stream")
+
+    @property
+    def hc_res_clamp(self) -> tuple:
+        """(min, max) the mixing logits are clamped to before the ``exp``."""
+        return float(self.mhc_h_res_clamp_min), float(self.mhc_h_res_clamp_max)
 
     def _check_hybrid(self) -> None:
         from arkflow_tpu.errors import ConfigError
@@ -763,7 +866,8 @@ class DecoderConfig:
             gate=self.attention_gate_type == "headwise",
             rescale=self.apply_mla_qkv_lora_rescale,
             index_n_heads=self.index_n_heads,
-            index_head_dim=self.index_head_dim, index_topk=self.index_topk)
+            index_head_dim=self.index_head_dim, index_topk=self.index_topk,
+            rope_scaling=self.rope_scaling)
 
     @property
     def routed(self) -> bool:
@@ -838,8 +942,69 @@ def _init_latent_layer(key, cfg: DecoderConfig, routed: bool,
         layer["index_k_norm"] = cm.layer_norm_init(sp.index_head_dim)
         layer["index_w"] = cm.dense_init(next(extra), cfg.dim, sp.index_n_heads,
                                          bias=False)
+    if cfg.hc_mult > 1:
+        for i, name in enumerate(("mhc_attn", "mhc_mlp")):
+            layer[name] = _init_mhc(jax.random.fold_in(key, 200 + i), cfg)
     layer.update(_init_ffn(k, cfg, routed))
     return layer
+
+
+def _init_mhc(key, cfg: DecoderConfig) -> dict:
+    """One sub-layer's hyper-connection leaves (``ops/mhc_mix``), float32.
+    ``phi`` ~ N(0, 1 / (n dim)): the normed projection ``m`` is then N(0, 1)
+    a coefficient, so ``alpha`` IS the size of a coefficient's dynamic part.
+    Seeded so that the dynamic and the static part of every logit are of
+    one size and no coefficient is trivial (no source states a trained
+    model's values): every ``alpha`` near 0.5; ``b`` ~ N(0, 0.5) (input
+    weights spread over (0, 1), output weights over (0, 2)), the n x n
+    logits plus 1 on the diagonal — after the normalisations a stream keeps
+    ~0.4 of itself and takes ~0.2 of each other: neither the identity
+    (streams that never mix) nor uniform (streams that are one), a token's
+    own matrix (its entries vary by ~0.08 over tokens), and of a spread at
+    which the configured twenty iterations reach rows AND columns of sum 1
+    to 1e-5 where one iteration leaves the columns 4 % off (logits of
+    spread 1 leave some tokens' columns 1e-3 off after twenty)."""
+    n = cfg.hc_mult
+    k_phi, k_b, k_a = jax.random.split(key, 3)
+    cols = n * n + 2 * n
+    phi = jax.random.normal(k_phi, (n * cfg.dim, cols), jnp.float32) / (
+        n * cfg.dim) ** 0.5
+    b = 0.5 * jax.random.normal(k_b, (cols,), jnp.float32)
+    b = b.at[2 * n:].add(jnp.eye(n, dtype=jnp.float32).reshape(-1))
+    alpha = 0.5 * jax.random.uniform(k_a, (3,), jnp.float32, 0.8, 1.2)
+    return {"phi": phi, "b": b, "alpha": alpha}
+
+
+def hc_expand(x: jnp.ndarray, cfg: DecoderConfig) -> jnp.ndarray:
+    """[B, S, dim] -> the ``hc_mult`` residual streams, each a copy of the
+    embedding, side by side in one lane-dense row [B, S, n dim] (stream j in
+    columns j dim .. (j + 1) dim: ``ops/mhc_mix`` says why flat)."""
+    return jnp.tile(x, (1, 1, cfg.hc_mult))
+
+
+def hc_collapse(x: jnp.ndarray, cfg: DecoderConfig) -> jnp.ndarray:
+    """The streams' sum [B, S, n dim] -> [B, S, dim] (float32 sums)."""
+    d = cfg.dim
+    return functools.reduce(lambda a, b: a + b, (
+        x[..., j * d:(j + 1) * d].astype(jnp.float32)
+        for j in range(cfg.hc_mult))).astype(x.dtype)
+
+
+def hc_pre(leaves: dict, x: jnp.ndarray, cfg: DecoderConfig, **form):
+    """A sub-layer's input ``u`` [B, S, dim] and the token's coefficients
+    ``H`` from the streams ``x`` [B, S, n dim] (``ops/mhc_mix.mhc_pre``)."""
+    from arkflow_tpu.ops.mhc_mix import mhc_pre
+
+    return mhc_pre(x, leaves, n=cfg.hc_mult, iters=cfg.hc_sinkhorn_iters,
+                   eps=cfg.hc_eps,
+                   clamp=cfg.hc_res_clamp, norm_eps=cfg.norm_eps, **form)
+
+
+def hc_post(x: jnp.ndarray, y: jnp.ndarray, h: jnp.ndarray, **form):
+    """The streams after a sub-layer's output ``y`` (``mhc_mix.mhc_post``)."""
+    from arkflow_tpu.ops.mhc_mix import mhc_post
+
+    return mhc_post(x, y, h, **form)
 
 
 def _init_ffn(k, cfg: DecoderConfig, routed: bool) -> dict:
@@ -1032,7 +1197,8 @@ def _init_runs(rng, cfg: DecoderConfig) -> dict:
     """``init`` for a model whose layers stack by runs (``layer_runs``)."""
     keys = iter(jax.random.split(rng, 2 + cfg.layers))
     params = {
-        "embed": cm.embedding_init(next(keys), cfg.vocab_size, cfg.dim),
+        "embed": cm.embedding_init(next(keys), cfg.vocab_size, cfg.dim,
+                                   cfg.embed_init_std),
         "norm_out": cm.rms_norm_init(cfg.dim),
         "lm_head": cm.dense_init(next(keys), cfg.dim, cfg.vocab_size, bias=False),
     }
@@ -1072,7 +1238,8 @@ def init(rng, cfg: DecoderConfig) -> dict:
     dh = cfg.dh
     keys = iter(jax.random.split(rng, 4 + 7 * cfg.layers))
     params = {
-        "embed": cm.embedding_init(next(keys), cfg.vocab_size, cfg.dim),
+        "embed": cm.embedding_init(next(keys), cfg.vocab_size, cfg.dim,
+                                   cfg.embed_init_std),
         "norm_out": cm.rms_norm_init(cfg.dim),
         "lm_head": cm.dense_init(next(keys), cfg.dim, cfg.vocab_size, bias=False),
         "layers": [],
@@ -1348,27 +1515,84 @@ def attn_out_gate(lp: dict, y: jnp.ndarray, attn: jnp.ndarray,
     return (attn.astype(jnp.float32) * gate.reshape(attn.shape)).astype(attn.dtype)
 
 
-def _rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
-    """Rotary embedding. x: [B, S, H, Dh]; positions: [B, S]."""
-    dh = x.shape[-1]
+#: what a ``rope_scaling`` of type "yarn" states (DeepSeek-V3's keys)
+_YARN_KEYS = ("factor", "original_max_position_embeddings", "beta_fast",
+              "beta_slow", "mscale", "mscale_all_dim")
+
+
+def _yarn_g(factor: float, mscale: float) -> float:
+    """YaRN's attention factor ``0.1 mscale ln(factor) + 1`` (1 at factor <= 1)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_softmax_mult(scaling) -> float:
+    """``g(mscale_all_dim)^2``: what YaRN multiplies a latent layer's
+    softmax scale by (DeepSeek-V3's modelling code)."""
+    y = dict(scaling)
+    return _yarn_g(float(y["factor"]), float(y["mscale_all_dim"])) ** 2
+
+
+def rope_frequencies(d: int, theta: float, scaling=None):
+    """The rotary table's ``d / 2`` frequencies, and what its cos and sin
+    are multiplied by: ``theta^(-2i/d)`` and 1 — or, under a YaRN
+    ``scaling`` (``DecoderConfig.rope_scaling``), DeepSeek-V3's: with ``L``
+    the original positions and ``corr(b) = d ln(L / (2 pi b)) / (2 ln
+    theta)``, pairs below ``floor(corr(beta_fast))`` keep their frequency,
+    pairs above ``ceil(corr(beta_slow))`` turn ``factor`` times slower and
+    those between are interpolated linearly; cos and sin are scaled by
+    ``g(mscale) / g(mscale_all_dim)``. The ONE table both rotations read."""
     # float: a published theta of 1e11 read as an int does not fit 32 bits
-    freqs = 1.0 / (float(theta) ** (jnp.arange(0, dh, 2, dtype=jnp.float32) / dh))
-    angles = positions[..., None].astype(jnp.float32) * freqs  # [B, S, Dh/2]
+    freqs = 1.0 / (float(theta) ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    if scaling is None:
+        return freqs, 1.0
+    y = dict(scaling)
+    factor, orig = float(y["factor"]), float(y["original_max_position_embeddings"])
+
+    def corr(rotations: float) -> float:
+        return d * math.log(orig / (rotations * 2 * math.pi)) / (
+            2 * math.log(float(theta)))
+
+    low = max(math.floor(corr(float(y["beta_fast"]))), 0)
+    high = min(math.ceil(corr(float(y["beta_slow"]))), d // 2 - 1)
+    if high == low:
+        high += 0.001  # as the published code: no division by zero
+    ramp = jnp.clip((jnp.arange(d // 2, dtype=jnp.float32) - low) / (high - low),
+                    0.0, 1.0)
+    mult = _yarn_g(factor, float(y["mscale"])) / _yarn_g(
+        factor, float(y["mscale_all_dim"]))
+    return freqs / factor * ramp + freqs * (1.0 - ramp), mult
+
+
+def _rope_angles(positions: jnp.ndarray, d: int, theta: float, scaling):
+    """The rotation angles [B, S, d / 2] of ``positions`` [B, S], and what
+    their cos and sin are multiplied by (``rope_frequencies``)."""
+    freqs, mult = rope_frequencies(d, theta, scaling)
+    return positions[..., None].astype(jnp.float32) * freqs, mult
+
+
+def _rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float,
+          scaling=None) -> jnp.ndarray:
+    """Rotary embedding. x: [B, S, H, Dh]; positions: [B, S]."""
+    angles, mult = _rope_angles(positions, x.shape[-1], theta, scaling)
     cos = jnp.cos(angles)[:, :, None, :]
     sin = jnp.sin(angles)[:, :, None, :]
+    if mult != 1.0:
+        cos, sin = cos * mult, sin * mult
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)
 
 
-def _rope_interleaved(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
+def _rope_interleaved(x: jnp.ndarray, positions: jnp.ndarray, theta: float,
+                      scaling=None) -> jnp.ndarray:
     """Rotary embedding over the pairs (2i, 2i+1) (``rope_interleave``).
     x: [B, S, ..., D]; positions: [B, S]."""
     d = x.shape[-1]
-    freqs = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
-    angles = positions[..., None].astype(jnp.float32) * freqs  # [B, S, D/2]
+    angles, mult = _rope_angles(positions, d, theta, scaling)
     angles = angles.reshape(angles.shape[:2] + (1,) * (x.ndim - 3) + (d // 2,))
     cos, sin = jnp.cos(angles), jnp.sin(angles)
+    if mult != 1.0:
+        cos, sin = cos * mult, sin * mult
     pairs = x.astype(jnp.float32).reshape(x.shape[:-1] + (d // 2, 2))
     a, b = pairs[..., 0], pairs[..., 1]
     out = jnp.stack([a * cos - b * sin, a * sin + b * cos], axis=-1)
@@ -1409,8 +1633,10 @@ def mla_project(lp: dict, y: jnp.ndarray, cfg: AttnSpec, positions, cq=None):
     kv = cm.dense(lp["wkv_a"], y)
     c = _rescaled(cm.rms_norm(lp["kv_norm"], kv[..., :cfg.kv_lora_rank],
                               cfg.norm_eps), cfg, cfg.kv_lora_rank)
-    k_r = rot(kv[..., None, cfg.kv_lora_rank:], positions, cfg.rope_theta)[:, :, 0]
-    return q[..., :nope], rot(q[..., nope:], positions, cfg.rope_theta), c, k_r
+    k_r = rot(kv[..., None, cfg.kv_lora_rank:], positions, cfg.rope_theta,
+              cfg.rope_scaling)[:, :, 0]
+    return (q[..., :nope],
+            rot(q[..., nope:], positions, cfg.rope_theta, cfg.rope_scaling), c, k_r)
 
 
 def mla_head_gate(lp: dict, y: jnp.ndarray, cfg):
@@ -1467,7 +1693,10 @@ def mla_expanded_attention(lp: dict, q_nope, q_rope, c, k_r, mask,
     k = jnp.concatenate(
         [k_nope, jnp.broadcast_to(k_r[:, :, None, :], k_nope.shape[:3] + k_r.shape[-1:])],
         axis=-1)
-    return _gated_out(lp, cm.attention(q, k, v, mask), cfg, gate)
+    if cfg.rope_scaling is None:
+        return _gated_out(lp, cm.attention(q, k, v, mask), cfg, gate)
+    return _gated_out(lp, cm.attention(q, k, v, mask, scale=cfg.softmax_scale),
+                      cfg, gate)
 
 
 def index_project(lp: dict, y: jnp.ndarray, cq: jnp.ndarray, cfg, positions):
@@ -1848,8 +2077,22 @@ def _forward_latent(params: dict, cfg: DecoderConfig, input_ids, axes: dict,
     positions = jnp.broadcast_to(jnp.arange(s)[None, :], (b, s))
     causal = jnp.tril(jnp.ones((s, s), bool))[None, None, :, :]
     key_pos = jnp.arange(s)
+    hc = cfg.hc_mult > 1
 
     def make_layer(routed: bool, sp: AttnSpec):
+        def streams_layer(x, lp):
+            # x: the residual streams [B, S, n dim]; each sub-layer reads
+            # their weighted sum and is written back into all of them
+            u, h = hc_pre(lp["mhc_attn"], x, cfg)
+            y = cm.rms_norm(lp["attn_norm"], u, cfg.norm_eps)
+            x = hc_post(x, mla_expanded_attention(
+                lp, *mla_project(lp, y, sp, positions, mla_query_latent(lp, y, sp)),
+                causal, sp), h)
+            u, h = hc_pre(lp["mhc_mlp"], x, cfg)
+            y = cm.rms_norm(lp["mlp_norm"], u, cfg.norm_eps)
+            return hc_post(x, routed_mlp(lp, y, cfg)[0] if routed
+                           else _mlp(lp, y, cfg), h), None
+
         def layer(x, lp):
             y = cm.rms_norm(lp["attn_norm"], x, cfg.norm_eps)
             cq = mla_query_latent(lp, y, sp)
@@ -1867,10 +2110,16 @@ def _forward_latent(params: dict, cfg: DecoderConfig, input_ids, axes: dict,
             y = cm.rms_norm(lp["mlp_norm"], x, cfg.norm_eps)
             x = x + (routed_mlp(lp, y, cfg)[0] if routed else _mlp(lp, y, cfg))
             return _shard_act(x, axes), None
+        if hc:
+            return streams_layer
         return jax.checkpoint(layer, prevent_cse=False) if cfg.remat else layer
 
+    if hc:
+        x = hc_expand(x, cfg)
     for stack, routed, kind, _ in layer_stacks(params, cfg):
         x, _ = jax.lax.scan(make_layer(routed, cfg.attn(kind)), x, stack)
+    if hc:
+        x = hc_collapse(x, cfg)
     x = cm.rms_norm(params["norm_out"], x, cfg.norm_eps)
     logits = cm.dense(params["lm_head"], x).astype(jnp.float32)
     if return_aux:
@@ -2063,6 +2312,9 @@ def _serve_dtypes_runs(cfg: DecoderConfig) -> dict:
         else:
             layer.update(wq={"w": bf16}, wo={"w": bf16},
                          **_attn_dtypes(cfg, kind))
+            if cfg.hc_mult > 1:  # the mixing selects and normalises: float32
+                layer.update({name: {"phi": f32, "b": f32, "alpha": f32}
+                              for name in ("mhc_attn", "mhc_mlp")})
         if routed:
             layer.update(router={"w": f32},
                          experts={"w_gate": bf16, "w_up": bf16, "w_down": bf16})

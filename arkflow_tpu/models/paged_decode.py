@@ -56,8 +56,10 @@ from dataclasses import dataclass
 from arkflow_tpu.models.decoder import (CONV, FULL, LINEAR, SLIDING,
                                         DecoderConfig, _mlp, _norm, _scaled,
                                         attn_out_gate, gdn_conv, gdn_operands,
-                                        gdn_output, gdn_project, index_project,
-                                        index_scores, layer_runs, layer_stacks,
+                                        gdn_output, gdn_project, hc_collapse,
+                                        hc_expand, hc_post, hc_pre,
+                                        index_project, index_scores,
+                                        layer_runs, layer_stacks,
                                         lm_logits, mla_absorb_query,
                                         mla_expanded_attention, mla_head_gate,
                                         mla_output, mla_project,
@@ -349,6 +351,10 @@ def _latent_layers(params: dict, cfg: DecoderConfig, x, k_pages, v_pages,
     context) summed over queries and indexed layers)."""
     kernel = attention_kernel == "paged"
     kern = dict(attention_kernel=attention_kernel, kernel_interpret=kernel_interpret)
+    # several residual streams (``hc_mult`` > 1): the carry is [B, S, n dim]
+    # and every ``x + f(norm(x))`` is mix in -> sub-layer -> mix back
+    hc = cfg.hc_mult > 1
+    mix = dict(kernel=kernel, interpret=kernel_interpret)
     layered = isinstance(k_pages, dict)
     kept, ring = page_table if isinstance(page_table, tuple) else (page_table, None)
     page = (k_pages["latent"] if layered else k_pages).shape[2]
@@ -368,6 +374,8 @@ def _latent_layers(params: dict, cfg: DecoderConfig, x, k_pages, v_pages,
             x, kp, vp, picked = carry
             lp, li, ei = scanned
             cp, rp = (kp[name], vp[name]) if layered else (kp, vp)
+            if hc:
+                streams, (x, h) = x, hc_pre(lp["mhc_attn"], x, cfg, **mix)
             y = cm.rms_norm(lp["attn_norm"], x, cfg.norm_eps)
             cq = mla_query_latent(lp, y, sp)
             q_nope, q_rope, c, k_r = mla_project(lp, y, sp, positions, cq)
@@ -399,7 +407,9 @@ def _latent_layers(params: dict, cfg: DecoderConfig, x, k_pages, v_pages,
                 kp, vp = {**kp, name: cp}, {**vp, name: rp}
             else:
                 kp, vp = cp, rp
-            x = x + attn
+            x = hc_post(streams, attn, h, **mix) if hc else x + attn
+            if hc:
+                streams, (x, h) = x, hc_pre(lp["mhc_mlp"], x, cfg, **mix)
             y = cm.rms_norm(lp["mlp_norm"], x, cfg.norm_eps)
             if routed:
                 out, load = routed_mlp(lp, y, cfg, token_mask=token_mask,
@@ -407,9 +417,12 @@ def _latent_layers(params: dict, cfg: DecoderConfig, x, k_pages, v_pages,
                                        stacked=(experts, ei))
             else:
                 out, load = _mlp(lp, y, cfg), None
-            return (x + out, kp, vp, picked), load
+            x = hc_post(streams, out, h, **mix) if hc else x + out
+            return (x, kp, vp, picked), load
         return layer
 
+    if hc:
+        x = hc_expand(x, cfg)
     carry = (x, k_pages, v_pages, jnp.zeros((2,), jnp.int32))
     loads = []
     for stack, routed, kind, kind_first in layer_stacks(params, cfg):
@@ -421,6 +434,8 @@ def _latent_layers(params: dict, cfg: DecoderConfig, x, k_pages, v_pages,
         if routed:
             loads.append(load)
     x, k_pages, v_pages, picked = carry
+    if hc:
+        x = hc_collapse(x, cfg)
     stats = moe_step_stats(jnp.concatenate(loads), cfg.experts_held and cfg.held)
     if cfg.index_topk:
         stats = jnp.concatenate([stats, picked])
@@ -495,7 +510,7 @@ def _attend_selected(lp, q_nope, q_rope, c_pages, r_pages, layer, page_table,
     and scored as ``_attend_latent`` scores a context."""
     page = c_pages.shape[2]
     q_lat = mla_absorb_query(lp, q_nope, cfg)
-    scale = float(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+    scale = cfg.softmax_scale
     if attention_kernel == "paged":
         from arkflow_tpu.ops.ragged_attention import mla_paged_attention
 
@@ -530,7 +545,7 @@ def _attend_window(lp, q_nope, q_rope, c_pages, r_pages, layer, ring, off,
     page's by now — the bound hides them). ``"paged"`` reads the pool in
     place (``mla_paged_attention`` with its lower bound)."""
     q_lat = mla_absorb_query(lp, q_nope, cfg)
-    scale = float(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+    scale = cfg.softmax_scale
     b, cols = ring.shape
     page = c_pages.shape[2]
     if attention_kernel == "paged":
@@ -568,7 +583,7 @@ def _attend_latent(lp, q_nope, q_rope, c_pages, r_pages, layer, page_table,
     query position); ``"gather"`` materializes the layer's context and
     masks with ``mask`` — the reference."""
     q_lat = mla_absorb_query(lp, q_nope, cfg)
-    scale = float(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+    scale = cfg.softmax_scale
     if attention_kernel == "paged":
         from arkflow_tpu.ops.ragged_attention import mla_paged_attention
 
@@ -714,8 +729,33 @@ def latent_kernel_probe(params: dict, cfg: DecoderConfig, page_size: int,
             named = (jax.nn.one_hot(idx, ctx) * (top > -jnp.inf)[..., None]).max(2)
             out.append((f"dsa_topk_select_{name}", named, dsa_topk_select(
                 s, positions, k=k, interpret=kernel_interpret)))
+    if cfg.hc_mult > 1:
+        out.extend(_mhc_probe(lp, cfg, rand, kernel_interpret))
     out.append(_expert_probe(params, cfg, keys, rand, kernel_interpret))
     return out
+
+
+def _mhc_probe(lp: dict, cfg: DecoderConfig, rand, kernel_interpret: bool) -> list:
+    """(name, plain XLA, kernel) of the two mixing kernels (``ops/mhc_mix``)
+    on one token tile of seeded streams under the first layer's own leaves:
+    ``mhc_pre``'s sub-layer input beside its coefficients, ``mhc_post``'s
+    streams from GIVEN coefficients (the plain form's)."""
+    from arkflow_tpu.ops.mhc_mix import TOKEN_TILE, n_coefficients
+
+    n = cfg.hc_mult
+    x = rand((1, TOKEN_TILE, n * cfg.dim))
+    y = rand((1, TOKEN_TILE, cfg.dim))
+    u, h = hc_pre(lp["mhc_attn"], x, cfg)
+    uk, hk = hc_pre(lp["mhc_attn"], x, cfg, kernel=True, interpret=kernel_interpret)
+    assert hk.shape[-1] != h.shape[-1], "the probe's streams must reach the kernel"
+    k = n_coefficients(n)
+    joined = lambda u, h: jnp.concatenate(  # noqa: E731
+        [u.astype(jnp.float32), h[..., :k]], axis=-1)
+    given = jnp.pad(h, ((0, 0), (0, 0), (0, hk.shape[-1] - k)))
+    return [("mhc_pre", joined(u, h), joined(uk, hk)),
+            ("mhc_post", hc_post(x, y, h).reshape(1, TOKEN_TILE, -1),
+             hc_post(x, y, given, kernel=True,
+                     interpret=kernel_interpret).reshape(1, TOKEN_TILE, -1))]
 
 
 def _expert_probe(params: dict, cfg: DecoderConfig, keys, rand,

@@ -169,6 +169,12 @@ class TpuGenerateProcessor(Processor):
                     "a latent-attention model (kv_lora_rank > 0) generates "
                     "through serving: continuous only: the batch path's "
                     "contiguous cache holds per-head K/V")
+            if mesh_config and getattr(self.cfg, "hc_mult", 1) > 1:
+                raise ConfigError(
+                    f"hc_mult {self.cfg.hc_mult} (several residual streams) "
+                    "is served on one chip: neither the streams' mixing nor "
+                    "the latent pools have a sharding over a tp mesh yet "
+                    "(remove mesh)")
             if mesh_config:
                 raise ConfigError(
                     "a latent-attention model is served on one chip: "

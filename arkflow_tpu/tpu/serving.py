@@ -294,6 +294,8 @@ class GenerationServer:
             if has]
         if self._layered:
             self._refuse_layered(prefix_cache_pages, speculative_tokens)
+        if cfg.hc_mult > 1:
+            _serve_streams(self, name, prefix_cache_pages, speculative_tokens)
         #: a state a slot beside the K/V pages (``cache_spec``'s per-slot
         #: pool: the hybrid block's ``ssm``, conv layers' ``conv``, linear
         #: attention layers' ``gdn``)
@@ -2515,3 +2517,31 @@ def _note_grouped(server: GenerationServer, kind: str, steps: int, rows: int) ->
         "(more rows than one token tile)",
         dict(server.m_moe[kind][0].labels),
     ).inc(steps * server._moe_layers if grouped else 0)
+
+
+# -- several residual streams (``hc_mult`` > 1) -----------------------------------
+
+
+def _serve_streams(server: GenerationServer, name: str, prefix_cache_pages,
+                   speculative_tokens) -> None:
+    """What a model with several residual streams is not served with yet,
+    and the two gauges that say what a token's residual costs."""
+    cfg = server.cfg
+    if speculative_tokens:
+        raise ConfigError(
+            f"speculative_tokens does not compose with hc_mult {cfg.hc_mult} "
+            "(several residual streams over latent pools): the verify step "
+            "(_verify) has not been held to the streams' reference")
+    if prefix_cache_pages:
+        raise ConfigError(
+            f"prefix_cache_pages does not compose with hc_mult {cfg.hc_mult} "
+            "yet: the prefix cache over latent pools has not been held to "
+            "the streams' reference")
+    reg = global_registry()
+    reg.gauge("arkflow_gen_residual_streams",
+              "residual streams a token carries between sub-layers (hc_mult)",
+              {"model": name}).set(cfg.hc_mult)
+    reg.gauge("arkflow_gen_residual_bytes_per_token",
+              "bytes of a token's residual between sub-layers (bfloat16 "
+              "streams: hc_mult x dim x 2)", {"model": name}).set(
+                  cfg.hc_mult * cfg.dim * 2)

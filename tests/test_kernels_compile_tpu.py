@@ -1066,3 +1066,29 @@ def test_gdn_steps_carry_pools_and_stacks_whole(v5e):
             assert name in text, name
         assert step.memory_analysis().temp_size_in_bytes < 300 * 1024 * 1024, \
             step.memory_analysis().temp_size_in_bytes
+
+
+# -- the residual streams' mixing (ops/mhc_mix.py), at Xing4.0's widths --------
+
+XING_STREAMS, XING_HIDDEN = 4, 3584
+
+
+@pytest.mark.parametrize("rows", [512, 32, 16])
+def test_mhc_mixing_kernels_compile_at_xing4_widths(v5e, rows):
+    """A chunk's 512 rows (four token tiles), a decode step's 32 lanes and
+    the fewest rows the kernels take, each ONE grid step of its own rows:
+    the 128 x 128 transposes of a short tile, the [14336, 128] operand in
+    one buffer, 64 MB of fast memory asked for."""
+    from arkflow_tpu.ops import mhc_mix as mm
+
+    n, c = XING_STREAMS, XING_HIDDEN
+    k = mm.n_coefficients(n)
+    kw = dict(n=n, iters=20, eps=1e-6, clamp=(-30.0, 30.0), norm_eps=1e-6)
+    pre = _compile(
+        lambda x, phi, b, alpha: mm.mhc_pre_kernel(
+            x, {"phi": phi, "b": b, "alpha": alpha}, **kw),
+        v5e, ((1, rows, n * c), BF16), ((n * c, k), jnp.float32),
+        ((k,), jnp.float32), ((3,), jnp.float32))
+    post = _compile(mm.mhc_post_kernel, v5e, ((1, rows, n * c), BF16),
+                    ((1, rows, c), BF16), ((1, rows, 128), jnp.float32))
+    assert "mhc_pre" in pre.as_text() and "mhc_post" in post.as_text()
