@@ -951,14 +951,14 @@ def _latent_group(tile_c: int, heads: int, page: int, lat: int, held: int,
     return group if group < lanes else group // lanes * lanes
 
 
-def _page_walk(copies, page_of, lo, hi, group: int):
+def _page_walk(copies, page_of, lo, hi, group: int, run: int = 0):
     """The double-buffered walk of the logical pages ``lo`` .. ``hi`` - 1 in
     groups of ``group``, ``_paged_kernel``'s shape: ``copies(slot, j, src)``
     are the copies of physical page ``src`` into ``slot`` as a group's
-    ``j``-th page, ``page_of(i)`` the physical page of logical page ``i``.
-    Returns (steps, ``start``, ``arrive``): ``start(0, 0)`` once, then
-    ``arrive(g)`` a step — it starts the group after the g-th, waits for the
-    g-th and returns its slot. A group's pages past ``hi`` are not copied."""
+    ``j``-th page (of ``run`` pages from ``src`` on: ``_by_runs``),
+    ``page_of(i)`` logical page ``i``'s. Returns (steps, ``start``,
+    ``arrive``): ``start(0, 0)`` once, then ``arrive(g)`` a step — starts the
+    group after the g-th, waits for the g-th, returns its slot. Nothing past ``hi``."""
     steps = (hi - lo + (group - 1)) // group
 
     def live(g):
@@ -988,7 +988,89 @@ def _page_walk(copies, page_of, lo, hi, group: int):
         wait(g, slot)
         return slot
 
+    if run:  # ``arrive`` finds the names at its call: the lines above stand
+        start, wait = _by_runs(copies, page_of, lambda g: lo + g * group, live, run)
     return steps, start, arrive
+
+
+#: pages ONE copy of the latent walk moves where a slot's table holds them
+#: side by side (``_by_runs``; ``tpu/serving.py`` hands a slot its pages in
+#: such blocks, ``_FreePages``): a copy's issue costs the same whatever its
+#: size, and the scalar core that issues it is what a walk waits for. It
+#: divides the group, or the walk takes no runs. On a v5e, us a call a layer
+#: at 2 / 4 / 8 pages a copy (PERF.md, PR 54), xing4_l10's 32 decode lanes at
+#: ~8.8k keys, 1,346 without runs: over a table laid in runs 1,513 / 1,288 /
+#: 693, over a permuted one 1,521 / 1,294 / 1,146; its 512-token chunk at
+#: offset 6,144, 2,040 without: 2,087 / 2,011 / 1,795 and 2,099 / 2,013 /
+#: 1,973. NO gain under 8: where the page-at-a-time branch is short (8
+#: copies at 4 pages) the test of a stretch costs what both branches cost
+#: (read: compiled to predicated straight-line code; with that branch a
+#: LOOP 4 pages gave 784, 8 gave 691 and a permuted table 1,464 / 1,336).
+#: 16 gave 673 where 8 gave 676: the bytes lead from there on
+PAGE_RUN = 8
+
+
+def _by_runs(copies, page_of, first, live, run: int):
+    """``_page_walk``'s ``start`` and ``wait`` over aligned stretches of
+    ``run`` pages: a stretch whose pages are all live and whose table entries
+    are consecutive physical pages is started as ONE copy a pool
+    (``copies(slot, j, src, run)``), any other a page at a time, as the walk
+    without runs; then the group's live pages past its last whole stretch.
+    A whole stretch is awaited as one copy however it was started: a
+    semaphore counts bytes, and the wait takes its size from the copy."""
+
+    def walk(g, slot, stretch_at, act):
+        n, i0 = live(g), first(g)
+
+        def page_at(j, _):
+            for dma in copies(slot, j, page_of(i0 + j)):
+                act(dma)
+
+        jax.lax.fori_loop(
+            0, n // run, lambda s, _: stretch_at(i0 + s * run, s * run), None)
+        jax.lax.fori_loop(n // run * run, n, page_at, None)
+
+    def start(g, slot):
+        def stretch_at(i, j):  # logical pages i .. i + run - 1, the group's j-th on
+            src = [page_of(i + k) for k in range(run)]
+
+            def whole():
+                for dma in copies(slot, j, src[0], run):
+                    dma.start()
+
+            def paged():
+                for k in range(run):
+                    for dma in copies(slot, j + k, src[k]):
+                        dma.start()
+
+            jax.lax.cond(functools.reduce(jnp.logical_and, (
+                src[k] == src[0] + k for k in range(1, run))), whole, paged)
+
+        walk(g, slot, stretch_at, lambda dma: dma.start())
+
+    def wait(g, slot):
+        def stretch_at(_, j):
+            for dma in copies(slot, j, 0, run):
+                dma.wait()
+
+        walk(g, slot, stretch_at, lambda dma: dma.wait())
+
+    return start, wait
+
+
+def pages_in_runs(table, walked) -> int:
+    """``_by_runs``'s test on the host (numpy, a server's counter): of the
+    first ``walked[b]`` columns of each row of ``table``, the pages in whole
+    aligned stretches of ``PAGE_RUN`` consecutive physical pages, which a
+    walk of the row moves a stretch a copy."""
+    import numpy as np
+
+    table, run, whole = np.asarray(table), PAGE_RUN, np.asarray(walked) // PAGE_RUN
+    # the stretches any row walks whole
+    n = min(int(whole.max(initial=0)), table.shape[1] // run)
+    laid = table[:, :n * run].reshape(len(table), n, run)
+    side_by_side = (laid[..., 1:] - laid[..., :-1] == 1).all(-1)
+    return run * int((side_by_side & (np.arange(n) < whole[:, None])).sum())
 
 
 def _latent_kernel(layer_ref, off_ref, table_ref, ql_ref, qr_ref, *rest,
@@ -1017,15 +1099,21 @@ def _latent_kernel(layer_ref, off_ref, table_ref, ql_ref, qr_ref, *rest,
         lo, hi = 0, jnp.minimum(hi, ring)
     layer = layer_ref[0]
 
-    def copies(slot, j, src):
-        dst = pl.ds(pl.multiple_of(j * page, page), page)
-        return (pltpu.make_async_copy(c_hbm.at[layer, src], c_buf.at[slot, dst],
-                                      sems.at[0, slot]),
-                pltpu.make_async_copy(r_hbm.at[layer, src], r_buf.at[slot, dst],
-                                      sems.at[1, slot]))
+    def copies(slot, j, src, n=None):
+        # a slot is [group, page, width]: ``n`` pages side by side in the
+        # pool are one stretch of it, and of the slot
+        at = (lambda a: a) if n is None else (lambda a: pl.ds(a, n))
+        return (pltpu.make_async_copy(c_hbm.at[layer, at(src)],
+                                      c_buf.at[slot, at(j)], sems.at[0, slot]),
+                pltpu.make_async_copy(r_hbm.at[layer, at(src)],
+                                      r_buf.at[slot, at(j)], sems.at[1, slot]))
 
+    # a ring's tile is one group of a window pool's pages: no runs there
+    # (nor in a pool that holds less than one)
+    runs = not window and group % PAGE_RUN == 0 and c_hbm.shape[1] >= PAGE_RUN
     steps, start, arrive = _page_walk(
-        copies, lambda i: table_ref[bi, i % ring if window else i], lo, hi, group)
+        copies, lambda i: table_ref[bi, i % ring if window else i], lo, hi, group,
+        PAGE_RUN if runs else 0)
 
     @pl.when(jnp.logical_and(bi == 0, ci == 0))
     def _finite():
@@ -1046,7 +1134,8 @@ def _latent_kernel(layer_ref, off_ref, table_ref, ql_ref, qr_ref, *rest,
     def body(g, acc):
         o, m, l = acc
         slot = arrive(g)
-        kc, kr = c_buf[slot], r_buf[slot]                 # [width, L], [width, R]
+        kc = c_buf[slot].reshape(width, lat)              # whole sublane tiles
+        kr = r_buf[slot].reshape(width, -1)               # [width, L], [width, R]
         scores = (jax.lax.dot_general(ql_ref[...], kc, dims,
                                       preferred_element_type=jnp.float32)
                   + jax.lax.dot_general(qr_ref[...], kr, dims,
@@ -1152,8 +1241,8 @@ def mla_paged_attention(q_lat, q_rope, c_pages, r_pages, layer, page_table,
         ],
         out_specs=pl.BlockSpec((None, rows, lat), _q_index),
         scratch_shapes=[
-            pltpu.VMEM((2, group * page, lat), c_pages.dtype),
-            pltpu.VMEM((2, group * page, held), r_pages.dtype),
+            pltpu.VMEM((2, group, page, lat), c_pages.dtype),
+            pltpu.VMEM((2, group, page, held), r_pages.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
         ],
     )

@@ -354,7 +354,7 @@ class GenerationServer:
         self._prefix_lengths: dict[int, int] = {}
 
         # host-side state
-        self._free_pages: list[int] = list(range(1, self.num_pages))
+        self._free_pages = _FreePages(self.num_pages)
         self._page_refs: dict[int, int] = {}
         self._slot_req: list[Optional[_Request]] = [None] * slots
         self._slot_pages: list[list[int]] = [[] for _ in range(slots)]
@@ -630,16 +630,16 @@ class GenerationServer:
         # since PR 44 a latent model's mla_paged_attention): pages walked
         # beside the table's columns, a layer, from lengths on the host.
         # Their ratio is the live share of the table (a chunk's earlier
-        # query tiles stop sooner than its last, which is counted)
+        # query tiles stop sooner than its last, which is counted). The
+        # latent walk moves a whole aligned stretch of PAGE_RUN pages that
+        # sit side by side in the pool as ONE copy (since PR 54): the walked
+        # pages of such stretches are counted too, by the kernel's predicate
+        # over the table rows the step carries (a per-head kernel takes no
+        # runs and counts none). Names and texts: ``_WALK_COUNTERS``, at
+        # the end of this file, where new lines re-key no served program
         self.m_attn_walk = {} if self.decode_kernel != "paged" else {
             kind: tuple(reg.counter(metric, text, {"model": name, "kind": kind})
-                        for metric, text in (
-                            ("arkflow_gen_attn_pages_walked_total",
-                             "kept-pool pages the attention kernel's rows "
-                             "walked, a layer"),
-                            ("arkflow_gen_attn_table_columns_total",
-                             "kept page-table columns of the rows the "
-                             "attention kernel was called with, a layer")))
+                        for metric, text in _WALK_COUNTERS)
             for kind in ("decode", "chunk")}
         # the (row, query tile) programs of the per-head kernel's calls, a
         # layer, by the product each makes: a K/V head at a time over that
@@ -1059,7 +1059,7 @@ class GenerationServer:
         self._cache_pages.clear()
         self._prefix_lengths.clear()
         self._page_refs.clear()
-        self._free_pages = list(range(1, self.num_pages))
+        self._free_pages = _FreePages(self.num_pages)
         self._win_free = list(range(1, self.num_win_pages))
         self._slot_win = [{} for _ in range(self.slots)]
         self.k_pages, self.v_pages = self._init_pools()
@@ -1561,12 +1561,14 @@ class GenerationServer:
     def _pages_needed(self, n_tokens: int) -> int:
         return -(-n_tokens // self.page_size)
 
-    def _alloc_page(self) -> Optional[int]:
-        """One fresh page (ref=1); evicts LRU prefix entries under pressure."""
+    def _alloc_page(self, pages: list[int]) -> Optional[int]:
+        """One fresh page (ref=1) for the next column of a slot that holds
+        ``pages``, out of a block of neighbours where one is free
+        (``_FreePages``); evicts LRU prefix entries under pressure."""
         while not self._free_pages:
             if not self._evict_one():
                 return None
-        p = self._free_pages.pop()
+        p = self._free_pages.take(len(pages), pages[-1] if pages else None)
         self._page_refs[p] = 1
         return p
 
@@ -1577,7 +1579,7 @@ class GenerationServer:
         self._page_refs[p] -= 1
         if self._page_refs[p] == 0:
             del self._page_refs[p]
-            self._free_pages.append(p)
+            self._free_pages.give(p)
 
     @property
     def _cache_held(self) -> int:
@@ -1670,7 +1672,7 @@ class GenerationServer:
                 self._ref_page(p)
         pages = list(shared)
         for _ in range(fresh_needed):
-            p = self._alloc_page()
+            p = self._alloc_page(pages)
             if p is None:  # shouldn't happen after the feasibility check
                 for q in pages:
                     self._unref_page(q)
@@ -1929,9 +1931,11 @@ class GenerationServer:
         ids = np.zeros(c, np.int32)
         ids[:len(chunk)] = chunk
         self._slide_window(slot, off, new_off - 1)
-        packed = pack_operands(ids, off, len(chunk), self._table(slot))
+        table = self._table(slot)
+        packed = pack_operands(ids, off, len(chunk), table)
         if kind == "chunk":
-            self._note_walk("chunk", np.asarray([off + c - 1]), len(chunk), c)
+            self._note_walk("chunk", np.asarray([off + c - 1]), len(chunk), c,
+                            table=table)
         if self._stateful:
             valid, masked = self.m_ssm["chunk"]
             valid.inc(len(chunk))
@@ -2046,7 +2050,7 @@ class GenerationServer:
             total = int(self._lengths[slot]) + 1
         need = self._pages_needed(total)
         while len(self._slot_pages[slot]) < need:
-            p = self._alloc_page()
+            p = self._alloc_page(self._slot_pages[slot])
             if p is None:
                 return False
             self._slot_pages[slot].append(p)
@@ -2342,17 +2346,21 @@ class GenerationServer:
             if act.any():
                 for s in map(int, np.flatnonzero(act)):
                     self._slide_window(s, int(lens[s]), int(lens[s]))
-                packed = pack_operands(cur, lens, act, self._table())
+                table = self._table()
+                packed = pack_operands(cur, lens, act, table)
                 # a packed step is issued
-                self._note_walk("decode", lens, int(act.sum()))
+                self._note_walk("decode", lens, int(act.sum()), table=table)
         return act, packed, prev, prep.dur_s
 
-    def _note_walk(self, kind: str, last, queries: int, width: int = 1) -> None:
+    def _note_walk(self, kind: str, last, queries: int, width: int = 1, *,
+                   table) -> None:
         """Count the kept pages a step's rows walk: ``last`` [rows] each
         row's last query position as the kernel is given it (an idle lane's
         0 walks its one scratch page). ``queries``: the step's real queries
         (active lanes, a chunk's unpadded positions), each a row of every
-        layer with a sink. ``width``: the positions a row of the step."""
+        layer with a sink. ``width``: the positions a row of the step.
+        ``table``: the rows of the page table the step carries, of which a
+        latent walk's pages in runs are counted."""
         if self.m_attn_tiles:
             for product, tiles in (self._tiles_of.get(width)
                                    or self._attn_tiles(width)).items():
@@ -2360,10 +2368,13 @@ class GenerationServer:
         if self.m_sink_rows:
             self.m_sink_rows[kind].inc(queries * self._sink_layers)
         if self.m_attn_walk:
-            walked, columns = self.m_attn_walk[kind]
+            walked, columns, in_runs = self.m_attn_walk[kind]
             cols = self.pages_per_slot
-            walked.inc(int(np.minimum(last // self.page_size + 1, cols).sum()))
+            pages = np.minimum(last // self.page_size + 1, cols)
+            walked.inc(int(pages.sum()))
             columns.inc(cols * len(last))
+            if self.cfg.latent:
+                in_runs.inc(pages_in_runs(table[:, :cols], pages))
 
     def _attn_tiles(self, width: int) -> dict[str, int]:
         """The attention kernel's query tiles of one row of ``width``
@@ -2545,3 +2556,73 @@ def _serve_streams(server: GenerationServer, name: str, prefix_cache_pages,
               "bytes of a token's residual between sub-layers (bfloat16 "
               "streams: hc_mult x dim x 2)", {"model": name}).set(
                   cfg.hc_mult * cfg.dim * 2)
+
+
+# -- page runs (new code ends this file: a served program's compile-cache key
+# carries the line of every frame above its kernels, ``_decode`` and ``_chunk``
+# among them, and lines added above them would re-key every cell's programs) --
+
+from arkflow_tpu.ops.ragged_attention import PAGE_RUN, pages_in_runs  # noqa: E402
+
+#: ``m_attn_walk[kind]``: pages walked, columns carried, pages walked in runs
+_WALK_COUNTERS = (
+    ("arkflow_gen_attn_pages_walked_total",
+     "kept-pool pages the attention kernel's rows walked, a layer"),
+    ("arkflow_gen_attn_table_columns_total",
+     "kept page-table columns of the rows the attention kernel was called "
+     "with, a layer"),
+    ("arkflow_gen_attn_pages_in_runs_total",
+     "kept-pool pages the latent kernel's rows walked in whole stretches of "
+     "neighbours, a stretch a copy, a layer"),
+)
+
+
+class _FreePages:
+    """The kept pool's free pages (page 0 is the scratch page), handed out so
+    that a slot's table holds RUNS: the pool is cut into blocks of ``run``
+    neighbours from page 1 on (pages past the last whole block are single),
+    and a slot's column c takes page c % run of a block — a new block's first
+    page, the lowest block that is free whole, at c % run == 0, the page
+    after its last one further on — so that an aligned stretch of its table
+    names ``run`` consecutive pages, which the latent walk moves as one copy
+    (``ops/ragged_attention._by_runs``). Nothing is set aside for a slot: the
+    rest of its block stays free, counts as free and goes to whoever asks
+    once no whole block is left, so the pool admits what it admitted as a
+    plain list; a page is given back alone (sharing and eviction are a
+    page's), and a block whose pages are all back is whole again. A take
+    scans the blocks' counts (a few thousand: microseconds)."""
+
+    def __init__(self, num_pages: int, run: int = PAGE_RUN):
+        self.run = run
+        self._free = np.ones(num_pages, bool)
+        self._free[0] = False
+        self._count = num_pages - 1
+        #: free pages of each block (a short last block is never whole)
+        self._left = np.bincount((np.arange(1, num_pages) - 1) // run)
+
+    def __len__(self) -> int:
+        return self._count
+
+    def take(self, column: int, last: Optional[int]) -> int:
+        """A free page for a slot's ``column``, ``last`` its page of the
+        column before."""
+        k = column % self.run
+        p = last + 1 if k else None
+        if not (k and p < len(self._free) and self._free[p]
+                and (p - 1) % self.run == k):
+            # no run to go on with: a new one where a block is whole (the
+            # lowest), else the lowest page of a block that is not
+            whole = self._left == self.run
+            partial = (self._left > 0) & ~whole
+            blocks = whole if whole.any() and not (k and partial.any()) else partial
+            first = 1 + int(np.argmax(blocks)) * self.run
+            p = first + int(np.argmax(self._free[first:first + self.run]))
+        self._free[p] = False
+        self._left[(p - 1) // self.run] -= 1
+        self._count -= 1
+        return p
+
+    def give(self, p: int) -> None:
+        self._free[p] = True
+        self._left[(p - 1) // self.run] += 1
+        self._count += 1

@@ -422,6 +422,22 @@ def test_mla_paged_attention_compiles(v5e, rows, chunk):
     assert compiled.memory_analysis().temp_size_in_bytes < LATENT_TEMP_LIMIT
 
 
+@pytest.mark.parametrize("rows,chunk", [(32, 1), (1, 512)], ids=["decode", "chunk512"])
+def test_the_latent_walk_in_runs_compiles_at_xing4_widths(v5e, rows, chunk):
+    """The walk that moves a stretch of ``PAGE_RUN`` neighbours as one copy
+    (PR 54), at the cell it was written for: 32 lanes over 992 columns, a
+    512-token chunk in tiles of 32 positions; the slots [group, page, width]
+    are read back as whole sublane tiles."""
+    from arkflow_tpu.ops import ragged_attention as ra
+
+    assert ra._latent_group(ra.latent_query_tile(chunk, 32, 512), 32, 16, 512,
+                            ROPE_HELD, 2) % ra.PAGE_RUN == 0
+    compiled = _latent_compiled(v5e, rows, chunk, 32, 512, (10, 1 + 32 * 992), 992,
+                                scale=192 ** -0.5)
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < LATENT_TEMP_LIMIT
+
+
 def test_latent_rope_keys_of_64_lanes_are_not_walked(v5e):
     """Why the rope keys are held in whole 128-lane rows: the kernel's own
     copy of a page out of a 64-lane pool is refused (Mosaic sees the pool

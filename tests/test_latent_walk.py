@@ -21,14 +21,16 @@ PAGE, LAT, ROPE, HEADS = 8, 16, 4, 4
 SCALE = float(8 + ROPE) ** -0.5
 
 
-def _case(seed, off, c, cols, window=0, heads=HEADS, lat=LAT):
+def _case(seed, off, c, cols, window=0, heads=HEADS, lat=LAT, lay=None):
     """Queries, pools and a table of ``cols`` columns a row for rows whose
-    first query sits at ``off[b]``: non-contiguous pages, layer 1 of two,
-    the rope keys as held; pages no attendable key sits on are NaN."""
+    first query sits at ``off[b]``: non-contiguous pages (or as ``lay`` lays
+    the pool's pages out), layer 1 of two, the rope keys as held; pages no
+    attendable key sits on are NaN."""
     rng = np.random.RandomState(seed)
     b = len(off)
     n_pages = 1 + b * cols
-    table = 1 + rng.permutation(b * cols).reshape(b, cols)
+    table = 1 + (rng.permutation(b * cols) if lay is None else lay(rng, b * cols)
+                 ).reshape(b, cols)
     table[np.asarray(off) == 0] = 0  # an idle lane: the scratch page
     cp = rng.normal(0, 1, (2, n_pages, PAGE, lat)).astype(np.float32)
     rp = np.zeros((2, n_pages, PAGE, _held_lanes(ROPE)), np.float32)
@@ -105,6 +107,87 @@ def test_the_walk_matches_the_gather_form(monkeypatch, case):
     off, c, cols, group = WALKS[case]
     operands = _case(len(case), off, c, cols)
     _close(_walk(monkeypatch, group, *operands), _reference(*operands))
+
+
+def _blocks(run, keep=lambda s: True, ascending=False):
+    """A layout for ``_case``: the pool cut into blocks of ``run`` neighbours,
+    a block an aligned stretch of the (flattened) table, the blocks in any
+    order (``ascending``: in the pool's, a row one long run); stretch ``s``
+    holds its block as it lies where ``keep(s)``, back to front (no two of
+    its entries consecutive upwards) where not."""
+    def lay(rng, n):
+        order = np.arange(n // run) if ascending else rng.permutation(n // run)
+        pages = order[:, None] * run + np.arange(run)
+        turned = [s for s in range(len(pages)) if not keep(s)]
+        pages[turned] = pages[turned, ::-1]
+        return pages.reshape(-1)
+    return lay
+
+
+#: (first query positions, queries a row, table columns, pages a group, pages
+#: a run, the layout of the table; a window). 8 keys a page
+RUNS = {
+    # every aligned stretch of a row's table is one copy
+    "all_runs": ([37, 90, 8], 1, 12, 4, 2, _blocks(2), 0),
+    # the file's permuted tables: every stretch a page at a time, as before
+    "no_runs": ([37, 90, 8], 1, 12, 4, 2, None, 0),
+    # every other stretch lies back to front
+    "mixed": ([61, 90], 3, 12, 8, 4, _blocks(4, lambda s: s % 2 == 0), 0),
+    # position 37 sits on page 4 of a stretch of pages 4-7: pages 5-7 are
+    # NaN in the pool, side by side with page 4, and are not read
+    "a_run_across_the_walks_last_live_page": ([37, 75], 1, 12, 8, 4, _blocks(4), 0),
+    # a row is ONE run of 12 neighbours over groups of 4 pages: a stretch
+    # ends where its group does, the next group starts the next copy
+    "a_run_across_a_groups_edge": ([90, 70], 2, 12, 4, 4,
+                                   _blocks(4, ascending=True), 0),
+    # a lane at 0: its table row is the scratch page, its walk one page
+    "an_idle_lane_on_the_scratch_page": ([0, 50, 0], 1, 8, 4, 2, _blocks(2), 0),
+    # a ring under a window is walked as before whatever its pages are
+    "a_window_ring_takes_no_runs": ([85, 3, 40], 1, 4, 32, 2, _blocks(2), 19),
+}
+
+
+@pytest.mark.parametrize("case", sorted(RUNS))
+def test_a_table_in_runs_is_walked_a_stretch_a_copy(monkeypatch, case):
+    """The walk over tables whose aligned stretches of ``PAGE_RUN`` entries
+    name neighbours in the pool (one copy a pool a stretch), over tables
+    without, and over both in one row, against the gather form: every table
+    gives the plain form's answers, and no page past a row's last live one
+    is read (they are NaN; a copied one would show)."""
+    off, c, cols, group, run, lay, window = RUNS[case]
+    monkeypatch.setattr(ra, "PAGE_RUN", run)
+    operands = _case(len(case), off, c, cols, window=window, lay=lay)
+    table, walked = np.asarray(operands[4]), np.asarray(off) // PAGE + 1
+    taken = ra.pages_in_runs(table, walked)
+    assert (taken == 0) == (lay is None)
+    if case == "a_run_across_the_walks_last_live_page":
+        assert taken == 4 + 8 and np.isnan(np.asarray(
+            operands[2], np.float32)[1, table[0, 5:8]]).all()
+    _close(_walk(monkeypatch, group, *operands, window=window),
+           _reference(*operands, window=window))
+
+
+def test_the_walk_takes_runs_only_where_a_stretch_fits():
+    """One copy moves ``PAGE_RUN`` pages of a group: a group that is no
+    multiple of it, a ring under a window and a pool smaller than a stretch
+    are walked a page at a time, the kernel as it was (no test of the table
+    in it); elsewhere a stretch's copy carries ``PAGE_RUN`` pages."""
+    def kernel_text(group, window=0, pages=40):
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(ra, "_LATENT_WALK_MAX", group)
+            q = jnp.zeros((2, 1, HEADS, LAT), jnp.bfloat16)
+            return str(jax.make_jaxpr(lambda *a: ra.mla_paged_attention.__wrapped__(
+                *a, scale=SCALE, window=window))(
+                q, q[..., :ROPE], jnp.zeros((2, pages, PAGE, LAT), jnp.bfloat16),
+                jnp.zeros((2, pages, PAGE, 128), jnp.bfloat16), 1,
+                jnp.zeros((2, 16), jnp.int32), jnp.zeros((2,), jnp.int32)))
+
+    assert ra.PAGE_RUN == 8
+    plain = [kernel_text(12), kernel_text(8, window=19), kernel_text(8, pages=7)]
+    assert len({text.count("cond[") for text in plain}) == 1
+    # the test of a stretch's entries, where the first group is started and
+    # where the next one is
+    assert kernel_text(8).count("cond[") == plain[0].count("cond[") + 2
 
 
 @pytest.mark.parametrize("tile", ["one_tile", "tiles_of_8"])
@@ -251,3 +334,45 @@ def test_a_latent_server_counts_the_pages_its_rows_walk(layout, kernel):
         1 + 2, 2 * cols)
     assert (got["pages_walked", "decode"], got["table_columns", "decode"]) == (
         3 * (2 + 1), 3 * 2 * cols)
+
+
+def test_a_latent_server_counts_its_runs_and_uploads_nothing_for_them():
+    """A prompt of 70 tokens in chunks of 8 and four new tokens over 13
+    columns of 8 keys: the slot's first eight pages are one block of the
+    pool (``_FreePages``), so every step whose row walks eight pages or more
+    moves its first stretch as one copy — the chunks that end at 63 and 71,
+    the three decode steps at 70..72 — and
+    ``arkflow_gen_attn_pages_in_runs_total`` counts eight pages each, by the
+    kernel's predicate over the table the step carries anyway: every step
+    still hands the device ONE host array (``gen_uploads_per_step`` 1.0)."""
+    import asyncio
+
+    from arkflow_tpu.components import Resource, build_component, ensure_plugins_loaded
+    from arkflow_tpu.obs import global_registry
+
+    ensure_plugins_loaded()
+    server = build_component("processor", {
+        "type": "tpu_generate", "model": "decoder_lm", "model_config": LATENT,
+        "serving": "continuous", "max_input": 100, "max_new_tokens": 4, "slots": 2,
+        "page_size": PAGE, "seq_buckets": [8], "prefill_chunk": 8, "eos_id": -1,
+        "decode_kernel": "paged", "kernel_interpret": True, "seed": 3,
+        "dispatch_depth": 1}, Resource())._server
+    assert ra.PAGE_RUN == 8 and server.pages_per_slot == 13  # 104 positions
+    reg = global_registry()
+
+    def counts():
+        return {kind: (reg.counter("arkflow_gen_attn_pages_in_runs_total", labels={
+            "model": "decoder_lm", "kind": kind}).value, server.m_uploads[kind].value)
+            for kind in ("decode", "chunk")}
+
+    steps = dict.fromkeys(("decode", "chunk"), 0)
+    for kind in steps:
+        def counted(*args, _fn=getattr(server, "_" + kind), _kind=kind):
+            steps[_kind] += 1
+            return _fn(*args)
+        setattr(server, "_" + kind, counted)
+    before = counts()
+    out = asyncio.run(server.generate(list(range(1, 71)), 4))
+    got = {k: (a - before[k][0], b - before[k][1]) for k, (a, b) in counts().items()}
+    assert len(out) == 4 and steps == {"decode": 3, "chunk": 9}
+    assert got == {"chunk": (8 + 8, 9), "decode": (3 * 8, 3)}

@@ -816,9 +816,10 @@ def test_server_counts_the_pages_its_rows_walk():
     assert len(out) == 5
     cols = 10                                    # 40 positions of 4 a page
     # the chunks' last queries sit at 7 and 15: 2 and 4 pages
-    assert got["chunk"] == [2 + 4, 2 * cols]
+    # (the third counter: pages in runs, which a per-head kernel never takes)
+    assert got["chunk"] == [2 + 4, 2 * cols, 0]
     # four decode steps at lengths 13..16 (4, 4, 4, 5 pages) + the idle lane
-    assert got["decode"] == [4 + 4 + 4 + 5 + 4, 4 * 2 * cols]
+    assert got["decode"] == [4 + 4 + 4 + 5 + 4, 4 * 2 * cols, 0]
 
 
 def test_server_counts_its_query_tiles_by_the_product_they_make():

@@ -916,3 +916,203 @@ def test_generation_server_observability_metrics():
     assert rep["page_pool_occupancy"] == pytest.approx(expected_occ, abs=1e-4)
     assert rep["prefix_cache"]["capacity_pages"] == 2
     assert "deadline_misses" in rep and rep["state"] == "healthy"
+
+
+# -- page runs (PR 54): the kept pool's free pages come in blocks of neighbours --
+
+
+def _take(free, n, pages=None):
+    """``n`` more pages for a slot that holds ``pages``, as ``_alloc_page`` asks."""
+    pages = [] if pages is None else pages
+    for _ in range(n):
+        pages.append(free.take(len(pages), pages[-1] if pages else None))
+    return pages
+
+
+def _blocks_are_aligned_and_ascending():
+    from arkflow_tpu.tpu.serving import _FreePages
+
+    free = _FreePages(1 + 40, run=4)
+    a = _take(free, 10)
+    assert a == list(range(1, 11))          # blocks 1-4, 5-8, and 9, 10 of the third
+    b = _take(free, 5)
+    assert b == [13, 14, 15, 16, 17]        # the lowest block that is whole: not 11, 12
+    assert _take(free, 2, a) == list(range(1, 13))  # a goes on in its own block
+    assert len(free) == 40 - 12 - 5
+
+
+def _release_re_forms_blocks():
+    from arkflow_tpu.tpu.serving import _FreePages
+
+    free = _FreePages(1 + 24, run=4)
+    a, b = _take(free, 7), _take(free, 6)
+    assert (a, b) == ([1, 2, 3, 4, 5, 6, 7], [9, 10, 11, 12, 13, 14])
+    for p in (5, 2, 7, 1, 4, 6, 3):         # a page at a time, in any order
+        free.give(p)
+    assert len(free) == 24 - 6
+    assert _take(free, 8) == [1, 2, 3, 4, 5, 6, 7, 8]   # whole again, lowest first
+
+
+def _single_pages_once_no_whole_block_is_free():
+    from arkflow_tpu.tpu.serving import _FreePages
+
+    free = _FreePages(1 + 12, run=4)
+    held = [_take(free, 2) for _ in range(3)]
+    assert held == [[1, 2], [5, 6], [9, 10]]
+    # no block is whole: the blocks' free pages, lowest first, a neighbour
+    # where the column is its place in its block (column 3: page 8)
+    assert _take(free, 5) == [3, 4, 7, 8, 11]
+    assert len(free) == 1 and free.take(5, 11) == 12 and len(free) == 0
+
+
+def _a_short_last_block_is_single_pages():
+    from arkflow_tpu.tpu.serving import _FreePages
+
+    free = _FreePages(1 + 11, run=8)        # 8 neighbours and 3 pages past them
+    assert _take(free, 11) == list(range(1, 12)) and len(free) == 0
+    for p in range(1, 12):
+        free.give(p)
+    # columns 2..4 behind two pages of another pool's numbering: no run to go
+    # on with, and a page of a block that is not whole before one that is
+    assert _take(free, 3, [40, 41]) == [40, 41, 9, 10, 11]
+
+
+ALLOCATOR = {f.__name__[1:]: f for f in (
+    _blocks_are_aligned_and_ascending, _release_re_forms_blocks,
+    _single_pages_once_no_whole_block_is_free, _a_short_last_block_is_single_pages)}
+
+
+@pytest.mark.parametrize("case", sorted(ALLOCATOR))
+def test_free_pages_come_in_runs(case):
+    ALLOCATOR[case]()
+
+
+@pytest.mark.parametrize("seed,run", [(0, 8), (1, 4), (2, 3)])
+def test_free_pages_admit_what_a_plain_list_admits(seed, run):
+    """The admission rule reads ``len(free)`` and takes that many pages: over
+    any sequence of takes and gives the count is a plain list's, a take
+    succeeds while it is not 0 and hands out a page that was free, once."""
+    from arkflow_tpu.tpu.serving import _FreePages
+
+    rng = np.random.RandomState(seed)
+    n = 1 + 50
+    free, plain = _FreePages(n, run=run), set(range(1, n))
+    slots, cached = [[] for _ in range(4)], []
+    for _ in range(600):
+        s, move = slots[rng.randint(4)], rng.rand()
+        if plain and move < 0.6:
+            p = free.take(len(s), s[-1] if s else None)
+            assert p in plain
+            plain.remove(p)
+            s.append(p)
+        elif move < 0.8:                    # a slot finishes: some pages stay cached
+            keep = [p for p in s if rng.rand() < 0.3]
+            back = [p for p in s if p not in keep]
+            cached += keep
+            del s[:]
+        else:                               # an eviction frees a page alone
+            back = [cached.pop(rng.randint(len(cached)))] if cached else []
+        for p in back if move >= 0.6 or not plain else []:
+            free.give(p)
+            plain.add(p)
+        assert len(free) == len(plain)
+
+
+def test_a_pool_of_one_slots_pages_serves_a_max_seq_request():
+    """``num_pages`` exactly 1 + ``pages_per_slot``, that no multiple of
+    ``PAGE_RUN`` (11 = 8 + 3): a request that grows to ``max_seq`` is served
+    from a block and three single pages, token for token the reference's."""
+    from arkflow_tpu.ops.ragged_attention import PAGE_RUN
+
+    fam = get_model("decoder_lm")
+    cfg = fam.make_config(**TINY)
+    params = fam.init(jax.random.PRNGKey(4), cfg)
+    prompt = [3, 17, 42, 7, 91, 12, 8, 2]
+    ref = _reference_generate(fam, params, cfg, prompt, max_new=36, eos_id=-1)
+
+    async def go():
+        server = GenerationServer(params, cfg, slots=1, page_size=4, max_seq=44,
+                                  num_pages=12, eos_id=-1)
+        assert server.pages_per_slot == 11 and server.pages_per_slot % PAGE_RUN
+        task = asyncio.ensure_future(server.generate(prompt, max_new_tokens=36))
+        while not task.done() and len(server._free_pages):
+            await asyncio.sleep(0)
+        pages = list(server._slot_pages[0])
+        out = await task
+        await server.close()
+        return out, pages, len(server._free_pages)
+
+    out, pages, free = asyncio.run(go())
+    assert out == ref and len(out) == 36
+    assert pages == list(range(1, 12)) and free == 11
+
+
+def test_a_page_shared_through_the_prefix_cache_is_freed_alone():
+    """Sharing and eviction stay a page's: the cache holds a finished
+    prompt's full pages — the head of a block whose other pages went back
+    —, a second request aliases them and takes its fresh pages elsewhere,
+    and an eviction frees exactly the cached pages, after which the block
+    is whole again and is handed out as one."""
+    fam = get_model("decoder_lm")
+    cfg = fam.make_config(**TINY)
+    params = fam.init(jax.random.PRNGKey(9), cfg)
+    common = list(range(3, 3 + 12))         # 3 full pages of 4
+
+    async def go():
+        server = GenerationServer(params, cfg, slots=2, page_size=4, max_seq=48,
+                                  prefix_cache_pages=8)
+        total = server.num_pages - 1
+        await server.generate(common + [60, 61], max_new_tokens=5)
+        (held,) = server._prefix_cache.values()
+        assert held == [1, 2, 3] and len(server._free_pages) == total - 3
+        # the second request: the three shared pages, then (columns 3..: no
+        # run to go on with in a block it shares) the block's free pages
+        before = dict(server._page_refs)
+        await server.generate(common + [70, 71, 72], max_new_tokens=5)
+        assert before == {1: 1, 2: 1, 3: 1} == dict(server._page_refs)
+        assert server._evict_one() and not server._page_refs
+        assert len(server._free_pages) == total
+        pages = [server._alloc_page(p) for p in ([], [1], [1, 2])]
+        await server.close()
+        return pages
+
+    assert asyncio.run(go()) == [1, 2, 3]
+
+
+def test_pages_in_runs_counts_what_the_predicate_says():
+    """``arkflow_gen_attn_pages_in_runs_total`` on a hand-made table of 16
+    columns (``PAGE_RUN`` 8): a row walks 13 pages, both of its stretches
+    neighbours — the first whole stretch counts, the second ends past its
+    last page; a row walks all 16, its first stretch two pages swapped, its
+    second neighbours; an idle lane walks its scratch page. A per-head
+    server's kernel takes no runs and counts none."""
+    from arkflow_tpu.obs import global_registry
+    from arkflow_tpu.ops.ragged_attention import PAGE_RUN, pages_in_runs
+
+    table = np.zeros((3, 16), np.int32)
+    table[0] = [*range(1, 9), *range(20, 28)]
+    table[1] = [9, 10, 12, 11, 13, 14, 15, 16, *range(30, 38)]
+    last = np.asarray([100, 127, 0])         # pages of 8 keys: 13, 16 and 1
+    assert PAGE_RUN == 8 and pages_in_runs(table, last // 8 + 1) == 8 + 8
+    assert pages_in_runs(table[:, :12], np.asarray([12, 12, 1])) == 8  # a short tail
+
+    fam = get_model("decoder_lm")
+    latent = fam.make_config(
+        vocab_size=128, dim=32, layers=3, heads=4, ffn=64, max_seq=128,
+        kv_lora_rank=16, qk_nope_head_dim=8, qk_rope_head_dim=4, v_head_dim=8,
+        rope_interleave=True, n_routed_experts=8, num_experts_per_tok=2,
+        n_shared_experts=2, moe_intermediate_size=16, first_k_dense_replace=1)
+    got = {}
+    for name, cfg in (("latent", latent), ("per_head", fam.make_config(**TINY))):
+        params = fam.init(jax.random.PRNGKey(1), cfg)
+        server = GenerationServer(params, cfg, slots=3, page_size=8, max_seq=128,
+                                  decode_kernel="paged", kernel_interpret=True)
+        counters = [global_registry().counter(
+            f"arkflow_gen_attn_{what}_total",
+            labels={"model": "decoder_lm", "kind": "decode"})
+            for what in ("pages_walked", "pages_in_runs")]
+        before = [m.value for m in counters]
+        server._note_walk("decode", last, 2, table=table)
+        got[name] = [m.value - v for m, v in zip(counters, before)]
+        asyncio.run(server.close())
+    assert got == {"latent": [13 + 16 + 1, 16], "per_head": [30, 0]}

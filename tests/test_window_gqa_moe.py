@@ -414,9 +414,14 @@ def test_window_0_gives_the_present_jaxpr(case):
 #: purpose again: an indexed layer's choice is ``dsa_topk_select``'s where it
 #: was a sort's (PR 44's: 5ed8445db227631b, f4474904b5691023); the ``latent``
 #: two stood — the layout without an indexer never reaches that branch.
+#: All four RE-RECORDED at PR 54, on purpose once more: the latent walk moves
+#: a whole stretch of ``PAGE_RUN`` neighbours as one copy and its slots are
+#: [group, page, width] (PR 47's: ccb2ee94b5d8cfe9, 8f160b67834088ca,
+#: fc3e4a6979722248, 4ac205702f34d60a); what that PR had to leave standing
+#: is ``WALK_BYPASS_GOLDEN``, below.
 BYPASS_GOLDEN = {
-    "latent.decode": "ccb2ee94b5d8cfe9", "latent.chunk": "8f160b67834088ca",
-    "pattern.decode": "fc3e4a6979722248", "pattern.chunk": "4ac205702f34d60a"}
+    "latent.decode": "fb8bb5e87fc3416b", "latent.chunk": "12309b7328d12d1a",
+    "pattern.decode": "095ffd5cfa34a880", "pattern.chunk": "d8c6eb810c5ac125"}
 
 
 @pytest.mark.parametrize("case", sorted(BYPASS_GOLDEN))
@@ -462,6 +467,34 @@ def test_latent_programs_do_not_move_with_the_per_head_kernel(case):
     # the served choice is the threshold kernel's: no sort but the router's
     assert ("dsa_topk_select" in text) == (layout == "pattern")
     assert _text_hash(text) == BYPASS_GOLDEN[case], _text_hash(text)
+
+
+#: the mirror of ``BYPASS_GOLDEN`` for PR 54, which changed the latent walk
+#: and ``_page_walk`` under it: the kernels that take no runs — the narrow
+#: head's (row-major pools, ``_page_walk``'s other caller) and the per-head
+#: one (its copies are its own) — trace to what they traced to at that PR's
+#: parent (aa369a0), where these were recorded. A decode tile and a chunk
+#: tile each, straight through ``paged_flash_attention``.
+WALK_BYPASS_GOLDEN = {
+    "narrow.decode": "2e05c2d8f5550240", "narrow.chunk": "b5498163e2e0b03b",
+    "per_head.decode": "4cf27ca18560d7e8", "per_head.chunk": "d86b64cd5e17f3d2"}
+
+
+@pytest.mark.parametrize("case", sorted(WALK_BYPASS_GOLDEN))
+def test_per_head_programs_do_not_move_with_the_latent_walk(case):
+    from arkflow_tpu.ops.ragged_attention import paged_flash_attention
+
+    kernel, step = case.split(".")
+    b, c = (2, 1) if step == "decode" else (1, 8)
+    q = jnp.zeros((b, c, 8, 64 if kernel == "narrow" else 128), jnp.bfloat16)
+    pool = jnp.zeros((2, 9, 16, 128) if kernel == "narrow" else (2, 9, 16, 2, 128),
+                     jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(lambda q, k, v, t, o: paged_flash_attention(
+        q, k, v, 1, t, o))(q, pool, pool, jnp.zeros((b, 4), jnp.int32),
+                           jnp.zeros((b,), jnp.int32))
+    text = _jaxpr_text(jaxpr)
+    assert "paged_flash_attention" in text
+    assert _text_hash(text) == WALK_BYPASS_GOLDEN[case], _text_hash(text)
 
 
 # -- the held share ---------------------------------------------------------------

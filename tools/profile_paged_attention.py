@@ -24,6 +24,7 @@ this process holds. One JSON line a point:
     python tools/profile_paged_attention.py --cell mimo_l7_full --kind chunk
     python tools/profile_paged_attention.py --group-max 16,32,64 # a sweep
     python tools/profile_paged_attention.py --cell lfm2_l12 --form gather
+    python tools/profile_paged_attention.py --cell xing4_l10 --table runs --run 2,4,8
 
 A head narrower than 128 lanes (``lfm2_l12``: 8 K/V heads of 64) has
 row-major pools and the narrow-head walk where the checkout has one
@@ -44,6 +45,7 @@ shapes there to rehearse the control flow; its lines say ``"rehearsal"``).
 from __future__ import annotations
 
 import argparse
+import itertools
 import json
 import sys
 import time
@@ -104,7 +106,13 @@ CELLS = {
     "dots3_l5_window": dict(h=64, lat=1024, rope=64, window=513, lanes=32,
                             cols=65, chunk=512, offsets=(4096,),
                             ctx=(4800, 0.5, 2400, 12500)),
+    # the longest walk of the matrix: 2k-15k tokens in, 512 out
+    "xing4_l10": dict(h=32, lat=512, rope=64, lanes=32, cols=992, chunk=512,
+                      offsets=(2048, 6144, 14848), ctx=(6400, 0.5, 2048, 15872)),
 }
+
+#: pages a block of a ``--table runs`` table: the largest ``--run`` it can show
+BLOCK = 8
 
 
 def _points(args):
@@ -158,8 +166,12 @@ def _pools(cell, seed: int, tiny: bool, rope_held: bool = True,
     return k, v, sink, lanes, cols
 
 
-def _queries(cell, kind, off, rng, lanes: int, cols: int, tiny: bool):
-    """q, the table and the offsets of one point over a cell's pools."""
+def _queries(cell, kind, off, rng, lanes: int, cols: int, tiny: bool,
+             runs: bool = False):
+    """q, the table and the offsets of one point over a cell's pools: a
+    row's pages any of the pool's (``permuted``: what a LIFO free list
+    leaves) or, ``runs``, whole blocks of ``BLOCK`` neighbours, the blocks
+    any of the pool's."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -175,8 +187,11 @@ def _queries(cell, kind, off, rng, lanes: int, cols: int, tiny: bool):
     if not cell.get("window", 0):
         ctx = np.minimum(ctx, cols * PAGE - c)
     key = jax.random.PRNGKey(int(rng.randint(1 << 30)))
-    table = jnp.asarray(
-        1 + rng.permutation(lanes * cols)[:b * cols].reshape(b, cols), jnp.int32)
+    pages = rng.permutation(lanes * cols)
+    if runs:
+        pages = (pages[pages < lanes * cols // BLOCK, None] * BLOCK
+                 + np.arange(BLOCK)).reshape(-1)
+    table = jnp.asarray(1 + pages[:b * cols].reshape(b, cols), jnp.int32)
     off = jnp.asarray(ctx.astype(np.int32))
     if "lat" not in cell:
         return (jax.random.normal(key, (b, c, cell["h"], cell["dk"]), jnp.bfloat16),
@@ -285,6 +300,13 @@ def main() -> int:
     ap.add_argument("--kind", default="all", choices=["all", "decode", "chunk"])
     ap.add_argument("--group-max", default="",
                     help="comma-separated ceilings of pages a group to sweep")
+    ap.add_argument("--table", default="permuted", choices=["permuted", "runs"],
+                    help="runs: a row's pages in blocks of 8 neighbours (what "
+                         "the server's allocator hands out), which the latent "
+                         "walk copies --run pages a descriptor")
+    ap.add_argument("--run", default="",
+                    help="comma-separated pages a copy of the latent walk to "
+                         "sweep (the module's PAGE_RUN)")
     ap.add_argument("--reps", type=int, default=10)
     ap.add_argument("--rounds", type=int, default=5)
     ap.add_argument("--seed", type=int, default=0)
@@ -325,11 +347,15 @@ def main() -> int:
                                              hasattr(ra, "_latent_group"),
                                              hasattr(ra, "narrow_run"))
             held_for = name
-        q, table, ctx = _queries(cell, kind, off, rng, lanes, cols, args.interpret)
+        q, table, ctx = _queries(cell, kind, off, rng, lanes, cols, args.interpret,
+                                 args.table == "runs")
         latent = "lat" in cell
         b, c, h, _ = (q[0] if latent else q).shape
         window = cell.get("window", 0)
-        for ceiling in ceilings:
+        for ceiling, run in itertools.product(
+                ceilings, [int(r) for r in args.run.split(",") if r] or [None]):
+            if run and hasattr(ra, "PAGE_RUN"):  # a checkout that takes runs
+                ra.PAGE_RUN = run
             if ceiling:
                 ra._PAGED_GROUP_MAX = ra._PAGED_CHUNK_GROUP_MAX = ceiling
                 ra._PAGED_ONE_HEAD_GROUP_MAX = ceiling
@@ -370,6 +396,8 @@ def main() -> int:
                     "offset": off, "ctx_mean": float(ctx.mean()),
                     **({"form": "gather"} if args.form == "gather" else {}),
                     **({"group_max": ceiling} if ceiling else {}),
+                    **({"table": args.table, "run": ra.PAGE_RUN}
+                       if latent and hasattr(ra, "PAGE_RUN") else {}),
                     "device": device.device_kind}
             if latent and hasattr(ra, "_latent_group"):  # a checkout that walks
                 tile_c = ra.latent_query_tile(c, h, cell["lat"])
