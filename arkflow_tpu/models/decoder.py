@@ -384,6 +384,30 @@ class DecoderConfig:
     #: (``rope_frequencies``) and a latent layer's softmax scale grows by
     #: ``g(mscale_all_dim)^2``. A latent model's full layers only.
     rope_scaling: Optional[tuple] = None
+    # -- a compacting window cache (EVA: Zheng et al., ICLR 2023,
+    # arXiv:2302.04542; EvaByte), under the published key names.
+    # ``attention_class`` "eva" on a per-head K/V model of full layers:
+    # positions fall into blocked windows of ``window_size`` tokens and
+    # chunks of ``chunk_size``; a query attends, in ONE softmax, the exact
+    # keys of its own window up to itself and one SUMMARY row for every chunk
+    # of every window before it (``ops/eva_summarise``: the chunk's keys
+    # pooled by a softmax over ``dk^-0.5 k . phi``, plus ``mu``; its values
+    # by the same weights), ``eva_phi`` / ``eva_mu`` float32 [kv heads, dk] a
+    # layer. A key is counted once: exactly inside its query's window,
+    # through its chunk outside it. What a sequence caches is its open
+    # window's rows and the summaries behind them (``paged_decode.cache_spec``:
+    # kind ``eva``, rows that are NOT positions). ``num_pred_heads`` > 1: the
+    # output head predicts that many bytes ahead; head 0 is the model's own
+    # (``lm_head``), the others are draft heads (``pred_heads``) that the
+    # plain forward returns and serving does not read. ``fp32_skip_add``: the
+    # residual is carried float32 from the table to the final norm.
+    # ``fp32_logits``: the head's product accumulates and stays float32.
+    attention_class: str = ""
+    window_size: int = 0
+    chunk_size: int = 0
+    num_pred_heads: int = 1
+    fp32_skip_add: bool = False
+    fp32_logits: bool = False
 
     def __post_init__(self):
         from arkflow_tpu.errors import ConfigError
@@ -397,6 +421,7 @@ class DecoderConfig:
                                tuple(sorted(self.rope_scaling.items())))
         self._check_streams()
         self._check_hybrid()
+        self._check_eva()
         if self.latent:
             if min(self.qk_nope_head_dim, self.qk_rope_head_dim,
                    self.v_head_dim) <= 0 or self.qk_rope_head_dim % 2:
@@ -457,6 +482,49 @@ class DecoderConfig:
                     "experts_held is (first, count) within n_routed_experts "
                     f"of a routed model, got {self.experts_held}")
         self._check_layer_pattern()
+
+    def _check_eva(self) -> None:
+        """``attention_class`` and the keys that belong to "eva"."""
+        from arkflow_tpu.errors import ConfigError
+
+        if self.attention_class not in ("", "eva"):
+            raise ConfigError(
+                f"attention_class {self.attention_class!r} is not served: "
+                "only 'eva' (a blocked exact window beside chunk summaries)")
+        if not self.eva:
+            if (self.window_size or self.chunk_size or self.num_pred_heads != 1
+                    or self.fp32_skip_add or self.fp32_logits):
+                raise ConfigError(
+                    "window_size, chunk_size, num_pred_heads, fp32_skip_add "
+                    "and fp32_logits belong to attention_class 'eva'")
+            return
+        if not (self.chunk_size > 0 and self.window_size > 0
+                and self.window_size % self.chunk_size == 0
+                and self.num_pred_heads >= 1):
+            raise ConfigError(
+                "attention_class 'eva' needs chunk_size > 0, a window_size "
+                "that is a multiple of it and num_pred_heads >= 1; got "
+                f"{self.window_size}, {self.chunk_size}, {self.num_pred_heads}")
+        if (self.latent or self.routed or self.num_experts > 1 or self.hybrid
+                or self.layer_types is not None or self.qk_norm
+                or self.hc_mult > 1 or self.use_ring_attention
+                or self.v_head_dim or self.partial_rotary_factor != 1.0
+                or self.add_full_attention_sink_bias
+                or self.attention_gate_type):
+            raise ConfigError(
+                "attention_class 'eva' is served on a per-head K/V model of "
+                "full layers with a dense SwiGLU: not beside latent attention "
+                "(kv_lora_rank), routed experts (n_routed_experts / "
+                "num_experts), the hybrid block (mamba_d_ssm), a layer "
+                "pattern (layer_types: another window or state kind), "
+                "qk_norm, hc_mult, ring attention, v_head_dim, "
+                "partial_rotary_factor, a sink or an output gate, yet")
+
+    @property
+    def eva(self) -> bool:
+        """True where attention is EVA's: a blocked exact window beside
+        chunk summaries, over a cache that compacts as windows close."""
+        return self.attention_class == "eva"
 
     def _check_streams(self) -> None:
         """``hc_mult`` and ``rope_scaling``: what they are served beside."""
@@ -765,7 +833,7 @@ class DecoderConfig:
         per-head norms); otherwise ``layers`` is the one stack of identical
         layers, as it always was."""
         return (self.latent or self.routed or self.layered or self.qk_norm
-                or self.hetero or self.conv or self.linear)
+                or self.hetero or self.conv or self.linear or self.eva)
 
     def _check_gqa_kinds(self) -> None:
         """The per-kind keys of a per-head K/V model (``gqa``)."""
@@ -1078,6 +1146,15 @@ def layer_runs(cfg: DecoderConfig) -> list:
     return [tuple(r) for r in runs]
 
 
+#: ``eva_phi`` and ``eva_mu`` are SEEDED normal(0, this). The family's own
+#: init (0.01275) makes a chunk's pooling logits ~0.01 wide: every summary is
+#: then its chunk's plain mean and ``mu`` moves no score, so a wrong ``phi``
+#: or a missing ``mu`` could not be told from a right one. At 0.5 the logits
+#: (and ``mu``'s part of a score) spread ~0.6, as wide as a seeded model's
+#: attention scores
+_EVA_INIT_STD = 0.5
+
+
 def _init_gqa_layer(key, cfg: DecoderConfig, routed: bool,
                     kind: str = FULL) -> dict:
     """One layer of a per-head K/V model that stacks by runs (routed experts
@@ -1112,6 +1189,10 @@ def _init_gqa_layer(key, cfg: DecoderConfig, routed: bool,
         # ([q | gate] a head): a leaf of its own, a permutation of its columns
         layer["w_out_gate"] = cm.dense_init(
             jax.random.fold_in(key, 300), cfg.dim, cfg.heads * sp.dv, bias=False)
+    if cfg.eva:  # the summariser's pooling direction and key offset, a head
+        for i, name in enumerate(("eva_phi", "eva_mu")):
+            layer[name] = _EVA_INIT_STD * jax.random.normal(
+                jax.random.fold_in(key, 600 + i), (sp.kv_heads, sp.dk), jnp.float32)
     layer.update(_init_ffn(k, cfg, routed))
     return layer
 
@@ -1212,6 +1293,10 @@ def _init_runs(rng, cfg: DecoderConfig) -> dict:
             for _ in range(first, stop))
     for name, stack in stacks.items():
         params[name] = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *stack)
+    if cfg.num_pred_heads > 1:  # the draft heads, head 1 first: [dim, 7 vocab]
+        params["pred_heads"] = cm.dense_init(
+            jax.random.fold_in(rng, 700), cfg.dim,
+            (cfg.num_pred_heads - 1) * cfg.vocab_size, bias=False)
     if cfg.norm_unit_offset:
         params = _seed_offset_norms(params, jax.random.fold_in(rng, 500))
     return params
@@ -1914,6 +1999,8 @@ def _attention_block(lp: dict, x: jnp.ndarray, cfg: DecoderConfig, positions,
     if sp.window:
         causal = causal & (positions[:, None, None, :]
                            > positions[:, None, :, None] - sp.window)
+    if cfg.eva:
+        k, v, causal = eva_keys(lp, k, v, cfg, positions)
     k = jnp.repeat(k, group, axis=2)
     v = jnp.repeat(v, group, axis=2)
     if ring_attn is not None:
@@ -1926,6 +2013,33 @@ def _attention_block(lp: dict, x: jnp.ndarray, cfg: DecoderConfig, positions,
     if cfg.hybrid:  # the parallel mixer: one norm feeds both, one residual
         out = out + _mixer_block(lp, y, cfg)
     return x + out
+
+
+def eva_keys(lp: dict, k, v, cfg: DecoderConfig, positions):
+    """What a block of EVA queries at ``positions`` [B, S] = 0..S-1 attends,
+    from the block's own rotated keys ``k`` and values ``v`` [B, S, kv heads,
+    width]: (keys, values, mask) with one SUMMARY row for every chunk of
+    every closed window first (``ops/eva_summarise``), then the exact rows.
+    Query t sees the summaries of the windows before its own and the exact
+    rows of its own window up to itself: a key is counted once."""
+    from arkflow_tpu.ops.eva_summarise import eva_summarise_plain
+
+    w, c = cfg.window_size, cfg.chunk_size
+    s = positions.shape[1]
+    closed = (s // w) * w            # tokens of whole windows: what can close
+    qw = positions[:, None, :, None] // w                          # [B,1,S,1]
+    kpos = positions[:, None, None, :]
+    exact = (kpos // w == qw) & (kpos <= positions[:, None, :, None])
+    if not closed:
+        return k, v, exact
+    ks, vs = eva_summarise_plain(k[:, :closed], v[:, :closed], lp["eva_phi"],
+                                 lp["eva_mu"], c)
+    chunk_window = (jnp.arange(closed // c) * c // w)[None, None, None, :]
+    mask = jnp.concatenate(
+        [jnp.broadcast_to(chunk_window < qw, exact.shape[:3] + (closed // c,)),
+         exact], axis=-1)
+    return (jnp.concatenate([ks, k], axis=1), jnp.concatenate([vs, v], axis=1),
+            mask)
 
 
 def qkv_project(lp: dict, y: jnp.ndarray, cfg: DecoderConfig, kind: str = FULL):
@@ -1983,8 +2097,29 @@ def lm_logits(params: dict, x: jnp.ndarray, cfg: DecoderConfig) -> jnp.ndarray:
     """The final norm and the output head: [..., dim] -> float32 [..., vocab]
     (times ``lm_head_multiplier`` where the model states one)."""
     x = _norm(params["norm_out"], x, cfg)
+    if cfg.fp32_logits:  # the product accumulates AND stays float32
+        return _head_product(params["lm_head"], x)
     logits = cm.dense(params["lm_head"], x).astype(jnp.float32)
     return logits if cfg.lm_head_multiplier == 1.0 else logits * cfg.lm_head_multiplier
+
+
+def _head_product(head: dict, x: jnp.ndarray) -> jnp.ndarray:
+    """``fp32_logits``: operands in the dtype the head is held in (bfloat16
+    as served, on the MXU), the result accumulated and kept float32."""
+    return jnp.dot(x.astype(head["w"].dtype), head["w"],
+                   preferred_element_type=jnp.float32)
+
+
+def pred_logits(params: dict, x: jnp.ndarray, cfg: DecoderConfig) -> jnp.ndarray:
+    """Every prediction head's logits, [..., dim] -> float32 [...,
+    num_pred_heads, vocab]: head ``m`` scores the token ``m + 1`` ahead; head
+    0 is ``lm_logits``, the others the draft heads (``pred_heads``)."""
+    first = lm_logits(params, x, cfg)[..., None, :]
+    if cfg.num_pred_heads == 1:
+        return first
+    rest = _head_product(params["pred_heads"], _norm(params["norm_out"], x, cfg))
+    return jnp.concatenate([first, rest.reshape(
+        *rest.shape[:-1], cfg.num_pred_heads - 1, cfg.vocab_size)], axis=-2)
 
 
 def _shard_act(x, axes):
@@ -1999,8 +2134,9 @@ def _shard_act(x, axes):
 
 
 def forward(params: dict, cfg: DecoderConfig, input_ids, *, axes=None, mesh=None,
-            return_aux: bool = False):
-    """[B, S] ids -> [B, S, vocab] float32 logits (causal).
+            return_aux: bool = False, pred_heads: bool = False):
+    """[B, S] ids -> [B, S, vocab] float32 logits (causal); ``pred_heads``:
+    every prediction head's, [B, S, num_pred_heads, vocab] (``pred_logits``).
 
     With ``cfg.use_ring_attention`` and a mesh carrying an ``sp`` axis, the
     attention core runs as an explicit K/V ring over sequence shards
@@ -2012,6 +2148,8 @@ def forward(params: dict, cfg: DecoderConfig, input_ids, *, axes=None, mesh=None
     if cfg.latent:
         return _forward_latent(params, cfg, input_ids, axes, return_aux)
     x = _scaled(cm.embedding(params["embed"], input_ids), cfg.embedding_multiplier)
+    if cfg.fp32_skip_add:  # every sub-layer's output adds into float32
+        x = x.astype(jnp.float32)
     x = _shard_act(x, axes)
     positions = jnp.broadcast_to(jnp.arange(s)[None, :], (b, s))
     causal = jnp.tril(jnp.ones((s, s), bool))[None, None, :, :]
@@ -2058,7 +2196,7 @@ def forward(params: dict, cfg: DecoderConfig, input_ids, *, axes=None, mesh=None
         aux.append(run_aux)
     lb_per_layer, z_per_layer = (jnp.concatenate(a) if len(aux) > 1 else a[0]
                                  for a in zip(*aux))
-    logits = lm_logits(params, x, cfg)
+    logits = (pred_logits if pred_heads else lm_logits)(params, x, cfg)
     if return_aux:
         return logits, {"load_balance": lb_per_layer.mean(), "router_z": z_per_layer.mean()}
     return logits
@@ -2278,6 +2416,8 @@ def _attn_dtypes(cfg: DecoderConfig, kind: str) -> dict:
             layer["attn_sink"] = f32
         if cfg.out_gate:
             layer["w_out_gate"] = {"w": bf16}
+        if cfg.eva:  # they shape a softmax's weights and a key: float32
+            layer.update(eva_phi=f32, eva_mu=f32)
     if sp.q_lora_rank:
         layer.update(wq_a={"w": bf16}, q_norm={"scale": f32})
     if sp.gate:
@@ -2301,6 +2441,8 @@ def _serve_dtypes_runs(cfg: DecoderConfig) -> dict:
         "norm_out": {"scale": f32},
         "lm_head": {"w": bf16},
     }
+    if cfg.num_pred_heads > 1:
+        out["pred_heads"] = {"w": bf16}
     for name, _, _, kind, routed, _ in layer_runs(cfg):
         layer = {"attn_norm": {"scale": f32}, "mlp_norm": {"scale": f32}}
         if kind == CONV:
@@ -2332,6 +2474,13 @@ def _no_latent(cfg: DecoderConfig, what: str) -> None:
     """The paths that know only per-head K/V kept for a request's life
     refuse a latent model, a model that carries a recurrent state beside
     them, and a per-head model with routed experts or a layer pattern."""
+    if cfg.eva:
+        from arkflow_tpu.errors import ConfigError
+
+        raise ConfigError(
+            f"{what} keeps a row a position: attention_class 'eva' (a window "
+            "that is compacted into chunk summaries when it closes) generates "
+            "through serving: continuous (the paged pool, by cached length)")
     if cfg.by_runs and not cfg.latent:
         from arkflow_tpu.errors import ConfigError
 

@@ -96,6 +96,7 @@ class CachePool:
     key_parts: int = 1
     row_major: bool = False
     split_heads: bool = False
+    compact: tuple = ()
 
     def shapes(self, pages: int, page_size: int) -> list:
         """The shapes of a per-head pool's K and V over ``pages`` pages."""
@@ -164,7 +165,13 @@ def cache_spec(cfg: DecoderConfig) -> tuple:
       dim] matrix a value head — and its conv's last
       ``linear_conv_kernel_dim - 1`` projected inputs (bfloat16), one row a
       SEQUENCE, over the linear_attention layers only (``kv`` over the
-      attention layers only, as beside ``conv``)."""
+      attention layers only, as beside ``conv``);
+    - ``eva``: per-head K and V of a compacting window cache
+      (``attention_class`` "eva"), every layer: rows that are NOT positions.
+      A slot's rows are the summary rows of its closed windows — ``window /
+      chunk`` a window, whole pages —, then the exact rows of the window it
+      is writing; position ``p`` is written at row ``eva_rows(p)`` and a
+      window's rows are pooled in place when its last one is written."""
     if not cfg.latent:
         full, swa = (cfg.kinds.count(k) for k in (FULL, SLIDING))
 
@@ -173,6 +180,9 @@ def cache_spec(cfg: DecoderConfig) -> tuple:
                         heads=sp.kv_heads, key_parts=sp.key_parts,
                         row_major=sp.row_major, split_heads=sp.split_heads)
 
+        if cfg.eva:
+            return (CachePool("eva", full, **widths(cfg.gqa(FULL)),
+                              compact=(cfg.window_size, cfg.chunk_size)),)
         pools = (CachePool("kv", full, **widths(cfg.gqa(FULL))),)
         if swa:
             pools += (CachePool("kv_window", swa, window=cfg.sliding_window,
@@ -305,6 +315,77 @@ def window_ring_pages(cfg: DecoderConfig, page_size: int, step_tokens: int) -> i
     if SLIDING not in cfg.kinds:
         return 0
     return (cfg.sliding_window + max(step_tokens, 1) - 2) // page_size + 2
+
+
+def eva_rows(cfg: DecoderConfig, positions):
+    """The cache row position ``p`` of a compacting window cache is written
+    at (ints, numpy or jax arrays alike): the summary rows of the windows
+    closed before it, then its place in its own window. Also the rows a slot
+    holds BEFORE that write, its cached length: a function of the position,
+    so the host mirrors it without a fetch."""
+    w = cfg.window_size
+    return positions // w * (w // cfg.chunk_size) + positions % w
+
+
+def eva_table_pages(cfg: DecoderConfig, page_size: int, max_seq: int) -> int:
+    """Pages a slot of a compacting window cache holds at most over
+    ``max_seq`` positions (its table's columns): the summary pages of every
+    window it can close and one whole window."""
+    w = cfg.window_size
+    summary = w // cfg.chunk_size // page_size
+    return max(max_seq - 1, 0) // w * summary + -(-min(w, max_seq) // page_size)
+
+
+class _EvaClose(NamedTuple):
+    """Which rows of a step close a window (``closing`` [B]: the step writes
+    the window's last row) and the window's first column of the row's page
+    table (``first`` [B])."""
+
+    closing: object
+    first: object
+
+
+def _eva_close(lp: dict, kp, vp, layer, table, eva: _EvaClose,
+               cfg: DecoderConfig, kernel: bool, interpret: bool):
+    """The window close of one layer, on the device: where any row of the
+    step wrote its window's last row, pool that window's rows to ``window /
+    chunk`` summary rows (``ops/eva_summarise``) and write them over the
+    window's FIRST pages, in place; the host hands the window's other pages
+    back and starts the next window behind the summaries. A closing row at a
+    time: rows that do not close cost nothing."""
+    from arkflow_tpu.ops.eva_summarise import eva_summarise, eva_summarise_plain
+
+    sp = cfg.gqa(FULL)
+    page = kp.shape[2]
+    w, c = cfg.window_size, cfg.chunk_size
+    # the closing rows first: a loop over as many as there are (none, in
+    # nearly every step) carries the pools in place, as the layer scan does
+    # — a ``cond`` around the close copied both pools in the chunk's program
+    order = jnp.argsort(jnp.logical_not(eva.closing))
+
+    def close(carry):
+        i, kp, vp = carry
+        row = order[i]
+        cols = jnp.minimum(eva.first[row] + jnp.arange(w // page),
+                           table.shape[1] - 1)
+        win = table[row][cols][None]                         # [1, w / page]
+        k = _read_keys(kp, layer, win, sp.dk, sp.kv_heads)   # [1, w, kv, dk]
+        v = _read_rows(vp, layer, win, sp.kv_heads)
+        if kernel:
+            ks, vs = eva_summarise(k, v, lp["eva_phi"], lp["eva_mu"], chunk=c,
+                                   interpret=interpret)
+        else:
+            ks, vs = eva_summarise_plain(k, v, lp["eva_phi"], lp["eva_mu"], c)
+        dest = win[:, :w // c // page]
+        kp, vp = (pool.at[layer, dest].set(rows.reshape(
+            1, dest.shape[1], page, *pool.shape[3:]).astype(pool.dtype))
+            for pool, rows in ((kp, ks), (vp, vs)))
+        return i + 1, kp, vp
+
+    with jax.named_scope("eva_summarise"):
+        closing = eva.closing.sum().astype(jnp.int32)
+        return jax.lax.while_loop(lambda carry: carry[0] < closing, close,
+                                  (jnp.int32(0), kp, vp))[1:]
 
 
 def _page_size(pools) -> int:
@@ -796,7 +877,8 @@ def gqa_kernel_probe(params: dict, cfg: DecoderConfig, page_size: int,
     their window, one at the ring's wrap, one short) against
     ``_attend_ring``; the expert product on GIVEN routing; and with
     linear_attention layers the delta rule's two kernels against their plain
-    forms (``_gdn_probe``)."""
+    forms (``_gdn_probe``); with a compacting window cache the chunk
+    summariser against its plain form."""
     keys = iter(jax.random.split(jax.random.PRNGKey(1234), 32))
     rand = lambda shape: jax.random.normal(  # noqa: E731
         next(keys), shape, jnp.float32).astype(jnp.bfloat16)
@@ -848,6 +930,19 @@ def gqa_kernel_probe(params: dict, cfg: DecoderConfig, page_size: int,
         out.append(_expert_probe(params, cfg, keys, rand, kernel_interpret))
     if cfg.linear:
         out.extend(_gdn_probe(cfg, kernel_interpret))
+    if cfg.eva:  # the summariser over two windows' worth of seeded rows
+        from arkflow_tpu.ops.eva_summarise import (eva_summarise,
+                                                   eva_summarise_plain)
+
+        stack = params[layer_runs(cfg)[0][0]]
+        phi, mu = stack["eva_phi"][0], stack["eva_mu"][0]
+        k, v = (rand((2, cfg.window_size, sp.kv_heads, d))
+                for d in (sp.dk, sp.dv))
+        ref = eva_summarise_plain(k, v, phi, mu, cfg.chunk_size)
+        got = eva_summarise(k, v, phi, mu, chunk=cfg.chunk_size,
+                            interpret=kernel_interpret)
+        out.extend((f"eva_summarise_{name}", r.reshape(2, -1), g.reshape(2, -1))
+                   for name, r, g in zip(("keys", "values"), ref, got))
     return out
 
 
@@ -1148,7 +1243,8 @@ def _dense_layers(params: dict, cfg: DecoderConfig, x, k_pages, v_pages,
                   positions, page_idx, offset, token_mask, *, page_table,
                   off, mask, block: bool, kv_sharding, attention_kernel: str,
                   kernel_interpret: bool, ssm_rows=None, ssm_fresh=None,
-                  chunk: Optional[_RidingChunk] = None):
+                  chunk: Optional[_RidingChunk] = None,
+                  eva: Optional[_EvaClose] = None):
     """The layer loop of a per-head K/V (GQA) model over the paged cache,
     with ``_latent_layers``' operands: one scan per run of layers of one
     shape and kind (``layer_runs``) — ONE, over ``layers``, for a model
@@ -1191,6 +1287,13 @@ def _dense_layers(params: dict, cfg: DecoderConfig, x, k_pages, v_pages,
     the lanes as [lanes, 1] queries under ``page_table`` / ``off`` /
     ``mask``, the chunk as [1, C] under its own (``_RidingChunk``), both
     after every token's K/V is written.
+
+    ``eva`` (a compacting window cache): ``page_idx`` / ``offset`` / ``off``
+    / ``mask`` are in CACHE ROWS (``eva_rows``), ``positions`` stay the
+    tokens' own (the rotary embedding's); the one softmax over summary rows
+    and window rows is the causal bound over the row's table as it stands,
+    and behind it each layer closes the windows that ``eva`` names
+    (``_eva_close``).
     Returns (x, k_pages, v_pages) and, from a routed model, the step's
     counters (``moe_step_stats``)."""
     b, t = positions.shape
@@ -1282,7 +1385,12 @@ def _dense_layers(params: dict, cfg: DecoderConfig, x, k_pages, v_pages,
                                     jnp.repeat(values, group, axis=2), mask,
                                     sink=sink)
 
-            if chunk is None:
+            if eva is not None:
+                with jax.named_scope("eva_attention"):
+                    attn = attend(q, kept, off, mask)
+                kp, vp = _eva_close(lp, kp, vp, li, kept, eva, cfg, kernel,
+                                    kernel_interpret)
+            elif chunk is None:
                 attn = attend(q, kept, off, mask)
             else:  # the lanes a row each, then the chunk's row
                 lanes = chunk.lanes
@@ -1344,6 +1452,19 @@ def _scan_run(layer, carry, stack: dict, first: int, stop: int,
     return jax.lax.scan(part, carry, (of_kind, *in_stack, jnp.arange(first, stop)))
 
 
+def _eva_step(cfg: DecoderConfig, page: int, first, count) -> dict:
+    """``_dense_layers``' operand of a compacting window cache (none for any
+    other model, and nothing traced for it): which rows of a step that
+    writes ``count`` [B] positions (a decode lane: whether it is active) from
+    ``first`` [B] on close their window, and that window's first column."""
+    if not cfg.eva:
+        return {}
+    w = cfg.window_size
+    last = first + count - 1
+    return dict(eva=_EvaClose((count > 0) & ((last + 1) % w == 0),
+                              last // w * (w // cfg.chunk_size // page)))
+
+
 def _ssm_operands(cfg: DecoderConfig, rows, fresh) -> dict:
     """``_dense_layers``' state operands: none for a model that caches no
     state a sequence (``cfg.stateful``: a mixer, conv layers, linear
@@ -1374,6 +1495,14 @@ def paged_prefill(params: dict, cfg: DecoderConfig, input_ids, lengths,
     paths read later; ``attention_kernel`` picks only its expert product.
     A routed model's step returns its routing counters as a fourth value.
     """
+    if cfg.eva:
+        from arkflow_tpu.errors import ConfigError
+
+        raise ConfigError(
+            "a compacting window cache (attention_class 'eva': pool eva) "
+            "prefills in chunks through the cache (prefill_chunk > 0, a "
+            "divisor of window_size): the one-shot prefill attends over its "
+            "own block and closes no window")
     if cfg.stateful:
         from arkflow_tpu.errors import ConfigError
 
@@ -1483,21 +1612,27 @@ def paged_prefill_chunk(params: dict, cfg: DecoderConfig, input_ids, chunk_off,
 
     positions = chunk_off[:, None] + jnp.arange(t)[None, :]       # [B, C]
     pos_valid = jnp.arange(t)[None, :] < chunk_len[:, None]       # [B, C]
-    logical_page = positions // page
+    # where a token's row sits in its slot's cache: its position, or — a
+    # compacting window cache — behind the summaries of the windows closed
+    # before it (the rotary embedding keeps ``positions``)
+    rows = eva_rows(cfg, positions) if cfg.eva else positions
+    logical_page = rows // page
     page_idx = jnp.where(
         pos_valid,
         jnp.take_along_axis(page_table, jnp.minimum(logical_page, p_slots - 1), axis=1),
         0,
     )
-    offset = jnp.where(pos_valid, positions % page, 0)
+    offset = jnp.where(pos_valid, rows % page, 0)
     key_pos = jnp.arange(ctx)[None, None, None, :]                # [1,1,1,ctx]
     # query i attends keys 0..off+i. Padded queries keep this causal mask
     # rather than an all-False row: a fully-masked softmax is NaN, and a
     # NaN activation would leak through the MoE dispatch einsum (0 * NaN)
     # into real tokens' expert inputs. Their finite garbage output is
     # excluded from routing by token_mask and never read out.
-    mask = key_pos <= positions[:, None, :, None]                 # [B,1,C,ctx]
+    mask = key_pos <= rows[:, None, :, None]                      # [B,1,C,ctx]
     x = _scaled(cm.embedding(params["embed"], input_ids), cfg.embedding_multiplier)
+    if cfg.fp32_skip_add:  # the residual is carried float32 between sub-layers
+        x = x.astype(jnp.float32)
 
     moe = ()  # a routed model appends its counters (``moe_step_stats``)
     if cfg.latent:
@@ -1511,11 +1646,13 @@ def paged_prefill_chunk(params: dict, cfg: DecoderConfig, input_ids, chunk_off,
     else:
         x, new_k, new_v, *moe = _dense_layers(
             params, cfg, x, k_pages, v_pages, positions, page_idx, offset,
-            pos_valid, page_table=tables, off=chunk_off, mask=mask,
+            pos_valid, page_table=tables,
+            off=eva_rows(cfg, chunk_off) if cfg.eva else chunk_off, mask=mask,
             block=False, kv_sharding=kv_sharding,
             attention_kernel=attention_kernel,
             kernel_interpret=kernel_interpret, **_ssm_operands(
-                cfg, ssm_rows, chunk_off == 0))
+                cfg, ssm_rows, chunk_off == 0), **_eva_step(
+                    cfg, page, chunk_off, chunk_len))
     logits = lm_logits(params, x, cfg)
     if not return_all:
         last = jnp.clip(chunk_len - 1, 0, t - 1)
@@ -1553,17 +1690,22 @@ def paged_decode_step(params: dict, cfg: DecoderConfig, token_ids, lengths,
     positions = lengths[:, None]                                  # [S, 1]
     x = _scaled(cm.embedding(params["embed"], token_ids[:, None]),
                 cfg.embedding_multiplier)                         # [S, 1, D]
+    if cfg.fp32_skip_add:
+        x = x.astype(jnp.float32)
 
-    write_logical = lengths // page
+    # the cached length: the tokens written, or — a compacting window
+    # cache — the rows they are held in (``eva_rows``)
+    rows = eva_rows(cfg, lengths) if cfg.eva else lengths
+    write_logical = rows // page
     write_page = jnp.where(
         active,
         jnp.take_along_axis(page_table, write_logical[:, None], axis=1)[:, 0],
         0,
     )                                                             # [S]
-    write_off = jnp.where(active, lengths % page, 0)              # [S]
+    write_off = jnp.where(active, rows % page, 0)                 # [S]
     # keys valid after the write: positions 0..lengths (inclusive)
     key_pos = jnp.arange(ctx)[None, :]                            # [1, ctx]
-    valid = (key_pos <= lengths[:, None])[:, None, None, :]       # [S,1,1,ctx]
+    valid = (key_pos <= rows[:, None])[:, None, None, :]          # [S,1,1,ctx]
 
     moe = ()  # a routed model appends its counters (``moe_step_stats``)
     if cfg.latent:
@@ -1581,12 +1723,13 @@ def paged_decode_step(params: dict, cfg: DecoderConfig, token_ids, lengths,
         x, new_k, new_v, *moe = _dense_layers(
             params, cfg, x, k_pages, v_pages, positions, write_page[:, None],
             write_off[:, None], active[:, None], page_table=tables,
-            off=lengths, mask=valid, block=False, kv_sharding=kv_sharding,
+            off=rows, mask=valid, block=False, kv_sharding=kv_sharding,
             attention_kernel=attention_kernel,
             kernel_interpret=kernel_interpret,
             # lane s holds slot s: its state is row s + 1; an inactive lane
             # reads and writes the scratch row
-            **_ssm_operands(cfg, jnp.where(active, jnp.arange(s) + 1, 0), None))
+            **_ssm_operands(cfg, jnp.where(active, jnp.arange(s) + 1, 0), None),
+            **_eva_step(cfg, page, lengths, active))
     logits = lm_logits(params, x, cfg)[:, -1, :]
     if not return_logits:
         logits = jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -1600,9 +1743,10 @@ def fusable(cfg: DecoderConfig) -> bool:
     and ``paged_prefill_chunk``'s own — no latent rows, no routed experts (a
     prompt's router counters) nor the Switch layer (a step's tokens share
     expert capacity), no state a sequence (a row of state slots), no layer
-    pattern (ring coordinates)."""
+    pattern (ring coordinates), no compacting window cache (which rows
+    close)."""
     return not (cfg.latent or cfg.routed or cfg.num_experts > 1
-                or cfg.stateful or cfg.layered)
+                or cfg.stateful or cfg.layered or cfg.eva)
 
 
 def paged_fused_step(params: dict, cfg: DecoderConfig, token_ids, lengths,
@@ -1632,7 +1776,8 @@ def paged_fused_step(params: dict, cfg: DecoderConfig, token_ids, lengths,
 
         raise ConfigError(
             "a chunk rides a decode step only on a per-head K/V model without "
-            "routed experts, a state a sequence or a layer pattern (pools "
+            "routed experts, a state a sequence, a layer pattern or a "
+            "compacting window cache (attention_class 'eva') (pools "
             f"{', '.join(pool.name for pool in cache_spec(cfg))})")
     s, c = token_ids.shape[0], input_ids.shape[1]
     page = _page_size(k_pages)

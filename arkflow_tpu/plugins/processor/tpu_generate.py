@@ -200,6 +200,14 @@ class TpuGenerateProcessor(Processor):
                     f"layers (pools {pools}) is served on one chip: the state pool and "
                     "the mixer's channels have no sharding over a mesh yet "
                     "(remove mesh)")
+        if getattr(self.cfg, "eva", False) and serving != "continuous":
+            # (a mesh: refused with every model that stacks by runs, below,
+            # and by name where the server is built: ``serving._serve_eva``)
+            raise ConfigError(
+                "attention_class 'eva' (a window that is compacted into "
+                "chunk summaries when it closes) generates through "
+                "serving: continuous only: the batch path's contiguous "
+                "cache keeps a row a position")
         if getattr(self.cfg, "by_runs", False) and not self.cfg.latent:
             # a per-head K/V model with routed experts, a layer pattern
             # (layer_types / sliding_window: window pages beside kept pages)
@@ -318,7 +326,8 @@ class TpuGenerateProcessor(Processor):
             if not (getattr(self.cfg, "latent", False)
                     or getattr(self.cfg, "stateful", False)
                     or getattr(self.cfg, "layered", False)
-                    or getattr(self.cfg, "hetero", False)):
+                    or getattr(self.cfg, "hetero", False)
+                    or getattr(self.cfg, "eva", False)):
                 self.disagg = self
 
         reg = global_registry()

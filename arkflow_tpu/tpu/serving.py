@@ -241,6 +241,12 @@ class GenerationServer:
         self.max_seq = max_seq
         self.eos_id = eos_id
         self.pages_per_slot = -(-max_seq // page_size)
+        #: a compacting window cache (``cache_spec``: pool ``eva``): a slot's
+        #: pages go by its cached length, not by its position (``_serve_eva``)
+        self._eva = bool(cfg.eva)
+        if self._eva:
+            _serve_eva(self, name, num_pages, prefill_chunk, speculative_tokens,
+                       prefix_cache_pages, mesh)
         # page 0 is scratch; default pool fits every slot at max_seq
         self.num_pages = num_pages or (1 + self.slots * self.pages_per_slot)
         if self.num_pages < 1 + self.pages_per_slot:
@@ -1444,6 +1450,13 @@ class GenerationServer:
                 "without it cannot be decoded from — a model with the "
                 "hybrid block, conv or linear_attention layers prefills and "
                 "decodes on the same server")
+        if self._eva:
+            raise ConfigError(
+                f"{what} ships a prompt's pages by position; a compacting "
+                f"window cache (pool {self._pool_names()}) holds summary "
+                "pages and an open window, which have no wire form yet — a "
+                "model with attention_class 'eva' prefills and decodes on "
+                "the same server")
         if self.cfg.latent:
             raise ConfigError(
                 f"{what} ships per-head K/V page slabs split along the "
@@ -1663,7 +1676,9 @@ class GenerationServer:
         # pages only (the finished request still donates to the cache)
         key = None if req.adopt is not None else self._lookup_prefix(req.prompt)
         shared = list(self._prefix_cache[key]) if key is not None else []
-        fresh_needed = self._pages_needed(n + 1) - len(shared)
+        # a compacting window cache takes its pages step by step (its pool
+        # holds every slot's worst case: ``_serve_eva``)
+        fresh_needed = 0 if self._eva else self._pages_needed(n + 1) - len(shared)
         if len(self._free_pages) + self._evictable_pages(key) < fresh_needed:
             return None
         if key is not None:
@@ -1767,7 +1782,7 @@ class GenerationServer:
         if shared_len > 0:
             self.m_prefix_hits.inc()
             self.m_prefix_pages.inc(shared_len // self.page_size)
-        if (shared_len > 0 or self._layered or self._stateful
+        if (shared_len > 0 or self._layered or self._stateful or self._eva
                 or (self.prefill_chunk and n > self.prefill_chunk)):
             # cooperative admission: the serve loop interleaves prefill
             # steps with decode; the slot joins decode once fully prefilled.
@@ -1931,8 +1946,12 @@ class GenerationServer:
         ids = np.zeros(c, np.int32)
         ids[:len(chunk)] = chunk
         self._slide_window(slot, off, new_off - 1)
+        if self._eva:
+            self._ensure_page_capacity(slot, new_off)
         table = self._table(slot)
         packed = pack_operands(ids, off, len(chunk), table)
+        if self._eva:  # behind the table the step carries: a close's frees
+            _eva_account(self, "chunk", slot, off, new_off - 1)
         if kind == "chunk":
             self._note_walk("chunk", np.asarray([off + c - 1]), len(chunk), c,
                             table=table)
@@ -2048,6 +2067,8 @@ class GenerationServer:
         (default: the next write position, lengths+1)."""
         if total is None:
             total = int(self._lengths[slot]) + 1
+        if self._eva:  # the rows that hold positions < total
+            total = int(eva_rows(self.cfg, total - 1)) + 1
         need = self._pages_needed(total)
         while len(self._slot_pages[slot]) < need:
             p = self._alloc_page(self._slot_pages[slot])
@@ -2084,6 +2105,8 @@ class GenerationServer:
         total = self.num_pages - 1
         if total:
             self.m_pool_occupancy.set((total - len(self._free_pages)) / total)
+        if self._eva:
+            _eva_gauges(self)
         for gauge, holder, unit_bytes in self.m_kv_live:
             held = {"window": self.num_win_pages - 1 - len(self._win_free),
                     "pages": total - len(self._free_pages),
@@ -2350,6 +2373,9 @@ class GenerationServer:
                 packed = pack_operands(cur, lens, act, table)
                 # a packed step is issued
                 self._note_walk("decode", lens, int(act.sum()), table=table)
+                if self._eva:
+                    for s in map(int, np.flatnonzero(act)):
+                        _eva_account(self, "decode", s, int(lens[s]), int(lens[s]))
         return act, packed, prev, prep.dur_s
 
     def _note_walk(self, kind: str, last, queries: int, width: int = 1, *,
@@ -2370,6 +2396,8 @@ class GenerationServer:
         if self.m_attn_walk:
             walked, columns, in_runs = self.m_attn_walk[kind]
             cols = self.pages_per_slot
+            if self._eva:  # the kernel's bound is a cache row
+                last = eva_rows(self.cfg, np.asarray(last))
             pages = np.minimum(last // self.page_size + 1, cols)
             walked.inc(int(pages.sum()))
             columns.inc(cols * len(last))
@@ -2626,3 +2654,117 @@ class _FreePages:
         self._free[p] = True
         self._left[(p - 1) // self.run] += 1
         self._count += 1
+
+
+# -- a compacting window cache (``attention_class`` "eva") --------------------------
+
+from arkflow_tpu.models.paged_decode import eva_rows, eva_table_pages  # noqa: E402
+
+
+def _serve_eva(server: GenerationServer, name: str, num_pages, prefill_chunk,
+               speculative_tokens, prefix_cache_pages, mesh) -> None:
+    """What a compacting window cache is served with: its table's columns
+    (``pages_per_slot``: by cached length, the summary pages of every window
+    a slot can close and one whole window), its counters, and a ConfigError
+    by name for what it is not served with yet."""
+    cfg, page = server.cfg, server.page_size
+    w, c = cfg.window_size, cfg.chunk_size
+    sp = cfg.gqa("full_attention")
+    if mesh is not None:
+        raise ConfigError(
+            "attention_class 'eva' is served on one chip: the window close "
+            "and the summary pages have no sharding over a mesh yet (remove "
+            "mesh)")
+    if prefill_chunk <= 0 or w % int(prefill_chunk):
+        raise ConfigError(
+            "attention_class 'eva' prefills in chunks through the cache: set "
+            f"prefill_chunk > 0 to a divisor of window_size {w} (a chunk "
+            f"never straddles a window's end), got {prefill_chunk}")
+    if w % page or (w // c) % page:
+        raise ConfigError(
+            f"attention_class 'eva': page_size {page} divides window_size "
+            f"{w} and a closed window's {w // c} summary rows (whole pages "
+            "turn into whole pages at a close)")
+    if speculative_tokens:
+        raise ConfigError(
+            "speculative_tokens does not compose with attention_class 'eva': "
+            "a verify step that crosses a window's end would pool rejected "
+            "drafts into the summaries, and there is no rollback yet")
+    if prefix_cache_pages:
+        raise ConfigError(
+            "prefix_cache_pages does not compose with attention_class 'eva': "
+            "a closed window's pages are pooled in place and handed back, so "
+            "a finished prompt has no pages by position to donate")
+    if sp.key_parts > 1 or sp.split_heads:
+        raise ConfigError(
+            "attention_class 'eva' pools a window's rows whole: a key held "
+            f"in parts or a head a pool layer (head_dim {sp.dk} on "
+            f"{sp.kv_heads} K/V heads) is not served with it")
+    server.pages_per_slot = eva_table_pages(cfg, page, server.max_seq)
+    if num_pages and num_pages < 1 + server.slots * server.pages_per_slot:
+        raise ConfigError(
+            f"num_pages={num_pages}: a compacting window cache takes its "
+            "pages step by step, so the pool holds every slot's worst case "
+            f"({server.slots} slots x {server.pages_per_slot} pages + scratch)")
+    reg = global_registry()
+    server.m_eva_closes = {
+        phase: reg.counter("arkflow_gen_eva_window_closes_total",
+                           "windows closed (a slot's: every layer pools the "
+                           "window's rows into summary rows, on the device), "
+                           "by the step that wrote the window's last row",
+                           {"model": name, "phase": phase})
+        for phase in ("chunk", "decode")}
+    server.m_eva_rows = {
+        (phase, kind): reg.counter(
+            "arkflow_gen_eva_rows_attended_total",
+            "cache rows the queries of issued steps attended, a layer: exact "
+            "rows of the open window, summary rows of closed windows",
+            {"model": name, "kind": kind, "phase": phase})
+        for phase in ("chunk", "decode") for kind in ("window", "summary")}
+    # a name a kind: a sampler that sums a gauge over its labels keeps them apart
+    server.m_eva_pages = {
+        kind: reg.gauge(f"arkflow_gen_eva_live_pages_{kind}",
+                        f"pages slots hold whose rows are {what}",
+                        {"model": name})
+        for kind, what in (("window", "the open window's exact rows"),
+                           ("summary", "closed windows' summary rows"))}
+
+
+def _eva_account(server: GenerationServer, phase: str, slot: int, first: int,
+                 last: int) -> None:
+    """The books of a step that writes positions ``first..last`` of ``slot``
+    (inside one window), behind the table it carries: the rows its queries
+    attend and, where it writes the window's last row, the close — every
+    page of the window but the first ``window / chunk / page``, which now
+    hold its summaries, goes back to the pool (the device reads them in
+    this step; whoever takes them next writes in a later one)."""
+    cfg = server.cfg
+    w, per = cfg.window_size, cfg.window_size // cfg.chunk_size
+    n = last - first + 1
+    server.m_eva_rows[phase, "summary"].inc(n * (first // w) * per)
+    server.m_eva_rows[phase, "window"].inc(n * (first % w + 1) + n * (n - 1) // 2)
+    if (last + 1) % w:
+        return
+    server.m_eva_closes[phase].inc()
+    pages = server._slot_pages[slot]
+    keep = (last // w + 1) * (per // server.page_size)
+    for p in pages[keep:]:
+        server._unref_page(p)
+    del pages[keep:]
+
+
+def _eva_gauges(server: GenerationServer) -> None:
+    """Pages held by slots, by kind: a slot's first columns are its summary
+    pages (from where its next write sits), the rest its open window's."""
+    cfg = server.cfg
+    per = cfg.window_size // cfg.chunk_size // server.page_size
+    summary = window = 0
+    for s, pages in enumerate(server._slot_pages):
+        if not pages:
+            continue
+        at = server._prefill_pos.get(s, int(server._lengths[s]))
+        held = min(at // cfg.window_size * per, len(pages))
+        summary += held
+        window += len(pages) - held
+    server.m_eva_pages["summary"].set(summary)
+    server.m_eva_pages["window"].set(window)

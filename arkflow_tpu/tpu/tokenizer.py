@@ -140,8 +140,52 @@ class HFTokenizer:
         return self._tok.decode(list(ids), skip_special_tokens=True)
 
 
+class ByteTokenizer:
+    """A byte-level vocabulary (``tokenizer: bytes``; EvaByte's layout): a
+    UTF-8 byte ``b`` is id ``b + 64``, the 64 ids below it are specials (0 pad,
+    1 bos, 2 eos; the rest unused here), 320 ids in all. Needs no file. A row
+    is [bos] + its bytes, cut to ``max_len``; decoding drops the specials."""
+
+    OFFSET = 64
+    vocab_size = 256 + OFFSET
+
+    def __init__(self):
+        self.pad_id, self.cls_id, self.sep_id = 0, 1, 2
+
+    def encode_batch(self, texts: Sequence[bytes], max_len: int) -> tuple[np.ndarray, np.ndarray]:
+        ids = np.zeros((len(texts), max_len), np.int32)
+        mask = np.zeros((len(texts), max_len), np.int32)
+        for i, t in enumerate(texts):
+            raw = np.frombuffer(t if isinstance(t, bytes) else t.encode(), np.uint8)
+            n = min(len(raw), max_len - 1)
+            ids[i, 0] = self.cls_id
+            ids[i, 1:n + 1] = raw[:n].astype(np.int32) + self.OFFSET
+            mask[i, :n + 1] = 1
+        return ids, mask
+
+    def encode_batch_view(self, values: np.ndarray, offsets: np.ndarray,
+                          max_len: int) -> tuple[np.ndarray, np.ndarray]:
+        buf = np.asarray(values, np.uint8)
+        return self.encode_batch(
+            [buf[offsets[i]:offsets[i + 1]].tobytes()
+             for i in range(len(offsets) - 1)], max_len)
+
+    def decode(self, ids: Sequence[int]) -> str:
+        return bytes(i - self.OFFSET for i in ids
+                     if i >= self.OFFSET).decode("utf-8", "replace")
+
+
 def build_tokenizer(name: Optional[str], vocab_size: int = 30522):
-    """HF tokenizer when cached locally; hashing fallback otherwise."""
+    """``bytes``: the byte-level vocabulary (no file); else an HF tokenizer
+    when cached locally; hashing fallback otherwise."""
+    if name == "bytes":
+        if vocab_size != ByteTokenizer.vocab_size:
+            from arkflow_tpu.errors import ConfigError
+
+            raise ConfigError(
+                f"tokenizer: bytes has {ByteTokenizer.vocab_size} ids (256 "
+                f"bytes + 64 specials); the model's vocab_size is {vocab_size}")
+        return ByteTokenizer()
     if name:
         try:
             return HFTokenizer(name)

@@ -1,0 +1,696 @@
+"""``attention_class: eva`` (EvaByte): a blocked exact window beside chunk
+summaries in ONE softmax, over a cache whose rows are not positions.
+
+The float32 program is held to ``benchmark/references/eva_dense_decoder.py``
+(plain jax.numpy, no cache, written from the configuration's equations) on
+seeded weights at small sizes (window 64, chunk 4): the forward with all
+eight prediction heads, chunked prefill and decoding through the page pools
+with windows closing in chunks and in decode steps, and the two identities
+that tie the mechanism to the model. The served path's page accounting, its
+refusals and the judge's controls (``CONTROLS``: the builder applies one to a
+process, then runs the benchmark's cell, to see the judge refuse it) live
+here too.
+"""
+
+from __future__ import annotations
+
+import asyncio
+import dataclasses
+import hashlib
+import importlib.util
+import re
+import sys
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+ROOT = Path(__file__).resolve().parent.parent
+sys.path.insert(0, str(ROOT))
+
+from arkflow_tpu.errors import ConfigError
+from arkflow_tpu.models import common as cm
+from arkflow_tpu.models import decoder as dec
+from arkflow_tpu.models import paged_decode as pd
+from arkflow_tpu.ops import eva_summarise as es
+from arkflow_tpu.tpu.serving import GenerationServer
+
+
+def _load(path: str, name: str):
+    spec = importlib.util.spec_from_file_location(name, ROOT / path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+ref = _load("benchmark/references/eva_dense_decoder.py", "eva_reference")
+dense_ref = _load("benchmark/references/dense_decoder.py", "dense_reference")
+
+W, C, PAGE, CHUNK = 64, 4, 8, 32
+SIZES = dict(vocab_size=320, dim=64, layers=3, heads=4, kv_heads=4, ffn=96,
+             max_seq=512, rope_theta=1e5, norm_eps=1e-5, attention_class="eva",
+             window_size=W, chunk_size=C, num_pred_heads=8, fp32_skip_add=True,
+             fp32_logits=True, norm_unit_offset=True)
+CFG = dec.DecoderConfig(**SIZES)
+#: a head of 128 lanes (what the cell serves): pools with a head axis, the
+#: wide head's kernel walk; the small model above has row-major pools
+WIDE = dataclasses.replace(CFG, dim=256, heads=2, kv_heads=2, layers=2)
+EXACT = 2e-4
+IDS = np.random.RandomState(7).randint(1, 320, 400).astype(np.int32)
+
+
+@pytest.fixture(scope="module")
+def params():
+    return dec.init(jax.random.PRNGKey(3), CFG)
+
+
+@pytest.fixture
+def exact(monkeypatch):
+    """The program's products in float32 at ``highest`` precision: what is
+    left between it and the reference is the order of float32 sums."""
+    monkeypatch.setattr(cm.dense, "__defaults__", (jnp.float32,))
+    monkeypatch.setattr(cm.embedding, "__defaults__", (jnp.float32,))
+    with jax.default_matmul_precision("highest"):
+        yield
+
+
+def _reference(params, ids, cfg=CFG, **kw):
+    return np.asarray(ref.decoder_logits(
+        params, jnp.asarray(ids), 0, new=len(ids), hp=ref.hyper(cfg), **kw))
+
+
+# -- the configuration and the cache kind ------------------------------------------
+
+
+def test_eva_is_a_cache_kind_whose_rows_are_not_positions():
+    assert CFG.eva and CFG.by_runs and not (CFG.stateful or CFG.layered or CFG.hetero)
+    (pool,) = pd.cache_spec(CFG)
+    assert pool.name == "eva" and pool.compact == (W, C) and not pool.per_slot
+    assert pd.kv_bytes_per_token(CFG) == 3 * 2 * 64 * 2   # a ROW, as held
+    assert not pd.fusable(CFG)
+    # position -> row: the summaries of the closed windows, then the window
+    assert [int(pd.eva_rows(CFG, p)) for p in (0, 63, 64, 65, 200)] == [
+        0, 63, 16, 17, 3 * 16 + 8]
+    assert np.array_equal(pd.eva_rows(CFG, np.array([63, 64])), [63, 16])
+
+
+def test_a_slot_of_the_cell_holds_120_summary_pages_and_128_window_pages():
+    cell = dataclasses.replace(CFG, window_size=2048, chunk_size=16)
+    assert pd.eva_table_pages(cell, 16, 30720 + 1024) == 120 + 128
+    assert int(pd.eva_rows(cell, 30000)) == 14 * 128 + 1328
+    assert pd.eva_table_pages(CFG, PAGE, 300) == 4 * 2 + 8
+
+
+@pytest.mark.parametrize("bad", [
+    dict(attention_class="linear"), dict(chunk_size=0), dict(window_size=66),
+    dict(layer_types=("full_attention",) * 3), dict(qk_norm=True),
+    dict(mamba_d_ssm=16, mamba_n_heads=2, mamba_d_head=8, mamba_d_state=4),
+    dict(num_experts=4), dict(v_head_dim=8),
+    dict(attention_class="", window_size=0, chunk_size=0, num_pred_heads=1,
+         fp32_skip_add=False)])
+def test_config_refuses_by_name(bad):
+    with pytest.raises(ConfigError, match="eva|attention_class"):
+        dec.DecoderConfig(**{**SIZES, **bad})
+
+
+def test_the_leaves_and_their_serving_dtypes(params):
+    lp = params["layers"]
+    assert lp["eva_phi"].shape == lp["eva_mu"].shape == (3, 4, 16)
+    assert params["pred_heads"]["w"].shape == (64, 7 * 320)
+    dt = dec.serve_dtypes(CFG)
+    assert dt["layers"]["eva_phi"] == dt["layers"]["eva_mu"] == jnp.float32
+    assert dt["pred_heads"]["w"] == jnp.bfloat16
+    assert jax.tree_util.tree_structure(dt) == jax.tree_util.tree_structure(params)
+    # seeded wide enough that a summary is no plain mean and mu moves a score
+    assert 0.3 < float(jnp.std(lp["eva_phi"])) < 0.7
+
+
+# -- the forward against the reference ---------------------------------------------
+
+
+@pytest.mark.parametrize("n", [40, 64, 150, 256])
+def test_forward_matches_the_reference_on_all_eight_heads(params, exact, n):
+    """Short of a window (plain causal), exactly one, across two closes, and
+    ending on a boundary."""
+    got = np.asarray(dec.forward(params, CFG, jnp.asarray(IDS[:n])[None],
+                                 pred_heads=True))[0]
+    want = _reference(params, IDS[:n], all_heads=True)
+    assert got.shape == want.shape == (n, 8, 320)
+    assert np.abs(got - want).max() < EXACT
+    # head 0 is the forward's own output and the draft heads do not enter it
+    first = np.asarray(dec.forward(params, CFG, jnp.asarray(IDS[:n])[None]))[0]
+    assert np.array_equal(first, got[:, 0])
+
+
+@pytest.mark.parametrize("identity", ["window_covers_the_sequence",
+                                      "chunks_of_one_and_no_mu"])
+def test_identities_with_plain_causal_attention(params, exact, identity):
+    """(1) Nothing is summarised before a window's end: a window as long as
+    the sequence is plain causal attention. (2) A chunk of one key pools to
+    that key, and with ``mu`` 0 the summaries ARE the keys: plain causal
+    attention at ANY window. Both against ``dense_decoder.py``'s forward."""
+    n = 150
+    if identity == "window_covers_the_sequence":
+        cfg = dataclasses.replace(CFG, window_size=160, chunk_size=4,
+                                  norm_unit_offset=False)
+        p = params
+    else:
+        cfg = dataclasses.replace(CFG, window_size=16, chunk_size=1,
+                                  norm_unit_offset=False)
+        p = {**params, "layers": {**params["layers"], "eva_mu": jnp.zeros_like(
+            params["layers"]["eva_mu"])}}
+    got = np.asarray(dec.forward(p, cfg, jnp.asarray(IDS[:n])[None]))[0]
+    want = np.asarray(dense_ref.decoder_logits(
+        p, jnp.asarray(IDS[:n])[None], 0, new=n, heads=4, kv_heads=4,
+        rope_theta=1e5, norm_eps=1e-5))
+    assert np.abs(got - want).max() < EXACT
+    assert np.abs(_reference(p, IDS[:n], cfg) - want).max() < EXACT
+    if identity != "window_covers_the_sequence":
+        # and the mechanism is no identity at the model's own sizes
+        assert np.abs(_reference(params, IDS[:n]) - want).max() > 50 * EXACT
+
+
+@pytest.mark.parametrize("ablation", ["no_mu", "mean_pooling", "sliding",
+                                      "summaries_unseen"])
+def test_reference_sees_what_the_configuration_assumes(params, exact, ablation,
+                                                       monkeypatch):
+    n, p, cfg = 200, params, CFG
+    zero = lambda name: {**params, "layers": {  # noqa: E731
+        **params["layers"], name: jnp.zeros_like(params["layers"][name])}}
+    if ablation == "no_mu":
+        p = zero("eva_mu")
+    elif ablation == "mean_pooling":
+        p = zero("eva_phi")
+    elif ablation == "sliding":
+        monkeypatch.setattr(dec, "eva_keys", _sliding_keys)
+    else:
+        monkeypatch.setattr(dec, "eva_keys", lambda lp, k, v, cfg, pos: (
+            k, v, (pos[:, None, None, :] // W == pos[:, None, :, None] // W)
+            & (pos[:, None, None, :] <= pos[:, None, :, None])))
+    got = np.asarray(dec.forward(p, cfg, jnp.asarray(IDS[:n])[None]))[0]
+    assert np.abs(got - _reference(params, IDS[:n])).max() > 20 * EXACT
+
+
+def _sliding_keys(lp, k, v, cfg, positions):
+    """A window that SLIDES (the last ``window`` keys exactly, every chunk
+    wholly behind them through its summary): what (E3) is assumed not to be."""
+    from arkflow_tpu.ops.eva_summarise import eva_summarise_plain
+
+    s = positions.shape[1]
+    n = s // C * C
+    ks, vs = eva_summarise_plain(k[:, :n], v[:, :n], lp["eva_phi"], lp["eva_mu"], C)
+    q, kpos = positions[:, None, :, None], positions[:, None, None, :]
+    exact = (kpos <= q) & (kpos > q - W)
+    last = (jnp.arange(n // C) * C + C - 1)[None, None, None, :]
+    mask = jnp.concatenate([jnp.broadcast_to(last <= q - W, exact.shape[:3] + (n // C,)),
+                            exact], -1)
+    return jnp.concatenate([ks, k], 1), jnp.concatenate([vs, v], 1), mask
+
+
+# -- the summariser -------------------------------------------------------------
+
+
+@pytest.mark.parametrize("shape", [(1, 64, 4, 16), (3, 32, 2, 128), (1, 20, 1, 128)])
+def test_summariser_kernel_matches_its_plain_form(shape):
+    b, n, h, d = shape
+    keys = jax.random.split(jax.random.PRNGKey(9), 4)
+    k, v = (jax.random.normal(key, shape, jnp.float32).astype(jnp.bfloat16)
+            for key in keys[:2])
+    phi, mu = (0.5 * jax.random.normal(key, (h, d)) for key in keys[2:])
+    want = es.eva_summarise_plain(k, v, phi, mu, C)
+    got = es.eva_summarise(k, v, phi, mu, chunk=C, interpret=True)
+    for g, w_ in zip(got, want):
+        assert g.shape == (b, n // C, h, d) and g.dtype == jnp.bfloat16
+        assert np.abs(np.asarray(g, np.float32) - np.asarray(w_, np.float32)).max() < 2e-2
+    # by hand, one chunk of one head
+    kc = np.asarray(k[0, :C, 0], np.float64)
+    a = np.exp(kc @ np.asarray(phi[0], np.float64) * d ** -0.5)
+    a /= a.sum()
+    assert np.abs(np.asarray(want[0][0, 0, 0], np.float64)
+                  - (a @ kc + np.asarray(mu[0]))).max() < 2e-2
+
+
+# -- through the page pools ---------------------------------------------------------
+
+
+class _Slots:
+    """The host's side of a compacting window cache, by hand: a slot's pages
+    in table order, taken as its cached length grows, all but a closed
+    window's summary pages handed back at a close."""
+
+    def __init__(self, cfg, slots, max_seq):
+        self.cfg = cfg
+        self.cols = pd.eva_table_pages(cfg, PAGE, max_seq)
+        self.free = list(range(slots * self.cols, 0, -1))
+        self.pages = [[] for _ in range(slots)]
+        self.most = 0
+
+    def cover(self, slot, position):
+        need = int(pd.eva_rows(self.cfg, position)) // PAGE + 1
+        while len(self.pages[slot]) < need:
+            self.pages[slot].append(self.free.pop())
+        self.most = max(self.most, len(self.pages[slot]))
+
+    def wrote(self, slot, position):
+        w, per = self.cfg.window_size, self.cfg.window_size // self.cfg.chunk_size
+        if (position + 1) % w == 0:
+            keep = (position // w + 1) * (per // PAGE)
+            self.free.extend(self.pages[slot][keep:])
+            del self.pages[slot][keep:]
+            return True
+        return False
+
+    def table(self, *slots):
+        t = np.zeros((len(slots), self.cols), np.int32)
+        for r, s in enumerate(slots):
+            t[r, :len(self.pages[s])] = self.pages[s]
+        return jnp.asarray(t)
+
+
+def _through_the_cache(cfg, params, rows, new, kern):
+    """Chunked prefill of ragged rows (row r in slot r), then lockstep decode
+    steps fed the rows' own tokens: the logits of every position, a row at a
+    time, what closed where, and the most pages a slot ever held."""
+    lens = [len(r) - new for r in rows]
+    host = _Slots(cfg, len(rows), max(len(r) for r in rows))
+    # float32 pools: what is left is the order of float32 sums
+    kp, vp = (a.astype(jnp.float32) for a in pd.init_page_pool(
+        cfg, 1 + len(rows) * host.cols, PAGE))
+    chunk = jax.jit(lambda p, i, o, n, t, k, v: pd.paged_prefill_chunk(
+        p, cfg, i, o, n, t, k, v, return_all=True, **kern))
+    step = jax.jit(lambda p, tok, n, a, t, k, v: pd.paged_decode_step(
+        p, cfg, tok, n, a, t, k, v, return_logits=True, **kern))
+    out = [[] for _ in rows]
+    closes = {"chunk": 0, "decode": 0}
+    for r, row in enumerate(rows):
+        for off in range(0, lens[r], CHUNK):
+            m = min(CHUNK, lens[r] - off)
+            ids = np.zeros((1, CHUNK), np.int32)
+            ids[0, :m] = row[off:off + m]
+            host.cover(r, off + m - 1)
+            logits, kp, vp = chunk(params, jnp.asarray(ids), jnp.asarray([off]),
+                                   jnp.asarray([m]), host.table(r), kp, vp)
+            out[r].append(np.asarray(logits)[0, :m])
+            closes["chunk"] += host.wrote(r, off + m - 1)
+    for i in range(new):
+        pos = np.asarray([n + i for n in lens], np.int32)
+        for r in range(len(rows)):
+            host.cover(r, int(pos[r]))
+        logits, kp, vp = step(
+            params, jnp.asarray([row[p] for row, p in zip(rows, pos)]),
+            jnp.asarray(pos), jnp.ones((len(rows),), bool),
+            host.table(*range(len(rows))), kp, vp)
+        for r in range(len(rows)):
+            out[r].append(np.asarray(logits)[r:r + 1])
+            closes["decode"] += host.wrote(r, int(pos[r]))
+    return [np.concatenate(o) for o in out], closes, host
+
+
+#: prompts of 150 (two closes in its chunks), 64 (ends exactly on a
+#: boundary: its last chunk closes), 100 and 40; of the 30 decode steps one
+#: closes a window for the third row (positions 100..129 pass 127) and
+#: another for the fourth (40..69 pass 63) while the other lanes ride
+ROWS = [IDS[:180], IDS[20:114], IDS[50:180], IDS[7:77]]
+
+
+@pytest.mark.parametrize("kernel", ["gather", "paged"])
+def test_chunks_then_decoding_through_closes_match_the_reference(params, exact,
+                                                                 kernel):
+    kern = dict(attention_kernel=kernel, kernel_interpret=True)
+    got, closes, host = _through_the_cache(CFG, params, ROWS, 30, kern)
+    assert closes == {"chunk": 2 + 1 + 1, "decode": 2}
+    for row, logits in zip(ROWS, got):
+        assert np.abs(logits - _reference(params, row)).max() < EXACT
+    # never more than the summaries of every closed window and one window
+    assert host.most <= host.cols == 2 * 2 + 8
+    assert len(host.free) + sum(map(len, host.pages)) == 4 * host.cols
+
+
+def test_a_head_of_128_lanes_through_the_kernel_walk(exact):
+    p = dec.init(jax.random.PRNGKey(5), WIDE)
+    rows = [IDS[:100], IDS[30:160]]
+    got, closes, _ = _through_the_cache(
+        WIDE, p, rows, 20, dict(attention_kernel="paged", kernel_interpret=True))
+    assert closes["chunk"] == 2 and closes["decode"] == 1
+    for row, logits in zip(rows, got):
+        assert np.abs(logits - _reference(p, row, WIDE)).max() < EXACT
+
+
+# -- served: the scheduler's books ----------------------------------------------------
+
+
+PROMPTS = [IDS[:n].tolist() for n in (150, 64, 40, 190, 128)]
+
+
+def _serve(params, prompts=PROMPTS, new=40, **kw):
+    async def run():
+        srv = GenerationServer(params, CFG, slots=3, page_size=PAGE, max_seq=300,
+                               eos_id=-1, prefill_chunk=CHUNK, **kw)
+        held = []
+        account = srv._table
+
+        def table(*slots):  # every step's table: what the slots hold then
+            held.append(max(map(len, srv._slot_pages)))
+            return account(*slots)
+
+        srv._table = table
+        out = await asyncio.gather(*[srv.generate(p, new) for p in prompts])
+        await srv.close()
+        return out, srv, max(held)
+    return asyncio.run(run())
+
+
+@pytest.fixture(scope="module")
+def lockstep(params):
+    return _serve(params, dispatch_depth=1)
+
+
+def test_running_ahead_serves_what_lockstep_serves(params, lockstep):
+    ahead, srv, _ = _serve(params, dispatch_depth=2)
+    assert ahead == lockstep[0] and srv._steps_ahead > 0
+    assert all(len(t) == 40 for t in ahead)
+
+
+def test_served_tokens_are_the_forward_s_greedy_choice(params, lockstep):
+    """Five prompts through three slots (two slots are REUSED, from zero
+    rows): where the forward's own top-2 margin decides, the served token
+    is its choice (teacher-forced on the served tokens)."""
+    fwd = jax.jit(lambda x: dec.forward(params, CFG, x))
+    decided = 0
+    for prompt, toks in zip(PROMPTS, lockstep[0]):
+        row = np.asarray(prompt + toks, np.int32)[None]
+        logits = np.asarray(fwd(jnp.asarray(row)))[0, len(prompt) - 1:-1]
+        top2 = np.sort(logits, -1)[:, -2:]
+        clear = top2[:, 1] - top2[:, 0] > 0.1
+        decided += int(clear.sum())
+        assert np.array_equal(logits.argmax(-1)[clear], np.asarray(toks)[clear])
+    assert decided > 100
+
+
+def test_page_accounting_by_cached_length(lockstep):
+    _, srv, most = lockstep
+    # 300 positions: the summaries of four closed windows and one window
+    assert srv.pages_per_slot == 4 * 2 + 8 and most <= srv.pages_per_slot
+    assert srv.num_pages == 1 + 3 * srv.pages_per_slot
+    assert len(srv._free_pages) == srv.num_pages - 1     # every page is back
+    assert all(not p for p in srv._slot_pages)
+
+
+def test_closes_and_rows_attended_are_counted(params):
+    before = {k: c.value for k, c in _counters().items()}
+    _serve(params, prompts=[IDS[:100].tolist()], new=40, dispatch_depth=1)
+    now = {k: c.value - before.get(k, 0.0) for k, c in _counters().items()}
+    assert now["closes", "chunk"] == 1 and now["closes", "decode"] == 1
+    # decode steps write positions 100..138: the summaries of one window (16)
+    # before position 128, of two after; the window's rows up to the query
+    steps = np.arange(100, 139)
+    assert now["rows", "decode", "summary"] == float((steps // W * 16).sum())
+    assert now["rows", "decode", "window"] == float((steps % W + 1).sum())
+    assert now["rows", "chunk", "summary"] == 16 * (100 - 64)
+
+
+def _counters():
+    from arkflow_tpu.obs import global_registry
+
+    out = {}
+    for m in global_registry().collect():
+        lab = m.labels
+        if m.name == "arkflow_gen_eva_window_closes_total":
+            out["closes", lab["phase"]] = m
+        elif m.name == "arkflow_gen_eva_rows_attended_total":
+            out["rows", lab["phase"], lab["kind"]] = m
+    return out
+
+
+@pytest.mark.parametrize("case,kw,match", [
+    ("one_shot_prefill", dict(prefill_chunk=0), "prefill_chunk"),
+    ("a_chunk_that_straddles", dict(prefill_chunk=48), "divisor of window_size"),
+    ("prefix_cache", dict(prefix_cache_pages=8), "prefix_cache_pages"),
+    ("speculation", dict(speculative_tokens=2), "speculative_tokens"),
+    ("a_page_that_cuts_a_window", dict(page_size=32), "page_size"),
+    ("a_small_pool", dict(num_pages=20), "worst case"),
+    ("mesh", dict(mesh=object()), "one chip"),
+])
+def test_server_refuses_by_name(params, case, kw, match):
+    base = dict(slots=2, page_size=PAGE, max_seq=300, eos_id=-1,
+                prefill_chunk=CHUNK)
+    with pytest.raises(ConfigError, match=match):
+        GenerationServer(params, CFG, **{**base, **kw})
+
+
+@pytest.mark.parametrize("case", ["kv_push_export", "kv_push_adopt",
+                                  "one_shot_program", "fused_program",
+                                  "serving_batch"])
+def test_paths_that_keep_a_row_a_position_refuse_by_name(params, case):
+    srv = GenerationServer(params, CFG, slots=2, page_size=PAGE, max_seq=300,
+                           eos_id=-1, prefill_chunk=CHUNK)
+    z = jnp.zeros
+    with pytest.raises(ConfigError, match="eva|compacting"):
+        if case == "kv_push_export":
+            asyncio.run(srv.prefill_export([1, 2, 3]))
+        elif case == "kv_push_adopt":
+            asyncio.run(srv.generate_from_pages({"prompt": [1], "max_new_tokens": 4}))
+        elif case == "one_shot_program":
+            pd.paged_prefill(params, CFG, z((1, 8), jnp.int32), z((1,), jnp.int32),
+                             z((1, 4), jnp.int32), srv.k_pages, srv.v_pages)
+        elif case == "fused_program":
+            pd.paged_fused_step(params, CFG, *[None] * 10)
+        else:
+            dec.init_kv_cache(CFG, 1, 64)
+
+
+@pytest.mark.parametrize("key", ["swap", "integrity", "mesh", "serving"])
+def test_processor_refuses_by_name(key):
+    from arkflow_tpu.components import Resource, build_component, ensure_plugins_loaded
+
+    ensure_plugins_loaded()
+    conf = {"type": "tpu_generate", "model": "decoder_lm", "model_config": SIZES,
+            "serving": "continuous", "slots": 2, "page_size": PAGE,
+            "max_input": 200, "max_new_tokens": 8, "prefill_chunk": CHUNK,
+            "seq_buckets": [CHUNK], "eos_id": -1, "kernel_parity_check": False}
+    conf.update({"swap": {"swap": {}}, "integrity": {"integrity": {}},
+                 "mesh": {"mesh": {"tp": 2}}, "serving": {"serving": "batch"}}[key])
+    with pytest.raises(ConfigError, match="eva|by kind|not supported"):
+        build_component("processor", conf, Resource())
+
+
+def test_byte_tokenizer_is_utf8_plus_64():
+    from arkflow_tpu.tpu.tokenizer import build_tokenizer
+
+    tok = build_tokenizer("bytes", vocab_size=320)
+    ids, mask = tok.encode_batch(["héllo".encode(), b"x" * 40], 8)
+    assert ids[0, :7].tolist() == [1] + [b + 64 for b in "héllo".encode()]
+    assert mask.sum(1).tolist() == [7, 8] and ids.max() < 320
+    assert tok.decode(ids[0, :7].tolist()) == "héllo"
+    with pytest.raises(ConfigError, match="320"):
+        build_tokenizer("bytes", vocab_size=2048)
+
+
+# -- old programs do not move -------------------------------------------------------
+
+
+def _jaxpr_text(jaxpr) -> str:
+    return re.sub(r"0x[0-9a-f]+", "0x", re.sub(r" at [^\s\]]+:\d+", "", str(jaxpr)))
+
+
+#: sha256 (first 16 hex, source positions stripped) of programs of models
+#: WITHOUT a compacting window cache, recorded at this PR's parent (eb57b91):
+#: a routed per-head model's decode step and chunk, a dense model's fused
+#: step, a model with conv layers. The new operand is absent at old shapes.
+OLD_PROGRAMS = {"routed.decode": "ea71334c76f1b40e", "routed.chunk": "76eb72292649c557",
+                "dense.fused": "e44cec964752cce5", "conv.decode": "e603e89a9e4c9787"}
+
+
+def _old_program(case: str) -> str:
+    layout, step = case.split(".")
+    sizes = dict(vocab_size=64, dim=32, layers=3, heads=4, kv_heads=2, ffn=48,
+                 max_seq=64, head_dim=128)
+    if layout == "routed":
+        sizes.update(n_routed_experts=4, num_experts_per_tok=2,
+                     moe_intermediate_size=16, first_k_dense_replace=1)
+    elif layout == "conv":
+        sizes.update(layer_types=("conv", "full_attention", "conv"),
+                     conv_L_cache=3)
+    cfg = dec.DecoderConfig(**sizes)
+    p = jax.eval_shape(lambda: dec.init(jax.random.PRNGKey(0), cfg))
+    kp, vp = jax.eval_shape(lambda: pd.init_page_pool(cfg, 9, 8, slots=2))
+    kw = dict(attention_kernel="paged", kernel_interpret=False)
+    i32 = jnp.int32
+    lanes = (jnp.zeros((2,), i32), jnp.ones((2,), i32), jnp.ones((2,), bool),
+             jnp.zeros((2, 4), i32))
+    chunk = (jnp.zeros((1, 8), i32), jnp.zeros((1,), i32), jnp.full((1,), 5, i32),
+             jnp.zeros((1, 4), i32))
+    if step == "decode":
+        fn = lambda p, k, v: pd.paged_decode_step(p, cfg, *lanes, k, v, **kw)  # noqa: E731
+    elif step == "chunk":
+        fn = lambda p, k, v: pd.paged_prefill_chunk(p, cfg, *chunk, k, v, **kw)  # noqa: E731
+    else:
+        fn = lambda p, k, v: pd.paged_fused_step(p, cfg, *lanes, *chunk, k, v, **kw)  # noqa: E731
+    text = _jaxpr_text(jax.make_jaxpr(fn)(p, kp, vp))
+    return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+
+@pytest.mark.parametrize("case", sorted(OLD_PROGRAMS))
+def test_programs_without_the_cache_kind_trace_as_before(case):
+    assert _old_program(case) == OLD_PROGRAMS[case], _old_program(case)
+
+
+# -- the judge and its controls -----------------------------------------------------
+
+
+def _bf16_residual(mp):
+    """The residual rounded to bfloat16 at every add (``fp32_skip_add`` read
+    the lax way), on the served path."""
+    layers = pd._dense_layers
+    mp.setattr(pd, "_dense_layers", lambda params, cfg, x, *a, **kw: layers(
+        params, cfg, x.astype(jnp.bfloat16), *a, **kw))
+
+
+def _summariser(change):
+    def patch(mp):
+        plain = es.eva_summarise_plain
+
+        def changed(k, v, phi, mu, chunk, **_):
+            return change(plain, k, v, phi, mu, chunk)
+
+        mp.setattr(es, "eva_summarise_plain", changed)
+        mp.setattr(es, "eva_summarise",
+                   lambda k, v, phi, mu, *, chunk, interpret=False: changed(
+                       k, v, phi, mu, chunk))
+    return patch
+
+
+def _last_page_unwritten(plain, k, v, phi, mu, chunk):
+    """A summary left out: the last 16th of each window's summary rows keeps
+    the window's own (stale) rows."""
+    ks, vs = plain(k, v, phi, mu, chunk)
+    n = ks.shape[-3]
+    keep = n - max(n // 16, 1)
+    stale = lambda s, x: jnp.concatenate(  # noqa: E731
+        [s[..., :keep, :, :], x[..., keep:n, :, :].astype(s.dtype)], axis=-3)
+    return stale(ks, k), stale(vs, v)
+
+
+def _bf16_statistics(plain, k, v, phi, mu, chunk):
+    """The pooling softmax and its sums in bfloat16."""
+    *lead, n, h, d = k.shape
+    kc = k.reshape(*lead, n // chunk, chunk, h, d).astype(jnp.bfloat16)
+    vc = v.reshape(*lead, n // chunk, chunk, h, v.shape[-1]).astype(jnp.bfloat16)
+    a = jax.nn.softmax(jnp.sum(kc * phi.astype(jnp.bfloat16), -1, keepdims=True)
+                       * jnp.bfloat16(d ** -0.5), axis=-3)
+    return ((jnp.sum(a * kc, -3) + mu.astype(jnp.bfloat16)).astype(k.dtype),
+            jnp.sum(a * vc, -3).astype(v.dtype))
+
+
+#: what the judge must refuse, applied to a process (a ``monkeypatch``-like
+#: object with ``setattr``) before it serves: ``tools/eva_control.py`` applies
+#: one, then runs the benchmark's cell. ``sliding_reference`` turns the
+#: REFERENCE's window into a sliding one instead (the served path has no
+#: sliding form to switch to): the distance the rules see is the same one
+CONTROLS = {
+    "bf16_residual": _bf16_residual,
+    "no_mu": _summariser(lambda plain, k, v, phi, mu, c: plain(
+        k, v, phi, jnp.zeros_like(mu), c)),
+    "mean_pooling": _summariser(lambda plain, k, v, phi, mu, c: plain(
+        k, v, jnp.zeros_like(phi), mu, c)),
+    "a_summary_left_out": _summariser(_last_page_unwritten),
+    "bf16_statistics": _summariser(_bf16_statistics),
+}
+
+
+def _greedy(params, cfg, prompt, new, width=256):
+    row, n = np.zeros((1, width), np.int32), len(prompt)
+    row[0, :n] = prompt
+    fwd = jax.jit(lambda p, x: dec.forward(p, cfg, x))
+    for _ in range(new):
+        row[0, n] = int(np.asarray(fwd(params, jnp.asarray(row)))[0, n - 1].argmax())
+        n += 1
+    return row[0, len(prompt):n].tolist()
+
+
+JUDGED = [IDS[:120].tolist(), IDS[40:230].tolist()]
+
+
+def test_judge_accepts_the_program_s_tokens_and_refuses_others(params, exact):
+    tokens = [_greedy(params, CFG, p, 12) for p in JUDGED]
+    hp = ref.hyper(CFG)
+    good = ref.judge_rows(params, hp, JUDGED, tokens, longest=256, scale=0.02)
+    assert good["ok"] and good["positions_checked"] == 24
+    assert good["diverged_share"] == 0.0 and good["worst_gap_tols"] == 0.0
+    wrong = [[(t + 1) % 320 for t in toks] for toks in tokens]
+    bad = ref.judge_rows(params, hp, JUDGED, wrong, longest=256)
+    assert not bad["ok"] and bad["diverged_share"] > 0.5
+    assert ref.crosses_a_close(100, 40, 64) and not ref.crosses_a_close(70, 40, 64)
+    assert ref.stated_float32_leaves_differ(params, params) == 0
+    placed = {**params, "layers": {**params["layers"], "eva_mu": params["layers"][
+        "eva_mu"].astype(jnp.bfloat16)}}
+    assert ref.stated_float32_leaves_differ(placed, params) == 3 * 4 * 16
+
+
+@pytest.mark.parametrize("control", ["no_mu", "mean_pooling", "sliding"])
+def test_judge_refuses_the_control(params, exact, monkeypatch, control):
+    """Tokens the program serves under a control are not the reference's:
+    the cell's limits are sized for bfloat16 products on the chip; here the
+    products are float32 and the program reads 0, so they are held at a
+    fiftieth."""
+    if control == "sliding":
+        monkeypatch.setattr(dec, "eva_keys", _sliding_keys)
+    else:
+        CONTROLS[control](monkeypatch)
+    tokens = [_greedy(params, CFG, p, 24) for p in JUDGED]
+    verdict = ref.judge_rows(params, ref.hyper(CFG), JUDGED, tokens, longest=256,
+                             scale=0.02)
+    assert not verdict["ok"]
+
+
+def _program(params, cfg=CFG):
+    return ref.program_logits(params, cfg, page=PAGE, chunk=CHUNK, lanes=3,
+                              kernel="gather", interpret=False, longest=256)
+
+
+@pytest.mark.parametrize("control", [None, "bf16_residual", "no_mu"])
+def test_judge_holds_the_program_s_own_logits(params, request, monkeypatch, control):
+    """Rules (d) and (f): the judged rows again through the chunk and the
+    decode function (a close in a prompt, a close while decoding), their
+    logits against the reference's; the residual's dtype from the traced
+    programs. What tokens cannot show, these do."""
+    if control != "bf16_residual":   # (its carry is the products' own dtype)
+        request.getfixturevalue("exact")
+    monkeypatch.setattr(ref, "LOGIT_CHUNK", 40)    # across a chunk's seam
+    pool = pd.init_page_pool    # float32 pools: what is left is the order of sums
+    monkeypatch.setattr(pd, "init_page_pool", lambda *a, **kw: tuple(
+        x.astype(jnp.float32) for x in pool(*a, **kw)))
+    if control:
+        CONTROLS[control](monkeypatch)
+    tokens = [_greedy(params, CFG, p, 12) for p in JUDGED]   # 120 + 12: a close
+    run, carried = _program(params)
+    verdict = ref.judge_rows(params, ref.hyper(CFG), JUDGED, tokens, longest=256,
+                             scale=1e-3, program=run)
+    assert verdict["logit_positions"] == [80, 22]
+    errs = verdict["logit_rel_err_chunk"], verdict["logit_rel_err_decode"]
+    if control is None:
+        assert verdict["ok"] and max(errs) < 1e-4 and carried() == ["float32"]
+    elif control == "bf16_residual":
+        assert not verdict["ok"] and carried() == ["bfloat16"]
+    else:
+        assert not verdict["ok"] and min(errs) > 1e-2 and carried() == ["float32"]
+
+
+@pytest.mark.parametrize("control", [None, "bf16_statistics", "mean_pooling"])
+@pytest.mark.parametrize("kernel", [False, True])
+def test_judge_holds_the_summariser(params, monkeypatch, control, kernel):
+    """Rule (e): the rows the summariser writes are bfloat16 whatever it sums
+    in; against (E2) in float32 rounded once, float32 statistics differ at a
+    rounding boundary only."""
+    if control:
+        CONTROLS[control](monkeypatch)
+    hp = ref.hyper(WIDE)
+    layers = dec.init(jax.random.PRNGKey(5), WIDE)["layers"]
+    got = ref.summariser_check(layers, hp, 7, kernel, True)
+    assert got["summary_values"] == 2 * (W // C) * 2 * 128
+    if control is None:
+        assert got["summary_values_off"] < 0.002
+    else:
+        assert got["summary_values_off"] > 0.3

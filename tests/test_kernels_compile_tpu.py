@@ -1108,3 +1108,67 @@ def test_mhc_mixing_kernels_compile_at_xing4_widths(v5e, rows):
     post = _compile(mm.mhc_post_kernel, v5e, ((1, rows, n * c), BF16),
                     ((1, rows, c), BF16), ((1, rows, 128), jnp.float32))
     assert "mhc_pre" in pre.as_text() and "mhc_post" in post.as_text()
+
+
+# -- a compacting window cache (EvaByte), at the cell's shapes -------------------
+
+EVA_WINDOW, EVA_CHUNK, EVA_KVH, EVA_SLOTS, EVA_TABLE = 2048, 16, 32, 20, 248
+
+
+@pytest.mark.parametrize("rows", [1, EVA_SLOTS], ids=["a_close", "a_probe_of_20"])
+def test_eva_summarise_compiles_at_evabyte_widths(v5e, rows):
+    """One window's 2,048 rows of 32 K/V heads x 128 pooled to 128 summary
+    rows: 16 programs of 8 summary rows, 1 MB of K and of V each."""
+    from arkflow_tpu.ops.eva_summarise import eva_summarise
+
+    kv = ((rows, EVA_WINDOW, EVA_KVH, 128), BF16)
+    leaf = ((EVA_KVH, 128), jnp.float32)
+    compiled = _compile(
+        lambda k, v, phi, mu: eva_summarise(k, v, phi, mu, chunk=EVA_CHUNK),
+        v5e, kv, kv, leaf, leaf)
+    assert "eva_summarise" in compiled.as_text()
+
+
+def test_eva_steps_close_windows_without_copying_the_pools(v5e):
+    """The cell's decode step (20 lanes) and chunk (512 tokens) at depth 2:
+    the close is a loop over the closing rows that carries both pools in
+    place — no pool-sized temporary (a ``cond`` around it copied both: 9.7 GB
+    at depth 8) — and both programs call the attention kernel and the
+    summariser."""
+    from arkflow_tpu.models import decoder as dec
+    from arkflow_tpu.models import paged_decode as pd
+
+    cfg = dec.DecoderConfig(
+        vocab_size=320, dim=4096, layers=2, heads=32, kv_heads=32, ffn=11008,
+        max_seq=32768, rope_theta=1e5, norm_eps=1e-5, norm_unit_offset=True,
+        attention_class="eva", window_size=EVA_WINDOW, chunk_size=EVA_CHUNK,
+        num_pred_heads=8, fp32_skip_add=True, fp32_logits=True)
+    assert pd.eva_table_pages(cfg, 16, 30720 + 1024) == EVA_TABLE
+    one = SingleDeviceSharding(v5e[0])
+    shapes = jax.eval_shape(lambda: dec.init(jax.random.PRNGKey(0), cfg))
+    params = jax.tree_util.tree_map(
+        lambda s, d: jax.ShapeDtypeStruct(s.shape, d, sharding=one),
+        shapes, dec.serve_dtypes(cfg))
+    kp, vp = (jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one)
+              for a in jax.eval_shape(lambda: pd.init_page_pool(
+                  cfg, 1 + EVA_SLOTS * EVA_TABLE, 16)))
+    arg = lambda shape, d=I32: jax.ShapeDtypeStruct(shape, d, sharding=one)  # noqa: E731
+    kern = dict(attention_kernel="paged", kernel_interpret=False)
+    s = EVA_SLOTS
+    steps = {
+        "decode": (lambda p, tok, n, act, t, k, v: pd.paged_decode_step(
+            p, cfg, tok, n, act, t, k, v, return_logits=True, **kern),
+            (arg((s,)), arg((s,)), arg((s,), jnp.bool_), arg((s, EVA_TABLE)))),
+        "chunk": (lambda p, ids, off, n, t, k, v: pd.paged_prefill_chunk(
+            p, cfg, ids, off, n, t, k, v, **kern),
+            (arg((1, 512)), arg((1,)), arg((1,)), arg((1, EVA_TABLE))))}
+    pool_bytes = 2 * 4961 * 16 * 32 * 128 * 2
+    for name, (fn, operands) in steps.items():
+        step = jax.jit(fn, donate_argnums=(5, 6)).lower(
+            params, *operands, kp, vp).compile()
+        text = step.as_text()
+        assert "paged_flash_attention" in text and "eva_summarise" in text, name
+        mem = step.memory_analysis()
+        assert mem.alias_size_in_bytes >= 2 * pool_bytes, name
+        assert mem.temp_size_in_bytes < 200 * 1024 * 1024, (
+            name, mem.temp_size_in_bytes)
