@@ -1835,7 +1835,8 @@ def index_mask(scores, positions, key_pos, topk: int):
     return chosen & causal
 
 
-def route_topk(lp: dict, y: jnp.ndarray, cfg: DecoderConfig, token_mask=None):
+def route_topk(lp: dict, y: jnp.ndarray, cfg: DecoderConfig, token_mask=None,
+               lanes: int = 0):
     """The router of one expert layer over tokens ``y`` [T, dim]: float32
     scores — ``scoring_func``: sigmoid of each logit, or softmax over all the
     experts' — (the product at ``highest`` precision — on a TPU a float32
@@ -1852,7 +1853,10 @@ def route_topk(lp: dict, y: jnp.ndarray, cfg: DecoderConfig, token_mask=None):
     ``sigmoid(y w_sg)``, the token's own) — and the layer's load
     [E] int32 (tokens routed to each of ALL the experts). Tokens that
     ``token_mask`` excludes (inactive lanes, padding) have an all-zero row:
-    they route nowhere and count nowhere."""
+    they route nowhere and count nowhere. ``lanes`` > 0 (a fused step's
+    block): the load by row range, [2, E] — of the first ``lanes`` tokens
+    (the decode step's), then of the rest (the chunk's); their sum is what
+    the block hit."""
     e, k = cfg.n_routed_experts, cfg.num_experts_per_tok
     score = jax.nn.softmax if cfg.scoring_func == "softmax" else jax.nn.sigmoid
     scores = score(jnp.dot(
@@ -1878,14 +1882,18 @@ def route_topk(lp: dict, y: jnp.ndarray, cfg: DecoderConfig, token_mask=None):
         shared = shared * jax.nn.sigmoid(jnp.dot(
             y.astype(jnp.float32), lp["shared_gate"]["w"].astype(jnp.float32),
             precision=jax.lax.Precision.HIGHEST))
-    return (jnp.concatenate([cw, shared], axis=-1),
-            assign.sum(axis=0).astype(jnp.int32))
+    cw = jnp.concatenate([cw, shared], axis=-1)
+    load = assign.sum(axis=0) if not lanes else jnp.stack(
+        [assign[:lanes].sum(axis=0), assign[lanes:].sum(axis=0)])
+    return cw, load.astype(jnp.int32)
 
 
 def routed_mlp(lp: dict, y: jnp.ndarray, cfg: DecoderConfig, token_mask=None,
-               kernel: bool = False, interpret: bool = False, stacked=None):
+               kernel: bool = False, interpret: bool = False, stacked=None,
+               lanes: int = 0):
     """Routed + shared experts over ``y`` [B, S, dim] -> (output [B, S,
-    dim], load [E]). ``kernel`` runs the products through the Pallas kernel
+    dim], load [E] — by row range, [2, E], where ``lanes`` > 0:
+    ``route_topk``). ``kernel`` runs the products through the Pallas kernel
     that reads only the experts hit (``ops/moe_experts``); otherwise plain
     XLA over every expert. The experts are ``lp["experts"]``, or — from a
     layer loop that must not slice them — ``stacked = (experts of the whole
@@ -1894,7 +1902,8 @@ def routed_mlp(lp: dict, y: jnp.ndarray, cfg: DecoderConfig, token_mask=None,
 
     b, s, d = y.shape
     yf = y.reshape(b * s, d)
-    cw, load = route_topk(lp, yf, cfg, token_mask)
+    cw, load = route_topk(lp, yf, cfg, token_mask,
+                          **({"lanes": lanes} if lanes else {}))
     ex, layer = stacked if stacked is not None else (lp["experts"], None)
     if kernel:
         out = moe_expert_swiglu(yf, cw, ex["w_gate"], ex["w_up"], ex["w_down"],

@@ -410,7 +410,8 @@ def _write_coords(table, positions, valid, page: int, ring: bool = False):
 def _latent_layers(params: dict, cfg: DecoderConfig, x, k_pages, v_pages,
                    positions, page_idx, offset, token_mask, *, page_table,
                    off, mask, block: bool, attention_kernel: str,
-                   kernel_interpret: bool):
+                   kernel_interpret: bool,
+                   chunk: Optional[_RidingChunk] = None):
     """The layer loop of a latent-attention model over the paged cache: one
     scan per layer run (leading dense layers, then expert layers; a layer
     pattern alternates kinds), each layer reading its kind's sizes
@@ -429,9 +430,18 @@ def _latent_layers(params: dict, cfg: DecoderConfig, x, k_pages, v_pages,
     form —, a sliding layer over its window, an indexed layer over its
     indexer's choice. Returns (x, k_pages, v_pages, the step's counters:
     ``moe_step_stats``, then with indexed layers (keys attended, keys in
-    context) summed over queries and indexed layers)."""
+    context) summed over queries and indexed layers).
+
+    ``chunk`` (``paged_fused_step``; a ``fusable`` model: plain full layers,
+    one residual stream): the block is ONE row [1, lanes + C] — a decode
+    step's lanes, then a prompt's chunk — through every norm, projection,
+    router and expert product; it attends as ``chunk`` says
+    (``_attend_latent``) once every token's row is written, and the
+    counters come back by row range, [3, ...]: the lanes', the chunk's, and
+    the block's own (their loads summed: what the expert products read)."""
     kernel = attention_kernel == "paged"
     kern = dict(attention_kernel=attention_kernel, kernel_interpret=kernel_interpret)
+    lanes = 0 if chunk is None else chunk.lanes
     # several residual streams (``hc_mult`` > 1): the carry is [B, S, n dim]
     # and every ``x + f(norm(x))`` is mix in -> sub-layer -> mix back
     hc = cfg.hc_mult > 1
@@ -483,7 +493,8 @@ def _latent_layers(params: dict, cfg: DecoderConfig, x, k_pages, v_pages,
                                               mask, sp, gate)
             else:
                 attn = _attend_latent(lp, q_nope, q_rope, cp, rp, li, kept,
-                                      off, mask, sp, gate=gate, **kern)
+                                      off, mask, sp, gate=gate, chunk=chunk,
+                                      **kern)
             if layered:
                 kp, vp = {**kp, name: cp}, {**vp, name: rp}
             else:
@@ -495,7 +506,7 @@ def _latent_layers(params: dict, cfg: DecoderConfig, x, k_pages, v_pages,
             if routed:
                 out, load = routed_mlp(lp, y, cfg, token_mask=token_mask,
                                        kernel=kernel, interpret=kernel_interpret,
-                                       stacked=(experts, ei))
+                                       stacked=(experts, ei), lanes=lanes)
             else:
                 out, load = _mlp(lp, y, cfg), None
             x = hc_post(streams, out, h, **mix) if hc else x + out
@@ -517,7 +528,12 @@ def _latent_layers(params: dict, cfg: DecoderConfig, x, k_pages, v_pages,
     x, k_pages, v_pages, picked = carry
     if hc:
         x = hc_collapse(x, cfg)
-    stats = moe_step_stats(jnp.concatenate(loads), cfg.experts_held and cfg.held)
+    loads, held = jnp.concatenate(loads), cfg.experts_held and cfg.held
+    if chunk is None:
+        stats = moe_step_stats(loads, held)
+    else:  # [layers, 2, E]: by row range, and the block's
+        stats = jnp.stack([moe_step_stats(part, held) for part in (
+            loads[:, 0], loads[:, 1], loads.sum(axis=1))])
     if cfg.index_topk:
         stats = jnp.concatenate([stats, picked])
     return x, k_pages, v_pages, stats
@@ -654,7 +670,8 @@ def _attend_window(lp, q_nope, q_rope, c_pages, r_pages, layer, ring, off,
 
 def _attend_latent(lp, q_nope, q_rope, c_pages, r_pages, layer, page_table,
                    off, mask, cfg, attention_kernel: str,
-                   kernel_interpret: bool, gate=None):
+                   kernel_interpret: bool, gate=None,
+                   chunk: Optional[_RidingChunk] = None):
     """Absorbed latent attention over the paged cache: the queries are
     carried into the latent space (``W_uk`` absorbed), scored against the
     cached latent rows and rope keys of every head's ONE shared row per
@@ -662,21 +679,39 @@ def _attend_latent(lp, q_nope, q_rope, c_pages, r_pages, layer, page_table,
     ``o_proj`` finish. ``"paged"`` reads the pools in place
     (ops/ragged_attention.mla_paged_attention; ``off`` is each row's first
     query position); ``"gather"`` materializes the layer's context and
-    masks with ``mask`` — the reference."""
+    masks with ``mask`` — the reference.
+
+    ``chunk`` (a fused step's block, ONE row [1, lanes + C]): the two weight
+    products run once over the block; between them the lanes attend as
+    [lanes, 1] queries under ``page_table`` / ``off`` / ``mask`` and the
+    chunk as [1, C] under its own — the two steps' own two calls."""
     q_lat = mla_absorb_query(lp, q_nope, cfg)
     scale = cfg.softmax_scale
-    if attention_kernel == "paged":
-        from arkflow_tpu.ops.ragged_attention import mla_paged_attention
 
-        o_lat = mla_paged_attention(q_lat, q_rope, c_pages, r_pages, layer,
-                                    page_table, off, scale=scale,
-                                    interpret=kernel_interpret)
-    else:
+    def context(q_lat, q_rope, page_table, off, mask):
+        """``sum p c`` of the queries [rows, positions] over their rows'
+        cached context."""
+        if attention_kernel == "paged":
+            from arkflow_tpu.ops.ragged_attention import mla_paged_attention
+
+            return mla_paged_attention(q_lat, q_rope, c_pages, r_pages, layer,
+                                       page_table, off, scale=scale,
+                                       interpret=kernel_interpret)
         b, ctx = page_table.shape[0], page_table.shape[1] * c_pages.shape[2]
         cc = c_pages[layer][page_table].reshape(b, ctx, -1).astype(q_lat.dtype)
         rr = r_pages[layer][page_table][..., :q_rope.shape[-1]].reshape(
             b, ctx, -1).astype(q_lat.dtype)
-        o_lat = _masked_latent_attention(q_lat, q_rope, cc, rr, mask, scale)
+        return _masked_latent_attention(q_lat, q_rope, cc, rr, mask, scale)
+
+    if chunk is None:
+        o_lat = context(q_lat, q_rope, page_table, off, mask)
+    else:  # the lanes a row each, then the chunk's row
+        n = chunk.lanes
+        o_lat = jnp.concatenate([
+            context(q_lat[0, :n, None], q_rope[0, :n, None], page_table, off,
+                    mask).reshape(1, n, *q_lat.shape[2:]),
+            context(q_lat[:, n:], q_rope[:, n:], chunk.table, chunk.off,
+                    chunk.mask)], axis=1)
     return mla_output(lp, o_lat, cfg, gate)
 
 
@@ -1228,8 +1263,9 @@ def _gdn_paged(lp: dict, y, cfg: DecoderConfig, states, windows, layer, rows,
 
 
 class _RidingChunk(NamedTuple):
-    """How the chunk behind a fused step's lanes attends (``_dense_layers``):
-    the block's first ``lanes`` tokens are the decode step's, a row each;
+    """How the chunk behind a fused step's lanes attends (``_dense_layers``,
+    ``_latent_layers``): the block's first ``lanes`` tokens are the decode
+    step's, a row each;
     the rest are one prompt's chunk, one row under its own ``table`` [1, P],
     ``off`` [1] and ``mask``."""
 
@@ -1738,15 +1774,29 @@ def paged_decode_step(params: dict, cfg: DecoderConfig, token_ids, lengths,
 
 def fusable(cfg: DecoderConfig) -> bool:
     """Whether a prompt's chunk can ride a decode step as one row block
-    (``paged_fused_step``): a per-head K/V model whose tokens meet only in
-    attention and whose step takes no operand beside ``paged_decode_step``'s
-    and ``paged_prefill_chunk``'s own — no latent rows, no routed experts (a
-    prompt's router counters) nor the Switch layer (a step's tokens share
-    expert capacity), no state a sequence (a row of state slots), no layer
-    pattern (ring coordinates), no compacting window cache (which rows
-    close)."""
-    return not (cfg.latent or cfg.routed or cfg.num_experts > 1
-                or cfg.stateful or cfg.layered or cfg.eva)
+    (``paged_fused_step``): a model whose tokens meet only in attention and
+    whose step takes no operand beside ``paged_decode_step``'s and
+    ``paged_prefill_chunk``'s own. Two families, by the layer kinds the
+    configuration states:
+
+    - per-head K/V, dense MLP (``_dense_layers``' ``chunk``);
+    - plain latent attention with routed experts (``_latent_layers``'
+      ``chunk``: dropless and per token, so the block's rows route as they
+      do apart; the counters come back by row range).
+
+    Left out, each for an operand or a rule of its own that the block does
+    not carry yet: a layer pattern on either loop (a sliding layer's ring
+    coordinates, an indexed layer's choice a query), several residual
+    streams (``hc_mult`` > 1: the mixing kernels' row tiles), a state a
+    sequence (a row of state slots), a compacting window cache (which rows
+    close), the Switch layer (a step's tokens share expert capacity), and
+    routed experts on the per-head loop (no served model has them without
+    one of the above beside them, so nothing could show a gain)."""
+    if cfg.num_experts > 1 or cfg.layered:
+        return False
+    if cfg.latent:
+        return bool(cfg.routed) and cfg.hc_mult == 1
+    return not (cfg.routed or cfg.stateful or cfg.eva)
 
 
 def paged_fused_step(params: dict, cfg: DecoderConfig, token_ids, lengths,
@@ -1762,22 +1812,30 @@ def paged_fused_step(params: dict, cfg: DecoderConfig, token_ids, lengths,
     own lane is not active, so the two parts write no page in common.
 
     The S + C tokens run as ONE row block [1, S + C, dim] through every
-    norm and weight product, so a layer's weights are read once for both;
-    attention is the two steps' own two calls (``_dense_layers``'
-    ``chunk``), and the head multiplies S + 1 rows: the lanes and the
-    chunk's last true position. A decode step and a chunk are each bound by
-    the weights' bytes; folded, the chunk's rows cost their attention.
+    norm and weight product, so a layer's weights are read once for both —
+    of routed experts, every expert that lanes OR chunk hit once —;
+    attention is the two steps' own two calls (``_dense_layers``' /
+    ``_latent_layers``' ``chunk``), and the head multiplies S + 1 rows: the
+    lanes and the chunk's last true position. A decode step and a chunk are
+    each bound by the weights' bytes; folded, the chunk's rows cost their
+    attention.
 
     Returns (logits [S + 1, vocab] — tokens with ``return_logits`` false —,
     k_pages, v_pages): row S is the prompt's next token where the chunk was
-    its last. Only for a ``fusable`` model."""
+    its last. A routed model's step returns its routing counters as a fourth
+    value, by row range [3, ...] (``moe_step_stats`` of each): the lanes',
+    the chunk's, the block's. Only for a ``fusable`` model."""
     if not fusable(cfg):
         from arkflow_tpu.errors import ConfigError
 
         raise ConfigError(
-            "a chunk rides a decode step only on a per-head K/V model without "
-            "routed experts, a state a sequence, a layer pattern or a "
-            "compacting window cache (attention_class 'eva') (pools "
+            "a chunk rides a decode step only on a per-head K/V model with a "
+            "dense MLP or a plain latent-attention model with routed "
+            "experts: a layer pattern (sliding or indexed layers), several "
+            "residual streams (hc_mult > 1), a state a sequence, a "
+            "compacting window cache (attention_class 'eva'), the Switch "
+            "layer and routed experts beside per-head K/V each want an "
+            "operand the block does not carry (pools "
             f"{', '.join(pool.name for pool in cache_spec(cfg))})")
     s, c = token_ids.shape[0], input_ids.shape[1]
     page = _page_size(k_pages)
@@ -1798,16 +1856,20 @@ def paged_fused_step(params: dict, cfg: DecoderConfig, token_ids, lengths,
     chunk_mask = key_pos[None, None, None, :] <= chunk_pos[:, None, :, None]
     x = _scaled(cm.embedding(params["embed"], row(token_ids, input_ids)),
                 cfg.embedding_multiplier)                         # [1, S + C, D]
-    x, new_k, new_v = _dense_layers(
-        params, cfg, x, k_pages, v_pages, row(lengths, chunk_pos),
-        row(lane_page, chunk_page), row(lane_at, chunk_at),
-        row(active, chunk_valid), page_table=page_table, off=lengths,
-        mask=lane_mask, block=False, kv_sharding=kv_sharding,
+    operands = dict(
+        page_table=page_table, off=lengths, mask=lane_mask, block=False,
         attention_kernel=attention_kernel, kernel_interpret=kernel_interpret,
         chunk=_RidingChunk(s, chunk_table, chunk_off, chunk_mask))
+    if not cfg.latent:
+        operands["kv_sharding"] = kv_sharding
+    # a routed model appends its counters, by row range
+    x, new_k, new_v, *moe = (_latent_layers if cfg.latent else _dense_layers)(
+        params, cfg, x, k_pages, v_pages, row(lengths, chunk_pos),
+        row(lane_page, chunk_page), row(lane_at, chunk_at),
+        row(active, chunk_valid), **operands)
     last = s + jnp.clip(chunk_len - 1, 0, c - 1)                  # [1]
     logits = lm_logits(params, jnp.concatenate(
         [x[0, :s], jnp.take(x[0], last, axis=0)]), cfg)           # [S + 1, V]
     if not return_logits:
         logits = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    return logits, new_k, new_v
+    return (logits, new_k, new_v, *moe)

@@ -288,7 +288,7 @@ class GenerationServer:
         reg = global_registry()
         self._extra_counters = [
             {kind: reg.counter(metric, text, {"model": name, "kind": kind})
-             for kind in ("decode", "chunk")}
+             for kind in _STEP_KINDS}
             for has, metric, text in (
                 (cfg.experts_held is not None,
                  "arkflow_gen_moe_held_assignments_total",
@@ -608,7 +608,7 @@ class GenerationServer:
                                  "tokens routed to the busiest expert of a "
                                  "step, over expert layers",
                                  {"model": name, "kind": kind}))
-            for kind in ("decode", "chunk", "prefill")}
+            for kind in _MOE_KINDS}
         #: (gauge, what holds its rows: "window" / "pages" / "slots", bytes
         #: one of those holds) per pool, where there is more than one kind
         self.m_kv_live = [
@@ -885,11 +885,11 @@ class GenerationServer:
         ``packed`` is the step's ONE host array (``pack_operands``; a fused
         step's: the decode step's, then the chunk's). Such a server's decode
         steps all return ``slots + 1`` tokens — the last the prompt's, where
-        a chunk rode and was its prompt's last — so that a step takes the
-        step before's output whichever kind that was. Under a mesh every step
-        carries explicit in/out shardings: the KV pools split over KV heads
-        on ``tp``, everything else is replicated — page-table gathers stay
-        static-shaped, so the layer scan lowers to plain GSPMD collectives."""
+        a chunk rode and was its prompt's last; a routed model's, ONE array
+        of ``_FusedLayout`` — so a step takes the step before's output
+        whichever kind that was. Under a mesh every step carries explicit
+        in/out shardings: the KV pools split over KV heads on ``tp``, the rest
+        replicated: table gathers stay static-shaped, plain GSPMD collectives."""
         from arkflow_tpu.models.decoder import select_token
         from arkflow_tpu.models.paged_decode import (paged_fused_step,
                                                      paged_prefill_chunk)
@@ -905,8 +905,8 @@ class GenerationServer:
         # a routed chunk's counters
         keyed = int(self._key is not None)
         piped = int(self._ahead)
-        routed = int(cfg.routed)
-        fuses = int(self._fuses)
+        routed, fuses = int(cfg.routed), int(self._fuses)
+        self._lay = lay = _fused_layout(self)  # fixed with the programs
 
         def _pick(logits, keys, *behind):
             """The step's token array (``behind`` appended: a routed model's
@@ -936,25 +936,25 @@ class GenerationServer:
             logits, kp, vp, *stats = paged_decode_step(
                 params, cfg, tok, lens, act != 0, table, kp, vp,
                 return_logits=True, kv_sharding=kv, **kern)
-            # a server that fuses: shaped as a fused step's output, the
-            # place of the prompt's token empty (no chunk rode)
+            # a server that fuses: shaped as a fused step's output (no chunk rode)
             no_seed = [jnp.zeros(1, jnp.int32)] if fuses else []
-            out, *key = _pick(logits, dev, *stats, *no_seed)
+            behind = lay.decode(*stats) if lay else (*stats, *no_seed)
+            out, *key = _pick(logits, dev, *behind)
             return out, kp, vp, *key
 
         def _fused(params, packed, kp, vp, *dev):
             lanes = self.slots * (3 + pages)
-            tok, lens, act, table = unpack_operands(
-                packed[:lanes], self.slots, pages)
+            tok, lens, act, table = unpack_operands(packed[:lanes], self.slots, pages)
             ids, off, clen, its_table = unpack_operands(packed[lanes:], 1, pages)
             tok = tok[:, 0]
             if piped:
                 prev, *dev = dev
                 tok = jnp.where(tok < 0, prev[:self.slots], tok)
-            logits, kp, vp = paged_fused_step(
+            logits, kp, vp, *stats = paged_fused_step(
                 params, cfg, tok, lens, act != 0, table, ids, off, clen,
                 its_table, kp, vp, return_logits=True, kv_sharding=kv, **kern)
-            out, *key = _pick(logits, dev)
+            dev, stats = (dev[1:], lay.fused(dev[0], *stats)) if lay else (dev, stats)
+            out, *key = _pick(logits, dev, *stats)
             return out, kp, vp, *key
 
         def _prefill(params, packed, kp, vp, *key):
@@ -975,9 +975,9 @@ class GenerationServer:
                 # a routed model: the prompt's counters ride on the device
                 # behind the chunk before's token (``_no_counts`` at first)
                 so_far, *dev = dev
-                stats = [_chunk_counts(so_far, stats[0])]
+                stats = [_chunk_counts(lay.so_far(so_far) if lay else so_far, stats[0])]
             out, *key = _pick(logits, dev, *stats)
-            return out, kp, vp, *key
+            return (lay.chunk(out) if lay else out), kp, vp, *key
 
         def _verify(params, packed, kp, vp):
             ids, lens, clen, table = unpack_operands(packed, self.slots, pages, ring)
@@ -1015,7 +1015,7 @@ class GenerationServer:
         self._prefill = bind(_prefill, keyed, keyed)
         self._chunk = bind(_chunk, routed + keyed, keyed)
         self._verify = bind(_verify, 0, 0)
-        self._fused = bind(_fused, piped, 0) if fuses else None
+        self._fused = bind(_fused, piped + routed, 0) if fuses else None
         # the programs this configuration can reach: cold until each has run
         note_programs(fn.__name__ for fn, reached in (
             (_decode, True), (_chunk, True), (_fused, fuses),
@@ -1026,9 +1026,9 @@ class GenerationServer:
         zeros = functools.partial(jnp.zeros, dtype=jnp.int32,
                                   device=self._repl_sharding)
         counted = len(self._extra_counters)
-        self._no_prev = (zeros(self.slots + fuses + routed * (3 + counted)),
-                         ) if piped else ()
-        self._no_counts = zeros(5 + counted)
+        self._no_prev = (zeros(lay.size if lay else self.slots + fuses + routed * (
+            3 + counted)),) if piped else ()
+        self._no_counts = zeros(lay.size if lay else 5 + counted)
 
     def _note_moe(self, kind: str, stats, steps: int = 1, *, rows: int) -> None:
         """Record the routing counters (``moe_step_stats``, on the host) of one
@@ -1983,6 +1983,8 @@ class GenerationServer:
         model's counters): the one fetch seeds the slot for decode. True
         where the request stops here and its pages are to be exported."""
         with loop_stage("gen_apply", kind):
+            if kind == "chunk" and self._lay is not None:
+                nxt = nxt[self.slots:]  # the prompt's place (``_FusedLayout``)
             return self._seed_slot(slot, req, kind, nxt)
 
     def _seed_slot(self, slot: int, req: _Request, kind: str, nxt) -> bool:
@@ -2133,8 +2135,9 @@ class GenerationServer:
         chunk of the prompt admitted first; both, ONE fused step — the
         lanes and that prompt's next chunk through one pass over the weights
         — where the server fuses (``_fuses``: greedy, chunked prefill, a
-        per-head K/V model without routed experts, a state a sequence or a
-        layer pattern), and else a chunk and a decode step in turn. A
+        model that ``paged_decode.fusable`` admits: per-head K/V with a dense
+        MLP, or plain latent attention with routed experts), and else a
+        chunk and a decode step in turn. A
         prompt that stops after prefill keeps its last chunk's own step, a
         one-shot prefill and a speculative step their own too."""
         try:
@@ -2303,13 +2306,16 @@ class GenerationServer:
             # land it and let the loop re-evaluate (admission / drain / exit)
             await self._drain_pipeline()
             return
-        req, seeds = None, None
+        req, seeds, rode = None, None, 0
         if span is not None:
-            off, c, new_off, final = span
+            off, rode, new_off, final = span
             req = self._slot_req[riding]
             with annotated("gen_prepare:chunk") as prep:
-                its, _ = self._span_operands(riding, req, "chunk", off, new_off, c)
-                packed = np.concatenate([packed, its])
+                # a routed prompt's counters so far ride in beside the step
+                # before's output
+                its, so_far = self._span_operands(riding, req, "chunk", off,
+                                                  new_off, rode)
+                packed, prev = np.concatenate([packed, its]), (*prev, *so_far)
             prep_s += prep.dur_s
             self.m_chunks["fused"].inc()
             if final:  # the step's last token seeds the slot, in its apply
@@ -2325,12 +2331,20 @@ class GenerationServer:
             reqs = list(self._slot_req)
             out = await self._run_ahead(
                 key, packed, prev,
-                functools.partial(self._apply_decode, act, reqs=reqs, seeds=seeds),
+                functools.partial(self._apply_decode, act, reqs=reqs, seeds=seeds,
+                                  rode=rode),
                 act=act, reqs=reqs, slot=riding if seeds else -1)
         else:
             # off-loop + gated: one device-step of wall time (plus first compile)
-            out = await self._run_device_step(key, packed, *prev)
-            self._apply_decode(act, out, seeds=seeds)
+            kept = bool(rode and self._lay is not None)
+            out = tokens = await self._run_device_step(key, packed, *prev,
+                                                       final=not kept)
+            if kept:  # a routed prompt's counters stay on the device: it is
+                # handed on as it is, and the step's one fetch is made here
+                with annotated("gen_fetch:fused") as fetch:
+                    tokens = np.asarray(out)
+                observe_stage("gen_fetch", fetch.dur_s, kind="fused")
+            self._apply_decode(act, tokens, seeds=seeds, rode=rode)
         if req is not None and not seeds:  # the chunk's books close here
             self._advance_prompt(riding, req, new_off, out)
 
@@ -2431,20 +2445,21 @@ class GenerationServer:
             tiles[product] += -(-width // tile_c)
         return tiles
 
-    def _apply_decode(self, act, nxt, reqs=None, seeds=None) -> None:
+    def _apply_decode(self, act, nxt, reqs=None, seeds=None, rode: int = 0) -> None:
         """One decode step's fetched tokens (then a routed model's counters)
         onto host state. A lane whose request is no longer the one in
         ``reqs`` (the snapshot of a step that ran ahead) rode one step too
         long: its token is dropped, and the step's routing counters, which
         counted the lane, are not recorded. ``seeds``: the step carried its
         prompt's last chunk, and the token behind the lanes' seeds that slot
-        (``_seed_slot``)."""
+        (``_seed_slot``). ``rode``: the rows of the chunk the step carried
+        (a fused step), which say where its counters go (``_note_step_moe``)."""
         with loop_stage("gen_apply", "decode"):
             self.m_steps.inc()
             lanes = np.flatnonzero(act)
             if self._moe_layers and (reqs is None or all(
                     self._slot_req[s] is reqs[s] for s in lanes)):
-                self._note_moe("decode", nxt[self.slots:], rows=self.slots)
+                _note_step_moe(self, nxt, rode)
             for s in map(int, lanes):
                 req = self._slot_req[s]
                 if req is None or (reqs is not None and req is not reqs[s]):
@@ -2549,6 +2564,8 @@ def _note_grouped(server: GenerationServer, kind: str, steps: int, rows: int) ->
     where every step is one token tile."""
     from arkflow_tpu.ops.moe_experts import runs_grouped
 
+    if not rows:  # a part of a step: the step's product is counted once
+        return
     grouped = server.decode_kernel == "paged" and runs_grouped(rows)
     global_registry().counter(
         "arkflow_gen_moe_grouped_products_total",
@@ -2768,3 +2785,88 @@ def _eva_gauges(server: GenerationServer) -> None:
         window += len(pages) - held
     server.m_eva_pages["summary"].set(summary)
     server.m_eva_pages["window"].set(window)
+
+
+# -- a chunk rides the decode step of a ROUTED model ----------------------------
+# Here, at the END of the file (above: "which expert kernel a step ran"), and
+# called from lines of ``_build_jitted`` that took the place of as many.
+
+#: the label values of the routing series (``m_moe``). ``decode`` is a
+#: ``_decode`` execution and takes nothing from a fused step (the
+#: benchmark's roofline readers divide it into that program's kernel time);
+#: ``chunk`` a prompt's chunks, fused or alone, summed on the device and
+#: recorded with its first token; ``prefill`` a one-shot prefill. A fused
+#: step's BLOCK — what lanes or chunk hit, what its expert products read —
+#: is ``fused``, its lanes alone ``fused_lanes``. The further counters
+#: (``_extra_counters``) have no one-shot series: ``_STEP_KINDS``.
+_STEP_KINDS = ("decode", "chunk", "fused", "fused_lanes")
+_MOE_KINDS = (*_STEP_KINDS, "prefill")
+
+
+@dataclass(frozen=True)
+class _FusedLayout:
+    """What EVERY step of a server that fuses a routed model returns: one
+    int32 array of one shape whichever program made it, so that a step takes
+    the step before's output on the device whatever that was — the lanes
+    their tokens (``prev``), a prompt its counters so far
+    (``req.chunk_moe``) — and one fetch brings all of it:
+
+        [the lanes' tokens: slots | the prompt's token, its counters so far
+         (``_chunk_counts``): 2 + n | the lanes' counters: n | the block's: n]
+
+    ``n`` the counters of one ``moe_step_stats``. A decode step leaves the
+    prompt's and the block's places empty, a chunk alone the lanes' and the
+    block's."""
+
+    slots: int
+    n: int
+
+    @property
+    def size(self) -> int:
+        return self.slots + 2 + 3 * self.n
+
+    @property
+    def lanes_at(self) -> int:
+        return self.slots + 2 + self.n
+
+    def decode(self, stats) -> list:
+        """What follows a decode step's tokens."""
+        return [jnp.zeros(2 + self.n, jnp.int32), stats, jnp.zeros(self.n, jnp.int32)]
+
+    def so_far(self, out):
+        """The prompt's place in the output of the step before."""
+        return out[self.slots:self.lanes_at]
+
+    def chunk(self, out):
+        """A chunk's own output (its token, the prompt's counters) in place."""
+        return jnp.concatenate([jnp.zeros(self.slots, jnp.int32), out,
+                                jnp.zeros(2 * self.n, jnp.int32)])
+
+    def fused(self, before, stats) -> list:
+        """What follows a fused step's ``slots + 1`` tokens: the prompt's
+        counters with this chunk's added (``stats`` [3, n]: the lanes', the
+        chunk's, the block's), the lanes', the block's."""
+        return [_chunk_counts(self.so_far(before), stats[1]), stats[0], stats[2]]
+
+
+def _fused_layout(server: GenerationServer) -> Optional[_FusedLayout]:
+    """The one output layout of a server that fuses a routed model; None for
+    every other server, whose steps return what they always did."""
+    if not (server._fuses and server.cfg.routed):
+        return None
+    return _FusedLayout(server.slots, 3 + len(server._extra_counters))
+
+
+def _note_step_moe(server: GenerationServer, nxt, rode: int) -> None:
+    """Record a decode-kind step's routing counters by the program that ran:
+    a ``_decode`` execution under ``decode``; a fused step that carried
+    ``rode`` chunk rows under ``fused`` (its block, whose rows decide the
+    expert kernel) and ``fused_lanes`` — the chunk's part rides on with the
+    prompt's counters (``chunk``, at its first token)."""
+    lay = server._lay
+    if not rode:
+        server._note_moe("decode", nxt[lay.lanes_at if lay else server.slots:],
+                         rows=server.slots)
+        return
+    server._note_moe("fused_lanes", nxt[lay.lanes_at:], rows=0)
+    server._note_moe("fused", nxt[lay.lanes_at + lay.n:], rows=server.slots + rode)

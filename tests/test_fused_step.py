@@ -1,14 +1,16 @@
 """A prefill chunk rides the decode step (tiny shapes, CPU).
 
-Where a decode step is due and a slot is prefilling, a greedy server of a
-dense per-head K/V model that prefills in chunks issues ONE program for both
+Where a decode step is due and a slot is prefilling, a greedy server that
+prefills in chunks issues ONE program for both
 (``paged_decode.paged_fused_step``, ``GenerationServer._step(active,
-riding)``): the lanes and the chunk run as one row block through every
-weight product, attention is the two steps' own two calls. What is served
-must be what a chunk and a decode step in turn serve, request by request;
-every other model, a sampling and a speculative server keep alternating and
-build no such program; the step takes one host array; the counter
-``arkflow_gen_chunks_total{mode}`` says what rode.
+riding)``) on a model that ``paged_decode.fusable`` admits — per-head K/V
+with a dense MLP, or plain latent attention with routed experts —: the lanes
+and the chunk run as one row block through every weight product, attention
+is the two steps' own two calls, and a routed model's counters come back by
+row range. What is served must be what a chunk and a decode step in turn
+serve, request by request; every other model, a sampling and a speculative
+server keep alternating and build no such program; the step takes one host
+array; the counter ``arkflow_gen_chunks_total{mode}`` says what rode.
 """
 
 from __future__ import annotations
@@ -25,12 +27,14 @@ import numpy as np
 import pytest
 
 from arkflow_tpu.errors import ConfigError
+from arkflow_tpu.models import decoder as dec
 from arkflow_tpu.models import get_model
 from arkflow_tpu.models.decoder import FULL, SLIDING
 from arkflow_tpu.models.paged_decode import (fusable, init_page_pool,
                                              paged_decode_step,
-                                             paged_fused_step,
-                                             paged_prefill_chunk)
+                                             paged_fused_step, paged_prefill,
+                                             paged_prefill_chunk,
+                                             window_ring_pages)
 from arkflow_tpu.obs import global_registry
 from arkflow_tpu.tpu.serving import GenerationServer
 
@@ -49,9 +53,21 @@ EXPERTS = dict(n_routed_experts=8, num_experts_per_tok=2, n_shared_experts=1,
 HYBRID = dict(vocab_size=128, dim=64, layers=2, heads=4, kv_heads=2, head_dim=8,
               ffn=96, mamba_d_ssm=32, mamba_n_heads=2, mamba_d_head=16,
               mamba_d_state=16, mamba_n_groups=2, mamba_chunk_size=8)
+#: the latent loop is served with routed experts only (the Kanana-2 layout)
+ROUTED_LATENT = {**LATENT, **EXPERTS}
+#: its sliding and indexed layers (the dots3 layout) and its several residual
+#: streams (the Xing4.0 layout) each want an operand the block does not carry
+PATTERN = dict(
+    layer_types=(FULL, SLIDING, FULL), sliding_window=9, q_lora_rank=12,
+    swa_heads=2, swa_q_lora_rank=12, swa_kv_lora_rank=24,
+    swa_qk_nope_head_dim=12, swa_qk_rope_head_dim=4, swa_v_head_dim=8,
+    swa_rope_theta=5e3, index_n_heads=4, index_head_dim=8, index_topk=16)
+STREAMS = dict(hc_mult=2, hc_sinkhorn_iters=20, hc_eps=1e-6,
+               mhc_h_res_clamp_min=-30, mhc_h_res_clamp_max=30)
 #: name -> (model, server options): what does NOT let a chunk ride
 ALTERNATES = {
-    "latent": ({**LATENT, **EXPERTS}, {}),  # served with routed experts only
+    "latent-pattern": ({**ROUTED_LATENT, **PATTERN}, {}),
+    "latent-streams": ({**ROUTED_LATENT, **STREAMS}, {}),
     "routed": ({**DENSE, **EXPERTS}, {}),
     "switch": (dict(DENSE, num_experts=4), {}),
     "hybrid": (HYBRID, {}),
@@ -144,13 +160,17 @@ def _chunks(name: str) -> dict:
 
 @pytest.mark.parametrize("clen", [8, 5], ids=["whole-chunk", "last-chunk"])
 @pytest.mark.parametrize("model_kw,kern", [
-    (DENSE, "gather"), (DENSE, "paged"), (WIDE, "paged")],
-    ids=["gather", "narrow-head-kernel", "wide-head-kernel"])
+    (DENSE, "gather"), (DENSE, "paged"), (WIDE, "paged"),
+    (ROUTED_LATENT, "gather"), (ROUTED_LATENT, "paged")],
+    ids=["gather", "narrow-head-kernel", "wide-head-kernel", "latent-gather",
+         "latent-kernel"])
 def test_fused_step_is_a_decode_step_then_a_chunk(model_kw, kern, clen):
     """``paged_fused_step``'s logits and pools equal ``paged_decode_step``
     then ``paged_prefill_chunk`` on the same inputs — both attention forms,
-    both walks of the kernel, a whole chunk and a prompt's short last one,
-    an idle lane (the prefilling slot's own) among the lanes."""
+    both walks of the kernel, the latent loop through its own, a whole chunk
+    and a prompt's short last one, an idle lane (the prefilling slot's own)
+    among the lanes. A routed model's counters by row range are what the two
+    steps report apart, and the block's what both hit: the loads summed."""
     cfg, params, _ = _model(model_kw)
     lanes, cols, page, c = 3, 6, 4, 8
     rng = np.random.RandomState(clen)
@@ -164,14 +184,27 @@ def test_fused_step_is_a_decode_step_then_a_chunk(model_kw, kern, clen):
     ids = jnp.asarray(rng.randint(0, 128, (1, c)), jnp.int32)
     off, n = jnp.asarray([8], jnp.int32), jnp.asarray([clen], jnp.int32)
     kw = dict(attention_kernel=kern, kernel_interpret=True)
-    dec, k1, v1 = paged_decode_step(params, cfg, tok, lens, act, table, kp, vp,
-                                    return_logits=True, **kw)
-    chunk, k2, v2 = paged_prefill_chunk(params, cfg, ids, off, n, table[2:],
-                                        k1, v1, **kw)
-    got, kf, vf = paged_fused_step(params, cfg, tok, lens, act, table, ids, off,
-                                   n, table[2:], kp, vp, return_logits=True, **kw)
+    step, k1, v1, *lanes_moe = paged_decode_step(
+        params, cfg, tok, lens, act, table, kp, vp, return_logits=True, **kw)
+    chunk, k2, v2, *chunk_moe = paged_prefill_chunk(
+        params, cfg, ids, off, n, table[2:], k1, v1, **kw)
+    got, kf, vf, *moe = paged_fused_step(
+        params, cfg, tok, lens, act, table, ids, off, n, table[2:], kp, vp,
+        return_logits=True, **kw)
     assert got.shape == (lanes + 1, cfg.vocab_size)
-    want = np.concatenate([np.asarray(dec), np.asarray(chunk)])
+    want = np.concatenate([np.asarray(step), np.asarray(chunk)])
+    assert len(moe) == bool(cfg.routed)
+    if moe:
+        by_range = np.asarray(moe[0])
+        np.testing.assert_array_equal(by_range[0], np.asarray(lanes_moe[0]))
+        np.testing.assert_array_equal(by_range[1], np.asarray(chunk_moe[0]))
+        # two active lanes and ``clen`` positions, two experts each, two
+        # expert layers; the block hit no fewer experts than either part and
+        # no more than both, its busiest expert no less than either's
+        pairs, hit, load = by_range.T
+        assert list(pairs) == [8, 4 * clen, 8 + 4 * clen]
+        assert max(hit[:2]) <= hit[2] <= min(hit[0] + hit[1], 16)
+        assert max(load[:2]) <= load[2] <= load[0] + load[1]
     np.testing.assert_allclose(np.asarray(got), want, atol=2e-5, rtol=1e-5)
     assert (np.asarray(got).argmax(-1) == want.argmax(-1)).all()
     # every page but the scratch page, which padding and idle lanes share
@@ -191,6 +224,7 @@ def test_the_program_refuses_what_needs_more_operands(case):
     program refuses the others by name."""
     cfg = get_model("decoder_lm").make_config(**ALTERNATES[case][0])
     assert not fusable(cfg) and fusable(_model(DENSE)[0])
+    assert fusable(_model(ROUTED_LATENT)[0])
     with pytest.raises(ConfigError, match="rides a decode step only"):
         paged_fused_step(None, cfg, *[None] * 10)
 
@@ -199,14 +233,19 @@ def test_the_program_refuses_what_needs_more_operands(case):
 
 
 @pytest.mark.parametrize("depth", [1, 2], ids=["lockstep", "ahead"])
-@pytest.mark.parametrize("case", ["gather", "paged", "tp2"])
+@pytest.mark.parametrize("case", ["gather", "paged", "tp2", "latent",
+                                  "latent-paged"])
 def test_a_fusing_server_serves_what_an_alternating_one_serves(case, depth):
     """Greedy tokens of every request equal those of the same server made
     to alternate, over prompts whose first, middle and last chunks ride —
     in lockstep and one step ahead, through the gather form and the
-    interpreted kernel, and over a 2-device ``tp`` mesh."""
+    interpreted kernel, over a 2-device ``tp`` mesh, and on the latent model
+    with routed experts (whose steps all return ``_FusedLayout``'s array,
+    the alternating ones too)."""
     kw = dict(dispatch_depth=depth, tp=2 if case == "tp2" else 0)
-    if case == "paged":
+    if case.startswith("latent"):
+        kw.update(model_kw=ROUTED_LATENT)
+    if case.endswith("paged"):
         kw.update(decode_kernel="paged", kernel_interpret=True)
     alternating = _server(**kw)
     assert alternating._fuses and alternating._fused is not None
@@ -324,10 +363,11 @@ def test_a_prompt_that_stops_after_prefill_keeps_its_last_chunks_own_step():
 
 @pytest.mark.parametrize("case", sorted(ALTERNATES))
 def test_everything_else_still_alternates(case):
-    """A latent, routed, Switch, hybrid, conv or layered model, a sampling
-    and a speculative server, and one that prefills in one shot: no fused
-    program is built, no step carries a chunk, and the chunks count as
-    issued alone."""
+    """A latent model with a layer pattern or several residual streams, a
+    per-head routed, Switch, hybrid, conv or layered model, a sampling and a
+    speculative server, and one that prefills in one shot: no fused program
+    is built, no step carries a chunk, and the chunks count as issued
+    alone."""
     model_kw, server_kw = ALTERNATES[case]
     name = f"alternates-{case}"
     server_kw = {"prefill_chunk": 8, **server_kw}
@@ -347,14 +387,17 @@ def test_everything_else_still_alternates(case):
 
 
 @pytest.mark.parametrize("depth", [1, 2], ids=["lockstep", "ahead"])
-def test_a_fused_step_takes_one_host_array_and_counts_its_chunk(depth):
+@pytest.mark.parametrize("family", ["dense", "latent"])
+def test_a_fused_step_takes_one_host_array_and_counts_its_chunk(family, depth):
     """``arkflow_gen_step_uploads_total{kind="fused"}`` moves by one a fused
     step (the decode step's operands and the chunk's go up as ONE array;
-    the step before's tokens stay on the device), the array is the two
-    steps' packed arrays end to end, and ``arkflow_gen_chunks_total{mode}``
-    counts every chunk once, by the step that carried it."""
-    name = f"fused-uploads-{depth}"
-    server = _server(name=name, dispatch_depth=depth)
+    the step before's tokens — and a routed prompt's counters so far — stay
+    on the device), the array is the two steps' packed arrays end to end,
+    ``arkflow_gen_chunks_total{mode}`` counts every chunk once, by the step
+    that carried it, and the host fetches one array a step."""
+    name = f"fused-uploads-{family}-{depth}"
+    server = _server(DENSE if family == "dense" else ROUTED_LATENT, name=name,
+                     dispatch_depth=depth)
     fused_steps, host_arrays, sizes = [0], [], set()
     real = server._fused
 
@@ -363,7 +406,10 @@ def test_a_fused_step_takes_one_host_array_and_counts_its_chunk(depth):
         host_arrays.append(sum(isinstance(a, np.ndarray) for a in args))
         assert all(isinstance(a, (np.ndarray, jax.Array)) for a in args)
         sizes.add(args[0].shape)
-        return real(*args)
+        out = real(*args)
+        # tokens (and a routed model's counters) in ONE array, then the pools
+        assert len(out) == 3 and out[0].ndim == 1
+        return out
 
     server._fused = counted
     alone, real_chunk = [0], server._chunk
@@ -387,6 +433,191 @@ def test_a_fused_step_takes_one_host_array_and_counts_its_chunk(depth):
     # the loop's own stages are observed once a device step, fused or not
     if depth == 2:
         assert _counter("arkflow_gen_steps_ahead_total", name, kind="fused") > 0
+
+
+# -- (e) a routed model's counters by the program that ran ----------------------------------
+
+
+def _routing(name: str, kind: str) -> tuple:
+    """(pairs, steps observed, distinct experts summed, largest loads summed)
+    of the routing series ``kind`` of the server ``name``."""
+    reg, labels = global_registry(), {"model": name, "kind": kind}
+    hit = reg.histogram("arkflow_gen_moe_experts_hit", labels=labels)
+    load = reg.histogram("arkflow_gen_moe_max_load", labels=labels)
+    return (_counter("arkflow_gen_moe_assignments_total", name, kind=kind),
+            hit.count, hit.sum, load.sum)
+
+
+@pytest.mark.parametrize("depth", [1, 2], ids=["lockstep", "ahead"])
+def test_routing_counters_go_by_the_program_that_ran(depth):
+    """On a seeded server of the latent model with routed experts (two
+    experts a token, two expert layers: four pairs a token): ``decode`` is
+    the ``_decode`` executions' alone and takes nothing from a fused step;
+    a fused step's lanes go under ``fused_lanes`` and its block — lanes and
+    chunk, what the expert products read — under ``fused``; ``chunk`` is
+    every prompt's chunks, fused or alone, summed on the device and recorded
+    with its first token. Beside an alternating server's the totals agree."""
+    names = {fuses: f"moe-kinds-{depth}-{fuses}" for fuses in (True, False)}
+    seen = {}
+    for fuses, name in names.items():
+        server = _server(ROUTED_LATENT, name=name, dispatch_depth=depth)
+        assert server._fuses and server._lay.size == 3 + 2 + 3 * 3
+        server._fuses = fuses
+        steps, apply, fused = [], server._apply_decode, server._fused
+
+        def applied(act, nxt, reqs=None, seeds=None, rode=0, steps=steps,
+                    apply=apply):
+            steps.append((int(act.sum()), rode))
+            return apply(act, nxt, reqs=reqs, seeds=seeds, rode=rode)
+
+        def issued(packed, *a, steps=steps, fused=fused,
+                   at=3 * (3 + server.pages_per_slot) + 4 + 1):
+            steps.append(("rode", int(np.asarray(packed)[at])))  # its tokens
+            return fused(packed, *a)
+
+        server._apply_decode, server._fused = applied, issued
+        outs, _ = _serve(server)
+        assert [len(o) for o in outs] == BUDGETS
+        seen[fuses] = steps
+    # every token but a request's last goes through a lane once
+    lane_tokens = sum(BUDGETS) - len(BUDGETS)
+    for fuses, name in names.items():
+        lanes = [s for s in seen[fuses] if s[0] != "rode"]
+        rode = sum(n for kind, n in seen[fuses] if kind == "rode")
+        alone = [n for n, r in lanes if not r]
+        fused = [n for n, r in lanes if r]
+        assert bool(fused) == fuses and sum(alone) + sum(fused) == lane_tokens
+        pairs, steps, hit, load = _routing(name, "decode")
+        assert (pairs, steps) == (4 * sum(alone), len(alone))
+        assert 2 * len(alone) <= 2 * hit <= pairs and load <= sum(alone)
+        pairs, steps, *_ = _routing(name, "fused_lanes")
+        assert (pairs, steps) == (4 * sum(fused), len(fused))
+        pairs, steps, hit, load = _routing(name, "fused")
+        assert (pairs, steps) == (4 * (sum(fused) + rode), len(fused))
+        assert fuses == (rode > 0)
+        # a prompt's chunks: every token of every prompt longer than a chunk
+        chunked = [p for p in PROMPTS if len(p) > 4]
+        pairs, steps, *_ = _routing(name, "chunk")
+        assert pairs == 4 * sum(map(len, chunked))
+        assert steps == sum(-(-len(p) // 4) for p in chunked)
+        pairs, steps, *_ = _routing(name, "prefill")
+        assert (pairs, steps) == (4 * sum(len(p) for p in PROMPTS if len(p) <= 4),
+                                  len(PROMPTS) - len(chunked))
+    # fused or not, the same lanes and the same prompts were routed
+    assert (_routing(names[True], "decode")[0] + _routing(names[True], "fused_lanes")[0]
+            == _routing(names[False], "decode")[0])
+    assert _routing(names[True], "chunk") == _routing(names[False], "chunk")
+
+
+def test_a_fused_step_counts_its_expert_product_by_its_block(monkeypatch):
+    """``arkflow_gen_moe_grouped_products_total``: a fused step's expert
+    products took the block's rows (lanes + chunk) and are counted once, under
+    ``fused``, by that row count; a decode step's by the lanes alone."""
+    from arkflow_tpu.ops import moe_experts
+    from arkflow_tpu.tpu.serving import _note_step_moe
+
+    name = "moe-grouped-fused"
+    server = _server(ROUTED_LATENT, name=name)
+    asyncio.run(server.close())
+    monkeypatch.setattr(server, "decode_kernel", "paged")
+    monkeypatch.setattr(moe_experts, "runs_grouped", lambda rows: rows > 5)
+    lay = server._lay
+    nxt = np.arange(lay.size)
+    _note_step_moe(server, nxt, 4)      # 3 lanes + 4 chunk rows: above "a tile"
+    _note_step_moe(server, nxt, 0)      # 3 lanes
+    grouped = {kind: _counter("arkflow_gen_moe_grouped_products_total", name,
+                              kind=kind) for kind in ("fused", "fused_lanes", "decode")}
+    assert grouped == {"fused": server._moe_layers, "fused_lanes": 0, "decode": 0}
+    # the lanes' three and the block's three are read from their own places
+    assert _routing(name, "fused_lanes")[0] == nxt[lay.lanes_at]
+    assert _routing(name, "fused")[0] == nxt[lay.lanes_at + lay.n]
+    assert _routing(name, "decode")[0] == nxt[lay.lanes_at]
+
+
+# -- (f) the programs that must not move -------------------------------------------------------
+
+#: sha256 (first 16 hex) of the jaxprs (source positions stripped, as
+#: ``tests/test_window_gqa_moe.py`` strips them) of the steps this file's
+#: change must leave alone, RECORDED AT ITS PARENT (47611bf): the latent loop
+#: without a riding chunk at a tiny Kanana-2 layout (its one-shot prefill
+#: too), a tiny dots3 layout (sliding and indexed layers, a held share) and a
+#: tiny Xing4.0 layout (four residual streams, YaRN), through the kernels and
+#: through the gather form; and the dense per-head fused step (the Mistral
+#: cells'). The first four ``paged`` hashes are ``BYPASS_GOLDEN``'s.
+PARENT_GOLDEN = {
+    "kanana2.decode.paged": "fb8bb5e87fc3416b", "kanana2.decode.gather": "b709f765129bcb03",
+    "kanana2.chunk.paged": "12309b7328d12d1a", "kanana2.chunk.gather": "328c7e20c9e1bcd0",
+    "kanana2.prefill.paged": "434205b7e2a363a9", "kanana2.prefill.gather": "3a36506ef823537a",
+    "dots3.decode.paged": "095ffd5cfa34a880", "dots3.decode.gather": "d33397f1f3923efa",
+    "dots3.chunk.paged": "d8c6eb810c5ac125", "dots3.chunk.gather": "b001d947ccbce8e8",
+    "xing4.decode.paged": "d336b92619bfd470", "xing4.decode.gather": "60a794ee6db7674d",
+    "xing4.chunk.paged": "8e15ccabd252533b", "xing4.chunk.gather": "ade850137b63adb5",
+    "mistral.fused.paged": "8b603762fe0f95ac", "mistral.fused.gather": "67d47148371a1896"}
+
+_KANANA = dict(vocab_size=64, dim=32, layers=3, heads=4, ffn=48, max_seq=64,
+               kv_lora_rank=16, qk_nope_head_dim=8, qk_rope_head_dim=4,
+               v_head_dim=8, rope_interleave=True, n_routed_experts=8,
+               num_experts_per_tok=2, n_shared_experts=1,
+               moe_intermediate_size=16, first_k_dense_replace=1)
+_LAYOUTS = {
+    "kanana2": _KANANA,
+    "dots3": dict(_KANANA, **dict(PATTERN, layer_types=(FULL, SLIDING, SLIDING, FULL)),
+                  layers=4, experts_held=(4, 2)),
+    "xing4": dict(_KANANA, **dict(STREAMS, hc_mult=4), dim=128, qk_rope_head_dim=8,
+                  q_lora_rank=12, routed_scaling_factor=2.0,
+                  rope_scaling={"type": "yarn", "factor": 64,
+                                "original_max_position_embeddings": 16,
+                                "beta_fast": 32, "beta_slow": 1, "mscale": 1,
+                                "mscale_all_dim": 1}),
+    "mistral": dict(vocab_size=64, dim=256, layers=2, heads=2, kv_heads=1, ffn=48,
+                    max_seq=64)}
+
+
+def _window_goldens():
+    """``tests/test_window_gqa_moe.py``: how a recorded jaxpr is stripped of
+    its source positions and hashed (``BYPASS_GOLDEN``'s way)."""
+    if "window" not in _BUILT:
+        spec = importlib.util.spec_from_file_location(
+            "window_goldens", os.path.join(ROOT, "tests", "test_window_gqa_moe.py"))
+        _BUILT["window"] = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(_BUILT["window"])
+    return _BUILT["window"]
+
+
+@pytest.mark.parametrize("case", sorted(PARENT_GOLDEN))
+def test_programs_without_a_riding_chunk_are_the_parents(case):
+    layout, step, kern = case.split(".")
+    cfg = dec.DecoderConfig(**_LAYOUTS[layout])
+    p = jax.eval_shape(lambda: dec.init(jax.random.PRNGKey(0), cfg))
+    kp, vp = jax.eval_shape(lambda: init_page_pool(cfg, 9, 8, 9 if cfg.layered else 0))
+    kw = dict(attention_kernel=kern, kernel_interpret=False)
+    i32 = jnp.int32
+
+    def tables(rows, step_tokens):
+        kept = jnp.zeros((rows, 4), i32)
+        if not cfg.layered:
+            return kept
+        return kept, jnp.zeros((rows, window_ring_pages(cfg, 8, step_tokens)), i32)
+
+    def lanes():  # made under the trace, as the recorded ones were
+        return jnp.zeros((2,), i32), jnp.ones((2,), i32), jnp.ones((2,), bool)
+
+    def chunk():
+        return jnp.zeros((1, 8), i32), jnp.zeros((1,), i32), jnp.full((1,), 5, i32)
+
+    jaxpr = jax.make_jaxpr({
+        "decode": lambda p, k, v: paged_decode_step(
+            p, cfg, *lanes(), tables(2, 1), k, v, **kw),
+        "chunk": lambda p, k, v: paged_prefill_chunk(
+            p, cfg, *chunk(), tables(1, 8), k, v, **kw),
+        "prefill": lambda p, k, v: paged_prefill(
+            p, cfg, jnp.zeros((1, 8), i32), jnp.full((1,), 5, i32), tables(1, 8),
+            k, v, **kw),
+        "fused": lambda p, k, v: paged_fused_step(
+            p, cfg, *lanes(), tables(2, 1), *chunk(), tables(1, 8), k, v, **kw),
+    }[step])(p, kp, vp)
+    got = _window_goldens()._text_hash(_window_goldens()._jaxpr_text(jaxpr))
+    assert got == PARENT_GOLDEN[case], got
 
 
 # -- the benchmark's three readers -------------------------------------------------------------

@@ -193,10 +193,11 @@ def test_running_ahead_serves_what_lockstep_serves(model_kw, server_kw):
     added = {k: v - before[k] for k, v in _ahead_counts(name).items()}
     assert added == ran_ahead
     assert server._steps_ahead == sum(ran_ahead.values()) > 0
-    # a dense greedy server that prefills in chunks lets them ride its decode
-    # steps, through one program too; a latent routed model's, and those of
-    # a model with a state a slot, alternate
-    fuses = model_kw is DENSE and server_kw.get("prefill_chunk", 4) > 0
+    # a greedy server that prefills in chunks lets them ride its decode
+    # steps, through one program too, on a dense model and on a latent
+    # routed one; those of a model with a state a slot alternate
+    fuses = (model_kw is DENSE or model_kw is ROUTED) and server_kw.get(
+        "prefill_chunk", 4) > 0
     assert server._fuses == fuses and (ran_ahead["fused"] > 0) == fuses
     assert server._fused is None or server._fused.jitted._cache_size() == 1
     # most steps find the queue occupied: the rest are cold (each program's

@@ -100,7 +100,7 @@ def _serve(server, new=6):
     return asyncio.run(go())
 
 
-KINDS = ("decode", "chunk", "prefill", "verify")
+KINDS = ("decode", "chunk", "prefill", "verify", "fused")
 
 
 def _counts(name):
@@ -170,21 +170,24 @@ def test_served_tokens_are_the_parents(case):
 
 
 @pytest.mark.parametrize("model_kw,server_kw,kinds", [
-    (DENSE, dict(prefill_chunk=4), {"decode", "chunk", "prefill"}),
+    (DENSE, dict(prefill_chunk=4), {"decode", "chunk", "prefill", "fused"}),
     (DENSE, dict(prefill_chunk=4, dispatch_depth=2),
-     {"decode", "chunk", "prefill"}),
+     {"decode", "chunk", "prefill", "fused"}),
     (DENSE, dict(prefill_chunk=4, speculative_tokens=2),
      {"verify", "chunk", "prefill"}),
     (DENSE, dict(prefill_chunk=4, **SAMPLE), {"decode", "chunk", "prefill"}),
-    (ROUTED, dict(prefill_chunk=8), {"decode", "chunk", "prefill"}),
+    (ROUTED, dict(prefill_chunk=8), {"decode", "fused", "prefill"}),
     (ROUTED, dict(prefill_chunk=8, **SAMPLE), {"decode", "chunk", "prefill"}),
 ], ids=["dense", "depth2", "speculative", "sampled", "routed",
         "routed-sampled"])
 def test_every_step_hands_the_device_one_host_array(model_kw, server_kw, kinds):
     """``arkflow_gen_step_uploads_total{kind}`` over the steps of that kind
     is 1.0 for every kind that ran — a routed prompt's first chunk (whose
-    counters start from a device constant) and a depth-2 step fed by the
-    step before included; everything else a step takes is on the device."""
+    counters start from a device constant), a depth-2 step fed by the step
+    before and a decode step that carries a chunk (a greedy server's chunks
+    ride; a latent routed model's all do here, its counters so far handed on
+    on the device) included; everything else a step takes is on the
+    device."""
     name = "uploads-" + "-".join(f"{k}{v}" for k, v in sorted(server_kw.items()))
     name += "-routed" if model_kw is ROUTED else ""
     server = _server(model_kw, server_kw, 0, name=name)
@@ -247,7 +250,10 @@ def test_sampling_server_splits_inside_its_programs(monkeypatch):
 def test_step_tokens_reach_the_loop_as_numpy():
     """The fetch happens inside the executor hop: the coroutine resumes with
     a host array (``gen_apply`` makes no device call); a prompt's chunk
-    before its last leaves its array on the device."""
+    before its last leaves its array on the device — and so does the
+    lockstep decode step that carries a routed prompt's chunk: the prompt's
+    counters ride on in that array, and ``_step`` reads the step's tokens
+    out of it (the array is ready: a copy, stage ``gen_fetch``)."""
     server = _server(ROUTED, dict(prefill_chunk=8), 0)
     seen = []
     run = server._run_device_step
@@ -259,7 +265,8 @@ def test_step_tokens_reach_the_loop_as_numpy():
 
     server._run_device_step = spying
     _serve(server)
-    assert {k for k, _, _ in seen} == {"decode", "chunk", "prefill"}
+    assert {k for k, _, _ in seen} == {"decode", "fused", "prefill"}
+    assert {final for k, final, _ in seen if k == "fused"} == {False}
     for kind, final, typ in seen:
         assert issubclass(typ, np.ndarray if final else jax.Array), (kind, final)
     assert any(not final for _, final, _ in seen)

@@ -501,6 +501,51 @@ def test_grouped_expert_product_compiles(v5e, tokens, layers, experts, dim, widt
     _no_copy_of_a_layer(compiled, layer_bytes, grouped=True)
 
 
+def test_a_latent_fused_step_compiles_at_kanana2_widths(v5e):
+    """``kanana2_l6``'s ``_fused`` program — 16 lanes and a 128-token chunk,
+    ONE block of 144 rows through six layers at the published widths, the
+    pools donated — compiled for the described chip: the latent kernel twice
+    a layer (the two steps' own calls), the expert product ONCE a layer and
+    grouped (144 rows are above one token tile), no pool and no layer's
+    experts copied, and the counters by row range beside the logits."""
+    from arkflow_tpu.models import decoder as dec
+    from arkflow_tpu.models.paged_decode import init_page_pool, paged_fused_step
+
+    cfg = dec.DecoderConfig(
+        vocab_size=128256, dim=KANANA_DIM, layers=6, heads=KANANA_HEADS, ffn=6144,
+        max_seq=32768, rope_theta=1e6, norm_eps=1e-6, kv_lora_rank=KANANA_LATENT,
+        qk_nope_head_dim=128, qk_rope_head_dim=KANANA_ROPE, v_head_dim=128,
+        rope_interleave=True, n_routed_experts=128, num_experts_per_tok=6,
+        n_shared_experts=2, moe_intermediate_size=KANANA_WIDTH,
+        first_k_dense_replace=1, routed_scaling_factor=2.448)
+    chip = SingleDeviceSharding(v5e[0])
+
+    def struct(a, dtype=None):
+        return jax.ShapeDtypeStruct(a.shape, dtype or a.dtype, sharding=chip)
+
+    params = jax.tree_util.tree_map(
+        struct, jax.eval_shape(lambda: dec.init(jax.random.PRNGKey(0), cfg)),
+        dec.serve_dtypes(cfg))               # as the server places them
+    kp, vp = jax.tree_util.tree_map(struct, jax.eval_shape(
+        lambda: init_page_pool(cfg, MISTRAL_PAGES, PAGE)))
+    s, table = MISTRAL_SLOTS, MISTRAL_TABLE
+    operands = [jax.ShapeDtypeStruct(shape, dtype, sharding=chip) for shape, dtype in (
+        ((s,), I32), ((s,), I32), ((s,), jnp.bool_), ((s, table), I32),
+        ((1, 128), I32), ((1,), I32), ((1,), I32), ((1, table), I32))]
+    def step(p, *a):
+        return paged_fused_step(p, cfg, *a, return_logits=True,
+                                attention_kernel="paged")
+
+    compiled = jax.jit(step, donate_argnums=(9, 10)).lower(
+        params, *operands, kp, vp).compile()
+    text = compiled.as_text()
+    assert "moe_expert_grouped" in text and "mla_paged_attention" in text
+    logits, _, _, counters = jax.eval_shape(step, params, *operands, kp, vp)
+    assert logits.shape == (s + 1, 128256) and counters.shape == (3, 3)
+    # a pool is 0.2 GB, a layer's experts 1.2 GB: neither is copied
+    assert compiled.memory_analysis().temp_size_in_bytes < LATENT_TEMP_LIMIT
+
+
 # -- a layer pattern's kernels (dots3-note-prev widths, page 16, chunk 512) ---
 
 DOTS_DIM, DOTS_WIDTH, DOTS_HELD = 5120, 1536, 33      # 32 held + 1 shared

@@ -435,8 +435,9 @@ def test_server_counters_equal_a_hand_count():
     load are recomputed from the reference's routing of the same tokens."""
     proc = _proc()
     server = proc._server
+    kinds = ("decode", "chunk", "prefill", "fused", "fused_lanes")
     before = {k: (m[0].value, m[1].count, m[1].sum, m[2].count)
-              for k in ("decode", "chunk", "prefill") for m in [_moe_metric(k)]}
+              for k in kinds for m in [_moe_metric(k)]}
     prompts = [np.random.RandomState(s).randint(1, 128, n).tolist()
                for s, n in ((1, 20), (2, 9))]
 
@@ -447,12 +448,18 @@ def test_server_counters_equal_a_hand_count():
     assert [len(o) for o in outs] == [4, 4]
     delta = {k: (m[0].value - before[k][0], m[1].count - before[k][1],
                  m[1].sum - before[k][2], m[2].count - before[k][3])
-             for k in ("decode", "chunk", "prefill") for m in [_moe_metric(k)]}
+             for k in kinds for m in [_moe_metric(k)]}
     assert delta["chunk"][:2] == (20 * 2 * 2, 2)      # 16 + 4 tokens, 2 chunks
     assert delta["prefill"][:2] == (9 * 2 * 2, 1)
     # 3 decode steps a request (the first token comes from prefill); lanes
-    # decode together when both are live, so count pairs, not steps
-    assert delta["decode"][0] == 2 * 3 * 2 * 2
+    # decode together when both are live, so count pairs, not steps. The
+    # chunked prompt's two chunks rode the other request's first two decode
+    # steps (PR 56): those steps' one lane counts under ``fused_lanes``,
+    # their blocks — the lane and the chunk's 16 and 4 tokens — under
+    # ``fused``, and ``decode`` takes none of either
+    assert delta["fused_lanes"][:2] == (2 * 1 * 2 * 2, 2)
+    assert delta["fused"][:2] == ((1 + 16 + 1 + 4) * 2 * 2, 2)
+    assert delta["decode"][0] + delta["fused_lanes"][0] == 2 * 3 * 2 * 2
     assert delta["decode"][1] == delta["decode"][3] >= 3
     # the one-shot prefill's distinct experts: the reference's routing
     hp = ref.hyper(proc.cfg)

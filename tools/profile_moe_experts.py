@@ -20,6 +20,12 @@ at weight 1. One JSON line a point:
 
     python tools/profile_moe_experts.py
     python tools/profile_moe_experts.py --cell kexaone_l5 --root /path/to/parent
+    python tools/profile_moe_experts.py --cell kanana2_l6 --points 16:62,128:42,144:78
+
+``--points rows:hit,...`` times the cell's widths at row counts of one's own
+under a router held to ``hit`` of the experts (a block of a fused step: 16
+lanes and a 128-token chunk are 144 rows, above one token tile, and hit what
+either part hits), in place of ``--rows``.
 
 ``--root`` imports ``arkflow_tpu`` from another checkout (a parent commit
 unpacked beside this one): run both in one call to compare on the same chip.
@@ -52,6 +58,7 @@ def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--cell", default="all", choices=["all", *CELLS])
     ap.add_argument("--rows", default="chunk", choices=["chunk", "decode", "both"])
+    ap.add_argument("--points", default="", help="rows:hit,... (in place of --rows)")
     ap.add_argument("--root", default=HERE, help="checkout to import arkflow_tpu from")
     ap.add_argument("--reps", type=int, default=8)
     ap.add_argument("--rounds", type=int, default=4)
@@ -81,13 +88,20 @@ def main() -> int:
         if args.interpret:
             d, f = 64, 128
         e = held + shared
-        keys = iter(jax.random.split(jax.random.PRNGKey(args.seed), 8))
+        keys = iter(jax.random.split(jax.random.PRNGKey(args.seed), 64))
         wg, wu = (jax.random.normal(next(keys), (2, e, d, f), jnp.bfloat16) / 8
                   for _ in "gu")
         wd = jax.random.normal(next(keys), (2, e, f, d), jnp.bfloat16) / 16
-        for t in {"chunk": [chunk], "decode": [lanes], "both": [lanes, chunk]}[args.rows]:
-            x = jax.random.normal(next(keys), (t, d), jnp.bfloat16)
-            chosen = jnp.argsort(jax.random.uniform(next(keys), (t, published)),
+        points = [(t, published) for t in {
+            "chunk": [chunk], "decode": [lanes], "both": [lanes, chunk]}[args.rows]]
+        if args.points:
+            points = [tuple(map(int, p.split(":"))) for p in args.points.split(",")]
+        for t, among in points:
+            x = jax.random.normal(jax.random.fold_in(next(keys), t), (t, d), jnp.bfloat16)
+            # a uniform router over the first ``among`` experts
+            draw = jax.random.uniform(jax.random.fold_in(next(keys), among),
+                                      (t, published))
+            chosen = jnp.argsort(jnp.where(jnp.arange(published) < among, draw, 2.0),
                                  axis=-1)[:, :k]
             cw = jax.nn.one_hot(chosen, published, dtype=jnp.float32).sum(1)[:, :held] / k
             cw = jnp.concatenate([cw, jnp.ones((t, shared))], axis=-1)
