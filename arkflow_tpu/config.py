@@ -388,6 +388,20 @@ def _validate_inference_mesh(processors: list[dict]) -> None:
         refuse_pp_serving(p)
 
 
+def refuse_continuous_split(axes: Mapping) -> None:
+    """``serving: continuous`` shards TENSOR-PARALLEL only: refuse a mesh
+    whose ``dp`` or ``sp`` is above 1. The one place that says so — here for
+    ``--validate`` (no jax), and called by the processor before its host
+    init and by the server for a mesh it is handed."""
+    for axis in ("dp", "sp"):
+        if int(axes.get(axis, 1)) > 1:
+            raise ConfigError(
+                f"tpu_generate: serving: continuous + mesh {axis} > 1 is "
+                "unsupported — continuous serving shards tensor-parallel "
+                "only: the lockstep slot grid does not batch-split; shard tp "
+                "(mesh: {tp: N}) or use serving: batch / tpu_inference for dp")
+
+
 def _validate_generate_mesh(processors: list[dict]) -> None:
     """Parse-time checks for multi-chip ``tpu_generate`` serving, looking
     through ``fault.inner`` chaos wrappers like the other cross-checks:
@@ -420,13 +434,7 @@ def _validate_generate_mesh(processors: list[dict]) -> None:
             axes[k] = v
         if str(p.get("serving", "batch")) != "continuous":
             continue
-        for axis in ("dp", "sp"):
-            if axes[axis] > 1:
-                raise ConfigError(
-                    f"tpu_generate: serving: continuous + mesh {axis} > 1 is "
-                    "unsupported — the lockstep slot grid does not "
-                    "batch-split; shard tp (mesh: {tp: N}) or use serving: "
-                    "batch / tpu_inference for dp")
+        refuse_continuous_split(axes)
         tp = axes["tp"]
         if tp > 1:
             mc = p.get("model_config")

@@ -2479,44 +2479,6 @@ def _serve_dtypes_runs(cfg: DecoderConfig) -> dict:
     return out
 
 
-def _no_latent(cfg: DecoderConfig, what: str) -> None:
-    """The paths that know only per-head K/V kept for a request's life
-    refuse a latent model, a model that carries a recurrent state beside
-    them, and a per-head model with routed experts or a layer pattern."""
-    if cfg.eva:
-        from arkflow_tpu.errors import ConfigError
-
-        raise ConfigError(
-            f"{what} keeps a row a position: attention_class 'eva' (a window "
-            "that is compacted into chunk summaries when it closes) generates "
-            "through serving: continuous (the paged pool, by cached length)")
-    if cfg.by_runs and not cfg.latent:
-        from arkflow_tpu.errors import ConfigError
-
-        raise ConfigError(
-            f"{what} runs one stack of identical dense layers over a "
-            "contiguous cache: a per-head K/V model with routed experts "
-            "(n_routed_experts), a layer pattern (layer_types: window "
-            "pages beside kept pages), qk_norm, or head sizes by kind "
-            "(swa_kv_heads, v_head_dim, partial_rotary_factor, "
-            "attention_value_scale, a sink, an output gate), or conv layers "
-            "or linear_attention layers among its attention layers (a state "
-            "a slot beside the K/V pages) generates through serving: continuous")
-    if cfg.hybrid:
-        from arkflow_tpu.errors import ConfigError
-
-        raise ConfigError(
-            f"{what} does not carry a recurrent state: a model with the "
-            "hybrid block (mamba_d_ssm > 0) generates through serving: "
-            "continuous (the state pool beside the K/V pages)")
-    if cfg.latent:
-        from arkflow_tpu.errors import ConfigError
-
-        raise ConfigError(
-            f"{what} does not carry a latent (MLA) cache: a latent-attention "
-            "model generates through serving: continuous (the paged pool)")
-
-
 def from_hf_state_dict(state: dict, cfg: DecoderConfig) -> dict:
     """Convert a HuggingFace ``LlamaForCausalLM`` state_dict (torch tensors —
     any dtype including bfloat16 — or numpy arrays) into this model's param
@@ -2582,7 +2544,11 @@ def init_kv_cache(cfg: DecoderConfig, batch: int, max_len: int) -> dict:
       attention forever).
     - ``prompt_len``: width of the prefilled prompt block (0 = pure stepwise).
     """
-    _no_latent(cfg, "the contiguous KV cache (serving: batch)")
+    # the contiguous cache knows per-head K/V of one width, kept for a
+    # request's life (``paged_decode`` imports this module, not the reverse)
+    from arkflow_tpu.models.paged_decode import refuse
+
+    refuse(cfg, "batch")
     dh = cfg.dh
     shape = (cfg.layers, batch, max_len, cfg.kv_heads, dh)
     return {

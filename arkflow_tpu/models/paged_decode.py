@@ -50,6 +50,7 @@ import jax.numpy as jnp
 import dataclasses
 from typing import NamedTuple, Optional
 
+from arkflow_tpu.errors import ConfigError
 from arkflow_tpu.models import common as cm
 from dataclasses import dataclass
 
@@ -216,6 +217,277 @@ def cache_spec(cfg: DecoderConfig) -> tuple:
                             _held_lanes(cfg.swa_qk_rope_head_dim)),
             cfg.sliding_window))
     return tuple(pools)
+
+
+# -- what each kind of cache is served with ----------------------------------
+#
+# ``UNSERVED``: a row for every kind of pool ``cache_spec`` names and for
+# every trait of a configuration that is not a pool's; a column for every
+# serving feature; a cell is the sentence that says why the row is not served
+# with the feature YET, and is left out where it is. Whoever turns a feature
+# on asks ``unserved`` / ``refuse``, once; nothing else decides by kind. A new
+# kind states its row here; a capability is added by taking a cell out, with
+# its tests. The features:
+#
+# - ``mesh_tp``: the pools sharded over KV heads on a mesh's ``tp`` axis;
+# - ``prefix_cache``: finished prompts' full pages aliased (``prefix_cache_pages``);
+# - ``speculation``: drafts verified in one chunk call (``speculative_tokens``);
+# - ``one_shot_prefill``: a prompt in one block (no ``prefill_chunk``);
+# - ``kv_push``: pages exported / adopted (``prefill_export``,
+#   ``generate_from_pages``: prefill and decode on different servers);
+# - ``batch``: the contiguous cache of ``decoder.init_kv_cache``
+#   (``serving: batch``);
+# - ``swap`` / ``integrity``: the processor's hot swap and its golden probes;
+# - ``fused_chunk``: a prompt's chunk rides a decode step (``paged_fused_step``);
+# - ``run_ahead``: a step is enqueued before the one before it is applied, so
+#   a prompt's lane joins decode a step later; ``run_ahead_eos``: the same
+#   under a live ``eos_id``, where a lane that ended on an EOS rides one step
+#   more (the server's own halves — greedy, depth, no speculation — are the
+#   server's: ``GenerationServer._ahead`` / ``_fuses``).
+#
+# ``{pools}`` is the configuration's pools by name, ``{hc}`` its ``hc_mult``.
+
+# the swap canary and the integrity golden run the family's batch forward
+# against per-head-cache assumptions that were never checked for a model
+# that stacks by runs: the key is refused and neither is attached
+_UNVERIFIED = dict.fromkeys(("swap", "integrity"), (
+    "is not supported for a latent-attention model, nor for a per-head K/V "
+    "model with routed experts, a layer pattern or head sizes by kind, yet "
+    "(its drain / flip / pool reset and golden forward are unverified for "
+    "latent and window pages); remove the key"))
+_BY_RUNS = {
+    # a per-head K/V model whose tree stacks by runs (``cfg.by_runs``)
+    **_UNVERIFIED,
+    "mesh_tp": (
+        "a per-head K/V model with routed experts, a layer pattern or head "
+        "sizes by kind (pools {pools}) is served on one chip: its expert "
+        "stack, its window pool and its stacks by kind have no sharding over "
+        "a mesh yet (remove mesh)"),
+    "batch": (
+        "the contiguous KV cache (serving: batch) runs one stack of "
+        "identical dense layers over keys and values of one width: a "
+        "per-head K/V model with routed experts (n_routed_experts), a layer "
+        "pattern (layer_types: window pages beside kept pages), qk_norm, or "
+        "head sizes by kind (swa_kv_heads, v_head_dim, "
+        "partial_rotary_factor, attention_value_scale, a sink, an output "
+        "gate) generates through serving: continuous only"),
+}
+_FUSED = (
+    "a chunk rides a decode step only on a per-head K/V model with a dense "
+    "MLP or a plain latent-attention model with routed experts (pools "
+    "{pools}): ")
+_PATTERN = {
+    # sliding or indexed layers: rows of more than one kind and lifetime
+    "speculation": (
+        "speculative_tokens does not compose with indexed or sliding layers "
+        "(pools {pools}): a rejected draft leaves its index key behind, "
+        "which a later query's indexer may select, and the verify step does "
+        "not slide the window pool"),
+    "one_shot_prefill": (
+        "a model with a layer pattern (sliding or indexed layers: pools "
+        "{pools}) prefills in chunks through the cache: set prefill_chunk > "
+        "0 (its size bounds a slot's window pages; the one-shot prefill "
+        "attends over its own block under one mask)"),
+    "kv_push": (
+        "ships the pages of ONE kept pool; a layer pattern's pools ({pools}) "
+        "have no wire form yet — the window pool's live pages and their "
+        "ring would have to ship beside the kept pages — so such a model "
+        "prefills and decodes on the same server"),
+    "fused_chunk": _FUSED + (
+        "a sliding layer wants its ring coordinates, an indexed layer its "
+        "choice a query, and the block carries neither"),
+}
+_WINDOW = {
+    **_PATTERN,
+    "prefix_cache": (
+        "prefix_cache_pages does not compose with window pages (pools "
+        "{pools}): a sliding layer's rows are freed as the window passes, so "
+        "a finished prompt has no full pages of them to donate"),
+}
+_LATENT = {
+    **_UNVERIFIED,
+    "mesh_tp": (
+        "continuous serving shards the KV pools over KV heads on the tp "
+        "axis; a latent (MLA) pool has one shared row per token and no head "
+        "axis to split — serve a latent-attention model on one chip (no "
+        "mesh)"),
+    "kv_push": (
+        "ships per-head K/V page slabs split along the kv_heads axis; a "
+        "latent (MLA) page has no head axis and no wire format yet — a "
+        "latent-attention model prefills and decodes on the same server"),
+    "batch": (
+        "the contiguous KV cache (serving: batch) holds per-head K/V and "
+        "carries no latent (MLA) cache: a latent-attention model "
+        "(kv_lora_rank > 0) generates through serving: continuous only (the "
+        "paged pool)"),
+}
+_STATE = {
+    # a state a SEQUENCE: overwritten by every token, so what is benign for
+    # K/V rows (a stale row, an aliased page, a lane that rides one step too
+    # long) is not for it
+    "mesh_tp": (
+        "a model with the hybrid block (mamba_d_ssm > 0), conv or "
+        "linear_attention layers (pools {pools}) is served on one chip: the "
+        "state pool and the mixer's channels have no sharding over a mesh "
+        "yet (remove mesh)"),
+    "prefix_cache": (
+        "prefix_cache_pages does not compose with a recurrent state (pools "
+        "{pools}): aliased pages skip the very tokens whose state the rest "
+        "of the prompt needs, and no state snapshot is kept beside a cached "
+        "prefix yet"),
+    "speculation": (
+        "speculative_tokens does not compose with a recurrent state (pools "
+        "{pools}): a rejected draft has already advanced the state (for K/V "
+        "it only leaves a stale row), and there is no rollback yet"),
+    "one_shot_prefill": (
+        "a model that carries a recurrent state (pools {pools}) prefills in "
+        "chunks through the cache: set prefill_chunk > 0 (the chunk's "
+        "program is the one that is told its slot's row of the state pool "
+        "and resets it)"),
+    "kv_push": (
+        "ships K/V page slabs; a recurrent state (pools {pools}) has no wire "
+        "form yet, and pages without it cannot be decoded from — a model "
+        "with the hybrid block, conv or linear_attention layers prefills and "
+        "decodes on the same server"),
+    "batch": (
+        "the contiguous KV cache (serving: batch) carries no recurrent "
+        "state: a model with the hybrid block (mamba_d_ssm > 0), conv layers "
+        "or linear_attention layers (pools {pools}: a state a slot beside "
+        "the K/V pages) generates through serving: continuous only"),
+    "fused_chunk": _FUSED + (
+        "a state a sequence wants its row of state slots, which the block "
+        "does not carry"),
+    "run_ahead_eos": (
+        "a lane that ended on an EOS rides the step behind, which would "
+        "advance its state past its sequence's end (for K/V it only leaves "
+        "a stale row): under a live eos_id such a model serves in lockstep"),
+}
+# conv and linear_attention layers stand AMONG the attention layers: by runs
+_STATE_BY_RUNS = {**_STATE, **_UNVERIFIED}
+_SWITCH = (
+    "the Switch layer queues a step's live lanes into shared expert "
+    "capacity, so a lane that joins a step later, or a chunk beside the "
+    "lanes, changes its neighbours' tokens")
+
+#: row -> {feature: why not}. ``unserved`` reads the rows in THIS order, the
+#: more particular reason first
+UNSERVED = {
+    "streams": {
+        "mesh_tp": (
+            "hc_mult {hc} (several residual streams) is served on one chip: "
+            "neither the streams' mixing nor the latent pools have a "
+            "sharding over a tp mesh yet (remove mesh)"),
+        "prefix_cache": (
+            "prefix_cache_pages does not compose with hc_mult {hc} yet: the "
+            "prefix cache over latent pools has not been held to the "
+            "streams' reference"),
+        "speculation": (
+            "speculative_tokens does not compose with hc_mult {hc} (several "
+            "residual streams over latent pools): the verify step (_verify) "
+            "has not been held to the streams' reference"),
+        "fused_chunk": _FUSED + (
+            "the streams' mixing kernels want row tiles the block does not "
+            "keep (hc_mult {hc})"),
+    },
+    "kv": {},
+    "kv_window": {**_BY_RUNS, **_WINDOW},
+    "latent": _LATENT,
+    "index": _PATTERN,    # beside ``latent`` only, which says the rest
+    "window": _WINDOW,
+    "ssm": _STATE,
+    "conv": _STATE_BY_RUNS,
+    "gdn": _STATE_BY_RUNS,
+    "eva": {
+        **_BY_RUNS,
+        "mesh_tp": (
+            "attention_class 'eva' is served on one chip: the window close "
+            "and the summary pages have no sharding over a mesh yet (remove "
+            "mesh)"),
+        "prefix_cache": (
+            "prefix_cache_pages does not compose with attention_class 'eva': "
+            "a closed window's pages are pooled in place and handed back, so "
+            "a finished prompt has no pages by position to donate"),
+        "speculation": (
+            "speculative_tokens does not compose with attention_class 'eva': "
+            "a verify step that crosses a window's end would pool rejected "
+            "drafts into the summaries, and there is no rollback yet"),
+        "one_shot_prefill": (
+            "a compacting window cache (attention_class 'eva': pool {pools}) "
+            "prefills in chunks through the cache: set prefill_chunk > 0 to "
+            "a divisor of window_size (a chunk never straddles a window's "
+            "end; the one-shot prefill attends over its own block and closes "
+            "no window)"),
+        "kv_push": (
+            "ships a prompt's pages by position; a compacting window cache "
+            "(pool {pools}) holds summary pages and an open window, which "
+            "have no wire form yet — a model with attention_class 'eva' "
+            "prefills and decodes on the same server"),
+        "batch": (
+            "the contiguous KV cache (serving: batch) keeps a row a "
+            "position: attention_class 'eva' (a window that is compacted "
+            "into chunk summaries when it closes) generates through serving: "
+            "continuous only (the paged pool, by cached length)"),
+        "fused_chunk": _FUSED + (
+            "a compacting window cache (attention_class 'eva') wants to be "
+            "told which rows close a window"),
+    },
+    "hetero": {
+        **_BY_RUNS,
+        "kv_push": (
+            "ships K and V page slabs of one shape; this model's pools "
+            "({pools}) hold keys and values of different widths (v_head_dim; "
+            "a key held in parts) or sizes by kind, which have no wire form "
+            "yet — such a model prefills and decodes on the same server"),
+    },
+    "routed": {
+        **_BY_RUNS,
+        "fused_chunk": _FUSED + (
+            "no served model has routed experts beside per-head K/V without "
+            "a layer pattern or a state beside them, so nothing could show a "
+            "gain"),
+    },
+    "qk_norm": _BY_RUNS,
+    "switch": {"fused_chunk": _FUSED + _SWITCH, "run_ahead": _SWITCH,
+               "run_ahead_eos": _SWITCH},
+}
+FEATURES = ("mesh_tp", "prefix_cache", "speculation", "one_shot_prefill",
+            "kv_push", "batch", "swap", "integrity", "fused_chunk",
+            "run_ahead", "run_ahead_eos")
+
+
+def cache_rows(cfg: DecoderConfig) -> tuple:
+    """The rows of ``UNSERVED`` a configuration has, in the table's order:
+    its pools (``cache_spec``) and the traits that are not a pool's —
+    ``streams``: several residual streams (``hc_mult`` > 1); ``hetero``: keys
+    and values of different widths, or sizes by kind; ``routed``: an expert
+    stack on the PER-HEAD loop (a latent model always has one); ``qk_norm``:
+    per-head norms (the tree stacks by runs for them alone); ``switch``: the
+    capacity-based Switch layer (``num_experts``)."""
+    traits = dict(streams=cfg.hc_mult > 1, hetero=cfg.hetero,
+                  routed=cfg.routed and not cfg.latent, qk_norm=cfg.qk_norm,
+                  switch=cfg.num_experts > 1)
+    pools = {pool.name for pool in cache_spec(cfg)}
+    return tuple(row for row in UNSERVED if row in pools or traits.get(row))
+
+
+def unserved(cfg: DecoderConfig, feature: str) -> Optional[str]:
+    """Why the configuration is not served with ``feature`` (``FEATURES``):
+    the first reason among its rows, or None where it is served."""
+    for row in cache_rows(cfg):
+        why = UNSERVED[row].get(feature)
+        if why is not None:
+            return why.format(hc=cfg.hc_mult, pools=", ".join(
+                pool.name for pool in cache_spec(cfg)))
+    return None
+
+
+def refuse(cfg: DecoderConfig, feature: str, who: str = "") -> None:
+    """Raise ``unserved``'s reason as a ConfigError, ``who`` — the caller by
+    the name its user knows, where the sentence is about the caller — in
+    front."""
+    why = unserved(cfg, feature)
+    if why is not None:
+        raise ConfigError(f"{who} {why}" if who else why)
 
 
 def init_page_pool(cfg: DecoderConfig, num_pages: int, page_size: int,
@@ -1531,30 +1803,7 @@ def paged_prefill(params: dict, cfg: DecoderConfig, input_ids, lengths,
     paths read later; ``attention_kernel`` picks only its expert product.
     A routed model's step returns its routing counters as a fourth value.
     """
-    if cfg.eva:
-        from arkflow_tpu.errors import ConfigError
-
-        raise ConfigError(
-            "a compacting window cache (attention_class 'eva': pool eva) "
-            "prefills in chunks through the cache (prefill_chunk > 0, a "
-            "divisor of window_size): the one-shot prefill attends over its "
-            "own block and closes no window")
-    if cfg.stateful:
-        from arkflow_tpu.errors import ConfigError
-
-        raise ConfigError(
-            "a model that caches a state a sequence (pools "
-            f"{', '.join(pool.name for pool in cache_spec(cfg))}) prefills "
-            "in chunks through the cache (prefill_chunk > 0): the chunk's "
-            "program is the one that is told its slot's row of the state pool")
-    if cfg.layered:
-        from arkflow_tpu.errors import ConfigError
-
-        raise ConfigError(
-            "a model with a layer pattern (sliding or indexed layers: "
-            f"pools {', '.join(pool.name for pool in cache_spec(cfg))}) "
-            "prefills in chunks through the cache (prefill_chunk > 0): the "
-            "one-shot prefill attends over its own block under one mask")
+    refuse(cfg, "one_shot_prefill")
     b, t = input_ids.shape
     page = k_pages.shape[2]
     positions = jnp.broadcast_to(jnp.arange(t)[None, :], (b, t))
@@ -1784,19 +2033,10 @@ def fusable(cfg: DecoderConfig) -> bool:
       ``chunk``: dropless and per token, so the block's rows route as they
       do apart; the counters come back by row range).
 
-    Left out, each for an operand or a rule of its own that the block does
-    not carry yet: a layer pattern on either loop (a sliding layer's ring
-    coordinates, an indexed layer's choice a query), several residual
-    streams (``hc_mult`` > 1: the mixing kernels' row tiles), a state a
-    sequence (a row of state slots), a compacting window cache (which rows
-    close), the Switch layer (a step's tokens share expert capacity), and
-    routed experts on the per-head loop (no served model has them without
-    one of the above beside them, so nothing could show a gain)."""
-    if cfg.num_experts > 1 or cfg.layered:
-        return False
-    if cfg.latent:
-        return bool(cfg.routed) and cfg.hc_mult == 1
-    return not (cfg.routed or cfg.stateful or cfg.eva)
+    Every other row of ``UNSERVED`` is left out, for an operand or a rule of
+    its own that the block does not carry yet: its ``fused_chunk`` cell says
+    which."""
+    return unserved(cfg, "fused_chunk") is None
 
 
 def paged_fused_step(params: dict, cfg: DecoderConfig, token_ids, lengths,
@@ -1825,18 +2065,7 @@ def paged_fused_step(params: dict, cfg: DecoderConfig, token_ids, lengths,
     its last. A routed model's step returns its routing counters as a fourth
     value, by row range [3, ...] (``moe_step_stats`` of each): the lanes',
     the chunk's, the block's. Only for a ``fusable`` model."""
-    if not fusable(cfg):
-        from arkflow_tpu.errors import ConfigError
-
-        raise ConfigError(
-            "a chunk rides a decode step only on a per-head K/V model with a "
-            "dense MLP or a plain latent-attention model with routed "
-            "experts: a layer pattern (sliding or indexed layers), several "
-            "residual streams (hc_mult > 1), a state a sequence, a "
-            "compacting window cache (attention_class 'eva'), the Switch "
-            "layer and routed experts beside per-head K/V each want an "
-            "operand the block does not carry (pools "
-            f"{', '.join(pool.name for pool in cache_spec(cfg))})")
+    refuse(cfg, "fused_chunk")
     s, c = token_ids.shape[0], input_ids.shape[1]
     page = _page_size(k_pages)
     ctx = page_table.shape[1] * page

@@ -53,7 +53,7 @@ Config:
                              # continuous mode shards TENSOR-PARALLEL only:
                              # KV pages split over KV heads on the tp axis
                              # (tp must divide the model's kv_heads; dp/sp
-                             # don't compose with the lockstep slot grid)
+                             # don't compose with the slot grid's lockstep)
     prefill_chunk: 128       # continuous mode: admit long prompts in chunks
                              # interleaved with decode steps (0 = one-shot)
     speculative_tokens: 3    # continuous+greedy: self-drafted (n-gram
@@ -148,85 +148,21 @@ class TpuGenerateProcessor(Processor):
                     f"here (generation shards over {sorted(allowed)}; "
                     f"ep/pp apply to training/forward paths)")
         if serving == "continuous" and mesh_config:
-            # continuous serving is tensor-parallel only: the lockstep slot
-            # grid does not batch-split, so dp/sp must stay 1 (parse-time
-            # config.py validation gives the same answer at --validate)
-            for axis in ("dp", "sp"):
-                if int(mesh_config.get(axis, 1)) > 1:
-                    raise ConfigError(
-                        f"tpu_generate: serving: continuous + mesh {axis} > 1 "
-                        "is unsupported — the lockstep slot grid does not "
-                        "batch-split; shard tp (mesh: {tp: N}) or use "
-                        "serving: batch / tpu_inference for dp")
+            from arkflow_tpu.config import refuse_continuous_split
+
+            refuse_continuous_split(mesh_config)
         self.family = get_model(model)
         if not {"generate", "serve_dtypes"} <= set(self.family.extras):
             raise ConfigError(f"model {model!r} does not support incremental decoding")
         self.cfg = self.family.make_config(**(model_config or {}))
-        if getattr(self.cfg, "latent", False):
-            # before the host init: seconds to minutes at real widths
-            if serving != "continuous":
-                raise ConfigError(
-                    "a latent-attention model (kv_lora_rank > 0) generates "
-                    "through serving: continuous only: the batch path's "
-                    "contiguous cache holds per-head K/V")
-            if mesh_config and getattr(self.cfg, "hc_mult", 1) > 1:
-                raise ConfigError(
-                    f"hc_mult {self.cfg.hc_mult} (several residual streams) "
-                    "is served on one chip: neither the streams' mixing nor "
-                    "the latent pools have a sharding over a tp mesh yet "
-                    "(remove mesh)")
-            if mesh_config:
-                raise ConfigError(
-                    "a latent-attention model is served on one chip: "
-                    "continuous serving shards the KV pool over KV heads, "
-                    "and a latent (MLA) page has one shared row per token "
-                    "(remove mesh)")
-        if getattr(self.cfg, "stateful", False):
-            # before the host init too: the hybrid block's state, conv
-            # layers' windows, linear attention layers' matrix states (a
-            # pool a slot beside the K/V pages)
-            from arkflow_tpu.models.paged_decode import cache_spec
+        # what the model's cache is not served with (``paged_decode.UNSERVED``),
+        # before the host init: seconds to minutes at real widths
+        from arkflow_tpu.models.paged_decode import refuse, unserved
 
-            pools = ", ".join(pool.name for pool in cache_spec(self.cfg))
-            if serving != "continuous":
-                raise ConfigError(
-                    "a model with the hybrid block (mamba_d_ssm > 0), conv "
-                    f"or linear_attention layers (pools {pools}) generates through "
-                    "serving: continuous only: the batch path's contiguous "
-                    "cache carries no recurrent state")
-            if mesh_config:
-                raise ConfigError(
-                    "a model with the hybrid block, conv or linear_attention "
-                    f"layers (pools {pools}) is served on one chip: the state pool and "
-                    "the mixer's channels have no sharding over a mesh yet "
-                    "(remove mesh)")
-        if getattr(self.cfg, "eva", False) and serving != "continuous":
-            # (a mesh: refused with every model that stacks by runs, below,
-            # and by name where the server is built: ``serving._serve_eva``)
-            raise ConfigError(
-                "attention_class 'eva' (a window that is compacted into "
-                "chunk summaries when it closes) generates through "
-                "serving: continuous only: the batch path's contiguous "
-                "cache keeps a row a position")
-        if getattr(self.cfg, "by_runs", False) and not self.cfg.latent:
-            # a per-head K/V model with routed experts, a layer pattern
-            # (layer_types / sliding_window: window pages beside kept pages)
-            # or head sizes by kind (swa_kv_heads, v_head_dim, a sink, ...)
-            if serving != "continuous":
-                raise ConfigError(
-                    "a per-head K/V model with routed experts "
-                    "(n_routed_experts), a layer pattern (layer_types) or "
-                    "head sizes by kind (swa_kv_heads, v_head_dim, "
-                    "partial_rotary_factor, attention_value_scale, a sink) "
-                    "generates through serving: continuous only: the batch "
-                    "path runs one stack of dense layers over a contiguous "
-                    "cache of keys and values of one width")
-            if mesh_config:
-                raise ConfigError(
-                    "a per-head K/V model with routed experts, a layer "
-                    "pattern or head sizes by kind is served on one chip: "
-                    "its expert stack, its window pool and its stacks by "
-                    "kind have no sharding over a mesh yet (remove mesh)")
+        if serving != "continuous":
+            refuse(self.cfg, "batch")
+        if mesh_config:
+            refuse(self.cfg, "mesh_tp")
         self.text_field = text_field
         self.tokenizer = tokenizer
         self.max_input = max_input
@@ -317,17 +253,10 @@ class TpuGenerateProcessor(Processor):
             #: prefill/decode disaggregation adapter: a prefill-role
             #: cluster worker (runtime/cluster.py) finds this through the
             #: same ``_inner``-chain walk as ``.runner``/``.swapper`` and
-            #: drives prefill_rows -> kv_push -> finalize_rows. A latent
-            #: (MLA) page has no wire format yet: no adapter is offered,
+            #: drives prefill_rows -> kv_push -> finalize_rows. Where the
+            #: model's pages have no wire form yet no adapter is offered,
             #: and the server's export / adopt calls raise ConfigError
-            # (nor has a recurrent state)
-            # (nor has a window pool's ring of live pages, nor K and V of
-            # different widths)
-            if not (getattr(self.cfg, "latent", False)
-                    or getattr(self.cfg, "stateful", False)
-                    or getattr(self.cfg, "layered", False)
-                    or getattr(self.cfg, "hetero", False)
-                    or getattr(self.cfg, "eva", False)):
+            if unserved(self.cfg, "kv_push") is None:
                 self.disagg = self
 
         reg = global_registry()
@@ -552,34 +481,28 @@ def _construct(config: dict) -> TpuGenerateProcessor:
         health_config=core_cfg["health_config"],
         checkpoint=config.get("checkpoint"),
     )
-    if getattr(proc.cfg, "by_runs", False):
-        # the swap canary and the integrity golden run the family's batch
-        # forward against per-head-cache assumptions that were never checked
-        # for a model that stacks by runs (latent attention, routed experts,
-        # a layer pattern): refuse the keys, attach neither
-        for key in ("swap", "integrity"):
-            if config.get(key) is not None:
-                raise ConfigError(
-                    f"tpu_generate: {key} is not supported for a "
-                    "latent-attention model, nor for a per-head K/V model "
-                    "with routed experts, a layer pattern or head sizes by "
-                    "kind, yet (its drain "
-                    "/ flip / pool reset and golden forward are unverified "
-                    "for latent and window pages); remove the key")
-        return proc
-    from arkflow_tpu.tpu.swap import build_generate_swapper, parse_swap_config
+    from arkflow_tpu.models.paged_decode import refuse, unserved
 
-    proc.swapper = build_generate_swapper(
-        proc, model=str(model), seed=int(config.get("seed", 0)),
-        swap_cfg=parse_swap_config(config.get("swap"), who="tpu_generate"),
-        checkpoint=config.get("checkpoint"))
-    from arkflow_tpu.tpu.integrity import (build_generate_integrity_monitor,
-                                           parse_integrity_config)
+    # a model whose row refuses the key has neither attached
+    # (``paged_decode.UNSERVED``, columns swap / integrity)
+    for key in ("swap", "integrity"):
+        if config.get(key) is not None:
+            refuse(proc.cfg, key, who=f"tpu_generate: {key}")
+    if unserved(proc.cfg, "swap") is None:
+        from arkflow_tpu.tpu.swap import build_generate_swapper, parse_swap_config
 
-    proc.integrity = build_generate_integrity_monitor(
-        proc, model=str(model),
-        cfg=parse_integrity_config(config.get("integrity"),
-                                   who="tpu_generate"))
+        proc.swapper = build_generate_swapper(
+            proc, model=str(model), seed=int(config.get("seed", 0)),
+            swap_cfg=parse_swap_config(config.get("swap"), who="tpu_generate"),
+            checkpoint=config.get("checkpoint"))
+    if unserved(proc.cfg, "integrity") is None:
+        from arkflow_tpu.tpu.integrity import (build_generate_integrity_monitor,
+                                               parse_integrity_config)
+
+        proc.integrity = build_generate_integrity_monitor(
+            proc, model=str(model),
+            cfg=parse_integrity_config(config.get("integrity"),
+                                       who="tpu_generate"))
     if proc.integrity is not None and proc.swapper is not None:
         # swaps and probes must coexist: probing quiesces across the roll
         # and the golden reference recomputes against committed weights

@@ -2054,7 +2054,8 @@ class ClusterDispatcher:
         worker produces a plausible, well-formed response that only
         disagreement can expose. On divergence neither side is trusted by
         fiat — each runs its golden probe, and whichever fails it is fenced
-        as corrupt; the other's answer is delivered. Transport failure on
+        as corrupt; the other's answer is delivered — where both pass
+        or both fail, neither is: the batch fails over. Transport failure on
         either leg degrades to normal single delivery ("skipped")."""
         self.m_shadow["issued"].inc()
         p_task = asyncio.ensure_future(self._attempt(primary, batch, **kw))
@@ -2081,28 +2082,34 @@ class ClusterDispatcher:
             "remote_tpu[%s]: shadow-verify divergence between %s and %s; "
             "running golden-probe tiebreak", self.name, primary.url,
             shadow_w.url)
-        bad: list[RemoteWorker] = []
+        corrupt: list[RemoteWorker] = []
+        passed: list[RemoteWorker] = []
         for w in (primary, shadow_w):
             try:
                 rep = await self._unary(w, {"action": "integrity_probe"},
                                         timeout=self.request_timeout_s)
             except Exception as e:
                 w.note_down(e)
-                bad.append(w)
                 continue
             if int(rep.get("mismatches", 0) or 0) or int(
                     rep.get("corrupt", 0) or 0):
                 self._fence_for_integrity(
                     w, "shadow-verify divergence confirmed by golden probe")
-                bad.append(w)
-        if primary not in bad:
-            return p_res
-        if shadow_w not in bad:
-            return s_res
+                corrupt.append(w)
+            else:
+                passed.append(w)
+        # an answer is delivered only where its worker passed AND the other
+        # was proven corrupt. A probe makes a corrupt worker repair on the
+        # spot, so the tiebreak of a second batch that diverged beside the
+        # first finds both sides clean AFTER the corrupt answer was given:
+        # one answer is wrong and no probe says which
+        if len(corrupt) == 1 and len(passed) == 1:
+            return p_res if passed[0] is primary else s_res
         raise ConnectError(
             f"remote_tpu[{self.name}]: shadow-verify divergence between "
-            f"{primary.url} and {shadow_w.url} and neither passed its "
-            "golden probe; failing over")
+            f"{primary.url} and {shadow_w.url}, and the golden probes "
+            f"({len(passed)} passed, {len(corrupt)} proven corrupt) do not "
+            "single out one side: neither answer is delivered; failing over")
 
     async def dispatch(self, batch: MessageBatch) -> list[MessageBatch]:
         """Route one emission to the fleet; failover along the ring on

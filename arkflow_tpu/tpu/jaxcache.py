@@ -17,6 +17,9 @@ module sets no directory. Where it is not, the cache goes to a fixed path
 ``.jax_cache`` next to the package for accelerator backends, a
 host-feature-keyed ``.jax_cache_cpu-<hash>`` for the CPU backend.
 ``ARKFLOW_JAX_CACHE=0`` disables.
+
+A program's key does not depend on where its kernels stand in their files
+(``jax_traceback_in_locations_limit`` 0, set with the cache).
 """
 
 from __future__ import annotations
@@ -99,6 +102,13 @@ def enable_persistent_cache() -> Optional[str]:
     # threshold of 1s would skip the small bucket-grid executables that
     # recompile on every engine restart)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    # no Python frame in an operation's location: a Pallas kernel's body is
+    # serialised WITH its locations into the program the cache keys on, and
+    # with frames in them (jax's default: ten) a line added anywhere above a
+    # kernel, in its own file or in any caller's, re-keys every program that
+    # runs it. A kernel that fails to compile still names itself (its
+    # ``name=``) and the operation at fault; only the line is not said
+    jax.config.update("jax_traceback_in_locations_limit", 0)
     _configured = path
     logger.debug("persistent XLA compilation cache at %s", path)
     return _configured

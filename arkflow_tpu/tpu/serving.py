@@ -48,16 +48,22 @@ from arkflow_tpu.errors import ConfigError, StepDeadlineExceeded
 from arkflow_tpu.models.decoder import DecoderConfig
 from arkflow_tpu.models.paged_decode import (
     cache_spec,
+    eva_rows,
+    eva_table_pages,
     fusable,
     init_page_pool,
+    kv_bytes_per_token,
     paged_decode_step,
     paged_prefill,
+    refuse,
+    unserved,
     window_ring_pages,
 )
 from arkflow_tpu.obs import global_registry
 from arkflow_tpu.obs.startup import cold_step, note_programs, setup_stage
 from arkflow_tpu.obs.trace import (annotated, current_scope, loop_stage,
                                    observe_stage, record_stage)
+from arkflow_tpu.ops.ragged_attention import PAGE_RUN, pages_in_runs
 from arkflow_tpu.tpu.health import HEALTHY
 from arkflow_tpu.tpu.serving_core import ServingRunnerCore
 
@@ -214,6 +220,127 @@ def _chunk_counts(so_far, stats):
         acc[4:] + stats[3:]])
 
 
+#: the label values of the routing series (``m_moe``). ``decode`` is a
+#: ``_decode`` execution and takes nothing from a fused step (the
+#: benchmark's roofline readers divide it into that program's kernel time);
+#: ``chunk`` a prompt's chunks, fused or alone, summed on the device and
+#: recorded with its first token; ``prefill`` a one-shot prefill. A fused
+#: step's BLOCK — what lanes or chunk hit, what its expert products read —
+#: is ``fused``, its lanes alone ``fused_lanes``. The further counters
+#: (``_extra_counters``) have no one-shot series: ``_STEP_KINDS``.
+_STEP_KINDS = ("decode", "chunk", "fused", "fused_lanes")
+_MOE_KINDS = (*_STEP_KINDS, "prefill")
+
+#: ``m_attn_walk[kind]``: pages walked, columns carried, pages walked in runs
+_WALK_COUNTERS = (
+    ("arkflow_gen_attn_pages_walked_total",
+     "kept-pool pages the attention kernel's rows walked, a layer"),
+    ("arkflow_gen_attn_table_columns_total",
+     "kept page-table columns of the rows the attention kernel was called "
+     "with, a layer"),
+    ("arkflow_gen_attn_pages_in_runs_total",
+     "kept-pool pages the latent kernel's rows walked in whole stretches of "
+     "neighbours, a stretch a copy, a layer"),
+)
+
+
+@dataclass(frozen=True)
+class _FusedLayout:
+    """What EVERY step of a server that fuses a routed model returns: one
+    int32 array of one shape whichever program made it, so that a step takes
+    the step before's output on the device whatever that was — the lanes
+    their tokens (``prev``), a prompt its counters so far
+    (``req.chunk_moe``) — and one fetch brings all of it:
+
+        [the lanes' tokens: slots | the prompt's token, its counters so far
+         (``_chunk_counts``): 2 + n | the lanes' counters: n | the block's: n]
+
+    ``n`` the counters of one ``moe_step_stats``. A decode step leaves the
+    prompt's and the block's places empty, a chunk alone the lanes' and the
+    block's."""
+
+    slots: int
+    n: int
+
+    @property
+    def size(self) -> int:
+        return self.slots + 2 + 3 * self.n
+
+    @property
+    def lanes_at(self) -> int:
+        return self.slots + 2 + self.n
+
+    def decode(self, stats) -> list:
+        """What follows a decode step's tokens."""
+        return [jnp.zeros(2 + self.n, jnp.int32), stats, jnp.zeros(self.n, jnp.int32)]
+
+    def so_far(self, out):
+        """The prompt's place in the output of the step before."""
+        return out[self.slots:self.lanes_at]
+
+    def chunk(self, out):
+        """A chunk's own output (its token, the prompt's counters) in place."""
+        return jnp.concatenate([jnp.zeros(self.slots, jnp.int32), out,
+                                jnp.zeros(2 * self.n, jnp.int32)])
+
+    def fused(self, before, stats) -> list:
+        """What follows a fused step's ``slots + 1`` tokens: the prompt's
+        counters with this chunk's added (``stats`` [3, n]: the lanes', the
+        chunk's, the block's), the lanes', the block's."""
+        return [_chunk_counts(self.so_far(before), stats[1]), stats[0], stats[2]]
+
+
+class _FreePages:
+    """The kept pool's free pages (page 0 is the scratch page), handed out so
+    that a slot's table holds RUNS: the pool is cut into blocks of ``run``
+    neighbours from page 1 on (pages past the last whole block are single),
+    and a slot's column c takes page c % run of a block — a new block's first
+    page, the lowest block that is free whole, at c % run == 0, the page
+    after its last one further on — so that an aligned stretch of its table
+    names ``run`` consecutive pages, which the latent walk moves as one copy
+    (``ops/ragged_attention._by_runs``). Nothing is set aside for a slot: the
+    rest of its block stays free, counts as free and goes to whoever asks
+    once no whole block is left, so the pool admits what it admitted as a
+    plain list; a page is given back alone (sharing and eviction are a
+    page's), and a block whose pages are all back is whole again. A take
+    scans the blocks' counts (a few thousand: microseconds)."""
+
+    def __init__(self, num_pages: int, run: int = PAGE_RUN):
+        self.run = run
+        self._free = np.ones(num_pages, bool)
+        self._free[0] = False
+        self._count = num_pages - 1
+        #: free pages of each block (a short last block is never whole)
+        self._left = np.bincount((np.arange(1, num_pages) - 1) // run)
+
+    def __len__(self) -> int:
+        return self._count
+
+    def take(self, column: int, last: Optional[int]) -> int:
+        """A free page for a slot's ``column``, ``last`` its page of the
+        column before."""
+        k = column % self.run
+        p = last + 1 if k else None
+        if not (k and p < len(self._free) and self._free[p]
+                and (p - 1) % self.run == k):
+            # no run to go on with: a new one where a block is whole (the
+            # lowest), else the lowest page of a block that is not
+            whole = self._left == self.run
+            partial = (self._left > 0) & ~whole
+            blocks = whole if whole.any() and not (k and partial.any()) else partial
+            first = 1 + int(np.argmax(blocks)) * self.run
+            p = first + int(np.argmax(self._free[first:first + self.run]))
+        self._free[p] = False
+        self._left[(p - 1) // self.run] -= 1
+        self._count -= 1
+        return p
+
+    def give(self, p: int) -> None:
+        self._free[p] = True
+        self._left[(p - 1) // self.run] += 1
+        self._count += 1
+
+
 class GenerationServer:
     """Greedy continuous-batching decode over ``slots`` lockstep lanes."""
 
@@ -234,6 +361,14 @@ class GenerationServer:
         enable_persistent_cache()
         if cfg.use_ring_attention:
             raise ConfigError("paged serving does not support ring attention")
+        # what the model's cache is not served with yet, asked once for
+        # each feature the arguments turn on (``paged_decode.UNSERVED``)
+        for feature, on in (("mesh_tp", mesh is not None),
+                            ("prefix_cache", prefix_cache_pages),
+                            ("speculation", speculative_tokens),
+                            ("one_shot_prefill", int(prefill_chunk) <= 0)):
+            if on:
+                refuse(cfg, feature)
         self.params = params
         self.cfg = cfg
         self.slots = slots
@@ -245,8 +380,7 @@ class GenerationServer:
         #: pages go by its cached length, not by its position (``_serve_eva``)
         self._eva = bool(cfg.eva)
         if self._eva:
-            _serve_eva(self, name, num_pages, prefill_chunk, speculative_tokens,
-                       prefix_cache_pages, mesh)
+            self._serve_eva(name, num_pages, int(prefill_chunk))
         # page 0 is scratch; default pool fits every slot at max_seq
         self.num_pages = num_pages or (1 + self.slots * self.pages_per_slot)
         if self.num_pages < 1 + self.pages_per_slot:
@@ -263,19 +397,6 @@ class GenerationServer:
         # token ids, lengths, active masks) stays replicated, so admission /
         # page accounting is identical whether one chip serves or eight
         self.mesh = mesh
-        if cfg.latent and mesh is not None:
-            raise ConfigError(
-                "continuous serving shards the KV pools over KV heads on the "
-                "tp axis; a latent (MLA) pool has one shared row per token "
-                "and no head axis to split — serve a latent-attention model "
-                "on one chip (no mesh)")
-        if cfg.by_runs and mesh is not None:
-            raise ConfigError(
-                "a per-head K/V model with routed experts, a layer pattern "
-                f"or head sizes by kind (pools {self._pool_names()}) is "
-                "served on one chip: its expert stack, its window pool and "
-                "its stacks by kind have no sharding over a mesh yet "
-                "(remove mesh)")
         # a layer pattern: rows of more than one kind and lifetime (the
         # model's ``cache_spec``). Window rows live in a pool of their own,
         # each slot's pages in a ring that a step's queries fit in
@@ -298,16 +419,18 @@ class GenerationServer:
                 (cfg.index_topk, "arkflow_gen_dsa_context_total",
                  "keys in context of the indexed layers' queries"))
             if has]
-        if self._layered:
-            self._refuse_layered(prefix_cache_pages, speculative_tokens)
-        if cfg.hc_mult > 1:
-            _serve_streams(self, name, prefix_cache_pages, speculative_tokens)
+        if cfg.hc_mult > 1:  # what a token's residual costs
+            reg.gauge("arkflow_gen_residual_streams",
+                      "residual streams a token carries between sub-layers "
+                      "(hc_mult)", {"model": name}).set(cfg.hc_mult)
+            reg.gauge("arkflow_gen_residual_bytes_per_token",
+                      "bytes of a token's residual between sub-layers (bfloat16 "
+                      "streams: hc_mult x dim x 2)", {"model": name}).set(
+                          cfg.hc_mult * cfg.dim * 2)
         #: a state a slot beside the K/V pages (``cache_spec``'s per-slot
         #: pool: the hybrid block's ``ssm``, conv layers' ``conv``, linear
         #: attention layers' ``gdn``)
         self._stateful = bool(cfg.stateful)
-        if self._stateful:
-            self._refuse_stateful(prefix_cache_pages, speculative_tokens)
         self._win_cols = window_ring_pages(cfg, page_size, self.prefill_chunk)
         #: page 0 of the window pool is scratch too; every slot can hold a
         #: whole ring, so a window page is never waited for
@@ -315,15 +438,12 @@ class GenerationServer:
         self._kv_io_sharding = None     # a pool: [L, pages, page, kv, dh]
         self._repl_sharding = None
         if mesh is not None:
+            from arkflow_tpu.config import refuse_continuous_split
             from arkflow_tpu.parallel.mesh import (dp_size, kv_pool_sharding,
                                                    replicated, tp_size,
                                                    validate_tp_heads)
 
-            if dp_size(mesh) > 1:
-                raise ConfigError(
-                    "continuous serving shards tensor-parallel only — the "
-                    "lockstep slot grid does not batch-split over dp (use "
-                    "serving: batch or tpu_inference for dp)")
+            refuse_continuous_split({"dp": dp_size(mesh)})
             validate_tp_heads(tp_size(mesh), cfg.kv_heads,
                               who="continuous serving")
             self._kv_io_sharding = kv_pool_sharding(
@@ -448,6 +568,8 @@ class GenerationServer:
         #   (``eos_id`` < 0: every end is a budget's) and serves in lockstep
         #   where one is;
         # - speculative decoding restructures the decode step: lockstep.
+        # The model's halves of this are two columns of its cache's table
+        # (``paged_decode.UNSERVED``: ``run_ahead``, ``run_ahead_eos``).
         self.dispatch_depth = int(dispatch_depth)
         if self.dispatch_depth < 1:
             raise ConfigError("dispatch_depth must be >= 1")
@@ -459,8 +581,8 @@ class GenerationServer:
         self._ahead = bool(
             self.dispatch_depth > 1 and self.temperature == 0.0
             and self.speculative_tokens == 0
-            and not getattr(cfg, "num_experts", 0)
-            and not (self._stateful and self.eos_id >= 0))
+            and unserved(cfg, "run_ahead" if self.eos_id < 0
+                         else "run_ahead_eos") is None)
         # where a decode step is due and a slot is prefilling, ONE program
         # carries the step's lanes and the prompt's next chunk through one
         # pass over the weights (``paged_fused_step``; ``_step``): a greedy
@@ -587,8 +709,6 @@ class GenerationServer:
             "arkflow_gen_token_gap_seconds",
             "gap between consecutive generated tokens of one request",
             {"model": name})
-        from arkflow_tpu.models.paged_decode import kv_bytes_per_token
-
         reg.gauge("arkflow_gen_kv_bytes_per_token",
                   "bytes one cached token costs over all layers, as the page "
                   "pools hold it", {"model": name}).set(kv_bytes_per_token(cfg))
@@ -641,8 +761,7 @@ class GenerationServer:
         # sit side by side in the pool as ONE copy (since PR 54): the walked
         # pages of such stretches are counted too, by the kernel's predicate
         # over the table rows the step carries (a per-head kernel takes no
-        # runs and counts none). Names and texts: ``_WALK_COUNTERS``, at
-        # the end of this file, where new lines re-key no served program
+        # runs and counts none). Names and texts: ``_WALK_COUNTERS``
         self.m_attn_walk = {} if self.decode_kernel != "paged" else {
             kind: tuple(reg.counter(metric, text, {"model": name, "kind": kind})
                         for metric, text in _WALK_COUNTERS)
@@ -700,66 +819,57 @@ class GenerationServer:
 
     # -- device plumbing (jit build / sharding / reset) --------------------
 
-    def _pool_names(self) -> str:
-        """The model's cache pools by name, for a refusal's message."""
-        return ", ".join(pool.name for pool in cache_spec(self.cfg))
-
-    def _refuse_layered(self, prefix_cache_pages, speculative_tokens) -> None:
-        """What a model with a layer pattern is not served with yet."""
-        pools = self._pool_names()
-        if self.prefill_chunk <= 0:
+    def _serve_eva(self, name: str, num_pages, prefill_chunk: int) -> None:
+        """A compacting window cache's geometry — its table's columns
+        (``pages_per_slot``: by cached length, the summary pages of every
+        window a slot can close and one whole window), a ConfigError by name
+        where chunk, page or pool do not fit its windows — and its counters."""
+        cfg, page = self.cfg, self.page_size
+        w, c = cfg.window_size, cfg.chunk_size
+        sp = cfg.gqa("full_attention")
+        if w % prefill_chunk:
             raise ConfigError(
-                "a model with a layer pattern (sliding or indexed layers: "
-                f"pools {pools}) prefills in chunks through the cache: set "
-                "prefill_chunk > 0 (its size bounds a slot's window pages)")
-        if prefix_cache_pages and self.cfg.sliding_window:
+                "attention_class 'eva' prefills in chunks through the cache: set "
+                f"prefill_chunk > 0 to a divisor of window_size {w} (a chunk "
+                f"never straddles a window's end), got {prefill_chunk}")
+        if w % page or (w // c) % page:
             raise ConfigError(
-                "prefix_cache_pages does not compose with window pages "
-                f"(pools {pools}): a sliding layer's rows are freed as the "
-                "window passes, so a finished prompt has no full pages of "
-                "them to donate")
-        if speculative_tokens:
+                f"attention_class 'eva': page_size {page} divides window_size "
+                f"{w} and a closed window's {w // c} summary rows (whole pages "
+                "turn into whole pages at a close)")
+        if sp.key_parts > 1 or sp.split_heads:
             raise ConfigError(
-                "speculative_tokens does not compose with indexed or sliding "
-                f"layers (pools {pools}): a rejected draft leaves its index "
-                "key behind, which a later query's indexer may select, and "
-                "the verify step does not slide the window pool")
-
-    def _refuse_stateful(self, prefix_cache_pages, speculative_tokens) -> None:
-        """What a model that carries a recurrent state a slot is not served
-        with yet, and why: a state is overwritten by every token, so what
-        is benign for K/V rows (a stale row, an aliased page) is not for it.
-        A lane that rides one step too long is the third such thing, and
-        only an EOS makes one: such a model runs ahead of the device where
-        ``eos_id`` < 0 and serves in lockstep otherwise (``_ahead``; no
-        refusal here, the server decides). The hybrid block's state,
-        conv layers' windows and linear attention layers' matrix states
-        alike (the messages name the pools)."""
-        pools = self._pool_names()
-        if self.mesh is not None:
+                "attention_class 'eva' pools a window's rows whole: a key held "
+                f"in parts or a head a pool layer (head_dim {sp.dk} on "
+                f"{sp.kv_heads} K/V heads) is not served with it")
+        self.pages_per_slot = eva_table_pages(cfg, page, self.max_seq)
+        if num_pages and num_pages < 1 + self.slots * self.pages_per_slot:
             raise ConfigError(
-                "a model with the hybrid block (mamba_d_ssm > 0), conv or "
-                f"linear_attention layers (pools {pools}) is served on one "
-                "chip: the state pool and the mixer's channels have no "
-                "sharding over a mesh yet (remove mesh)")
-        if self.prefill_chunk <= 0:
-            raise ConfigError(
-                "a model that carries a recurrent state (pools "
-                f"{pools}) prefills in chunks through the cache: set "
-                "prefill_chunk > 0 (the chunk's program is the one that is "
-                "told its slot and resets it)")
-        if prefix_cache_pages:
-            raise ConfigError(
-                "prefix_cache_pages does not compose with a recurrent "
-                f"state (pools {pools}): aliased pages skip the very tokens "
-                "whose state the rest of the prompt needs, and no state "
-                "snapshot is kept beside a cached prefix yet")
-        if speculative_tokens:
-            raise ConfigError(
-                "speculative_tokens does not compose with a recurrent "
-                f"state (pools {pools}): a rejected draft has already "
-                "advanced the state (for K/V it only leaves a stale row), "
-                "and there is no rollback yet")
+                f"num_pages={num_pages}: a compacting window cache takes its "
+                "pages step by step, so the pool holds every slot's worst case "
+                f"({self.slots} slots x {self.pages_per_slot} pages + scratch)")
+        reg = global_registry()
+        self.m_eva_closes = {
+            phase: reg.counter("arkflow_gen_eva_window_closes_total",
+                               "windows closed (a slot's: every layer pools the "
+                               "window's rows into summary rows, on the device), "
+                               "by the step that wrote the window's last row",
+                               {"model": name, "phase": phase})
+            for phase in ("chunk", "decode")}
+        self.m_eva_rows = {
+            (phase, kind): reg.counter(
+                "arkflow_gen_eva_rows_attended_total",
+                "cache rows the queries of issued steps attended, a layer: exact "
+                "rows of the open window, summary rows of closed windows",
+                {"model": name, "kind": kind, "phase": phase})
+            for phase in ("chunk", "decode") for kind in ("window", "summary")}
+        # a name a kind: a sampler that sums a gauge over its labels keeps them apart
+        self.m_eva_pages = {
+            kind: reg.gauge(f"arkflow_gen_eva_live_pages_{kind}",
+                            f"pages slots hold whose rows are {what}",
+                            {"model": name})
+            for kind, what in (("window", "the open window's exact rows"),
+                               ("summary", "closed windows' summary rows"))}
 
     def _on_tpu(self) -> bool:
         """Backend check for the compiled Pallas path (the probe shared
@@ -906,7 +1016,11 @@ class GenerationServer:
         keyed = int(self._key is not None)
         piped = int(self._ahead)
         routed, fuses = int(cfg.routed), int(self._fuses)
-        self._lay = lay = _fused_layout(self)  # fixed with the programs
+        #: the one output layout of a server that fuses a routed model, fixed
+        #: with the programs; every other server's steps return what they
+        #: always did
+        self._lay = lay = _FusedLayout(
+            self.slots, 3 + len(self._extra_counters)) if fuses and routed else None
 
         def _pick(logits, keys, *behind):
             """The step's token array (``behind`` appended: a routed model's
@@ -1030,10 +1144,28 @@ class GenerationServer:
             3 + counted)),) if piped else ()
         self._no_counts = zeros(lay.size if lay else 5 + counted)
 
+    def _note_grouped(self, kind: str, steps: int, rows: int) -> None:
+        """Count the expert layers of ``steps`` device steps whose product ran
+        grouped by expert (``ops/moe_grouped``): the step's row count decides,
+        per program; none under ``decode_kernel: gather``, which runs no expert
+        kernel. Registered at a model's first step, so it reads 0, not nothing,
+        where every step is one token tile."""
+        from arkflow_tpu.ops.moe_experts import runs_grouped
+
+        if not rows:  # a part of a step: the step's product is counted once
+            return
+        grouped = self.decode_kernel == "paged" and runs_grouped(rows)
+        global_registry().counter(
+            "arkflow_gen_moe_grouped_products_total",
+            "expert layers of device steps whose product ran grouped by expert "
+            "(more rows than one token tile)",
+            dict(self.m_moe[kind][0].labels),
+        ).inc(steps * self._moe_layers if grouped else 0)
+
     def _note_moe(self, kind: str, stats, steps: int = 1, *, rows: int) -> None:
         """Record the routing counters (``moe_step_stats``, on the host) of one
         step of ``rows`` rows, or a prompt's ``steps`` chunks summed (each their mean)."""
-        _note_grouped(self, kind, steps, rows)
+        self._note_grouped(kind, steps, rows)
         pairs, hit, max_load = (int(v) for v in stats[:3])
         total, experts_hit, load = self.m_moe[kind]
         total.inc(pairs)
@@ -1077,7 +1209,7 @@ class GenerationServer:
 
         The four generation steps bind ``self.params`` at build time
         (``_build_jitted``), so a flip rebinds them. The sequence: pause
-        admission, let the lockstep slot grid run dry (queued requests WAIT,
+        admission, let the slot grid run dry (queued requests WAIT,
         they are never failed), flip params, rebuild the jits (the cleared
         ``_seen_steps`` grants the next step the first-compile budget), and
         reset the page pools + prefix cache — cached KV against new weights
@@ -1442,42 +1574,6 @@ class GenerationServer:
                 self._serve_loop(), context=contextvars.Context())
         return req.future
 
-    def _refuse_latent_pages(self, what: str) -> None:
-        if self._stateful:
-            raise ConfigError(
-                f"{what} ships K/V page slabs; a recurrent state (pools "
-                f"{self._pool_names()}) has no wire form yet, and pages "
-                "without it cannot be decoded from — a model with the "
-                "hybrid block, conv or linear_attention layers prefills and "
-                "decodes on the same server")
-        if self._eva:
-            raise ConfigError(
-                f"{what} ships a prompt's pages by position; a compacting "
-                f"window cache (pool {self._pool_names()}) holds summary "
-                "pages and an open window, which have no wire form yet — a "
-                "model with attention_class 'eva' prefills and decodes on "
-                "the same server")
-        if self.cfg.latent:
-            raise ConfigError(
-                f"{what} ships per-head K/V page slabs split along the "
-                "kv_heads axis; a latent (MLA) page has no head axis and no "
-                "wire format yet — a latent-attention model prefills and "
-                "decodes on the same server")
-        if self.cfg.hetero:
-            raise ConfigError(
-                f"{what} ships K and V page slabs of one shape; this model's "
-                f"pools ({self._pool_names()}) hold keys and values of "
-                "different widths (v_head_dim; a key held in parts) or "
-                "sizes by kind, which have no wire form yet — such a model "
-                "prefills and decodes on the same server")
-        if self._layered:
-            raise ConfigError(
-                f"{what} ships the pages of ONE kept pool; a layer pattern's "
-                f"pools ({self._pool_names()}) have no wire form yet — the "
-                "window pool's live pages and their ring would have to ship "
-                "beside the kept pages — so such a model prefills and "
-                "decodes on the same server")
-
     async def prefill_export(self, prompt_ids: list[int],
                              max_new_tokens: int = 64) -> dict:
         """Disaggregated prefill: run (chunked) prefill for one prompt, then
@@ -1492,7 +1588,7 @@ class GenerationServer:
         ``done`` and ships no pages. Pages are unreffed (and donated to the
         prefix cache) locally once exported — the scratch pool recycles.
         """
-        self._refuse_latent_pages("prefill_export (kv_push)")
+        refuse(self.cfg, "kv_push", who="prefill_export (kv_push)")
         if self._closed:
             raise ConfigError("generation server is closed")
         if len(prompt_ids) == 0:
@@ -1516,7 +1612,7 @@ class GenerationServer:
         table it is handed just points at the adopted pages. Returns the
         full token list including the shipped first token, exactly what
         :meth:`generate` would have returned locally."""
-        self._refuse_latent_pages("generate_from_pages (kv_push)")
+        refuse(self.cfg, "kv_push", who="generate_from_pages (kv_push)")
         if self._closed:
             raise ConfigError("generation server is closed")
         if export.get("done"):
@@ -1937,6 +2033,27 @@ class GenerationServer:
              else self._bucket(n - off))
         return off, c, min(off + c, n), off + c >= n
 
+    def _eva_account(self, phase: str, slot: int, first: int, last: int) -> None:
+        """The books of a step that writes positions ``first..last`` of ``slot``
+        (inside one window), behind the table it carries: the rows its queries
+        attend and, where it writes the window's last row, the close — every
+        page of the window but the first ``window / chunk / page``, which now
+        hold its summaries, goes back to the pool (the device reads them in
+        this step; whoever takes them next writes in a later one)."""
+        cfg = self.cfg
+        w, per = cfg.window_size, cfg.window_size // cfg.chunk_size
+        n = last - first + 1
+        self.m_eva_rows[phase, "summary"].inc(n * (first // w) * per)
+        self.m_eva_rows[phase, "window"].inc(n * (first % w + 1) + n * (n - 1) // 2)
+        if (last + 1) % w:
+            return
+        self.m_eva_closes[phase].inc()
+        pages = self._slot_pages[slot]
+        keep = (last // w + 1) * (per // self.page_size)
+        for p in pages[keep:]:
+            self._unref_page(p)
+        del pages[keep:]
+
     def _span_operands(self, slot: int, req: _Request, kind: str, off: int,
                        new_off: int, c: int) -> tuple:
         """A prefill step's packed operands (ids, offset, tokens present, the
@@ -1951,7 +2068,7 @@ class GenerationServer:
         table = self._table(slot)
         packed = pack_operands(ids, off, len(chunk), table)
         if self._eva:  # behind the table the step carries: a close's frees
-            _eva_account(self, "chunk", slot, off, new_off - 1)
+            self._eva_account("chunk", slot, off, new_off - 1)
         if kind == "chunk":
             self._note_walk("chunk", np.asarray([off + c - 1]), len(chunk), c,
                             table=table)
@@ -1987,12 +2104,18 @@ class GenerationServer:
                 nxt = nxt[self.slots:]  # the prompt's place (``_FusedLayout``)
             return self._seed_slot(slot, req, kind, nxt)
 
+    def _span_rows(self, kind: str, left: int) -> int:
+        """Rows of the prefill steps of a prompt whose LAST step had ``left``
+        tokens to go: the configured chunk, or the one bucketed span."""
+        return (self.prefill_chunk if kind == "chunk" and self.prefill_chunk
+                else self._bucket(left))
+
     def _seed_slot(self, slot: int, req: _Request, kind: str, nxt) -> bool:
         req.chunks += 1
         left = len(req.prompt) - self._prefill_pos.pop(slot, 0)
         if self._moe_layers:
             self._note_moe(kind, nxt[1:], int(nxt[4]) if kind == "chunk" else 1,
-                           rows=_span_rows(self, kind, left))
+                           rows=self._span_rows(kind, left))
         self._lengths[slot] = len(req.prompt)
         self._cur_tokens[slot] = int(nxt[0])
         if req.prefill_only:
@@ -2101,6 +2224,22 @@ class GenerationServer:
             self._finish(longest)
             act[longest] = False
 
+    def _eva_gauges(self) -> None:
+        """Pages held by slots, by kind: a slot's first columns are its summary
+        pages (from where its next write sits), the rest its open window's."""
+        cfg = self.cfg
+        per = cfg.window_size // cfg.chunk_size // self.page_size
+        summary = window = 0
+        for s, pages in enumerate(self._slot_pages):
+            if not pages:
+                continue
+            at = self._prefill_pos.get(s, int(self._lengths[s]))
+            held = min(at // cfg.window_size * per, len(pages))
+            summary += held
+            window += len(pages) - held
+        self.m_eva_pages["summary"].set(summary)
+        self.m_eva_pages["window"].set(window)
+
     def _update_gauges(self, busy: int) -> None:
         self.m_slots_busy.set(busy)
         self.m_waiting.set(len(self._pending))
@@ -2108,7 +2247,7 @@ class GenerationServer:
         if total:
             self.m_pool_occupancy.set((total - len(self._free_pages)) / total)
         if self._eva:
-            _eva_gauges(self)
+            self._eva_gauges()
         for gauge, holder, unit_bytes in self.m_kv_live:
             held = {"window": self.num_win_pages - 1 - len(self._win_free),
                     "pages": total - len(self._free_pages),
@@ -2389,7 +2528,7 @@ class GenerationServer:
                 self._note_walk("decode", lens, int(act.sum()), table=table)
                 if self._eva:
                     for s in map(int, np.flatnonzero(act)):
-                        _eva_account(self, "decode", s, int(lens[s]), int(lens[s]))
+                        self._eva_account("decode", s, int(lens[s]), int(lens[s]))
         return act, packed, prev, prep.dur_s
 
     def _note_walk(self, kind: str, last, queries: int, width: int = 1, *,
@@ -2445,6 +2584,20 @@ class GenerationServer:
             tiles[product] += -(-width // tile_c)
         return tiles
 
+    def _note_step_moe(self, nxt, rode: int) -> None:
+        """Record a decode-kind step's routing counters by the program that ran:
+        a ``_decode`` execution under ``decode``; a fused step that carried
+        ``rode`` chunk rows under ``fused`` (its block, whose rows decide the
+        expert kernel) and ``fused_lanes`` — the chunk's part rides on with the
+        prompt's counters (``chunk``, at its first token)."""
+        lay = self._lay
+        if not rode:
+            self._note_moe("decode", nxt[lay.lanes_at if lay else self.slots:],
+                           rows=self.slots)
+            return
+        self._note_moe("fused_lanes", nxt[lay.lanes_at:], rows=0)
+        self._note_moe("fused", nxt[lay.lanes_at + lay.n:], rows=self.slots + rode)
+
     def _apply_decode(self, act, nxt, reqs=None, seeds=None, rode: int = 0) -> None:
         """One decode step's fetched tokens (then a routed model's counters)
         onto host state. A lane whose request is no longer the one in
@@ -2459,7 +2612,7 @@ class GenerationServer:
             lanes = np.flatnonzero(act)
             if self._moe_layers and (reqs is None or all(
                     self._slot_req[s] is reqs[s] for s in lanes)):
-                _note_step_moe(self, nxt, rode)
+                self._note_step_moe(nxt, rode)
             for s in map(int, lanes):
                 req = self._slot_req[s]
                 if req is None or (reqs is not None and req is not reqs[s]):
@@ -2539,334 +2692,3 @@ class GenerationServer:
                     self._handle_token(s, int(t))
                     if self._slot_req[s] is None:
                         break
-
-
-# -- which expert kernel a step ran -------------------------------------------
-# Here, at the END of the file, and called from lines that took the place of
-# as many: a Mosaic kernel's body carries the line of every frame it was
-# traced under, the jitted steps' in this file among them, so a line added
-# above them re-keys every kernel of every program (PERF.md §7, "From PR 41
-# (1)").
-
-
-def _span_rows(server: GenerationServer, kind: str, left: int) -> int:
-    """Rows of the prefill steps of a prompt whose LAST step had ``left``
-    tokens to go: the configured chunk, or the one bucketed span."""
-    return (server.prefill_chunk if kind == "chunk" and server.prefill_chunk
-            else server._bucket(left))
-
-
-def _note_grouped(server: GenerationServer, kind: str, steps: int, rows: int) -> None:
-    """Count the expert layers of ``steps`` device steps whose product ran
-    grouped by expert (``ops/moe_grouped``): the step's row count decides,
-    per program; none under ``decode_kernel: gather``, which runs no expert
-    kernel. Registered at a model's first step, so it reads 0, not nothing,
-    where every step is one token tile."""
-    from arkflow_tpu.ops.moe_experts import runs_grouped
-
-    if not rows:  # a part of a step: the step's product is counted once
-        return
-    grouped = server.decode_kernel == "paged" and runs_grouped(rows)
-    global_registry().counter(
-        "arkflow_gen_moe_grouped_products_total",
-        "expert layers of device steps whose product ran grouped by expert "
-        "(more rows than one token tile)",
-        dict(server.m_moe[kind][0].labels),
-    ).inc(steps * server._moe_layers if grouped else 0)
-
-
-# -- several residual streams (``hc_mult`` > 1) -----------------------------------
-
-
-def _serve_streams(server: GenerationServer, name: str, prefix_cache_pages,
-                   speculative_tokens) -> None:
-    """What a model with several residual streams is not served with yet,
-    and the two gauges that say what a token's residual costs."""
-    cfg = server.cfg
-    if speculative_tokens:
-        raise ConfigError(
-            f"speculative_tokens does not compose with hc_mult {cfg.hc_mult} "
-            "(several residual streams over latent pools): the verify step "
-            "(_verify) has not been held to the streams' reference")
-    if prefix_cache_pages:
-        raise ConfigError(
-            f"prefix_cache_pages does not compose with hc_mult {cfg.hc_mult} "
-            "yet: the prefix cache over latent pools has not been held to "
-            "the streams' reference")
-    reg = global_registry()
-    reg.gauge("arkflow_gen_residual_streams",
-              "residual streams a token carries between sub-layers (hc_mult)",
-              {"model": name}).set(cfg.hc_mult)
-    reg.gauge("arkflow_gen_residual_bytes_per_token",
-              "bytes of a token's residual between sub-layers (bfloat16 "
-              "streams: hc_mult x dim x 2)", {"model": name}).set(
-                  cfg.hc_mult * cfg.dim * 2)
-
-
-# -- page runs (new code ends this file: a served program's compile-cache key
-# carries the line of every frame above its kernels, ``_decode`` and ``_chunk``
-# among them, and lines added above them would re-key every cell's programs) --
-
-from arkflow_tpu.ops.ragged_attention import PAGE_RUN, pages_in_runs  # noqa: E402
-
-#: ``m_attn_walk[kind]``: pages walked, columns carried, pages walked in runs
-_WALK_COUNTERS = (
-    ("arkflow_gen_attn_pages_walked_total",
-     "kept-pool pages the attention kernel's rows walked, a layer"),
-    ("arkflow_gen_attn_table_columns_total",
-     "kept page-table columns of the rows the attention kernel was called "
-     "with, a layer"),
-    ("arkflow_gen_attn_pages_in_runs_total",
-     "kept-pool pages the latent kernel's rows walked in whole stretches of "
-     "neighbours, a stretch a copy, a layer"),
-)
-
-
-class _FreePages:
-    """The kept pool's free pages (page 0 is the scratch page), handed out so
-    that a slot's table holds RUNS: the pool is cut into blocks of ``run``
-    neighbours from page 1 on (pages past the last whole block are single),
-    and a slot's column c takes page c % run of a block — a new block's first
-    page, the lowest block that is free whole, at c % run == 0, the page
-    after its last one further on — so that an aligned stretch of its table
-    names ``run`` consecutive pages, which the latent walk moves as one copy
-    (``ops/ragged_attention._by_runs``). Nothing is set aside for a slot: the
-    rest of its block stays free, counts as free and goes to whoever asks
-    once no whole block is left, so the pool admits what it admitted as a
-    plain list; a page is given back alone (sharing and eviction are a
-    page's), and a block whose pages are all back is whole again. A take
-    scans the blocks' counts (a few thousand: microseconds)."""
-
-    def __init__(self, num_pages: int, run: int = PAGE_RUN):
-        self.run = run
-        self._free = np.ones(num_pages, bool)
-        self._free[0] = False
-        self._count = num_pages - 1
-        #: free pages of each block (a short last block is never whole)
-        self._left = np.bincount((np.arange(1, num_pages) - 1) // run)
-
-    def __len__(self) -> int:
-        return self._count
-
-    def take(self, column: int, last: Optional[int]) -> int:
-        """A free page for a slot's ``column``, ``last`` its page of the
-        column before."""
-        k = column % self.run
-        p = last + 1 if k else None
-        if not (k and p < len(self._free) and self._free[p]
-                and (p - 1) % self.run == k):
-            # no run to go on with: a new one where a block is whole (the
-            # lowest), else the lowest page of a block that is not
-            whole = self._left == self.run
-            partial = (self._left > 0) & ~whole
-            blocks = whole if whole.any() and not (k and partial.any()) else partial
-            first = 1 + int(np.argmax(blocks)) * self.run
-            p = first + int(np.argmax(self._free[first:first + self.run]))
-        self._free[p] = False
-        self._left[(p - 1) // self.run] -= 1
-        self._count -= 1
-        return p
-
-    def give(self, p: int) -> None:
-        self._free[p] = True
-        self._left[(p - 1) // self.run] += 1
-        self._count += 1
-
-
-# -- a compacting window cache (``attention_class`` "eva") --------------------------
-
-from arkflow_tpu.models.paged_decode import eva_rows, eva_table_pages  # noqa: E402
-
-
-def _serve_eva(server: GenerationServer, name: str, num_pages, prefill_chunk,
-               speculative_tokens, prefix_cache_pages, mesh) -> None:
-    """What a compacting window cache is served with: its table's columns
-    (``pages_per_slot``: by cached length, the summary pages of every window
-    a slot can close and one whole window), its counters, and a ConfigError
-    by name for what it is not served with yet."""
-    cfg, page = server.cfg, server.page_size
-    w, c = cfg.window_size, cfg.chunk_size
-    sp = cfg.gqa("full_attention")
-    if mesh is not None:
-        raise ConfigError(
-            "attention_class 'eva' is served on one chip: the window close "
-            "and the summary pages have no sharding over a mesh yet (remove "
-            "mesh)")
-    if prefill_chunk <= 0 or w % int(prefill_chunk):
-        raise ConfigError(
-            "attention_class 'eva' prefills in chunks through the cache: set "
-            f"prefill_chunk > 0 to a divisor of window_size {w} (a chunk "
-            f"never straddles a window's end), got {prefill_chunk}")
-    if w % page or (w // c) % page:
-        raise ConfigError(
-            f"attention_class 'eva': page_size {page} divides window_size "
-            f"{w} and a closed window's {w // c} summary rows (whole pages "
-            "turn into whole pages at a close)")
-    if speculative_tokens:
-        raise ConfigError(
-            "speculative_tokens does not compose with attention_class 'eva': "
-            "a verify step that crosses a window's end would pool rejected "
-            "drafts into the summaries, and there is no rollback yet")
-    if prefix_cache_pages:
-        raise ConfigError(
-            "prefix_cache_pages does not compose with attention_class 'eva': "
-            "a closed window's pages are pooled in place and handed back, so "
-            "a finished prompt has no pages by position to donate")
-    if sp.key_parts > 1 or sp.split_heads:
-        raise ConfigError(
-            "attention_class 'eva' pools a window's rows whole: a key held "
-            f"in parts or a head a pool layer (head_dim {sp.dk} on "
-            f"{sp.kv_heads} K/V heads) is not served with it")
-    server.pages_per_slot = eva_table_pages(cfg, page, server.max_seq)
-    if num_pages and num_pages < 1 + server.slots * server.pages_per_slot:
-        raise ConfigError(
-            f"num_pages={num_pages}: a compacting window cache takes its "
-            "pages step by step, so the pool holds every slot's worst case "
-            f"({server.slots} slots x {server.pages_per_slot} pages + scratch)")
-    reg = global_registry()
-    server.m_eva_closes = {
-        phase: reg.counter("arkflow_gen_eva_window_closes_total",
-                           "windows closed (a slot's: every layer pools the "
-                           "window's rows into summary rows, on the device), "
-                           "by the step that wrote the window's last row",
-                           {"model": name, "phase": phase})
-        for phase in ("chunk", "decode")}
-    server.m_eva_rows = {
-        (phase, kind): reg.counter(
-            "arkflow_gen_eva_rows_attended_total",
-            "cache rows the queries of issued steps attended, a layer: exact "
-            "rows of the open window, summary rows of closed windows",
-            {"model": name, "kind": kind, "phase": phase})
-        for phase in ("chunk", "decode") for kind in ("window", "summary")}
-    # a name a kind: a sampler that sums a gauge over its labels keeps them apart
-    server.m_eva_pages = {
-        kind: reg.gauge(f"arkflow_gen_eva_live_pages_{kind}",
-                        f"pages slots hold whose rows are {what}",
-                        {"model": name})
-        for kind, what in (("window", "the open window's exact rows"),
-                           ("summary", "closed windows' summary rows"))}
-
-
-def _eva_account(server: GenerationServer, phase: str, slot: int, first: int,
-                 last: int) -> None:
-    """The books of a step that writes positions ``first..last`` of ``slot``
-    (inside one window), behind the table it carries: the rows its queries
-    attend and, where it writes the window's last row, the close — every
-    page of the window but the first ``window / chunk / page``, which now
-    hold its summaries, goes back to the pool (the device reads them in
-    this step; whoever takes them next writes in a later one)."""
-    cfg = server.cfg
-    w, per = cfg.window_size, cfg.window_size // cfg.chunk_size
-    n = last - first + 1
-    server.m_eva_rows[phase, "summary"].inc(n * (first // w) * per)
-    server.m_eva_rows[phase, "window"].inc(n * (first % w + 1) + n * (n - 1) // 2)
-    if (last + 1) % w:
-        return
-    server.m_eva_closes[phase].inc()
-    pages = server._slot_pages[slot]
-    keep = (last // w + 1) * (per // server.page_size)
-    for p in pages[keep:]:
-        server._unref_page(p)
-    del pages[keep:]
-
-
-def _eva_gauges(server: GenerationServer) -> None:
-    """Pages held by slots, by kind: a slot's first columns are its summary
-    pages (from where its next write sits), the rest its open window's."""
-    cfg = server.cfg
-    per = cfg.window_size // cfg.chunk_size // server.page_size
-    summary = window = 0
-    for s, pages in enumerate(server._slot_pages):
-        if not pages:
-            continue
-        at = server._prefill_pos.get(s, int(server._lengths[s]))
-        held = min(at // cfg.window_size * per, len(pages))
-        summary += held
-        window += len(pages) - held
-    server.m_eva_pages["summary"].set(summary)
-    server.m_eva_pages["window"].set(window)
-
-
-# -- a chunk rides the decode step of a ROUTED model ----------------------------
-# Here, at the END of the file (above: "which expert kernel a step ran"), and
-# called from lines of ``_build_jitted`` that took the place of as many.
-
-#: the label values of the routing series (``m_moe``). ``decode`` is a
-#: ``_decode`` execution and takes nothing from a fused step (the
-#: benchmark's roofline readers divide it into that program's kernel time);
-#: ``chunk`` a prompt's chunks, fused or alone, summed on the device and
-#: recorded with its first token; ``prefill`` a one-shot prefill. A fused
-#: step's BLOCK — what lanes or chunk hit, what its expert products read —
-#: is ``fused``, its lanes alone ``fused_lanes``. The further counters
-#: (``_extra_counters``) have no one-shot series: ``_STEP_KINDS``.
-_STEP_KINDS = ("decode", "chunk", "fused", "fused_lanes")
-_MOE_KINDS = (*_STEP_KINDS, "prefill")
-
-
-@dataclass(frozen=True)
-class _FusedLayout:
-    """What EVERY step of a server that fuses a routed model returns: one
-    int32 array of one shape whichever program made it, so that a step takes
-    the step before's output on the device whatever that was — the lanes
-    their tokens (``prev``), a prompt its counters so far
-    (``req.chunk_moe``) — and one fetch brings all of it:
-
-        [the lanes' tokens: slots | the prompt's token, its counters so far
-         (``_chunk_counts``): 2 + n | the lanes' counters: n | the block's: n]
-
-    ``n`` the counters of one ``moe_step_stats``. A decode step leaves the
-    prompt's and the block's places empty, a chunk alone the lanes' and the
-    block's."""
-
-    slots: int
-    n: int
-
-    @property
-    def size(self) -> int:
-        return self.slots + 2 + 3 * self.n
-
-    @property
-    def lanes_at(self) -> int:
-        return self.slots + 2 + self.n
-
-    def decode(self, stats) -> list:
-        """What follows a decode step's tokens."""
-        return [jnp.zeros(2 + self.n, jnp.int32), stats, jnp.zeros(self.n, jnp.int32)]
-
-    def so_far(self, out):
-        """The prompt's place in the output of the step before."""
-        return out[self.slots:self.lanes_at]
-
-    def chunk(self, out):
-        """A chunk's own output (its token, the prompt's counters) in place."""
-        return jnp.concatenate([jnp.zeros(self.slots, jnp.int32), out,
-                                jnp.zeros(2 * self.n, jnp.int32)])
-
-    def fused(self, before, stats) -> list:
-        """What follows a fused step's ``slots + 1`` tokens: the prompt's
-        counters with this chunk's added (``stats`` [3, n]: the lanes', the
-        chunk's, the block's), the lanes', the block's."""
-        return [_chunk_counts(self.so_far(before), stats[1]), stats[0], stats[2]]
-
-
-def _fused_layout(server: GenerationServer) -> Optional[_FusedLayout]:
-    """The one output layout of a server that fuses a routed model; None for
-    every other server, whose steps return what they always did."""
-    if not (server._fuses and server.cfg.routed):
-        return None
-    return _FusedLayout(server.slots, 3 + len(server._extra_counters))
-
-
-def _note_step_moe(server: GenerationServer, nxt, rode: int) -> None:
-    """Record a decode-kind step's routing counters by the program that ran:
-    a ``_decode`` execution under ``decode``; a fused step that carried
-    ``rode`` chunk rows under ``fused`` (its block, whose rows decide the
-    expert kernel) and ``fused_lanes`` — the chunk's part rides on with the
-    prompt's counters (``chunk``, at its first token)."""
-    lay = server._lay
-    if not rode:
-        server._note_moe("decode", nxt[lay.lanes_at if lay else server.slots:],
-                         rows=server.slots)
-        return
-    server._note_moe("fused_lanes", nxt[lay.lanes_at:], rows=0)
-    server._note_moe("fused", nxt[lay.lanes_at + lay.n:], rows=server.slots + rode)

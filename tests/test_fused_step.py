@@ -514,7 +514,6 @@ def test_a_fused_step_counts_its_expert_product_by_its_block(monkeypatch):
     products took the block's rows (lanes + chunk) and are counted once, under
     ``fused``, by that row count; a decode step's by the lanes alone."""
     from arkflow_tpu.ops import moe_experts
-    from arkflow_tpu.tpu.serving import _note_step_moe
 
     name = "moe-grouped-fused"
     server = _server(ROUTED_LATENT, name=name)
@@ -523,8 +522,8 @@ def test_a_fused_step_counts_its_expert_product_by_its_block(monkeypatch):
     monkeypatch.setattr(moe_experts, "runs_grouped", lambda rows: rows > 5)
     lay = server._lay
     nxt = np.arange(lay.size)
-    _note_step_moe(server, nxt, 4)      # 3 lanes + 4 chunk rows: above "a tile"
-    _note_step_moe(server, nxt, 0)      # 3 lanes
+    server._note_step_moe(nxt, 4)       # 3 lanes + 4 chunk rows: above "a tile"
+    server._note_step_moe(nxt, 0)       # 3 lanes
     grouped = {kind: _counter("arkflow_gen_moe_grouped_products_total", name,
                               kind=kind) for kind in ("fused", "fused_lanes", "decode")}
     assert grouped == {"fused": server._moe_layers, "fused_lanes": 0, "decode": 0}

@@ -448,6 +448,52 @@ def test_dispatcher_shadow_cadence_is_deterministic():
     assert ClusterDispatcher(urls, shadow_verify={})._shadow_every == 20
 
 
+def test_shadow_divergence_that_no_probe_explains_delivers_neither_answer():
+    """Two batches diverge at once on one corrupt worker (two pipeline
+    threads): the first tiebreak's probe makes the worker repair ON THE SPOT,
+    so the second tiebreak's probes both come back clean — after the corrupt
+    answer was given. One of the two answers is wrong and no probe says
+    which: neither is delivered, the batch fails over to redelivery."""
+    from arkflow_tpu.batch import MessageBatch
+    from arkflow_tpu.errors import ConnectError
+    from arkflow_tpu.runtime.cluster import ClusterDispatcher
+
+    disp = ClusterDispatcher(["arkflow://h:1", "arkflow://h:2"],
+                             shadow_verify={"fraction": 1.0})
+    primary, shadow = disp.workers.values()
+    batch = MessageBatch.new_binary([b"row"])
+    answers = {primary.url: [MessageBatch.new_binary([b"garbled"])],
+               shadow.url: [MessageBatch.new_binary([b"clean"])]}
+
+    async def attempt(w, b, **kw):
+        return answers[w.url]
+
+    async def clean_probe(w, req, timeout=None):
+        return {"ok": True, "mismatches": 0, "corrupt": 0}
+
+    disp._attempt, disp._unary = attempt, clean_probe
+    with pytest.raises(ConnectError, match="neither answer is delivered"):
+        asyncio.run(disp._attempt_shadow(primary, shadow, batch))
+    assert disp.m_shadow["diverged"].value >= 1
+    assert disp.m_integrity_fence.value == 0    # nobody was proven corrupt
+
+    async def clean_side_unreachable(w, req, timeout=None):
+        if w is shadow:
+            raise ConnectError("probe timed out")
+        return await clean_probe(w, req)
+
+    disp._unary = clean_side_unreachable   # ... and the repaired one passes
+    with pytest.raises(ConnectError, match="neither answer is delivered"):
+        asyncio.run(disp._attempt_shadow(primary, shadow, batch))
+
+    async def primary_fails_probe(w, req, timeout=None):
+        return {"ok": True, "mismatches": int(w is primary), "corrupt": 0}
+
+    disp._unary = primary_fails_probe
+    out = asyncio.run(disp._attempt_shadow(primary, shadow, batch))
+    assert out is answers[shadow.url] and disp.m_integrity_fence.value == 1
+
+
 def test_dispatcher_fences_self_reported_corrupt_worker():
     """A heartbeat carrying integrity_corrupt > 0 fences that worker's
     incarnation immediately (no probe needed — the worker proved it

@@ -181,6 +181,7 @@ def fresh_jaxcache(monkeypatch):
 
     old_dir = jax.config.jax_compilation_cache_dir
     old_min = jax.config.jax_persistent_cache_min_compile_time_secs
+    old_frames = jax.config.jax_traceback_in_locations_limit
     monkeypatch.setattr(jaxcache, "_attempted", False)
     monkeypatch.setattr(jaxcache, "_configured", None)
     monkeypatch.delenv("ARKFLOW_JAX_CACHE", raising=False)
@@ -188,6 +189,39 @@ def fresh_jaxcache(monkeypatch):
     yield jaxcache
     jax.config.update("jax_compilation_cache_dir", old_dir)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", old_min)
+    jax.config.update("jax_traceback_in_locations_limit", old_frames)
+
+
+def test_a_kernel_s_place_in_its_file_is_not_in_the_program(fresh_jaxcache, tmp_path):
+    """What the persistent cache keys on — the program lowered for the chip,
+    a Pallas kernel's serialised body included — is the same text wherever
+    the kernel stands in its file: one of the repo's own kernels, lowered
+    from a copy of its module and from the same copy seven lines lower."""
+    import importlib.util
+
+    import jax
+    import jax.numpy as jnp
+
+    jax.config.update("jax_traceback_in_locations_limit", 10)  # jax's default
+    fresh_jaxcache.enable_persistent_cache()
+    source = open(os.path.join(os.path.dirname(fresh_jaxcache.__file__), "..",
+                               "ops", "topk_select.py")).read()
+    lower = "\ndef _select_kernel"
+    assert source.count(lower) == 1
+    texts = []
+    for name, text in (("as_is", source),
+                       ("lower", source.replace(lower, "\n" * 7 + lower))):
+        path = tmp_path / f"topk_select_{name}.py"
+        path.write_text(text)
+        spec = importlib.util.spec_from_file_location(path.stem, path)
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        texts.append(jax.jit(lambda s, p: mod.dsa_topk_select(s, p, k=16)).trace(
+            jax.ShapeDtypeStruct((2, 8, 256), jnp.float32),
+            jax.ShapeDtypeStruct((2, 8), jnp.int32),
+        ).lower(lowering_platforms=("tpu",)).as_text())
+    assert "dsa_topk_select" in texts[0] and "tpu_custom_call" in texts[0]
+    assert texts[0] == texts[1]
 
 
 def test_persistent_cache_placed_by_jax_env(fresh_jaxcache, tmp_path, monkeypatch):
