@@ -1472,28 +1472,42 @@ def _mixer_block(lp: dict, y: jnp.ndarray, cfg: DecoderConfig) -> jnp.ndarray:
     return ssm_output(lp, o, x, z, cfg, y.dtype)
 
 
+def conv_gate(lp: dict, y: jnp.ndarray):
+    """A conv mixer's input product over normed activations ``y`` [B, S,
+    dim]: ``[B | C | u] = y W_in``. Returns (the output gate ``C`` float32,
+    the gated input ``v = B * u`` rounded to the activations' type: what a
+    sequence caches)."""
+    d = y.shape[-1]
+    bcu = cm.dense(lp["conv_in"], y)
+    gate_b, gate_c, u = (bcu[..., i * d:(i + 1) * d].astype(jnp.float32)
+                         for i in range(3))
+    return gate_c, (gate_b * u).astype(bcu.dtype)
+
+
+def conv_taps(lp: dict, ext: jnp.ndarray, s: int, cfg: DecoderConfig):
+    """The depthwise causal conv ``c_t = sum_j w[:, j] v_{t - (L - 1) + j}``
+    of the last ``s`` positions of ``ext`` [B, L - 1 + s, dim] (the gated
+    inputs before a block, then the block's), in float32 with no activation."""
+    w = lp["conv_w"].astype(jnp.float32)                          # [dim, L]
+    return sum(ext[:, j:j + s].astype(jnp.float32) * w[:, j]
+               for j in range(cfg.conv_L_cache))
+
+
 def short_conv(lp: dict, y: jnp.ndarray, cfg: DecoderConfig, before=None):
     """A conv layer's mixer over normed activations ``y`` [B, S, dim]:
-    ``[B | C | u] = y W_in``, the gated input ``v = B * u`` (rounded to the
-    activations' type: what a sequence caches), the depthwise causal conv
-    ``c_t = sum_j w[:, j] v_{t - (L - 1) + j}`` in float32 with no
-    activation, ``C * c``, then ``W_out``. ``before`` [B, L - 1, dim]: the
+    ``[B | C | u] = y W_in``, the gated input ``v = B * u`` (``conv_gate``),
+    the depthwise causal conv over it (``conv_taps``), ``C * c``, then
+    ``W_out``. ``before`` [B, L - 1, dim]: the
     gated inputs of the positions before the block, oldest first (None: the
     sequence starts here, zeros). Returns (the mixer's output [B, S, dim],
     ``before`` and the block's gated inputs joined [B, L - 1 + S, dim])."""
     b, s, d = y.shape
-    taps = cfg.conv_L_cache
-    bcu = cm.dense(lp["conv_in"], y)
-    gate_b, gate_c, u = (bcu[..., i * d:(i + 1) * d].astype(jnp.float32)
-                         for i in range(3))
-    v = (gate_b * u).astype(bcu.dtype)
+    gate_c, v = conv_gate(lp, y)
     if before is None:
-        before = jnp.zeros((b, taps - 1, d), v.dtype)
+        before = jnp.zeros((b, cfg.conv_L_cache - 1, d), v.dtype)
     ext = jnp.concatenate([before.astype(v.dtype), v], axis=1)
-    w = lp["conv_w"].astype(jnp.float32)                          # [dim, L]
-    conved = sum(ext[:, j:j + s].astype(jnp.float32) * w[:, j]
-                 for j in range(taps))
-    return cm.dense(lp["conv_out"], (gate_c * conved).astype(bcu.dtype)), ext
+    conved = conv_taps(lp, ext, s, cfg)
+    return cm.dense(lp["conv_out"], (gate_c * conved).astype(v.dtype)), ext
 
 
 def _norm(p: dict, x: jnp.ndarray, cfg: DecoderConfig) -> jnp.ndarray:

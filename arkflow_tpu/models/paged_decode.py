@@ -56,7 +56,8 @@ from dataclasses import dataclass
 
 from arkflow_tpu.models.decoder import (CONV, FULL, LINEAR, SLIDING,
                                         DecoderConfig, _mlp, _norm, _scaled,
-                                        attn_out_gate, gdn_conv, gdn_operands,
+                                        attn_out_gate, conv_gate, conv_taps,
+                                        gdn_conv, gdn_operands,
                                         gdn_output, gdn_project, hc_collapse,
                                         hc_expand, hc_post, hc_pre,
                                         index_project, index_scores,
@@ -274,7 +275,8 @@ _BY_RUNS = {
 }
 _FUSED = (
     "a chunk rides a decode step only on a per-head K/V model with a dense "
-    "MLP or a plain latent-attention model with routed experts (pools "
+    "MLP or routed experts, with conv layers among its attention layers or "
+    "none, or a plain latent-attention model with routed experts (pools "
     "{pools}): ")
 _PATTERN = {
     # sliding or indexed layers: rows of more than one kind and lifetime
@@ -354,9 +356,6 @@ _STATE = {
         "state: a model with the hybrid block (mamba_d_ssm > 0), conv layers "
         "or linear_attention layers (pools {pools}: a state a slot beside "
         "the K/V pages) generates through serving: continuous only"),
-    "fused_chunk": _FUSED + (
-        "a state a sequence wants its row of state slots, which the block "
-        "does not carry"),
     "run_ahead_eos": (
         "a lane that ended on an EOS rides the step behind, which would "
         "advance its state past its sequence's end (for K/V it only leaves "
@@ -364,6 +363,13 @@ _STATE = {
 }
 # conv and linear_attention layers stand AMONG the attention layers: by runs
 _STATE_BY_RUNS = {**_STATE, **_UNVERIFIED}
+# the block carries each part's rows of a state pool (``_by_parts``); a conv
+# layer's windows are all its state. A scan is not split yet:
+_SCANNED = {"fused_chunk": _FUSED + (
+    "the block carries each part's row of the state pool, but a mixer's "
+    "chunk scan beside its one-token update in one program (the hybrid "
+    "block's, a linear_attention layer's) is not written yet, and no served "
+    "cell could show its gain")}
 _SWITCH = (
     "the Switch layer queues a step's live lanes into shared expert "
     "capacity, so a lane that joins a step later, or a chunk beside the "
@@ -394,9 +400,9 @@ UNSERVED = {
     "latent": _LATENT,
     "index": _PATTERN,    # beside ``latent`` only, which says the rest
     "window": _WINDOW,
-    "ssm": _STATE,
+    "ssm": {**_STATE, **_SCANNED},
     "conv": _STATE_BY_RUNS,
-    "gdn": _STATE_BY_RUNS,
+    "gdn": {**_STATE_BY_RUNS, **_SCANNED},
     "eva": {
         **_BY_RUNS,
         "mesh_tp": (
@@ -439,13 +445,7 @@ UNSERVED = {
             "a key held in parts) or sizes by kind, which have no wire form "
             "yet — such a model prefills and decodes on the same server"),
     },
-    "routed": {
-        **_BY_RUNS,
-        "fused_chunk": _FUSED + (
-            "no served model has routed experts beside per-head K/V without "
-            "a layer pattern or a state beside them, so nothing could show a "
-            "gain"),
-    },
+    "routed": _BY_RUNS,
     "qk_norm": _BY_RUNS,
     "switch": {"fused_chunk": _FUSED + _SWITCH, "run_ahead": _SWITCH,
                "run_ahead_eos": _SWITCH},
@@ -800,12 +800,7 @@ def _latent_layers(params: dict, cfg: DecoderConfig, x, k_pages, v_pages,
     x, k_pages, v_pages, picked = carry
     if hc:
         x = hc_collapse(x, cfg)
-    loads, held = jnp.concatenate(loads), cfg.experts_held and cfg.held
-    if chunk is None:
-        stats = moe_step_stats(loads, held)
-    else:  # [layers, 2, E]: by row range, and the block's
-        stats = jnp.stack([moe_step_stats(part, held) for part in (
-            loads[:, 0], loads[:, 1], loads.sum(axis=1))])
+    stats = _routing_stats(loads, cfg, chunk)
     if cfg.index_topk:
         stats = jnp.concatenate([stats, picked])
     return x, k_pages, v_pages, stats
@@ -1539,12 +1534,75 @@ class _RidingChunk(NamedTuple):
     ``_latent_layers``): the block's first ``lanes`` tokens are the decode
     step's, a row each;
     the rest are one prompt's chunk, one row under its own ``table`` [1, P],
-    ``off`` [1] and ``mask``."""
+    ``off`` [1] and ``mask``. A model that caches a state a sequence: the
+    chunk's row of the state pool ``rows`` [1] and whether it starts its
+    sequence, ``fresh`` [1] (``paged_prefill_chunk``'s two; the lanes' are
+    ``_dense_layers``' ``ssm_rows``, a decode step's)."""
 
     lanes: int
     table: object
     off: object
     mask: object
+    rows: object = None
+    fresh: object = None
+
+
+def _by_parts(chunk: _RidingChunk, part, state, rows, valid, *per_token):
+    """The half of a state mixer that is a SEQUENCE'S, over a fused step's
+    block [1, lanes + C]: ``part(state, rows, fresh, valid, *per_token) ->
+    (out, state)`` first over the lanes as [lanes, 1] rows under ``rows``
+    [lanes] and no ``fresh`` (a decode step's rules), then over the chunk as
+    one row [1, C] under ``chunk.rows`` / ``chunk.fresh`` (a chunk's), the
+    state pool handed from the one to the other as the two steps hand it.
+    ``valid`` [1, lanes + C] and every ``per_token`` array [1, lanes + C,
+    ...] are cut likewise; the outputs come back joined, [1, lanes + C, ...].
+    The prompt's own lane is not active, so the parts write no pool row in
+    common but the scratch row 0."""
+    n = chunk.lanes
+    out, state = part(state, rows, None, valid[0, :n, None],
+                      *(a[0, :n, None] for a in per_token))
+    behind, state = part(state, chunk.rows, chunk.fresh, valid[:, n:],
+                         *(a[:, n:] for a in per_token))
+    return jax.tree_util.tree_map(
+        lambda a, b: jnp.concatenate([a.reshape(1, n, *a.shape[2:]), b], axis=1),
+        out, behind), state
+
+
+def _conv_fused(lp: dict, y, cfg: DecoderConfig, windows, layer, rows, valid,
+                chunk: _RidingChunk):
+    """``_conv_paged`` over a fused step's block ``y`` [1, lanes + C, dim]:
+    the mixer's two weight products (``conv_in``, ``conv_out``) run ONCE
+    over the block's rows; between them each part reads its rows' windows,
+    sums its taps and writes its windows back under its own step's rules
+    (``_by_parts``: the lanes under ``rows``, the chunk under its own row
+    and ``fresh``). Returns (the mixer's output, windows)."""
+    keep = cfg.conv_L_cache - 1
+    gate_c, v = conv_gate(lp, y)
+
+    def part(windows, rows, fresh, valid, v):
+        before = windows[layer, rows]                             # [B, L-1, dim]
+        if fresh is not None:
+            before = jnp.where(fresh[:, None, None], 0, before)
+        ext = jnp.concatenate([before.astype(v.dtype), v], axis=1)
+        return conv_taps(lp, ext, v.shape[1], cfg), windows.at[layer, rows].set(
+            _last_valid(ext, valid, keep).astype(windows.dtype))
+
+    conved, windows = _by_parts(chunk, part, windows, rows, valid, v)
+    return cm.dense(lp["conv_out"], (gate_c * conved).astype(v.dtype)), windows
+
+
+def _routing_stats(loads: list, cfg: DecoderConfig,
+                   chunk: Optional[_RidingChunk]):
+    """A step's routing counters from its expert layers' loads
+    (``moe_step_stats``); of a fused step by row range, [3, ...]: the
+    lanes', the chunk's, and the block's own (their loads summed: what the
+    expert products read)."""
+    loads, held = jnp.concatenate(loads), cfg.experts_held and cfg.held
+    if chunk is None:
+        return moe_step_stats(loads, held)
+    # [layers, 2, E]: by row range (``route_topk``'s ``lanes``)
+    return jnp.stack([moe_step_stats(part, held) for part in (
+        loads[:, 0], loads[:, 1], loads.sum(axis=1))])
 
 
 def _dense_layers(params: dict, cfg: DecoderConfig, x, k_pages, v_pages,
@@ -1594,7 +1652,11 @@ def _dense_layers(params: dict, cfg: DecoderConfig, x, k_pages, v_pages,
     chunk — through every weight product, and ``chunk`` says how it attends:
     the lanes as [lanes, 1] queries under ``page_table`` / ``off`` /
     ``mask``, the chunk as [1, C] under its own (``_RidingChunk``), both
-    after every token's K/V is written.
+    after every token's K/V is written. A conv layer's windows are read,
+    summed and written a part at a time too (``_conv_fused``: the lanes
+    under ``ssm_rows``, a decode step's, the chunk under its own row and
+    ``fresh``), and a routed model's counters come back by row range, [3,
+    ...] (``_routing_stats``).
 
     ``eva`` (a compacting window cache): ``page_idx`` / ``offset`` / ``off``
     / ``mask`` are in CACHE ROWS (``eva_rows``), ``positions`` stay the
@@ -1606,6 +1668,7 @@ def _dense_layers(params: dict, cfg: DecoderConfig, x, k_pages, v_pages,
     counters (``moe_step_stats``)."""
     b, t = positions.shape
     kernel = attention_kernel == "paged"
+    lanes = 0 if chunk is None else chunk.lanes
     kept, ring = page_table if isinstance(page_table, tuple) else (page_table, None)
     page = _page_size(k_pages)
     ctx = kept.shape[1] * page
@@ -1622,7 +1685,7 @@ def _dense_layers(params: dict, cfg: DecoderConfig, x, k_pages, v_pages,
             # the kernel indexes the layer itself (``_latent_layers``)
             out, load = routed_mlp(lp, y, cfg, token_mask=token_mask,
                                    kernel=kernel, interpret=kernel_interpret,
-                                   stacked=(experts, ei[0]))
+                                   stacked=(experts, ei[0]), lanes=lanes)
             return (x + out, kp, vp), load
 
         if kind == CONV:
@@ -1631,9 +1694,13 @@ def _dense_layers(params: dict, cfg: DecoderConfig, x, k_pages, v_pages,
                 (``_conv_paged``), no K/V written or read."""
                 x, kp, vp = carry
                 lp, li, *ei = scanned
-                mixed, windows = _conv_paged(
-                    lp, cm.rms_norm(lp["attn_norm"], x, cfg.norm_eps), cfg,
-                    kp["conv"], li, ssm_rows, ssm_fresh, token_mask)
+                y = cm.rms_norm(lp["attn_norm"], x, cfg.norm_eps)
+                if chunk is None:
+                    mixed, windows = _conv_paged(lp, y, cfg, kp["conv"], li,
+                                                 ssm_rows, ssm_fresh, token_mask)
+                else:  # the products once, the windows a part at a time
+                    mixed, windows = _conv_fused(lp, y, cfg, kp["conv"], li,
+                                                 ssm_rows, token_mask, chunk)
                 return ffn(lp, x + mixed, {**kp, "conv": windows}, vp, ei)
 
             return conv_layer
@@ -1701,7 +1768,6 @@ def _dense_layers(params: dict, cfg: DecoderConfig, x, k_pages, v_pages,
             elif chunk is None:
                 attn = attend(q, kept, off, mask)
             else:  # the lanes a row each, then the chunk's row
-                lanes = chunk.lanes
                 attn = jnp.concatenate([
                     attend(q[0, :lanes, None], kept, off, mask).reshape(
                         1, lanes, cfg.heads, sp.dv),
@@ -1731,8 +1797,7 @@ def _dense_layers(params: dict, cfg: DecoderConfig, x, k_pages, v_pages,
             loads.append(load)
     if not loads:
         return carry
-    return (*carry, moe_step_stats(jnp.concatenate(loads),
-                                   cfg.experts_held and cfg.held))
+    return (*carry, _routing_stats(loads, cfg, chunk))
 
 
 def _scan_run(layer, carry, stack: dict, first: int, stop: int,
@@ -2024,14 +2089,18 @@ def paged_decode_step(params: dict, cfg: DecoderConfig, token_ids, lengths,
 def fusable(cfg: DecoderConfig) -> bool:
     """Whether a prompt's chunk can ride a decode step as one row block
     (``paged_fused_step``): a model whose tokens meet only in attention and
-    whose step takes no operand beside ``paged_decode_step``'s and
-    ``paged_prefill_chunk``'s own. Two families, by the layer kinds the
-    configuration states:
+    in a state a sequence that the block carries a row of, and whose step
+    takes no operand beside ``paged_decode_step``'s and
+    ``paged_prefill_chunk``'s own. Three families, by the layer kinds and
+    pools the configuration states:
 
     - per-head K/V, dense MLP (``_dense_layers``' ``chunk``);
     - plain latent attention with routed experts (``_latent_layers``'
       ``chunk``: dropless and per token, so the block's rows route as they
-      do apart; the counters come back by row range).
+      do apart; the counters come back by row range);
+    - per-head K/V with routed experts, and conv layers among the attention
+      layers (the LFM2 layout; ``_dense_layers``' ``chunk`` again: the
+      block carries each part's rows of the conv pool, ``_conv_fused``).
 
     Every other row of ``UNSERVED`` is left out, for an operand or a rule of
     its own that the block does not carry yet: its ``fused_chunk`` cell says
@@ -2043,22 +2112,26 @@ def paged_fused_step(params: dict, cfg: DecoderConfig, token_ids, lengths,
                      active, page_table, input_ids, chunk_off, chunk_len,
                      chunk_table, k_pages, v_pages, return_logits: bool = False,
                      kv_sharding=None, attention_kernel: str = "gather",
-                     kernel_interpret: bool = False):
+                     kernel_interpret: bool = False, ssm_rows=None):
     """One decode step over all serving slots AND one chunk of a prompt that
     is still prefilling, in one pass over the weights: ``paged_decode_step``'s
     operands (``token_ids`` / ``lengths`` / ``active`` [S], ``page_table``
     [S, P]) and ``paged_prefill_chunk``'s for one row (``input_ids`` [1, C],
-    ``chunk_off`` / ``chunk_len`` [1], ``chunk_table`` [1, P]). The prompt's
-    own lane is not active, so the two parts write no page in common.
+    ``chunk_off`` / ``chunk_len`` [1], ``chunk_table`` [1, P], and of a model
+    that caches a state a sequence ``ssm_rows`` [1], the chunk's row of the
+    state pool). The prompt's own lane is not active, so the two parts write
+    no page — and no row of a state pool but the scratch row — in common.
 
     The S + C tokens run as ONE row block [1, S + C, dim] through every
     norm and weight product, so a layer's weights are read once for both —
     of routed experts, every expert that lanes OR chunk hit once —;
     attention is the two steps' own two calls (``_dense_layers``' /
-    ``_latent_layers``' ``chunk``), and the head multiplies S + 1 rows: the
-    lanes and the chunk's last true position. A decode step and a chunk are
-    each bound by the weights' bytes; folded, the chunk's rows cost their
-    attention.
+    ``_latent_layers``' ``chunk``), a conv layer's windows are each part's
+    own under its own step's rules (lane s the pool's row s + 1, an idle
+    lane the scratch row; the chunk its row, from zeros where ``chunk_off``
+    is 0), and the head multiplies S + 1 rows: the lanes and the chunk's
+    last true position. A decode step and a chunk are each bound by the
+    weights' bytes; folded, the chunk's rows cost their attention.
 
     Returns (logits [S + 1, vocab] — tokens with ``return_logits`` false —,
     k_pages, v_pages): row S is the prompt's next token where the chunk was
@@ -2085,12 +2158,20 @@ def paged_fused_step(params: dict, cfg: DecoderConfig, token_ids, lengths,
     chunk_mask = key_pos[None, None, None, :] <= chunk_pos[:, None, :, None]
     x = _scaled(cm.embedding(params["embed"], row(token_ids, input_ids)),
                 cfg.embedding_multiplier)                         # [1, S + C, D]
+    riding = _RidingChunk(s, chunk_table, chunk_off, chunk_mask)
     operands = dict(
         page_table=page_table, off=lengths, mask=lane_mask, block=False,
-        attention_kernel=attention_kernel, kernel_interpret=kernel_interpret,
-        chunk=_RidingChunk(s, chunk_table, chunk_off, chunk_mask))
+        attention_kernel=attention_kernel, kernel_interpret=kernel_interpret)
     if not cfg.latent:
         operands["kv_sharding"] = kv_sharding
+    if cfg.stateful:
+        # the state rows of each part, as its own step names them: the
+        # chunk's under ``paged_prefill_chunk``'s rule, the lanes' under
+        # ``paged_decode_step``'s
+        its = _ssm_operands(cfg, ssm_rows, chunk_off == 0)
+        riding = riding._replace(rows=its["ssm_rows"], fresh=its["ssm_fresh"])
+        operands["ssm_rows"] = jnp.where(active, jnp.arange(s) + 1, 0)
+    operands["chunk"] = riding
     # a routed model appends its counters, by row range
     x, new_k, new_v, *moe = (_latent_layers if cfg.latent else _dense_layers)(
         params, cfg, x, k_pages, v_pages, row(lengths, chunk_pos),

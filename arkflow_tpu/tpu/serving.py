@@ -1057,16 +1057,20 @@ class GenerationServer:
             return out, kp, vp, *key
 
         def _fused(params, packed, kp, vp, *dev):
-            lanes = self.slots * (3 + pages)
-            tok, lens, act, table = unpack_operands(packed[:lanes], self.slots, pages)
-            ids, off, clen, its_table = unpack_operands(packed[lanes:], 1, pages)
+            lanes = self.slots * (3 + pages + state)
+            tok, lens, act, table = unpack_operands(
+                packed[:lanes], self.slots, pages, state=state)[:4]
+            # the chunk's held row of the state pool, as ``_chunk``'s
+            ids, off, clen, its_table, *held = unpack_operands(
+                packed[lanes:], 1, pages, state=state)
             tok = tok[:, 0]
             if piped:
                 prev, *dev = dev
                 tok = jnp.where(tok < 0, prev[:self.slots], tok)
             logits, kp, vp, *stats = paged_fused_step(
                 params, cfg, tok, lens, act != 0, table, ids, off, clen,
-                its_table, kp, vp, return_logits=True, kv_sharding=kv, **kern)
+                its_table, kp, vp, return_logits=True, kv_sharding=kv, **kern,
+                ssm_rows=next(iter(held), None))
             dev, stats = (dev[1:], lay.fused(dev[0], *stats)) if lay else (dev, stats)
             out, *key = _pick(logits, dev, *stats)
             return out, kp, vp, *key
@@ -2275,7 +2279,8 @@ class GenerationServer:
         lanes and that prompt's next chunk through one pass over the weights
         — where the server fuses (``_fuses``: greedy, chunked prefill, a
         model that ``paged_decode.fusable`` admits: per-head K/V with a dense
-        MLP, or plain latent attention with routed experts), and else a
+        MLP or with routed experts, conv layers among its attention layers or
+        none, or plain latent attention with routed experts), and else a
         chunk and a decode step in turn. A
         prompt that stops after prefill keeps its last chunk's own step, a
         one-shot prefill and a speculative step their own too."""

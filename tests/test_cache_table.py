@@ -25,7 +25,7 @@ from arkflow_tpu.tpu.serving import GenerationServer
 from tests.test_conv_gqa_moe import TINY as CONV
 from tests.test_eva_decoder import SIZES as EVA
 from tests.test_gdn_gqa_moe import TINY as GDN
-from tests.test_gen_run_ahead import ALTERNATING as ROUTED
+from tests.test_gen_run_ahead import PER_HEAD_ROUTED as ROUTED
 from tests.test_hetero_gqa_moe import DENSE
 from tests.test_hybrid_ssm import TINY as HYBRID
 from tests.test_mhc_mla_moe import TINY as STREAMS

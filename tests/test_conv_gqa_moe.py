@@ -366,6 +366,83 @@ def test_chunked_prefill_then_decode_matches_reference(all_params, exact, name,
 
 
 @KERNELS
+@pytest.mark.parametrize("name,chunk", [("h64", 12), ("h64", 20), ("h32", 12)])
+def test_a_chunk_riding_the_decode_step_matches_reference(all_params, exact, name,
+                                                          chunk, kern):
+    """``paged_fused_step`` against the float32 reference: rows 0 and 1 are
+    prefilled, then every chunk of row 2 (53 tokens: whole chunks and a
+    short last one, the first from a slot whose conv rows hold NOISE) RIDES
+    a decode step of rows 0 and 1, fed their own tokens; then all three
+    decode. Every step's logits — the lanes' and, behind the prompt's last
+    chunk, its own — are the reference's full-forward logits, the counters
+    by row range a hand count, and the conv windows left are the reference's
+    gated inputs of the last two positions fed."""
+    cfg, p = CONFIGS[name], all_params[name]
+    lens, pages_per = [41, 26, 53], 10
+    rides = -(-lens[2] // chunk)
+    new = rides + 2
+    rows = [np.random.RandomState(61 + r).randint(1, 128, n + new).astype(np.int32)
+            for r, n in enumerate(lens)]
+    kept = jnp.asarray(np.random.RandomState(2).permutation(
+        np.arange(1, 1 + 3 * pages_per)).reshape(3, pages_per), jnp.int32)
+    kp, vp = jax.tree_util.tree_map(
+        lambda a: a.astype(jnp.float32),
+        init_page_pool(cfg, 1 + 3 * pages_per, PAGE, slots=3))
+    kp = {**kp, "conv": jnp.full_like(kp["conv"], 7.0)}       # earlier tenants
+    chunked = jax.jit(lambda p, *a, ssm_rows: paged_prefill_chunk(
+        p, cfg, *a, ssm_rows=ssm_rows, **kern))
+    fused = jax.jit(lambda p, *a, ssm_rows: pd.paged_fused_step(
+        p, cfg, *a, return_logits=True, ssm_rows=ssm_rows, **kern))
+    step = jax.jit(lambda p, *a: paged_decode_step(
+        p, cfg, *a, return_logits=True, **kern))
+
+    def padded(r, off):
+        c = rows[r][off:min(off + chunk, lens[r])]
+        ids = np.zeros((1, chunk), np.int32)
+        ids[0, :len(c)] = c
+        return jnp.asarray(ids), jnp.asarray([off]), jnp.asarray([len(c)])
+
+    got = [[] for _ in lens]
+    for r in (0, 1):
+        for off in range(0, lens[r], chunk):
+            logits, kp, vp, _ = chunked(p, *padded(r, off), kept[r:r + 1], kp, vp,
+                                        ssm_rows=jnp.asarray([r + 1]))
+        got[r].append(np.asarray(logits)[0])
+    cur = np.asarray(lens, np.int32)
+    for i in range(new - 1):
+        act = np.asarray([True, True, i >= rides])
+        tok = jnp.asarray([rows[r][lens[r] + i - (rides if r == 2 else 0)] if act[r]
+                           else 0 for r in range(3)])
+        operands = (tok, jnp.asarray(np.where(act, cur, 0)), jnp.asarray(act), kept)
+        if i < rides:
+            ids, off, clen = padded(2, i * chunk)
+            logits, kp, vp, stats = fused(p, *operands, ids, off, clen, kept[2:3],
+                                          kp, vp, ssm_rows=jnp.asarray([3]))
+            pairs, hit, load = np.asarray(stats).T
+            per = 2 * cfg.expert_layers
+            assert list(pairs) == [2 * per, int(clen[0]) * per,
+                                   (2 + int(clen[0])) * per]
+            assert max(hit[:2]) <= hit[2] <= hit[0] + hit[1]
+            if i == rides - 1:  # the prompt's own next token
+                got[2].append(np.asarray(logits)[3])
+        else:
+            logits, kp, vp, _ = step(p, *operands, kp, vp)
+        for r in np.flatnonzero(act):
+            got[r].append(np.asarray(logits)[r])
+        cur += act
+    hp = ref.hyper(cfg)
+    for r, n in enumerate(lens):
+        fed = n + len(got[r]) - 1
+        want = _reference(p, rows[r][:fed], cfg)
+        np.testing.assert_allclose(np.stack(got[r]), want[n - 1:], atol=EXACT)
+        with jax.default_matmul_precision("highest"):
+            gated = ref.decoder_logits(p, jnp.asarray(rows[r][:fed]), 0, new=1,
+                                       hp=hp, state_at=fed - 2)[2]
+        np.testing.assert_allclose(np.asarray(kp["conv"][:, r + 1]),
+                                   np.asarray(gated), atol=EXACT)
+
+
+@KERNELS
 @pytest.mark.parametrize("into", [1, 2, 3])
 def test_a_prompt_that_ends_just_into_a_chunk_crosses_the_seam(params, exact,
                                                                kern, into):

@@ -4,9 +4,11 @@ Where a decode step is due and a slot is prefilling, a greedy server that
 prefills in chunks issues ONE program for both
 (``paged_decode.paged_fused_step``, ``GenerationServer._step(active,
 riding)``) on a model that ``paged_decode.fusable`` admits — per-head K/V
-with a dense MLP, or plain latent attention with routed experts —: the lanes
+with a dense MLP or routed experts, conv layers among its attention layers
+or none, or plain latent attention with routed experts —: the lanes
 and the chunk run as one row block through every weight product, attention
-is the two steps' own two calls, and a routed model's counters come back by
+is the two steps' own two calls, a conv layer's windows are each part's own
+rows of the conv pool, and a routed model's counters come back by
 row range. What is served must be what a chunk and a decode step in turn
 serve, request by request; every other model, a sampling and a speculative
 server keep alternating and build no such program; the step takes one host
@@ -64,15 +66,33 @@ PATTERN = dict(
     swa_rope_theta=5e3, index_n_heads=4, index_head_dim=8, index_topk=16)
 STREAMS = dict(hc_mult=2, hc_sinkhorn_iters=20, hc_eps=1e-6,
                mhc_h_res_clamp_min=-30, mhc_h_res_clamp_max=30)
+#: routed experts on the per-head loop, and conv layers among the attention
+#: layers with a dense MLP: each half of the LFM2 layout alone
+ROUTED = {**DENSE, **EXPERTS}
+CONV = dict(DENSE, layers=3, layer_types=("conv", FULL, "conv"), conv_L_cache=3)
+#: the LFM2 layout: conv, conv, full, conv; two dense layers, then routed
+#: under a selection bias, no shared expert, per-head norms. (Experts of 24:
+#: at 16 this seed's bfloat16 logits TIE exactly — 1.140625 twice — at two
+#: of the served tokens, and the two compiled programs, equal to the last
+#: bit run op by op, break an exact tie differently)
+CONV_ROUTED = dict(DENSE, layers=4, layer_types=("conv", "conv", FULL, "conv"),
+                   conv_L_cache=3, qk_norm=True, n_routed_experts=8,
+                   num_experts_per_tok=2, n_shared_experts=0,
+                   moe_intermediate_size=24, first_k_dense_replace=2,
+                   norm_topk_eps=1e-6, router_bias_std=0.1)
 #: name -> (model, server options): what does NOT let a chunk ride
 ALTERNATES = {
     "latent-pattern": ({**ROUTED_LATENT, **PATTERN}, {}),
     "latent-streams": ({**ROUTED_LATENT, **STREAMS}, {}),
-    "routed": ({**DENSE, **EXPERTS}, {}),
+    "routed-pattern": (dict(ROUTED, layer_types=(SLIDING, FULL), sliding_window=9),
+                       {}),
     "switch": (dict(DENSE, num_experts=4), {}),
     "hybrid": (HYBRID, {}),
-    "conv": (dict(DENSE, layers=3, layer_types=("conv", FULL, "conv"),
-                  conv_L_cache=3), {}),
+    "linear": (dict(DENSE, layers=3, layer_types=("linear_attention", FULL,
+                                                  "linear_attention"),
+                    linear_num_key_heads=2, linear_num_value_heads=4,
+                    linear_key_head_dim=8, linear_value_head_dim=16,
+                    linear_conv_kernel_dim=4), {}),
     "layered": (dict(DENSE, layer_types=(SLIDING, FULL), sliding_window=9), {}),
     "speculative": (DENSE, dict(speculative_tokens=2)),
     "sampling": (DENSE, dict(temperature=1.2, top_k=8, seed=42)),
@@ -158,39 +178,49 @@ def _chunks(name: str) -> dict:
 # -- (b) the program: one pass is the two steps -----------------------------------------
 
 
-@pytest.mark.parametrize("clen", [8, 5], ids=["whole-chunk", "last-chunk"])
+@pytest.mark.parametrize("clen,start", [(8, 8), (5, 8), (8, 0)],
+                         ids=["whole-chunk", "last-chunk", "first-chunk"])
 @pytest.mark.parametrize("model_kw,kern", [
     (DENSE, "gather"), (DENSE, "paged"), (WIDE, "paged"),
-    (ROUTED_LATENT, "gather"), (ROUTED_LATENT, "paged")],
+    (ROUTED_LATENT, "gather"), (ROUTED_LATENT, "paged"),
+    (ROUTED, "gather"), (CONV, "gather"),
+    (CONV_ROUTED, "gather"), (CONV_ROUTED, "paged")],
     ids=["gather", "narrow-head-kernel", "wide-head-kernel", "latent-gather",
-         "latent-kernel"])
-def test_fused_step_is_a_decode_step_then_a_chunk(model_kw, kern, clen):
+         "latent-kernel", "routed-gather", "conv-gather", "conv-routed-gather",
+         "conv-routed-kernel"])
+def test_fused_step_is_a_decode_step_then_a_chunk(model_kw, kern, clen, start):
     """``paged_fused_step``'s logits and pools equal ``paged_decode_step``
     then ``paged_prefill_chunk`` on the same inputs — both attention forms,
-    both walks of the kernel, the latent loop through its own, a whole chunk
-    and a prompt's short last one, an idle lane (the prefilling slot's own)
-    among the lanes. A routed model's counters by row range are what the two
-    steps report apart, and the block's what both hit: the loads summed."""
+    both walks of the kernel, the latent loop through its own, a whole chunk,
+    a prompt's short last one and its first, idle lanes (the prefilling
+    slot's own, and one more) among the lanes. A routed model's counters by
+    row range are what the two steps report apart, and the block's what both
+    hit: the loads summed. A model with conv layers: the conv pool's rows too
+    — the lanes' moved by their token, the chunk's row left as a chunk leaves
+    it (a first chunk reads ZEROS, not its slot's earlier tenant's rows: the
+    pool is seeded with noise), an idle lane's row untouched."""
     cfg, params, _ = _model(model_kw)
-    lanes, cols, page, c = 3, 6, 4, 8
-    rng = np.random.RandomState(clen)
+    lanes, cols, page, c = 4, 6, 4, 8
+    rng = np.random.RandomState(clen + start)
     kp, vp = jax.tree_util.tree_map(
         lambda a: jnp.asarray(rng.randn(*a.shape), a.dtype),
         init_page_pool(cfg, 1 + lanes * cols, page, slots=lanes))
     table = jnp.asarray(1 + np.arange(lanes * cols).reshape(lanes, cols), jnp.int32)
-    tok = jnp.asarray([5, 9, 0], jnp.int32)
-    lens = jnp.asarray([7, 13, 0], jnp.int32)
-    act = jnp.asarray([True, True, False])
+    tok = jnp.asarray([5, 9, 0, 0], jnp.int32)
+    lens = jnp.asarray([7, 13, 0, 0], jnp.int32)
+    act = jnp.asarray([True, True, False, False])
     ids = jnp.asarray(rng.randint(0, 128, (1, c)), jnp.int32)
-    off, n = jnp.asarray([8], jnp.int32), jnp.asarray([clen], jnp.int32)
+    off, n = jnp.asarray([start], jnp.int32), jnp.asarray([clen], jnp.int32)
     kw = dict(attention_kernel=kern, kernel_interpret=True)
+    # the prompt sits in slot 2 (an idle lane): its state is the pool's row 3
+    held = {"ssm_rows": jnp.asarray([3], jnp.int32)} if cfg.stateful else {}
     step, k1, v1, *lanes_moe = paged_decode_step(
         params, cfg, tok, lens, act, table, kp, vp, return_logits=True, **kw)
     chunk, k2, v2, *chunk_moe = paged_prefill_chunk(
-        params, cfg, ids, off, n, table[2:], k1, v1, **kw)
+        params, cfg, ids, off, n, table[2:3], k1, v1, **kw, **held)
     got, kf, vf, *moe = paged_fused_step(
-        params, cfg, tok, lens, act, table, ids, off, n, table[2:], kp, vp,
-        return_logits=True, **kw)
+        params, cfg, tok, lens, act, table, ids, off, n, table[2:3], kp, vp,
+        return_logits=True, **kw, **held)
     assert got.shape == (lanes + 1, cfg.vocab_size)
     want = np.concatenate([np.asarray(step), np.asarray(chunk)])
     assert len(moe) == bool(cfg.routed)
@@ -198,24 +228,43 @@ def test_fused_step_is_a_decode_step_then_a_chunk(model_kw, kern, clen):
         by_range = np.asarray(moe[0])
         np.testing.assert_array_equal(by_range[0], np.asarray(lanes_moe[0]))
         np.testing.assert_array_equal(by_range[1], np.asarray(chunk_moe[0]))
-        # two active lanes and ``clen`` positions, two experts each, two
-        # expert layers; the block hit no fewer experts than either part and
+        # two active lanes and ``clen`` positions, two experts each in every
+        # expert layer; the block hit no fewer experts than either part and
         # no more than both, its busiest expert no less than either's
         pairs, hit, load = by_range.T
-        assert list(pairs) == [8, 4 * clen, 8 + 4 * clen]
-        assert max(hit[:2]) <= hit[2] <= min(hit[0] + hit[1], 16)
+        per = 2 * cfg.expert_layers
+        assert list(pairs) == [2 * per, per * clen, per * (2 + clen)]
+        assert max(hit[:2]) <= hit[2] <= min(hit[0] + hit[1], 8 * cfg.expert_layers)
         assert max(load[:2]) <= load[2] <= load[0] + load[1]
-    np.testing.assert_allclose(np.asarray(got), want, atol=2e-5, rtol=1e-5)
-    assert (np.asarray(got).argmax(-1) == want.argmax(-1)).all()
-    # every page but the scratch page, which padding and idle lanes share
-    for a, b in ((k2, kf), (v2, vf)):
+    # but the prompt's own lane: idle, it looks at its slot's first row, which
+    # the chunk has written by then in the one and not in the other; nobody
+    # reads an idle lane's token
+    read = [0, 1, 3, 4]
+    np.testing.assert_allclose(np.asarray(got)[read], want[read], atol=2e-5,
+                               rtol=1e-5)
+    assert (np.asarray(got).argmax(-1) == want.argmax(-1))[read].all()
+    # every page but the scratch page, every row of a state pool but the
+    # scratch row, which padding and idle lanes share
+    for a, b in zip(jax.tree_util.tree_leaves((k2, v2)),
+                    jax.tree_util.tree_leaves((kf, vf))):
         np.testing.assert_allclose(np.asarray(b[:, 1:], np.float32),
                                    np.asarray(a[:, 1:], np.float32),
                                    atol=2e-5, rtol=1e-5)
+    if cfg.conv:
+        before, after = (np.asarray(p["conv"], np.float32) for p in (kp, kf))
+        assert (after[:, [1, 2]] != before[:, [1, 2]]).any()      # the lanes'
+        np.testing.assert_array_equal(after[:, 4], before[:, 4])  # an idle lane's
+        if not start:  # a short first chunk would keep the tenant's last row
+            fresh = jax.tree_util.tree_map(jnp.zeros_like, (kp, vp))
+            clean = paged_fused_step(
+                params, cfg, tok, lens, act, table, ids, off, n, table[2:3],
+                *fresh, return_logits=True, **kw, **held)[0]
+            np.testing.assert_array_equal(np.asarray(clean)[lanes:],
+                                          np.asarray(got)[lanes:])
     picked = paged_fused_step(params, cfg, tok, lens, act, table, ids, off, n,
-                              table[2:], kp, vp, **kw)[0]
+                              table[2:3], kp, vp, **kw, **held)[0]
     assert picked.dtype == jnp.int32
-    np.testing.assert_array_equal(np.asarray(picked), want.argmax(-1))
+    np.testing.assert_array_equal(np.asarray(picked)[read], want.argmax(-1)[read])
 
 
 @pytest.mark.parametrize("case", [k for k, v in ALTERNATES.items() if v[0] is not DENSE])
@@ -224,7 +273,8 @@ def test_the_program_refuses_what_needs_more_operands(case):
     program refuses the others by name."""
     cfg = get_model("decoder_lm").make_config(**ALTERNATES[case][0])
     assert not fusable(cfg) and fusable(_model(DENSE)[0])
-    assert fusable(_model(ROUTED_LATENT)[0])
+    assert all(fusable(get_model("decoder_lm").make_config(**kw))
+               for kw in (ROUTED_LATENT, ROUTED, CONV, CONV_ROUTED))
     with pytest.raises(ConfigError, match="rides a decode step only"):
         paged_fused_step(None, cfg, *[None] * 10)
 
@@ -234,17 +284,22 @@ def test_the_program_refuses_what_needs_more_operands(case):
 
 @pytest.mark.parametrize("depth", [1, 2], ids=["lockstep", "ahead"])
 @pytest.mark.parametrize("case", ["gather", "paged", "tp2", "latent",
-                                  "latent-paged"])
+                                  "latent-paged", "routed", "conv",
+                                  "conv-routed", "conv-routed-paged"])
 def test_a_fusing_server_serves_what_an_alternating_one_serves(case, depth):
     """Greedy tokens of every request equal those of the same server made
     to alternate, over prompts whose first, middle and last chunks ride —
     in lockstep and one step ahead, through the gather form and the
-    interpreted kernel, over a 2-device ``tp`` mesh, and on the latent model
+    interpreted kernel, over a 2-device ``tp`` mesh, on the latent model
     with routed experts (whose steps all return ``_FusedLayout``'s array,
-    the alternating ones too)."""
+    the alternating ones too), and on the per-head loop's routed experts and
+    conv layers, apart and together (the LFM2 layout: eight prompts over
+    three slots, so a slot's conv row passes from tenant to tenant)."""
     kw = dict(dispatch_depth=depth, tp=2 if case == "tp2" else 0)
-    if case.startswith("latent"):
-        kw.update(model_kw=ROUTED_LATENT)
+    models = {"latent": ROUTED_LATENT, "routed": ROUTED, "conv": CONV,
+              "conv-routed": CONV_ROUTED}
+    if case.removesuffix("-paged") in models:
+        kw.update(model_kw=models[case.removesuffix("-paged")])
     if case.endswith("paged"):
         kw.update(decode_kernel="paged", kernel_interpret=True)
     alternating = _server(**kw)
@@ -274,6 +329,8 @@ def test_a_fusing_server_serves_what_an_alternating_one_serves(case, depth):
     assert (depth == 2) == any(ahead for kind, ahead in steps if kind == "fused")
     assert server._fused.jitted._cache_size() == 1
     assert server._decode.jitted._cache_size() == 1
+    if server._stateful:  # every tenancy began with a first chunk's reset
+        assert [t for _, _, t in server._state_tenant] == [3, 3, 2]
 
 
 def test_every_seam_of_a_fused_step_is_crossed_ahead():
@@ -364,7 +421,8 @@ def test_a_prompt_that_stops_after_prefill_keeps_its_last_chunks_own_step():
 @pytest.mark.parametrize("case", sorted(ALTERNATES))
 def test_everything_else_still_alternates(case):
     """A latent model with a layer pattern or several residual streams, a
-    per-head routed, Switch, hybrid, conv or layered model, a sampling and a
+    per-head routed model with a layer pattern, a Switch, hybrid,
+    linear-attention or layered model, a sampling and a
     speculative server, and one that prefills in one shot: no fused program
     is built, no step carries a chunk, and the chunks count as issued
     alone."""
@@ -387,7 +445,7 @@ def test_everything_else_still_alternates(case):
 
 
 @pytest.mark.parametrize("depth", [1, 2], ids=["lockstep", "ahead"])
-@pytest.mark.parametrize("family", ["dense", "latent"])
+@pytest.mark.parametrize("family", ["dense", "latent", "conv-routed"])
 def test_a_fused_step_takes_one_host_array_and_counts_its_chunk(family, depth):
     """``arkflow_gen_step_uploads_total{kind="fused"}`` moves by one a fused
     step (the decode step's operands and the chunk's go up as ONE array;
@@ -396,7 +454,8 @@ def test_a_fused_step_takes_one_host_array_and_counts_its_chunk(family, depth):
     ``arkflow_gen_chunks_total{mode}`` counts every chunk once, by the step
     that carried it, and the host fetches one array a step."""
     name = f"fused-uploads-{family}-{depth}"
-    server = _server(DENSE if family == "dense" else ROUTED_LATENT, name=name,
+    server = _server({"dense": DENSE, "latent": ROUTED_LATENT,
+                      "conv-routed": CONV_ROUTED}[family], name=name,
                      dispatch_depth=depth)
     fused_steps, host_arrays, sizes = [0], [], set()
     real = server._fused
@@ -404,7 +463,9 @@ def test_a_fused_step_takes_one_host_array_and_counts_its_chunk(family, depth):
     def counted(*args):
         fused_steps[0] += 1
         host_arrays.append(sum(isinstance(a, np.ndarray) for a in args))
-        assert all(isinstance(a, (np.ndarray, jax.Array)) for a in args)
+        # (a model with a state pool carries its pools as dicts by name)
+        assert all(isinstance(a, (np.ndarray, jax.Array))
+                   for a in jax.tree_util.tree_leaves(args))
         sizes.add(args[0].shape)
         out = real(*args)
         # tokens (and a routed model's counters) in ONE array, then the pools
@@ -424,12 +485,15 @@ def test_a_fused_step_takes_one_host_array_and_counts_its_chunk(family, depth):
     assert fused_steps[0] > 8 and set(host_arrays) == {1}
     assert _counter("arkflow_gen_step_uploads_total", name, kind="fused") \
         == fused_steps[0]
-    pages = server.pages_per_slot
+    # a table row of a model with a state a slot ends with the slot's row
+    # of the state pool: the lanes' and the chunk's
+    pages = server.pages_per_slot + (family == "conv-routed")
     assert sizes == {(3 * (3 + pages) + 4 + 2 + pages,)}
     assert _chunks(name) == {"fused": fused_steps[0], "alone": alone[0]}
-    # every chunk of every prompt longer than a chunk, once
+    # every chunk of every prompt longer than a chunk, once (a model with
+    # a state pool prefills in chunks only: the short prompts' too)
     assert fused_steps[0] + alone[0] == sum(
-        -(-len(p) // 4) for p in PROMPTS if len(p) > 4)
+        -(-len(p) // 4) for p in PROMPTS if len(p) > 4 or server._stateful)
     # the loop's own stages are observed once a device step, fused or not
     if depth == 2:
         assert _counter("arkflow_gen_steps_ahead_total", name, kind="fused") > 0
@@ -449,18 +513,22 @@ def _routing(name: str, kind: str) -> tuple:
 
 
 @pytest.mark.parametrize("depth", [1, 2], ids=["lockstep", "ahead"])
-def test_routing_counters_go_by_the_program_that_ran(depth):
-    """On a seeded server of the latent model with routed experts (two
+@pytest.mark.parametrize("family", ["latent", "conv-routed"])
+def test_routing_counters_go_by_the_program_that_ran(family, depth):
+    """On a seeded server of the latent model with routed experts, and of
+    the LFM2 layout on the per-head loop (each two
     experts a token, two expert layers: four pairs a token): ``decode`` is
     the ``_decode`` executions' alone and takes nothing from a fused step;
     a fused step's lanes go under ``fused_lanes`` and its block — lanes and
     chunk, what the expert products read — under ``fused``; ``chunk`` is
     every prompt's chunks, fused or alone, summed on the device and recorded
     with its first token. Beside an alternating server's the totals agree."""
-    names = {fuses: f"moe-kinds-{depth}-{fuses}" for fuses in (True, False)}
+    names = {fuses: f"moe-kinds-{family}-{depth}-{fuses}" for fuses in (True, False)}
     seen = {}
+    state = family == "conv-routed"  # a table row's last column
     for fuses, name in names.items():
-        server = _server(ROUTED_LATENT, name=name, dispatch_depth=depth)
+        server = _server(CONV_ROUTED if state else ROUTED_LATENT, name=name,
+                         dispatch_depth=depth)
         assert server._fuses and server._lay.size == 3 + 2 + 3 * 3
         server._fuses = fuses
         steps, apply, fused = [], server._apply_decode, server._fused
@@ -471,7 +539,7 @@ def test_routing_counters_go_by_the_program_that_ran(depth):
             return apply(act, nxt, reqs=reqs, seeds=seeds, rode=rode)
 
         def issued(packed, *a, steps=steps, fused=fused,
-                   at=3 * (3 + server.pages_per_slot) + 4 + 1):
+                   at=3 * (3 + server.pages_per_slot + state) + 4 + 1):
             steps.append(("rode", int(np.asarray(packed)[at])))  # its tokens
             return fused(packed, *a)
 
@@ -496,12 +564,14 @@ def test_routing_counters_go_by_the_program_that_ran(depth):
         assert (pairs, steps) == (4 * (sum(fused) + rode), len(fused))
         assert fuses == (rode > 0)
         # a prompt's chunks: every token of every prompt longer than a chunk
-        chunked = [p for p in PROMPTS if len(p) > 4]
+        # (a model with a state pool prefills in chunks only)
+        chunked = [p for p in PROMPTS if len(p) > 4 or state]
         pairs, steps, *_ = _routing(name, "chunk")
         assert pairs == 4 * sum(map(len, chunked))
         assert steps == sum(-(-len(p) // 4) for p in chunked)
         pairs, steps, *_ = _routing(name, "prefill")
-        assert (pairs, steps) == (4 * sum(len(p) for p in PROMPTS if len(p) <= 4),
+        assert (pairs, steps) == (4 * sum(len(p) for p in PROMPTS
+                                          if p not in chunked),
                                   len(PROMPTS) - len(chunked))
     # fused or not, the same lanes and the same prompts were routed
     assert (_routing(names[True], "decode")[0] + _routing(names[True], "fused_lanes")[0]
@@ -542,7 +612,8 @@ def test_a_fused_step_counts_its_expert_product_by_its_block(monkeypatch):
 #: too), a tiny dots3 layout (sliding and indexed layers, a held share) and a
 #: tiny Xing4.0 layout (four residual streams, YaRN), through the kernels and
 #: through the gather form; and the dense per-head fused step (the Mistral
-#: cells'). The first four ``paged`` hashes are ``BYPASS_GOLDEN``'s.
+#: cells'). The first four ``paged`` hashes are ``BYPASS_GOLDEN``'s. PR 58
+#: added what ITS change must leave alone, recorded at its parent.
 PARENT_GOLDEN = {
     "kanana2.decode.paged": "fb8bb5e87fc3416b", "kanana2.decode.gather": "b709f765129bcb03",
     "kanana2.chunk.paged": "12309b7328d12d1a", "kanana2.chunk.gather": "328c7e20c9e1bcd0",
@@ -551,7 +622,13 @@ PARENT_GOLDEN = {
     "dots3.chunk.paged": "d8c6eb810c5ac125", "dots3.chunk.gather": "b001d947ccbce8e8",
     "xing4.decode.paged": "d336b92619bfd470", "xing4.decode.gather": "60a794ee6db7674d",
     "xing4.chunk.paged": "8e15ccabd252533b", "xing4.chunk.gather": "ade850137b63adb5",
-    "mistral.fused.paged": "8b603762fe0f95ac", "mistral.fused.gather": "67d47148371a1896"}
+    "mistral.fused.paged": "8b603762fe0f95ac", "mistral.fused.gather": "67d47148371a1896",
+    # recorded at PR 58's parent (15665ab): an LFM2 layout (conv, conv, full,
+    # conv; two dense layers, then routed) without a riding chunk
+    "lfm2.decode.paged": "b4f07a44820525ad", "lfm2.decode.gather": "b08a99f7fadeafec",
+    "lfm2.chunk.paged": "8963e06a9eddc080", "lfm2.chunk.gather": "309068d48b2977f6",
+    # and the latent fused step (``kanana2_l6``'s), at the same parent
+    "kanana2.fused.paged": "06c6e0ab99066c9f", "kanana2.fused.gather": "931ec15d39328497"}
 
 _KANANA = dict(vocab_size=64, dim=32, layers=3, heads=4, ffn=48, max_seq=64,
                kv_lora_rank=16, qk_nope_head_dim=8, qk_rope_head_dim=4,
@@ -569,7 +646,13 @@ _LAYOUTS = {
                                 "beta_fast": 32, "beta_slow": 1, "mscale": 1,
                                 "mscale_all_dim": 1}),
     "mistral": dict(vocab_size=64, dim=256, layers=2, heads=2, kv_heads=1, ffn=48,
-                    max_seq=64)}
+                    max_seq=64),
+    "lfm2": dict(vocab_size=64, dim=32, layers=4, heads=4, kv_heads=2, head_dim=64,
+                 ffn=48, max_seq=64, qk_norm=True, conv_L_cache=3,
+                 layer_types=("conv", "conv", FULL, "conv"), n_routed_experts=8,
+                 num_experts_per_tok=2, n_shared_experts=0,
+                 moe_intermediate_size=16, first_k_dense_replace=2,
+                 norm_topk_eps=1e-6, router_bias_std=0.1)}
 
 
 def _window_goldens():
@@ -588,7 +671,9 @@ def test_programs_without_a_riding_chunk_are_the_parents(case):
     layout, step, kern = case.split(".")
     cfg = dec.DecoderConfig(**_LAYOUTS[layout])
     p = jax.eval_shape(lambda: dec.init(jax.random.PRNGKey(0), cfg))
-    kp, vp = jax.eval_shape(lambda: init_page_pool(cfg, 9, 8, 9 if cfg.layered else 0))
+    kp, vp = jax.eval_shape(lambda: init_page_pool(
+        cfg, 9, 8, 9 if cfg.layered else 0, slots=2))
+    held = {"ssm_rows": jnp.ones((1,), jnp.int32)} if cfg.stateful else {}
     kw = dict(attention_kernel=kern, kernel_interpret=False)
     i32 = jnp.int32
 
@@ -608,7 +693,7 @@ def test_programs_without_a_riding_chunk_are_the_parents(case):
         "decode": lambda p, k, v: paged_decode_step(
             p, cfg, *lanes(), tables(2, 1), k, v, **kw),
         "chunk": lambda p, k, v: paged_prefill_chunk(
-            p, cfg, *chunk(), tables(1, 8), k, v, **kw),
+            p, cfg, *chunk(), tables(1, 8), k, v, **kw, **held),
         "prefill": lambda p, k, v: paged_prefill(
             p, cfg, jnp.zeros((1, 8), i32), jnp.full((1,), 5, i32), tables(1, 8),
             k, v, **kw),

@@ -78,12 +78,16 @@ STATE_KINDS = {"hybrid": HYBRID, "conv": CONV, "gdn": GDN}
 SWITCH = dict(vocab_size=128, dim=32, layers=2, heads=2, kv_heads=1, ffn=48,
               max_seq=64, num_experts=4)
 
-#: DENSE with top-2 of 8 routed experts behind a dense layer: its chunks
-#: do not ride its decode steps (``paged_decode.fusable``), so its server
-#: ALTERNATES them — as a dense server did before a chunk could ride
-ALTERNATING = dict(DENSE, n_routed_experts=8, num_experts_per_tok=2,
-                   n_shared_experts=1, moe_intermediate_size=16,
-                   first_k_dense_replace=1)
+#: DENSE with top-2 of 8 routed experts behind a dense layer (its chunks ride
+#: its decode steps since PR 58) ...
+PER_HEAD_ROUTED = dict(DENSE, n_routed_experts=8, num_experts_per_tok=2,
+                       n_shared_experts=1, moe_intermediate_size=16,
+                       first_k_dense_replace=1)
+#: ... and with a sliding layer before its full one: its chunks do not ride
+#: (``paged_decode.fusable``: the block carries no ring coordinates), so its
+#: server ALTERNATES them — as a dense server did before a chunk could ride
+ALTERNATING = dict(PER_HEAD_ROUTED, sliding_window=9,
+                   layer_types=("sliding_attention", "full_attention"))
 
 #: more prompts than slots; chunks before a last one (22, 15 tokens at chunk
 #: 4 or 8), prompts of one chunk, and budgets that end at different steps
@@ -194,10 +198,11 @@ def test_running_ahead_serves_what_lockstep_serves(model_kw, server_kw):
     assert added == ran_ahead
     assert server._steps_ahead == sum(ran_ahead.values()) > 0
     # a greedy server that prefills in chunks lets them ride its decode
-    # steps, through one program too, on a dense model and on a latent
-    # routed one; those of a model with a state a slot alternate
-    fuses = (model_kw is DENSE or model_kw is ROUTED) and server_kw.get(
-        "prefill_chunk", 4) > 0
+    # steps, through one program too, on a dense model, on a latent routed
+    # one and on one with conv layers and per-head routed experts; those of a
+    # model with a recurrent or delta-rule state a slot alternate
+    fuses = (model_kw is DENSE or model_kw is ROUTED or model_kw is CONV
+             ) and server_kw.get("prefill_chunk", 4) > 0
     assert server._fuses == fuses and (ran_ahead["fused"] > 0) == fuses
     assert server._fused is None or server._fused.jitted._cache_size() == 1
     # most steps find the queue occupied: the rest are cold (each program's
@@ -350,7 +355,8 @@ def test_running_ahead_leaves_the_states_lockstep_leaves(kind, server_kw):
         k: v for k, v in ref_books.items() if k != idle}
     assert books["decode", "tokens"] == sum(n - 1 for n in BUDGETS)
     assert books["resets"] == len(PROMPTS)
-    decodes = [sum(1 for k, _, _ in run if k == "decode")
+    # (a decode step that carried a chunk — the conv kind's — is one too)
+    decodes = [sum(1 for k, _, _ in run if k in ("decode", "fused"))
                for run in (steps, ref_steps)]
     assert books[idle] - ref_books[idle] == 2 * (decodes[0] - decodes[1])
 

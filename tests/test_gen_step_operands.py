@@ -144,6 +144,80 @@ def test_packed_layout_round_trips(kind, rows, width, tp):
         np.testing.assert_array_equal(np.asarray(part), want)
 
 
+@pytest.mark.parametrize("state", [0, 1], ids=["pages-only", "state-column"])
+def test_a_fused_step_s_packed_array_is_both_steps_end_to_end(state):
+    """A fused step's ONE array is the decode step's packed operands and then
+    the chunk's; of a server whose model caches a state a slot, a table row
+    of EITHER part ends with the slot's row of the state pool (``state`` 1:
+    the lanes' column is dropped — a lane's row follows from its index — and
+    the chunk's is handed on as a fifth part, as ``_chunk`` takes it)."""
+    slots, pages, c = 3, 5, 4
+    rng = np.random.RandomState(7 + state)
+    tok, lens, act = rng.randint(0, 99, slots), rng.randint(0, 40, slots), [1, 0, 1]
+    table = rng.randint(1, 99, (slots, pages + state))
+    ids, row = rng.randint(0, 99, c), rng.randint(1, 99, (1, pages + state))
+    if state:
+        table[:, -1], row[:, -1] = np.arange(slots) + 1, 2   # slot 1 prefills
+    packed = np.concatenate([pack_operands(tok, lens, act, table),
+                             pack_operands(ids, 8, 3, row)])
+    lanes = slots * (3 + pages + state)
+    assert packed.shape == (lanes + c + 2 + pages + state,)
+
+    @jax.jit
+    def apart(p):
+        return (unpack_operands(p[:lanes], slots, pages, state=state)[:4],
+                unpack_operands(p[lanes:], 1, pages, state=state))
+
+    (t, n, a, tab), (i, off, clen, its, *held) = apart(packed)
+    np.testing.assert_array_equal(np.asarray(t)[:, 0], tok)
+    np.testing.assert_array_equal(np.asarray(n), lens)
+    np.testing.assert_array_equal(np.asarray(a), act)
+    np.testing.assert_array_equal(np.asarray(tab), table[:, :pages])
+    np.testing.assert_array_equal(np.asarray(i), ids[None])
+    assert (int(off[0]), int(clen[0])) == (8, 3)
+    np.testing.assert_array_equal(np.asarray(its), row[:, :pages])
+    assert [np.asarray(h).tolist() for h in held] == ([[2]] if state else [])
+
+
+def test_a_stateful_server_packs_both_parts_state_columns():
+    """The array a server of the LFM2 layout hands its ``_fused`` program:
+    the lanes' table rows end with rows 1..slots of the state pool and the
+    riding chunk's with its own slot's (``_table``), so the program is told
+    both parts' rows in the one upload."""
+    from tests.test_fused_step import CONV_ROUTED
+
+    fam = get_model("decoder_lm")
+    cfg = fam.make_config(**CONV_ROUTED)
+    server = GenerationServer(fam.init(jax.random.PRNGKey(11), cfg), cfg, slots=3,
+                              page_size=4, max_seq=48, eos_id=-1, prefill_chunk=4,
+                              name="fused-state-columns")
+    assert server._stateful and server._fuses
+    seen, real = [], server._fused
+
+    def spy(packed, *a):
+        seen.append(np.asarray(packed))
+        return real(packed, *a)
+
+    server._fused = spy
+
+    async def go():
+        outs = await asyncio.gather(server.generate(list(range(3, 25)), 6),
+                                    server.generate(list(range(40, 55)), 5))
+        await server.close()
+        return outs
+
+    assert [len(o) for o in asyncio.run(go())] == [6, 5]
+    cols = server.pages_per_slot + 1
+    lanes = 3 * (3 + cols)
+    assert seen and {p.shape for p in seen} == {(lanes + 4 + 2 + cols,)}
+    for packed in seen:
+        table = packed[3 * 3:lanes].reshape(3, cols)
+        np.testing.assert_array_equal(table[:, -1], [1, 2, 3])
+        act = packed[6:9]
+        riding = int(packed[-1]) - 1            # the chunk's slot, by its row
+        assert riding in (0, 1) and not act[riding] and act.any()
+
+
 def test_packed_scalars_and_bools_flatten_in_order():
     """A chunk's offset and length are scalars and a decode mask is bool:
     each part lands as int32 where the layout says."""

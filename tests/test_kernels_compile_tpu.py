@@ -488,12 +488,14 @@ def test_moe_expert_swiglu_compiles(v5e, tokens):
 
 @pytest.mark.parametrize("tokens,layers,experts,dim,width", [
     (512, 4, 17, 6144, 2048), (512, 6, 16, 4096, 2048), (512, 8, 65, 2048, 512),
-    (256, 10, 32, 2048, 1792)], ids=["kexaone512", "mimo512", "qwen3next512", "lfm2_256"])
+    (256, 10, 32, 2048, 1792), (384, 10, 32, 2048, 1792)],
+    ids=["kexaone512", "mimo512", "qwen3next512", "lfm2_256", "lfm2_fused384"])
 def test_grouped_expert_product_compiles(v5e, tokens, layers, experts, dim, width):
     """A chunk's grouped product at the four other routed cells' widths and
     expert layers (K-EXAONE 16 + 1 of 6,144 x 2,048, MiMo 16 of 4,096 x
     2,048, Qwen3-Next 64 + 1 of 2,048 x 512, LFM2 32 of 2,048 x 1,792 at
-    its 256-row chunk): the rows, their float32 sums, a group's two
+    its 256-row chunk and at its fused step's block of 128 lanes + 256 rows):
+    the rows, their float32 sums, a group's two
     scratches and the weights' slices inside the fast-memory limit the call
     asks for, and no copy of a layer."""
     compiled, layer_bytes = _expert_product_compiled(
@@ -999,6 +1001,50 @@ def test_conv_steps_carry_pools_and_stacks_whole(v5e):
             assert name in text, name
         assert step.memory_analysis().temp_size_in_bytes < 300 * 1024 * 1024, \
             step.memory_analysis().temp_size_in_bytes
+
+
+def test_a_conv_routed_fused_step_compiles_at_lfm2_widths(v5e):
+    """``lfm2_l12``'s ``_fused`` program — 128 lanes and a 256-token chunk,
+    ONE block of 384 rows through twelve layers at the published widths, the
+    pools donated — compiled for the described chip: the narrow-head walk
+    twice an attention layer (the two steps' own calls), the expert product
+    ONCE a layer and grouped (384 rows are three token tiles), the conv
+    windows a part at a time, no pool and no run's experts copied, and the
+    counters by row range beside the logits."""
+    from arkflow_tpu.models import decoder as dec
+    from arkflow_tpu.models.paged_decode import init_page_pool, paged_fused_step
+
+    cfg = dec.DecoderConfig(**LFM2)
+    chip = SingleDeviceSharding(v5e[0])
+
+    def struct(a, dtype=None):
+        return jax.ShapeDtypeStruct(a.shape, dtype or a.dtype, sharding=chip)
+
+    params = jax.tree_util.tree_map(
+        struct, jax.eval_shape(lambda: dec.init(jax.random.PRNGKey(0), cfg)),
+        dec.serve_dtypes(cfg))
+    kp, vp = jax.tree_util.tree_map(struct, jax.eval_shape(
+        lambda: init_page_pool(cfg, 1 + LFM2_SLOTS * LFM2_TABLE, PAGE,
+                               slots=LFM2_SLOTS)))
+    s, table = LFM2_SLOTS, LFM2_TABLE
+    operands = [jax.ShapeDtypeStruct(shape, dtype, sharding=chip) for shape, dtype in (
+        ((s,), I32), ((s,), I32), ((s,), jnp.bool_), ((s, table), I32),
+        ((1, 256), I32), ((1,), I32), ((1,), I32), ((1, table), I32))]
+    rows = jax.ShapeDtypeStruct((1,), I32, sharding=chip)
+
+    def step(p, rows, *a):
+        return paged_fused_step(p, cfg, *a, return_logits=True,
+                                attention_kernel="paged", ssm_rows=rows)
+
+    compiled = jax.jit(step, donate_argnums=(10, 11)).lower(
+        params, rows, *operands, kp, vp).compile()
+    text = compiled.as_text()
+    assert "moe_expert_grouped" in text and "paged_flash_attention" in text
+    logits, _, _, counters = jax.eval_shape(step, params, rows, *operands, kp, vp)
+    assert logits.shape == (s + 1, 65536) and counters.shape == (3, 3)
+    # as the two steps apart: no pool (3 x 1.21 GB) and no run's experts
+    assert compiled.memory_analysis().temp_size_in_bytes < 300 * 1024 * 1024, \
+        compiled.memory_analysis().temp_size_in_bytes
 
 
 # -- the matrix-state kind (Qwen3-Next-80B-A3B widths, 128 slots, chunk 512) ----
