@@ -33,9 +33,10 @@ FULL, SLIDING = "full_attention", "sliding_attention"
 #: attention weights, no keys or values, a per-slot window of its last
 #: ``conv_L_cache - 1`` gated inputs instead
 CONV = "conv"
-#: a layer whose mixer is a Gated DeltaNet (``gated_delta_net``): linear
-#: attention by the gated delta rule — no keys or values by token, a float32
-#: matrix state a value head and a short conv window a SEQUENCE instead
+#: a layer whose mixer is a Gated DeltaNet (``gated_delta_net``) or — under a
+#: ``linear_attn_config`` — Kimi Delta Attention: linear attention by the
+#: gated delta rule — no keys or values by token, a float32 matrix state a
+#: value head and a short conv window a SEQUENCE instead
 LINEAR = "linear_attention"
 
 
@@ -64,6 +65,9 @@ class AttnSpec:
     index_topk: int = 0
     #: ``DecoderConfig.rope_scaling`` (YaRN's keys as sorted pairs) or None
     rope_scaling: Optional[tuple] = None
+    #: false (``mla_use_nope``): the shared key and the queries' rope part
+    #: are left as projected — no position reaches the layer
+    rotate: bool = True
 
     @property
     def softmax_scale(self) -> float:
@@ -360,6 +364,25 @@ class DecoderConfig:
     linear_conv_kernel_dim: int = 0
     norm_unit_offset: bool = False
     shared_expert_gate: bool = False
+    # -- Kimi Delta Attention layers among position-free latent layers (Kimi
+    # Linear, arXiv:2510.26692), under the published key names. The published
+    # ``linear_attn_config`` mapping (``num_heads``, ``head_dim``,
+    # ``short_conv_kernel_size``, and ``kda_layers`` / ``full_attn_layers``,
+    # both 1-based) PRESENT is what says that a ``linear_attention`` layer is
+    # a KDA layer and not a Gated DeltaNet (``linear_num_*``): q, k and v each
+    # projected to ``num_heads x head_dim`` behind a depthwise causal conv of
+    # its own and SiLU, a log-decay a head AND key channel from a low-rank
+    # projection (``kda_fa`` -> ``kda_fb``, + ``dt_bias``), an output gate from
+    # another (``kda_ga`` -> ``kda_gb``) under a sigmoid inside a per-head
+    # RMSNorm; what a SEQUENCE caches of it is a float32 state [heads, head
+    # dim, head dim] and the convs' last ``short_conv_kernel_size - 1``
+    # projected inputs (``paged_decode.cache_spec``: kind ``kda``). Held as
+    # sorted (key, value) pairs. ``mla_use_nope``: a latent layer rotates
+    # NOTHING — its ``qk_rope_head_dim`` wide shared key and the queries'
+    # part that meets it are cached and scored as projected (positions reach
+    # the model through the KDA layers alone; ``rope_theta`` is then unread).
+    linear_attn_config: Optional[tuple] = None
+    mla_use_nope: bool = False
     # -- several residual streams mixed by manifold-constrained
     # hyper-connections (mHC, arXiv:2512.24880; Xing4.0), under the published
     # key names. ``hc_mult`` = n > 1: a token's residual is n rows of ``dim``
@@ -419,6 +442,10 @@ class DecoderConfig:
         if isinstance(self.rope_scaling, dict):  # a JSON mapping: hashable
             object.__setattr__(self, "rope_scaling",
                                tuple(sorted(self.rope_scaling.items())))
+        if isinstance(self.linear_attn_config, dict):
+            object.__setattr__(self, "linear_attn_config", tuple(sorted(
+                (k, tuple(v) if isinstance(v, list) else v)
+                for k, v in self.linear_attn_config.items())))
         self._check_streams()
         self._check_hybrid()
         self._check_eva()
@@ -437,7 +464,13 @@ class DecoderConfig:
                 raise ConfigError(
                     "latent attention composes with neither ring attention "
                     "nor the Switch top-1 layer (num_experts)")
-        if self.latent != self.rope_interleave or (
+        if self.mla_use_nope and (not self.latent or self.rope_interleave
+                                  or self.rope_scaling is not None):
+            raise ConfigError(
+                "mla_use_nope (no rotation anywhere) belongs to a "
+                "latent-attention model (kv_lora_rank > 0) and leaves "
+                "rope_interleave and rope_scaling nothing to say: remove them")
+        if (self.latent != self.rope_interleave and not self.mla_use_nope) or (
                 self.latent and not self.routed):
             raise ConfigError(
                 "latent attention (kv_lora_rank) is served with top-k routed "
@@ -567,8 +600,8 @@ class DecoderConfig:
         if set(self.kinds) != {FULL} or self.index_topk:
             raise ConfigError(
                 f"hc_mult {self.hc_mult} is served over full_attention "
-                "latent layers only: sliding_attention layers and indexed "
-                f"layers carry ONE residual stream, got {self.layer_types} "
+                "latent layers only: sliding_attention, linear_attention and "
+                f"indexed layers carry ONE residual stream, got {self.layer_types} "
                 f"and index_topk {self.index_topk}")
         if self.remat:
             raise ConfigError(
@@ -738,6 +771,13 @@ class DecoderConfig:
         sizes = (self.linear_num_key_heads, self.linear_num_value_heads,
                  self.linear_key_head_dim, self.linear_value_head_dim,
                  self.linear_conv_kernel_dim)
+        if self.kda:
+            if any(sizes):
+                raise ConfigError(
+                    "linear_attn_config (Kimi Delta Attention) and "
+                    "linear_num_*_heads / linear_*_head_dim (a Gated DeltaNet) "
+                    "both say what a linear_attention layer is: state one")
+            return self._check_kda()
         if not self.linear:
             if any(sizes):
                 raise ConfigError("linear_num_*_heads / linear_*_head_dim / "
@@ -760,9 +800,87 @@ class DecoderConfig:
                 "a sliding window's pool (kv_window), conv layers (pool "
                 "conv) or the hybrid block (pool ssm) they are not, yet")
 
+    def _check_kda(self) -> None:
+        """``linear_attn_config``'s keys, its layer lists against
+        ``layer_types``, and what Kimi Delta Attention layers are served
+        beside: full latent layers, and nothing else yet."""
+        from arkflow_tpu.errors import ConfigError
+
+        spec = dict(self.linear_attn_config)
+        sizes = {"num_heads", "head_dim", "short_conv_kernel_size"}
+        if (not sizes <= set(spec) <= sizes | {"kda_layers", "full_attn_layers"}
+                or min(self.kda_heads, self.kda_head_dim) <= 0
+                or self.kda_taps < 2):
+            raise ConfigError(
+                "linear_attn_config states num_heads, head_dim > 0 and "
+                "short_conv_kernel_size >= 2 (and kda_layers / "
+                f"full_attn_layers, 1-based), got {spec}")
+        if not self.linear:
+            raise ConfigError("linear_attn_config without a linear_attention "
+                              "layer in layer_types")
+        for key, kind in (("kda_layers", LINEAR), ("full_attn_layers", FULL)):
+            if key not in spec:
+                continue
+            named = tuple(i for i in spec[key] if i <= self.layers)
+            mine = tuple(i + 1 for i, k in enumerate(self.kinds) if k == kind)
+            if named != mine:
+                raise ConfigError(
+                    f"linear_attn_config.{key} (1-based) names layers {named} "
+                    f"of the first {self.layers}; layer_types names its "
+                    f"{kind} layers {mine}")
+        if not self.latent:
+            raise ConfigError(
+                "Kimi Delta Attention layers (linear_attn_config: pool kda) "
+                "are served among latent-attention layers (kv_lora_rank > 0): "
+                "among per-head K/V layers they are not, yet (a Gated "
+                "DeltaNet, linear_num_*, is)")
+        if CONV in self.kinds or self.hybrid:
+            raise ConfigError(
+                "conv layers (pool conv) and the hybrid block (pool ssm) are "
+                "not served beside a latent row, with Kimi Delta Attention "
+                "layers or without, yet")
+        if SLIDING in self.kinds or self.index_topk or FULL not in self.kinds:
+            raise ConfigError(
+                "Kimi Delta Attention layers (pool kda) are served among "
+                "plain full_attention latent layers (at least one): beside "
+                "sliding latent layers (pool window) or indexed ones (pool "
+                "index) they are not, yet")
+
     @property
     def latent(self) -> bool:
         return self.kv_lora_rank > 0
+
+    @property
+    def kda(self) -> bool:
+        """True where a ``linear_attention`` layer is Kimi Delta Attention
+        (``linear_attn_config`` present), not a Gated DeltaNet."""
+        return self.linear_attn_config is not None
+
+    def _kda_size(self, key: str) -> int:
+        return int(dict(self.linear_attn_config).get(key, 0))
+
+    @property
+    def kda_heads(self) -> int:
+        return self._kda_size("num_heads")
+
+    @property
+    def kda_head_dim(self) -> int:
+        """A KDA head's key width, and its value width."""
+        return self._kda_size("head_dim")
+
+    @property
+    def kda_taps(self) -> int:
+        return self._kda_size("short_conv_kernel_size")
+
+    @property
+    def kda_conv_dim(self) -> int:
+        """Channels the KDA convs run over: q | k | v, each heads x head dim."""
+        return 3 * self.kda_heads * self.kda_head_dim
+
+    @property
+    def linear_taps(self) -> int:
+        """Taps of a linear_attention layer's causal conv, either mixer's."""
+        return self.kda_taps if self.kda else self.linear_conv_kernel_dim
 
     @property
     def hybrid(self) -> bool:
@@ -776,7 +894,8 @@ class DecoderConfig:
 
     @property
     def linear(self) -> bool:
-        """True where some layers' mixer is a Gated DeltaNet."""
+        """True where some layers' mixer is a Gated DeltaNet, or — ``kda``
+        — Kimi Delta Attention."""
         return LINEAR in self.kinds
 
     @property
@@ -935,7 +1054,7 @@ class DecoderConfig:
             rescale=self.apply_mla_qkv_lora_rescale,
             index_n_heads=self.index_n_heads,
             index_head_dim=self.index_head_dim, index_topk=self.index_topk,
-            rope_scaling=self.rope_scaling)
+            rope_scaling=self.rope_scaling, rotate=not self.mla_use_nope)
 
     @property
     def routed(self) -> bool:
@@ -1137,6 +1256,8 @@ def layer_runs(cfg: DecoderConfig) -> list:
     for i, kind in enumerate(cfg.kinds):
         routed = cfg.routed and i >= cfg.first_k_dense_replace
         name = _STACKS[kind if by_kind else FULL, routed or not cfg.routed]
+        if cfg.kda and kind == LINEAR:  # the other mixer's leaves: its own stacks
+            name = name.replace("gdn", "kda")
         at, kat = in_stack.get(name, 0), of_kind.get(kind, 0)
         if runs and runs[-1][0] == name and runs[-1][3] == kind:
             runs[-1][2] = at + 1
@@ -1231,6 +1352,48 @@ def _init_gdn_layer(key, cfg: DecoderConfig, routed: bool) -> dict:
     return layer
 
 
+def _init_kda_layer(key, cfg: DecoderConfig, routed: bool) -> dict:
+    """One Kimi Delta Attention layer (HF names: input_layernorm,
+    self_attn.q_proj / k_proj / v_proj, q_conv1d / k_conv1d / v_conv1d,
+    f_a_proj -> f_b_proj, b_proj, A_log, dt_bias, g_a_proj -> g_b_proj,
+    o_norm, o_proj, post_attention_layernorm). ``kda_qkv``'s columns are q | k
+    | v, each head-major (three matrices side by side: the same bytes);
+    ``kda_conv_w`` [q | k | v channels, taps] the three convs' depthwise taps,
+    oldest input first, no bias (torch's default init). ``A_log`` is the log
+    of uniform(1, 16) a head and ``dt_bias`` the inverse softplus of a step
+    log-uniform in [1e-3, 1e-1] a CHANNEL (the Mamba-2 / flash-linear-attention
+    initialisation this family ships: ``_init_mixer``'s): a head's memory
+    then spans tens to thousands of tokens, so what a state holds is most of
+    its sequence and its precision shows. The gated norm's scale is seeded
+    around one, so that leaving it out shows."""
+    k = iter(jax.random.split(key, 16))
+    h, d, taps = cfg.kda_heads, cfg.kda_head_dim, cfg.kda_taps
+    p, bound = h * d, taps ** -0.5
+    extra = (jax.random.fold_in(key, 800 + i) for i in range(4))
+    dt = jnp.exp(jax.random.uniform(next(extra), (p,), jnp.float32,
+                                    jnp.log(1e-3), jnp.log(1e-1)))
+    layer = {
+        "attn_norm": cm.rms_norm_init(cfg.dim),
+        "kda_qkv": cm.dense_init(next(k), cfg.dim, 3 * p, bias=False),
+        "kda_conv_w": jax.random.uniform(next(k), (3 * p, taps), jnp.float32,
+                                         -bound, bound),
+        "kda_fa": cm.dense_init(next(k), cfg.dim, d, bias=False),
+        "kda_fb": cm.dense_init(next(k), d, p, bias=False),
+        "kda_b": cm.dense_init(next(k), cfg.dim, h, bias=False),
+        "kda_ga": cm.dense_init(next(k), cfg.dim, d, bias=False),
+        "kda_gb": cm.dense_init(next(k), d, p, bias=False),
+        "kda_A_log": jnp.log(jax.random.uniform(next(extra), (h,), jnp.float32,
+                                                1.0, 16.0)),
+        "kda_dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+        "kda_norm": {"scale": 1.0 + 0.1 * jax.random.normal(
+            next(extra), (d,), jnp.float32)},
+        "kda_out": cm.dense_init(next(k), p, cfg.dim, bias=False),
+        "mlp_norm": cm.rms_norm_init(cfg.dim),
+    }
+    layer.update(_init_ffn(k, cfg, routed))
+    return layer
+
+
 #: the norms whose scale ``norm_unit_offset`` holds as an offset from one
 _OFFSET_NORMS = ("attn_norm", "mlp_norm", "norm_out", "q_head_norm", "k_head_norm")
 
@@ -1286,7 +1449,8 @@ def _init_runs(rng, cfg: DecoderConfig) -> dict:
     stacks: dict = {}
     for name, first, stop, kind, routed, _ in layer_runs(cfg):
         stacks.setdefault(name, []).extend(
-            _init_latent_layer(next(keys), cfg, routed, kind) if cfg.latent
+            _init_kda_layer(next(keys), cfg, routed) if cfg.kda and kind == LINEAR
+            else _init_latent_layer(next(keys), cfg, routed, kind) if cfg.latent
             else _init_conv_layer(next(keys), cfg, routed) if kind == CONV
             else _init_gdn_layer(next(keys), cfg, routed) if kind == LINEAR
             else _init_gqa_layer(next(keys), cfg, routed, kind)
@@ -1539,8 +1703,13 @@ def gdn_conv(lp: dict, ext: jnp.ndarray, s: int) -> jnp.ndarray:
     taps - 1 + S, channels] — the ``taps - 1`` projected inputs before the
     block, then the block's — in float32: output t reads ``ext[t : t +
     taps]``."""
-    ext = ext.astype(jnp.float32)
-    w = lp["gdn_conv_w"].astype(jnp.float32)                      # [C, taps]
+    return _conv_silu(lp["gdn_conv_w"], ext, s)
+
+
+def _conv_silu(w: jnp.ndarray, ext: jnp.ndarray, s: int) -> jnp.ndarray:
+    """``silu`` of the depthwise causal conv of taps ``w`` [channels, taps]
+    over ``ext`` [B, taps - 1 + S, channels], float32 sums."""
+    ext, w = ext.astype(jnp.float32), w.astype(jnp.float32)
     return jax.nn.silu(sum(ext[:, j:j + s] * w[:, j] for j in range(w.shape[1])))
 
 
@@ -1601,6 +1770,100 @@ def _gdn_block(lp: dict, y: jnp.ndarray, cfg: DecoderConfig) -> jnp.ndarray:
                     cfg.linear_value_head_dim), jnp.float32)
     o, _ = chunk_from(s0, q, k, v, g, beta)
     return gdn_output(lp, o, z, cfg, y.dtype)
+
+
+def kda_project(lp: dict, y: jnp.ndarray, cfg: DecoderConfig):
+    """A Kimi Delta Attention layer's input projections of normed
+    activations ``y`` [B, S, dim], in ``gdn_project``'s places: the convs'
+    input ``q | k | v`` [B, S, 3 heads x head dim] AS PROJECTED (bfloat16:
+    what the conv window caches), the output gate's logits ``z = (y W_ga)
+    W_gb`` [B, S, heads x head dim], the raw ``b`` [B, S, heads] and the raw
+    decay ``a = (y W_fa) W_fb`` [B, S, heads x head dim], float32."""
+    f32 = jnp.float32
+    return (cm.dense(lp["kda_qkv"], y),
+            cm.dense(lp["kda_gb"], cm.dense(lp["kda_ga"], y)).astype(f32),
+            cm.dense(lp["kda_b"], y).astype(f32),
+            cm.dense(lp["kda_fb"], cm.dense(lp["kda_fa"], y)).astype(f32))
+
+
+def kda_conv(lp: dict, ext: jnp.ndarray, s: int) -> jnp.ndarray:
+    """The three depthwise causal convs (no bias) and their SiLU over ``ext``
+    [B, taps - 1 + S, q | k | v channels], ``gdn_conv``'s convention: float32
+    sums, output t reads ``ext[t : t + taps]``."""
+    return _conv_silu(lp["kda_conv_w"], ext, s)
+
+
+def kda_operands(lp: dict, conved: jnp.ndarray, b: jnp.ndarray, a: jnp.ndarray,
+                 cfg: DecoderConfig, valid=None):
+    """What the delta rule reads (``ops/kda_scan``), from the convs' output
+    [B, S, channels], the raw ``b`` [B, S, heads] and the raw decay ``a``
+    [B, S, heads x head dim], float32: queries and keys L2-normalised a head
+    (``x rsqrt(sum x^2 + 1e-6)``), the queries times ``head dim ** -0.5``,
+    and values, each [B, S, heads, head dim]; ``g = -exp(A_log) softplus(a +
+    dt_bias)`` [B, S, heads, head dim] — a log-decay a head AND key channel —
+    and ``beta = sigmoid(b)`` [B, S, heads]; both 0 where ``valid`` [B, S] is
+    false, so that a padded position or an idle lane leaves the state as it
+    is."""
+    bsz, s = conved.shape[:2]
+    h, d = cfg.kda_heads, cfg.kda_head_dim
+
+    def unit(x):
+        return x * jax.lax.rsqrt(jnp.square(x).sum(-1, keepdims=True) + 1e-6)
+
+    q, k, v = (conved[..., i * h * d:(i + 1) * h * d].reshape(bsz, s, h, d)
+               for i in range(3))
+    q, k = unit(q) * d ** -0.5, unit(k)
+    g = -jnp.exp(lp["kda_A_log"].astype(jnp.float32))[:, None] * jax.nn.softplus(
+        a + lp["kda_dt_bias"].astype(jnp.float32)).reshape(bsz, s, h, d)
+    beta = jax.nn.sigmoid(b)
+    if valid is not None:
+        g = jnp.where(valid[..., None, None], g, 0.0)
+        beta = jnp.where(valid[..., None], beta, 0.0)
+    return q, k, v, g, beta
+
+
+def kda_output(lp: dict, o: jnp.ndarray, z: jnp.ndarray, cfg: DecoderConfig,
+               dtype) -> jnp.ndarray:
+    """The delta rule's output ``o`` [B, S, heads, head dim] -> the mixer's
+    [B, S, dim]: RMSNorm over each head's values times the norm's plain scale
+    (one set for all heads), times ``sigmoid(z)``, ``o_proj``."""
+    bsz, s = z.shape[:2]
+    normed = o * jax.lax.rsqrt(jnp.square(o).mean(-1, keepdims=True)
+                               + cfg.norm_eps) * lp["kda_norm"]["scale"]
+    gated = normed.reshape(bsz, s, -1) * jax.nn.sigmoid(z)
+    return cm.dense(lp["kda_out"], gated.astype(dtype))
+
+
+def linear_mixer(cfg: DecoderConfig) -> tuple:
+    """The four steps of the model's ``linear_attention`` layers — project,
+    conv, operands, output, each pair of one signature — and the two forms
+    of its delta rule (one token a lane; a chunk): Kimi Delta Attention's
+    under a ``linear_attn_config``, else the Gated DeltaNet's. The one seam
+    ``paged_decode`` calls either mixer through."""
+    if cfg.kda:
+        from arkflow_tpu.ops.kda_scan import kda_chunk_scan, kda_state_update
+
+        return (kda_project, kda_conv, kda_operands, kda_output,
+                kda_state_update, kda_chunk_scan)
+    from arkflow_tpu.ops.gdn_scan import gdn_chunk_scan, gdn_state_update
+
+    return (gdn_project, gdn_conv, gdn_operands, gdn_output,
+            gdn_state_update, gdn_chunk_scan)
+
+
+def _kda_block(lp: dict, y: jnp.ndarray, cfg: DecoderConfig) -> jnp.ndarray:
+    """The Kimi Delta Attention mixer over a whole block from a zero state
+    and empty windows (``forward``): the chunked form in plain XLA, no cache."""
+    from arkflow_tpu.ops.kda_scan import chunk_from
+
+    bsz, s = y.shape[:2]
+    u, z, b, a = kda_project(lp, y, cfg)
+    ext = jnp.pad(u, ((0, 0), (cfg.kda_taps - 1, 0), (0, 0)))
+    q, k, v, g, beta = kda_operands(lp, kda_conv(lp, ext, s), b, a, cfg)
+    s0 = jnp.zeros((bsz, cfg.kda_heads, cfg.kda_head_dim, cfg.kda_head_dim),
+                   jnp.float32)
+    o, _ = chunk_from(s0, q, k, v, g, beta)
+    return kda_output(lp, o, z, cfg, y.dtype)
 
 
 def attn_out_gate(lp: dict, y: jnp.ndarray, attn: jnp.ndarray,
@@ -1722,7 +1985,8 @@ def mla_project(lp: dict, y: jnp.ndarray, cfg: AttnSpec, positions, cq=None):
     no-position part [B, S, H, nope] and rotated rope part [B, S, H, rope],
     and — what the cache holds — the normed latent row ``c`` [B, S,
     kv_lora_rank] and the one rotated rope key ``k_r`` [B, S, rope] that
-    every head shares. ``cfg`` is the layer kind's ``AttnSpec``; ``cq`` the
+    every head shares (neither part rotated where ``cfg.rotate`` is false:
+    ``mla_use_nope``). ``cfg`` is the layer kind's ``AttnSpec``; ``cq`` the
     query latent (``mla_query_latent``), if any."""
     b, s = positions.shape
     nope, rope = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
@@ -1732,6 +1996,8 @@ def mla_project(lp: dict, y: jnp.ndarray, cfg: AttnSpec, positions, cq=None):
     kv = cm.dense(lp["wkv_a"], y)
     c = _rescaled(cm.rms_norm(lp["kv_norm"], kv[..., :cfg.kv_lora_rank],
                               cfg.norm_eps), cfg, cfg.kv_lora_rank)
+    if not cfg.rotate:  # position-free: the shared key and q_r as projected
+        return q[..., :nope], q[..., nope:], c, kv[..., cfg.kv_lora_rank:]
     k_r = rot(kv[..., None, cfg.kv_lora_rank:], positions, cfg.rope_theta,
               cfg.rope_scaling)[:, :, 0]
     return (q[..., :nope],
@@ -2240,7 +2506,7 @@ def _forward_latent(params: dict, cfg: DecoderConfig, input_ids, axes: dict,
     key_pos = jnp.arange(s)
     hc = cfg.hc_mult > 1
 
-    def make_layer(routed: bool, sp: AttnSpec):
+    def make_layer(routed: bool, sp: Optional[AttnSpec]):
         def streams_layer(x, lp):
             # x: the residual streams [B, S, n dim]; each sub-layer reads
             # their weighted sum and is written back into all of them
@@ -2271,14 +2537,24 @@ def _forward_latent(params: dict, cfg: DecoderConfig, input_ids, axes: dict,
             y = cm.rms_norm(lp["mlp_norm"], x, cfg.norm_eps)
             x = x + (routed_mlp(lp, y, cfg)[0] if routed else _mlp(lp, y, cfg))
             return _shard_act(x, axes), None
+        def kda_layer(x, lp):  # from a zero state and empty windows
+            x = x + _kda_block(lp, cm.rms_norm(lp["attn_norm"], x, cfg.norm_eps), cfg)
+            x = _shard_act(x, axes)
+            y = cm.rms_norm(lp["mlp_norm"], x, cfg.norm_eps)
+            x = x + (routed_mlp(lp, y, cfg)[0] if routed else _mlp(lp, y, cfg))
+            return _shard_act(x, axes), None
+
         if hc:
             return streams_layer
+        if sp is None:
+            layer = kda_layer
         return jax.checkpoint(layer, prevent_cse=False) if cfg.remat else layer
 
     if hc:
         x = hc_expand(x, cfg)
     for stack, routed, kind, _ in layer_stacks(params, cfg):
-        x, _ = jax.lax.scan(make_layer(routed, cfg.attn(kind)), x, stack)
+        x, _ = jax.lax.scan(make_layer(
+            routed, None if kind == LINEAR else cfg.attn(kind)), x, stack)
     if hc:
         x = hc_collapse(x, cfg)
     x = cm.rms_norm(params["norm_out"], x, cfg.norm_eps)
@@ -2470,6 +2746,12 @@ def _serve_dtypes_runs(cfg: DecoderConfig) -> dict:
         layer = {"attn_norm": {"scale": f32}, "mlp_norm": {"scale": f32}}
         if kind == CONV:
             layer.update(conv_in={"w": bf16}, conv_w=bf16, conv_out={"w": bf16})
+        elif kind == LINEAR and cfg.kda:
+            layer.update(kda_qkv={"w": bf16}, kda_conv_w=bf16,
+                         kda_fa={"w": bf16}, kda_fb={"w": bf16},
+                         kda_b={"w": bf16}, kda_ga={"w": bf16},
+                         kda_gb={"w": bf16}, kda_A_log=f32, kda_dt_bias=f32,
+                         kda_norm={"scale": f32}, kda_out={"w": bf16})
         elif kind == LINEAR:  # what shapes the decay and the gated norm: f32
             layer.update(gdn_in={"w": bf16}, gdn_ba={"w": bf16}, gdn_conv_w=bf16,
                          gdn_A_log=f32, gdn_dt_bias=f32,
@@ -2497,10 +2779,13 @@ def from_hf_state_dict(state: dict, cfg: DecoderConfig) -> dict:
     """Convert a HuggingFace ``LlamaForCausalLM`` state_dict (torch tensors —
     any dtype including bfloat16 — or numpy arrays) into this model's param
     pytree. Linear weights transpose from torch's [out, in] to [in, out]."""
+    if cfg.kda:
+        return _from_kimi_linear_state_dict(state, cfg)
     if cfg.num_experts > 1 or cfg.by_runs or cfg.hybrid:
-        raise ValueError("from_hf_state_dict maps dense Llama checkpoints; "
-                         "MoE, latent-attention, layer-pattern and hybrid "
-                         "configs unsupported")
+        raise ValueError("from_hf_state_dict maps dense Llama checkpoints and "
+                         "Kimi Linear's (linear_attn_config); other MoE, "
+                         "latent-attention, layer-pattern and hybrid configs "
+                         "unsupported")
 
     def t(name, transpose=False):
         return cm.hf_tensor(state, name, transpose)
@@ -2530,6 +2815,80 @@ def from_hf_state_dict(state: dict, cfg: DecoderConfig) -> dict:
         "lm_head": {"w": t(lm_head, transpose=True)},
         "layers": stacked,
     }
+
+
+def _from_kimi_linear_state_dict(state: dict, cfg: DecoderConfig) -> dict:
+    """``from_hf_state_dict`` for a ``KimiLinearForCausalLM`` state_dict
+    (``model_type: kimi_linear``), under its published key names: a KDA
+    layer's ``self_attn.{q,k,v}_proj`` / ``{q,k,v}_conv1d`` ([channels, 1,
+    taps]) / ``f_a_proj`` -> ``f_b_proj`` / ``b_proj`` / ``A_log`` /
+    ``dt_bias`` / ``g_a_proj`` -> ``g_b_proj`` / ``o_norm`` / ``o_proj``; an
+    MLA layer's ``q_proj`` / ``kv_a_proj_with_mqa`` / ``kv_a_layernorm`` /
+    ``kv_b_proj`` / ``o_proj``; ``mlp.*`` on the dense layers and
+    ``block_sparse_moe.gate`` (+ ``e_score_correction_bias``), ``experts.N.
+    w1 | w3 | w2`` (gate, up, down) and ``shared_experts.*`` on the others.
+    Of the routed experts those HELD here are read (``cfg.held``). The real
+    checkpoint is not in the repository: held by a seeded state dict of
+    these names (``tests/test_kda_mla_moe.py``)."""
+    def t(name, transpose=False):
+        return cm.hf_tensor(state, name, transpose)
+
+    def lin(name):
+        return {"w": t(f"{name}.weight", transpose=True)}
+
+    def layer(i, kind, routed):
+        p, a = f"model.layers.{i}", f"model.layers.{i}.self_attn"
+        out = {"attn_norm": {"scale": t(f"{p}.input_layernorm.weight")},
+               "mlp_norm": {"scale": t(f"{p}.post_attention_layernorm.weight")}}
+        if kind == LINEAR:
+            out.update(
+                kda_qkv={"w": jnp.concatenate(
+                    [t(f"{a}.{x}_proj.weight", True) for x in "qkv"], axis=1)},
+                kda_conv_w=jnp.concatenate(
+                    [t(f"{a}.{x}_conv1d.weight")[:, 0] for x in "qkv"], axis=0),
+                kda_fa=lin(f"{a}.f_a_proj"), kda_fb=lin(f"{a}.f_b_proj"),
+                kda_b=lin(f"{a}.b_proj"), kda_ga=lin(f"{a}.g_a_proj"),
+                kda_gb=lin(f"{a}.g_b_proj"),
+                kda_A_log=t(f"{a}.A_log").reshape(-1),
+                kda_dt_bias=t(f"{a}.dt_bias").reshape(-1),
+                kda_norm={"scale": t(f"{a}.o_norm.weight")},
+                kda_out=lin(f"{a}.o_proj"))
+        else:
+            out.update(wq=lin(f"{a}.q_proj"), wkv_a=lin(f"{a}.kv_a_proj_with_mqa"),
+                       kv_norm={"scale": t(f"{a}.kv_a_layernorm.weight")},
+                       wkv_b=lin(f"{a}.kv_b_proj"), wo=lin(f"{a}.o_proj"))
+        if not routed:
+            out.update({ours: lin(f"{p}.mlp.{theirs}") for ours, theirs in (
+                ("w_gate", "gate_proj"), ("w_up", "up_proj"), ("w_down", "down_proj"))})
+            return out
+        m = f"{p}.block_sparse_moe"
+        first, count = cfg.held
+        out.update(router=lin(f"{m}.gate"),
+                   router_bias=t(f"{m}.gate.e_score_correction_bias"))
+        out["experts"] = {
+            ours: jnp.stack(
+                [t(f"{m}.experts.{e}.{theirs}.weight", True)
+                 for e in range(first, first + count)]
+                + [t(f"{m}.shared_experts.{shared}.weight", True)])
+            for ours, theirs, shared in (("w_gate", "w1", "gate_proj"),
+                                         ("w_up", "w3", "up_proj"),
+                                         ("w_down", "w2", "down_proj"))}
+        return out
+
+    if cfg.n_shared_experts != 1:
+        raise ValueError("a Kimi Linear checkpoint has one shared expert a layer")
+    params = {"embed": {"table": t("model.embed_tokens.weight")},
+              "norm_out": {"scale": t("model.norm.weight")},
+              "lm_head": {"w": t("lm_head.weight", transpose=True)}}
+    stacks: dict = {}
+    at = 0
+    for name, first, stop, kind, routed, _ in layer_runs(cfg):
+        stacks.setdefault(name, []).extend(
+            layer(at + j, kind, routed) for j in range(stop - first))
+        at += stop - first
+    for name, stack in stacks.items():
+        params[name] = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *stack)
+    return params
 
 
 # -- incremental decoding (batched summarization path) ---------------------

@@ -16,7 +16,8 @@ The cache's shape is the model's to state and this module's to own
 ROW-MAJOR pools, ``[layers, pages, page, kv_heads * dh]``, which the
 attention kernel's narrow-head walk copies a page at a time —, a state a
 SEQUENCE beside them (a hybrid layer's Mamba-2 state, a conv layer's last
-gated inputs, a Gated DeltaNet layer's matrix state: a row a slot), or latent rows (MLA, ``cfg.latent``:
+gated inputs, a Gated DeltaNet layer's matrix state: a row a slot), or latent rows (MLA, ``cfg.latent``;
+Kimi Delta Attention layers' matrix states a SEQUENCE beside them, ``kda``:
 one normed latent row and one rotated rope key a token for all heads;
 attention runs in the absorbed form over them). Either kind's pools ride
 whole through the layer loop (``_dense_layers``, ``_latent_layers``): a
@@ -57,12 +58,11 @@ from dataclasses import dataclass
 from arkflow_tpu.models.decoder import (CONV, FULL, LINEAR, SLIDING,
                                         DecoderConfig, _mlp, _norm, _scaled,
                                         attn_out_gate, conv_gate, conv_taps,
-                                        gdn_conv, gdn_operands,
-                                        gdn_output, gdn_project, hc_collapse,
+                                        hc_collapse,
                                         hc_expand, hc_post, hc_pre,
                                         index_project, index_scores,
                                         layer_runs, layer_stacks,
-                                        lm_logits, mla_absorb_query,
+                                        linear_mixer, lm_logits, mla_absorb_query,
                                         mla_expanded_attention, mla_head_gate,
                                         mla_output, mla_project,
                                         mla_query_latent, moe_step_stats,
@@ -168,6 +168,11 @@ def cache_spec(cfg: DecoderConfig) -> tuple:
       ``linear_conv_kernel_dim - 1`` projected inputs (bfloat16), one row a
       SEQUENCE, over the linear_attention layers only (``kv`` over the
       attention layers only, as beside ``conv``);
+    - ``kda``: a Kimi Delta Attention layer's float32 state — a [head dim,
+      head dim] matrix a head — and its three convs' last
+      ``short_conv_kernel_size - 1`` projected inputs (bfloat16), one row a
+      SEQUENCE, over a LATENT model's linear_attention layers (``latent``
+      over its attention layers only);
     - ``eva``: per-head K and V of a compacting window cache
       (``attention_class`` "eva"), every layer: rows that are NOT positions.
       A slot's rows are the summary rows of its closed windows — ``window /
@@ -210,6 +215,12 @@ def cache_spec(cfg: DecoderConfig) -> tuple:
     full, swa = (cfg.kinds.count(k) for k in (FULL, SLIDING))
     pools = [CachePool("latent", full, (cfg.kv_lora_rank,
                                         _held_lanes(cfg.qk_rope_head_dim)))]
+    if cfg.linear:
+        pools.append(CachePool(
+            "kda", cfg.kinds.count(LINEAR),
+            (cfg.kda_heads * cfg.kda_head_dim ** 2,
+             (cfg.kda_taps - 1) * cfg.kda_conv_dim),
+            itemsizes=(4, 2), per_slot=True))
     if cfg.index_topk:
         pools.append(CachePool("index", full, (cfg.index_head_dim,)))
     if swa:
@@ -403,6 +414,7 @@ UNSERVED = {
     "ssm": {**_STATE, **_SCANNED},
     "conv": _STATE_BY_RUNS,
     "gdn": {**_STATE_BY_RUNS, **_SCANNED},
+    "kda": {**_STATE_BY_RUNS, **_SCANNED},  # beside ``latent``: the union
     "eva": {
         **_BY_RUNS,
         "mesh_tp": (
@@ -524,10 +536,26 @@ def init_page_pool(cfg: DecoderConfig, num_pages: int, page_size: int,
       and ``{"kv": V, "gdn": the conv windows}`` — ``kv`` over the attention
       layers, the states float32 [linear layers, slots + 1, value heads, key
       dim, value dim] (``ops/gdn_scan``), the windows [linear layers, slots
-      + 1, linear_conv_kernel_dim - 1, conv channels], rows as above.
+      + 1, linear_conv_kernel_dim - 1, conv channels], rows as above;
+    - a latent model with Kimi Delta Attention layers: ``{"latent": the
+      latent rows, "kda": the states}`` and ``{"latent": the rope keys,
+      "kda": the conv windows}`` — ``latent`` over the attention layers, the
+      states float32 [linear layers, slots + 1, heads, head dim, head dim]
+      (``ops/kda_scan``), the windows [linear layers, slots + 1,
+      short_conv_kernel_size - 1, 3 heads x head dim], rows as above.
 
     A head narrower than 128 lanes has ROW-MAJOR K and V, [layers, pages,
     page, kv_heads * width] (``CachePool.row_major``)."""
+    if cfg.latent and cfg.linear:
+        latent, kda = cache_spec(cfg)
+        c, r = (jnp.zeros((latent.layers, num_pages, page_size, w), jnp.bfloat16)
+                for w in latent.widths)
+        rows = (kda.layers, slots + 1)
+        return ({"latent": c, "kda": jnp.zeros(
+                    rows + (cfg.kda_heads, cfg.kda_head_dim, cfg.kda_head_dim),
+                    jnp.float32)},
+                {"latent": r, "kda": jnp.zeros(
+                    rows + (cfg.kda_taps - 1, cfg.kda_conv_dim), jnp.bfloat16)})
     if cfg.latent and cfg.layered:
         wide, rope = {}, {}
         for pool in cache_spec(cfg):
@@ -663,8 +691,10 @@ def _eva_close(lp: dict, kp, vp, layer, table, eva: _EvaClose,
 def _page_size(pools) -> int:
     """Tokens a page of the pools a step carries (``init_page_pool``: one
     array, or a dict by pool name whose per-slot pools have no pages)."""
-    if isinstance(pools, dict) and "kv" in pools:
-        return pools["kv"].shape[2]
+    if isinstance(pools, dict):  # a per-slot pool beside it has no pages
+        for paged in ("kv", "latent"):
+            if paged in pools:
+                return pools[paged].shape[2]
     return jax.tree_util.tree_leaves(pools)[0].shape[2]
 
 
@@ -683,7 +713,8 @@ def _latent_layers(params: dict, cfg: DecoderConfig, x, k_pages, v_pages,
                    positions, page_idx, offset, token_mask, *, page_table,
                    off, mask, block: bool, attention_kernel: str,
                    kernel_interpret: bool,
-                   chunk: Optional[_RidingChunk] = None):
+                   chunk: Optional[_RidingChunk] = None,
+                   ssm_rows=None, ssm_fresh=None):
     """The layer loop of a latent-attention model over the paged cache: one
     scan per layer run (leading dense layers, then expert layers; a layer
     pattern alternates kinds), each layer reading its kind's sizes
@@ -710,7 +741,15 @@ def _latent_layers(params: dict, cfg: DecoderConfig, x, k_pages, v_pages,
     router and expert product; it attends as ``chunk`` says
     (``_attend_latent``) once every token's row is written, and the
     counters come back by row range, [3, ...]: the lanes', the chunk's, and
-    the block's own (their loads summed: what the expert products read)."""
+    the block's own (their loads summed: what the expert products read).
+
+    A model with Kimi Delta Attention layers carries ``{"latent", "kda"}``
+    twice (the states beside the latent rows, the conv windows beside the
+    rope keys): a ``linear_attention`` run's layers advance the ``kda``
+    pool's rows (``_gdn_paged`` under ``ssm_rows`` [B] and ``ssm_fresh`` [B],
+    None in a decode step, ``token_mask`` the tokens that advance a state:
+    ``_dense_layers``' three operands) and touch no page; its runs are walked
+    by index (``_scan_run``), a stack that two runs share never sliced."""
     kernel = attention_kernel == "paged"
     kern = dict(attention_kernel=attention_kernel, kernel_interpret=kernel_interpret)
     lanes = 0 if chunk is None else chunk.lanes
@@ -726,6 +765,29 @@ def _latent_layers(params: dict, cfg: DecoderConfig, x, k_pages, v_pages,
         where[SLIDING] = _write_coords(ring, positions, token_mask, page, ring=True)
 
     def make_layer(routed: bool, kind: str, experts):
+        if kind == LINEAR:
+            def kda_layer(carry, scanned):
+                """A Kimi Delta Attention layer: its mixer over the kda
+                pool's rows, no page written or read."""
+                x, kp, vp, picked = carry
+                lp, li, *ei = scanned
+                mixed, states, windows = _gdn_paged(
+                    lp, cm.rms_norm(lp["attn_norm"], x, cfg.norm_eps), cfg,
+                    kp["kda"], vp["kda"], li, ssm_rows, ssm_fresh, token_mask,
+                    kernel, kernel_interpret)
+                x = x + mixed
+                y = cm.rms_norm(lp["mlp_norm"], x, cfg.norm_eps)
+                if routed:
+                    out, load = routed_mlp(lp, y, cfg, token_mask=token_mask,
+                                           kernel=kernel, interpret=kernel_interpret,
+                                           stacked=(experts, ei[0]))
+                else:
+                    out, load = _mlp(lp, y, cfg), None
+                return (x + out, {**kp, "kda": states},
+                        {**vp, "kda": windows}, picked), load
+
+            return kda_layer
+
         sp = cfg.attn(kind)
         name = "window" if sp.window else "latent"
         pi, po = where[kind]
@@ -735,7 +797,8 @@ def _latent_layers(params: dict, cfg: DecoderConfig, x, k_pages, v_pages,
         # the kernel every step; whole, the kernel indexes the layer itself
         def layer(carry, scanned):
             x, kp, vp, picked = carry
-            lp, li, ei = scanned
+            lp, li, *ei = scanned
+            ei = ei[0] if ei else None
             cp, rp = (kp[name], vp[name]) if layered else (kp, vp)
             if hc:
                 streams, (x, h) = x, hc_pre(lp["mhc_attn"], x, cfg, **mix)
@@ -789,14 +852,22 @@ def _latent_layers(params: dict, cfg: DecoderConfig, x, k_pages, v_pages,
         x = hc_expand(x, cfg)
     carry = (x, k_pages, v_pages, jnp.zeros((2,), jnp.int32))
     loads = []
-    for stack, routed, kind, kind_first in layer_stacks(params, cfg):
-        n = stack["attn_norm"]["scale"].shape[0]
-        scanned = {k: v for k, v in stack.items() if k != "experts"}
-        carry, load = jax.lax.scan(
-            make_layer(routed, kind, stack.get("experts")), carry,
-            (scanned, kind_first + jnp.arange(n), jnp.arange(n)))
-        if routed:
-            loads.append(load)
+    if cfg.linear:  # by index: a stack that two runs share is never sliced
+        for name, first, stop, kind, routed, kind_first in layer_runs(cfg):
+            carry, load = _scan_run(
+                make_layer(routed, kind, params[name].get("experts")), carry,
+                params[name], first, stop, kind_first, routed)
+            if routed:
+                loads.append(load)
+    else:
+        for stack, routed, kind, kind_first in layer_stacks(params, cfg):
+            n = stack["attn_norm"]["scale"].shape[0]
+            scanned = {k: v for k, v in stack.items() if k != "experts"}
+            carry, load = jax.lax.scan(
+                make_layer(routed, kind, stack.get("experts")), carry,
+                (scanned, kind_first + jnp.arange(n), jnp.arange(n)))
+            if routed:
+                loads.append(load)
     x, k_pages, v_pages, picked = carry
     if hc:
         x = hc_collapse(x, cfg)
@@ -1003,7 +1074,8 @@ def latent_kernel_probe(params: dict, cfg: DecoderConfig, page_size: int,
     a page boundary) against the gather path; with a layer pattern the
     sliding layer's window attention, the indexer's scores, the attention
     over a GIVEN selection and the choice of the largest GIVEN scores, each
-    against its plain-XLA form; and
+    against its plain-XLA form; with linear_attention layers the per-channel
+    delta rule's two kernels against their plain forms (``_gdn_probe``); and
     the expert product on GIVEN routing against plain XLA over the experts
     routed to.
 
@@ -1114,6 +1186,8 @@ def latent_kernel_probe(params: dict, cfg: DecoderConfig, page_size: int,
                 s, positions, k=k, interpret=kernel_interpret)))
     if cfg.hc_mult > 1:
         out.extend(_mhc_probe(lp, cfg, rand, kernel_interpret))
+    if cfg.linear:
+        out.extend(_gdn_probe(cfg, kernel_interpret))
     out.append(_expert_probe(params, cfg, keys, rand, kernel_interpret))
     return out
 
@@ -1250,17 +1324,20 @@ def gqa_kernel_probe(params: dict, cfg: DecoderConfig, page_size: int,
 
 def _gdn_probe(cfg: DecoderConfig, kernel_interpret: bool) -> list:
     """(name, reference, kernel output) of the delta rule's two kernels
-    against their plain forms (``ops/gdn_scan``) at the model's own head
+    against their plain forms (``ops/gdn_scan``; Kimi Delta Attention's:
+    ``ops/kda_scan``, a gate a key channel) at the model's own head
     counts and widths, on seeded operands of the statistics the layer hands
     them (unit keys, scaled unit queries, gates of every strength): a decode
     step of two lanes on rows 2 and 1 of a seeded pool, and a chunk of two
     blocks and a ragged tail, its first row fresh, its last positions padded
     (``g = beta = 0``). Each line is the outputs and the rows' states after,
     joined."""
-    from arkflow_tpu.ops.gdn_scan import BLOCK, gdn_chunk_scan, gdn_state_update
+    from arkflow_tpu.ops.gdn_scan import BLOCK
 
-    nv, dk, dv = (cfg.linear_num_value_heads, cfg.linear_key_head_dim,
-                  cfg.linear_value_head_dim)
+    *_, state_update, chunk_scan = linear_mixer(cfg)
+    nv, dk, dv = ((cfg.kda_heads, cfg.kda_head_dim, cfg.kda_head_dim) if cfg.kda
+                  else (cfg.linear_num_value_heads, cfg.linear_key_head_dim,
+                        cfg.linear_value_head_dim))
     keys = iter(jax.random.split(jax.random.PRNGKey(4242), 8))
     t = 2 * BLOCK + 5
     pool = jax.random.normal(next(keys), (1, 3, nv, dk, dv), jnp.float32)
@@ -1271,6 +1348,9 @@ def _gdn_probe(cfg: DecoderConfig, kernel_interpret: bool) -> list:
     v = jax.random.normal(next(keys), (2, t, nv, dv), jnp.float32)
     live = (jnp.arange(t) < t - 3)[None, :, None]
     g = jnp.where(live, -jnp.exp(2 * jax.random.normal(next(keys), (2, t, nv)) - 2), 0.0)
+    if cfg.kda:  # a gate a key channel: each head's spread over its channels
+        g = g[..., None] * jnp.exp(0.5 * jax.random.normal(
+            jax.random.PRNGKey(2424), (2, t, nv, dk)))
     beta = jnp.where(live, jax.nn.sigmoid(jax.random.normal(next(keys), (2, t, nv))), 0.0)
     rows = jnp.asarray([2, 1], jnp.int32)
     fresh = jnp.asarray([True, False])
@@ -1279,15 +1359,16 @@ def _gdn_probe(cfg: DecoderConfig, kernel_interpret: bool) -> list:
         return jnp.concatenate([o.reshape(-1, dv), states[0, rows].reshape(-1, dv)])
 
     def update(**kern):
-        return joined(*gdn_state_update(pool, 0, rows, q[:, 0], k[:, 0], v[:, 0],
-                                        g[:, 0], beta[:, 0], **kern))
+        return joined(*state_update(pool, 0, rows, q[:, 0], k[:, 0], v[:, 0],
+                                    g[:, 0], beta[:, 0], **kern))
 
     def scan(**kern):
-        return joined(*gdn_chunk_scan(pool, 0, rows, fresh, q, k, v, g, beta, **kern))
+        return joined(*chunk_scan(pool, 0, rows, fresh, q, k, v, g, beta, **kern))
 
     kern = dict(kernel=True, interpret=kernel_interpret)
-    return [("gdn_state_update", update(), update(**kern)),
-            ("gdn_chunk_scan", scan(), scan(**kern))]
+    name = "kda" if cfg.kda else "gdn"
+    return [(f"{name}_state_update", update(), update(**kern)),
+            (f"{name}_chunk_scan", scan(), scan(**kern))]
 
 
 def _write_keys(kp, k, layer, pi, po, parts: int):
@@ -1499,34 +1580,34 @@ def _conv_paged(lp: dict, y, cfg: DecoderConfig, windows, layer, rows, fresh,
 
 def _gdn_paged(lp: dict, y, cfg: DecoderConfig, states, windows, layer, rows,
                fresh, valid, kernel: bool, interpret: bool):
-    """A Gated DeltaNet layer's mixer over the ``gdn`` pool: ``states`` /
+    """A linear_attention layer's mixer over its state pool — a Gated
+    DeltaNet's over ``gdn``, Kimi Delta Attention's over ``kda``: the one
+    seam, ``decoder.linear_mixer`` —: ``states`` /
     ``windows`` whole (``init_page_pool``), ``rows`` / ``fresh`` / ``valid``
     as ``_mixer_paged``'s: a decode step is one token a lane
-    (``gdn_state_update``), a chunk runs from the row's state — a zero state
-    and an empty window where ``fresh`` — to the row's state
-    (``gdn_chunk_scan``). A position that is not valid leaves state and
+    (``gdn_state_update`` / ``kda_state_update``), a chunk runs from the
+    row's state — a zero state and an empty window where ``fresh`` — to the
+    row's state (``gdn_chunk_scan`` / ``kda_chunk_scan``). A position that is not valid leaves state and
     window as they are: its ``g`` and ``beta`` are 0, and the window keeps the
-    last ``linear_conv_kernel_dim - 1`` VALID inputs. Returns (the mixer's
+    last ``taps - 1`` VALID inputs. Returns (the mixer's
     output [B, S, dim], states, windows)."""
-    from arkflow_tpu.ops.gdn_scan import gdn_chunk_scan, gdn_state_update
-
-    t, keep = y.shape[1], cfg.linear_conv_kernel_dim - 1
-    u, z, b, a = gdn_project(lp, y, cfg)
+    project, conv, operands, output, update, scan = linear_mixer(cfg)
+    t, keep = y.shape[1], cfg.linear_taps - 1
+    u, z, b, a = project(lp, y, cfg)
     before = windows[layer, rows]                                 # [B, K-1, C]
     if fresh is not None:
         before = jnp.where(fresh[:, None, None], 0, before)
     ext = jnp.concatenate([before, u.astype(before.dtype)], axis=1)
     windows = windows.at[layer, rows].set(_last_valid(ext, valid, keep))
-    q, k, v, g, beta = gdn_operands(lp, gdn_conv(lp, ext, t), b, a, cfg, valid)
+    q, k, v, g, beta = operands(lp, conv(lp, ext, t), b, a, cfg, valid)
     kern = dict(kernel=kernel, interpret=interpret)
     if fresh is None:
-        o, states = gdn_state_update(states, layer, rows, q[:, 0], k[:, 0],
-                                     v[:, 0], g[:, 0], beta[:, 0], **kern)
+        o, states = update(states, layer, rows, q[:, 0], k[:, 0], v[:, 0],
+                           g[:, 0], beta[:, 0], **kern)
         o = o[:, None]
     else:
-        o, states = gdn_chunk_scan(states, layer, rows, fresh, q, k, v, g, beta,
-                                   **kern)
-    return gdn_output(lp, o, z, cfg, y.dtype), states, windows
+        o, states = scan(states, layer, rows, fresh, q, k, v, g, beta, **kern)
+    return output(lp, o, z, cfg, y.dtype), states, windows
 
 
 class _RidingChunk(NamedTuple):
@@ -1992,7 +2073,11 @@ def paged_prefill_chunk(params: dict, cfg: DecoderConfig, input_ids, chunk_off,
             params, cfg, x, k_pages, v_pages, positions, page_idx, offset,
             pos_valid, page_table=tables, off=chunk_off, mask=mask,
             block=False, attention_kernel=attention_kernel,
-            kernel_interpret=kernel_interpret)
+            kernel_interpret=kernel_interpret,
+            # traced for a latent model that caches a state only: the others'
+            # programs stay as they were
+            **(_ssm_operands(cfg, ssm_rows, chunk_off == 0)
+               if cfg.stateful else {}))
     else:
         x, new_k, new_v, *moe = _dense_layers(
             params, cfg, x, k_pages, v_pages, positions, page_idx, offset,
@@ -2065,7 +2150,9 @@ def paged_decode_step(params: dict, cfg: DecoderConfig, token_ids, lengths,
             write_off[:, None], active[:, None], page_table=tables,
             off=lengths, mask=valid, block=False,
             attention_kernel=attention_kernel,
-            kernel_interpret=kernel_interpret)
+            kernel_interpret=kernel_interpret,
+            **(_ssm_operands(cfg, jnp.where(active, jnp.arange(s) + 1, 0), None)
+               if cfg.stateful else {}))
     else:
         # the single query sits at absolute position lengths[s]: the
         # kernel's causal bound (key <= lengths) is exactly ``valid``;

@@ -40,7 +40,9 @@ Config:
                              # (layer_types: conv, conv_L_cache: a window
                              # of gated inputs a slot) and Gated DeltaNet
                              # layers (layer_types: linear_attention,
-                             # linear_*: a float32 matrix state a slot)
+                             # linear_*: a float32 matrix state a slot) —
+                             # or, beside latent layers, Kimi Delta
+                             # Attention layers (linear_attn_config)
     text_field: __value__
     tokenizer: meta-llama/Llama-3-8B     # optional (hash fallback otherwise)
     max_input: 256

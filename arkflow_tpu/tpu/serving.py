@@ -429,7 +429,7 @@ class GenerationServer:
                           cfg.hc_mult * cfg.dim * 2)
         #: a state a slot beside the K/V pages (``cache_spec``'s per-slot
         #: pool: the hybrid block's ``ssm``, conv layers' ``conv``, linear
-        #: attention layers' ``gdn``)
+        #: attention layers' ``gdn`` — or, beside latent pages, ``kda``)
         self._stateful = bool(cfg.stateful)
         self._win_cols = window_ring_pages(cfg, page_size, self.prefill_chunk)
         #: page 0 of the window pool is scratch too; every slot can hold a
@@ -978,11 +978,11 @@ class GenerationServer:
         kp, vp = init_page_pool(self.cfg, self.num_pages, self.page_size,
                                 self.num_win_pages, slots=self.slots)
         #: whose state each slot's row of the state pool holds — the
-        #: tenant's (prompt, tokens), its own lists — and which tenant of
-        #: the slot that is: set where a prompt's first chunk resets the row
-        #: and KEPT when the tenant finishes (the row moves again only under
-        #: the next tenant)
-        self._state_tenant: list[tuple] = [(None, None, 0)] * self.slots
+        #: tenant's (prompt, tokens), its own lists — which tenant of the
+        #: slot that is, and the tenant's page list (its own too): set where
+        #: a prompt's first chunk resets the row and KEPT when the tenant
+        #: finishes (the row moves again only under the next tenant)
+        self._state_tenant: list[tuple] = [(None, None, 0, ())] * self.slots
         if self._kv_io_sharding is not None:
             kp = jax.device_put(kp, self._kv_io_sharding)
             vp = jax.device_put(vp, self._kv_io_sharding)
@@ -1813,29 +1813,41 @@ class GenerationServer:
                 table[row, -1] = s + 1
         return table
 
-    def slot_state(self, slot: int) -> dict:
+    def slot_state(self, slot: int, latent: bool = False) -> dict:
         """What ``slot``'s row of the state pool holds, fetched from the
         device: ``prompt`` and ``tokens`` of the tenant whose first chunk
         reset the row last (None: never held), ``tenancy`` which tenant of
         the slot that is, ``state`` the row itself over the pool's layers
         (the hybrid block: [layers, heads, d_state, d_head] float32; conv
         layers: [conv layers, conv_L_cache - 1, dim], the last gated inputs
-        oldest first; linear attention layers: [linear layers, value heads,
-        key dim, value dim] float32, and under ``window`` the conv's last
-        projected inputs [linear layers, linear_conv_kernel_dim - 1,
+        oldest first; linear attention layers, either mixer's: [linear
+        layers, value heads, key dim, value dim] float32, and under
+        ``window`` the conv's last projected inputs [linear layers, taps - 1,
         channels], oldest first). A finished tenant's row stays as its last step left it —
         after its prompt and all but the last of its tokens — until the next
-        tenant's first chunk. Call between steps: a step in flight holds the
+        tenant's first chunk. With ``latent``, beside latent pages: the rows
+        the tenant's pages hold for the positions it fed, (latent rows
+        [latent layers, positions, kv_lora_rank], shared keys [latent layers,
+        positions, key lanes]) — the tenant's own only until another request
+        takes a page it gave back, so right after it finished with nothing
+        else in flight. Call between steps: a step in flight holds the
         donated pools."""
         if not self._stateful:
             raise ConfigError("slot_state: this model carries no recurrent state")
-        prompt, tokens, tenancy = self._state_tenant[slot]
+        prompt, tokens, tenancy, pages = self._state_tenant[slot]
         row = jnp.asarray(slot + 1, jnp.int32)  # an operand: one program
         pool = next(p.name for p in cache_spec(self.cfg) if p.per_slot)
         out = {"prompt": prompt, "tokens": tokens, "tenancy": tenancy,
                "state": jax.device_get(self.k_pages[pool][:, row])}
         if self.cfg.linear:  # the pool's second array: the conv windows
             out["window"] = jax.device_get(self.v_pages[pool][:, row])
+        if latent and self.cfg.latent and prompt is not None:
+            fed = len(prompt) + len(tokens) - 1
+            held = jnp.asarray(pages[:self._pages_needed(fed)], jnp.int32)
+            out["latent"] = tuple(
+                np.asarray(jax.device_get(rows[:, held])).reshape(
+                    rows.shape[0], -1, rows.shape[-1])[:, :fed]
+                for rows in (self.k_pages["latent"], self.v_pages["latent"]))
         return out
 
     def _slide_window(self, slot: int, first: int, last: int) -> None:
@@ -2083,7 +2095,8 @@ class GenerationServer:
             if off == 0:  # the chunk's program starts from a zero state
                 self.m_ssm_resets.inc()
                 self._state_tenant[slot] = (
-                    req.prompt, req.tokens, self._state_tenant[slot][2] + 1)
+                    req.prompt, req.tokens, self._state_tenant[slot][2] + 1,
+                    self._slot_pages[slot])
         so_far = () if kind != "chunk" or not self._moe_layers else (
             self._no_counts if req.chunk_moe is None else req.chunk_moe,)
         return packed, so_far

@@ -28,6 +28,7 @@ from tests.test_gdn_gqa_moe import TINY as GDN
 from tests.test_gen_run_ahead import PER_HEAD_ROUTED as ROUTED
 from tests.test_hetero_gqa_moe import DENSE
 from tests.test_hybrid_ssm import TINY as HYBRID
+from tests.test_kda_mla_moe import TINY as KDA
 from tests.test_mhc_mla_moe import TINY as STREAMS
 from tests.test_mla_moe import TINY as LATENT
 from tests.test_sparse_window_moe import TINY as PATTERN
@@ -43,6 +44,7 @@ KINDS = {
     "ssm": (HYBRID, {"kv", "ssm"}),
     "conv": (CONV, {"kv", "conv"}),
     "gdn": (GDN, {"kv", "gdn"}),
+    "kda": (KDA, {"latent", "kda"}),
     "eva": (EVA, {"eva"}),
     "streams": (STREAMS, {"streams", "latent"}),
     "hetero": ({**DENSE, "head_dim": 16, "v_head_dim": 8}, {"kv", "hetero"}),

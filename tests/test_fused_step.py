@@ -330,7 +330,7 @@ def test_a_fusing_server_serves_what_an_alternating_one_serves(case, depth):
     assert server._fused.jitted._cache_size() == 1
     assert server._decode.jitted._cache_size() == 1
     if server._stateful:  # every tenancy began with a first chunk's reset
-        assert [t for _, _, t in server._state_tenant] == [3, 3, 2]
+        assert [t[2] for t in server._state_tenant] == [3, 3, 2]
 
 
 def test_every_seam_of_a_fused_step_is_crossed_ahead():

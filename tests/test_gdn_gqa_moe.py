@@ -310,8 +310,7 @@ def _no_dt_bias(monkeypatch):
     def without(lp, *args, **kw):
         return real({**lp, "gdn_dt_bias": jnp.zeros_like(lp["gdn_dt_bias"])},
                     *args, **kw)
-    for mod in (dec, pd):
-        _set(monkeypatch, mod, "gdn_operands", without)
+    _set(monkeypatch, dec, "gdn_operands", without)  # (read through dec.linear_mixer)
 
 
 def _set(monkeypatch, target, name, value):
@@ -326,8 +325,7 @@ def _operands_control(change):
         def changed(lp, conved, b, a, cfg, valid=None):
             return change(*real(lp, conved, b, a, cfg, valid), conved=conved,
                           cfg=cfg)
-        for mod in (dec, pd):
-            _set(monkeypatch, mod, "gdn_operands", changed)
+        _set(monkeypatch, dec, "gdn_operands", changed)  # (read through dec.linear_mixer)
     return apply
 
 
@@ -382,8 +380,7 @@ def _gate_left_out(name):
             def ungated(lp, o, z, cfg, dtype):
                 # silu(z0) = 1 at z0 = 1.27846
                 return real(lp, o, jnp.full_like(z, 1.2784645), cfg, dtype)
-            for mod in (dec, pd):
-                _set(monkeypatch, mod, "gdn_output", ungated)
+            _set(monkeypatch, dec, "gdn_output", ungated)  # (read through dec.linear_mixer)
         elif name == "attention":  # sigmoid(gate) on the attention's output
             for mod in (dec, pd):
                 _set(monkeypatch, mod, "attn_out_gate", lambda lp, y, attn, cfg: attn)
@@ -405,8 +402,7 @@ def _conv_row_late(monkeypatch=None):
         shifted = {**lp, "gdn_conv_w": jnp.concatenate(
             [lp["gdn_conv_w"][..., 1:], jnp.zeros_like(lp["gdn_conv_w"][..., :1])], -1)}
         return real(shifted, ext, s)
-    for mod in (dec, pd):
-        _set(monkeypatch, mod, "gdn_conv", late)
+    _set(monkeypatch, dec, "gdn_conv", late)  # (read through dec.linear_mixer)
 
 
 def _coarse(x):

@@ -1098,6 +1098,41 @@ def test_gdn_kernels_compile_in_place(v5e, step):
     assert compiled.memory_analysis().temp_size_in_bytes < 64 * 1024 * 1024
 
 
+@pytest.mark.parametrize("step", ["update", "scan"])
+def test_kda_kernels_compile_in_place(v5e, step):
+    """The decode update over 128 lanes and the chunk scan of one 512-token
+    chunk at Kimi Linear's mixer sizes (32 heads of 128 x 128, a decay a key
+    channel), on the whole 1.62 GB state pool, aliased to the output: the
+    decay reaches the update as ROWS beside k and q, and the scan's level
+    products, its transposes and its one-hot gathers are the chip
+    compiler's to accept."""
+    from arkflow_tpu.ops import kda_scan as ks
+
+    f32 = jnp.float32
+    pool = ((6, 129, 32, 128, 128), f32)
+    if step == "update":
+        b = 128
+        fn = lambda st, layer, rows, q, k, v, g, beta: ks.kda_state_update(  # noqa: E731
+            st, layer, rows, q, k, v, g, beta, kernel=True)
+        heads = ((b, 32, 128), f32)
+        shapes = (pool, ((), I32), ((b,), I32), heads, heads, heads, heads,
+                  ((b, 32), f32))
+    else:
+        t = 512
+        fn = lambda st, layer, rows, fr, q, k, v, g, beta: ks.kda_chunk_scan(  # noqa: E731
+            st, layer, rows, fr, q, k, v, g, beta, kernel=True)
+        heads = ((1, t, 32, 128), f32)
+        shapes = (pool, ((), I32), ((1,), I32), ((1,), jnp.bool_), heads, heads,
+                  heads, heads, ((1, t, 32), f32))
+    one = SingleDeviceSharding(v5e[0])
+    compiled = jax.jit(fn, donate_argnums=(0,)).lower(
+        *[jax.ShapeDtypeStruct(s, d, sharding=one) for s, d in shapes]).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert ("kda_state_update" if step == "update" else "kda_chunk_scan") in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 96 * 1024 * 1024
+
+
 @pytest.mark.parametrize("kvh,pool_kvh,relaid", [(2, 2, True), (2, 1, False)],
                          ids=["joined", "a-head-a-layer"])
 def test_a_head_of_256_on_two_kv_heads_is_viewed_in_place_a_head_a_layer(
@@ -1172,6 +1207,79 @@ def test_gdn_steps_carry_pools_and_stacks_whole(v5e):
         for name in ("paged_flash_attention", "moe_expert_swiglu", kernel):
             assert name in text, name
         assert step.memory_analysis().temp_size_in_bytes < 300 * 1024 * 1024, \
+            step.memory_analysis().temp_size_in_bytes
+
+
+KIMI = dict(vocab_size=20480, dim=2304, layers=8, heads=32, ffn=9216,
+            max_seq=1048576, norm_eps=1e-5, kv_lora_rank=512,
+            qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+            mla_use_nope=True,
+            layer_types=(("linear_attention",) * 3 + ("full_attention",)) * 2,
+            linear_attn_config={"num_heads": 32, "head_dim": 128,
+                                "short_conv_kernel_size": 4},
+            n_routed_experts=256, experts_held=(0, 32), num_experts_per_tok=8,
+            n_shared_experts=1, moe_intermediate_size=1024,
+            first_k_dense_replace=1, routed_scaling_factor=2.446)
+#: 128 slots x 1,072 kept pages + scratch: every slot's whole table
+KIMI_SLOTS, KIMI_TABLE, KIMI_CHUNK = 128, 1072, 512
+
+
+def test_kda_steps_carry_pools_and_stacks_whole(v5e):
+    """The ``_decode`` (128 lanes) and ``_chunk`` (1 x 512) programs of the
+    Kimi Linear cut as the server jits them, pools donated: the latent walk,
+    the expert kernel and the per-channel delta rule's kernel are in the
+    text, and the temporaries stay under 400 MB — no pool (1.62 GB of float32
+    states, 2.81 GB of latent pages a pool) and no run's experts (0.9 GB a
+    run of two; the ``kda_layers`` stack is walked by index, its two runs
+    never sliced) is copied for a step."""
+    from arkflow_tpu.models import decoder as dec
+    from arkflow_tpu.models.paged_decode import (init_page_pool, paged_decode_step,
+                                                 paged_prefill_chunk)
+
+    cfg = dec.DecoderConfig(**KIMI)
+    repl = SingleDeviceSharding(v5e[0])
+
+    def struct(a, dtype=None):
+        return jax.ShapeDtypeStruct(a.shape, dtype or a.dtype, sharding=repl)
+
+    params = jax.tree_util.tree_map(
+        struct, jax.eval_shape(lambda: dec.init(jax.random.PRNGKey(0), cfg)),
+        dec.serve_dtypes(cfg))
+    kp, vp = jax.tree_util.tree_map(struct, jax.eval_shape(
+        lambda: init_page_pool(cfg, 1 + KIMI_SLOTS * KIMI_TABLE, PAGE,
+                               slots=KIMI_SLOTS)))
+    assert kp["latent"].shape == (2, 1 + 128 * 1072, 16, 512)
+    assert vp["latent"].shape == (2, 1 + 128 * 1072, 16, 128)
+    assert kp["kda"].shape == (6, 129, 32, 128, 128)
+    assert vp["kda"].shape == (6, 129, 3, 12288)
+    kern = dict(attention_kernel="paged")
+
+    def decode(p, tok, lens, act, table, kp, vp):
+        return paged_decode_step(p, cfg, tok, lens, act, table, kp, vp,
+                                 return_logits=True, **kern)
+
+    def chunk(p, ids, off, clen, table, rows, kp, vp):
+        return paged_prefill_chunk(p, cfg, ids, off, clen, table, kp, vp,
+                                   ssm_rows=rows, **kern)
+
+    def compiled(fn, *operands):
+        n = len(operands)
+        return jax.jit(fn, donate_argnums=(n + 1, n + 2)).lower(
+            params, *[jax.ShapeDtypeStruct(s, d, sharding=repl)
+                      for s, d in operands], kp, vp).compile()
+
+    s = KIMI_SLOTS
+    for kernels, step in (
+            (("kda_state_update", "moe_expert_swiglu"), compiled(
+                decode, ((s,), I32), ((s,), I32), ((s,), jnp.bool_),
+                ((s, KIMI_TABLE), I32))),
+            (("kda_chunk_scan", "moe_expert_grouped"), compiled(
+                chunk, ((1, KIMI_CHUNK), I32), ((1,), I32), ((1,), I32),
+                ((1, KIMI_TABLE), I32), ((1,), I32)))):
+        text = step.as_text()
+        for name in ("mla_paged_attention", *kernels):
+            assert name in text, name
+        assert step.memory_analysis().temp_size_in_bytes < 400 * 1024 * 1024, \
             step.memory_analysis().temp_size_in_bytes
 
 

@@ -1,4 +1,4 @@
-"""Time the gated delta rule's two kernels alone, at the shapes ``qwen3next_l8`` serves.
+"""Time the gated delta rule's two kernels alone, at the shapes ``qwen3next_l8`` serves (``--mixer kda``: the per-channel delta rule's, ``ops/kda_scan.py``, at ``kimilinear_l8``'s).
 
 The kernel-alone microbench behind PERF.md's numbers for ``ops/gdn_scan.py``
 (PR 49): the decode update (``gdn_state_update``: 128 lanes, 32 heads of
@@ -17,6 +17,7 @@ holds, each checked against its plain form first. One JSON line a point:
 
     python tools/profile_gdn_scan.py
     python tools/profile_gdn_scan.py --kind scan --chunk 256
+    python tools/profile_gdn_scan.py --mixer kda      (a decay a key channel)
 
 Needs a TPU (``--interpret`` rehearses tiny shapes on the CPU; its lines say
 ``"rehearsal"``).
@@ -35,6 +36,9 @@ sys.path.insert(0, __file__.rsplit("/", 2)[0])
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--kind", default="all", choices=["all", "update", "scan"])
+    ap.add_argument("--mixer", default="gdn", choices=["gdn", "kda"],
+                    help="gdn: one decay a head (ops/gdn_scan); kda: a decay a "
+                         "key channel (ops/kda_scan), both at 32 heads of 128")
     ap.add_argument("--lanes", type=int, default=128)
     ap.add_argument("--chunk", type=int, default=512)
     ap.add_argument("--reps", type=int, default=12)
@@ -46,7 +50,13 @@ def main() -> int:
     import jax.numpy as jnp
 
     from arkflow_tpu.ops import gdn_scan as gs
+    from arkflow_tpu.ops import kda_scan as ks
     from benchmark.lib import costs_gdn_gqa_moe as costs
+    from benchmark.lib import costs_kda_mla_moe as kda_costs
+
+    kda = args.mixer == "kda"
+    update = ks.kda_state_update if kda else gs.gdn_state_update
+    scan = ks.kda_chunk_scan if kda else gs.gdn_chunk_scan
 
     device = jax.devices()[0]
     if device.platform != "tpu" and not args.interpret:
@@ -58,6 +68,7 @@ def main() -> int:
         with open(__file__.rsplit("/", 2)[0] + "/benchmark/peaks.json") as f:
             peaks = json.load(f)[device.device_kind]
     layers, heads, dk, dv = (2, 4, 16, 128) if args.interpret else (6, 32, 128, 128)
+    dv = dk if kda and args.interpret else dv   # a KDA head's widths are one
     lanes = 3 if args.interpret else args.lanes
     chunk = 70 if args.interpret else args.chunk
     keys = iter(jax.random.split(jax.random.PRNGKey(0), 16))
@@ -70,7 +81,8 @@ def main() -> int:
         q = unit(jax.random.normal(next(keys), (b, t, heads, dk))) * dk ** -0.5
         k = unit(jax.random.normal(next(keys), (b, t, heads, dk)))
         v = jax.random.normal(next(keys), (b, t, heads, dv))
-        g = -jnp.exp(2 * jax.random.normal(next(keys), (b, t, heads)) - 2)
+        g = -jnp.exp(2 * jax.random.normal(
+            next(keys), (b, t, heads) + ((dk,) if kda else ())) - 2)
         beta = jax.nn.sigmoid(jax.random.normal(next(keys), (b, t, heads)))
         return q, k, v, g, beta
 
@@ -79,7 +91,7 @@ def main() -> int:
         ops = tuple(a[:, 0] for a in operands(lanes, 1))
         rows = 1 + jnp.arange(lanes, dtype=jnp.int32)
         points.append(("update", lambda st, layer, rows=rows, ops=ops, **kw:
-                       gs.gdn_state_update(st, layer, rows, *ops, **kw),
+                       update(st, layer, rows, *ops, **kw),
             costs.update_bytes(lanes=lanes, layers=1, value_heads=heads,
                                key_dim=dk, value_dim=dv, conv_channels=0, taps=1),
             0.0))
@@ -87,16 +99,20 @@ def main() -> int:
         ops = operands(1, chunk)
         rows, fresh = jnp.asarray([1], jnp.int32), jnp.asarray([False])
         points.append(("scan", lambda st, layer, rows=rows, ops=ops, **kw:
-                       gs.gdn_chunk_scan(st, layer, rows, fresh, *ops, **kw),
+                       scan(st, layer, rows, fresh, *ops, **kw),
+            kda_costs.chunk_scan_bytes(tokens=chunk, layers=1, heads=heads,
+                                       head_dim=dk) if kda else
             costs.chunk_scan_bytes(tokens=chunk, layers=1, value_heads=heads,
                                    key_dim=dk, value_dim=dv),
+            kda_costs.chunk_scan_flops(tokens=chunk, layers=1, heads=heads,
+                                       head_dim=dk) if kda else
             costs.chunk_scan_flops(tokens=chunk, layers=1, value_heads=heads,
                                    key_dim=dk, value_dim=dv)))
     kern = dict(kernel=True, interpret=args.interpret)
     for name, call, nbytes, flops in points:
         want_o, want_pool = jax.jit(lambda st: call(st, 1))(pool)
         got_o, got_pool = jax.jit(lambda st: call(st, 1, **kern))(pool)
-        line = {"kernel": name, "lanes": lanes, "chunk": chunk, "heads": heads,
+        line = {"kernel": f"{args.mixer}_{name}", "lanes": lanes, "chunk": chunk, "heads": heads,
                 "device": device.device_kind,
                 "max_abs_err": float(max(jnp.abs(got_o - want_o).max(),
                                          jnp.abs(got_pool - want_pool).max()))}
