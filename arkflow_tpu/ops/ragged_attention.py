@@ -16,6 +16,7 @@ from __future__ import annotations
 
 import functools
 import math
+import operator
 
 import jax
 import jax.numpy as jnp
@@ -178,14 +179,27 @@ def ragged_flash_attention(q, k, v, lengths, *, causal: bool = False,
 # exponentiated and reduced for nothing (3,667 us a 512-token call at
 # offset 4,096 on MiMo's full layers against 1,614 a head at a time,
 # PERF.md PR 43). So a chunk's queries are folded (kv head, position,
-# query head of the group) by the wrapper, the group's K and V are cast
-# once into float32 scratch (a strided read of VMEM takes 32-bit rows of
-# 128 lanes, not bfloat16's packed ones), and each K/V head's rows are read
-# out of it with a sublane stride and multiplied by that head's query rows
-# alone: [rows / kv_heads, dh] x [dh, G*page], nothing masked across heads,
-# the online softmax a head, its sums carried by the walk's loop (in VMEM
-# scratch, read and written a step, a call cost 20-25 % more). GQA needs
-# no ``jnp.repeat`` of K/V either way.
+# query head of the group) by the wrapper, and each K/V head's rows are read
+# out of the slot as it was copied, with a sublane stride, and multiplied by
+# that head's query rows alone: [rows / kvh, dh] x [dh, G*page], nothing
+# masked across heads, the online softmax a head, its sums carried by the
+# walk's loop (in VMEM scratch, read and written a step, a call cost 20-25 %
+# more). A strided read of VMEM takes 32-bit rows of 128 lanes, and a
+# bfloat16 slot packs two neighbouring rows — two K/V heads of one key — a
+# word: so the slot is VIEWED as 32-bit words, the read takes the words that
+# hold the head, and the head's half of each is moved out (``_word_runs``,
+# ``_head_rows``: nothing is cast, nothing copied). (Until PR 60 the group's K
+# and V were cast into float32 scratch first and read out of that: on 32 K/V
+# heads that cast and its traffic were half of a call — 3,062 us a 512-token
+# call over 3,072 rows, 1,390 now; rounding the float32 read back to bfloat16
+# for the products, the scratch kept, 2,975: PERF.md PR 60.) GQA needs no
+# ``jnp.repeat`` of K/V either way.
+#
+# Both products take their operands in the type the queries and the pools
+# hold — bfloat16 on a server, float32 in the CPU tests' plain cases — and
+# sum in float32; the probabilities are rounded to that type for the value
+# product, as the narrow and the latent kernel's are. Maxima, denominators,
+# the scale, the mask and the sink stay float32.
 
 #: folded query rows (positions x heads) per program: bounds VMEM whatever
 #: the chunk length is ([rows, 128] f32 score tiles of 512 KiB)
@@ -225,14 +239,22 @@ _PAGED_GROUP_MAX = 16
 #: layer of a pool: ``GqaSpec.split_heads``), so that a page adds only
 #: ``page`` columns under the call's few rows — measured there and nowhere
 #: else: 128 lanes at 3,072 keys of 256, 8 query heads a call, 3,609 us at
-#: 16, 3,218 at 32, 2,965 at 64 (PERF.md, PR 49)
+#: 16, 3,218 at 32, 2,965 at 64 (PERF.md, PR 49; 2,947 at 64 under bfloat16
+#: products, the parent beside it 2,961: the copies bind it, PR 60)
 _PAGED_ONE_HEAD_GROUP_MAX = 64
 
 #: and a chunk tile's, a K/V head at a time, where a page adds only ``page``
 #: columns a head: a 512-token call at offset 4,096 / 11,776 on MiMo's full
 #: layers 1,926 / 5,019 us at 16, 1,614 / 4,154 at 32, 1,669 / 4,022 at 64;
 #: at 2,048 on K-EXAONE's 989, 961, 1,068; at 512 on l6 104, 116, 116 (the
-#: walk's last group is multiplied whole, its dead pages too: PERF.md, PR 43)
+#: walk's last group is multiplied whole, its dead pages too: PERF.md, PR 43).
+#: Measured again under bfloat16 products (PERF.md, PR 60), us at 32 / 64:
+#: MiMo's full layers at 1,024 / 4,096 / 11,776 565, 637 / 1,504, 1,520 /
+#: 3,864, 3,636; K-EXAONE's at 512 / 2,048 / 7,680 409, 412 / 787, 851 /
+#: 2,278, 2,076; one K/V head of 256 (Qwen3-Next's) at 2,048 / 7,680 295, 315
+#: / 758, 725; 32 K/V heads (EvaByte's; 45 fit) over 2,176 / 3,072 / 3,968
+#: rows 1,052, 1,093 / 1,395, 1,458 / 1,736, 1,813: 64 gains 4-9 % past ~8k
+#: keys and loses 5-12 % under ~3k, where the cells' chunks are: it stays
 _PAGED_CHUNK_GROUP_MAX = 32
 
 
@@ -272,35 +294,43 @@ def per_kv_head(tile_c: int, heads: int, kvh: int) -> bool:
     over that head's own query rows (a chunk), or once for all heads with
     the other heads' columns masked (a decode step): per head where a head's
     rows of the tile, ``tile_c`` positions x its query heads, are whole
-    float32 sublane tiles. The ONE predicate: the kernel's walk cuts its
+    float32 sublane tiles — and the K/V heads one or an even number: a
+    bfloat16 pool packs two heads' rows a 32-bit word, and an odd number
+    would pack a key's last head with the next key's first
+    (``_head_rows``). The ONE predicate: the kernel's walk cuts its
     tile by it and the server counts its programs by it (``tpu/serving.py``:
     ``arkflow_gen_attn_tiles_total``). (A row-major pool's walk,
     ``_narrow_kernel``, multiplies a 128-lane run of heads at a time over
     those heads' own query rows whatever the tile: counted per head.)"""
-    return tile_c > 1 and tile_c * (heads // kvh) % 8 == 0
+    return (tile_c > 1 and tile_c * (heads // kvh) % 8 == 0
+            and (kvh == 1 or kvh % 2 == 0))
 
 
 def _page_group(rows: int, page: int, kvh: int, dh: int, itemsize: int,
-                dv: int = 0, per_head: bool = False) -> int:
+                dv: int = 0, per_head: bool = False, parts: int = 1) -> int:
     """Pages a program takes per step of its walk, from the shapes it is
-    called with (``dh`` the keys' width as held, ``dv`` the values' where it
-    is another). A page costs VMEM in two places: its K and V rows — twice
-    (two slots) in the pools' type, once more in float32 for the products —
-    and its columns of every [rows, columns] float32 tile the softmax holds
-    at once (scores, probabilities, the mask's bounds: four). All heads at
-    once, a page is ``page * kvh`` columns under all ``rows``: a decode
-    step's 8-64 folded rows leave room for many pages, so its groups stop at
-    ``_PAGED_GROUP_MAX`` (``_PAGED_ONE_HEAD_GROUP_MAX`` over pools of one K/V
-    head of more than 128 lanes). A K/V head at a time (``per_head``, a chunk tile)
-    it is ``page`` columns under that head's ``rows / kvh`` rows, and the
-    head's K and V rows once more as read out of the scratch."""
+    called with (``dh`` the keys' width as held, in ``parts`` parts; ``dv``
+    the values' where it is another). A page costs VMEM in two places: its K
+    and V rows, twice (two slots) in the pools' type — the products take
+    them as held, no float32 copy —, and its columns of every [rows,
+    columns] float32 tile the softmax holds at once (scores, probabilities,
+    the mask's bounds: four). All heads at once, a page is ``page * kvh``
+    columns under all ``rows``: a decode step's 8-64 folded rows leave room
+    for many pages, so its groups stop at ``_PAGED_GROUP_MAX``
+    (``_PAGED_ONE_HEAD_GROUP_MAX`` over pools of one K/V head of more than
+    128 lanes). A K/V head at a time (``per_head``, a chunk tile) it is
+    ``page`` columns under that head's ``rows / kvh`` rows, and the head's K
+    and V rows once more as read out of the slot (32-bit words, then the
+    pools' type); a slot of several heads wider than one 128-lane run is
+    copied once more for that read (``_word_runs``)."""
     cols = page * kvh
     kv = (dh + (dv or dh)) // 2
     if per_head:
-        a_page = (cols * kv * (4 * itemsize + 8) + page * kv * 8
-                  + page * (rows // kvh) * 16)
+        copied = kvh > 1 and max(dh // parts, dv or dh) > 128
+        a_page = (cols * kv * (4 + 2 * copied) * itemsize
+                  + page * kv * (8 + 2 * itemsize) + page * (rows // kvh) * 16)
         return max(1, min(_PAGED_CHUNK_GROUP_MAX, _walk_budget() // a_page))
-    a_page = cols * (kv * (4 * itemsize + 8) + rows * 16)
+    a_page = cols * (kv * 4 * itemsize + rows * 16)
     most = (_PAGED_ONE_HEAD_GROUP_MAX if kvh == 1 and dh > 128
             else _PAGED_GROUP_MAX)
     return max(1, min(most, _walk_budget() // a_page))
@@ -311,6 +341,39 @@ def _lane_runs(width: int) -> list[tuple[int, int]]:
     (the chip's; a narrower or ragged width, interpreted only, whole)."""
     run = 128 if width % 128 == 0 else width
     return [(a, a + run) for a in range(0, width, run)]
+
+
+def _word_runs(buf, slot, spare):
+    """``buf[slot]``, a group's rows ordered (key, K/V head), as refs of
+    32-bit WORDS a 128-lane run wide, which is what a strided read of VMEM
+    takes: a bfloat16 pool packs two neighbouring rows a word — K/V heads
+    2i and 2i + 1 of one key —, so no read picks ONE head's 16-bit rows, but
+    one picks the words that hold it (``_head_rows``). A slot of one run is
+    viewed in place, nothing copied; a wider one ("the last dim size is not
+    128 in original base memref") is copied run by run into the next of the
+    ``spare`` scratch refs (``_walk_call``'s ``word_scratch``)."""
+    view = buf.bitcast(jnp.uint32)
+    runs = _lane_runs(buf.shape[-1])
+    if len(runs) == 1:
+        return [view.at[slot]]
+    out = [next(spare) for _ in runs]
+    for ref, (a, z) in zip(out, runs):
+        ref[...] = view[slot, :, a:z]
+    return out
+
+
+def _head_rows(words, j: int, keys: int, kvh: int, dtype):
+    """K/V head ``j``'s row of each of ``keys`` keys out of ``_word_runs``'s
+    words, in the pools' ``dtype``: the words at a stride of the heads'
+    words a key, and of each word the head's bits (exact: a move)."""
+    pack = 4 // jnp.dtype(dtype).itemsize               # rows a word
+    assert kvh % pack == 0, (kvh, dtype)                # ``per_kv_head``
+    got = words[pl.ds(j // pack, keys, stride=kvh // pack), :]
+    if pack == 1:
+        return jax.lax.bitcast_convert_type(got, dtype)
+    bits = 32 // pack
+    return jax.lax.bitcast_convert_type(
+        (got >> (bits * (j % pack))).astype(jnp.dtype(f"uint{bits}")), dtype)
 
 
 def _window_start(first, window: int, cols: int):
@@ -328,11 +391,10 @@ def _paged_kernel(off_ref, table_ref, q_ref, *rest, page: int,
     if sink:  # [rows, 1] float32: each folded row's head's sink logit
         sink_ref, *rest = rest
     if per_head:
-        # the group's K and V in float32, rows as held, a scratch a run of
-        # 128 lanes; the accumulators ride the walk's loop, not VMEM scratch
-        k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, *floats = rest
-        k_lanes = _lane_runs(q_ref.shape[2] // parts) * parts
-        kf_bufs, vf_bufs = floats[:len(k_lanes)], floats[len(k_lanes):]
+        # the accumulators ride the walk's loop, not VMEM scratch; ``words``:
+        # where a slot is wider than one 128-lane run, its rows once more in
+        # runs a strided read takes (``_word_runs``)
+        k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, *words = rest
     else:
         k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, o_acc, m_acc, l_acc = rest
     bi = pl.program_id(0)
@@ -399,6 +461,11 @@ def _paged_kernel(off_ref, table_ref, q_ref, *rest, page: int,
     start(0, 0)
     scale = scale or 1.0 / math.sqrt(d)
     dims = (((1,), (1,)), ((), ()))
+    # both products take their operands in the type the queries and the
+    # pools agree on — a server's are both the pools', bfloat16, and nothing
+    # is cast —, the sums float32; the probabilities are rounded to it for
+    # the value product, as the narrow and the latent kernel's
+    op = jnp.promote_types(q_ref.dtype, k_buf.dtype)
 
     def arrive(g):
         """Start the copies of the group after the g-th, wait for the
@@ -426,35 +493,40 @@ def _paged_kernel(off_ref, table_ref, q_ref, *rest, page: int,
         # a head's [rows / kvh, group * page] tile: row r is position
         # r // (heads / kvh) of the tile, column c key c of the group;
         # nothing to mask across heads
-        mine, keys, w = rows // kvh, group * page, k_lanes[0][1]
+        mine, keys = rows // kvh, group * page
         r = jax.lax.broadcasted_iota(jnp.int32, (mine, keys), 0)
         c = jax.lax.broadcasted_iota(jnp.int32, (mine, keys), 1)
         ahead = first + r // (heads // kvh) - c
 
         def head_by_head(g, acc):
             slot = arrive(g)
-            # a strided read takes whole 128-lane rows of 32 bits
-            per = len(kf_bufs) // parts
-            for i, (buf, (a, z)) in enumerate(zip(kf_bufs, k_lanes)):
-                buf[...] = k_buf[2 * (i // per) + slot, :, a:z].astype(jnp.float32)
-            for buf, (a, z) in zip(vf_bufs, _lane_runs(v_buf.shape[-1])):
-                buf[...] = v_buf[slot, :, a:z].astype(jnp.float32)
+            if kvh > 1:  # the slots as 32-bit words, a ref a 128-lane run
+                spare = iter(words)
+                k_words = [ref for p in range(parts)
+                           for ref in _word_runs(k_buf, 2 * p + slot, spare)]
+                v_words = _word_runs(v_buf, slot, spare)
             keep = kept(g, ahead, ahead)
             out = []
             for j, (o, m, l) in enumerate(acc):
                 at = slice(j * mine, (j + 1) * mine)
-                own = pl.ds(j, keys, stride=kvh)    # kv head j's row of every key
-                scores = sum(jax.lax.dot_general(
-                    q_ref[0, at, i * w:(i + 1) * w].astype(jnp.float32),
-                    buf[own, :], dims, preferred_element_type=jnp.float32)
-                    for i, buf in enumerate(kf_bufs)) * scale     # [mine, keys]
+                if kvh == 1:  # the group's rows ARE the head's
+                    k = [k_buf[2 * p + slot] for p in range(parts)]
+                    v = v_buf[slot]
+                else:
+                    k = [_head_rows(ref, j, keys, kvh, k_buf.dtype) for ref in k_words]
+                    v = jnp.concatenate([_head_rows(ref, j, keys, kvh, v_buf.dtype)
+                                         for ref in v_words], axis=1)
+                w = d // len(k)
+                scores = functools.reduce(operator.add, (jax.lax.dot_general(
+                    q_ref[0, at, i * w:(i + 1) * w].astype(op), k_i.astype(op),
+                    dims, preferred_element_type=jnp.float32)
+                    for i, k_i in enumerate(k))) * scale          # [mine, keys]
                 scores = jnp.where(keep, scores, _NEG)
                 m_new = jnp.maximum(m, scores.max(axis=-1, keepdims=True))
                 p = jnp.exp(scores - m_new)
                 corr = jnp.exp(m - m_new)
-                v = jnp.concatenate([buf[own, :] for buf in vf_bufs], axis=1)
                 out.append((o * corr + jax.lax.dot_general(
-                    p, v, (((1,), (0,)), ((), ())),
+                    p.astype(op), v.astype(op), (((1,), (0,)), ((), ())),
                     preferred_element_type=jnp.float32),
                     m_new, l * corr + p.sum(axis=-1, keepdims=True)))
             return tuple(out)
@@ -490,21 +562,17 @@ def _paged_kernel(off_ref, table_ref, q_ref, *rest, page: int,
     ahead = first + r // heads - ((c // cols) * page + (c % cols) // kvh)
     own_head = (r % heads) // (heads // kvh) == c % kvh
     bound = jnp.where(own_head, ahead, -1)
-    q = q_ref[0].astype(jnp.float32)                              # [rows, D]
+    q = q_ref[0].astype(op)                                       # [rows, D]
+    w = d // parts
 
     def body(g, _):
         slot = arrive(g)
-        k = k_buf[slot].astype(jnp.float32)                       # [width, D]
-        v = v_buf[slot].astype(jnp.float32)
-        if parts == 1:
-            scores = jax.lax.dot_general(
-                q, k, dims, preferred_element_type=jnp.float32) * scale
-        else:  # a key in parts: the products of each part's lanes, summed
-            w = k.shape[1]
-            scores = sum(jax.lax.dot_general(
-                q[:, p * w:(p + 1) * w], k_buf[2 * p + slot].astype(jnp.float32),
-                dims, preferred_element_type=jnp.float32)
-                for p in range(parts)) * scale
+        # a key in parts: the products of each part's lanes, summed
+        scores = functools.reduce(operator.add, (jax.lax.dot_general(
+            q[:, p * w:(p + 1) * w] if parts > 1 else q,
+            k_buf[2 * p + slot].astype(op),                       # [width, D]
+            dims, preferred_element_type=jnp.float32)
+            for p in range(parts))) * scale
         scores = jnp.where(kept(g, bound, ahead), scores, _NEG)   # [rows, width]
         m = m_acc[:, :1]                                          # [rows, 1]
         m_new = jnp.maximum(m, scores.max(axis=-1, keepdims=True))
@@ -514,7 +582,7 @@ def _paged_kernel(off_ref, table_ref, q_ref, *rest, page: int,
             l_acc[:, :1] * corr + p.sum(axis=-1, keepdims=True), l_acc.shape)
         m_acc[:] = jnp.broadcast_to(m_new, m_acc.shape)
         o_acc[:] = o_acc[:] * corr + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
+            p.astype(op), v_buf[slot].astype(op), (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
     jax.lax.fori_loop(0, steps, body, None)
@@ -537,7 +605,7 @@ def _walk_call(b, tiles, rows, dh, dtype, *, page, kvh, heads, tile_c, ring,
     from jax.experimental.pallas import tpu as pltpu
 
     per_head = per_kv_head(tile_c, heads, kvh)
-    group = _page_group(rows, page, kvh, dh, dtype.itemsize, dv, per_head)
+    group = _page_group(rows, page, kvh, dh, dtype.itemsize, dv, per_head, parts)
     if per_head and window:
         # a tile's whole walk under a window: pages past it would be dead
         # columns of every step's products
@@ -546,6 +614,14 @@ def _walk_call(b, tiles, rows, dh, dtype, *, page, kvh, heads, tile_c, ring,
 
     def _q_index(bi, ci, *_):
         return (bi, ci, 0)
+
+    def word_scratch(width):
+        """``_word_runs``'s scratch for a slot ``width`` lanes wide: none
+        where the per-head cut reads the slot in place."""
+        runs = _lane_runs(width)
+        return [] if kvh == 1 or len(runs) == 1 else [
+            pltpu.VMEM((group * page * kvh * dtype.itemsize // 4, z - a),
+                       jnp.uint32) for a, z in runs]
 
     kernel = functools.partial(
         _paged_kernel, page=page, kvh=kvh, heads=heads, tile_c=tile_c,
@@ -565,8 +641,7 @@ def _walk_call(b, tiles, rows, dh, dtype, *, page, kvh, heads, tile_c, ring,
             pltpu.VMEM((2 * parts, group * page * kvh, dh // parts), dtype),
             pltpu.VMEM((2, group * page * kvh, dv), dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
-            *([pltpu.VMEM((group * page * kvh, z - a), jnp.float32)
-               for a, z in (*_lane_runs(dh // parts) * parts, *_lane_runs(dv))]
+            *(word_scratch(dh // parts) * parts + word_scratch(dv)
               if per_head else [pltpu.VMEM((rows, dv), jnp.float32),
                                 pltpu.VMEM((rows, 128), jnp.float32),
                                 pltpu.VMEM((rows, 128), jnp.float32)]),

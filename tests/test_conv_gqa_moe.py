@@ -531,9 +531,14 @@ def test_kernel_probe_holds_the_narrow_head_walk_to_gather(all_params, name):
 #: lanes' multiples through the Pallas kernels: recorded at PR 46's parent.
 #: The Mistral, Falcon-H1 and latent layouts' stand in
 #: ``tests/test_window_gqa_moe.py`` (``WINDOW_0_GOLDEN``, ``BYPASS_GOLDEN``)
+#: PR 60 RE-RECORDED all four: it changed the
+#: kernel's body on purpose (both products take the type the pools hold; a
+#: chunk tile reads a head's rows out of the slot's own words), so every
+#: program that holds ``_paged_kernel`` moved and nothing else did (both layouts call it on
+#: every attention layer)
 ROUTED_GOLDEN = {
-    "kexaone.decode": "5fe9cb69036d50e0", "kexaone.chunk": "ed24adc411641614",
-    "mimo.decode": "48314e1bdc26af76", "mimo.chunk": "1f0bfbbcc2f0b4ba"}
+    "kexaone.decode": "2ca563491bcd87c4", "kexaone.chunk": "4571e4f2f4d62228",
+    "mimo.decode": "3479e88feaf87a78", "mimo.chunk": "4ec43e19441a44be"}
 
 
 def _routed_text(case: str) -> str:

@@ -499,8 +499,13 @@ def _jaxpr_text(jaxpr) -> str:
 #: WITHOUT a compacting window cache, recorded at this PR's parent (eb57b91):
 #: a routed per-head model's decode step and chunk, a dense model's fused
 #: step, a model with conv layers. The new operand is absent at old shapes.
-OLD_PROGRAMS = {"routed.decode": "ea71334c76f1b40e", "routed.chunk": "76eb72292649c557",
-                "dense.fused": "e44cec964752cce5", "conv.decode": "e603e89a9e4c9787"}
+#: PR 60 RE-RECORDED all four: it changed the
+#: kernel's body on purpose (both products take the type the pools hold; a
+#: chunk tile reads a head's rows out of the slot's own words), so every
+#: program that holds ``_paged_kernel`` moved and nothing else did (each holds a per-head
+#: call at a head of 128 lanes)
+OLD_PROGRAMS = {"routed.decode": "6333e014f76ad6f8", "routed.chunk": "afa36b916c34c852",
+                "dense.fused": "a3e1460f31ac44a8", "conv.decode": "29c23afa71acc69d"}
 
 
 def _old_program(case: str) -> str:

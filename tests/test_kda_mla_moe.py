@@ -855,8 +855,13 @@ def test_judge_holds_the_float32_leaves():
 #: probe's shared lines trace the same program for the mixer that was there.
 #: The latent layouts' (``kanana2``, ``dots3``, ``xing4``) are
 #: ``test_fused_step.PARENT_GOLDEN``'s, which holds as it was
+#: PR 60 RE-RECORDED the two ``paged`` entries: it changed the
+#: kernel's body on purpose (both products take the type the pools hold; a
+#: chunk tile reads a head's rows out of the slot's own words), so every
+#: program that holds ``_paged_kernel`` moved and nothing else did (the layout's
+#: full-attention layers call it; the ``gather`` two stand as recorded)
 GDN_PARENT_GOLDEN = {
-    "decode.paged": "fdc83537dd8b9e6a", "chunk.paged": "c87c8876756f8cd2",
+    "decode.paged": "2711701e5e0aa4e2", "chunk.paged": "8ca449a2ed3963f3",
     "decode.gather": "05d2f806133264e8", "chunk.gather": "f45e7c39e0e07910"}
 
 

@@ -203,10 +203,10 @@ CHUNKS = {"mistral_l6": (32, 8, 128, 136, 128, 0, False),
 def test_a_chunks_per_head_product_compiles_inside_its_vmem_limit(v5e, cell):
     """Every per-head cell's chunk takes the K/V-head-at-a-time cut (the
     kernel's own predicate on the call's shapes), what its program holds in
-    VMEM — two slots of a group's pages, their float32 copies in 128-lane
-    runs, the accumulators, the query and output blocks twice — is inside
-    the limit the call asks for, and the chip's compiler takes its strided
-    reads of the scratch."""
+    VMEM — two slots of a group's pages (and no copy of them: every cell's
+    slot is one 128-lane run a part), the accumulators, the query and output
+    blocks twice — is inside the limit the call asks for, and the chip's
+    compiler takes its strided reads of the slots' 32-bit words."""
     import math
 
     from arkflow_tpu.ops import ragged_attention as ra

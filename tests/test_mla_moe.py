@@ -643,11 +643,14 @@ def test_judge_holds_the_float32_leaves_to_their_masters():
 #: walk since (they stood at fdea09887a70df43, e5fbbb5138b92dea and
 #: 1af74e9152f85a54, 2626a52b0b48a18d). A PR that changes the dense programs
 #: on purpose recomputes them with this test's code.
+#: PR 60 did, ``paged_wide`` alone (5cd67e6cd4990bd3, 108db1c0c9f52ed8 before
+#: it): both products of the per-head kernel take the type the pools hold and
+#: a chunk tile reads a head's rows out of the slot's own words.
 DENSE_HLO = {
     "gather": ("60ffd56343915631", "9992b028c6149b1d"),
     "paged": ("94a8d586436a9de5", "567d43b22ded3d2f"),
     "gather_wide": ("eaf5b975d560c31b", "502b93d7f55f0b71"),
-    "paged_wide": ("5cd67e6cd4990bd3", "108db1c0c9f52ed8"),
+    "paged_wide": ("0243bd5358771527", "5f8510cd32802d47"),
 }
 
 

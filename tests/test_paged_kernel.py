@@ -372,7 +372,7 @@ def _per_head_operands(name):
     ring of just the pages a window needs —, K in parts of 128 lanes where
     it is wider, as layer 1 of three (the others NaN), every column past a
     row's last padded query a page of NaN."""
-    h, kvh, dk, dv, page, c, offs, window, sink = PER_HEAD_CASES[name]
+    h, kvh, dk, dv, page, c, offs, window, sink = CASES[name]
     rng = np.random.RandomState(len(name))
     b = len(offs)
     tile_c = ragged_attention.query_tile(c, h)
@@ -455,15 +455,165 @@ def test_a_chunks_tile_multiplies_each_kv_head_by_its_own_rows(name):
         q, k, v, offs, window, sinks), atol=3e-5)
 
 
+# -- both products take what the pools hold -------------------------------------
+
+#: ``PER_HEAD_CASES``' columns, served types: bfloat16 queries over bfloat16
+#: pools. Decode tiles (the all-heads product), plain and under a window's
+#: ring with a sink and a key in two parts; ONE K/V head of 256 lanes (a
+#: head a layer of the pools: the slot is read whole), 256 lanes on two K/V
+#: heads (a slot of two 128-lane runs: copied a run at a time for the
+#: strided read), and a query head a K/V head (EvaByte's)
+SERVED_TYPE_CASES = {
+    "decode_4_a_head": (8, 2, 8, 8, 4, 1, (0, 37, 150), 0, False),
+    "decode_window_ring_sink_parts": (64, 8, 192, 128, 16, 1, (0, 130, 3000), 128, True),
+    "split_head_of_256_lanes": (8, 1, 256, 256, 4, 16, (5, 200), 0, False),
+    "two_heads_of_256_lanes": (8, 2, 256, 256, 4, 8, (3, 100), 0, False),
+    "a_query_head_a_kv_head": (8, 8, 128, 128, 4, 8, (3, 190), 0, False),
+}
+CASES = {**PER_HEAD_CASES, **SERVED_TYPE_CASES}   # what ``_per_head_operands`` builds
+SERVED_TYPE = [*SERVED_TYPE_CASES, "l6_4_a_head_8_kv_one_tile",
+               "kexaone_window_ring", "mimo_full_16_a_head_key_in_parts",
+               "mimo_window_ring_sink_parts"]
+
+#: two bfloat16 steps of the largest value: what an output rounded ONCE to
+#: bfloat16 from float32 sums keeps, whichever type the products took
+BF16_TOL = 2.0 ** -7
+
+
+def _bf16_round(a):
+    return np.asarray(jnp.asarray(a, jnp.bfloat16).astype(jnp.float32))
+
+
+@pytest.mark.parametrize("name", SERVED_TYPE)
+def test_bfloat16_operands_give_the_float32_reference(name):
+    """Queries and pools both bfloat16, as a server's: the products take them
+    as held and the probabilities rounded to bfloat16, the sums float32.
+    Against the plain float32 attention the distance stays inside
+    ``BF16_TOL`` — which the same call with float32 queries (float32
+    operands: the old products), rounded to the output's type, meets too."""
+    h, kvh, dk, dv, page, c, offs, window, sink = CASES[name]
+    assert ragged_attention.per_kv_head(
+        ragged_attention.query_tile(c, h), h, kvh) == (c > 1)
+    q, k, v, k_pool, v_pool, table, offs, sinks = _per_head_operands(name)
+    want = _plain_attention(q, k, v, offs, window, sinks)
+    tol = BF16_TOL * np.abs(want).max()
+    for dtype in (jnp.bfloat16, jnp.float32):
+        out = paged_flash_attention(
+            jnp.asarray(q, dtype), k_pool, v_pool, 1, table,
+            jnp.asarray(offs, jnp.int32), interpret=True, window=window,
+            sink=None if sinks is None else jnp.asarray(sinks))
+        assert out.dtype == dtype and out.shape == (*q.shape[:3], dv)
+        err = np.abs(_bf16_round(out) - want).max()
+        assert err <= tol, (dtype, err, tol)
+
+
+#: (batch, chunk, query heads, K/V heads, key width, window, sink): a decode
+#: tile, a chunk tile a K/V head at a time, one over a slot read whole (one
+#: K/V head), and one under a ring with a sink and a key in two parts
+MECHANISM = {"decode": (2, 1, 8, 2, 128, 0, False),
+             "chunk.per_head": (1, 8, 8, 2, 128, 0, False),
+             "chunk.one_kv_head": (1, 8, 8, 1, 256, 0, False),
+             "chunk.ring.sink.parts": (1, 8, 8, 4, 192, 32, True),
+             "decode.ring.sink.parts": (2, 1, 8, 4, 192, 32, True)}
+
+
+@pytest.mark.parametrize("pools", ["bfloat16", "float32"])
+@pytest.mark.parametrize("case", list(MECHANISM))
+def test_the_kernels_products_take_the_pools_type(case, pools):
+    """The mechanism itself, read off the traced kernel: under bfloat16
+    queries and pools NO ``dot_general`` of ``_paged_kernel`` has a float32
+    operand and every one sums in float32; float32 pools (the CPU tests'
+    plain cases) keep float32 operands. Nothing chooses but the operands'
+    types: no knob."""
+    b, c, h, kvh, dk, window, sink = MECHANISM[case]
+    dtype = jnp.dtype(pools)
+    parts = 1 if dk % 128 == 0 else -(-dk // 128)
+    q = jnp.zeros((b, c, h, dk), dtype)
+    k_pool = jnp.zeros((2 * parts, 9, 16, kvh, dk if parts == 1 else 128), dtype)
+    v_pool = jnp.zeros((2, 9, 16, kvh, 128), dtype)
+    traced = jax.make_jaxpr(lambda q, k, v, t, o, s: paged_flash_attention(
+        q, k, v, 1, t, o, window=window, sink=s))(
+        q, k_pool, v_pool, jnp.zeros((b, 4), jnp.int32), jnp.zeros((b,), jnp.int32),
+        jnp.zeros((h,), jnp.float32) if sink else None)
+    (call,) = _paged_calls(traced.jaxpr)
+    dots = list(_eqns(call.params["jaxpr"], "dot_general"))
+    # the scores' product a part of the key, and the values'
+    per_head = ragged_attention.per_kv_head(ragged_attention.query_tile(c, h), h, kvh)
+    assert len(dots) == (parts + 1) * (kvh if per_head else 1)
+    for eqn in dots:
+        assert [v.aval.dtype for v in eqn.invars] == [dtype, dtype], eqn
+        assert eqn.outvars[0].aval.dtype == jnp.float32
+        assert eqn.params["preferred_element_type"] == jnp.float32
+
+
+def _sums_rounded(q, k, v, off, step: int):
+    """What a kernel that kept its SUMS in bfloat16 would give: the online
+    softmax over groups of ``step`` keys, the value sum and the denominator
+    rounded to bfloat16 after every group (one row, every key attendable)."""
+    h, rep = q.shape[1], q.shape[1] // k.shape[1]
+    kk, vv = np.repeat(k, rep, axis=1), np.repeat(v, rep, axis=1)
+    s = np.einsum("chd,shd->hcs", q, kk[:off + 1]) * q.shape[-1] ** -0.5
+    o = np.zeros((h, q.shape[0], v.shape[-1]), np.float32)
+    m, l = np.full(s.shape[:2] + (1,), -1e30, np.float32), 0.0
+    for a in range(0, s.shape[-1], step):
+        m_new = np.maximum(m, s[..., a:a + step].max(-1, keepdims=True))
+        p, corr = np.exp(s[..., a:a + step] - m_new), np.exp(m - m_new)
+        o = _bf16_round(o * corr + np.einsum("hcs,shd->hcd", p, vv[a:a + step]))
+        l = _bf16_round(l * corr + p.sum(-1, keepdims=True))
+        m = m_new
+    return (o / l).transpose(1, 0, 2)
+
+
+@pytest.mark.parametrize("c", [1, 8], ids=["decode", "chunk"])
+def test_a_row_of_many_small_probabilities_keeps_its_sum(c):
+    """What rounding the probabilities could hurt: a row whose mass sits in
+    4,096 terms of about 1 / 4,096 each (queries near zero: scores within a
+    few hundredths of each other, none equal). Each term is rounded to eight
+    bits on its own and the errors do not line up: the value sum stays
+    inside ``BF16_TOL`` of the float32 attention's — where the same softmax
+    with its SUMS kept in bfloat16 (``_sums_rounded``) does not, so the
+    tolerance can tell the two."""
+    rng = np.random.RandomState(60)
+    h, kvh, dh, page, keys = 8, 2, 128, 16, 4096
+    off = keys - c
+    k, v = (_bf16_round(rng.randn(keys, kvh, dh) * 0.5) for _ in range(2))
+    q = _bf16_round(rng.randn(1, c, h, dh) * 0.02)
+    n_pages = keys // page
+    table = 1 + rng.permutation(n_pages)
+    pool = lambda a: jnp.zeros((2, 1 + n_pages, page, kvh, dh), jnp.bfloat16).at[  # noqa: E731
+        1, table].set(jnp.asarray(a.reshape(n_pages, page, kvh, dh), jnp.bfloat16))
+    out = paged_flash_attention(
+        jnp.asarray(q, jnp.bfloat16), pool(k), pool(v), 1,
+        jnp.asarray(table[None], jnp.int32), jnp.asarray([off], jnp.int32),
+        interpret=True)
+    want = _plain_attention(q, k[None], v[None], [off], 0, None)
+    p_max = np.exp(np.einsum("chd,shd->hcs", q[0], np.repeat(k, h // kvh, 1))
+                   * dh ** -0.5)
+    assert (p_max / p_max.sum(-1, keepdims=True)).max() < 2.0 / keys
+    tol = BF16_TOL * np.abs(want).max()
+    assert np.abs(_bf16_round(out) - want).max() <= tol
+    group = _page_group(ragged_attention.query_tile(c, h) * h, page, kvh, dh, 2,
+                        per_head=c > 1) * page
+    assert keys // group >= 8                     # many steps of the walk
+    rounded = _sums_rounded(q[0, -1:], k, v, keys - 1, group)
+    assert np.abs(rounded - want[0, -1:]).max() > tol
+
+
 #: sha256 (first 16 hex, source positions stripped) of the jaxprs of calls
 #: the predicate keeps on the all-heads product, recorded at PR 43's parent
 #: (f4299c3): a decode tile, a decode tile under a window with a sink, and
 #: chunk tiles whose rows a K/V head are no multiple of a sublane tile
+#: PR 60 RE-RECORDED all four: it changed the
+#: kernel's body on purpose (both products take the type the pools hold; a
+#: chunk tile reads a head's rows out of the slot's own words), so every
+#: program that holds ``_paged_kernel`` moved and nothing else did (recorded before it: 4cf27ca18560d7e8,
+#: 9c5d04ac365f833f, 492d84aa8529d818, c9a9dc405233f779): what the test holds
+#: since is that these tiles stay on the all-heads product
 ALL_HEADS_GOLDEN = {
-    "decode": ((2, 1, 8, 2, 0, False), "4cf27ca18560d7e8"),
-    "decode.window.sink": ((2, 1, 8, 4, 32, True), "9c5d04ac365f833f"),
-    "odd_rows": ((1, 3, 4, 2, 0, False), "492d84aa8529d818"),
-    "odd_rows.five_a_head": ((1, 7, 10, 2, 0, False), "c9a9dc405233f779")}
+    "decode": ((2, 1, 8, 2, 0, False), "ea922909c22c8d02"),
+    "decode.window.sink": ((2, 1, 8, 4, 32, True), "4ba87dd276930d01"),
+    "odd_rows": ((1, 3, 4, 2, 0, False), "cd476856d8a41b8a"),
+    "odd_rows.five_a_head": ((1, 7, 10, 2, 0, False), "a0f623dd5bf43b43")}
 
 
 @pytest.mark.parametrize("case", sorted(ALL_HEADS_GOLDEN))
@@ -486,15 +636,22 @@ def test_the_predicate_keeps_decode_and_odd_tiles_on_the_all_heads_product(case)
     assert hashlib.sha256(text.encode()).hexdigest()[:16] == want
 
 
-def _paged_calls(jaxpr):
+def _eqns(jaxpr, name):
+    """Every equation of primitive ``name`` under a jaxpr, those inside
+    its calls and loops too."""
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pallas_call":
+        if eqn.primitive.name == name:
             yield eqn
         for v in eqn.params.values():
-            inner = getattr(v, "jaxpr", v)
-            inner = getattr(inner, "jaxpr", inner)
-            if hasattr(inner, "eqns"):
-                yield from _paged_calls(inner)
+            for inner in (v if isinstance(v, (tuple, list)) else (v,)):
+                inner = getattr(inner, "jaxpr", inner)
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    yield from _eqns(inner, name)
+
+
+def _paged_calls(jaxpr):
+    return _eqns(jaxpr, "pallas_call")
 
 
 def _paged_call(dh, row_major=False, **kw):
@@ -1265,16 +1422,20 @@ def test_tpu_generate_processor_plumbs_kernel_and_depth():
     assert rep["decode_kernel"] == "paged" and rep["dispatch_depth"] == 2
 
 
-def test_profile_paged_attention_rehearses_on_the_cpu_and_times_nothing_there():
+@pytest.mark.parametrize("cell,decodes,chunks", [
+    ("mistral_tp4_local", 1, 2), ("evabyte_l8", 3, 3)])
+def test_profile_paged_attention_rehearses_on_the_cpu_and_times_nothing_there(
+        cell, decodes, chunks):
     """``tools/profile_paged_attention.py``: without a TPU it refuses to
     time; ``--interpret`` rehearses a cell's points at tiny sizes — the
-    decode call all heads at once, the chunk calls a K/V head at a time,
-    each against the gather reference — and writes no time."""
+    decode call all heads at once, the chunk calls a K/V head at a time
+    (EvaByte's 32 K/V heads, a query head each, too), each against the
+    gather reference — and writes no time."""
     from arkflow_tpu.utils.cleanenv import cpu_child_env
 
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     tool = [sys.executable, os.path.join(repo, "tools", "profile_paged_attention.py"),
-            "--cell", "mistral_tp4_local"]
+            "--cell", cell]
     env = cpu_child_env(n_devices=1)
     res = subprocess.run(tool, env=env, capture_output=True, timeout=300, cwd=repo)
     assert res.returncode == 1 and b"found no TPU" in res.stderr and not res.stdout
@@ -1283,6 +1444,6 @@ def test_profile_paged_attention_rehearses_on_the_cpu_and_times_nothing_there():
     assert res.returncode == 0, res.stderr.decode(errors="replace")[-2000:]
     lines = [json.loads(l) for l in res.stdout.decode().strip().splitlines()]
     assert [(l["kind"], l["per_kv_head"]) for l in lines] == [
-        ("decode", False), ("chunk", True), ("chunk", True)]
+        *[("decode", False)] * decodes, *[("chunk", True)] * chunks]
     assert all(l["rehearsal"] and "us_per_call" not in l for l in lines)
     assert all(l["max_abs_err"] < 2e-3 for l in lines)

@@ -348,6 +348,11 @@ def _text_hash(text: str) -> str:
 #: 128 lanes has row-major pools and the narrow-head walk since (until then
 #: these stood for the GRID's walk at a head of 8, 48f260fdc014f61e ..
 #: 31135aa3fc6bb0df, which went with the grid)
+#: PR 60 RE-RECORDED the four ``walk`` entries: it changed the
+#: kernel's body on purpose (both products take the type the pools hold; a
+#: chunk tile reads a head's rows out of the slot's own words), so every
+#: program that holds ``_paged_kernel`` moved and nothing else did (the gather
+#: forms and the narrow head's ``paged`` stand as recorded)
 WINDOW_0_GOLDEN = {
     "dense.decode.gather": "0c702f3da9e0d32f", "dense.chunk.gather": "9c8368cf57b10193",
     "dense.decode.paged": "54c9aa64d0a27507", "dense.chunk.paged": "2d700266541cda06",
@@ -357,8 +362,8 @@ WINDOW_0_GOLDEN = {
     "dense.chunk.gather_wide": "a6ada1e181277574",
     "hybrid.decode.gather_wide": "652c84eb6ce512e5",
     "hybrid.chunk.gather_wide": "d7b395f58ea511f1",
-    "dense.decode.walk": "8fb05ab0dea5dbe0", "dense.chunk.walk": "b81f1547cdd2021a",
-    "hybrid.decode.walk": "41b44ac90e51a17a", "hybrid.chunk.walk": "e6532038eb591939"}
+    "dense.decode.walk": "a0adb19001bf947b", "dense.chunk.walk": "5010a46cdf3eff63",
+    "hybrid.decode.walk": "c471586378e96771", "hybrid.chunk.walk": "8669ac1ba2980a61"}
 
 
 def _window_0_text(case: str) -> str:
@@ -475,9 +480,14 @@ def test_latent_programs_do_not_move_with_the_per_head_kernel(case):
 #: one (its copies are its own) — trace to what they traced to at that PR's
 #: parent (aa369a0), where these were recorded. A decode tile and a chunk
 #: tile each, straight through ``paged_flash_attention``.
+#: PR 60 RE-RECORDED the two ``per_head`` entries: it changed the
+#: kernel's body on purpose (both products take the type the pools hold; a
+#: chunk tile reads a head's rows out of the slot's own words), so every
+#: program that holds ``_paged_kernel`` moved and nothing else did: the
+#: ``narrow`` two stand as recorded at aa369a0, which is the bypass PR 60 owes
 WALK_BYPASS_GOLDEN = {
     "narrow.decode": "2e05c2d8f5550240", "narrow.chunk": "b5498163e2e0b03b",
-    "per_head.decode": "4cf27ca18560d7e8", "per_head.chunk": "d86b64cd5e17f3d2"}
+    "per_head.decode": "ea922909c22c8d02", "per_head.chunk": "5c5ee50d4b1d3929"}
 
 
 @pytest.mark.parametrize("case", sorted(WALK_BYPASS_GOLDEN))

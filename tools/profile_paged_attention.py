@@ -96,6 +96,14 @@ CELLS = {
     "qwen3next_l8_joined": dict(h=16, kvh=2, dk=256, dv=256, lanes=128,
                                 cols=576, chunk=512, offsets=(2048,),
                                 decode_ctx=(3072,)),
+    # 32 K/V heads, a query head each, and a cache whose rows are not
+    # positions: a lane's table holds its closed windows' summary rows (128 a
+    # window), then its open window's (``attention_class: eva``). A chunk is
+    # the last 512 tokens of a 2,048-row window behind 1 / 8 / 15 closed
+    # windows; a decode lane sits in the middle of its window behind as many
+    "evabyte_l8": dict(h=32, kvh=32, dk=128, dv=128, lanes=20, cols=248,
+                       chunk=512, offsets=(1664, 2560, 3456),
+                       decode_ctx=(1152, 2048, 2944)),
     # the latent kernel (``lat``: one shared row a token of that width beside
     # a rope key of ``rope``; ``topk``: under an indexer's choice of so many)
     "kanana2_l6": dict(h=32, lat=512, rope=64, lanes=16, cols=136, chunk=128,
