@@ -139,8 +139,13 @@ def ragged_flash_attention(q, k, v, lengths, *, causal: bool = False,
 # up to its last query's page, from 0 or from the window's first page —,
 # loops over them in groups of ``G`` and copies each group's K and V pages
 # out of the pool itself into one of two VMEM slots, group g + 1 started
-# before group g is waited for. A table column past a row's context costs
-# nothing: no grid step, no copy, no skipped branch. (A grid over every
+# before group g is waited for (``_page_walk``, the one walk of this file's
+# three kernels) — an aligned stretch of ``PAGE_RUN`` table entries that name
+# neighbours in the pool as ONE copy a pool (``_by_runs``: the server's
+# allocator hands a slot its pages in such blocks), and a program's last
+# step starting the first group of the program after it. A table column
+# past a row's context costs nothing: no grid step, no copy, no skipped
+# branch. (A grid over every
 # column cost 0.14-0.24 us a column a lane on a v5e, 110 ns where the math
 # was skipped, with a third of the columns live: PERF.md, PR 41.)
 #
@@ -157,10 +162,11 @@ def ragged_flash_attention(q, k, v, lengths, *, causal: bool = False,
 # page a step through a BlockSpec, read them: 3-6 x a call, PERF.md PR 41.)
 #
 # Layout (what the TPU tiling accepts): a copy takes ALL kv heads of a
-# page. The pool rides whole and is viewed as [layers*num_pages,
-# page*kv_heads, dh] — a free bitcast of its HBM layout at a head of 128
-# lanes, rows ordered (slot, kv head), the table offset to the layer's
-# pages so the layer loop never slices it. (The lane-dense view [..,
+# page. The pool rides whole and is viewed as flat rows, [layers*num_pages
+# *page*kv_heads, dh] — a free bitcast of its HBM layout at a head of 128
+# lanes, rows ordered (page, slot, kv head), so that neighbouring pages are
+# one stretch of rows; the table is offset to the layer's pages so the
+# layer loop never slices the pool. (The lane-dense view [..,
 # page, kv_heads*dh] is NOT free: XLA keeps the pool tiled over (kv head,
 # dh) and re-lays all of it for that view; and a bfloat16 pool packs two
 # kv heads' rows into one word, so no copy can take one head's rows. A
@@ -232,7 +238,18 @@ def _walk_budget() -> int:
 
 #: pages a group may take whatever the budget allows: each page is two
 #: copies (K and V) issued one by one, and a decode step gains nothing past
-#: sixteen (l6, 16 lanes: 236 us a call at 4, 178 at 8, 155 at 16, 161 at 32)
+#: sixteen (l6, 16 lanes: 236 us a call at 4, 178 at 8, 155 at 16, 161 at 32).
+#: Measured again where a stretch of ``PAGE_RUN`` pages is one copy and a
+#: program's first group is handed on (PERF.md, PR 61; us a decode call a
+#: layer over a table laid in runs, at 8 / 16 / 32 / 64): mistral_l6 145.7,
+#: 129.1, 122.6, 126.3; mistral_tp4_local 80.6, 60.8, 52.5, 50.8;
+#: falconh1_l4 421.4, 310.2, 265.7, 282.3; kexaone_l5_full 633.8, 517.0,
+#: 521.8, 542.5; mimo_l7_full (at 16 / 32 / 64) 1,635.6, 1,502.7, 1,503.8;
+#: evabyte_l8 (32 K/V heads: 32 pages are all its budget fits) at 1,152 /
+#: 2,048 / 2,944 rows 536.1, 542.6 / 963.0, 988.4 / 1,315.1, 1,315.6 — 32
+#: would gain 5-14 % on five points (a step's fixed costs, no longer its
+#: copies' issue, are what a larger group saves) and cost evabyte_l8 0-2.6 %.
+#: NOT taken in PR 61: nothing end to end was measured on it (ROADMAP S11)
 _PAGED_GROUP_MAX = 16
 
 #: and where the pools hold ONE K/V head wider than a 128-lane run (a head a
@@ -240,7 +257,10 @@ _PAGED_GROUP_MAX = 16
 #: ``page`` columns under the call's few rows — measured there and nowhere
 #: else: 128 lanes at 3,072 keys of 256, 8 query heads a call, 3,609 us at
 #: 16, 3,218 at 32, 2,965 at 64 (PERF.md, PR 49; 2,947 at 64 under bfloat16
-#: products, the parent beside it 2,961: the copies bind it, PR 60)
+#: products, the parent beside it 2,961: the copies bind it, PR 60 — and they
+#: did: a run of 8 pages a copy 1,544, a program's first group handed on
+#: 1,407, at 64, the parent beside it 2,908; under runs 2,237.6 at 16,
+#: 1,652.2 at 32, 1,403.1 at 64: PR 61)
 _PAGED_ONE_HEAD_GROUP_MAX = 64
 
 #: and a chunk tile's, a K/V head at a time, where a page adds only ``page``
@@ -329,11 +349,20 @@ def _page_group(rows: int, page: int, kvh: int, dh: int, itemsize: int,
         copied = kvh > 1 and max(dh // parts, dv or dh) > 128
         a_page = (cols * kv * (4 + 2 * copied) * itemsize
                   + page * kv * (8 + 2 * itemsize) + page * (rows // kvh) * 16)
-        return max(1, min(_PAGED_CHUNK_GROUP_MAX, _walk_budget() // a_page))
+        return _whole_runs(min(_PAGED_CHUNK_GROUP_MAX, _walk_budget() // a_page))
     a_page = cols * (kv * 4 * itemsize + rows * 16)
     most = (_PAGED_ONE_HEAD_GROUP_MAX if kvh == 1 and dh > 128
             else _PAGED_GROUP_MAX)
-    return max(1, min(most, _walk_budget() // a_page))
+    return _whole_runs(min(most, _walk_budget() // a_page))
+
+
+def _whole_runs(fit: int) -> int:
+    """A group of at most ``fit`` pages that ``PAGE_RUN`` divides wherever it
+    holds a stretch (the ceilings do; one the budget bounds is rounded
+    down, as ``_latent_group`` rounds to whole lane tiles), so that its walk
+    takes runs; at least a page."""
+    run = PAGE_RUN or 1
+    return max(1, fit if fit < run else fit // run * run)
 
 
 def _lane_runs(width: int) -> list[tuple[int, int]]:
@@ -394,71 +423,94 @@ def _paged_kernel(off_ref, table_ref, q_ref, *rest, page: int,
         # the accumulators ride the walk's loop, not VMEM scratch; ``words``:
         # where a slot is wider than one 128-lane run, its rows once more in
         # runs a strided read takes (``_word_runs``)
-        k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, *words = rest
+        k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, slot_ref, *words = rest
     else:
-        k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, o_acc, m_acc, l_acc = rest
+        (k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, slot_ref,
+         o_acc, m_acc, l_acc) = rest
     bi = pl.program_id(0)
     ci = pl.program_id(1)
     rows, d = q_ref.shape[1], q_ref.shape[2]
     cols = page * kvh
     width = group * cols
 
-    # folded row r is (chunk position ci*tile_c + r // heads, q head
-    # r % heads) at absolute position off + that — per head: (kv head
-    # r // (rows / kvh), position, q head of its group) —; the tile's last
-    # attendable key is its last query's position, so the walk ends at that
-    # page. The table's width bounds it too: queries padded past a chunk may
-    # sit past the last column (a ring has no last column: it wraps)
-    first = off_ref[bi] + ci * tile_c
-    hi = (first + (tile_c - 1)) // page + 1
-    if window:  # the walk starts at the window's first page, not at 0
-        lo = _window_start(first, window, page)
-    else:
-        lo, hi = 0, jnp.minimum(hi, ring)
-    steps = (hi - lo + (group - 1)) // group
+    def walked(bi, ci):
+        """Program (``bi``, ``ci``)'s first query and the logical pages lo
+        .. hi - 1 it walks. Folded row r is (chunk position ci*tile_c +
+        r // heads, q head r % heads) at absolute position off + that — per
+        head: (kv head r // (rows / kvh), position, q head of its group) —;
+        the tile's last attendable key is its last query's position, so the
+        walk ends at that page. The table's width bounds it too: queries
+        padded past a chunk may sit past the last column (a ring has no last
+        column: it wraps)."""
+        first = off_ref[bi] + ci * tile_c
+        hi = (first + (tile_c - 1)) // page + 1
+        if window:  # the walk starts at the window's first page, not at 0
+            return first, _window_start(first, window, page), hi
+        return first, 0, jnp.minimum(hi, ring)
 
-    def copies(slot, j, src):
-        """The K and the V copy of page ``src`` of the pool into ``slot``, as
-        the ``j``-th page of a group (a key held in parts: a copy a part,
-        the parts ``part_stride`` pages apart, into slots 2p + slot)."""
-        dst = pl.ds(pl.multiple_of(j * cols, cols), cols)
-        return (*(pltpu.make_async_copy(k_hbm.at[src + p * part_stride],
+    first, lo, hi = walked(bi, ci)
+
+    def copies(slot, j, src, n=1):
+        """The K and the V copy of ``n`` pages from ``src`` on — side by
+        side in the pools, which ride as flat rows, ``cols`` a page — into
+        ``slot``, the ``j``-th page of a group on: ONE copy a pool however
+        many pages (a key held in parts: a copy a part, the parts
+        ``part_stride`` pages apart, into slots 2p + slot)."""
+        def at(page0):  # the rows of ``n`` pages from ``page0`` on
+            return pl.ds(pl.multiple_of(page0 * cols, cols), n * cols)
+
+        dst = at(j)
+        return (*(pltpu.make_async_copy(k_hbm.at[at(src + p * part_stride)],
                                         k_buf.at[2 * p + slot, dst],
                                         sems.at[0, slot])
                   for p in range(1, parts)),
-                pltpu.make_async_copy(k_hbm.at[src], k_buf.at[slot, dst],
+                pltpu.make_async_copy(k_hbm.at[at(src)], k_buf.at[slot, dst],
                                       sems.at[0, slot]),
-                pltpu.make_async_copy(v_hbm.at[src], v_buf.at[slot, dst],
+                pltpu.make_async_copy(v_hbm.at[at(src)], v_buf.at[slot, dst],
                                       sems.at[1, slot]))
 
-    def live(g):
-        """Pages of the g-th group up to ``hi``: all but the walk's last are
-        whole. The others are not copied."""
-        return jnp.minimum(hi - (lo + g * group), group)
+    # a ring's tile starts at an unaligned page: no runs there (nor in a
+    # pool that holds less than one)
+    run = PAGE_RUN if _takes_runs(
+        window, group, min(k_hbm.shape[0], v_hbm.shape[0]) // cols) else 0
 
-    def start(g, slot):
-        def page_at(j, _):
-            i = lo + g * group + j
-            for dma in copies(slot, j, table_ref[bi, i % ring if window else i]):
-                dma.start()
+    def walk(bi, lo, hi, **kw):
+        return _page_walk(
+            copies, lambda i: table_ref[bi, i % ring if window else i], lo, hi,
+            group, run, **kw)
 
-        jax.lax.fori_loop(0, live(g), page_at, None)
+    opens = jnp.logical_and(bi == 0, ci == 0)
 
-    def wait(g, slot):
-        def page_at(j, _):  # a wait takes its size from the copy, not its source
-            for dma in copies(slot, j, 0):
-                dma.wait()
-
-        jax.lax.fori_loop(0, live(g), page_at, None)
-
-    @pl.when(jnp.logical_and(bi == 0, ci == 0))
+    @pl.when(opens)
     def _finite():
         # a group's dead pages keep what the slot held: rows of the pool or,
         # before the call's first copy, whatever VMEM held. Their columns
         # are masked, but a probability of 0 times a NaN is a NaN
         v_buf[...] = jnp.zeros_like(v_buf)
+        slot_ref[0] = 0
 
-    start(0, 0)
+    # the programs run one after another on one core, so a program's LAST
+    # step starts the first group of the program after it, into the slot it
+    # is not reading (whose number rides in SMEM): a walk's first group is
+    # the one that nothing of its own program hides (PERF.md, PR 61: a
+    # decode call of 16 lanes at 13 / 29 / 61 / 125 pages a lane 62.5 / 84.8
+    # / 130.7 / 218.2 us each program starting its own, 47.9 / 69.5 / 118.2 /
+    # 204.0 handed on). The call's first program starts its own
+    ci_next = jnp.where(ci + 1 < pl.num_programs(1), ci + 1, 0)
+    bi_next = bi + (ci_next == 0)
+    more = bi_next < pl.num_programs(0)
+    bi_next = jnp.where(more, bi_next, bi)
+    _, start_next, _ = walk(bi_next, *walked(bi_next, ci_next)[1:])
+
+    def hand_on(slot):
+        @pl.when(more)
+        def _next():
+            slot_ref[0] = slot
+            start_next(0, slot)
+
+    steps, start, arrive = walk(bi, lo, hi, first_slot=slot_ref[0], hand_on=hand_on)
+    pl.when(opens)(lambda: start(0, 0))
+
     scale = scale or 1.0 / math.sqrt(d)
     dims = (((1,), (1,)), ((), ()))
     # both products take their operands in the type the queries and the
@@ -466,18 +518,6 @@ def _paged_kernel(off_ref, table_ref, q_ref, *rest, page: int,
     # is cast —, the sums float32; the probabilities are rounded to it for
     # the value product, as the narrow and the latent kernel's
     op = jnp.promote_types(q_ref.dtype, k_buf.dtype)
-
-    def arrive(g):
-        """Start the copies of the group after the g-th, wait for the
-        g-th's: its slot."""
-        slot = g % 2
-
-        @pl.when(g + 1 < steps)
-        def _ahead():
-            start(g + 1, 1 - slot)
-
-        wait(g, slot)
-        return slot
 
     def kept(g, bound, ahead):
         """The g-th group's mask from the bounds worked out once: ``base +
@@ -641,6 +681,7 @@ def _walk_call(b, tiles, rows, dh, dtype, *, page, kvh, heads, tile_c, ring,
             pltpu.VMEM((2 * parts, group * page * kvh, dh // parts), dtype),
             pltpu.VMEM((2, group * page * kvh, dv), dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SMEM((1,), jnp.int32),
             *(word_scratch(dh // parts) * parts + word_scratch(dv)
               if per_head else [pltpu.VMEM((rows, dv), jnp.float32),
                                 pltpu.VMEM((rows, 128), jnp.float32),
@@ -899,7 +940,8 @@ def paged_flash_attention(q, k_pages, v_pages, layer, page_table, off, *,
     if h % kvh:
         raise ValueError(f"q heads {h} must be a multiple of kv heads {kvh}")
     # the layer rides in the page index: the pools are viewed as one run of
-    # layers * num_pages pages and the table names pages of that run
+    # layers * num_pages pages (flat rows, page * kv_heads a page) and the
+    # table names pages of that run
     table = (jnp.asarray(page_table, jnp.int32)
              + jnp.asarray(layer, jnp.int32) * n_pages)
     tile_c = query_tile(c, h)
@@ -939,8 +981,8 @@ def paged_flash_attention(q, k_pages, v_pages, layer, page_table, off, *,
         **params,
         **({"name": PAGED_WINDOW_NAME} if window else {}),
     )(jnp.asarray(off, jnp.int32), table, q.reshape(b, c_pad * h, dh), *sinks,
-      k_pages.reshape(layers * n_pages, page * kvh, dh // parts),
-      v_pages.reshape(v_pages.shape[0] * n_pages, page * kvh, dv))
+      k_pages.reshape(layers * n_pages * page * kvh, dh // parts),
+      v_pages.reshape(v_pages.shape[0] * n_pages * page * kvh, dv))
     if per_head:
         out = out.reshape(b, tiles, kvh, tile_c, group, dv).transpose(
             0, 1, 3, 2, 4, 5)
@@ -1026,14 +1068,19 @@ def _latent_group(tile_c: int, heads: int, page: int, lat: int, held: int,
     return group if group < lanes else group // lanes * lanes
 
 
-def _page_walk(copies, page_of, lo, hi, group: int, run: int = 0):
+def _page_walk(copies, page_of, lo, hi, group: int, run: int = 0,
+               first_slot=None, hand_on=None):
     """The double-buffered walk of the logical pages ``lo`` .. ``hi`` - 1 in
     groups of ``group``, ``_paged_kernel``'s shape: ``copies(slot, j, src)``
     are the copies of physical page ``src`` into ``slot`` as a group's
     ``j``-th page (of ``run`` pages from ``src`` on: ``_by_runs``),
     ``page_of(i)`` logical page ``i``'s. Returns (steps, ``start``,
     ``arrive``): ``start(0, 0)`` once, then ``arrive(g)`` a step — starts the
-    group after the g-th, waits for the g-th, returns its slot. Nothing past ``hi``."""
+    group after the g-th, waits for the g-th, returns its slot. Nothing past
+    ``hi``. ``first_slot``: the slot group 0 was started into, where that is
+    not 0, and ``hand_on(slot)``: called in the walk's last step, before its
+    wait, with the slot that step leaves free (``_paged_kernel``: a program's
+    first group is started by the program before it)."""
     steps = (hi - lo + (group - 1)) // group
 
     def live(g):
@@ -1054,12 +1101,14 @@ def _page_walk(copies, page_of, lo, hi, group: int, run: int = 0):
         jax.lax.fori_loop(0, live(g), page_at, None)
 
     def arrive(g):
-        slot = g % 2
+        slot = g % 2 if first_slot is None else (g + first_slot) % 2
 
         @pl.when(g + 1 < steps)
         def _ahead():
             start(g + 1, 1 - slot)
 
+        if hand_on is not None:
+            pl.when(g + 1 == steps)(lambda: hand_on(1 - slot))
         wait(g, slot)
         return slot
 
@@ -1081,8 +1130,32 @@ def _page_walk(copies, page_of, lo, hi, group: int, run: int = 0):
 #: copies at 4 pages) the test of a stretch costs what both branches cost
 #: (read: compiled to predicated straight-line code; with that branch a
 #: LOOP 4 pages gave 784, 8 gave 691 and a permuted table 1,464 / 1,336).
-#: 16 gave 673 where 8 gave 676: the bytes lead from there on
+#: 16 gave 673 where 8 gave 676: the bytes lead from there on.
+#: The per-head walk takes the same 8 (PERF.md, PR 61; us a DECODE call a
+#: layer, the parent's page-a-copy walk / this walk without runs / with,
+#: over a table laid in runs, then with over a permuted one; before a
+#: program handed its successor its first group): mistral_l6 (32 KB a copy)
+#: 142.7 / 142.2 / 141.1, 146.4; mistral_tp4_local (8 KB) 87.2 / 85.4 /
+#: 67.8, 79.7; falconh1_l4 (16 KB) 413.1 / 418.2 / 382.6, 418.8;
+#: kexaone_l5_full (32 KB) 588.6 / 595.2 / 557.2, 577.7; mimo_l7_full (16 KB
+#: x 3: keys in two parts) 2,348.8 / 2,270.7 / 1,616.6, 2,133.8;
+#: qwen3next_l8 (8 KB, one K/V head a call) 2,912.1 / 2,912.5 / 1,543.7,
+#: 2,464.9; evabyte_l8 (128 KB, at its bytes) 1,355.4 / 1,353.0 / 1,352.1,
+#: 1,361.2: the gain goes by how far a copy's ~0.045 us of issue exceeds its
+#: bytes' time (32 KB at 819 GB/s: 0.04 us, the balance point). A stretch
+#: that is NO run starts its 16-24 copies in line there too: as a loop the
+#: permuted table read 142.3 / 90.7 / 441.9 / 601.6 / 2,429.4 / 2,984.2 (l6
+#: 3 % better, every other cell 4-21 % worse), a table in runs the same
 PAGE_RUN = 8
+
+
+def _takes_runs(window: int, group: int, pages: int) -> bool:
+    """Whether a walk in groups of ``group`` over a pool of ``pages`` moves
+    stretches of ``PAGE_RUN`` neighbours a copy, decided where the call is
+    traced: not a ring under a ``window`` (its tile starts at an unaligned
+    page), whole stretches a group, a pool that holds one."""
+    return bool(PAGE_RUN and not window and group % PAGE_RUN == 0
+                and pages >= PAGE_RUN)
 
 
 def _by_runs(copies, page_of, first, live, run: int):
@@ -1185,7 +1258,7 @@ def _latent_kernel(layer_ref, off_ref, table_ref, ql_ref, qr_ref, *rest,
 
     # a ring's tile is one group of a window pool's pages: no runs there
     # (nor in a pool that holds less than one)
-    runs = not window and group % PAGE_RUN == 0 and c_hbm.shape[1] >= PAGE_RUN
+    runs = _takes_runs(window, group, c_hbm.shape[1])
     steps, start, arrive = _page_walk(
         copies, lambda i: table_ref[bi, i % ring if window else i], lo, hi, group,
         PAGE_RUN if runs else 0)

@@ -239,8 +239,9 @@ _WALK_COUNTERS = (
      "kept page-table columns of the rows the attention kernel was called "
      "with, a layer"),
     ("arkflow_gen_attn_pages_in_runs_total",
-     "kept-pool pages the latent kernel's rows walked in whole stretches of "
-     "neighbours, a stretch a copy, a layer"),
+     "kept-pool pages the attention kernel's rows walked in whole stretches "
+     "of neighbours, a stretch a copy, a layer (a kernel that takes runs: "
+     "the latent one, the per-head one over a layer that keeps every key)"),
 )
 
 
@@ -756,16 +757,23 @@ class GenerationServer:
         # since PR 44 a latent model's mla_paged_attention): pages walked
         # beside the table's columns, a layer, from lengths on the host.
         # Their ratio is the live share of the table (a chunk's earlier
-        # query tiles stop sooner than its last, which is counted). The
-        # latent walk moves a whole aligned stretch of PAGE_RUN pages that
-        # sit side by side in the pool as ONE copy (since PR 54): the walked
-        # pages of such stretches are counted too, by the kernel's predicate
-        # over the table rows the step carries (a per-head kernel takes no
-        # runs and counts none). Names and texts: ``_WALK_COUNTERS``
+        # query tiles stop sooner than its last, which is counted). A
+        # walk moves a whole aligned stretch of PAGE_RUN pages that sit
+        # side by side in the pool as ONE copy (the latent kernel's since
+        # PR 54, the per-head kernel's since PR 61): the walked pages of
+        # such stretches are counted too, by the kernel's predicate over
+        # the table rows the step carries, on a server whose kernel takes
+        # runs — a latent model's, a per-head model's with a layer that
+        # keeps every key at heads of 128 lanes' multiples (a window's ring
+        # and the narrow-head walk take none and count none). Names and
+        # texts: ``_WALK_COUNTERS``
         self.m_attn_walk = {} if self.decode_kernel != "paged" else {
             kind: tuple(reg.counter(metric, text, {"model": name, "kind": kind})
                         for metric, text in _WALK_COUNTERS)
             for kind in ("decode", "chunk")}
+        self._walk_in_runs = cfg.latent or any(
+            not (sp.window or sp.row_major)
+            for sp in map(cfg.gqa, dict.fromkeys(cfg.attn_kinds)))
         # the (row, query tile) programs of the per-head kernel's calls, a
         # layer, by the product each makes: a K/V head at a time over that
         # head's own query rows, or all heads at once under a mask — the
@@ -2556,8 +2564,8 @@ class GenerationServer:
         0 walks its one scratch page). ``queries``: the step's real queries
         (active lanes, a chunk's unpadded positions), each a row of every
         layer with a sink. ``width``: the positions a row of the step.
-        ``table``: the rows of the page table the step carries, of which a
-        latent walk's pages in runs are counted."""
+        ``table``: the rows of the page table the step carries, of which
+        the pages a walk takes in runs are counted."""
         if self.m_attn_tiles:
             for product, tiles in (self._tiles_of.get(width)
                                    or self._attn_tiles(width)).items():
@@ -2572,7 +2580,7 @@ class GenerationServer:
             pages = np.minimum(last // self.page_size + 1, cols)
             walked.inc(int(pages.sum()))
             columns.inc(cols * len(last))
-            if self.cfg.latent:
+            if self._walk_in_runs:
                 in_runs.inc(pages_in_runs(table[:, :cols], pages))
 
     def _attn_tiles(self, width: int) -> dict[str, int]:

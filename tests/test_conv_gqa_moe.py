@@ -536,9 +536,14 @@ def test_kernel_probe_holds_the_narrow_head_walk_to_gather(all_params, name):
 #: chunk tile reads a head's rows out of the slot's own words), so every
 #: program that holds ``_paged_kernel`` moved and nothing else did (both layouts call it on
 #: every attention layer)
+#: PR 61 RE-RECORDED all four (2ca563491bcd87c4, 4571e4f2f4d62228 (kexaone), 3479e88feaf87a78, 4ec43e19441a44be (mimo) before it): its walk is ``_page_walk``'s (a run of ``PAGE_RUN`` neighbours a copy out of pools that
+#: ride as flat rows, a program's last step starting the next program's first
+#: group): every program that holds ``_paged_kernel`` moved — a window call's
+#: too, whose walk takes no runs but shares the copies and the hand-on — and
+#: nothing else did
 ROUTED_GOLDEN = {
-    "kexaone.decode": "2ca563491bcd87c4", "kexaone.chunk": "4571e4f2f4d62228",
-    "mimo.decode": "3479e88feaf87a78", "mimo.chunk": "4ec43e19441a44be"}
+    "kexaone.decode": "a05b400e52d0fbc0", "kexaone.chunk": "3bf729e722e5e392",
+    "mimo.decode": "e89ec1dfbce0698e", "mimo.chunk": "6444332fe89b5db7"}
 
 
 def _routed_text(case: str) -> str:

@@ -504,8 +504,13 @@ def _jaxpr_text(jaxpr) -> str:
 #: chunk tile reads a head's rows out of the slot's own words), so every
 #: program that holds ``_paged_kernel`` moved and nothing else did (each holds a per-head
 #: call at a head of 128 lanes)
-OLD_PROGRAMS = {"routed.decode": "6333e014f76ad6f8", "routed.chunk": "afa36b916c34c852",
-                "dense.fused": "a3e1460f31ac44a8", "conv.decode": "29c23afa71acc69d"}
+#: PR 61 RE-RECORDED all four (6333e014f76ad6f8, afa36b916c34c852, a3e1460f31ac44a8, 29c23afa71acc69d before it): its walk is ``_page_walk``'s (a run of ``PAGE_RUN`` neighbours a copy out of pools that
+#: ride as flat rows, a program's last step starting the next program's first
+#: group): every program that holds ``_paged_kernel`` moved — a window call's
+#: too, whose walk takes no runs but shares the copies and the hand-on — and
+#: nothing else did
+OLD_PROGRAMS = {"routed.decode": "f7d108d8f27742ae", "routed.chunk": "2248be6050b7774b",
+                "dense.fused": "28bb710fae17c5ca", "conv.decode": "efb32abacb8cd4ba"}
 
 
 def _old_program(case: str) -> str:

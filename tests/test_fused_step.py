@@ -620,6 +620,11 @@ def test_a_fused_step_counts_its_expert_product_by_its_block(monkeypatch):
 #: program that holds ``_paged_kernel`` moved and nothing else did: the latent,
 #: ``lfm2.*`` and every ``gather`` entry stand as recorded, which is the bypass
 #: PR 60 owes
+#: PR 61 RE-RECORDED ``mistral.fused.paged`` (85e2cb4948cd216b before it): its walk is ``_page_walk``'s (a run of ``PAGE_RUN`` neighbours a copy out of pools that
+#: ride as flat rows, a program's last step starting the next program's first
+#: group): every program that holds ``_paged_kernel`` moved — a window call's
+#: too, whose walk takes no runs but shares the copies and the hand-on — and
+#: nothing else did
 PARENT_GOLDEN = {
     "kanana2.decode.paged": "fb8bb5e87fc3416b", "kanana2.decode.gather": "b709f765129bcb03",
     "kanana2.chunk.paged": "12309b7328d12d1a", "kanana2.chunk.gather": "328c7e20c9e1bcd0",
@@ -628,7 +633,7 @@ PARENT_GOLDEN = {
     "dots3.chunk.paged": "d8c6eb810c5ac125", "dots3.chunk.gather": "b001d947ccbce8e8",
     "xing4.decode.paged": "d336b92619bfd470", "xing4.decode.gather": "60a794ee6db7674d",
     "xing4.chunk.paged": "8e15ccabd252533b", "xing4.chunk.gather": "ade850137b63adb5",
-    "mistral.fused.paged": "85e2cb4948cd216b", "mistral.fused.gather": "67d47148371a1896",
+    "mistral.fused.paged": "aa2676b9bea00ba3", "mistral.fused.gather": "67d47148371a1896",
     # recorded at PR 58's parent (15665ab): an LFM2 layout (conv, conv, full,
     # conv; two dense layers, then routed) without a riding chunk
     "lfm2.decode.paged": "b4f07a44820525ad", "lfm2.decode.gather": "b08a99f7fadeafec",

@@ -860,8 +860,13 @@ def test_judge_holds_the_float32_leaves():
 #: chunk tile reads a head's rows out of the slot's own words), so every
 #: program that holds ``_paged_kernel`` moved and nothing else did (the layout's
 #: full-attention layers call it; the ``gather`` two stand as recorded)
+#: PR 61 RE-RECORDED the two ``paged`` entries (2711701e5e0aa4e2, 8ca449a2ed3963f3 before it): its walk is ``_page_walk``'s (a run of ``PAGE_RUN`` neighbours a copy out of pools that
+#: ride as flat rows, a program's last step starting the next program's first
+#: group): every program that holds ``_paged_kernel`` moved — a window call's
+#: too, whose walk takes no runs but shares the copies and the hand-on — and
+#: nothing else did
 GDN_PARENT_GOLDEN = {
-    "decode.paged": "2711701e5e0aa4e2", "chunk.paged": "8ca449a2ed3963f3",
+    "decode.paged": "5afaf368bd21de59", "chunk.paged": "357ca1f4e65014b2",
     "decode.gather": "05d2f806133264e8", "chunk.gather": "f45e7c39e0e07910"}
 
 

@@ -237,6 +237,42 @@ def test_a_chunks_per_head_product_compiles_inside_its_vmem_limit(v5e, cell):
     assert compiled.memory_analysis().temp_size_in_bytes < 8 * 1024 * 1024
 
 
+#: the per-head walk in runs (PR 61) at the widths whose copies were the
+#: smallest: (lanes, table columns, query heads, K/V heads, key width, value
+#: width, chunk) — l6's, tp4's shard's, ``mimo_l7``'s full layers' (keys of 192
+#: in two parts) and ``qwen3next_l8``'s (one K/V head of 256 a call)
+RUN_WALKS = {"mistral_l6": (16, 136, 32, 8, 128, 128, 128),
+             "mistral_tp4_local": (16, 136, 8, 2, 128, 128, 128),
+             "mimo_l7_full": (64, 832, 64, 4, 192, 128, 512),
+             "qwen3next_l8": (128, 576, 8, 1, 256, 256, 512)}
+
+
+@pytest.mark.parametrize("step", ["decode", "chunk"])
+@pytest.mark.parametrize("cell", list(RUN_WALKS))
+def test_the_per_head_walk_in_runs_compiles(v5e, cell, step):
+    """A stretch of ``PAGE_RUN`` neighbours as ONE copy a pool and a key part
+    out of pools that ride as flat rows, and a program's last step starting
+    the next program's first group: the chip's compiler takes both at a
+    decode tile and a chunk tile, the group whole runs, nothing re-laid."""
+    from arkflow_tpu.ops import ragged_attention as ra
+
+    lanes, table, h, kvh, dk, dv, chunk = RUN_WALKS[cell]
+    parts = 1 if dk % 128 == 0 else -(-dk // 128)
+    held = dk // parts if dk % 128 == 0 else 128
+    b, c = (lanes, 1) if step == "decode" else (1, chunk)
+    tile_c = ra.query_tile(c, h)
+    group = _page_group(tile_c * h, PAGE, kvh, parts * held, 2, dv,
+                        ra.per_kv_head(tile_c, h, kvh), parts)
+    pages = 1 + lanes * table // 4
+    assert ra.PAGE_RUN == 8 and ra._takes_runs(0, group, pages)
+    compiled = _compile(
+        paged_flash_attention, v5e, ((b, c, h, dk), BF16),
+        ((2 * parts, pages, PAGE, kvh, held), BF16), ((2, pages, PAGE, kvh, dv), BF16),
+        ((), I32), ((b, table), I32), ((b,), I32))
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 * 1024 * 1024
+
+
 def test_paged_flash_is_a_mosaic_kernel(v5e):
     compiled = _compile(paged_flash_attention, v5e,
                         *_paged_shapes("llama3_8b", 1))

@@ -646,11 +646,16 @@ def test_judge_holds_the_float32_leaves_to_their_masters():
 #: PR 60 did, ``paged_wide`` alone (5cd67e6cd4990bd3, 108db1c0c9f52ed8 before
 #: it): both products of the per-head kernel take the type the pools hold and
 #: a chunk tile reads a head's rows out of the slot's own words.
+#: PR 61 RE-RECORDED ``paged_wide`` alone (0243bd5358771527, 5f8510cd32802d47 before it): its walk is ``_page_walk``'s (a run of ``PAGE_RUN`` neighbours a copy out of pools that
+#: ride as flat rows, a program's last step starting the next program's first
+#: group): every program that holds ``_paged_kernel`` moved — a window call's
+#: too, whose walk takes no runs but shares the copies and the hand-on — and
+#: nothing else did
 DENSE_HLO = {
     "gather": ("60ffd56343915631", "9992b028c6149b1d"),
     "paged": ("94a8d586436a9de5", "567d43b22ded3d2f"),
     "gather_wide": ("eaf5b975d560c31b", "502b93d7f55f0b71"),
-    "paged_wide": ("0243bd5358771527", "5f8510cd32802d47"),
+    "paged_wide": ("54a4fa02fc5d16d7", "998bd77cc688572f"),
 }
 
 

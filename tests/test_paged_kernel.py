@@ -21,6 +21,7 @@ are chosen tie-free under their seed so assertions are exact and stable.
 from __future__ import annotations
 
 import asyncio
+import functools
 import json
 import math
 import os
@@ -455,6 +456,158 @@ def test_a_chunks_tile_multiplies_each_kv_head_by_its_own_rows(name):
         q, k, v, offs, window, sinks), atol=3e-5)
 
 
+# -- a run of neighbours is one copy ---------------------------------------------
+
+#: (query heads, K/V heads, key width, value width): ONE K/V head of 256
+#: lanes (a call of ``GqaSpec.split_heads``' pools), 2 and 8 heads of 128,
+#: and keys of 192 held in two parts of 128 beside values of 128
+RUN_GEOMETRIES = {"one_head_of_256": (4, 1, 256, 256), "two_heads_of_128": (8, 2, 128, 128),
+                  "eight_heads_of_128": (16, 8, 128, 128),
+                  "keys_in_two_parts": (16, 4, 192, 128)}
+
+#: how a row's 40-column table lies in the pool (4 keys a page), and each
+#: row's first query, as (decode, chunk of 8): every aligned stretch of 8
+#: columns 8 neighbours, the walks ending where a stretch does (16 and 32
+#: pages); any page anywhere; a live stretch with two of its entries
+#: swapped; walks that end INSIDE a stretch, whose other pages lie beside
+#: the live ones and hold NaN; and a pool of 6 pages, less than one run
+RUN_LAYOUTS = {
+    "runs": ((63, 127, 0), (56, 120)),
+    "permuted": ((63, 130, 37), (56, 100)),
+    "a_stretch_broken_in_its_middle": ((130, 90), (100, 61)),
+    "last_stretch_part_dead": ((37, 130, 90), (30, 100)),
+    "pool_shorter_than_a_run": ((17,), (9,)),
+}
+RUN_PAGE, RUN_COLS = 4, 40
+
+
+def _run_operands(geometry, layouts, c):
+    """``_per_head_operands``' pools over a table whose rows lie as
+    ``layouts`` say, layout by layout: a row owns ``RUN_COLS`` neighbouring
+    pages, column i names the i-th of them (``runs``) or what its layout
+    makes of that; pages no query of the row attends hold NaN."""
+    h, kvh, dk, dv = RUN_GEOMETRIES[geometry]
+    rows = [(name, off) for name in layouts for off in RUN_LAYOUTS[name][c > 1]]
+    offs = np.asarray([off for _, off in rows])
+    short = layouts == ("pool_shorter_than_a_run",)
+    b, page, cols = len(rows), RUN_PAGE, 5 if short else RUN_COLS
+    rng = np.random.RandomState(len(geometry) + c)
+    bf = lambda a: np.asarray(jnp.asarray(a, jnp.bfloat16).astype(jnp.float32))  # noqa: E731
+    t = cols * page
+    k, v = bf(rng.randn(b, t, kvh, dk) * 0.5), bf(rng.randn(b, t, kvh, dv) * 0.5)
+    parts = 1 if dk % 128 == 0 else -(-dk // 128)
+    held = dk if parts == 1 else 128
+    n_pages = 1 + b * cols
+    kp = np.full((n_pages, page, kvh, parts * held), np.nan, np.float32)
+    vp = np.full((n_pages, page, kvh, dv), np.nan, np.float32)
+    table = np.zeros((b, cols), np.int32)
+    for r, (layout, off) in enumerate(rows):
+        own = np.arange(cols)
+        if layout == "permuted":
+            own = rng.permutation(cols)
+        elif not short:  # the blocks of 8 in any order
+            own = (rng.permutation(cols // 8)[:, None] * 8 + np.arange(8)).reshape(-1)
+        if layout == "a_stretch_broken_in_its_middle":
+            own[[10, 12]] = own[[12, 10]]
+        table[r] = 1 + r * cols + own
+        for i in range((off + c - 1) // page + 1):
+            kp[table[r, i], :, :, dk:] = 0.0
+            kp[table[r, i], :, :, :dk] = k[r, i * page:(i + 1) * page]
+            vp[table[r, i]] = v[r, i * page:(i + 1) * page]
+    # layer 1 of three, the others NaN; a short pool is ONE layer, or the
+    # view of all layers' pages would hold a run
+    pool = (lambda a: jnp.asarray(a, jnp.bfloat16)[None]) if short else (
+        lambda a: jnp.full((3, *a.shape), jnp.nan, jnp.bfloat16).at[1].set(
+            jnp.asarray(a, jnp.bfloat16)))
+    k_pool = jnp.concatenate([pool(kp[..., p * held:(p + 1) * held])
+                              for p in range(parts)])
+    q = bf(rng.randn(b, c, h, dk) * 0.5)
+    return q, k, v, k_pool, pool(vp), table, offs, 0 if short else 1
+
+
+@functools.lru_cache(maxsize=None)
+def _walked_both_ways(geometry, c, short):
+    """ONE call a geometry and a tile, every layout's rows in it (a short
+    pool: a call of its own), with runs and — ``PAGE_RUN`` 0 — a page a
+    copy, groups of two stretches (a decode tile) and of one (a chunk
+    tile); what each layout's case below looks at: the rows' layouts, the
+    table and offsets, both outputs and the plain attention's."""
+    layouts = ("pool_shorter_than_a_run",) if short else tuple(
+        name for name in RUN_LAYOUTS if name != "pool_shorter_than_a_run")
+    q, k, v, k_pool, v_pool, table, offs, layer = _run_operands(geometry, layouts, c)
+    outs = []
+    with pytest.MonkeyPatch.context() as mp:
+        for name, most in (("_PAGED_GROUP_MAX", 16), ("_PAGED_ONE_HEAD_GROUP_MAX", 16),
+                           ("_PAGED_CHUNK_GROUP_MAX", 8)):
+            mp.setattr(ragged_attention, name, most)
+        # (a short pool's call traces to ONE program whatever ``PAGE_RUN``
+        # is — the test below —: made once)
+        for run in (8,) if short else (8, 0):
+            mp.setattr(ragged_attention, "PAGE_RUN", run)
+            outs.append(np.asarray(paged_flash_attention.__wrapped__(
+                jnp.asarray(q), k_pool, v_pool, layer, jnp.asarray(table),
+                jnp.asarray(offs, jnp.int32), interpret=True)))
+    outs = outs * 2 if short else outs
+    of = [name for name in layouts for _ in RUN_LAYOUTS[name][c > 1]]
+    return of, table, offs, outs, _plain_attention(q, k, v, offs, 0, None)
+
+
+@pytest.mark.parametrize("c", [1, 8], ids=["decode_tile", "chunk_tile"])
+@pytest.mark.parametrize("layout", list(RUN_LAYOUTS))
+@pytest.mark.parametrize("geometry", list(RUN_GEOMETRIES))
+def test_a_run_of_neighbours_is_one_copy_and_the_same_bits(geometry, layout, c):
+    """The per-head kernel on ``_page_walk`` with runs (PR 61): over every
+    layout of the table, at a decode tile (all heads at once) and a chunk
+    tile (a K/V head at a time), the call gives BIT FOR BIT what the walk
+    without runs gives (``PAGE_RUN`` 0: a page a copy), the plain
+    attention's answer, and copies no page past a row's last live one (they
+    hold NaN, beside the live ones)."""
+    h, kvh, dk, dv = RUN_GEOMETRIES[geometry]
+    assert ragged_attention.per_kv_head(ragged_attention.query_tile(c, h), h, kvh) == (c > 1)
+    of, table, offs, (with_runs, without), plain = _walked_both_ways(
+        geometry, c, layout == "pool_shorter_than_a_run")
+    mine = [r for r, name in enumerate(of) if name == layout]
+    walked = (offs[mine] + c - 1) // RUN_PAGE + 1
+    in_runs = ragged_attention.pages_in_runs(table[mine], walked)
+    assert (in_runs > 0) == (layout not in ("permuted", "pool_shorter_than_a_run"))
+    if layout == "runs":  # every walked page but an idle lane's one
+        assert in_runs == walked[walked >= 8].sum()
+    got = with_runs[mine]
+    assert got.shape == (len(mine), c, h, dv) and np.isfinite(got).all()
+    np.testing.assert_array_equal(got, without[mine])
+    np.testing.assert_allclose(got, plain[mine], atol=3e-5)
+
+
+def _walk_conds(monkeypatch, run, **call):
+    """The ``cond`` equations (``_by_runs``' test of a stretch) of the
+    kernel a call traces to with ``PAGE_RUN`` held to ``run``."""
+    monkeypatch.setattr(ragged_attention, "PAGE_RUN", run)
+    q = jnp.zeros((2, 1, 8, 128), jnp.bfloat16)
+    pool = jnp.zeros((2, call.pop("pages", 40), 16, 2, 128), jnp.bfloat16)
+    traced = jax.make_jaxpr(lambda *a: paged_flash_attention.__wrapped__(*a, **call))(
+        q, pool, pool, 1, jnp.zeros((2, 16), jnp.int32), jnp.zeros((2,), jnp.int32))
+    return len(list(_eqns(traced.jaxpr, "cond")))
+
+
+def test_the_per_head_walk_takes_runs_only_where_a_stretch_fits(monkeypatch):
+    """``_takes_runs``, the latent kernel's rule: a call under a window (a
+    ring's tile starts at an unaligned page) and one over a pool of less
+    than ``PAGE_RUN`` pages trace to the walk WITHOUT runs — no test of a
+    stretch in them, the program ``PAGE_RUN`` 0 gives —; a full layer's call
+    holds the test where the call's first group is started, where a
+    program's next is and where the next program's first is."""
+    assert ragged_attention.PAGE_RUN == 8
+    plain = _walk_conds(monkeypatch, 0)
+    assert _walk_conds(monkeypatch, 8) == plain + 3
+    assert _walk_conds(monkeypatch, 8, window=32) == _walk_conds(
+        monkeypatch, 0, window=32)
+    assert _walk_conds(monkeypatch, 8, pages=3) == plain
+    monkeypatch.setattr(ragged_attention, "PAGE_RUN", 8)
+    # a group the budget bounds is whole runs where it holds one
+    assert [ragged_attention._whole_runs(n) for n in (1, 7, 8, 13, 45)] == [
+        1, 7, 8, 8, 40]
+
+
 # -- both products take what the pools hold -------------------------------------
 
 #: ``PER_HEAD_CASES``' columns, served types: bfloat16 queries over bfloat16
@@ -609,11 +762,16 @@ def test_a_row_of_many_small_probabilities_keeps_its_sum(c):
 #: program that holds ``_paged_kernel`` moved and nothing else did (recorded before it: 4cf27ca18560d7e8,
 #: 9c5d04ac365f833f, 492d84aa8529d818, c9a9dc405233f779): what the test holds
 #: since is that these tiles stay on the all-heads product
+#: PR 61 RE-RECORDED all four (ea922909c22c8d02, 4ba87dd276930d01, cd476856d8a41b8a, a0f623dd5bf43b43 before it): its walk is ``_page_walk``'s (a run of ``PAGE_RUN`` neighbours a copy out of pools that
+#: ride as flat rows, a program's last step starting the next program's first
+#: group): every program that holds ``_paged_kernel`` moved — a window call's
+#: too, whose walk takes no runs but shares the copies and the hand-on — and
+#: nothing else did
 ALL_HEADS_GOLDEN = {
-    "decode": ((2, 1, 8, 2, 0, False), "ea922909c22c8d02"),
-    "decode.window.sink": ((2, 1, 8, 4, 32, True), "4ba87dd276930d01"),
-    "odd_rows": ((1, 3, 4, 2, 0, False), "cd476856d8a41b8a"),
-    "odd_rows.five_a_head": ((1, 7, 10, 2, 0, False), "a0f623dd5bf43b43")}
+    "decode": ((2, 1, 8, 2, 0, False), "e7d4eeaa6d183826"),
+    "decode.window.sink": ((2, 1, 8, 4, 32, True), "7785e8990af24804"),
+    "odd_rows": ((1, 3, 4, 2, 0, False), "7fa92c3bfeea186b"),
+    "odd_rows.five_a_head": ((1, 7, 10, 2, 0, False), "bb8a50a130b69455")}
 
 
 @pytest.mark.parametrize("case", sorted(ALL_HEADS_GOLDEN))
@@ -973,10 +1131,56 @@ def test_server_counts_the_pages_its_rows_walk():
     assert len(out) == 5
     cols = 10                                    # 40 positions of 4 a page
     # the chunks' last queries sit at 7 and 15: 2 and 4 pages
-    # (the third counter: pages in runs, which a per-head kernel never takes)
+    # (the third counter: pages in runs, which the narrow-head walk of this
+    # model's heads of 16 never takes: the test below)
     assert got["chunk"] == [2 + 4, 2 * cols, 0]
     # four decode steps at lengths 13..16 (4, 4, 4, 5 pages) + the idle lane
     assert got["decode"] == [4 + 4 + 4 + 5 + 4, 4 * 2 * cols, 0]
+
+
+def test_server_counts_the_pages_a_per_head_walk_takes_in_runs():
+    """``arkflow_gen_attn_pages_in_runs_total`` on a per-head server (PR 61):
+    a model with a layer that keeps every key at heads of 128 lanes counts a
+    hand-made table's walked pages by the kernel's predicate
+    (``pages_in_runs``: row 0 walks 13 pages, its first stretch whole; row 1
+    all 16, its first stretch two pages swapped; an idle lane its scratch
+    page), beside a sliding layer too; a ``gather`` server counts nothing,
+    and a model of sliding layers alone (a ring takes no runs; the paged
+    kernel's parity probe wants a full layer, so it is asked as a ``gather``
+    server) would count none."""
+    from arkflow_tpu.obs import global_registry
+    from arkflow_tpu.ops.ragged_attention import pages_in_runs
+
+    table = np.zeros((3, 16), np.int32)
+    table[0] = [*range(1, 9), *range(20, 28)]
+    table[1] = [9, 10, 12, 11, 13, 14, 15, 16, *range(30, 38)]
+    last = np.asarray([100, 127, 0])         # pages of 8 keys: 13, 16 and 1
+    assert pages_in_runs(table, last // 8 + 1) == 8 + 8
+    fam = get_model("decoder_lm")
+    wide = {**TINY, "head_dim": 128, "max_seq": 128}
+    sliding = dict(layer_types=("sliding_attention", "full_attention"),
+                   sliding_window=9)
+    sizes = dict(slots=3, page_size=8, max_seq=128, prefill_chunk=8)
+    got = {}
+    for name, more in (("full", {}), ("full_beside_sliding", sliding)):
+        cfg = fam.make_config(**wide, **more)
+        params = fam.init(jax.random.PRNGKey(1), cfg)
+        server = GenerationServer(params, cfg, **sizes, decode_kernel="paged",
+                                  kernel_interpret=True)
+        counters = [global_registry().counter(
+            f"arkflow_gen_attn_{what}_total",
+            labels={"model": "decoder_lm", "kind": "decode"})
+            for what in ("pages_walked", "pages_in_runs")]
+        before = [m.value for m in counters]
+        server._note_walk("decode", last, 2, table=table)
+        got[name] = [m.value - v for m, v in zip(counters, before)]
+        asyncio.run(server.close())
+    assert got == {"full": [30, 16], "full_beside_sliding": [30, 16]}
+    cfg = fam.make_config(**wide, **{**sliding, "layer_types": (
+        "sliding_attention",) * 2})
+    gather = GenerationServer(fam.init(jax.random.PRNGKey(1), cfg), cfg, **sizes)
+    assert gather.m_attn_walk == {} and not gather._walk_in_runs
+    asyncio.run(gather.close())
 
 
 def test_server_counts_its_query_tiles_by_the_product_they_make():

@@ -1085,7 +1085,8 @@ def test_pages_in_runs_counts_what_the_predicate_says():
     neighbours — the first whole stretch counts, the second ends past its
     last page; a row walks all 16, its first stretch two pages swapped, its
     second neighbours; an idle lane walks its scratch page. A per-head
-    server's kernel takes no runs and counts none."""
+    server of heads narrower than 128 lanes (the narrow-head walk takes no
+    runs) counts none; one of wide heads: ``tests/test_paged_kernel.py``."""
     from arkflow_tpu.obs import global_registry
     from arkflow_tpu.ops.ragged_attention import PAGE_RUN, pages_in_runs
 

@@ -353,6 +353,11 @@ def _text_hash(text: str) -> str:
 #: chunk tile reads a head's rows out of the slot's own words), so every
 #: program that holds ``_paged_kernel`` moved and nothing else did (the gather
 #: forms and the narrow head's ``paged`` stand as recorded)
+#: PR 61 RE-RECORDED the four ``walk`` entries (a0adb19001bf947b, 5010a46cdf3eff63, c471586378e96771, 8669ac1ba2980a61 before it): its walk is ``_page_walk``'s (a run of ``PAGE_RUN`` neighbours a copy out of pools that
+#: ride as flat rows, a program's last step starting the next program's first
+#: group): every program that holds ``_paged_kernel`` moved — a window call's
+#: too, whose walk takes no runs but shares the copies and the hand-on — and
+#: nothing else did
 WINDOW_0_GOLDEN = {
     "dense.decode.gather": "0c702f3da9e0d32f", "dense.chunk.gather": "9c8368cf57b10193",
     "dense.decode.paged": "54c9aa64d0a27507", "dense.chunk.paged": "2d700266541cda06",
@@ -362,8 +367,8 @@ WINDOW_0_GOLDEN = {
     "dense.chunk.gather_wide": "a6ada1e181277574",
     "hybrid.decode.gather_wide": "652c84eb6ce512e5",
     "hybrid.chunk.gather_wide": "d7b395f58ea511f1",
-    "dense.decode.walk": "a0adb19001bf947b", "dense.chunk.walk": "5010a46cdf3eff63",
-    "hybrid.decode.walk": "c471586378e96771", "hybrid.chunk.walk": "8669ac1ba2980a61"}
+    "dense.decode.walk": "ca05ec537e8bbabd", "dense.chunk.walk": "8e5362ff59a68efe",
+    "hybrid.decode.walk": "12bf7c4f086daba0", "hybrid.chunk.walk": "f014436fe73184fa"}
 
 
 def _window_0_text(case: str) -> str:
@@ -485,9 +490,14 @@ def test_latent_programs_do_not_move_with_the_per_head_kernel(case):
 #: chunk tile reads a head's rows out of the slot's own words), so every
 #: program that holds ``_paged_kernel`` moved and nothing else did: the
 #: ``narrow`` two stand as recorded at aa369a0, which is the bypass PR 60 owes
+#: PR 61 RE-RECORDED the two ``per_head`` entries (ea922909c22c8d02, 5c5ee50d4b1d3929; the ``narrow`` two stand: ``_page_walk``'s new arguments default to the walk it was before it): its walk is ``_page_walk``'s (a run of ``PAGE_RUN`` neighbours a copy out of pools that
+#: ride as flat rows, a program's last step starting the next program's first
+#: group): every program that holds ``_paged_kernel`` moved — a window call's
+#: too, whose walk takes no runs but shares the copies and the hand-on — and
+#: nothing else did
 WALK_BYPASS_GOLDEN = {
     "narrow.decode": "2e05c2d8f5550240", "narrow.chunk": "b5498163e2e0b03b",
-    "per_head.decode": "ea922909c22c8d02", "per_head.chunk": "5c5ee50d4b1d3929"}
+    "per_head.decode": "e7d4eeaa6d183826", "per_head.chunk": "58d6776638c2a055"}
 
 
 @pytest.mark.parametrize("case", sorted(WALK_BYPASS_GOLDEN))

@@ -25,6 +25,7 @@ this process holds. One JSON line a point:
     python tools/profile_paged_attention.py --group-max 16,32,64 # a sweep
     python tools/profile_paged_attention.py --cell lfm2_l12 --form gather
     python tools/profile_paged_attention.py --cell xing4_l10 --table runs --run 2,4,8
+    python tools/profile_paged_attention.py --cell mimo_l7_full --kind decode --table runs --run 0,8 --check
 
 A head narrower than 128 lanes (``lfm2_l12``: 8 K/V heads of 64) has
 row-major pools and the narrow-head walk where the checkout has one
@@ -36,8 +37,13 @@ heads, masked attention).
 
 ``--group-max`` sets the module's group ceilings for the run (a
 microbench's lever, not a serving knob; for a latent point it IS the group,
-under a window too, where the kernel would take a tile's whole walk). ``--check`` compares each point's
-output with the gather reference on the device. Needs a TPU: on the CPU the
+under a window too, where the kernel would take a tile's whole walk).
+``--run`` sets the pages ONE copy of a walk moves where the table lays them
+side by side (the module's ``PAGE_RUN``; 0: the walk without runs, a page a
+copy, on the same checkout), the per-head and the latent walk alike.
+``--check`` compares each point's output with the gather reference on the
+device and, where the walk takes runs, BIT FOR BIT with the same call
+without (``same_as_run_0``). Needs a TPU: on the CPU the
 kernel is interpreted and a time says nothing (``--interpret`` runs tiny
 shapes there to rehearse the control flow; its lines say ``"rehearsal"``).
 """
@@ -125,10 +131,11 @@ BLOCK = 8
 
 def _points(args):
     for name, cell in CELLS.items():
-        if args.cell not in ("all", name):
+        if args.cell != "all" and name not in args.cell.split(","):
             continue
         if args.kind in ("all", "decode"):
-            for ctx in cell.get("decode_ctx", (None,)):
+            for ctx in ([int(c) for c in args.decode_ctx.split(",") if c]
+                        or cell.get("decode_ctx", (None,))):
                 yield name, cell, "decode", ctx
         if args.kind in ("all", "chunk"):
             for off in cell["offsets"]:
@@ -304,17 +311,21 @@ def _latent_reference(q, c_pool, r_pool, table, off, cell):
 
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    ap.add_argument("--cell", default="all", choices=["all", *CELLS])
+    ap.add_argument("--cell", default="all",
+                    help="a cell of CELLS, several with commas, or all")
     ap.add_argument("--kind", default="all", choices=["all", "decode", "chunk"])
     ap.add_argument("--group-max", default="",
                     help="comma-separated ceilings of pages a group to sweep")
     ap.add_argument("--table", default="permuted", choices=["permuted", "runs"],
                     help="runs: a row's pages in blocks of 8 neighbours (what "
-                         "the server's allocator hands out), which the latent "
-                         "walk copies --run pages a descriptor")
+                         "the server's allocator hands out), which a walk "
+                         "copies --run pages a descriptor")
     ap.add_argument("--run", default="",
-                    help="comma-separated pages a copy of the latent walk to "
-                         "sweep (the module's PAGE_RUN)")
+                    help="comma-separated pages a copy of a walk to sweep "
+                         "(the module's PAGE_RUN; 0: no runs)")
+    ap.add_argument("--decode-ctx", default="",
+                    help="comma-separated contexts: a decode point each, every "
+                         "lane at that context (instead of the cell's own)")
     ap.add_argument("--reps", type=int, default=10)
     ap.add_argument("--rounds", type=int, default=5)
     ap.add_argument("--seed", type=int, default=0)
@@ -327,6 +338,9 @@ def main() -> int:
                     help="another checkout to import arkflow_tpu from (a parent "
                          "commit unpacked beside this one)")
     args = ap.parse_args()
+    unknown = set(args.cell.split(",")) - {"all", *CELLS}
+    if unknown:
+        ap.error(f"--cell: no such cell: {sorted(unknown)}")
     if args.root:
         sys.path.insert(0, args.root)
 
@@ -362,7 +376,7 @@ def main() -> int:
         window = cell.get("window", 0)
         for ceiling, run in itertools.product(
                 ceilings, [int(r) for r in args.run.split(",") if r] or [None]):
-            if run and hasattr(ra, "PAGE_RUN"):  # a checkout that takes runs
+            if run is not None and hasattr(ra, "PAGE_RUN"):  # one that takes runs
                 ra.PAGE_RUN = run
             if ceiling:
                 ra._PAGED_GROUP_MAX = ra._PAGED_CHUNK_GROUP_MAX = ceiling
@@ -405,7 +419,7 @@ def main() -> int:
                     **({"form": "gather"} if args.form == "gather" else {}),
                     **({"group_max": ceiling} if ceiling else {}),
                     **({"table": args.table, "run": ra.PAGE_RUN}
-                       if latent and hasattr(ra, "PAGE_RUN") else {}),
+                       if hasattr(ra, "PAGE_RUN") else {}),
                     "device": device.device_kind}
             if latent and hasattr(ra, "_latent_group"):  # a checkout that walks
                 tile_c = ra.latent_query_tile(c, h, cell["lat"])
@@ -431,14 +445,22 @@ def main() -> int:
                         2, cell["dv"], per_head=per_head))
             if args.interpret:
                 jax.block_until_ready(jax.jit(call)(1, *operands))
+            got = jax.jit(call)(1, *operands) if args.check else None
+            if args.check and getattr(ra, "PAGE_RUN", 0):
+                # the walk without runs, the same call else: a stretch moves
+                # the bytes its pages' copies moved
+                ra.PAGE_RUN, run_was = 0, ra.PAGE_RUN
+                line["same_as_run_0"] = bool(
+                    (got == jax.jit(call)(1, *operands)).all())
+                ra.PAGE_RUN = run_was
             if args.check and not (window or sink is not None
                                    or cell.get("split")):
                 # a ring's table and a sink have no plain twin here: the
                 # tests hold them (tests/test_paged_kernel.py)
-                got = jax.jit(call)(1, *operands).astype(jnp.float32)
                 want = (_latent_reference if latent else _reference)(
                     q, k, v, table, ctx, cell)
-                line["max_abs_err"] = float(jnp.abs(got - want).max())
+                line["max_abs_err"] = float(
+                    jnp.abs(got.astype(jnp.float32) - want).max())
             if args.interpret:
                 line["rehearsal"] = True
             else:
