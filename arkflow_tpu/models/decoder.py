@@ -38,6 +38,14 @@ CONV = "conv"
 #: gated delta rule — no keys or values by token, a float32 matrix state a
 #: value head and a short conv window a SEQUENCE instead
 LINEAR = "linear_attention"
+#: the kinds of a model whose blocks hold ONE mixer each and nothing after it
+#: (``nemotron_h``; ``hybrid_override_pattern`` spells them ``M``, ``E``,
+#: ``*``): a Mamba-2 layer (no keys or values, a float32 state and a conv
+#: window a SEQUENCE), a routed-expert layer and — ``full_attention`` — an
+#: attention layer with no MLP behind it. The family's fourth, ``-`` (a dense
+#: MLP block), is no kind here: the pattern's check refuses it by name
+MAMBA, MOE = "mamba", "moe"
+_PATTERN_KINDS = {"M": MAMBA, "E": MOE, "*": FULL}
 
 
 @dataclass(frozen=True)
@@ -431,6 +439,26 @@ class DecoderConfig:
     num_pred_heads: int = 1
     fp32_skip_add: bool = False
     fp32_logits: bool = False
+    # -- blocks of ONE mixer each (Nemotron-H: ``nemotron_h``), under the
+    # published key names. ``hybrid_override_pattern`` spells a kind a layer —
+    # ``M`` a Mamba-2 layer, ``E`` a routed-expert layer, ``*`` an attention
+    # layer — and is read as the ``layer_types`` ``mamba`` / ``moe`` /
+    # ``full_attention`` (either key may state them; the first ``layers``
+    # entries are read): block i is ``x + mixer(RMSNorm_i(x))``, one norm
+    # (``attn_norm``) and one mixer, nothing after it. The Mamba-2 layer is the
+    # hybrid block's mixer at its own sizes (``mamba_n_heads`` heads of
+    # ``mamba_d_head``, ``mamba_d_ssm`` their product where it is left 0;
+    # ``cache_spec``: pool ``ssm`` over the mamba layers only, ``kv`` over the
+    # attention layers only). ``mlp_hidden_act`` "relu2": an expert is TWO
+    # matrices, ``relu(x W_up)^2 W_down``, no gate (``ops/moe_experts``:
+    # ``moe_expert_relu2``); ``moe_shared_expert_intermediate_size``: the
+    # shared expert's width, a multiple of ``moe_intermediate_size`` (a
+    # relu-squared expert of width 2 f IS two of width f side by side, and is
+    # held so: ``shared_stack``). The attention layer is position-free:
+    # ``full_attention_rope`` false says so.
+    hybrid_override_pattern: str = ""
+    mlp_hidden_act: str = "silu"
+    moe_shared_expert_intermediate_size: int = 0
 
     def __post_init__(self):
         from arkflow_tpu.errors import ConfigError
@@ -446,6 +474,7 @@ class DecoderConfig:
             object.__setattr__(self, "linear_attn_config", tuple(sorted(
                 (k, tuple(v) if isinstance(v, list) else v)
                 for k, v in self.linear_attn_config.items())))
+        self._read_override_pattern()
         self._check_streams()
         self._check_hybrid()
         self._check_eva()
@@ -621,13 +650,13 @@ class DecoderConfig:
             raise ConfigError(
                 "head_dim is an even attention head size of a per-head K/V "
                 f"model (a latent model states its own), got {self.head_dim}")
-        if not self.hybrid:
+        if not (self.hybrid or self.mamba):
             if self.mamba_n_heads or self.mamba_d_head or self.mamba_d_state:
                 raise ConfigError("mamba_n_heads / mamba_d_head / "
                                   "mamba_d_state without mamba_d_ssm")
             return
-        if self.latent or self.routed or self.num_experts > 1 \
-                or self.use_ring_attention:
+        if self.hybrid and (self.latent or self.routed or self.num_experts > 1
+                            or self.use_ring_attention):
             raise ConfigError(
                 "the hybrid block (mamba_d_ssm > 0: a Mamba-2 mixer beside "
                 "per-head GQA attention, dense SwiGLU) composes with "
@@ -662,13 +691,14 @@ class DecoderConfig:
         kinds = self.kinds
         if self.layer_types is not None and (
                 len(kinds) != self.layers
-                or set(kinds) - {FULL, SLIDING, CONV, LINEAR}):
+                or set(kinds) - {FULL, SLIDING, CONV, LINEAR, MAMBA, MOE}):
             raise ConfigError(
                 f"layer_types names each of the {self.layers} layers "
-                f"{FULL!r}, {SLIDING!r}, {CONV!r} or {LINEAR!r}, got "
-                f"{self.layer_types}")
+                f"{FULL!r}, {SLIDING!r}, {CONV!r}, {LINEAR!r}, {MAMBA!r} or "
+                f"{MOE!r}, got {self.layer_types}")
         self._check_conv()
         self._check_linear()
+        self._check_one_mixer()
         if not self.latent:
             if self.attention_gate_type == "elementwise":
                 gates = gates[1:]  # a per-head layer's own gate
@@ -800,6 +830,99 @@ class DecoderConfig:
                 "a sliding window's pool (kv_window), conv layers (pool "
                 "conv) or the hybrid block (pool ssm) they are not, yet")
 
+    def _read_override_pattern(self) -> None:
+        """``hybrid_override_pattern`` read as ``layer_types``, and
+        ``mamba_d_ssm`` as the mamba layers' heads x head size where it is
+        left out (``nemotron_h`` publishes neither ``layer_types`` nor it)."""
+        from arkflow_tpu.errors import ConfigError
+
+        pattern = self.hybrid_override_pattern
+        if pattern:
+            if set(pattern) - set(_PATTERN_KINDS) or len(pattern) < self.layers:
+                raise ConfigError(
+                    "hybrid_override_pattern spells a kind a layer — M (a "
+                    "Mamba-2 layer), E (a routed-expert layer), * (an "
+                    f"attention layer) — for at least the {self.layers} "
+                    f"layers, got {pattern!r}; '-', the family's dense MLP "
+                    "block, is not served yet (no source here states a model "
+                    "with one: Nemotron-3-Nano's pattern has none)")
+            spelled = tuple(_PATTERN_KINDS[c] for c in pattern[:self.layers])
+            if self.layer_types is not None and self.kinds != spelled:
+                raise ConfigError(
+                    "hybrid_override_pattern and layer_types both name the "
+                    f"layers and disagree: {pattern[:self.layers]!r} against "
+                    f"{self.kinds}; state one")
+            object.__setattr__(self, "layer_types", spelled)
+        if self.mamba and not self.mamba_d_ssm:
+            object.__setattr__(self, "mamba_d_ssm",
+                               self.mamba_n_heads * self.mamba_d_head)
+
+    def _check_one_mixer(self) -> None:
+        """Blocks of one mixer each (``mamba`` / ``moe`` layers): their keys,
+        and what they are not served beside yet."""
+        from arkflow_tpu.errors import ConfigError
+
+        if not self.one_mixer:
+            if (self.mlp_hidden_act != "silu"
+                    or self.moe_shared_expert_intermediate_size):
+                raise ConfigError(
+                    "mlp_hidden_act (other than 'silu') and "
+                    "moe_shared_expert_intermediate_size belong to a model of "
+                    "one-mixer blocks (mamba / moe layers in layer_types or "
+                    "hybrid_override_pattern): every other model's MLPs and "
+                    "experts are SwiGLU, its shared experts of "
+                    "moe_intermediate_size")
+            return
+        if (self.latent or self.num_experts > 1 or self.use_ring_attention
+                or self.hc_mult > 1 or self.eva
+                or set(self.kinds) - {MAMBA, MOE, FULL}
+                or not {MAMBA, FULL} <= set(self.kinds)):
+            raise ConfigError(
+                "mamba and moe layers (blocks of one mixer each) are served "
+                "among each other and full_attention per-head K/V layers (at "
+                "least one mamba layer, which carries order, and one "
+                "full_attention layer, which the page pools are built for): "
+                "not beside latent attention (kv_lora_rank), sliding_attention, "
+                "conv or linear_attention layers, the Switch layer "
+                "(num_experts), ring attention, hc_mult or attention_class "
+                "'eva', yet")
+        if (MOE in self.kinds) != self.routed or self.first_k_dense_replace:
+            raise ConfigError(
+                "a moe layer and n_routed_experts > 0 go together, with "
+                "first_k_dense_replace 0: where the blocks hold one mixer "
+                "each, layer_types says which layers route, and no layer "
+                f"has an MLP behind its mixer; got {self.n_routed_experts} "
+                f"experts, first_k_dense_replace {self.first_k_dense_replace} "
+                f"and {self.layer_types}")
+        if self.mlp_hidden_act != "relu2":
+            raise ConfigError(
+                "a moe layer's experts are two matrices, relu(x W_up)^2 "
+                "W_down (mlp_hidden_act 'relu2'): a gated expert behind a "
+                f"one-mixer block is not served, got {self.mlp_hidden_act!r}")
+        shared, f = self.moe_shared_expert_intermediate_size, self.moe_intermediate_size
+        if self.routed and (shared < 0 or shared % f or bool(shared) != bool(
+                self.n_shared_experts)):
+            raise ConfigError(
+                "moe_shared_expert_intermediate_size is the shared experts' "
+                "width, a multiple of moe_intermediate_size (a relu-squared "
+                "expert of width 2 f is held as two of width f), and 0 with "
+                f"n_shared_experts 0; got {shared} beside {f} and "
+                f"{self.n_shared_experts} shared experts")
+        if (self.scoring_func, self.shared_expert_gate) != ("sigmoid", False):
+            raise ConfigError(
+                "a moe layer routes by sigmoid scores with a selection bias "
+                "(scoring_func sigmoid, topk_method noaux_tc) and adds its "
+                "shared expert ungated (shared_expert_gate false)")
+        if self.full_attention_rope or self.qk_norm or self.hetero \
+                or self.norm_unit_offset:
+            raise ConfigError(
+                "the attention layer among one-mixer blocks is position-free "
+                "GQA at one head size: state full_attention_rope false (the "
+                "family reads neither rope_theta nor partial_rotary_factor; "
+                "the mamba layers carry order), and neither qk_norm, "
+                "norm_unit_offset, v_head_dim, partial_rotary_factor, "
+                "attention_value_scale, a sink nor an output gate")
+
     def _check_kda(self) -> None:
         """``linear_attn_config``'s keys, its layer lists against
         ``layer_types``, and what Kimi Delta Attention layers are served
@@ -885,7 +1008,61 @@ class DecoderConfig:
     @property
     def hybrid(self) -> bool:
         """True where every layer runs a Mamba-2 mixer beside its attention."""
-        return self.mamba_d_ssm > 0
+        return self.mamba_d_ssm > 0 and not self.mamba
+
+    @property
+    def mamba(self) -> bool:
+        """True where some layers' ONE mixer is a Mamba-2 mixer."""
+        return MAMBA in self.kinds
+
+    @property
+    def ssm_heads_packed(self) -> int:
+        """Mixer heads a row of the state pool holds side by side: a ``mamba``
+        layer's as many as fill 128 lanes (``ops/ssm_scan.heads_packed``: two
+        of 64 lanes; 1 from 128 on). The parallel hybrid block's pool stays a
+        head a row at every size, as its recorded programs hold it (Falcon-H1's
+        heads are 128 wide: nothing to pack); the kernels tell by shapes."""
+        from arkflow_tpu.ops.ssm_scan import heads_packed
+
+        if not self.mamba:
+            return 1
+        return heads_packed(self.mamba_n_heads, self.mamba_n_groups,
+                            self.mamba_d_head)
+
+    @property
+    def one_mixer(self) -> bool:
+        """True where a block holds one mixer and nothing after it (mamba /
+        moe layers; an attention layer among them has no MLP)."""
+        return bool({MAMBA, MOE} & set(self.kinds))
+
+    @property
+    def relu2(self) -> bool:
+        """True where an expert is two matrices, ``relu(x W_up)^2 W_down``."""
+        return self.mlp_hidden_act == "relu2"
+
+    @property
+    def expert_width_held(self) -> int:
+        """An expert's width as the stack holds it: ``moe_intermediate_size``,
+        or — two-matrix experts of a width that is no multiple of 128 lanes
+        (1,856) — the next multiple (1,920), zero columns of ``w_up`` and
+        zero rows of ``w_down`` behind the expert's own (``relu(0)^2`` adds
+        nothing). The chip tiles ``w_up``'s minor axis to whole 128-lane rows
+        anyway, and a kernel's copy takes a slice of an expert out of the
+        stack only in such rows: handed the published width, the compiler
+        re-lays the whole stack for every call (3.3 GB at Nemotron-3-Nano's
+        sizes). One layout, whatever serves."""
+        f = self.moe_intermediate_size
+        return -(-f // 128) * 128 if self.relu2 and f > 128 else f
+
+    @property
+    def shared_stack(self) -> int:
+        """Shared experts AS STACKED behind the held ones, each
+        ``moe_intermediate_size`` wide: a relu-squared shared expert of a
+        multiple of that width is so many side by side."""
+        if not self.moe_shared_expert_intermediate_size:
+            return self.n_shared_experts
+        return self.n_shared_experts * (self.moe_shared_expert_intermediate_size
+                                        // self.moe_intermediate_size)
 
     @property
     def conv(self) -> bool:
@@ -902,13 +1079,14 @@ class DecoderConfig:
     def stateful(self) -> bool:
         """True where a sequence caches a state beside its rows by token
         (``paged_decode.cache_spec``: a ``per_slot`` pool)."""
-        return self.hybrid or self.conv or self.linear
+        return self.hybrid or self.conv or self.linear or self.mamba
 
     @property
     def attn_kinds(self) -> tuple:
         """The kinds of the layers that attend (``gqa`` / ``attn`` state
         their sizes), in the layers' order."""
-        return tuple(k for k in self.kinds if k not in (CONV, LINEAR))
+        return tuple(k for k in self.kinds
+                     if k not in (CONV, LINEAR, MAMBA, MOE))
 
     @property
     def out_gate(self) -> bool:
@@ -952,7 +1130,8 @@ class DecoderConfig:
         per-head norms); otherwise ``layers`` is the one stack of identical
         layers, as it always was."""
         return (self.latent or self.routed or self.layered or self.qk_norm
-                or self.hetero or self.conv or self.linear or self.eva)
+                or self.hetero or self.conv or self.linear or self.eva
+                or self.one_mixer)
 
     def _check_gqa_kinds(self) -> None:
         """The per-kind keys of a per-head K/V model (``gqa``)."""
@@ -1027,7 +1206,7 @@ class DecoderConfig:
         in: a latent model's kinds, and a per-head model's where their
         shapes differ (a conv or linear_attention layer has no attention
         weights at all)."""
-        return self.latent or self.conv or self.linear or len(
+        return self.latent or self.conv or self.linear or self.one_mixer or len(
             {self.gqa(kind).shape for kind in self.kinds}) > 1
 
     def attn(self, kind: str) -> "AttnSpec":
@@ -1072,7 +1251,10 @@ class DecoderConfig:
 
     @property
     def expert_layers(self) -> int:
-        """Layers of the routed-expert stack that follows it."""
+        """Layers of the routed-expert stack that follows it — among
+        one-mixer blocks, the moe layers."""
+        if self.one_mixer:
+            return self.kinds.count(MOE)
         return self.layers - self.dense_layers
 
 
@@ -1194,6 +1376,14 @@ def hc_post(x: jnp.ndarray, y: jnp.ndarray, h: jnp.ndarray, **form):
     return mhc_post(x, y, h, **form)
 
 
+@functools.partial(jax.jit, static_argnums=(1, 2, 3))
+def _uniform_padded(key, shape: tuple, bound: float, pad: tuple):
+    """uniform(-bound, bound) of ``shape`` with zeros behind it, drawn INTO
+    the padded array by one program (drawn and then padded, a layer's 1.3 GB
+    of experts is written twice)."""
+    return jnp.pad(jax.random.uniform(key, shape, jnp.float32, -bound, bound), pad)
+
+
 def _init_ffn(k, cfg: DecoderConfig, routed: bool) -> dict:
     """A layer's MLP half, drawn from the key iterator ``k``: a dense SwiGLU
     of width ``ffn``, or the router, its selection bias and the stacked
@@ -1203,7 +1393,7 @@ def _init_ffn(k, cfg: DecoderConfig, routed: bool) -> dict:
                 "w_up": cm.dense_init(next(k), cfg.dim, cfg.ffn, bias=False),
                 "w_down": cm.dense_init(next(k), cfg.ffn, cfg.dim, bias=False)}
     layer = {}
-    e = cfg.held[1] + cfg.n_shared_experts
+    e = cfg.held[1] + cfg.shared_stack
     f = cfg.moe_intermediate_size
     up, down = 1.0 / (cfg.dim ** 0.5), 1.0 / (f ** 0.5)
     layer["router"] = cm.dense_init(next(k), cfg.dim, cfg.n_routed_experts, bias=False)
@@ -1224,6 +1414,18 @@ def _init_ffn(k, cfg: DecoderConfig, routed: bool) -> dict:
     if cfg.shared_expert_gate:  # one logit a token: sigmoid of it weighs
         layer["shared_gate"] = cm.dense_init(  # the shared experts' output
             jax.random.fold_in(bias_key, 1), cfg.dim, 1, bias=False)
+    if cfg.relu2:
+        # two matrices an expert, relu(x W_up)^2 W_down: no gate is drawn (a
+        # third of the layer's draws), and zeros stand behind the width (as
+        # held: ``expert_width_held``)
+        next(k)
+        pad = cfg.expert_width_held - f
+        layer["experts"] = {
+            "w_up": _uniform_padded(next(k), (e, cfg.dim, f), up,
+                                    ((0, 0), (0, 0), (0, pad))),
+            "w_down": _uniform_padded(next(k), (e, f, cfg.dim), down,
+                                      ((0, 0), (0, pad), (0, 0)))}
+        return layer
     layer["experts"] = {
         "w_gate": jax.random.uniform(next(k), (e, cfg.dim, f), jnp.float32, -up, up),
         "w_up": jax.random.uniform(next(k), (e, cfg.dim, f), jnp.float32, -up, up),
@@ -1237,7 +1439,10 @@ def _init_ffn(k, cfg: DecoderConfig, routed: bool) -> dict:
 _STACKS = {(FULL, False): "dense_layers", (FULL, True): "layers",
            (SLIDING, False): "swa_dense_layers", (SLIDING, True): "swa_layers",
            (CONV, False): "conv_dense_layers", (CONV, True): "conv_layers",
-           (LINEAR, False): "gdn_dense_layers", (LINEAR, True): "gdn_layers"}
+           (LINEAR, False): "gdn_dense_layers", (LINEAR, True): "gdn_layers",
+           # blocks of one mixer each: an attention layer among them is a
+           # ``dense_layers`` entry with no MLP
+           (MAMBA, False): "mamba_layers", (MOE, True): "moe_layers"}
 
 
 def layer_runs(cfg: DecoderConfig) -> list:
@@ -1254,8 +1459,10 @@ def layer_runs(cfg: DecoderConfig) -> list:
     runs, in_stack, of_kind = [], {}, {}
     by_kind = cfg.kind_stacks
     for i, kind in enumerate(cfg.kinds):
-        routed = cfg.routed and i >= cfg.first_k_dense_replace
-        name = _STACKS[kind if by_kind else FULL, routed or not cfg.routed]
+        routed = (kind == MOE if cfg.one_mixer
+                  else cfg.routed and i >= cfg.first_k_dense_replace)
+        name = _STACKS[kind if by_kind else FULL,
+                       routed if cfg.one_mixer else routed or not cfg.routed]
         if cfg.kda and kind == LINEAR:  # the other mixer's leaves: its own stacks
             name = name.replace("gdn", "kda")
         at, kat = in_stack.get(name, 0), of_kind.get(kind, 0)
@@ -1293,6 +1500,9 @@ def _init_gqa_layer(key, cfg: DecoderConfig, routed: bool,
         "wo": cm.dense_init(next(k), cfg.heads * sp.dv, cfg.dim, bias=False),
         "mlp_norm": cm.rms_norm_init(cfg.dim),
     }
+    if cfg.one_mixer:  # the block ends with its attention: no MLP behind it
+        del layer["mlp_norm"]
+        return layer
     if cfg.qk_norm:
         layer.update(q_head_norm=cm.rms_norm_init(sp.dk),
                      k_head_norm=cm.rms_norm_init(sp.dk))
@@ -1316,6 +1526,20 @@ def _init_gqa_layer(key, cfg: DecoderConfig, routed: bool,
                 jax.random.fold_in(key, 600 + i), (sp.kv_heads, sp.dk), jnp.float32)
     layer.update(_init_ffn(k, cfg, routed))
     return layer
+
+
+def _init_one_mixer_layer(key, cfg: DecoderConfig, kind: str) -> dict:
+    """One block of a model whose blocks hold one mixer each (HF names:
+    backbone.layers.i.norm, .mixer): the block's norm — ``attn_norm``, the
+    name every stack's depth is read from — and a Mamba-2 mixer
+    (``_init_mixer``), the routed experts (``_init_ffn``) or the attention
+    projections (``_init_gqa_layer``)."""
+    if kind == MAMBA:
+        return {"attn_norm": cm.rms_norm_init(cfg.dim), **_init_mixer(key, cfg)}
+    if kind == MOE:
+        return {"attn_norm": cm.rms_norm_init(cfg.dim),
+                **_init_ffn(iter(jax.random.split(key, 6)), cfg, True)}
+    return _init_gqa_layer(key, cfg, False, kind)
 
 
 def _init_gdn_layer(key, cfg: DecoderConfig, routed: bool) -> dict:
@@ -1449,14 +1673,15 @@ def _init_runs(rng, cfg: DecoderConfig) -> dict:
     stacks: dict = {}
     for name, first, stop, kind, routed, _ in layer_runs(cfg):
         stacks.setdefault(name, []).extend(
-            _init_kda_layer(next(keys), cfg, routed) if cfg.kda and kind == LINEAR
+            _init_one_mixer_layer(next(keys), cfg, kind) if cfg.one_mixer
+            else _init_kda_layer(next(keys), cfg, routed) if cfg.kda and kind == LINEAR
             else _init_latent_layer(next(keys), cfg, routed, kind) if cfg.latent
             else _init_conv_layer(next(keys), cfg, routed) if kind == CONV
             else _init_gdn_layer(next(keys), cfg, routed) if kind == LINEAR
             else _init_gqa_layer(next(keys), cfg, routed, kind)
             for _ in range(first, stop))
-    for name, stack in stacks.items():
-        params[name] = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *stack)
+    for name in list(stacks):
+        params[name] = _stack_layers(stacks.pop(name))
     if cfg.num_pred_heads > 1:  # the draft heads, head 1 first: [dim, 7 vocab]
         params["pred_heads"] = cm.dense_init(
             jax.random.fold_in(rng, 700), cfg.dim,
@@ -1464,6 +1689,24 @@ def _init_runs(rng, cfg: DecoderConfig) -> dict:
     if cfg.norm_unit_offset:
         params = _seed_offset_norms(params, jax.random.fold_in(rng, 500))
     return params
+
+
+def _stack_layers(layers: list) -> dict:
+    """The layers of one stack joined on a leading axis, LEAF BY LEAF, each
+    layer's own leaf let go as its stacked copy is made: the host holds the
+    layers once and one leaf twice, not every stack twice (at 4 B float32
+    masters of which 3.4 B are one stack's experts that is 22 GB, not 31, of
+    a 40 GB host)."""
+    flat = [jax.tree_util.tree_flatten(layer) for layer in layers]
+    treedef, leaves = flat[0][1], [list(f[0]) for f in flat]
+    layers.clear()
+    del flat
+    out = []
+    for i in range(treedef.num_leaves):
+        out.append(jnp.stack([own[i] for own in leaves]))
+        for own in leaves:
+            own[i] = None
+    return treedef.unflatten(out)
 
 
 def layer_stacks(params: dict, cfg: DecoderConfig) -> list:
@@ -2157,7 +2400,7 @@ def route_topk(lp: dict, y: jnp.ndarray, cfg: DecoderConfig, token_mask=None,
     if cfg.experts_held is not None:
         first, count = cfg.held
         cw = cw[:, first:first + count]
-    shared = jnp.broadcast_to(live[:, None], (y.shape[0], cfg.n_shared_experts))
+    shared = jnp.broadcast_to(live[:, None], (y.shape[0], cfg.shared_stack))
     if cfg.shared_expert_gate:
         shared = shared * jax.nn.sigmoid(jnp.dot(
             y.astype(jnp.float32), lp["shared_gate"]["w"].astype(jnp.float32),
@@ -2166,6 +2409,17 @@ def route_topk(lp: dict, y: jnp.ndarray, cfg: DecoderConfig, token_mask=None,
     load = assign.sum(axis=0) if not lanes else jnp.stack(
         [assign[:lanes].sum(axis=0), assign[lanes:].sum(axis=0)])
     return cw, load.astype(jnp.int32)
+
+
+def expert_products(cfg: DecoderConfig) -> tuple:
+    """(the kernel product, its plain-XLA twin, an expert's leaves in the
+    products' operand order): gate | up | down for a SwiGLU expert, up | down
+    for a two-matrix one (``mlp_hidden_act`` relu2)."""
+    from arkflow_tpu.ops import moe_experts as me
+
+    if cfg.relu2:
+        return me.moe_expert_relu2, me.expert_relu2_dense, ("w_up", "w_down")
+    return me.moe_expert_swiglu, me.expert_swiglu_dense, ("w_gate", "w_up", "w_down")
 
 
 def routed_mlp(lp: dict, y: jnp.ndarray, cfg: DecoderConfig, token_mask=None,
@@ -2178,20 +2432,18 @@ def routed_mlp(lp: dict, y: jnp.ndarray, cfg: DecoderConfig, token_mask=None,
     XLA over every expert. The experts are ``lp["experts"]``, or — from a
     layer loop that must not slice them — ``stacked = (experts of the whole
     stack, this layer's index)``."""
-    from arkflow_tpu.ops.moe_experts import expert_swiglu_dense, moe_expert_swiglu
-
     b, s, d = y.shape
     yf = y.reshape(b * s, d)
     cw, load = route_topk(lp, yf, cfg, token_mask,
                           **({"lanes": lanes} if lanes else {}))
     ex, layer = stacked if stacked is not None else (lp["experts"], None)
+    product, dense, names = expert_products(cfg)
     if kernel:
-        out = moe_expert_swiglu(yf, cw, ex["w_gate"], ex["w_up"], ex["w_down"],
-                                layer, interpret=interpret)
+        out = product(yf, cw, *(ex[n] for n in names), layer, interpret=interpret)
     else:
         if layer is not None:
             ex = jax.tree_util.tree_map(lambda a: a[layer], ex)
-        out = expert_swiglu_dense(yf, cw, ex["w_gate"], ex["w_up"], ex["w_down"])
+        out = dense(yf, cw, *(ex[n] for n in names))
     return out.reshape(b, s, d), load
 
 
@@ -2454,6 +2706,14 @@ def forward(params: dict, cfg: DecoderConfig, input_ids, *, axes=None, mesh=None
 
     def make_layer(routed: bool, kind: str):
         def layer(x, lp):
+            if cfg.one_mixer:  # one mixer a block, nothing after it
+                if kind == FULL:
+                    x = _attention_block(lp, x, cfg, positions, causal, None, kind)
+                else:
+                    y = _norm(lp["attn_norm"], x, cfg)
+                    x = x + (_mixer_block(lp, y, cfg) if kind == MAMBA
+                             else routed_mlp(lp, y, cfg)[0])
+                return _shard_act(x, axes), (jnp.zeros((), jnp.float32),) * 2
             if kind == CONV:  # from the sequence's start: zeros before it
                 x = x + short_conv(
                     lp, cm.rms_norm(lp["attn_norm"], x, cfg.norm_eps), cfg)[0]
@@ -2744,6 +3004,18 @@ def _serve_dtypes_runs(cfg: DecoderConfig) -> dict:
         out["pred_heads"] = {"w": bf16}
     for name, _, _, kind, routed, _ in layer_runs(cfg):
         layer = {"attn_norm": {"scale": f32}, "mlp_norm": {"scale": f32}}
+        if cfg.one_mixer:  # one norm and one mixer a block
+            del layer["mlp_norm"]
+            if kind == MAMBA:
+                layer.update(_mixer_dtypes())
+            elif kind == MOE:
+                layer.update(router={"w": f32}, router_bias=f32,
+                             experts={"w_up": bf16, "w_down": bf16})
+            else:
+                layer.update(wq={"w": bf16}, wo={"w": bf16},
+                             **_attn_dtypes(cfg, kind))
+            out[name] = layer
+            continue
         if kind == CONV:
             layer.update(conv_in={"w": bf16}, conv_w=bf16, conv_out={"w": bf16})
         elif kind == LINEAR and cfg.kda:

@@ -55,9 +55,10 @@ from arkflow_tpu.errors import ConfigError
 from arkflow_tpu.models import common as cm
 from dataclasses import dataclass
 
-from arkflow_tpu.models.decoder import (CONV, FULL, LINEAR, SLIDING,
+from arkflow_tpu.models.decoder import (CONV, FULL, LINEAR, MAMBA, MOE, SLIDING,
                                         DecoderConfig, _mlp, _norm, _scaled,
                                         attn_out_gate, conv_gate, conv_taps,
+                                        expert_products,
                                         hc_collapse,
                                         hc_expand, hc_post, hc_pre,
                                         index_project, index_scores,
@@ -159,7 +160,9 @@ def cache_spec(cfg: DecoderConfig) -> tuple:
       the window passes;
     - ``ssm``: a hybrid layer's recurrent state — the mixer's float32 state
       matrices and the conv's last ``d_conv - 1`` inputs —, one row a
-      SEQUENCE whatever its length, beside that layer's ``kv`` rows;
+      SEQUENCE whatever its length, beside that layer's ``kv`` rows; among
+      blocks of one mixer each, over the mamba layers only (``kv`` over the
+      attention layers only, as beside ``conv``);
     - ``conv``: a conv layer's last ``conv_L_cache - 1`` gated inputs, one
       row a SEQUENCE too, over the conv layers only (which have no ``kv``
       rows: ``kv`` is over the attention layers only);
@@ -194,9 +197,9 @@ def cache_spec(cfg: DecoderConfig) -> tuple:
         if swa:
             pools += (CachePool("kv_window", swa, window=cfg.sliding_window,
                                 **widths(cfg.gqa(SLIDING))),)
-        if cfg.hybrid:
+        if cfg.hybrid or cfg.mamba:
             pools += (CachePool(
-                "ssm", cfg.layers,
+                "ssm", cfg.kinds.count(MAMBA) if cfg.mamba else cfg.layers,
                 (cfg.mamba_d_ssm * cfg.mamba_d_state,
                  (cfg.mamba_d_conv - 1) * cfg.ssm_conv_dim),
                 itemsizes=(4, 2), per_slot=True),)
@@ -458,6 +461,8 @@ UNSERVED = {
             "yet — such a model prefills and decodes on the same server"),
     },
     "routed": _BY_RUNS,
+    # blocks of one mixer each: by runs, mamba layers (pool ``ssm``) among them
+    "one_mixer": _STATE_BY_RUNS,
     "qk_norm": _BY_RUNS,
     "switch": {"fused_chunk": _FUSED + _SWITCH, "run_ahead": _SWITCH,
                "run_ahead_eos": _SWITCH},
@@ -474,9 +479,12 @@ def cache_rows(cfg: DecoderConfig) -> tuple:
     and values of different widths, or sizes by kind; ``routed``: an expert
     stack on the PER-HEAD loop (a latent model always has one); ``qk_norm``:
     per-head norms (the tree stacks by runs for them alone); ``switch``: the
-    capacity-based Switch layer (``num_experts``)."""
+    capacity-based Switch layer (``num_experts``); ``one_mixer``: blocks of
+    one mixer each (mamba / moe layers: stacked by runs, with or without
+    experts)."""
     traits = dict(streams=cfg.hc_mult > 1, hetero=cfg.hetero,
                   routed=cfg.routed and not cfg.latent, qk_norm=cfg.qk_norm,
+                  one_mixer=cfg.one_mixer,
                   switch=cfg.num_experts > 1)
     pools = {pool.name for pool in cache_spec(cfg)}
     return tuple(row for row in UNSERVED if row in pools or traits.get(row))
@@ -526,9 +534,12 @@ def init_page_pool(cfg: DecoderConfig, num_pages: int, page_size: int,
     - a hybrid model: two dicts by pool name — ``{"kv": K, "ssm": the
       states}`` and ``{"kv": V, "ssm": the conv windows}`` — the states
       float32 [layers, slots + 1, heads, d_state, d_head] (``ops/ssm_scan``
-      says why in that order), the windows [layers, slots + 1, d_conv - 1,
+      says why in that order; a head narrower than 128 lanes is held
+      ``heads_packed`` heads side by side: [.., heads / k, d_state, k
+      d_head]), the windows [layers, slots + 1, d_conv - 1,
       conv channels]: row 0 scratch, as page 0 is, row ``s + 1`` slot
-      ``s``'s;
+      ``s``'s; among blocks of one mixer each the same two, ``ssm`` over the
+      mamba layers and ``kv`` over the attention layers;
     - a model with conv layers: ``{"kv": K, "conv": the windows}`` and
       ``{"kv": V}`` — ``kv`` over the attention layers, the windows [conv
       layers, slots + 1, conv_L_cache - 1, dim], rows as a hybrid model's;
@@ -589,12 +600,13 @@ def init_page_pool(cfg: DecoderConfig, num_pages: int, page_size: int,
                 {"kv": v, "gdn": jnp.zeros(
                     rows + (cfg.linear_conv_kernel_dim - 1, cfg.gdn_conv_dim),
                     jnp.bfloat16)})
-    if not cfg.hybrid:
+    if not (cfg.hybrid or cfg.mamba):
         return k, v
-    rows = (cfg.layers, slots + 1)
+    rows = (spec[-1].layers, slots + 1)
+    packed = cfg.ssm_heads_packed
     return ({"kv": k, "ssm": jnp.zeros(
-                rows + (cfg.mamba_n_heads, cfg.mamba_d_state, cfg.mamba_d_head),
-                jnp.float32)},
+                rows + (cfg.mamba_n_heads // packed, cfg.mamba_d_state,
+                        packed * cfg.mamba_d_head), jnp.float32)},
             {"kv": v, "ssm": jnp.zeros(
                 rows + (cfg.mamba_d_conv - 1, cfg.ssm_conv_dim), jnp.bfloat16)})
 
@@ -1223,23 +1235,20 @@ def _expert_probe(params: dict, cfg: DecoderConfig, keys, rand,
     given, copies those few (94 MB at Kanana-2 widths, by static slices: an
     index array over the stack cost 1.9 GB on a v5e) and not the layer
     (1.2 GB); the kernel reads the whole stack as when serving."""
-    from arkflow_tpu.ops.moe_experts import expert_swiglu_dense, moe_expert_swiglu
-
     e, k = cfg.held[1], cfg.num_experts_per_tok
     few = min(e, 8)
     x = rand((16, cfg.dim))
     chosen = jnp.argsort(jax.random.uniform(next(keys), (16, few)), axis=-1)[:, :min(k, few)]
     cw = jax.nn.one_hot(chosen, e, dtype=jnp.float32).sum(1) * (
         cfg.routed_scaling_factor / k)
-    cw = jnp.concatenate([cw, jnp.ones((16, cfg.n_shared_experts))], axis=-1)
+    cw = jnp.concatenate([cw, jnp.ones((16, cfg.shared_stack))], axis=-1)
     ex = params[next(r[0] for r in layer_runs(cfg) if r[4])]["experts"]
-    cols = jnp.concatenate([jnp.arange(few), jnp.arange(e, e + cfg.n_shared_experts)])
+    cols = jnp.concatenate([jnp.arange(few), jnp.arange(e, e + cfg.shared_stack)])
+    product, dense, names = expert_products(cfg)
     twin = [jnp.concatenate([ex[w][0, :few], ex[w][0, e:]])  # static slices
-            for w in ("w_gate", "w_up", "w_down")]
-    return ("expert_product",
-            expert_swiglu_dense(x, cw[:, cols], *twin),
-            moe_expert_swiglu(x, cw, ex["w_gate"], ex["w_up"],
-                              ex["w_down"], 0, interpret=kernel_interpret))
+            for w in names]
+    return ("expert_product", dense(x, cw[:, cols], *twin),
+            product(x, cw, *(ex[w] for w in names), 0, interpret=kernel_interpret))
 
 
 def gqa_kernel_probe(params: dict, cfg: DecoderConfig, page_size: int,
@@ -1306,6 +1315,8 @@ def gqa_kernel_probe(params: dict, cfg: DecoderConfig, page_size: int,
         out.append(_expert_probe(params, cfg, keys, rand, kernel_interpret))
     if cfg.linear:
         out.extend(_gdn_probe(cfg, kernel_interpret))
+    if cfg.mamba:
+        out.extend(_ssm_probe(cfg, kernel_interpret))
     if cfg.eva:  # the summariser over two windows' worth of seeded rows
         from arkflow_tpu.ops.eva_summarise import (eva_summarise,
                                                    eva_summarise_plain)
@@ -1320,6 +1331,47 @@ def gqa_kernel_probe(params: dict, cfg: DecoderConfig, page_size: int,
         out.extend((f"eva_summarise_{name}", r.reshape(2, -1), g.reshape(2, -1))
                    for name, r, g in zip(("keys", "values"), ref, got))
     return out
+
+
+def _ssm_probe(cfg: DecoderConfig, kernel_interpret: bool) -> list:
+    """(name, reference, kernel output) of the Mamba-2 recurrence's two
+    kernels against their plain forms (``ops/ssm_scan``) at the model's own
+    head count, head size, state size and groups, over a seeded pool AS HELD
+    (``heads_packed``): a decode step of two lanes on rows 2 and 1, and a
+    chunk of two blocks, its first row fresh, its last positions padded (a
+    zero step). Each line is the outputs and the rows' states after, joined."""
+    from arkflow_tpu.ops.ssm_scan import ssm_chunk_scan, ssm_state_update
+
+    h, p, n, g = (cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state,
+                  cfg.mamba_n_groups)
+    k = cfg.ssm_heads_packed
+    keys = iter(jax.random.split(jax.random.PRNGKey(4343), 8))
+    t = 2 * cfg.mamba_chunk_size
+    pool = jax.random.normal(next(keys), (1, 3, h // k, n, k * p), jnp.float32)
+    x = jax.random.normal(next(keys), (2, t, h, p), jnp.float32)
+    live = (jnp.arange(t) < t - 3)[None, :, None]
+    dt = jnp.where(live, jnp.exp(jax.random.uniform(
+        next(keys), (2, t, h), jnp.float32, jnp.log(1e-3), jnp.log(1e-1))), 0.0)
+    a = -jax.random.uniform(next(keys), (h,), jnp.float32, 1.0, 16.0)
+    bm, cmat = (jax.random.normal(next(keys), (2, t, g, n), jnp.float32)
+                for _ in range(2))
+    rows = jnp.asarray([2, 1], jnp.int32)
+    fresh = jnp.asarray([True, False])
+
+    def joined(o, states):
+        return jnp.concatenate([o.reshape(-1, p), states[0, rows].reshape(-1, p)])
+
+    def update(**kern):
+        return joined(*ssm_state_update(pool, 0, rows, x[:, 0], dt[:, 0], a,
+                                        bm[:, 0], cmat[:, 0], **kern))
+
+    def scan(**kern):
+        return joined(*ssm_chunk_scan(pool, 0, rows, fresh, x, dt, a, bm, cmat,
+                                      cfg.mamba_chunk_size, **kern))
+
+    kern = dict(kernel=True, interpret=kernel_interpret)
+    return [("ssm_state_update", update(), update(**kern)),
+            ("ssm_chunk_scan", scan(), scan(**kern))]
 
 
 def _gdn_probe(cfg: DecoderConfig, kernel_interpret: bool) -> list:
@@ -1727,6 +1779,10 @@ def _dense_layers(params: dict, cfg: DecoderConfig, x, k_pages, v_pages,
     model with linear_attention layers carries ``{"kv", "gdn"}`` twice (the
     states beside K, the conv windows beside V): a ``linear_attention`` run's
     layers advance the ``gdn`` pool's rows (``_gdn_paged``) and touch no K/V.
+    A model of one-mixer blocks carries ``{"kv", "ssm"}`` twice: a ``mamba``
+    run's layers advance the ``ssm`` pool's rows (``_mixer_paged``), a ``moe``
+    run's route under the block's own norm, an attention run's write and read
+    ``kv`` — and no block has an MLP behind its mixer.
 
     ``chunk`` (``paged_fused_step``; a ``fusable`` model): the block is ONE
     row [1, lanes + C] — a decode step's lanes, a token each, then a prompt's
@@ -1758,8 +1814,8 @@ def _dense_layers(params: dict, cfg: DecoderConfig, x, k_pages, v_pages,
         where[SLIDING] = _write_coords(ring, positions, token_mask, page, ring=True)
 
     def make_layer(routed: bool, kind: str, experts):
-        def ffn(lp, x, kp, vp, ei):
-            y = _norm(lp["mlp_norm"], x, cfg)
+        def ffn(lp, x, kp, vp, ei, norm="mlp_norm"):
+            y = _norm(lp[norm], x, cfg)
             if not routed:
                 return (x + _mlp(lp, y, cfg, token_mask=token_mask), kp, vp), None
             # the stack's experts stay OUT of the scanned tree and whole:
@@ -1768,6 +1824,30 @@ def _dense_layers(params: dict, cfg: DecoderConfig, x, k_pages, v_pages,
                                    kernel=kernel, interpret=kernel_interpret,
                                    stacked=(experts, ei[0]), lanes=lanes)
             return (x + out, kp, vp), load
+
+        if kind == MOE:
+            def moe_layer(carry, scanned):
+                """A block whose one mixer is its routed experts, under the
+                block's own norm; no pool read or written."""
+                lp, _, *ei = scanned
+                return ffn(lp, *carry, ei, "attn_norm")
+
+            return moe_layer
+
+        if kind == MAMBA:
+            def mamba_layer(carry, scanned):
+                """A block whose one mixer is a Mamba-2 mixer over the ssm
+                pool's rows (``_mixer_paged``); no K/V, nothing after it."""
+                x, kp, vp = carry
+                lp, li = scanned
+                mixed, states, windows = _mixer_paged(
+                    lp, _norm(lp["attn_norm"], x, cfg), cfg, kp["ssm"],
+                    vp["ssm"], li, ssm_rows, ssm_fresh, token_mask, kernel,
+                    kernel_interpret)
+                return (x + mixed, {**kp, "ssm": states},
+                        {**vp, "ssm": windows}), None
+
+            return mamba_layer
 
         if kind == CONV:
             def conv_layer(carry, scanned):
@@ -1812,7 +1892,7 @@ def _dense_layers(params: dict, cfg: DecoderConfig, x, k_pages, v_pages,
             pools = kp, vp
             if cfg.hybrid:
                 (kp, states), (vp, windows) = ((p["kv"], p["ssm"]) for p in (kp, vp))
-            elif cfg.layered or cfg.conv or cfg.linear:
+            elif cfg.layered or cfg.conv or cfg.linear or cfg.mamba:
                 kp, vp = kp[name], vp[name]
             y = _norm(lp["attn_norm"], x, cfg)
             q, k, v = qkv_project(lp, y, cfg, kind)
@@ -1863,8 +1943,10 @@ def _dense_layers(params: dict, cfg: DecoderConfig, x, k_pages, v_pages,
                     token_mask, kernel, kernel_interpret)
                 out = out + mixed
                 kp, vp = {"kv": kp, "ssm": states}, {"kv": vp, "ssm": windows}
-            elif cfg.layered or cfg.conv or cfg.linear:
+            elif cfg.layered or cfg.conv or cfg.linear or cfg.mamba:
                 kp, vp = {**pools[0], name: kp}, {**pools[1], name: vp}
+            if cfg.one_mixer:  # the block ends with its attention
+                return (x + out, kp, vp), None
             return ffn(lp, x + out, kp, vp, ei)
         return layer
 

@@ -7,7 +7,7 @@ are free. A prefill chunk of 256 or 512 rows is above it. Cut into token
 tiles it read every expert up to once a TILE; as one wide tile it would
 multiply 512 rows by every expert, eight times the routed work.
 
-``grouped_expert_swiglu`` is the product for such a call, one Pallas kernel
+``grouped_expert_product`` is the product for such a call, one Pallas kernel
 over the same operands. The grid walks (hit expert, slice of the expert
 width), as the one-tile kernel's does, so every hit expert's three matrices
 cross HBM exactly once a call and an expert that no row chose is not read.
@@ -56,6 +56,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from arkflow_tpu.ops.moe_experts import hit_list
 
 #: rows of one grouped call (bounds VMEM: see the module docstring)
 GROUPED_ROWS = 512
@@ -159,28 +161,106 @@ def _grouped_kernel(layer_ref, ids_ref, cnt_ref, nhit_ref, x_ref, cwt_ref, rank_
         o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 
 
+def _grouped_relu2_kernel(layer_ref, ids_ref, cnt_ref, nhit_ref, x_ref, cwt_ref,
+                          rank_ref, rankt_ref, wu_ref, wd_ref, o_ref, acc_ref,
+                          xs_ref, ws_ref, ys_ref, *, n_slices: int):
+    """``_grouped_kernel`` for two-matrix experts (``relu(x W_up)^2 W_down``):
+    the same gather of a group, the same sum back into the call's rows; a
+    slice's product is one up product, its relu squared in float32."""
+    i = pl.program_id(0)
+    j = pl.program_id(1)
+    t, d = x_ref.shape
+    dims = (((1,), (0,)), ((), ()))
+    expert = ids_ref[i]
+    # row tiles of this expert's group (none for an entry past the hit list)
+    tiles = jnp.where(i < nhit_ref[0], pl.cdiv(cnt_ref[i], _ROW_TILE), 0)
+
+    def _rows(s):
+        return pl.ds(pl.multiple_of(s * _ROW_TILE, _ROW_TILE), _ROW_TILE)
+
+    @pl.when(jnp.logical_and(i == 0, j == 0))
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(j == 0)
+    def _gather():
+        rank = rankt_ref[pl.ds(expert, 1), :]                     # [1, T]
+        weight = cwt_ref[pl.ds(expert, 1), :]
+
+        def tile(s, _):
+            want = s * _ROW_TILE + jax.lax.broadcasted_iota(
+                jnp.int32, (_ROW_TILE, t), 0)
+            sel = rank == want                                    # [rows, T]
+            xs_ref[_rows(s), :] = jax.lax.dot_general(
+                jnp.where(sel, 1.0, 0.0).astype(x_ref.dtype), x_ref[...], dims,
+                preferred_element_type=jnp.float32).astype(xs_ref.dtype)
+            ws_ref[_rows(s), :] = jnp.sum(jnp.where(sel, weight, 0.0), axis=1,
+                                          keepdims=True)
+
+        jax.lax.fori_loop(0, tiles, tile, None)
+
+    def _product(s, _):
+        xs = xs_ref[_rows(s), :]                                  # [rows, D]
+        up = jax.lax.dot_general(xs, wu_ref[...], dims,
+                                 preferred_element_type=jnp.float32)
+        act = (jnp.square(jnp.maximum(up, 0.0))
+               * ws_ref[_rows(s), :]).astype(xs.dtype)
+        down = jax.lax.dot_general(act, wd_ref[...], dims,
+                                   preferred_element_type=jnp.float32)
+        ys_ref[_rows(s), :] = jnp.where(j == 0, 0.0, ys_ref[_rows(s), :]) + down
+
+    jax.lax.fori_loop(0, tiles, _product, None)
+
+    @pl.when(j == n_slices - 1)
+    def _scatter():
+        col = jax.lax.broadcasted_iota(jnp.int32, rank_ref.shape, 1)
+        rank = jnp.sum(jnp.where(col == expert, rank_ref[...], 0), axis=1,
+                       keepdims=True)                             # [T, 1]
+
+        def tile(s, _):
+            want = s * _ROW_TILE + jax.lax.broadcasted_iota(
+                jnp.int32, (t, _ROW_TILE), 1)
+            sel = jnp.where(rank == want, 1.0, 0.0).astype(jnp.bfloat16)
+            sel = jnp.concatenate([sel] * 3, axis=1)              # [T, 3 rows]
+            for c in range(0, d, _LANE_CHUNK):
+                lanes = pl.ds(c, min(_LANE_CHUNK, d - c))
+                # float32 = three bfloat16 terms, summed by the one product
+                rest, terms = ys_ref[_rows(s), lanes], []
+                for _ in range(3):
+                    terms.append(rest.astype(jnp.bfloat16))
+                    rest = rest - terms[-1].astype(jnp.float32)
+                acc_ref[:, lanes] += jax.lax.dot_general(
+                    sel, jnp.concatenate(terms, axis=0), dims,
+                    preferred_element_type=jnp.float32)
+
+        jax.lax.fori_loop(0, tiles, tile, None)
+
+    @pl.when(jnp.logical_and(i == pl.num_programs(0) - 1, j == n_slices - 1))
+    def _fin():
+        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+
+
 def _grouped_block(x, cw, w_gate, w_up, w_down, layer, interpret: bool):
     """One call of at most ``GROUPED_ROWS`` rows (a multiple of the row
-    tile); ``cw`` float32, the weights stacked, ``layer`` int32 [1]."""
+    tile); ``cw`` float32, the weights stacked, ``layer`` int32 [1];
+    ``w_gate`` None: two-matrix (relu-squared) experts."""
     from jax.experimental.pallas import tpu as pltpu
 
     t, d = x.shape
-    _, e, _, f = w_gate.shape
-    tf = grouped_slice_width(d, f, jnp.dtype(w_gate.dtype).itemsize)
+    _, e, _, f = w_up.shape
+    ups = [w_up] if w_gate is None else [w_gate, w_up]
+    # the slices are budgeted for three matrices either way: one width, and
+    # one set of programs' worth of VMEM, whatever the expert is made of
+    tf = grouped_slice_width(d, f, jnp.dtype(w_up.dtype).itemsize)
     n_slices = f // tf
     routed = cw != 0.0                                            # [T, E]
     counts = routed.sum(axis=0).astype(jnp.int32)
     before = jnp.cumsum(routed, axis=0, dtype=jnp.int32) - routed
     rank = jnp.where(routed, before, -1)
-    # the hit list, as ``moe_experts._one_tile`` makes its own (that one may
-    # not move a line); ``rank`` rides in both layouts: a ROW of one is the
-    # gather's [rows, T] one-hot, a COLUMN of the other the sum's [T, rows],
-    # and the kernel transposes nothing
-    hit = counts > 0
-    n_hit = hit.sum().astype(jnp.int32)
-    order = jnp.argsort(jnp.logical_not(hit), stable=True).astype(jnp.int32)
-    # entries past the last hit expert repeat it: same block, no copy
-    ids = order[jnp.minimum(jnp.arange(e), jnp.maximum(n_hit - 1, 0))]
+    # ``rank`` rides in both layouts: a ROW of one is the gather's [rows, T]
+    # one-hot, a COLUMN of the other the sum's [T, rows], and the kernel
+    # transposes nothing
+    ids, n_hit = hit_list(counts > 0)
 
     def _slice(i, j, nhit_ref):
         return jnp.where(i < nhit_ref[0], j, n_slices - 1)
@@ -201,8 +281,7 @@ def _grouped_block(x, cw, w_gate, w_up, w_down, layer, interpret: bool):
         grid=(e, n_slices),
         in_specs=[
             whole(t, d), whole(e, t), whole(t, e), whole(e, t),
-            pl.BlockSpec((None, None, d, tf), _up_index),
-            pl.BlockSpec((None, None, d, tf), _up_index),
+            *(pl.BlockSpec((None, None, d, tf), _up_index) for _ in ups),
             pl.BlockSpec((None, None, tf, d), _down_index),
         ],
         out_specs=whole(t, d),
@@ -212,22 +291,25 @@ def _grouped_block(x, cw, w_gate, w_up, w_down, layer, interpret: bool):
                         pltpu.VMEM((t, d), jnp.float32)],     # their outputs
     )
     return pl.pallas_call(
-        functools.partial(_grouped_kernel, n_slices=n_slices),
+        functools.partial(_grouped_kernel if w_gate is not None
+                          else _grouped_relu2_kernel, n_slices=n_slices),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((t, d), x.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
-        name="moe_expert_grouped",
+        name=("moe_expert_grouped" if w_gate is not None
+              else "moe_expert_relu2_grouped"),
     )(layer, ids, counts[ids], n_hit.reshape(1), x, cw.T, rank, rank.T,
-      w_gate, w_up, w_down)
+      *ups, w_down)
 
 
-def grouped_expert_swiglu(x, cw, w_gate, w_up, w_down, layer, interpret: bool):
+def grouped_expert_product(x, cw, w_gate, w_up, w_down, layer, interpret: bool):
     """x: [T, D], T of any size; cw: [T, E] float32 (0 = not routed); the
     weights a stack of layers ([layers, E, ...]), ``layer`` int32 [1]: the
-    operands ``moe_expert_swiglu`` has made of its own. Returns [T, D]."""
+    operands ``moe_experts._expert_product`` has made of its own (``w_gate``
+    None: two-matrix experts). Returns [T, D]."""
     t = x.shape[0]
     out = []
     for first in range(0, t, GROUPED_ROWS):
@@ -240,3 +322,7 @@ def grouped_expert_swiglu(x, cw, w_gate, w_up, w_down, layer, interpret: bool):
         out.append(_grouped_block(xb, cwb, w_gate, w_up, w_down, layer,
                                   interpret)[:rows])
     return jnp.concatenate(out) if len(out) > 1 else out[0]
+
+
+#: the gated product under the name it had before two-matrix experts came
+grouped_expert_swiglu = grouped_expert_product

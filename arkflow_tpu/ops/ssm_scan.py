@@ -28,6 +28,16 @@ tests hold it to (and ``decode_kernel: gather`` serves with):
   a block of ``chunk`` tokens the output is a masked matrix product, between
   blocks the state is passed on.
 
+A head NARROWER than the chip's 128 lanes (Nemotron-H: 64) is held
+``heads_packed`` heads side by side on the pool's minor axis, ``[layers,
+rows, heads / k, d_state, k * d_head]`` — a float32 array whose minor axis is
+64 wide is tiled to 128 lanes anyway: twice the bytes, held and moved. The
+heads of one row share B and C (``k`` divides a group's heads), so the decode
+update's kernel runs on such a pool as it stands: its body decays, adds and
+contracts lane by lane. The chunk scan multiplies a HEAD's own masked matrix,
+so it takes the chunk's rows out of the pool a head each
+(``unpack_state``), runs, and writes them back packed: a few MB a chunk.
+
 ``dt = 0`` leaves a state untouched and adds nothing: that is how a padded
 position and an idle lane are told (the caller zeroes their ``dt``; an idle
 lane also names row 0). ``fresh`` rows start from a zero state, whatever the
@@ -47,6 +57,35 @@ _HI = jax.lax.Precision.HIGHEST
 def _by_head(m, heads: int):
     """[..., groups, n] -> [..., heads, n]: head h reads group h // (heads / groups)."""
     return jnp.repeat(m, heads // m.shape[-2], axis=-2)
+
+
+def heads_packed(heads: int, groups: int, d_head: int) -> int:
+    """Heads a row of the state pool holds side by side on its minor axis:
+    as many as fill 128 lanes, where ``d_head`` divides them and a group's
+    heads divide into such rows; else 1 (every head of 128 lanes or more)."""
+    k = 128 // d_head if d_head < 128 and 128 % d_head == 0 else 1
+    while k > 1 and (heads // groups) % k:
+        k //= 2
+    return k
+
+
+def pack_state(s, k: int):
+    """[..., H, N, P] -> [..., H / k, N, k P]: head ``h`` on lanes ``(h % k)
+    P ..`` of row ``h // k`` (``heads_packed``); ``s`` itself where k is 1."""
+    if k == 1:
+        return s
+    *lead, h, n, p = s.shape
+    return jnp.moveaxis(s.reshape(*lead, h // k, k, n, p), -3, -2).reshape(
+        *lead, h // k, n, k * p)
+
+
+def unpack_state(s, k: int):
+    """``pack_state``'s inverse: [..., H / k, N, k P] -> [..., H, N, P]."""
+    if k == 1:
+        return s
+    *lead, hk, n, kp = s.shape
+    return jnp.moveaxis(s.reshape(*lead, hk, n, k, kp // k), -2, -3).reshape(
+        *lead, hk * k, n, kp // k)
 
 
 # -- plain forms ----------------------------------------------------------------
@@ -88,12 +127,13 @@ def scan_from(s0, x, dt, a, bm, cmat, chunk: int):
 
 
 def _update_plain(state, layer, rows, x, dt, a, bm, cmat):
-    s = state[layer, rows].astype(jnp.float32)                    # [b, H, N, P]
     h = x.shape[1]
+    k = h // state.shape[2]
+    s = unpack_state(state[layer, rows], k).astype(jnp.float32)   # [b, H, N, P]
     s = (jnp.exp(dt * a)[..., None, None] * s
          + _by_head(bm, h)[..., :, None] * (x * dt[..., None])[..., None, :])
     y = jnp.einsum("bhnp,bhn->bhp", s, _by_head(cmat, h), precision=_HI)
-    return y, state.at[layer, rows].set(s.astype(state.dtype))
+    return y, state.at[layer, rows].set(pack_state(s, k).astype(state.dtype))
 
 
 def _scan_plain(state, layer, rows, fresh, x, dt, a, bm, cmat, chunk):
@@ -127,11 +167,11 @@ def _update_pallas(state, layer, rows, x, dt, a, bm, cmat, interpret):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    layers, n_rows, h, n, p = state.shape
+    layers, n_rows, h, n, p = state.shape     # rows of ``heads_packed`` heads
     b, g = x.shape[0], bm.shape[1]
     hb = _head_block(h, g)
     per_group = h // g // hb
-    keep = jnp.broadcast_to(jnp.exp(dt * a)[..., None], (b, h, p))
+    keep = jnp.broadcast_to(jnp.exp(dt * a)[..., None], x.shape)
     bc = jnp.stack([bm, cmat], axis=-1)                           # [b, G, N, 2]
     # the layer rides in the row index: the pool is one run of layers * rows
     at = jnp.asarray(rows, jnp.int32) + jnp.asarray(layer, jnp.int32) * n_rows
@@ -142,23 +182,36 @@ def _update_pallas(state, layer, rows, x, dt, a, bm, cmat, interpret):
     def row(i, j, at_ref):
         return (at_ref[i], j, 0, 0)
 
+    lanes, lane_block = (b, h, p), pl.BlockSpec((None, hb, p), lane)
+    if x.shape[1] != h and hb % 8 and hb != h:
+        # packed rows, 4 a group: a block of fewer than 8 head rows does not
+        # tile, so the rows ride on an axis of their own, whole (the block's
+        # last two dims are then the array's); the kernel sees [hb, p] either
+        # way. (A pool a head a row keeps the layout its programs were
+        # recorded with: Falcon-H1's blocks are 16 rows.)
+        lanes = (b, h // hb, hb, p)
+        lane_block = pl.BlockSpec((None, None, hb, p),
+                                  lambda i, j, at_ref: (i, j, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(b, h // hb),
         in_specs=[
             pl.BlockSpec((None, hb, n, p), row),
-            pl.BlockSpec((None, hb, p), lane),
-            pl.BlockSpec((None, hb, p), lane),
+            lane_block,
+            lane_block,
             pl.BlockSpec((None, None, n, 2),
                          lambda i, j, at_ref: (i, j // per_group, 0, 0)),
         ],
-        out_specs=[pl.BlockSpec((None, hb, p), lane),
+        out_specs=[lane_block,
                    pl.BlockSpec((None, hb, n, p), row)],
     )
+    pool, xdt = state.reshape(layers * n_rows, h, n, p), x * dt[..., None]
+    if keep.shape != lanes:  # heads side by side on the lanes: [b, H / k, k P]
+        keep, xdt = keep.reshape(lanes), xdt.reshape(lanes)
     y, pool = pl.pallas_call(
         functools.partial(_update_kernel, hb=hb),
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((b, h, p), jnp.float32),
+        out_shape=[jax.ShapeDtypeStruct(lanes, jnp.float32),
                    jax.ShapeDtypeStruct((layers * n_rows, h, n, p), state.dtype)],
         input_output_aliases={1: 1},
         compiler_params=pltpu.CompilerParams(
@@ -166,8 +219,8 @@ def _update_pallas(state, layer, rows, x, dt, a, bm, cmat, interpret):
             vmem_limit_bytes=64 * 1024 * 1024),
         interpret=interpret,
         name="ssm_state_update",
-    )(at, state.reshape(layers * n_rows, h, n, p), keep, x * dt[..., None], bc)
-    return y, pool.reshape(state.shape)
+    )(at, pool, keep, xdt, bc)
+    return y.reshape(x.shape), pool.reshape(state.shape)
 
 
 def ssm_state_update(state, layer, rows, x, dt, a, bm, cmat, *,
@@ -290,6 +343,15 @@ def ssm_chunk_scan(state, layer, rows, fresh, x, dt, a, bm, cmat, chunk: int, *,
     (all T in one where it does not divide T). Returns (y [b, T, H, P],
     the pool with the rows advanced)."""
     f32 = jnp.float32
+    k = x.shape[2] // state.shape[2]
+    if k > 1:
+        # packed rows: the chunk's rows a head each in a pool of their own
+        # (``fresh`` zeroes them there), scanned, written back packed
+        own = unpack_state(state[layer, rows], k)[None]           # [1, b, H, N, P]
+        y, own = ssm_chunk_scan(own, 0, jnp.arange(x.shape[0]), fresh, x, dt, a,
+                                bm, cmat, chunk, kernel=kernel,
+                                interpret=interpret)
+        return y, state.at[layer, rows].set(pack_state(own[0], k))
     x, dt, a, bm, cmat = (v.astype(f32) for v in (x, dt, a, bm, cmat))
     if kernel:
         return _scan_pallas(state, layer, rows, fresh, x, dt, a, bm, cmat,

@@ -121,6 +121,32 @@ from arkflow_tpu.tpu.tokenizer import build_tokenizer
 
 logger = logging.getLogger("arkflow.generate")
 
+#: a float32 master of this many bytes or more is placed a piece at a time
+#: (``_put_in_pieces``). Measured on a v5e host (ledger PR 61, PERF.md PR 62):
+#: trees whose largest leaf is 3.3 / 4.1 GB place in 2.0 / 2.2 s, one with a
+#: leaf of 5.35 GB in 32 s, one with two of 6.8 GB in 30-42 s
+_PIECES_OVER = 6 << 30
+
+
+def _put_in_pieces(leaf: np.ndarray, dtype, device):
+    """``leaf`` on ``device`` in ``dtype``, an index of its leading axis at a
+    time: the piece is transferred, cast and written into the placed array in
+    place (donated), and waited for — the device holds the placed leaf and one
+    piece's float32 copy, not the leaf's."""
+    import jax
+    import jax.numpy as jnp
+
+    @functools.partial(jax.jit, donate_argnums=0)
+    def write(out, piece, i):
+        return jax.lax.dynamic_update_index_in_dim(
+            out, piece.astype(out.dtype), i, 0)
+
+    out = jnp.zeros(leaf.shape, dtype, device=device)
+    for i in range(leaf.shape[0]):
+        out = write(out, jax.device_put(leaf[i], device),
+                    np.int32(i)).block_until_ready()
+    return out
+
 
 class TpuGenerateProcessor(Processor):
     def __init__(self, model: str, model_config: Optional[dict], *, text_field: str,
@@ -294,8 +320,12 @@ class TpuGenerateProcessor(Processor):
         which ``device_put`` slices on the host, one shard to each chip; as a
         CPU ``jax.Array`` it is staged whole through the mesh's first chip
         (tp=4, 3.76 B weights: 19 s and a 9 GB spike there, against 0.7 s and
-        2.4 GB). Construction, the hot-swap manager and the integrity
-        monitor's repair all place through this."""
+        2.4 GB). On one chip a leaf of ``_PIECES_OVER`` bytes or more that
+        needs the cast goes an index of its leading axis at a time
+        (``_put_in_pieces``: 4.0 B weights of which two leaves are 6.8 GB
+        float32 each, PERF.md PR 62: 30-42 s whole, with both copies of a
+        leaf on the device). Construction, the hot-swap manager and the
+        integrity monitor's repair all place through this."""
         import jax
         from jax.sharding import NamedSharding, PartitionSpec
 
@@ -307,6 +337,9 @@ class TpuGenerateProcessor(Processor):
             if isinstance(leaf, jax.Array) and all(
                     d.platform == "cpu" for d in leaf.devices()):
                 leaf = np.asarray(leaf)  # no copy
+            if (self.mesh is None and leaf.dtype != dtype and leaf.ndim > 1
+                    and leaf.nbytes >= _PIECES_OVER):
+                return _put_in_pieces(leaf, dtype, device)
             placed = jax.device_put(leaf, to)
             if placed.dtype != dtype:
                 placed = placed.astype(dtype).block_until_ready()

@@ -1847,6 +1847,11 @@ class GenerationServer:
         pool = next(p.name for p in cache_spec(self.cfg) if p.per_slot)
         out = {"prompt": prompt, "tokens": tokens, "tenancy": tenancy,
                "state": jax.device_get(self.k_pages[pool][:, row])}
+        if pool == "ssm":  # narrow heads ride side by side: a head a row here
+            from arkflow_tpu.ops.ssm_scan import unpack_state
+
+            out["state"] = np.asarray(unpack_state(
+                out["state"], self.cfg.ssm_heads_packed))
         if self.cfg.linear:  # the pool's second array: the conv windows
             out["window"] = jax.device_get(self.v_pages[pool][:, row])
         if latent and self.cfg.latent and prompt is not None:

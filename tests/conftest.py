@@ -25,6 +25,8 @@ jax.config.update("jax_default_device", jax.devices("cpu")[0])
 import asyncio
 import inspect
 
+import pytest
+
 
 def pytest_pyfunc_call(pyfuncitem):
     """Run ``async def`` tests under asyncio.run (no pytest-asyncio in image)."""
@@ -45,3 +47,20 @@ def pytest_runtest_teardown(item):
     except ImportError:
         return
     bucket_cap_bus().reset()
+
+
+@pytest.fixture(autouse=True, scope="module")
+def _release_executables():
+    """A process maps about ten regions of memory an executable it holds
+    (measured here, PR 62: 12,561 mappings after ``test_nemotron_h.py``'s 55
+    cases), pytest keeps every module it ran alive to the session's end —
+    its jitted steps, its servers, its fixtures — and the kernel allows a
+    process 65,530 (``vm.max_map_count``): a worker that has run enough
+    files dies in the next executable it loads or serialises (``Fatal Python
+    error: Segmentation fault`` under ``compilation_cache``, ROADMAP D0).
+    Dropping JAX's caches where a module ends releases them."""
+    yield
+    import gc
+
+    jax.clear_caches()
+    gc.collect()

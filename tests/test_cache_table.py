@@ -31,6 +31,7 @@ from tests.test_hybrid_ssm import TINY as HYBRID
 from tests.test_kda_mla_moe import TINY as KDA
 from tests.test_mhc_mla_moe import TINY as STREAMS
 from tests.test_mla_moe import TINY as LATENT
+from tests.test_nemotron_h import TINY as ONE_MIXER
 from tests.test_sparse_window_moe import TINY as PATTERN
 from tests.test_window_gqa_moe import TINY as WINDOW
 
@@ -49,6 +50,7 @@ KINDS = {
     "streams": (STREAMS, {"streams", "latent"}),
     "hetero": ({**DENSE, "head_dim": 16, "v_head_dim": 8}, {"kv", "hetero"}),
     "routed": (ROUTED, {"kv", "routed"}),
+    "one_mixer": (ONE_MIXER, {"kv", "ssm", "routed", "one_mixer"}),
     "qk_norm": ({**DENSE, "qk_norm": True}, {"kv", "qk_norm"}),
     "switch": ({**DENSE, "num_experts": 4}, {"kv", "switch"}),
 }

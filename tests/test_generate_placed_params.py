@@ -273,3 +273,25 @@ def test_swap_and_integrity_repair_end_with_a_placed_tree(model, tmp_path):
         await srv.close()
 
     asyncio.run(asyncio.wait_for(go(), timeout=300))
+
+
+def test_a_large_leaf_placed_in_pieces_is_the_leaf_placed_whole(monkeypatch):
+    """Over ``_PIECES_OVER`` bytes a float32 master goes to the device an
+    index of its leading axis at a time: the placed tree is, bit for bit and
+    dtype for dtype, the one placed whole."""
+    from arkflow_tpu.plugins.processor import tpu_generate as tg
+
+    proc = _proc(MODELS["moe"])
+    whole = proc.params
+    pieces = []
+    real = tg._put_in_pieces
+    monkeypatch.setattr(tg, "_PIECES_OVER", 1024)
+    monkeypatch.setattr(tg, "_put_in_pieces", lambda leaf, *a: (
+        pieces.append(leaf.shape), real(leaf, *a))[1])
+    again = proc._place_params(proc.host_params)
+    assert pieces and all(len(s) > 1 for s in pieces)
+    assert _leaf_dtypes(again) == _leaf_dtypes(whole)
+    for a, b in zip(jax.tree_util.tree_leaves(again),
+                    jax.tree_util.tree_leaves(whole)):
+        np.testing.assert_array_equal(np.asarray(a.astype(jnp.float32)),
+                                      np.asarray(b.astype(jnp.float32)))

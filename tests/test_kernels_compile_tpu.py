@@ -1407,3 +1407,140 @@ def test_eva_steps_close_windows_without_copying_the_pools(v5e):
         assert mem.alias_size_in_bytes >= 2 * pool_bytes, name
         assert mem.temp_size_in_bytes < 200 * 1024 * 1024, (
             name, mem.temp_size_in_bytes)
+
+
+# -- one-mixer blocks: Mamba-2 | relu-squared experts | position-free GQA ------
+# (NVIDIA-Nemotron-3-Nano-30B-A3B's widths, the cell's cut and shapes)
+
+NEMOTRON = dict(vocab_size=65536, dim=2688, layers=13, heads=32, kv_heads=2,
+                head_dim=128, max_seq=262144, norm_eps=1e-5,
+                hybrid_override_pattern="MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*"
+                                        "EMEMEMEM*EMEMEMEME",
+                full_attention_rope=False, mamba_n_heads=64, mamba_d_head=64,
+                mamba_d_state=128, mamba_n_groups=8, mamba_d_conv=4,
+                mamba_chunk_size=128, mlp_hidden_act="relu2",
+                n_routed_experts=128, experts_held=(0, 64),
+                num_experts_per_tok=6, n_shared_experts=1,
+                moe_intermediate_size=1856,
+                moe_shared_expert_intermediate_size=3712,
+                routed_scaling_factor=2.5)
+#: 192 slots x 280 kept pages + scratch: every slot's whole table
+NEMOTRON_SLOTS, NEMOTRON_TABLE, NEMOTRON_CHUNK = 192, 280, 512
+
+
+@pytest.mark.parametrize("tokens", [16, 192, 512], ids=["tile", "decode192", "chunk512"])
+def test_moe_expert_relu2_compiles(v5e, tokens):
+    """The two-matrix expert product at Nemotron-3-Nano's widths over the
+    cell's five expert layers (64 held + the shared expert as two): the
+    one-tile kernel up to 128 rows, the grouped one for the cell's 192 lanes
+    and its 512-row chunk; no layer's 1.3 GB is copied out for either. (At
+    the published 1,856 columns, no multiple of 128 lanes, the compiler
+    re-lays the whole stack, 3.3 GB, for every call: the stack holds 1,920,
+    ``DecoderConfig.expert_width_held``.)"""
+    from arkflow_tpu.ops.moe_experts import moe_expert_relu2
+
+    layers, e, d, f = 5, 66, 2688, 1920      # 1,856 as held: whole 128-lane rows
+    compiled = _compile(
+        lambda x, cw, wu, wd, layer: moe_expert_relu2(x, cw, wu, wd, layer),
+        v5e, ((tokens, d), BF16), ((tokens, e), jnp.float32),
+        ((layers, e, d, f), BF16), ((layers, e, f, d), BF16), ((), I32))
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert ("moe_expert_relu2_grouped" in text) == (tokens > 128)
+    assert "moe_expert_relu2" in text and "swiglu" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 1024 * 1024
+
+
+@pytest.mark.parametrize("step", ["update", "scan"])
+def test_ssm_kernels_compile_on_packed_narrow_heads(v5e, step):
+    """The decode update over 192 lanes and the scan of one 512-token chunk
+    at Nemotron-3-Nano's mixer sizes — 64 heads of 64, state 128, 8 groups —
+    on the 2.4 GB state pool AS HELD, two heads side by side on the lanes
+    ([.., 32, 128, 128]): the update runs on the pool in place; the scan
+    takes its one row out a head each and writes it back (a few MB)."""
+    from arkflow_tpu.ops import ssm_scan as ss
+
+    f32 = jnp.float32
+    assert ss.heads_packed(64, 8, 64) == 2
+    pool = ((6, NEMOTRON_SLOTS + 1, 32, 128, 128), f32)
+    if step == "update":
+        b = NEMOTRON_SLOTS
+        fn = lambda st, layer, rows, x, dt, a, bm, cm: ss.ssm_state_update(  # noqa: E731
+            st, layer, rows, x, dt, a, bm, cm, kernel=True)
+        shapes = (pool, ((), I32), ((b,), I32), ((b, 64, 64), f32),
+                  ((b, 64), f32), ((64,), f32), ((b, 8, 128), f32),
+                  ((b, 8, 128), f32))
+    else:
+        t = NEMOTRON_CHUNK
+        fn = lambda st, layer, rows, fr, x, dt, a, bm, cm: ss.ssm_chunk_scan(  # noqa: E731
+            st, layer, rows, fr, x, dt, a, bm, cm, 128, kernel=True)
+        shapes = (pool, ((), I32), ((1,), I32), ((1,), jnp.bool_),
+                  ((1, t, 64, 64), f32), ((1, t, 64), f32), ((64,), f32),
+                  ((1, t, 8, 128), f32), ((1, t, 8, 128), f32))
+    one = SingleDeviceSharding(v5e[0])
+    compiled = jax.jit(fn, donate_argnums=(0,)).lower(
+        *[jax.ShapeDtypeStruct(s, d, sharding=one) for s, d in shapes]).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert ("ssm_state_update" if step == "update" else "ssm_chunk_scan") in text
+    # the update's B and C ride as COLUMNS, [lanes, 8 groups, 128, 2], tiled to
+    # 128 lanes: 100 MB of the 201 (Falcon-H1's [128, 2, 256, 2]: 33 MB)
+    assert compiled.memory_analysis().temp_size_in_bytes < 256 * 1024 * 1024
+
+
+def test_one_mixer_steps_carry_pools_and_stacks_whole(v5e):
+    """The ``_decode`` (192 lanes) and ``_chunk`` (1 x 512) programs of the
+    Nemotron-3-Nano cut as the server jits them, pools donated: thirteen runs
+    of one block each over three stacks walked by index; the per-head kernel
+    on 16 query heads a K/V head, the recurrence's two kernels and the
+    two-matrix expert product are in the text; no pool (2.4 GB of float32
+    states) and no stack's experts (6.6 GB) is copied for a step."""
+    from arkflow_tpu.models import decoder as dec
+    from arkflow_tpu.models.paged_decode import (init_page_pool, paged_decode_step,
+                                                 paged_prefill_chunk)
+
+    cfg = dec.DecoderConfig(**NEMOTRON)
+    repl = SingleDeviceSharding(v5e[0])
+
+    def struct(a, dtype=None):
+        return jax.ShapeDtypeStruct(a.shape, dtype or a.dtype, sharding=repl)
+
+    params = jax.tree_util.tree_map(
+        struct, jax.eval_shape(lambda: dec.init(jax.random.PRNGKey(0), cfg)),
+        dec.serve_dtypes(cfg))
+    kp, vp = jax.tree_util.tree_map(struct, jax.eval_shape(
+        lambda: init_page_pool(cfg, 1 + NEMOTRON_SLOTS * NEMOTRON_TABLE, PAGE,
+                               slots=NEMOTRON_SLOTS)))
+    assert kp["kv"].shape == (2, 1 + 192 * 280, 16, 2, 128)
+    assert kp["ssm"].shape == (6, 193, 32, 128, 128)
+    assert vp["ssm"].shape == (6, 193, 3, 6144)
+    assert params["moe_layers"]["experts"]["w_up"].shape == (5, 66, 2688, 1920)
+    kern = dict(attention_kernel="paged")
+
+    def decode(p, tok, lens, act, table, kp, vp):
+        return paged_decode_step(p, cfg, tok, lens, act, table, kp, vp,
+                                 return_logits=True, **kern)
+
+    def chunk(p, ids, off, clen, table, rows, kp, vp):
+        return paged_prefill_chunk(p, cfg, ids, off, clen, table, kp, vp,
+                                   ssm_rows=rows, **kern)
+
+    def compiled(fn, *operands):
+        n = len(operands)
+        return jax.jit(fn, donate_argnums=(n + 1, n + 2)).lower(
+            params, *[jax.ShapeDtypeStruct(s, d, sharding=repl)
+                      for s, d in operands], kp, vp).compile()
+
+    s = NEMOTRON_SLOTS
+    for kernels, step in (
+            (("ssm_state_update",), compiled(
+                decode, ((s,), I32), ((s,), I32), ((s,), jnp.bool_),
+                ((s, NEMOTRON_TABLE), I32))),
+            (("ssm_chunk_scan",), compiled(
+                chunk, ((1, NEMOTRON_CHUNK), I32), ((1,), I32), ((1,), I32),
+                ((1, NEMOTRON_TABLE), I32), ((1,), I32)))):
+        text = step.as_text()
+        for name in ("paged_flash_attention", "moe_expert_relu2_grouped", *kernels):
+            assert name in text, name
+        assert step.memory_analysis().temp_size_in_bytes < 400 * 1024 * 1024, \
+            step.memory_analysis().temp_size_in_bytes
